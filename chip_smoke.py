@@ -1,0 +1,382 @@
+"""The quickest proof that the system still starts on the chip.
+
+    python chip_smoke.py
+
+drives the main path once on a TPU, through the entry points a user
+calls and at the widths the repo already uses, and checks what comes
+out: ResNet-50 trained through `Executor.run` and through the
+FunctionalProgram step bench.py times, the Program-stack transformer
+trained through the flash-attention kernel (and the kernel checked
+against dense attention), ResNet-50 served over HTTP as serve_cli
+serves it, and — on a host with four chips — ResNet-50 under
+SpmdTrainer.  Weights are random, from a seed; no phase is cut down.
+
+One process holds the chip from start to end and starts no other.  It
+needs no network, no native runtime and no file outside the checkout
+but the compile cache when JAX_COMPILATION_CACHE_DIR places it
+elsewhere.  FLAGS_compile_cache_dir (compile/pcache.py, the home-made
+executable cache) stays off: JAX's own persistent cache is the only
+one here.
+
+Exit code 0 and, as the last line of stdout,
+{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": ...}}
+only when every phase passed on a TPU.  Anything else — no accelerator,
+a failed check, an exception — ends the run non-zero with no such line.
+"""
+
+import http.client
+import json
+import os
+import re
+import shutil
+import sys
+import time
+
+import numpy as np
+
+CHECKOUT = os.path.dirname(os.path.abspath(__file__))
+# bf16 keeps 8 bits of mantissa: errors are judged against the largest
+# magnitude of the reference tensor
+BF16_TOL = 2e-2
+
+
+def check(ok, message):
+    if not ok:
+        raise RuntimeError("chip_smoke: " + message)
+
+
+def check_falling(name, losses):
+    print("  %s losses: %s" % (name, " ".join("%.4f" % v for v in losses)),
+          flush=True)
+    check(all(np.isfinite(losses)), "%s: non-finite loss" % name)
+    check(losses[-1] < losses[0],
+          "%s: loss did not fall (%r)" % (name, losses))
+
+
+def check_on(name, arrays, devices):
+    """Every jax array of `arrays` lives on `devices` and nowhere else."""
+    import jax
+
+    arrays = [a for a in arrays if isinstance(a, jax.Array)]
+    check(arrays, "%s: no device arrays to look at" % name)
+    for a in arrays:
+        check(a.devices() <= devices,
+              "%s: an array sits on %s, outside %s"
+              % (name, a.devices(), devices))
+
+
+class CompileClock:
+    """What JAX compiled since the last `lap()`: seconds inside the
+    backend compile call (a persistent-cache hit counts its load time
+    there) and persistent-cache hits and misses."""
+
+    def __init__(self):
+        import jax.monitoring
+
+        self._seconds = self._hits = self._misses = 0
+        jax.monitoring.register_event_duration_secs_listener(
+            self._on_duration)
+        jax.monitoring.register_event_listener(self._on_event)
+
+    def _on_duration(self, event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            self._seconds += duration
+
+    def _on_event(self, event, **_):
+        if event == "/jax/compilation_cache/cache_hits":
+            self._hits += 1
+        elif event == "/jax/compilation_cache/cache_misses":
+            self._misses += 1
+
+    def lap(self):
+        out = (self._seconds, self._hits, self._misses)
+        self._seconds = self._hits = self._misses = 0
+        return out
+
+
+def scalar(fetch):
+    return float(np.asarray(fetch).reshape(-1)[0])
+
+
+# ---------------------------------------------------------------------------
+# phases
+# ---------------------------------------------------------------------------
+
+def resnet50_train(batch=128, image_size=224, class_dim=1000):
+    import jax
+    import bench
+    import paddle_tpu.fluid as fluid
+
+    devices = set(jax.devices())
+    fluid.amp.enable_bf16()
+    main, startup, _, loss = bench._build_image_model(
+        "resnet50", batch, image_size, class_dim)
+    feeds = bench._image_feeds(batch, image_size, class_dim)
+
+    scope = fluid.Scope()
+    exe = fluid.Executor(fluid.TPUPlace(0))
+    exe.run(startup, scope=scope)
+    losses = [scalar(exe.run(main, feed=feeds, fetch_list=[loss],
+                             scope=scope)[0]) for _ in range(3)]
+    check_falling("Executor.run", losses)
+    check_on("executor scope",
+             [scope.get(n) for n in scope.local_var_names()], devices)
+
+    step, state = bench.functional_step(
+        main, ["image", "label"], loss.name, scope, jax.devices()[0])
+    dev_feeds = jax.device_put(feeds, jax.devices()[0])
+    losses = []
+    for _ in range(5):
+        (fetch,), state = step(state, dev_feeds)
+        losses.append(scalar(fetch))
+    check_falling("FunctionalProgram step", losses)
+    check_on("functional state", state.values(), devices)
+
+
+def flash_kernel_check(shape, causal):
+    """flash_attention against reference_attention on one input:
+    outputs and all three gradients."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.kernels.flash_attention import (flash_attention,
+                                                    reference_attention)
+
+    q, k, v, do = (
+        jax.random.normal(key, shape, jnp.float32).astype(jnp.bfloat16)
+        for key in jax.random.split(jax.random.PRNGKey(0), 4))
+
+    def run(attention):
+        def loss(q, k, v):
+            o = attention(q, k, v, None, causal)
+            return jnp.sum(o.astype(jnp.float32)
+                           * do.astype(jnp.float32)), o
+
+        (_, o), grads = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+        return (o,) + grads
+
+    worst = 0.0
+    for name, got, want in zip(("o", "dq", "dk", "dv"),
+                               run(flash_attention),
+                               run(reference_attention)):
+        got = np.asarray(got, np.float32)
+        want = np.asarray(want, np.float32)
+        check(got.shape == want.shape and np.isfinite(got).all(),
+              "flash %s %s causal=%s: bad shape or non-finite"
+              % (name, shape, causal))
+        err = float(np.abs(got - want).max() / np.abs(want).max())
+        check(err < BF16_TOL,
+              "flash %s %s causal=%s: off the reference by %.4f of its "
+              "largest value" % (name, shape, causal, err))
+        worst = max(worst, err)
+    print("  flash_attention %s causal=%s: within %.4f of the reference"
+          % (shape, causal, worst), flush=True)
+
+
+def transformer_train(batch=16, seq_len=512, d_model=512, n_layer=6,
+                      n_head=8, vocab=8192,
+                      kernel_shapes=((2, 8, 512, 64), (1, 8, 4096, 128))):
+    import jax
+    import bench
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.models.transformer_program import (
+        build_transformer_program, transformer_program_feeds)
+
+    fluid.amp.enable_bf16()
+    main, startup, loss, _ = build_transformer_program(
+        batch, seq_len, vocab, n_layer=n_layer, n_head=n_head,
+        d_model=d_model)
+    with fluid.program_guard(main, startup):
+        fluid.optimizer.MomentumOptimizer(
+            learning_rate=0.01, momentum=0.9).minimize(loss)
+
+    scope = fluid.Scope()
+    fluid.Executor(fluid.TPUPlace(0)).run(startup, scope=scope)
+    dev = jax.devices()[0]
+    step, state = bench.functional_step(
+        main, ["tokens", "positions", "targets"], loss.name, scope, dev)
+    feeds = jax.device_put(
+        transformer_program_feeds(batch, seq_len, vocab), dev)
+    # the Mosaic custom call in the step is the proof that neither the
+    # pallas interpreter nor reference_attention stood in for the kernel
+    check("tpu_custom_call" in step.lower(state, feeds).as_text(),
+          "transformer step lowered without a Mosaic kernel")
+    losses = []
+    for _ in range(3):
+        (fetch,), state = step(state, feeds)
+        losses.append(scalar(fetch))
+    check_falling("transformer step", losses)
+    check_on("transformer state", state.values(), set(jax.devices()))
+
+    for shape in kernel_shapes:
+        for causal in (False, True):
+            flash_kernel_check(shape, causal)
+
+
+def resnet50_serve(image_size=224, class_dim=1000, buckets=(1, 4, 16),
+                   sizes=(1, 2, 4, 3, 8, 16, 5, 1)):
+    import jax
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu import models
+    from paddle_tpu.tools import serve_cli
+
+    fluid.amp.enable_bf16()
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        image = fluid.layers.data(
+            name="image", shape=[3, image_size, image_size],
+            dtype="float32")
+        probs = fluid.layers.softmax(
+            models.resnet50(image, class_dim=class_dim))
+    model_dir = os.path.join(CHECKOUT, "build", "chip_smoke_model")
+    server = None
+    try:
+        exe = fluid.Executor(fluid.TPUPlace(0))
+        with fluid.scope_guard(fluid.Scope()):
+            exe.run(startup)
+            fluid.io.save_inference_model(
+                model_dir, ["image"], [probs], exe,
+                main_program=main.clone(for_test=True))
+
+        server = serve_cli.start_server(serve_cli.parse_args([
+            "--model_dir", model_dir, "--port", "0",
+            "--max_batch", str(max(buckets)),
+            "--batch_buckets", ",".join(map(str, buckets))]))
+        engine = server.engine
+        check(engine.last_warmup_stats is not None,
+              "the server started without warming its buckets")
+        on = engine.param_devices()
+        check(on == {jax.devices()[0]},
+              "engine parameters are on %s, not on %s"
+              % (on, jax.devices()[0]))
+
+        host, port = server.address
+        conn = http.client.HTTPConnection(host, port, timeout=300)
+        rs = np.random.RandomState(0)
+        images = rs.rand(max(sizes), 3, image_size,
+                         image_size).astype(np.float32).round(3)
+        first = {}
+        for n in sizes:
+            conn.request(
+                "POST", "/v1/infer",
+                json.dumps({"inputs": {"image": images[:n].tolist()}}),
+                {"Content-Type": "application/json"})
+            resp = conn.getresponse()
+            payload = json.loads(resp.read())
+            check(resp.status == 200,
+                  "POST /v1/infer of %d image(s) answered %d: %r"
+                  % (n, resp.status, payload))
+            out = np.asarray(payload["outputs"][engine.fetch_names[0]])
+            check(out.shape == (n, class_dim) and np.isfinite(out).all(),
+                  "%d image(s): bad output shape %s or non-finite"
+                  % (n, out.shape))
+            check(np.abs(out.sum(axis=1) - 1).max() < BF16_TOL,
+                  "%d image(s): a row of probabilities does not sum "
+                  "to 1 (%r)" % (n, out.sum(axis=1)))
+            # image 0 leads every request.  Requests padded to the same
+            # bucket run the same executable, where a sample's answer
+            # does not depend on its neighbours: padding and slicing
+            # must hand back the very same row
+            same = first.setdefault(engine.config.bucket_for(n), out[0])
+            check(np.abs(out[0] - same).max() < 1e-6,
+                  "image 0 answered differently in a request of %d" % n)
+
+        conn.request("GET", "/metrics")
+        metrics = conn.getresponse().read().decode()
+        conn.close()
+        miss = re.search(r"^serving_compile_cache_miss_total (\S+)$",
+                         metrics, re.M)
+        hit = re.search(r"^serving_compile_cache_hit_total (\S+)$",
+                        metrics, re.M)
+        check(miss and hit, "/metrics lacks the compile-cache counters")
+        check(float(miss.group(1)) == 0 and float(hit.group(1)) > 0,
+              "/metrics shows a compile after warmup: %s miss(es), %s "
+              "hit(s)" % (miss.group(1), hit.group(1)))
+        print("  %d requests answered 200; compile-cache %s hit(s), 0 "
+              "misses after warmup" % (len(sizes), hit.group(1)),
+              flush=True)
+    finally:
+        if server is not None:
+            server.shutdown()
+        shutil.rmtree(model_dir, ignore_errors=True)
+
+
+def multichip(n_devices=4, batch=512, image_size=224, class_dim=1000):
+    import jax
+    from jax.sharding import NamedSharding
+    import bench
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.parallel import make_mesh
+    from paddle_tpu.parallel.sharding import batch_spec
+    from paddle_tpu.spmd import SpmdTrainer
+
+    fluid.amp.enable_bf16()
+    mesh = make_mesh(n_devices=n_devices)
+    main, startup, _, loss = bench._build_image_model(
+        "resnet50", batch, image_size, class_dim)
+    trainer = SpmdTrainer(main, startup, feed_names=["image", "label"],
+                          fetch_names=[loss.name], mesh=mesh)
+    trainer.init()
+    feeds = {
+        n: jax.device_put(v, NamedSharding(
+            mesh, batch_spec(v.shape, mesh)))
+        for n, v in bench._image_feeds(batch, image_size,
+                                       class_dim).items()}
+    for n, v in feeds.items():
+        check(len(v.sharding.device_set) == n_devices,
+              "feed %s is on %d device(s), not %d"
+              % (n, len(v.sharding.device_set), n_devices))
+    losses = [scalar(trainer.step(feeds)[0]) for _ in range(3)]
+    check_falling("SpmdTrainer step", losses)
+    for n, v in trainer.state.items():
+        check(v.sharding.device_set == set(mesh.devices.flat),
+              "state %s is on %s, not on the mesh" % (n, v.devices()))
+
+
+# ---------------------------------------------------------------------------
+
+def main():
+    import jax
+
+    devices = jax.devices()
+    dev = devices[0]
+    print("jax %s platform=%s device_kind=%s device_count=%d"
+          % (jax.__version__, dev.platform, dev.device_kind,
+             len(devices)), flush=True)
+    if dev.platform != "tpu":
+        print("chip_smoke: JAX found no TPU (platform %s)" % dev.platform,
+              file=sys.stderr)
+        return 1
+
+    from paddle_tpu.utils.compile_cache import enable_compile_cache
+
+    print("compile cache: %s" % enable_compile_cache(), flush=True)
+    clock = CompileClock()
+    phases = [resnet50_train, transformer_train, resnet50_serve]
+    if len(devices) >= 4:
+        phases.append(multichip)
+    for phase in phases:
+        name = phase.__name__.replace("_", "-")
+        print("phase %s ..." % name, flush=True)
+        t0 = time.perf_counter()
+        phase()
+        seconds, hits, misses = clock.lap()
+        print("phase %s: passed on platform=%s device_kind=%s — wall "
+              "%.1fs, compile %.1fs, cache %d hit(s) %d miss(es)"
+              % (name, dev.platform, dev.device_kind,
+                 time.perf_counter() - t0, seconds, hits, misses),
+              flush=True)
+    if len(devices) < 4:
+        print("multichip: not run, %d chip(s)" % len(devices), flush=True)
+
+    from paddle_tpu import native
+
+    check(native._lib is None, "something loaded the native runtime")
+    print(json.dumps({"ok": True, "device": {
+        "platform": dev.platform, "kind": dev.device_kind,
+        "count": len(devices)}}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

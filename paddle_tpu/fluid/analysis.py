@@ -47,11 +47,20 @@ import numpy as np
 from ..core.types import GRAD_SUFFIX
 from ..ops import registry as op_registry
 
-__all__ = ["program_costs", "roofline_report", "format_report"]
+__all__ = ["program_costs", "roofline_report", "format_report",
+           "DEVICE_PEAKS"]
 
-# v5e-class defaults; override per call for other parts
-DEFAULT_PEAK_TFLOPS = 197.0
-DEFAULT_HBM_GBPS = 819.0
+# Published peaks of one chip, keyed by jax's `device_kind` (Google
+# Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM).  The
+# one table: a kind that is not here has no peak, and whoever measures
+# on it reports no utilization instead of assuming one.
+DEVICE_PEAKS = {
+    "TPU v5 lite": {"bf16_tflops": 197.0, "hbm_gbps": 819.0},
+}
+
+# the static reports price a program for the v5e unless told otherwise
+DEFAULT_PEAK_TFLOPS = DEVICE_PEAKS["TPU v5 lite"]["bf16_tflops"]
+DEFAULT_HBM_GBPS = DEVICE_PEAKS["TPU v5 lite"]["hbm_gbps"]
 
 _MXU_FWD = {"conv2d", "conv3d", "conv2d_transpose", "conv3d_transpose",
             "depthwise_conv2d", "mul", "matmul"}

@@ -139,7 +139,11 @@ class TPUPlace(Place):
 
     def device(self):
         devs = jax.devices()
-        return devs[self.device_id % len(devs)]
+        if not 0 <= self.device_id < len(devs):
+            raise ValueError(
+                "%r: the %s platform has %d device(s)"
+                % (self, devs[0].platform, len(devs)))
+        return devs[self.device_id]
 
     def __repr__(self):
         return "TPUPlace(%d)" % self.device_id
@@ -755,11 +759,12 @@ class _CompiledProgram:
         double compile: per (segment, signature) the FIRST build is
         `fn.lower().compile()` — the memory/cost analyses are
         published from that artifact AND the artifact executes the
-        step, so attribution costs zero extra XLA compiles (the AOT
-        path does not share the jit call path's executable cache,
-        measured on jax 0.4.37 — hence executing the artifact instead
-        of discarding it).  Returns (outs, rng), or None to fall back
-        to the jit call path: an unknown signature with
+        step, so attribution costs zero extra XLA compiles.  (Written
+        when a jit call after `lower().compile()` compiled again; at
+        jax 0.9.0 it does not — the two share the executable, checked
+        on the CPU backend — so executing the artifact is a habit now,
+        not a saving: ROADMAP Design 2.)  Returns (outs, rng), or None
+        to fall back to the jit call path: an unknown signature with
         `allow_compile` off (post-warmup retraces, and signatures
         already warm in the jit cache, compile through the normal jit
         path), a failed lowering, or a signature quarantined by an

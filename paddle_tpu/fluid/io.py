@@ -33,15 +33,24 @@ def is_persistable(var):
     return var.persistable
 
 
+def _host_values(value):
+    """bf16 is an internal compute dtype (a startup program run under
+    AMP leaves its outputs in it) that numpy's file formats cannot
+    name — it comes back as raw `|V2` — so files hold f32, like every
+    fetch (Executor._to_numpy)."""
+    arr = np.asarray(value)
+    return arr.astype(np.float32) if arr.dtype.name == "bfloat16" else arr
+
+
 def _save_one(dirname, name, value):
     path = os.path.join(dirname, name.replace("/", "_"))
     if isinstance(value, RaggedTensor):
-        np.savez(path, __ragged__=1, values=np.asarray(value.values),
+        np.savez(path, __ragged__=1, values=_host_values(value.values),
                  nvalid=np.asarray(value.nvalid),
                  **{"rs%d" % i: np.asarray(rs)
                     for i, rs in enumerate(value.row_splits)})
     else:
-        np.savez(path, __ragged__=0, values=np.asarray(value))
+        np.savez(path, __ragged__=0, values=_host_values(value))
 
 
 def _load_one(dirname, name, missing_ok=False, fileobj=None):

@@ -3,12 +3,15 @@
 No reference counterpart (the 2018 snapshot predates flash attention;
 its attention is composed ops — reference: python/paddle/v2/fluid/
 nets.py:338 scaled_dot_product_attention materializes the full [T,T]
-probability matrix).  This kernel never materializes T×T in HBM: K/V
-stream through VMEM in blocks with running max/sum accumulation, the
+probability matrix).  This kernel never materializes T×T in HBM: the
+grid's innermost axis walks K/V one (block_k, d) tile at a time, the
+running max/sum/accumulator live in VMEM scratch across that axis, the
 MXU sees [block_q, d] x [d, block_k] matmuls, and the backward pass
-recomputes probabilities blockwise (custom VJP).
+recomputes probabilities blockwise (custom VJP, plain XLA).
 
-On CPU (tests) the same kernel runs under pallas interpret mode.
+The kernel compiles through Mosaic when lowered for the TPU and runs
+under pallas interpret mode when lowered for the CPU (tests, dry runs);
+any other platform is refused at lowering.
 """
 
 import functools
@@ -16,97 +19,128 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+_LANES = 128
 
 
-def _needs_interpret():
-    return jax.default_backend() != "tpu"
+def _block(seq, block, what, shape):
+    """The block size along one sequence axis.  It must divide the
+    sequence: the grid has no ragged last tile, and shrinking the block
+    until it fits would hand the MXU slivers without saying so."""
+    block = min(block, seq)
+    if seq % block:
+        raise ValueError(
+            "flash_attention: %s length %d of shape %s is not a multiple "
+            "of its block size %d" % (what, seq, tuple(shape), block))
+    return block
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, *, block_k,
-                sm_scale, causal, q_offset):
-    """One (batch*head, q_block) program: stream K/V blocks with online
-    softmax accumulation."""
-    q = q_ref[...] * sm_scale                    # [bq, d]
-    bq, d = q.shape
-    kt = k_ref[...]                              # [Tk, d]
-    vt = v_ref[...]                              # [Tk, d]
-    Tk = kt.shape[0]
-    q_idx = pl.program_id(1)
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, m_scr, l_scr,
+                acc_scr, *, sm_scale, causal, q_offset):
+    """One (batch*head, q_block, k_block) grid step.  The k_block axis
+    is innermost and sequential: scratch is initialised on its first
+    step, folded into on every step and written out on its last."""
+    bq, bk = q_ref.shape[0], k_ref.shape[0]
+    i, j = pl.program_id(1), pl.program_id(2)
 
-    m = jnp.full((bq,), NEG_INF, jnp.float32)
-    l = jnp.zeros((bq,), jnp.float32)
-    acc = jnp.zeros((bq, d), jnp.float32)
+    @pl.when(j == 0)
+    def _init():
+        m_scr[...] = jnp.full(m_scr.shape, NEG_INF, jnp.float32)
+        l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+        acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
 
-    nblocks = Tk // block_k
-
-    def body(i, carry):
-        m, l, acc = carry
-        k_blk = jax.lax.dynamic_slice_in_dim(kt, i * block_k, block_k)
-        v_blk = jax.lax.dynamic_slice_in_dim(vt, i * block_k, block_k)
-        s = jnp.dot(q, k_blk.T,
-                    preferred_element_type=jnp.float32)  # [bq, bk]
+    def _fold():
+        s = jax.lax.dot_general(
+            q_ref[...], k_ref[...], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * sm_scale   # [bq, bk]
         if causal:
-            q_pos = q_offset + q_idx * bq + jax.lax.broadcasted_iota(
-                jnp.int32, (bq, block_k), 0)
-            k_pos = i * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (bq, block_k), 1)
+            q_pos = q_offset + i * bq + jax.lax.broadcasted_iota(
+                jnp.int32, (bq, bk), 0)
+            k_pos = j * bk + jax.lax.broadcasted_iota(
+                jnp.int32, (bq, bk), 1)
             s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
-        alpha = jnp.exp(m - m_new)
-        l_new = l * alpha + jnp.sum(p, axis=1)
-        acc_new = acc * alpha[:, None] + jnp.dot(
-            p.astype(vt.dtype), v_blk,
+        # m/l are [bq, 1] columns here; the scratch keeps them
+        # replicated across a vreg's lanes
+        m_prev, l_prev = m_scr[:, :1], l_scr[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+        acc_scr[...] = alpha * acc_scr[...] + jnp.dot(
+            p.astype(v_ref.dtype), v_ref[...],
             preferred_element_type=jnp.float32)
-        return m_new, l_new, acc_new
+        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
 
-    m, l, acc = jax.lax.fori_loop(0, nblocks, body, (m, l, acc))
-    safe_l = jnp.where(l > 0, l, 1.0)
-    o_ref[...] = (acc / safe_l[:, None]).astype(o_ref.dtype)
-    m_ref[...] = m
-    l_ref[...] = l
+    if causal:
+        # blocks wholly above the diagonal contribute nothing
+        pl.when(j * bk <= q_offset + (i + 1) * bq - 1)(_fold)
+    else:
+        _fold()
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _finish():
+        l = l_scr[:, :1]
+        o_ref[...] = (acc_scr[...] / jnp.where(l > 0, l, 1.0)).astype(
+            o_ref.dtype)
+        # m/l leave as [1, bq] rows: a row is what the (8, 128) tiling
+        # rule lets a per-position vector be stored as
+        m_ref[...] = m_scr[...].T[:1]
+        l_ref[...] = l_scr[...].T[:1]
 
 
 def _fwd(q, k, v, sm_scale, causal, block_q, block_k, q_offset):
     B, H, Tq, D = q.shape
     Tk = k.shape[2]
-    bq = min(block_q, Tq)
-    bk = min(block_k, Tk)
-    while Tq % bq:
-        bq //= 2
-    while Tk % bk:
-        bk //= 2
-    bq, bk = max(bq, 1), max(bk, 1)
+    bq = _block(Tq, block_q, "query", q.shape)
+    bk = _block(Tk, block_k, "key", k.shape)
 
-    qf = q.reshape(B * H, Tq, D)
-    kf = k.reshape(B * H, Tk, D)
-    vf = v.reshape(B * H, Tk, D)
+    def kv_index(b, i, j):
+        if causal:
+            # a skipped block re-names the last visible one, so the
+            # pipeline does not fetch what the kernel will not read
+            j = jnp.minimum(j, (q_offset + (i + 1) * bq - 1) // bk)
+        return (b, j, 0)
 
-    grid = (B * H, Tq // bq)
-    kernel = functools.partial(_fwd_kernel, block_k=bk, sm_scale=sm_scale,
-                               causal=causal, q_offset=q_offset)
-    o, m, l = pl.pallas_call(
-        kernel,
-        grid=grid,
+    call = functools.partial(
+        pl.pallas_call,
+        functools.partial(_fwd_kernel, sm_scale=sm_scale, causal=causal,
+                          q_offset=q_offset),
+        grid=(B * H, Tq // bq, Tk // bk),
         in_specs=[
-            pl.BlockSpec((None, bq, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((None, Tk, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((None, Tk, D), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((None, bq, D), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((None, bk, D), kv_index),
+            pl.BlockSpec((None, bk, D), kv_index),
         ],
         out_specs=[
-            pl.BlockSpec((None, bq, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((None, bq), lambda b, i: (b, i)),
-            pl.BlockSpec((None, bq), lambda b, i: (b, i)),
+            pl.BlockSpec((None, bq, D), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((None, 1, bq), lambda b, i, j: (b, 0, i)),
+            pl.BlockSpec((None, 1, bq), lambda b, i, j: (b, 0, i)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B * H, Tq, D), q.dtype),
-            jax.ShapeDtypeStruct((B * H, Tq), jnp.float32),
-            jax.ShapeDtypeStruct((B * H, Tq), jnp.float32),
+            jax.ShapeDtypeStruct((B * H, 1, Tq), jnp.float32),
+            jax.ShapeDtypeStruct((B * H, 1, Tq), jnp.float32),
         ],
-        interpret=_needs_interpret(),
-    )(qf, kf, vf)
+        scratch_shapes=[
+            pltpu.VMEM((bq, _LANES), jnp.float32),
+            pltpu.VMEM((bq, _LANES), jnp.float32),
+            pltpu.VMEM((bq, D), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="flash_attention_fwd",
+    )
+    # chosen by the platform the computation is lowered for, not by the
+    # default backend: an export for the TPU from a CPU host gets the
+    # Mosaic kernel, a CPUPlace program on a TPU host gets the
+    # interpreter, and with no default branch anything else is an error
+    o, m, l = jax.lax.platform_dependent(
+        q.reshape(B * H, Tq, D), k.reshape(B * H, Tk, D),
+        v.reshape(B * H, Tk, D),
+        tpu=call(interpret=False), cpu=call(interpret=True))
     return (o.reshape(B, H, Tq, D), m.reshape(B, H, Tq),
             l.reshape(B, H, Tq))
 
@@ -142,10 +176,7 @@ def _flash_bwd_rule(sm_scale, causal, block_q, block_k, q_offset, res,
         sm_scale = q.shape[-1] ** -0.5
     B, H, Tq, D = q.shape
     Tk = k.shape[2]
-    bk = min(block_k, Tk)
-    while Tk % bk:
-        bk //= 2
-    bk = max(bk, 1)
+    bk = _block(Tk, block_k, "key", k.shape)
 
     safe_l = jnp.where(l > 0, l, 1.0)
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),

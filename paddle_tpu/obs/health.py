@@ -21,7 +21,7 @@ silently.  This module closes the gap in three layers:
     EAGERLY with FLAGS_check_nan_inf set and returns the first op
     whose output went non-finite (op type, index, output var) — the
     bisection the eager-only flag almost gives us today.
-  * `publish_compile_stats(segment, compiled)` — best-effort
+  * `publish_compile_stats(segment, compiled)` —
     `compiled.memory_analysis()` / `cost_analysis()` capture at
     jit-build time (FLAGS_xla_cost_attribution), exported as
     per-segment-label gauges `xla_temp_bytes`, `xla_argument_bytes`,
@@ -384,17 +384,13 @@ _COST_GAUGES = (
 
 
 def publish_compile_stats(segment, compiled):
-    """Best-effort capture of `compiled.memory_analysis()` /
-    `cost_analysis()` into per-segment-label gauges.  Returns the dict
-    of published values, or None when the runtime exposes neither
-    analysis (older jaxlibs, some backends) — skipping is graceful by
-    contract."""
+    """Capture `compiled.memory_analysis()` / `cost_analysis()` (a
+    CompiledMemoryStats and one dict of properties, as jax 0.9.0
+    returns them) into per-segment-label gauges.  Returns the dict of
+    published values, or None when the executable reports neither."""
     reg = registry_mod.get_registry()
     published = {}
-    try:
-        ma = compiled.memory_analysis()
-    except Exception:
-        ma = None
+    ma = compiled.memory_analysis()
     if ma is not None:
         for gauge, attr, help_text in _MEMORY_GAUGES:
             v = getattr(ma, attr, None)
@@ -403,19 +399,14 @@ def publish_compile_stats(segment, compiled):
             reg.gauge(gauge, help_text, labelnames=("segment",)) \
                .labels(segment=segment).set(int(v))
             published[gauge] = int(v)
-    try:
-        ca = compiled.cost_analysis()
-    except Exception:
-        ca = None
-    if ca:
-        c0 = ca[0] if isinstance(ca, (list, tuple)) else ca
-        for gauge, key, help_text in _COST_GAUGES:
-            v = c0.get(key) if hasattr(c0, "get") else None
-            if v is None:
-                continue
-            reg.gauge(gauge, help_text, labelnames=("segment",)) \
-               .labels(segment=segment).set(float(v))
-            published[gauge] = float(v)
+    ca = compiled.cost_analysis() or {}
+    for gauge, key, help_text in _COST_GAUGES:
+        v = ca.get(key)
+        if v is None:
+            continue
+        reg.gauge(gauge, help_text, labelnames=("segment",)) \
+           .labels(segment=segment).set(float(v))
+        published[gauge] = float(v)
     if published:
         # the memory-observability side of the same capture: obs.mem
         # stores the actuals for the static-vs-XLA drift join and the

@@ -755,7 +755,7 @@ def build_tiny_engine(dim=16, classes=4, buckets=(1, 2, 4, 8)):
         probs = fluid.layers.fc(input=hidden, size=classes,
                                 act="softmax")
     scope = Scope()
-    exe = fluid.Executor(fluid.CPUPlace())
+    exe = fluid.Executor()
     with fluid.scope_guard(scope):
         exe.run(startup)
     program = fluid_io.prune_program(main, [probs])
@@ -774,6 +774,8 @@ def run_serving_bench():
     BENCH_SERVING_N (requests, 400), BENCH_SERVING_MIX ("1:2,2:1,4:1"),
     BENCH_SERVING_SLO_MS (50), BENCH_SERVING_SEED (0)."""
     import os
+
+    import jax
 
     from paddle_tpu.serving import InferenceServer, ServerConfig
 
@@ -801,12 +803,8 @@ def run_serving_bench():
     finally:
         server.shutdown()
 
-    try:
-        import jax
-
-        platform = jax.default_backend()
-    except Exception:  # noqa: BLE001 — the leg must not need a device
-        platform = "cpu"
+    # the device the engine's parameters are on, not the one asked for
+    (device,) = engine.param_devices()
     mix_tag = ",".join("%d:%g" % (b, w)
                        for b, w in mix.weights.items())
     return {
@@ -816,7 +814,9 @@ def run_serving_bench():
         "step_ms": None,
         "mfu": None,
         "amp_bf16": False,
-        "platform": platform,
+        "platform": device.platform,
+        "device_kind": device.device_kind,
+        "device_count": len(jax.devices()),
         "latency": latency_blob(report),
         "config": {"model": "tiny-fc", "mode": "serving",
                    "rate": rate, "n": n, "mix": mix_tag,

@@ -677,8 +677,7 @@ class GateResult:
 def is_stale_platform(platform):
     """True when a record's platform string marks a stale/degraded
     re-emit (`*-stale`, `*-fallback`, or empty) — the class the gate
-    hard-fails.  Public so emitters (scripts/mega_bench.py) can warn
-    at EMIT time instead of leaving the discovery to gate time."""
+    hard-fails (the history still holds such records)."""
     p = str(platform or "")
     return p.endswith("-stale") or p.endswith("-fallback") or p == ""
 

@@ -22,12 +22,11 @@ from .registry import register_op
 
 
 def _ambient_mesh():
-    """The mesh of the enclosing `with mesh:` scope (empty Mesh if not
-    inside one) — how a program-level op discovers the sp topology
-    without threading a mesh argument through every layer."""
-    from jax._src import mesh as mesh_lib
-
-    return mesh_lib.thread_resources.env.physical_mesh
+    """The (abstract) mesh of the enclosing `with jax.set_mesh(mesh):`
+    scope, empty if not inside one — how a program-level op discovers
+    the sp topology without threading a mesh argument through every
+    layer."""
+    return jax.sharding.get_abstract_mesh()
 
 
 def _split_heads(x, num_heads):

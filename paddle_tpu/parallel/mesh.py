@@ -154,26 +154,13 @@ def make_mesh(n_devices=None, dp=None, mp=1, sp=1, pp=1, ep=1,
     if devices is None:
         devices = jax.devices()
         if n_devices is not None and len(devices) < n_devices:
-            # asked for more chips than the default platform has (e.g.
-            # a dry run on a host with one real TPU): fall back to the
-            # virtual CPU devices ONLY when the caller deliberately
-            # provisioned enough of them via
-            # xla_force_host_platform_device_count; otherwise this is a
-            # genuine under-provisioning error — say so.
-            try:
-                cpu_devices = jax.devices("cpu")
-            except RuntimeError:  # cpu backend excluded by JAX_PLATFORMS
-                cpu_devices = []
-            if len(cpu_devices) >= n_devices:
-                devices = cpu_devices
-            else:
-                raise ValueError(
-                    "requested a %d-device mesh but only %d %s device(s)"
-                    " are available (and %d virtual CPU devices); set "
-                    "xla_force_host_platform_device_count for a CPU dry "
-                    "run or pass devices= explicitly"
-                    % (n_devices, len(devices), devices[0].platform,
-                       len(cpu_devices)))
+            raise ValueError(
+                "requested a %d-device mesh but the %s platform has %d "
+                "device(s); a CPU dry run selects the cpu platform "
+                "itself (JAX_PLATFORMS=cpu with "
+                "xla_force_host_platform_device_count), or pass "
+                "devices= explicitly"
+                % (n_devices, devices[0].platform, len(devices)))
     if n_devices is None:
         n_devices = len(devices)
     devices = devices[:n_devices]

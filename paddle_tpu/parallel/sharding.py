@@ -9,6 +9,7 @@ gets a NamedSharding over the mesh and XLA GSPMD partitions the program.
 import re as _re
 
 import jax
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 __all__ = ["param_spec", "param_spec_reason", "batch_spec", "replicated",
@@ -179,14 +180,6 @@ def zero1_spec(base_spec, shape, mesh, dp_axis="dp"):
 
 
 def shard_map_norep(fn, **kwargs):
-    """shard_map with replication checking off, across jax versions
-    (`check_vma` replaced `check_rep`).  One shim shared by the ring /
-    pipeline / moe modules so the compat logic can't drift."""
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
-    try:
-        return shard_map(fn, check_vma=False, **kwargs)
-    except TypeError:
-        return shard_map(fn, check_rep=False, **kwargs)
+    """shard_map with replication checking off — the one spelling the
+    ring / pipeline / moe / spmd modules share."""
+    return shard_map(fn, check_vma=False, **kwargs)

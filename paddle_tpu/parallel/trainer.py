@@ -246,7 +246,7 @@ class ParallelTrainer:
                                step=step_id):
                 # trace under the mesh context so mesh-aware op kernels
                 # (ring flash_attention) see the sp topology
-                with self.mesh:
+                with jax.set_mesh(self.mesh):
                     fetches, self.state = self._step_fn(self.state,
                                                         feeds, rng)
                 jax.block_until_ready(fetches)

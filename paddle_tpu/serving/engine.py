@@ -126,14 +126,14 @@ class InferenceEngine:
         self.fetch_names = [
             f.name if isinstance(f, framework.Variable) else str(f)
             for f in fetch_list]
-        self.place = place or executor_mod.CPUPlace()
+        self._exe = executor_mod.Executor(place)
+        self.place = self._exe.place
         self.config = config or EngineConfig()
         # scope=None tracks the *current* global scope at each run
         # (offline v2.infer semantics); pass an explicit Scope for an
         # isolated parameter store (from_saved_model does)
         self.scope = scope
         self.metrics = metrics
-        self._exe = executor_mod.Executor(self.place)
         self._lock = threading.Lock()
         self.last_warmup_stats = None  # set by warmup()
         # feed_meta: the export-time metadata dict from
@@ -161,7 +161,7 @@ class InferenceEngine:
         from ..fluid import io as fluid_io
 
         scope = Scope()
-        exe = executor_mod.Executor(place or executor_mod.CPUPlace())
+        exe = executor_mod.Executor(place)
         with executor_mod.scope_guard(scope):
             program, feed_names, fetch_vars, extra = \
                 fluid_io.load_inference_model(
@@ -177,6 +177,20 @@ class InferenceEngine:
         return cls(program, feed_names, fetch_vars, place=place,
                    config=config, scope=scope, metrics=metrics,
                    feed_meta=extra.get("feed_meta"))
+
+    def param_devices(self):
+        """The devices this engine's parameters are on, read from the
+        arrays themselves rather than from the place that was asked
+        for."""
+        import jax
+
+        scope = self.scope if self.scope is not None else global_scope()
+        devices = set()
+        for var in self.program.global_block().vars.values():
+            val = scope.get(var.name) if var.persistable else None
+            if isinstance(val, jax.Array):
+                devices |= val.devices()
+        return devices
 
     def _var_meta(self, name):
         var = self.program.global_block().var(name)

@@ -205,7 +205,7 @@ class SpmdTrainer(ParallelTrainer):
                         None, self._state_template, self._fetch_all,
                         donate_state=False)
                 t0 = time.perf_counter()
-                with self.mesh:
+                with jax.set_mesh(self.mesh):
                     compiled = fn.lower(
                         self.state, feeds, rng).compile()
                 cache.put(key, compiled,

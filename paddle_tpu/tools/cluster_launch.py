@@ -82,8 +82,10 @@ def launch(script_argv, pservers, trainers, sync=True, env=None,
         for ep in pservers:
             ps_procs.append(subprocess.Popen(
                 [python, "-c", code],
+                # a pserver needs no chip and must not take the
+                # trainer's: a chip belongs to one process at a time
                 env={**base_env, "TRAINING_ROLE": "PSERVER",
-                     "PSERVER_ENDPOINT": ep},
+                     "PSERVER_ENDPOINT": ep, "JAX_PLATFORMS": "cpu"},
                 stdout=subprocess.PIPE, text=True))
         # trainers have no connect retry: wait until every pserver has
         # bound its port (and, elastic, registered) before spawning them
@@ -200,7 +202,7 @@ def launch_remote(script_argv, hosts, trainers_per_host=1, base_port=7164,
             ps_procs.append(_ssh_popen(
                 ssh_cmd, host, workdir,
                 {**base_env, "TRAINING_ROLE": "PSERVER",
-                 "PSERVER_ENDPOINT": ep},
+                 "PSERVER_ENDPOINT": ep, "JAX_PLATFORMS": "cpu"},
                 ["-c", _pserver_code("stdin")], python,
                 stdin=subprocess.PIPE,
                 stdout=subprocess.PIPE, text=True))

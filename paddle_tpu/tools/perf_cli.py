@@ -7,7 +7,7 @@ inspection, and the noise-aware regression gate over
 
     # roofline + bottleneck verdict for a bench model (pass --step-ms
     # to classify a measured step against its floors):
-    PYTHONPATH= JAX_PLATFORMS=cpu python -m paddle_tpu.tools.perf_cli \
+    JAX_PLATFORMS=cpu python -m paddle_tpu.tools.perf_cli \
         classify --model resnet50 --batch 128 --step-ms 51.8
 
     # the regression gate (exit 1 on regression — wire into CI after

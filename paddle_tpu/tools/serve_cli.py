@@ -103,6 +103,17 @@ def _serve(engine, args, ready=None):
     return server
 
 
+def start_server(args):
+    """Load the export named by `args` and start serving it: all of
+    main() short of waiting for a signal.  The engine is given no
+    place, so it sits on JAX's default device."""
+    from paddle_tpu.serving import InferenceEngine
+
+    engine = InferenceEngine.from_saved_model(
+        args.model_dir, config=_engine_config(args))
+    return _serve(engine, args)
+
+
 def _install_drain_handlers(server, done):
     def drain(signum, frame):
         print("[serve] signal %d: draining ..." % signum, flush=True)
@@ -172,16 +183,15 @@ def _selftest(args):
 
 def main(argv=None):
     args = parse_args(argv)
+
+    from paddle_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.selftest:
         return _selftest(args)
     if not args.model_dir:
         raise SystemExit("--model_dir is required (or --selftest)")
-
-    from paddle_tpu.serving import InferenceEngine
-
-    engine = InferenceEngine.from_saved_model(
-        args.model_dir, config=_engine_config(args))
-    server = _serve(engine, args)
+    server = start_server(args)
     done = threading.Event()
     _install_drain_handlers(server, done)
     done.wait()

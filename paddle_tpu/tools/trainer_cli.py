@@ -50,6 +50,9 @@ def main(argv=None):
     import paddle_tpu as paddle
     import paddle_tpu.v2 as v2
     from paddle_tpu.trainer_config_helpers import config as tc_config
+    from paddle_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     cfg = tc_config.reset_config()
     # execute the config: its DSL calls build into the default Program

@@ -169,7 +169,6 @@ def _rank_plan(args, extra_candidates=(), hbm_gb="arg"):
 
     # explicit disable on --f32: amp state is process-global, and a
     # prior in-process plan (or library caller) may have enabled it
-    # (the mega_bench run_one convention)
     if args.bf16:
         fluid.amp.enable_bf16()
     else:

@@ -165,8 +165,7 @@ def measure_plan(plan, topk=3, history=None, iters=2, warmup=1,
                 env=env, capture_output=True, text=True,
                 timeout=timeout)
         except subprocess.TimeoutExpired:
-            # one wedged compile forfeits its leg, never the rest
-            # (the mega_bench subprocess-guard convention)
+            # one hung compile forfeits its leg, never the rest
             results.append({"tag": tag, "ok": False,
                             "error": "bench.py exceeded the %gs "
                             "budget" % timeout})

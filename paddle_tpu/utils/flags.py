@@ -97,9 +97,8 @@ DEFINE_flag("xla_cost_attribution", False,
             "_run_attr_aot) — one XLA compile, no throwaway capture "
             "compile.  Default off only because the flag changes the "
             "dispatch path (AOT call instead of jax.jit's) for "
-            "segments it touched; serving warmup and mega_bench's "
-            "non-risky legs enable it, the surfaces whose /metrics "
-            "and BENCH artifacts consume the attribution")
+            "segments it touched; serving warmup enables it, the "
+            "surface whose /metrics consumes the attribution")
 DEFINE_flag("mem_budget_gb", 0.0,
             "OOM pre-flight (obs/mem.py): before compiling a program, "
             "check its static peak-HBM estimate (params + optimizer "

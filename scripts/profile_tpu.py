@@ -25,8 +25,6 @@ import os
 import sys
 import tempfile
 
-import numpy as np
-
 
 def aggregate_trace(trace_dir):
     paths = glob.glob(os.path.join(trace_dir, "**", "*.trace.json.gz"),
@@ -62,13 +60,13 @@ def main():
 
     import jax
     import bench
+    from paddle_tpu.utils.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     model = os.environ.get("BENCH_MODEL", "resnet50")
     batch = int(os.environ.get("BENCH_BATCH", "128"))
 
     import paddle_tpu.fluid as fluid
-    from paddle_tpu.jit import FunctionalProgram, state_from_scope
-    from paddle_tpu.fluid.executor import RNG_STATE_NAME
 
     if os.environ.get("BENCH_AMP", "1") != "0":
         fluid.amp.enable_bf16()
@@ -82,14 +80,11 @@ def main():
     scope = fluid.Scope()
     exe = fluid.Executor(fluid.TPUPlace(0))
     exe.run(startup, scope=scope)
-    fp = FunctionalProgram(main_prog, ["image", "label"], [avg_loss.name])
     dev = jax.devices()[0]
-    state = {n: jax.device_put(np.asarray(v), dev)
-             for n, v in state_from_scope(fp, scope).items()}
-    state[RNG_STATE_NAME] = jax.device_put(jax.random.PRNGKey(0), dev)
+    step, state = bench.functional_step(
+        main_prog, ["image", "label"], avg_loss.name, scope, dev)
     feeds = jax.device_put(
         bench._image_feeds(batch, image_size, class_dim), dev)
-    step = jax.jit(lambda s, f: fp(s, f), donate_argnums=(0,))
 
     for _ in range(3):
         fetches, state = step(state, feeds)

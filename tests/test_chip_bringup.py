@@ -1,0 +1,195 @@
+"""What the TPU bring-up settled, as far as a CPU can check it: the flash
+kernel lowers for the TPU through Mosaic, no entry point hides the
+device it ran on, and the compile cache has one fixed place."""
+
+import json
+import os
+import subprocess
+import sys
+import time
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+import paddle_tpu.fluid as fluid
+from paddle_tpu.kernels.flash_attention import flash_attention
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _run(argv, env_update=None, env_drop=(), timeout=300):
+    env = {k: v for k, v in os.environ.items() if k not in env_drop}
+    env.update(env_update or {})
+    return subprocess.run([sys.executable] + argv, cwd=REPO, env=env,
+                          capture_output=True, text=True, timeout=timeout)
+
+
+def _json_lines(text):
+    return [line for line in text.splitlines() if line.startswith("{")]
+
+
+# -- the kernel the compiler accepts ---------------------------------------
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("shape", [(2, 8, 512, 64), (1, 8, 4096, 128)])
+def test_flash_attention_lowers_for_tpu(shape, causal):
+    """Forward and backward lower for the TPU platform from this CPU
+    host, and what they lower to is the Mosaic kernel — not the pallas
+    interpreter the CPU tests run.  Every refusal of the Pallas TPU
+    lowering (block tiling, unimplemented primitives) surfaces here."""
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+
+    def forward(q, k, v):
+        return flash_attention(q, k, v, None, causal)
+
+    def backward(q, k, v):
+        return jax.grad(
+            lambda *qkv: forward(*qkv).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+
+    for fn in (forward, backward):
+        module = jax.export.export(
+            jax.jit(fn), platforms=["tpu"])(x, x, x).mlir_module()
+        assert "tpu_custom_call" in module
+
+
+def test_flash_attention_refuses_a_ragged_block():
+    """A sequence its block does not divide raises with the shape in the
+    message; the block no longer shrinks toward 1 without saying so."""
+    x = jnp.zeros((1, 2, 200, 16), jnp.float32)
+    with pytest.raises(ValueError, match=r"200.*\(1, 2, 200, 16\)"):
+        flash_attention(x, x, x, None, False, 128, 128)
+
+
+# -- no fallback that hides the device -------------------------------------
+
+def test_chip_smoke_fails_fast_without_a_tpu():
+    t0 = time.time()
+    proc = _run(["chip_smoke.py"], {"JAX_PLATFORMS": "cpu"}, timeout=120)
+    assert proc.returncode != 0
+    assert time.time() - t0 < 60
+    assert "platform=cpu" in proc.stdout
+    # it stopped before the cache, before any model and before any result
+    assert "compile cache" not in proc.stdout
+    assert "phase" not in proc.stdout
+    assert not _json_lines(proc.stdout)
+
+
+def test_bench_refuses_to_run_without_a_chip():
+    """JAX finds only the CPU and nobody asked for it: non-zero, and no
+    record that could pass for a measurement."""
+    proc = _run(["bench.py"], {"BENCH_HISTORY": "0"},
+                env_drop=("JAX_PLATFORMS",))
+    assert proc.returncode != 0
+    assert not _json_lines(proc.stdout)
+    assert "no accelerator" in proc.stderr
+
+
+def test_bench_runs_on_the_cpu_when_asked_to(tmp_path):
+    """JAX_PLATFORMS=cpu said out loud still runs a tiny shape, as the
+    smoke gate does, and the record names its device.  (lenet5 here:
+    the gate's own ResNet-50 shape, scripts/smoke.sh, costs tier-1 half
+    a minute of compiling for the same answer.)"""
+    proc = _run(["bench.py"], {
+        "JAX_PLATFORMS": "cpu", "BENCH_MODEL": "lenet5", "BENCH_ITERS": "1",
+        "BENCH_WARMUP": "1", "BENCH_BATCH": "4", "BENCH_HISTORY": "0",
+        "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cache")})
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    (line,) = _json_lines(proc.stdout)
+    record = json.loads(line)
+    assert record["platform"] == "cpu"
+    assert record["device_kind"] == jax.devices()[0].device_kind
+    assert record["device_count"] >= 1
+    # the CPU is not in the peaks table: no utilization, never a default
+    assert record["mfu"] is None
+    assert os.listdir(tmp_path / "cache")
+
+
+def test_engine_without_a_place_sits_on_the_default_device():
+    from paddle_tpu.obs.load import build_tiny_engine
+
+    engine = build_tiny_engine()
+    assert engine.place == fluid.Executor().place
+    assert engine.param_devices() == {jax.devices()[0]}
+
+
+def test_v2_placement_follows_the_backend_unless_told_not_to():
+    from paddle_tpu.v2 import config
+
+    saved = dict(config._state)
+    try:
+        config._state["use_tpu"] = None
+        assert config._place() == fluid.TPUPlace(0)
+        config.init(use_gpu=False)
+        assert config._place() == fluid.CPUPlace()
+        config.init(use_tpu=True)
+        assert config._place() == fluid.TPUPlace(0)
+    finally:
+        config._state.update(saved)
+
+
+def test_mesh_larger_than_the_platform_is_an_error():
+    from paddle_tpu.parallel import make_mesh
+
+    with pytest.raises(ValueError, match="platform has"):
+        make_mesh(n_devices=len(jax.devices()) + 1)
+
+
+def test_place_out_of_range_is_an_error():
+    with pytest.raises(ValueError, match=r"TPUPlace\(99\)"):
+        fluid.TPUPlace(99).device()
+    assert fluid.TPUPlace(0).device() == jax.devices()[0]
+
+
+# -- one place for the compile cache ---------------------------------------
+
+_CACHE_PROBE = ("from paddle_tpu.utils.compile_cache import "
+                "enable_compile_cache; import jax; "
+                "print(enable_compile_cache()); "
+                "print(jax.config.jax_compilation_cache_dir)")
+
+
+def test_compile_cache_dir_is_fixed_inside_the_checkout():
+    """Unset, the cache goes to <checkout>/.jax_cache: the same string
+    from two processes started in different directories."""
+    outs = [subprocess.run(
+        [sys.executable, "-c", _CACHE_PROBE], cwd=cwd, text=True,
+        capture_output=True, timeout=120, check=True,
+        env={**{k: v for k, v in os.environ.items()
+                if k != "JAX_COMPILATION_CACHE_DIR"},
+             "PYTHONPATH": REPO}).stdout.split()
+        for cwd in (REPO, os.path.join(REPO, "tests"))]
+    want = os.path.join(REPO, ".jax_cache")
+    assert outs == [[want, want], [want, want]]
+
+
+def test_compile_cache_dir_follows_the_environment(tmp_path):
+    proc = _run(["-c", _CACHE_PROBE],
+                {"JAX_COMPILATION_CACHE_DIR": str(tmp_path)})
+    assert proc.stdout.split() == [str(tmp_path), str(tmp_path)]
+
+
+def test_save_after_amp_startup_round_trips(tmp_path):
+    """A startup program run under AMP leaves bf16 parameters; numpy's
+    formats cannot name bf16, so the export holds f32 and loads back
+    (it used to come back as raw `|V2` and fail in device_put)."""
+    from paddle_tpu.core.scope import Scope
+    from paddle_tpu.serving import InferenceEngine
+
+    fluid.amp.enable_bf16()
+    try:
+        main, startup = fluid.Program(), fluid.Program()
+        with fluid.program_guard(main, startup):
+            x = fluid.layers.data(name="x", shape=[8], dtype="float32")
+            y = fluid.layers.fc(input=x, size=4, act="softmax")
+        exe = fluid.Executor()
+        with fluid.scope_guard(Scope()):
+            exe.run(startup)
+            fluid.io.save_inference_model(str(tmp_path), ["x"], [y], exe,
+                                          main_program=main)
+        engine = InferenceEngine.from_saved_model(str(tmp_path))
+        (out,) = engine.run({"x": jnp.ones((2, 8), jnp.float32)})
+        assert abs(float(out.sum()) - 2.0) < 2e-2
+    finally:
+        fluid.amp.disable_bf16()

@@ -10,6 +10,8 @@ import textwrap
 
 from paddle_tpu.tools.cluster_launch import launch
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 TRAIN_SCRIPT = textwrap.dedent("""
     import os, sys
     import numpy as np
@@ -57,7 +59,7 @@ def test_cluster_launch_end_to_end(tmp_path):
 
     ps_procs, tr_procs, _ = launch(
         [str(script)], pservers, trainers=2, sync=True,
-        env={"PYTHONPATH": "/root/repo", "JAX_PLATFORMS": "cpu"})
+        env={"PYTHONPATH": REPO, "JAX_PLATFORMS": "cpu"})
     try:
         rcs = [p.wait(timeout=240) for p in tr_procs]
         assert rcs == [0, 0], rcs
@@ -113,8 +115,8 @@ def test_cluster_launch_remote_over_ssh(tmp_path):
     ps_procs, tr_procs = launch_remote(
         [str(script)], hosts=["127.0.0.1", "localhost"],
         trainers_per_host=1, base_port=port, port_step=1, sync=True,
-        python=sys.executable, ssh_cmd=(str(shim),), workdir="/root/repo",
-        env={"PYTHONPATH": "/root/repo", "JAX_PLATFORMS": "cpu"})
+        python=sys.executable, ssh_cmd=(str(shim),), workdir=REPO,
+        env={"PYTHONPATH": REPO, "JAX_PLATFORMS": "cpu"})
     try:
         rcs = [p.wait(timeout=240) for p in tr_procs]
         assert rcs == [0, 0], rcs
@@ -144,7 +146,7 @@ def test_cluster_launch_elastic(tmp_path):
     ps_procs, tr_procs, master = launch(
         [str(script)], ["x:0", "x:0"], trainers=2, sync=True,
         elastic=True,
-        env={"PYTHONPATH": "/root/repo", "JAX_PLATFORMS": "cpu"})
+        env={"PYTHONPATH": REPO, "JAX_PLATFORMS": "cpu"})
     try:
         rcs = [p.wait(timeout=240) for p in tr_procs]
         assert rcs == [0, 0], rcs

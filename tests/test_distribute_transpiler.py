@@ -186,7 +186,8 @@ def test_multiprocess_roles():
         sk.bind(("127.0.0.1", 0))
         port = sk.getsockname()[1]
     endpoint = "127.0.0.1:%d" % port
-    env_base = {**os.environ, "PYTHONPATH": "/root/repo",
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env_base = {**os.environ, "PYTHONPATH": repo,
                 "JAX_PLATFORMS": "cpu",
                 "PSERVER_ENDPOINT": endpoint, "TRAINERS": "2"}
 

@@ -557,38 +557,3 @@ def test_service_lease_heartbeat_histogram_and_fault_survival():
         r_faults.disable()
         master.stop()
 
-
-# ---------------------------------------------------------------------------
-# mega_bench emits the platform-stale warning at emit time
-# ---------------------------------------------------------------------------
-
-def test_mega_bench_warns_on_stale_platform(tmp_path, monkeypatch,
-                                            capsys):
-    import sys
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    monkeypatch.syspath_prepend(os.path.join(repo, "scripts"))
-    monkeypatch.syspath_prepend(repo)
-    import bench
-    import mega_bench
-
-    store = {
-        "resnet50-train-img/s|b128": {"metric": "resnet50",
-                                      "platform": "tpu-stale",
-                                      "value": 100.0},
-        "vgg16-train-img/s|b64": {"metric": "vgg16",
-                                  "platform": "tpu-v6e-1",
-                                  "value": 50.0},
-        "alex|skipped": {"metric": "alex", "skipped": "compile-timeout",
-                         "platform": ""},
-    }
-    path = str(tmp_path / "BENCH.json")
-    with open(path, "w") as f:
-        json.dump(store, f)
-    monkeypatch.setattr(bench, "_LAST_TPU_PATH", path)
-    mega_bench._warn_stale_platform("headline-leg", set(store))
-    out = capsys.readouterr().out
-    assert "WARNING: leg headline-leg emitted platform-stale record" \
-        in out
-    assert "resnet50-train-img/s|b128" in out
-    assert "vgg16" not in out          # fresh platform: no warning
-    assert "alex|skipped" not in out   # skip markers exempt

@@ -106,7 +106,7 @@ def test_rewrite_reaches_xla():
     platform policy: XLA:CPU strips the barrier and CSEs the clones
     away — verified jax.checkpoint itself gets undone there too — while
     XLA:TPU schedules them late, which is where the HBM win lands; the
-    on-chip A/B lives in the bench suite, scripts/tpu_watch.sh.)"""
+    on-chip A/B is mega_bench's b256rcp8 leg.)"""
     import jax
 
     stats = {}

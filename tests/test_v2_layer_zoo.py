@@ -21,7 +21,7 @@ def _forward(fetches, feeds):
 
 def test_export_surface():
     """The DSL exports at least 80 layer names and every one resolves
-    to a callable (VERDICT round-2 item 3: >= 80)."""
+    to a callable (the reference's layer surface: >= 80)."""
     assert len(v2_layer.__all__) >= 80, len(v2_layer.__all__)
     for n in v2_layer.__all__:
         assert callable(getattr(v2_layer, n)), n
