@@ -126,8 +126,8 @@ def _lstm_feeds(batch, seq_len, dict_dim):
 def functional_step(main_prog, feed_names, fetch_name, scope, dev):
     """(step, state) — the step this file times: the whole program
     through FunctionalProgram under one jax.jit, every state array on
-    `dev` and donated to the call.  chip_smoke.py and
-    scripts/profile_tpu.py drive the same function."""
+    `dev` and donated to the call.  chip_smoke.py drives the same
+    function."""
     import jax
     from paddle_tpu.analysis.alias import state_donation
     from paddle_tpu.fluid.executor import RNG_STATE_NAME

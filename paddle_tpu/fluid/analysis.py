@@ -7,7 +7,7 @@ floor on a machine with a given MXU peak and HBM bandwidth:
     t_op >= max(flops / peak_flops, bytes / bandwidth)
 
 This is the tool behind the "profile-backed ceiling analysis" in
-docs/PERF.md: the per-HLO device profile (scripts/profile_tpu.py) says
+docs/PERF.md: the device profile (benchmark/run.py --trace 1) says
 where the time WENT; this says where it HAS to go, so the gap between
 the two is the actionable headroom.  The reference has no counterpart
 (its benchmark suite only reports throughput); on TPU the

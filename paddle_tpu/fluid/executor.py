@@ -262,11 +262,17 @@ def apply_op(ctx, op_desc):
         ins[slot] = [None if n == "@EMPTY@" else _env_get(ctx, n)
                      for n in names]
 
-    if is_generic_grad:
-        outs = op_registry.run_generic_grad(
-            ctx, op_registry.forward_type_of_grad(t), ins, op_desc.attrs)
-    else:
-        outs = kernel(ctx, ins, op_desc.attrs)
+    # the op's type on everything it lowers to: the compiled program's
+    # `op_name` metadata, and with it a device trace, says which op (and,
+    # by the `_grad` suffix or the optimizer's type, which pass) an
+    # instruction came from.  Trace-time only; no arithmetic changes.
+    with jax.named_scope(t):
+        if is_generic_grad:
+            outs = op_registry.run_generic_grad(
+                ctx, op_registry.forward_type_of_grad(t), ins,
+                op_desc.attrs)
+        else:
+            outs = kernel(ctx, ins, op_desc.attrs)
 
     for slot, names in op_desc.outputs.items():
         vals = outs.get(slot)
@@ -387,6 +393,7 @@ class _CompiledProgram:
                 "jit": jit_ok, "ops": ops, "reads": reads,
                 "writes": writes, "outputs": out_names,
                 "persist_writes": persist_writes, "rng": rng,
+                "label": self._segment_label(i, ops),
             })
         return plan
 
@@ -423,7 +430,6 @@ class _CompiledProgram:
     # -- execution ----------------------------------------------------------
     def run(self, scope, feed_env, eager=False):
         executor = self.executor
-        program = self.program
         env = dict(feed_env)
 
         def resolve(name):
@@ -447,42 +453,11 @@ class _CompiledProgram:
             scope.set_local(RNG_STATE_NAME, rng_state)
 
         for i, seg in enumerate(self._plan):
-            in_vals = {n: resolve(n) for n in seg["reads"] if n in env
-                       or scope.has_var(n)}
-            if seg["jit"] and not eager:
-                out_vals, rng_state = self._run_jit_segment(
-                    i, seg, in_vals, rng_state)
-            else:
-                ctx = ExecContext(executor, program, self.block_idx,
-                                  dict(in_vals), rng=rng_state, scope=scope,
-                                  place=executor.place)
-                for od in seg["ops"]:
-                    # per-op attribution like the reference interpreter
-                    # (reference: executor.cc:126-127 RecordEvent per op,
-                    # executor.cc:29+66-77 FLAGS_check_nan_inf scan);
-                    # record_event is span-backed: rows land in the
-                    # profiler table AND on the obs trace timeline
-                    with profiler_mod.record_event(od.type):
-                        outs = apply_op(ctx, od)
-                    if flags.get_flag("check_nan_inf"):
-                        try:
-                            _check_outputs_finite(od, outs)
-                        except NonfiniteError as err:
-                            # annotate the block-wide op position (error
-                            # path only; list.index is identity-based)
-                            try:
-                                err.op_index = self.program.desc.block(
-                                    self.block_idx).ops.index(od)
-                            except ValueError:
-                                pass
-                            raise
-                rng_state = ctx.rng
-                out_vals = {n: ctx.env[n] for n in seg["outputs"]
-                            if n in ctx.env}
-            env.update(out_vals)
-            for n in seg["persist_writes"]:
-                if n in out_vals:
-                    scope.set(n, out_vals[n])
+            with obs_trace.span("executor/segment", cat="executor",
+                                index=i, segment=seg["label"],
+                                jit=seg["jit"] and not eager):
+                rng_state = self._run_segment(i, seg, scope, env,
+                                              resolve, rng_state, eager)
         scope.set(RNG_STATE_NAME, rng_state)
 
         # fetches not written this run (parameters, accumulated state)
@@ -491,19 +466,63 @@ class _CompiledProgram:
         return [env[n] if n in env else scope.get(n)
                 for n in self.fetch_names]
 
-    def _segment_label(self, i, seg):
-        """Stable display name: index + op-type span + op count."""
-        types = [od.type for od in seg["ops"]]
-        span = types[0] if len(types) == 1 else "%s..%s" % (types[0],
-                                                            types[-1])
-        return "jit_segment[%d:%s x%d]" % (i, span, len(types))
+    def _run_segment(self, i, seg, scope, env, resolve, rng_state, eager):
+        """One segment: resolve what it reads, run it (one jitted call,
+        or op by op), put its outputs into `env` and its persistables
+        back into the scope.  Returns the advanced rng state."""
+        in_vals = {n: resolve(n) for n in seg["reads"] if n in env
+                   or scope.has_var(n)}
+        if seg["jit"] and not eager:
+            out_vals, rng_state = self._run_jit_segment(
+                i, seg, in_vals, rng_state)
+        else:
+            executor = self.executor
+            ctx = ExecContext(executor, self.program, self.block_idx,
+                              dict(in_vals), rng=rng_state, scope=scope,
+                              place=executor.place)
+            for od in seg["ops"]:
+                # per-op attribution like the reference interpreter
+                # (reference: executor.cc:126-127 RecordEvent per op,
+                # executor.cc:29+66-77 FLAGS_check_nan_inf scan);
+                # record_event is span-backed: rows land in the
+                # profiler table AND on the obs trace timeline
+                with profiler_mod.record_event(od.type):
+                    outs = apply_op(ctx, od)
+                if flags.get_flag("check_nan_inf"):
+                    try:
+                        _check_outputs_finite(od, outs)
+                    except NonfiniteError as err:
+                        # annotate the block-wide op position (error
+                        # path only; list.index is identity-based)
+                        try:
+                            err.op_index = self.program.desc.block(
+                                self.block_idx).ops.index(od)
+                        except ValueError:
+                            pass
+                        raise
+            rng_state = ctx.rng
+            out_vals = {n: ctx.env[n] for n in seg["outputs"]
+                        if n in ctx.env}
+        env.update(out_vals)
+        for n in seg["persist_writes"]:
+            if n in out_vals:
+                scope.set(n, out_vals[n])
+        return rng_state
+
+    @staticmethod
+    def _segment_label(i, ops):
+        """Stable display name: index + op-type span + op count.  Made
+        once, when the plan is built (`seg["label"]`)."""
+        span = ops[0].type if len(ops) == 1 else "%s..%s" % (
+            ops[0].type, ops[-1].type)
+        return "jit_segment[%d:%s x%d]" % (i, span, len(ops))
 
     def _run_jit_segment(self, i, seg, in_vals, rng_state):
         first_call = i not in self._jit_cache
         jitted = self._jit_cache.get(i)
         if jitted is None:
             obs_trace.instant("jit_build", cat="compile",
-                              segment=self._segment_label(i, seg))
+                              segment=seg["label"])
             ops = seg["ops"]
             out_names = tuple(seg["outputs"])
             program = self.program
@@ -548,7 +567,7 @@ class _CompiledProgram:
                 # publish_compile_stats call supplies the XLA half
                 try:
                     obs_mem.register_segment_static(
-                        self._segment_label(i, seg), ops,
+                        seg["label"], ops,
                         seg["outputs"],
                         program.desc.block(block_idx))
                 except Exception:
@@ -558,8 +577,8 @@ class _CompiledProgram:
         mutated = jitted["mutated"]
         mut_ins = {n: v for n, v in in_vals.items() if n in mutated}
         ro_ins = {n: v for n, v in in_vals.items() if n not in mutated}
+        label = seg["label"]
         profiled = profiler_mod.is_enabled()
-        tracing = obs_trace.is_enabled()
 
         # persistent executable cache (FLAGS_compile_cache_dir): serve
         # this (segment, signature) from an AOT executable — loaded
@@ -583,11 +602,9 @@ class _CompiledProgram:
                                         sig)
                 jitted["aot"][sig] = aot if aot is not None else False
             if aot not in (None, False):
-                label = self._segment_label(i, seg)
                 try:
                     return self._exec_aot(aot, label, mut_ins, ro_ins,
-                                          rng_state, profiled, tracing,
-                                          "pcache")
+                                          rng_state, profiled)
                 except Exception as exc:
                     # signature drift / backend mismatch: quarantine
                     # THIS signature to the jit path and keep running
@@ -636,46 +653,34 @@ class _CompiledProgram:
                 first_call or not (size_fn() or 0))
             res = self._run_attr_aot(i, seg, jitted, mut_ins, ro_ins,
                                      rng_state, allow_compile,
-                                     profiled, tracing, sig)
+                                     profiled, sig)
             if res is not None:
                 return res
 
-        if not (profiled or tracing):
-            # hot path: dispatch async; compile detection stays on (a
-            # retrace is the single costliest event, telemetry must see
-            # it even unprofiled) — _cache_size is a cheap int read
-            pre_traces = size_fn()
-            outs, rng = jitted["fn"](mut_ins, ro_ins, rng_state)
-            post_traces = size_fn()
-            if first_call or (pre_traces is not None
-                              and post_traces is not None
-                              and post_traces > pre_traces):
-                obs_tele.on_jit_trace(self._segment_label(i, seg))
-            return outs, rng
-        # profiled/traced: block on the segment's outputs so the wall
-        # time is the device time, not just the dispatch (ParseEvents
-        # analog for the compiled path; per-op rows come from eager
-        # mode).  A trace hit (new shapes/dtypes) also lands in the
-        # /first(trace) row and as a jit_trace instant on the timeline.
-        label = self._segment_label(i, seg)
+        # dispatch async, traced or not: the host runs ahead of the
+        # device, and device time is the device trace's to tell.  Compile
+        # detection stays on (a retrace is the single costliest event,
+        # telemetry must see it even unprofiled) - _cache_size is a
+        # cheap int read.  Only fluid.profiler's table blocks on the
+        # segment's outputs, so that a row is wall time and not just the
+        # dispatch (the reference's RecordEvent/ParseEvents for the
+        # compiled path; per-op rows come from eager mode); a trace hit
+        # (new shapes/dtypes) lands in the /first(trace) row.
         pre_traces = size_fn()
         t0 = time.perf_counter()
-        outs, rng = jitted["fn"](mut_ins, ro_ins, rng_state)
-        jax.block_until_ready((outs, rng))
-        dt = time.perf_counter() - t0
-        traced = first_call or (
-            pre_traces is not None
-            and jitted["fn"]._cache_size() > pre_traces)
+        with obs_trace.span("executor/dispatch", cat="executor"):
+            outs, rng = jitted["fn"](mut_ins, ro_ins, rng_state)
+        post_traces = size_fn()
+        traced = first_call or (pre_traces is not None
+                                and post_traces is not None
+                                and post_traces > pre_traces)
         if traced:
             obs_tele.on_jit_trace(label)
-        if tracing:
-            obs_trace.emit_span("executor/" + label, t0, dt,
-                                cat="executor",
-                                args={"traced": traced} if traced
-                                else None)
         if profiled:
+            jax.block_until_ready((outs, rng))
             profiler_mod.record(
-                label + ("/first(trace)" if traced else ""), dt)
+                label + ("/first(trace)" if traced else ""),
+                time.perf_counter() - t0)
         return outs, rng
 
     def _pcache_base(self):
@@ -709,7 +714,7 @@ class _CompiledProgram:
         from ..compile import fingerprint as fp_mod
         from ..compile import pcache as pcache_mod
 
-        label = self._segment_label(i, seg)
+        label = seg["label"]
         try:
             cache = pcache_mod.get_cache()
             if cache is None:
@@ -754,7 +759,7 @@ class _CompiledProgram:
             return None
 
     def _run_attr_aot(self, i, seg, jitted, mut_ins, ro_ins, rng_state,
-                      allow_compile, profiled, tracing, sig=None):
+                      allow_compile, profiled, sig=None):
         """Attribution on the plain jit path, without the historical
         double compile: per (segment, signature) the FIRST build is
         `fn.lower().compile()` — the memory/cost analyses are
@@ -783,7 +788,7 @@ class _CompiledProgram:
         aot = attr.get(sig)
         if aot is False:
             return None
-        label = self._segment_label(i, seg)
+        label = seg["label"]
         if aot is None:
             if not allow_compile:
                 return None
@@ -800,8 +805,7 @@ class _CompiledProgram:
             attr[sig] = aot = compiled
         try:
             return self._exec_aot(aot, label, mut_ins, ro_ins,
-                                  rng_state, profiled, tracing,
-                                  "attr_aot")
+                                  rng_state, profiled)
         except Exception as exc:
             # same contract as the pcache execute fallback: quarantine
             # THIS signature, keep running — unless dispatch already
@@ -816,23 +820,17 @@ class _CompiledProgram:
             return None
 
     @staticmethod
-    def _exec_aot(aot, label, mut_ins, ro_ins, rng_state, profiled,
-                  tracing, span_flag):
-        """Dispatch one AOT artifact under the shared timing contract:
-        async on the hot path; blocked + span/profiler rows when
-        profiled or tracing (`span_flag` names which AOT path this
-        was).  Raises on failure — the caller owns quarantine."""
-        if not (profiled or tracing):
-            return aot(mut_ins, ro_ins, rng_state)
+    def _exec_aot(aot, label, mut_ins, ro_ins, rng_state, profiled):
+        """Dispatch one AOT artifact under the jit path's timing
+        contract: async, and blocked with a profiler row only while
+        fluid.profiler is on.  Raises on failure - the caller owns
+        quarantine."""
         t0 = time.perf_counter()
-        outs, rng = aot(mut_ins, ro_ins, rng_state)
-        jax.block_until_ready((outs, rng))
-        dt = time.perf_counter() - t0
-        if tracing:
-            obs_trace.emit_span("executor/" + label, t0, dt,
-                                cat="executor", args={span_flag: True})
+        with obs_trace.span("executor/dispatch", cat="executor"):
+            outs, rng = aot(mut_ins, ro_ins, rng_state)
         if profiled:
-            profiler_mod.record(label, dt)
+            jax.block_until_ready((outs, rng))
+            profiler_mod.record(label, time.perf_counter() - t0)
         return outs, rng
 
 
@@ -915,74 +913,23 @@ class Executor:
             feed_env = {}
             block0 = program.desc.block(0)
             if feed:
-                t_feed = time.perf_counter()
-                for name, val in feed.items():
-                    feed_env[name] = self._prepare_feed(block0, name,
-                                                        val)
-                # input time as a counter of seconds: snapshot_delta
-                # turns it into the per-step/per-leg h2d-INPUT share
-                # the obs.perf classifier reads (bytes alone can't say
-                # whether the feed path is the bottleneck)
-                obs_tele.on_feed_seconds(time.perf_counter() - t_feed)
+                with obs_trace.span("executor/feed", cat="executor"):
+                    t_feed = time.perf_counter()
+                    for name, val in feed.items():
+                        feed_env[name] = self._prepare_feed(block0, name,
+                                                            val)
+                    # input time as a counter of seconds:
+                    # snapshot_delta turns it into the per-step/per-leg
+                    # h2d-INPUT share the obs.perf classifier reads
+                    # (bytes alone can't say whether the feed path is
+                    # the bottleneck)
+                    obs_tele.on_feed_seconds(time.perf_counter() - t_feed)
 
-            # dtype policy and the rewrite pipeline are trace-time
-            # state: a flipped amp flag (or pass config) must not
-            # reuse executables built under the old policy
-            key = (program._cache_token, program.version, 0,
-                   tuple(sorted(feed_env.keys())), tuple(fetch_names),
-                   flags.get_flag("amp_bf16"),
-                   flags.get_flag("amp_bf16_act"),
-                   flags.get_flag("bn_shifted_stats"),
-                   flags.get_flag("compile_passes"),
-                   flags.get_flag("donation"))
-            compiled = self._cache.get(key) if use_program_cache else None
-            if compiled is None:
-                # verify-before-first-compile (FLAGS_verify_program):
-                # a malformed program fails HERE with a Diagnostic-
-                # derived error naming op index + var, not three
-                # layers down as an XLA trace error
-                if flags.get_flag("verify_program"):
-                    self._verify_program(program, fetch_names)
-                # FLAGS_compile_passes: rewrite a CLONE through the
-                # verified pass pipeline (dce/fold/cse/dve) before
-                # segmentation; the original program (and the cache
-                # key above) are untouched
-                program_to_compile = program
-                spec = flags.get_flag("compile_passes")
-                if spec:
-                    from ..compile import passes as passes_mod
-
-                    program_to_compile, _ = passes_mod.optimize_program(
-                        program, spec, fetches=list(fetch_names))
-                # OOM pre-flight (FLAGS_mem_budget_gb): refuse a
-                # program whose static peak busts the budget BEFORE
-                # any compile, on the program that will actually run
-                # (post-pass: auto_remat may have bought headroom).
-                # The MemoryBudgetError routes through the same OOM
-                # flight-bundle path a device RESOURCE_EXHAUSTED does.
-                budget = flags.get_flag("mem_budget_gb")
-                if budget:
-                    obs_mem.preflight(program_to_compile, fetch_names,
-                                      budget)
-                compiled = _CompiledProgram(self, program_to_compile, 0,
-                                            sorted(feed_env.keys()),
-                                            fetch_names)
-                if use_program_cache:
-                    self._cache[key] = compiled
-                    while len(self._cache) > self._CACHE_MAX:
-                        ekey, evicted = self._cache.popitem(last=False)
-                        # LRU eviction was silent: a hot serving mix
-                        # thrashing the program cache looked like
-                        # random recompiles.  Count it and name the
-                        # victim.
-                        obs_tele.on_program_cache_evict()
-                        self._retire_segment_gauges(evicted)
-                        _log.debug(
-                            "evicted program cache entry: token=%s "
-                            "version=%s feeds=%s fetches=%s",
-                            ekey[0], ekey[1], ekey[3], ekey[4])
-            elif use_program_cache:
-                self._cache.move_to_end(key)
+            with obs_trace.span("executor/plan",
+                                cat="executor") as plan_span:
+                compiled, miss = self._compiled_for(
+                    program, feed_env, fetch_names, use_program_cache)
+                plan_span.set(miss=miss)
 
             try:
                 results = compiled.run(scope, feed_env, eager=eager)
@@ -1001,8 +948,75 @@ class Executor:
                 raise
 
             if return_numpy:
-                results = [self._to_numpy(r) for r in results]
+                with obs_trace.span("executor/fetch", cat="executor"):
+                    results = [self._to_numpy(r) for r in results]
             return results
+
+    def _compiled_for(self, program, feed_env, fetch_names,
+                      use_program_cache):
+        """(the `_CompiledProgram` for this call, whether it had to be
+        built): the cache key, the lookup and, on a miss, verification,
+        the rewrite passes and the plan itself."""
+        # dtype policy and the rewrite pipeline are trace-time
+        # state: a flipped amp flag (or pass config) must not
+        # reuse executables built under the old policy
+        key = (program._cache_token, program.version, 0,
+               tuple(sorted(feed_env.keys())), tuple(fetch_names),
+               flags.get_flag("amp_bf16"),
+               flags.get_flag("amp_bf16_act"),
+               flags.get_flag("bn_shifted_stats"),
+               flags.get_flag("compile_passes"),
+               flags.get_flag("donation"))
+        compiled = self._cache.get(key) if use_program_cache else None
+        miss = compiled is None
+        if miss:
+            # verify-before-first-compile (FLAGS_verify_program):
+            # a malformed program fails HERE with a Diagnostic-
+            # derived error naming op index + var, not three
+            # layers down as an XLA trace error
+            if flags.get_flag("verify_program"):
+                self._verify_program(program, fetch_names)
+            # FLAGS_compile_passes: rewrite a CLONE through the
+            # verified pass pipeline (dce/fold/cse/dve) before
+            # segmentation; the original program (and the cache
+            # key above) are untouched
+            program_to_compile = program
+            spec = flags.get_flag("compile_passes")
+            if spec:
+                from ..compile import passes as passes_mod
+
+                program_to_compile, _ = passes_mod.optimize_program(
+                    program, spec, fetches=list(fetch_names))
+            # OOM pre-flight (FLAGS_mem_budget_gb): refuse a
+            # program whose static peak busts the budget BEFORE
+            # any compile, on the program that will actually run
+            # (post-pass: auto_remat may have bought headroom).
+            # The MemoryBudgetError routes through the same OOM
+            # flight-bundle path a device RESOURCE_EXHAUSTED does.
+            budget = flags.get_flag("mem_budget_gb")
+            if budget:
+                obs_mem.preflight(program_to_compile, fetch_names,
+                                  budget)
+            compiled = _CompiledProgram(self, program_to_compile, 0,
+                                        sorted(feed_env.keys()),
+                                        fetch_names)
+            if use_program_cache:
+                self._cache[key] = compiled
+                while len(self._cache) > self._CACHE_MAX:
+                    ekey, evicted = self._cache.popitem(last=False)
+                    # LRU eviction was silent: a hot serving mix
+                    # thrashing the program cache looked like
+                    # random recompiles.  Count it and name the
+                    # victim.
+                    obs_tele.on_program_cache_evict()
+                    self._retire_segment_gauges(evicted)
+                    _log.debug(
+                        "evicted program cache entry: token=%s "
+                        "version=%s feeds=%s fetches=%s",
+                        ekey[0], ekey[1], ekey[3], ekey[4])
+        elif use_program_cache:
+            self._cache.move_to_end(key)
+        return compiled, miss
 
     def _retire_segment_gauges(self, evicted):
         """Per-segment gauges (`xla_*`/`mem_*{segment=}`) are
@@ -1016,12 +1030,10 @@ class Executor:
         live metrics would silently vanish for the process
         lifetime)."""
         try:
-            labels = {evicted._segment_label(i, seg)
-                      for i, seg in enumerate(evicted._plan)}
+            labels = {seg["label"] for seg in evicted._plan}
             for other in self._cache.values():
                 labels.difference_update(
-                    other._segment_label(i, seg)
-                    for i, seg in enumerate(other._plan))
+                    seg["label"] for seg in other._plan)
             if labels:
                 obs_health.retire_compile_stats(labels)
                 obs_mem.retire_segments(labels)

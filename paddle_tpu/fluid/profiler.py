@@ -4,7 +4,11 @@ reference: paddle/platform/profiler.h:27-146 (RecordEvent around every op,
 ParseEvents table) + python/paddle/v2/fluid/profiler.py.  The compiled
 path profiles at segment granularity (XLA owns fusion); the eager executor
 mode gives reference-style per-op attribution.  `profiler(...)` can also
-start JAX's own trace for TensorBoard.
+start JAX's own trace for TensorBoard (`trace_dir=`), in which every
+`obs.trace.span` of the program (`executor/run`, `executor/segment`, ...)
+and every op's `jax.named_scope` appear beside the device's operations.
+While the table is on, the executor blocks on each segment's outputs so
+that a row is wall time; that is the only thing that makes it block.
 
 Since the obs layer landed this module is the back-compat veneer over
 `paddle_tpu.obs`: `record_event` is a span (it lands on the obs trace
@@ -32,6 +36,17 @@ _enabled = [False]
 
 def is_enabled():
     return _enabled[0]
+
+
+def set_enabled(on):
+    """Switch the timing table on or off without the reset and the
+    printed table of `profiler()`; returns what it was.  While it is on
+    the executor blocks on each compiled segment's outputs, so a row,
+    and the `executor/segment` span around it, covers the segment's
+    device time (obs.perf's sampled steps want that)."""
+    prev = _enabled[0]
+    _enabled[0] = bool(on)
+    return prev
 
 
 # cached (registry, seconds_family, calls_family): record() runs on

@@ -171,6 +171,11 @@ def _flash_bwd_rule(sm_scale, causal, block_q, block_k, q_offset, res,
     dv = p^T do; dp = do v^T; ds = p*(dp - rowsum(do*o)); dq = ds k;
     dk = ds^T q.  Runs as plain XLA over k-blocks via scan — the
     recompute keeps memory at O(T*block) like the forward."""
+    with jax.named_scope("flash_attention_bwd"):
+        return _bwd(sm_scale, causal, block_k, q_offset, res, do)
+
+
+def _bwd(sm_scale, causal, block_k, q_offset, res, do):
     q, k, v, o, m, l = res
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5

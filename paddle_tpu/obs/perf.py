@@ -17,7 +17,7 @@ XLA cost attribution), turning raw numbers into verdicts:
     cost-attribution numbers) into ONE verdict per step/leg:
     `compute_bound | hbm_bound | input_bound | host_bound`, with the
     dominant segment/op named.  This is the logic that used to be a
-    hand-run sweep (scripts/profile_tpu.py is the per-HLO follow-up).
+    hand-run sweep (the benchmark's traced run is the per-op follow-up).
   * the perf history store + regression gate — bench.py/mega_bench
     append normalized records to `perf_history.jsonl`;
     `gate_history()` compares the newest run per metric against a
@@ -77,10 +77,12 @@ class StepProfiler:
     every step in `telemetry.step(...)`, so no trainer changes are
     needed.  Unsampled steps cost one registry snapshot + delta (the
     flight recorder pays the same per step); sampled steps additionally
-    turn span tracing on for the step's duration, which makes the
-    executor block per jit segment — device-true timings at the price
-    of losing dispatch overlap for that ONE step.  `sample_every=0`
-    never samples (counters-only records).
+    turn span tracing and fluid.profiler's segment timing on for the
+    step's duration, and the latter makes the executor block per jit
+    segment — device-true `executor/segment` spans at the price of
+    losing dispatch overlap for that ONE step (and of that step's rows
+    in the profiler table).  `sample_every=0` never samples
+    (counters-only records).
     """
 
     def __init__(self, capacity=512, sample_every=16):
@@ -96,6 +98,7 @@ class StepProfiler:
         self._snap_before = None
         self._sampling = False
         self._trace_owned = False
+        self._timing_before = False
         self._ev_mark = 0
         self._t0 = None
 
@@ -113,10 +116,13 @@ class StepProfiler:
         self._sampling = (self.sample_every > 0
                           and self._steps % self.sample_every == 0)
         if self._sampling:
+            from ..fluid import profiler as fluid_profiler
+
             if not trace_mod.is_enabled():
                 # sample window only: keep whatever the process had
                 trace_mod.enable(clear=False)
                 self._trace_owned = True
+            self._timing_before = fluid_profiler.set_enabled(True)
             self._ev_mark = trace_mod.event_count()
         self._snap_before = telemetry_mod.snapshot()
         self._t0 = time.perf_counter()
@@ -130,14 +136,18 @@ class StepProfiler:
         device_s = None
         segments = None
         if sampling:
+            from ..fluid import profiler as fluid_profiler
+
+            fluid_profiler.set_enabled(self._timing_before)
             spans = [ev for ev in trace_mod.events_since(self._ev_mark)
                      if ev.get("ph") == "X"
-                     and ev["name"].startswith("executor/jit_segment")]
+                     and ev["name"] == "executor/segment"
+                     and ev["args"]["jit"]]
             if spans:
                 device_s = sum(ev.get("dur", 0) for ev in spans) / 1e6
                 top = max(spans, key=lambda ev: ev.get("dur", 0))
                 segments = {"count": len(spans),
-                            "slowest": top["name"],
+                            "slowest": top["args"]["segment"],
                             "slowest_ms": round(top["dur"] / 1e3, 3)}
             if self._trace_owned:
                 # the window's spans are copied out above: splice just

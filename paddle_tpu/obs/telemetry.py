@@ -12,6 +12,10 @@ reads back):
                one labeled metric family shared by the v2 SGD loop and
                the mesh-parallel trainer (`trainer` label).
   * scalars:   loss-scale / grad-norm style gauges via `set_gauge`.
+  * jit:       seconds JAX spent tracing, lowering and compiling each
+               jitted function (`jit_phase_seconds_total`), from
+               `jax.monitoring`: the set-up time a warm compile cache
+               cannot save is the trace and lower phases.
 
 Everything funnels into the default registry (`obs.registry`), so one
 Prometheus scrape / `obs_dump` call sees executor, trainer and serving
@@ -20,6 +24,8 @@ unconditionally: a counter inc is one dict lookup + locked add.
 """
 
 import time
+
+import jax.monitoring
 
 from . import registry as registry_mod
 from . import trace as trace_mod
@@ -107,6 +113,39 @@ def transfer_bytes(direction):
 
 
 # ---------------------------------------------------------------------------
+# jit phases
+# ---------------------------------------------------------------------------
+
+_JIT_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+}
+
+
+def _on_jit_phase(event, duration, fun_name="", **_):
+    """`jit_phase_seconds_total{phase, fun_name}`: JAX reports the
+    python function's name for the trace and `jit(<name>)` for the
+    other two; one label value serves all three (the executor's
+    function is `segment_fn`, the trainers' is `step`).  A persistent
+    cache hit is a `compile` of its load time.  Fires only when
+    something compiles."""
+    phase = _JIT_PHASES.get(event)
+    if phase is None:
+        return
+    if fun_name.startswith("jit(") and fun_name.endswith(")"):
+        fun_name = fun_name[len("jit("):-1]
+    _reg().counter("jit_phase_seconds_total",
+                   "seconds JAX spent tracing, lowering and compiling "
+                   "(or loading from the persistent cache), per jitted "
+                   "function", labelnames=("phase", "fun_name")) \
+          .labels(phase=phase, fun_name=fun_name).inc(duration)
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_jit_phase)
+
+
+# ---------------------------------------------------------------------------
 # trainer-side hooks
 # ---------------------------------------------------------------------------
 
@@ -131,15 +170,19 @@ def step_observer():
 
 
 class _StepTimer:
-    """Times one training step; on exit feeds the trainer metric
-    family and leaves a `<trainer>/step` span on the trace."""
+    """Times one training step inside a `<trainer>/step` span, in
+    which whatever the step runs nests, and feeds the trainer metric
+    family.  `examples` may be set after entry, by a step that learns
+    its batch size from its feeds."""
 
-    __slots__ = ("trainer", "examples", "args", "_t0", "_obs")
+    __slots__ = ("trainer", "examples", "args", "_t0", "_dt", "_obs",
+                 "_span")
 
     def __init__(self, trainer, examples, args):
         self.trainer = trainer
         self.examples = examples
         self.args = args
+        self._dt = None
 
     def __enter__(self):
         # pin the observer for the step: an install/uninstall mid-step
@@ -147,19 +190,30 @@ class _StepTimer:
         self._obs = _step_observer
         if self._obs is not None:
             self._obs.begin_step(self.trainer)
+        self._span = trace_mod.span(self.trainer + "/step",
+                                    cat="trainer", **self.args)
+        self._span.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        t0 = self._t0
-        dt = time.perf_counter() - t0
-        trace_mod.emit_span(self.trainer + "/step", t0, dt,
-                            cat="trainer", args=self.args)
+        if exc_type is None and self._dt is None:
+            self.record()
+        dt = self._dt
+        if dt is None:
+            dt = time.perf_counter() - self._t0
+        self._span.__exit__(exc_type, exc, tb)
         if self._obs is not None:
             self._obs.end_step(self.trainer, dt, self.examples,
                                failed=exc_type is not None)
-        if exc_type is not None:
-            return False
+        return False
+
+    def record(self):
+        """Feed the trainer metrics with the time since entry.  Exit
+        does it; a step with work of its own after the timed part (its
+        monitor, its flight record, which reads these counters) calls
+        it there, inside its span, and exit does not count it twice."""
+        self._dt = dt = time.perf_counter() - self._t0
         reg = _reg()
         reg.counter("trainer_steps_total", "completed train steps",
                     labelnames=("trainer",)) \
@@ -179,13 +233,13 @@ class _StepTimer:
                           labelnames=("trainer",)) \
                    .labels(trainer=self.trainer) \
                    .set(self.examples / dt)
-        return False
 
 
 def step(trainer, examples=None, **args):
     """`with telemetry.step("v2", examples=len(batch)): run_step()` —
-    times the step, feeds the trainer metrics, emits a span."""
-    return _StepTimer(trainer, examples, args or None)
+    times the step inside a `<trainer>/step` span, feeds the trainer
+    metrics."""
+    return _StepTimer(trainer, examples, args)
 
 
 def set_gauge(name, value, **labels):

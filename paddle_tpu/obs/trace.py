@@ -1,6 +1,17 @@
-"""Span tracer: nested, thread-safe, exportable as Chrome trace-event
-JSON (the `{"traceEvents": [...]}` format Perfetto and chrome://tracing
-load directly).
+"""Span tracer: nested, thread-safe, with two sinks.
+
+Every `span(...)` is a `jax.profiler.TraceAnnotation`: while a profiler
+session runs (`jax.profiler.start_trace`,
+`fluid.profiler.profiler(trace_dir=...)`) it lands on the host line of
+the profiler's own trace, on the clock the device's operations are on,
+nested by containment beside JAX's `PjitFunction(...)` and
+`PjRt...Execute` events, its arguments as the event's stats.  The
+profiler's own check decides whether it records; with no session it is
+one object made and dropped.  While `obs.trace` is enabled
+(`enable()`/`tracing()`) the same span is also kept in memory and is
+exportable as Chrome trace-event JSON (the `{"traceEvents": [...]}`
+format Perfetto and chrome://tracing load directly); `obs.tail`,
+`obs.perf` and `obs_dump` read that sink.
 
 Spans are recorded as complete ("X") events — begin timestamp plus
 duration — which Perfetto nests by containment per thread track, so
@@ -11,8 +22,11 @@ moments rather than ranges (jit trace/compile detections).
 Concurrency model: one global event list behind a lock, appended to
 only at span *exit* (one append per span), with per-thread track ids
 and thread-name metadata emitted lazily.  The disabled path is one
-module-level flag check returning a shared null context manager, so
-leaving tracing off costs nothing measurable on the executor hot path.
+module-level flag check and the bare annotation (under a microsecond a
+span), so leaving tracing off costs nothing measurable on the executor
+hot path.  `emit_span` and `instant` record after the fact or at a
+point, which the profiler's annotation cannot, so they reach the
+in-memory sink only.
 
 The buffer is bounded (`max_events`); once full, new events are
 dropped and counted (`dropped_events()`), never silently swallowed:
@@ -22,6 +36,8 @@ the export embeds the drop count as process metadata.
 import json
 import threading
 import time
+
+from jax.profiler import TraceAnnotation
 
 __all__ = ["enable", "disable", "is_enabled", "reset", "tracing",
            "span", "instant", "emit_span", "events", "event_count",
@@ -187,48 +203,43 @@ def dropped_events():
 # spans
 # ---------------------------------------------------------------------------
 
-class _NullSpan:
-    """Shared no-op context manager returned while tracing is off."""
+class _ProfilerSpan(TraceAnnotation):
+    """What `span` returns while obs.trace is off: the profiler's
+    annotation and nothing else."""
 
     __slots__ = ()
 
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
     def set(self, **args):
+        """Attach/extend args after entry (e.g. a compile-hit flag
+        only known at the end of the span)."""
+        self.set_metadata(**args)
         return self
 
 
-_NULL = _NullSpan()
+class _Span(_ProfilerSpan):
+    """The profiler's annotation plus one in-memory event at exit."""
 
-
-class _Span:
     __slots__ = ("name", "cat", "args", "_t0")
 
     def __init__(self, name, cat, args):
+        super().__init__(name, **args)
         self.name = name
         self.cat = cat
         self.args = args
 
     def set(self, **args):
-        """Attach/extend args after entry (e.g. a compile-hit flag
-        only known at the end of the span)."""
-        if self.args is None:
-            self.args = args
-        else:
-            self.args.update(args)
-        return self
+        self.args.update(args)
+        return super().set(**args)
 
     def __enter__(self):
+        super().__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         t0 = self._t0
         dur = time.perf_counter() - t0
+        super().__exit__(*exc)
         ev = {"name": self.name, "cat": self.cat, "ph": "X",
               "ts": (t0 - _epoch) * 1e6, "dur": dur * 1e6,
               "pid": _PID, "tid": _tid()}
@@ -239,11 +250,13 @@ class _Span:
 
 
 def span(name, cat="paddle_tpu", **args):
-    """Context manager timing one nested region.  Cheap no-op while
-    tracing is disabled."""
+    """Context manager around one nested region: always an annotation
+    in the profiler's trace (recorded while a profiler session runs),
+    and an in-memory event too while obs.trace is enabled.  `name` is a
+    fixed `<layer>/<what>`; what varies goes into `args`."""
     if not _enabled:
-        return _NULL
-    return _Span(name, cat, args or None)
+        return _ProfilerSpan(name, **args)
+    return _Span(name, cat, args)
 
 
 def emit_span(name, t0_perf, dur_s, cat="paddle_tpu", args=None):

@@ -17,6 +17,7 @@ from ..jit import FunctionalProgram, state_from_scope
 from ..obs import flight as obs_flight
 from ..obs import health as obs_health
 from ..obs import telemetry as obs_tele
+from ..obs import trace as obs_trace
 from ..utils import flags as _flags
 from .sharding import (param_spec, batch_spec, is_optimizer_state,
                        optimizer_state_names, zero1_spec)
@@ -229,45 +230,55 @@ class ParallelTrainer:
             fp=fp, zero_stage=self.zero_stage, feed_specs=self.feed_specs)
 
     def step(self, feeds):
-        rng = jax.random.fold_in(self._base_rng, self._step_count)
         step_id = self._step_count
         self._step_count += 1
-        feeds = {n: jnp_asarray(v) for n, v in feeds.items()}
-        examples = next((int(v.shape[0]) for v in feeds.values()
-                         if getattr(v, "ndim", 0)), None)
-        # step telemetry into the unified registry + a parallel/step
-        # span; block on the fetches so trainer_step_seconds is device
-        # time, never just the async dispatch (~µs).  Fetches are the
-        # replicated loss/metric scalars every caller reads right
-        # after, and new_state materializes in the same executable, so
-        # this costs the host-side feed-prep overlap only.
-        try:
-            with obs_tele.step("parallel", examples=examples,
-                               step=step_id):
+        # step telemetry into the unified registry inside a
+        # parallel/step span, each part of the step a child span of its
+        # own: in the profiler's trace they say what the host was doing
+        # while the devices idled
+        with obs_tele.step("parallel", step=step_id) as timer:
+            with obs_trace.span("parallel/prepare", cat="trainer"):
+                rng = jax.random.fold_in(self._base_rng, step_id)
+                feeds = {n: jnp_asarray(v) for n, v in feeds.items()}
+                timer.examples = next(
+                    (int(v.shape[0]) for v in feeds.values()
+                     if getattr(v, "ndim", 0)), None)
+            try:
                 # trace under the mesh context so mesh-aware op kernels
                 # (ring flash_attention) see the sp topology
-                with jax.set_mesh(self.mesh):
+                with obs_trace.span("parallel/dispatch", cat="trainer"), \
+                        jax.set_mesh(self.mesh):
                     fetches, self.state = self._step_fn(self.state,
                                                         feeds, rng)
-                jax.block_until_ready(fetches)
-        except Exception as exc:
-            obs_flight.on_crash(exc, origin="parallel/step",
-                                step=step_id,
-                                feeds=obs_flight.describe_feeds(feeds))
-            raise
-        monitor = getattr(self, "_monitor", None)
-        if monitor is not None:
-            n_user = len(self.fetch_names)
-            monitor.record(dict(zip(monitor.fetch_names,
-                                    fetches[n_user:])))
-            fetches = fetches[:n_user]
-        if obs_flight.active():
-            loss = None
-            first = fetches[0] if fetches else None
-            if first is not None and getattr(first, "size", 0) == 1:
-                loss = float(np.asarray(first).reshape(-1)[0])
-            obs_flight.record_step("parallel", step_id, feeds=feeds,
-                                   loss=loss)
+                # block on the fetches so trainer_step_seconds is device
+                # time, never just the async dispatch (~us).  Fetches
+                # are the replicated loss/metric scalars every caller
+                # reads right after, and new_state materializes in the
+                # same executable, so this costs the host-side
+                # feed-prep overlap only.
+                with obs_trace.span("parallel/wait", cat="trainer"):
+                    jax.block_until_ready(fetches)
+            except Exception as exc:
+                obs_flight.on_crash(
+                    exc, origin="parallel/step", step=step_id,
+                    feeds=obs_flight.describe_feeds(feeds))
+                raise
+            with obs_trace.span("parallel/record", cat="trainer"):
+                timer.record()
+                monitor = getattr(self, "_monitor", None)
+                if monitor is not None:
+                    n_user = len(self.fetch_names)
+                    monitor.record(dict(zip(monitor.fetch_names,
+                                            fetches[n_user:])))
+                    fetches = fetches[:n_user]
+                if obs_flight.active():
+                    loss = None
+                    first = fetches[0] if fetches else None
+                    if first is not None \
+                            and getattr(first, "size", 0) == 1:
+                        loss = float(np.asarray(first).reshape(-1)[0])
+                    obs_flight.record_step("parallel", step_id,
+                                           feeds=feeds, loss=loss)
         return fetches
 
     def fetch_state(self, name):

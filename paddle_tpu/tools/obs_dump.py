@@ -551,7 +551,7 @@ def selftest(args):
     events = validate_chrome_trace(trace_path)
     steps = _find_span(events, "v2/step")
     runs = _find_span(events, "executor/run")
-    segs = _find_span(events, "executor/jit_segment")
+    segs = _find_span(events, "executor/segment")
     serving_spans = _find_span(events, "serving/engine_run")
     assert steps, "no trainer spans in trace"
     assert runs, "no executor spans in trace"

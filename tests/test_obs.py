@@ -388,13 +388,16 @@ def test_executor_spans_and_profiler_records_together():
     events = validate_chrome_trace(obs_trace.to_chrome_trace())
     names = {e["name"] for e in events if e["ph"] == "X"}
     assert any(n.startswith("executor/run") for n in names)
-    assert any(n.startswith("executor/jit_segment") for n in names)
+    assert "executor/segment" in names
     assert any("mean" in n for n in names)  # eager op span
-    # run spans contain their segment spans on the same thread
+    # run spans contain their segment spans on the same thread; the
+    # segment's label (the profiler table's row) is an argument
     runs = [e for e in events if e["ph"] == "X"
             and e["name"] == "executor/run"]
     segs = [e for e in events if e["ph"] == "X"
-            and e["name"].startswith("executor/jit_segment")]
+            and e["name"] == "executor/segment"]
+    assert {s["args"]["segment"] for s in segs if s["args"]["jit"]} \
+        <= set(records) | {k.split("/first")[0] for k in records}
     assert any(r["ts"] <= s["ts"] + 1e-3
                and s["ts"] + s["dur"] <= r["ts"] + r["dur"] + 1e-3
                and r["tid"] == s["tid"]
