@@ -57,7 +57,7 @@ def cached_attention(query, key, value, k_cache, v_cache, position,
 
 def flash_attention(queries, keys, values, num_heads=1, causal=False,
                     sm_scale=None, sequence_parallel_axis="",
-                    sequence_parallel_mode="ring", block_size=128,
+                    sequence_parallel_mode="ring", block_size=None,
                     name=None):
     """Fused multi-head attention over dense [batch, seq, dim] tensors.
 
@@ -71,7 +71,8 @@ def flash_attention(queries, keys, values, num_heads=1, causal=False,
     mode "ring" rotates K/V over ICI neighbors while q/k/v stay
     sequence-sharded; mode "ulysses" all-to-alls the shard axis from
     sequence to heads and attends full sequences locally
-    (parallel/ring.py).
+    (parallel/ring.py).  The kernel chooses its block sizes from the
+    shapes unless `block_size` names one.
     """
     helper = LayerHelper("flash_attention", name=name)
     out = helper.create_tmp_variable(queries.dtype)
@@ -83,7 +84,7 @@ def flash_attention(queries, keys, values, num_heads=1, causal=False,
                "sm_scale": float(sm_scale or 0.0),
                "sequence_parallel_axis": sequence_parallel_axis,
                "sequence_parallel_mode": sequence_parallel_mode,
-               "block_size": int(block_size)})
+               "block_size": int(block_size or 0)})
     return out
 
 

@@ -30,7 +30,8 @@ import jax.monitoring
 from . import registry as registry_mod
 from . import trace as trace_mod
 
-__all__ = ["on_executor_run", "on_jit_trace", "on_transfer",
+__all__ = ["on_executor_run", "on_jit_trace",
+           "on_flash_attention_lowering", "on_transfer",
            "on_feed_seconds", "on_program_cache_evict",
            "jit_trace_count", "transfer_bytes", "step", "set_gauge",
            "install_step_observer", "step_observer", "snapshot",
@@ -70,6 +71,17 @@ def jit_trace_count():
     return _reg().counter("executor_jit_traces_total",
                           "XLA trace/compile events detected across "
                           "jitted segments").value
+
+
+def on_flash_attention_lowering(block_q, block_k, kv_resident):
+    """The flash-attention forward kernel was traced into a program,
+    with the tiling it chose from the shapes (or was told): one count
+    per kernel instance a lowered program holds."""
+    _reg().counter("flash_attention_lowerings_total",
+                   "flash-attention forward kernels lowered, by tiling",
+                   labelnames=("block_q", "block_k", "kv_resident")) \
+          .labels(block_q=block_q, block_k=block_k,
+                  kv_resident=str(bool(kv_resident)).lower()).inc()
 
 
 def on_program_cache_evict():

@@ -81,7 +81,8 @@ def flash_attention_op(ctx, ins, attrs):
                 % sp_mode)
         out = sp_shard_map(sp_fn, mesh, axis_name=sp_axis)(qh, kh, vh)
     else:
-        block = int(attrs.get("block_size", 128))
+        # 0: the kernel chooses its blocks from the shapes
+        block = int(attrs.get("block_size", 0)) or None
         out = flash_attention(qh, kh, vh, sm_scale, causal,
                               block_q=block, block_k=block)
     return {"Out": [_merge_heads(out).astype(q.dtype)]}

@@ -182,3 +182,44 @@ def test_flash_attention_op_in_program_grads_vs_reference():
     for g, w in zip(got, want):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w),
                                    rtol=1e-4, atol=1e-6)
+
+
+def _flash_lowerings():
+    from paddle_tpu.obs import telemetry
+
+    return {k: v for k, v in telemetry.snapshot().items()
+            if k.startswith("flash_attention_lowerings_total")}
+
+
+def test_block_size_in_a_program_is_honoured_and_its_absence_chooses():
+    """A program whose op says block_size=128 still lowers with 128 x 128
+    blocks; one that names none leaves the choice to the kernel (here
+    the whole 256-long sequence).  The counter's labels say which."""
+    B, T, D = 1, 256, 32
+    x0 = RS.randn(B, T, D).astype("float32")
+
+    def lowered_with(**layer_args):
+        main, startup = fluid.Program(), fluid.Program()
+        with fluid.program_guard(main, startup):
+            x = fluid.layers.data(name="x", shape=[B, T, D],
+                                  dtype="float32",
+                                  append_batch_size=False)
+            out = fluid.layers.flash_attention(x, x, x, num_heads=2,
+                                               **layer_args)
+        before = _flash_lowerings()
+        got, = fluid.Executor(fluid.CPUPlace()).run(
+            main, feed={"x": x0}, fetch_list=[out])
+        after = _flash_lowerings()
+        return np.asarray(got), {k: v - before.get(k, 0)
+                                 for k, v in after.items()
+                                 if v != before.get(k, 0)}
+
+    named, delta = lowered_with(block_size=128)
+    assert delta == {"flash_attention_lowerings_total{block_k=128,"
+                     "block_q=128,kv_resident=true}": 1}
+    chosen, delta = lowered_with()
+    assert delta == {"flash_attention_lowerings_total{block_k=256,"
+                     "block_q=256,kv_resident=true}": 1}
+    np.testing.assert_allclose(named, chosen, atol=2e-5)
+    np.testing.assert_allclose(named, _dense_ref(x0, x0, x0, 2, False),
+                               atol=2e-5)
