@@ -188,14 +188,24 @@ def append_backward(loss, parameter_list=None, no_grad_set=None,
         wanted = set(parameter_list)
         params = [p for p in params if p.name in wanted]
     params_grads = []
+    shared_uses = 0
     for p in params:
         if not getattr(p, "trainable", True):
             continue
+        uses = len(state.contribs[p.name])
+        if uses > 1:
+            # a weight read by several ops (shared across depth): its
+            # gradient is the `sum` of one contribution per use
+            shared_uses += uses
         gname = state.finalize(p.name)
         if gname is None:
             continue
         params_grads.append((p, gname))
 
+    if shared_uses:
+        from ..obs import telemetry
+
+        telemetry.on_shared_parameter_uses(program, shared_uses)
     if callbacks is None:
         callbacks = [_error_clip_callback]
     elif not isinstance(callbacks, (list, tuple)):

@@ -10,8 +10,8 @@ program and initialized exactly once in the startup program via
 :meth:`_declare_initialized`.
 """
 
-from .framework import Variable, unique_name, default_main_program, \
-    default_startup_program
+from .framework import Variable, Parameter, unique_name, \
+    default_main_program, default_startup_program
 from .param_attr import ParamAttr
 
 __all__ = ["LayerHelper"]
@@ -124,6 +124,20 @@ class LayerHelper:
         assert isinstance(attr, ParamAttr)
         if attr.name is None:
             attr.name = self._uniq("w")
+        shape = [int(s) for s in shape]
+        shared = self.main_program.global_block().vars.get(attr.name)
+        if shared is not None:
+            # a second layer names a parameter that exists: the weight
+            # is shared.  It keeps its one declaration (and that one's
+            # type: under AMP the second reader's input may be bfloat16
+            # where the first's was float32) and its one initialiser in
+            # the start-up program.
+            if not isinstance(shared, Parameter) or \
+                    list(shared.shape) != shape:
+                raise ValueError(
+                    "parameter %r is shared with shape %s but exists as %r"
+                    % (attr.name, shape, shared))
+            return shared
         if default_initializer is not None:
             attr.set_default_initializer(default_initializer)
         elif is_bias:
@@ -131,7 +145,6 @@ class LayerHelper:
         else:
             attr.set_default_param_initializer()
 
-        shape = [int(s) for s in shape]
         param_kwargs = attr.to_kwargs()
         param_kwargs.pop("name", None)
         param = self.main_program.global_block().create_parameter(

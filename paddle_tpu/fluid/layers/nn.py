@@ -28,7 +28,7 @@ __all__ = [
     "sequence_slice", "lod_reset", "edit_distance", "ctc_greedy_decoder",
     "sequence_concat", "beam_search", "beam_search_decode",
     "sequence_reverse", "sequence_unnest", "sequence_renest",
-    "flash_attention", "cached_attention",
+    "flash_attention", "cached_attention", "rms_norm", "rope",
 ]
 
 
@@ -642,6 +642,34 @@ def layer_norm(input, scale=True, shift=True, begin_norm_axis=1,
         outputs={"Y": [out], "Mean": [mean_out], "Variance": [var_out]},
         attrs={"epsilon": epsilon, "begin_norm_axis": begin_norm_axis})
     return helper.append_activation(out)
+
+
+def rms_norm(input, epsilon=1e-6, param_attr=None, **kwargs):
+    """Root-mean-square normalisation over the last axis with a learned
+    scale (ops/norm.py rms_norm): no mean, no bias."""
+    helper = LayerHelper("rms_norm", param_attr=param_attr, **kwargs)
+    scale = helper.create_parameter(
+        helper.param_attr, shape=[input.shape[-1]], dtype=input.dtype,
+        default_initializer=Constant(1.0))
+    out = helper.create_tmp_variable(input.dtype)
+    helper.append_op(type="rms_norm",
+                     inputs={"X": [input], "Scale": [scale]},
+                     outputs={"Y": [out]}, attrs={"epsilon": epsilon})
+    return out
+
+
+def rope(input, positions, num_heads, theta=10000.0, **kwargs):
+    """Rotary position embedding on each head of `input` [batch, seq,
+    num_heads * head_dim] at `positions` [batch, seq] (ops/attention.py
+    rope): rotate-half form, base `theta`."""
+    helper = LayerHelper("rope", **kwargs)
+    out = helper.create_tmp_variable(input.dtype)
+    helper.append_op(type="rope",
+                     inputs={"X": [input], "Positions": [positions]},
+                     outputs={"Out": [out]},
+                     attrs={"num_heads": int(num_heads),
+                            "theta": float(theta)})
+    return out
 
 
 def lrn(input, n=5, k=1.0, alpha=1e-4, beta=0.75, **kwargs):

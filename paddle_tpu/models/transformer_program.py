@@ -1,7 +1,9 @@
 """Transformer built through the Program stack (fluid layers).
 
-The raw-JAX flagship (models/transformer.py) covers scale experiments;
-this is the same GPT-style decoder expressed as a fluid Program, so the
+The GPT-2-shaped decoder (learned positions, LayerNorm, a ReLU MLP)
+expressed as a fluid Program; the block open models have been made of
+since 2023 (RMSNorm, rotary positions, a gated SiLU MLP, weights shared
+across depth) is models/looped_program.py, on the same stack.  Here the
 whole framework surface applies: real optimizers with accumulators,
 regularizers/clipping, LR schedules, checkpointing, the transpiler, and
 `ParallelTrainer` sharding over dp×mp×sp meshes.  Attention is the
@@ -11,8 +13,7 @@ is the in-framework surface the reference lacks (its nets-module
 attention materializes the [T,T] matrix, reference:
 python/paddle/v2/fluid/nets.py:338).
 
-Activation is relu (the 2018 reference op set has no gelu; the raw-JAX
-stack uses gelu where it matters for parity with modern checkpoints).
+Activation is relu (the 2018 reference op set has no gelu).
 """
 
 import numpy as np

@@ -31,7 +31,8 @@ from . import registry as registry_mod
 from . import trace as trace_mod
 
 __all__ = ["on_executor_run", "on_jit_trace",
-           "on_flash_attention_lowering", "on_transfer",
+           "on_flash_attention_lowering", "on_shared_parameter_uses",
+           "on_transfer",
            "on_feed_seconds", "on_program_cache_evict",
            "jit_trace_count", "transfer_bytes", "step", "set_gauge",
            "install_step_observer", "step_observer", "snapshot",
@@ -82,6 +83,17 @@ def on_flash_attention_lowering(block_q, block_k, kv_resident):
                    labelnames=("block_q", "block_k", "kv_resident")) \
           .labels(block_q=block_q, block_k=block_k,
                   kv_resident=str(bool(kv_resident)).lower()).inc()
+
+
+def on_shared_parameter_uses(program, uses):
+    """`append_backward` found parameters that more than one op reads
+    (weights shared across depth): `uses` gradient contributions a step
+    are summed into their gradients, one `sum` op per such parameter."""
+    _reg().counter("program_shared_parameter_uses",
+                   "gradient contributions summed per step for "
+                   "parameters that several ops read",
+                   labelnames=("program",)) \
+          .labels(program=str(program._cache_token)).inc(uses)
 
 
 def on_program_cache_evict():

@@ -17,8 +17,9 @@ programs scale to long context without leaving the Program stack.
 """
 
 import jax
+import jax.numpy as jnp
 
-from .registry import register_op
+from .registry import register_op, same_meta_infer_shape
 
 
 def _ambient_mesh():
@@ -86,6 +87,32 @@ def flash_attention_op(ctx, ins, attrs):
         out = flash_attention(qh, kh, vh, sm_scale, causal,
                               block_q=block, block_k=block)
     return {"Out": [_merge_heads(out).astype(q.dtype)]}
+
+
+@register_op("rope", nondiff_inputs=("Positions",),
+             infer_shape=same_meta_infer_shape("X", "Out"))
+def rope(ctx, ins, attrs):
+    """Rotary position embedding (Su et al. 2021) in the rotate-half
+    form, on each head of X [batch, seq, heads * head_dim] at Positions
+    [batch, seq]: the pair (x_i, x_{i + head_dim/2}) is turned by the
+    angle position * theta^(-2i / head_dim).  Angles and rotation in
+    float32, the result in X's type."""
+    x = ins["X"][0]
+    pos = ins["Positions"][0]
+    num_heads = int(attrs["num_heads"])
+    theta = float(attrs.get("theta", 10000.0))
+    b, t, d = x.shape
+    if d % (2 * num_heads):
+        raise ValueError("rope: hidden size %d is not num_heads %d times "
+                         "an even head size" % (d, num_heads))
+    half = d // num_heads // 2
+    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    angles = pos.reshape(b, t, 1, 1).astype(jnp.float32) * inv_freq
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    xs = x.astype(jnp.float32).reshape(b, t, num_heads, 2, half)
+    x1, x2 = xs[..., 0, :], xs[..., 1, :]
+    out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-2)
+    return {"Out": [out.reshape(b, t, d).astype(x.dtype)]}
 
 
 @register_op("cached_attention", stop_gradient_op=True)

@@ -1,4 +1,4 @@
-"""Normalization op kernels: batch_norm, layer_norm, norm (l2).
+"""Normalization op kernels: batch_norm, layer_norm, rms_norm, norm (l2).
 
 TPU-native equivalents of reference ops (paddle/operators/
 batch_norm_op.cc + cudnn variant, norm_op.cc; layer_norm is provided for
@@ -10,7 +10,8 @@ which must not be differentiated through.
 import jax
 import jax.numpy as jnp
 
-from .registry import register_op, register_grad_kernel
+from .registry import (register_op, register_grad_kernel,
+                       same_meta_infer_shape)
 from ..utils import flags
 
 
@@ -313,6 +314,20 @@ def layer_norm_grad(ctx, ins, attrs):
             jnp.zeros(x2.shape[1], jnp.float32)
         out["Bias@GRAD"] = [bg]
     return out
+
+
+@register_op("rms_norm", infer_shape=same_meta_infer_shape("X", "Y"))
+def rms_norm(ctx, ins, attrs):
+    """y = x * rsqrt(mean(x^2) + eps) * scale over the last axis (Zhang &
+    Sennrich 2019), statistics and scaling in float32 whatever x's type,
+    the result in x's type."""
+    x = ins["X"][0]
+    eps = attrs.get("epsilon", 1e-6)
+    xs = x.astype(jnp.float32)
+    inv = jax.lax.rsqrt(jnp.mean(jnp.square(xs), axis=-1, keepdims=True)
+                        + eps)
+    y = xs * inv * ins["Scale"][0].astype(jnp.float32)
+    return {"Y": [y.astype(x.dtype)]}
 
 
 @register_op("norm")

@@ -231,6 +231,19 @@ def generic_infer_shape(op_type, ins_meta, attrs):
     return result
 
 
+def same_meta_infer_shape(in_slot, out_slot):
+    """An explicit shape rule for an op whose output has its input's
+    shape, type and LoD: the build then copies the meta instead of
+    tracing the kernel (registry.generic_infer_shape)."""
+    def infer(block, op_desc):
+        src = block.var_recursive(op_desc.input(in_slot)[0]).desc
+        for name in op_desc.output(out_slot):
+            dst = block.var_recursive(name).desc
+            dst.shape, dst.dtype = tuple(src.shape), src.dtype
+            dst.lod_level = src.lod_level
+    return infer
+
+
 # ---------------------------------------------------------------------------
 # Generic vjp-based grad kernel
 # ---------------------------------------------------------------------------
