@@ -31,7 +31,8 @@ from . import registry as registry_mod
 from . import trace as trace_mod
 
 __all__ = ["on_executor_run", "on_jit_trace",
-           "on_flash_attention_lowering", "on_shared_parameter_uses",
+           "on_flash_attention_lowering",
+           "on_flash_attention_bwd_lowering", "on_shared_parameter_uses",
            "on_transfer",
            "on_feed_seconds", "on_program_cache_evict",
            "jit_trace_count", "transfer_bytes", "step", "set_gauge",
@@ -83,6 +84,18 @@ def on_flash_attention_lowering(block_q, block_k, kv_resident):
                    labelnames=("block_q", "block_k", "kv_resident")) \
           .labels(block_q=block_q, block_k=block_k,
                   kv_resident=str(bool(kv_resident)).lower()).inc()
+
+
+def on_flash_attention_bwd_lowering(kernel, block_q, block_k):
+    """One of the flash-attention backward kernels ("dq_dkv", the one
+    that makes all three gradients, or "dkv" and "dq", the two that
+    walk) was traced into a program, with the tiling chosen for it: one
+    count per kernel instance a lowered program holds."""
+    _reg().counter("flash_attention_bwd_lowerings_total",
+                   "flash-attention backward kernels lowered, by kernel "
+                   "and tiling",
+                   labelnames=("kernel", "block_q", "block_k")) \
+          .labels(kernel=kernel, block_q=block_q, block_k=block_k).inc()
 
 
 def on_shared_parameter_uses(program, uses):

@@ -5,9 +5,9 @@ probability matrix (reference: python/paddle/v2/fluid/nets.py:338
 scaled_dot_product_attention); registering the fused kernel as a
 first-class op exceeds that surface: programs built with
 `fluid.layers.flash_attention` get the pallas online-softmax kernel
-(kernels/flash_attention.py) on TPU, interpret mode on CPU, and the
-blockwise-recompute VJP through the generic grad machinery (the
-kernel's custom_vjp is what jax.vjp differentiates).
+(kernels/flash_attention.py) on TPU, interpret mode on CPU, and its
+backward kernels through the generic grad machinery (the kernel's
+custom_vjp is what jax.vjp differentiates).
 
 When the op's `sequence_parallel_axis` attr names an axis of the
 ambient device mesh (the mesh `ParallelTrainer` compiles under), the

@@ -33,14 +33,15 @@ def _json_lines(text):
 
 @pytest.mark.parametrize("causal", [False, True])
 @pytest.mark.parametrize("shape", [(2, 8, 512, 64), (1, 8, 4096, 128),
-                                   (8, 16, 1024, 64)])
+                                   (8, 16, 1024, 64), (1, 8, 32768, 128)])
 def test_flash_attention_lowers_for_tpu(shape, causal):
     """Forward and backward lower for the TPU platform from this CPU
     host, and what they lower to is the Mosaic kernel — not the pallas
     interpreter the CPU tests run.  Every refusal of the Pallas TPU
     lowering (block tiling, unimplemented primitives) surfaces here, at
     the blocks the kernel chooses for the smoke's shapes and for the
-    benchmark's gpt2m-train."""
+    benchmark's gpt2m-train, and at a length whose K/V and queries the
+    grids of all three kernels walk."""
     x = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
 
     def forward(q, k, v):

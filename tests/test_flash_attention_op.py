@@ -223,3 +223,53 @@ def test_block_size_in_a_program_is_honoured_and_its_absence_chooses():
     np.testing.assert_allclose(named, chosen, atol=2e-5)
     np.testing.assert_allclose(named, _dense_ref(x0, x0, x0, 2, False),
                                atol=2e-5)
+
+
+def test_block_size_reaches_the_backward_kernels_of_a_program():
+    """The gradient of a program's op runs the backward kernel (one for
+    a head this short), one count per lowering, at the block size the op
+    names (the generic gradient differentiates the kernel's custom_vjp);
+    the gradients are dense attention's."""
+    from paddle_tpu.obs import telemetry
+
+    B, T, D, H = 1, 256, 32, 2
+    x0 = (0.5 * RS.randn(B, T, D)).astype("float32")
+
+    def bwd_lowerings():
+        return {k: v for k, v in telemetry.snapshot().items()
+                if k.startswith("flash_attention_bwd_lowerings_total")}
+
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        x = fluid.layers.create_parameter([B, T, D], "float32")
+        out = fluid.layers.flash_attention(x, x, x, num_heads=H,
+                                           causal=True, block_size=128)
+        loss = fluid.layers.mean(x=out)
+        grads = fluid.backward.calc_gradient(loss, [x])
+
+    from paddle_tpu.core.scope import Scope
+    from paddle_tpu.fluid.executor import scope_guard, global_scope
+
+    before = bwd_lowerings()
+    with scope_guard(Scope()):
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        global_scope().set(x.name, jnp.asarray(x0))
+        got, = exe.run(main, feed={}, fetch_list=grads)
+    delta = {k: v - before.get(k, 0) for k, v in bwd_lowerings().items()
+             if v != before.get(k, 0)}
+    assert delta == {
+        "flash_attention_bwd_lowerings_total{block_k=128,block_q=128,"
+        "kernel=dq_dkv}": 1}
+
+    def heads(x):
+        return x.reshape(B, T, H, D // H).transpose(0, 2, 1, 3)
+
+    def ref_loss(x):
+        o = reference_attention(heads(x), heads(x), heads(x), None, True)
+        return jnp.mean(o.transpose(0, 2, 1, 3).reshape(B, T, D))
+
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(jax.grad(ref_loss)(
+                                   jnp.asarray(x0))),
+                               rtol=1e-4, atol=1e-7)

@@ -2,7 +2,8 @@
 sees (kernels/flash_attention.py: `_choose_blocks`, `_step_bytes`,
 `_VMEM_BUDGET`): the chooser as a pure function, the kernel's agreement
 with dense attention at the chosen blocks under the Pallas interpreter,
-the backward's own block, and the counter that names the tiling."""
+the backward kernels' own chooser and their agreement with dense
+attention's gradients, and the counters that name the tilings."""
 
 import importlib
 
@@ -20,8 +21,7 @@ fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
 
 # -- the chooser --------------------------------------------------------------
 
-@pytest.mark.parametrize("causal", [False, True])
-@pytest.mark.parametrize("tq,tk,d,dtype", [
+CHOOSER_SHAPES = [
     (1024, 1024, 64, jnp.bfloat16),
     (512, 512, 64, jnp.bfloat16),
     (4096, 4096, 128, jnp.bfloat16),
@@ -29,7 +29,11 @@ fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
     (1024, 1024, 64, jnp.float32),
     (200, 200, 16, jnp.float32),
     (128, 1024, 64, jnp.bfloat16),
-])
+]
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("tq,tk,d,dtype", CHOOSER_SHAPES)
 def test_chosen_blocks_tile_the_sequences_within_the_budget(
         tq, tk, d, dtype, causal):
     itemsize = jnp.dtype(dtype).itemsize
@@ -85,9 +89,11 @@ def _qkv(tq, tk, d=16, heads=2, seed=0):
 SHAPES = [(384, 384, 0), (384, 640, 256)]
 # 128 x 128 blocks at head size 16 in float32 hold 811008 bytes by
 # `_step_bytes` with one chunk of K/V in VMEM, 1351680 with 384 keys and
-# 1892352 with 640: the first budget keeps K/V resident, the second
-# makes the grid walk them
-BUDGETS = {"resident": 2000000, "walked": 1000000}
+# 1892352 with 640, and by `_bwd_step_bytes` 1327104 in the two kernels
+# that walk and 2482176 in the one that holds a head's 384 queries: the
+# first budget keeps K/V resident in the forward and gives the backward
+# its one kernel, the second makes the grids walk in both
+BUDGETS = {"resident": 2500000, "walked": 1340000}
 
 
 @pytest.mark.parametrize("causal", [False, True])
@@ -101,6 +107,8 @@ def test_chosen_blocks_match_dense_attention(monkeypatch, kv, tq, tk,
     # several blocks on both axes, and the path the budget asks for
     assert tq // bq >= 2 and tk // bk >= 2
     assert resident == (kv == "resident")
+    assert fa._choose_bwd_blocks(q.shape, k.shape, 4, causal) \
+        == (bq, bk, resident)
 
     def loss(attention):
         return lambda q, k, v: jnp.sum(jnp.sin(attention(q, k, v)))
@@ -129,27 +137,121 @@ def test_forward_statistics_keep_their_shapes_and_meaning():
                                rtol=1e-5)
 
 
-# -- the backward's block is its own ------------------------------------------
+# -- the backward's blocks are its own -----------------------------------------
 
-@pytest.mark.parametrize("seq,named,expected", [(256, None, 128),
-                                                (64, 16, 16)])
-def test_backward_block_does_not_follow_the_forward(monkeypatch, seq,
-                                                    named, expected):
-    """The scan materialises [B, H, Tq, block_k] float32 tensors: with no
-    block named it keeps 128 whatever the forward chose."""
-    seen = []
-    real = fa._bwd
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("tq,tk,d,dtype", CHOOSER_SHAPES)
+def test_backward_blocks_tile_the_sequences_within_the_budget(
+        tq, tk, d, dtype, causal):
+    itemsize = jnp.dtype(dtype).itemsize
+    q_shape, k_shape = (8, 16, tq, d), (8, 16, tk, d)
+    bq, bk, one_kernel = fa._choose_bwd_blocks(q_shape, k_shape, itemsize,
+                                               causal)
+    assert tq % bq == 0 and tk % bk == 0
+    assert bq % 128 == 0 or bq == tq
+    assert bk % 128 == 0 or bk == tk
+    # a step holds four chunks of scores where the forward's holds two
+    assert fa._bwd_step_bytes(bq, bk, d, itemsize) \
+        > fa._step_bytes(bq, bk, bk, d, itemsize)
+    assert fa._bwd_step_bytes(bq, bk, d, itemsize,
+                              tq if one_kernel else None) <= fa._VMEM_BUDGET
+    if not one_kernel:
+        # not even the smallest blocks leave room for a head's queries
+        assert fa._bwd_step_bytes(min(128, tq), min(128, tk), d, itemsize,
+                                  tq) > fa._VMEM_BUDGET
+    # the benchmark's shapes get the one kernel, 32k positions the two
+    assert one_kernel == (tq <= 4096)
+    if causal:
+        # the diagonal still cuts work off, unless nothing tiles
+        assert bq <= max(128, tq // 2) or tq % 128
+        assert bk <= max(128, tk // 2) or tk % 128
+    # a named block is kept beside a chosen one
+    if tq % 128 == 0:
+        assert fa._choose_bwd_blocks(q_shape, k_shape, itemsize, causal,
+                                     block_q=128)[0] == 128
+        assert fa._choose_bwd_blocks(q_shape, k_shape, itemsize, causal,
+                                     128, 128)[:2] == (128, 128)
 
-    def spy(sm_scale, causal, block_k, *rest):
-        seen.append(block_k)
-        return real(sm_scale, causal, block_k, *rest)
 
-    monkeypatch.setattr(fa, "_bwd", spy)
-    q, k, v = _qkv(seq, seq)
-    assert fa._choose_blocks(q.shape, k.shape, 4, False)[1] == seq
-    jax.grad(lambda q: fa.flash_attention(
-        q, k, v, None, False, named, named).sum())(q)
-    assert seen == [expected]
+def _grads(attention, q, k, v, do):
+    return jax.vjp(attention, q, k, v)[1](do)
+
+
+@pytest.mark.parametrize("d,tolerance", [(64, 0.03), (128, 0.03)])
+def test_bf16_gradients_agree_with_dense_float32_attention(d, tolerance):
+    """Operands of every product in bf16, as both cells run it, float32
+    to accumulate: each gradient lies within 3% of its largest entry of
+    what dense attention gives in float32 on the same (bf16) values."""
+    q, k, v = (x.astype(jnp.bfloat16) for x in _qkv(256, 256, d=d, seed=3))
+    do = _qkv(256, 256, d=d, seed=4)[0].astype(jnp.bfloat16)
+    assert fa._choose_bwd_blocks(q.shape, k.shape, 2, True) \
+        == (128, 128, True)
+    got = _grads(lambda q, k, v: fa.flash_attention(q, k, v, None, True),
+                 q, k, v, do)
+    want = _grads(
+        lambda q, k, v: fa.reference_attention(q, k, v, None, True),
+        *(x.astype(jnp.float32) for x in (q, k, v, do)))
+    for a, b in zip(got, want):
+        assert a.dtype == jnp.bfloat16
+        np.testing.assert_allclose(
+            a.astype(jnp.float32), b,
+            atol=tolerance * float(jnp.max(jnp.abs(b))))
+
+
+@pytest.mark.parametrize("tq,tk,q_offset,unseen_from", [
+    # the first 128 queries of 384 positions: key blocks 1 and 2 are
+    # seen by no query
+    (128, 384, 0, 128),
+    # a query shard that starts at position 128 and stops before the
+    # last key block
+    (256, 512, 128, 384),
+])
+def test_key_blocks_no_query_sees_get_exact_zeros(tq, tk, q_offset,
+                                                  unseen_from):
+    q, k, v = _qkv(tq, tk, seed=5)
+    do = _qkv(tq, tk, seed=6)[0]
+    got = _grads(lambda q, k, v: fa.flash_attention(
+        q, k, v, None, True, None, None, q_offset), q, k, v, do)
+    want = _grads(lambda q, k, v: fa.reference_attention(
+        q, k, v, None, True, q_offset), q, k, v, do)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, atol=5e-5)
+    dq, dk, dv = got
+    assert np.abs(np.asarray(dk[:, :, :unseen_from])).max() > 0
+    assert not np.asarray(dk[:, :, unseen_from:]).any()
+    assert not np.asarray(dv[:, :, unseen_from:]).any()
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_a_ragged_sequence_is_one_whole_block_in_the_backward(causal):
+    q, k, v = _qkv(200, 200, seed=7)
+    do = _qkv(200, 200, seed=8)[0]
+    assert fa._choose_bwd_blocks(q.shape, k.shape, 4, causal) \
+        == (200, 200, True)
+    got = _grads(lambda q, k, v: fa.flash_attention(q, k, v, None, causal),
+                 q, k, v, do)
+    want = _grads(lambda q, k, v: fa.reference_attention(
+        q, k, v, None, causal), q, k, v, do)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, atol=5e-5)
+
+
+@pytest.mark.parametrize("budget", [None, 0])
+def test_named_blocks_of_16_are_kept_by_one_kernel_and_by_two(
+        monkeypatch, budget):
+    if budget is not None:
+        # named blocks are kept though nothing fits: the two kernels
+        monkeypatch.setattr(fa, "_VMEM_BUDGET", budget)
+    q, k, v = _qkv(64, 64, seed=9)
+    do = _qkv(64, 64, seed=10)[0]
+    assert fa._choose_bwd_blocks(q.shape, k.shape, 4, True, 16, 16) \
+        == (16, 16, budget is None)
+    got = _grads(lambda q, k, v: fa.flash_attention(
+        q, k, v, None, True, 16, 16), q, k, v, do)
+    want = _grads(lambda q, k, v: fa.reference_attention(
+        q, k, v, None, True), q, k, v, do)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, atol=5e-5)
 
 
 # -- the counter names the tiling ---------------------------------------------
@@ -175,3 +277,44 @@ def test_counter_rises_once_per_lowering(named):
     jax.jit(jax.grad(lambda q, k, v: fn(q, k, v).astype(
         jnp.float32).sum())).lower(x, x, x)
     assert _lowerings(*labels) == before + 2
+
+
+def _bwd_lowerings(kernel, bq, bk):
+    return telemetry.snapshot().get(
+        "flash_attention_bwd_lowerings_total{block_k=%d,block_q=%d,"
+        "kernel=%s}" % (bk, bq, kernel), 0)
+
+
+@pytest.mark.parametrize("shape,named,blocks,kernels", [
+    ((8, 16, 1024, 64), None, (512, 512), {"dq_dkv": ""}),
+    ((8, 16, 1024, 64), 128, (128, 128), {"dq_dkv": ""}),
+    ((1, 8, 32768, 128), None, (1024, 512),
+     {"dkv": "_dkv", "dq": "_dq"}),
+])
+def test_backward_counter_rises_once_per_kernel_and_lowering(
+        shape, named, blocks, kernels):
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    bq, bk, one_kernel = fa._choose_bwd_blocks(shape, shape, 2, True,
+                                               named, named)
+    assert (bq, bk) == blocks and one_kernel == (len(kernels) == 1)
+    before = [_bwd_lowerings(kernel, bq, bk) for kernel in kernels]
+
+    def loss(q, k, v):
+        return fa.flash_attention(q, k, v, None, True, named, named) \
+            .astype(jnp.float32).sum()
+
+    # the forward alone holds no backward kernel
+    jax.jit(loss).lower(x, x, x)
+    assert [_bwd_lowerings(kernel, bq, bk) for kernel in kernels] == before
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(x, x, x) \
+        .as_text(debug_info=True)
+    assert [_bwd_lowerings(kernel, bq, bk) for kernel in kernels] \
+        == [n + 1 for n in before]
+    for suffix in kernels.values():
+        assert "flash_attention_bwd%s_q%d_k%d" % (suffix, bq, bk) in text
+    assert "flash_attention_bwd/" in text or "flash_attention_bwd)" in text
+    # another program with the same attention counts again, though it
+    # shares the kernels' trace
+    jax.jit(jax.grad(lambda q, k, v: 2 * loss(q, k, v))).lower(x, x, x)
+    assert [_bwd_lowerings(kernel, bq, bk) for kernel in kernels] \
+        == [n + 2 for n in before]

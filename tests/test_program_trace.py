@@ -208,7 +208,7 @@ def test_compiled_flash_program_names_the_backward():
         "positions": jnp.zeros((1, 128), jnp.int32),
         "targets": jnp.zeros((1, 128, 1), jnp.int32)})
     assert any("/flash_attention/" in n for n in names)
-    # the backward scan lies under the grad op's scope; JAX wraps a
+    # the backward kernels lie under the grad op's scope; JAX wraps a
     # scope opened under a transformation in the transformation's name
     # ("jvp(flash_attention_bwd)")
     assert any(re.search(r"^[^/]*/flash_attention_grad/.*"
