@@ -14,9 +14,7 @@ SpmdTrainer.  Weights are random, from a seed; no phase is cut down.
 One process holds the chip from start to end and starts no other.  It
 needs no network, no native runtime and no file outside the checkout
 but the compile cache when JAX_COMPILATION_CACHE_DIR places it
-elsewhere.  FLAGS_compile_cache_dir (compile/pcache.py, the home-made
-executable cache) stays off: JAX's own persistent cache is the only
-one here.
+elsewhere.
 
 Exit code 0 and, as the last line of stdout,
 {"ok": true, "device": {"platform": "tpu", "kind": ..., "count": ...}}

@@ -47,8 +47,10 @@ def run_trainer():
 
     exe = fluid.Executor(fluid.TPUPlace(0))
     exe.run(fluid.default_startup_program())
-    if trainer_id == 0:
-        t.init_pservers()  # push initial parameter values
+    # every trainer: the first to arrive initialises the servers, and
+    # all pull the same values, so none sends a gradient for a
+    # parameter its server does not hold yet
+    t.init_pservers()
 
     feeder = fluid.DataFeeder(place=fluid.TPUPlace(0), feed_list=[x, y])
     reader = paddle.batch(paddle.dataset.uci_housing.train(),

@@ -3,8 +3,7 @@
 XLA buffer donation (`donate_argnums`) lets an input buffer be reused
 for an output, halving the HBM footprint of params and optimizer state
 — but a donated `jax.Array` is deleted after dispatch, so donating a
-buffer something still reads is a crash (or, worse, a silent wrong
-value on backends whose reloaded executables drop the aliasing).  This
+buffer something still reads is a crash.  This
 module is the static proof obligation: an abstract interpretation over
 block 0, layered on `dataflow.Liveness`, that classifies every buffer
 per jit segment as provably-donatable or not and explains each refusal
@@ -37,10 +36,6 @@ Diagnostic codes (docs/ANALYSIS.md):
         (the fetch would return a deleted array).
   A004  in-place update stranded outside its jit segment: eager
         execution never donates, so the declared reuse cannot happen.
-  A005  donation requested on a backend where
-        `pcache.donation_aliasing_safe()` is false: `auto` degrades to
-        `conservative` (live-jit donation is safe everywhere; it is
-        the serialized-executable reload that loses the aliasing).
 
 The executor consumes the resulting `DonationPlan` at jit build behind
 `FLAGS_donation=auto|conservative|off` (default `auto`); `pmem audit`
@@ -99,38 +94,35 @@ class DonationPlan:
       (code None only under mode=off, where the refusal IS the flag).
     """
 
-    def __init__(self, mode, effective_mode, backend_safe, report,
-                 segments, entries):
+    def __init__(self, mode, report, segments, entries):
         self.mode = mode
-        self.effective_mode = effective_mode
-        self.backend_safe = backend_safe
         self.report = report
         self.segments = segments
         self.entries = entries
 
     def donate(self, i):
-        """The names segment `i` donates under the effective mode."""
-        if self.effective_mode == "off":
+        """The names segment `i` donates under the plan's mode."""
+        if self.mode == "off":
             return ()
         seg = self.segments[i]
-        if self.effective_mode == "conservative":
+        if self.mode == "conservative":
             return tuple(seg["conservative"])
         return tuple(seg["conservative"]) + tuple(seg["widened"])
 
     def widened(self, i):
         """The names `auto` adds beyond conservative for segment `i`
         (empty under conservative/off)."""
-        if self.effective_mode != "auto":
+        if self.mode != "auto":
             return ()
         return tuple(self.segments[i]["widened"])
 
     def fingerprint(self):
-        """Stable content hash of the effective donation decision —
-        folds into compile-cache keys so a plan change re-keys."""
+        """Stable content hash of the donation decision (the BENCH
+        record's `donation` blob carries it)."""
         import hashlib
 
         h = hashlib.sha256()
-        h.update(self.effective_mode.encode())
+        h.update(self.mode.encode())
         for i in range(len(self.segments)):
             h.update(b"|%d:" % i)
             h.update(",".join(self.donate(i)).encode())
@@ -176,8 +168,6 @@ class DonationPlan:
     def to_dict(self):
         return {
             "mode": self.mode,
-            "effective_mode": self.effective_mode,
-            "backend_safe": self.backend_safe,
             "fingerprint": self.fingerprint(),
             "segments": [dict(s) for s in self.segments],
             "entries": [dict(e) for e in self.entries],
@@ -197,8 +187,8 @@ def _find_vd(desc, bd, name):
 
 
 def analyze_donation(program, fetches=(), feeds=(), mode=None,
-                     backend_safe=None, suppress=(), report=None,
-                     publish=False, origin="alias"):
+                     suppress=(), report=None, publish=False,
+                     origin="alias"):
     """Whole-program donation-safety analysis; returns a DonationPlan.
 
     program: a Program or ProgramDesc (block 0 is analyzed, segmented
@@ -209,11 +199,6 @@ def analyze_donation(program, fetches=(), feeds=(), mode=None,
         device-prefetch path re-uses them across steps), never donated
         beyond what the caller's own jit signature says.
     mode: "auto" | "conservative" | "off"; None reads FLAGS_donation.
-    backend_safe: tri-state.  True/False is the
-        `pcache.donation_aliasing_safe()` verdict (False degrades
-        `auto` to `conservative` with an A005); None means "do not
-        consult the backend" — static audits and `proglint` stay
-        zero-device and emit no A005.
     """
     # lazy import: the executor imports analysis lazily and vice versa
     from ..fluid.executor import _segment_block
@@ -222,16 +207,6 @@ def analyze_donation(program, fetches=(), feeds=(), mode=None,
     bd = desc.block(0)
     mode = donation_mode(mode)
     report = report if report is not None else Report(suppress=suppress)
-
-    effective = mode
-    if mode == "auto" and backend_safe is False:
-        report.add(Diagnostic(
-            "A005", Severity.WARNING,
-            "donation mode 'auto' requested but this backend's "
-            "executable reload does not preserve donation aliasing "
-            "(pcache.donation_aliasing_safe() is false); degrading to "
-            "'conservative'", block_idx=0))
-        effective = "conservative"
 
     fetch_set = set(fetches or ())
     feed_set = set(feeds or ())
@@ -331,7 +306,7 @@ def analyze_donation(program, fetches=(), feeds=(), mode=None,
                     entries.append(entry)
                     if out_name == in_name \
                             and in_name in conservative_set:
-                        if effective == "off":
+                        if mode == "off":
                             entry["status"] = "reclaimable"
                             entry["reason"] = (
                                 "donation disabled "
@@ -386,5 +361,4 @@ def analyze_donation(program, fetches=(), feeds=(), mode=None,
 
     if publish:
         report.publish(origin=origin)
-    return DonationPlan(mode, effective, backend_safe, report,
-                        seg_rows, entries)
+    return DonationPlan(mode, report, seg_rows, entries)

@@ -1,37 +1,20 @@
-"""paddle_tpu.compile — compilation as a first-class, cached,
-pass-driven pipeline (the TVM direction; ROADMAP item 3).
+"""paddle_tpu.compile — Program-level IR rewrite passes.
 
-Two halves:
+`passes` + `opt_passes`: rewrite passes over the analysis subsystem's
+def-use/liveness machinery: the cleanup set (dead-op/dead-var
+elimination, shape/fill constant folding, pure-op CSE) plus the
+cost-model-guided optimization passes (`layout` NCHW→NHWC gated on the
+TPU-tiled roofline, `fuse` elementwise-chain fusion, `auto_remat`
+budget-driven activation checkpointing — knobs like `fuse:cap=8` fold
+into the pipeline id), run by a `PassManager` that re-verifies the IR
+around every pass.  Gated by `FLAGS_compile_passes`.
 
-  * `pcache` + `fingerprint` — a persistent on-disk executable cache.
-    The executor's jit-miss path AOT-compiles each segment, serializes
-    the lowered executable, and stores it keyed by a canonical
-    content-addressed Program fingerprint (IR + avals + dtype-policy
-    flags + pass-pipeline id + backend build).  A later process —
-    serving warmup, a supervisor auto-resume — reloads it with ZERO
-    new XLA compiles.  Gated by `FLAGS_compile_cache_dir`; off means
-    the jit call path is exactly the pre-cache behavior.
-  * `passes` + `opt_passes` — Program-level IR rewrite passes over
-    the analysis subsystem's def-use/liveness machinery: the cleanup
-    set (dead-op/dead-var elimination, shape/fill constant folding,
-    pure-op CSE) plus the cost-model-guided optimization passes
-    (`layout` NCHW→NHWC gated on the TPU-tiled roofline, `fuse`
-    elementwise-chain fusion, `auto_remat` budget-driven activation
-    checkpointing — knobs like `fuse:cap=8` fold into the pipeline
-    id), run by a `PassManager` that re-verifies the IR around every
-    pass.  Gated by `FLAGS_compile_passes`.
-
-Operator surface: `python -m paddle_tpu.tools.pcache_cli` ("pcc") for
-stats / prewarm / gc / --selftest.  docs/COMPILE_CACHE.md documents
-the cache-key anatomy, invalidation rules, and the ops runbook.
+Compiled executables persist through JAX's own compilation cache
+(`utils/compile_cache.py`), not through anything here.
 """
 
-from . import fingerprint
-from . import pcache
 from . import passes
 from . import opt_passes
 from .passes import PassManager, optimize_program
-from .pcache import PersistentCache
 
-__all__ = ["fingerprint", "pcache", "passes", "opt_passes",
-           "PassManager", "optimize_program", "PersistentCache"]
+__all__ = ["passes", "opt_passes", "PassManager", "optimize_program"]

@@ -42,11 +42,10 @@ a cost model, not a heuristic flag:
               memory-vs-speed trade the tuner searches).
 
 All three fold their knob settings into the PassManager's
-``pipeline_id`` (pcache entries never alias across configs), keep the
-verifier green around every rewrite, and preserve fetch numerics
+``pipeline_id`` (compiled programs never alias across configs), keep
+the verifier green around every rewrite, and preserve fetch numerics
 bit-identically (f32) / within amp tolerance (bf16) — proven on the
-golden fixtures by tests/test_opt_passes.py and on lenet5 by
-``pcc --selftest``.
+golden fixtures by tests/test_opt_passes.py.
 """
 
 from ..ops import registry as op_registry

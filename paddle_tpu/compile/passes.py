@@ -16,9 +16,10 @@ ops using the def-use chains.  Every pass:
     rewritten) — `PassManager(explain=True)` + `explain_text()` dumps
     the per-pass diff.
 
-The PassManager's `pipeline_id` feeds the executable-cache fingerprint
-(`compile.fingerprint`), so cached entries never alias across pass
-configs.
+The PassManager's `pipeline_id` names a pass config (knobs included):
+bench records and the tuner's search space carry it.  The executor's
+in-memory program cache keys on the spec itself, so compiled programs
+never alias across pass configs.
 
 Cleanup passes (the "default" pipeline, in order):
 
@@ -57,8 +58,8 @@ appended to the spec — "default+layout+fuse+auto_remat"):
 Spec grammar: pass tokens separated by ',' or '+' ("default" expands
 to the cleanup pipeline), each token optionally carrying ':'-joined
 `key=value` knobs — `"default+fuse:cap=8+auto_remat:stride=4"`.  The
-knobs fold into `pipeline_id`, so pcache entries never alias across
-knob settings.
+knobs fold into `pipeline_id`, so compiled programs never alias
+across knob settings.
 
 Semantics-preservation contract: every pass either removes work whose
 result is never observable (dce/dve), replaces an op by one computing
@@ -66,8 +67,8 @@ the same values from attrs (fold), reuses an existing bit-identical
 value (cse), re-expresses the same math in another layout (layout) or
 as one fused kernel applying the identical stage sequence (fuse), or
 recomputes identical forward values in the backward (auto_remat).
-`pcache_cli --selftest` proves pass-optimized and unoptimized lenet5
-forwards produce bit-identical outputs.
+tests/test_compile_passes.py and tests/test_opt_passes.py hold each
+pass to bit-identical fetches.
 """
 
 import json
@@ -80,14 +81,14 @@ from ..analysis import dataflow
 from ..analysis.common import EMPTY, resolve_op_info
 from ..analysis.diagnostics import Report
 from ..analysis.verifier import verify_program
-from ..core.desc import OpDesc
-from .fingerprint import _jsonable
+from ..core.desc import OpDesc, _attr_to_jsonable
 
 __all__ = ["PassManager", "optimize_program", "available_passes",
            "register_pass", "DEFAULT_PIPELINE"]
 
 # bump when any pass's rewrite semantics change: the version is part
-# of pipeline_id, so stale cache entries miss instead of aliasing
+# of pipeline_id, so records taken under the old semantics do not join
+# with the new
 _PIPELINE_VERSION = 1
 
 
@@ -152,7 +153,7 @@ class RewritePass:
                     # an explicitly-spelled default ("fuse:cap=0") is
                     # the SAME pipeline as the bare pass: it must not
                     # mint a distinct spec_token/pipeline_id (one
-                    # semantics -> one pcache key, one ptune point)
+                    # semantics -> one cache key, one ptune point)
                     self._explicit[key] = value
             else:
                 value = default
@@ -367,7 +368,7 @@ class CommonSubexpression(RewritePass):
                 and not (set(outs) & keep))
             if candidate:
                 key = (od.type,
-                       json.dumps({k: _jsonable(v) for k, v in
+                       json.dumps({k: _attr_to_jsonable(v) for k, v in
                                    sorted(od.attrs.items())},
                                   sort_keys=True),
                        tuple((slot,
@@ -462,7 +463,7 @@ class PassManager:
     spec: comma list of pass names, or "default".
     verify_level: "structural" (default — pure desc walking before and
         after every pass) or "full" (adds the infer-shape
-        re-derivation; what `pcc --selftest` runs).
+        re-derivation; what tests/test_opt_passes.py runs).
     """
 
     def __init__(self, spec=DEFAULT_PIPELINE, verify=True,
@@ -491,9 +492,8 @@ class PassManager:
 
     @property
     def pipeline_id(self):
-        """Stable id of this pass config — part of the executable-
-        cache fingerprint, so entries never alias across configs (knob
-        settings included)."""
+        """Stable id of this pass config (knob settings included):
+        what bench records and the tuner tell configs apart by."""
         return "v%d:%s" % (_PIPELINE_VERSION, self.spec)
 
     def _verify(self, desc):
@@ -579,7 +579,7 @@ def optimize_program(program, spec=DEFAULT_PIPELINE, fetches=(),
 
 def pipeline_id(spec):
     """The pipeline id a spec resolves to, without running anything
-    (the executor folds this into the cache fingerprint; '' -> '')."""
+    ('' -> '')."""
     spec = (spec or "").strip()
     if not spec:
         return ""

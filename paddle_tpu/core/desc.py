@@ -15,6 +15,8 @@ Differences from the reference, by design:
 import json
 from collections import OrderedDict
 
+import numpy as np
+
 from .types import VarType, canonical_dtype
 
 
@@ -38,10 +40,20 @@ class BlockRef:
 
 
 def _attr_to_jsonable(v):
+    """Coerce an attr value to a canonical JSON-able form (BlockRefs
+    and the numpy scalars that sneak in from shape math included)."""
     if isinstance(v, BlockRef):
         return {"__block__": v.idx}
     if isinstance(v, (list, tuple)):
         return [_attr_to_jsonable(x) for x in v]
+    if isinstance(v, np.bool_):
+        return bool(v)
+    if isinstance(v, np.integer):
+        return int(v)
+    if isinstance(v, np.floating):
+        return float(v)
+    if isinstance(v, bytes):
+        return v.decode("utf-8", "backslashreplace")
     return v
 
 

@@ -319,6 +319,31 @@ def _segment_block(op_descs):
     return segments
 
 
+def _value_sig(v):
+    # RaggedTensor / SelectedRows carry nested arrays; describe each
+    if isinstance(v, RaggedTensor):
+        return ("ragged", _value_sig(v.values),
+                tuple(_value_sig(np.asarray(rs)) for rs in v.row_splits))
+    if isinstance(v, SelectedRows):
+        return ("rows", _value_sig(v.values), _value_sig(v.rows))
+    shape = getattr(v, "shape", None)
+    dtype = getattr(v, "dtype", None)
+    if shape is None or dtype is None:
+        return ("py", type(v).__name__, repr(v))
+    return ("t", tuple(int(s) for s in shape), str(dtype))
+
+
+def _values_signature_key(named_values):
+    """Hashable signature tuple for (name, value) pairs: names sorted,
+    each value reduced to its shape/dtype aval (nested container types
+    included).  The per-call specialization key of the attribution
+    artifacts (`_run_attr_aot`): same segment + same key means the
+    same executable."""
+    return tuple((str(n), _value_sig(v))
+                 for n, v in sorted(named_values,
+                                    key=lambda kv: str(kv[0])))
+
+
 class _CompiledProgram:
     """A lowered program: a list of segment runners sharing a host-side env.
 
@@ -336,11 +361,6 @@ class _CompiledProgram:
         block_desc = program.desc.block(block_idx)
         self.segments = _segment_block(block_desc.ops)
         self._jit_cache = {}
-        # persistent executable cache (FLAGS_compile_cache_dir): the
-        # program-level fingerprint is computed lazily on the first
-        # jit miss and combined per segment+signature (see
-        # _aot_acquire); None until then
-        self._pcache_base_fp = None
         self._plan = self._analyze()
         self._donation = self._donation_setup()
 
@@ -403,22 +423,16 @@ class _CompiledProgram:
         or None}.  Only "auto" runs the donation-safety analysis
         (analysis/alias.py) — and any analysis failure degrades to
         "conservative": the plan must never be the reason a step
-        fails.  "auto" also degrades when the backend's executable
-        reload drops donation aliasing (A005)."""
+        fails."""
         from .. import analysis
 
         mode = analysis.donation_mode()
         if mode != "auto":
             return {"mode": mode, "widened": None}
         try:
-            from ..compile import pcache as pcache_mod
-
             plan = analysis.analyze_donation(
                 self.program, fetches=self.fetch_names,
-                feeds=self.feed_names,
-                backend_safe=pcache_mod.donation_aliasing_safe())
-            if plan.effective_mode != "auto":
-                return {"mode": plan.effective_mode, "widened": None}
+                feeds=self.feed_names)
             return {"mode": "auto",
                     "widened": [tuple(s["widened"])
                                 for s in plan.segments]}
@@ -440,6 +454,13 @@ class _CompiledProgram:
                 raise RuntimeError(
                     "variable %r is not initialized; run the startup "
                     "program first" % name)
+            if isinstance(val, np.ndarray):
+                # a host array someone put into the scope (a restored
+                # checkpoint): placed once, committed like a feed and
+                # like every segment's outputs, so that the step lowers
+                # to the program an uninterrupted run compiled
+                val = jax.device_put(val, executor.place.device())
+                scope.set(name, val)
             return val
 
         rng_state = scope.get(RNG_STATE_NAME)
@@ -553,10 +574,6 @@ class _CompiledProgram:
             jitted = {
                 "fn": jax.jit(segment_fn, donate_argnums=(0,)),
                 "mutated": mutated,
-                # per-signature AOT executables from the persistent
-                # cache (False = permanent fallback to the jit path
-                # for that signature)
-                "aot": {},
             }
             self._jit_cache[i] = jitted
             if flags.get_flag("xla_cost_attribution") \
@@ -579,50 +596,6 @@ class _CompiledProgram:
         ro_ins = {n: v for n, v in in_vals.items() if n not in mutated}
         label = seg["label"]
         profiled = profiler_mod.is_enabled()
-
-        # persistent executable cache (FLAGS_compile_cache_dir): serve
-        # this (segment, signature) from an AOT executable — loaded
-        # from disk (zero XLA compiles) or compiled once and stored —
-        # instead of the jit call path.  Disabled, this whole branch
-        # is one flag read.  `sig` is shared with the attribution
-        # branch below so one dispatch never hashes its inputs twice.
-        sig = None
-        if flags.get_flag("compile_cache_dir"):
-            from ..compile import fingerprint as fp_mod
-
-            # hashable tuple, not a string: this runs on every
-            # dispatch — the repr lands in the disk key only on miss
-            sig = fp_mod.values_signature_key(
-                list(mut_ins.items()) + list(ro_ins.items())
-                + [("@rng", rng_state)])
-            aot = jitted["aot"].get(sig)
-            if aot is None:
-                aot = self._aot_acquire(i, seg, jitted,
-                                        (mut_ins, ro_ins, rng_state),
-                                        sig)
-                jitted["aot"][sig] = aot if aot is not None else False
-            if aot not in (None, False):
-                try:
-                    return self._exec_aot(aot, label, mut_ins, ro_ins,
-                                          rng_state, profiled)
-                except Exception as exc:
-                    # signature drift / backend mismatch: quarantine
-                    # THIS signature to the jit path and keep running
-                    # — the cache must never be the reason a step
-                    # fails.  Exception: a failure AFTER dispatch may
-                    # already have donated (deleted) the mutable
-                    # inputs; re-running on dead buffers would only
-                    # mask the real error, so it propagates.
-                    from ..compile import pcache as pcache_mod
-
-                    pcache_mod._errors("execute").inc()
-                    jitted["aot"][sig] = False
-                    if any(getattr(v, "is_deleted", lambda: False)()
-                           for v in mut_ins.values()):
-                        raise
-                    _log.warning("pcache executable for %s failed "
-                                 "(%r); falling back to jit path",
-                                 label, exc)
 
         # cost attribution on the plain jit path
         # (FLAGS_xla_cost_attribution / health.force_attribution):
@@ -653,7 +626,7 @@ class _CompiledProgram:
                 first_call or not (size_fn() or 0))
             res = self._run_attr_aot(i, seg, jitted, mut_ins, ro_ins,
                                      rng_state, allow_compile,
-                                     profiled, sig)
+                                     profiled)
             if res is not None:
                 return res
 
@@ -683,83 +656,8 @@ class _CompiledProgram:
                 time.perf_counter() - t0)
         return outs, rng
 
-    def _pcache_base(self):
-        """Program-level fingerprint base for the persistent cache:
-        canonical IR + feed/fetch names + the dtype-policy flags that
-        specialize the trace + the rewrite-pipeline id + the backend
-        build.  Computed once per _CompiledProgram."""
-        if self._pcache_base_fp is None:
-            from ..compile import fingerprint as fp_mod
-            from ..compile import passes as passes_mod
-
-            prog_fp = fp_mod.program_fingerprint(
-                self.program, feeds=self.feed_names,
-                fetches=self.fetch_names,
-                flag_items=[(k, flags.get_flag(k)) for k in
-                            ("amp_bf16", "amp_bf16_act",
-                             "bn_shifted_stats", "donation")],
-                pipeline_id=passes_mod.pipeline_id(
-                    flags.get_flag("compile_passes")))
-            self._pcache_base_fp = fp_mod.combine(
-                prog_fp, fp_mod.environment_fingerprint())
-        return self._pcache_base_fp
-
-    def _aot_acquire(self, i, seg, jitted, args, sig):
-        """Load the (segment, signature) executable from the
-        persistent cache, or AOT-compile + store it.  Returns a
-        callable `jax.stages.Compiled`, or None when the cache is
-        unusable (the caller falls back to the jit path).  Only a real
-        XLA compile counts as a jit trace — a disk hit is the whole
-        point: zero new compiles."""
-        from ..compile import fingerprint as fp_mod
-        from ..compile import pcache as pcache_mod
-
-        label = seg["label"]
-        try:
-            cache = pcache_mod.get_cache()
-            if cache is None:
-                return None
-            key = fp_mod.combine(self._pcache_base(), "seg%d" % i,
-                                 ",".join(seg["outputs"]),
-                                 ",".join(jitted["mutated"]),
-                                 repr(sig))
-            loaded = cache.get(key)
-            if loaded is not None:
-                obs_trace.instant("pcache_hit", cat="compile",
-                                  segment=label)
-                if flags.get_flag("xla_cost_attribution") \
-                        or obs_health.attribution_forced():
-                    # attribution rides the loaded artifact — free on
-                    # a hit, no recompile (the plain jit path gets the
-                    # same property from _run_attr_aot)
-                    obs_health.publish_compile_stats(label, loaded)
-                return loaded
-            t0 = time.perf_counter()
-            compiled = jitted["fn"].lower(*args).compile()
-            dt = time.perf_counter() - t0
-            # this is a real XLA compile: telemetry must see it (the
-            # warm-restart contract is asserted on this counter)
-            obs_tele.on_jit_trace(label)
-            cache.put(key, compiled, compile_seconds=dt,
-                      meta={"segment": label,
-                            "ops": len(seg["ops"])})
-            if flags.get_flag("xla_cost_attribution") \
-                    or obs_health.attribution_forced():
-                # satellite fix: the AOT artifact is at hand — no
-                # second lower().compile() for attribution
-                obs_health.publish_compile_stats(label, compiled)
-            return compiled
-        except Exception as exc:
-            _log.warning("persistent compile cache unusable for %s "
-                         "(%r); using jit path", label, exc)
-            try:
-                pcache_mod._errors("acquire").inc()
-            except Exception:
-                pass
-            return None
-
     def _run_attr_aot(self, i, seg, jitted, mut_ins, ro_ins, rng_state,
-                      allow_compile, profiled, sig=None):
+                      allow_compile, profiled):
         """Attribution on the plain jit path, without the historical
         double compile: per (segment, signature) the FIRST build is
         `fn.lower().compile()` — the memory/cost analyses are
@@ -773,18 +671,14 @@ class _CompiledProgram:
         `allow_compile` off (post-warmup retraces, and signatures
         already warm in the jit cache, compile through the normal jit
         path), a failed lowering, or a signature quarantined by an
-        execute failure.  `sig` reuses the pcache branch's signature
-        when that branch already computed it."""
-        from ..compile import fingerprint as fp_mod
-
+        execute failure."""
         attr = jitted.setdefault("attr_aot", {})
-        if sig is None:
-            try:
-                sig = fp_mod.values_signature_key(
-                    list(mut_ins.items()) + list(ro_ins.items())
-                    + [("@rng", rng_state)])
-            except Exception:
-                return None
+        try:
+            sig = _values_signature_key(
+                list(mut_ins.items()) + list(ro_ins.items())
+                + [("@rng", rng_state)])
+        except Exception:
+            return None
         aot = attr.get(sig)
         if aot is False:
             return None
@@ -807,10 +701,9 @@ class _CompiledProgram:
             return self._exec_aot(aot, label, mut_ins, ro_ins,
                                   rng_state, profiled)
         except Exception as exc:
-            # same contract as the pcache execute fallback: quarantine
-            # THIS signature, keep running — unless dispatch already
-            # donated (deleted) the mutable inputs, where a re-run
-            # would only mask the real error
+            # quarantine THIS signature and keep running — unless
+            # dispatch already donated (deleted) the mutable inputs,
+            # where a re-run would only mask the real error
             attr[sig] = False
             if any(getattr(v, "is_deleted", lambda: False)()
                    for v in mut_ins.values()):

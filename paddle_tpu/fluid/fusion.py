@@ -39,7 +39,7 @@ of the `fuse` rewrite pass (compile/opt_passes.py).
 import json
 from collections import OrderedDict
 
-from ..core.desc import OpDesc
+from ..core.desc import OpDesc, _attr_to_jsonable
 from ..core.types import FUSED_ELEMWISE_OP
 from ..utils import flags
 from .backward import EMPTY
@@ -233,8 +233,6 @@ def fuse_elemwise_chains(desc, block_idx=0, keep=(), cap=0):
     (one entry per fused chain); the block is rewritten in place and
     the dead intermediate VarDescs are dropped.
     """
-    from ..compile.fingerprint import _jsonable
-
     bd = desc.block(block_idx)
     ops = bd.ops
     keep = set(keep)
@@ -291,7 +289,8 @@ def fuse_elemwise_chains(desc, block_idx=0, keep=(), cap=0):
         for k, idx in enumerate(chain):
             od = ops[idx]
             st = {"op": od.type}
-            attrs = {a: _jsonable(v) for a, v in sorted(od.attrs.items())}
+            attrs = {a: _attr_to_jsonable(v)
+                     for a, v in sorted(od.attrs.items())}
             if attrs:
                 st["attrs"] = attrs
             if k == 0:

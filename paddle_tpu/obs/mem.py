@@ -576,8 +576,6 @@ def audit_donation(program, fetches=(), mode=None):
     desc = getattr(program, "desc", program)
     bd = desc.block(0)
     bf16_act = _bf16_act_now()
-    # zero-device audit: backend_safe=None skips the A005 backend
-    # consultation — the executor re-asks at jit build
     plan = analyze_donation(program, fetches=fetches, mode=mode)
 
     def full_bytes(name):
@@ -617,7 +615,6 @@ def audit_donation(program, fetches=(), mode=None):
         "ops": len(bd.ops), "jit_segments": sum(
             1 for s in plan.segments if s["jit"]),
         "mode": plan.mode,
-        "effective_mode": plan.effective_mode,
         "widened": sorted(n for s in plan.segments
                           for n in s["widened"]),
         "donated": donated,
@@ -651,7 +648,7 @@ def render_audit(audit):
 def bench_donation_blob(program, fetches=()):
     """The BENCH record's `donation` blob: the plan's verdict in bytes
     — planned (everything provably donatable), donated (what the
-    effective mode actually donates, widened buffers included), and
+    mode actually donates, widened buffers included), and
     declined (refusals, split by A-code) — so `pperf gate
     --mem-tolerance` can lock the peak-HBM win in CI."""
     from ..analysis.alias import analyze_donation
@@ -682,14 +679,14 @@ def bench_donation_blob(program, fetches=()):
     for s in plan.segments:
         for n in s["widened"]:
             b = full_bytes(n)
-            if plan.effective_mode == "auto":
+            if plan.mode == "auto":
                 donated += b
             else:
-                # proven donatable but the effective mode declines it
-                # (off, or auto degraded to conservative via A005)
+                # proven donatable but the mode (off, conservative)
+                # declines it
                 declined += b
-                declined_by_code[plan.effective_mode] = \
-                    declined_by_code.get(plan.effective_mode, 0) + b
+                declined_by_code[plan.mode] = \
+                    declined_by_code.get(plan.mode, 0) + b
         for d in s["declined"]:
             b = full_bytes(d["name"])
             declined += b
@@ -697,7 +694,6 @@ def bench_donation_blob(program, fetches=()):
                 declined_by_code.get(d["code"], 0) + b
     return {
         "mode": plan.mode,
-        "effective_mode": plan.effective_mode,
         "fingerprint": plan.fingerprint(),
         "planned_bytes": int(donated + declined),
         "donated_bytes": int(donated),

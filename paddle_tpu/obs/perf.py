@@ -7,7 +7,7 @@ XLA cost attribution), turning raw numbers into verdicts:
   * `StepProfiler` — a continuous, sampling step profiler.  Installed
     as the `telemetry.step(...)` observer it sees every v2/parallel
     trainer step, records a structured per-step record into a bounded
-    ring (wall time, h2d-input time, retraces, pcache hits, transfer
+    ring (wall time, h2d-input time, retraces, compile-cache hits, transfer
     bytes), and every `sample_every`-th step additionally captures the
     executor's jit-segment spans (blocking, device-true timings) to
     split the step into device / input / host time.  Records export as

@@ -15,7 +15,9 @@ reads back):
   * jit:       seconds JAX spent tracing, lowering and compiling each
                jitted function (`jit_phase_seconds_total`), from
                `jax.monitoring`: the set-up time a warm compile cache
-               cannot save is the trace and lower phases.
+               cannot save is the trace and lower phases; and what
+               JAX's persistent cache served or had to compile
+               (`compile_cache_{hits,misses}_total`).
 
 Everything funnels into the default registry (`obs.registry`), so one
 Prometheus scrape / `obs_dump` call sees executor, trainer and serving
@@ -180,6 +182,30 @@ def _on_jit_phase(event, duration, fun_name="", **_):
 
 
 jax.monitoring.register_event_duration_secs_listener(_on_jit_phase)
+
+
+_COMPILE_CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": (
+        "compile_cache_hits_total",
+        "executables JAX's persistent compilation cache loaded from "
+        "disk"),
+    "/jax/compilation_cache/cache_misses": (
+        "compile_cache_misses_total",
+        "executables compiled and written to JAX's persistent "
+        "compilation cache"),
+}
+
+
+def _on_compile_cache_event(event, **_):
+    """`compile_cache_{hits,misses}_total`: what JAX's persistent
+    compilation cache (`utils/compile_cache.py`) reports.  Both stay 0
+    while the cache is off."""
+    counter = _COMPILE_CACHE_EVENTS.get(event)
+    if counter is not None:
+        _reg().counter(*counter).inc()
+
+
+jax.monitoring.register_event_listener(_on_compile_cache_event)
 
 
 # ---------------------------------------------------------------------------

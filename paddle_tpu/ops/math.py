@@ -120,7 +120,7 @@ def fused_elemwise_chain(ctx, ins, attrs):
     Each stage applies the ORIGINAL registered kernel with the
     original attrs, so per-lane numerics are identical to the unfused
     op sequence by construction (same primitives, same order — the
-    bit-identity `pcc --selftest` asserts)."""
+    bit-identity tests/test_opt_passes.py asserts)."""
     import json as _json
 
     from .registry import get_op_info

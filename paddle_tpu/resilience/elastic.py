@@ -536,7 +536,6 @@ class ElasticTrainer:
                          devices=devices, drop_unit_axes=True)
         main, startup, feed_names, fetch_names = self.build_fn()
         kw = dict(self.trainer_kw)
-        kw.setdefault("use_pcache", False)
         trainer = SpmdTrainer(main, startup, feed_names=feed_names,
                               fetch_names=fetch_names, mesh=mesh,
                               rules=self.rules,

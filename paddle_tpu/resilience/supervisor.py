@@ -262,18 +262,6 @@ class TrainingSupervisor:
         trace_mod.instant("supervisor_restore", cat="supervisor",
                           step=self._step, epoch=self._epoch,
                           batch=self._batch)
-        # resume is THE moment the persistent executable cache pays
-        # off (a restarted process replays its first step with zero
-        # XLA compiles): surface what the cache holds so /metrics on
-        # a resumed run says whether the warm start was real
-        from ..compile import pcache
-
-        if pcache.enabled():
-            stats = pcache.publish_stats()
-            if stats is not None:
-                trace_mod.instant("supervisor_pcache", cat="supervisor",
-                                  entries=stats["entries"],
-                                  bytes=stats["bytes"])
         return self._step
 
     # -- the supervised loop ------------------------------------------------

@@ -373,12 +373,12 @@ class InferenceEngine:
         shape — see the module docstring).  Returns the number of
         buckets warmed.
 
-        With the persistent executable cache on
-        (FLAGS_compile_cache_dir), a warmup after a restart serves
-        each bucket's executables straight from disk — zero fresh XLA
-        compiles (docs/COMPILE_CACHE.md measures the cold-vs-warm
-        gap).  `last_warmup_stats` records what this warmup actually
-        did: buckets, seconds, fresh compiles, and disk hits."""
+        With JAX's persistent compilation cache on
+        (`utils/compile_cache.enable_compile_cache`), a warmup after
+        a restart loads each bucket's executables from disk: the
+        programs are traced again, nothing is compiled.
+        `last_warmup_stats` records what this warmup actually did:
+        buckets, seconds, traces, and the cache's hits and misses."""
         # deploy-time static analysis FIRST — it must run even when
         # bucketing (and thus warmup compiling) is disabled: the
         # engine serves a program it did not build (a
@@ -444,8 +444,7 @@ class InferenceEngine:
         finally:
             self.metrics = saved_metrics
         # what this warmup cost and where the executables came from:
-        # fresh XLA compiles vs persistent-cache disk hits (the
-        # cold-vs-warm evidence for docs/COMPILE_CACHE.md)
+        # traces, and persistent-cache loads against fresh compiles
         delta = obs_tele.snapshot_delta(snap_before)
         self.last_warmup_stats = {
             "buckets": warmed,

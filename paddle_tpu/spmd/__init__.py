@@ -7,8 +7,8 @@ distributed stack the whole 2018 design existed for):
   * `plan`       — rule-driven partition planning: regex partition
                    rules layered over the `sharding.param_spec`
                    heuristics, producing a serializable plan artifact
-                   (`pshard plan`) the S001 analyzer and the pcache
-                   key both consume.
+                   (`pshard plan`) the S001 analyzer and the trainer
+                   both consume.
   * `trainer`    — `SpmdTrainer`: the pjit/NamedSharding lowering of
                    the fluid train step, with zero1 optimizer-state
                    sharding and optional bucketed ring-allreduce

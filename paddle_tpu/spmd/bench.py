@@ -133,7 +133,7 @@ def measure_comm(trainer, reps=5, bucket_bytes=None):
 
 def run_leg(model="lenet5", mesh_spec="dp=8", batch=None, iters=8,
             warmup=2, rules=None, zero_stage=0, bucket_bytes=0,
-            history=None, use_pcache=False):
+            history=None):
     """One MULTICHIP leg: train `model` on `mesh_spec`, return the
     perf-history record (appended to `history` when given)."""
     import jax
@@ -159,7 +159,7 @@ def run_leg(model="lenet5", mesh_spec="dp=8", batch=None, iters=8,
     trainer = SpmdTrainer(
         main, startup, ["image", "label"], [loss_name], mesh,
         rules=rules, zero_stage=zero_stage, bucket_bytes=bucket_bytes,
-        model=model, use_pcache=use_pcache)
+        model=model)
     trainer.init()
 
     rs = np.random.RandomState(0)
@@ -236,7 +236,6 @@ def run_leg(model="lenet5", mesh_spec="dp=8", batch=None, iters=8,
             "mesh": str(mesh_spec), "zero_stage": zero_stage,
             "bucket_bytes": bucket_bytes,
             "step_mode": trainer.step_mode,
-            "aot": trainer._aot_state,
         },
     }
     record["platform_class"] = obs_perf.platform_class(record)

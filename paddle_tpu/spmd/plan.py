@@ -93,7 +93,8 @@ class PartitionPlan:
     """The partition-plan artifact: mesh axes, per-var specs with
     replication reasons, the rule list that produced them, comm/HBM
     estimates, and the analyzer's diagnostics — one JSON document
-    shared by `pshard plan`, the trainer's layout, and the pcache key.
+    shared by `pshard plan`, the trainer's layout, and the checkpoint
+    manifest.
     """
 
     def __init__(self, mesh_axes, var_specs, param_reasons=None,
@@ -198,9 +199,8 @@ class PartitionPlan:
     def fingerprint(self):
         """Content hash of exactly what changes the compiled layout:
         mesh axes, per-var specs, zero stage, and the rule list —
-        NOT the diagnostics or cost estimates (a costmodel tweak must
-        not invalidate every cached executable).  `SpmdTrainer` folds
-        this into the pjit pcache key."""
+        NOT the diagnostics or cost estimates.  The sharded checkpoint
+        manifest and the flight context carry it."""
         basis = {
             "mesh": sorted(self.mesh_axes.items()),
             "zero_stage": self.zero_stage,

@@ -14,33 +14,19 @@ through the partition-plan artifact (`plan.build_partition_plan`):
     — gradients ring-reduce in buckets while the backward still runs —
     and falls back to the fused path otherwise
     (`overlap_fallback_reason` says why);
-  * the first `step()` tries a persistent-compile-cache AOT acquire
-    keyed on (program, mesh, flags) + the plan fingerprint + the feed
-    signature, so an 8-chip relaunch after preemption skips the XLA
-    compile entirely;
   * `attach_supervisor` wires the sharded per-host checkpoint saver
     into the resilience supervisor: preempt/resume round-trips WITHOUT
     densifying the (possibly zero1-sharded) optimizer state.
 """
 
-import time
-
 import jax
 
-from ..parallel.trainer import (ParallelTrainer, make_parallel_step,
-                                jnp_asarray)
-from ..obs import telemetry as obs_tele
-from ..utils import flags as _flags
+from ..parallel.trainer import ParallelTrainer, make_parallel_step
 from .overlap import (make_overlapped_dp_step, overlap_supported,
                       DEFAULT_BUCKET_BYTES)
 from .plan import build_partition_plan, load_rules
 
 __all__ = ["SpmdTrainer", "attach_supervisor"]
-
-# the flag set that changes what a train-step trace contains — must
-# match the executor's pcache key discipline (fluid/executor.py)
-_TRACE_FLAGS = ("amp_bf16", "amp_bf16_act", "bn_shifted_stats",
-                "donation")
 
 
 class SpmdTrainer(ParallelTrainer):
@@ -70,18 +56,16 @@ class SpmdTrainer(ParallelTrainer):
 
     def __init__(self, main_program, startup_program, feed_names,
                  fetch_names, mesh, rules=None, plan=None,
-                 bucket_bytes=0, model=None, use_pcache=True, **kw):
+                 bucket_bytes=0, model=None, **kw):
         super().__init__(main_program, startup_program, feed_names,
                          fetch_names, mesh, **kw)
         self.rules = load_rules(rules) if rules is not None else None
         self.plan = plan
         self.bucket_bytes = int(bucket_bytes or 0)
         self.model = model
-        self.use_pcache = bool(use_pcache)
         self.step_mode = None
         self.overlap_fallback_reason = None
         self._fetch_all = list(fetch_names)
-        self._aot_state = "pending" if self.use_pcache else "off"
         # elastic membership identity: None = not elastic (no restore
         # guard); the elastic layer (resilience/elastic.py) sets the
         # committed view's generation here so checkpoints are stamped
@@ -122,14 +106,7 @@ class SpmdTrainer(ParallelTrainer):
                        for a, s in dict(self.mesh.shape).items()},
             plan_fingerprint=self.plan.fingerprint())
 
-    def _make_step(self, fp, state, fetch_all, donate_state=None):
-        # donate_state None routes through the donation plan (the
-        # FLAGS_donation gate, analysis.state_donation); the AOT
-        # "-nodonate" twin passes an explicit False
-        if donate_state is None:
-            from ..analysis.alias import state_donation
-
-            donate_state = state_donation()
+    def _make_step(self, fp, state, fetch_all):
         if self.plan is None:       # init() not used (tests drive
             self.plan = self._build_plan()  # _make_step directly)
         self._fetch_all = list(fetch_all)
@@ -144,7 +121,6 @@ class SpmdTrainer(ParallelTrainer):
                     self.main_program, self.feed_names, fetch_all,
                     self.mesh, state, dp_axis=self.dp_axis,
                     bucket_bytes=self.bucket_bytes,
-                    donate_state=donate_state,
                     feed_specs=self.feed_specs)
             self.overlap_fallback_reason = reason
         self.step_mode = "gspmd"
@@ -154,96 +130,7 @@ class SpmdTrainer(ParallelTrainer):
             self.main_program, self.feed_names, fetch_all, self.mesh,
             state, dp_axis=self.dp_axis, mp_axis=self.mp_axis, fp=fp,
             zero_stage=self.zero_stage, feed_specs=self.feed_specs,
-            donate_state=donate_state, spec_overrides=overrides)
-
-    # -- persistent-compile-cache AOT ---------------------------------------
-    def _pcache_key(self, feeds):
-        from ..compile import fingerprint as fp_mod
-
-        return fp_mod.combine(
-            fp_mod.program_fingerprint(
-                self.main_program, feeds=self.feed_names,
-                fetches=self._fetch_all,
-                flag_items=[(k, _flags.get_flag(k))
-                            for k in _TRACE_FLAGS],
-                mesh=self.mesh),
-            fp_mod.environment_fingerprint(),
-            "spmd:%s:z%d:b%d" % (self.step_mode, self.zero_stage,
-                                 self.bucket_bytes),
-            self.plan.fingerprint(),
-            fp_mod.values_signature(feeds),
-        )
-
-    def _try_aot(self, feeds):
-        """First-step AOT acquire: hit -> run the deserialized
-        executable (no trace, no compile); miss -> lower+compile once
-        and persist.  Any failure falls back to the plain jitted path
-        — the cache is an accelerant, never a correctness dependency.
-
-        On backends whose executable reload does not preserve
-        donation aliasing (`pcache.donation_aliasing_safe`), the
-        cached executable is a NON-donating twin of the step: warm
-        restarts trade in-place state-buffer reuse for zero compiles,
-        instead of risking silently wrong values.
-        """
-        from ..compile import pcache as pcache_mod
-
-        try:
-            cache = pcache_mod.get_cache()
-            if cache is None:
-                self._aot_state = "no-cache"
-                return
-            rng = jax.random.fold_in(self._base_rng, self._step_count)
-            donate = pcache_mod.donation_aliasing_safe()
-            key = self._pcache_key(feeds) + ("" if donate
-                                             else "-nodonate")
-            compiled = cache.get(key)
-            if compiled is None:
-                fn = self._step_fn
-                if not donate:
-                    fn, _ = self._make_step(
-                        None, self._state_template, self._fetch_all,
-                        donate_state=False)
-                t0 = time.perf_counter()
-                with jax.set_mesh(self.mesh):
-                    compiled = fn.lower(
-                        self.state, feeds, rng).compile()
-                cache.put(key, compiled,
-                          compile_seconds=time.perf_counter() - t0,
-                          meta={"origin": "spmd_step",
-                                "mode": self.step_mode,
-                                "donated": donate,
-                                "mesh": {a: int(s) for a, s in
-                                         dict(self.mesh.shape).items()},
-                                "plan": self.plan.fingerprint()})
-                obs_tele.on_jit_trace("spmd_step")
-                self._aot_state = "compiled"
-            else:
-                self._aot_state = "hit"
-        except Exception:
-            self._aot_state = "error"
-            return
-        jitted, trainer = self._step_fn, self
-
-        def guarded(state, feeds, rng, _c=compiled, _j=jitted):
-            # a feed shape/dtype drift no longer matches the AOT
-            # executable — drop back to the jitted fn permanently
-            # (input validation precedes execution, so donation has
-            # not consumed the state buffers on the failed call)
-            try:
-                return _c(state, feeds, rng)
-            except Exception:
-                trainer._step_fn = _j
-                return _j(state, feeds, rng)
-
-        self._step_fn = guarded
-
-    def step(self, feeds):
-        if self._aot_state == "pending":
-            self._aot_state = "tried"
-            self._try_aot({n: jnp_asarray(v)
-                           for n, v in feeds.items()})
-        return super().step(feeds)
+            spec_overrides=overrides)
 
     # -- sharded checkpoints ------------------------------------------------
     def save_checkpoint(self, root, step):

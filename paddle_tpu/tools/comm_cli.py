@@ -142,8 +142,7 @@ def _make_trainer(mesh, bucket_bytes):
     main, startup, avg = _build_mlp()
     return SpmdTrainer(main, startup, feed_names=["x", "label"],
                        fetch_names=[avg.name], mesh=mesh,
-                       bucket_bytes=bucket_bytes,
-                       use_pcache=False).init()
+                       bucket_bytes=bucket_bytes).init()
 
 
 # ---------------------------------------------------------------------------

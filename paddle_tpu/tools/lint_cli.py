@@ -42,8 +42,8 @@ budget) asserting each exact code.  The donation leg does the same
 for the A0xx family: lenet5 + golden fixtures plan clean, then one
 seeded corruption per code — forked Adam slot (A001), plan replayed
 over a program with a late reader (A002), fetched donatable
-intermediate (A003), in-place update in a non-jit segment (A004),
-donation-unsafe backend (A005) — each asserting its exact code.
+intermediate (A003), in-place update in a non-jit segment (A004)
+— each asserting its exact code.
 """
 
 import argparse
@@ -175,9 +175,9 @@ def _report_exit(name, report, args, plan=None, donation=None):
             refused = sum(1 for e in donation.entries
                           if e["status"] == "reclaimable") \
                 + sum(len(s["declined"]) for s in donation.segments)
-            print("[lint] %s: donation mode=%s(effective %s) "
+            print("[lint] %s: donation mode=%s "
                   "donates %d buffer(s)/step, %d refused, plan %s"
-                  % (name, donation.mode, donation.effective_mode,
+                  % (name, donation.mode,
                      donate, refused, donation.fingerprint()))
         print("[lint] %s: %d error(s), %d warning(s), %d info, "
               "%d suppressed"
@@ -536,12 +536,6 @@ def _donation_corruptions():
         return analysis.analyze_donation(
             main, fetches=[cost.name], publish=False).report
 
-    def a005_unsafe_backend(analysis):
-        main, _startup, cost = _build_adam_toy()
-        return analysis.analyze_donation(
-            main, fetches=[cost.name], mode="auto",
-            backend_safe=False, publish=False).report
-
     return [
         ("forked in-place slot", "A001", a001_forked_slot),
         ("read-after-donation hazard", "A002", a002_late_reader),
@@ -549,7 +543,6 @@ def _donation_corruptions():
          a003_fetched_candidate),
         ("in-place update stranded non-jit", "A004",
          a004_non_jit_update),
-        ("donation-unsafe backend", "A005", a005_unsafe_backend),
     ]
 
 

@@ -32,11 +32,7 @@ inspection, and the noise-aware regression gate over
   3. **SLO burn on a loopback engine** — requests through a real
      serving engine + server (in-process), /healthz must carry
      `slo_burn_rate`: ~0 under a generous objective, > 1 under an
-     impossible one;
-  4. **warm compile-cache blob** — with FLAGS_compile_cache_dir set, a
-     restart-simulated second run must report pcache hits in the
-     mega_bench-style compile_cache summary (the ROADMAP item 3
-     flip, asserted).
+     impossible one.
 """
 
 import argparse
@@ -434,49 +430,6 @@ def _selftest_slo():
     return health["slo_burn_rate"]
 
 
-def _selftest_warm_cache(workdir):
-    """The mega_bench compile-cache flip, asserted: a second
-    (restart-simulated) run of the same program must serve its
-    executables from the persistent cache and say so in the
-    mega-style compile_cache summary blob."""
-    import numpy as np
-
-    import paddle_tpu.fluid as fluid
-    from paddle_tpu.obs import telemetry as obs_tele
-    from paddle_tpu.utils import flags
-
-    cache_dir = os.path.join(workdir, "pcache")
-    prev = flags.get_flag("compile_cache_dir")
-    flags.set_flag("compile_cache_dir", cache_dir)
-    try:
-        def one_run():
-            main, startup = fluid.Program(), fluid.Program()
-            with fluid.program_guard(main, startup):
-                x = fluid.layers.data(name="x", shape=[6],
-                                      dtype="float32")
-                h = fluid.layers.fc(input=x, size=4)
-                cost = fluid.layers.mean(x=h)
-            scope = fluid.Scope()
-            exe = fluid.Executor(fluid.CPUPlace())
-            with fluid.scope_guard(scope):
-                exe.run(startup)
-                return exe.run(main,
-                               feed={"x": np.ones((2, 6), np.float32)},
-                               fetch_list=[cost])
-
-        one_run()  # cold: populates the cache
-        snap = obs_tele.snapshot()
-        one_run()  # fresh programs/executor/scope: must reload
-        delta = obs_tele.snapshot_delta(snap)
-        blob = {"hits": delta.get("compile_cache_hits_total", 0),
-                "misses": delta.get("compile_cache_misses_total", 0)}
-        assert blob["hits"] > 0, \
-            "warm rerun reported no pcache hits: %r" % (delta,)
-        return blob
-    finally:
-        flags.set_flag("compile_cache_dir", prev)
-
-
 def selftest(args):
     import shutil
 
@@ -488,15 +441,14 @@ def selftest(args):
         gate_text = _selftest_gate(workdir)
         steps, verdict = _selftest_profiler(workdir)
         burn = _selftest_slo()
-        warm = _selftest_warm_cache(workdir)
     finally:
         # ci.sh/smoke.sh run this every time: don't stack /tmp dirs
         shutil.rmtree(workdir, ignore_errors=True)
 
     print("[pperf] selftest green: gate discriminates (sample fail "
           "line below), %d profiled steps (verdict %s), loopback "
-          "slo_burn_rate %.1f, warm cache blob %s\n%s"
-          % (steps, verdict, burn, warm,
+          "slo_burn_rate %.1f\n%s"
+          % (steps, verdict, burn,
              gate_text.splitlines()[1] if len(gate_text.splitlines())
              > 1 else gate_text), flush=True)
     return 0

@@ -3,7 +3,7 @@
     # build the partition-plan artifact for a model x mesh: run the
     # static sharding analyzer (rules layered over the param_spec
     # heuristics), print the layout summary, save the JSON document
-    # the trainer / pcache key / CI consume
+    # the trainer / checkpoint manifest / CI consume
     pshard plan --model lenet5 --mesh dp=4,mp=2 --batch 64 \\
                 [--rules rules.json] [--zero-stage 1] [--out plan.json]
 
@@ -163,8 +163,7 @@ def selftest(args):
     batch = 4 * n
     main, startup, loss_name = _build_program("lenet5", batch)
     trainer = SpmdTrainer(main, startup, ["image", "label"],
-                          [loss_name], mesh, model="lenet5",
-                          use_pcache=False)
+                          [loss_name], mesh, model="lenet5")
     trainer.init()
     rs = np.random.RandomState(7)
     feeds = {"image": rs.rand(batch, 1, 28, 28).astype(np.float32),
@@ -181,8 +180,7 @@ def selftest(args):
     with tempfile.TemporaryDirectory() as tmp:
         trainer.save_checkpoint(tmp, step=2)
         fresh = SpmdTrainer(main, startup, ["image", "label"],
-                            [loss_name], mesh, model="lenet5",
-                            use_pcache=False)
+                            [loss_name], mesh, model="lenet5")
         fresh.init()
         info = fresh.restore_checkpoint(tmp)
         same = all(

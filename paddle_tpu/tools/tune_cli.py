@@ -33,7 +33,7 @@ only the top-K, learn from what was measured.
      rank time with their exact codes, and the S002 candidate
      provably never reaches measurement;
   3. **measured top-K** — bench.py runs the top-2 candidates through
-     the AOT + pcache path; their records land in the history file
+     its normal AOT path; their records land in the history file
      with `"config"` blobs and `ptune:` legs;
   4. **calibration** — `fit` over those records reports a model error
      that DECREASES after ingesting the measurements, and a re-rank
@@ -131,9 +131,6 @@ def parse_args(argv=None):
                    help="perf history path (bench.py appends here)")
     p.add_argument("--iters", type=int, default=2)
     p.add_argument("--warmup", type=int, default=1)
-    p.add_argument("--cache-dir", default=None,
-                   help="measure: FLAGS_compile_cache_dir for the "
-                        "bench runs (the pcache path)")
     p.add_argument("--timeout", type=float, default=900,
                    help="measure: per-candidate wall-clock bound")
     return p.parse_args(argv)
@@ -234,8 +231,7 @@ def cmd_measure(args):
     results = tune_measure.measure_plan(
         plan, topk=args.topk or 3, history=args.history,
         iters=args.iters, warmup=args.warmup,
-        image_size=args.image_size, cache_dir=args.cache_dir,
-        timeout=args.timeout,
+        image_size=args.image_size, timeout=args.timeout,
         echo=lambda msg: print(msg, flush=True))
     ok = 0
     for r in results:
@@ -397,7 +393,6 @@ def _selftest_measure_fit(args, plan, bad, workdir):
     history = os.path.join(workdir, "ptune_history.jsonl")
     results = tune_measure.measure_plan(
         plan, topk=2, history=history, iters=1, warmup=1,
-        cache_dir=os.path.join(workdir, "pcache"),
         extra_env={"JAX_PLATFORMS": "cpu"}, timeout=600)
     assert len(results) == 2, results
     for r in results:

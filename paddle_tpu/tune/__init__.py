@@ -12,7 +12,7 @@ subsystem an earlier PR built:
     prices their wire bytes, the roofline floors predict their step
     time, and the per-device HBM estimate enforces the budget.
   * `measure` — only the top-K survivors ever touch hardware, each
-    through bench.py's normal AOT + pcache path, landing tagged
+    through bench.py's normal AOT path, landing tagged
     records (leg `ptune:<tag>` + a `"config"` blob) in
     `perf_history.jsonl`.
   * `fit`     — a least-squares per-term correction of predicted vs

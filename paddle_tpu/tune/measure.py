@@ -4,9 +4,10 @@ The static model (tune/rank.py) earns nothing until it is checked
 against hardware, but measuring the WHOLE space is exactly the pod
 burn the tuner exists to avoid — so this module runs only the plan's
 top-K survivors, each as one bench.py subprocess through the exact
-path every other measurement takes: the AOT steady-state compile, the
-persistent executable cache when `FLAGS_compile_cache_dir` is set,
-and the perf-history append.  Nothing bespoke to un-trust.
+path every other measurement takes: the AOT steady-state compile,
+JAX's persistent compilation cache (bench.py turns it on; the children
+share the directory `utils/compile_cache.py` names), and the
+perf-history append.  Nothing bespoke to un-trust.
 
 What one chip can measure of a multi-chip candidate is its per-device
 proxy: bench runs the candidate's per-device batch slice
@@ -66,8 +67,7 @@ def _entries(plan, model=None):
 
 
 def measurement_env(env_over, context, model, history=None, iters=2,
-                    warmup=1, image_size=None, cache_dir=None,
-                    extra_env=None):
+                    warmup=1, image_size=None, extra_env=None):
     """The full env overrides for one candidate's bench.py run.
 
     Starts from the candidate's own `bench_env` and replays the PLAN
@@ -91,8 +91,6 @@ def measurement_env(env_over, context, model, history=None, iters=2,
         env["BENCH_CLASS_DIM"] = str(context["class_dim"])
     if history:
         env["BENCH_HISTORY"] = os.path.abspath(history)
-    if cache_dir:
-        env["FLAGS_compile_cache_dir"] = os.path.abspath(cache_dir)
     env.update(extra_env or {})
     return env
 
@@ -122,15 +120,13 @@ def _config_matches(expected, got, context):
 
 
 def measure_plan(plan, topk=3, history=None, iters=2, warmup=1,
-                 model=None, image_size=None, cache_dir=None,
-                 extra_env=None, timeout=900, echo=None):
+                 model=None, image_size=None, extra_env=None,
+                 timeout=900, echo=None):
     """Run bench.py on the plan's top-K ranked candidates.
 
     plan: a `RankedPlan` or a loaded plan-JSON dict.
     history: perf-history path the records append to (bench.py's
         default — `perf_history.jsonl` at the repo root — when None).
-    cache_dir: FLAGS_compile_cache_dir for the runs (the pcache path);
-        inherited from the environment when None.
     extra_env: overrides applied last (the selftest pins
         JAX_PLATFORMS=cpu and tiny iters here).
 
@@ -148,14 +144,13 @@ def measure_plan(plan, topk=3, history=None, iters=2, warmup=1,
         # export, say) would silently measure a different program than
         # the one the plan ranked — scrub them; the candidate's env is
         # the only bench config (re-add knobs via extra_env if needed).
-        # FLAGS_compile_cache_dir deliberately inherits (see above).
         env = {k: v for k, v in os.environ.items()
                if not k.startswith("BENCH_")
                and k != "FLAGS_compile_passes"}
         env.update(measurement_env(
             env_over, context, model, history=history, iters=iters,
             warmup=warmup, image_size=image_size,
-            cache_dir=cache_dir, extra_env=extra_env))
+            extra_env=extra_env))
         if echo:
             echo("[ptune] measuring %s (batch %s x mb %s)"
                  % (tag, env["BENCH_BATCH"], env["BENCH_MICRO_BATCH"]))

@@ -130,21 +130,6 @@ DEFINE_flag("verify_sharding", False,
             "instead of surfacing minutes later as an XLA GSPMD "
             "error.  Default off: the multichip dryrun, tests, and "
             "proglint --mesh opt in explicitly")
-DEFINE_flag("compile_cache_dir", "",
-            "root directory of the persistent executable cache "
-            "(paddle_tpu.compile.pcache).  When set, the executor's "
-            "jit-miss path AOT-compiles each segment, serializes the "
-            "lowered executable to disk keyed by a canonical Program "
-            "fingerprint, and a later process (serving warmup, "
-            "supervisor auto-resume) reloads it with ZERO new XLA "
-            "compiles.  Empty (the default) disables the cache "
-            "entirely — the jit call path is byte-for-byte the "
-            "pre-cache behavior")
-DEFINE_flag("compile_cache_max_bytes", 2 << 30,
-            "LRU size cap for the persistent executable cache; the "
-            "oldest-used entries are evicted after each store until "
-            "the cache fits (compile_cache_evictions_total counts "
-            "them).  0 disables eviction")
 DEFINE_flag("compile_passes", "",
             "Program-level IR rewrite pipeline applied by the "
             "executor before compiling a program "
@@ -155,10 +140,10 @@ DEFINE_flag("compile_passes", "",
             "knobs attached via ':' as in "
             "'default+fuse:cap=8+auto_remat:stride=4'.  Every pass "
             "is re-verified with the analysis verifier before and "
-            "after it runs, and the pipeline id (knobs included) "
-            "feeds the executable-cache fingerprint so cached "
-            "entries never alias across pass configs.  Empty (the "
-            "default) compiles programs exactly as built")
+            "after it runs, and the spec is part of the executor's "
+            "program-cache key, so compiled programs never alias "
+            "across pass configs.  Empty (the default) compiles "
+            "programs exactly as built")
 DEFINE_flag("donation", "auto",
             "jit-segment buffer donation policy (analysis/alias.py). "
             "'conservative' donates the executor's classic "
@@ -166,13 +151,12 @@ DEFINE_flag("donation", "auto",
             "updates); 'auto' (default) additionally donates every "
             "buffer the A0xx donation-safety analysis proves dead "
             "after its segment — and degrades itself to "
-            "'conservative' when pcache.donation_aliasing_safe() says "
-            "reloaded executables drop the aliasing, or when the "
-            "analysis fails for any reason; 'off' disables donation "
-            "entirely (the numerics-baseline mode: donation is "
-            "value-preserving, so off/auto must match bit-for-bit). "
-            "The mode folds into the compile-cache key — a flag flip "
-            "can never serve a stale executable")
+            "'conservative' when the analysis fails for any reason; "
+            "'off' disables donation entirely (the numerics-baseline "
+            "mode: donation is value-preserving, so off/auto must "
+            "match bit-for-bit).  The mode is part of the executor's "
+            "program-cache key — a flag flip can never serve a stale "
+            "executable")
 DEFINE_flag("amp_bf16_act", True,
             "when amp_bf16 is on, keep activations bfloat16 between ops "
             "instead of casting every MXU output back to f32 — halves "

@@ -31,10 +31,7 @@ timeout 300 python -m paddle_tpu.tools.chaos_cli --selftest
 echo "[smoke] pelastic selftest (view-change protocol + simulated-fleet shrink/grow + 2-worker SIGTERM chaos drill) ..."
 timeout 600 python -m paddle_tpu.tools.elastic_cli --selftest
 
-echo "[smoke] pcc selftest (persistent compile cache: cold->warm reload, quarantine, rewrite passes incl. layout+fuse opt pipeline) ..."
-timeout 300 python -m paddle_tpu.tools.pcache_cli --selftest
-
-echo "[smoke] pperf selftest (regression gate, step profiler, SLO burn, warm pcache blob) ..."
+echo "[smoke] pperf selftest (regression gate, step profiler, SLO burn) ..."
 timeout 300 python -m paddle_tpu.tools.perf_cli --selftest
 
 echo "[smoke] pload selftest (open vs closed loop omission gap, tail join, replay fidelity, latency gate) ..."

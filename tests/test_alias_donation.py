@@ -3,11 +3,10 @@ and its executor/trainer/audit wiring behind FLAGS_donation.
 
 Donation is value-preserving: XLA reuses the donated input's buffer
 for an output, so numerics across off/conservative/auto must be
-BIT-identical on f32 — several tests below pin exactly that.  On the
-CPU backend donation is a silent no-op (and
-`pcache.donation_aliasing_safe()` is False), so tests that need the
-widened path monkeypatch the backend-safety probe rather than assert
-buffer deletion.
+BIT-identical on f32 — several tests below pin exactly that, on the
+toy and on the models the benchmark's cells run at their smallest
+sizes.  On the CPU backend donation is a silent no-op, so the tests
+read the plan the executor applied rather than assert buffer deletion.
 """
 
 import numpy as np
@@ -15,7 +14,6 @@ import pytest
 
 import paddle_tpu.fluid as fluid
 from paddle_tpu import analysis
-from paddle_tpu.compile import pcache
 from paddle_tpu.core.desc import OpDesc
 from paddle_tpu.core.scope import Scope
 from paddle_tpu.obs import mem as obs_mem
@@ -25,32 +23,30 @@ from paddle_tpu.utils import flags
 
 
 @pytest.fixture(autouse=True)
-def _restore_donation_flags():
-    old = {k: flags.get_flag(k)
-           for k in ("donation", "compile_cache_dir")}
+def _restore_donation_flag():
+    old = flags.get_flag("donation")
     yield
-    for k, v in old.items():
-        flags.set_flag(k, v)
-    pcache.reset()
+    flags.set_flag("donation", old)
 
 
-def _feeds(rs=None, n=4, d=64):
-    rs = rs or np.random.RandomState(0)
-    return {"x": rs.randn(n, d).astype(np.float32)}
+def _toy_feeds(n=4, d=64):
+    rs = np.random.RandomState(0)
+    return lambda: {"x": rs.randn(n, d).astype(np.float32)}
 
 
-def _train_losses(main, startup, cost, steps=4, d=64):
+def _train_losses(main, startup, cost, steps=4, feeds=None):
     """Fresh Executor+Scope run; returns (per-step losses, final
-    param values) for exact cross-mode comparison."""
+    param values) for exact cross-mode comparison.  `feeds()` gives a
+    step's feed dict (the toy's random batches when None)."""
+    feeds = feeds or _toy_feeds()
     scope = Scope()
     exe = fluid.Executor(fluid.CPUPlace())
     with fluid.scope_guard(scope):
         exe.run(startup, scope=scope)
-        rs = np.random.RandomState(0)
         losses = []
         for _ in range(steps):
-            out, = exe.run(main, feed=_feeds(rs, d=d),
-                           fetch_list=[cost], scope=scope)
+            out, = exe.run(main, feed=feeds(), fetch_list=[cost],
+                           scope=scope)
             losses.append(np.asarray(out).copy())
         params = {n: np.asarray(scope.get(n)).copy()
                   for n in main.global_block().vars
@@ -148,20 +144,9 @@ def test_a003_fetch_declines_widening():
                    for i in range(len(plan.segments)))
 
 
-def test_a005_unsafe_backend_degrades():
-    main, _startup, cost = _build_adam_toy()
-    plan = analysis.analyze_donation(main, fetches=[cost.name],
-                                     mode="auto", backend_safe=False)
-    assert plan.effective_mode == "conservative"
-    assert "A005" in plan.report.codes()
-    assert not plan.report.errors
-
-
 # -- executor wiring --------------------------------------------------------
 
-def test_executor_applies_widened_plan(monkeypatch):
-    monkeypatch.setattr(pcache, "donation_aliasing_safe",
-                        lambda backend=None: True)
+def test_executor_applies_widened_plan():
     flags.set_flag("donation", "auto")
     main, startup, hname, lname = _build_two_segment()
     scope = Scope()
@@ -176,36 +161,77 @@ def test_executor_applies_widened_plan(monkeypatch):
     assert any(hname in m for m in muts), muts
 
 
-def test_auto_degrades_on_unsafe_backend_bit_identical(monkeypatch):
-    """Satellite: on a backend where executable reload drops donation
-    aliasing, auto quietly becomes conservative and numerics match
-    off exactly."""
-    monkeypatch.setattr(pcache, "donation_aliasing_safe",
-                        lambda backend=None: False)
-    runs = {}
-    for mode in ("off", "auto"):
-        flags.set_flag("donation", mode)
-        main, startup, cost = _build_adam_toy()
-        runs[mode] = _train_losses(main, startup, cost)
-    _losses, _params, exe = runs["auto"]
-    cp = list(exe._cache.values())[-1]
-    assert cp._donation["mode"] == "conservative"
-    for a, b in zip(runs["off"][0], runs["auto"][0]):
-        np.testing.assert_array_equal(a, b)
-    for n, v in runs["off"][1].items():
-        np.testing.assert_array_equal(v, runs["auto"][1][n])
+def _adam_toy():
+    main, startup, cost = _build_adam_toy()
+    return main, startup, cost, _toy_feeds()
 
 
-def test_modes_bit_identical_f32(monkeypatch):
-    """The core safety property: donation never changes a value.
-    Backend forced 'safe' so auto actually widens."""
-    monkeypatch.setattr(pcache, "donation_aliasing_safe",
-                        lambda backend=None: True)
+def _resnet_cifar10():
+    """`resnet50-train`'s family at its smallest depth (one block a
+    group), 32x32 images, Momentum."""
+    from paddle_tpu.models.image import resnet_cifar10
+
+    fluid.framework.reset_unique_name()
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        image = fluid.layers.data(name="image", shape=[3, 32, 32],
+                                  dtype="float32")
+        label = fluid.layers.data(name="label", shape=[1], dtype="int64")
+        logits = resnet_cifar10(image, class_dim=10, depth=8)
+        cost = fluid.layers.mean(
+            fluid.layers.softmax_with_cross_entropy(logits, label))
+        fluid.optimizer.Momentum(learning_rate=0.01,
+                                 momentum=0.9).minimize(cost)
+    rs = np.random.RandomState(0)
+    feed = {"image": rs.rand(4, 3, 32, 32).astype(np.float32),
+            "label": rs.randint(0, 10, size=(4, 1)).astype(np.int64)}
+    return main, startup, cost, lambda: feed
+
+
+def _transformer():
+    """`gpt2m-train`'s program (the `flash_attention` op, interpreted
+    here) at tests/test_flash_attention_op.py's size, Adam."""
+    from paddle_tpu.models.transformer_program import (
+        build_transformer_program, transformer_program_feeds)
+
+    fluid.framework.reset_unique_name()
+    main, startup, cost, _ = build_transformer_program(
+        4, 16, 64, n_layer=1, n_head=4, d_model=32)
+    with fluid.program_guard(main, startup):
+        fluid.optimizer.Adam(learning_rate=3e-4).minimize(cost)
+    feed = transformer_program_feeds(4, 16, 64, seed=1)
+    return main, startup, cost, lambda: feed
+
+
+def _looped():
+    """`ouro-train-4k`'s program at tests/test_looped_program.py's
+    size: two blocks applied three times over shared weights, Adam."""
+    from paddle_tpu.models.looped_program import build_looped_program
+    from paddle_tpu.models.transformer_program import (
+        transformer_program_feeds)
+
+    fluid.framework.reset_unique_name()
+    main, startup, cost, _ = build_looped_program(
+        2, 32, 97, n_layer=2, n_loop=3, n_head=4, d_model=64, d_ff=160)
+    with fluid.program_guard(main, startup):
+        fluid.optimizer.Adam(learning_rate=3e-4).minimize(cost)
+    feed = transformer_program_feeds(2, 32, 97, seed=1)
+    return main, startup, cost, lambda: feed
+
+
+@pytest.mark.parametrize("build", [_adam_toy, _resnet_cifar10,
+                                   _transformer, _looped],
+                         ids=lambda b: b.__name__.lstrip("_"))
+def test_modes_bit_identical_f32(build):
+    """The core safety property: donation never changes a value — on
+    the toy and on the programs the benchmark's cells run.  `auto` is
+    the mode those cells run, and here it stays `auto`."""
     runs = {}
     for mode in ("off", "conservative", "auto"):
         flags.set_flag("donation", mode)
-        main, startup, cost = _build_adam_toy()
-        runs[mode] = _train_losses(main, startup, cost)
+        main, startup, cost, feeds = build()
+        runs[mode] = _train_losses(main, startup, cost, steps=3,
+                                   feeds=feeds)
     ref_losses, ref_params, _ = runs["off"]
     for mode in ("conservative", "auto"):
         losses, params, _ = runs[mode]
@@ -213,16 +239,18 @@ def test_modes_bit_identical_f32(monkeypatch):
             np.testing.assert_array_equal(a, b)
         for n, v in ref_params.items():
             np.testing.assert_array_equal(v, params[n])
+    cp = list(runs["auto"][2]._cache.values())[-1]
+    assert cp._donation["mode"] == "auto"
+    if sum(1 for seg in cp._plan if seg["jit"]) > 1:
+        assert any(cp._donation["widened"]), cp._donation
 
 
-def test_donation_under_amp_bf16(monkeypatch):
+def test_donation_under_amp_bf16():
     """Satellite: under amp_bf16 the state dtypes take two steps to
     reach their fixed point (f32 -> bf16 -> f32 masters).  The
     donation plan must ride the re-traces: after the fixed point no
     segment traces again, and auto matches off bit-for-bit (same
     casts, donation is aliasing only)."""
-    monkeypatch.setattr(pcache, "donation_aliasing_safe",
-                        lambda backend=None: True)
     runs = {}
     for mode in ("off", "auto"):
         flags.set_flag("donation", mode)
@@ -242,71 +270,13 @@ def test_donation_under_amp_bf16(monkeypatch):
     assert sizes and all(s <= 3 for s in sizes.values()), sizes
 
 
-# -- compile-cache key separation -------------------------------------------
-
-def _build_two_segment_infer():
-    """fc -> print -> mean with NO optimizer: zero in-place ops, so
-    the program is donation-free on every backend and all three
-    modes' pcache entries are non-donated (reloadable even where
-    donation_aliasing_safe is False)."""
-    main, startup = fluid.Program(), fluid.Program()
-    with fluid.program_guard(main, startup):
-        x = fluid.layers.data(name="x", shape=[16], dtype="float32")
-        h = fluid.layers.fc(input=x, size=8)
-        loss = fluid.layers.mean(x=h)
-    bd = main.desc.block(0)
-    i = next(i for i, od in enumerate(bd.ops)
-             if od.type == "mean")
-    bd.ops.insert(i, OpDesc("print", {"X": [h.name]},
-                            {"Out": [h.name]},
-                            {"message": "seg", "summarize": 1}))
-    return main, startup, loss.name
-
-
-def test_pcache_keys_separate_modes(tmp_path):
-    """FLAGS_donation folds into the persistent-cache keys: each mode
-    populates its own entries cold and reloads its own warm (0 new
-    entries), never another mode's."""
-    from paddle_tpu.obs import telemetry as obs_tele
-
-    flags.set_flag("compile_cache_dir", str(tmp_path))
-    x = np.zeros((4, 16), np.float32)
-
-    def run_once(mode):
-        flags.set_flag("donation", mode)
-        main, startup, lname = _build_two_segment_infer()
-        scope = Scope()
-        exe = fluid.Executor(fluid.CPUPlace())
-        with fluid.scope_guard(scope):
-            exe.run(startup, scope=scope)
-            exe.run(main, feed={"x": x}, fetch_list=[lname],
-                    scope=scope)
-
-    entries = {}
-    for mode in ("off", "conservative", "auto"):
-        before = pcache.get_cache().stats()["entries"]
-        run_once(mode)
-        entries[mode] = pcache.get_cache().stats()["entries"]
-        assert entries[mode] > before, \
-            "mode %r reused another mode's entries" % mode
-    # warm rerun per mode: 0 fresh entries, served from disk
-    for mode in ("off", "conservative", "auto"):
-        pcache.reset()
-        before = pcache.get_cache().stats()["entries"]
-        hits0 = obs_tele.snapshot().get("compile_cache_hits_total", 0)
-        run_once(mode)
-        assert pcache.get_cache().stats()["entries"] == before
-        assert obs_tele.snapshot().get("compile_cache_hits_total",
-                                       0) > hits0
-
-
 # -- audit ------------------------------------------------------------------
 
 def test_audit_clean_toy_zero_reclaimable_under_auto():
     main, _startup, cost = _build_adam_toy()
     audit = obs_mem.audit_donation(main, fetches=[cost.name],
                                    mode="auto")
-    assert audit["effective_mode"] == "auto"
+    assert audit["mode"] == "auto"
     assert audit["reclaimable_bytes"] == 0, audit["reclaimable"]
     assert audit["donated_bytes"] > 0
     # every reclaimable entry in ANY mode carries its explanation

@@ -154,7 +154,7 @@ _CACHE_PROBE = ("from paddle_tpu.utils.compile_cache import "
                 "print(jax.config.jax_compilation_cache_dir)")
 
 
-def test_compile_cache_dir_is_fixed_inside_the_checkout():
+def test_cache_dir_is_fixed_inside_the_checkout():
     """Unset, the cache goes to <checkout>/.jax_cache: the same string
     from two processes started in different directories."""
     outs = [subprocess.run(
@@ -168,10 +168,25 @@ def test_compile_cache_dir_is_fixed_inside_the_checkout():
     assert outs == [[want, want], [want, want]]
 
 
-def test_compile_cache_dir_follows_the_environment(tmp_path):
+def test_cache_dir_follows_the_environment(tmp_path):
     proc = _run(["-c", _CACHE_PROBE],
                 {"JAX_COMPILATION_CACHE_DIR": str(tmp_path)})
     assert proc.stdout.split() == [str(tmp_path), str(tmp_path)]
+
+
+@pytest.mark.parametrize("ambient, want", [(None, "0"), ("2.5", "2.5")])
+def test_compile_cache_min_compile_time(tmp_path, ambient, want):
+    """Unset, every compile is kept (0 s: a warm start redoes none);
+    set by the environment, the threshold is the environment's."""
+    name = "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"
+    env = {"JAX_COMPILATION_CACHE_DIR": str(tmp_path)}
+    if ambient is not None:
+        env[name] = ambient
+    proc = _run(["-c", _CACHE_PROBE.replace(
+        "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_compile_time_secs")], env,
+        env_drop=(name,))
+    assert proc.stdout.split() == [str(tmp_path), want]
 
 
 def test_save_after_amp_startup_round_trips(tmp_path):

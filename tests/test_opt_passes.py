@@ -6,8 +6,7 @@ The load-bearing contracts:
     "default+layout+fuse+auto_remat" (and the forced-knob variant)
     keeps the verifier green and its fetches numerically equal —
     bit-identical in f32, tolerance-equal under amp_bf16;
-  * pipeline ids are distinct per pass AND per knob setting, so
-    pcache entries can never alias across configs;
+  * pipeline ids are distinct per pass AND per knob setting;
   * the layout pass accepts/declines off the TPU-tiled roofline, and
     the layout/fuse-optimized ResNet-50 b256 program carries a
     strictly lower max(MXU, HBM) floor than the unoptimized one;
@@ -215,19 +214,9 @@ class TestSpecGrammar:
         ids = [passes.pipeline_id(s) for s in specs]
         assert len(set(ids)) == len(ids), ids
 
-    def test_knob_changes_pcache_fingerprint(self):
-        from paddle_tpu.compile import fingerprint
-
-        _fetch, _feed = _build_fit_a_line()
-        main = fluid.default_main_program()
-        fps = {fingerprint.program_fingerprint(
-            main, pipeline_id=passes.pipeline_id(s))
-            for s in ("default", "default+fuse", "default+fuse:cap=2")}
-        assert len(fps) == 3
-
     def test_explicit_default_knob_is_same_pipeline(self):
         # "fuse:cap=0" IS the bare fuse pass: one semantics -> one
-        # pipeline id (no duplicate pcache entries / ptune points)
+        # pipeline id (no duplicate ptune points)
         assert passes.pipeline_id("fuse:cap=0") == \
             passes.pipeline_id("fuse")
         assert passes.pipeline_id("layout:force=0") == \

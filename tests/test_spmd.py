@@ -73,7 +73,7 @@ def _run(mesh, steps=4, **kw):
     main, startup, avg = _build_mlp()
     tr = SpmdTrainer(main, startup, feed_names=["x", "label"],
                      fetch_names=[avg.name], mesh=mesh,
-                     use_pcache=False, **kw).init()
+                     **kw).init()
     losses = []
     for i in range(steps):
         (loss,) = tr.step(_feeds(i))
@@ -202,8 +202,7 @@ def test_trainer_rejects_mismatched_plan():
                                 ["x", "label"], [avg.name])
     tr = SpmdTrainer(main, startup, feed_names=["x", "label"],
                      fetch_names=[avg.name],
-                     mesh=make_mesh(n_devices=8), plan=plan,
-                     use_pcache=False)
+                     mesh=make_mesh(n_devices=8), plan=plan)
     with pytest.raises(ValueError, match="pshard plan"):
         tr.init()
 
@@ -260,7 +259,7 @@ def test_sharded_checkpoint_roundtrip_no_densify(tmp_path):
     fresh = SpmdTrainer(main, startup, feed_names=["x", "label"],
                         fetch_names=[avg.name],
                         mesh=make_mesh(n_devices=8, mp=2),
-                        zero_stage=1, use_pcache=False).init()
+                        zero_stage=1).init()
     info = fresh.restore_checkpoint(str(tmp_path))
     assert info["step"] == 2 and info["densified"] == []
     for n in tr.state:
@@ -286,8 +285,7 @@ def test_supervisor_auto_resume_sharded(tmp_path):
                       fetch_names=[avg.name],
                       mesh=make_mesh(n_devices=8, mp=2),
                       zero_stage=1,
-                      rules=[[r"fc_1\.w_0", ["mp", None]]],
-                      use_pcache=False).init()
+                      rules=[[r"fc_1\.w_0", ["mp", None]]]).init()
     sup2 = attach_supervisor(tr2, root, interval_secs=0.0)
     assert sup2._latest_snapshot() is not None
     assert sup2._restore_latest() == 3
