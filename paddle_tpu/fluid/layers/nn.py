@@ -72,14 +72,19 @@ def flash_attention(queries, keys, values, num_heads=1, causal=False,
     sequence-sharded; mode "ulysses" all-to-alls the shard axis from
     sequence to heads and attends full sequences locally
     (parallel/ring.py).  The kernel chooses its block sizes from the
-    shapes unless `block_size` names one.
+    shapes unless `block_size` names one.  Beside the result the op
+    writes `Lse`, each score row's log-sum-exp as float32 [batch,
+    heads, seq] whatever the compute type: the one statistic its
+    gradient reads, kept so that the backward pass does not run the
+    forward kernel again.
     """
     helper = LayerHelper("flash_attention", name=name)
     out = helper.create_tmp_variable(queries.dtype)
+    lse = helper.create_tmp_variable("float32", stop_gradient=True)
     helper.append_op(
         type="flash_attention",
         inputs={"Q": [queries], "K": [keys], "V": [values]},
-        outputs={"Out": [out]},
+        outputs={"Out": [out], "Lse": [lse]},
         attrs={"num_heads": int(num_heads), "causal": bool(causal),
                "sm_scale": float(sm_scale or 0.0),
                "sequence_parallel_axis": sequence_parallel_axis,

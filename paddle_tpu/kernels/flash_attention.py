@@ -48,6 +48,9 @@ _LANES = 128
 _VMEM_BUDGET = 14 * 2 ** 20
 # the block sizes the chooser tries: multiples of the MXU's 128 rows
 _BLOCKS = (1024, 512, 256, 128)
+# the scope the backward's operations lie under, whoever calls `_bwd`:
+# the benchmark's readers find them by it
+BWD_SCOPE = "flash_attention_bwd"
 
 
 def _block(seq, block, what, shape):
@@ -298,7 +301,28 @@ def _fwd(q, k, v, sm_scale, causal, block_q, block_k, q_offset):
             l.reshape(B, H, Tq))
 
 
+def _flash_fwd_rule(q, k, v, sm_scale, causal, block_q, block_k,
+                    q_offset):
+    if sm_scale is None:
+        sm_scale = q.shape[-1] ** -0.5
+    o, m, l = _fwd(q, k, v, sm_scale, causal, block_q, block_k, q_offset)
+    # a probability is exp(s - lse): of the two statistics the backward
+    # reads only their log-sum-exp; a row that saw no key has l = 0
+    lse = m + jnp.log(jnp.where(l > 0, l, 1.0))
+    return (o, lse), (q, k, v, o, lse)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def flash_attention_with_lse(q, k, v, sm_scale=None, causal=False,
+                             block_q=None, block_k=None, q_offset=0):
+    """`flash_attention` and, beside its result, the float32 [B, H, Tq]
+    log-sum-exp of every row of scores: what a caller that writes its
+    own gradient (`ops/attention.py`) keeps for `_bwd`, so that its
+    backward pass need not run the forward kernel again."""
+    return _flash_fwd_rule(q, k, v, sm_scale, causal, block_q, block_k,
+                           q_offset)[0]
+
+
 def flash_attention(q, k, v, sm_scale=None, causal=False, block_q=None,
                     block_k=None, q_offset=0):
     """softmax(q k^T * scale [+ causal mask]) v without materializing
@@ -306,18 +330,8 @@ def flash_attention(q, k, v, sm_scale=None, causal=False, block_q=None,
     diagonal (used by ring attention where q is a sequence shard).
     A block left None is chosen by the kernel from the shapes; one that
     is named must divide its sequence."""
-    if sm_scale is None:
-        sm_scale = q.shape[-1] ** -0.5
-    o, _, _ = _fwd(q, k, v, sm_scale, causal, block_q, block_k, q_offset)
-    return o
-
-
-def _flash_fwd_rule(q, k, v, sm_scale, causal, block_q, block_k,
-                    q_offset):
-    if sm_scale is None:
-        sm_scale = q.shape[-1] ** -0.5
-    o, m, l = _fwd(q, k, v, sm_scale, causal, block_q, block_k, q_offset)
-    return o, (q, k, v, o, m, l)
+    return flash_attention_with_lse(q, k, v, sm_scale, causal, block_q,
+                                    block_k, q_offset)[0]
 
 
 def _bwd_step_bytes(bq, bk, d, itemsize, tq=None):
@@ -560,7 +574,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 
 def _flash_bwd_rule(sm_scale, causal, block_q, block_k, q_offset, res,
-                    do):
+                    cotangents):
     """The flash-attention VJP as kernels that recompute the
     probabilities chunk by chunk from the forward's statistics:
     dv = p^T do; dp = do v^T; ds = p * (dp - rowsum(do * o));
@@ -571,38 +585,48 @@ def _flash_bwd_rule(sm_scale, causal, block_q, block_k, q_offset, res,
     block of queries and gathers dq over the keys it sees (seven).
     Products take their operands in the type they arrive in and
     accumulate in float32; nothing the size of the score square reaches
-    HBM."""
-    q, k = res[:2]
+    HBM.  A cotangent of the log-sum-exp enters with the row sums:
+    d lse / d s = p, so ds = p * (dp - (rowsum(do * o) - dlse))."""
+    q, k, v, o, lse = res
+    do, dlse = cotangents
+    with jax.named_scope(BWD_SCOPE):
+        delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
+                        axis=-1) - dlse
+        return _bwd(q, k, v, do, lse, delta, sm_scale, causal, block_q,
+                    block_k, q_offset)
+
+
+def _bwd(q, k, v, do, lse, delta, sm_scale, causal, block_q, block_k,
+         q_offset=0):
+    """dq, dk, dv of one call from the forward's row statistics `lse`
+    and the row sums `delta` of do * o, both float32 [B, H, Tq], at
+    blocks chosen here as the forward chooses its own: what the
+    custom VJP above and the `flash_attention` op's gradient
+    (`ops/attention.py`), which saved `lse`, both end in."""
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
-    with jax.named_scope("flash_attention_bwd"):
-        bq, bk, one_kernel = _choose_bwd_blocks(
-            q.shape, k.shape, q.dtype.itemsize, causal, block_q, block_k)
-        for kernel in (("dq_dkv",) if one_kernel else ("dkv", "dq")):
-            telemetry.on_flash_attention_bwd_lowering(kernel, bq, bk)
-        return _bwd_kernels(res, do, sm_scale=sm_scale, causal=causal,
-                            q_offset=q_offset, bq=bq, bk=bk,
-                            one_kernel=one_kernel)
+    bq, bk, one_kernel = _choose_bwd_blocks(
+        q.shape, k.shape, q.dtype.itemsize, causal, block_q, block_k)
+    for kernel in (("dq_dkv",) if one_kernel else ("dkv", "dq")):
+        telemetry.on_flash_attention_bwd_lowering(kernel, bq, bk)
+    return _bwd_kernels(q, k, v, do, lse, delta, sm_scale=sm_scale,
+                        causal=causal, q_offset=q_offset, bq=bq, bk=bk,
+                        one_kernel=one_kernel)
 
 
 @functools.partial(jax.jit, static_argnames=(
     "sm_scale", "causal", "q_offset", "bq", "bk", "one_kernel"))
-def _bwd_kernels(res, do, *, sm_scale, causal, q_offset, bq, bk,
-                 one_kernel):
-    """dq, dk, dv from the residuals and do, at blocks already chosen.
-    Under `jax.jit` so that a program holding the same attention many
-    times (one a layer) traces these kernels once, and the build's
-    shape inference, the executor's program and the functional step
-    share that trace: a kernel body is some hundred primitives and a
-    step program would trace it twice an op (PERF.md section 6, PR 26)."""
-    q, k, v, o, m, l = res
+def _bwd_kernels(q, k, v, do, lse, delta, *, sm_scale, causal, q_offset,
+                 bq, bk, one_kernel):
+    """dq, dk, dv from the row statistics and do, at blocks already
+    chosen.  Under `jax.jit` so that a program holding the same
+    attention many times (one a layer) traces these kernels once, and
+    the build's shape inference, the executor's program and the
+    functional step share that trace: a kernel body is some hundred
+    primitives and a step program would trace it twice an op (PERF.md
+    section 6, PR 26)."""
     B, H, Tq, D = q.shape
     Tk = k.shape[2]
-    # a probability is exp(s - lse): one row a query, and the row sums
-    # of do * o another; a row that saw no key has l = 0
-    lse = m + jnp.log(jnp.where(l > 0, l, 1.0))
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1)
     operands = [x.reshape(B * H, x.shape[2], D) for x in (q, k, v, do)] \
         + [x.reshape(B * H, 1, Tq) for x in (lse, delta)]
     name = "flash_attention_bwd%%s_q%d_k%d" % (bq, bk)
@@ -692,7 +716,7 @@ def _bwd_kernels(res, do, *, sm_scale, causal, q_offset, bq, bk,
     return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
 
 
-flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
+flash_attention_with_lse.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
 def reference_attention(q, k, v, sm_scale=None, causal=False, q_offset=0):

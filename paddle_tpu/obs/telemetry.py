@@ -34,7 +34,8 @@ from . import trace as trace_mod
 
 __all__ = ["on_executor_run", "on_jit_trace",
            "on_flash_attention_lowering",
-           "on_flash_attention_bwd_lowering", "on_shared_parameter_uses",
+           "on_flash_attention_bwd_lowering",
+           "on_flash_attention_grad_lowering", "on_shared_parameter_uses",
            "on_transfer",
            "on_feed_seconds", "on_program_cache_evict",
            "jit_trace_count", "transfer_bytes", "step", "set_gauge",
@@ -98,6 +99,20 @@ def on_flash_attention_bwd_lowering(kernel, block_q, block_k):
                    "and tiling",
                    labelnames=("kernel", "block_q", "block_k")) \
           .labels(kernel=kernel, block_q=block_q, block_k=block_k).inc()
+
+
+def on_flash_attention_grad_lowering(residuals):
+    """A `flash_attention_grad` op was traced into a program: "saved"
+    where its backward kernels read the row statistics the forward op
+    kept, "recomputed" where the generic gradient ran the forward again
+    to get them (a sequence-parallel op, or a program built before the
+    op had the output).  One count per gradient op a lowered program
+    holds."""
+    _reg().counter("flash_attention_grad_lowerings_total",
+                   "flash_attention_grad ops lowered, by where their "
+                   "residuals came from",
+                   labelnames=("residuals",)) \
+          .labels(residuals=residuals).inc()
 
 
 def on_shared_parameter_uses(program, uses):

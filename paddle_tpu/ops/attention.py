@@ -5,21 +5,28 @@ probability matrix (reference: python/paddle/v2/fluid/nets.py:338
 scaled_dot_product_attention); registering the fused kernel as a
 first-class op exceeds that surface: programs built with
 `fluid.layers.flash_attention` get the pallas online-softmax kernel
-(kernels/flash_attention.py) on TPU, interpret mode on CPU, and its
-backward kernels through the generic grad machinery (the kernel's
-custom_vjp is what jax.vjp differentiates).
+(kernels/flash_attention.py) on TPU, interpret mode on CPU.  The op
+keeps the kernel's row statistics as a second output, `Lse`, and its
+gradient is an explicit kernel that hands them to the backward kernels:
+the generic gradient (jax.vjp of the whole op) has to run the forward
+kernel again to get them back, which no compiler pass undoes for a
+custom call.
 
 When the op's `sequence_parallel_axis` attr names an axis of the
 ambient device mesh (the mesh `ParallelTrainer` compiles under), the
 kernel runs ring attention instead: q/k/v stay sequence-sharded and
 K/V blocks rotate over ICI neighbors (parallel/ring.py), so fluid-built
 programs scale to long context without leaving the Program stack.
+The gradient of that branch, and of a program built before the op had
+`Lse`, is the generic one.
 """
 
 import jax
 import jax.numpy as jnp
 
-from .registry import register_op, same_meta_infer_shape
+from ..obs import telemetry
+from .registry import (register_op, register_grad_kernel, run_generic_grad,
+                       same_meta_infer_shape)
 
 
 def _ambient_mesh():
@@ -40,10 +47,24 @@ def _merge_heads(x):
     return x.transpose(0, 2, 1, 3).reshape(b, t, h * dh)
 
 
+def _sequence_parallel(attrs):
+    """The ambient mesh if the op's `sequence_parallel_axis` names an
+    axis of it larger than 1, else None: what sends the op, and its
+    gradient after it, down the ring or ulysses path."""
+    sp_axis = attrs.get("sequence_parallel_axis", "")
+    mesh = _ambient_mesh()
+    if sp_axis and not mesh.empty and mesh.shape.get(sp_axis, 1) > 1:
+        return mesh
+    return None
+
+
 @register_op("flash_attention")
 def flash_attention_op(ctx, ins, attrs):
-    """Q,K,V: [batch, seq, dim] dense; Out: [batch, seq_q, dim]."""
-    from ..kernels.flash_attention import flash_attention
+    """Q,K,V: [batch, seq, dim] dense; Out: [batch, seq_q, dim]; Lse:
+    float32 [batch, heads, seq_q], the log-sum-exp of each row of
+    scores, which the gradient reads (zeros on the sequence-parallel
+    branches, whose gradient reads none)."""
+    from ..kernels.flash_attention import flash_attention_with_lse
     from ..parallel.ring import (ring_attention, ulysses_attention,
                                  sp_shard_map)
 
@@ -66,8 +87,8 @@ def flash_attention_op(ctx, ins, attrs):
     kh = _split_heads(k, num_heads)
     vh = _split_heads(v, num_heads)
 
-    mesh = _ambient_mesh()
-    if sp_axis and not mesh.empty and mesh.shape.get(sp_axis, 1) > 1:
+    mesh = _sequence_parallel(attrs)
+    if mesh is not None:
         if sp_mode == "ring":
             sp_fn = lambda q, k, v: ring_attention(  # noqa: E731
                 q, k, v, sp_axis, sm_scale, causal)
@@ -81,12 +102,52 @@ def flash_attention_op(ctx, ins, attrs):
                 "sequence_parallel_mode must be ring or ulysses, got %r"
                 % sp_mode)
         out = sp_shard_map(sp_fn, mesh, axis_name=sp_axis)(qh, kh, vh)
+        lse = jnp.zeros(qh.shape[:3], jnp.float32)
     else:
         # 0: the kernel chooses its blocks from the shapes
         block = int(attrs.get("block_size", 0)) or None
-        out = flash_attention(qh, kh, vh, sm_scale, causal,
-                              block_q=block, block_k=block)
-    return {"Out": [_merge_heads(out).astype(q.dtype)]}
+        out, lse = flash_attention_with_lse(qh, kh, vh, sm_scale, causal,
+                                            block_q=block, block_k=block)
+    return {"Out": [_merge_heads(out).astype(q.dtype)], "Lse": [lse]}
+
+
+@register_grad_kernel("flash_attention")
+def flash_attention_grad(ctx, ins, attrs):
+    """Q@GRAD, K@GRAD, V@GRAD from the backward kernels on what the
+    forward op saved: `O@Lse`, and `O@Out` for the row sums of
+    dOut * Out, taken on the merged [batch, seq, dim] tensors so that
+    Out is never split into heads again.  Where the forward took the
+    sequence-parallel path, or the op desc carries no `O@Lse` (a
+    program from before the op had it), the generic gradient
+    differentiates the op as a whole and runs its forward again."""
+    from ..kernels.flash_attention import BWD_SCOPE, _bwd
+
+    lse = (ins.get("O@Lse") or [None])[0]
+    if lse is None or _sequence_parallel(attrs) is not None:
+        telemetry.on_flash_attention_grad_lowering("recomputed")
+        return run_generic_grad(ctx, "flash_attention", ins, attrs)
+    telemetry.on_flash_attention_grad_lowering("saved")
+
+    q, k, v, o = (ins[slot][0] for slot in ("Q", "K", "V", "O@Out"))
+    do = ins["OG@Out"][0].astype(q.dtype)
+    num_heads = int(attrs.get("num_heads", 1))
+    block = int(attrs.get("block_size", 0)) or None
+    heads = [_split_heads(x, num_heads) for x in (q, k, v, do)]
+    # the scope holds what it holds under the kernel's own VJP: the row
+    # sums and the kernels, not the split-head copies around them
+    with jax.named_scope(BWD_SCOPE):
+        b, t, d = o.shape
+        # behind a barrier, or XLA moves the row sums onto the forward
+        # kernel's split o and keeps that for the backward beside Out
+        o = jax.lax.optimization_barrier(o)
+        delta = jnp.sum(
+            (do.astype(jnp.float32) * o.astype(jnp.float32))
+            .reshape(b, t, num_heads, d // num_heads), axis=-1)
+        grads = _bwd(*heads, lse, delta.transpose(0, 2, 1),
+                     float(attrs.get("sm_scale", 0.0)) or None,
+                     bool(attrs.get("causal", False)), block, block)
+    return {slot + "@GRAD": [_merge_heads(g)]
+            for slot, g in zip(("Q", "K", "V"), grads)}
 
 
 @register_op("rope", nondiff_inputs=("Positions",),

@@ -281,9 +281,12 @@ def run_generic_grad(ctx, fwd_type, ins, attrs):
     maker (see backward.py; reference: grad_op_desc_maker.h
     DefaultGradOpDescMaker which forwards Input/Output/OutputGrad):
       ins[slot]       : forward inputs (original slots)
-      ins["O@SLOT"]   : forward outputs (ignored here — XLA CSEs the
-                        recomputation against the forward pass; explicit
-                        grad kernels may use them)
+      ins["O@SLOT"]   : forward outputs (ignored here: jax.vjp runs the
+                        forward kernel again, and XLA CSEs that against
+                        the forward pass where it is XLA's own ops.  Not
+                        where it is a custom call: an op whose forward
+                        is a Pallas kernel registers an explicit grad
+                        kernel that reads them, as flash_attention does)
       ins["OG@SLOT"]  : grads of forward outputs (may be absent)
     Returns {"SLOT@GRAD": [...]} for differentiable forward input slots.
     """

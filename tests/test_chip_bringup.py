@@ -58,6 +58,38 @@ def test_flash_attention_lowers_for_tpu(shape, causal):
         assert "tpu_custom_call" in module
 
 
+@pytest.mark.parametrize("shape,heads", [((8, 1024, 1024), 16),
+                                         ((1, 4096, 2048), 16)])
+def test_the_op_and_its_gradient_lower_one_forward_kernel_for_tpu(shape,
+                                                                  heads):
+    """The `flash_attention` op and its gradient at the shapes of
+    gpt2m-train and ouro-train-4k, lowered for the TPU as one program:
+    the forward kernel once and the backward's one kernel, where the
+    generic gradient holds the forward kernel a second time."""
+    from paddle_tpu.ops import registry
+
+    info = registry.get_op_info("flash_attention")
+    attrs = {"num_heads": heads, "causal": True}
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+
+    def op_and(gradient):
+        def step(q, k, v, dout):
+            ins = {"Q": [q], "K": [k], "V": [v]}
+            outs = info.kernel(None, ins, attrs)
+            return outs["Out"], gradient(dict(
+                ins, **{"O@Out": outs["Out"], "O@Lse": outs["Lse"],
+                        "OG@Out": [dout]}))
+        return jax.export.export(jax.jit(step), platforms=["tpu"])(
+            x, x, x, x).mlir_module()
+
+    explicit = op_and(lambda ins: info.grad_kernel(None, ins, attrs))
+    generic = op_and(lambda ins: registry.run_generic_grad(
+        None, "flash_attention", ins, attrs))
+    forward = 'kernel_name = "flash_attention_fwd'
+    assert (explicit.count(forward), generic.count(forward)) == (1, 2)
+    assert explicit.count("tpu_custom_call") == 2
+
+
 def test_flash_attention_refuses_a_ragged_block():
     """A sequence its block does not divide raises with the shape in the
     message; the block no longer shrinks toward 1 without saying so."""

@@ -10,6 +10,7 @@ and ring-vs-dense gradient parity through the Program stack.
 """
 
 import numpy as np
+import pytest
 
 import jax
 import jax.numpy as jnp
@@ -33,6 +34,25 @@ def _dense_ref(q, k, v, num_heads, causal):
     o = reference_attention(jnp.asarray(heads(q)), jnp.asarray(heads(k)),
                             jnp.asarray(heads(v)), None, causal)
     return np.asarray(o).transpose(0, 2, 1, 3).reshape(b, t, d)
+
+
+FWD_LOWERINGS = "flash_attention_lowerings_total"
+BWD_LOWERINGS = "flash_attention_bwd_lowerings_total"
+GRAD_LOWERINGS = "flash_attention_grad_lowerings_total"
+
+
+def _counters(prefix):
+    from paddle_tpu.obs import telemetry
+
+    return {k: v for k, v in telemetry.snapshot().items()
+            if k.startswith(prefix)}
+
+
+def _rose(prefix, before):
+    """{counter: by how much} of the counters called `prefix...` that
+    moved since `before = _counters(prefix)`."""
+    return {k: v - before.get(k, 0) for k, v in _counters(prefix).items()
+            if v != before.get(k, 0)}
 
 
 class TestFlashAttentionOp(OpTest):
@@ -89,9 +109,16 @@ def _train_transformer(sp_axis, mesh, feed_specs, steps=3,
         [avg_loss.name], mesh, feed_specs=feed_specs, seed=0)
     trainer.init()
     losses = []
+    before = _counters(GRAD_LOWERINGS)
     for _ in range(steps):
         (l,) = trainer.step(transformer_program_feeds(B, T, V, seed=1))
         losses.append(float(np.asarray(l).reshape(-1)[0]))
+    # which gradient the one attention op's got, whatever the number of
+    # times the trainer traced its step
+    residuals = {k[len(GRAD_LOWERINGS):] for k in _rose(GRAD_LOWERINGS,
+                                                        before)}
+    assert residuals == {"{residuals=recomputed}" if sp_axis
+                         else "{residuals=saved}"}, residuals
     weight = sorted(n for n in trainer.state if n.startswith("fc_"))[0]
     return losses, np.asarray(trainer.state[weight]), trainer
 
@@ -184,13 +211,6 @@ def test_flash_attention_op_in_program_grads_vs_reference():
                                    rtol=1e-4, atol=1e-6)
 
 
-def _flash_lowerings():
-    from paddle_tpu.obs import telemetry
-
-    return {k: v for k, v in telemetry.snapshot().items()
-            if k.startswith("flash_attention_lowerings_total")}
-
-
 def test_block_size_in_a_program_is_honoured_and_its_absence_chooses():
     """A program whose op says block_size=128 still lowers with 128 x 128
     blocks; one that names none leaves the choice to the kernel (here
@@ -206,13 +226,10 @@ def test_block_size_in_a_program_is_honoured_and_its_absence_chooses():
                                   append_batch_size=False)
             out = fluid.layers.flash_attention(x, x, x, num_heads=2,
                                                **layer_args)
-        before = _flash_lowerings()
+        before = _counters(FWD_LOWERINGS)
         got, = fluid.Executor(fluid.CPUPlace()).run(
             main, feed={"x": x0}, fetch_list=[out])
-        after = _flash_lowerings()
-        return np.asarray(got), {k: v - before.get(k, 0)
-                                 for k, v in after.items()
-                                 if v != before.get(k, 0)}
+        return np.asarray(got), _rose(FWD_LOWERINGS, before)
 
     named, delta = lowered_with(block_size=128)
     assert delta == {"flash_attention_lowerings_total{block_k=128,"
@@ -228,16 +245,10 @@ def test_block_size_in_a_program_is_honoured_and_its_absence_chooses():
 def test_block_size_reaches_the_backward_kernels_of_a_program():
     """The gradient of a program's op runs the backward kernel (one for
     a head this short), one count per lowering, at the block size the op
-    names (the generic gradient differentiates the kernel's custom_vjp);
-    the gradients are dense attention's."""
-    from paddle_tpu.obs import telemetry
-
+    names (the op's gradient hands it to the kernels' chooser); the
+    gradients are dense attention's."""
     B, T, D, H = 1, 256, 32, 2
     x0 = (0.5 * RS.randn(B, T, D)).astype("float32")
-
-    def bwd_lowerings():
-        return {k: v for k, v in telemetry.snapshot().items()
-                if k.startswith("flash_attention_bwd_lowerings_total")}
 
     main, startup = fluid.Program(), fluid.Program()
     with fluid.program_guard(main, startup):
@@ -250,15 +261,13 @@ def test_block_size_reaches_the_backward_kernels_of_a_program():
     from paddle_tpu.core.scope import Scope
     from paddle_tpu.fluid.executor import scope_guard, global_scope
 
-    before = bwd_lowerings()
+    before = _counters(BWD_LOWERINGS)
     with scope_guard(Scope()):
         exe = fluid.Executor(fluid.CPUPlace())
         exe.run(startup)
         global_scope().set(x.name, jnp.asarray(x0))
         got, = exe.run(main, feed={}, fetch_list=grads)
-    delta = {k: v - before.get(k, 0) for k, v in bwd_lowerings().items()
-             if v != before.get(k, 0)}
-    assert delta == {
+    assert _rose(BWD_LOWERINGS, before) == {
         "flash_attention_bwd_lowerings_total{block_k=128,block_q=128,"
         "kernel=dq_dkv}": 1}
 
@@ -273,3 +282,179 @@ def test_block_size_reaches_the_backward_kernels_of_a_program():
                                np.asarray(jax.grad(ref_loss)(
                                    jnp.asarray(x0))),
                                rtol=1e-4, atol=1e-7)
+
+
+# -- the explicit gradient against the generic one ----------------------------
+
+def _attention_program(x0, num_heads, weights, with_lse=True, **layer_args):
+    """A program of one attention op a row of `x0` = (q, k, v) values,
+    each op's result weighted by `weights` into the loss, so that every
+    element of dOut differs; `with_lse=False` appends the op as a
+    program built before it had `Lse` holds it, with `Out` alone.
+    Returns (program, start-up program, parameters and their values,
+    their gradients)."""
+    main, startup = fluid.Program(), fluid.Program()
+    total = None
+    with fluid.program_guard(main, startup):
+        w = fluid.layers.create_parameter(list(weights.shape), "float32")
+        params = [(w, weights)]
+        for qkv in x0:
+            q, k, v = (fluid.layers.create_parameter(list(x.shape),
+                                                     "float32")
+                       for x in qkv)
+            params += zip((q, k, v), qkv)
+            if with_lse:
+                out = fluid.layers.flash_attention(
+                    q, k, v, num_heads=num_heads, **layer_args)
+            else:
+                helper = fluid.layer_helper.LayerHelper("flash_attention")
+                out = helper.create_tmp_variable("float32")
+                helper.append_op(
+                    type="flash_attention",
+                    inputs={"Q": [q], "K": [k], "V": [v]},
+                    outputs={"Out": [out]},
+                    attrs={"num_heads": num_heads,
+                           "causal": layer_args.get("causal", False),
+                           "block_size": layer_args.get("block_size") or 0})
+            part = fluid.layers.mean(
+                x=fluid.layers.elementwise_mul(x=out, y=w))
+            total = part if total is None else total + part
+        grads = fluid.backward.calc_gradient(
+            total, [p for p, _ in params[1:]])
+    return main, startup, params, grads
+
+
+def _run_gradients(main, startup, params, grads):
+    from paddle_tpu.core.scope import Scope
+    from paddle_tpu.fluid.executor import scope_guard, global_scope
+
+    with scope_guard(Scope()):
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        for var, val in params:
+            global_scope().set(var.name, jnp.asarray(val))
+        return [np.asarray(g) for g in
+                exe.run(main, feed={}, fetch_list=grads)]
+
+
+def _reference_gradients(x0, num_heads, weights, causal):
+    def heads(x):
+        b, t, d = x.shape
+        return x.reshape(b, t, num_heads, d // num_heads) \
+                .transpose(0, 2, 1, 3)
+
+    def loss(q, k, v):
+        o = reference_attention(heads(q), heads(k), heads(v), None, causal)
+        return jnp.mean(o.transpose(0, 2, 1, 3).reshape(q.shape) * weights)
+
+    return [np.asarray(g) for qkv in x0 for g in jax.grad(
+        loss, argnums=(0, 1, 2))(*map(jnp.asarray, qkv))]
+
+
+def _qkv(n_ops, B, T, dim):
+    return [tuple((0.5 * RS.randn(B, T, dim)).astype("float32")
+                  for _ in range(3)) for _ in range(n_ops)]
+
+
+@pytest.mark.parametrize("block_size", [None, 128])
+@pytest.mark.parametrize("head_dim", [64, 128])
+@pytest.mark.parametrize("causal", [False, True])
+def test_the_explicit_gradient_is_the_generic_one(monkeypatch, causal,
+                                                  head_dim, block_size):
+    """The op's gradient from the statistics the forward saved runs the
+    kernels the generic gradient (jax.vjp of the whole op, what the
+    executor falls back on when an op registers none) runs on the
+    values it recomputes: the same gradients to float32 rounding of
+    the row sums, and dense attention's."""
+    from paddle_tpu.ops import registry
+
+    B, T, H = 1, 256, 2
+    x0 = _qkv(1, B, T, H * head_dim)
+    weights = RS.randn(B, T, H * head_dim).astype("float32")
+    program = _attention_program(x0, H, weights, causal=causal,
+                                 block_size=block_size)
+    before = _counters(GRAD_LOWERINGS)
+    explicit = _run_gradients(*program)
+    assert _rose(GRAD_LOWERINGS, before) == {
+        GRAD_LOWERINGS + "{residuals=saved}": 1}
+    monkeypatch.setattr(registry.get_op_info("flash_attention"),
+                        "grad_kernel", None)
+    generic = _run_gradients(*_attention_program(
+        x0, H, weights, causal=causal, block_size=block_size))
+    want = _reference_gradients(x0, H, weights, causal)
+    for got, same, dense in zip(explicit, generic, want):
+        np.testing.assert_allclose(got, same, rtol=1e-6,
+                                   atol=1e-6 * np.abs(same).max())
+        np.testing.assert_allclose(got, dense, rtol=1e-4, atol=1e-6)
+
+
+def test_a_step_lowers_the_forward_kernel_once_an_op():
+    """A program of n attention ops and their gradients holds n forward
+    kernels, not 2n: every gradient op reads what its forward op saved."""
+    B, T, H, n = 1, 128, 2, 3
+    x0 = _qkv(n, B, T, 32)
+    weights = RS.randn(B, T, 32).astype("float32")
+    program = _attention_program(x0, H, weights, causal=True)
+    before = {p: _counters(p) for p in (FWD_LOWERINGS, BWD_LOWERINGS,
+                                        GRAD_LOWERINGS)}
+    got = _run_gradients(*program)
+    assert sum(_rose(FWD_LOWERINGS, before[FWD_LOWERINGS]).values()) == n
+    assert sum(_rose(BWD_LOWERINGS, before[BWD_LOWERINGS]).values()) == n
+    assert _rose(GRAD_LOWERINGS, before[GRAD_LOWERINGS]) == {
+        GRAD_LOWERINGS + "{residuals=saved}": n}
+    for g, w in zip(got, _reference_gradients(x0, H, weights, True)):
+        np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-6)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_a_program_without_the_statistics_takes_the_generic_gradient(
+        causal):
+    """An op desc with `Out` alone, as a program built or saved before
+    the op had `Lse` holds it: its gradient op has no `O@Lse`, runs the
+    forward again through the generic gradient, says so, and gives the
+    gradients of a program that has the output."""
+    B, T, H = 1, 128, 2
+    x0 = _qkv(1, B, T, 64)
+    weights = RS.randn(B, T, 64).astype("float32")
+    old = _attention_program(x0, H, weights, with_lse=False, causal=causal)
+    grad_op, = [od for od in old[0].global_block().desc.ops
+                if od.type == "flash_attention_grad"]
+    assert "O@Lse" not in grad_op.inputs and "O@Out" in grad_op.inputs
+    before = {p: _counters(p) for p in (FWD_LOWERINGS, GRAD_LOWERINGS)}
+    got = _run_gradients(*old)
+    assert sum(_rose(FWD_LOWERINGS, before[FWD_LOWERINGS]).values()) == 2
+    assert _rose(GRAD_LOWERINGS, before[GRAD_LOWERINGS]) == {
+        GRAD_LOWERINGS + "{residuals=recomputed}": 1}
+    new = _run_gradients(*_attention_program(x0, H, weights,
+                                             causal=causal))
+    for g, same in zip(got, new):
+        np.testing.assert_allclose(g, same, rtol=1e-6,
+                                   atol=1e-6 * np.abs(same).max())
+
+
+def test_the_statistics_are_float32_whatever_the_compute_type():
+    """`Lse` has a static float32 [batch, heads, seq] meta at build time
+    for bfloat16 operands too, and holds the rows' log-sum-exp."""
+    B, T, H, dim = 2, 128, 4, 64
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        x = fluid.layers.data(name="x", shape=[B, T, dim], dtype="bfloat16",
+                              append_batch_size=False)
+        out = fluid.layers.flash_attention(x, x, x, num_heads=H,
+                                           causal=True)
+    op, = [od for od in main.global_block().desc.ops
+           if od.type == "flash_attention"]
+    lse = main.global_block().var(op.output("Lse")[0])
+    assert (tuple(lse.shape), lse.dtype, lse.stop_gradient) \
+        == ((B, H, T), "float32", True)
+    assert out.dtype == "bfloat16"
+    x0 = jnp.asarray(0.5 * RS.randn(B, T, dim), jnp.bfloat16)
+    got, = fluid.Executor(fluid.CPUPlace()).run(
+        main, feed={"x": x0}, fetch_list=[lse])
+    xh = x0.astype(jnp.float32).reshape(B, T, H, dim // H) \
+           .transpose(0, 2, 1, 3)
+    s = jnp.einsum("bhqd,bhkd->bhqk", xh, xh) * (dim // H) ** -0.5
+    s = jnp.where(jnp.tril(jnp.ones((T, T), bool)), s, -jnp.inf)
+    assert np.asarray(got).dtype == np.float32
+    np.testing.assert_allclose(got, jax.nn.logsumexp(s, axis=-1),
+                               rtol=2e-2, atol=2e-2)

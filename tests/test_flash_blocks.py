@@ -124,6 +124,39 @@ def test_chosen_blocks_match_dense_attention(monkeypatch, kv, tq, tk,
         np.testing.assert_allclose(a, b, atol=5e-5)
 
 
+@pytest.mark.parametrize("causal", [False, True])
+def test_the_log_sum_exp_is_an_output_with_a_gradient_of_its_own(causal):
+    """`flash_attention_with_lse` gives each row's log-sum-exp beside o,
+    and a cotangent of it reaches dq and dk (d lse / d s = p) as dense
+    attention's does."""
+    q, k, v = _qkv(256, 256)
+
+    def dense(q, k, v):
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * q.shape[-1] ** -0.5
+        if causal:
+            s = jnp.where(jnp.tril(jnp.ones(s.shape[-2:], bool)), s,
+                          fa.NEG_INF)
+        return (fa.reference_attention(q, k, v, None, causal),
+                jax.nn.logsumexp(s, axis=-1))
+
+    def flash(q, k, v):
+        return fa.flash_attention_with_lse(q, k, v, None, causal)
+
+    def loss(attention):
+        def f(q, k, v):
+            o, lse = attention(q, k, v)
+            return jnp.sum(jnp.sin(o)) + jnp.sum(jnp.cos(lse))
+        return f
+
+    for got, want in zip(flash(q, k, v), dense(q, k, v)):
+        np.testing.assert_allclose(got, want, atol=2e-5)
+    assert flash(q, k, v)[1].dtype == jnp.float32
+    got = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(dense), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, atol=5e-5)
+
+
 def test_forward_statistics_keep_their_shapes_and_meaning():
     """(o, m, l) as the backward and ring attention read them: m the row
     maximum of the scaled, masked scores, l the row sum of exp(s - m)."""
