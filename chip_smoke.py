@@ -7,7 +7,9 @@ calls and at the widths the repo already uses, and checks what comes
 out: ResNet-50 trained through `Executor.run` and through the
 FunctionalProgram step bench.py times, the Program-stack transformer
 trained through the flash-attention kernel (and the kernel checked
-against dense attention), ResNet-50 served over HTTP as serve_cli
+against dense attention), the routed expert op forward and backward at
+OLMoE's widths against the dense reference, ResNet-50 served over HTTP
+as serve_cli
 serves it, and — on a host with four chips — ResNet-50 under
 SpmdTrainer.  Weights are random, from a seed; no phase is cut down.
 
@@ -211,6 +213,89 @@ def transformer_train(batch=16, seq_len=512, d_model=512, n_layer=6,
             flash_kernel_check(shape, causal)
 
 
+def moe_experts_check(tokens=4096, hidden=2048, experts=64, width=1024,
+                      top_k=8):
+    """The routed expert op (`moe_experts`: ordering, the grouped Pallas
+    kernels, the combine) and its explicit gradient at OLMoE's widths,
+    bfloat16 products, against every expert applied densely to every
+    token in float32 and weighted afterwards (as the plain reference,
+    models/reference/olmoe.py, applies them): the output, and the
+    gradients of the input, the routing weights and the three stacks of
+    matrices."""
+    import jax
+    import jax.numpy as jnp
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.ops import registry
+
+    fluid.amp.enable_bf16()
+    keys = jax.random.split(jax.random.PRNGKey(0), 6)
+    x = jax.random.normal(keys[0], (tokens, hidden), jnp.float32) \
+        .astype(jnp.bfloat16)
+    d_out = jax.random.normal(keys[1], (tokens, hidden), jnp.float32) \
+        .astype(jnp.bfloat16)
+    w_gate, w_up = (jax.random.normal(k, (experts, hidden, width),
+                                      jnp.float32) * hidden ** -0.5
+                    for k in keys[2:4])
+    w_down = jax.random.normal(keys[4], (experts, width, hidden),
+                               jnp.float32) * width ** -0.5
+    probs = jax.nn.softmax(
+        jax.random.normal(keys[5], (tokens, experts), jnp.float32))
+    top_w, top_idx = jax.lax.top_k(probs, top_k)
+    info = registry.get_op_info("moe_experts")
+
+    def program(x, top_w, w_gate, w_up, w_down):
+        ins = {"X": [x], "TopW": [top_w], "TopIdx": [top_idx],
+               "WGate": [w_gate], "WUp": [w_up], "WDown": [w_down]}
+        outs = info.kernel(None, ins, {})
+        grad_ins = dict(ins, **{"OG@Out": [d_out]})
+        grad_ins.update({"O@" + slot: v for slot, v in outs.items()})
+        grads = info.grad_kernel(None, grad_ins, {})
+        return [outs["Out"][0]] + [grads[s + "@GRAD"][0] for s in (
+            "X", "TopW", "WGate", "WUp", "WDown")], outs["Counts"][0]
+
+    def plain(x, top_w, w_gate, w_up, w_down):
+        def out(x, top_w, w_gate, w_up, w_down):
+            weights = jnp.zeros((tokens, experts)).at[
+                jnp.arange(tokens)[:, None], top_idx].set(top_w)
+
+            def add_expert(m, expert):
+                gate, up, down, weight = expert
+                h = jax.nn.silu(x @ gate) * (x @ up)
+                return m + weight[:, None] * (h @ down), None
+
+            # a scan: 64 experts unrolled take minutes to compile
+            return jax.lax.scan(add_expert, jnp.zeros_like(x), (
+                w_gate, w_up, w_down, weights.T))[0]
+
+        with jax.default_matmul_precision("highest"):
+            m, vjp = jax.vjp(out, x, top_w, w_gate, w_up, w_down)
+            return [m] + list(vjp(d_out.astype(jnp.float32)))
+
+    got, counts = jax.jit(program)(x, top_w, w_gate, w_up, w_down)
+    check("tpu_custom_call" in jax.jit(program).lower(
+        x, top_w, w_gate, w_up, w_down).as_text(),
+        "moe_experts lowered without a Mosaic kernel")
+    want = jax.jit(plain)(x.astype(jnp.float32), top_w, w_gate, w_up, w_down)
+    counts = np.asarray(counts)
+    check(int(counts.sum()) == tokens * top_k,
+          "moe_experts: %d rows for %d assignments"
+          % (counts.sum(), tokens * top_k))
+    worst = 0.0
+    for name, g, w in zip(("out", "dx", "dtop_w", "dw_gate", "dw_up",
+                           "dw_down"), got, want):
+        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+        check(g.shape == w.shape and np.isfinite(g).all(),
+              "moe_experts %s: bad shape or non-finite" % name)
+        err = float(np.abs(g - w).max() / np.abs(w).max())
+        check(err < BF16_TOL, "moe_experts %s: off the reference by %.4f "
+              "of its largest value" % (name, err))
+        worst = max(worst, err)
+    print("  moe_experts [%d, %d] x %d experts of %d, top-%d (rows an "
+          "expert %d..%d): within %.4f of the reference"
+          % (tokens, hidden, experts, width, top_k, counts.min(),
+             counts.max(), worst), flush=True)
+
+
 def resnet50_serve(image_size=224, class_dim=1000, buckets=(1, 4, 16),
                    sizes=(1, 2, 4, 3, 8, 16, 5, 1)):
     import jax
@@ -350,7 +435,8 @@ def main():
 
     print("compile cache: %s" % enable_compile_cache(), flush=True)
     clock = CompileClock()
-    phases = [resnet50_train, transformer_train, resnet50_serve]
+    phases = [resnet50_train, transformer_train, moe_experts_check,
+              resnet50_serve]
     if len(devices) >= 4:
         phases.append(multichip)
     for phase in phases:

@@ -12,7 +12,11 @@ Activations BETWEEN ops also stay bf16 by default
 so the elementwise/norm chains read and write half the bytes (HBM
 bandwidth is the usual TPU bottleneck).  What remains f32 regardless:
 parameters + optimizer state (masters), all reduction statistics
-(batch/layer norm mean/var), losses, and everything crossing the
+(batch/layer norm mean/var), losses, the router of an expert layer
+(`moe_router`: its product at the highest precision, softmax, top-k and
+both auxiliary losses, since a rounded logit sends a token to another
+expert; the experts' own products are bf16 with f32 accumulation and
+their weight gradients add up in f32), and everything crossing the
 feed/fetch boundary.  Set FLAGS_amp_bf16_act=0 for the conservative
 cast-back-to-f32 behaviour.
 """

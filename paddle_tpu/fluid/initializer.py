@@ -25,8 +25,15 @@ class Initializer:
     def __call__(self, var, block):
         raise NotImplementedError
 
-    def _fan_in_out(self, var):
+    def _fan_in_out(self, var, stacked=False):
         shape = var.shape
+        if stacked:
+            # [count, in, out]: `count` matrices side by side (the
+            # experts of a routed layer), each with its own fans
+            if len(shape) != 3:
+                raise ValueError("a stacked parameter is [count, in, out], "
+                                 "got shape %s" % (tuple(shape),))
+            return shape[1], shape[2]
         if len(shape) < 2:
             return (1, shape[0] if shape else 1)
         receptive = 1
@@ -75,14 +82,18 @@ class NormalInitializer(Initializer):
 
 
 class XavierInitializer(Initializer):
-    """reference: initializer.py XavierInitializer (Glorot & Bengio 2010)."""
+    """reference: initializer.py XavierInitializer (Glorot & Bengio 2010).
+    `stacked`: the parameter is [count, in, out], `count` matrices with
+    the fans of one, not a convolution's [out, in, width]."""
 
-    def __init__(self, uniform=True, fan_in=None, fan_out=None, seed=0):
+    def __init__(self, uniform=True, fan_in=None, fan_out=None, seed=0,
+                 stacked=False):
         self.uniform, self.fan_in, self.fan_out, self.seed = \
             uniform, fan_in, fan_out, seed
+        self.stacked = stacked
 
     def __call__(self, var, block):
-        fan_in, fan_out = self._fan_in_out(var)
+        fan_in, fan_out = self._fan_in_out(var, self.stacked)
         fan_in = self.fan_in if self.fan_in is not None else fan_in
         fan_out = self.fan_out if self.fan_out is not None else fan_out
         if self.uniform:

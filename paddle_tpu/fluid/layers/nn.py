@@ -28,7 +28,7 @@ __all__ = [
     "sequence_slice", "lod_reset", "edit_distance", "ctc_greedy_decoder",
     "sequence_concat", "beam_search", "beam_search_decode",
     "sequence_reverse", "sequence_unnest", "sequence_renest",
-    "flash_attention", "cached_attention", "rms_norm", "rope",
+    "flash_attention", "cached_attention", "rms_norm", "rope", "moe",
 ]
 
 
@@ -675,6 +675,63 @@ def rope(input, positions, num_heads, theta=10000.0, **kwargs):
                      attrs={"num_heads": int(num_heads),
                             "theta": float(theta)})
     return out
+
+
+def moe(input, num_experts, expert_size, top_k, router_attr=None,
+        gate_attr=None, up_attr=None, down_attr=None, name=None):
+    """A routed expert layer over `input` [..., hidden] (ops/moe.py): a
+    float32 router sends every token to its `top_k` of `num_experts`
+    gated-SiLU experts of width `expert_size`, each computed for it (no
+    capacity, nothing dropped), and the token adds them up weighted by
+    their router probabilities as they are.  The experts' weights are
+    three parameters, [num_experts, hidden, expert_size] twice and
+    [num_experts, expert_size, hidden], initialised with one expert's
+    fans.  Returns (out, lb_loss, z_loss, routing): the layer's output,
+    its load-balance and router z-loss (float32 [1] each, to be added
+    to the objective with their coefficients) and a dict of the
+    router's Variables "logits" [tokens, num_experts], "top_w" and
+    "top_idx" [tokens, top_k] and the experts' "counts" [num_experts],
+    the rows each expert was given.
+    """
+    helper = LayerHelper("moe", name=name)
+    hidden = int(input.shape[-1])
+    dtype = input.dtype
+
+    def param(attr, shape, init):
+        return helper.create_parameter(
+            attr or ParamAttr(), shape=shape, dtype=dtype,
+            default_initializer=init)
+
+    w_router = param(router_attr, [hidden, num_experts], Xavier())
+    stacked = Xavier(stacked=True)
+    w_gate = param(gate_attr, [num_experts, hidden, expert_size], stacked)
+    w_up = param(up_attr, [num_experts, hidden, expert_size], stacked)
+    w_down = param(down_attr, [num_experts, expert_size, hidden], stacked)
+
+    def tmp(dtype, stop_gradient=False):
+        return helper.create_tmp_variable(dtype, stop_gradient=stop_gradient)
+
+    logits, top_w = tmp("float32"), tmp("float32")
+    top_idx = tmp("int32", stop_gradient=True)
+    lb_loss, z_loss = tmp("float32"), tmp("float32")
+    helper.append_op(
+        type="moe_router", inputs={"X": [input], "W": [w_router]},
+        outputs={"Logits": [logits], "TopW": [top_w], "TopIdx": [top_idx],
+                 "LbLoss": [lb_loss], "ZLoss": [z_loss]},
+        attrs={"top_k": int(top_k)})
+    out = tmp(dtype)
+    kept = {slot: tmp("int32" if slot in ("RowSlot", "TokenRow", "Counts")
+                      else dtype, stop_gradient=True)
+            for slot in ("Xs", "Gate", "Up", "RowSlot", "TokenRow",
+                         "Counts")}
+    helper.append_op(
+        type="moe_experts",
+        inputs={"X": [input], "TopW": [top_w], "TopIdx": [top_idx],
+                "WGate": [w_gate], "WUp": [w_up], "WDown": [w_down]},
+        outputs=dict({"Out": [out]}, **{s: [v] for s, v in kept.items()}))
+    return out, lb_loss, z_loss, {"logits": logits, "top_w": top_w,
+                                  "top_idx": top_idx,
+                                  "counts": kept["Counts"]}
 
 
 def lrn(input, n=5, k=1.0, alpha=1e-4, beta=0.75, **kwargs):

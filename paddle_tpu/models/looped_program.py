@@ -18,6 +18,8 @@ are in `models/reference/ouro.py`, which the tests hold this to.
 
 from .. import fluid
 from ..fluid.param_attr import ParamAttr
+from .decoder_block import attention as _attention, linear as _linear, \
+    norm as _norm
 
 __all__ = ["build_looped_program", "looped_param_names"]
 
@@ -38,26 +40,11 @@ def looped_param_names(n_layer):
     }
 
 
-def _linear(x, size, name):
-    return fluid.layers.fc(input=x, size=size, num_flatten_dims=2,
-                           param_attr=ParamAttr(name=name), bias_attr=False)
-
-
-def _norm(x, eps, name):
-    return fluid.layers.rms_norm(x, epsilon=eps,
-                                 param_attr=ParamAttr(name=name))
-
-
 def _block(x, positions, names, n_head, d_head, d_ff, eps, theta):
     d_model = x.shape[-1]
-    h = _norm(x, eps, names["norm_1"])
-    q, k, v = (_linear(h, n_head * d_head, names[w])
-               for w in ("wq", "wk", "wv"))
-    o = fluid.layers.flash_attention(
-        fluid.layers.rope(q, positions, n_head, theta),
-        fluid.layers.rope(k, positions, n_head, theta), v,
-        num_heads=n_head, causal=True)
-    x = x + _norm(_linear(o, d_model, names["wo"]), eps, names["norm_2"])
+    a = _attention(_norm(x, eps, names["norm_1"]), positions, names, n_head,
+                   d_head, theta)
+    x = x + _norm(a, eps, names["norm_2"])
 
     h = _norm(x, eps, names["norm_3"])
     m = fluid.layers.swish(_linear(h, d_ff, names["w_gate"])) \

@@ -35,7 +35,8 @@ from . import trace as trace_mod
 __all__ = ["on_executor_run", "on_jit_trace",
            "on_flash_attention_lowering",
            "on_flash_attention_bwd_lowering",
-           "on_flash_attention_grad_lowering", "on_shared_parameter_uses",
+           "on_flash_attention_grad_lowering", "on_moe_lowering",
+           "on_moe_gmm_lowering", "on_shared_parameter_uses",
            "on_transfer",
            "on_feed_seconds", "on_program_cache_evict",
            "jit_trace_count", "transfer_bytes", "step", "set_gauge",
@@ -113,6 +114,29 @@ def on_flash_attention_grad_lowering(residuals):
                    "residuals came from",
                    labelnames=("residuals",)) \
           .labels(residuals=residuals).inc()
+
+
+def on_moe_lowering(experts, top_k):
+    """A routed expert layer (`moe_experts`, ops/moe.py) was traced
+    into a program: one count per op instance a lowered program
+    holds."""
+    _reg().counter("moe_lowerings_total",
+                   "routed expert layers lowered, by experts and experts "
+                   "a token",
+                   labelnames=("experts", "top_k")) \
+          .labels(experts=experts, top_k=top_k).inc()
+
+
+def on_moe_gmm_lowering(kernel, block_m, block_n, block_k):
+    """One of the grouped-product kernels ("fwd", "dx", "dw":
+    kernels/grouped_matmul.py) was traced into a program, with the
+    tiling chosen for it: one count per kernel instance a lowered
+    program holds."""
+    _reg().counter("moe_gmm_lowerings_total",
+                   "grouped-product kernels lowered, by kernel and tiling",
+                   labelnames=("kernel", "block_m", "block_n", "block_k")) \
+          .labels(kernel=kernel, block_m=block_m, block_n=block_n,
+                  block_k=block_k).inc()
 
 
 def on_shared_parameter_uses(program, uses):

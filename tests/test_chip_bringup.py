@@ -90,6 +90,61 @@ def test_the_op_and_its_gradient_lower_one_forward_kernel_for_tpu(shape,
     assert explicit.count("tpu_custom_call") == 2
 
 
+@pytest.mark.parametrize("kernel,lhs,rhs", [
+    ("fwd", (32768, 2048), (64, 2048, 1024)),
+    ("fwd", (32768, 1024), (64, 1024, 2048)),
+    ("dx", (32768, 1024), (64, 2048, 1024)),
+    ("dx", (32768, 2048), (64, 1024, 2048)),
+    ("dw", (32768, 2048), (32768, 1024)),
+    ("dw", (32768, 1024), (32768, 2048)),
+])
+def test_grouped_products_lower_for_tpu(kernel, lhs, rhs):
+    """The grouped-product kernels at the shapes of olmoe-train-4k (32768
+    routed rows, 64 experts, 2048 <-> 1024, bfloat16), lowered for the
+    TPU from this CPU host: one Mosaic kernel, named with the blocks it
+    chose from the shapes."""
+    from paddle_tpu.kernels import grouped_matmul
+
+    fn = {"fwd": grouped_matmul.gmm, "dx": grouped_matmul.gmm_dx,
+          "dw": grouped_matmul.gmm_dw}[kernel]
+    module = jax.export.export(jax.jit(fn), platforms=["tpu"])(
+        jax.ShapeDtypeStruct(lhs, jnp.bfloat16),
+        jax.ShapeDtypeStruct(rhs, jnp.bfloat16),
+        jax.ShapeDtypeStruct((64,), jnp.int32)).mlir_module()
+    assert module.count("tpu_custom_call") == 1
+    assert 'kernel_name = "moe_gmm_%s_m256_' % kernel in module
+
+
+def test_the_expert_op_and_its_gradient_lower_nine_products_for_tpu():
+    """`moe_experts` and its explicit gradient at the cell's shapes as
+    one TPU program: the three forward products once, three dx and
+    three dw, and no forward product a second time."""
+    from paddle_tpu.ops import registry
+
+    info = registry.get_op_info("moe_experts")
+    n, k, e, d, f = 4096, 8, 64, 2048, 1024
+    bf16 = jnp.bfloat16
+    ins = {"X": [jax.ShapeDtypeStruct((1, n, d), bf16)],
+           "TopW": [jax.ShapeDtypeStruct((n, k), jnp.float32)],
+           "TopIdx": [jax.ShapeDtypeStruct((n, k), jnp.int32)],
+           "WGate": [jax.ShapeDtypeStruct((e, d, f), jnp.float32)],
+           "WUp": [jax.ShapeDtypeStruct((e, d, f), jnp.float32)],
+           "WDown": [jax.ShapeDtypeStruct((e, f, d), jnp.float32)]}
+
+    def step(ins, d_out):
+        outs = info.kernel(None, ins, {})
+        grad_ins = dict(ins, **{"OG@Out": [d_out]})
+        grad_ins.update({"O@" + slot: v for slot, v in outs.items()})
+        return outs["Out"], info.grad_kernel(None, grad_ins, {})
+
+    with fluid.amp.bf16_guard():
+        module = jax.export.export(jax.jit(step), platforms=["tpu"])(
+            ins, ins["X"][0]).mlir_module()
+    assert [module.count('kernel_name = "moe_gmm_%s_' % kernel)
+            for kernel in ("fwd", "dx", "dw")] == [3, 3, 3]
+    assert module.count("tpu_custom_call") == 9
+
+
 def test_flash_attention_refuses_a_ragged_block():
     """A sequence its block does not divide raises with the shape in the
     message; the block no longer shrinks toward 1 without saying so."""
