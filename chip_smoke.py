@@ -133,13 +133,28 @@ def resnet50_train(batch=128, image_size=224, class_dim=1000):
     check_on("functional state", state.values(), devices)
 
 
-def flash_kernel_check(shape, causal):
+def flash_kernel_check(shape, causal, num_heads=None):
     """flash_attention against reference_attention on one input:
-    outputs and all three gradients."""
+    outputs and all three gradients.  `shape` is
+    [batch, heads, seq, dim], or with `num_heads`
+    [batch, seq, num_heads * dim], where the kernels pick the heads
+    themselves."""
     import jax
     import jax.numpy as jnp
-    from paddle_tpu.kernels.flash_attention import (flash_attention,
-                                                    reference_attention)
+    from paddle_tpu.kernels.flash_attention import (
+        flash_attention, flash_attention_with_lse, merge_heads,
+        reference_attention, split_heads)
+
+    flash, dense = flash_attention, reference_attention
+    if num_heads is not None:
+        def flash(q, k, v, sm_scale, causal):
+            return flash_attention_with_lse(q, k, v, sm_scale, causal,
+                                            num_heads=num_heads)[0]
+
+        def dense(q, k, v, sm_scale, causal):
+            return merge_heads(reference_attention(
+                *(split_heads(x, num_heads) for x in (q, k, v)), sm_scale,
+                causal))
 
     q, k, v, do = (
         jax.random.normal(key, shape, jnp.float32).astype(jnp.bfloat16)
@@ -157,8 +172,7 @@ def flash_kernel_check(shape, causal):
 
     worst = 0.0
     for name, got, want in zip(("o", "dq", "dk", "dv"),
-                               run(flash_attention),
-                               run(reference_attention)):
+                               run(flash), run(dense)):
         got = np.asarray(got, np.float32)
         want = np.asarray(want, np.float32)
         check(got.shape == want.shape and np.isfinite(got).all(),
@@ -169,8 +183,9 @@ def flash_kernel_check(shape, causal):
               "flash %s %s causal=%s: off the reference by %.4f of its "
               "largest value" % (name, shape, causal, err))
         worst = max(worst, err)
-    print("  flash_attention %s causal=%s: within %.4f of the reference"
-          % (shape, causal, worst), flush=True)
+    print("  flash_attention %s%s causal=%s: within %.4f of the reference"
+          % (shape, "" if num_heads is None else " of %d heads" % num_heads,
+             causal, worst), flush=True)
 
 
 def transformer_train(batch=16, seq_len=512, d_model=512, n_layer=6,
@@ -211,6 +226,9 @@ def transformer_train(batch=16, seq_len=512, d_model=512, n_layer=6,
     for shape in kernel_shapes:
         for causal in (False, True):
             flash_kernel_check(shape, causal)
+    # heads of 64 side by side as a projection writes them, two a grid
+    # step: what the `flash_attention` op hands the kernels
+    flash_kernel_check((2, 1024, 512), True, num_heads=8)
 
 
 def moe_experts_check(tokens=4096, hidden=2048, experts=64, width=1024,

@@ -25,11 +25,34 @@ backward) fits the budget beside a chunk it stays resident across the
 head's blocks and the chunk loop stops at the causal diagonal; where it
 does not, the grid's innermost axis walks it one chunk a step.
 
+Two layouts, one set of kernel bodies.  `flash_attention`,
+`flash_attention_with_lse` and `_bwd` take q, k, v (and do) as
+[batch, heads, seq, dim], one head a grid step: for the callers that
+hold heads apart for a reason (ring attention's sequence shards,
+ulysses' head-sharded all-to-all, the functional transformer).  Given
+`num_heads`, the last two take them as [batch, seq, heads * dim], as a
+projection writes them and the `flash_attention` op (ops/attention.py)
+holds them, and write o, dq, dk and dv so: the BlockSpecs pick the
+head, so nothing is transposed around the kernels.  A grid step's lane
+block is max(dim, 128): one head where dim is a multiple of 128, and
+128 // dim heads side by side in one lane-dense block where dim
+divides 128 (two at GPT-2's 64; all the heads of a model narrower than
+128 in all), every one of them at each turn of the chunk loop, which
+gives the scheduler one head's products to run beside another's
+exponentials.  The transposed scratch ([d, positions]) gives head h the
+rows [h * dim, (h + 1) * dim), a sublane slice; a product that
+contracts over the lanes takes one operand with the other heads' lanes
+zeroed, which adds zeros to the sum and costs the MXU what a 64-deep
+pass costs, a 128-deep one.  Any other width (96, 80, 192) is split
+into [batch, heads, seq, dim] around the kernels, as every width once
+was.  The row statistics are float32 [batch, heads, seq] in both layouts.
+
 The kernels compile through Mosaic when lowered for the TPU and run
 under pallas interpret mode when lowered for the CPU (tests, dry runs);
 any other platform is refused at lowering.
 """
 
+import collections
 import functools
 import itertools
 
@@ -80,14 +103,96 @@ def _pad_to_lanes(n):
     return -(-n // _LANES) * _LANES
 
 
+def split_heads(x, num_heads):
+    """[batch, seq, heads * dim] -> [batch, heads, seq, dim]."""
+    b, t, d = x.shape
+    return x.reshape(b, t, num_heads, d // num_heads).transpose(0, 2, 1, 3)
+
+
+def merge_heads(x):
+    """[batch, heads, seq, dim] -> [batch, seq, heads * dim]."""
+    b, h, t, dh = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b, t, h * dh)
+
+
+class _Call(collections.namedtuple("_Call", "merged batch heads tq tk d g")):
+    """What the kernels see of one call: operands [batch, heads, seq, d]
+    (`merged` false) or [batch, seq, heads * d], and `g`, the heads one
+    grid step holds: 1, or of merged operands the 128 // d that share a
+    lane block (all of them where their whole width is one block), or
+    None where no lane block holds whole heads."""
+
+    @classmethod
+    def of(cls, q_shape, k_shape, num_heads):
+        if num_heads is None:
+            (b, h, tq, d), tk = q_shape, k_shape[2]
+            return cls(False, b, h, tq, tk, d, 1)
+        (b, tq, width), tk = q_shape, k_shape[1]
+        d = width // num_heads
+        if d % _LANES == 0:
+            g = 1
+        elif _LANES % d == 0 and num_heads % (_LANES // d) == 0:
+            g = _LANES // d
+        elif width <= _LANES:
+            g = num_heads
+        else:
+            g = None
+        return cls(True, b, num_heads, tq, tk, d, g)
+
+    @property
+    def lanes(self):
+        return self.g * self.d
+
+    @property
+    def steps(self):
+        """The grid's two leading axes."""
+        return (self.batch, self.heads // self.g)
+
+    @property
+    def step_shapes(self):
+        """q's and k's shapes with a grid step's heads as one head, for
+        the block choosers: they size VMEM by what a step holds."""
+        return tuple(self.steps + (t, self.lanes) for t in (self.tq, self.tk))
+
+    @property
+    def suffix(self):
+        """What the kernels' names end in: the heads a step holds of
+        merged operands, nothing for [batch, heads, seq, d]."""
+        return "_h%d" % self.g if self.merged else ""
+
+    def rows(self, n, index):
+        """BlockSpec of the [n, lanes] tile of q, k, v, o, do or a
+        gradient that holds rows index(*steps) * n .. of the grid
+        step's heads; `steps` are the grid indices after (batch,
+        head)."""
+        if self.merged:
+            return pl.BlockSpec((None, n, self.lanes),
+                                lambda b, h, *steps: (b, index(*steps), h))
+        return pl.BlockSpec((None, None, n, self.lanes),
+                            lambda b, h, *steps: (b, h, index(*steps), 0))
+
+    def stats(self, n, index):
+        """BlockSpec of the [g, n] float32 rows of per-query statistics
+        of the grid step's heads, from [batch, heads / g, g, seq]."""
+        return pl.BlockSpec((None, None, self.g, n),
+                            lambda b, h, *steps: (b, h, 0, index(*steps)))
+
+    @property
+    def stats_shape(self):
+        """[batch, heads, Tq] statistics as `stats` blocks them."""
+        return self.steps + (self.g, self.tq)
+
+
 def _step_bytes(bq, bk, kv_rows, d, itemsize):
-    """VMEM bytes one grid step holds: the q and o tiles [bq, d] and
-    the K and V blocks [kv_rows, d], each double-buffered by the
-    pipeline, their minor dimension padded to 128 lanes; V transposed
-    [d, kv_rows]; the float32 accumulator, its rows padded likewise;
-    the m and l rows [1, bq] (a row pads to 8 sublanes, and they are
-    double-buffered too); one chunk's scores and probabilities [bk, bq]
-    in float32 and the probabilities cast for the second product."""
+    """VMEM bytes one grid step holds, `d` the width of its heads (of
+    one head, or of those that share a lane block): the q and o tiles
+    [bq, d] and the K and V blocks [kv_rows, d], each double-buffered
+    by the pipeline, their minor dimension padded to 128 lanes; V
+    transposed [d, kv_rows]; the float32 accumulator, its rows padded
+    likewise; the m and l rows [1, bq] a head (they pad to 8 sublanes,
+    and they are double-buffered too); one chunk's scores and
+    probabilities [bk, bq] in float32 and the probabilities cast for
+    the second product."""
     lanes = _pad_to_lanes(d)
     tiles = 2 * itemsize * lanes * (2 * bq + 2 * kv_rows)
     scratch = itemsize * d * kv_rows + 4 * lanes * bq
@@ -99,12 +204,13 @@ def _step_bytes(bq, bk, kv_rows, d, itemsize):
 def _choose_blocks(q_shape, k_shape, itemsize, causal, block_q=None,
                    block_k=None):
     """(block_q, block_k, kv_resident) for one call, from what the
-    kernel sees: the sequence lengths, the head size, the item size and
-    whether the mask is causal.  A block the caller names is kept as it
-    is; what is chosen is the pair that folds most scores at a time
-    under the VMEM budget, with half its block_k if all of one head's K
-    and V then fit beside the fold: `kv_resident` says a grid step
-    holds them all, not one block_k chunk of them."""
+    kernel sees: the sequence lengths, the width of a grid step's
+    heads, the item size and whether the mask is causal.  A block the
+    caller names is kept as it is; what is chosen is the pair that
+    folds most scores at a time under the VMEM budget, with half its
+    block_k if all of one head's K and V then fit beside the fold:
+    `kv_resident` says a grid step holds them all, not one block_k
+    chunk of them."""
     tq, d = q_shape[2], q_shape[3]
     tk = k_shape[2]
     qs = (_candidates(tq, causal) if block_q is None
@@ -138,6 +244,30 @@ def _fold_chunks(fold, first, last, masked):
     lax.fori_loop(first, last, lambda c, _: fold(c, masked), None)
 
 
+def _only_head(x, h, d):
+    """`x` [rows, lanes] with the lanes of every head but the h-th
+    (lanes [h * d, (h + 1) * d)) zeroed, so that a product contracting
+    `x` with all the lanes of another tile is head h's product alone;
+    `x` itself where its lanes are one head's."""
+    if x.shape[1] == d:
+        return x
+
+    def own(shape):
+        lane = lax.broadcasted_iota(jnp.int32, shape, 1)
+        return lax.bitwise_and(lax.ge(lane, h * d), lax.lt(lane, (h + 1) * d))
+
+    if x.dtype.itemsize < 4 and x.shape[0] * x.dtype.itemsize % 4 == 0:
+        # a 16-bit tile: one AND a vreg on its packed 32-bit words (two
+        # rows a word, the same lane); a select would unpack it to
+        # float32 and pack it again (PERF.md section 6, PR 30)
+        words = pltpu.bitcast(x, jnp.uint32)
+        keep = lax.select(own(words.shape),
+                          lax.full(words.shape, 0xFFFFFFFF, jnp.uint32),
+                          lax.full(words.shape, 0, jnp.uint32))
+        return pltpu.bitcast(lax.bitwise_and(words, keep), x.dtype)
+    return lax.select(own(x.shape), x, lax.full_like(x, 0))
+
+
 def _on_platform(call, *args):
     """The Mosaic kernel where the computation is lowered for the TPU,
     the Pallas interpreter where for the CPU.  Chosen by the platform
@@ -150,13 +280,14 @@ def _on_platform(call, *args):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_scr, vt_scr,
-                *, sm_scale, causal, q_offset, bk, resident):
-    """One (batch*head, q_block, kv_block) grid step: the K/V block in
-    VMEM (all of the head's keys, or one chunk of them) is folded, bk
-    keys at a time, into the running max and sum, which are the m and l
-    output blocks themselves, and into the float32 accumulator.  The
-    kv_block axis is innermost and sequential: the three are
-    initialised on its first step, and o is written on its last.
+                *, sm_scale, causal, q_offset, bk, resident, d):
+    """One (batch, heads, q_block, kv_block) grid step: the K/V block
+    in VMEM (all of the keys, or one chunk of them) is folded, bk keys
+    at a time and for every head of the step's, into the running max
+    and sum, which are the m and l output blocks themselves, and into
+    the float32 accumulator.  The kv_block axis is
+    innermost and sequential: the three are initialised on its first
+    step, and o is written on its last.
 
     The scores are held transposed, [keys, queries], and so are the
     accumulator and V, [d, positions].  The softmax's reductions then
@@ -166,13 +297,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_scr, vt_scr,
     lane a vreg and every reduction crosses the lanes.  V is transposed
     into scratch once a head where its keys are resident (the q_block
     axis is sequential for that), else once a step; o is transposed
-    back as it is written.
+    back as it is written.  Where the tiles hold several heads side by
+    side in their lanes, head h has rows [h * d, (h + 1) * d) of the
+    transposed scratch and row h of the statistics.
 
-    Written in lax primitives, not jnp: a step program holds this body
-    once per attention op and pass, and every jnp call or operator on a
+    Written in lax primitives, not jnp: every jnp call or operator on a
     tracer is a jitted function to trace besides."""
-    (bq, d), kv_rows = q_ref.shape, k_ref.shape[0]
-    i, j = pl.program_id(1), pl.program_id(2)
+    (bq, lanes), kv_rows = q_ref.shape, k_ref.shape[0]
+    i, j = pl.program_id(2), pl.program_id(3)
 
     def _transpose_v():
         vt_scr[...] = lax.transpose(v_ref[...], (1, 0))
@@ -196,30 +328,35 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_scr, vt_scr,
         if kv_rows == bk:
             # one chunk, read whole: a block that is the whole of a
             # ragged sequence has no aligned slice
-            k, vt = k_ref[...], vt_scr[...]
+            keys = slice(None)
         else:
             keys = pl.ds(pl.multiple_of(lax.mul(c, bk), bk), bk)
-            k, vt = k_ref[keys, :], vt_scr[:, keys]
-        s = lax.mul(_matmul(k, q_ref[...], 1), sm_scale)     # [bk, bq]
-        if masked:
-            # a query sees the keys at or before its own position
-            lead = lax.sub(lax.broadcasted_iota(jnp.int32, s.shape, 1),
-                           lax.broadcasted_iota(jnp.int32, s.shape, 0))
-            s = lax.select(
-                lax.ge(lead, lax.sub(lax.mul(c, bk), ahead)), s,
-                lax.full_like(s, NEG_INF))
-        m_prev = m_ref[...]                                  # [1, bq]
-        m_new = lax.max(m_prev,
-                        lax.expand_dims(lax.reduce_max(s, (0,)), (0,)))
-        alpha = lax.exp(lax.sub(m_prev, m_new))
-        p = lax.exp(lax.sub(s, m_new))
-        l_ref[...] = lax.add(
-            lax.mul(alpha, l_ref[...]),
-            lax.expand_dims(lax.reduce_sum(p, (0,)), (0,)))
-        acc_scr[:d] = lax.add(
-            lax.mul(alpha, acc_scr[:d]),
-            _matmul(vt, lax.convert_element_type(p, vt.dtype)))
-        m_ref[...] = m_new
+        k = k_ref[keys, :]
+        for h in range(lanes // d):
+            head, stat = slice(h * d, (h + 1) * d), slice(h, h + 1)
+            vt = vt_scr[head, keys]
+            s = lax.mul(_matmul(k, _only_head(q_ref[...], h, d), 1),
+                        sm_scale)                            # [bk, bq]
+            if masked:
+                # a query sees the keys at or before its own position
+                lead = lax.sub(
+                    lax.broadcasted_iota(jnp.int32, s.shape, 1),
+                    lax.broadcasted_iota(jnp.int32, s.shape, 0))
+                s = lax.select(
+                    lax.ge(lead, lax.sub(lax.mul(c, bk), ahead)), s,
+                    lax.full_like(s, NEG_INF))
+            m_prev = m_ref[stat, :]                          # [1, bq]
+            m_new = lax.max(
+                m_prev, lax.expand_dims(lax.reduce_max(s, (0,)), (0,)))
+            alpha = lax.exp(lax.sub(m_prev, m_new))
+            p = lax.exp(lax.sub(s, m_new))
+            l_ref[stat, :] = lax.add(
+                lax.mul(alpha, l_ref[stat, :]),
+                lax.expand_dims(lax.reduce_sum(p, (0,)), (0,)))
+            acc_scr[head] = lax.add(
+                lax.mul(alpha, acc_scr[head]),
+                _matmul(vt, lax.convert_element_type(p, vt.dtype)))
+            m_ref[stat, :] = m_new
 
     chunks = kv_rows // bk
     if causal:
@@ -235,92 +372,116 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_scr, vt_scr,
     else:
         _fold_chunks(_fold, 0, chunks, False)
 
-    @pl.when(lax.eq(j, lax.sub(pl.num_programs(2), 1)))
+    @pl.when(lax.eq(j, lax.sub(pl.num_programs(3), 1)))
     def _finish():
-        l = l_ref[...]
-        o = lax.div(acc_scr[...],
-                    lax.select(lax.gt(l, 0.0), l, lax.full_like(l, 1)))
+        for h in range(lanes // d):
+            head, l = slice(h * d, (h + 1) * d), l_ref[h:h + 1, :]
+            acc_scr[head] = lax.div(
+                acc_scr[head],
+                lax.select(lax.gt(l, 0.0), l, lax.full_like(l, 1)))
         o_ref[...] = lax.convert_element_type(
-            lax.transpose(o, (1, 0))[:, :d], o_ref.dtype)
+            lax.transpose(acc_scr[...], (1, 0))[:, :lanes], o_ref.dtype)
 
 
-def _fwd(q, k, v, sm_scale, causal, block_q, block_k, q_offset):
-    B, H, Tq, D = q.shape
-    Tk = k.shape[2]
-    bq, bk, resident = _choose_blocks(q.shape, k.shape, q.dtype.itemsize,
+def _fwd(q, k, v, sm_scale, causal, block_q, block_k, q_offset,
+         num_heads=None, split=False):
+    """o, laid out as q, and the float32 [batch, heads, Tq] row
+    statistics m and l, at blocks chosen here from what a grid step
+    holds.  With `num_heads` the operands are [batch, seq, heads * d];
+    where no lane block holds whole heads of that they are split into
+    [batch, heads, seq, d] around the call (`split` says this is that
+    inner call)."""
+    call = _Call.of(q.shape, k.shape, num_heads)
+    if call.g is None:
+        o, m, l = _fwd(*(split_heads(x, num_heads) for x in (q, k, v)),
+                       sm_scale, causal, block_q, block_k, q_offset,
+                       split=True)
+        return merge_heads(o), m, l
+    bq, bk, resident = _choose_blocks(*call.step_shapes, q.dtype.itemsize,
                                       causal, block_q, block_k)
-    kv_rows = Tk if resident else bk
-    telemetry.on_flash_attention_lowering(bq, bk, resident)
+    telemetry.on_flash_attention_lowering(
+        bq, bk, resident, "split" if split else call.g)
+    return _fwd_kernels(q, k, v, num_heads=num_heads, sm_scale=sm_scale,
+                        causal=causal, q_offset=q_offset, bq=bq, bk=bk,
+                        resident=resident)
 
-    def kv_index(b, i, j):
+
+@functools.partial(jax.jit, static_argnames=(
+    "num_heads", "sm_scale", "causal", "q_offset", "bq", "bk", "resident"))
+def _fwd_kernels(q, k, v, *, num_heads, sm_scale, causal, q_offset, bq, bk,
+                 resident):
+    """The forward kernel at blocks already chosen; under `jax.jit` for
+    the reason `_bwd_kernels` gives."""
+    call = _Call.of(q.shape, k.shape, num_heads)
+    kv_rows = call.tk if resident else bk
+
+    def kv_index(i, j):
         if causal:
             # a skipped block re-names the last visible one, so the
             # pipeline does not fetch what the kernel will not read
             last = lax.add(lax.mul(i, bq), q_offset + bq - 1)
             j = lax.min(j, lax.div(lax.max(last, 0), kv_rows))
-        return (b, j, 0)
+        return j
 
-    call = functools.partial(
+    def q_index(i, j):
+        return i
+
+    stats = jax.ShapeDtypeStruct(call.stats_shape, jnp.float32)
+    pallas_call = functools.partial(
         pl.pallas_call,
         functools.partial(_fwd_kernel, sm_scale=sm_scale, causal=causal,
-                          q_offset=q_offset, bk=bk, resident=resident),
-        grid=(B * H, Tq // bq, Tk // kv_rows),
-        in_specs=[
-            pl.BlockSpec((None, bq, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((None, kv_rows, D), kv_index),
-            pl.BlockSpec((None, kv_rows, D), kv_index),
-        ],
-        out_specs=[
-            pl.BlockSpec((None, bq, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((None, 1, bq), lambda b, i, j: (b, 0, i)),
-            pl.BlockSpec((None, 1, bq), lambda b, i, j: (b, 0, i)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B * H, Tq, D), q.dtype),
-            jax.ShapeDtypeStruct((B * H, 1, Tq), jnp.float32),
-            jax.ShapeDtypeStruct((B * H, 1, Tq), jnp.float32),
-        ],
+                          q_offset=q_offset, bk=bk, resident=resident,
+                          d=call.d),
+        grid=call.steps + (call.tq // bq, call.tk // kv_rows),
+        in_specs=[call.rows(bq, q_index), call.rows(kv_rows, kv_index),
+                  call.rows(kv_rows, kv_index)],
+        out_specs=[call.rows(bq, q_index), call.stats(bq, q_index),
+                   call.stats(bq, q_index)],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype), stats, stats],
         scratch_shapes=[
-            # the accumulator [d, bq], its rows padded to 128 so that
-            # the last step can transpose it; V transposed
-            pltpu.VMEM((_pad_to_lanes(D), bq), jnp.float32),
-            pltpu.VMEM((D, kv_rows), v.dtype),
+            # the accumulator [lanes, bq], its rows padded to 128 so
+            # that the last step can transpose it; V transposed
+            pltpu.VMEM((_pad_to_lanes(call.lanes), bq), jnp.float32),
+            pltpu.VMEM((call.lanes, kv_rows), v.dtype),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=(
-                "parallel", "arbitrary" if resident else "parallel",
-                "arbitrary")),
+                "parallel", "parallel",
+                "arbitrary" if resident else "parallel", "arbitrary")),
         # the trace shows which tiling ran; readers match the prefix
-        name="flash_attention_fwd_q%d_k%d%s"
-             % (bq, bk, "_kvres" if resident else ""),
+        name="flash_attention_fwd_q%d_k%d%s%s"
+             % (bq, bk, "_kvres" if resident else "", call.suffix),
     )
-    o, m, l = _on_platform(
-        call, q.reshape(B * H, Tq, D), k.reshape(B * H, Tk, D),
-        v.reshape(B * H, Tk, D))
-    return (o.reshape(B, H, Tq, D), m.reshape(B, H, Tq),
-            l.reshape(B, H, Tq))
+    o, m, l = _on_platform(pallas_call, q, k, v)
+    rows = (call.batch, call.heads, call.tq)
+    return o, m.reshape(rows), l.reshape(rows)
 
 
 def _flash_fwd_rule(q, k, v, sm_scale, causal, block_q, block_k,
-                    q_offset):
+                    q_offset, num_heads):
     if sm_scale is None:
-        sm_scale = q.shape[-1] ** -0.5
-    o, m, l = _fwd(q, k, v, sm_scale, causal, block_q, block_k, q_offset)
+        sm_scale = _Call.of(q.shape, k.shape, num_heads).d ** -0.5
+    o, m, l = _fwd(q, k, v, sm_scale, causal, block_q, block_k, q_offset,
+                   num_heads)
     # a probability is exp(s - lse): of the two statistics the backward
     # reads only their log-sum-exp; a row that saw no key has l = 0
     lse = m + jnp.log(jnp.where(l > 0, l, 1.0))
     return (o, lse), (q, k, v, o, lse)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def flash_attention_with_lse(q, k, v, sm_scale=None, causal=False,
-                             block_q=None, block_k=None, q_offset=0):
-    """`flash_attention` and, beside its result, the float32 [B, H, Tq]
-    log-sum-exp of every row of scores: what a caller that writes its
-    own gradient (`ops/attention.py`) keeps for `_bwd`, so that its
-    backward pass need not run the forward kernel again."""
+                             block_q=None, block_k=None, q_offset=0,
+                             num_heads=None):
+    """`flash_attention` and, beside its result, the float32
+    [batch, heads, Tq] log-sum-exp of every row of scores: what a
+    caller that writes its own gradient (`ops/attention.py`) keeps for
+    `_bwd`, so that its backward pass need not run the forward kernel
+    again.  q, k, v: [batch, heads, seq, dim], or with `num_heads`
+    [batch, seq, num_heads * dim], and the result laid out as they
+    are."""
     return _flash_fwd_rule(q, k, v, sm_scale, causal, block_q, block_k,
-                           q_offset)[0]
+                           q_offset, num_heads)[0]
 
 
 def flash_attention(q, k, v, sm_scale=None, causal=False, block_q=None,
@@ -334,40 +495,42 @@ def flash_attention(q, k, v, sm_scale=None, causal=False, block_q=None,
                                     block_k, q_offset)[0]
 
 
-def _bwd_step_bytes(bq, bk, d, itemsize, tq=None):
-    """VMEM bytes one grid step of the backward holds.  Every kernel
-    holds four [bk, bq] float32 chunks (scores, probabilities, dp, ds)
-    and the casts of two of them, and the lse and delta rows (padded
-    and double-buffered as `_step_bytes` says).  With `tq` the one
-    kernel that holds all tq queries of a head: their q, do and dq
-    [tq, d] and the K, V, dk and dv tiles [bk, d], double-buffered;
-    dq^T in float32 [d, tq], its rows padded to 128; K transposed
-    [d, bk]; two float32 accumulators [bk, d].  Without, the larger of
-    the two kernels that walk: dK/dV holds the tiles of q, do, K, V,
-    dk and dv and two accumulators, dQ those of q, do, dq, K and V, K
-    transposed and one accumulator."""
+def _bwd_step_bytes(bq, bk, d, itemsize, tq=None, heads=1):
+    """VMEM bytes one grid step of the backward holds, `d` the width of
+    its `heads` heads together.  Every kernel holds four [bk, bq]
+    float32 chunks (scores, probabilities, dp, ds) and the casts of two
+    of them, and the lse and delta rows (padded and double-buffered as
+    `_step_bytes` says).  With `tq` the one kernel that holds all tq
+    queries of a head: their q, do and dq [tq, d] and the K, V, dk and
+    dv tiles [bk, d], double-buffered; dq^T in float32 [d, tq], its
+    rows padded to 128; K transposed [d, bk]; two float32 accumulators
+    [bk, d] a head.  Without, the larger of the two kernels that walk:
+    dK/dV holds the tiles of q, do, K, V, dk and dv and two
+    accumulators a head, dQ those of q, do, dq, K and V, K transposed
+    and one accumulator."""
     lanes = _pad_to_lanes(d)
     chunk = bq * bk * (4 * 4 + 2 * itemsize)
+    accumulators = heads * 2 * 4 * lanes * bk
     if tq is not None:
         return (chunk + 2 * itemsize * lanes * (3 * tq + 4 * bk)
-                + 4 * lanes * tq + itemsize * d * bk + 2 * 4 * lanes * bk
+                + 4 * lanes * tq + itemsize * d * bk + accumulators
                 + 2 * 2 * 8 * tq * 4)
-    dkv = 2 * itemsize * lanes * (2 * bq + 4 * bk) + 2 * 4 * lanes * bk
+    dkv = 2 * itemsize * lanes * (2 * bq + 4 * bk) + accumulators
     dq = (2 * itemsize * lanes * (3 * bq + 2 * bk) + itemsize * d * bk
           + 4 * lanes * bq)
     return chunk + max(dkv, dq) + 2 * 2 * 8 * bq * 4
 
 
 def _choose_bwd_blocks(q_shape, k_shape, itemsize, causal, block_q=None,
-                       block_k=None):
+                       block_k=None, heads=1):
     """(block_q, block_k, one_kernel) for the backward of one call,
-    from what `_choose_blocks` reads: a named block is kept, and the
-    chosen pair is the one that holds most scores at a time under the
-    VMEM budget (the squarer of two that hold as many), first among
-    the pairs that leave room for all of a head's queries and its dq:
-    `one_kernel` says one kernel then makes dq, dk and dv together, and
-    not one kernel dk and dv and another dq, each walking the other
-    side a block a grid step."""
+    from what `_choose_blocks` reads and the `heads` a grid step holds:
+    a named block is kept, and the chosen pair is the one that holds
+    most scores at a time under the VMEM budget (the squarer of two
+    that hold as many), first among the pairs that leave room for all
+    of a head's queries and its dq: `one_kernel` says one kernel then
+    makes dq, dk and dv together, and not one kernel dk and dv and
+    another dq, each walking the other side a block a grid step."""
     tq, d = q_shape[2], q_shape[3]
     tk = k_shape[2]
     qs = (_candidates(tq, causal) if block_q is None
@@ -380,7 +543,8 @@ def _choose_bwd_blocks(q_shape, k_shape, itemsize, causal, block_q=None,
     for one_kernel in (True, False):
         for bq, bk in pairs:
             if _bwd_step_bytes(bq, bk, d, itemsize,
-                               tq if one_kernel else None) <= _VMEM_BUDGET:
+                               tq if one_kernel else None,
+                               heads) <= _VMEM_BUDGET:
                 return bq, bk, one_kernel
     if named:
         return pairs[0] + (False,)
@@ -405,12 +569,14 @@ def _bwd_matmul(a, b, rhs_contracts, widen):
 
 
 def _bwd_chunk(q, k, v, do, lse, delta, sm_scale, behind, widen):
-    """Probabilities and ds of one chunk, both [keys, queries] float32:
-    p = exp(s - lse), ds = p * (dp - delta), from the scores k q^T and
-    dp = v do^T; lse and delta are [1, queries] rows.  `behind` is None
-    where every query sees every key of the chunk, else how far the
-    chunk's first key lies ahead of its first query: a query sees the
-    keys at or before its own position, and p is 0 elsewhere."""
+    """Probabilities and ds of one chunk of one head, both
+    [keys, queries] float32: p = exp(s - lse), ds = p * (dp - delta),
+    from the scores k q^T and dp = v do^T; lse and delta are
+    [1, queries] rows.  Where the tiles hold several heads, k and v
+    come with the other heads' lanes zeroed (`_only_head`).  `behind`
+    is None where every query sees every key of the chunk, else how far
+    the chunk's first key lies ahead of its first query: a query sees
+    the keys at or before its own position, and p is 0 elsewhere."""
     s = lax.mul(_bwd_matmul(k, q, 1, widen), sm_scale)
     p = lax.exp(lax.sub(s, lse))
     if behind is not None:
@@ -421,19 +587,33 @@ def _bwd_chunk(q, k, v, do, lse, delta, sm_scale, behind, widen):
     return p, ds
 
 
-def _add_dkv(dk_scr, dv_scr, p, ds, q, do, widen):
-    dv_scr[...] = lax.add(dv_scr[...], _bwd_matmul(p, do, 0, widen))
-    dk_scr[...] = lax.add(dk_scr[...], _bwd_matmul(ds, q, 0, widen))
+def _add_dkv(dk_scr, dv_scr, h, p, ds, q, do, widen):
+    """Head h's p do and ds q into its own accumulators, all the lanes
+    wide: the lanes of the tile's other heads gather products nobody
+    reads, at no cost to the MXU, which pads a narrower result."""
+    dv_scr[h] = lax.add(dv_scr[h], _bwd_matmul(p, do, 0, widen))
+    dk_scr[h] = lax.add(dk_scr[h], _bwd_matmul(ds, q, 0, widen))
 
 
-def _write_dkv(dk_ref, dv_ref, dk_scr, dv_scr, sm_scale):
+def _side_by_side(scr, d):
+    """[rows, lanes] with head h's d lanes from `scr[h]`, of the
+    per-head accumulators [heads, rows, lanes]."""
+    out = scr[0]
+    lane = lax.broadcasted_iota(jnp.int32, out.shape, 1)
+    for h in range(1, scr.shape[0]):
+        out = lax.select(lax.ge(lane, h * d), scr[h], out)
+    return out
+
+
+def _write_dkv(dk_ref, dv_ref, dk_scr, dv_scr, sm_scale, d):
     dk_ref[...] = lax.convert_element_type(
-        lax.mul(dk_scr[...], sm_scale), dk_ref.dtype)
-    dv_ref[...] = lax.convert_element_type(dv_scr[...], dv_ref.dtype)
+        lax.mul(_side_by_side(dk_scr, d), sm_scale), dk_ref.dtype)
+    dv_ref[...] = lax.convert_element_type(_side_by_side(dv_scr, d),
+                                           dv_ref.dtype)
 
 
 def _write_dq(dq_ref, dqt_scr, sm_scale):
-    """dq from dq^T [d padded to 128, queries] in float32 scratch."""
+    """dq from dq^T [lanes padded to 128, queries] in float32 scratch."""
     dq_ref[...] = lax.convert_element_type(
         lax.transpose(lax.mul(dqt_scr[...], sm_scale),
                       (1, 0))[:, :dq_ref.shape[1]], dq_ref.dtype)
@@ -441,20 +621,21 @@ def _write_dq(dq_ref, dqt_scr, sm_scale):
 
 def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                 dk_ref, dv_ref, dqt_scr, kt_scr, dk_scr, dv_scr, *,
-                sm_scale, causal, q_offset, widen, bq):
-    """One (batch*head, k_block) grid step of the whole backward: the
-    step's bk keys meet the head's queries, all in VMEM, bq at a time,
-    from the first chunk that sees a key of the block: dv += p do,
-    dk += ds q, and dq^T += k^T ds into the head's float32 [d, Tq]
-    scratch, which the k_block axis (sequential) fills and its last
-    step scales, transposes back and writes.  Five products a chunk:
-    the scores and dp are made once for all three gradients.  Chunks
-    are [keys, queries] as in `_fwd_kernel`, so the row statistics are
-    lane-dense rows and every product takes its operands as they are
-    but dq's, which is why K is transposed (once a step) and dq
-    accumulates transposed."""
-    (bk, d), tq = k_ref.shape, q_ref.shape[0]
-    j = pl.program_id(1)
+                sm_scale, causal, q_offset, widen, bq, d):
+    """One (batch, heads, k_block) grid step of the whole backward: the
+    step's bk keys meet the queries, all in VMEM, bq at a time and for
+    every head of the step's, from the first chunk that sees a key of
+    the block: dv += p do, dk += ds q, and dq^T += k^T ds into
+    the float32 [lanes, Tq] scratch, which the k_block axis
+    (sequential) fills and its last step scales, transposes back and
+    writes.  Five products a chunk: the scores and dp are made once for
+    all three gradients.  Chunks are [keys, queries] as in
+    `_fwd_kernel`, so the row statistics are lane-dense rows and every
+    product takes its operands as they are but dq's, which is why K is
+    transposed (once a step) and dq accumulates transposed: head h has
+    rows [h * d, (h + 1) * d) of both."""
+    (bk, lanes), tq = k_ref.shape, q_ref.shape[0]
+    j = pl.program_id(2)
     kt_scr[...] = lax.transpose(k_ref[...], (1, 0))
     dk_scr[...] = lax.full(dk_scr.shape, 0, jnp.float32)
     dv_scr[...] = lax.full(dv_scr.shape, 0, jnp.float32)
@@ -474,14 +655,19 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         else:
             rows = pl.ds(pl.multiple_of(lax.mul(c, bq), bq), bq)
         q, do = q_ref[rows, :], do_ref[rows, :]
-        p, ds = _bwd_chunk(
-            q, k_ref[...], v_ref[...], do, lse_ref[:, rows],
-            delta_ref[:, rows], sm_scale,
-            lax.sub(behind, lax.mul(c, bq)) if masked else None, widen)
-        ds = lax.convert_element_type(ds, q.dtype)
-        _add_dkv(dk_scr, dv_scr, p, ds, q, do, widen)
-        dqt_scr[:d, rows] = lax.add(
-            dqt_scr[:d, rows], _bwd_matmul(kt_scr[...], ds, 0, widen))
+        for h in range(lanes // d):
+            head, stat = slice(h * d, (h + 1) * d), slice(h, h + 1)
+            p, ds = _bwd_chunk(
+                q, _only_head(k_ref[...], h, d),
+                _only_head(v_ref[...], h, d), do, lse_ref[stat, rows],
+                delta_ref[stat, rows], sm_scale,
+                lax.sub(behind, lax.mul(c, bq)) if masked else None,
+                widen)
+            ds = lax.convert_element_type(ds, q.dtype)
+            _add_dkv(dk_scr, dv_scr, h, p, ds, q, do, widen)
+            dqt_scr[head, rows] = lax.add(
+                dqt_scr[head, rows],
+                _bwd_matmul(kt_scr[head, :], ds, 0, widen))
 
     chunks = tq // bq
     if causal:
@@ -494,8 +680,8 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         _fold_chunks(_fold, whole, chunks, False)
     else:
         _fold_chunks(_fold, 0, chunks, False)
-    _write_dkv(dk_ref, dv_ref, dk_scr, dv_scr, sm_scale)
-    pl.when(lax.eq(j, lax.sub(pl.num_programs(1), 1)))(
+    _write_dkv(dk_ref, dv_ref, dk_scr, dv_scr, sm_scale, d)
+    pl.when(lax.eq(j, lax.sub(pl.num_programs(2), 1)))(
         lambda: _write_dq(dq_ref, dqt_scr, sm_scale))
 
 
@@ -514,14 +700,14 @@ def _fold_where_seen(fold, causal, behind, bq, bk):
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_scr, dv_scr, *, sm_scale, causal,
-                    q_offset, widen):
-    """One (batch*head, k_block, q_block) grid step of dK and dV where
-    a head's queries do not fit VMEM: dv += p do and dk += ds q in
-    float32 scratch for the step's bk keys and bq queries.  The q_block
-    axis is innermost and sequential: the scratch is zeroed on its
-    first step and written, dk scaled, on its last."""
-    bq, bk = q_ref.shape[0], k_ref.shape[0]
-    j, i = pl.program_id(1), pl.program_id(2)
+                    q_offset, widen, d):
+    """One (batch, heads, k_block, q_block) grid step of dK and dV
+    where a head's queries do not fit VMEM: dv += p do and dk += ds q
+    in float32 scratch for the step's bk keys and bq queries, a head at
+    a time.  The q_block axis is innermost and sequential: the scratch
+    is zeroed on its first step and written, dk scaled, on its last."""
+    (bq, lanes), bk = q_ref.shape, k_ref.shape[0]
+    j, i = pl.program_id(2), pl.program_id(3)
 
     @pl.when(lax.eq(i, 0))
     def _init():
@@ -532,25 +718,29 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     def _fold(masked):
         q, do = q_ref[...], do_ref[...]
-        p, ds = _bwd_chunk(q, k_ref[...], v_ref[...], do, lse_ref[...],
-                           delta_ref[...], sm_scale,
-                           behind if masked else None, widen)
-        _add_dkv(dk_scr, dv_scr, p, ds, q, do, widen)
+        for h in range(lanes // d):
+            p, ds = _bwd_chunk(
+                q, _only_head(k_ref[...], h, d),
+                _only_head(v_ref[...], h, d), do, lse_ref[h:h + 1, :],
+                delta_ref[h:h + 1, :], sm_scale,
+                behind if masked else None, widen)
+            _add_dkv(dk_scr, dv_scr, h, p, ds, q, do, widen)
 
     _fold_where_seen(_fold, causal, behind, bq, bk)
-    pl.when(lax.eq(i, lax.sub(pl.num_programs(2), 1)))(
-        lambda: _write_dkv(dk_ref, dv_ref, dk_scr, dv_scr, sm_scale))
+    pl.when(lax.eq(i, lax.sub(pl.num_programs(3), 1)))(
+        lambda: _write_dkv(dk_ref, dv_ref, dk_scr, dv_scr, sm_scale, d))
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   acc_scr, *, sm_scale, causal, q_offset, widen):
-    """One (batch*head, q_block, k_block) grid step of dQ where a
+                   acc_scr, *, sm_scale, causal, q_offset, widen, d):
+    """One (batch, heads, q_block, k_block) grid step of dQ where a
     head's keys do not fit VMEM, shaped as `_fwd_kernel`'s walk:
-    dq^T += k^T ds as [d, bq] float32 for the step's bq queries and bk
-    keys; the k_block axis is innermost and sequential, and its last
-    step scales dq, transposes it back and writes it."""
-    (bq, d), bk = q_ref.shape, k_ref.shape[0]
-    i, j = pl.program_id(1), pl.program_id(2)
+    dq^T += k^T ds as [lanes, bq] float32 for the step's bq queries and
+    bk keys, a head (d rows) at a time; the k_block axis is innermost
+    and sequential, and its last step scales dq, transposes it back and
+    writes it."""
+    (bq, lanes), bk = q_ref.shape, k_ref.shape[0]
+    i, j = pl.program_id(2), pl.program_id(3)
 
     @pl.when(lax.eq(j, 0))
     def _init():
@@ -560,21 +750,40 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
     def _fold(masked):
         k = k_ref[...]
-        _, ds = _bwd_chunk(q_ref[...], k, v_ref[...], do_ref[...],
-                           lse_ref[...], delta_ref[...], sm_scale,
-                           behind if masked else None, widen)
-        acc_scr[:d] = lax.add(
-            acc_scr[:d],
-            _bwd_matmul(lax.transpose(k, (1, 0)),
-                        lax.convert_element_type(ds, k.dtype), 0, widen))
+        kt = lax.transpose(k, (1, 0))
+        for h in range(lanes // d):
+            head = slice(h * d, (h + 1) * d)
+            _, ds = _bwd_chunk(
+                q_ref[...], _only_head(k, h, d),
+                _only_head(v_ref[...], h, d), do_ref[...],
+                lse_ref[h:h + 1, :], delta_ref[h:h + 1, :], sm_scale,
+                behind if masked else None, widen)
+            acc_scr[head] = lax.add(
+                acc_scr[head],
+                _bwd_matmul(lax.slice_in_dim(kt, h * d, (h + 1) * d),
+                            lax.convert_element_type(ds, k.dtype), 0,
+                            widen))
 
     _fold_where_seen(_fold, causal, behind, bq, bk)
-    pl.when(lax.eq(j, lax.sub(pl.num_programs(2), 1)))(
+    pl.when(lax.eq(j, lax.sub(pl.num_programs(3), 1)))(
         lambda: _write_dq(dq_ref, acc_scr, sm_scale))
 
 
-def _flash_bwd_rule(sm_scale, causal, block_q, block_k, q_offset, res,
-                    cotangents):
+def row_sums(do, o, num_heads=None):
+    """`delta`: the float32 [batch, heads, seq] sums over a head's
+    width of do * o, both laid out as the attention's operands are
+    ([batch, heads, seq, dim], or with `num_heads`
+    [batch, seq, num_heads * dim])."""
+    prod = do.astype(jnp.float32) * o.astype(jnp.float32)
+    if num_heads is None:
+        return jnp.sum(prod, axis=-1)
+    b, t, width = prod.shape
+    return jnp.sum(prod.reshape(b, t, num_heads, width // num_heads),
+                   axis=-1).transpose(0, 2, 1)
+
+
+def _flash_bwd_rule(sm_scale, causal, block_q, block_k, q_offset,
+                    num_heads, res, cotangents):
     """The flash-attention VJP as kernels that recompute the
     probabilities chunk by chunk from the forward's statistics:
     dv = p^T do; dp = do v^T; ds = p * (dp - rowsum(do * o));
@@ -590,34 +799,43 @@ def _flash_bwd_rule(sm_scale, causal, block_q, block_k, q_offset, res,
     q, k, v, o, lse = res
     do, dlse = cotangents
     with jax.named_scope(BWD_SCOPE):
-        delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                        axis=-1) - dlse
-        return _bwd(q, k, v, do, lse, delta, sm_scale, causal, block_q,
-                    block_k, q_offset)
+        return _bwd(q, k, v, do, lse, row_sums(do, o, num_heads) - dlse,
+                    sm_scale, causal, block_q, block_k, q_offset,
+                    num_heads)
 
 
 def _bwd(q, k, v, do, lse, delta, sm_scale, causal, block_q, block_k,
-         q_offset=0):
-    """dq, dk, dv of one call from the forward's row statistics `lse`
-    and the row sums `delta` of do * o, both float32 [B, H, Tq], at
-    blocks chosen here as the forward chooses its own: what the
-    custom VJP above and the `flash_attention` op's gradient
-    (`ops/attention.py`), which saved `lse`, both end in."""
+         q_offset=0, num_heads=None, split=False):
+    """dq, dk, dv of one call, laid out as q, k and v are (as `_fwd`
+    takes them), from the forward's row statistics `lse` and the row
+    sums `delta` of do * o (`row_sums`), both float32
+    [batch, heads, Tq], at blocks chosen here as the forward chooses
+    its own: what the custom VJP above and the `flash_attention` op's
+    gradient (`ops/attention.py`), which saved `lse`, both end in."""
+    call = _Call.of(q.shape, k.shape, num_heads)
+    if call.g is None:
+        grads = _bwd(*(split_heads(x, num_heads) for x in (q, k, v, do)),
+                     lse, delta, sm_scale, causal, block_q, block_k,
+                     q_offset, split=True)
+        return tuple(merge_heads(g) for g in grads)
     if sm_scale is None:
-        sm_scale = q.shape[-1] ** -0.5
+        sm_scale = call.d ** -0.5
     bq, bk, one_kernel = _choose_bwd_blocks(
-        q.shape, k.shape, q.dtype.itemsize, causal, block_q, block_k)
+        *call.step_shapes, q.dtype.itemsize, causal, block_q, block_k,
+        call.g)
     for kernel in (("dq_dkv",) if one_kernel else ("dkv", "dq")):
-        telemetry.on_flash_attention_bwd_lowering(kernel, bq, bk)
-    return _bwd_kernels(q, k, v, do, lse, delta, sm_scale=sm_scale,
-                        causal=causal, q_offset=q_offset, bq=bq, bk=bk,
-                        one_kernel=one_kernel)
+        telemetry.on_flash_attention_bwd_lowering(
+            kernel, bq, bk, "split" if split else call.g)
+    return _bwd_kernels(q, k, v, do, lse, delta, num_heads=num_heads,
+                        sm_scale=sm_scale, causal=causal, q_offset=q_offset,
+                        bq=bq, bk=bk, one_kernel=one_kernel)
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "sm_scale", "causal", "q_offset", "bq", "bk", "one_kernel"))
-def _bwd_kernels(q, k, v, do, lse, delta, *, sm_scale, causal, q_offset,
-                 bq, bk, one_kernel):
+    "num_heads", "sm_scale", "causal", "q_offset", "bq", "bk",
+    "one_kernel"))
+def _bwd_kernels(q, k, v, do, lse, delta, *, num_heads, sm_scale, causal,
+                 q_offset, bq, bk, one_kernel):
     """dq, dk, dv from the row statistics and do, at blocks already
     chosen.  Under `jax.jit` so that a program holding the same
     attention many times (one a layer) traces these kernels once, and
@@ -625,95 +843,84 @@ def _bwd_kernels(q, k, v, do, lse, delta, *, sm_scale, causal, q_offset,
     functional step share that trace: a kernel body is some hundred
     primitives and a step program would trace it twice an op (PERF.md
     section 6, PR 26)."""
-    B, H, Tq, D = q.shape
-    Tk = k.shape[2]
-    operands = [x.reshape(B * H, x.shape[2], D) for x in (q, k, v, do)] \
-        + [x.reshape(B * H, 1, Tq) for x in (lse, delta)]
-    name = "flash_attention_bwd%%s_q%d_k%d" % (bq, bk)
-    lanes = _pad_to_lanes(D)
+    call = _Call.of(q.shape, k.shape, num_heads)
+    tq, tk, lanes = call.tq, call.tk, call.lanes
+    operands = [q, k, v, do] \
+        + [x.reshape(call.stats_shape) for x in (lse, delta)]
+    name = "flash_attention_bwd%%s_q%d_k%d%s" % (bq, bk, call.suffix)
+    accumulator = pltpu.VMEM((call.g, bk, lanes), jnp.float32)
+    transposed = pltpu.VMEM((_pad_to_lanes(lanes), tq if one_kernel else bq),
+                            jnp.float32)
 
-    def call(kernel, interpret, **args):
+    def pallas_call(kernel, interpret, **args):
         return pl.pallas_call(
             functools.partial(kernel, sm_scale=sm_scale, causal=causal,
-                              q_offset=q_offset, widen=interpret),
+                              q_offset=q_offset, widen=interpret, d=call.d),
             interpret=interpret, **args)
 
-    def out(x):
-        return jax.ShapeDtypeStruct((B * H,) + x.shape[2:], x.dtype)
+    def like(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype)
 
     if one_kernel:
-        def head(b, j):
-            return (b, 0, 0)
+        def whole(j):
+            return 0
 
-        def block(b, j):
-            return (b, j, 0)
+        def block(j):
+            return j
 
-        dq, dk, dv = _on_platform(functools.partial(
-            call, functools.partial(_bwd_kernel, bq=bq),
-            grid=(B * H, Tk // bk),
-            in_specs=[pl.BlockSpec((None, Tq, D), head),
-                      pl.BlockSpec((None, bk, D), block),
-                      pl.BlockSpec((None, bk, D), block),
-                      pl.BlockSpec((None, Tq, D), head),
-                      pl.BlockSpec((None, 1, Tq), head),
-                      pl.BlockSpec((None, 1, Tq), head)],
-            out_specs=[pl.BlockSpec((None, Tq, D), head),
-                       pl.BlockSpec((None, bk, D), block),
-                       pl.BlockSpec((None, bk, D), block)],
-            out_shape=[out(q), out(k), out(v)],
-            scratch_shapes=[pltpu.VMEM((lanes, Tq), jnp.float32),
-                            pltpu.VMEM((D, bk), k.dtype),
-                            pltpu.VMEM((bk, D), jnp.float32),
-                            pltpu.VMEM((bk, D), jnp.float32)],
+        queries, keys = call.rows(tq, whole), call.rows(bk, block)
+        return tuple(_on_platform(functools.partial(
+            pallas_call, functools.partial(_bwd_kernel, bq=bq),
+            grid=call.steps + (tk // bk,),
+            in_specs=[queries, keys, keys, queries,
+                      call.stats(tq, whole), call.stats(tq, whole)],
+            out_specs=[queries, keys, keys],
+            out_shape=[like(q), like(k), like(v)],
+            scratch_shapes=[transposed, pltpu.VMEM((lanes, bk), k.dtype),
+                            accumulator, accumulator],
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary")),
-            name=name % ""), *operands)
-        return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            name=name % ""), *operands))
 
-    def q_index(b, j, i):
+    def q_walked(j, i):
         if causal:
             # a q block before the first that sees this k block
             # re-names that one: it is fetched once, not per step
             first = lax.div(lax.max(lax.sub(lax.mul(j, bk), q_offset), 0),
                             bq)
-            i = lax.max(i, lax.min(first, Tq // bq - 1))
+            i = lax.max(i, lax.min(first, tq // bq - 1))
         return i
 
-    def k_index(b, i, j):
+    def k_walked(i, j):
         if causal:
             last = lax.add(lax.mul(i, bq), q_offset + bq - 1)
             j = lax.min(j, lax.div(lax.max(last, 0), bk))
-        return (b, j, 0)
+        return j
 
-    def q_tile(index):
-        return [pl.BlockSpec((None, bq, D),
-                             lambda *g: (g[0], index(*g), 0)),
-                pl.BlockSpec((None, 1, bq),
-                             lambda *g: (g[0], 0, index(*g)))]
+    def held(a, b):
+        return a
 
-    q_walked, stats_walked = q_tile(q_index)
-    q_held, stats_held = q_tile(lambda b, i, j: i)
-    k_held = pl.BlockSpec((None, bk, D), lambda b, j, i: (b, j, 0))
-    k_walked = pl.BlockSpec((None, bk, D), k_index)
-    walk = dict(dimension_semantics=("parallel", "parallel", "arbitrary"))
+    walk = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "parallel",
+                             "arbitrary"))
+    queries, stats = call.rows(bq, q_walked), call.stats(bq, q_walked)
+    keys = call.rows(bk, held)
     dk, dv = _on_platform(functools.partial(
-        call, _bwd_dkv_kernel, grid=(B * H, Tk // bk, Tq // bq),
-        in_specs=[q_walked, k_held, k_held, q_walked, stats_walked,
-                  stats_walked],
-        out_specs=[k_held, k_held], out_shape=[out(k), out(v)],
-        scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
-                        pltpu.VMEM((bk, D), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(**walk),
+        pallas_call, _bwd_dkv_kernel,
+        grid=call.steps + (tk // bk, tq // bq),
+        in_specs=[queries, keys, keys, queries, stats, stats],
+        out_specs=[keys, keys], out_shape=[like(k), like(v)],
+        scratch_shapes=[accumulator, accumulator], compiler_params=walk,
         name=name % "_dkv"), *operands)
+    queries, stats = call.rows(bq, held), call.stats(bq, held)
+    keys = call.rows(bk, k_walked)
     dq = _on_platform(functools.partial(
-        call, _bwd_dq_kernel, grid=(B * H, Tq // bq, Tk // bk),
-        in_specs=[q_held, k_walked, k_walked, q_held, stats_held,
-                  stats_held],
-        out_specs=q_held, out_shape=out(q),
-        scratch_shapes=[pltpu.VMEM((lanes, bq), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(**walk),
-        name=name % "_dq"), *operands)
-    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
+        pallas_call, _bwd_dq_kernel,
+        grid=call.steps + (tq // bq, tk // bk),
+        in_specs=[queries, keys, keys, queries, stats, stats],
+        out_specs=queries, out_shape=like(q), scratch_shapes=[transposed],
+        compiler_params=walk, name=name % "_dq"), *operands)
+    return dq, dk, dv
 
 
 flash_attention_with_lse.defvjp(_flash_fwd_rule, _flash_bwd_rule)

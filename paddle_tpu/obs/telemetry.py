@@ -79,27 +79,40 @@ def jit_trace_count():
                           "jitted segments").value
 
 
-def on_flash_attention_lowering(block_q, block_k, kv_resident):
+def on_flash_attention_lowering(block_q, block_k, kv_resident,
+                                heads_per_step):
     """The flash-attention forward kernel was traced into a program,
-    with the tiling it chose from the shapes (or was told): one count
-    per kernel instance a lowered program holds."""
+    with the tiling it chose from the shapes (or was told) and the
+    heads one grid step holds: 1, or the 2 (4, ...) narrow heads that
+    share a 128-lane block of [batch, seq, heads * dim] operands, or
+    "split" where operands so laid out were split into
+    [batch, heads, seq, dim] around the kernel, no lane block holding
+    whole heads.  One count per kernel instance a lowered program
+    holds."""
     _reg().counter("flash_attention_lowerings_total",
-                   "flash-attention forward kernels lowered, by tiling",
-                   labelnames=("block_q", "block_k", "kv_resident")) \
+                   "flash-attention forward kernels lowered, by tiling "
+                   "and heads a grid step",
+                   labelnames=("block_q", "block_k", "kv_resident",
+                               "heads_per_step")) \
           .labels(block_q=block_q, block_k=block_k,
-                  kv_resident=str(bool(kv_resident)).lower()).inc()
+                  kv_resident=str(bool(kv_resident)).lower(),
+                  heads_per_step=heads_per_step).inc()
 
 
-def on_flash_attention_bwd_lowering(kernel, block_q, block_k):
+def on_flash_attention_bwd_lowering(kernel, block_q, block_k,
+                                    heads_per_step):
     """One of the flash-attention backward kernels ("dq_dkv", the one
     that makes all three gradients, or "dkv" and "dq", the two that
-    walk) was traced into a program, with the tiling chosen for it: one
-    count per kernel instance a lowered program holds."""
+    walk) was traced into a program, with the tiling chosen for it and
+    the heads one grid step holds (as `on_flash_attention_lowering`
+    says): one count per kernel instance a lowered program holds."""
     _reg().counter("flash_attention_bwd_lowerings_total",
-                   "flash-attention backward kernels lowered, by kernel "
-                   "and tiling",
-                   labelnames=("kernel", "block_q", "block_k")) \
-          .labels(kernel=kernel, block_q=block_q, block_k=block_k).inc()
+                   "flash-attention backward kernels lowered, by kernel, "
+                   "tiling and heads a grid step",
+                   labelnames=("kernel", "block_q", "block_k",
+                               "heads_per_step")) \
+          .labels(kernel=kernel, block_q=block_q, block_k=block_k,
+                  heads_per_step=heads_per_step).inc()
 
 
 def on_flash_attention_grad_lowering(residuals):

@@ -6,17 +6,22 @@ scaled_dot_product_attention); registering the fused kernel as a
 first-class op exceeds that surface: programs built with
 `fluid.layers.flash_attention` get the pallas online-softmax kernel
 (kernels/flash_attention.py) on TPU, interpret mode on CPU.  The op
-keeps the kernel's row statistics as a second output, `Lse`, and its
-gradient is an explicit kernel that hands them to the backward kernels:
-the generic gradient (jax.vjp of the whole op) has to run the forward
-kernel again to get them back, which no compiler pass undoes for a
-custom call.
+hands Q, K and V to the kernels as they arrive, [batch, seq,
+heads * dim], and `Out` is what the kernel wrote: the kernels'
+BlockSpecs pick the heads (two 64-wide ones a grid step), so no head
+is transposed around them.  The op keeps the kernel's row statistics
+as a second output, `Lse`, and its gradient is an explicit kernel that
+hands them to the backward kernels, in the same layout: the generic
+gradient (jax.vjp of the whole op) has to run the forward kernel again
+to get them back, which no compiler pass undoes for a custom call.
 
 When the op's `sequence_parallel_axis` attr names an axis of the
 ambient device mesh (the mesh `ParallelTrainer` compiles under), the
 kernel runs ring attention instead: q/k/v stay sequence-sharded and
 K/V blocks rotate over ICI neighbors (parallel/ring.py), so fluid-built
 programs scale to long context without leaving the Program stack.
+That branch splits the heads into [batch, heads, seq, dim], which the
+ring's shards and ulysses' all-to-all over heads need.
 The gradient of that branch, and of a program built before the op had
 `Lse`, is the generic one.
 """
@@ -37,16 +42,6 @@ def _ambient_mesh():
     return jax.sharding.get_abstract_mesh()
 
 
-def _split_heads(x, num_heads):
-    b, t, d = x.shape
-    return x.reshape(b, t, num_heads, d // num_heads).transpose(0, 2, 1, 3)
-
-
-def _merge_heads(x):
-    b, h, t, dh = x.shape
-    return x.transpose(0, 2, 1, 3).reshape(b, t, h * dh)
-
-
 def _sequence_parallel(attrs):
     """The ambient mesh if the op's `sequence_parallel_axis` names an
     axis of it larger than 1, else None: what sends the op, and its
@@ -60,11 +55,12 @@ def _sequence_parallel(attrs):
 
 @register_op("flash_attention")
 def flash_attention_op(ctx, ins, attrs):
-    """Q,K,V: [batch, seq, dim] dense; Out: [batch, seq_q, dim]; Lse:
-    float32 [batch, heads, seq_q], the log-sum-exp of each row of
-    scores, which the gradient reads (zeros on the sequence-parallel
-    branches, whose gradient reads none)."""
-    from ..kernels.flash_attention import flash_attention_with_lse
+    """Q,K,V: [batch, seq, dim] dense, dim = heads * head size; Out:
+    [batch, seq_q, dim]; Lse: float32 [batch, heads, seq_q], the
+    log-sum-exp of each row of scores, which the gradient reads (zeros
+    on the sequence-parallel branches, whose gradient reads none)."""
+    from ..kernels.flash_attention import (flash_attention_with_lse,
+                                           merge_heads, split_heads)
     from ..parallel.ring import (ring_attention, ulysses_attention,
                                  sp_shard_map)
 
@@ -83,12 +79,9 @@ def flash_attention_op(ctx, ins, attrs):
             raise ValueError("hidden size %d must divide num_heads %d"
                              % (t.shape[-1], num_heads))
 
-    qh = _split_heads(q, num_heads)
-    kh = _split_heads(k, num_heads)
-    vh = _split_heads(v, num_heads)
-
     mesh = _sequence_parallel(attrs)
     if mesh is not None:
+        qh, kh, vh = (split_heads(x, num_heads) for x in (q, k, v))
         if sp_mode == "ring":
             sp_fn = lambda q, k, v: ring_attention(  # noqa: E731
                 q, k, v, sp_axis, sm_scale, causal)
@@ -101,26 +94,28 @@ def flash_attention_op(ctx, ins, attrs):
             raise ValueError(
                 "sequence_parallel_mode must be ring or ulysses, got %r"
                 % sp_mode)
-        out = sp_shard_map(sp_fn, mesh, axis_name=sp_axis)(qh, kh, vh)
+        out = merge_heads(
+            sp_shard_map(sp_fn, mesh, axis_name=sp_axis)(qh, kh, vh))
         lse = jnp.zeros(qh.shape[:3], jnp.float32)
     else:
         # 0: the kernel chooses its blocks from the shapes
         block = int(attrs.get("block_size", 0)) or None
-        out, lse = flash_attention_with_lse(qh, kh, vh, sm_scale, causal,
-                                            block_q=block, block_k=block)
-    return {"Out": [_merge_heads(out).astype(q.dtype)], "Lse": [lse]}
+        out, lse = flash_attention_with_lse(
+            q, k, v, sm_scale, causal, block_q=block, block_k=block,
+            num_heads=num_heads)
+    return {"Out": [out.astype(q.dtype)], "Lse": [lse]}
 
 
 @register_grad_kernel("flash_attention")
 def flash_attention_grad(ctx, ins, attrs):
     """Q@GRAD, K@GRAD, V@GRAD from the backward kernels on what the
     forward op saved: `O@Lse`, and `O@Out` for the row sums of
-    dOut * Out, taken on the merged [batch, seq, dim] tensors so that
-    Out is never split into heads again.  Where the forward took the
-    sequence-parallel path, or the op desc carries no `O@Lse` (a
-    program from before the op had it), the generic gradient
-    differentiates the op as a whole and runs its forward again."""
-    from ..kernels.flash_attention import BWD_SCOPE, _bwd
+    dOut * Out; operands and gradients stay [batch, seq, dim], as the
+    forward's.  Where the forward took the sequence-parallel path, or
+    the op desc carries no `O@Lse` (a program from before the op had
+    it), the generic gradient differentiates the op as a whole and runs
+    its forward again."""
+    from ..kernels.flash_attention import BWD_SCOPE, _bwd, row_sums
 
     lse = (ins.get("O@Lse") or [None])[0]
     if lse is None or _sequence_parallel(attrs) is not None:
@@ -132,21 +127,14 @@ def flash_attention_grad(ctx, ins, attrs):
     do = ins["OG@Out"][0].astype(q.dtype)
     num_heads = int(attrs.get("num_heads", 1))
     block = int(attrs.get("block_size", 0)) or None
-    heads = [_split_heads(x, num_heads) for x in (q, k, v, do)]
     # the scope holds what it holds under the kernel's own VJP: the row
-    # sums and the kernels, not the split-head copies around them
+    # sums and the kernels
     with jax.named_scope(BWD_SCOPE):
-        b, t, d = o.shape
-        # behind a barrier, or XLA moves the row sums onto the forward
-        # kernel's split o and keeps that for the backward beside Out
-        o = jax.lax.optimization_barrier(o)
-        delta = jnp.sum(
-            (do.astype(jnp.float32) * o.astype(jnp.float32))
-            .reshape(b, t, num_heads, d // num_heads), axis=-1)
-        grads = _bwd(*heads, lse, delta.transpose(0, 2, 1),
+        grads = _bwd(q, k, v, do, lse, row_sums(do, o, num_heads),
                      float(attrs.get("sm_scale", 0.0)) or None,
-                     bool(attrs.get("causal", False)), block, block)
-    return {slot + "@GRAD": [_merge_heads(g)]
+                     bool(attrs.get("causal", False)), block, block,
+                     num_heads=num_heads)
+    return {slot + "@GRAD": [g]
             for slot, g in zip(("Q", "K", "V"), grads)}
 
 
@@ -190,7 +178,7 @@ def cached_attention_op(ctx, ins, attrs):
     never needs gradients (matching the reference's host-side
     generation loop), so the op stops them.
     """
-    import jax.numpy as jnp
+    from ..kernels.flash_attention import merge_heads, split_heads
 
     q, k_new, v_new = ins["Q"][0], ins["KNew"][0], ins["VNew"][0]
     k_cache, v_cache = ins["KCache"][0], ins["VCache"][0]
@@ -200,9 +188,9 @@ def cached_attention_op(ctx, ins, attrs):
     num_heads = int(attrs.get("num_heads", 1))
     sm_scale = float(attrs.get("sm_scale", 0.0)) or None
 
-    qh = _split_heads(q, num_heads)            # [B, H, 1, Dh]
-    kh = _split_heads(k_new, num_heads)
-    vh = _split_heads(v_new, num_heads)
+    qh = split_heads(q, num_heads)             # [B, H, 1, Dh]
+    kh = split_heads(k_new, num_heads)
+    vh = split_heads(v_new, num_heads)
     if sm_scale is None:
         sm_scale = qh.shape[-1] ** -0.5
 
@@ -219,5 +207,5 @@ def cached_attention_op(ctx, ins, attrs):
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhqk,bhkd->bhqd", p,
                      v_cache.astype(jnp.float32))
-    return {"Out": [_merge_heads(out).astype(q.dtype)],
+    return {"Out": [merge_heads(out).astype(q.dtype)],
             "KCacheOut": [k_cache], "VCacheOut": [v_cache]}

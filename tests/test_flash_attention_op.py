@@ -214,7 +214,8 @@ def test_flash_attention_op_in_program_grads_vs_reference():
 def test_block_size_in_a_program_is_honoured_and_its_absence_chooses():
     """A program whose op says block_size=128 still lowers with 128 x 128
     blocks; one that names none leaves the choice to the kernel (here
-    the whole 256-long sequence).  The counter's labels say which."""
+    the whole 256-long sequence).  The counter's labels say which, and
+    that the op's two heads of 16 share a grid step."""
     B, T, D = 1, 256, 32
     x0 = RS.randn(B, T, D).astype("float32")
 
@@ -233,10 +234,10 @@ def test_block_size_in_a_program_is_honoured_and_its_absence_chooses():
 
     named, delta = lowered_with(block_size=128)
     assert delta == {"flash_attention_lowerings_total{block_k=128,"
-                     "block_q=128,kv_resident=true}": 1}
+                     "block_q=128,heads_per_step=2,kv_resident=true}": 1}
     chosen, delta = lowered_with()
     assert delta == {"flash_attention_lowerings_total{block_k=256,"
-                     "block_q=256,kv_resident=true}": 1}
+                     "block_q=256,heads_per_step=2,kv_resident=true}": 1}
     np.testing.assert_allclose(named, chosen, atol=2e-5)
     np.testing.assert_allclose(named, _dense_ref(x0, x0, x0, 2, False),
                                atol=2e-5)
@@ -269,7 +270,7 @@ def test_block_size_reaches_the_backward_kernels_of_a_program():
         got, = exe.run(main, feed={}, fetch_list=grads)
     assert _rose(BWD_LOWERINGS, before) == {
         "flash_attention_bwd_lowerings_total{block_k=128,block_q=128,"
-        "kernel=dq_dkv}": 1}
+        "heads_per_step=2,kernel=dq_dkv}": 1}
 
     def heads(x):
         return x.reshape(B, T, H, D // H).transpose(0, 2, 1, 3)
@@ -282,6 +283,123 @@ def test_block_size_reaches_the_backward_kernels_of_a_program():
                                np.asarray(jax.grad(ref_loss)(
                                    jnp.asarray(x0))),
                                rtol=1e-4, atol=1e-7)
+
+
+# -- the op hands the kernels what it holds -----------------------------------
+
+def _equations(jaxpr, inside=()):
+    """(equation, names of the jitted functions around it) of a jaxpr
+    and of every jaxpr its equations hold, but for the kernels'
+    bodies."""
+    for eqn in jaxpr.eqns:
+        yield eqn, inside
+        if eqn.primitive.name == "pallas_call":
+            continue
+        within = inside + ((eqn.params["name"],)
+                           if eqn.primitive.name == "jit" else ())
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _equations(sub, within)
+
+
+# gpt2m-train's and ouro-train-4k's (and olmoe-train-4k's) attention
+CELL_SHAPES = [((8, 1024, 1024), 16, 2), ((1, 4096, 2048), 16, 1)]
+
+
+@pytest.mark.parametrize("shape,heads,heads_per_step", CELL_SHAPES)
+def test_no_head_is_transposed_around_the_kernels(shape, heads,
+                                                  heads_per_step):
+    """The op and its gradient at the cells' shapes, traced: Q, K, V,
+    dOut and the results reach and leave the kernels as
+    [batch, seq, heads * dim].  Outside the kernels nothing of that size
+    is transposed (the one transpose is of the float32 row sums,
+    [batch, seq, heads] -> [batch, heads, seq], a 64th or a 128th of an
+    operand), and nothing stands behind an optimization barrier; the
+    forward is one kernel and the backward one, named by the prefixes
+    the benchmark's readers match."""
+    from paddle_tpu.ops import registry
+
+    info = registry.get_op_info("flash_attention")
+    attrs = {"num_heads": heads, "causal": True}
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    lse = jax.ShapeDtypeStruct((shape[0], heads, shape[1]), jnp.float32)
+
+    def forward(q, k, v):
+        outs = info.kernel(None, {"Q": [q], "K": [k], "V": [v]}, attrs)
+        return outs["Out"][0], outs["Lse"][0]
+
+    def gradient(q, k, v, out, lse, dout):
+        grads = info.grad_kernel(None, {
+            "Q": [q], "K": [k], "V": [v], "O@Out": [out], "O@Lse": [lse],
+            "OG@Out": [dout]}, attrs)
+        return [grads[slot + "@GRAD"][0] for slot in ("Q", "K", "V")]
+
+    for fn, args, prefix in (
+            (forward, (x, x, x), "flash_attention_fwd"),
+            (gradient, (x, x, x, x, lse, x), "flash_attention_bwd")):
+        traced = jax.make_jaxpr(fn)(*args)
+        assert [v.aval.shape for v in traced.jaxpr.outvars[:1]] == [shape]
+        eqns = list(_equations(traced.jaxpr))
+        names = [e.primitive.name for e, _ in eqns]
+        assert "optimization_barrier" not in names
+        assert [e.outvars[0].aval.shape for e, _ in eqns
+                if e.primitive.name == "transpose"] \
+            == [lse.shape] * (fn is gradient)
+        # the kernel lowered for the TPU (its twin under the CPU's
+        # interpreter sits in the other branch of the platform switch)
+        kernels = [e.params["name"] for e, _ in eqns
+                   if e.primitive.name == "pallas_call"
+                   and not e.params["interpret"]]
+        assert len(kernels) == 1 and kernels[0].startswith(prefix + "_q")
+        assert kernels[0].endswith("_h%d" % heads_per_step)
+        # operands enter the kernel as the op got them
+        (kernel, inside), = [(e, inside) for e, inside in eqns
+                             if e.primitive.name == "pallas_call"
+                             and not e.params["interpret"]]
+        assert [v.aval.shape for v in kernel.invars[:3]] == [shape] * 3
+        assert inside[-1] == ("_fwd_kernels" if fn is forward
+                              else "_bwd_kernels")
+
+
+@pytest.mark.parametrize("shape,heads,heads_per_step", CELL_SHAPES)
+def test_the_counters_say_how_many_heads_a_grid_step_holds(
+        tmp_path, shape, heads, heads_per_step):
+    """A program with the cell's attention and its gradient, built and
+    its shapes inferred (nothing run): one forward and one backward
+    lowering an op, under `heads_per_step` 2 for GPT-2's heads of 64 and
+    1 for Ouro's of 128, none under "split"; `obs_dump` lists the label
+    with the others."""
+    from paddle_tpu.ops import registry
+    from paddle_tpu.tools import obs_dump
+
+    info = registry.get_op_info("flash_attention")
+    attrs = {"num_heads": heads, "causal": True}
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+
+    def step(q, k, v, dout):
+        ins = {"Q": [q], "K": [k], "V": [v]}
+        outs = info.kernel(None, ins, attrs)
+        return info.grad_kernel(None, dict(
+            ins, **{"O@Out": outs["Out"], "O@Lse": outs["Lse"],
+                    "OG@Out": [dout]}), attrs)
+
+    before = {p: _counters(p) for p in (FWD_LOWERINGS, BWD_LOWERINGS)}
+    jax.eval_shape(step, x, x, x, x)
+    for prefix in (FWD_LOWERINGS, BWD_LOWERINGS):
+        (key, n), = _rose(prefix, before[prefix]).items()
+        assert n == 1 and "heads_per_step=%d," % heads_per_step in key
+    path = str(tmp_path / "metrics.prom")
+    assert obs_dump.main(["--metrics-out", path]) == 0
+    with open(path) as f:
+        listed = [line for line in f if line.startswith(
+            ("flash_attention_lowerings_total{",
+             "flash_attention_bwd_lowerings_total{"))]
+    assert len(listed) == 2
+    assert all('heads_per_step="%d"' % heads_per_step in line
+               for line in listed)
 
 
 # -- the explicit gradient against the generic one ----------------------------

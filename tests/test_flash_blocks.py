@@ -3,8 +3,11 @@ sees (kernels/flash_attention.py: `_choose_blocks`, `_step_bytes`,
 `_VMEM_BUDGET`): the chooser as a pure function, the kernel's agreement
 with dense attention at the chosen blocks under the Pallas interpreter,
 the backward kernels' own chooser and their agreement with dense
-attention's gradients, and the counters that name the tilings."""
+attention's gradients, the two layouts the kernels index
+([batch, heads, seq, dim] and [batch, seq, heads * dim]) against each
+other, and the counters that name the tilings."""
 
+import collections
 import importlib
 
 import numpy as np
@@ -287,12 +290,129 @@ def test_named_blocks_of_16_are_kept_by_one_kernel_and_by_two(
         np.testing.assert_allclose(a, b, atol=5e-5)
 
 
+# -- operands as a projection writes them: the heads side by side -------------
+
+def _merged(t, heads, d, seed, dtype=jnp.float32):
+    rs = np.random.RandomState(seed)
+    return [jnp.asarray(0.5 * rs.randn(2, t, heads * d), dtype)
+            for _ in range(4)]
+
+
+def _both_layouts(q, k, v, do, heads, causal):
+    """((o, lse), (dq, dk, dv)) from operands [batch, seq, heads * dim]
+    as they are, and the same through [batch, heads, seq, dim], every
+    result put back side by side."""
+    def merged(q, k, v):
+        return fa.flash_attention_with_lse(q, k, v, None, causal,
+                                           num_heads=heads)
+
+    def apart(q, k, v):
+        o, lse = fa.flash_attention_with_lse(
+            *(fa.split_heads(x, heads) for x in (q, k, v)), None, causal)
+        return fa.merge_heads(o), lse
+
+    results = []
+    for attention in (merged, apart):
+        out, vjp = jax.vjp(attention, q, k, v)
+        results.append((out, vjp((do, jnp.cos(out[1])))))
+    return results
+
+
+# the head sizes a 128-lane block holds whole (four, two, one a grid
+# step), and one it does not: that call splits its heads around the
+# kernel as every call once did
+HEADS_A_STEP = {32: 4, 64: 2, 128: 1, 96: "split"}
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("kernels", ["one", "walking"])
+@pytest.mark.parametrize("d", sorted(HEADS_A_STEP))
+def test_heads_side_by_side_are_the_heads_held_apart(monkeypatch, d,
+                                                     kernels, causal):
+    """[batch, seq, heads * dim] operands, the head picked by the
+    BlockSpecs, give what [batch, heads, seq, dim] operands give: o, the
+    log-sum-exp, dq, dk and dv, through the one backward kernel and
+    through the pair that walks, and the counters say how many heads a
+    grid step held."""
+    heads, t = 4, 256
+    q, k, v, do = _merged(t, heads, d, seed=11)
+    call = fa._Call.of(q.shape, k.shape, heads)
+    assert (call.g or "split") == HEADS_A_STEP[d]
+    apart = fa._Call.of((2, heads, t, d), (2, heads, t, d), None)
+    if kernels == "walking":
+        # room for the pair's 128 x 128 blocks, none for a grid step's
+        # 256 queries beside them
+        monkeypatch.setattr(fa, "_VMEM_BUDGET", fa._bwd_step_bytes(
+            128, 128, (call if call.g else apart).lanes, 4, None,
+            call.g or 1))
+    # what each call lowers: the side-by-side one under its own label
+    # (its inner call's, where it splits its heads), the other under 1
+    expected = collections.Counter()
+    for inner, label in ((call if call.g else apart, HEADS_A_STEP[d]),
+                         (apart, "apart")):
+        one_kernel = fa._choose_bwd_blocks(*inner.step_shapes, 4, causal,
+                                           heads=inner.g)[2]
+        if label == "apart":
+            label = 1
+        else:
+            assert one_kernel == (kernels == "one")
+        expected["flash_attention_lowerings_total{%s}" % label] += 1
+        expected["flash_attention_bwd_lowerings_total{%s}" % label] \
+            += 1 if one_kernel else 2
+    before = telemetry.snapshot()
+    (out, grads), (out_apart, grads_apart) = _both_layouts(
+        q, k, v, do, heads, causal)
+    rose = collections.Counter()
+    for key, n in telemetry.snapshot().items():
+        if key.startswith("flash_attention_") and "heads_per_step" in key:
+            name, labels = key.rstrip("}").split("{")
+            labels = dict(pair.split("=") for pair in labels.split(","))
+            rose["%s{%s}" % (name, labels["heads_per_step"])] \
+                += n - before.get(key, 0)
+    rose = {key: n for key, n in rose.items() if n}
+    assert rose == dict(expected)
+    assert out[0].shape == q.shape and out[1].shape == (2, heads, t)
+    for a, b in zip(out + grads, out_apart + grads_apart):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        np.testing.assert_allclose(a, b, atol=2e-6)
+    # and both are dense attention's
+    want = fa.merge_heads(fa.reference_attention(
+        *(fa.split_heads(x, heads) for x in (q, k, v)), None, causal))
+    np.testing.assert_allclose(out[0], want, atol=2e-5)
+
+
+@pytest.mark.parametrize("shape,heads,forward,backward", [
+    # gpt2m-train: 16 heads of 64, two a grid step
+    ((8, 1024, 1024), 16, (512, 512, True), (512, 512, True)),
+    # ouro-train-4k and olmoe-train-4k: 16 heads of 128
+    ((1, 4096, 2048), 16, (1024, 512, True), (512, 256, True)),
+])
+def test_the_cells_tilings_are_those_of_heads_held_apart(shape, heads,
+                                                         forward, backward):
+    """The blocks chosen for the benchmark's attention, bfloat16 and
+    causal, are the same whether a grid step sees one
+    [batch, heads, seq, dim] head or the heads that share a lane block
+    of [batch, seq, heads * dim]."""
+    b, t, width = shape
+    for call in (fa._Call.of(shape, shape, heads),
+                 fa._Call.of((b, heads, t, width // heads),
+                             (b, heads, t, width // heads), None)):
+        assert fa._choose_blocks(*call.step_shapes, 2, True) == forward
+        assert fa._choose_bwd_blocks(*call.step_shapes, 2, True,
+                                     heads=call.g) == backward
+        # and two heads a step fit where one did
+        assert fa._bwd_step_bytes(
+            backward[0], backward[1], call.lanes, 2, t, call.g) \
+            <= fa._VMEM_BUDGET
+
+
 # -- the counter names the tiling ---------------------------------------------
 
-def _lowerings(bq, bk, resident):
+def _lowerings(bq, bk, resident, heads_per_step=1):
     return telemetry.snapshot().get(
         "flash_attention_lowerings_total{block_k=%d,block_q=%d,"
-        "kv_resident=%s}" % (bk, bq, str(resident).lower()), 0)
+        "heads_per_step=%s,kv_resident=%s}"
+        % (bk, bq, heads_per_step, str(resident).lower()), 0)
 
 
 @pytest.mark.parametrize("named", [None, 128])
@@ -312,10 +432,11 @@ def test_counter_rises_once_per_lowering(named):
     assert _lowerings(*labels) == before + 2
 
 
-def _bwd_lowerings(kernel, bq, bk):
+def _bwd_lowerings(kernel, bq, bk, heads_per_step=1):
     return telemetry.snapshot().get(
         "flash_attention_bwd_lowerings_total{block_k=%d,block_q=%d,"
-        "kernel=%s}" % (bk, bq, kernel), 0)
+        "heads_per_step=%s,kernel=%s}" % (bk, bq, heads_per_step, kernel),
+        0)
 
 
 @pytest.mark.parametrize("shape,named,blocks,kernels", [

@@ -440,4 +440,5 @@ def test_the_flash_kernel_tiles_the_cells_shape_as_the_counter_says():
     counts = {k: v for k, v in telemetry.snapshot().items()
               if k.startswith("flash_attention_lowerings_total")}
     assert list(counts) == ["flash_attention_lowerings_total{block_k=512,"
-                            "block_q=1024,kv_resident=true}"]
+                            "block_q=1024,heads_per_step=1,"
+                            "kv_resident=true}"]
