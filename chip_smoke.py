@@ -8,7 +8,9 @@ out: ResNet-50 trained through `Executor.run` and through the
 FunctionalProgram step bench.py times, the Program-stack transformer
 trained through the flash-attention kernel (and the kernel checked
 against dense attention), the routed expert op forward and backward at
-OLMoE's widths against the dense reference, ResNet-50 served over HTTP
+OLMoE's widths against the dense reference, the state-space scan op
+forward and backward at granite-4.0-h-micro's widths against the
+sequential recurrence, ResNet-50 served over HTTP
 as serve_cli
 serves it, and — on a host with four chips — ResNet-50 under
 SpmdTrainer.  Weights are random, from a seed; no phase is cut down.
@@ -314,6 +316,79 @@ def moe_experts_check(tokens=4096, hidden=2048, experts=64, width=1024,
              counts.max(), worst), flush=True)
 
 
+def ssd_scan_check(seq=4096, heads=64, dim=64, state=128, chunk=256):
+    """The state-space scan op (`ssd_scan`: the chunked Mosaic kernels,
+    the carried state over 16 chunks) and its explicit gradient at
+    granite-4.0-h-micro's widths, bfloat16 products, against the
+    recurrence walked one position after another in float32 (as the
+    plain reference, models/reference/granite_hybrid.py, walks it): the
+    output and the seven gradients."""
+    import jax
+    import jax.numpy as jnp
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.models.reference import granite_hybrid as reference
+    from paddle_tpu.ops import registry
+
+    fluid.amp.enable_bf16()
+    keys = jax.random.split(jax.random.PRNGKey(0), 8)
+    wide, f32 = (1, seq, heads * dim), jnp.float32
+    slots = ("X", "Dt", "DtBias", "ALog", "B", "C", "D")
+    values = (
+        jax.random.normal(keys[0], wide, f32),
+        jax.random.normal(keys[1], (1, seq, heads), f32),
+        # steps log-uniform on [1e-3, 1e-1], rates uniform on [1, 16]:
+        # decays of 0.2 to 0.999 a step, state that crosses chunks
+        jnp.log(jnp.expm1(jnp.exp(jax.random.uniform(
+            keys[2], (heads,), f32, np.log(1e-3), np.log(1e-1))))),
+        jnp.log(jax.random.uniform(keys[3], (heads,), f32, 1.0, 16.0)),
+        0.5 * jax.random.normal(keys[4], (1, seq, state), f32),
+        0.5 * jax.random.normal(keys[5], (1, seq, state), f32),
+        1.0 + 0.1 * jax.random.normal(keys[6], (heads,), f32))
+    d_y = jax.random.normal(keys[7], wide, f32)
+    info = registry.get_op_info("ssd_scan")
+    attrs = {"num_heads": heads, "chunk_size": chunk}
+
+    def program(*values):
+        ins = {s: [v] for s, v in zip(slots, values)}
+        outs = info.kernel(None, ins, attrs)
+        grad_ins = dict(ins, **{"OG@Y": [d_y.astype(outs["Y"][0].dtype)]})
+        grad_ins.update({"O@" + slot: v for slot, v in outs.items()})
+        grads = info.grad_kernel(None, grad_ins, attrs)
+        return [outs["Y"][0]] + [grads[s + "@GRAD"][0] for s in slots]
+
+    def plain(x, dt, dt_bias, a_log, b, c, d_skip):
+        def out(*v):
+            x, dt, dt_bias, a_log, b, c, d_skip = v
+            return reference.recurrence(
+                x.reshape(1, seq, heads, dim),
+                jax.nn.softplus(dt + dt_bias), -jnp.exp(a_log), b, c,
+                d_skip, segment=64).reshape(wide)
+
+        with jax.default_matmul_precision("highest"):
+            y, vjp = jax.vjp(out, x, dt, dt_bias, a_log, b, c, d_skip)
+            return [y] + list(vjp(d_y))
+
+    got = jax.jit(program)(*values)
+    check(jax.jit(program).lower(*values).as_text().count(
+        "tpu_custom_call") == 2,
+        "ssd_scan and its gradient lowered without their two Mosaic "
+        "kernels")
+    want = jax.jit(plain)(*values)
+    worst = 0.0
+    for name, g, w in zip(("y",) + tuple("d" + s for s in slots), got,
+                          want):
+        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+        check(g.shape == w.shape and np.isfinite(g).all(),
+              "ssd_scan %s: bad shape or non-finite" % name)
+        err = float(np.abs(g - w).max() / np.abs(w).max())
+        check(err < BF16_TOL, "ssd_scan %s: off the recurrence by %.4f of "
+              "its largest value" % (name, err))
+        worst = max(worst, err)
+    print("  ssd_scan [1, %d, %d x %d], state %d, %d chunks of %d: within "
+          "%.4f of the recurrence" % (seq, heads, dim, state, seq // chunk,
+                                      chunk, worst), flush=True)
+
+
 def resnet50_serve(image_size=224, class_dim=1000, buckets=(1, 4, 16),
                    sizes=(1, 2, 4, 3, 8, 16, 5, 1)):
     import jax
@@ -454,7 +529,7 @@ def main():
     print("compile cache: %s" % enable_compile_cache(), flush=True)
     clock = CompileClock()
     phases = [resnet50_train, transformer_train, moe_experts_check,
-              resnet50_serve]
+              ssd_scan_check, resnet50_serve]
     if len(devices) >= 4:
         phases.append(multichip)
     for phase in phases:

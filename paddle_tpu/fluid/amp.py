@@ -16,8 +16,11 @@ parameters + optimizer state (masters), all reduction statistics
 (`moe_router`: its product at the highest precision, softmax, top-k and
 both auxiliary losses, since a rounded logit sends a token to another
 expert; the experts' own products are bf16 with f32 accumulation and
-their weight gradients add up in f32), and everything crossing the
-feed/fetch boundary.  Set FLAGS_amp_bf16_act=0 for the conservative
+their weight gradients add up in f32), inside a state-space scan
+(`ssd_scan`) the steps dt = softplus(Dt + DtBias), dt A, their sums,
+every decay and every carried state (the products with X, B and C are
+bf16 with f32 accumulation; the gradients of ALog, D and DtBias add up
+in f32), and everything crossing the feed/fetch boundary.  Set FLAGS_amp_bf16_act=0 for the conservative
 cast-back-to-f32 behaviour.
 """
 

@@ -11,7 +11,8 @@ import math
 
 from . import framework
 
-__all__ = ["Constant", "Uniform", "Normal", "Xavier", "MSRA",
+__all__ = ["Constant", "Uniform", "Normal", "Xavier", "MSRA", "LogScale",
+           "LogScaleInitializer",
            "ConstantInitializer", "UniformInitializer", "NormalInitializer",
            "XavierInitializer", "MSRAInitializer", "force_init_on_cpu"]
 
@@ -121,8 +122,42 @@ class MSRAInitializer(Initializer):
             NormalInitializer(0.0, std, self.seed)(var, block)
 
 
+class LogScaleInitializer(Initializer):
+    """A state-space layer's scalars, drawn on a log scale as
+    arXiv:2405.21060 draws them.  `kind` "log_uniform": log(u), u
+    uniform on [low, high] (`A_log`, the log of a decay rate).
+    "inverse_softplus_log_uniform": the x with softplus(x) = s, s
+    log-uniform on [low, high] (`dt_bias`, so that a step starts at
+    s)."""
+
+    KINDS = ("log_uniform", "inverse_softplus_log_uniform")
+
+    def __init__(self, low, high, kind="log_uniform", seed=0):
+        if kind not in self.KINDS or not 0 < low < high:
+            raise ValueError("LogScale(%r, %r, %r)" % (low, high, kind))
+        self.low, self.high, self.kind, self.seed = low, high, kind, seed
+
+    def __call__(self, var, block):
+        def in_place(op_type, **attrs):
+            block.append_op(type=op_type, inputs={"X": var},
+                            outputs={"Out": var}, attrs=attrs)
+
+        if self.kind == "log_uniform":
+            UniformInitializer(self.low, self.high, self.seed)(var, block)
+            in_place("log")
+            return
+        UniformInitializer(math.log(self.low), math.log(self.high),
+                           self.seed)(var, block)
+        # s = exp(u); x = log(exp(s) - 1)
+        in_place("exp")
+        in_place("exp")
+        in_place("increment", step=-1.0)
+        in_place("log")
+
+
 Constant = ConstantInitializer
 Uniform = UniformInitializer
 Normal = NormalInitializer
 Xavier = XavierInitializer
 MSRA = MSRAInitializer
+LogScale = LogScaleInitializer

@@ -10,7 +10,7 @@ import numpy as np
 
 from ..layer_helper import LayerHelper
 from ..framework import Variable
-from ..initializer import Constant, Normal, Xavier
+from ..initializer import Constant, LogScale, Normal, Xavier
 from ..param_attr import ParamAttr
 
 __all__ = [
@@ -29,6 +29,7 @@ __all__ = [
     "sequence_concat", "beam_search", "beam_search_decode",
     "sequence_reverse", "sequence_unnest", "sequence_renest",
     "flash_attention", "cached_attention", "rms_norm", "rope", "moe",
+    "ssd_scan", "causal_conv1d", "expand",
 ]
 
 
@@ -732,6 +733,75 @@ def moe(input, num_experts, expert_size, top_k, router_attr=None,
     return out, lb_loss, z_loss, {"logits": logits, "top_w": top_w,
                                   "top_idx": top_idx,
                                   "counts": kept["Counts"]}
+
+
+def ssd_scan(x, dt, b, c, num_heads, chunk_size=256, a_log_attr=None,
+             d_attr=None, dt_bias_attr=None, name=None):
+    """Mamba-2's selective state-space scan (ops/ssm.py ssd_scan) over
+    `x` [batch, seq, num_heads * head_dim] with the steps `dt` [batch,
+    seq, num_heads] as the projection gives them (the op adds its bias
+    and takes the softplus, in float32) and the shared `b`, `c` [batch,
+    seq, d_state]: per head S_t = exp(dt_t A) S_{t-1} + dt_t x_t b_t^T,
+    y_t = S_t c_t + D x_t, computed `chunk_size` positions at a time.
+    Three float32 parameters of [num_heads]: `ALog` (A = -exp(ALog);
+    log of a uniform [1, 16]), `D` (ones) and `DtBias` (the inverse
+    softplus of a step drawn log-uniformly from [1e-3, 1e-1]), as
+    arXiv:2405.21060 initialises them, so that decays lie between 0.2
+    and 0.999 a step.  `seq` must be a multiple of `chunk_size`."""
+    helper = LayerHelper("ssd_scan", name=name)
+
+    def param(attr, init):
+        return helper.create_parameter(
+            attr or ParamAttr(), shape=[num_heads], dtype="float32",
+            default_initializer=init)
+
+    a_log = param(a_log_attr, LogScale(1.0, 16.0, "log_uniform"))
+    d_skip = param(d_attr, Constant(1.0))
+    dt_bias = param(dt_bias_attr,
+                    LogScale(1e-3, 1e-1, "inverse_softplus_log_uniform"))
+    out = helper.create_tmp_variable(x.dtype)
+    states = helper.create_tmp_variable("float32", stop_gradient=True)
+    helper.append_op(
+        type="ssd_scan",
+        inputs={"X": [x], "Dt": [dt], "DtBias": [dt_bias],
+                "ALog": [a_log], "B": [b], "C": [c], "D": [d_skip]},
+        outputs={"Y": [out], "States": [states]},
+        attrs={"num_heads": int(num_heads), "chunk_size": int(chunk_size)})
+    return out
+
+
+def causal_conv1d(input, filter_size=4, activation="silu", param_attr=None,
+                  bias_attr=None, name=None):
+    """Causal depthwise convolution over the sequence axis of `input`
+    [batch, seq, channels] (ops/ssm.py causal_conv1d): out_t =
+    act(bias + sum_j filter[:, j] x_{t-(filter_size-1)+j}), zeros before
+    position 0, each channel by itself; `activation` "silu" or None."""
+    helper = LayerHelper("causal_conv1d", name=name)
+    channels = int(input.shape[-1])
+    filt = helper.create_parameter(
+        param_attr or ParamAttr(), shape=[channels, filter_size],
+        dtype=input.dtype,
+        default_initializer=Xavier(fan_in=filter_size,
+                                   fan_out=filter_size))
+    bias = helper.create_parameter(
+        bias_attr or ParamAttr(), shape=[channels], dtype=input.dtype,
+        default_initializer=Constant(0.0))
+    out = helper.create_tmp_variable(input.dtype)
+    helper.append_op(
+        type="causal_conv1d",
+        inputs={"X": [input], "Filter": [filt], "Bias": [bias]},
+        outputs={"Out": [out]}, attrs={"activation": activation or ""})
+    return out
+
+
+def expand(x, expand_times, **kwargs):
+    """`x` tiled `expand_times[i]` times along axis i (the `expand` op)."""
+    helper = LayerHelper("expand", **kwargs)
+    out = helper.create_tmp_variable(x.dtype)
+    helper.append_op(type="expand", inputs={"X": [x]},
+                     outputs={"Out": [out]},
+                     attrs={"expand_times": [int(t) for t in expand_times]})
+    return out
 
 
 def lrn(input, n=5, k=1.0, alpha=1e-4, beta=0.75, **kwargs):

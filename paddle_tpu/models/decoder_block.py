@@ -7,7 +7,7 @@ given, so a builder decides what is shared."""
 from .. import fluid
 from ..fluid.param_attr import ParamAttr
 
-__all__ = ["linear", "norm", "attention"]
+__all__ = ["linear", "norm", "attention", "gated_feed_forward"]
 
 
 def linear(x, size, name):
@@ -20,21 +20,53 @@ def norm(x, eps, name):
                                  param_attr=ParamAttr(name=name))
 
 
-def attention(h, positions, names, n_head, d_head, theta, qk_norm_eps=None):
+def _repeat_heads(x, n_kv_head, times, d_head):
+    """[batch, seq, n_kv_head * d_head] -> [batch, seq, n_kv_head * times
+    * d_head], each key/value head `times` times in a row, so that query
+    head i reads key/value head i // times: reshape, `expand` on a new
+    axis, reshape.  The copies are the Program's; the gradient of
+    `expand` sums a group's heads."""
+    batch, seq = x.shape[0], x.shape[1]
+    x = fluid.layers.reshape(x, [batch, seq, n_kv_head, 1, d_head])
+    x = fluid.layers.expand(x, [1, 1, 1, times, 1])
+    return fluid.layers.reshape(x, [batch, seq, n_kv_head * times * d_head])
+
+
+def attention(h, positions, names, n_head, d_head, theta, qk_norm_eps=None,
+              n_kv_head=None, sm_scale=None):
     """Causal self-attention over `h` [batch, seq, hidden], already
     normed: projections `names["wq"|"wk"|"wv"]`, rotary positions
-    (rotate-half over each head, base `theta`), the `flash_attention`
+    (rotate-half over each head, base `theta`; none where `theta` is
+    None, and `positions` is then not read), the `flash_attention`
     op, the projection `names["wo"]` back to hidden.  With
     `qk_norm_eps`, q and k are RMS-normed over their whole projection
     (`names["q_norm"|"k_norm"]`) before they are split into heads and
-    rotated, as OLMoE does."""
-    q, k, v = (linear(h, n_head * d_head, names[w])
-               for w in ("wq", "wk", "wv"))
+    rotated, as OLMoE does.  With `n_kv_head` fewer than `n_head`, k and
+    v are projected to that many heads and each is repeated for its
+    group of query heads.  `sm_scale` scales the scores (default
+    1 / sqrt(d_head))."""
+    n_kv_head = n_kv_head or n_head
+    q = linear(h, n_head * d_head, names["wq"])
+    k, v = (linear(h, n_kv_head * d_head, names[w]) for w in ("wk", "wv"))
     if qk_norm_eps is not None:
         q = norm(q, qk_norm_eps, names["q_norm"])
         k = norm(k, qk_norm_eps, names["k_norm"])
-    o = fluid.layers.flash_attention(
-        fluid.layers.rope(q, positions, n_head, theta),
-        fluid.layers.rope(k, positions, n_head, theta), v,
-        num_heads=n_head, causal=True)
+    if theta is not None:
+        q = fluid.layers.rope(q, positions, n_head, theta)
+        k = fluid.layers.rope(k, positions, n_kv_head, theta)
+    if n_kv_head != n_head:
+        k, v = (_repeat_heads(t, n_kv_head, n_head // n_kv_head, d_head)
+                for t in (k, v))
+    o = fluid.layers.flash_attention(q, k, v, num_heads=n_head, causal=True,
+                                     sm_scale=sm_scale)
     return linear(o, h.shape[-1], names["wo"])
+
+
+def gated_feed_forward(u, width, names):
+    """(silu(g) * v) W_out with [g | v] = u W_in, gate and up in one
+    [hidden, 2 * width] matrix `names["w_in"]`, the first `width`
+    columns the gate; `names["w_out"]` back to hidden."""
+    gate, up = fluid.layers.split(
+        linear(u, 2 * width, names["w_in"]), 2, dim=-1)
+    return linear(fluid.layers.swish(gate) * up, u.shape[-1],
+                  names["w_out"])

@@ -36,7 +36,8 @@ __all__ = ["on_executor_run", "on_jit_trace",
            "on_flash_attention_lowering",
            "on_flash_attention_bwd_lowering",
            "on_flash_attention_grad_lowering", "on_moe_lowering",
-           "on_moe_gmm_lowering", "on_shared_parameter_uses",
+           "on_moe_gmm_lowering", "on_ssd_lowering",
+           "on_causal_conv1d_lowering", "on_shared_parameter_uses",
            "on_transfer",
            "on_feed_seconds", "on_program_cache_evict",
            "jit_trace_count", "transfer_bytes", "step", "set_gauge",
@@ -150,6 +151,31 @@ def on_moe_gmm_lowering(kernel, block_m, block_n, block_k):
                    labelnames=("kernel", "block_m", "block_n", "block_k")) \
           .labels(kernel=kernel, block_m=block_m, block_n=block_n,
                   block_k=block_k).inc()
+
+
+def on_ssd_lowering(kernel, chunk, heads_per_step):
+    """The chunked state-space scan ("fwd") or its gradient ("bwd":
+    kernels/ssd.py) was traced into a program, with its chunk and the
+    heads a grid step takes: one count per instance a lowered program
+    holds.  A gradient op that ran the forward again would count a
+    "fwd" of its own."""
+    _reg().counter("ssd_lowerings_total",
+                   "chunked state-space scans lowered, by kernel, chunk "
+                   "and heads a grid step",
+                   labelnames=("kernel", "chunk", "heads_per_step")) \
+          .labels(kernel=kernel, chunk=chunk,
+                  heads_per_step=heads_per_step).inc()
+
+
+def on_causal_conv1d_lowering(width, activation):
+    """A causal depthwise convolution (`causal_conv1d`, ops/ssm.py) was
+    traced into a program: one count per op instance a lowered program
+    holds."""
+    _reg().counter("causal_conv1d_lowerings_total",
+                   "causal depthwise convolutions lowered, by width and "
+                   "activation",
+                   labelnames=("width", "activation")) \
+          .labels(width=width, activation=activation).inc()
 
 
 def on_shared_parameter_uses(program, uses):

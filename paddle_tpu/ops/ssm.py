@@ -1,0 +1,362 @@
+"""A state-space layer's two ops: Mamba-2's selective scan and the
+causal depthwise convolution in front of it.
+
+`ssd_scan` is the recurrence of "Transformers are SSMs"
+(arXiv:2405.21060), one state `S` [head_dim, d_state] a head:
+
+    dt_t = softplus(Dt_t + DtBias)          A = -exp(ALog)
+    S_t  = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T        S_{-1} = 0
+    y_t  = S_t C_t + D x_t
+
+computed in chunks (section 6 of the paper, "SSD"): with `cum` the sums
+of `dt A` inside a chunk, `Y = (L . (C B^T)) (dt X)` with `L[i, j] =
+exp(cum_i - cum_j)` for `i >= j` is what a chunk's own positions give
+one another, `C S_in^T` decayed by `exp(cum)` is what the state entering
+the chunk gives, and the state is handed on as `exp(cum_last) S_in +
+(exp(cum_last - cum) dt X)^T B`.  Nothing is [seq, seq] and nothing
+walks the positions one by one; `seq` that is no multiple of the chunk
+is an error when the program is built.  Every head reads the same B and
+C (one group).
+
+The op takes Dt *before* the softplus, with DtBias: under bfloat16
+compute the projection that makes Dt is bfloat16, and a softplus op of
+its own between the two would round dt, the step of every decay, to
+eight bits.  Here Dt, DtBias, ALog, `dt`, `dt A`, their sums, every
+decay and every carried state are float32 whatever the compute type;
+the products take the compute type's operands and add up in float32;
+the gradients of ALog, D and DtBias add up in float32.
+
+The gradient is explicit, for the reason `flash_attention`'s and
+`moe_experts`' are: `jax.vjp` of the op would run the forward's chunk
+products a second time.  The forward op keeps, beside Y,
+
+    States [batch, chunks, d_state, heads * head_dim] float32
+
+the state entering each chunk (33.5 MB a layer at 4096 x 64 x 64 x 128;
+transposed, state rows by head lanes, as the kernel carries it), and the
+gradient op reads it.  What it computes again: the softplus and the sums
+(three [batch, seq, heads] float32 arrays, 1 MB each: keeping them would
+cost 3 MB to save three elementwise passes over 1 MB); `C B^T` and the
+decay mask `L` (0.27 GFLOP and 67M exponentials a layer; keeping `L . (C
+B^T)` would be 134 MB a layer in bfloat16); and `C S_in^T` (4.3 GFLOP a
+layer, a third of the forward's 13), because the decays' gradient needs
+`sum_p dY_i Y_i` of what the entering state gave.  The same sum over what
+the chunk's own positions gave is taken from `dL . L` without its
+diagonal, not from the Y the forward wrote: Y is rounded to the compute
+type, and on the diagonal, where L is 1 whatever the decay, gains and
+losses cancel to nothing but their rounding.  It runs none of the
+forward's other products (`M (dt X)`, the chunk states).
+
+`causal_conv1d` is out_t = act(bias + sum_j filter[:, j] x_{t-(K-1)+j}),
+each channel by itself, zeros before position 0: one fused pass.  Its
+gradient is explicit too and reads X, Filter, Bias and dOut only: it
+computes the pre-activation again (one more fused pass over X) where
+`jax.vjp` would keep K shifted copies of X.
+"""
+
+import jax
+import jax.numpy as jnp
+
+from ..obs import telemetry
+from .amp_util import amp_result, mxu_operands
+from .registry import register_grad_kernel, register_op
+
+F32 = jnp.float32
+_ACC = {"preferred_element_type": F32}
+
+
+def _set_meta(block, name, shape, dtype):
+    desc = block.var_recursive(name).desc
+    desc.shape, desc.dtype, desc.lod_level = tuple(shape), dtype, 0
+
+
+# -- the chunked scan as plain jax.numpy ---------------------------------------
+
+def _chunks(t, chunk):
+    """[batch, seq, ...] -> [batch, chunks, chunk, ...]."""
+    return t.reshape(t.shape[0], t.shape[1] // chunk, chunk, *t.shape[2:])
+
+
+def _decay_mask(cum):
+    """L [batch, chunks, heads, i, j] = exp(cum_i - cum_j) for i >= j,
+    else 0, from cum [batch, chunks, chunk, heads]."""
+    ch = jnp.swapaxes(cum, 2, 3)
+    q = ch.shape[-1]
+    lower = jnp.tril(jnp.ones((q, q), bool))
+    return jnp.exp(jnp.where(lower, ch[..., :, None] - ch[..., None, :],
+                             -jnp.inf))
+
+
+def _carried(decay, local, reverse=False):
+    """The state entering each chunk (leaving it, walked in `reverse`):
+    s_0 = 0, s_{c+1} = decay_c s_c + local_c.  decay [batch, chunks,
+    heads], local [batch, chunks, d_state, heads, head_dim]."""
+    def step(s, inputs):
+        d, loc = inputs
+        return d[:, None, :, None] * s + loc, s
+
+    _, entering = jax.lax.scan(
+        step, jnp.zeros_like(local[:, 0]),
+        (jnp.moveaxis(decay, 1, 0), jnp.moveaxis(local, 1, 0)),
+        reverse=reverse)
+    return jnp.moveaxis(entering, 0, 1)
+
+
+def _state_product(rows, cols):
+    """sum_j rows[.., j, n] cols[.., j, h, p] -> [.., n, h, p], float32.
+    (With the heads folded into one axis: XLA's CPU backend has no
+    bfloat16 product of this form over two free axes.)"""
+    flat = cols.reshape(*cols.shape[:3], -1)
+    return jnp.einsum("bcjn,bcjw->bcnw", rows, flat, **_ACC).reshape(
+        *cols.shape[:2], rows.shape[-1], *cols.shape[3:])
+
+
+def chunked_scan(x, dt, a, b, c, d_skip, chunk):
+    """x [batch, seq, heads * head_dim], b and c [batch, seq, d_state] in
+    the compute type; dt (after the softplus), a = dt * A [batch, seq,
+    heads] and d_skip [heads] float32 -> y float32 [batch, seq, heads *
+    head_dim] and the states entering the chunks, float32 [batch,
+    chunks, d_state, heads * head_dim]."""
+    heads = dt.shape[-1]
+    xc = _chunks(x.reshape(*x.shape[:2], heads, -1), chunk)
+    bc, cc, dtc = _chunks(b, chunk), _chunks(c, chunk), _chunks(dt, chunk)
+    cum = jnp.cumsum(_chunks(a, chunk), axis=2)
+    xd = xc.astype(F32) * dtc[..., None]
+    with jax.named_scope("ssd_intra"):
+        g = jnp.einsum("bcin,bcjn->bcij", cc, bc, **_ACC)
+        m = (_decay_mask(cum) * g[:, :, None]).astype(x.dtype)
+        y = jnp.einsum("bchij,bcjhp->bcihp", m, xd.astype(x.dtype), **_ACC)
+    with jax.named_scope("ssd_state"):
+        to_end = jnp.exp(cum[:, :, -1:] - cum)
+        local = _state_product(bc, (xd * to_end[..., None]).astype(x.dtype))
+        entering = _carried(jnp.exp(cum[:, :, -1]), local)
+    with jax.named_scope("ssd_inter"):
+        y = y + jnp.exp(cum)[..., None] * jnp.einsum(
+            "bcin,bcnhp->bcihp", cc, entering.astype(x.dtype), **_ACC)
+    y = y + d_skip[:, None] * xc.astype(F32)
+    return y.reshape(x.shape), entering.reshape(*entering.shape[:3], -1)
+
+
+def chunked_scan_grad(x, dt, a, b, c, d_skip, states, dy, chunk):
+    """The gradient of `chunked_scan` to x (in dy's type), dt and a (as
+    if independent), b, c and d_skip (float32), from the states the
+    forward kept."""
+    heads = dt.shape[-1]
+    split = lambda t: _chunks(t.reshape(*t.shape[:2], heads, -1), chunk)
+    xc, dyc = split(x), split(dy)
+    bc, cc, dtc = _chunks(b, chunk), _chunks(c, chunk), _chunks(dt, chunk)
+    cum = jnp.cumsum(_chunks(a, chunk), axis=2)
+    entering = states.reshape(*states.shape[:3], heads, -1)
+    kind = x.dtype
+    xf, dyf = xc.astype(F32), dyc.astype(F32)
+    xd = xf * dtc[..., None]
+    with jax.named_scope("ssd_inter"):
+        from_start = jnp.exp(cum)[..., None]
+        dy_dec = (dyf * from_start).astype(kind)
+        d_entering = _state_product(cc, dy_dec)
+        dc = jnp.einsum("bcihp,bcnhp->bcin", dy_dec, entering.astype(kind),
+                        **_ACC)
+        # sum_p dY_i Y_i of what the entering state gave: C S_in^T again
+        d_cum = jnp.sum(dyf * from_start * jnp.einsum(
+            "bcin,bcnhp->bcihp", cc, entering.astype(kind), **_ACC), axis=-1)
+    with jax.named_scope("ssd_state"):
+        dec = jnp.exp(cum[:, :, -1])
+        # the cotangent of the state leaving each chunk
+        d_leaving = _carried(dec, d_entering, reverse=True)
+        to_end = jnp.exp(cum[:, :, -1:] - cum)
+        dxd_state = to_end[..., None] * jnp.einsum(
+            "bcjn,bcnhp->bcjhp", bc, d_leaving.astype(kind), **_ACC)
+        db = jnp.einsum("bcjhp,bcnhp->bcjn",
+                        (xd * to_end[..., None]).astype(kind),
+                        d_leaving.astype(kind), **_ACC)
+        owed = jnp.sum(dxd_state * xd, axis=-1)
+        # the last position's decay is also the whole chunk's, to the
+        # state that is handed on
+        d_cum = (d_cum - owed).at[:, :, -1].add(
+            jnp.sum(owed, axis=2)
+            + dec * jnp.sum(d_leaving * entering, axis=(2, 4)))
+    with jax.named_scope("ssd_intra"):
+        g = jnp.einsum("bcin,bcjn->bcij", cc, bc, **_ACC)
+        mask = _decay_mask(cum)
+        dm = jnp.einsum("bcihp,bcjhp->bchij", dyc.astype(kind),
+                        xd.astype(kind), **_ACC) * mask
+        dg = jnp.sum(dm, axis=2).astype(kind)
+        dc = dc + jnp.einsum("bcij,bcjn->bcin", dg, bc, **_ACC)
+        db = db + jnp.einsum("bcij,bcin->bcjn", dg, cc, **_ACC)
+        dxd = dxd_state + jnp.einsum(
+            "bchij,bcihp->bcjhp", (mask * g[:, :, None]).astype(kind),
+            dyc.astype(kind), **_ACC)
+        # E[i, j] = dL[i, j] L[i, j] for i > j: position i's sum gains its
+        # row, position j's loses its column (the diagonal, where L is 1
+        # whatever the decay, would cancel, and is left out of both)
+        q = mask.shape[-1]
+        e = jnp.where(jnp.tril(jnp.ones((q, q), bool), -1),
+                      dm * g[:, :, None], 0.0)
+        d_cum = d_cum + jnp.swapaxes(jnp.sum(e, -1) - jnp.sum(e, -2), 2, 3)
+    da = jnp.flip(jnp.cumsum(jnp.flip(d_cum, 2), axis=2), 2)
+    ddt = jnp.sum(dxd * xf, axis=-1)
+    dx = dxd * dtc[..., None] + d_skip[:, None] * dyf
+    dd = jnp.sum(dyf * xf, axis=(0, 1, 2, 4))
+    flat = lambda t: t.reshape(t.shape[0], -1, *t.shape[3:])
+    return (dx.astype(dy.dtype).reshape(x.shape), flat(ddt), flat(da),
+            flat(db), flat(dc), dd)
+
+
+# -- ssd_scan --------------------------------------------------------------------
+
+def _scan_sizes(x_shape, dt_shape, chunk):
+    batch, seq, width = (int(s) for s in x_shape)
+    heads = int(dt_shape[-1])
+    if width % heads:
+        raise ValueError("ssd_scan: X's width %d is no multiple of %d heads"
+                         % (width, heads))
+    if seq % chunk:
+        raise ValueError(
+            "ssd_scan: a sequence of %d positions is no multiple of the "
+            "chunk (%d); pad the data, the op pads nothing" % (seq, chunk))
+    return batch, seq, width, heads
+
+
+def _scan_infer_shape(block, op_desc):
+    x = block.var_recursive(op_desc.input("X")[0]).desc
+    dt = block.var_recursive(op_desc.input("Dt")[0]).desc
+    b = block.var_recursive(op_desc.input("B")[0]).desc
+    chunk = int(op_desc.attrs["chunk_size"])
+    batch, seq, width, _ = _scan_sizes(x.shape, dt.shape, chunk)
+    _set_meta(block, op_desc.output("Y")[0], x.shape, x.dtype)
+    _set_meta(block, op_desc.output("States")[0],
+              (batch, seq // chunk, int(b.shape[-1]), width), "float32")
+
+
+def _steps(dt_raw, dt_bias, a_log):
+    """dt = softplus(Dt + DtBias) and a = dt * A, A = -exp(ALog), float32
+    [batch, seq, heads]; and A."""
+    neg_a = -jnp.exp(a_log.astype(F32))
+    dt = jax.nn.softplus(dt_raw.astype(F32) + dt_bias.astype(F32))
+    return dt, dt * neg_a, neg_a
+
+
+def _scan_inputs(ins):
+    x, b, c = mxu_operands(ins["X"][0], ins["B"][0], ins["C"][0])
+    return x, b, c, ins["Dt"][0], ins["DtBias"][0], ins["ALog"][0], \
+        ins["D"][0].astype(F32)
+
+
+@register_op("ssd_scan", infer_shape=_scan_infer_shape)
+def ssd_scan(ctx, ins, attrs):
+    """X [batch, seq, heads * head_dim], Dt [batch, seq, heads] (before
+    the softplus), DtBias, ALog, D [heads], B and C [batch, seq,
+    d_state] -> Y, X's shape and type, and States (the module's
+    docstring)."""
+    from ..kernels import ssd
+
+    x, b, c, dt_raw, dt_bias, a_log, d_skip = _scan_inputs(ins)
+    chunk = int(attrs["chunk_size"])
+    _scan_sizes(x.shape, dt_raw.shape, chunk)
+    with jax.named_scope("ssd_decay"):
+        dt, a, _ = _steps(dt_raw, dt_bias, a_log)
+    y, states = ssd.scan(x, dt, a, b, c, d_skip, chunk,
+                         plain=chunked_scan)
+    return {"Y": [amp_result(y, ins["X"][0].dtype)], "States": [states]}
+
+
+@register_grad_kernel("ssd_scan")
+def ssd_scan_grad(ctx, ins, attrs):
+    """The seven gradients from O@States and OG@Y; X's in dY's type,
+    B's and C's in theirs, the parameters' float32."""
+    from ..kernels import ssd
+
+    x, b, c, dt_raw, dt_bias, a_log, d_skip = _scan_inputs(ins)
+    chunk = int(attrs["chunk_size"])
+    states, dy = ins["O@States"][0], ins["OG@Y"][0]
+    with jax.named_scope("ssd_decay"):
+        dt, a, neg_a = _steps(dt_raw, dt_bias, a_log)
+    dx, ddt, da, db, dc, dd = ssd.scan_grad(
+        x, dt, a, b, c, d_skip, states, dy.astype(x.dtype), chunk,
+        plain=chunked_scan_grad)
+    with jax.named_scope("ssd_decay"):
+        ddt = ddt + da * neg_a
+        d_a_log = jnp.sum(da * dt, axis=(0, 1)) * neg_a
+        # softplus' = sigmoid = 1 - exp(-softplus)
+        d_raw = ddt * -jnp.expm1(-dt)
+    return {"X@GRAD": [dx.astype(dy.dtype)],
+            "Dt@GRAD": [d_raw.astype(dt_raw.dtype)],
+            "DtBias@GRAD": [jnp.sum(d_raw, axis=(0, 1))
+                            .astype(dt_bias.dtype)],
+            "ALog@GRAD": [d_a_log.astype(a_log.dtype)],
+            "B@GRAD": [db.astype(ins["B"][0].dtype)],
+            "C@GRAD": [dc.astype(ins["C"][0].dtype)],
+            "D@GRAD": [dd.astype(ins["D"][0].dtype)]}
+
+
+# -- causal_conv1d ---------------------------------------------------------------
+
+_ACTIVATIONS = ("", "silu")
+
+
+def _conv_infer_shape(block, op_desc):
+    x = block.var_recursive(op_desc.input("X")[0]).desc
+    _set_meta(block, op_desc.output("Out")[0], x.shape, x.dtype)
+
+
+def _shifted(x, width, j):
+    """x_{t - (width - 1) + j} at position t, zeros before position 0."""
+    back = width - 1 - j
+    if not back:
+        return x
+    return jnp.pad(x, ((0, 0), (back, 0), (0, 0)))[:, :x.shape[1]]
+
+
+def _pre_activation(x, filt, bias):
+    """bias + sum_j filter[:, j] x_{t-(K-1)+j}, float32."""
+    width = filt.shape[1]
+    xf, w = x.astype(F32), filt.astype(F32)
+    return bias.astype(F32) + sum(
+        _shifted(xf, width, j) * w[:, j] for j in range(width))
+
+
+def _conv_attrs(attrs):
+    act = attrs.get("activation", "") or ""
+    if act not in _ACTIVATIONS:
+        raise ValueError("causal_conv1d: activation %r (one of %s)"
+                         % (act, _ACTIVATIONS))
+    return act
+
+
+@register_op("causal_conv1d", infer_shape=_conv_infer_shape)
+def causal_conv1d(ctx, ins, attrs):
+    """X [batch, seq, channels], Filter [channels, width], Bias
+    [channels] -> Out, X's shape and type (the module's docstring);
+    attr `activation` "" or "silu".  Sums in float32."""
+    x, filt, bias = ins["X"][0], ins["Filter"][0], ins["Bias"][0]
+    act = _conv_attrs(attrs)
+    telemetry.on_causal_conv1d_lowering(filt.shape[1], act or "none")
+    pre = _pre_activation(x, filt, bias)
+    out = pre * jax.nn.sigmoid(pre) if act == "silu" else pre
+    return {"Out": [out.astype(x.dtype)]}
+
+
+@register_grad_kernel("causal_conv1d")
+def causal_conv1d_grad(ctx, ins, attrs):
+    """X@GRAD (dOut's type), Filter@GRAD and Bias@GRAD (float32 sums,
+    the parameters' type) from X, Filter, Bias and dOut alone."""
+    x, filt, bias = ins["X"][0], ins["Filter"][0], ins["Bias"][0]
+    d_out = ins["OG@Out"][0]
+    width = filt.shape[1]
+    d_pre = d_out.astype(F32)
+    if _conv_attrs(attrs) == "silu":
+        pre = _pre_activation(x, filt, bias)
+        sig = jax.nn.sigmoid(pre)
+        d_pre = d_pre * sig * (1.0 + pre * (1.0 - sig))
+    xf, w = x.astype(F32), filt.astype(F32)
+    seq = x.shape[1]
+    # position t reaches the outputs t .. t + width - 1
+    ahead = jnp.pad(d_pre, ((0, 0), (0, width - 1), (0, 0)))
+    dx = sum(ahead[:, width - 1 - j:width - 1 - j + seq] * w[:, j]
+             for j in range(width))
+    d_filter = jnp.stack(
+        [jnp.sum(d_pre * _shifted(xf, width, j), axis=(0, 1))
+         for j in range(width)], axis=1)
+    return {"X@GRAD": [dx.astype(d_out.dtype)],
+            "Filter@GRAD": [d_filter.astype(filt.dtype)],
+            "Bias@GRAD": [jnp.sum(d_pre, axis=(0, 1)).astype(bias.dtype)]}

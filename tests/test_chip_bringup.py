@@ -145,6 +145,66 @@ def test_the_expert_op_and_its_gradient_lower_nine_products_for_tpu():
     assert module.count("tpu_custom_call") == 9
 
 
+def test_the_scan_kernels_lower_for_tpu():
+    """The chunked state-space scan's two kernels at the shapes of
+    granite-train-4k (1 x 4096 positions, 64 heads of 64, state 128,
+    chunk 256, bfloat16 operands), lowered for the TPU from this CPU
+    host: one Mosaic kernel each, named with its chunk and the heads a
+    grid step takes."""
+    import functools
+
+    from paddle_tpu.kernels import ssd
+
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    wide = jax.ShapeDtypeStruct((1, 4096, 4096), bf16)
+    steps = jax.ShapeDtypeStruct((1, 4096, 64), f32)
+    narrow = jax.ShapeDtypeStruct((1, 4096, 128), bf16)
+    skip = jax.ShapeDtypeStruct((64,), f32)
+    states = jax.ShapeDtypeStruct((1, 16, 128, 4096), f32)
+    fwd = jax.export.export(
+        jax.jit(functools.partial(ssd.fwd_kernels, chunk=256)),
+        platforms=["tpu"])(wide, steps, steps, narrow, narrow,
+                           skip).mlir_module()
+    assert fwd.count("tpu_custom_call") == 1
+    assert 'kernel_name = "ssd_fwd_c256_h2"' in fwd
+    bwd = jax.export.export(
+        jax.jit(functools.partial(ssd.bwd_kernels, chunk=256)),
+        platforms=["tpu"])(wide, steps, steps, narrow, narrow, skip,
+                           states, wide).mlir_module()
+    assert bwd.count("tpu_custom_call") == 1
+    assert 'kernel_name = "ssd_bwd_c256_h2"' in bwd
+
+
+def test_the_scan_op_and_its_gradient_lower_one_kernel_each_for_tpu():
+    """`ssd_scan` and its explicit gradient at the cell's shapes as one
+    TPU program under bfloat16 compute: the forward kernel once, the
+    backward kernel once, no forward kernel under the gradient."""
+    from paddle_tpu.ops import registry
+
+    info = registry.get_op_info("ssd_scan")
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    attrs = {"num_heads": 64, "chunk_size": 256}
+    ins = {"X": [jax.ShapeDtypeStruct((1, 4096, 4096), bf16)],
+           "Dt": [jax.ShapeDtypeStruct((1, 4096, 64), bf16)],
+           "B": [jax.ShapeDtypeStruct((1, 4096, 128), bf16)],
+           "C": [jax.ShapeDtypeStruct((1, 4096, 128), bf16)]}
+    ins.update({slot: [jax.ShapeDtypeStruct((64,), f32)]
+                for slot in ("DtBias", "ALog", "D")})
+
+    def step(ins, dy):
+        outs = info.kernel(None, ins, attrs)
+        grad_ins = dict(ins, **{"OG@Y": [dy]})
+        grad_ins.update({"O@" + slot: v for slot, v in outs.items()})
+        return outs["Y"], info.grad_kernel(None, grad_ins, attrs)
+
+    with fluid.amp.bf16_guard():
+        module = jax.export.export(jax.jit(step), platforms=["tpu"])(
+            ins, ins["X"][0]).mlir_module()
+    assert [module.count('kernel_name = "ssd_%s_c256_h2"' % kernel)
+            for kernel in ("fwd", "bwd")] == [1, 1]
+    assert module.count("tpu_custom_call") == 2
+
+
 def test_flash_attention_refuses_a_ragged_block():
     """A sequence its block does not divide raises with the shape in the
     message; the block no longer shrinks toward 1 without saying so."""
