@@ -378,8 +378,9 @@ def overlap_report(trainer, feeds, reps=3, bucket_report=None):
     comm_s = float(bucket_report["measured_s"]) if bucket_report \
         else 0.0
 
-    # the real overlapped step (trainer.step blocks on fetches; block
-    # the state too so the timed wall covers the whole executable)
+    # the real overlapped step (trainer.step returns with the step in
+    # flight, its fetches pending: block on the state so the timed wall
+    # covers the whole executable)
     trainer.step(feeds)                          # warm / poison jit
     jax.block_until_ready(trainer.state)
     bookmark = trace_mod.event_count()

@@ -148,6 +148,16 @@ class ParallelTrainer:
                                   fetch_names=[loss.name], mesh=mesh)
         trainer.init()                       # run startup, shard params
         (loss,) = trainer.step({"image": x, "label": y})
+
+    `step()` keeps one step in flight: it dispatches step N, waits for
+    step N-1 and returns step N's fetches as `jax.Array`s that may
+    still be pending, so the host prepares the next step while the
+    device runs this one.  Reading a fetch on the host waits for it;
+    the state is whole once `jax.block_until_ready(trainer.state)` or
+    any host read of it (`fetch_state`, `dump_state_to`, a checkpoint)
+    returns.  With the numerics monitor, the flight recorder or a
+    telemetry step observer on, which need a step's values on the host
+    inside that step, `step()` waits for its own fetches instead.
     """
 
     def __init__(self, main_program, startup_program, feed_names,
@@ -165,7 +175,20 @@ class ParallelTrainer:
         self._base_rng = jax.random.PRNGKey(seed)
         self._step_count = 0
         self._step_fn = None
+        self._monitor = None
         self.state = None
+
+    @property
+    def state(self):
+        return self._state
+
+    @state.setter
+    def state(self, value):
+        # whoever hands the trainer a state (init, a restore) starts it
+        # afresh: the step in flight ran on the state this replaces,
+        # and what it raised is not the next step's to report
+        self._state = value
+        self._in_flight = None
 
     def init(self, scope=None, executor=None):
         """Run the startup program (single device), then lay the state out
@@ -243,42 +266,59 @@ class ParallelTrainer:
                 timer.examples = next(
                     (int(v.shape[0]) for v in feeds.values()
                      if getattr(v, "ndim", 0)), None)
+                described = obs_flight.describe_feeds(feeds)
+            monitor = self._monitor
+            recording = obs_flight.active()
+            # whoever needs this step's values on the host inside this
+            # step makes it wait for its own fetches; otherwise one
+            # step stays in flight and the wait is for the one before
+            own = (monitor is not None or recording
+                   or obs_tele.step_observer() is not None)
+            blamed = step_id, described
             try:
                 # trace under the mesh context so mesh-aware op kernels
                 # (ring flash_attention) see the sp topology
                 with obs_trace.span("parallel/dispatch", cat="trainer"), \
                         jax.set_mesh(self.mesh):
-                    fetches, self.state = self._step_fn(self.state,
-                                                        feeds, rng)
-                # block on the fetches so trainer_step_seconds is device
-                # time, never just the async dispatch (~us).  Fetches
-                # are the replicated loss/metric scalars every caller
-                # reads right after, and new_state materializes in the
-                # same executable, so this costs the host-side
-                # feed-prep overlap only.
-                with obs_trace.span("parallel/wait", cat="trainer"):
-                    jax.block_until_ready(fetches)
+                    fetches, self._state = self._step_fn(self._state,
+                                                         feeds, rng)
+                # bounded run-ahead: the device always has the next
+                # step queued behind the one it runs, the host is never
+                # more than one step ahead, and trainer_step_seconds
+                # stays a step's worth of wall time (the dispatch of
+                # this step and the rest of the last), never just the
+                # async dispatch.  The fetches returned may be pending.
+                this = step_id, described, fetches
+                if own:
+                    waited, self._in_flight = this, None
+                else:
+                    waited, self._in_flight = self._in_flight, this
+                with obs_trace.span(
+                        "parallel/wait", cat="trainer",
+                        for_step=-1 if waited is None else waited[0],
+                        own=int(own)):
+                    if waited is not None:
+                        blamed = waited[:2]
+                        jax.block_until_ready(waited[2])
             except Exception as exc:
-                obs_flight.on_crash(
-                    exc, origin="parallel/step", step=step_id,
-                    feeds=obs_flight.describe_feeds(feeds))
+                obs_flight.on_crash(exc, origin="parallel/step",
+                                    step=blamed[0], feeds=blamed[1])
                 raise
             with obs_trace.span("parallel/record", cat="trainer"):
                 timer.record()
-                monitor = getattr(self, "_monitor", None)
                 if monitor is not None:
                     n_user = len(self.fetch_names)
                     monitor.record(dict(zip(monitor.fetch_names,
                                             fetches[n_user:])))
                     fetches = fetches[:n_user]
-                if obs_flight.active():
+                if recording:
                     loss = None
                     first = fetches[0] if fetches else None
                     if first is not None \
                             and getattr(first, "size", 0) == 1:
                         loss = float(np.asarray(first).reshape(-1)[0])
                     obs_flight.record_step("parallel", step_id,
-                                           feeds=feeds, loss=loss)
+                                           feeds=described, loss=loss)
         return fetches
 
     def fetch_state(self, name):

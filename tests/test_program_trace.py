@@ -124,6 +124,9 @@ def test_trainer_step_is_an_enclosing_span_with_four_children(tmp_path):
         starts = [c[0][1] for c in children]
         assert starts == sorted(starts)
         assert _inside(line, children[1][0], "PjitFunction(step)")
+        # one step in flight: the wait is for the step before
+        assert children[2][0][3] == {"for_step": step[3]["step"] - 1,
+                                     "own": 0}
     # the step's telemetry is still fed, once a step
     snap = obs_tele.snapshot()
     assert snap["trainer_steps_total{trainer=parallel}"] == 3
