@@ -241,6 +241,38 @@ def _find_var_desc_or_none(program, block_idx, name):
         bd = program.desc.block(bd.parent_idx)
 
 
+# what starts an op instance's scope: no scope a kernel opens and no op
+# type starts with it, so a reader that looks a scope up by name cannot
+# meet an instance
+INSTANCE_SIGIL = "~"
+# an `op_name` path is split on "/", XLA joins the paths of merged
+# instructions with ";", and what follows an "@" never reaches the
+# compiled program (a location's `name@callsite`)
+_PATH_SYNTAX = str.maketrans("/;@", "...")
+
+
+def op_instance(op_desc):
+    """Which op of its type an op is, as the scope `apply_op` opens inside
+    the type's: the sigil and the name of a variable the op is bound to.
+
+    A forward op has the name of its first output (the op's own slot
+    order, "@EMPTY@" skipped).  A `<type>_grad` op has the name its
+    forward op has: `append_backward` hands it the forward's outputs as
+    the `O@<slot>` inputs, in the forward's order, so an op and its
+    gradient share an instance and join without a table.  An op that
+    updates a parameter (the optimizers' ops, a fused update) has its
+    first `Param`.  A weight applied four times gives four instances;
+    two ops of one type that write one variable in place share one."""
+    names = op_desc.input("Param")
+    if not names and op_registry.is_grad_op_type(op_desc.type):
+        names = [n for slot, vs in op_desc.inputs.items()
+                 if slot.startswith("O@") for n in vs if n != "@EMPTY@"]
+    if not names:
+        names = [n for n in op_desc.output_names() + op_desc.input_names()
+                 if n != "@EMPTY@"] or [op_desc.type]
+    return INSTANCE_SIGIL + names[0].translate(_PATH_SYNTAX)
+
+
 def apply_op(ctx, op_desc):
     """Apply one op's kernel against ctx.env (pure; used both under trace
     and eagerly)."""
@@ -262,11 +294,12 @@ def apply_op(ctx, op_desc):
         ins[slot] = [None if n == "@EMPTY@" else _env_get(ctx, n)
                      for n in names]
 
-    # the op's type on everything it lowers to: the compiled program's
-    # `op_name` metadata, and with it a device trace, says which op (and,
-    # by the `_grad` suffix or the optimizer's type, which pass) an
-    # instruction came from.  Trace-time only; no arithmetic changes.
-    with jax.named_scope(t):
+    # the op's type, and inside it the op's instance, on everything it
+    # lowers to: the compiled program's `op_name` metadata, and with it a
+    # device trace, says which op of the Program (and, by the `_grad`
+    # suffix or the optimizer's type, which pass) an instruction came
+    # from.  Trace-time only; no arithmetic changes.
+    with jax.named_scope(t), jax.named_scope(op_instance(op_desc)):
         if is_generic_grad:
             outs = op_registry.run_generic_grad(
                 ctx, op_registry.forward_type_of_grad(t), ins,

@@ -233,3 +233,205 @@ def test_jit_phases_are_counted_per_function():
     exe.run(main, feed=feeds, fetch_list=[loss], scope=scope)
     assert {k: v for k, v in obs_tele.snapshot_delta(snap).items()
             if k.startswith("jit_phase_seconds_total")} == {}
+
+
+# -- the op's instance inside its type ----------------------------------------
+
+from paddle_tpu.fluid.executor import INSTANCE_SIGIL, op_instance  # noqa: E402
+
+
+def _instances_under(names, op_type):
+    """The instance components right after `op_type` in the paths."""
+    found = set()
+    for n in names:
+        parts = n.split(";")[0].split("/")
+        for i, part in enumerate(parts[:-1]):
+            if re.sub(r"^(\w+\()*|\)*$", "", part) == op_type \
+                    and parts[i + 1].startswith(INSTANCE_SIGIL):
+                found.add(parts[i + 1])
+    return found
+
+
+def _conv_program():
+    fluid.framework.reset_unique_name()
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        img = fluid.layers.data(name="img", shape=[2, 3, 8, 8],
+                                dtype="float32", append_batch_size=False)
+        label = fluid.layers.data(name="label", shape=[2, 1],
+                                  dtype="int64", append_batch_size=False)
+        conv = fluid.layers.conv2d(input=img, num_filters=4,
+                                   filter_size=3, padding=1)
+        conv = fluid.layers.conv2d(input=conv, num_filters=4,
+                                   filter_size=1)
+        bn = fluid.layers.batch_norm(input=conv, act="relu")
+        logits = fluid.layers.fc(input=bn, size=3)
+        loss = fluid.layers.mean(
+            fluid.layers.softmax_with_cross_entropy(logits, label))
+        fluid.optimizer.MomentumOptimizer(
+            learning_rate=0.1, momentum=0.9).minimize(loss)
+    feeds = {"img": jnp.ones((2, 3, 8, 8), jnp.float32),
+             "label": jnp.zeros((2, 1), jnp.int32)}
+    return main, startup, loss, feeds
+
+
+def _compiled_names(main, startup, loss, feeds):
+    scope = fluid.Scope()
+    fluid.Executor(fluid.CPUPlace()).run(startup, scope=scope)
+    fp = FunctionalProgram(main, sorted(feeds), [loss.name])
+    return _op_names(fp, state_from_scope(fp, scope), feeds)
+
+
+def test_compiled_program_names_each_op_instance_under_its_type():
+    main, startup, loss, feeds = _conv_program()
+    names = _compiled_names(main, startup, loss, feeds)
+    ops = main.global_block().desc.ops
+    convs = [od for od in ops if od.type == "conv2d"]
+    assert [op_instance(od) for od in convs] == \
+        [INSTANCE_SIGIL + od.output("Output")[0] for od in convs]
+    assert len(set(map(op_instance, convs))) == 2
+    # the two convolutions are told apart, forward and backward, and an
+    # op and its gradient share a name
+    wanted = {op_instance(od) for od in convs}
+    assert _instances_under(names, "conv2d") == wanted
+    assert _instances_under(names, "conv2d_grad") == wanted
+    grads = [od for od in ops if od.type == "conv2d_grad"]
+    assert sorted(map(op_instance, grads)) == sorted(wanted)
+    # the type stays the first component after the jit wrapper
+    assert any(re.match(r"^jit\([^)]*\)/conv2d/%s[^/]+/" % INSTANCE_SIGIL, n)
+               for n in names)
+    # an optimizer op has its parameter's name
+    updates = [od for od in ops if od.type == "momentum"]
+    assert len(updates) == 8
+    assert [op_instance(od) for od in updates] == \
+        [INSTANCE_SIGIL + od.input("Param")[0] for od in updates]
+    assert _instances_under(names, "momentum") == \
+        set(map(op_instance, updates))
+
+
+@pytest.mark.parametrize("op_type", [
+    "conv2d", "batch_norm", "mul", "elementwise_add", "relu", "mean",
+    "softmax_with_cross_entropy"])
+def test_an_op_and_its_gradient_share_an_instance(op_type):
+    main, _, _, _ = _conv_program()
+    ops = main.global_block().desc.ops
+    forward = sorted(op_instance(od) for od in ops if od.type == op_type)
+    backward = sorted(op_instance(od) for od in ops
+                      if od.type == op_type + "_grad")
+    assert forward and forward == backward
+
+
+def test_a_weight_applied_twice_gives_two_instances():
+    from paddle_tpu.fluid.param_attr import ParamAttr
+
+    fluid.framework.reset_unique_name()
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        x = fluid.layers.data(name="x", shape=[8, 16], dtype="float32",
+                              append_batch_size=False)
+        h = x
+        for _ in range(2):
+            h = fluid.layers.fc(input=h, size=16, bias_attr=False,
+                                param_attr=ParamAttr(name="shared.w"))
+        loss = fluid.layers.mean(h)
+        fluid.optimizer.SGD(learning_rate=0.1).minimize(loss)
+    ops = main.global_block().desc.ops
+    products = [od for od in ops if od.type == "mul"]
+    assert [od.input("Y") for od in products] == [["shared.w"]] * 2
+    assert len({op_instance(od) for od in products}) == 2
+    names = _compiled_names(main, startup, loss,
+                            {"x": jnp.ones((8, 16), jnp.float32)})
+    wanted = {op_instance(od) for od in products}
+    assert _instances_under(names, "mul") == wanted
+    assert _instances_under(names, "mul_grad") == wanted
+    # one parameter, one update, named for it
+    assert _instances_under(names, "sgd") == {INSTANCE_SIGIL + "shared.w"}
+
+
+def test_an_op_in_a_sub_block_nests_under_the_op_that_holds_it():
+    fluid.framework.reset_unique_name()
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        x = fluid.layers.data(name="x", shape=[1, 4], dtype="float32",
+                              append_batch_size=False)
+        acc = fluid.layers.fill_constant(shape=[1, 4], dtype="float32",
+                                         value=0.0)
+        i = fluid.layers.fill_constant(shape=[1], dtype="int64", value=0)
+        limit = fluid.layers.fill_constant(shape=[1], dtype="int64",
+                                           value=3)
+        cond = fluid.layers.less_than(x=i, y=limit)
+        loop = fluid.layers.While(cond=cond, max_steps=8)
+        with loop.block():
+            t = fluid.layers.scale(x=x, scale=3.0)
+            fluid.layers.sums(input=[acc, t], out=acc)
+            fluid.layers.increment(x=i, value=1, in_place=True)
+            fluid.layers.less_than(x=i, y=limit, cond=cond)
+        loss = fluid.layers.mean(x=acc)
+    names = _compiled_names(main, startup, loss,
+                            {"x": jnp.ones((1, 4), jnp.float32)})
+    outer, = [od for od in main.global_block().desc.ops
+              if od.type == "while"]
+    inner = "scale/%s%s" % (INSTANCE_SIGIL, t.name)
+    nested = [n for n in names if inner in n]
+    assert nested
+    for n in nested:
+        assert re.match(r"^jit\([^)]*\)/while/%s/.*%s/"
+                        % (re.escape(op_instance(outer)), re.escape(inner)),
+                        n), n
+
+
+def _scopes_the_kernels_open():
+    """Every literal (or module constant) handed to `jax.named_scope`
+    under paddle_tpu/, and every registered op type."""
+    import paddle_tpu
+    from paddle_tpu.ops import registry
+
+    root = os.path.dirname(paddle_tpu.__file__)
+    found = set(registry.registered_ops())
+    for path in glob.glob(os.path.join(root, "**", "*.py"), recursive=True):
+        with open(path) as f:
+            text = f.read()
+        found.update(re.findall(r'named_scope\(\s*"([^"]+)"', text))
+        for const in re.findall(r"named_scope\(\s*([A-Z_]+)\s*\)", text):
+            found.update(re.findall(r'^%s\s*=\s*"([^"]+)"' % const, text,
+                                    re.M))
+    return found
+
+
+@pytest.mark.parametrize("first_output", [
+    "conv2d_7.tmp_0", "scope/of/names/fc_0.tmp_1", "fc_0.w_0@GRAD",
+    "a;b", "sum", "rope", "moe_route", "ssd_decay", "flash_attention_bwd",
+    "pp_stage", "mul"])
+def test_no_instance_breaks_a_path_or_equals_a_scope(first_output):
+    from paddle_tpu.core.desc import OpDesc
+
+    scopes = _scopes_the_kernels_open()
+    assert {"moe_route", "ssd_decay", "flash_attention_bwd",
+            "pp_stage"} <= scopes
+    for od in (OpDesc("scale", {"X": ["x"]},
+                      {"Out": ["@EMPTY@", first_output]}),
+               OpDesc("scale_grad", {"X": ["x"], "O@Out": [first_output],
+                                     "OG@Out": ["g"]}, {"X@GRAD": ["gx"]}),
+               OpDesc("adam", {"Param": [first_output], "Grad": ["g"]},
+                      {"ParamOut": [first_output]})):
+        name = op_instance(od)
+        assert name.startswith(INSTANCE_SIGIL) and len(name) > 1
+        assert not set("/;@") & set(name)
+        assert name not in scopes
+        # what a reader's path parser makes of it: itself
+        assert re.match(r"^\w+\((.*)\)$", name) is None
+    # and no scope a kernel opens, and no op type, starts like one
+    assert not [s for s in scopes if s.startswith(INSTANCE_SIGIL)]
+
+
+def test_an_op_without_outputs_still_has_an_instance():
+    from paddle_tpu.core.desc import OpDesc
+
+    assert op_instance(OpDesc("print", {"In": ["x"]}, {})) == \
+        INSTANCE_SIGIL + "x"
+    assert op_instance(OpDesc("barrier", {}, {})) == \
+        INSTANCE_SIGIL + "barrier"
+    # a fused update names its first parameter
+    assert op_instance(OpDesc(
+        "fused_update", {"Param": ["w0", "w1"], "Grad": ["g0", "g1"]},
+        {"ParamOut": ["w0", "w1"]})) == INSTANCE_SIGIL + "w0"
