@@ -18,12 +18,29 @@ dq over the keys it sees.
 How much one grid step does is chosen from the shapes
 (`_choose_blocks`, `_choose_bwd_blocks`): a grid step costs about
 0.5 us empty and every chunk has costs of its own, so the blocks are
-as large as the VMEM budget allows, and a causal block at most half its
-sequence so that the diagonal still cuts work off.  Where the side a
-kernel walks (one head's K and V in the forward, its queries in the
-backward) fits the budget beside a chunk it stays resident across the
-head's blocks and the chunk loop stops at the causal diagonal; where it
-does not, the grid's innermost axis walks it one chunk a step.
+as large as the VMEM budget allows, under a causal mask too.  Where the
+side a kernel walks (one head's K and V in the forward, its queries in
+the backward) fits the budget beside a chunk it stays resident across
+the head's blocks and the chunk loop stops at the causal diagonal;
+where it does not, the grid's innermost axis walks it one chunk a step.
+
+A chunk every query sees is folded whole and unmasked, one above the
+causal diagonal not at all.  One the diagonal crosses is folded as a
+staircase (`_stairs`): pieces of `_STAIR` keys, each over only the
+queries at or after its first key, the mask's iota / compare / select
+on the piece's leading square alone.  The
+queries a piece leaves out are those the mask would set to NEG_INF,
+which add exp(NEG_INF - m) = 0: the sums hold the same terms.  A chunk
+of n pieces then costs (n + 1) / 2n of its pairs, whatever the blocks,
+which is why a causal block may be its whole sequence.  Where the
+diagonal enters a chunk has to be known when the kernel is traced: the
+blocks and `q_offset` multiples of a piece (`_stair_width`; a `pl.when`
+for each place, `_leads`, where several chunks of a grid step are
+crossed).  Elsewhere (a decode step's offset, a ragged sequence that is
+one block, a sequence shard at an odd offset) and in the two backward
+kernels that walk, a crossed chunk is folded whole through the same
+fold, every score compared.  `score_pairs` counts what either way
+folds, and the counter `flash_attention_pairs_total` reports it.
 
 Two layouts, one set of kernel bodies.  `flash_attention`,
 `flash_attention_with_lse` and `_bwd` take q, k, v (and do) as
@@ -55,6 +72,7 @@ any other platform is refused at lowering.
 import collections
 import functools
 import itertools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -71,6 +89,16 @@ _LANES = 128
 _VMEM_BUDGET = 14 * 2 ** 20
 # the block sizes the chooser tries: multiples of the MXU's 128 rows
 _BLOCKS = (1024, 512, 256, 128)
+# the keys a piece of a staircase holds at most (`_stairs`).  ms a call
+# on the v5e at (bq, bk), whole / 128 / 256 (scripts/flash_stair_bench.py;
+# PERF.md section 6, PR 34): forward [8, 1024, 16 x 64] at (1024, 1024)
+# 0.570 / 0.425 / 0.405 and at (512, 512) 0.488 / 0.478 / 0.466,
+# [1, 4096, 16 x 128] at (1024, 512) 0.792 / 0.717 / 0.718; backward at
+# (512, 512) 0.735 / 0.626 / 0.634, at (512, 256) 1.278 / 1.227 / 1.222.
+# A piece rescales the forward's accumulator and adds to the backward's
+# dq^T once more, and is a body more to trace and lower at every start
+# of a process: 128 buys the backward 1.4% and the forward nothing.
+_STAIR = 256
 # the scope the backward's operations lie under, whoever calls `_bwd`:
 # the benchmark's readers find them by it
 BWD_SCOPE = "flash_attention_bwd"
@@ -88,14 +116,10 @@ def _block(seq, block, what, shape):
     return block
 
 
-def _candidates(seq, causal):
+def _candidates(seq):
     """Block sizes to try along one axis, largest first: those that
-    divide the sequence, else the whole of one that fits in a block.  A
-    causal block is at most half its sequence: with one block nothing
-    lies above the diagonal, and every row of a block walks as far as
-    its last row does."""
-    most = max(_LANES, seq // 2) if causal else seq
-    found = [b for b in _BLOCKS if b <= most and seq % b == 0]
+    divide the sequence, else the whole of one that fits in a block."""
+    found = [b for b in _BLOCKS if seq % b == 0]
     return found or ([seq] if seq <= _BLOCKS[0] else [])
 
 
@@ -201,21 +225,20 @@ def _step_bytes(bq, bk, kv_rows, d, itemsize):
     return tiles + scratch + stats + chunk
 
 
-def _choose_blocks(q_shape, k_shape, itemsize, causal, block_q=None,
-                   block_k=None):
+def _choose_blocks(q_shape, k_shape, itemsize, block_q=None, block_k=None):
     """(block_q, block_k, kv_resident) for one call, from what the
-    kernel sees: the sequence lengths, the width of a grid step's
-    heads, the item size and whether the mask is causal.  A block the
-    caller names is kept as it is; what is chosen is the pair that
-    folds most scores at a time under the VMEM budget, with half its
-    block_k if all of one head's K and V then fit beside the fold:
-    `kv_resident` says a grid step holds them all, not one block_k
-    chunk of them."""
+    kernel sees: the sequence lengths, the width of a grid step's heads
+    and the item size (not the mask: the staircase cuts off what lies
+    above a causal diagonal whatever the blocks).  A block the caller
+    names is kept as it is; what is chosen is the pair that folds most
+    scores at a time under the VMEM budget, with half its block_k if
+    all of one head's K and V then fit beside the fold: `kv_resident`
+    says a grid step holds them all, not one block_k chunk of them."""
     tq, d = q_shape[2], q_shape[3]
     tk = k_shape[2]
-    qs = (_candidates(tq, causal) if block_q is None
+    qs = (_candidates(tq) if block_q is None
           else [_block(tq, block_q, "query", q_shape)])
-    ks = (_candidates(tk, causal) if block_k is None
+    ks = (_candidates(tk) if block_k is None
           else [_block(tk, block_k, "key", k_shape)])
     named = block_q is not None and block_k is not None
     fit = [(bq, bk) for bq, bk in sorted(
@@ -240,32 +263,171 @@ def _matmul(a, b, rhs_contracts=0):
                            preferred_element_type=jnp.float32)
 
 
-def _fold_chunks(fold, first, last, masked):
-    lax.fori_loop(first, last, lambda c, _: fold(c, masked), None)
+def _fold_chunks(fold, first, last):
+    lax.fori_loop(first, last, lambda c, _: fold(c), None)
 
 
-def _only_head(x, h, d):
-    """`x` [rows, lanes] with the lanes of every head but the h-th
-    (lanes [h * d, (h + 1) * d)) zeroed, so that a product contracting
-    `x` with all the lanes of another tile is head h's product alone;
-    `x` itself where its lanes are one head's."""
-    if x.shape[1] == d:
+def _stair_width(bq, bk, q_offset, widest):
+    """How many keys wide the pieces are that a chunk the causal
+    diagonal crosses is folded in (`_stairs`): the widest multiple of a
+    lane block up to `widest` that the blocks and the offset are
+    multiples of, or None where there is none and the chunk is folded
+    whole: where the diagonal enters a chunk is then not known when the
+    kernel is traced, or a piece's queries would not start on a lane
+    block (a decode step's offset, a ragged sequence that is one block,
+    a sequence shard at an odd offset)."""
+    for s in range(widest or 0, 0, -_LANES):
+        if bq % s == bk % s == q_offset % s == 0:
+            return s
+    return None
+
+
+def _leads(bq, bk, q_offset):
+    """Every place the diagonal can enter a [bk, bq] chunk it crosses,
+    as how far the chunk's first key lies ahead of its first query: a
+    multiple of what both blocks are multiples of, less the offset,
+    above -bk (at or below it every query sees every key) and below bq
+    (there no query sees any)."""
+    g = math.gcd(bq, bk)
+    return [t for t in range(-q_offset % g - bk, bq, g) if t > -bk]
+
+
+def _stairs(lead, bq, bk, s):
+    """The pieces (first key, keys, first query, crossed) of a [bk, bq]
+    chunk whose first key lies `lead` ahead of its first query, `lead`
+    and both blocks multiples of `s`: the keys behind the first query,
+    which every query sees, as one piece the diagonal does not cross;
+    then `s` keys a piece, each with the queries at or after its first
+    key alone, until the keys or the queries end.  The queries a piece
+    leaves out see none of its keys: whole, the chunk would give them
+    exp(NEG_INF - m) = 0 to add."""
+    pieces = [(0, -lead, 0, False)] if lead < 0 else []
+    for key in range(max(0, -lead), min(bk, bq - lead), s):
+        pieces.append((key, s, lead + key, True))
+    return pieces
+
+
+def _fold_crossed(fold, lead, bq, bk, q_offset, s):
+    """Fold the [bk, bq] chunk the causal diagonal crosses, whose first
+    key lies `lead` (traced) ahead of its first query, through
+    `fold(first key, keys, first query, lead)`: as a staircase of pieces
+    `s` keys wide (`_stairs`) under a `pl.when` for each place the
+    diagonal can enter the chunk; where `s` is None whole, every score
+    compared."""
+    if s is None:
+        return fold(0, bk, 0, lead)
+
+    def staircase(t):
+        def run():
+            for key, keys, query, crossed in _stairs(t, bq, bk, s):
+                fold(key, keys, query, 0 if crossed else None)
+        return run
+
+    leads = _leads(bq, bk, q_offset)
+    if len(leads) == 1:
+        return staircase(leads[0])()
+    for t in leads:
+        pl.when(lax.eq(lead, t))(staircase(t))
+
+
+def _chunk_pairs(lead, bq, bk, s):
+    """The score pairs a kernel folds of a [bk, bq] chunk whose first
+    key lies `lead` ahead of its first query."""
+    if lead >= bq:
+        return 0
+    if lead <= 1 - bk or s is None:
+        return bq * bk
+    return sum(keys * (bq - query)
+               for _, keys, query, _ in _stairs(lead, bq, bk, s))
+
+
+@functools.lru_cache(maxsize=None)
+def score_pairs(tq, tk, causal, q_offset, bq, bk, widest):
+    """(folded, attended): the score pairs the kernels compute for one
+    head at blocks (bq, bk) with pieces up to `widest` keys (None: a
+    kernel that folds a crossed chunk whole whatever the shapes, as the
+    two backward kernels that walk), and those among them a query
+    attends.  The forward (a block of queries over chunks of keys) and
+    the backward (a block of keys over chunks of queries) meet the same
+    [bk, bq] chunks."""
+    if not causal:
+        return tq * tk, tq * tk
+    s = _stair_width(bq, bk, q_offset, widest)
+    folded = sum(_chunk_pairs(c * bk - i * bq - q_offset, bq, bk, s)
+                 for i in range(tq // bq) for c in range(tk // bk))
+    attended = sum(min(max(q_offset + i + 1, 0), tk) for i in range(tq))
+    return folded, attended
+
+
+def _see(x, lead, fill):
+    """`x` [keys, queries] with `fill` where the query does not see the
+    key: a query sees the keys at or before its own position, and the
+    first key lies `lead` ahead of the first query.  Where `lead` is
+    known when the kernel is traced, only the first lead + keys queries
+    can miss a key, and only their columns are compared."""
+    keys, queries = x.shape
+    crossed = min(lead + keys, queries) if isinstance(lead, int) else queries
+    part = x if crossed == queries else lax.slice_in_dim(x, 0, crossed, axis=1)
+    ahead = lax.sub(lax.broadcasted_iota(jnp.int32, part.shape, 1),
+                    lax.broadcasted_iota(jnp.int32, part.shape, 0))
+    part = lax.select(lax.ge(ahead, lead), part, lax.full_like(part, fill))
+    if crossed == queries:
+        return part
+    return lax.concatenate(
+        [part, lax.slice_in_dim(x, crossed, queries, axis=1)], 1)
+
+
+def _each_head(heads, d, fold):
+    """`fold(h, head, stat)` for each of the `heads` heads a grid step
+    holds, `head` the head's d rows of the transposed scratch and `stat`
+    its row of the statistics.  Several heads are the turns of a loop
+    that the lowering unrolls: h reaches the compiler as a constant and
+    both heads' operations lie in one block for its scheduler, as if
+    written out, but the fold is traced once and not once a head."""
+    if heads == 1:
+        return fold(0, slice(0, d), slice(0, 1))
+
+    def turn(h, _):
+        fold(h, pl.ds(pl.multiple_of(lax.mul(h, d), d), d), pl.ds(h, 1))
+
+    lax.fori_loop(0, heads, turn, None, unroll=True)
+
+
+def _head_lanes(h, d, lanes, dtype):
+    """The [1, lanes] row `_only_head` keeps the h-th head's lanes with,
+    of the heads side by side in a tile's lanes: all-ones 32-bit words
+    over lanes [h * d, (h + 1) * d) for a 16-bit tile (zeros elsewhere),
+    a boolean row for a wider one; None where the lanes are one head's.
+    One vreg: a fold then pays a broadcast and an AND a tile."""
+    if lanes == d:
+        return None
+    lane = lax.broadcasted_iota(jnp.int32, (1, lanes), 1)
+    first = lax.mul(h, d)
+    own = lax.bitwise_and(lax.ge(lane, first), lax.lt(lane, lax.add(first, d)))
+    if dtype.itemsize < 4:
+        own = lax.select(own, lax.full(own.shape, 0xFFFFFFFF, jnp.uint32),
+                         lax.full(own.shape, 0, jnp.uint32))
+    return own
+
+
+def _only_head(x, own):
+    """`x` [rows, lanes] with the lanes of every head but one zeroed,
+    `own` that head's row of `_head_lanes`, so that a product
+    contracting `x` with all the lanes of another tile is that head's
+    product alone; `x` itself where its lanes are one head's."""
+    if own is None:
         return x
-
-    def own(shape):
-        lane = lax.broadcasted_iota(jnp.int32, shape, 1)
-        return lax.bitwise_and(lax.ge(lane, h * d), lax.lt(lane, (h + 1) * d))
-
-    if x.dtype.itemsize < 4 and x.shape[0] * x.dtype.itemsize % 4 == 0:
+    if own.dtype == jnp.uint32 and x.shape[0] * x.dtype.itemsize % 4 == 0:
         # a 16-bit tile: one AND a vreg on its packed 32-bit words (two
         # rows a word, the same lane); a select would unpack it to
         # float32 and pack it again (PERF.md section 6, PR 30)
         words = pltpu.bitcast(x, jnp.uint32)
-        keep = lax.select(own(words.shape),
-                          lax.full(words.shape, 0xFFFFFFFF, jnp.uint32),
-                          lax.full(words.shape, 0, jnp.uint32))
+        keep = lax.broadcast_in_dim(own, words.shape, (0, 1))
         return pltpu.bitcast(lax.bitwise_and(words, keep), x.dtype)
-    return lax.select(own(x.shape), x, lax.full_like(x, 0))
+    if own.dtype == jnp.uint32:
+        own = lax.ne(own, lax.full_like(own, 0))
+    return lax.select(lax.broadcast_in_dim(own, x.shape, (0, 1)), x,
+                      lax.full_like(x, 0))
 
 
 def _on_platform(call, *args):
@@ -280,7 +442,7 @@ def _on_platform(call, *args):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_scr, vt_scr,
-                *, sm_scale, causal, q_offset, bk, resident, d):
+                *, sm_scale, causal, q_offset, bk, resident, d, stair):
     """One (batch, heads, q_block, kv_block) grid step: the K/V block
     in VMEM (all of the keys, or one chunk of them) is folded, bk keys
     at a time and for every head of the step's, into the running max
@@ -324,53 +486,64 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_scr, vt_scr,
     ahead = lax.sub(lax.add(lax.mul(i, bq), q_offset),
                     lax.mul(j, kv_rows))
 
-    def _fold(c, masked):
-        if kv_rows == bk:
+    def _fold(c, key, keys, query, lead):
+        """`keys` keys of chunk c from its `key`-th into the block's
+        queries from the `query`-th on; `lead` as `_see` takes it, None
+        where every query sees every key."""
+        if keys == kv_rows:
             # one chunk, read whole: a block that is the whole of a
             # ragged sequence has no aligned slice
-            keys = slice(None)
+            rows = slice(None)
         else:
-            keys = pl.ds(pl.multiple_of(lax.mul(c, bk), bk), bk)
-        k = k_ref[keys, :]
-        for h in range(lanes // d):
-            head, stat = slice(h * d, (h + 1) * d), slice(h, h + 1)
-            vt = vt_scr[head, keys]
-            s = lax.mul(_matmul(k, _only_head(q_ref[...], h, d), 1),
-                        sm_scale)                            # [bk, bq]
-            if masked:
-                # a query sees the keys at or before its own position
-                lead = lax.sub(
-                    lax.broadcasted_iota(jnp.int32, s.shape, 1),
-                    lax.broadcasted_iota(jnp.int32, s.shape, 0))
-                s = lax.select(
-                    lax.ge(lead, lax.sub(lax.mul(c, bk), ahead)), s,
-                    lax.full_like(s, NEG_INF))
-            m_prev = m_ref[stat, :]                          # [1, bq]
+            rows = pl.ds(pl.multiple_of(lax.add(lax.mul(c, bk), key),
+                                        math.gcd(bk, key)), keys)
+        cols = slice(query, bq)
+        k, q = k_ref[rows, :], q_ref[cols, :]
+
+        def one(h, head, stat):
+            vt = vt_scr[head, rows]
+            own = _head_lanes(h, d, lanes, q.dtype)
+            s = lax.mul(_matmul(k, _only_head(q, own), 1),
+                        sm_scale)                         # [keys, queries]
+            if lead is not None:
+                s = _see(s, lead, NEG_INF)
+            m_prev = m_ref[stat, cols]                       # [1, queries]
             m_new = lax.max(
                 m_prev, lax.expand_dims(lax.reduce_max(s, (0,)), (0,)))
             alpha = lax.exp(lax.sub(m_prev, m_new))
             p = lax.exp(lax.sub(s, m_new))
-            l_ref[stat, :] = lax.add(
-                lax.mul(alpha, l_ref[stat, :]),
+            l_ref[stat, cols] = lax.add(
+                lax.mul(alpha, l_ref[stat, cols]),
                 lax.expand_dims(lax.reduce_sum(p, (0,)), (0,)))
-            acc_scr[head] = lax.add(
-                lax.mul(alpha, acc_scr[head]),
+            acc_scr[head, cols] = lax.add(
+                lax.mul(alpha, acc_scr[head, cols]),
                 _matmul(vt, lax.convert_element_type(p, vt.dtype)))
-            m_ref[stat, :] = m_new
+            m_ref[stat, cols] = m_new
+
+        _each_head(lanes // d, d, one)
+
+    def _fold_whole(c):
+        _fold(c, 0, bk, 0, None)
+
+    def _fold_masked(c):
+        _fold_crossed(functools.partial(_fold, c),
+                      lax.sub(lax.mul(c, bk), ahead), bq, bk, q_offset,
+                      stair)
 
     chunks = kv_rows // bk
     if causal:
         # chunks every query sees whole need no mask; those the
-        # diagonal crosses are masked; those above it are never
-        # touched.  (lax.div truncates: nothing negative reaches it.)
+        # diagonal crosses are masked, or folded as a staircase; those
+        # above it are never touched.  (lax.div truncates: nothing
+        # negative reaches it.)
         whole = lax.min(lax.div(lax.max(lax.add(ahead, 1), 0), bk),
                         chunks)
         seen = lax.min(lax.div(lax.max(lax.add(ahead, bq + bk - 1), 0),
                                bk), chunks)
-        _fold_chunks(_fold, 0, whole, False)
-        _fold_chunks(_fold, whole, seen, True)
+        _fold_chunks(_fold_whole, 0, whole)
+        _fold_chunks(_fold_masked, whole, seen)
     else:
-        _fold_chunks(_fold, 0, chunks, False)
+        _fold_chunks(_fold_whole, 0, chunks)
 
     @pl.when(lax.eq(j, lax.sub(pl.num_programs(3), 1)))
     def _finish():
@@ -381,6 +554,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_scr, vt_scr,
                 lax.select(lax.gt(l, 0.0), l, lax.full_like(l, 1)))
         o_ref[...] = lax.convert_element_type(
             lax.transpose(acc_scr[...], (1, 0))[:, :lanes], o_ref.dtype)
+
+
+def _stair_suffix(stair):
+    """What a kernel's name says of its staircase: the pieces' width,
+    nothing where a crossed chunk is folded whole."""
+    return "_s%d" % stair if stair else ""
 
 
 def _fwd(q, k, v, sm_scale, causal, block_q, block_k, q_offset,
@@ -398,9 +577,12 @@ def _fwd(q, k, v, sm_scale, causal, block_q, block_k, q_offset,
                        split=True)
         return merge_heads(o), m, l
     bq, bk, resident = _choose_blocks(*call.step_shapes, q.dtype.itemsize,
-                                      causal, block_q, block_k)
+                                      block_q, block_k)
     telemetry.on_flash_attention_lowering(
         bq, bk, resident, "split" if split else call.g)
+    telemetry.on_flash_attention_pairs("fwd", *(
+        call.batch * call.heads * n for n in score_pairs(
+            call.tq, call.tk, causal, q_offset, bq, bk, _STAIR)))
     return _fwd_kernels(q, k, v, num_heads=num_heads, sm_scale=sm_scale,
                         causal=causal, q_offset=q_offset, bq=bq, bk=bk,
                         resident=resident)
@@ -414,6 +596,7 @@ def _fwd_kernels(q, k, v, *, num_heads, sm_scale, causal, q_offset, bq, bk,
     the reason `_bwd_kernels` gives."""
     call = _Call.of(q.shape, k.shape, num_heads)
     kv_rows = call.tk if resident else bk
+    stair = _stair_width(bq, bk, q_offset, _STAIR) if causal else None
 
     def kv_index(i, j):
         if causal:
@@ -431,7 +614,7 @@ def _fwd_kernels(q, k, v, *, num_heads, sm_scale, causal, q_offset, bq, bk,
         pl.pallas_call,
         functools.partial(_fwd_kernel, sm_scale=sm_scale, causal=causal,
                           q_offset=q_offset, bk=bk, resident=resident,
-                          d=call.d),
+                          d=call.d, stair=stair),
         grid=call.steps + (call.tq // bq, call.tk // kv_rows),
         in_specs=[call.rows(bq, q_index), call.rows(kv_rows, kv_index),
                   call.rows(kv_rows, kv_index)],
@@ -449,8 +632,9 @@ def _fwd_kernels(q, k, v, *, num_heads, sm_scale, causal, q_offset, bq, bk,
                 "parallel", "parallel",
                 "arbitrary" if resident else "parallel", "arbitrary")),
         # the trace shows which tiling ran; readers match the prefix
-        name="flash_attention_fwd_q%d_k%d%s%s"
-             % (bq, bk, "_kvres" if resident else "", call.suffix),
+        name="flash_attention_fwd_q%d_k%d%s%s%s"
+             % (bq, bk, "_kvres" if resident else "", _stair_suffix(stair),
+                call.suffix),
     )
     o, m, l = _on_platform(pallas_call, q, k, v)
     rows = (call.batch, call.heads, call.tq)
@@ -521,7 +705,7 @@ def _bwd_step_bytes(bq, bk, d, itemsize, tq=None, heads=1):
     return chunk + max(dkv, dq) + 2 * 2 * 8 * bq * 4
 
 
-def _choose_bwd_blocks(q_shape, k_shape, itemsize, causal, block_q=None,
+def _choose_bwd_blocks(q_shape, k_shape, itemsize, block_q=None,
                        block_k=None, heads=1):
     """(block_q, block_k, one_kernel) for the backward of one call,
     from what `_choose_blocks` reads and the `heads` a grid step holds:
@@ -533,9 +717,9 @@ def _choose_bwd_blocks(q_shape, k_shape, itemsize, causal, block_q=None,
     another dq, each walking the other side a block a grid step."""
     tq, d = q_shape[2], q_shape[3]
     tk = k_shape[2]
-    qs = (_candidates(tq, causal) if block_q is None
+    qs = (_candidates(tq) if block_q is None
           else [_block(tq, block_q, "query", q_shape)])
-    ks = (_candidates(tk, causal) if block_k is None
+    ks = (_candidates(tk) if block_k is None
           else [_block(tk, block_k, "key", k_shape)])
     named = block_q is not None and block_k is not None
     pairs = sorted(itertools.product(qs, ks),
@@ -575,24 +759,23 @@ def _bwd_chunk(q, k, v, do, lse, delta, sm_scale, behind, widen):
     [1, queries] rows.  Where the tiles hold several heads, k and v
     come with the other heads' lanes zeroed (`_only_head`).  `behind`
     is None where every query sees every key of the chunk, else how far
-    the chunk's first key lies ahead of its first query: a query sees
-    the keys at or before its own position, and p is 0 elsewhere."""
+    the chunk's first key lies ahead of its first query, as `_see`
+    takes it: p is 0 where the query does not see the key."""
     s = lax.mul(_bwd_matmul(k, q, 1, widen), sm_scale)
     p = lax.exp(lax.sub(s, lse))
     if behind is not None:
-        lead = lax.sub(lax.broadcasted_iota(jnp.int32, s.shape, 1),
-                       lax.broadcasted_iota(jnp.int32, s.shape, 0))
-        p = lax.select(lax.ge(lead, behind), p, lax.full_like(p, 0))
+        p = _see(p, behind, 0)
     ds = lax.mul(p, lax.sub(_bwd_matmul(v, do, 1, widen), delta))
     return p, ds
 
 
-def _add_dkv(dk_scr, dv_scr, h, p, ds, q, do, widen):
-    """Head h's p do and ds q into its own accumulators, all the lanes
-    wide: the lanes of the tile's other heads gather products nobody
-    reads, at no cost to the MXU, which pads a narrower result."""
-    dv_scr[h] = lax.add(dv_scr[h], _bwd_matmul(p, do, 0, widen))
-    dk_scr[h] = lax.add(dk_scr[h], _bwd_matmul(ds, q, 0, widen))
+def _add_dkv(dk_scr, dv_scr, h, p, ds, q, do, widen, keys=slice(None)):
+    """Head h's p do and ds q into the rows `keys` of its own
+    accumulators, all the lanes wide: the lanes of the tile's other
+    heads gather products nobody reads, at no cost to the MXU, which
+    pads a narrower result."""
+    dv_scr[h, keys] = lax.add(dv_scr[h, keys], _bwd_matmul(p, do, 0, widen))
+    dk_scr[h, keys] = lax.add(dk_scr[h, keys], _bwd_matmul(ds, q, 0, widen))
 
 
 def _side_by_side(scr, d):
@@ -621,7 +804,7 @@ def _write_dq(dq_ref, dqt_scr, sm_scale):
 
 def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                 dk_ref, dv_ref, dqt_scr, kt_scr, dk_scr, dv_scr, *,
-                sm_scale, causal, q_offset, widen, bq, d):
+                sm_scale, causal, q_offset, widen, bq, d, stair):
     """One (batch, heads, k_block) grid step of the whole backward: the
     step's bk keys meet the queries, all in VMEM, bq at a time and for
     every head of the step's, from the first chunk that sees a key of
@@ -647,39 +830,55 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     # how far this step's first key is ahead of the first query
     behind = lax.sub(lax.mul(j, bk), q_offset)
 
-    def _fold(c, masked):
+    def _fold(c, key, keys, query, lead):
+        """The block's `keys` keys from its `key`-th meet the queries of
+        chunk c from the chunk's `query`-th on; `lead` as `_see` takes
+        it, None where every query sees every key."""
         if tq == bq:
             # one chunk, read whole: a block that is the whole of a
             # ragged sequence has no aligned slice
-            rows = slice(None)
+            rows = slice(query, bq) if query else slice(None)
         else:
-            rows = pl.ds(pl.multiple_of(lax.mul(c, bq), bq), bq)
+            rows = pl.ds(pl.multiple_of(lax.add(lax.mul(c, bq), query),
+                                        math.gcd(bq, query)), bq - query)
+        these = slice(key, key + keys)
         q, do = q_ref[rows, :], do_ref[rows, :]
-        for h in range(lanes // d):
-            head, stat = slice(h * d, (h + 1) * d), slice(h, h + 1)
+        k, v = k_ref[these, :], v_ref[these, :]
+
+        def one(h, head, stat):
+            own = _head_lanes(h, d, lanes, k.dtype)
             p, ds = _bwd_chunk(
-                q, _only_head(k_ref[...], h, d),
-                _only_head(v_ref[...], h, d), do, lse_ref[stat, rows],
-                delta_ref[stat, rows], sm_scale,
-                lax.sub(behind, lax.mul(c, bq)) if masked else None,
+                q, _only_head(k, own), _only_head(v, own), do,
+                lse_ref[stat, rows], delta_ref[stat, rows], sm_scale, lead,
                 widen)
             ds = lax.convert_element_type(ds, q.dtype)
-            _add_dkv(dk_scr, dv_scr, h, p, ds, q, do, widen)
+            _add_dkv(dk_scr, dv_scr, h, p, ds, q, do, widen, these)
             dqt_scr[head, rows] = lax.add(
                 dqt_scr[head, rows],
-                _bwd_matmul(kt_scr[head, :], ds, 0, widen))
+                _bwd_matmul(kt_scr[head, these], ds, 0, widen))
+
+        _each_head(lanes // d, d, one)
+
+    def _fold_whole(c):
+        _fold(c, 0, bk, 0, None)
+
+    def _fold_masked(c):
+        _fold_crossed(functools.partial(_fold, c),
+                      lax.sub(behind, lax.mul(c, bq)), bq, bk, q_offset,
+                      stair)
 
     chunks = tq // bq
     if causal:
         # query chunks before the diagonal are never touched; those it
-        # crosses are masked; those after it see the block whole
+        # crosses are masked, or folded as a staircase; those after it
+        # see the block whole
         seen = lax.min(lax.div(lax.max(behind, 0), bq), chunks)
         whole = lax.min(lax.div(lax.max(lax.add(behind, bk + bq - 2), 0),
                                 bq), chunks)
-        _fold_chunks(_fold, seen, whole, True)
-        _fold_chunks(_fold, whole, chunks, False)
+        _fold_chunks(_fold_masked, seen, whole)
+        _fold_chunks(_fold_whole, whole, chunks)
     else:
-        _fold_chunks(_fold, 0, chunks, False)
+        _fold_chunks(_fold_whole, 0, chunks)
     _write_dkv(dk_ref, dv_ref, dk_scr, dv_scr, sm_scale, d)
     pl.when(lax.eq(j, lax.sub(pl.num_programs(2), 1)))(
         lambda: _write_dq(dq_ref, dqt_scr, sm_scale))
@@ -688,8 +887,9 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 def _fold_where_seen(fold, causal, behind, bq, bk):
     """`fold(masked)` for the one [bk, bq] chunk of a walking kernel's
     grid step, whose first key lies `behind` ahead of its first query:
-    unmasked where every query sees every key, masked where the
-    diagonal crosses the chunk, not at all above it."""
+    unmasked where every query sees every key, masked and whole where
+    the diagonal crosses the chunk (these kernels take no staircase),
+    not at all above it."""
     if not causal:
         return fold(False)
     whole = lax.le(lax.add(behind, bk - 1), 0)
@@ -719,9 +919,10 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _fold(masked):
         q, do = q_ref[...], do_ref[...]
         for h in range(lanes // d):
+            own = _head_lanes(h, d, lanes, k_ref.dtype)
             p, ds = _bwd_chunk(
-                q, _only_head(k_ref[...], h, d),
-                _only_head(v_ref[...], h, d), do, lse_ref[h:h + 1, :],
+                q, _only_head(k_ref[...], own),
+                _only_head(v_ref[...], own), do, lse_ref[h:h + 1, :],
                 delta_ref[h:h + 1, :], sm_scale,
                 behind if masked else None, widen)
             _add_dkv(dk_scr, dv_scr, h, p, ds, q, do, widen)
@@ -753,9 +954,10 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         kt = lax.transpose(k, (1, 0))
         for h in range(lanes // d):
             head = slice(h * d, (h + 1) * d)
+            own = _head_lanes(h, d, lanes, k.dtype)
             _, ds = _bwd_chunk(
-                q_ref[...], _only_head(k, h, d),
-                _only_head(v_ref[...], h, d), do_ref[...],
+                q_ref[...], _only_head(k, own),
+                _only_head(v_ref[...], own), do_ref[...],
                 lse_ref[h:h + 1, :], delta_ref[h:h + 1, :], sm_scale,
                 behind if masked else None, widen)
             acc_scr[head] = lax.add(
@@ -821,11 +1023,14 @@ def _bwd(q, k, v, do, lse, delta, sm_scale, causal, block_q, block_k,
     if sm_scale is None:
         sm_scale = call.d ** -0.5
     bq, bk, one_kernel = _choose_bwd_blocks(
-        *call.step_shapes, q.dtype.itemsize, causal, block_q, block_k,
-        call.g)
+        *call.step_shapes, q.dtype.itemsize, block_q, block_k, call.g)
     for kernel in (("dq_dkv",) if one_kernel else ("dkv", "dq")):
         telemetry.on_flash_attention_bwd_lowering(
             kernel, bq, bk, "split" if split else call.g)
+        telemetry.on_flash_attention_pairs("bwd", *(
+            call.batch * call.heads * n for n in score_pairs(
+                call.tq, call.tk, causal, q_offset, bq, bk,
+                _STAIR if one_kernel else None)))
     return _bwd_kernels(q, k, v, do, lse, delta, num_heads=num_heads,
                         sm_scale=sm_scale, causal=causal, q_offset=q_offset,
                         bq=bq, bk=bk, one_kernel=one_kernel)
@@ -847,7 +1052,10 @@ def _bwd_kernels(q, k, v, do, lse, delta, *, num_heads, sm_scale, causal,
     tq, tk, lanes = call.tq, call.tk, call.lanes
     operands = [q, k, v, do] \
         + [x.reshape(call.stats_shape) for x in (lse, delta)]
-    name = "flash_attention_bwd%%s_q%d_k%d%s" % (bq, bk, call.suffix)
+    stair = (_stair_width(bq, bk, q_offset, _STAIR)
+             if causal and one_kernel else None)
+    name = "flash_attention_bwd%%s_q%d_k%d%s%s" % (
+        bq, bk, _stair_suffix(stair), call.suffix)
     accumulator = pltpu.VMEM((call.g, bk, lanes), jnp.float32)
     transposed = pltpu.VMEM((_pad_to_lanes(lanes), tq if one_kernel else bq),
                             jnp.float32)
@@ -870,7 +1078,7 @@ def _bwd_kernels(q, k, v, do, lse, delta, *, num_heads, sm_scale, causal,
 
         queries, keys = call.rows(tq, whole), call.rows(bk, block)
         return tuple(_on_platform(functools.partial(
-            pallas_call, functools.partial(_bwd_kernel, bq=bq),
+            pallas_call, functools.partial(_bwd_kernel, bq=bq, stair=stair),
             grid=call.steps + (tk // bk,),
             in_specs=[queries, keys, keys, queries,
                       call.stats(tq, whole), call.stats(tq, whole)],

@@ -41,7 +41,7 @@ def test_chosen_blocks_tile_the_sequences_within_the_budget(
         tq, tk, d, dtype, causal):
     itemsize = jnp.dtype(dtype).itemsize
     bq, bk, resident = fa._choose_blocks(
-        (8, 16, tq, d), (8, 16, tk, d), itemsize, causal)
+        (8, 16, tq, d), (8, 16, tk, d), itemsize)
     # a block divides its sequence; one that is no multiple of 128 is
     # the whole of a sequence nothing else tiles
     assert tq % bq == 0 and tk % bk == 0
@@ -55,22 +55,37 @@ def test_chosen_blocks_tile_the_sequences_within_the_budget(
         # the benchmark's shape: 8 x 16 heads; 128 x 128 blocks made
         # 128 x 8 x 8 = 8192 grid steps a call
         assert 8 * 16 * (tq // bq) * (tk // kv_rows) <= 1024
+    _pairs_are_bounded(tq, tk, causal, bq, bk, fa._STAIR)
 
+
+def _pairs_are_bounded(tq, tk, causal, bq, bk, widest):
+    """The mask does not enter the choice of blocks: whatever they are,
+    a staircase holds under half a piece's width of pairs a query beyond
+    those it attends; only a ragged sequence, one block that no piece
+    divides, is folded whole."""
+    folded, attended = fa.score_pairs(tq, tk, causal, 0, bq, bk, widest)
+    if not causal:
+        assert folded == attended == tq * tk
+    elif tq % 128 == 0 and widest:
+        assert attended <= folded < attended + tq * widest / 2
+    else:
+        assert folded == fa.score_pairs(tq, tk, True, 0, bq, bk, None)[0]
+        assert folded > attended
 
 def test_a_named_block_is_kept_beside_a_chosen_one():
     shape = (1, 8, 4096, 128)
-    assert fa._choose_blocks(shape, shape, 2, True, 128, 128)[:2] \
+    assert fa._choose_blocks(shape, shape, 2, 128, 128)[:2] \
         == (128, 128)
-    bq, bk, _ = fa._choose_blocks(shape, shape, 2, False, block_q=256)
+    bq, bk, _ = fa._choose_blocks(shape, shape, 2, block_q=256)
     assert bq == 256 and bk > 256
-    bq, bk, _ = fa._choose_blocks(shape, shape, 2, False, block_k=256)
+    bq, bk, _ = fa._choose_blocks(shape, shape, 2, block_k=256)
     assert bk == 256 and bq > 256
 
 
 def test_a_long_sequence_nothing_tiles_is_refused():
     shape = (1, 8, 32769, 128)
     with pytest.raises(ValueError, match=r"32769.*\(1, 8, 32769, 128\)"):
-        fa._choose_blocks(shape, shape, 2, True)
+        fa._choose_blocks(shape, shape, 2)
     with pytest.raises(ValueError, match=r"32769.*\(1, 8, 32769, 128\)"):
         fa.flash_attention(*(jnp.zeros(shape, jnp.bfloat16),) * 3)
 
@@ -106,11 +121,11 @@ def test_chosen_blocks_match_dense_attention(monkeypatch, kv, tq, tk,
                                              q_offset, causal):
     monkeypatch.setattr(fa, "_VMEM_BUDGET", BUDGETS[kv])
     q, k, v = _qkv(tq, tk)
-    bq, bk, resident = fa._choose_blocks(q.shape, k.shape, 4, causal)
+    bq, bk, resident = fa._choose_blocks(q.shape, k.shape, 4)
     # several blocks on both axes, and the path the budget asks for
     assert tq // bq >= 2 and tk // bk >= 2
     assert resident == (kv == "resident")
-    assert fa._choose_bwd_blocks(q.shape, k.shape, 4, causal) \
+    assert fa._choose_bwd_blocks(q.shape, k.shape, 4) \
         == (bq, bk, resident)
 
     def loss(attention):
@@ -181,8 +196,7 @@ def test_backward_blocks_tile_the_sequences_within_the_budget(
         tq, tk, d, dtype, causal):
     itemsize = jnp.dtype(dtype).itemsize
     q_shape, k_shape = (8, 16, tq, d), (8, 16, tk, d)
-    bq, bk, one_kernel = fa._choose_bwd_blocks(q_shape, k_shape, itemsize,
-                                               causal)
+    bq, bk, one_kernel = fa._choose_bwd_blocks(q_shape, k_shape, itemsize)
     assert tq % bq == 0 and tk % bk == 0
     assert bq % 128 == 0 or bq == tq
     assert bk % 128 == 0 or bk == tk
@@ -197,15 +211,15 @@ def test_backward_blocks_tile_the_sequences_within_the_budget(
                                   tq) > fa._VMEM_BUDGET
     # the benchmark's shapes get the one kernel, 32k positions the two
     assert one_kernel == (tq <= 4096)
-    if causal:
-        # the diagonal still cuts work off, unless nothing tiles
-        assert bq <= max(128, tq // 2) or tq % 128
-        assert bk <= max(128, tk // 2) or tk % 128
+    # the one kernel folds a crossed chunk as a staircase, the two that
+    # walk fold it whole
+    _pairs_are_bounded(tq, tk, causal, bq, bk,
+                       fa._STAIR if one_kernel else None)
     # a named block is kept beside a chosen one
     if tq % 128 == 0:
-        assert fa._choose_bwd_blocks(q_shape, k_shape, itemsize, causal,
+        assert fa._choose_bwd_blocks(q_shape, k_shape, itemsize,
                                      block_q=128)[0] == 128
-        assert fa._choose_bwd_blocks(q_shape, k_shape, itemsize, causal,
+        assert fa._choose_bwd_blocks(q_shape, k_shape, itemsize,
                                      128, 128)[:2] == (128, 128)
 
 
@@ -220,8 +234,7 @@ def test_bf16_gradients_agree_with_dense_float32_attention(d, tolerance):
     what dense attention gives in float32 on the same (bf16) values."""
     q, k, v = (x.astype(jnp.bfloat16) for x in _qkv(256, 256, d=d, seed=3))
     do = _qkv(256, 256, d=d, seed=4)[0].astype(jnp.bfloat16)
-    assert fa._choose_bwd_blocks(q.shape, k.shape, 2, True) \
-        == (128, 128, True)
+    assert fa._choose_bwd_blocks(q.shape, k.shape, 2) == (256, 256, True)
     got = _grads(lambda q, k, v: fa.flash_attention(q, k, v, None, True),
                  q, k, v, do)
     want = _grads(
@@ -262,7 +275,7 @@ def test_key_blocks_no_query_sees_get_exact_zeros(tq, tk, q_offset,
 def test_a_ragged_sequence_is_one_whole_block_in_the_backward(causal):
     q, k, v = _qkv(200, 200, seed=7)
     do = _qkv(200, 200, seed=8)[0]
-    assert fa._choose_bwd_blocks(q.shape, k.shape, 4, causal) \
+    assert fa._choose_bwd_blocks(q.shape, k.shape, 4) \
         == (200, 200, True)
     got = _grads(lambda q, k, v: fa.flash_attention(q, k, v, None, causal),
                  q, k, v, do)
@@ -280,7 +293,7 @@ def test_named_blocks_of_16_are_kept_by_one_kernel_and_by_two(
         monkeypatch.setattr(fa, "_VMEM_BUDGET", budget)
     q, k, v = _qkv(64, 64, seed=9)
     do = _qkv(64, 64, seed=10)[0]
-    assert fa._choose_bwd_blocks(q.shape, k.shape, 4, True, 16, 16) \
+    assert fa._choose_bwd_blocks(q.shape, k.shape, 4, 16, 16) \
         == (16, 16, budget is None)
     got = _grads(lambda q, k, v: fa.flash_attention(
         q, k, v, None, True, 16, 16), q, k, v, do)
@@ -288,6 +301,106 @@ def test_named_blocks_of_16_are_kept_by_one_kernel_and_by_two(
         q, k, v, None, True), q, k, v, do)
     for a, b in zip(got, want):
         np.testing.assert_allclose(a, b, atol=5e-5)
+
+
+# -- a chunk the diagonal crosses is folded as a staircase ---------------------
+
+# name: (tq, tk, q_offset, head dim, heads a lane block, bq, bk, the places
+# the diagonal enters a crossed chunk, None where it is folded whole;
+# a block of one piece's width is one piece, all of it)
+STAIRCASES = {
+    # square blocks, the diagonal through their corners; two 64-wide
+    # heads a grid step, as gpt2m-train runs them
+    "start": (512, 512, 0, 64, 2, 256, 256, [0]),
+    # one block is the whole sequence: two pieces and no other chunk
+    "one_block": (512, 512, 0, 128, 1, 512, 512, [0]),
+    # two key chunks a query block, as Ouro's forward: the second is
+    # entered in the middle of its queries; in the backward every other
+    # key block is
+    "middle": (512, 512, 0, 128, 1, 512, 256, [0, 256]),
+    # two query chunks a key block: the first keys of every other chunk
+    # lie behind its first query and are folded unmasked
+    "behind": (512, 512, 0, 32, 4, 256, 512, [-256, 0]),
+    # a query shard at an offset that is a multiple of a piece and of no
+    # block
+    "shard": (256, 512, 128, 64, 1, 256, 256, [-128, 128]),
+    # an offset that is no multiple of 128: every crossed chunk whole
+    "unaligned": (256, 512, 200, 64, 2, 256, 256, None),
+    # a decode step's few queries at the end of the keys
+    "decode": (8, 512, 504, 64, 1, 8, 256, None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(STAIRCASES))
+def test_a_staircase_is_dense_float32_attention(case):
+    """Where the blocks and the offset are multiples of a piece, a chunk
+    the diagonal crosses is folded a piece of keys at a time over the
+    queries at or after it alone; elsewhere whole, every score compared:
+    o, the log-sum-exp and dq, dk, dv are dense attention's either way,
+    and the pairs counted say which way it went."""
+    tq, tk, q_offset, d, g, bq, bk, leads = STAIRCASES[case]
+    heads = 2 * g
+    rs = np.random.RandomState(12)
+    q, k, v, do = (jnp.asarray(0.5 * rs.randn(1, t, heads * d), jnp.float32)
+                   for t in (tq, tk, tk, tq))
+    s = fa._stair_width(bq, bk, q_offset, fa._STAIR)
+    assert (s is None) == (leads is None)
+    if s is not None:
+        assert fa._leads(bq, bk, q_offset) == leads
+    folded, attended = fa.score_pairs(tq, tk, True, q_offset, bq, bk,
+                                      fa._STAIR)
+    whole = fa.score_pairs(tq, tk, True, q_offset, bq, bk, None)[0]
+    assert attended == sum(min(q_offset + i + 1, tk) for i in range(tq))
+    assert attended <= folded <= whole
+    assert (folded == whole) == (leads is None or s == bq == bk)
+
+    def flash(q, k, v):
+        return fa.flash_attention_with_lse(q, k, v, None, True, bq, bk,
+                                           q_offset, heads)
+
+    def dense(q, k, v):
+        q, k, v = (fa.split_heads(x, heads) for x in (q, k, v))
+        scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) * d ** -0.5
+        sees = (q_offset + jnp.arange(tq))[:, None] >= jnp.arange(tk)
+        return (fa.merge_heads(fa.reference_attention(
+                    q, k, v, None, True, q_offset)),
+                jax.nn.logsumexp(jnp.where(sees, scores, fa.NEG_INF), -1))
+
+    before = telemetry.snapshot()
+    got, vjp = jax.vjp(flash, q, k, v)
+    want, dense_vjp = jax.vjp(dense, q, k, v)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, atol=2e-5)
+    for a, b in zip(vjp((do, jnp.cos(got[1]))),
+                    dense_vjp((do, jnp.cos(want[1])))):
+        np.testing.assert_allclose(a, b, atol=5e-5)
+    # the forward and the backward's one kernel each counted their pairs
+    rose = telemetry.snapshot_delta(before)
+    for kernel_pass in ("fwd", "bwd"):
+        assert [rose["flash_attention_pairs_total{kind=%s,pass=%s}"
+                     % (kind, kernel_pass)]
+                for kind in ("folded", "attended")] \
+            == [heads * folded, heads * attended]
+
+
+def test_stairs_cover_what_the_queries_see_once():
+    """The pieces of a crossed chunk hold every pair a query attends
+    exactly once and, but for the triangle above the diagonal of each
+    piece's leading square, nothing else."""
+    for bq, bk, s in ((512, 512, 128), (1024, 512, 128), (512, 256, 256),
+                      (256, 512, 128)):
+        for lead in fa._leads(bq, bk, 0) + fa._leads(bq, bk, s):
+            held = np.zeros((bk, bq), int)
+            for key, keys, query, crossed in fa._stairs(lead, bq, bk, s):
+                assert keys % s == 0 and query % s == 0
+                held[key:key + keys, query:] += 1
+                assert crossed == (lead + key >= 0)
+            sees = np.arange(bq)[None, :] - np.arange(bk)[:, None] >= lead
+            assert held.max() == 1 and (held >= sees).all()
+            # a piece is s keys wide: it holds under s / 2 pairs a query
+            # beyond the attended ones
+            assert held.sum() - sees.sum() < bq * s / 2
+            assert held.sum() == fa._chunk_pairs(lead, bq, bk, s)
 
 
 # -- operands as a projection writes them: the heads side by side -------------
@@ -350,7 +463,7 @@ def test_heads_side_by_side_are_the_heads_held_apart(monkeypatch, d,
     expected = collections.Counter()
     for inner, label in ((call if call.g else apart, HEADS_A_STEP[d]),
                          (apart, "apart")):
-        one_kernel = fa._choose_bwd_blocks(*inner.step_shapes, 4, causal,
+        one_kernel = fa._choose_bwd_blocks(*inner.step_shapes, 4,
                                            heads=inner.g)[2]
         if label == "apart":
             label = 1
@@ -381,25 +494,37 @@ def test_heads_side_by_side_are_the_heads_held_apart(monkeypatch, d,
     np.testing.assert_allclose(out[0], want, atol=2e-5)
 
 
-@pytest.mark.parametrize("shape,heads,forward,backward", [
+@pytest.mark.parametrize("shape,heads,forward,backward,apart", [
     # gpt2m-train: 16 heads of 64, two a grid step
-    ((8, 1024, 1024), 16, (512, 512, True), (512, 512, True)),
+    ((8, 1024, 1024), 16, (1024, 1024, True), (512, 512, True),
+     (1024, 512, True)),
     # ouro-train-4k and olmoe-train-4k: 16 heads of 128
-    ((1, 4096, 2048), 16, (1024, 512, True), (512, 256, True)),
+    ((1, 4096, 2048), 16, (1024, 512, True), (512, 256, True),
+     (512, 256, True)),
 ])
-def test_the_cells_tilings_are_those_of_heads_held_apart(shape, heads,
-                                                         forward, backward):
-    """The blocks chosen for the benchmark's attention, bfloat16 and
-    causal, are the same whether a grid step sees one
-    [batch, heads, seq, dim] head or the heads that share a lane block
-    of [batch, seq, heads * dim]."""
+def test_the_cells_tilings_are_those_of_heads_held_apart(
+        shape, heads, forward, backward, apart):
+    """The blocks chosen for the benchmark's attention, bfloat16, are
+    the same whether a grid step sees one [batch, heads, seq, dim] head
+    or the heads that share a lane block of [batch, seq, heads * dim],
+    but for the backward at GPT-2's shape.  Since a crossed chunk is
+    folded as a staircase the mask does not enter the choice: a causal
+    block was at most half its sequence so that the diagonal cut work
+    off, which the staircase now does whatever the blocks.  GPT-2's
+    forward holds all 1024 queries and keys of a grid step's heads in
+    one block (half the grid steps, the same pairs: 0.403 ms a call on
+    the chip against 0.467 at 512 x 512).  Its backward holds 512 x 512
+    chunks as before where two heads' accumulators share the step, and
+    1024 x 512 where one head leaves room for them (0.705 ms a call
+    against 0.721, heads held apart)."""
     b, t, width = shape
-    for call in (fa._Call.of(shape, shape, heads),
-                 fa._Call.of((b, heads, t, width // heads),
-                             (b, heads, t, width // heads), None)):
-        assert fa._choose_blocks(*call.step_shapes, 2, True) == forward
-        assert fa._choose_bwd_blocks(*call.step_shapes, 2, True,
-                                     heads=call.g) == backward
+    for call, blocks in (
+            (fa._Call.of(shape, shape, heads), backward),
+            (fa._Call.of((b, heads, t, width // heads),
+                         (b, heads, t, width // heads), None), apart)):
+        assert fa._choose_blocks(*call.step_shapes, 2) == forward
+        assert fa._choose_bwd_blocks(*call.step_shapes, 2,
+                                     heads=call.g) == blocks
         # and two heads a step fit where one did
         assert fa._bwd_step_bytes(
             backward[0], backward[1], call.lanes, 2, t, call.g) \
@@ -418,8 +543,8 @@ def _lowerings(bq, bk, resident, heads_per_step=1):
 @pytest.mark.parametrize("named", [None, 128])
 def test_counter_rises_once_per_lowering(named):
     x = jax.ShapeDtypeStruct((8, 16, 1024, 64), jnp.bfloat16)
-    labels = fa._choose_blocks(x.shape, x.shape, 2, True, named, named)
-    assert labels == ((512, 512, True) if named is None
+    labels = fa._choose_blocks(x.shape, x.shape, 2, named, named)
+    assert labels == ((1024, 1024, True) if named is None
                       else (128, 128, True))
     before = _lowerings(*labels)
     fn = jax.jit(lambda q, k, v: fa.flash_attention(
@@ -432,6 +557,92 @@ def test_counter_rises_once_per_lowering(named):
     assert _lowerings(*labels) == before + 2
 
 
+def _pairs_counted():
+    snapshot = telemetry.snapshot()
+    return {(kernel_pass, kind): snapshot.get(
+        "flash_attention_pairs_total{kind=%s,pass=%s}"
+        % (kind, kernel_pass), 0)
+        for kernel_pass in ("fwd", "bwd") for kind in ("folded", "attended")}
+
+
+# a head's pairs, worked out by hand.  gpt2m-train, 1024 tokens: a query
+# attends the keys up to its own, 1024 * 1025 / 2 = 524800 pairs.  The
+# forward's one 1024 x 1024 block is four pieces of 256 keys over 1024,
+# 768, 512 and 256 queries: 256 * 2560 = 655360.  The backward's four
+# 512 x 512 chunks: one above the diagonal (none), one below (262144),
+# two crossed, each two pieces of 256 keys over 512 and 256 queries,
+# 256 * 768 = 196608: 655360 too.  Whole, the crossed chunks were
+# 262144 each: 786432, 1.50 times the attended.
+# ouro-train-4k, 4096 tokens: 4096 * 4097 / 2 = 8390656 attended.  The
+# forward's 1024-query blocks meet 512-key chunks: 0 + 2 + 4 + 6 = 12
+# below the diagonal (524288 each) and two crossed a block, the first
+# entered at its corner (256 * (1024 + 768) = 458752), the second in the
+# middle of its queries (256 * (512 + 256) = 196608): 6291456 + 4 *
+# 655360 = 8912896; whole 20 chunks, 10485760, 1.25 times.  The
+# backward's 256-key blocks meet 512-query chunks: block j sees 7 - j //
+# 2 chunks whole (2 * 28 = 56, 131072 each) and crosses one, entered at
+# its corner (one piece, all of it: 131072) or in the middle (256 * 256
+# = 65536): 7340032 + 8 * 196608 = 8912896 too; whole 72 chunks,
+# 9437184, 1.125 times.
+CELL_PAIRS = [
+    ((8, 1024, 1024), 16, 524800, (655360, 655360), (786432, 786432),
+     (512, 512), (512, 512)),
+    ((1, 4096, 2048), 16, 8390656, (8912896, 8912896), (10485760, 9437184),
+     (1024, 512), (512, 256)),
+]
+
+
+@pytest.mark.parametrize(
+    "shape,heads,attended,folded,whole,parent_fwd,parent_bwd", CELL_PAIRS)
+def test_pairs_counter_says_what_the_mask_throws_away(
+        shape, heads, attended, folded, whole, parent_fwd, parent_bwd):
+    """`flash_attention_pairs_total` at the two attention cells' shapes:
+    folded and attended pairs are the numbers worked out by hand above,
+    folded / attended under 1.25 (GPT-2) and 1.07 (Ouro); a crossed
+    chunk folded whole, as every one was before the staircase, costs
+    1.50, 1.25 and 1.125 times the attended pairs at the blocks chosen
+    then; and an offset that is no multiple of a piece takes the
+    staircase out: the kernels' names lose `_s<width>` and the pairs
+    counted are the whole chunks'."""
+    batch, t, width = shape
+    call = fa._Call.of(shape, shape, heads)
+    blocks = (fa._choose_blocks(*call.step_shapes, 2)[:2],
+              fa._choose_bwd_blocks(*call.step_shapes, 2, heads=call.g)[:2])
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+
+    def lowered(q_offset):
+        before = _pairs_counted()
+        text = jax.jit(jax.grad(
+            lambda q, k, v: fa.flash_attention_with_lse(
+                q, k, v, None, True, None, None, q_offset, heads)[0]
+            .astype(jnp.float32).sum(), argnums=(0, 1, 2))).lower(x, x, x) \
+            .as_text(debug_info=True)
+        return text, {key: (n - before[key]) // (batch * heads)
+                      for key, n in _pairs_counted().items()}
+
+    text, rose = lowered(0)
+    assert rose == {("fwd", "folded"): folded[0], ("fwd", "attended"): attended,
+                    ("bwd", "folded"): folded[1], ("bwd", "attended"): attended}
+    limit = 1.25 if t == 1024 else 1.07
+    assert max(folded) / attended < limit
+    for kernel_pass, (bq, bk) in zip(("fwd", "bwd"), blocks):
+        assert "flash_attention_%s_q%d_k%d" % (kernel_pass, bq, bk) in text
+    assert text.count("_s256") >= 2
+    # what the parent's kernels folded, at the parent's blocks
+    for (bq, bk), n, ratio in zip((parent_fwd, parent_bwd), whole,
+                                  (1.5, 1.5) if t == 1024 else (1.25, 1.125)):
+        assert fa.score_pairs(t, t, True, 0, bq, bk, None) == (n, attended)
+        assert n / attended == pytest.approx(ratio, abs=2e-3)
+    # 64 positions on: no piece of 128 starts on the diagonal
+    text, rose = lowered(64)
+    assert "_s256" not in text and "_s128" not in text
+    for kernel_pass, (bq, bk) in zip(("fwd", "bwd"), blocks):
+        assert (rose[kernel_pass, "folded"], rose[kernel_pass, "attended"]) \
+            == fa.score_pairs(t, t, True, 64, bq, bk, None)
+        assert rose[kernel_pass, "folded"] / rose[kernel_pass, "attended"] \
+            > limit
+
+
 def _bwd_lowerings(kernel, bq, bk, heads_per_step=1):
     return telemetry.snapshot().get(
         "flash_attention_bwd_lowerings_total{block_k=%d,block_q=%d,"
@@ -440,7 +651,7 @@ def _bwd_lowerings(kernel, bq, bk, heads_per_step=1):
 
 
 @pytest.mark.parametrize("shape,named,blocks,kernels", [
-    ((8, 16, 1024, 64), None, (512, 512), {"dq_dkv": ""}),
+    ((8, 16, 1024, 64), None, (1024, 512), {"dq_dkv": ""}),
     ((8, 16, 1024, 64), 128, (128, 128), {"dq_dkv": ""}),
     ((1, 8, 32768, 128), None, (1024, 512),
      {"dkv": "_dkv", "dq": "_dq"}),
@@ -448,8 +659,8 @@ def _bwd_lowerings(kernel, bq, bk, heads_per_step=1):
 def test_backward_counter_rises_once_per_kernel_and_lowering(
         shape, named, blocks, kernels):
     x = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
-    bq, bk, one_kernel = fa._choose_bwd_blocks(shape, shape, 2, True,
-                                               named, named)
+    bq, bk, one_kernel = fa._choose_bwd_blocks(shape, shape, 2, named,
+                                               named)
     assert (bq, bk) == blocks and one_kernel == (len(kernels) == 1)
     before = [_bwd_lowerings(kernel, bq, bk) for kernel in kernels]
 
