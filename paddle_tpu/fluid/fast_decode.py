@@ -59,7 +59,11 @@ class ProgramDecoder:
         feed_names = [token_name] + [f for f, _ in self.state_pairs]
         fetch_names = [logits_name] + [o for _, o in self.state_pairs]
         self._fp = FunctionalProgram(program, feed_names, fetch_names)
-        self._params = {n: jnp.asarray(np.asarray(v)) for n, v in
+        # the scope's device arrays as they are: a round trip through
+        # the host would hold every weight twice on the device until
+        # the scope lets go of its own
+        self._params = {n: v if isinstance(v, jax.Array)
+                        else jnp.asarray(np.asarray(v)) for n, v in
                         state_from_scope(self._fp, scope).items()}
         missing = sorted(set(self._fp.state_in_names) - set(self._params))
         if missing:
@@ -138,50 +142,67 @@ class ProgramDecoder:
     def _prefilled_run(self, params, state, prompt, decode_fn, eos,
                       max_len):
         """Shared prompt path: prefill, then decode_fn(step, state,
-        first) for the remaining max_len-1 tokens (skipped when
-        max_len == 1 — the 'predict one continuation token' call)."""
+        first) -> (tokens, final state) for the remaining max_len-1
+        tokens (skipped when max_len == 1 — the 'predict one
+        continuation token' call).  Returns (tokens, lengths, state)."""
         step = self._step_fn(params)
         state, first = prefill(step, state, prompt)
         if max_len == 1:
             toks = first[:, None]
         else:
-            toks, _ = decode_fn(step, state, first)
+            toks, state = decode_fn(step, state, first)
             toks = jnp.concatenate([first[:, None], toks], axis=1)
         lengths = jnp.argmax(toks == eos, axis=1) + 1
         lengths = jnp.where(jnp.any(toks == eos, axis=1), lengths,
                             max_len)
-        return toks, lengths
+        return toks, lengths, state
 
     def greedy(self, bos, eos, max_len, batch_size=None, init_state=None,
-               prompt=None):
+               prompt=None, return_state=()):
         """Returns (tokens [batch, max_len], lengths [batch]).
 
         `prompt` (int [batch, P]) warms the decode state through the
         step program first (one scan — for a KV-cache step program this
         is the prefill); the first output token is then the prompt's
-        continuation and `bos` is ignored."""
+        continuation and `bos` is ignored.
+
+        `return_state` names state feeds whose values after the last
+        step come back as a third result, {feed name: array}: a state
+        pair the step only writes (its feed unread) is how a caller
+        sees an intermediate of the step that chose the last token."""
         state, batch_size = self._prep(init_state, batch_size)
         prompt = self._norm_prompt(prompt, max_len)
+        return_state = tuple(return_state)
+        want = bool(return_state)
+
+        def decode(step, st, bos, n):
+            # (tokens, lengths, the state after the last step where one
+            # is asked for)
+            out = greedy_decode(step, st, bos=bos, eos=eos, max_len=n,
+                                batch_size=batch_size, with_state=want)
+            return out[0], out[1], out[2] if want else {}
+
+        def kept(toks, lengths, last):
+            return toks, lengths, {f: last[f] for f in return_state}
+
         if prompt is None:
             fn = self._jitted(
-                ("greedy", bos, eos, max_len, batch_size),
-                lambda: lambda params, s: greedy_decode(
-                    self._step_fn(params), s, bos=bos, eos=eos,
-                    max_len=max_len, batch_size=batch_size))
-            toks, lengths = fn(self._params, state)
-            return np.asarray(toks), np.asarray(lengths)
-
-        fn = self._jitted(
-            ("greedy-prefill", eos, max_len, batch_size,
-             prompt.shape[1]),
-            lambda: lambda params, s, p: self._prefilled_run(
-                params, s, p,
-                lambda step, st, first: greedy_decode(
-                    step, st, bos=first, eos=eos, max_len=max_len - 1,
-                    batch_size=batch_size),
-                eos, max_len))
-        toks, lengths = fn(self._params, state, jnp.asarray(prompt))
-        return np.asarray(toks), np.asarray(lengths)
+                ("greedy", bos, eos, max_len, batch_size, return_state),
+                lambda: lambda params, s: kept(*decode(
+                    self._step_fn(params), s, bos, max_len)))
+            out = fn(self._params, state)
+        else:
+            fn = self._jitted(
+                ("greedy-prefill", eos, max_len, batch_size,
+                 prompt.shape[1], return_state),
+                lambda: lambda params, s, p: kept(*self._prefilled_run(
+                    params, s, p,
+                    lambda step, st, first: decode(step, st, first,
+                                                   max_len - 1)[::2],
+                    eos, max_len)))
+            out = fn(self._params, state, jnp.asarray(prompt))
+        toks, lengths, last = jax.tree_util.tree_map(np.asarray, out)
+        return (toks, lengths, last) if return_state else (toks, lengths)
 
     def sample(self, bos, eos, max_len, batch_size=None, init_state=None,
                prompt=None, seed=0, temperature=1.0, top_k=0):
@@ -206,11 +227,12 @@ class ProgramDecoder:
                 key,
                 lambda: lambda params, s, p, rng: self._prefilled_run(
                     params, s, p,
-                    lambda step, st, first: sample_decode(
+                    lambda step, st, first: (sample_decode(
                         step, st, bos=first, eos=eos,
                         max_len=max_len - 1, batch_size=batch_size,
-                        rng=rng, temperature=temperature, top_k=top_k),
-                    eos, max_len))
+                        rng=rng, temperature=temperature,
+                        top_k=top_k)[0], None),
+                    eos, max_len)[:2])
             toks, lengths = fn(self._params, state, jnp.asarray(prompt),
                                jax.random.PRNGKey(seed))
         return np.asarray(toks), np.asarray(lengths)

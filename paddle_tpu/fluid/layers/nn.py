@@ -28,7 +28,8 @@ __all__ = [
     "sequence_slice", "lod_reset", "edit_distance", "ctc_greedy_decoder",
     "sequence_concat", "beam_search", "beam_search_decode",
     "sequence_reverse", "sequence_unnest", "sequence_renest",
-    "flash_attention", "cached_attention", "rms_norm", "rope", "moe",
+    "flash_attention", "cached_attention", "mla_cached_attention",
+    "rms_norm", "rope", "moe",
     "ssd_scan", "causal_conv1d", "expand",
 ]
 
@@ -54,6 +55,41 @@ def cached_attention(query, key, value, k_cache, v_cache, position,
         attrs={"num_heads": int(num_heads),
                "sm_scale": float(sm_scale or 0.0)})
     return out, kc_out, vc_out
+
+
+def mla_cached_attention(q_nope, q_rope, c_new, r_new, cache, position,
+                         num_heads, v_head_dim, uk_attr=None, uv_attr=None,
+                         name=None):
+    """One decode step of latent attention over a cache of latents
+    (ops/attention.py mla_cached_attention): `q_nope` [batch, 1,
+    num_heads * nope] and `q_rope` [batch, 1, num_heads * rope] (rotated)
+    the query, `c_new` [batch, 1, latent] (normed) and `r_new` [batch, 1,
+    rope] (rotated) the token's cache entry, `cache` [batch, positions,
+    latent + rope], `position` int [1] or [batch].  Creates the keys' and
+    values' up-projections [latent, num_heads * nope] and [latent,
+    num_heads * v_head_dim], which the op absorbs; the scores' scale is
+    the op's own, (nope + rope) ** -0.5.  Returns (out [batch,
+    1, num_heads * v_head_dim], cache_out): thread `cache_out` back as
+    decode state (`fluid.ProgramDecoder` state pairs)."""
+    helper = LayerHelper("mla_cached_attention", name=name)
+    latent = int(c_new.shape[-1])
+    nope = int(q_nope.shape[-1]) // int(num_heads)
+    w_uk = helper.create_parameter(
+        uk_attr or ParamAttr(), shape=[latent, num_heads * nope],
+        dtype=q_nope.dtype, default_initializer=Xavier())
+    w_uv = helper.create_parameter(
+        uv_attr or ParamAttr(), shape=[latent, num_heads * v_head_dim],
+        dtype=q_nope.dtype, default_initializer=Xavier())
+    out = helper.create_tmp_variable(q_nope.dtype)
+    cache_out = helper.create_tmp_variable(cache.dtype)
+    helper.append_op(
+        type="mla_cached_attention",
+        inputs={"QNope": [q_nope], "QRope": [q_rope], "CNew": [c_new],
+                "RNew": [r_new], "Cache": [cache], "WUk": [w_uk],
+                "WUv": [w_uv], "Position": [position]},
+        outputs={"Out": [out], "CacheOut": [cache_out]},
+        attrs={"num_heads": int(num_heads)})
+    return out, cache_out
 
 
 def flash_attention(queries, keys, values, num_heads=1, causal=False,
@@ -679,7 +715,8 @@ def rope(input, positions, num_heads, theta=10000.0, **kwargs):
 
 
 def moe(input, num_experts, expert_size, top_k, router_attr=None,
-        gate_attr=None, up_attr=None, down_attr=None, name=None):
+        gate_attr=None, up_attr=None, down_attr=None, name=None,
+        scoring="softmax", norm_topk=False, scale=1.0, held=None):
     """A routed expert layer over `input` [..., hidden] (ops/moe.py): a
     float32 router sends every token to its `top_k` of `num_experts`
     gated-SiLU experts of width `expert_size`, each computed for it (no
@@ -703,15 +740,27 @@ def moe(input, num_experts, expert_size, top_k, router_attr=None,
             attr or ParamAttr(), shape=shape, dtype=dtype,
             default_initializer=init)
 
+    first, count = held or (0, num_experts)
+    if not 0 <= first <= first + count <= num_experts:
+        raise ValueError("moe: held experts %d..%d are not among the %d "
+                         "scored" % (first, first + count, num_experts))
     w_router = param(router_attr, [hidden, num_experts], Xavier())
     stacked = Xavier(stacked=True)
-    w_gate = param(gate_attr, [num_experts, hidden, expert_size], stacked)
-    w_up = param(up_attr, [num_experts, hidden, expert_size], stacked)
-    w_down = param(down_attr, [num_experts, expert_size, hidden], stacked)
+    w_gate = param(gate_attr, [count, hidden, expert_size], stacked)
+    w_up = param(up_attr, [count, hidden, expert_size], stacked)
+    w_down = param(down_attr, [count, expert_size, hidden], stacked)
 
     def tmp(dtype, stop_gradient=False):
         return helper.create_tmp_variable(dtype, stop_gradient=stop_gradient)
 
+    # an op carries only what differs from the softmax layer that holds
+    # every expert: that layer's Program stays attr for attr what it was
+    routing = {"scoring": scoring, "norm_topk": bool(norm_topk),
+               "scale": float(scale)}
+    if routing == {"scoring": "softmax", "norm_topk": False, "scale": 1.0}:
+        routing = {}
+    share = {} if (first, count) == (0, num_experts) else {
+        "first_expert": int(first), "scored": int(num_experts)}
     logits, top_w = tmp("float32"), tmp("float32")
     top_idx = tmp("int32", stop_gradient=True)
     lb_loss, z_loss = tmp("float32"), tmp("float32")
@@ -719,7 +768,7 @@ def moe(input, num_experts, expert_size, top_k, router_attr=None,
         type="moe_router", inputs={"X": [input], "W": [w_router]},
         outputs={"Logits": [logits], "TopW": [top_w], "TopIdx": [top_idx],
                  "LbLoss": [lb_loss], "ZLoss": [z_loss]},
-        attrs={"top_k": int(top_k)})
+        attrs=dict({"top_k": int(top_k)}, **routing))
     out = tmp(dtype)
     kept = {slot: tmp("int32" if slot in ("RowSlot", "TokenRow", "Counts")
                       else dtype, stop_gradient=True)
@@ -729,7 +778,8 @@ def moe(input, num_experts, expert_size, top_k, router_attr=None,
         type="moe_experts",
         inputs={"X": [input], "TopW": [top_w], "TopIdx": [top_idx],
                 "WGate": [w_gate], "WUp": [w_up], "WDown": [w_down]},
-        outputs=dict({"Out": [out]}, **{s: [v] for s, v in kept.items()}))
+        outputs=dict({"Out": [out]}, **{s: [v] for s, v in kept.items()}),
+        attrs=share)
     return out, lb_loss, z_loss, {"logits": logits, "top_w": top_w,
                                   "top_idx": top_idx,
                                   "counts": kept["Counts"]}

@@ -41,9 +41,11 @@ def prefill(step_fn, init_state, prompt):
     return state, jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
 
-def greedy_decode(step_fn, init_state, bos, eos, max_len, batch_size):
+def greedy_decode(step_fn, init_state, bos, eos, max_len, batch_size,
+                  with_state=False):
     """step_fn(state, tokens[B]) -> (logits [B,V], new_state).
-    Returns (tokens [B, max_len], lengths [B]).  `bos` may be a scalar
+    Returns (tokens [B, max_len], lengths [B]), and with `with_state`
+    the state after the last step as a third.  `bos` may be a scalar
     or a per-row [B] array (e.g. prefill's first_token)."""
 
     def body(carry, _):
@@ -61,12 +63,12 @@ def greedy_decode(step_fn, init_state, bos, eos, max_len, batch_size):
     # GPT-2 endoftext convention) and must still generate
     done0 = (tok0 == eos) if bos.ndim else \
         jnp.zeros((batch_size,), bool)
-    (_, _, done), toks = jax.lax.scan(body, (init_state, tok0, done0),
-                                      None, length=max_len)
+    (state, _, done), toks = jax.lax.scan(body, (init_state, tok0, done0),
+                                          None, length=max_len)
     toks = jnp.moveaxis(toks, 0, 1)               # [B, L]
     lengths = jnp.argmax(toks == eos, axis=1) + 1
     lengths = jnp.where(jnp.any(toks == eos, axis=1), lengths, max_len)
-    return toks, lengths
+    return (toks, lengths, state) if with_state else (toks, lengths)
 
 
 def sample_decode(step_fn, init_state, bos, eos, max_len, batch_size,

@@ -180,6 +180,30 @@ def on_moe_gmm_lowering(kernel, block_m, block_n, block_k):
                   block_k=block_k).inc()
 
 
+def on_moe_share_lowering(scored, held, top_k):
+    """A routed expert layer that holds a range of the experts its
+    router scores (`moe_experts` with fewer experts than the router's
+    width, ops/moe.py) was traced into a program: one count per op
+    instance a lowered program holds."""
+    _reg().counter("moe_share_lowerings_total",
+                   "expert layers lowered that hold a range of the "
+                   "experts scored, by experts scored, held, and a token",
+                   labelnames=("scored", "held", "top_k")) \
+          .labels(scored=scored, held=held, top_k=top_k).inc()
+
+
+def on_mla_cached_attention_lowering(heads, latent, rope, cache_dtype):
+    """A decode step of latent attention (`mla_cached_attention`,
+    ops/attention.py) was traced into a program: one count per op
+    instance a lowered program holds."""
+    _reg().counter("mla_cached_attention_lowerings_total",
+                   "latent-attention decode steps lowered, by heads, "
+                   "latent and rotated-key widths and the cache's type",
+                   labelnames=("heads", "latent", "rope", "cache_dtype")) \
+          .labels(heads=heads, latent=latent, rope=rope,
+                  cache_dtype=str(cache_dtype)).inc()
+
+
 def on_ssd_lowering(kernel, chunk, heads_per_step):
     """The chunked state-space scan ("fwd") or its gradient ("bwd":
     kernels/ssd.py) was traced into a program, with its chunk and the

@@ -209,3 +209,96 @@ def cached_attention_op(ctx, ins, attrs):
                      v_cache.astype(jnp.float32))
     return {"Out": [merge_heads(out).astype(q.dtype)],
             "KCacheOut": [k_cache], "VCacheOut": [v_cache]}
+
+
+def _mla_infer_shape(block, op_desc):
+    q = block.var_recursive(op_desc.input("QNope")[0]).desc
+    w_uv = block.var_recursive(op_desc.input("WUv")[0]).desc
+    cache = block.var_recursive(op_desc.input("Cache")[0]).desc
+    out = block.var_recursive(op_desc.output("Out")[0]).desc
+    out.shape, out.dtype, out.lod_level = \
+        tuple(q.shape[:-1]) + (w_uv.shape[1],), q.dtype, 0
+    kept = block.var_recursive(op_desc.output("CacheOut")[0]).desc
+    kept.shape, kept.dtype, kept.lod_level = cache.shape, cache.dtype, 0
+
+
+@register_op("mla_cached_attention", stop_gradient_op=True,
+             infer_shape=_mla_infer_shape)
+def mla_cached_attention_op(ctx, ins, attrs):
+    """One decode step of multi-head latent attention (DeepSeek-V2,
+    arXiv:2405.04434, section 2.1) over a cache of *latents*: a token
+    and layer keep the normed compressed key/value `c` [latent] and the
+    one rotated key `r` [rope] that all heads share, side by side, and
+    no head's key or value.
+
+    QNope [batch, 1, heads * nope] and QRope [batch, 1, heads * rope]
+    (rotated) are this token's query; CNew [batch, 1, latent] (normed)
+    and RNew [batch, 1, rope] (rotated) its cache entry; Cache [batch,
+    positions, latent + rope]; WUk [latent, heads * nope] and WUv
+    [latent, heads * value] the up-projections of keys and values;
+    Position int [1] or [batch] (lockstep rows), the slot this step
+    writes (slots 0..Position attend).
+
+    The up-projections are absorbed, so that no key or value of a head
+    is ever made: with k_h = [c W_uk,h | r] and v_h = c W_uv,h,
+
+        q_lat,h = q_nope,h W_uk,h^T                    (`mla_absorb`)
+        s_h,t   = (q_lat,h . c_t + q_rope,h . r_t) / sqrt(nope + rope)
+        p       = softmax_t(s) over t <= Position      (`mla_scores`)
+        o_h     = (sum_t p_h,t c_t) W_uv,h             (`mla_values`)
+
+    which is attention over k_h, v_h exactly.  Both contractions over
+    the cache are one batched matrix product each ([heads, latent +
+    rope] x [positions, latent + rope]^T a row, and [heads, positions]
+    x [positions, latent + rope], whose last `rope` columns are dropped:
+    cheaper than a copy of the cache without them), in the cache's
+    type with float32 sums; scores and softmax are float32.  Out [batch,
+    1, heads * value], CacheOut the cache with the slot written: a
+    `ProgramDecoder` state pair.  No gradient, as `cached_attention`."""
+    q_nope, q_rope = ins["QNope"][0], ins["QRope"][0]
+    c_new, r_new = ins["CNew"][0], ins["RNew"][0]
+    cache, w_uk, w_uv = ins["Cache"][0], ins["WUk"][0], ins["WUv"][0]
+    pos = jnp.reshape(ins["Position"][0], (-1,))[0].astype(jnp.int32)
+    heads = int(attrs["num_heads"])
+    batch, positions, width = cache.shape
+    latent, rope_dim = c_new.shape[-1], r_new.shape[-1]
+    if latent + rope_dim != width or w_uk.shape[0] != latent:
+        raise ValueError(
+            "mla_cached_attention: the cache holds %d values a token, "
+            "the latent is %d wide and the rotated key %d; W_uk is %s"
+            % (width, latent, rope_dim, w_uk.shape))
+    nope = q_nope.shape[-1] // heads
+    sm_scale = (nope + rope_dim) ** -0.5
+    telemetry.on_mla_cached_attention_lowering(heads, latent, rope_dim,
+                                               cache.dtype)
+    dtype = q_nope.dtype
+    f32 = jnp.float32
+
+    entry = jnp.concatenate([c_new, r_new], axis=-1).reshape(batch, 1, width)
+    cache = jax.lax.dynamic_update_slice_in_dim(
+        cache, entry.astype(cache.dtype), pos, axis=1)
+    # a cache in a narrower type than the products' is read up to it
+    live = cache.astype(dtype)
+
+    with jax.named_scope("mla_absorb"):
+        q_lat = jnp.einsum(
+            "bhd,chd->bhc", q_nope.reshape(batch, heads, nope),
+            w_uk.reshape(latent, heads, nope).astype(dtype),
+            preferred_element_type=f32).astype(dtype)
+        q = jnp.concatenate(
+            [q_lat, q_rope.reshape(batch, heads, rope_dim)], axis=-1)
+    with jax.named_scope("mla_scores"):
+        s = jnp.einsum("bhw,btw->bht", q, live,
+                       preferred_element_type=f32) * sm_scale
+        valid = jnp.arange(positions) <= pos
+        p = jax.nn.softmax(jnp.where(valid[None, None, :], s, -1e30),
+                           axis=-1)
+    with jax.named_scope("mla_values"):
+        o_lat = jnp.einsum("bht,btw->bhw", p.astype(dtype), live,
+                           preferred_element_type=f32)[..., :latent]
+        out = jnp.einsum(
+            "bhc,chd->bhd", o_lat.astype(dtype),
+            w_uv.reshape(latent, heads, -1).astype(dtype),
+            preferred_element_type=f32)
+    return {"Out": [out.reshape(batch, 1, -1).astype(dtype)],
+            "CacheOut": [cache]}

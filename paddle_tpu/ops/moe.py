@@ -21,6 +21,18 @@ each token adds up its rows weighted by the router's probabilities.
 Products take the compute type's operands (bfloat16 under AMP) and add
 up in float32.
 
+`moe_router` can also score as the DeepSeek-V3 family serves
+(`scoring="sigmoid"`: each expert's sigmoid on its own; `norm_topk`
+divides the chosen weights by their sum, `scale` multiplies them), with
+no auxiliary loss: those two are the softmax router's.  And
+`moe_experts` can hold a *range* of the experts the router scores, as
+one chip of an expert-parallel deployment does: `WGate.shape[0]` experts
+from `first_expert` on.  An assignment to an expert outside the range is
+left out before the ordering (it sorts behind every held one and
+belongs to no group, so no product visits it) and adds nothing: the
+output is the held experts' part of the layer's, which the other chips'
+parts would be added to.  Forward only.
+
 The gradient of `moe_experts` is explicit, for the reason
 `flash_attention`'s is: jax.vjp of the op would run the forward's
 three grouped products again.  The forward op keeps, as outputs of its
@@ -79,6 +91,15 @@ def _router_infer_shape(block, op_desc):
         _set_meta(block, op_desc.output(slot)[0], shape, dtype)
 
 
+def _chosen(top_w, attrs):
+    """The chosen experts' weights as the op's attrs ask for them; as
+    they are where neither is set."""
+    if attrs.get("norm_topk", False):
+        top_w = top_w / (jnp.sum(top_w, axis=-1, keepdims=True) + 1e-20)
+    scale = float(attrs.get("scale", 1.0))
+    return top_w if scale == 1.0 else top_w * scale
+
+
 @register_op("moe_router", infer_shape=_router_infer_shape)
 def moe_router(ctx, ins, attrs):
     """X [..., hidden], W [hidden, experts] -> Logits [tokens, experts],
@@ -88,13 +109,28 @@ def moe_router(ctx, ins, attrs):
     constant to the gradient; P_e the mean probability of e) and ZLoss
     = mean(logsumexp(logits)^2), each [1].  float32 throughout, the
     product at the highest precision: the TPU's default would round its
-    operands to bfloat16."""
+    operands to bfloat16.
+
+    With `scoring` "sigmoid" the scores are sigmoid(logits), TopW the
+    largest of them, and both losses 0.  `norm_topk` divides TopW by its
+    sum over the chosen (+ 1e-20) and `scale` multiplies it, under
+    either scoring."""
     x, w = ins["X"][0], ins["W"][0]
     k = int(attrs["top_k"])
     experts = w.shape[1]
+    scoring = attrs.get("scoring", "softmax")
     logits = jnp.dot(x.reshape(-1, x.shape[-1]).astype(jnp.float32),
                      w.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
+    if scoring == "sigmoid":
+        top_w, top_idx = jax.lax.top_k(jax.nn.sigmoid(logits), k)
+        zero = jnp.zeros((1,), jnp.float32)
+        return {"Logits": [logits], "TopW": [_chosen(top_w, attrs)],
+                "TopIdx": [top_idx.astype(jnp.int32)],
+                "LbLoss": [zero], "ZLoss": [zero]}
+    if scoring != "softmax":
+        raise ValueError("moe_router: scoring is softmax or sigmoid, got "
+                         "%r" % scoring)
     lse = jax.nn.logsumexp(logits, axis=-1, keepdims=True)
     probs = jnp.exp(logits - lse)
     top_w, top_idx = jax.lax.top_k(probs, k)
@@ -102,7 +138,7 @@ def moe_router(ctx, ins, attrs):
         / top_idx.size
     lb = experts * jnp.sum(share * jnp.mean(probs, axis=0))
     z = jnp.mean(jnp.square(lse))
-    return {"Logits": [logits], "TopW": [top_w],
+    return {"Logits": [logits], "TopW": [_chosen(top_w, attrs)],
             "TopIdx": [top_idx.astype(jnp.int32)],
             "LbLoss": [lb.reshape(1)], "ZLoss": [z.reshape(1)]}
 
@@ -119,6 +155,12 @@ def _experts_infer_shape(block, op_desc):
             ("RowSlot", (rows,), "int32"), ("TokenRow", (rows,), "int32"),
             ("Counts", (experts,), "int32")):
         _set_meta(block, op_desc.output(slot)[0], shape, dtype)
+
+
+def _held_range(attrs, experts):
+    """(first_expert, scored) of an expert op that holds `experts`."""
+    return (int(attrs.get("first_expert", 0)),
+            int(attrs.get("scored", 0)) or experts)
 
 
 def _token_rows(rows, token_row, n, k):
@@ -145,7 +187,9 @@ def moe_experts(ctx, ins, attrs):
     [experts, hidden, width], WDown [experts, width, hidden] -> Out, X's
     shape: sum_j TopW[n, j] * down_e(silu(gate_e(x_n)) * up_e(x_n)) with
     e = TopIdx[n, j]; and what the gradient reads (the module's
-    docstring)."""
+    docstring).  Where the router scores `scored` experts and the op
+    holds fewer (`experts` of them from `first_expert` on), the sum is
+    over the held e alone."""
     from ..kernels.grouped_matmul import gmm
 
     x, top_w, top_idx = ins["X"][0], ins["TopW"][0], ins["TopIdx"][0]
@@ -153,9 +197,19 @@ def moe_experts(ctx, ins, attrs):
     n, k = top_idx.shape
     experts = w_gate.shape[0]
     telemetry.on_moe_lowering(experts, k)
+    first, scored = _held_range(attrs, experts)
+    ranged = (first, scored) != (0, experts)
 
     with jax.named_scope("moe_route"):
         flat = top_idx.reshape(-1).astype(jnp.int32)
+        if ranged:
+            telemetry.on_moe_share_lowering(scored, experts, k)
+            with jax.named_scope("moe_hold"):
+                # an absent expert's assignments sort behind every held
+                # one's and are counted for no group
+                flat = flat - first
+                held = (flat >= 0) & (flat < experts)
+                flat = jnp.where(held, flat, experts)
         slots = jnp.arange(n * k, dtype=jnp.int32)
         # row r of the order holds slot row_slot[r] = token * k + j, and
         # slot s lies in row token_row[s]
@@ -172,8 +226,11 @@ def moe_experts(ctx, ins, attrs):
             .astype(xs.dtype)
         y = gmm(h, wd, counts)
     with jax.named_scope("moe_combine"):
-        out = jnp.sum(_token_rows(y, token_row, n, k).astype(jnp.float32)
-                      * top_w.astype(jnp.float32)[..., None], axis=1)
+        rows = _token_rows(y, token_row, n, k).astype(jnp.float32)
+        if ranged:
+            # no product wrote an absent assignment's row
+            rows = jnp.where(held.reshape(n, k, 1), rows, 0.0)
+        out = jnp.sum(rows * top_w.astype(jnp.float32)[..., None], axis=1)
     out = amp_result(out, x.dtype).reshape(x.shape)
     return {"Out": [out], "Xs": [xs], "Gate": [gate], "Up": [up],
             "RowSlot": [row_slot], "TokenRow": [token_row],
@@ -190,6 +247,10 @@ def moe_experts_grad(ctx, ins, attrs):
 
     x, top_w = ins["X"][0], ins["TopW"][0]
     w_gate, w_up, w_down = (ins[s][0] for s in ("WGate", "WUp", "WDown"))
+    if _held_range(attrs, w_gate.shape[0]) != (0, w_gate.shape[0]):
+        raise NotImplementedError(
+            "moe_experts holding a range of the experts scored has no "
+            "gradient: the rows of absent experts are never computed")
     xs, gate, up, row_slot, token_row, counts = (
         ins["O@" + s][0] for s in ("Xs", "Gate", "Up", "RowSlot",
                                    "TokenRow", "Counts"))

@@ -1,0 +1,574 @@
+"""Latent attention over a cache of latents (`mla_cached_attention`), the
+router's sigmoid scoring and the expert op's range form (ops/moe.py), and
+the cached step Program built on them (models/latent_moe_program.py)
+against the plain float32 reference (models/reference/pangu_moe.py): the
+step driven position by position through its cache against the
+reference's unabsorbed full-sequence forward; the shares of an expert
+layer adding up to the uncut layer; the router; a bfloat16 cache against
+the float32 one; the counters; and `ProgramDecoder` taking the scope's
+arrays as they are.
+
+Tiny sizes on the CPU: 3 layers (1 dense), hidden 64, 4 heads of 16 + 8
+(values 16), query rank 32, latent 16, 8 experts scored of which 4 are
+held, 2 a token, vocabulary 97, seeded random weights (norm scales moved
+off their initial 1, so that a scale left out shows).
+"""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import paddle_tpu.fluid as fluid
+from paddle_tpu.fluid.param_attr import ParamAttr
+from paddle_tpu.models.latent_moe_program import (
+    build_latent_moe_cached_step_program, latent_moe_param_names)
+from paddle_tpu.models.reference import pangu_moe as reference
+from paddle_tpu.models.transformer_program import (
+    build_transformer_cached_step_program)
+from paddle_tpu.obs import telemetry
+from paddle_tpu.ops import registry
+
+B, T, V, L, DENSE = 3, 12, 97, 3, 1
+H, D, QR, KVR, NOPE, ROPE, DV, FF, FE = 4, 64, 32, 16, 16, 8, 16, 128, 32
+E, K, HELD = 8, 2, (2, 4)
+SIZES = dict(n_layer=L, n_dense=DENSE, n_head=H, d_model=D, q_rank=QR,
+             kv_rank=KVR, d_nope=NOPE, d_rope=ROPE, d_v=DV, d_ff=FF,
+             d_expert=FE, n_experts=E, held=HELD, top_k=K)
+CFG = {"num_attention_heads": H, "rms_norm_eps": 1e-5, "rope_theta": 1e4,
+       "kv_lora_rank": KVR, "num_experts_per_tok": K,
+       "norm_topk_prob": True, "routed_scaling_factor": 2.5}
+NAMES = latent_moe_param_names(L, DENSE)
+
+# float32 on the CPU.  The step absorbs the keys' and values'
+# up-projections and reads a cache; the reference makes every head's keys
+# and values and the whole score matrix: other sums in another order.
+# Logits of size ~4 were seen to differ by 2e-6 (5e-7 of them); 1e-5 of
+# the largest logit is a dozen times that and a hundred times under one
+# bfloat16 rounding.
+LOGITS_RTOL = 1e-5
+
+
+def _start(startup, seed=3):
+    scope = fluid.Scope()
+    exe = fluid.Executor(fluid.CPUPlace())
+    startup.random_seed = seed
+    exe.run(startup, scope=scope)
+    rs = np.random.RandomState(seed)
+    for name in jax.tree_util.tree_leaves(NAMES):
+        value = np.asarray(scope.get(name))
+        if value.ndim == 1:
+            scope.set(name, jnp.asarray(
+                value + 0.1 * rs.randn(*value.shape).astype("float32")))
+    return scope
+
+
+def _decoder(main, logits, pairs, scope):
+    return fluid.ProgramDecoder(
+        main.clone(for_test=True), token_name="tok",
+        logits_name=logits.name, state_pairs=pairs, scope=scope,
+        max_positions=T)
+
+
+def _empty(dtype=jnp.float32, layers=L):
+    state = {"latent_cache_%d" % i: jnp.zeros((B, T, KVR + ROPE), dtype)
+             for i in range(layers)}
+    state["pos"] = jnp.zeros((B,), jnp.int32)
+    return state
+
+
+def _drive(decoder, tokens, state):
+    """[B, T, V]: the step applied position by position."""
+    step = decoder._step_fn(decoder._params)
+    out = []
+    for t in range(tokens.shape[1]):
+        logits, state = step(state, jnp.asarray(tokens[:, t]))
+        out.append(logits)
+    return np.stack([np.asarray(z, np.float32) for z in out], axis=1), state
+
+
+@pytest.fixture(scope="module")
+def built():
+    before = telemetry.snapshot()
+    main, startup, logits, pairs, parts = \
+        build_latent_moe_cached_step_program(B, T, V, **SIZES)
+    at_build = telemetry.snapshot_delta(before)
+    scope = _start(startup)
+    decoder = _decoder(main, logits, pairs, scope)
+    tokens = np.random.RandomState(1).randint(0, V, (B, T)).astype("int32")
+    got, state = _drive(decoder, tokens, _empty())
+    params = jax.tree_util.tree_map(scope.get, NAMES)
+    want = reference.forward(CFG, params, jnp.asarray(tokens), held=HELD)
+    return {"main": main, "logits": logits, "pairs": pairs, "scope": scope,
+            "decoder": decoder, "tokens": tokens, "got": got,
+            "state": state, "params": params, "want": want,
+            "at_build": at_build}
+
+
+# -- (a) the step through its cache against the full forward ------------------
+
+@pytest.mark.parametrize("position", range(T))
+def test_step_logits_agree_with_the_reference_at_every_position(
+        built, position):
+    want = np.asarray(built["want"]["logits"])[:, position]
+    got = built["got"][:, position]
+    assert np.abs(got - want).max() <= LOGITS_RTOL * np.abs(want).max()
+
+
+def test_the_cache_holds_the_latents_and_no_heads(built):
+    """576 values a token at the published widths: here 16 + 8, written
+    at the slot of each position, the normed latent beside the rotated
+    key."""
+    cache = np.asarray(built["state"]["latent_cache_1"])
+    assert cache.shape == (B, T, KVR + ROPE)
+    assert np.all(np.abs(cache).sum(axis=-1) > 0)
+    assert int(built["state"]["pos"][0]) == T
+    # the normed latent has a root mean square near its scale's
+    rms = np.sqrt(np.mean(np.square(cache[..., :KVR]), axis=-1))
+    assert 0.5 < rms.min() and rms.max() < 2.0
+
+
+def test_greedy_through_the_decoder_is_the_references_greedy(built):
+    """Prefill then decode through `ProgramDecoder.greedy`: every served
+    token is the reference's first given the tokens before it (or lies
+    within rounding of it)."""
+    prompt = built["tokens"][:, :5]
+    tokens, lengths = built["decoder"].greedy(
+        bos=0, eos=V, max_len=T - 4, init_state=_empty(), prompt=prompt)
+    assert tokens.shape == (B, T - 4) and (lengths == T - 4).all()
+    full = np.concatenate([prompt, tokens], axis=1)[:, :T]
+    z = np.asarray(reference.forward(
+        CFG, built["params"], jnp.asarray(full), held=HELD)["logits"])
+    served = tokens[:, :T - 4]
+    picked = np.take_along_axis(z[:, 4:4 + served.shape[1]],
+                                served[..., None], axis=-1)[..., 0]
+    gap = z[:, 4:4 + served.shape[1]].max(axis=-1) - picked
+    assert gap.max() <= 1e-4
+
+
+def test_a_position_past_the_cache_is_refused(built):
+    with pytest.raises(ValueError, match="extent"):
+        built["decoder"].greedy(bos=0, eos=V, max_len=T, init_state=_empty(),
+                                prompt=built["tokens"][:, :5])
+
+
+# -- the attention op alone ----------------------------------------------------
+
+def _mla_ins(rs, dtype=jnp.float32, cache_dtype=jnp.float32, pos=5):
+    def draw(*shape):
+        return jnp.asarray(rs.randn(*shape), dtype)
+
+    cache = jnp.asarray(rs.randn(B, T, KVR + ROPE), cache_dtype)
+    cache = cache.at[:, pos:].set(0)
+    return {"QNope": [draw(B, 1, H * NOPE)], "QRope": [draw(B, 1, H * ROPE)],
+            "CNew": [draw(B, 1, KVR)], "RNew": [draw(B, 1, ROPE)],
+            "Cache": [cache], "WUk": [0.3 * draw(KVR, H * NOPE)],
+            "WUv": [0.3 * draw(KVR, H * DV)],
+            "Position": [jnp.full((B,), pos, jnp.int32)]}
+
+
+def _unabsorbed(ins, pos):
+    """Attention over keys [c W_uk | r] and values c W_uv, made whole."""
+    f32 = jnp.float32
+    cache = ins["Cache"][0].astype(f32)
+    entry = jnp.concatenate([ins["CNew"][0], ins["RNew"][0]], -1).astype(f32)
+    cache = cache.at[:, pos].set(entry[:, 0])[:, :pos + 1]
+    c, r = cache[..., :KVR], cache[..., KVR:]
+    k_nope = (c @ ins["WUk"][0].astype(f32)).reshape(B, pos + 1, H, NOPE)
+    v = (c @ ins["WUv"][0].astype(f32)).reshape(B, pos + 1, H, DV)
+    k = jnp.concatenate(
+        [k_nope, jnp.broadcast_to(r[:, :, None], (B, pos + 1, H, ROPE))], -1)
+    q = jnp.concatenate(
+        [ins["QNope"][0].astype(f32).reshape(B, H, NOPE),
+         ins["QRope"][0].astype(f32).reshape(B, H, ROPE)], -1)
+    s = jnp.einsum("bhd,bthd->bht", q, k) / np.sqrt(NOPE + ROPE)
+    return jnp.einsum("bht,bthd->bhd", jax.nn.softmax(s, -1),
+                      v).reshape(B, 1, H * DV)
+
+
+@pytest.mark.parametrize("pos", [0, 5, T - 1])
+def test_absorbed_attention_is_attention_over_the_heads_keys(pos):
+    ins = _mla_ins(np.random.RandomState(pos), pos=pos)
+    outs = registry.get_op_info("mla_cached_attention").kernel(
+        None, ins, {"num_heads": H})
+    np.testing.assert_allclose(outs["Out"][0], _unabsorbed(ins, pos),
+                               atol=2e-5)
+    kept = np.asarray(outs["CacheOut"][0])
+    np.testing.assert_array_equal(kept[:, pos, :KVR],
+                                  np.asarray(ins["CNew"][0])[:, 0])
+    np.testing.assert_array_equal(kept[:, pos, KVR:],
+                                  np.asarray(ins["RNew"][0])[:, 0])
+    np.testing.assert_array_equal(kept[:, :pos],
+                                  np.asarray(ins["Cache"][0])[:, :pos])
+
+
+def test_a_slot_past_the_position_is_not_attended():
+    ins = _mla_ins(np.random.RandomState(7), pos=4)
+    kernel = registry.get_op_info("mla_cached_attention").kernel
+    want = kernel(None, ins, {"num_heads": H})["Out"][0]
+    dirty = dict(ins, Cache=[ins["Cache"][0].at[:, 5:].set(9.0)])
+    np.testing.assert_array_equal(
+        kernel(None, dirty, {"num_heads": H})["Out"][0], want)
+
+
+def test_both_contractions_over_the_cache_are_matrix_products():
+    """Two `dot_general`s over the cache with float32 sums, and no
+    multiply followed by a reduction over its extent."""
+    ins = _mla_ins(np.random.RandomState(2), jnp.bfloat16, jnp.bfloat16)
+    kernel = registry.get_op_info("mla_cached_attention").kernel
+    jaxpr = jax.make_jaxpr(
+        lambda i: kernel(None, i, {"num_heads": H})["Out"][0])(ins)
+    dots = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "dot_general"]
+    over_cache = [e for e in dots
+                  if (B, T, KVR + ROPE) in [v.aval.shape for v in e.invars]]
+    assert len(over_cache) == 2 and len(dots) == 4
+    assert all(e.params["preferred_element_type"] == jnp.float32
+               for e in dots)
+    assert all(v.aval.dtype == jnp.bfloat16
+               for e in over_cache for v in e.invars)
+
+
+def test_a_cache_of_another_width_is_refused():
+    ins = _mla_ins(np.random.RandomState(2))
+    ins["Cache"] = [jnp.zeros((B, T, KVR + ROPE + 1))]
+    with pytest.raises(ValueError, match="cache holds"):
+        registry.get_op_info("mla_cached_attention").kernel(
+            None, ins, {"num_heads": H})
+
+
+# -- (b) the shares add up ------------------------------------------------------
+
+def _expert_weights(rs, experts=E):
+    w_gate, w_up = (jnp.asarray(rs.randn(experts, D, FE) * 0.2, jnp.float32)
+                    for _ in range(2))
+    return w_gate, w_up, jnp.asarray(rs.randn(experts, FE, D) * 0.2,
+                                     jnp.float32)
+
+
+def _held_part(x, top_w, top_idx, weights, first, count):
+    ins = {"X": [x], "TopW": [top_w], "TopIdx": [top_idx],
+           "WGate": [weights[0][first:first + count]],
+           "WUp": [weights[1][first:first + count]],
+           "WDown": [weights[2][first:first + count]]}
+    return registry.get_op_info("moe_experts").kernel(
+        None, ins, {"first_expert": first, "scored": E})
+
+
+@pytest.mark.parametrize("ranges", [[(0, 4), (4, 4)], [(0, 2), (2, 4), (6, 2)],
+                                    [(i, 1) for i in range(E)]])
+def test_the_shares_add_up_to_the_uncut_layer(ranges):
+    """The held parts of all N ranges plus the shared expert counted once
+    equal the reference's uncut layer."""
+    rs = np.random.RandomState(len(ranges))
+    n = 24
+    u = jnp.asarray(rs.randn(n, D), jnp.float32)
+    weights = _expert_weights(rs)
+    block = {"router": jnp.asarray(rs.randn(D, E) * 0.3, jnp.float32),
+             "w_gate": weights[0], "w_up": weights[1], "w_down": weights[2],
+             "shared_in": jnp.asarray(rs.randn(D, 2 * FE) * 0.2, jnp.float32),
+             "shared_out": jnp.asarray(rs.randn(FE, D) * 0.2, jnp.float32)}
+    want, indices = reference.feed_forward(CFG, block, u)
+    routed = registry.get_op_info("moe_router").kernel(
+        None, {"X": [u], "W": [block["router"]]},
+        {"top_k": K, "scoring": "sigmoid", "norm_topk": True, "scale": 2.5})
+    np.testing.assert_array_equal(routed["TopIdx"][0], indices)
+    total = reference.gated(u, block["shared_in"], block["shared_out"])
+    rows = 0
+    for first, count in ranges:
+        part = _held_part(u, routed["TopW"][0], routed["TopIdx"][0],
+                          weights, first, count)
+        rows += int(np.asarray(part["Counts"][0]).sum())
+        assert part["Counts"][0].shape == (count,)
+        total = total + part["Out"][0]
+        # each share is the reference's own part of the routed sum
+        cut = dict(block, **{w: block[w][first:first + count]
+                             for w in ("w_gate", "w_up", "w_down")})
+        np.testing.assert_allclose(
+            part["Out"][0],
+            reference.feed_forward(CFG, cut, u, first, shared=False)[0],
+            atol=2e-5)
+    assert rows == n * K        # every assignment computed once, somewhere
+    np.testing.assert_allclose(total, want, atol=3e-5)
+
+
+def test_a_share_none_of_whose_experts_is_chosen_adds_nothing():
+    rs = np.random.RandomState(4)
+    x = jnp.asarray(rs.randn(8, D), jnp.float32)
+    top_idx = jnp.asarray(rs.randint(0, 4, (8, K)), jnp.int32)
+    top_w = jnp.asarray(rs.uniform(0.1, 0.5, (8, K)), jnp.float32)
+    part = _held_part(x, top_w, top_idx, _expert_weights(rs), 4, 4)
+    assert not np.asarray(part["Out"][0]).any()
+    assert not np.asarray(part["Counts"][0]).any()
+
+
+def test_the_whole_range_is_the_op_as_it_was():
+    """`first_expert` 0 with every scored expert held lowers as the op
+    without the attrs does: the same jaxpr."""
+    rs = np.random.RandomState(5)
+    x = jnp.asarray(rs.randn(8, D), jnp.float32)
+    top_idx = jnp.asarray(rs.randint(0, E, (8, K)), jnp.int32)
+    top_w = jnp.asarray(rs.uniform(0.1, 0.5, (8, K)), jnp.float32)
+    w = _expert_weights(rs)
+    ins = {"X": [x], "TopW": [top_w], "TopIdx": [top_idx], "WGate": [w[0]],
+           "WUp": [w[1]], "WDown": [w[2]]}
+    kernel = registry.get_op_info("moe_experts").kernel
+    plain = jax.make_jaxpr(lambda i: kernel(None, i, {})["Out"][0])(ins)
+    whole = jax.make_jaxpr(lambda i: kernel(
+        None, i, {"first_expert": 0, "scored": E})["Out"][0])(ins)
+    assert str(plain) == str(whole)
+
+
+def test_a_share_has_no_gradient():
+    rs = np.random.RandomState(6)
+    w = _expert_weights(rs, 4)
+    ins = {"X": [jnp.zeros((8, D))], "TopW": [jnp.zeros((8, K))],
+           "WGate": [w[0]], "WUp": [w[1]], "WDown": [w[2]]}
+    with pytest.raises(NotImplementedError, match="range"):
+        registry.get_op_info("moe_experts").grad_kernel(
+            None, ins, {"first_expert": 2, "scored": E})
+
+
+def test_the_layer_refuses_a_range_outside_the_scored_experts():
+    with fluid.program_guard(fluid.Program(), fluid.Program()):
+        x = fluid.layers.data(name="x", shape=[4, D], dtype="float32",
+                              append_batch_size=False)
+        with pytest.raises(ValueError, match="held experts"):
+            fluid.layers.moe(x, E, FE, K, held=(6, 4))
+
+
+# -- (c) the router --------------------------------------------------------------
+
+def _router(attrs, u, w):
+    return registry.get_op_info("moe_router").kernel(
+        None, {"X": [u], "W": [w]}, dict({"top_k": K}, **attrs))
+
+
+def test_sigmoid_router_agrees_with_the_reference():
+    rs = np.random.RandomState(8)
+    u = jnp.asarray(rs.randn(40, D), jnp.float32)
+    w = jnp.asarray(rs.randn(D, E) * 0.3, jnp.float32)
+    got = _router({"scoring": "sigmoid", "norm_topk": True, "scale": 2.5},
+                  u, w)
+    weights, indices, scores = reference.route(CFG, {"router": w}, u)
+    np.testing.assert_array_equal(got["TopIdx"][0], indices)
+    np.testing.assert_allclose(got["TopW"][0].sum(-1), 2.5, rtol=1e-6)
+    np.testing.assert_allclose(
+        got["TopW"][0],
+        np.take_along_axis(np.asarray(weights), np.asarray(indices), 1),
+        rtol=1e-6)
+    top = np.take_along_axis(np.asarray(scores), np.asarray(indices), 1)
+    np.testing.assert_allclose(
+        got["TopW"][0], 2.5 * top / top.sum(-1, keepdims=True), rtol=1e-6)
+    # no auxiliary loss at serving: both are the softmax router's
+    assert float(got["LbLoss"][0][0]) == 0.0 == float(got["ZLoss"][0][0])
+    assert got["Logits"][0].dtype == jnp.float32
+
+
+def test_softmax_scoring_gives_todays_outputs_exactly():
+    rs = np.random.RandomState(9)
+    u = jnp.asarray(rs.randn(40, D), jnp.float32)
+    w = jnp.asarray(rs.randn(D, E) * 0.3, jnp.float32)
+    was, now = _router({}, u, w), _router(
+        {"scoring": "softmax", "norm_topk": False, "scale": 1.0}, u, w)
+    for slot in ("Logits", "TopW", "TopIdx", "LbLoss", "ZLoss"):
+        np.testing.assert_array_equal(was[slot][0], now[slot][0])
+    kernel = registry.get_op_info("moe_router").kernel
+    jaxprs = [str(jax.make_jaxpr(lambda a, b: kernel(
+        None, {"X": [a], "W": [b]}, dict({"top_k": K}, **attrs))["TopW"][0])(
+            u, w)) for attrs in ({}, {"scoring": "softmax", "scale": 1.0})]
+    assert jaxprs[0] == jaxprs[1]
+    probs = jax.nn.softmax(u @ w, axis=-1)
+    np.testing.assert_allclose(was["TopW"][0], jax.lax.top_k(probs, K)[0],
+                               rtol=1e-5)
+
+
+def test_an_unknown_scoring_is_refused():
+    with pytest.raises(ValueError, match="scoring"):
+        _router({"scoring": "tanh"}, jnp.zeros((4, D)), jnp.zeros((D, E)))
+
+
+# -- (d) a bfloat16 cache against the float32 one ------------------------------
+
+def test_a_bfloat16_latent_cache_stays_near_the_float32_one(built):
+    """Float32 weights and products, the cache alone in bfloat16: each
+    cached value is off by at most 2^-9 of itself, and logits of size ~3
+    were seen to move by at most 0.016 (5e-3 of the largest); 0.02 of the
+    largest is four times that and fails a float8 cache (seen: 0.65 of
+    the largest)."""
+    got, state = _drive(built["decoder"], built["tokens"],
+                        _empty(jnp.bfloat16))
+    assert state["latent_cache_0"].dtype == jnp.bfloat16
+    moved = np.abs(got - built["got"]).max()
+    assert 0 < moved <= 0.02 * np.abs(built["got"]).max()
+    eighth, _ = _drive(built["decoder"], built["tokens"],
+                       _empty(jnp.float8_e4m3fn))
+    assert np.abs(eighth - built["got"]).max() > \
+        0.02 * np.abs(built["got"]).max()
+
+
+def test_a_bfloat16_key_value_cache_stays_near_the_float32_one():
+    """The GPT-2-shaped cached step (`cached_attention`): the same
+    comparison on its key and value caches (PERF.md section 7 asked)."""
+    heads, width, layers, vocab = 2, 32, 2, 31
+    main, startup, logits, pairs = build_transformer_cached_step_program(
+        B, T, vocab, n_layer=layers, n_head=heads, d_model=width)
+    scope = fluid.Scope()
+    startup.random_seed = 11
+    fluid.Executor(fluid.CPUPlace()).run(startup, scope=scope)
+    decoder = fluid.ProgramDecoder(
+        main.clone(for_test=True), token_name="tok",
+        logits_name=logits.name, state_pairs=pairs, scope=scope,
+        max_positions=T)
+    tokens = np.random.RandomState(2).randint(0, vocab, (B, T))
+
+    def run(dtype):
+        state = {name: jnp.zeros((B, heads, T, width // heads), dtype)
+                 for name, _ in pairs if name != "pos"}
+        state["pos"] = jnp.zeros((1,), jnp.int32)
+        return _drive(decoder, tokens.astype("int32"), state)[0]
+
+    whole, half = run(jnp.float32), run(jnp.bfloat16)
+    moved = np.abs(half - whole).max()
+    assert 0 < moved <= 0.02 * np.abs(whole).max()
+
+
+# -- (e) the counters -------------------------------------------------------------
+
+def test_the_build_lowers_nothing(built):
+    assert not [k for k in built["at_build"]
+                if k.startswith(("mla_cached_attention_lowerings_total",
+                                 "moe_share_lowerings_total"))]
+
+
+def test_counters_say_what_was_lowered(built):
+    decoder = built["decoder"]
+    before = telemetry.snapshot()
+    jax.make_jaxpr(decoder._step_fn(decoder._params))(
+        _empty(), jnp.asarray(built["tokens"][:, 0]))
+    lowered = telemetry.snapshot_delta(before)
+    mla = "mla_cached_attention_lowerings_total{cache_dtype=float32," \
+        "heads=%d,latent=%d,rope=%d}" % (H, KVR, ROPE)
+    share = "moe_share_lowerings_total{held=%d,scored=%d,top_k=%d}" \
+        % (HELD[1], E, K)
+    # one count an op instance a traced step holds
+    assert lowered[mla] == L
+    assert lowered[share] == L - DENSE
+    assert lowered["moe_lowerings_total{experts=%d,top_k=%d}"
+                   % (HELD[1], K)] == L - DENSE
+
+
+# -- (f) the decoder takes the scope's arrays as they are -----------------------
+
+def test_program_decoder_params_are_the_scopes_arrays(built):
+    scope, decoder = built["scope"], built["decoder"]
+    names = jax.tree_util.tree_leaves(NAMES)
+    assert set(names) <= set(decoder._params)
+    for name in names:
+        assert decoder._params[name] is scope.get(name)
+
+
+def test_program_decoder_still_takes_host_arrays():
+    """A scope filled from the host (a checkpoint's numpy arrays) is put
+    on the device once."""
+    main, startup, logits, pairs, _ = build_latent_moe_cached_step_program(
+        B, T, V, **dict(SIZES, n_layer=1, n_dense=1))
+    scope = fluid.Scope()
+    fluid.Executor(fluid.CPUPlace()).run(startup, scope=scope)
+    for name in jax.tree_util.tree_leaves(latent_moe_param_names(1, 1)):
+        scope.set(name, np.asarray(scope.get(name)))
+    decoder = _decoder(main, logits, pairs, scope)
+    assert all(isinstance(v, jax.Array) for v in decoder._params.values())
+
+
+# -- a write-only state pair shows an intermediate of the last step -------------
+
+@pytest.mark.parametrize("gen", [1, 5])
+def test_greedy_returns_the_last_steps_value_of_a_state_it_is_asked_for(gen):
+    """`return_state`: the held experts' part and the router's choice of
+    the last expert layer ride as state pairs whose feed the step does
+    not read; after a call they are what the step that chose the last
+    token computed, and the tokens are those of a call that carries no
+    such pair."""
+    main, startup, logits, pairs, parts = \
+        build_latent_moe_cached_step_program(B, T, V, **SIZES)
+    scope = _start(startup)
+    probes = [("probe.idx", parts["top_idx"][-1].name),
+              ("probe.out", parts["moe_out"][-1].name)]
+    plain = _decoder(main, logits, pairs, scope)
+    probed = _decoder(main, logits, pairs + probes, scope)
+    prompt = np.random.RandomState(2).randint(0, V, (B, 4)).astype("int32")
+    init = dict(_empty())
+    want_toks, want_len = plain.greedy(bos=0, eos=V, max_len=gen,
+                                       init_state=init, prompt=prompt)
+    init.update({"probe.idx": np.zeros((B, K), np.int32),
+                 "probe.out": np.zeros((B, 1, D), np.float32)})
+    toks, lengths, last = probed.greedy(
+        bos=0, eos=V, max_len=gen, init_state=init, prompt=prompt,
+        return_state=["probe.idx", "probe.out"])
+    np.testing.assert_array_equal(toks, want_toks)
+    np.testing.assert_array_equal(lengths, want_len)
+    assert sorted(last) == ["probe.idx", "probe.out"]
+    # the step that chose the last token read the sequence before it
+    fed = np.concatenate([prompt, toks[:, :-1]], axis=1)
+    step = probed._step_fn(probed._params)
+    state = {k: jnp.asarray(v) for k, v in init.items()}
+    for t in range(fed.shape[1]):
+        _, state = step(state, jnp.asarray(fed[:, t]))
+    np.testing.assert_array_equal(last["probe.idx"],
+                                  np.asarray(state["probe.idx"]))
+    np.testing.assert_allclose(last["probe.out"],
+                               np.asarray(state["probe.out"]), rtol=1e-4,
+                               atol=1e-5)
+    assert np.abs(last["probe.out"]).max() > 0
+    # unasked, a call gives two things as before
+    assert len(probed.greedy(bos=0, eos=V, max_len=gen, init_state=init,
+                             prompt=prompt)) == 2
+
+
+def test_greedy_without_a_prompt_returns_state_too():
+    main, startup, logits, pairs, parts = \
+        build_latent_moe_cached_step_program(B, T, V, **SIZES)
+    decoder = _decoder(main, logits, pairs, _start(startup))
+    toks, _, last = decoder.greedy(bos=1, eos=V, max_len=3,
+                                   init_state=_empty(),
+                                   return_state=["pos"])
+    assert toks.shape == (B, 3) and last["pos"].tolist() == [3] * B
+
+
+# -- the Program -------------------------------------------------------------------
+
+def test_parameter_names_are_the_references_tree(built):
+    block = built["main"].global_block()
+    made = {p.name: tuple(p.shape) for p in block.all_parameters()}
+    assert set(made) == set(jax.tree_util.tree_leaves(NAMES))
+    assert "ffn_in" in NAMES["blocks"][0] and "router" in NAMES["blocks"][1]
+    first, count = HELD
+    b1 = NAMES["blocks"][1]
+    assert made[b1["router"]] == (D, E)
+    assert made[b1["w_gate"]] == (count, D, FE)
+    assert made[b1["w_down"]] == (count, FE, D)
+    assert made[b1["w_uk"]] == (KVR, H * NOPE)
+    assert made[NAMES["blocks"][0]["ffn_in"]] == (D, 2 * FF)
+    ops = [od.type for od in block.desc.ops]
+    assert ops.count("mla_cached_attention") == L
+    assert ops.count("moe_experts") == L - DENSE
+    assert "cached_attention" not in ops and "flash_attention" not in ops
+    # four norms a layer, two inside the attention, one at the end
+    assert ops.count("rms_norm") == 6 * L + 1
+
+
+def test_the_layer_function_names_its_parameters():
+    with fluid.program_guard(fluid.Program(), fluid.Program()):
+        def data(name, shape, dtype="float32"):
+            return fluid.layers.data(name=name, shape=shape, dtype=dtype,
+                                     append_batch_size=False)
+
+        out, kept = fluid.layers.mla_cached_attention(
+            data("qn", [B, 1, H * NOPE]), data("qr", [B, 1, H * ROPE]),
+            data("c", [B, 1, KVR]), data("r", [B, 1, ROPE]),
+            data("cache", [B, T, KVR + ROPE]), data("pos", [B], "int64"),
+            H, DV, uk_attr=ParamAttr(name="uk"),
+            uv_attr=ParamAttr(name="uv"))
+        assert tuple(out.shape) == (B, 1, H * DV)
+        assert tuple(kept.shape) == (B, T, KVR + ROPE)
