@@ -45,6 +45,15 @@ class ProgramDecoder:
     (`state_pairs` lists (feed_name, fetch_var_name) in order).
     Parameters and other persistables come from `scope` (default: the
     global scope the program was trained in).
+
+    A step that can take a block of positions says so in its Program:
+    its token feed is declared [batch, -1], it advances its state by
+    the T >= 1 consecutive tokens of every row it is fed, and its
+    logits are those of the last (`models/transformer_program.py
+    build_transformer_cached_step_program`).  The decoder then feeds
+    [rows, 1] at every decode step and beam row, and prefills a prompt
+    `models.decode.PREFILL_BLOCK` positions an application instead of
+    one.  Nothing but the declaration decides it.
     """
 
     def __init__(self, program, token_name, logits_name, state_pairs=(),
@@ -59,6 +68,10 @@ class ProgramDecoder:
         feed_names = [token_name] + [f for f, _ in self.state_pairs]
         fetch_names = [logits_name] + [o for _, o in self.state_pairs]
         self._fp = FunctionalProgram(program, feed_names, fetch_names)
+        # the Program's own declaration of its token feed: [batch], or
+        # [batch, -1] for a step that takes a block of positions
+        self._takes_block = len(
+            program.global_block().var(token_name).shape) == 2
         # the scope's device arrays as they are: a round trip through
         # the host would hold every weight twice on the device until
         # the scope lets go of its own
@@ -79,9 +92,13 @@ class ProgramDecoder:
         fp = self._fp
         token = self.token_name
         pairs = self.state_pairs
+        takes_block = self._takes_block
 
         def step(state, tok):
-            feeds = {token: tok}
+            # the decoders choose one token a row; a block-taking step
+            # reads it as a block of one
+            feeds = {token: tok[:, None] if takes_block and tok.ndim == 1
+                     else tok}
             feeds.update({f: state[f] for f, _ in pairs})
             (logits, *new_states), _ = fp(params, feeds)
             return logits, {f: ns for (f, _), ns in zip(pairs,
@@ -146,7 +163,7 @@ class ProgramDecoder:
         tokens (skipped when max_len == 1 — the 'predict one
         continuation token' call).  Returns (tokens, lengths, state)."""
         step = self._step_fn(params)
-        state, first = prefill(step, state, prompt)
+        state, first = prefill(step, state, prompt, self._takes_block)
         if max_len == 1:
             toks = first[:, None]
         else:
@@ -162,9 +179,10 @@ class ProgramDecoder:
         """Returns (tokens [batch, max_len], lengths [batch]).
 
         `prompt` (int [batch, P]) warms the decode state through the
-        step program first (one scan — for a KV-cache step program this
-        is the prefill); the first output token is then the prompt's
-        continuation and `bos` is ignored.
+        step program first (one scan of the prompt's positions, or of
+        blocks of them where the step takes a block — for a KV-cache
+        step program this is the prefill); the first output token is
+        then the prompt's continuation and `bos` is ignored.
 
         `return_state` names state feeds whose values after the last
         step come back as a third result, {feed name: array}: a state

@@ -30,17 +30,21 @@ __all__ = [
     "sequence_reverse", "sequence_unnest", "sequence_renest",
     "flash_attention", "cached_attention", "mla_cached_attention",
     "rms_norm", "rope", "moe",
-    "ssd_scan", "causal_conv1d", "expand",
+    "ssd_scan", "causal_conv1d", "expand", "slice", "cumsum",
 ]
 
 
 def cached_attention(query, key, value, k_cache, v_cache, position,
                      num_heads=1, sm_scale=None, name=None):
-    """One KV-cached decode step (ops/attention.py cached_attention):
-    query/key/value [batch, 1, dim], caches [batch, heads, max_len,
-    head_dim], position int [1].  Returns (out, k_cache_out,
-    v_cache_out) — thread the cache outputs back as decode state
-    (`fluid.ProgramDecoder` state pairs)."""
+    """Attention through a KV cache over a block of T >= 1 consecutive
+    positions of every row (ops/attention.py cached_attention; T = 1 is
+    a decode step): query/key/value [batch, T, dim], caches [batch,
+    heads, max_len, head_dim], position int [1] or [batch], the slot
+    the block's first position writes (query i attends slots 0 ..
+    position + i).  T may be left open (-1) in the Program.  Returns
+    (out [batch, T, dim], k_cache_out, v_cache_out) — thread the cache
+    outputs back as decode state (`fluid.ProgramDecoder` state
+    pairs)."""
     helper = LayerHelper("cached_attention", name=name)
     out = helper.create_tmp_variable(query.dtype)
     kc_out = helper.create_tmp_variable(k_cache.dtype)
@@ -963,6 +967,31 @@ def split(input, num_or_sections, dim=-1, **kwargs):
                      attrs={"axis": dim, "sections": sections, "num":
                             0 if sections else num})
     return outs
+
+
+def slice(input, axes, starts, ends, **kwargs):
+    """`input[starts[i]:ends[i]]` along each of `axes` (reference:
+    slice_op.cc): a negative index counts from the end, so `starts=[-1],
+    ends=[2 ** 31 - 1]` is the last element of an axis whose extent the
+    Program leaves open."""
+    helper = LayerHelper("slice", **kwargs)
+    out = helper.create_tmp_variable(input.dtype)
+    helper.append_op(type="slice", inputs={"Input": [input]},
+                     outputs={"Out": [out]},
+                     attrs={"axes": list(axes), "starts": list(starts),
+                            "ends": list(ends)})
+    return out
+
+
+def cumsum(x, axis=-1, exclusive=False, **kwargs):
+    """Running sum along `axis` (reference: cum_op.cc); `exclusive`
+    leaves each element out of its own sum."""
+    helper = LayerHelper("cumsum", **kwargs)
+    out = helper.create_tmp_variable(x.dtype)
+    helper.append_op(type="cumsum", inputs={"X": [x]},
+                     outputs={"Out": [out]},
+                     attrs={"axis": axis, "exclusive": exclusive})
+    return out
 
 
 def multiplex(inputs, index, **kwargs):

@@ -38,9 +38,16 @@ def create_global_var(shape, value, dtype, persistable=False, name=None,
 
 
 def cast(x, dtype, **kwargs):
+    """`x` in `dtype`: a type's name, or a Variable, for the type that
+    variable has as the Program runs (a Program states float32 for a
+    parameter, and for all that is computed from it, where a scope may
+    hold the parameter in bfloat16)."""
     helper = LayerHelper("cast", **kwargs)
+    inputs = {"X": [x]}
+    if isinstance(dtype, Variable):
+        inputs["Like"], dtype = [dtype], dtype.dtype
     out = helper.create_tmp_variable(dtype, lod_level=x.lod_level)
-    helper.append_op(type="cast", inputs={"X": [x]}, outputs={"Out": [out]},
+    helper.append_op(type="cast", inputs=inputs, outputs={"Out": [out]},
                      attrs={"in_dtype": x.dtype, "out_dtype": dtype})
     return out
 

@@ -14,30 +14,73 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ..obs import telemetry
+
 __all__ = ["greedy_decode", "beam_search_decode_dense", "prefill",
            "sample_decode"]
 
 NEG_INF = -1e30
 
 
-def prefill(step_fn, init_state, prompt):
-    """Feed a prompt through the step function (one scan), returning
+# The positions of a row that one application of a block-taking step
+# prefills.  Swept on the chip (PERF.md section 5, gpt2m-decode): the
+# float32 scores of a block, [rows, heads, block, extent], size it, not
+# the FLOPs.
+PREFILL_BLOCK = 128
+
+
+def prefill(step_fn, init_state, prompt, takes_block=False):
+    """Feed a prompt through the step function, returning
     (state, first_token) where first_token [B] is the argmax of the
     last prompt position's logits — the natural continuation to seed
     the decode with.  prompt: int [B, P].
 
-    Only the LAST logits ride the scan carry (the first step runs
-    outside to shape the carry leaf), so prefill memory is O(B*V)
-    regardless of prompt length."""
-    toks = jnp.moveaxis(jnp.asarray(prompt, jnp.int32), 0, 1)  # [P, B]
-    logits, state = step_fn(init_state, toks[0])
+    By default step_fn(state, tokens[B]) takes one position, and the
+    prompt is one scan of it.  Only the LAST logits ride the scan carry
+    (the first step runs outside to shape the carry leaf), so prefill
+    memory is O(B*V) regardless of prompt length.
 
-    def body(carry, tok):
-        state, _ = carry
-        logits, state = step_fn(state, tok)
-        return (state, logits), None
+    `takes_block`: step_fn(state, tokens[B, T]) takes T >= 1
+    consecutive positions of every row and gives the logits of the
+    last.  The prompt goes through in blocks of PREFILL_BLOCK, the
+    equal blocks inside one scan, a shorter block first for the
+    remainder: every position is processed, in P / PREFILL_BLOCK
+    applications instead of P."""
+    prompt = jnp.asarray(prompt, jnp.int32)
+    if not takes_block:
+        telemetry.on_prefill_lowering("step", 1)
+        toks = jnp.moveaxis(prompt, 0, 1)  # [P, B]
+        logits, state = step_fn(init_state, toks[0])
 
-    (state, logits), _ = jax.lax.scan(body, (state, logits), toks[1:])
+        def body(carry, tok):
+            state, _ = carry
+            logits, state = step_fn(state, tok)
+            return (state, logits), None
+
+        (state, logits), _ = jax.lax.scan(body, (state, logits), toks[1:])
+        return state, jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+    block = PREFILL_BLOCK
+    telemetry.on_prefill_lowering("block", block)
+    rows, length = prompt.shape
+    state, logits = init_state, None
+    if length % block:
+        logits, state = step_fn(state, prompt[:, :length % block])
+    if length >= block:
+        blocks = jnp.moveaxis(
+            prompt[:, length % block:].reshape(rows, -1, block), 1, 0)
+        # traced once: the scan's body and, where no block has given
+        # any yet, the shape of the logits the scan carries
+        step_fn, closed = jax.closure_convert(step_fn, state, blocks[0])
+        if logits is None:
+            like = jax.eval_shape(step_fn, state, blocks[0], *closed)[0]
+            logits = jnp.zeros(like.shape, like.dtype)
+
+        def body(carry, toks):
+            logits, state = step_fn(carry[0], toks, *closed)
+            return (state, logits), None
+
+        (state, logits), _ = jax.lax.scan(body, (state, logits), blocks)
     return state, jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
 

@@ -140,22 +140,31 @@ def build_transformer_step_program(batch, window, vocab_size, n_layer=2,
 def build_transformer_cached_step_program(batch, max_len, vocab_size,
                                           n_layer=2, n_head=4,
                                           d_model=64, d_ff=None):
-    """KV-cached decode step: O(1) attention work per generated token.
+    """KV-cached decode step over a block of T >= 1 consecutive
+    positions of every row: O(1) attention work per generated token
+    (T = 1), and a prompt prefilled many positions at a time.
 
-    Feeds: tok [batch] int32, pos [batch] int64 (the slot being
-    written; per-row so beam expansion can repeat it — rows advance in
-    lockstep), per-layer caches k_cache_i/v_cache_i [batch, n_head,
-    max_len, d_head].  Fetches: logits [batch, vocab], pos+1, and the
-    updated caches.  Returns (main, startup, logits, state_pairs)
-    where state_pairs wires straight into `fluid.ProgramDecoder`
-    (greedy and beam; pass max_positions=max_len so decoding past the
-    cache extent errors instead of clamping).
+    Feeds: tok [batch, T] int32 (declared [batch, -1]: the open second
+    axis is how `fluid.ProgramDecoder` knows the step takes a block),
+    pos [batch] int64 (the slot the block's first token writes; per-row
+    so beam expansion can repeat it — rows advance in lockstep),
+    per-layer caches k_cache_i/v_cache_i [batch, n_head, max_len,
+    d_head].  Fetches: logits [batch, vocab] of the block's LAST
+    position alone (the only one whose continuation a caller chooses),
+    pos + T, and the updated caches.  Returns (main, startup, logits,
+    state_pairs) where state_pairs wires straight into
+    `fluid.ProgramDecoder` (greedy, sampling and beam; pass
+    max_positions=max_len so decoding past the cache extent errors
+    instead of clamping).
 
     Parameter names match `build_transformer_program` of the same
     architecture (per-program name scopes; cache feeds and the
     cast/reshape glue create no parameters), so the trained scope
     drives this program directly — max_len must not exceed the trained
-    sequence length (the position embedding's extent).
+    sequence length (the position embedding's extent).  Types follow
+    the scope's (bfloat16 weights give bfloat16 activations, logits and
+    caches), except that the two embeddings are summed in float32 and
+    stay so through the first block's LayerNorm and residual add.
     """
     if d_ff is None:
         d_ff = 4 * d_model
@@ -163,8 +172,8 @@ def build_transformer_cached_step_program(batch, max_len, vocab_size,
     main = fluid.Program()
     startup = fluid.Program()
     with fluid.program_guard(main, startup):
-        tok = fluid.layers.data(name="tok", shape=[batch], dtype="int32",
-                                append_batch_size=False)
+        tok = fluid.layers.data(name="tok", shape=[batch, -1],
+                                dtype="int32", append_batch_size=False)
         pos = fluid.layers.data(name="pos", shape=[-1], dtype="int64",
                                 append_batch_size=False)
         caches = []
@@ -180,20 +189,36 @@ def build_transformer_cached_step_program(batch, max_len, vocab_size,
                     dtype="float32", append_batch_size=False)))
 
         # lookup_table squeezes a trailing size-1 ids dim (reference
-        # convention), so [batch, 1, 1] ids yield [batch, 1, d]
+        # convention), so [rows, T, 1] ids yield [rows, T, d]; 0 keeps
+        # an axis as it comes (beam search feeds batch * beam rows)
         tok64 = fluid.layers.reshape(
-            x=fluid.layers.cast(tok, "int64"), shape=[batch, 1, 1])
-        # rows move in lockstep: one wpe row serves the whole batch
-        pos_scalar = fluid.layers.reduce_max(pos)
-        pos_ids = fluid.layers.reshape(x=pos_scalar, shape=[1, 1, 1])
-        # wpe lookup is [1, 1, d]; the residual add broadcasts it over
-        # the batch
-        x = fluid.layers.embedding(tok64, size=[vocab_size, d_model]) \
-            + fluid.layers.embedding(pos_ids, size=[max_len, d_model])
+            x=fluid.layers.cast(tok, "int64"), shape=[0, 0, 1])
+        # rows move in lockstep: one run of wpe rows, pos .. pos + T - 1,
+        # serves the whole batch.  T is read off the token feed: a one
+        # a position of a row, counted before each for its offset and
+        # all together for the advance
+        ones = fluid.layers.fill_constant_batch_size_like(
+            tok, shape=[1, 1], dtype="int64", value=1, input_dim_idx=1,
+            output_dim_idx=1)
+        pos_ids = fluid.layers.reshape(
+            x=fluid.layers.cumsum(ones, axis=1, exclusive=True)
+            + fluid.layers.reduce_max(pos), shape=[1, -1, 1])
+        # wpe lookup is [1, T, d]; the residual add broadcasts it over
+        # the batch.  The embeddings' sum is what every layer's input
+        # is built on, so it stays float32 until the first block has
+        # added to it: rounded to a scope's bfloat16 it carries that
+        # rounding into all the layers (a one-token step never rounded
+        # it: XLA fuses the sum into its readers there)
+        wte = fluid.layers.embedding(tok64, size=[vocab_size, d_model])
+        x = fluid.layers.cast(wte, "float32") + fluid.layers.cast(
+            fluid.layers.embedding(pos_ids, size=[max_len, d_model]),
+            "float32")
 
         state_pairs = []
         for i in range(n_layer):
             h = fluid.layers.layer_norm(x, begin_norm_axis=2)
+            if i == 0:
+                h = fluid.layers.cast(h, wte)   # the weights' type
             qkv = fluid.layers.fc(input=h, size=3 * d_model,
                                   num_flatten_dims=2)
             q, k, v = fluid.layers.split(qkv, num_or_sections=3, dim=-1)
@@ -202,20 +227,31 @@ def build_transformer_cached_step_program(batch, max_len, vocab_size,
                 num_heads=n_head)
             state_pairs.append(("k_cache_%d" % i, kc_out.name))
             state_pairs.append(("v_cache_%d" % i, vc_out.name))
-            x = x + fluid.layers.fc(input=o, size=d_model,
-                                    num_flatten_dims=2)
+            o = fluid.layers.fc(input=o, size=d_model, num_flatten_dims=2)
+            if i == 0:
+                # the float32 sum ends here, in the weights' type (the
+                # cast up is said, not left to promotion: a float8
+                # scope's values promote to nothing)
+                x = fluid.layers.cast(
+                    x + fluid.layers.cast(o, "float32"), wte)
+            else:
+                x = x + o
             h = fluid.layers.layer_norm(x, begin_norm_axis=2)
             h = fluid.layers.fc(input=h, size=d_ff, num_flatten_dims=2,
                                 act="relu")
             x = x + fluid.layers.fc(input=h, size=d_model,
                                     num_flatten_dims=2)
 
+        # the head reads the block's last position alone: [batch, T,
+        # vocab] logits would be most of a block's memory for T - 1
+        # rows nobody reads
+        x = fluid.layers.slice(x, axes=[1], starts=[-1],
+                               ends=[2 ** 31 - 1])
         x = fluid.layers.layer_norm(x, begin_norm_axis=2)
         logits3 = fluid.layers.fc(input=x, size=vocab_size,
                                   num_flatten_dims=2)
-        logits = fluid.layers.reshape(x=logits3,
-                                      shape=[batch, vocab_size])
-        pos_out = fluid.layers.increment(pos, value=1, in_place=False)
+        logits = fluid.layers.reshape(x=logits3, shape=[0, vocab_size])
+        pos_out = pos + fluid.layers.reduce_sum(ones)
         state_pairs.append(("pos", pos_out.name))
     return main, startup, logits, state_pairs
 

@@ -37,7 +37,8 @@ __all__ = ["on_executor_run", "on_jit_trace",
            "on_flash_attention_bwd_lowering",
            "on_flash_attention_pairs",
            "on_flash_attention_grad_lowering", "on_moe_lowering",
-           "on_moe_gmm_lowering", "on_ssd_lowering",
+           "on_moe_gmm_lowering", "on_cached_attention_lowering",
+           "on_prefill_lowering", "on_ssd_lowering",
            "on_causal_conv1d_lowering", "on_shared_parameter_uses",
            "on_transfer",
            "on_feed_seconds", "on_program_cache_evict",
@@ -202,6 +203,29 @@ def on_mla_cached_attention_lowering(heads, latent, rope, cache_dtype):
                    labelnames=("heads", "latent", "rope", "cache_dtype")) \
           .labels(heads=heads, latent=latent, rope=rope,
                   cache_dtype=str(cache_dtype)).inc()
+
+
+def on_cached_attention_lowering(block):
+    """Attention through a key/value cache (`cached_attention`,
+    ops/attention.py) was traced into a program, over `block` positions
+    of every row (1: a decode step): one count per op instance a lowered
+    program holds."""
+    _reg().counter("cached_attention_lowerings_total",
+                   "key/value-cached attention ops lowered, by the "
+                   "positions of a row one application takes",
+                   labelnames=("block",)).labels(block=block).inc()
+
+
+def on_prefill_lowering(form, block):
+    """A prompt's prefill (models/decode.py `prefill`) was traced into a
+    program: as a scan of the one-token step ("step", `block` 1), or in
+    blocks of at most `block` positions through a step that takes a
+    block ("block").  One count per prefill a lowered program holds."""
+    _reg().counter("prefill_lowerings_total",
+                   "prompt prefills lowered, by form (a scan of one-token "
+                   "steps, or of blocks of positions) and block length",
+                   labelnames=("form", "block")) \
+          .labels(form=form, block=block).inc()
 
 
 def on_ssd_lowering(kernel, chunk, heads_per_step):

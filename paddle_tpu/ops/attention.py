@@ -164,22 +164,39 @@ def rope(ctx, ins, attrs):
     return {"Out": [out.reshape(b, t, d).astype(x.dtype)]}
 
 
-@register_op("cached_attention", stop_gradient_op=True)
-def cached_attention_op(ctx, ins, attrs):
-    """One autoregressive decode step with a KV cache: O(1) work per
-    token instead of re-attending the whole window.
+def _cached_attention_infer_shape(block, op_desc):
+    """`Out` is `Q`'s, each cache comes out as it went in: stated, not
+    traced, so that a block axis the Program leaves open (-1) stays
+    open and is never written into the cache's extent."""
+    for src, dst in (("Q", "Out"), ("KCache", "KCacheOut"),
+                     ("VCache", "VCacheOut")):
+        same_meta_infer_shape(src, dst)(block, op_desc)
 
-    Q/KNew/VNew: [batch, 1, dim] (this token's projections);
+
+@register_op("cached_attention", stop_gradient_op=True,
+             infer_shape=_cached_attention_infer_shape)
+def cached_attention_op(ctx, ins, attrs):
+    """Autoregressive attention through a KV cache, over a block of
+    T >= 1 consecutive positions of every row: a decode step is T = 1
+    (O(1) work per token instead of re-attending the whole window), a
+    prompt's prefill takes many positions at once.
+
+    Q/KNew/VNew: [batch, T, dim] (the block's projections);
     KCache/VCache: [batch, heads, max_len, head_dim]; Position: int
-    [1] or [batch] (lockstep rows), the slot this step writes (tokens
-    0..Position attend).
-    Outputs the attended context [batch, 1, dim] and the updated
+    [1] or [batch] (lockstep rows), the slot the block's first position
+    writes: slots Position .. Position + T - 1 are written, and query i
+    of the block attends slots 0 .. Position + i.
+    Outputs the attended context [batch, T, dim] and the updated
     caches — wire them as ProgramDecoder state pairs.  Generation
     never needs gradients (matching the reference's host-side
     generation loop), so the op stops them.
-    """
-    from ..kernels.flash_attention import merge_heads, split_heads
 
+    No operand is narrower at T > 1 than at T = 1: both products read
+    their operands as float32 at the highest precision (on the TPU the
+    default rounds a float32 operand to bfloat16, the probabilities
+    among them; a one-row product never reaches the MXU and is exact
+    anyway), the mask and the softmax are float32.
+    """
     q, k_new, v_new = ins["Q"][0], ins["KNew"][0], ins["VNew"][0]
     k_cache, v_cache = ins["KCache"][0], ins["VCache"][0]
     # Position may be [1] or per-row [batch] (rows advance in lockstep;
@@ -187,10 +204,16 @@ def cached_attention_op(ctx, ins, attrs):
     pos = jnp.reshape(ins["Position"][0], (-1,))[0].astype(jnp.int32)
     num_heads = int(attrs.get("num_heads", 1))
     sm_scale = float(attrs.get("sm_scale", 0.0)) or None
+    rows, block, width = q.shape
+    extent = k_cache.shape[2]
+    telemetry.on_cached_attention_lowering(block)
 
-    qh = split_heads(q, num_heads)             # [B, H, 1, Dh]
-    kh = split_heads(k_new, num_heads)
-    vh = split_heads(v_new, num_heads)
+    # [B, T, H * Dh] -> [B, H, T, Dh]: kernels/flash_attention.py has
+    # the same two lines behind an import of Pallas, which a decoder
+    # would pay at its first trace for a reshape
+    qh, kh, vh = (
+        x.reshape(rows, block, num_heads, -1).transpose(0, 2, 1, 3)
+        for x in (q, k_new, v_new))
     if sm_scale is None:
         sm_scale = qh.shape[-1] ** -0.5
 
@@ -199,15 +222,17 @@ def cached_attention_op(ctx, ins, attrs):
     v_cache = jax.lax.dynamic_update_slice_in_dim(
         v_cache, vh.astype(v_cache.dtype), pos, axis=2)
 
+    highest = jax.lax.Precision.HIGHEST
     s = jnp.einsum("bhqd,bhkd->bhqk", qh.astype(jnp.float32),
-                   k_cache.astype(jnp.float32)) * sm_scale
-    T = k_cache.shape[2]
-    valid = jnp.arange(T) <= pos
-    s = jnp.where(valid[None, None, None, :], s, -1e30)
+                   k_cache.astype(jnp.float32),
+                   precision=highest) * sm_scale
+    valid = jnp.arange(extent)[None, :] <= pos + jnp.arange(block)[:, None]
+    s = jnp.where(valid[None, None], s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhqk,bhkd->bhqd", p,
-                     v_cache.astype(jnp.float32))
-    return {"Out": [merge_heads(out).astype(q.dtype)],
+    out = jnp.einsum("bhqk,bhkd->bhqd", p, v_cache.astype(jnp.float32),
+                     precision=highest)
+    out = out.transpose(0, 2, 1, 3).reshape(rows, block, width)
+    return {"Out": [out.astype(q.dtype)],
             "KCacheOut": [k_cache], "VCacheOut": [v_cache]}
 
 

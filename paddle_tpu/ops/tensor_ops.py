@@ -75,11 +75,16 @@ def fill(ctx, ins, attrs):
     return {"Out": [jnp.asarray(values)]}
 
 
-@register_op("cast")
+@register_op("cast", nondiff_inputs=("Like",))
 def cast(ctx, ins, attrs):
     x = _x(ins)
-    dtype = np_dtype(attrs["out_dtype"] if "out_dtype" in attrs
-                     else attrs["dtype"])
+    if "Like" in ins:
+        # the type another variable has as it runs: a Program states
+        # float32 for a parameter that a scope may hold in bfloat16
+        dtype = _vals(ins["Like"][0]).dtype
+    else:
+        dtype = np_dtype(attrs["out_dtype"] if "out_dtype" in attrs
+                         else attrs["dtype"])
     if isinstance(x, RaggedTensor):
         return {"Out": [x.with_values(x.values.astype(dtype))]}
     return {"Out": [x.astype(dtype)]}
@@ -252,6 +257,36 @@ def crop(ctx, ins, attrs):
     slices = tuple(slice(int(o), int(o) + int(s))
                    for o, s in zip(offsets, shape))
     return {"Out": [x[slices]]}
+
+
+@register_op("slice")
+def slice_op(ctx, ins, attrs):
+    """reference: slice_op.cc — Input[starts[i]:ends[i]] along axes[i];
+    a negative index counts from the end, an end past the extent is the
+    extent."""
+    x = _x(ins, "Input")
+    index = [slice(None)] * x.ndim
+    for axis, start, end in zip(attrs["axes"], attrs["starts"],
+                                attrs["ends"]):
+        index[int(axis)] = slice(int(start), int(end))
+    return {"Out": [x[tuple(index)]]}
+
+
+@register_op("cumsum")
+def cumsum(ctx, ins, attrs):
+    """reference: cum_op.cc — running sum along `axis`; `exclusive`
+    leaves an element out of its own sum."""
+    x = _x(ins)
+    axis = int(attrs.get("axis", -1))
+    out = jnp.cumsum(x, axis=axis)
+    if attrs.get("exclusive"):
+        # the sums before each element: shifted by one, not `out - x`,
+        # which would leave rounding where the sum is empty
+        out = jnp.concatenate(
+            [jnp.zeros_like(jax.lax.slice_in_dim(x, 0, 1, axis=axis)),
+             jax.lax.slice_in_dim(out, 0, x.shape[axis] - 1, axis=axis)],
+            axis=axis)
+    return {"Out": [out]}
 
 
 @register_op("multiplex", nondiff_inputs=("Ids",))

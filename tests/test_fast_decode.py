@@ -135,3 +135,32 @@ def test_program_decoder_beam_orders_scores():
     assert seqs.shape == (4, 3, 8)
     # best-first ordering per source
     assert np.all(np.diff(scores, axis=1) <= 1e-6)
+
+
+def test_a_recurrent_state_step_prefills_a_position_at_a_time():
+    """A `[batch]` token feed: the decoder scans the prompt through the
+    step one position at a time (the counter says so), and the state it
+    reaches is the state of feeding the prompt by hand."""
+    from paddle_tpu.obs import telemetry
+
+    main, startup, tok, h_in, h_out, logits = _build_step_program()
+    exe = _train(main, startup, logits.name)
+    infer = main.clone(for_test=True)
+    dec = fluid.ProgramDecoder(infer, token_name="tok",
+                               logits_name=logits.name,
+                               state_pairs=[("h_in", h_out.name)])
+    assert not dec._takes_block
+    prompt = np.array([[3, 5, 7], [2, 4, 6]], np.int64)
+    before = telemetry.snapshot()
+    toks, _ = dec.greedy(bos=BOS, eos=EOS, max_len=6,
+                         init_state={"h_in": np.zeros((2, H), np.float32)},
+                         prompt=prompt)
+    counted = {k: v for k, v in telemetry.snapshot_delta(before).items()
+               if k.startswith("prefill_lowerings_total")}
+    assert counted == {"prefill_lowerings_total{block=1,form=step}": 1}
+
+    h = np.zeros((2, H), np.float32)
+    for t in range(prompt.shape[1]):
+        lg, h = exe.run(infer, feed={"tok": prompt[:, t], "h_in": h},
+                        fetch_list=[logits, h_out])
+    np.testing.assert_array_equal(toks[:, 0], np.argmax(lg, axis=-1))

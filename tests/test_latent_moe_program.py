@@ -147,6 +147,18 @@ def test_greedy_through_the_decoder_is_the_references_greedy(built):
     assert gap.max() <= 1e-4
 
 
+def test_the_shares_one_token_step_is_prefilled_a_position_at_a_time(built):
+    """The share's step declares `tok` [batch]: its prefill stays the
+    scan of single positions."""
+    assert not built["decoder"]._takes_block
+    before = telemetry.snapshot()
+    built["decoder"].greedy(bos=0, eos=V, max_len=2, init_state=_empty(),
+                            prompt=built["tokens"][:, :3])
+    counted = {k: v for k, v in telemetry.snapshot_delta(before).items()
+               if k.startswith("prefill_lowerings_total")}
+    assert counted == {"prefill_lowerings_total{block=1,form=step}": 1}
+
+
 def test_a_position_past_the_cache_is_refused(built):
     with pytest.raises(ValueError, match="extent"):
         built["decoder"].greedy(bos=0, eos=V, max_len=T, init_state=_empty(),

@@ -6,6 +6,7 @@ test_fill_*.py, test_assign_*.py, test_one_hot, test_lookup_table_op.py,
 test_shape_op, test_im2sequence, test_bilinear_tensor_product_op.py)."""
 
 import numpy as np
+import pytest
 
 from op_test import OpTest
 
@@ -108,6 +109,61 @@ class TestCrop(OpTest):
         self.inputs = {"X": x}
         self.attrs = {"offsets": [1, 2], "shape": [2, 3]}
         self.outputs = {"Out": x[1:3, 2:5]}
+        self.check_output()
+        self.check_grad(["X"], "Out")
+
+
+def test_cast_to_the_type_another_variable_runs_in():
+    """`cast(x, <Variable>)`: the type that variable holds as the
+    Program runs, which a scope decides (bfloat16 weights under a
+    Program that states float32), not the one the Program states."""
+    import jax.numpy as jnp
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.ops.registry import get_op_info
+
+    out = get_op_info("cast").kernel(
+        None, {"X": [jnp.ones((2, 3), jnp.float32)],
+               "Like": [jnp.zeros((1,), jnp.bfloat16)]},
+        {"out_dtype": "float32"})["Out"][0]
+    assert out.dtype == jnp.bfloat16 and out.shape == (2, 3)
+
+    main = fluid.Program()
+    with fluid.program_guard(main, fluid.Program()):
+        a = fluid.layers.data(name="a", shape=[3], dtype="float32")
+        b = fluid.layers.data(name="b", shape=[1], dtype="float32")
+        c = fluid.layers.cast(a, b)
+    op = main.global_block().ops[-1]
+    assert op.type == "cast" and op.input("Like") == [b.name]
+    assert c.dtype == b.dtype
+
+
+class TestSlice(OpTest):
+    op_type = "slice"
+
+    @pytest.mark.parametrize("axes,starts,ends,want", [
+        ([1], [-1], [2 ** 31 - 1], (slice(None), slice(-1, None))),
+        ([0, 1], [1, -4], [3, -1], (slice(1, 3), slice(-4, -1))),
+    ])
+    def test(self, axes, starts, ends, want):
+        x = RS.rand(4, 5).astype("float32")
+        self.inputs = {"Input": x}
+        self.attrs = {"axes": axes, "starts": starts, "ends": ends}
+        self.outputs = {"Out": x[want]}
+        self.check_output()
+        self.check_grad(["Input"], "Out")
+
+
+class TestCumsum(OpTest):
+    op_type = "cumsum"
+
+    @pytest.mark.parametrize("exclusive", [False, True])
+    @pytest.mark.parametrize("axis", [0, 1])
+    def test(self, axis, exclusive):
+        x = RS.rand(3, 5).astype("float32")
+        self.inputs = {"X": x}
+        self.attrs = {"axis": axis, "exclusive": exclusive}
+        self.outputs = {"Out": np.cumsum(x, axis=axis)
+                        - (x if exclusive else 0)}
         self.check_output()
         self.check_grad(["X"], "Out")
 
