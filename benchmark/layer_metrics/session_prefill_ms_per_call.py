@@ -1,0 +1,17 @@
+"""Milliseconds a generation call of the session cell spends before its
+first token: a call with `max_len=1`, which puts the session's caches on
+the device, prefills the question through the step's scan and returns
+the first continuations, timed on the host after the windows (its second
+call: the first loads its program).  As `share_prefill_ms_per_call` is
+for the share cell, whose calls start from empty caches."""
+
+LAYER = "decoding"
+MOVES = "decode_tok_per_s"
+UNIT = "ms"
+SOURCE = "host_clock"
+
+
+def read(run):
+    if run.peaks is None:
+        return None
+    return run.facts.get("session_prefill_ms")

@@ -46,6 +46,14 @@ class ProgramDecoder:
     Parameters and other persistables come from `scope` (default: the
     global scope the program was trained in).
 
+    A call need not start at position 0: `init_state` may hand in
+    caches that already hold a session and the position it ends at (a
+    decode-pool chip is handed its caches by a prefill pool), and the
+    prompt and the generated tokens continue from there.  `max_positions`
+    is checked against the prompt and `max_len` alone (`_check_extent`):
+    the caller of such a call answers for pos + prompt + max_len - 1 <=
+    max_positions.
+
     A step that can take a block of positions says so in its Program:
     its token feed is declared [batch, -1], it advances its state by
     the T >= 1 consecutive tokens of every row it is fed, and its
@@ -132,6 +140,12 @@ class ProgramDecoder:
         return self._compiled[key]
 
     def _check_extent(self, max_len, prompt_len=0):
+        """The positions a call writes, counted from slot 0: the prompt
+        and `max_len`.  A call that starts past position 0 (a session
+        handed in: caches already filled and a `pos` inside `init_state`
+        that says how far) is not seen here, where `init_state` is
+        opaque: its caller answers for pos + prompt + max_len - 1 <=
+        max_positions."""
         if self.max_positions is None:
             return
         need = prompt_len + max_len - 1 if prompt_len else max_len
@@ -139,8 +153,12 @@ class ProgramDecoder:
             raise ValueError(
                 "decoding %d positions (prompt %d + %d generated) "
                 "exceeds the step program's extent %d — the compiled "
-                "scatter would clamp and corrupt the cache"
-                % (need, prompt_len, max_len, self.max_positions))
+                "scatter would clamp and corrupt the cache.  (Counted "
+                "from slot 0: a position the call starts from inside "
+                "init_state, such as a session's pos, is not seen here; "
+                "its caller answers for pos + prompt + max_len - 1 <= %d)"
+                % (need, prompt_len, max_len, self.max_positions,
+                   self.max_positions))
 
     def _norm_prompt(self, prompt, max_len):
         """Validate and convert the optional prompt once; returns a

@@ -29,7 +29,7 @@ __all__ = [
     "sequence_concat", "beam_search", "beam_search_decode",
     "sequence_reverse", "sequence_unnest", "sequence_renest",
     "flash_attention", "cached_attention", "mla_cached_attention",
-    "rms_norm", "rope", "moe",
+    "mla_index_select", "rms_norm", "rope", "moe",
     "ssd_scan", "causal_conv1d", "expand", "slice", "cumsum",
 ]
 
@@ -63,7 +63,8 @@ def cached_attention(query, key, value, k_cache, v_cache, position,
 
 def mla_cached_attention(q_nope, q_rope, c_new, r_new, cache, position,
                          num_heads, v_head_dim, uk_attr=None, uv_attr=None,
-                         name=None):
+                         name=None, selected=None, live=None,
+                         sm_scale=None):
     """One decode step of latent attention over a cache of latents
     (ops/attention.py mla_cached_attention): `q_nope` [batch, 1,
     num_heads * nope] and `q_rope` [batch, 1, num_heads * rope] (rotated)
@@ -72,9 +73,16 @@ def mla_cached_attention(q_nope, q_rope, c_new, r_new, cache, position,
     latent + rope], `position` int [1] or [batch].  Creates the keys' and
     values' up-projections [latent, num_heads * nope] and [latent,
     num_heads * v_head_dim], which the op absorbs; the scores' scale is
-    the op's own, (nope + rope) ** -0.5.  Returns (out [batch,
+    the op's own, (nope + rope) ** -0.5, unless `sm_scale` gives one.
+    With `selected` int32 [batch, top_k] and `live` int32 [batch]
+    (`mla_index_select`'s two) the step attends the slots `selected`
+    names, the first `live` of each row, and not every slot up to
+    `position`.  Returns (out [batch,
     1, num_heads * v_head_dim], cache_out): thread `cache_out` back as
     decode state (`fluid.ProgramDecoder` state pairs)."""
+    if (selected is None) != (live is None):
+        raise ValueError("mla_cached_attention: `selected` and `live` "
+                         "come together")
     helper = LayerHelper("mla_cached_attention", name=name)
     latent = int(c_new.shape[-1])
     nope = int(q_nope.shape[-1]) // int(num_heads)
@@ -86,14 +94,47 @@ def mla_cached_attention(q_nope, q_rope, c_new, r_new, cache, position,
         dtype=q_nope.dtype, default_initializer=Xavier())
     out = helper.create_tmp_variable(q_nope.dtype)
     cache_out = helper.create_tmp_variable(cache.dtype)
+    inputs = {"QNope": [q_nope], "QRope": [q_rope], "CNew": [c_new],
+              "RNew": [r_new], "Cache": [cache], "WUk": [w_uk],
+              "WUv": [w_uv], "Position": [position]}
+    attrs = {"num_heads": int(num_heads)}
+    # an op carries only what it was given: the Program of a step that
+    # attends every slot at the op's own scale stays what it was
+    if selected is not None:
+        inputs.update(Selected=[selected], Live=[live])
+    if sm_scale:
+        attrs["sm_scale"] = float(sm_scale)
     helper.append_op(
-        type="mla_cached_attention",
-        inputs={"QNope": [q_nope], "QRope": [q_rope], "CNew": [c_new],
-                "RNew": [r_new], "Cache": [cache], "WUk": [w_uk],
-                "WUv": [w_uv], "Position": [position]},
-        outputs={"Out": [out], "CacheOut": [cache_out]},
-        attrs={"num_heads": int(num_heads)})
+        type="mla_cached_attention", inputs=inputs,
+        outputs={"Out": [out], "CacheOut": [cache_out]}, attrs=attrs)
     return out, cache_out
+
+
+def mla_index_select(q, w, k_new, cache, position, num_heads, top_k,
+                     scale=1.0, name=None):
+    """One decode step of a learned chooser of cache slots
+    (ops/attention.py mla_index_select): `q` [batch, 1, num_heads * dim]
+    (rotated) and `w` [batch, 1, num_heads] the token's index queries
+    and their weights, `k_new` [batch, 1, dim] its index key, `cache`
+    [batch, positions, dim] the chooser's own cache, `position` int [1]
+    or [batch].  A slot s <= position scores scale * sum_j w_j relu(q_j
+    . k_s).  Returns (selected int32 [batch, top_k], live int32 [batch],
+    cache_out): hand the first two to `mla_cached_attention`, thread
+    `cache_out` back as decode state."""
+    helper = LayerHelper("mla_index_select", name=name)
+    selected = helper.create_tmp_variable("int32", stop_gradient=True)
+    live = helper.create_tmp_variable("int32", stop_gradient=True)
+    cache_out = helper.create_tmp_variable(cache.dtype)
+    attrs = {"num_heads": int(num_heads), "top_k": int(top_k)}
+    if scale != 1.0:
+        attrs["scale"] = float(scale)
+    helper.append_op(
+        type="mla_index_select",
+        inputs={"Q": [q], "W": [w], "KNew": [k_new], "Cache": [cache],
+                "Position": [position]},
+        outputs={"CacheOut": [cache_out], "Selected": [selected],
+                 "Live": [live]}, attrs=attrs)
+    return selected, live, cache_out
 
 
 def flash_attention(queries, keys, values, num_heads=1, causal=False,
@@ -704,23 +745,31 @@ def rms_norm(input, epsilon=1e-6, param_attr=None, **kwargs):
     return out
 
 
-def rope(input, positions, num_heads, theta=10000.0, **kwargs):
+def rope(input, positions, num_heads, theta=10000.0, inv_freq=None,
+         rotary_dim=None, **kwargs):
     """Rotary position embedding on each head of `input` [batch, seq,
     num_heads * head_dim] at `positions` [batch, seq] (ops/attention.py
-    rope): rotate-half form, base `theta`."""
+    rope): rotate-half form, base `theta`, or the rates `inv_freq` (a
+    list, one a pair: `ops.attention.yarn_inv_freq` makes YaRN's) in
+    place of theta's powers; `rotary_dim` turns the first so many values
+    of every head and hands on the rest."""
     helper = LayerHelper("rope", **kwargs)
     out = helper.create_tmp_variable(input.dtype)
+    attrs = {"num_heads": int(num_heads), "theta": float(theta)}
+    if inv_freq is not None:
+        attrs["inv_freq"] = [float(f) for f in inv_freq]
+    if rotary_dim:
+        attrs["rotary_dim"] = int(rotary_dim)
     helper.append_op(type="rope",
                      inputs={"X": [input], "Positions": [positions]},
-                     outputs={"Out": [out]},
-                     attrs={"num_heads": int(num_heads),
-                            "theta": float(theta)})
+                     outputs={"Out": [out]}, attrs=attrs)
     return out
 
 
 def moe(input, num_experts, expert_size, top_k, router_attr=None,
         gate_attr=None, up_attr=None, down_attr=None, name=None,
-        scoring="softmax", norm_topk=False, scale=1.0, held=None):
+        scoring="softmax", norm_topk=False, scale=1.0, held=None,
+        bias_attr=None, n_group=0, topk_group=0):
     """A routed expert layer over `input` [..., hidden] (ops/moe.py): a
     float32 router sends every token to its `top_k` of `num_experts`
     gated-SiLU experts of width `expert_size`, each computed for it (no
@@ -734,6 +783,12 @@ def moe(input, num_experts, expert_size, top_k, router_attr=None,
     router's Variables "logits" [tokens, num_experts], "top_w" and
     "top_idx" [tokens, top_k] and the experts' "counts" [num_experts],
     the rows each expert was given.
+
+    Under `scoring="sigmoid"`, `bias_attr` creates a selection bias
+    [num_experts] (zeros at the start) that is added to the scores for
+    the choice and not for the weights, and `n_group` > 1 limits the
+    choice to the experts of a token's `topk_group` best groups of
+    consecutive experts (ops/moe.py moe_router).
     """
     helper = LayerHelper("moe", name=name)
     hidden = int(input.shape[-1])
@@ -765,11 +820,17 @@ def moe(input, num_experts, expert_size, top_k, router_attr=None,
         routing = {}
     share = {} if (first, count) == (0, num_experts) else {
         "first_expert": int(first), "scored": int(num_experts)}
+    router_ins = {"X": [input], "W": [w_router]}
+    if bias_attr is not None:
+        router_ins["Bias"] = [helper.create_parameter(
+            bias_attr, shape=[num_experts], dtype="float32", is_bias=True)]
+    if n_group and n_group > 1:
+        routing.update(n_group=int(n_group), topk_group=int(topk_group))
     logits, top_w = tmp("float32"), tmp("float32")
     top_idx = tmp("int32", stop_gradient=True)
     lb_loss, z_loss = tmp("float32"), tmp("float32")
     helper.append_op(
-        type="moe_router", inputs={"X": [input], "W": [w_router]},
+        type="moe_router", inputs=router_ins,
         outputs={"Logits": [logits], "TopW": [top_w], "TopIdx": [top_idx],
                  "LbLoss": [lb_loss], "ZLoss": [z_loss]},
         attrs=dict({"top_k": int(top_k)}, **routing))

@@ -1,7 +1,10 @@
 """One chip's share of a latent-attention mixture-of-experts decoder as a
-cached decode step Program: openPangu-Ultra-MoE-718B
-(huggingface.co/FreedomIntelligence/openPangu-Ultra-MoE-718B), the
-DeepSeek-V3 family's block (arXiv:2412.19437) with sandwich norms.
+cached decode step Program: the DeepSeek-V3 family's block
+(arXiv:2412.19437), as openPangu-Ultra-MoE-718B
+(huggingface.co/FreedomIntelligence/openPangu-Ultra-MoE-718B) has it
+with sandwich norms, and as DeepSeek-V3.2
+(huggingface.co/deepseek-ai/DeepSeek-V3.2) has it pre-norm with a learned
+chooser of the cache slots its attention reads.
 
 One token in, the next token's logits out, one cache of latents a layer
 through the `mla_cached_attention` op (ops/attention.py): `c` and the
@@ -12,30 +15,59 @@ and no head's key or value.  The feed-forward is dense in the first
 scaled) that holds the experts `held` = (first, count) of the
 `n_experts` its router scores: what one chip of an expert-parallel
 deployment computes, with no exchange and nothing that stands in for the
-other chips.  Every sub-layer's output is normed before it is added
-(`sandwich_norm`).  `fluid.ProgramDecoder` scans the step; prefill is
-its scan over the prompt.  The equations are in
-`models/reference/pangu_moe.py`, which the tests hold this to.
+other chips.  `fluid.ProgramDecoder` scans the step; prefill is its scan
+over the prompt.
+
+What a model's options change:
+
+- `sandwich_norm` (pangu: True): every sub-layer's output is normed
+  before it is added, four norms a layer; False is the pre-norm block,
+  two norms a layer (`input_norm`, `pre_mlp_norm`).
+- `indexer` = (heads, width, top_k) (DeepSeek-V3.2's lightning indexer):
+  a layer carries a second cache, `index_cache_<i>` [batch, max_len,
+  width], of one small key a token.  The normed query latent goes to two
+  consumers, the heads' up-projections and the index queries `w_iq`;
+  the index key is LayerNorm(h `w_ik`), the first `d_rope` values of it
+  and of every index query rotated; the index heads' weights are h
+  `w_iw`; `mla_index_select` writes the key, scores the live slots and
+  picks `top_k`, and `mla_cached_attention` attends those.
+- `n_group`, `topk_group`, `router_bias`: the router's choice limited to
+  the best groups of experts and steered by a selection bias
+  (`fluid.layers.moe`).
+- `yarn` = {"factor", "original_positions", "beta_fast", "beta_slow",
+  "mscale"}: YaRN's blended rotary frequencies for every rotation, and
+  the attention's scale times (0.1 mscale ln factor + 1)^2.
+
+The equations are in `models/reference/pangu_moe.py` and
+`models/reference/deepseek_v32.py`, which the tests hold this to.
 """
 
 from .. import fluid
 from ..fluid.param_attr import ParamAttr
+from ..ops.attention import yarn_inv_freq, yarn_mscale
 from .decoder_block import gated_feed_forward, linear, norm
 
 __all__ = ["build_latent_moe_cached_step_program", "latent_moe_param_names"]
 
 _ATTENTION = ("input_norm", "w_dq", "q_norm", "w_uq_nope", "w_uq_rope",
-              "w_dkv", "kv_norm", "w_uk", "w_uv", "wo", "post_attn_norm",
-              "pre_mlp_norm")
+              "w_dkv", "kv_norm", "w_uk", "w_uv", "wo")
+_INDEXER = ("w_iq", "w_ik", "ik_norm", "ik_norm_b", "w_iw")
 _DENSE = ("ffn_in", "ffn_out")
 _EXPERTS = ("shared_in", "shared_out", "router", "w_gate", "w_up", "w_down")
 
 
-def latent_moe_param_names(n_layer, n_dense):
-    """The parameters' names, laid out as the reference's `params`."""
+def latent_moe_param_names(n_layer, n_dense, sandwich_norm=True,
+                           indexer=False, router_bias=False):
+    """The parameters' names, laid out as the reference's `params`; the
+    indexer's, the router's bias and the sandwich's two further norms
+    only where the options ask for them."""
     def block(i):
-        kinds = _ATTENTION + (_DENSE if i < n_dense else _EXPERTS) \
-            + ("post_mlp_norm",)
+        kinds = _ATTENTION \
+            + (("post_attn_norm",) if sandwich_norm else ()) \
+            + ("pre_mlp_norm",) + (_INDEXER if indexer else ()) \
+            + (_DENSE if i < n_dense else _EXPERTS
+               + (("router_bias",) if router_bias else ())) \
+            + (("post_mlp_norm",) if sandwich_norm else ())
         return {w: "block_%d.%s" % (i, w) for w in kinds}
 
     return {"embed": "embed.w", "blocks": [block(i) for i in range(n_layer)],
@@ -46,20 +78,40 @@ def build_latent_moe_cached_step_program(
         batch, max_len, vocab_size, n_layer=2, n_dense=1, n_head=4,
         d_model=64, q_rank=32, kv_rank=16, d_nope=16, d_rope=8, d_v=16,
         d_ff=128, d_expert=32, n_experts=8, held=None, top_k=2,
-        norm_topk=True, routed_scale=2.5, eps=1e-5, rope_theta=1e4):
+        norm_topk=True, routed_scale=2.5, eps=1e-5, rope_theta=1e4,
+        sandwich_norm=True, indexer=None, n_group=0, topk_group=0,
+        router_bias=False, yarn=None):
     """Returns (main, startup, logits, state_pairs, parts): feeds "tok"
     int32 [batch], "pos" int64 [batch] and "latent_cache_<i>" [batch,
     max_len, kv_rank + d_rope] a layer (declared float32; a feed is taken
     in the type it arrives in, and the op casts a new entry to the
-    cache's); `logits` [batch, vocab_size];
+    cache's), with an `indexer` also "index_cache_<i>" [batch, max_len,
+    its width]; `logits` [batch, vocab_size];
     `state_pairs` wires the caches and the position into
     `fluid.ProgramDecoder` (pass max_positions=max_len).  `parts` holds,
     per expert layer, the router's Variables "top_w" and "top_idx", the
     experts' "counts", and the routed layer's input "moe_in" and its
-    held experts' part "moe_out" [batch, 1, d_model]; and per layer
-    "hidden", the layer's output [batch, 1, d_model]."""
-    names = latent_moe_param_names(n_layer, n_dense)
+    held experts' part "moe_out" [batch, 1, d_model]; per layer
+    "hidden", the layer's output [batch, 1, d_model], "attn_in", its
+    attention sub-layer's normed input, and "attn_out", that sub-layer's
+    output (after `wo`, before any norm); and with an
+    `indexer`, per layer, "selected" [batch, top_k] and "live"
+    [batch]."""
+    names = latent_moe_param_names(n_layer, n_dense, sandwich_norm,
+                                   indexer is not None, router_bias)
     width = kv_rank + d_rope
+    inv_freq = sm_scale = None
+    if yarn is not None:
+        inv_freq = yarn_inv_freq(
+            d_rope, rope_theta, yarn["factor"], yarn["original_positions"],
+            yarn["beta_fast"], yarn["beta_slow"])
+        sm_scale = (d_nope + d_rope) ** -0.5 \
+            * yarn_mscale(yarn["factor"], yarn.get("mscale", 1.0)) ** 2
+
+    def rotate(x, heads, rotary_dim=None):
+        return fluid.layers.rope(x, positions, heads, rope_theta,
+                                 inv_freq=inv_freq, rotary_dim=rotary_dim)
+
     main = fluid.Program()
     startup = fluid.Program()
     with fluid.program_guard(main, startup):
@@ -71,6 +123,12 @@ def build_latent_moe_cached_step_program(
             name="latent_cache_%d" % i, shape=[batch, max_len, width],
             dtype="float32", append_batch_size=False)
             for i in range(n_layer)]
+        if indexer is not None:
+            i_heads, i_dim, i_top_k = indexer
+            index_caches = [fluid.layers.data(
+                name="index_cache_%d" % i, shape=[batch, max_len, i_dim],
+                dtype="float32", append_batch_size=False)
+                for i in range(n_layer)]
         # lookup_table squeezes a trailing size-1 ids dim
         x = fluid.layers.embedding(
             fluid.layers.reshape(x=fluid.layers.cast(tok, "int64"),
@@ -80,26 +138,48 @@ def build_latent_moe_cached_step_program(
         positions = fluid.layers.reshape(x=pos, shape=[batch, 1])
 
         state_pairs = []
-        parts = {"hidden": [], "top_w": [], "top_idx": [], "counts": [],
-                 "moe_in": [], "moe_out": []}
+        parts = {"hidden": [], "attn_in": [], "attn_out": [], "top_w": [],
+                 "top_idx": [],
+                 "counts": [], "moe_in": [], "moe_out": [], "selected": [],
+                 "live": []}
         for i, block in enumerate(names["blocks"]):
             h = norm(x, eps, block["input_norm"])
+            parts["attn_in"].append(h)
             c_q = norm(linear(h, q_rank, block["w_dq"]), eps,
                        block["q_norm"])
             q_nope = linear(c_q, n_head * d_nope, block["w_uq_nope"])
-            q_rope = fluid.layers.rope(
-                linear(c_q, n_head * d_rope, block["w_uq_rope"]),
-                positions, n_head, rope_theta)
+            q_rope = rotate(linear(c_q, n_head * d_rope, block["w_uq_rope"]),
+                            n_head)
             c, r = fluid.layers.split(
                 linear(h, width, block["w_dkv"]), [kv_rank, d_rope], dim=-1)
+            chosen = {}
+            if indexer is not None:
+                k_index = fluid.layers.layer_norm(
+                    linear(h, i_dim, block["w_ik"]), begin_norm_axis=2,
+                    epsilon=eps, param_attr=ParamAttr(name=block["ik_norm"]),
+                    bias_attr=ParamAttr(name=block["ik_norm_b"]))
+                selected, live, index_out = fluid.layers.mla_index_select(
+                    rotate(linear(c_q, i_heads * i_dim, block["w_iq"]),
+                           i_heads, d_rope),
+                    linear(h, i_heads, block["w_iw"]),
+                    rotate(k_index, 1, d_rope), index_caches[i], pos,
+                    i_heads, i_top_k, scale=(i_heads * i_dim) ** -0.5)
+                chosen = {"selected": selected, "live": live}
+                parts["selected"].append(selected)
+                parts["live"].append(live)
             o, cache_out = fluid.layers.mla_cached_attention(
                 q_nope, q_rope, norm(c, eps, block["kv_norm"]),
-                fluid.layers.rope(r, positions, 1, rope_theta), caches[i],
+                rotate(r, 1), caches[i],
                 pos, n_head, d_v, uk_attr=ParamAttr(name=block["w_uk"]),
-                uv_attr=ParamAttr(name=block["w_uv"]))
+                uv_attr=ParamAttr(name=block["w_uv"]), sm_scale=sm_scale,
+                **chosen)
             state_pairs.append(("latent_cache_%d" % i, cache_out.name))
-            a = x + norm(linear(o, d_model, block["wo"]), eps,
-                         block["post_attn_norm"])
+            if indexer is not None:
+                state_pairs.append(("index_cache_%d" % i, index_out.name))
+            o = linear(o, d_model, block["wo"])
+            parts["attn_out"].append(o)
+            a = x + (norm(o, eps, block["post_attn_norm"])
+                     if sandwich_norm else o)
             u = norm(a, eps, block["pre_mlp_norm"])
             if i < n_dense:
                 f = gated_feed_forward(u, d_ff, {"w_in": block["ffn_in"],
@@ -111,7 +191,10 @@ def build_latent_moe_cached_step_program(
                       for w in ("router", "w_gate", "w_up", "w_down")),
                     scoring="sigmoid", norm_topk=norm_topk,
                     scale=routed_scale,
-                    held=held)
+                    held=held,
+                    bias_attr=ParamAttr(name=block["router_bias"])
+                    if router_bias else None,
+                    n_group=n_group, topk_group=topk_group)
                 f = gated_feed_forward(
                     u, d_expert, {"w_in": block["shared_in"],
                                   "w_out": block["shared_out"]}) + m
@@ -119,7 +202,8 @@ def build_latent_moe_cached_step_program(
                     parts[key].append(routing[key])
                 parts["moe_in"].append(u)
                 parts["moe_out"].append(m)
-            x = a + norm(f, eps, block["post_mlp_norm"])
+            x = a + (norm(f, eps, block["post_mlp_norm"])
+                     if sandwich_norm else f)
             parts["hidden"].append(x)
 
         logits = fluid.layers.reshape(

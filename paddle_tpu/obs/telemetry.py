@@ -37,7 +37,10 @@ __all__ = ["on_executor_run", "on_jit_trace",
            "on_flash_attention_bwd_lowering",
            "on_flash_attention_pairs",
            "on_flash_attention_grad_lowering", "on_moe_lowering",
-           "on_moe_gmm_lowering", "on_cached_attention_lowering",
+           "on_moe_gmm_lowering", "on_moe_share_lowering",
+           "on_moe_grouped_router_lowering",
+           "on_mla_cached_attention_lowering",
+           "on_mla_index_select_lowering", "on_cached_attention_lowering",
            "on_prefill_lowering", "on_ssd_lowering",
            "on_causal_conv1d_lowering", "on_shared_parameter_uses",
            "on_transfer",
@@ -193,16 +196,45 @@ def on_moe_share_lowering(scored, held, top_k):
           .labels(scored=scored, held=held, top_k=top_k).inc()
 
 
-def on_mla_cached_attention_lowering(heads, latent, rope, cache_dtype):
+def on_mla_cached_attention_lowering(heads, latent, rope, cache_dtype,
+                                     selected="all"):
     """A decode step of latent attention (`mla_cached_attention`,
-    ops/attention.py) was traced into a program: one count per op
-    instance a lowered program holds."""
+    ops/attention.py) was traced into a program, over every slot of its
+    cache (`selected` "all") or over so many chosen ones: one count per
+    op instance a lowered program holds."""
     _reg().counter("mla_cached_attention_lowerings_total",
                    "latent-attention decode steps lowered, by heads, "
-                   "latent and rotated-key widths and the cache's type",
-                   labelnames=("heads", "latent", "rope", "cache_dtype")) \
+                   "latent and rotated-key widths, the cache's type and "
+                   "the slots attended (all, or so many chosen)",
+                   labelnames=("heads", "latent", "rope", "cache_dtype",
+                               "selected")) \
           .labels(heads=heads, latent=latent, rope=rope,
+                  cache_dtype=str(cache_dtype), selected=selected).inc()
+
+
+def on_mla_index_select_lowering(heads, dim, top_k, cache_dtype):
+    """A decode step of a chooser of cache slots (`mla_index_select`,
+    ops/attention.py) was traced into a program: one count per op
+    instance a lowered program holds."""
+    _reg().counter("mla_index_select_lowerings_total",
+                   "index-select decode steps lowered, by index heads, "
+                   "their width, the slots chosen and the key cache's type",
+                   labelnames=("heads", "dim", "top_k", "cache_dtype")) \
+          .labels(heads=heads, dim=dim, top_k=top_k,
                   cache_dtype=str(cache_dtype)).inc()
+
+
+def on_moe_grouped_router_lowering(experts, groups, kept, top_k):
+    """A router whose choice is limited to the best `kept` of `groups`
+    groups of its experts (`moe_router` with `n_group`, ops/moe.py) was
+    traced into a program: one count per op instance a lowered program
+    holds."""
+    _reg().counter("moe_grouped_router_lowerings_total",
+                   "group-limited routers lowered, by experts scored, "
+                   "groups, groups kept and experts a token",
+                   labelnames=("experts", "groups", "kept", "top_k")) \
+          .labels(experts=experts, groups=groups, kept=kept,
+                  top_k=top_k).inc()
 
 
 def on_cached_attention_lowering(block):

@@ -26,6 +26,8 @@ The gradient of that branch, and of a program built before the op had
 `Lse`, is the generic one.
 """
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -138,6 +140,43 @@ def flash_attention_grad(ctx, ins, attrs):
             for slot, g in zip(("Q", "K", "V"), grads)}
 
 
+def yarn_inv_freq(dim, theta, factor, original_positions, beta_fast,
+                  beta_slow):
+    """YaRN's blended inverse frequencies (Peng et al. 2023,
+    arXiv:2309.00071, as the DeepSeek-V3 family's released inference
+    code makes them) of a rotary width `dim`, as `dim // 2` Python
+    floats: with f_i = theta^(-2i / dim) and corr(n) = dim ln(original /
+    (2 pi n)) / (2 ln theta) the pair index that turns n times over the
+    original context, lo = max(floor(corr(beta_fast)), 0), hi =
+    min(ceil(corr(beta_slow)), dim - 1), ramp_i = clip((i - lo) / (hi -
+    lo), 0, 1):
+
+        f'_i = f_i / factor * ramp_i + f_i * (1 - ramp_i)
+
+    (pairs that turn often keep their frequency, those that never
+    complete a turn over the original context are slowed by `factor`)."""
+    def corr(turns):
+        return dim * math.log(original_positions / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    lo = max(math.floor(corr(beta_fast)), 0)
+    hi = min(math.ceil(corr(beta_slow)), dim - 1)
+    span = (hi - lo) or 0.001
+    out = []
+    for i in range(dim // 2):
+        f = theta ** (-2.0 * i / dim)
+        ramp = min(max((i - lo) / span, 0.0), 1.0)
+        out.append(f / factor * ramp + f * (1.0 - ramp))
+    return out
+
+
+def yarn_mscale(factor, mscale=1.0):
+    """What YaRN multiplies attention's logits' scale by, squared by the
+    caller (once for the query, once for the key): 0.1 mscale ln(factor)
+    + 1, and 1 where nothing is stretched."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
 @register_op("rope", nondiff_inputs=("Positions",),
              infer_shape=same_meta_infer_shape("X", "Out"))
 def rope(ctx, ins, attrs):
@@ -145,7 +184,12 @@ def rope(ctx, ins, attrs):
     form, on each head of X [batch, seq, heads * head_dim] at Positions
     [batch, seq]: the pair (x_i, x_{i + head_dim/2}) is turned by the
     angle position * theta^(-2i / head_dim).  Angles and rotation in
-    float32, the result in X's type."""
+    float32, the result in X's type.
+
+    `inv_freq` (a list of floats, one a pair) gives the angles' rates in
+    place of theta's powers (`yarn_inv_freq` makes YaRN's); `rotary_dim`
+    turns only the first so many values of every head, paired (x_i,
+    x_{i + rotary_dim/2}), and hands the rest on as they are."""
     x = ins["X"][0]
     pos = ins["Positions"][0]
     num_heads = int(attrs["num_heads"])
@@ -154,13 +198,31 @@ def rope(ctx, ins, attrs):
     if d % (2 * num_heads):
         raise ValueError("rope: hidden size %d is not num_heads %d times "
                          "an even head size" % (d, num_heads))
-    half = d // num_heads // 2
-    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    head = d // num_heads
+    rotary = int(attrs.get("rotary_dim", 0)) or head
+    if rotary % 2 or rotary > head:
+        raise ValueError("rope: rotary_dim %d is not an even part of a "
+                         "head of %d" % (rotary, head))
+    half = rotary // 2
+    if attrs.get("inv_freq"):
+        if len(attrs["inv_freq"]) != half:
+            raise ValueError("rope: %d inverse frequencies for %d pairs"
+                             % (len(attrs["inv_freq"]), half))
+        inv_freq = jnp.asarray(attrs["inv_freq"], jnp.float32)
+    else:
+        inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
     angles = pos.reshape(b, t, 1, 1).astype(jnp.float32) * inv_freq
     cos, sin = jnp.cos(angles), jnp.sin(angles)
-    xs = x.astype(jnp.float32).reshape(b, t, num_heads, 2, half)
-    x1, x2 = xs[..., 0, :], xs[..., 1, :]
-    out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-2)
+    if rotary == head:
+        xs = x.astype(jnp.float32).reshape(b, t, num_heads, 2, half)
+        x1, x2 = xs[..., 0, :], xs[..., 1, :]
+        out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                        axis=-2)
+        return {"Out": [out.reshape(b, t, d).astype(x.dtype)]}
+    xs = x.astype(jnp.float32).reshape(b, t, num_heads, head)
+    x1, x2 = xs[..., :half], xs[..., half:rotary]
+    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin,
+                           xs[..., rotary:]], axis=-1)
     return {"Out": [out.reshape(b, t, d).astype(x.dtype)]}
 
 
@@ -236,6 +298,91 @@ def cached_attention_op(ctx, ins, attrs):
             "KCacheOut": [k_cache], "VCacheOut": [v_cache]}
 
 
+def _set_meta(block, name, shape, dtype):
+    desc = block.var_recursive(name).desc
+    desc.shape, desc.dtype, desc.lod_level = tuple(shape), dtype, 0
+
+
+def _index_select_infer_shape(block, op_desc):
+    cache = block.var_recursive(op_desc.input("Cache")[0]).desc
+    top_k = int(op_desc.attrs["top_k"])
+    _set_meta(block, op_desc.output("CacheOut")[0], cache.shape, cache.dtype)
+    _set_meta(block, op_desc.output("Selected")[0],
+              (cache.shape[0], top_k), "int32")
+    _set_meta(block, op_desc.output("Live")[0], (cache.shape[0],), "int32")
+
+
+@register_op("mla_index_select", stop_gradient_op=True,
+             infer_shape=_index_select_infer_shape)
+def mla_index_select_op(ctx, ins, attrs):
+    """One decode step of a learned chooser of cache slots (the
+    "lightning indexer" of DeepSeek-V3.2's sparse attention, as the
+    family's released inference code has it): a token keeps one small key a layer
+    in a cache of the chooser's own, and a query's few heads score every
+    live slot to pick the `top_k` the attention is to read.
+
+    Q [batch, 1, heads * dim] (rotated) and W [batch, 1, heads] are this
+    token's index queries and their weights, KNew [batch, 1, dim]
+    (normed, rotated) its index key; Cache [batch, positions, dim];
+    Position int [1] or [batch] (lockstep rows), the slot this step
+    writes.
+
+        I_s = scale * sum_j W_j relu(q_j . k_s)      s <= Position
+        Selected = the top_k slots with the largest I_s
+
+    The products take their operands in Q's type (a cache in a narrower
+    type is read up to it) and add up in float32; relu, the weighted sum
+    over the heads and the top-k are float32.  A slot past Position
+    scores -inf and is never among the live ones.  Scopes: `dsa_index`
+    holds the key's write and everything that makes the scores,
+    `dsa_select` the top-k.
+    CacheOut is the cache with the slot written (a `ProgramDecoder`
+    state pair); Selected int32 [batch, top_k], best first; Live int32
+    [batch] = min(top_k, Position + 1): only the first Live entries of a
+    row are slots to attend, what follows them (where fewer slots are
+    live than were asked for) names slots that hold nothing and must be
+    masked.  No gradient, as `mla_cached_attention`."""
+    q, w, k_new = ins["Q"][0], ins["W"][0], ins["KNew"][0]
+    cache = ins["Cache"][0]
+    pos = jnp.reshape(ins["Position"][0], (-1,))[0].astype(jnp.int32)
+    top_k, heads = int(attrs["top_k"]), int(attrs["num_heads"])
+    scale = float(attrs.get("scale", 1.0))
+    batch, positions, dim = cache.shape
+    if q.shape[-1] != heads * dim or w.shape[-1] != heads \
+            or k_new.shape[-1] != dim:
+        raise ValueError(
+            "mla_index_select: the cache holds %d values a token; %d heads "
+            "want Q %d wide, W %d and KNew %d, got %s, %s and %s"
+            % (dim, heads, heads * dim, heads, dim, q.shape, w.shape,
+               k_new.shape))
+    if top_k > positions:
+        raise ValueError("mla_index_select: top_k %d of a cache of %d "
+                         "positions" % (top_k, positions))
+    telemetry.on_mla_index_select_lowering(heads, dim, top_k, cache.dtype)
+    f32 = jnp.float32
+
+    # everything that makes the [batch, positions] scores is one scope:
+    # XLA fuses the heads' products into the sum that reads them, and a
+    # fusion's time goes to the scope of its root
+    with jax.named_scope("dsa_index"):
+        cache = jax.lax.dynamic_update_slice_in_dim(
+            cache, k_new.reshape(batch, 1, dim).astype(cache.dtype), pos,
+            axis=1)
+        s = jnp.einsum("bhd,btd->bht", q.reshape(batch, heads, dim),
+                       cache.astype(q.dtype), preferred_element_type=f32)
+        score = jnp.einsum("bh,bht->bt", w.reshape(batch, heads).astype(f32),
+                           jax.nn.relu(s),
+                           precision=jax.lax.Precision.HIGHEST)
+        if scale != 1.0:
+            score = score * scale
+        score = jnp.where(jnp.arange(positions) <= pos, score, -jnp.inf)
+    with jax.named_scope("dsa_select"):
+        _, selected = jax.lax.top_k(score, top_k)
+    live = jnp.full((batch,), jnp.minimum(top_k, pos + 1), jnp.int32)
+    return {"CacheOut": [cache], "Selected": [selected.astype(jnp.int32)],
+            "Live": [live]}
+
+
 def _mla_infer_shape(block, op_desc):
     q = block.var_recursive(op_desc.input("QNope")[0]).desc
     w_uv = block.var_recursive(op_desc.input("WUv")[0]).desc
@@ -279,11 +426,22 @@ def mla_cached_attention_op(ctx, ins, attrs):
     cheaper than a copy of the cache without them), in the cache's
     type with float32 sums; scores and softmax are float32.  Out [batch,
     1, heads * value], CacheOut the cache with the slot written: a
-    `ProgramDecoder` state pair.  No gradient, as `cached_attention`."""
+    `ProgramDecoder` state pair.  No gradient, as `cached_attention`.
+
+    `sm_scale` (an attr) takes the place of 1 / sqrt(nope + rope): YaRN
+    multiplies it by its mscale squared.  With Selected int32 [batch,
+    top_k] and Live int32 [batch] (`mla_index_select`'s) the step
+    attends a chosen set and not every slot: the rows Selected names are
+    gathered from the cache, after this step's slot is written
+    (`dsa_gather`: one [batch, top_k, latent + rope] copy), the same two
+    contractions run over the gathered entries, and of a row's top_k
+    entries the first Live count, the others are masked: a chosen set is
+    a set, the softmax does not care for its order."""
     q_nope, q_rope = ins["QNope"][0], ins["QRope"][0]
     c_new, r_new = ins["CNew"][0], ins["RNew"][0]
     cache, w_uk, w_uv = ins["Cache"][0], ins["WUk"][0], ins["WUv"][0]
     pos = jnp.reshape(ins["Position"][0], (-1,))[0].astype(jnp.int32)
+    selected = (ins.get("Selected") or [None])[0]
     heads = int(attrs["num_heads"])
     batch, positions, width = cache.shape
     latent, rope_dim = c_new.shape[-1], r_new.shape[-1]
@@ -293,17 +451,25 @@ def mla_cached_attention_op(ctx, ins, attrs):
             "the latent is %d wide and the rotated key %d; W_uk is %s"
             % (width, latent, rope_dim, w_uk.shape))
     nope = q_nope.shape[-1] // heads
-    sm_scale = (nope + rope_dim) ** -0.5
-    telemetry.on_mla_cached_attention_lowering(heads, latent, rope_dim,
-                                               cache.dtype)
+    sm_scale = float(attrs.get("sm_scale", 0.0)) \
+        or (nope + rope_dim) ** -0.5
+    telemetry.on_mla_cached_attention_lowering(
+        heads, latent, rope_dim, cache.dtype,
+        "all" if selected is None else selected.shape[-1])
     dtype = q_nope.dtype
     f32 = jnp.float32
 
     entry = jnp.concatenate([c_new, r_new], axis=-1).reshape(batch, 1, width)
     cache = jax.lax.dynamic_update_slice_in_dim(
         cache, entry.astype(cache.dtype), pos, axis=1)
-    # a cache in a narrower type than the products' is read up to it
-    live = cache.astype(dtype)
+    if selected is None:
+        # a cache in a narrower type than the products' is read up to it
+        live = cache.astype(dtype)
+    else:
+        with jax.named_scope("dsa_gather"):
+            live = jnp.take_along_axis(
+                cache, selected[:, :, None].astype(jnp.int32),
+                axis=1).astype(dtype)
 
     with jax.named_scope("mla_absorb"):
         q_lat = jnp.einsum(
@@ -315,7 +481,11 @@ def mla_cached_attention_op(ctx, ins, attrs):
     with jax.named_scope("mla_scores"):
         s = jnp.einsum("bhw,btw->bht", q, live,
                        preferred_element_type=f32) * sm_scale
-        valid = jnp.arange(positions) <= pos
+        if selected is None:
+            valid = jnp.arange(positions) <= pos
+        else:
+            valid = jnp.arange(selected.shape[-1]) \
+                < jnp.reshape(ins["Live"][0], (-1,))[0]
         p = jax.nn.softmax(jnp.where(valid[None, None, :], s, -1e30),
                            axis=-1)
     with jax.named_scope("mla_values"):

@@ -100,6 +100,27 @@ def _chosen(top_w, attrs):
     return top_w if scale == 1.0 else top_w * scale
 
 
+def _kept_groups(choice, groups, kept, top_k):
+    """`choice` [tokens, experts] with -inf on every expert outside the
+    token's `kept` best of `groups` groups of consecutive experts; a
+    group scores the sum of its two largest entries."""
+    n, experts = choice.shape
+    if experts % groups or not 0 < kept <= groups \
+            or experts // groups < 2 or kept * (experts // groups) < top_k:
+        raise ValueError(
+            "moe_router: %d experts in %d groups of which %d are kept "
+            "cannot give %d experts a token" % (experts, groups, kept,
+                                                top_k))
+    telemetry.on_moe_grouped_router_lowering(experts, groups, kept, top_k)
+    with jax.named_scope("moe_groups"):
+        grouped = choice.reshape(n, groups, experts // groups)
+        group_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
+        _, best = jax.lax.top_k(group_score, kept)
+        keep = jnp.any(best[:, :, None] == jnp.arange(groups), axis=1)
+        return jnp.where(keep[:, :, None], grouped,
+                         -jnp.inf).reshape(n, experts)
+
+
 @register_op("moe_router", infer_shape=_router_infer_shape)
 def moe_router(ctx, ins, attrs):
     """X [..., hidden], W [hidden, experts] -> Logits [tokens, experts],
@@ -114,16 +135,40 @@ def moe_router(ctx, ins, attrs):
     With `scoring` "sigmoid" the scores are sigmoid(logits), TopW the
     largest of them, and both losses 0.  `norm_topk` divides TopW by its
     sum over the chosen (+ 1e-20) and `scale` multiplies it, under
-    either scoring."""
+    either scoring.
+
+    Under sigmoid scoring the *choice* can be steered apart from the
+    weights, as DeepSeek-V3 routes (`noaux_tc`): Bias [experts] is added
+    to the scores for the choice alone (s' = s + b; TopW still reads s),
+    and with `n_group` > 1 the experts are `n_group` groups of
+    consecutive ones, a group scores the sum of its two largest s', and
+    an expert outside the `topk_group` best groups cannot be chosen
+    whatever its score (`moe_groups`)."""
     x, w = ins["X"][0], ins["W"][0]
     k = int(attrs["top_k"])
     experts = w.shape[1]
     scoring = attrs.get("scoring", "softmax")
+    bias = (ins.get("Bias") or [None])[0]
+    groups = int(attrs.get("n_group", 0))
     logits = jnp.dot(x.reshape(-1, x.shape[-1]).astype(jnp.float32),
                      w.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
+    if (bias is not None or groups > 1) and scoring != "sigmoid":
+        raise ValueError("moe_router: a selection bias and groups steer "
+                         "the sigmoid router's choice, scoring is %r"
+                         % scoring)
     if scoring == "sigmoid":
-        top_w, top_idx = jax.lax.top_k(jax.nn.sigmoid(logits), k)
+        scores = jax.nn.sigmoid(logits)
+        if bias is None and groups <= 1:
+            top_w, top_idx = jax.lax.top_k(scores, k)
+        else:
+            choice = scores if bias is None \
+                else scores + bias.astype(jnp.float32).reshape(1, experts)
+            if groups > 1:
+                choice = _kept_groups(choice, groups,
+                                      int(attrs["topk_group"]), k)
+            _, top_idx = jax.lax.top_k(choice, k)
+            top_w = jnp.take_along_axis(scores, top_idx, axis=1)
         zero = jnp.zeros((1,), jnp.float32)
         return {"Logits": [logits], "TopW": [_chosen(top_w, attrs)],
                 "TopIdx": [top_idx.astype(jnp.int32)],

@@ -1,0 +1,727 @@
+"""DeepSeek-V3.2's share on the generation path: the chooser of cache
+slots (`mla_index_select`), latent attention over a chosen set
+(`mla_cached_attention` with `Selected`), YaRN's rotary frequencies
+(`rope` with `inv_freq`), the group-limited router with a selection bias
+(`moe_router` with `Bias`, `n_group`), and the cached step Program that
+`models/latent_moe_program.py` builds from them, against the plain
+float32 reference (models/reference/deepseek_v32.py): the step from
+position 0 and from a handed-in session against the reference's full
+forward; the ops alone; the shares adding up under grouped routing; what
+the ops lowered to before they grew their options
+(tests/parent_lowerings.py); the counters; `ProgramDecoder`'s extent
+check for a call that starts past position 0.
+
+Tiny sizes on the CPU, where selection bites: 2 layers (1 dense), hidden
+64, 4 heads of 16 + 8 (values 16), query rank 32, latent 16, 8 index
+heads of 16 (the first 8 rotated) choosing 8 of up to 48 slots, 8
+experts scored in 4 groups of which 2 are kept, 2 a token, 4 held, a
+non-zero selection bias, YaRN x 40 over 16 original positions (so that
+its ramp lies inside the 4 pairs), vocabulary 97, seeded weights.
+"""
+
+import json
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import paddle_tpu.fluid as fluid
+from paddle_tpu.models.latent_moe_program import (
+    build_latent_moe_cached_step_program, latent_moe_param_names)
+from paddle_tpu.models.reference import deepseek_v32 as reference
+from paddle_tpu.obs import telemetry
+from paddle_tpu.ops import registry
+from paddle_tpu.ops.attention import yarn_inv_freq, yarn_mscale
+
+import parent_lowerings
+
+B, T, V, L, DENSE = 2, 48, 97, 2, 1
+H, D, QR, KVR, NOPE, ROPE, DV, FF, FE = 4, 64, 32, 16, 16, 8, 16, 128, 32
+E, K, HELD, GROUPS, KEPT = 8, 2, (2, 4), 4, 2
+IH, ID, TOPK = 8, 16, 8
+SESSION = 24
+YARN = {"factor": 40, "original_positions": 16, "beta_fast": 32,
+        "beta_slow": 1, "mscale": 1}
+SIZES = dict(n_layer=L, n_dense=DENSE, n_head=H, d_model=D, q_rank=QR,
+             kv_rank=KVR, d_nope=NOPE, d_rope=ROPE, d_v=DV, d_ff=FF,
+             d_expert=FE, n_experts=E, held=HELD, top_k=K, eps=1e-6,
+             sandwich_norm=False, indexer=(IH, ID, TOPK), n_group=GROUPS,
+             topk_group=KEPT, router_bias=True, yarn=YARN)
+CFG = {"num_attention_heads": H, "rms_norm_eps": 1e-6, "rope_theta": 1e4,
+       "kv_lora_rank": KVR, "qk_nope_head_dim": NOPE,
+       "qk_rope_head_dim": ROPE, "num_experts_per_tok": K,
+       "norm_topk_prob": True, "routed_scaling_factor": 2.5,
+       "n_group": GROUPS, "topk_group": KEPT, "index_n_heads": IH,
+       "index_head_dim": ID, "index_topk": TOPK,
+       "rope_scaling": {"factor": 40, "beta_fast": 32, "beta_slow": 1,
+                        "mscale": 1, "mscale_all_dim": 1,
+                        "original_max_position_embeddings": 16}}
+NAMES = latent_moe_param_names(L, DENSE, sandwich_norm=False, indexer=True,
+                               router_bias=True)
+
+# float32 on the CPU.  The step absorbs the up-projections, reads two
+# caches and attends a gathered set; the reference makes every head's
+# keys and values, the whole [T, T] index scores and a masked softmax:
+# other sums in another order.  Logits of size ~3 were seen to differ by
+# 2e-6; 2e-5 of the largest logit is a dozen times that, and every
+# wrong choice this file knows (a `top_k` off by one, a dropped bias, an
+# ungrouped router: `test_a_wrong_choice_shows`) moves them by 1e-2 of it
+# or more.  A chosen set that differs by one slot at one position would
+# show too, so the seeds here have no near-tie (the margins are printed
+# by `test_the_choices_are_no_near_ties`).
+LOGITS_RTOL = 2e-5
+
+
+def _start(startup, names=NAMES, seed=3):
+    scope = fluid.Scope()
+    exe = fluid.Executor(fluid.CPUPlace())
+    startup.random_seed = seed
+    exe.run(startup, scope=scope)
+    rs = np.random.RandomState(seed)
+    for name in jax.tree_util.tree_leaves(names):
+        value = np.asarray(scope.get(name))
+        if value.ndim == 1:
+            # norm scales off their 1, biases off their 0 (the selection
+            # bias wide enough to move a choice)
+            wide = 0.3 if name.endswith("router_bias") else 0.1
+            scope.set(name, jnp.asarray(
+                value + wide * rs.randn(*value.shape).astype("float32")))
+    return scope
+
+
+def _decoder(main, logits, pairs, scope, extent=T):
+    return fluid.ProgramDecoder(
+        main.clone(for_test=True), token_name="tok",
+        logits_name=logits.name, state_pairs=pairs, scope=scope,
+        max_positions=extent)
+
+
+def _empty(dtype=jnp.float32):
+    state = {}
+    for i in range(L):
+        state["latent_cache_%d" % i] = jnp.zeros((B, T, KVR + ROPE), dtype)
+        state["index_cache_%d" % i] = jnp.zeros((B, T, ID), dtype)
+    state["pos"] = jnp.zeros((B,), jnp.int32)
+    return state
+
+
+def _drive(decoder, tokens, state):
+    """([B, n, V] logits, state): the step applied token by token."""
+    step = decoder._step_fn(decoder._params)
+    out = []
+    for t in range(tokens.shape[1]):
+        logits, state = step(state, jnp.asarray(tokens[:, t]))
+        out.append(logits)
+    return np.stack([np.asarray(z, np.float32) for z in out], axis=1), state
+
+
+def _build(**changed):
+    return build_latent_moe_cached_step_program(
+        B, T, V, **dict(SIZES, **changed))
+
+
+@pytest.fixture(scope="module")
+def built():
+    before = telemetry.snapshot()
+    main, startup, logits, pairs, parts = _build()
+    at_build = telemetry.snapshot_delta(before)
+    scope = _start(startup)
+    decoder = _decoder(main, logits, pairs, scope)
+    tokens = np.random.RandomState(1).randint(0, V, (B, T)).astype("int32")
+    got, state = _drive(decoder, tokens, _empty())
+    params = jax.tree_util.tree_map(scope.get, NAMES)
+    want = reference.forward(CFG, params, jnp.asarray(tokens), held=HELD)
+    return {"main": main, "logits": logits, "pairs": pairs, "parts": parts,
+            "scope": scope, "decoder": decoder, "tokens": tokens,
+            "got": got, "state": state, "params": params, "want": want,
+            "at_build": at_build}
+
+
+# -- (a) the step from position 0 against the full forward ---------------------
+
+@pytest.mark.parametrize("position", range(T))
+def test_step_logits_agree_with_the_reference_at_every_position(
+        built, position):
+    """Positions 0..7 attend every live slot (fewer live than `top_k`),
+    8..47 the 8 chosen of 9..48."""
+    want = np.asarray(built["want"]["logits"])[:, position]
+    got = built["got"][:, position]
+    assert np.abs(got - want).max() <= LOGITS_RTOL * np.abs(want).max()
+
+
+def test_the_choices_are_no_near_ties(built):
+    """What licenses comparing logits under each side's own choice: at
+    every query the reference's score of the last slot taken lies a
+    float32 rounding and more above the first left out."""
+    for scores in built["want"]["index_scores"]:
+        s = np.asarray(scores)
+        for t in range(TOPK, T):
+            live = np.sort(s[:, t, :t + 1], axis=-1)[:, ::-1]
+            margin = (live[:, TOPK - 1] - live[:, TOPK]) \
+                / np.abs(live[:, 0])
+            assert margin.min() > 1e-4, (t, margin)
+
+
+def test_both_caches_hold_what_the_reference_computes(built):
+    """What licenses a session made by the reference: the latents and
+    the index keys the step wrote are the reference's `c | r` and `k^I`
+    of every position."""
+    for i in range(L):
+        for cache, want in (("latent_cache_%d", "latents"),
+                            ("index_cache_%d", "index_keys")):
+            got = np.asarray(built["state"][cache % i])
+            ref = np.asarray(built["want"][want][i])
+            assert got.shape == ref.shape
+            np.testing.assert_allclose(got, ref, atol=2e-5 * np.abs(
+                ref).max())
+    assert int(built["state"]["pos"][0]) == T
+
+
+def test_greedy_through_the_decoder_is_the_references_greedy(built):
+    """Prefill then decode through `ProgramDecoder.greedy`: every served
+    token is the reference's first given the tokens before it."""
+    prompt = built["tokens"][:, :20]
+    tokens, lengths = built["decoder"].greedy(
+        bos=0, eos=V, max_len=T - 19, init_state=_empty(), prompt=prompt)
+    assert tokens.shape == (B, T - 19) and (lengths == T - 19).all()
+    full = np.concatenate([prompt, tokens], axis=1)[:, :T]
+    z = np.asarray(reference.forward(
+        CFG, built["params"], jnp.asarray(full), held=HELD)["logits"])
+    served = tokens[:, :T - 19]
+    picked = np.take_along_axis(z[:, 19:19 + served.shape[1]],
+                                served[..., None], axis=-1)[..., 0]
+    gap = z[:, 19:19 + served.shape[1]].max(axis=-1) - picked
+    assert gap.max() <= 1e-4
+
+
+# -- (b) continued from a handed-in session ------------------------------------
+
+@pytest.fixture(scope="module")
+def continued(built):
+    """The caches made by the reference over the first SESSION tokens
+    (its `c | r` and `k^I`, padded to the extent), `pos` = SESSION, and
+    the step driven over the rest."""
+    session = reference.forward(
+        CFG, built["params"], jnp.asarray(built["tokens"][:, :SESSION]),
+        held=HELD)
+    state = _empty()
+    for i in range(L):
+        for cache, made in (("latent_cache_%d", "latents"),
+                            ("index_cache_%d", "index_keys")):
+            state[cache % i] = state[cache % i].at[:, :SESSION].set(
+                session[made][i])
+    state["pos"] = jnp.full((B,), SESSION, jnp.int32)
+    return _drive(built["decoder"], built["tokens"][:, SESSION:], state)
+
+
+@pytest.mark.parametrize("position", range(SESSION, T))
+def test_a_handed_in_session_continues_as_the_reference(
+        built, continued, position):
+    """Session + turn + answer: the logits at the positions after the
+    session are the reference's over the whole sequence."""
+    want = np.asarray(built["want"]["logits"])[:, position]
+    got = continued[0][:, position - SESSION]
+    assert np.abs(got - want).max() <= LOGITS_RTOL * np.abs(want).max()
+
+
+def test_greedy_from_a_session_starts_past_position_zero(built, continued):
+    state = {k: np.asarray(v) for k, v in continued[1].items()}
+    assert int(state["pos"][0]) == T
+    init = _empty()
+    for name in init:
+        if name != "pos":
+            init[name] = jnp.asarray(state[name]).at[:, SESSION:].set(0)
+    init["pos"] = jnp.full((B,), SESSION, jnp.int32)
+    turn = built["tokens"][:, SESSION:SESSION + 8]
+    tokens, _ = built["decoder"].greedy(
+        bos=0, eos=V, max_len=T - SESSION - 7, init_state=init, prompt=turn)
+    full = np.concatenate([built["tokens"][:, :SESSION + 8], tokens],
+                          axis=1)[:, :T]
+    z = np.asarray(reference.forward(
+        CFG, built["params"], jnp.asarray(full), held=HELD)["logits"])
+    at = SESSION + 7
+    picked = np.take_along_axis(z[:, at:at + tokens.shape[1]],
+                                tokens[..., None], axis=-1)[..., 0]
+    assert (z[:, at:at + tokens.shape[1]].max(-1) - picked).max() <= 1e-4
+
+
+def test_the_extent_check_cannot_see_a_session(built):
+    """`_check_extent` sees the prompt and `max_len`, not a `pos` inside
+    `init_state`: its message says who answers for the session."""
+    with pytest.raises(ValueError, match="init_state.*pos.*caller"):
+        built["decoder"].greedy(bos=0, eos=V, max_len=T, init_state=_empty(),
+                                prompt=built["tokens"][:, :5])
+
+
+# -- (c) a wrong choice shows ----------------------------------------------------
+
+@pytest.mark.parametrize("wrong", [
+    {"indexer": (IH, ID, TOPK + 1)}, {"indexer": (IH, ID, TOPK - 1)},
+    {"router_bias": False}, {"n_group": 0, "topk_group": 0},
+    {"yarn": None}], ids=["top_k+1", "top_k-1", "no bias", "no groups",
+                          "no yarn"])
+def test_a_wrong_choice_shows(built, wrong):
+    """The same weights under a program that chooses otherwise: the
+    logits leave the tolerance by two orders of magnitude and more."""
+    main, _, logits, pairs, _ = _build(**wrong)
+    got, _ = _drive(_decoder(main, logits, pairs, built["scope"]),
+                    built["tokens"], _empty())
+    want = np.asarray(built["want"]["logits"])
+    off = np.abs(got - want).max() / np.abs(want).max()
+    assert off > 100 * LOGITS_RTOL, off
+
+
+# -- the chooser alone -----------------------------------------------------------
+
+def _index_ins(rs, pos, dtype=jnp.float32):
+    q = jnp.asarray(rs.randn(B, 1, IH * ID), dtype)
+    w = jnp.asarray(rs.uniform(0.2, 1.0, (B, 1, IH)), dtype)
+    k_new = jnp.asarray(rs.randn(B, 1, ID), dtype)
+    cache = jnp.asarray(rs.randn(B, T, ID), dtype).at[:, pos:].set(0)
+    return {"Q": [q], "W": [w], "KNew": [k_new], "Cache": [cache],
+            "Position": [jnp.full((B,), pos, jnp.int32)]}
+
+
+@pytest.mark.parametrize("pos", [0, 3, TOPK - 1, TOPK, 20, T - 1])
+def test_index_select_is_the_references_scores_and_set(pos):
+    """Against the reference's `index_scores` and `choose` of the same
+    query and keys (continuous seeded scores: no ties); with `pos + 1 <
+    top_k` the first `Live` entries are the live slots and what follows
+    names none of them twice."""
+    ins = _index_ins(np.random.RandomState(pos), pos)
+    outs = registry.get_op_info("mla_index_select").kernel(
+        None, ins, {"num_heads": IH, "top_k": TOPK, "scale": 0.5})
+    kept = np.asarray(outs["CacheOut"][0])
+    np.testing.assert_array_equal(kept[:, pos],
+                                  np.asarray(ins["KNew"][0])[:, 0])
+    np.testing.assert_array_equal(kept[:, :pos],
+                                  np.asarray(ins["Cache"][0])[:, :pos])
+    scores = reference.index_scores(
+        ins["Q"][0].reshape(B, 1, IH, ID), jnp.asarray(kept),
+        ins["W"][0].reshape(B, 1, IH))
+    mask = np.asarray(reference.choose(scores, TOPK, jnp.asarray([pos])))
+    live = min(TOPK, pos + 1)
+    assert np.asarray(outs["Live"][0]).tolist() == [live] * B
+    selected = np.asarray(outs["Selected"][0])
+    assert selected.shape == (B, TOPK) and selected.dtype == np.int32
+    for row in range(B):
+        assert sorted(selected[row, :live]) == \
+            np.flatnonzero(mask[row, 0]).tolist()
+        assert selected[row, :live].max() <= pos
+        # best first
+        taken = np.asarray(scores)[row, 0, selected[row, :live]]
+        assert (np.diff(taken) <= 0).all()
+
+
+def test_index_scores_add_up_in_float32():
+    """A bfloat16 cache gives bfloat16 operands and float32 sums: the
+    set is the one float32 arithmetic picks on the rounded operands, and
+    not the one sums rounded to bfloat16 would pick."""
+    rs = np.random.RandomState(11)
+    ins = _index_ins(rs, T - 1, jnp.bfloat16)
+    kernel = registry.get_op_info("mla_index_select").kernel
+    attrs = {"num_heads": IH, "top_k": TOPK}
+    outs = kernel(None, ins, attrs)
+    f32 = {k: [v[0].astype(jnp.float32) if k != "Position" else v[0]]
+           for k, v in ins.items()}
+    want = kernel(None, f32, attrs)
+    np.testing.assert_array_equal(np.sort(outs["Selected"][0], -1),
+                                  np.sort(want["Selected"][0], -1))
+    jaxpr = jax.make_jaxpr(lambda i: kernel(None, i, attrs)["Selected"][0])(
+        ins)
+    dots = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "dot_general"]
+    assert dots and all(
+        e.params["preferred_element_type"] == jnp.float32 for e in dots)
+    over_cache = [e for e in dots
+                  if (B, T, ID) in [v.aval.shape for v in e.invars]]
+    assert len(over_cache) == 1 and all(
+        v.aval.dtype == jnp.bfloat16 for v in over_cache[0].invars)
+    top = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "top_k"]
+    assert [e.invars[0].aval.dtype for e in top] == [jnp.float32]
+
+
+def test_index_select_refuses_what_it_cannot_do():
+    ins = _index_ins(np.random.RandomState(2), 4)
+    kernel = registry.get_op_info("mla_index_select").kernel
+    with pytest.raises(ValueError, match="top_k"):
+        kernel(None, ins, {"num_heads": IH, "top_k": T + 1})
+    with pytest.raises(ValueError, match="cache holds"):
+        kernel(None, dict(ins, KNew=[jnp.zeros((B, 1, ID + 1))]),
+               {"num_heads": IH, "top_k": TOPK})
+    assert registry.get_op_info("mla_index_select").stop_gradient_op
+
+
+# -- attention over a chosen set -------------------------------------------------
+
+def _mla_ins(rs, pos):
+    def draw(*shape):
+        return jnp.asarray(rs.randn(*shape), jnp.float32)
+
+    cache = draw(B, T, KVR + ROPE).at[:, pos:].set(0)
+    return {"QNope": [draw(B, 1, H * NOPE)], "QRope": [draw(B, 1, H * ROPE)],
+            "CNew": [draw(B, 1, KVR)], "RNew": [draw(B, 1, ROPE)],
+            "Cache": [cache], "WUk": [0.3 * draw(KVR, H * NOPE)],
+            "WUv": [0.3 * draw(KVR, H * DV)],
+            "Position": [jnp.full((B,), pos, jnp.int32)]}
+
+
+@pytest.mark.parametrize("pos", [0, 5, T - 1])
+def test_selecting_every_live_slot_is_the_op_without_a_selection(pos):
+    """`Selected` = the live slots in any order, padded with dead ones
+    past `Live`: the op without `Selected`."""
+    rs = np.random.RandomState(pos)
+    ins = _mla_ins(rs, pos)
+    kernel = registry.get_op_info("mla_cached_attention").kernel
+    want = kernel(None, ins, {"num_heads": H})
+    order = np.stack([np.concatenate([
+        rs.permutation(pos + 1), rs.permutation(np.arange(pos + 1, T))])
+        for _ in range(B)]).astype(np.int32)
+    chosen = dict(ins, Selected=[jnp.asarray(order)],
+                  Live=[jnp.full((B,), pos + 1, jnp.int32)])
+    got = kernel(None, chosen, {"num_heads": H})
+    np.testing.assert_allclose(got["Out"][0], want["Out"][0], atol=1e-5)
+    np.testing.assert_array_equal(got["CacheOut"][0], want["CacheOut"][0])
+
+
+def test_a_dead_entry_of_the_selection_is_not_attended():
+    ins = _mla_ins(np.random.RandomState(7), 9)
+    kernel = registry.get_op_info("mla_cached_attention").kernel
+    selected = jnp.asarray([[9, 2, 4, 30, 31], [0, 9, 7, 40, 41]], jnp.int32)
+    chosen = dict(ins, Selected=[selected],
+                  Live=[jnp.full((B,), 3, jnp.int32)])
+    want = kernel(None, chosen, {"num_heads": H})["Out"][0]
+    dirty = dict(chosen, Cache=[ins["Cache"][0].at[:, 30:].set(9.0)])
+    np.testing.assert_array_equal(
+        kernel(None, dirty, {"num_heads": H})["Out"][0], want)
+    # and the three it does attend are not all of the live ones
+    assert np.abs(np.asarray(want) - np.asarray(
+        kernel(None, ins, {"num_heads": H})["Out"][0])).max() > 1e-3
+
+
+def test_the_scale_is_the_attrs_where_given():
+    ins = _mla_ins(np.random.RandomState(8), 6)
+    kernel = registry.get_op_info("mla_cached_attention").kernel
+    own = kernel(None, ins, {"num_heads": H})["Out"][0]
+    same = kernel(None, ins, {"num_heads": H,
+                              "sm_scale": (NOPE + ROPE) ** -0.5})["Out"][0]
+    np.testing.assert_array_equal(own, same)
+    other = kernel(None, ins, {"num_heads": H, "sm_scale": 0.5})["Out"][0]
+    assert np.abs(np.asarray(other) - np.asarray(own)).max() > 1e-3
+
+
+def test_the_layer_wants_selected_and_live_together():
+    with fluid.program_guard(fluid.Program(), fluid.Program()):
+        def data(name, shape, dtype="float32"):
+            return fluid.layers.data(name=name, shape=shape, dtype=dtype,
+                                     append_batch_size=False)
+
+        with pytest.raises(ValueError, match="come together"):
+            fluid.layers.mla_cached_attention(
+                data("qn", [B, 1, H * NOPE]), data("qr", [B, 1, H * ROPE]),
+                data("c", [B, 1, KVR]), data("r", [B, 1, ROPE]),
+                data("cache", [B, T, KVR + ROPE]), data("pos", [B], "int64"),
+                H, DV, selected=data("sel", [B, TOPK], "int32"))
+        selected, live, kept = fluid.layers.mla_index_select(
+            data("qi", [B, 1, IH * ID]), data("wi", [B, 1, IH]),
+            data("ki", [B, 1, ID]), data("icache", [B, T, ID]),
+            data("pos2", [B], "int64"), IH, TOPK)
+        assert tuple(selected.shape) == (B, TOPK)
+        assert tuple(live.shape) == (B,)
+        assert tuple(kept.shape) == (B, T, ID)
+
+
+# -- the router ------------------------------------------------------------------
+
+def _router(attrs, u, w, bias=None):
+    ins = {"X": [u], "W": [w]}
+    if bias is not None:
+        ins["Bias"] = [bias]
+    return registry.get_op_info("moe_router").kernel(
+        None, ins, dict({"top_k": K, "scoring": "sigmoid",
+                         "norm_topk": True, "scale": 2.5}, **attrs))
+
+
+def _routing_inputs(seed, n=40):
+    rs = np.random.RandomState(seed)
+    return (jnp.asarray(rs.randn(n, D), jnp.float32),
+            jnp.asarray(rs.randn(D, E) * 0.3, jnp.float32),
+            jnp.asarray(rs.randn(E) * 0.3, jnp.float32))
+
+
+def test_the_grouped_router_agrees_with_the_reference():
+    u, w, bias = _routing_inputs(8)
+    got = _router({"n_group": GROUPS, "topk_group": KEPT}, u, w, bias)
+    weights, indices, scores = reference.route(
+        CFG, {"router": w, "router_bias": bias}, u)
+    np.testing.assert_array_equal(got["TopIdx"][0], indices)
+    np.testing.assert_allclose(
+        got["TopW"][0],
+        np.take_along_axis(np.asarray(weights), np.asarray(indices), 1),
+        rtol=1e-6)
+    np.testing.assert_allclose(got["TopW"][0].sum(-1), 2.5, rtol=1e-6)
+
+
+def test_the_bias_moves_the_choice_and_not_the_weights():
+    u, w, bias = _routing_inputs(9)
+    plain = _router({}, u, w)
+    biased = _router({}, u, w, bias)
+    moved = (np.sort(plain["TopIdx"][0], 1)
+             != np.sort(biased["TopIdx"][0], 1)).any(1)
+    assert 0 < moved.sum() < moved.size
+    s = np.asarray(jax.nn.sigmoid(biased["Logits"][0]))
+    top = np.take_along_axis(s, np.asarray(biased["TopIdx"][0]), 1)
+    # the weights are the unbiased scores of the experts chosen
+    np.testing.assert_allclose(
+        biased["TopW"][0], 2.5 * top / top.sum(-1, keepdims=True),
+        rtol=1e-6)
+    # and the choice is by score + bias
+    np.testing.assert_array_equal(
+        np.sort(biased["TopIdx"][0], 1),
+        np.sort(np.argsort(-(s + np.asarray(bias)), 1)[:, :K], 1))
+    # a bias of zeros chooses as no bias does
+    zero = _router({}, u, w, jnp.zeros((E,)))
+    np.testing.assert_array_equal(zero["TopIdx"][0], plain["TopIdx"][0])
+    np.testing.assert_array_equal(zero["TopW"][0], plain["TopW"][0])
+
+
+def test_an_expert_outside_the_kept_groups_is_never_chosen():
+    """Expert 0's score is the largest of all at every token, its group
+    mate's the smallest: the group's two-largest sum loses to the three
+    other groups', and with 2 of 4 groups kept expert 0 is out."""
+    n = 16
+    rs = np.random.RandomState(10)
+    logits = rs.uniform(0.0, 1.0, (n, E)).astype("float32")
+    logits[:, 0], logits[:, 1] = 3.0, -9.0
+    logits[:, 2:] += 1.5
+    u = jnp.asarray(np.eye(n, D, dtype="float32"))
+    w = jnp.asarray(np.concatenate(
+        [logits, np.zeros((D - n, E), "float32")]))
+    got = _router({"n_group": GROUPS, "topk_group": KEPT}, u, w)
+    assert np.asarray(got["Logits"][0]).argmax(1).tolist() == [0] * n
+    assert not (np.asarray(got["TopIdx"][0]) < 2).any()
+    free = _router({}, u, w)
+    assert (np.asarray(free["TopIdx"][0])[:, 0] == 0).all()
+    # every chosen pair lies inside two groups at most, by construction
+    assert got["TopIdx"][0].shape == (n, K)
+
+
+def test_the_grouped_router_refuses_what_it_cannot_do():
+    u, w, bias = _routing_inputs(3, 4)
+    with pytest.raises(ValueError, match="groups"):
+        _router({"n_group": 3, "topk_group": 2}, u, w)
+    with pytest.raises(ValueError, match="sigmoid"):
+        _router({"scoring": "softmax", "n_group": 4, "topk_group": 2}, u, w)
+    with pytest.raises(ValueError, match="sigmoid"):
+        _router({"scoring": "softmax"}, u, w, bias)
+
+
+# -- what the ops lowered to before this ------------------------------------------
+
+@pytest.fixture(scope="module")
+def lowerings():
+    with open(parent_lowerings.RECORDING) as f:
+        return json.load(f), parent_lowerings.lowerings()
+
+
+@pytest.mark.parametrize("what", [
+    "rope", "moe_router softmax", "moe_router sigmoid",
+    "mla_cached_attention float32", "mla_cached_attention bfloat16",
+    "program"])
+def test_without_the_new_inputs_everything_lowers_as_the_parent(
+        lowerings, what):
+    """The jaxpr of each op without its new inputs and attrs, and the
+    Program the builder makes from pangu's arguments, op for op, against
+    the recording made on the parent commit."""
+    recorded, now = lowerings
+    assert now[what] == recorded[what]
+
+
+# -- YaRN ---------------------------------------------------------------------------
+
+def test_yarn_at_the_published_numbers():
+    """rope_scaling of DeepSeek-V3.2: factor 40 over 4096 original
+    positions, beta_fast 32, beta_slow 1, theta 1e4, 64 rotary values:
+    the ramp runs from pair 10 to pair 23."""
+    f = yarn_inv_freq(64, 1e4, 40, 4096, 32, 1)
+    assert len(f) == 32
+    plain = [1e4 ** (-2 * i / 64) for i in range(32)]
+    # pairs up to 10 keep their frequency, from 23 on they are slowed 40x
+    np.testing.assert_allclose(f[:11], plain[:11], rtol=1e-12)
+    np.testing.assert_allclose(f[23:], [p / 40 for p in plain[23:]],
+                               rtol=1e-12)
+    assert f[10] == plain[10] and f[11] < plain[11]
+    # inside the ramp: ramp_i = (i - 10) / 13
+    for i in (11, 16, 22):
+        ramp = (i - 10) / 13
+        np.testing.assert_allclose(
+            f[i], plain[i] / 40 * ramp + plain[i] * (1 - ramp), rtol=1e-12)
+    np.testing.assert_allclose(f[16], 1e4 ** -0.5 * (6 / 13 / 40 + 7 / 13),
+                               rtol=1e-12)
+    assert round(yarn_mscale(40), 4) == 1.3689
+    assert round(yarn_mscale(40) ** 2, 4) == 1.8739
+    assert yarn_mscale(1) == 1.0
+    # the reference makes the same from the configuration's own keys
+    cfg = dict(CFG, qk_rope_head_dim=64, qk_nope_head_dim=128,
+               rope_scaling=dict(CFG["rope_scaling"],
+                                 original_max_position_embeddings=4096))
+    np.testing.assert_allclose(reference.yarn_inv_freq(cfg), f, rtol=1e-6)
+    np.testing.assert_allclose(reference.softmax_scale(cfg),
+                               192 ** -0.5 * 1.8739, rtol=1e-4)
+
+
+def test_rope_takes_the_frequencies_and_a_rotary_width():
+    rs = np.random.RandomState(4)
+    x = jnp.asarray(rs.randn(B, 3, IH * ID), jnp.float32)
+    pos = jnp.asarray(rs.randint(0, 200, (B, 3)))
+    kernel = registry.get_op_info("rope").kernel
+    freq = yarn_inv_freq(ROPE, 1e4, 40, 16, 32, 1)
+    got = kernel(None, {"X": [x], "Positions": [pos]},
+                 {"num_heads": IH, "inv_freq": freq,
+                  "rotary_dim": ROPE})["Out"][0]
+    want = jnp.stack([reference.rope(
+        x[b:b + 1].reshape(1, 3, IH, ID), pos[b],
+        jnp.asarray(freq, jnp.float32)) for b in range(B)])
+    np.testing.assert_allclose(got, want.reshape(B, 3, IH * ID), atol=1e-5)
+    # the part past the rotary width is handed on as it is
+    np.testing.assert_array_equal(
+        np.asarray(got).reshape(B, 3, IH, ID)[..., ROPE:],
+        np.asarray(x).reshape(B, 3, IH, ID)[..., ROPE:])
+    # theta's powers, given as a list, are the op without a list
+    plain = kernel(None, {"X": [x], "Positions": [pos]},
+                   {"num_heads": IH, "theta": 1e4})["Out"][0]
+    listed = kernel(None, {"X": [x], "Positions": [pos]},
+                    {"num_heads": IH, "inv_freq": [
+                        1e4 ** (-i / (ID // 2)) for i in range(ID // 2)]})
+    np.testing.assert_allclose(listed["Out"][0], plain, atol=1e-5)
+    with pytest.raises(ValueError, match="inverse frequencies"):
+        kernel(None, {"X": [x], "Positions": [pos]},
+               {"num_heads": IH, "inv_freq": freq})
+    with pytest.raises(ValueError, match="rotary_dim"):
+        kernel(None, {"X": [x], "Positions": [pos]},
+               {"num_heads": IH, "rotary_dim": ID + 2})
+
+
+# -- the shares add up under grouped routing ---------------------------------------
+
+def _expert_weights(rs, experts=E):
+    w_gate, w_up = (jnp.asarray(rs.randn(experts, D, FE) * 0.2, jnp.float32)
+                    for _ in range(2))
+    return w_gate, w_up, jnp.asarray(rs.randn(experts, FE, D) * 0.2,
+                                     jnp.float32)
+
+
+@pytest.mark.parametrize("ranges", [[(i, 1) for i in range(E)],
+                                    [(0, 4), (4, 4)],
+                                    [(0, 2), (2, 4), (6, 2)]],
+                         ids=["8 shares", "2 shares", "3 shares"])
+def test_the_shares_add_up_to_the_uncut_layer_under_grouped_routing(ranges):
+    """model-configs section 4's share test: the held parts of all the
+    shares, the shared expert counted once, add up to the uncut
+    reference's expert layer, routed by score + bias inside the kept
+    groups."""
+    rs = np.random.RandomState(len(ranges))
+    n = 24
+    u = jnp.asarray(rs.randn(n, D), jnp.float32)
+    weights = _expert_weights(rs)
+    block = {"router": jnp.asarray(rs.randn(D, E) * 0.3, jnp.float32),
+             "router_bias": jnp.asarray(rs.randn(E) * 0.3, jnp.float32),
+             "w_gate": weights[0], "w_up": weights[1], "w_down": weights[2],
+             "shared_in": jnp.asarray(rs.randn(D, 2 * FE) * 0.2, jnp.float32),
+             "shared_out": jnp.asarray(rs.randn(FE, D) * 0.2, jnp.float32)}
+    want, indices = reference.feed_forward(CFG, block, u)
+    routed = _router({"n_group": GROUPS, "topk_group": KEPT}, u,
+                     block["router"], block["router_bias"])
+    np.testing.assert_array_equal(routed["TopIdx"][0], indices)
+    total = reference.gated(u, block["shared_in"], block["shared_out"])
+    rows = 0
+    for first, count in ranges:
+        part = registry.get_op_info("moe_experts").kernel(
+            None, {"X": [u], "TopW": routed["TopW"],
+                   "TopIdx": routed["TopIdx"],
+                   "WGate": [weights[0][first:first + count]],
+                   "WUp": [weights[1][first:first + count]],
+                   "WDown": [weights[2][first:first + count]]},
+            {"first_expert": first, "scored": E})
+        rows += int(np.asarray(part["Counts"][0]).sum())
+        total = total + part["Out"][0]
+        cut = dict(block, **{w: block[w][first:first + count]
+                             for w in ("w_gate", "w_up", "w_down")})
+        np.testing.assert_allclose(
+            part["Out"][0],
+            reference.feed_forward(CFG, cut, u, first, shared=False)[0],
+            atol=2e-5)
+    assert rows == n * K
+    np.testing.assert_allclose(total, want, atol=3e-5)
+
+
+# -- the Program and its counters ---------------------------------------------------
+
+def test_parameter_names_follow_the_options(built):
+    block = built["main"].global_block()
+    made = {p.name: tuple(p.shape) for p in block.all_parameters()}
+    assert set(made) == set(jax.tree_util.tree_leaves(NAMES))
+    b0, b1 = NAMES["blocks"]
+    for absent in ("post_attn_norm", "post_mlp_norm"):
+        assert absent not in b0 and absent not in b1
+    assert "router_bias" in b1 and "router_bias" not in b0
+    assert made[b1["router_bias"]] == (E,)
+    assert made[b0["w_iq"]] == (QR, IH * ID)
+    assert made[b0["w_ik"]] == (D, ID)
+    assert made[b0["ik_norm"]] == (ID,) == made[b0["ik_norm_b"]]
+    assert made[b0["w_iw"]] == (D, IH)
+    # pangu's names and their order are what they were
+    assert list(latent_moe_param_names(2, 1)["blocks"][1]) == [
+        "input_norm", "w_dq", "q_norm", "w_uq_nope", "w_uq_rope", "w_dkv",
+        "kv_norm", "w_uk", "w_uv", "wo", "post_attn_norm", "pre_mlp_norm",
+        "shared_in", "shared_out", "router", "w_gate", "w_up", "w_down",
+        "post_mlp_norm"]
+    ops = [od.type for od in block.desc.ops]
+    assert ops.count("mla_index_select") == L == \
+        ops.count("mla_cached_attention")
+    # two norms a layer, two inside the attention, one at the end
+    assert ops.count("rms_norm") == 4 * L + 1
+    assert ops.count("layer_norm") == L
+    assert [f for f, _ in built["pairs"]] == [
+        "latent_cache_0", "index_cache_0", "latent_cache_1",
+        "index_cache_1", "pos"]
+    assert len(built["parts"]["selected"]) == L == \
+        len(built["parts"]["attn_out"])
+
+
+def test_counters_say_what_was_lowered(built):
+    assert not [k for k in built["at_build"] if k.startswith((
+        "mla_index_select_lowerings_total",
+        "moe_grouped_router_lowerings_total"))]
+    decoder = built["decoder"]
+    before = telemetry.snapshot()
+    jax.make_jaxpr(decoder._step_fn(decoder._params))(
+        _empty(), jnp.asarray(built["tokens"][:, 0]))
+    lowered = telemetry.snapshot_delta(before)
+    # one count an op instance a traced step holds
+    assert lowered[
+        "mla_index_select_lowerings_total{cache_dtype=float32,dim=%d,"
+        "heads=%d,top_k=%d}" % (ID, IH, TOPK)] == L
+    assert lowered[
+        "mla_cached_attention_lowerings_total{cache_dtype=float32,"
+        "heads=%d,latent=%d,rope=%d,selected=%d}"
+        % (H, KVR, ROPE, TOPK)] == L
+    assert lowered[
+        "moe_grouped_router_lowerings_total{experts=%d,groups=%d,kept=%d,"
+        "top_k=%d}" % (E, GROUPS, KEPT, K)] == L - DENSE
+
+
+def test_obs_dump_lists_the_new_counters(built, tmp_path):
+    from paddle_tpu.tools import obs_dump
+
+    decoder = built["decoder"]
+    jax.make_jaxpr(decoder._step_fn(decoder._params))(
+        _empty(), jnp.asarray(built["tokens"][:, 0]))
+    path = str(tmp_path / "metrics.jsonl")
+    assert obs_dump.main(["--metrics-out", path]) == 0
+    with open(path) as f:
+        text = f.read()
+    for name in ("mla_index_select_lowerings_total",
+                 "moe_grouped_router_lowerings_total", "selected"):
+        assert name in text

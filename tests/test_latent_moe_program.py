@@ -460,7 +460,7 @@ def test_counters_say_what_was_lowered(built):
         _empty(), jnp.asarray(built["tokens"][:, 0]))
     lowered = telemetry.snapshot_delta(before)
     mla = "mla_cached_attention_lowerings_total{cache_dtype=float32," \
-        "heads=%d,latent=%d,rope=%d}" % (H, KVR, ROPE)
+        "heads=%d,latent=%d,rope=%d,selected=all}" % (H, KVR, ROPE)
     share = "moe_share_lowerings_total{held=%d,scored=%d,top_k=%d}" \
         % (HELD[1], E, K)
     # one count an op instance a traced step holds
