@@ -40,6 +40,7 @@ __all__ = ["on_executor_run", "on_jit_trace",
            "on_moe_gmm_lowering", "on_moe_share_lowering",
            "on_moe_grouped_router_lowering",
            "on_mla_cached_attention_lowering",
+           "on_mla_decode_lowering",
            "on_mla_index_select_lowering", "on_cached_attention_lowering",
            "on_prefill_lowering", "on_ssd_lowering",
            "on_causal_conv1d_lowering", "on_shared_parameter_uses",
@@ -210,6 +211,20 @@ def on_mla_cached_attention_lowering(heads, latent, rope, cache_dtype,
                                "selected")) \
           .labels(heads=heads, latent=latent, rope=rope,
                   cache_dtype=str(cache_dtype), selected=selected).inc()
+
+
+def on_mla_decode_lowering(path, block_k):
+    """Which way an `mla_cached_attention` op traced into a program
+    takes over its cache: "kernel", the walk of the live slots in blocks
+    of `block_k` (kernels/mla_decode.py), or "plain", the contractions
+    over the whole extent or a gathered set (`block_k` 0).  One count
+    per op instance a lowered program holds."""
+    _reg().counter("mla_decode_lowerings_total",
+                   "latent-attention decode steps lowered, by path (the "
+                   "kernel over the live slots, or the plain products) "
+                   "and the kernel's block of slots",
+                   labelnames=("path", "block_k")) \
+          .labels(path=path, block_k=block_k).inc()
 
 
 def on_mla_index_select_lowering(heads, dim, top_k, cache_dtype):

@@ -458,6 +458,17 @@ def mla_cached_attention_op(ctx, ins, attrs):
         "all" if selected is None else selected.shape[-1])
     dtype = q_nope.dtype
     f32 = jnp.float32
+    # the walk of the live slots (kernels/mla_decode.py) where what the
+    # op sees of its inputs fits it, the plain path otherwise
+    blocks = None
+    if selected is None:
+        from ..kernels import mla_decode
+        if mla_decode.fits(q_nope.shape[1], positions, latent):
+            blocks = mla_decode.choose_blocks(
+                batch, heads, positions, width, latent,
+                jnp.dtype(dtype).itemsize)
+    telemetry.on_mla_decode_lowering(
+        "kernel" if blocks else "plain", blocks[0] if blocks else 0)
 
     entry = jnp.concatenate([c_new, r_new], axis=-1).reshape(batch, 1, width)
     cache = jax.lax.dynamic_update_slice_in_dim(
@@ -479,18 +490,23 @@ def mla_cached_attention_op(ctx, ins, attrs):
         q = jnp.concatenate(
             [q_lat, q_rope.reshape(batch, heads, rope_dim)], axis=-1)
     with jax.named_scope("mla_scores"):
-        s = jnp.einsum("bhw,btw->bht", q, live,
-                       preferred_element_type=f32) * sm_scale
-        if selected is None:
-            valid = jnp.arange(positions) <= pos
+        if blocks:
+            o_lat = mla_decode.mla_decode(q, live, pos, sm_scale, latent,
+                                          blocks)
         else:
-            valid = jnp.arange(selected.shape[-1]) \
-                < jnp.reshape(ins["Live"][0], (-1,))[0]
-        p = jax.nn.softmax(jnp.where(valid[None, None, :], s, -1e30),
-                           axis=-1)
+            s = jnp.einsum("bhw,btw->bht", q, live,
+                           preferred_element_type=f32) * sm_scale
+            if selected is None:
+                valid = jnp.arange(positions) <= pos
+            else:
+                valid = jnp.arange(selected.shape[-1]) \
+                    < jnp.reshape(ins["Live"][0], (-1,))[0]
+            p = jax.nn.softmax(jnp.where(valid[None, None, :], s, -1e30),
+                               axis=-1)
     with jax.named_scope("mla_values"):
-        o_lat = jnp.einsum("bht,btw->bhw", p.astype(dtype), live,
-                           preferred_element_type=f32)[..., :latent]
+        if not blocks:
+            o_lat = jnp.einsum("bht,btw->bhw", p.astype(dtype), live,
+                               preferred_element_type=f32)[..., :latent]
         out = jnp.einsum(
             "bhc,chd->bhd", o_lat.astype(dtype),
             w_uv.reshape(latent, heads, -1).astype(dtype),

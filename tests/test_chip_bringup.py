@@ -205,6 +205,39 @@ def test_the_scan_op_and_its_gradient_lower_one_kernel_each_for_tpu():
     assert module.count("tpu_custom_call") == 2
 
 
+def test_the_latent_decode_kernel_lowers_for_tpu():
+    """`mla_cached_attention` at pangu-decode-ep16's shapes (256 rows,
+    128 heads, a 1024-slot bfloat16 cache of 512 + 64 values) lowered
+    for the TPU from this CPU host: one Mosaic kernel, named with the
+    block of slots chosen from the shapes; with a chosen set the same
+    op holds none."""
+    from paddle_tpu.ops import registry
+
+    kernel = registry.get_op_info("mla_cached_attention").kernel
+    b, h, t, c, r, d = 256, 128, 1024, 512, 64, 128
+    bf16 = jnp.bfloat16
+    ins = {"QNope": [jax.ShapeDtypeStruct((b, 1, h * d), bf16)],
+           "QRope": [jax.ShapeDtypeStruct((b, 1, h * r), bf16)],
+           "CNew": [jax.ShapeDtypeStruct((b, 1, c), bf16)],
+           "RNew": [jax.ShapeDtypeStruct((b, 1, r), bf16)],
+           "Cache": [jax.ShapeDtypeStruct((b, t, c + r), bf16)],
+           "WUk": [jax.ShapeDtypeStruct((c, h * d), bf16)],
+           "WUv": [jax.ShapeDtypeStruct((c, h * d), bf16)],
+           "Position": [jax.ShapeDtypeStruct((b,), jnp.int32)]}
+
+    def step(ins):
+        return kernel(None, ins, {"num_heads": h})
+
+    module = jax.export.export(jax.jit(step), platforms=["tpu"])(
+        ins).mlir_module()
+    assert module.count("tpu_custom_call") == 1
+    assert 'kernel_name = "mla_decode_k512"' in module
+    chosen = dict(ins, Selected=[jax.ShapeDtypeStruct((b, 256), jnp.int32)],
+                  Live=[jax.ShapeDtypeStruct((b,), jnp.int32)])
+    assert "tpu_custom_call" not in jax.export.export(
+        jax.jit(step), platforms=["tpu"])(chosen).mlir_module()
+
+
 def test_flash_attention_refuses_a_ragged_block():
     """A sequence its block does not divide raises with the shape in the
     message; the block no longer shrinks toward 1 without saying so."""

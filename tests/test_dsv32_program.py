@@ -707,6 +707,9 @@ def test_counters_say_what_was_lowered(built):
         "mla_cached_attention_lowerings_total{cache_dtype=float32,"
         "heads=%d,latent=%d,rope=%d,selected=%d}"
         % (H, KVR, ROPE, TOPK)] == L
+    # a chosen set keeps the plain products, whatever the shapes
+    assert lowered["mla_decode_lowerings_total{block_k=0,path=plain}"] == L
+    assert not [k for k in lowered if "path=kernel" in k]
     assert lowered[
         "moe_grouped_router_lowerings_total{experts=%d,groups=%d,kept=%d,"
         "top_k=%d}" % (E, GROUPS, KEPT, K)] == L - DENSE

@@ -181,22 +181,26 @@ def _mla_ins(rs, dtype=jnp.float32, cache_dtype=jnp.float32, pos=5):
 
 
 def _unabsorbed(ins, pos):
-    """Attention over keys [c W_uk | r] and values c W_uv, made whole."""
+    """Attention over keys [c W_uk | r] and values c W_uv, made whole,
+    at the inputs' own sizes (H heads)."""
     f32 = jnp.float32
+    b, kvr, rope = ins["CNew"][0].shape[0], ins["CNew"][0].shape[-1], \
+        ins["RNew"][0].shape[-1]
+    nope = ins["QNope"][0].shape[-1] // H
     cache = ins["Cache"][0].astype(f32)
     entry = jnp.concatenate([ins["CNew"][0], ins["RNew"][0]], -1).astype(f32)
     cache = cache.at[:, pos].set(entry[:, 0])[:, :pos + 1]
-    c, r = cache[..., :KVR], cache[..., KVR:]
-    k_nope = (c @ ins["WUk"][0].astype(f32)).reshape(B, pos + 1, H, NOPE)
-    v = (c @ ins["WUv"][0].astype(f32)).reshape(B, pos + 1, H, DV)
+    c, r = cache[..., :kvr], cache[..., kvr:]
+    k_nope = (c @ ins["WUk"][0].astype(f32)).reshape(b, pos + 1, H, nope)
+    v = (c @ ins["WUv"][0].astype(f32)).reshape(b, pos + 1, H, -1)
     k = jnp.concatenate(
-        [k_nope, jnp.broadcast_to(r[:, :, None], (B, pos + 1, H, ROPE))], -1)
+        [k_nope, jnp.broadcast_to(r[:, :, None], (b, pos + 1, H, rope))], -1)
     q = jnp.concatenate(
-        [ins["QNope"][0].astype(f32).reshape(B, H, NOPE),
-         ins["QRope"][0].astype(f32).reshape(B, H, ROPE)], -1)
-    s = jnp.einsum("bhd,bthd->bht", q, k) / np.sqrt(NOPE + ROPE)
+        [ins["QNope"][0].astype(f32).reshape(b, H, nope),
+         ins["QRope"][0].astype(f32).reshape(b, H, rope)], -1)
+    s = jnp.einsum("bhd,bthd->bht", q, k) / np.sqrt(nope + rope)
     return jnp.einsum("bht,bthd->bhd", jax.nn.softmax(s, -1),
-                      v).reshape(B, 1, H * DV)
+                      v).reshape(b, 1, -1)
 
 
 @pytest.mark.parametrize("pos", [0, 5, T - 1])
@@ -247,6 +251,153 @@ def test_a_cache_of_another_width_is_refused():
     with pytest.raises(ValueError, match="cache holds"):
         registry.get_op_info("mla_cached_attention").kernel(
             None, ins, {"num_heads": H})
+
+
+# -- the walk of the live slots (kernels/mla_decode.py) -----------------------
+# a shape the kernel takes, small: 2 rows, 4 heads, three blocks of 128
+# slots, latent 128 + rope 64, under the Pallas interpreter
+
+WB, WP, WL, WR, WBK = 2, 384, 128, 64, 128
+
+
+def _walk_ins(rs, dtype, pos, past=0.0):
+    """The op's inputs at the walked shape; slots past `pos` hold `past`."""
+    def draw(*shape):
+        return jnp.asarray(rs.randn(*shape), dtype)
+
+    cache = draw(WB, WP, WL + WR).at[:, pos + 1:].set(past)
+    return {"QNope": [draw(WB, 1, H * NOPE)], "QRope": [draw(WB, 1, H * WR)],
+            "CNew": [draw(WB, 1, WL)], "RNew": [draw(WB, 1, WR)],
+            "Cache": [cache], "WUk": [0.1 * draw(WL, H * NOPE)],
+            "WUv": [0.1 * draw(WL, H * DV)],
+            "Position": [jnp.full((WB,), pos, jnp.int32)]}
+
+
+def _mla_paths(ins):
+    """{path: count} of `mla_decode_lowerings_total` and the op's outputs
+    for one trace of the op over `ins`."""
+    before = telemetry.snapshot()
+    outs = registry.get_op_info("mla_cached_attention").kernel(
+        None, ins, {"num_heads": H})
+    return {k[len("mla_decode_lowerings_total"):]: v
+            for k, v in telemetry.snapshot_delta(before).items()
+            if k.startswith("mla_decode_lowerings_total")}, outs
+
+
+@pytest.mark.parametrize("dtype,atol", [(jnp.float32, 2e-5),
+                                        (jnp.bfloat16, 6e-2)])
+@pytest.mark.parametrize("pos", [0, WBK - 1, WBK, WP - 1])
+def test_the_walk_of_live_slots_is_the_plain_path(pos, dtype, atol,
+                                                  monkeypatch):
+    """The kernel against the op's plain path on the same inputs (the
+    test says the shape does not fit; the op has no switch), at the
+    first slot, the last of a block, the first of the next and the last
+    of the cache."""
+    from paddle_tpu.kernels import mla_decode
+
+    ins = _walk_ins(np.random.RandomState(pos), dtype, pos)
+    paths, walked = _mla_paths(ins)
+    assert paths == {"{block_k=%d,path=kernel}" % WBK: 1}
+    monkeypatch.setattr(mla_decode, "fits", lambda *shape: False)
+    paths, plain = _mla_paths(ins)
+    assert paths == {"{block_k=0,path=plain}": 1}
+    assert walked["Out"][0].dtype == plain["Out"][0].dtype == dtype
+    np.testing.assert_allclose(
+        np.asarray(walked["Out"][0], np.float32),
+        np.asarray(plain["Out"][0], np.float32), atol=atol)
+    np.testing.assert_array_equal(
+        np.asarray(walked["CacheOut"][0], np.float32),
+        np.asarray(plain["CacheOut"][0], np.float32))
+
+
+@pytest.mark.parametrize("pos", [0, 5, WBK - 1, WBK, 2 * WBK + 9])
+def test_the_walk_reads_nothing_past_the_position(pos):
+    """NaN in every slot past the position, those of the block the
+    position falls in and whole dead blocks alike, reaches no sum: a
+    dead block is not folded, a dead slot's score is masked before the
+    maximum and its values are zeroed before the product."""
+    rs = np.random.RandomState(11)
+    clean = _walk_ins(rs, jnp.float32, pos)
+    dirty = dict(clean, Cache=[clean["Cache"][0].at[:, pos + 1:].set(
+        jnp.nan)])
+    paths, got = _mla_paths(dirty)
+    assert list(paths) == ["{block_k=%d,path=kernel}" % WBK]
+    np.testing.assert_array_equal(got["Out"][0],
+                                  _mla_paths(clean)[1]["Out"][0])
+
+
+def test_the_walk_is_attention_over_the_heads_keys():
+    """The kernel path against attention made whole, as the plain path
+    is held above."""
+    pos = WBK + 3
+    ins = _walk_ins(np.random.RandomState(4), jnp.float32, pos)
+    np.testing.assert_allclose(_mla_paths(ins)[1]["Out"][0],
+                               _unabsorbed(ins, pos), atol=5e-5)
+
+
+@pytest.mark.parametrize("why,change", [
+    ("a chosen set", {"Selected": [jnp.zeros((WB, 4), jnp.int32)],
+                      "Live": [jnp.full((WB,), 4, jnp.int32)]}),
+    ("6 positions", {"Cache": [jnp.zeros((WB, 6, WL + WR))],
+                     "Position": [jnp.full((WB,), 3, jnp.int32)]}),
+    ("a latent of 8", {"Cache": [jnp.zeros((WB, WP, 8 + WR))],
+                       "CNew": [jnp.zeros((WB, 1, 8))],
+                       "WUk": [jnp.zeros((8, H * NOPE))],
+                       "WUv": [jnp.zeros((8, H * DV))]}),
+])
+def test_what_the_walk_does_not_take_is_the_plain_path(why, change):
+    """The op chooses by what it sees in its inputs, and the counter's
+    `path` says which way it went; no Pallas call is traced."""
+    ins = dict(_walk_ins(np.random.RandomState(1), jnp.float32, 3),
+               **change)
+    kernel = registry.get_op_info("mla_cached_attention").kernel
+    before = telemetry.snapshot()
+    jaxpr = jax.make_jaxpr(
+        lambda i: kernel(None, i, {"num_heads": H})["Out"][0])(ins)
+    lowered = telemetry.snapshot_delta(before)
+    assert lowered["mla_decode_lowerings_total{block_k=0,path=plain}"] == 1
+    assert "pallas_call" not in str(jaxpr) and "platform_index" not in \
+        str(jaxpr), why
+
+
+@pytest.mark.parametrize("blocks", [(128, 1), (128, 2), (384, 2)])
+def test_the_walk_gives_the_same_at_any_blocks(blocks):
+    """One row a grid step or two, three blocks of slots or one: the
+    same sums, up to the order the blocks are folded in."""
+    from paddle_tpu.kernels import mla_decode
+
+    rs = np.random.RandomState(5)
+    q = jnp.asarray(rs.randn(WB, H, WL + WR), jnp.float32)
+    cache = jnp.asarray(rs.randn(WB, WP, WL + WR), jnp.float32)
+    for pos in (7, WBK, WP - 1):
+        s = jnp.einsum("bhw,btw->bht", q, cache[:, :pos + 1]) * 0.1
+        want = jnp.einsum("bht,btw->bhw", jax.nn.softmax(s, -1),
+                          cache[:, :pos + 1, :WL])
+        got = mla_decode.mla_decode(q, cache, jnp.int32(pos), 0.1, WL,
+                                    blocks)
+        np.testing.assert_allclose(got, want, atol=2e-5)
+
+
+def test_the_walks_blocks_are_chosen_from_the_shapes():
+    """pangu-decode-ep16's step (256 rows, 128 heads, 1024 slots of
+    512 + 64 bfloat16 values) walks blocks of 512 slots, four rows a
+    grid step; a cache that 512 does not tile takes the next block
+    down, a batch that 4 does not tile fewer rows; a shape nothing
+    tiles is the plain path's."""
+    from paddle_tpu.kernels import mla_decode
+
+    assert mla_decode.choose_blocks(256, 128, 1024, 576, 512, 2) == (512, 4)
+    assert mla_decode.choose_blocks(256, 128, 768, 576, 512, 2) == (256, 4)
+    assert mla_decode.choose_blocks(6, 128, 1024, 576, 512, 2) == (512, 2)
+    assert mla_decode.choose_blocks(WB, 4, WP, WL + WR, WL, 4) == (WBK, 2)
+    assert mla_decode.choose_blocks(3, 4, WP, WL + WR, WL, 4) == (WBK, 1)
+    assert mla_decode.fits(1, 1024, 512) and mla_decode.fits(1, 128, 128)
+    assert not mla_decode.fits(2, 1024, 512)
+    assert not mla_decode.fits(1, 1000, 512)
+    assert not mla_decode.fits(1, 1024, 320)
+    with pytest.raises(ValueError, match="no step the kernel takes"):
+        mla_decode.mla_decode(jnp.zeros((2, 4, 24)), jnp.zeros((2, 6, 24)),
+                              0, 1.0, 16)
 
 
 # -- (b) the shares add up ------------------------------------------------------
@@ -465,6 +616,8 @@ def test_counters_say_what_was_lowered(built):
         % (HELD[1], E, K)
     # one count an op instance a traced step holds
     assert lowered[mla] == L
+    # a latent of 16 is nothing the walk of live slots takes
+    assert lowered["mla_decode_lowerings_total{block_k=0,path=plain}"] == L
     assert lowered[share] == L - DENSE
     assert lowered["moe_lowerings_total{experts=%d,top_k=%d}"
                    % (HELD[1], K)] == L - DENSE
