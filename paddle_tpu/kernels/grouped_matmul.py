@@ -18,9 +18,19 @@ group keep its weight block (gmm, gmm_dx) or its output block (gmm_dw)
 in VMEM, and consecutive visits of one tile keep that tile.  Visits
 past the end of the list name the last real one again and do nothing.
 
+Which groups a product visits is what it has to write.  gmm and gmm_dx
+write rows, and an empty group owns none: they walk the non-empty groups
+alone, so no block of a weight nobody chose is fetched (an expert layer
+that holds 16 experts for 8 assignments reads those that got one).
+Rows of no group (`sum(counts) < M`: the rows past the last group's, and
+every row where all counts are zero, when the list is empty and no visit
+does anything) are not written: they hold whatever was there.  gmm_dw
+writes a block a group and gives an empty group one visit (of the tile
+its offset lies in, where it owns no row), which writes its zeros.
+
 Products are in the operands' type (bfloat16 under AMP) with float32
 accumulation; gmm_dw adds up in float32 and returns float32, the
-master weights' type.  An empty group's dw is written as zeros.
+master weights' type.
 
 Lowered for the TPU these are Mosaic kernels named
 `moe_gmm_{fwd,dx,dw}_m<block_m>_n<block_n>_k<block_k>`; lowered for any
@@ -83,26 +93,34 @@ def choose_blocks(m, k, n, itemsize, kernel):
     return (bm, bc, k) if kernel == "fwd" else (bm, n, bc)
 
 
-def visits(counts, m, block_m):
+def visits(counts, m, block_m, empty_groups):
     """The (group, tile) visits in row order, from the group sizes, as
     int32 arrays for the scalar prefetch: `group[v]`, `tile[v]` for
     `v < length`, padded to the static `m / block_m + E - 1` by naming
     the last visit again; `offsets[E + 1]`, the groups' first rows; and
-    `length[1]`.  An empty group gets one visit (of the tile its offset
-    lies in, where it owns no row), so that gmm_dw writes its zeros."""
+    `length[1]`.  With `empty_groups` (gmm_dw) an empty group gets one
+    visit, of the tile its offset lies in, where it owns no row, so that
+    the kernel writes its zeros; without (gmm, gmm_dx) it gets none.
+    Where no group is empty the two lists are the same.  Where every
+    group is and none is visited, `length` is 0 and every entry names
+    the last group and tile 0: a block a kernel may fetch and must not
+    write."""
     e = counts.shape[0]
     tiles_m = m // block_m
     counts = counts.astype(jnp.int32)
     ends = jnp.cumsum(counts)
     starts = ends - counts
     first = jnp.minimum(starts // block_m, tiles_m - 1)
-    last = jnp.where(counts > 0, (ends - 1) // block_m, first)
-    per_group = last - first + 1
+    per_group = jnp.where(counts > 0, (ends - 1) // block_m - first + 1,
+                          1 if empty_groups else 0)
     upto = jnp.cumsum(per_group)
     length = upto[-1]
     most = tiles_m + e - 1
-    v = jnp.minimum(jnp.arange(most, dtype=jnp.int32), length - 1)
-    group = jnp.searchsorted(upto, v, side="right").astype(jnp.int32)
+    v = jnp.minimum(jnp.arange(most, dtype=jnp.int32),
+                    jnp.maximum(length - 1, 0))
+    # in an empty list no group reaches past v = 0: the last one is named
+    group = jnp.minimum(jnp.searchsorted(upto, v, side="right"),
+                        e - 1).astype(jnp.int32)
     tile = first[group] + v - (upto - per_group)[group]
     offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32), ends])
     return group, tile, offsets, length.reshape(1)
@@ -173,7 +191,7 @@ def _rows_call(kernel, blocks, x, w, counts):
     bm, bn, bk = blocks
     bc = bn if fwd else bk
     _check(m, bm, "moe_gmm_" + kernel)
-    group, tile, offsets, length = visits(counts, m, bm)
+    group, tile, offsets, length = visits(counts, m, bm, empty_groups=False)
     if fwd:
         w_spec = pl.BlockSpec((None, k, bc),
                               lambda j, v, g, t, o, l: (g[v], 0, j))
@@ -206,7 +224,7 @@ def _dw_call(blocks, x, dy, counts):
     e = counts.shape[0]
     bm, bn, bk = blocks
     _check(m, bm, "moe_gmm_dw")
-    group, tile, offsets, length = visits(counts, m, bm)
+    group, tile, offsets, length = visits(counts, m, bm, empty_groups=True)
     return pl.pallas_call(
         functools.partial(_dw_kernel, bm=bm),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -265,7 +283,9 @@ def _grouped(kernel, plain, a, b, counts):
     m = a.shape[0]
     k, n = (a.shape[1], b.shape[1]) if kernel == "dw" else b.shape[1:]
     blocks = choose_blocks(m, k, n, a.dtype.itemsize, kernel)
-    telemetry.on_moe_gmm_lowering(kernel, *blocks)
+    telemetry.on_moe_gmm_lowering(
+        kernel, *blocks,
+        empty_groups="visited" if kernel == "dw" else "skipped")
     call = (functools.partial(_dw_call, blocks) if kernel == "dw"
             else functools.partial(_rows_call, kernel, blocks))
     return lax.platform_dependent(a, b, counts, tpu=call, default=plain)

@@ -173,16 +173,20 @@ def on_moe_lowering(experts, top_k):
           .labels(experts=experts, top_k=top_k).inc()
 
 
-def on_moe_gmm_lowering(kernel, block_m, block_n, block_k):
+def on_moe_gmm_lowering(kernel, block_m, block_n, block_k, empty_groups):
     """One of the grouped-product kernels ("fwd", "dx", "dw":
     kernels/grouped_matmul.py) was traced into a program, with the
-    tiling chosen for it: one count per kernel instance a lowered
-    program holds."""
+    tiling chosen for it and what its list of visits does with a group
+    that has no row ("skipped": the row products; "visited": the weight
+    gradient, which writes its zeros): one count per kernel instance a
+    lowered program holds."""
     _reg().counter("moe_gmm_lowerings_total",
-                   "grouped-product kernels lowered, by kernel and tiling",
-                   labelnames=("kernel", "block_m", "block_n", "block_k")) \
+                   "grouped-product kernels lowered, by kernel, tiling and "
+                   "whether an empty group is visited",
+                   labelnames=("kernel", "block_m", "block_n", "block_k",
+                               "empty_groups")) \
           .labels(kernel=kernel, block_m=block_m, block_n=block_n,
-                  block_k=block_k).inc()
+                  block_k=block_k, empty_groups=empty_groups).inc()
 
 
 def on_moe_share_lowering(scored, held, top_k):

@@ -31,7 +31,10 @@ from `first_expert` on.  An assignment to an expert outside the range is
 left out before the ordering (it sorts behind every held one and
 belongs to no group, so no product visits it) and adds nothing: the
 output is the held experts' part of the layer's, which the other chips'
-parts would be added to.  Forward only.
+parts would be added to.  A held expert that no token chose is not
+visited either (the grouped row products walk the groups that have a
+row), so a lightly loaded share reads the weights of the experts that
+were chosen and no others.  Forward only.
 
 The gradient of `moe_experts` is explicit, for the reason
 `flash_attention`'s is: jax.vjp of the op would run the forward's

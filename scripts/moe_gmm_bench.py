@@ -1,11 +1,27 @@
-"""Times the grouped-product kernels (kernels/grouped_matmul.py) against
-XLA's own `ragged_dot` at the shapes of the cell olmoe-train-4k, on the
-chip: 32768 rows ordered by 64 experts, [2048 -> 1024] and [1024 ->
-2048], bfloat16 with float32 accumulation.  Prints one line a variant:
-milliseconds a call (host clock around 20 calls that end in
-block_until_ready) and the share of the bf16 peak the required FLOPs
-make of it.  `python scripts/moe_gmm_bench.py [sweep]`: with `sweep`
-also the tilings beside the one the kernel chooses.
+"""Times the grouped-product kernels (kernels/grouped_matmul.py) on the
+chip, bfloat16 with float32 accumulation, one line a variant.
+
+`olmoe`: against XLA's own `ragged_dot` at the shapes of the cell
+olmoe-train-4k, 32768 rows ordered by 64 experts, [2048 -> 1024] and
+[1024 -> 2048]: milliseconds a call (host clock around 20 calls that end
+in block_until_ready) and the share of the bf16 peak the required FLOPs
+make of it; with `sweep` also the tilings beside the one the kernel
+chooses.
+
+`shares`: the forward product where a chip holds 16 experts of 256 and
+few rows reach them (`moe_experts`' range form: the sizes sum below the
+rows).  dsv32-turn-16k-ep16's [128, 7168] x [16, 7168, 2048] and [128,
+2048] x [16, 2048, 7168] with 0, 1, 2, 4, 6, 8 and 16 experts that got
+one row each, and pangu-decode-ep16's [2048, 7680] x [16, 7680, 2048]
+with 8 rows on each of 16: milliseconds a call (host clock around one
+program that makes 100 calls in a scan, so the list of visits' own
+operations are in it and the host's dispatch is not), the share of the
+HBM peak that the bytes of the experts *that have a row* make of it, and
+the largest difference from `ragged_dot` over the rows that are a
+group's.
+
+`python scripts/moe_gmm_bench.py [olmoe] [sweep] [shares]`; both parts
+when none is named.
 """
 
 import sys
@@ -20,7 +36,9 @@ from paddle_tpu.kernels import grouped_matmul as G  # noqa: E402
 
 M, D, F, E = 32768, 2048, 1024, 64
 PEAK = 197e12
+HBM = 819e9
 CALLS = 20
+SCANNED = 100
 
 
 def timed(fn, *args):
@@ -33,10 +51,67 @@ def timed(fn, *args):
     return (time.perf_counter() - t0) / CALLS * 1e3, out
 
 
+def scanned(call, a, b, counts):
+    """ms a call of `call(a, b, counts)` inside one program that makes
+    SCANNED of them, and the last call's result."""
+    def many(a, b, all_counts):
+        def body(_, c):
+            return call(a, b, c), None
+        return jax.lax.scan(body, jnp.zeros((a.shape[0], b.shape[2]),
+                                            a.dtype), all_counts)[0]
+    many = jax.jit(many)
+    all_counts = jnp.broadcast_to(counts, (SCANNED,) + counts.shape)
+    out = jax.block_until_ready(many(a, b, all_counts))
+    t0 = time.perf_counter()
+    out = jax.block_until_ready(many(a, b, all_counts))
+    return (time.perf_counter() - t0) / SCANNED * 1e3, out
+
+
+def shares():
+    """The forward product of an expert layer's share, by how many of
+    its 16 experts got a row."""
+    bf = jnp.bfloat16
+    cases = [("dsv32 gate/up", 128, 7168, 2048, 1, (0, 1, 2, 4, 6, 8, 16)),
+             ("dsv32 down", 128, 2048, 7168, 1, (0, 1, 2, 4, 6, 8, 16)),
+             ("pangu gate/up", 2048, 7680, 2048, 8, (16,))]
+    for name, m, k, n, rows, groups in cases:
+        ks = jax.random.split(jax.random.PRNGKey(k), 2)
+        x = jax.random.normal(ks[0], (m, k), bf)
+        w = jax.random.normal(ks[1], (16, k, n), bf) * 0.02
+        blocks = G.choose_blocks(m, k, n, 2, "fwd")
+        for held in groups:
+            sizes = np.zeros(16, np.int32)
+            # the experts with a row, spread evenly over the 16
+            sizes[np.round(np.linspace(0, 15, held)).astype(int)] = rows
+            counts = jnp.asarray(sizes)
+            owned = int(sizes.sum())
+            ms, got = scanned(
+                lambda a, b, c: G._rows_call("fwd", blocks, a, b, c),
+                x, w, counts)
+            want = G.ragged_gmm(x, w, counts)
+            off = float(jnp.max(jnp.abs(
+                got[:owned].astype(jnp.float32)
+                - want[:owned].astype(jnp.float32)))) if owned else 0.0
+            due = held * k * n * 2 + m * k * 2 + m * n * 2
+            print("%-14s [%d, %d] x [16, %d, %d] kernel %s  %2d experts x "
+                  "%d rows  %8.4f ms  %5.1f%% of the HBM peak (%.1f MB "
+                  "due)  max|diff| %.3g"
+                  % (name, m, k, k, n, blocks, held, rows, ms,
+                     due / (ms * 1e-3) / HBM * 100, due / 1e6, off),
+                  flush=True)
+
+
 def main():
-    sweep = "sweep" in sys.argv[1:]
+    named = [a for a in sys.argv[1:] if a in ("olmoe", "shares")]
     dev = jax.devices()[0]
     print("platform=%s kind=%s" % (dev.platform, dev.device_kind))
+    if not named or "shares" in named:
+        shares()
+    if not named or "olmoe" in named:
+        olmoe("sweep" in sys.argv[1:])
+
+
+def olmoe(sweep):
     key = jax.random.PRNGKey(0)
     ks = jax.random.split(key, 6)
     bf = jnp.bfloat16

@@ -715,6 +715,22 @@ def test_counters_say_what_was_lowered(built):
         "top_k=%d}" % (E, GROUPS, KEPT, K)] == L - DENSE
 
 
+def test_the_shares_grouped_products_skip_the_experts_nobody_chose(built):
+    """A traced step holds three grouped products an expert layer, all
+    of them the row product whose list of visits leaves an empty group
+    out: a share holds 16 experts for 8 assignments, and reads the
+    weights of those that got one."""
+    decoder = built["decoder"]
+    before = telemetry.snapshot()
+    jax.make_jaxpr(decoder._step_fn(decoder._params))(
+        _empty(), jnp.asarray(built["tokens"][:, 0]))
+    gmm = {k: v for k, v in telemetry.snapshot_delta(before).items()
+           if k.startswith("moe_gmm_lowerings_total")}
+    assert sum(gmm.values()) == 3 * (L - DENSE)
+    assert all("kernel=fwd" in k and "empty_groups=skipped" in k
+               for k in gmm)
+
+
 def test_obs_dump_lists_the_new_counters(built, tmp_path):
     from paddle_tpu.tools import obs_dump
 

@@ -294,31 +294,48 @@ GROUPS = {
     "tile-aligned groups": [128, 128, 0, 256, 0, 0],
     "every group a few rows short of the tile": [100, 100, 100, 100, 100,
                                                  12],
+    # the range form of `moe_experts`: the sizes sum below the rows, and
+    # the rows behind the last group's are no group's
+    "rows of no group behind the groups": [0, 70, 0, 200, 3, 0],
+    # dsv32-turn-16k-ep16's regime: 16 held experts, one tile of 128 rows,
+    # 8 assignments on 6 of them
+    "16 groups, one tile, 8 rows on 6 groups": [0, 2, 0, 0, 1, 0, 0, 1, 0,
+                                                2, 0, 0, 1, 0, 0, 1],
+    "every group empty": [0] * 16,
+    "only the last group has rows": [0, 0, 0, 0, 0, 5],
+    "only the first group has rows": [130, 0, 0, 0, 0, 0],
 }
+ROWS = {"16 groups, one tile, 8 rows on 6 groups": 128,
+        "every group empty": 128}
 
 
-def _operands(kernel, rs):
-    x = jnp.asarray(rs.randn(M, GK), jnp.float32)
-    w = jnp.asarray(rs.randn(GE, GK, GN), jnp.float32)
-    dy = jnp.asarray(rs.randn(M, GN), jnp.float32)
+def _operands(kernel, rs, m=M, e=GE):
+    x = jnp.asarray(rs.randn(m, GK), jnp.float32)
+    w = jnp.asarray(rs.randn(e, GK, GN), jnp.float32)
+    dy = jnp.asarray(rs.randn(m, GN), jnp.float32)
     return {"fwd": (x, w), "dx": (dy, w), "dw": (x, dy)}[kernel]
 
 
 @pytest.mark.parametrize("groups", list(GROUPS))
 @pytest.mark.parametrize("kernel", ["fwd", "dx", "dw"])
 def test_grouped_kernel_against_ragged_dot(kernel, groups):
-    """The Mosaic kernels' bodies under the Pallas interpreter, at tiles
-    smaller than the groups and larger, against XLA's ragged product and
-    against a loop over the groups."""
+    """The Mosaic kernels' bodies under the Pallas interpreter (which
+    fills what nothing wrote with NaN and raises on a block out of
+    range), at tiles smaller than the groups and larger, against XLA's
+    ragged product and against a loop over the groups.  Rows of no group
+    are not written by a row product and not compared."""
     from jax.experimental.pallas import tpu as pltpu
 
-    counts = jnp.asarray(GROUPS[groups], jnp.int32)
-    a, b = _operands(kernel, np.random.RandomState(7))
+    sizes = GROUPS[groups]
+    m = ROWS.get(groups, M)
+    counts = jnp.asarray(sizes, jnp.int32)
+    a, b = _operands(kernel, np.random.RandomState(7), m, len(sizes))
     plain = {"fwd": grouped_matmul.ragged_gmm,
              "dx": grouped_matmul.ragged_gmm_dx,
              "dw": grouped_matmul.ragged_gmm_dw}[kernel](a, b, counts)
-    ends = np.cumsum(GROUPS[groups])
-    rows = [slice(e - c, e) for c, e in zip(GROUPS[groups], ends)]
+    ends = np.cumsum(sizes)
+    rows = [slice(e - c, e) for c, e in zip(sizes, ends)]
+    owned = slice(None) if kernel == "dw" else slice(0, ends[-1])
     if kernel == "dw":
         loop = np.stack([np.asarray(a)[r].T @ np.asarray(b)[r]
                          for r in rows])
@@ -327,7 +344,7 @@ def test_grouped_kernel_against_ragged_dot(kernel, groups):
         for g, r in enumerate(rows):
             w = np.asarray(b)[g]
             loop[r] = np.asarray(a)[r] @ (w if kernel == "fwd" else w.T)
-    np.testing.assert_allclose(plain, loop, atol=2e-4)
+    np.testing.assert_allclose(plain[owned], loop[owned], atol=2e-4)
     blocks = {"fwd": (TILE, GN, GK), "dx": (TILE, GN, 128),
               "dw": (TILE, GN, 128)}[kernel]
     with pltpu.force_tpu_interpret_mode():
@@ -336,30 +353,122 @@ def test_grouped_kernel_against_ragged_dot(kernel, groups):
         else:
             got = grouped_matmul._rows_call(kernel, blocks, a, b, counts)
     assert got.shape == plain.shape and got.dtype == plain.dtype
-    np.testing.assert_allclose(got, loop, atol=2e-4)
+    np.testing.assert_allclose(got[owned], loop[owned], atol=2e-4)
 
 
-@pytest.mark.parametrize("seed", range(3))
-def test_visits_cover_every_row_of_every_group_once(seed):
+def _visits(sizes, m, empty_groups):
+    return [np.asarray(v) for v in grouped_matmul.visits(
+        jnp.asarray(sizes), m, TILE, empty_groups)]
+
+
+def _times_seen(group, tile, offsets, m):
+    """[m]: how many of the visits own each row."""
+    seen = np.zeros(m, int)
+    for g, t in zip(group, tile):
+        seen[max(offsets[g], t * TILE):min(offsets[g + 1],
+                                           (t + 1) * TILE)] += 1
+    return seen
+
+
+@pytest.mark.parametrize("seed", range(5))    # 1, 3 and 4 draw empty groups
+@pytest.mark.parametrize("product", ["rows", "dw"])
+def test_visits_cover_every_row_of_every_group_once(product, seed):
     rs = np.random.RandomState(seed)
     sizes = rs.multinomial(M, rs.dirichlet(np.ones(GE) * 0.3))
-    group, tile, offsets, length = (np.asarray(v) for v in
-                                    grouped_matmul.visits(
-                                        jnp.asarray(sizes), M, TILE))
+    group, tile, offsets, length = _visits(sizes, M, product == "dw")
     assert group.shape == (M // TILE + GE - 1,)
     n = int(length[0])
-    seen = np.zeros(M, int)
-    for g, t in zip(group[:n], tile[:n]):
-        lo = max(offsets[g], t * TILE)
-        hi = min(offsets[g + 1], (t + 1) * TILE)
-        seen[lo:hi] += 1
-    assert (seen == 1).all()
-    # every group is visited (an empty one once, for its zeros), in order,
-    # and the padding names the last real visit again
-    assert sorted(set(group[:n])) == list(range(GE))
+    assert (_times_seen(group[:n], tile[:n], offsets, M) == 1).all()
+    # the weight gradient visits every group (an empty one once, for its
+    # zeros), the row products the groups that have a row and no other;
+    # in order, and the padding names the last real visit again
+    assert sorted(set(group[:n])) == [
+        g for g in range(GE) if product == "dw" or sizes[g]]
     assert (np.diff(group[:n]) >= 0).all()
     assert (group[n:] == group[n - 1]).all() and \
         (tile[n:] == tile[n - 1]).all()
+
+
+@pytest.mark.parametrize("groups", [
+    "rows of no group behind the groups", "every group empty",
+    "16 groups, one tile, 8 rows on 6 groups", "only the last group has rows"])
+def test_visits_of_a_row_product_where_rows_are_no_groups(groups):
+    """The range form: a list that may be short of the rows, or empty.
+    Every entry, real or padding, names a block that exists."""
+    sizes = np.asarray(GROUPS[groups])
+    m = ROWS.get(groups, M)
+    group, tile, offsets, length = _visits(sizes, m, False)
+    n = int(length[0])
+    assert sorted(set(group[:n])) == list(np.flatnonzero(sizes))
+    assert (np.diff(group[:n]) >= 0).all()
+    assert ((group >= 0) & (group < len(sizes))).all()
+    assert ((tile >= 0) & (tile < m // TILE)).all()
+    seen = _times_seen(group[:n], tile[:n], offsets, m)
+    assert (seen[:sizes.sum()] == 1).all() and not seen[sizes.sum():].any()
+    if not n:
+        assert not sizes.any() and len(set(group)) == 1 == len(set(tile))
+
+
+# (sizes, group, tile, length) of `visits` on the parent commit (d4f593b),
+# which knew one list for all three kernels, on count vectors drawn from
+# seeds 0 and 3 in units of 32 rows and from seed 100 row by row: no empty
+# group, lists of 8, 7 and 9 real visits of the 9 there is room for
+PARENT_VISITS = [
+    ([64, 160, 128, 32, 64, 64], [0, 1, 1, 2, 2, 3, 4, 5, 5],
+     [0, 0, 1, 1, 2, 2, 3, 3, 3], 8),
+    ([32, 64, 32, 64, 64, 256], [0, 1, 2, 3, 4, 5, 5, 5, 5],
+     [0, 0, 0, 1, 1, 2, 3, 3, 3], 7),
+    ([104, 49, 71, 263, 1, 24], [0, 1, 1, 2, 3, 3, 3, 4, 5],
+     [0, 0, 1, 1, 1, 2, 3, 3, 3], 9),
+]
+
+
+@pytest.mark.parametrize("recorded", PARENT_VISITS,
+                         ids=lambda r: "length %d" % r[3])
+@pytest.mark.parametrize("product", ["rows", "dw"])
+def test_without_an_empty_group_the_visits_are_the_parents(product,
+                                                           recorded):
+    sizes, want_group, want_tile, want_length = recorded
+    group, tile, offsets, length = _visits(sizes, M, product == "dw")
+    assert list(group) == want_group and list(tile) == want_tile
+    assert list(length) == [want_length]
+    assert list(offsets) == [0] + list(np.cumsum(sizes))
+
+
+@pytest.mark.parametrize("path", ["plain", "interpreter"])
+def test_a_share_with_no_held_assignment_adds_exactly_zero(path,
+                                                           monkeypatch):
+    """`moe_experts` holding experts 4..7 of 8 when every token chose
+    among 0..3: no grouped product visits anything, and the rows nothing
+    wrote (NaN under the interpreter) do not reach `Out`."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    rs = np.random.RandomState(11)
+    n = 8
+    ins = {"X": [jnp.asarray(rs.randn(n, D), jnp.float32)],
+           "TopW": [jnp.asarray(rs.uniform(0.1, 0.5, (n, K)), jnp.float32)],
+           "TopIdx": [jnp.asarray(rs.randint(0, 4, (n, K)), jnp.int32)],
+           "WGate": [jnp.asarray(rs.randn(4, D, F) * 0.2, jnp.float32)],
+           "WUp": [jnp.asarray(rs.randn(4, D, F) * 0.2, jnp.float32)],
+           "WDown": [jnp.asarray(rs.randn(4, F, D) * 0.2, jnp.float32)]}
+    kernel = registry.get_op_info("moe_experts").kernel
+    attrs = {"first_expert": 4, "scored": E}
+    if path == "plain":
+        outs = kernel(None, ins, attrs)
+    else:
+        def through_the_kernel(x, w, counts):
+            blocks = grouped_matmul.choose_blocks(
+                x.shape[0], w.shape[1], w.shape[2], 4, "fwd")
+            return grouped_matmul._rows_call("fwd", blocks, x, w, counts)
+
+        # off the TPU `gmm` takes its plain path: hand it the kernel
+        monkeypatch.setattr(grouped_matmul, "ragged_gmm", through_the_kernel)
+        with pltpu.force_tpu_interpret_mode():
+            outs = kernel(None, ins, attrs)
+        assert np.isnan(np.asarray(outs["Gate"][0])).all()
+    assert not np.asarray(outs["Counts"][0]).any()
+    out = np.asarray(outs["Out"][0])
+    assert out.shape == (n, D) and not out.any()
 
 
 def test_two_products_of_one_shape_are_counted_twice():
@@ -459,6 +568,16 @@ def test_counters_say_what_was_lowered(trained_once):
     assert by_kernel == {"fwd": 3 * L, "dx": 3 * L, "dw": 3 * L}
     assert all("block_m=" in k and "block_n=" in k and "block_k=" in k
                for k in lowered if k.startswith("moe_gmm_lowerings_total"))
+    # the row products walk the groups that have a row, the weight
+    # gradient every group
+    walks = {(kernel, form): sum(
+        v for key, v in lowered.items()
+        if key.startswith("moe_gmm_lowerings_total")
+        and "kernel=%s" % kernel in key and "empty_groups=%s" % form in key)
+        for kernel in ("fwd", "dx", "dw") for form in ("skipped", "visited")}
+    assert walks == {("fwd", "skipped"): 3 * L, ("fwd", "visited"): 0,
+                     ("dx", "skipped"): 3 * L, ("dx", "visited"): 0,
+                     ("dw", "skipped"): 0, ("dw", "visited"): 3 * L}
 
 
 def test_router_stays_float32_under_bfloat16_compute():
