@@ -20,8 +20,10 @@ pays it.
 freed, on what the window itself served: every row of one of its calls,
 drawn from the seed, against the plain float32 reference
 (benchmark/reference/<reference>.py), which makes its own weights from
-the same seed.  The numbers compared and their limits are the workload
-file's `correct`.
+the same key (`model_key`: the workload file's `weights.seed` where it
+states one, else `--seed`; the prompts, the checked rows and the call
+picked are `--seed`'s always).  The numbers compared and their limits
+are the workload file's `correct`.
 """
 
 import time
@@ -31,8 +33,24 @@ import numpy as np
 from benchmark import harness
 
 
+def model_key(run):
+    """The key the cell's model is drawn from, all of it (the weights
+    served, and the blocks the reference is handed): the workload file's
+    `weights.seed` where it states one, `--seed` where it does not.  A
+    cell is one model under varying traffic; where a model's speed
+    depends on its draw (a router's favourites among the held experts),
+    runs on different seeds must not be different models."""
+    import jax
+
+    spec = run.workload["weights"]
+    if "seed" in spec:
+        return jax.random.PRNGKey(spec["seed"])
+    return jax.random.PRNGKey(run.seed)
+
+
 def make_weights(run, model):
-    """The parameter tree from the seed, on the device, one jitted call."""
+    """The parameter tree from `model_key`, on the device, one jitted
+    call."""
     import jax
 
     cfg, spec = run.config, run.workload["weights"]
@@ -40,8 +58,7 @@ def make_weights(run, model):
     def seeded_weights(key):
         return model.weights(cfg, spec, key)
 
-    return jax.block_until_ready(
-        jax.jit(seeded_weights)(jax.random.PRNGKey(run.seed)))
+    return jax.block_until_ready(jax.jit(seeded_weights)(model_key(run)))
 
 
 def serve(run, model):

@@ -5,7 +5,7 @@ question through the step's own prefill scan) and decodes a long answer,
 init_state=<the session's caches and its position>)` in a closed loop,
 one call in flight, as drivers/decode_share.py drives the share cell
 whose `window` and `checked_rows` this imports as they are (and, through
-it, drivers/decode_program.py's `make_weights` and
+it, drivers/decode_program.py's `model_key`, `make_weights` and
 `trace_lower_seconds`; read those files for the window and the rate).
 
 What differs.  A decode-pool chip is *handed* its caches by the prefill
@@ -51,12 +51,13 @@ from benchmark import harness
 
 
 def seeded(run, model):
-    """(`ends`, `block_of(layer)`): the seeded parameters as the
-    reference asks for them, one block at a time."""
+    """(`ends`, `block_of(layer)`): the parameters `make_weights` serves
+    (one `model_key`) as the reference asks for them, one block at a
+    time."""
     import jax
 
     cfg, spec = run.config, run.workload["weights"]
-    key = jax.random.PRNGKey(run.seed)
+    key = run.lookup.module("drivers", "decode_program").model_key(run)
     ends = jax.jit(lambda k: model.ends(cfg, spec, model.root(k)))(key)
 
     def block_of(layer):

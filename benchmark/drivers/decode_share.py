@@ -137,7 +137,7 @@ def compare(run, model, pool, call):
     cfg, workload = run.config, run.workload
     reference = run.lookup.module("reference", workload["reference"])
     spec = workload["weights"]
-    key = jax.random.PRNGKey(run.seed)
+    key = run.lookup.module("drivers", "decode_program").model_key(run)
     ends = jax.jit(lambda k: model.ends(cfg, spec, model.root(k)))(key)
 
     def block_of(layer):

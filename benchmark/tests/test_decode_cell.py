@@ -350,8 +350,10 @@ def test_benchmark_json_has_the_cell_and_its_metrics():
     assert len(four) <= max(1, len(cells) // 4)
     end_to_end = {m["name"]: m for m in bench["end_to_end"]}
     rate = end_to_end["decode_tok_per_s"]
-    assert (rate["unit"], rate["better"], rate["source"],
-            rate["workloads"]) == ("tok/s", "higher", "host_clock", [CELL])
+    # the cell is among the metric's, not all of them: later generation
+    # cells report it too
+    assert (rate["unit"], rate["better"], rate["source"]) == \
+        ("tok/s", "higher", "host_clock") and CELL in rate["workloads"]
     assert 0.01 <= rate["bound"] <= 0.1
     assert "workloads" not in end_to_end["setup_s"]
     listed = {m["name"]: m for m in bench["per_layer"]}
@@ -362,7 +364,10 @@ def test_benchmark_json_has_the_cell_and_its_metrics():
     assert "decode_idle_share" not in listed
     for name in NEW_READERS:
         reader = LOOKUP.module("layer_metrics", name)
-        assert listed[name]["workloads"] == [CELL]
+        # this cell's alone, but for the one every generation cell reads
+        cells_of = listed[name]["workloads"]
+        assert cells_of == [CELL] or (
+            name == "decode_trace_lower_s" and cells_of[0] == CELL)
         assert listed[name]["moves"] == reader.MOVES
         assert (listed[name]["layer"], listed[name]["unit"],
                 listed[name]["source"]) == \

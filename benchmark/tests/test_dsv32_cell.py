@@ -225,6 +225,96 @@ def test_documents_and_questions_are_the_seeds():
     assert model.prompts(cfg, workload, 7).shape == (2, 4, 8)
 
 
+def _a_run(seed, model_seed=None):
+    """A run of the toy cell on `--seed`, its file stating `weights.seed`
+    where `model_seed` is given (the fixture's file states none)."""
+    import time
+
+    import jax
+    from benchmark import harness
+
+    workload = LOOKUP.json("workloads", TOY)
+    if model_seed is not None:
+        workload["weights"]["seed"] = model_seed
+    return harness.Run(dict(workload, name=TOY),
+                       LOOKUP.json("configs", TOY_CONFIG), seed, 0.0, False,
+                       LOOKUP, jax.devices()[:1], None,
+                       harness.SetupClock(time.perf_counter()),
+                       harness.CompileClock())
+
+
+def _same(a, b):
+    import jax
+    import numpy as np
+
+    flat_a, flat_b = (jax.tree_util.tree_leaves(t) for t in (a, b))
+    return len(flat_a) == len(flat_b) and all(
+        x.dtype == y.dtype and np.array_equal(x, y)
+        for x, y in zip(flat_a, flat_b))
+
+
+def test_the_model_is_the_files_draw_and_the_traffic_is_the_seeds():
+    """Under two `--seed`s a file that states `weights.seed` hands the
+    reference the same `ends` and blocks bit for bit, under two
+    `weights.seed`s different ones; the documents, the questions and the
+    checked rows follow `--seed`."""
+    import numpy as np
+
+    driver = LOOKUP.module("drivers", "decode_session")
+    share = LOOKUP.module("drivers", "decode_share")
+    model = LOOKUP.module("models", "dsv32_decode")
+    one, other, drawn_otherwise = \
+        _a_run(11, 4000000501), _a_run(13, 4000000501), _a_run(11, 4000000503)
+
+    def handed(run):
+        ends, block_of = driver.seeded(run, model)
+        return ends, [block_of(i)
+                      for i in range(run.config["num_hidden_layers"])]
+
+    (ends, blocks), (others_ends, others_blocks) = \
+        handed(one), handed(drawn_otherwise)
+    assert _same((ends, blocks), handed(other))
+    assert not _same(ends, others_ends)
+    for mine, theirs in zip(blocks, others_blocks):
+        assert not _same(mine, theirs)
+    cfg, workload = one.config, one.workload
+    assert (model.documents(cfg, workload, one.seed)
+            != model.documents(cfg, workload, other.seed)).any()
+    assert (model.prompts(cfg, workload, one.seed)
+            != model.prompts(cfg, workload, other.seed)).any()
+    assert share.checked_rows(one).tolist() != \
+        share.checked_rows(other).tolist()
+    np.testing.assert_array_equal(share.checked_rows(one),
+                                  share.checked_rows(drawn_otherwise))
+
+
+@pytest.mark.parametrize("model_seed", [None, 4000000501])
+def test_the_served_weights_are_the_blocks_the_reference_is_handed(
+        model_seed):
+    """`make_weights` (what is served) and `seeded` (what the reference
+    gets, a block at a time) draw from one key, with and without
+    `weights.seed` in the file; with it, the model is the one a file
+    without the key drew on that `--seed` (PR 40's readings of seed
+    4000000501 are this model's)."""
+    shared = LOOKUP.module("drivers", "decode_program")
+    driver = LOOKUP.module("drivers", "decode_session")
+    model = LOOKUP.module("models", "dsv32_decode")
+    run = _a_run(17, model_seed)
+    served = shared.make_weights(run, model)
+    ends, block_of = driver.seeded(run, model)
+    blocks = served.pop("blocks")
+    assert _same(served, ends)
+    assert len(blocks) == run.config["num_hidden_layers"]
+    for i, block in enumerate(blocks):
+        assert _same(block, block_of(i))
+    as_the_parent = shared.make_weights(
+        _a_run(17 if model_seed is None else model_seed), model)
+    assert _same(blocks, as_the_parent["blocks"])
+    on_another_seed = shared.make_weights(_a_run(19, model_seed), model)
+    assert _same(blocks, on_another_seed["blocks"]) \
+        == (model_seed is not None)
+
+
 @pytest.fixture(scope="module")
 def toy_forward():
     """The program's own reference over 2 sequences of 32 tokens, and
@@ -637,6 +727,9 @@ def test_benchmark_json_has_the_cell_and_its_metrics():
         (CONFIG, CELL, 1)
     assert cell["why"] == workload["why"] and len(cell["why"]) <= 200
     assert 9 <= len(cells) <= 24
+    # one model under varying traffic: the file states the draw (PR 42)
+    assert workload["weights"]["seed"] == 4000000501
+    assert "4000000501" in workload["weights"]["why"]
     configs = {c["name"]: c for c in bench["configs"]}
     entry, config = configs[CONFIG], LOOKUP.json("configs", CONFIG)
     assert entry["file"] == "benchmark/configs/%s.json" % CONFIG

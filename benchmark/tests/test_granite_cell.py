@@ -102,20 +102,25 @@ def test_the_cells_files_state_what_the_issue_fixes():
         assert (listed[name]["layer"], listed[name]["unit"],
                 listed[name]["source"]) == (reader.LAYER, reader.UNIT,
                                             reader.SOURCE)
-    assert [m["name"] for m in bench["per_layer"][-3:]] == list(NEW_READERS)
+    # a run of names where it begins, not "the last three", and the cell
+    # and its configuration by name: later PRs append their own
+    first = list(listed).index(NEW_READERS[0])
+    assert list(listed)[first:first + 3] == list(NEW_READERS)
     cell = {"name": "granite-train-4k", "config": "granite-4.0-h-micro",
             "traffic": "granite-train-4k", "chips": 1,
             "why": workload["why"]}
-    assert bench["workloads"][-1] == cell and len(cell["why"]) <= 200
-    entry = bench["configs"][-1]
+    assert [w for w in bench["workloads"] if w["name"] == cell["name"]] \
+        == [cell] and len(cell["why"]) <= 200
+    entry, = [c for c in bench["configs"] if c["name"] == cfg["name"]]
     assert (entry["name"], entry["source"], entry["reduced"]) == (
         cfg["name"], cfg["source"], cfg["reduced"])
     assert all(1 <= len(e["why"]) <= 200
                for e in bench["configs"] + bench["workloads"])
-    # one four-chip cell of six: the quota is a quarter, rounded down
+    # one four-chip cell: the quota is a quarter, rounded down
     four = [w["name"] for w in bench["workloads"] if w["chips"] == 4]
     assert four == ["resnet50-train-dp4"]
-    assert len(bench["workloads"]) == 6 and len(bench["configs"]) == 5
+    assert 6 <= len(bench["workloads"]) <= 24
+    assert 5 <= len(bench["configs"]) <= 24
 
 
 def test_the_reference_copy_is_the_programs():
