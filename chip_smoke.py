@@ -4,8 +4,8 @@
 
 drives the main path once on a TPU, through the entry points a user
 calls and at the widths the repo already uses, and checks what comes
-out: ResNet-50 trained through `Executor.run` and through the
-FunctionalProgram step bench.py times, the Program-stack transformer
+out: ResNet-50 trained through `Executor.run` and through one jitted
+FunctionalProgram step with donated state, the Program-stack transformer
 trained through the flash-attention kernel (and the kernel checked
 against dense attention), the routed expert op forward and backward at
 OLMoE's widths against the dense reference, the state-space scan op
@@ -100,20 +100,57 @@ def scalar(fetch):
     return float(np.asarray(fetch).reshape(-1)[0])
 
 
+def build_image_model(model, batch, image_size, class_dim):
+    """(main, startup, logits, loss): `paddle_tpu.models.<model>` with
+    its loss and a momentum optimizer, as `__graft_entry__` builds it."""
+    from __graft_entry__ import _build_model
+    from paddle_tpu import models
+    from paddle_tpu.tune.models import MODELS
+
+    return _build_model(getattr(models, model), batch, image_size,
+                        class_dim, with_loss=True,
+                        channels=MODELS[model]["channels"])
+
+
+def image_feeds(batch, image_size, class_dim, channels=3):
+    rs = np.random.RandomState(0)
+    image = rs.rand(batch, channels, image_size,
+                    image_size).astype(np.float32)
+    label = rs.randint(0, class_dim, size=(batch, 1)).astype(np.int64)
+    return {"image": image, "label": label}
+
+
+def functional_step(main_prog, feed_names, fetch_name, scope, dev):
+    """(step, state): the whole program through FunctionalProgram under
+    one jax.jit, every state array on `dev` and donated to the call."""
+    import jax
+    from paddle_tpu.analysis.alias import state_donation
+    from paddle_tpu.fluid.executor import RNG_STATE_NAME
+    from paddle_tpu.jit import FunctionalProgram, state_from_scope
+
+    fp = FunctionalProgram(main_prog, feed_names, [fetch_name])
+    state = {n: jax.device_put(np.asarray(v), dev)
+             for n, v in state_from_scope(fp, scope).items()}
+    # stochastic ops (dropout) draw from a state-carried key
+    state[RNG_STATE_NAME] = jax.device_put(jax.random.PRNGKey(0), dev)
+    step = jax.jit(lambda s, f: fp(s, f),
+                   donate_argnums=(0,) if state_donation() else ())
+    return step, state
+
+
 # ---------------------------------------------------------------------------
 # phases
 # ---------------------------------------------------------------------------
 
 def resnet50_train(batch=128, image_size=224, class_dim=1000):
     import jax
-    import bench
     import paddle_tpu.fluid as fluid
 
     devices = set(jax.devices())
     fluid.amp.enable_bf16()
-    main, startup, _, loss = bench._build_image_model(
+    main, startup, _, loss = build_image_model(
         "resnet50", batch, image_size, class_dim)
-    feeds = bench._image_feeds(batch, image_size, class_dim)
+    feeds = image_feeds(batch, image_size, class_dim)
 
     scope = fluid.Scope()
     exe = fluid.Executor(fluid.TPUPlace(0))
@@ -124,7 +161,7 @@ def resnet50_train(batch=128, image_size=224, class_dim=1000):
     check_on("executor scope",
              [scope.get(n) for n in scope.local_var_names()], devices)
 
-    step, state = bench.functional_step(
+    step, state = functional_step(
         main, ["image", "label"], loss.name, scope, jax.devices()[0])
     dev_feeds = jax.device_put(feeds, jax.devices()[0])
     losses = []
@@ -194,7 +231,6 @@ def transformer_train(batch=16, seq_len=512, d_model=512, n_layer=6,
                       n_head=8, vocab=8192,
                       kernel_shapes=((2, 8, 512, 64), (1, 8, 4096, 128))):
     import jax
-    import bench
     import paddle_tpu.fluid as fluid
     from paddle_tpu.models.transformer_program import (
         build_transformer_program, transformer_program_feeds)
@@ -210,7 +246,7 @@ def transformer_train(batch=16, seq_len=512, d_model=512, n_layer=6,
     scope = fluid.Scope()
     fluid.Executor(fluid.TPUPlace(0)).run(startup, scope=scope)
     dev = jax.devices()[0]
-    step, state = bench.functional_step(
+    step, state = functional_step(
         main, ["tokens", "positions", "targets"], loss.name, scope, dev)
     feeds = jax.device_put(
         transformer_program_feeds(batch, seq_len, vocab), dev)
@@ -480,7 +516,6 @@ def resnet50_serve(image_size=224, class_dim=1000, buckets=(1, 4, 16),
 def multichip(n_devices=4, batch=512, image_size=224, class_dim=1000):
     import jax
     from jax.sharding import NamedSharding
-    import bench
     import paddle_tpu.fluid as fluid
     from paddle_tpu.parallel import make_mesh
     from paddle_tpu.parallel.sharding import batch_spec
@@ -488,7 +523,7 @@ def multichip(n_devices=4, batch=512, image_size=224, class_dim=1000):
 
     fluid.amp.enable_bf16()
     mesh = make_mesh(n_devices=n_devices)
-    main, startup, _, loss = bench._build_image_model(
+    main, startup, _, loss = build_image_model(
         "resnet50", batch, image_size, class_dim)
     trainer = SpmdTrainer(main, startup, feed_names=["image", "label"],
                           fetch_names=[loss.name], mesh=mesh)
@@ -496,8 +531,7 @@ def multichip(n_devices=4, batch=512, image_size=224, class_dim=1000):
     feeds = {
         n: jax.device_put(v, NamedSharding(
             mesh, batch_spec(v.shape, mesh)))
-        for n, v in bench._image_feeds(batch, image_size,
-                                       class_dim).items()}
+        for n, v in image_feeds(batch, image_size, class_dim).items()}
     for n, v in feeds.items():
         check(len(v.sharding.device_set) == n_devices,
               "feed %s is on %d device(s), not %d"

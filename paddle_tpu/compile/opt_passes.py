@@ -17,8 +17,7 @@ a cost model, not a heuristic flag:
               conv stacks whose spatial dims shrank below the lane
               width accept.  Forward/inference programs only — a
               training program declines with a note (convert the
-              forward BEFORE append_backward: fluid.convert_layout /
-              bench.py BENCH_LAYOUT=NHWC).
+              forward BEFORE append_backward: fluid.convert_layout).
 
   fuse        greedy fusion of single-consumer elementwise/activation/
               bias chains into ``fused_elemwise_chain`` ops
@@ -125,7 +124,7 @@ class LayoutOptimize(RewritePass):
         if _has_grad_ops(desc):
             ctx.note = ("training program: layout must convert the "
                         "forward before append_backward "
-                        "(fluid.convert_layout / BENCH_LAYOUT=NHWC)")
+                        "(fluid.convert_layout)")
             return None
         bd = desc.block(0)
         capable = [od for od in bd.ops

@@ -17,8 +17,7 @@ This module closes the loop from three sides:
     jit) — they are the schedule's shape, not its runtime.
   * **Runtime per-bucket timing** — `measure_bucket_times` replays
     each bucket's ring chain as its own jitted shard_map and times it
-    with `block_until_ready` (the `spmd/bench.measure_comm` technique,
-    at bucket granularity), observing
+    with `block_until_ready`, observing
     `comm_collective_seconds{collective,bucket}` and
     `comm_bytes_total{collective}`, and pairing every bucket's
     measured time with its analytic ring floor
@@ -294,8 +293,8 @@ def measure_bucket_times(mesh, grads, bucket_bytes, axis_name="dp",
 
 def measure_trainer_comm(trainer, reps=3, bucket_bytes=None):
     """`measure_bucket_times` over a trainer's gradient volume (the
-    plan-priced trainable parameters, the `spmd/bench.measure_comm`
-    proxy: gradient volume == parameter volume).  None when the dp
+    plan-priced trainable parameters: gradient volume == parameter
+    volume).  None when the dp
     axis moves nothing."""
     import numpy as np
 

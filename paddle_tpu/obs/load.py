@@ -61,7 +61,6 @@ __all__ = [
     "LoopbackTarget", "vector_payload", "run_open_loop",
     "run_closed_loop", "build_report", "percentile", "latency_blob",
     "join_tail", "parse_exemplars", "join_exemplars", "format_report",
-    "run_serving_bench",
 ]
 
 # client-side failure pseudo-status (connection refused/reset/timeout):
@@ -322,7 +321,7 @@ class HttpTarget:
 class LoopbackTarget:
     """Drives an in-process `InferenceServer` through the same
     `handle_infer` the HTTP handler calls — no sockets, same
-    measurement path (tests + the bench leg)."""
+    measurement path (tests)."""
 
     def __init__(self, server):
         self.server = server
@@ -735,13 +734,13 @@ def format_report(report):
 
 
 # ---------------------------------------------------------------------------
-# the serving-slo bench leg (bench.py BENCH_SERVING=1)
+# the loopback model
 # ---------------------------------------------------------------------------
 
 def build_tiny_engine(dim=16, classes=4, buckets=(1, 2, 4, 8)):
     """A startup-initialized fc classifier engine, built in-process
-    (no export round-trip): the loopback model for the bench leg and
-    the pload selftest."""
+    (no export round-trip): the loopback model of the pload
+    selftest."""
     import paddle_tpu.fluid as fluid
     from paddle_tpu.core.scope import Scope
     from paddle_tpu.fluid import io as fluid_io
@@ -762,63 +761,3 @@ def build_tiny_engine(dim=16, classes=4, buckets=(1, 2, 4, 8)):
     return InferenceEngine(
         program, ["img"], [probs], scope=scope,
         config=EngineConfig(batch_buckets=list(buckets)))
-
-
-def run_serving_bench():
-    """The `serving-slo` mega_bench leg: a loopback server + an
-    open-loop Poisson run over a mixed-bucket profile, distilled into
-    a bench.py-style record whose `latency` blob lands in
-    perf_history.jsonl for `pperf gate --latency-tolerance`.
-
-    Env knobs (mega_bench-managed): BENCH_SERVING_RATE (req/s, 80),
-    BENCH_SERVING_N (requests, 400), BENCH_SERVING_MIX ("1:2,2:1,4:1"),
-    BENCH_SERVING_SLO_MS (50), BENCH_SERVING_SEED (0)."""
-    import os
-
-    import jax
-
-    from paddle_tpu.serving import InferenceServer, ServerConfig
-
-    rate = float(os.environ.get("BENCH_SERVING_RATE", "80"))
-    n = int(os.environ.get("BENCH_SERVING_N", "400"))
-    mix = TrafficMix.parse(
-        os.environ.get("BENCH_SERVING_MIX", "1:2,2:1,4:1"))
-    slo_ms = float(os.environ.get("BENCH_SERVING_SLO_MS", "50"))
-    seed = int(os.environ.get("BENCH_SERVING_SEED", "0"))
-
-    engine = build_tiny_engine()
-    server = InferenceServer(engine, ServerConfig(
-        port=0, max_batch=8, max_wait_ms=1.0, queue_size=128,
-        slo_ms=slo_ms, model_name="tiny-fc",
-        tail_slow_ms=slo_ms)).start()
-    try:
-        host, port = server.address
-        target = HttpTarget("http://%s:%d" % (host, port))
-        schedule = build_schedule(rate, n=n, arrival="poisson",
-                                  mix=mix, seed=seed)
-        report = run_open_loop(target, schedule,
-                               vector_payload("img", 16),
-                               slo_ms=slo_ms)
-        join_tail(report, target.get("/debug/tail"))
-    finally:
-        server.shutdown()
-
-    # the device the engine's parameters are on, not the one asked for
-    (device,) = engine.param_devices()
-    mix_tag = ",".join("%d:%g" % (b, w)
-                       for b, w in mix.weights.items())
-    return {
-        "metric": "serving_slo_openloop_rps",
-        "value": report["achieved_rps"],
-        "unit": "req/s",
-        "step_ms": None,
-        "mfu": None,
-        "amp_bf16": False,
-        "platform": device.platform,
-        "device_kind": device.device_kind,
-        "device_count": len(jax.devices()),
-        "latency": latency_blob(report),
-        "config": {"model": "tiny-fc", "mode": "serving",
-                   "rate": rate, "n": n, "mix": mix_tag,
-                   "slo_ms": slo_ms},
-    }

@@ -645,64 +645,6 @@ def render_audit(audit):
     return "\n".join(lines)
 
 
-def bench_donation_blob(program, fetches=()):
-    """The BENCH record's `donation` blob: the plan's verdict in bytes
-    — planned (everything provably donatable), donated (what the
-    mode actually donates, widened buffers included), and
-    declined (refusals, split by A-code) — so `pperf gate
-    --mem-tolerance` can lock the peak-HBM win in CI."""
-    from ..analysis.alias import analyze_donation
-    from ..fluid import analysis as fluid_analysis
-
-    desc = getattr(program, "desc", program)
-    bd = desc.block(0)
-    bf16_act = _bf16_act_now()
-    plan = analyze_donation(program, fetches=fetches)
-
-    def full_bytes(name):
-        vd = bd.vars.get(name)
-        if vd is None or vd.shape is None:
-            return 0
-        return fluid_analysis._numel(vd.shape) * \
-            fluid_analysis._elem_bytes(str(vd.dtype), True, bf16_act)
-
-    donated = declined = 0
-    declined_by_code = {}
-    for e in plan.entries:
-        if e["status"] == "donated":
-            donated += full_bytes(e["name"])
-        elif e["status"] == "reclaimable":
-            b = full_bytes(e["name"])
-            declined += b
-            code = e["code"] or "off"
-            declined_by_code[code] = declined_by_code.get(code, 0) + b
-    for s in plan.segments:
-        for n in s["widened"]:
-            b = full_bytes(n)
-            if plan.mode == "auto":
-                donated += b
-            else:
-                # proven donatable but the mode (off, conservative)
-                # declines it
-                declined += b
-                declined_by_code[plan.mode] = \
-                    declined_by_code.get(plan.mode, 0) + b
-        for d in s["declined"]:
-            b = full_bytes(d["name"])
-            declined += b
-            declined_by_code[d["code"]] = \
-                declined_by_code.get(d["code"], 0) + b
-    return {
-        "mode": plan.mode,
-        "fingerprint": plan.fingerprint(),
-        "planned_bytes": int(donated + declined),
-        "donated_bytes": int(donated),
-        "declined_bytes": int(declined),
-        "declined_by_code": {k: int(v) for k, v in
-                             sorted(declined_by_code.items())},
-    }
-
-
 # ---------------------------------------------------------------------------
 # OOM pre-flight + post-mortem
 # ---------------------------------------------------------------------------
@@ -800,12 +742,11 @@ def oom_context(exc, program=None, fetches=None):
 
 def bench_memory_blob(program, fetches=(), xla_stats=None):
     """The BENCH-record "memory" blob for one leg: static peak, the
-    AOT artifact's XLA temp/arg/output bytes (bench.py's
-    publish_compile_stats capture), the device watermark, and the
+    AOT artifact's XLA temp/arg/output bytes (what
+    `obs.health.publish_compile_stats` returns), the device watermark, and the
     estimate ratio — XLA total footprint / static total, the SAME
     actual/static direction as `mem_estimate_ratio` and the
-    calibration blob (1.0 = the static model is exact).  Never
-    raises contractually at the bench call site (wrapped there)."""
+    calibration blob (1.0 = the static model is exact)."""
     tl = program_timeline(program, fetches=fetches, top_n=3)
     xla = xla_stats or {}
     blob = {

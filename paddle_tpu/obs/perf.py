@@ -18,8 +18,8 @@ XLA cost attribution), turning raw numbers into verdicts:
     `compute_bound | hbm_bound | input_bound | host_bound`, with the
     dominant segment/op named.  This is the logic that used to be a
     hand-run sweep (the benchmark's traced run is the per-op follow-up).
-  * the perf history store + regression gate — bench.py/mega_bench
-    append normalized records to `perf_history.jsonl`;
+  * the perf history store + regression gate — `append_history`
+    adds normalized records to `perf_history.jsonl`;
     `gate_history()` compares the newest run per metric against a
     rolling median-of-N baseline with per-metric tolerances, and
     hard-fails platform mismatches (the round-5 `tpu-stale` re-emit
@@ -437,9 +437,9 @@ def attribution_floors(peak_tflops, hbm_gbps, registry=None,
     segments, with the dominant segment named — measured-XLA numbers
     where the IR roofline is an estimate.  None when attribution never
     ran.  Only segments matching `segment_prefix` are summed (the
-    executor's per-segment labels): bench.py's whole-step
-    "bench/step" gauge covers the same work as the segments and would
-    double-count; pass a different prefix (or "") to target other
+    executor's per-segment labels): a whole-step gauge published
+    under another label covers the same work as the segments and
+    would double-count; pass a different prefix (or "") to target other
     publishers.  Gauges are last-written-wins per label — in a
     process that attributed several programs, restrict the prefix or
     reset the registry between them."""
@@ -541,7 +541,7 @@ def leg_perf_blob(program, step_s, bf16_act=False, peak_tflops=None,
 # ---------------------------------------------------------------------------
 
 def normalize_record(record, leg=None, ts=None):
-    """Distill a bench.py record into the perf-history schema (None
+    """Distill a measured record into the perf-history schema (None
     for skip markers — they carry no measurement).  The perf blob is
     kept down to its verdict fields so history lines stay one-screen
     greppable."""
@@ -589,7 +589,7 @@ def normalize_record(record, leg=None, ts=None):
         norm["config"] = cfg
     comm = record.get("comm")
     if comm:
-        # multichip comm measurement (spmd/bench.py + obs/comm.py):
+        # multichip comm measurement (obs/comm.py):
         # the plan's analytic ring floor vs the timed grad-allreduce
         # (the pair `ptune fit` prices the comm coefficient from),
         # plus the overlap-efficiency split and the mode stamps that

@@ -482,9 +482,8 @@ class MetricsRegistry:
         return {"metrics": samples}
 
     def render_jsonl(self):
-        """One JSON object per metric sample — the format mega_bench
-        embeds into BENCH records and obs_dump writes with
-        --format jsonl."""
+        """One JSON object per metric sample — the format obs_dump
+        writes with --format jsonl."""
         return "\n".join(json.dumps(s, sort_keys=True)
                          for s in self.to_dict()["metrics"]) + "\n"
 

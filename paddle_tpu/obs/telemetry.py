@@ -537,10 +537,9 @@ def snapshot():
     """Flat {metric_name or name{labels}: value} view of the default
     registry (histograms contribute _count/_sum) — for embedding
     registry state into artifacts or asserting on it in tests.  This
-    is the flight recorder's per-step delta base, and
-    `snapshot_delta` over it is mega_bench's per-leg BENCH "metrics"
-    blob, so those artifacts carry the full registry (including the
-    per-segment xla_* memory/cost gauges)."""
+    is the flight recorder's per-step delta base, so those artifacts
+    carry the full registry (including the per-segment xla_*
+    memory/cost gauges)."""
     return snapshot_and_delta({})[0]
 
 

@@ -20,9 +20,6 @@ distributed stack the whole 2018 design existed for):
                    files + manifests) that restore WITHOUT densifying,
                    composing with the resilience supervisor for
                    preempt/auto-resume.
-  * `bench`      — the MULTICHIP_* measurement legs: img/s + MFU
-                   scaling curves over mesh shapes, comm measurements
-                   for `ptune fit`, per-host fleet telemetry.
 """
 
 from .plan import (PartitionPlan, build_partition_plan,

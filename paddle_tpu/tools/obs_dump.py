@@ -533,7 +533,7 @@ def selftest(args):
     workdir = tempfile.mkdtemp(prefix="paddle_obs_")
     obs_trace.enable(clear=True)
     # exercise the memory/cost attribution path (off by default; the
-    # serving warmup and bench suite enable it in production)
+    # serving warmup enables it in production)
     attr_prev = pt_flags.get_flag("xla_cost_attribution")
     pt_flags.set_flag("xla_cost_attribution", True)
     try:

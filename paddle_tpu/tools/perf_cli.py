@@ -67,7 +67,7 @@ def parse_args(argv=None):
                         "the verdict (floors only when absent)")
     # gate / history
     p.add_argument("--history", default="perf_history.jsonl",
-                   help="perf history path (bench.py appends here)")
+                   help="perf history path")
     p.add_argument("--metric", action="append", default=None,
                    help="restrict gate/history to metric name(s); "
                         "history treats it as a substring")
@@ -82,7 +82,7 @@ def parse_args(argv=None):
     p.add_argument("--mem-tolerance", type=float, default=None,
                    help="gate: OPT-IN relative peak-memory tolerance "
                         "over the records' \"memory\" blobs "
-                        "(bench.py stamps them; obs/mem.py) — an HBM "
+                        "(obs/mem.py) — an HBM "
                         "regression fails CI like a step-time one; "
                         "omitted = memory is not gated")
     p.add_argument("--comm-tolerance", type=float, default=None,
@@ -126,12 +126,12 @@ def cmd_classify(args):
     from paddle_tpu.obs import perf as obs_perf
 
     try:
-        # the bench model builder lives at the repo root (it is the
-        # same program bench.py times, deliberately not packaged)
+        # the model builder lives at the repo root (deliberately not
+        # packaged)
         from __graft_entry__ import _build_model
     except ImportError:
         raise SystemExit(
-            "pperf classify builds the bench models via the repo's "
+            "pperf classify builds its models via the repo's "
             "__graft_entry__ module — run it from the repo root "
             "(cd <repo> && python -m paddle_tpu.tools.perf_cli "
             "classify ...).  `pperf gate`/`history`/--selftest work "

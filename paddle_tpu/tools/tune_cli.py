@@ -1,6 +1,6 @@
 """Autotuner CLI ("ptune"): offline launch-config search over
-`paddle_tpu.tune` — rank the whole space with zero devices, measure
-only the top-K, learn from what was measured.
+`paddle_tpu.tune` — rank the whole space with zero devices, learn
+from what was measured.
 
     # the CI entry point (scripts/ci.sh, scripts/smoke.sh):
     python -m paddle_tpu.tools.tune_cli --selftest
@@ -11,12 +11,9 @@ only the top-K, learn from what was measured.
     python -m paddle_tpu.tools.tune_cli plan --model lenet5 \
         --chips 8 --hbm-gb 16 --out plan.json
 
-    # burn hardware on only the top-3 survivors (records land in
-    # perf_history.jsonl with leg ptune:<tag> + a "config" blob):
-    python -m paddle_tpu.tools.tune_cli measure --plan plan.json --topk 3
-
-    # fit the per-term correction from everything measured so far and
-    # save it; the next `plan --calibration` ranks with it:
+    # fit the per-term correction from the history's measured records
+    # of this plan's candidates (leg ptune:<tag> + a "config" blob)
+    # and save it; the next `plan --calibration` ranks with it:
     python -m paddle_tpu.tools.tune_cli fit --plan plan.json \
         --calibration ptune_cal.json
     python -m paddle_tpu.tools.tune_cli plan --model lenet5 --chips 8 \
@@ -31,13 +28,14 @@ only the top-K, learn from what was measured.
   2. **static rejection** — an injected S002-invalid mesh (batch not
      divisible by dp) and an S005 over-HBM budget are rejected at
      rank time with their exact codes, and the S002 candidate
-     provably never reaches measurement;
-  3. **measured top-K** — bench.py runs the top-2 candidates through
-     its normal AOT path; their records land in the history file
-     with `"config"` blobs and `ptune:` legs;
+     is not in the ranked list;
+  3. **history join** — synthetic records of the top-2 candidates
+     (50x the predicted floor, as a CPU reads a TPU-priced plan) go
+     through the history file with `"config"` blobs and `ptune:`
+     legs and join back to their plan entries;
   4. **calibration** — `fit` over those records reports a model error
-     that DECREASES after ingesting the measurements, and a re-rank
-     with the fitted calibration changes the predictions.
+     that DECREASES after ingesting them, and a re-rank with the
+     fitted calibration changes the predictions.
 """
 
 import argparse
@@ -58,11 +56,11 @@ def _csv_int(text):
 def parse_args(argv=None):
     p = argparse.ArgumentParser(prog="ptune")
     p.add_argument("cmd", nargs="?",
-                   choices=["plan", "measure", "fit", "report"],
+                   choices=["plan", "fit", "report"],
                    help="operator command (or use --selftest)")
     p.add_argument("--selftest", action="store_true",
-                   help="full plan->rank->measure->fit loop on lenet5 "
-                        "with a fake 8-device mesh")
+                   help="full plan->rank->fit loop on lenet5 with a "
+                        "fake 8-device mesh")
     # plan: the model + target
     p.add_argument("--model", default="lenet5",
                    help="model to tune (tune/models.py zoo)")
@@ -120,19 +118,14 @@ def parse_args(argv=None):
     p.add_argument("--out", default=None,
                    help="plan: write the launch plan JSON here")
     p.add_argument("--topk", type=int, default=None,
-                   help="plan: table rows; measure: candidates to run "
-                        "(default 3)")
+                   help="plan: table rows")
     p.add_argument("--json", action="store_true",
                    help="machine-readable output")
-    # measure / fit / report
+    # fit / report
     p.add_argument("--plan", dest="plan_path", default=None,
                    help="launch plan JSON from `ptune plan --out`")
     p.add_argument("--history", default="perf_history.jsonl",
-                   help="perf history path (bench.py appends here)")
-    p.add_argument("--iters", type=int, default=2)
-    p.add_argument("--warmup", type=int, default=1)
-    p.add_argument("--timeout", type=float, default=900,
-                   help="measure: per-candidate wall-clock bound")
+                   help="perf history path")
     return p.parse_args(argv)
 
 
@@ -183,8 +176,7 @@ def _rank_plan(args, extra_candidates=(), hbm_gb="arg"):
     builder = tune_models.builder(args.model, image_size=args.image_size,
                                   class_dim=args.class_dim)
     # the EFFECTIVE builder knobs (CLI override or model default) ride
-    # in the plan context so `ptune measure` replays the same program
-    # the ranking priced
+    # in the plan context: they say which program the ranking priced
     spec = tune_models.MODELS[args.model]
     extra_context = {
         "image_size": int(args.image_size or spec["image_size"]),
@@ -224,32 +216,6 @@ def _load_plan(args):
         return json.load(f)
 
 
-def cmd_measure(args):
-    from paddle_tpu.tune import measure as tune_measure
-
-    plan = _load_plan(args)
-    results = tune_measure.measure_plan(
-        plan, topk=args.topk or 3, history=args.history,
-        iters=args.iters, warmup=args.warmup,
-        image_size=args.image_size, timeout=args.timeout,
-        echo=lambda msg: print(msg, flush=True))
-    ok = 0
-    for r in results:
-        if r["ok"]:
-            ok += 1
-            rec = r["record"]
-            print("[ptune] %-44s %10.4g %-9s step %.2f ms (%s)"
-                  % (r["tag"], rec.get("value") or 0.0,
-                     rec.get("unit") or "", rec.get("step_ms") or 0.0,
-                     rec.get("platform")))
-        else:
-            print("[ptune] %-44s FAILED: %s" % (r["tag"], r["error"]),
-                  file=sys.stderr)
-    print("[ptune] measured %d/%d candidate(s); history: %s"
-          % (ok, len(results), args.history))
-    return 0 if ok == len(results) and results else 1
-
-
 def _join(args, plan):
     from paddle_tpu.obs import perf as obs_perf
     from paddle_tpu.tune import fit as tune_fit
@@ -266,10 +232,10 @@ def cmd_fit(args):
     pairs = _join(args, plan)
     if not pairs:
         print("[ptune] no ptune-tagged measurements in %s for this "
-              "plan — run `ptune measure` first" % args.history)
+              "plan" % args.history)
         return 2
-    # multichip comm measurements (spmd/bench.py legs) price the comm
-    # coefficient when the history has any from the training class
+    # multichip comm measurements (`multichip:<mesh>` legs) price the
+    # comm coefficient when the history has any from the training class
     comm_pairs = tune_fit.join_comm_history(
         obs_perf.load_history(args.history))
     if getattr(args, "comm_calibration", None):
@@ -382,22 +348,27 @@ def _selftest_rejections(args):
     for r in tiny.rejected:
         assert r.code == "S005" and r.peak_hbm_bytes > 0, r
         assert "GiB" in r.message and "budget" in r.message, r
-    return plan, bad
+    return plan
 
 
-def _selftest_measure_fit(args, plan, bad, workdir):
+def _selftest_history_fit(args, plan, workdir):
     from paddle_tpu.obs import perf as obs_perf
     from paddle_tpu.tune import fit as tune_fit
-    from paddle_tpu.tune import measure as tune_measure
 
+    # records as a measured run of the top-2 would leave them: the
+    # single-chip proxy of each, 50x slower than its floor
     history = os.path.join(workdir, "ptune_history.jsonl")
-    results = tune_measure.measure_plan(
-        plan, topk=2, history=history, iters=1, warmup=1,
-        extra_env={"JAX_PLATFORMS": "cpu"}, timeout=600)
-    assert len(results) == 2, results
-    for r in results:
-        assert r["ok"], "measurement failed: %r" % (r,)
-        assert r["record"]["config"]["mesh"], r["record"]
+    for e in plan.ranked[:2]:
+        c = e.candidate
+        step_s = 50 * (e.terms["compute_s"] * c.n_devices / c.dp
+                       + e.terms["overhead_s"])
+        obs_perf.append_history(
+            {"metric": "lenet5_train_imgs_per_sec_batch%d"
+                       % c.per_device_batch,
+             "value": c.per_device_batch / step_s, "unit": "img/s",
+             "step_ms": step_s * 1e3, "platform": "cpu",
+             "config": c.config("lenet5")},
+            history, leg=tune_fit.LEG_PREFIX + c.tag())
 
     # the history file carries the join keys: ptune legs + config
     records = obs_perf.load_history(history)
@@ -406,11 +377,6 @@ def _selftest_measure_fit(args, plan, bad, workdir):
         assert rec.get("leg", "").startswith(tune_fit.LEG_PREFIX), rec
         assert rec.get("config", {}).get("mesh"), \
             "history line has no config blob: %r" % rec
-    # the rejected candidate never reached measurement
-    assert not any(r.get("leg") == tune_fit.LEG_PREFIX + bad.tag()
-                   for r in records), \
-        "S002-rejected candidate was measured"
-
     # calibration: error must decrease after ingesting measurements
     pairs = tune_fit.join_history(plan, records)
     assert len(pairs) == 2, pairs
@@ -447,15 +413,15 @@ def selftest(args):
     workdir = tempfile.mkdtemp(prefix="paddle_ptune_")
     try:
         _selftest_determinism()
-        plan, bad = _selftest_rejections(args)
-        measured, cal = _selftest_measure_fit(args, plan, bad, workdir)
+        plan = _selftest_rejections(args)
+        measured, cal = _selftest_history_fit(args, plan, workdir)
     finally:
         # ci.sh/smoke.sh run this every time: don't stack /tmp dirs
         shutil.rmtree(workdir, ignore_errors=True)
 
     print("[ptune] selftest green: deterministic plan (%d candidates "
-          "ranked), S002 + S005 rejected before measurement, %d "
-          "top-K records measured into history with config blobs, "
+          "ranked), S002 + S005 rejected at rank time, %d top-K "
+          "records joined from history by their config blobs, "
           "calibration error %.1f%% -> %.1f%%"
           % (len(plan.ranked), measured, cal.error_before * 100,
              cal.error_after * 100), flush=True)
@@ -468,14 +434,12 @@ def main(argv=None):
         return selftest(args)
     if args.cmd == "plan":
         return cmd_plan(args)
-    if args.cmd == "measure":
-        return cmd_measure(args)
     if args.cmd == "fit":
         return cmd_fit(args)
     if args.cmd == "report":
         return cmd_report(args)
-    raise SystemExit("nothing to do: pass a command (plan | measure "
-                     "| fit | report) or --selftest")
+    raise SystemExit("nothing to do: pass a command (plan | fit | "
+                     "report) or --selftest")
 
 
 if __name__ == "__main__":

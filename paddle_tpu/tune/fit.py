@@ -9,8 +9,8 @@ stamped `"config"` blob), fits a per-term correction, and reports how
 wrong the model was before and after — so ranking error shrinks with
 every measured run.
 
-What gets fitted: bench.py measures a candidate's single-chip proxy
-(per-device batch slice; see tune/measure.py), so the measurable
+What gets fitted: a `ptune:<tag>` record times a candidate's
+single-chip proxy (the per-device batch slice), so the measurable
 prediction for a record is
 
     meas_pred = a * compute_s * n_devices / dp   (the slice's floor)
@@ -19,8 +19,8 @@ prediction for a record is
 and the least-squares fit learns (a, b, bias) — the multiplicative
 gap between roofline floors and reality, and the real dispatch cost.
 
-The comm term: multichip bench legs (spmd/bench.py, leg
-`multichip:<mesh>`) stamp a `comm` blob pairing the plan's analytic
+The comm term: multichip records (leg `multichip:<mesh>`) carry a
+`comm` blob pairing the plan's analytic
 ring floor (`pred_s`) with a measured grad-allreduce time
 (`measured_s`); `join_comm_history` collects those pairs and
 `fit_calibration(comm_pairs=...)` prices the comm coefficient from
@@ -143,7 +143,7 @@ def join_history(plan, records):
 
     Returns a list of {"tag", "measured_s", "meas_compute_s",
     "overhead_s", "platform", "leg"} — `meas_compute_s` is the
-    compute floor of what bench actually ran (the per-device slice),
+    compute floor of what the record timed (the per-device slice),
     i.e. compute_s rescaled from 1/n_devices to 1/dp.  Stale-platform
     records are skipped (never train on a re-emit)."""
     from ..obs import perf as obs_perf
@@ -180,7 +180,7 @@ def join_history(plan, records):
 def join_comm_history(records):
     """Comm-measurement pairs from multichip history records.
 
-    A multichip bench record (spmd/bench.py) carries a `comm` blob:
+    A multichip record carries a `comm` blob:
     `pred_s` (the partition plan's analytic ring floor for one step's
     gradient traffic) and `measured_s` (the timed bucketed
     ring-allreduce of the same gradients on the same mesh).  Returns

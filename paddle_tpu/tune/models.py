@@ -1,21 +1,21 @@
 """Model-name -> program-builder mapping for the autotuner.
 
 `tune/rank.py` scores Programs, not model names; this module turns
-the bench-suite image-model names into `builder(batch)` callables
-that construct EXACTLY the training topology bench.py measures
+the image-model names into `builder(batch)` callables that construct
+the training topology of `__graft_entry__._build_model`
 (concrete-shape feeds, softmax-with-cross-entropy loss, Momentum
-update — the `__graft_entry__._build_model` recipe), so a ranked
-prediction and its measured record describe the same program.
+update), so a ranked prediction and a measured record describe the
+same program.
 
-Kept inside the package (unlike bench.py's builder at the repo root)
-because ranking must work wheel-installed with zero devices; only
-`tune/measure.py` needs the repo checkout."""
+Kept inside the package because ranking must work wheel-installed
+with zero devices."""
 
 __all__ = ["MODELS", "builder", "model_names"]
 
 # channels / default image size / default class count per model —
 # lenet5 is the canonical 1x28x28 MNIST topology (the proglint and
-# ptune selftest flagship); the rest mirror bench.py's defaults
+# ptune selftest flagship); the rest are the reference benchmark
+# set's shapes
 MODELS = {
     "lenet5": dict(channels=1, image_size=28, class_dim=10),
     "smallnet": dict(channels=3, image_size=32, class_dim=10),
@@ -48,11 +48,11 @@ def builder(model, image_size=None, class_dim=None,
     """batch -> (main_program, loss_name) for `model`.
 
     with_startup=True returns (main, startup, loss_name) instead —
-    callers that actually RUN the program (spmd/bench.py, pshard
-    selftest) need the startup program to materialize parameters;
+    callers that actually RUN the program (the pshard selftest)
+    need the startup program to materialize parameters;
     ranking-only callers keep the two-tuple contract.
 
-    Mirrors bench.py's training program: concrete feed shapes
+    The training program: concrete feed shapes
     (append_batch_size=False, so the sharding analyzer sees the real
     batch dim), softmax_with_cross_entropy -> mean, Momentum(0.01,
     0.9).  Raises KeyError-style ValueError for unknown names so the

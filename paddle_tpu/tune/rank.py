@@ -176,7 +176,6 @@ class ScoredCandidate:
                               sorted(self.hbm_breakdown.items())
                               if isinstance(v, (int, float))},
             "warnings": dict(sorted(self.warnings.items())),
-            "bench_env": c.bench_env(model),
         }
 
 

@@ -158,9 +158,8 @@ class Candidate:
 
     Everything downstream keys off `tag()` — the stable identity the
     measurement leg name (`ptune:<tag>`) and the calibration join use
-    — and `config()`, the blob bench.py stamps into its record so a
-    measured row joins back to its candidate point without filename
-    archaeology."""
+    — and `config()`, the blob a measured record carries so that it
+    joins back to its candidate point."""
 
     __slots__ = ("mesh_spec", "pipeline", "batch", "micro_batches")
 
@@ -220,9 +219,8 @@ class Candidate:
                                    self.pipeline_label)
 
     def config(self, model=None):
-        """The candidate point as the "config" blob schema bench.py
-        stamps (tune/measure.py asserts the measured record's blob
-        matches this)."""
+        """The candidate point as the "config" blob of a measured
+        record (`tune/fit.py` joins history rows on it)."""
         cfg = {
             "mesh": self.mesh_spec,
             "batch": self.batch,
@@ -233,24 +231,6 @@ class Candidate:
         if model is not None:
             cfg["model"] = model
         return cfg
-
-    def bench_env(self, model=None):
-        """The env overrides that make bench.py measure this point's
-        single-chip proxy: the per-device batch slice, the micro-batch
-        split, the candidate's pass pipeline, and the mesh/leg tags
-        that join the record back here (`tune/measure.py` runs it;
-        the plan JSON embeds it so a plan alone reproduces the
-        measurement)."""
-        env = {
-            "BENCH_BATCH": str(self.per_device_batch),
-            "BENCH_MICRO_BATCH": str(self.micro_batches),
-            "BENCH_MESH": self.mesh_spec,
-            "BENCH_LEG": "ptune:" + self.tag(),
-            "FLAGS_compile_passes": self.pipeline,
-        }
-        if model is not None:
-            env["BENCH_MODEL"] = model
-        return env
 
     def to_dict(self):
         return {"mesh": self.mesh_spec, "pipeline": self.pipeline_label,

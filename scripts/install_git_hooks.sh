@@ -13,7 +13,7 @@ set -euo pipefail
 echo "[pre-push] fast gate (scripts/ci.sh has the full one)"
 export JAX_PLATFORMS=cpu
 export XLA_FLAGS="--xla_force_host_platform_device_count=8"
-python -m compileall -q paddle_tpu tests examples bench.py __graft_entry__.py
+python -m compileall -q paddle_tpu tests examples __graft_entry__.py
 make -C native -q || make -C native
 # the checked-in golden ProgramDescs must be well-formed IR, not just
 # byte-stable: proglint walks each fixture through the full verifier,

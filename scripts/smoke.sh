@@ -13,12 +13,6 @@ export XLA_FLAGS="--xla_force_host_platform_device_count=8"
 echo "[smoke] import paddle_tpu ..."
 python -c "import paddle_tpu; import __graft_entry__; print('  ok:', len(paddle_tpu.ops.registry.registered_ops()), 'ops registered')"
 
-# The two driver entry points, exactly as the driver invokes them.  Two
-# rounds were red because the gate never ran these.  Fresh processes,
-# no env presets beyond what this script exports.
-echo "[smoke] bench.py (1 iter, tiny shapes, AMP ON — the driver default) ..."
-BENCH_ITERS=1 BENCH_WARMUP=1 BENCH_BATCH=4 BENCH_IMAGE_SIZE=32 python bench.py
-
 echo "[smoke] serving selftest (server up, one request, /metrics, drain) ..."
 timeout 300 python -m paddle_tpu.tools.serve_cli --selftest
 
@@ -43,7 +37,7 @@ timeout 300 python -m paddle_tpu.tools.mem_cli --selftest
 echo "[smoke] pcomm selftest (comm spans, overlap split, cross-host merge, comm gate) ..."
 timeout 300 python -m paddle_tpu.tools.comm_cli --selftest
 
-echo "[smoke] ptune selftest (deterministic plan, S002/S005 rejected pre-measurement, measured top-K + calibration) ..."
+echo "[smoke] ptune selftest (deterministic plan, S002/S005 rejected at rank time, history join + calibration) ..."
 timeout 600 python -m paddle_tpu.tools.tune_cli --selftest
 
 echo "[smoke] proglint selftest (verifier + hazard detector + executor verify gate + sharding analyzer over the 4 dryrun meshes + donation A-code corruptions) ..."
@@ -57,11 +51,6 @@ _plan=$(mktemp)
 timeout 300 python -m paddle_tpu.tools.shard_cli plan --model lenet5 \
     --mesh dp=4,mp=2 --batch 64 --zero-stage 1 --out "$_plan"
 rm -f "$_plan"
-
-echo "[smoke] MULTICHIP legs (SPMD scaling across 2 mesh shapes, comm measured vs ring floor) ..."
-BENCH_MULTICHIP="dp=8|dp=4,mp=2" BENCH_MODEL=lenet5 BENCH_ITERS=2 \
-    BENCH_WARMUP=1 BENCH_PEAK_TFLOPS=0.05 \
-    timeout 600 python bench.py
 
 echo "[smoke] dryrun_multichip(8) ..."
 # The gate's copy of the driver dryrun, pinned to the virtual CPU mesh

@@ -2,7 +2,6 @@
 kernel lowers for the TPU through Mosaic, no entry point hides the
 device it ran on, and the compile cache has one fixed place."""
 
-import json
 import os
 import subprocess
 import sys
@@ -260,34 +259,35 @@ def test_chip_smoke_fails_fast_without_a_tpu():
     assert not _json_lines(proc.stdout)
 
 
-def test_bench_refuses_to_run_without_a_chip():
-    """JAX finds only the CPU and nobody asked for it: non-zero, and no
-    record that could pass for a measurement."""
-    proc = _run(["bench.py"], {"BENCH_HISTORY": "0"},
-                env_drop=("JAX_PLATFORMS",))
-    assert proc.returncode != 0
-    assert not _json_lines(proc.stdout)
-    assert "no accelerator" in proc.stderr
+def test_chip_smoke_step_agrees_with_the_executor_and_stays_put():
+    """chip_smoke's own builder and step at a toy size: the jitted
+    FunctionalProgram step with donated state gives the loss
+    `Executor.run` gives on the same feeds, and leaves every state
+    array on the device it was put on."""
+    import chip_smoke
 
+    main, startup, _, loss = chip_smoke.build_image_model(
+        "lenet5", 4, 28, 10)
+    feeds = chip_smoke.image_feeds(4, 28, 10, channels=1)
+    scope = fluid.Scope()
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(startup, scope=scope)
 
-def test_bench_runs_on_the_cpu_when_asked_to(tmp_path):
-    """JAX_PLATFORMS=cpu said out loud still runs a tiny shape, as the
-    smoke gate does, and the record names its device.  (lenet5 here:
-    the gate's own ResNet-50 shape, scripts/smoke.sh, costs tier-1 half
-    a minute of compiling for the same answer.)"""
-    proc = _run(["bench.py"], {
-        "JAX_PLATFORMS": "cpu", "BENCH_MODEL": "lenet5", "BENCH_ITERS": "1",
-        "BENCH_WARMUP": "1", "BENCH_BATCH": "4", "BENCH_HISTORY": "0",
-        "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cache")})
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    (line,) = _json_lines(proc.stdout)
-    record = json.loads(line)
-    assert record["platform"] == "cpu"
-    assert record["device_kind"] == jax.devices()[0].device_kind
-    assert record["device_count"] >= 1
-    # the CPU is not in the peaks table: no utilization, never a default
-    assert record["mfu"] is None
-    assert os.listdir(tmp_path / "cache")
+    # not the default device: an array that strayed would show
+    dev = jax.devices()[-1]
+    step, state = chip_smoke.functional_step(
+        main, ["image", "label"], loss.name, scope, dev)
+    dev_feeds = jax.device_put(feeds, dev)
+    got = []
+    for _ in range(3):
+        (fetch,), state = step(state, dev_feeds)
+        got.append(chip_smoke.scalar(fetch))
+    chip_smoke.check_on("functional state", state.values(), {dev})
+
+    want = [chip_smoke.scalar(exe.run(main, feed=feeds, fetch_list=[loss],
+                                      scope=scope)[0]) for _ in range(3)]
+    assert got == pytest.approx(want, rel=1e-5)
+    assert got[-1] < got[0]
 
 
 def test_engine_without_a_place_sits_on_the_default_device():

@@ -305,15 +305,22 @@ class TestProgramCacheEvictionMetric:
                    for r in caplog.records)
 
 
-def test_no_source_names_the_deleted_cache():
-    """The home-made executable cache is gone with its flag, its
-    option and the mode it degraded donation to.  `pcache_hits` / `pcache_misses` stay: they are keys of
-    records others read (`obs/perf.py`, the engine's warm-up stats)."""
-    gone = re.compile(
-        r"compile_cache_dir|use_pcache|effective_mode"
-        r"|pcache(?!_hits|_misses)")
-    sources = [os.path.join(REPO, "bench.py"),
-               os.path.join(REPO, "chip_smoke.py")]
+@pytest.mark.parametrize("gone", [
+    # the home-made executable cache, gone with its flag, its option and
+    # the mode it degraded donation to.  `pcache_hits` / `pcache_misses`
+    # stay: they are keys of records others read (`obs/perf.py`, the
+    # engine's warm-up stats)
+    r"compile_cache_dir|use_pcache|effective_mode"
+    r"|pcache(?!_hits|_misses)",
+    # the measuring programs from before `benchmark/run.py`, gone with
+    # the environment names that steered them
+    r"\b(BENCH|MEGA)_[A-Z]|\bbench\.py\b|mega_bench|spmd[./]bench"
+    r"|run_serving_bench|bench_decode",
+], ids=["cache", "benches"])
+def test_no_source_names_what_was_deleted(gone):
+    gone = re.compile(gone)
+    sources = [os.path.join(REPO, "chip_smoke.py"),
+               os.path.join(REPO, "__graft_entry__.py")]
     for top in ("paddle_tpu", "scripts"):
         for root, _, names in os.walk(os.path.join(REPO, top)):
             sources += [os.path.join(root, n) for n in names
@@ -327,3 +334,21 @@ def test_no_source_names_the_deleted_cache():
                       for i, line in enumerate(f, 1)
                       if gone.search(line)]
     assert not found, "\n".join(found)
+
+
+def test_the_model_builder_reads_no_layout_from_the_environment(
+        monkeypatch):
+    """`__graft_entry__._build_model` builds the Program it is asked
+    for, op for op, whatever a deleted knob says."""
+    from __graft_entry__ import _build_model
+    from paddle_tpu import models
+
+    def op_types():
+        main, _, _, _ = _build_model(models.lenet5, 4, 28, 10,
+                                     with_loss=True, channels=1)
+        return [op.type for op in main.global_block().ops]
+
+    asked = op_types()
+    monkeypatch.setenv("BENCH_LAYOUT", "NHWC")
+    assert op_types() == asked
+    assert "transpose" not in asked

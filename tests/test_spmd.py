@@ -349,24 +349,3 @@ def test_fit_prices_comm_from_multichip_pairs():
     # no multichip pairs: the comm term stays analytic, and says so
     cal = tune_fit.fit_calibration([], comm_pairs=[])
     assert cal.coef.get("comm", 1.0) == pytest.approx(1.0)
-
-
-def test_multichip_bench_record_schema(tmp_path):
-    from paddle_tpu.spmd import bench as spmd_bench
-
-    hist = str(tmp_path / "hist.jsonl")
-    rec = spmd_bench.run_leg(model="lenet5", mesh_spec="dp=8",
-                             batch=16, iters=2, warmup=1,
-                             history=hist)
-    assert rec["unit"] == "img/s" and rec["value"] > 0
-    assert rec["n_devices"] == 8 and rec["mesh"] == {"dp": 8, "mp": 1}
-    assert rec["platform_class"].startswith("cpu:d8:")
-    comm = rec["comm"]
-    assert comm["wire_bytes"] > 0 and comm["measured_s"] > 0
-    # the history line round-trips through the fit's comm join
-    from paddle_tpu.obs import perf as obs_perf
-    from paddle_tpu.tune import fit as tune_fit
-
-    (line,) = obs_perf.load_history(hist)
-    assert line["platform_class"] == rec["platform_class"]
-    assert tune_fit.join_comm_history([line])

@@ -60,7 +60,7 @@ def test_space_rejects_invalid_knobs_at_construction():
         SearchSpace(8, meshes=["dq=8"])  # unknown axis name
 
 
-def test_candidate_identity_and_bench_env():
+def test_candidate_identity():
     cand = Candidate("dp=4,mp=2", "default", batch=64, micro_batches=2)
     assert cand.n_devices == 8 and cand.dp == 4
     assert cand.per_device_batch == 16
@@ -68,9 +68,6 @@ def test_candidate_identity_and_bench_env():
     cfg = cand.config("lenet5")
     assert cfg["per_device_batch"] == 16
     assert cfg["pass_pipeline"] == "v1:dce,fold,cse,dve"
-    env = cand.bench_env("lenet5")
-    assert env["BENCH_BATCH"] == "16" and env["BENCH_MESH"] == "dp=4,mp=2"
-    assert env["BENCH_LEG"] == "ptune:" + cand.tag()
     # "none" and "" are the same pipeline, so one candidate — not two
     assert Candidate("dp=4,mp=2", "none", 64, 2) == \
         Candidate("dp=4,mp=2", "", 64, 2)
@@ -185,7 +182,6 @@ def test_rank_entries_carry_prices():
         d = e.to_dict("lenet5")
         assert d["predicted_step_ms"] > 0
         assert "comm_wire_bytes" in d and "peak_hbm_bytes" in d
-        assert d["bench_env"]["BENCH_LEG"] == "ptune:" + d["tag"]
     # ascending predicted step time
     steps = [e.predicted_step_s for e in plan.ranked]
     assert steps == sorted(steps)
@@ -424,3 +420,38 @@ def test_ptune_cli_plan_in_process(tmp_path, capsys):
         in text
     plan = json.load(open(out))
     assert plan["model"] == "lenet5" and len(plan["ranked"]) == 1
+
+
+def test_ptune_plan_entries_round_trip_through_report(tmp_path, capsys):
+    """A plan's entries are what the ranking priced and nothing that
+    tells a program how to measure them; records tagged with an
+    entry's leg join back to it through `ptune report`."""
+    from paddle_tpu.tools import tune_cli
+
+    out = str(tmp_path / "plan.json")
+    assert tune_cli.main([
+        "plan", "--model", "lenet5", "--chips", "4", "--meshes",
+        "dp=4,mp=1", "--batches", "32", "--micro-batches", "1,2",
+        "--pipelines", "none", "--hbm-gb", "16", "--f32", "--out",
+        out]) == 0
+    plan = json.load(open(out))
+    assert len(plan["ranked"]) == 2
+    for entry in plan["ranked"]:
+        assert set(entry) == {
+            "tag", "config", "predicted_step_ms",
+            "predicted_samples_per_sec", "terms_ms", "comm_wire_bytes",
+            "peak_hbm_bytes", "hbm_breakdown", "warnings"}
+        assert entry["config"]["model"] == "lenet5"
+
+    history = str(tmp_path / "history.jsonl")
+    for entry in plan["ranked"]:
+        obs_perf.append_history(
+            {"metric": "m", "value": 1.0, "platform": "cpu",
+             "step_ms": 50 * entry["predicted_step_ms"],
+             "config": entry["config"]},
+            history, leg=tune_fit.LEG_PREFIX + entry["tag"])
+    capsys.readouterr()
+    assert tune_cli.main(["report", "--plan", out, "--history", history,
+                          "--json"]) == 0
+    report = json.loads(capsys.readouterr().out)
+    assert report["pairs"] == 2 and report["median_rel_error"] > 0.9
