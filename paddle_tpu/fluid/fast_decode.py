@@ -52,7 +52,10 @@ class ProgramDecoder:
     prompt and the generated tokens continue from there.  `max_positions`
     is checked against the prompt and `max_len` alone (`_check_extent`):
     the caller of such a call answers for pos + prompt + max_len - 1 <=
-    max_positions.
+    max_positions.  It is the extent of the *positions*; what a state
+    feed holds of them is its own declaration in the Program (a window
+    layer's ring holds `window` slots whatever the extent), and a state
+    handed in is held to that, feed by feed.
 
     A step that can take a block of positions says so in its Program:
     its token feed is declared [batch, -1], it advances its state by
@@ -80,6 +83,13 @@ class ProgramDecoder:
         # [batch, -1] for a step that takes a block of positions
         self._takes_block = len(
             program.global_block().var(token_name).shape) == 2
+        # what the Program declares of each state feed past its rows: a
+        # step's caches need not be of one extent (a window layer's ring
+        # beside a full layer's whole extent), so each feed is held to
+        # its own declaration, not to `max_positions`
+        block = program.global_block()
+        self._declared = {f: tuple(block.var(f).shape)
+                          for f, _ in self.state_pairs if block.has_var(f)}
         # the scope's device arrays as they are: a round trip through
         # the host would hold every weight twice on the device until
         # the scope lets go of its own
@@ -126,6 +136,16 @@ class ProgramDecoder:
                 "init_state has keys %s that are not in state_pairs %s"
                 % (extra, sorted(known)))
         state = {f: jnp.asarray(np.asarray(v)) for f, v in state.items()}
+        for f, value in state.items():
+            declared = self._declared.get(f, ())
+            if len(declared) == value.ndim and any(
+                    d > 0 and d != n for d, n in
+                    zip(declared[1:], value.shape[1:])):
+                raise ValueError(
+                    "init_state[%r] is %s, the step program declares %s: "
+                    "a cache is handed in at the extent its layer "
+                    "declares (a window layer's ring, a full layer's "
+                    "whole extent)" % (f, value.shape, declared))
         if batch_size is None:
             if not state:
                 raise ValueError(

@@ -35,20 +35,34 @@ __all__ = [
 
 
 def cached_attention(query, key, value, k_cache, v_cache, position,
-                     num_heads=1, sm_scale=None, name=None):
+                     num_heads=1, sm_scale=None, num_kv_heads=None,
+                     window=0, name=None):
     """Attention through a KV cache over a block of T >= 1 consecutive
     positions of every row (ops/attention.py cached_attention; T = 1 is
-    a decode step): query/key/value [batch, T, dim], caches [batch,
-    heads, max_len, head_dim], position int [1] or [batch], the slot
-    the block's first position writes (query i attends slots 0 ..
-    position + i).  T may be left open (-1) in the Program.  Returns
-    (out [batch, T, dim], k_cache_out, v_cache_out) — thread the cache
-    outputs back as decode state (`fluid.ProgramDecoder` state
-    pairs)."""
+    a decode step): query [batch, T, num_heads * head_dim], key/value
+    [batch, T, kv heads * head_dim], caches [batch, kv heads, slots,
+    head_dim], position int [1] or [batch], the position of the block's
+    first entry (query i attends slots 0 .. position + i).  T may be
+    left open (-1) in the Program.  `num_kv_heads` fewer than
+    `num_heads`: grouped-query attention, query head j reads key/value
+    head j // (num_heads / num_kv_heads).  `window` > 0 (T = 1): the
+    caches are rings of `window` slots written at position mod window,
+    and a query sees itself and the window - 1 positions before it.
+    Returns (out [batch, T, num_heads * head_dim], k_cache_out,
+    v_cache_out) — thread the cache outputs back as decode state
+    (`fluid.ProgramDecoder` state pairs)."""
     helper = LayerHelper("cached_attention", name=name)
     out = helper.create_tmp_variable(query.dtype)
     kc_out = helper.create_tmp_variable(k_cache.dtype)
     vc_out = helper.create_tmp_variable(v_cache.dtype)
+    attrs = {"num_heads": int(num_heads),
+             "sm_scale": float(sm_scale or 0.0)}
+    # said only where asked for: a Program without them is, attr for
+    # attr, the Program it was
+    if num_kv_heads and int(num_kv_heads) != int(num_heads):
+        attrs["num_kv_heads"] = int(num_kv_heads)
+    if window:
+        attrs["window"] = int(window)
     helper.append_op(
         type="cached_attention",
         inputs={"Q": [query], "KNew": [key], "VNew": [value],
@@ -56,8 +70,7 @@ def cached_attention(query, key, value, k_cache, v_cache, position,
                 "Position": [position]},
         outputs={"Out": [out], "KCacheOut": [kc_out],
                  "VCacheOut": [vc_out]},
-        attrs={"num_heads": int(num_heads),
-               "sm_scale": float(sm_scale or 0.0)})
+        attrs=attrs)
     return out, kc_out, vc_out
 
 

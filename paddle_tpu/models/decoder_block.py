@@ -1,13 +1,16 @@
 """What the decoder builders share (`looped_program.py`,
-`moe_program.py`): the post-2023 block's bias-free projection, its
-RMSNorm with a named scale, and its attention sub-layer, from
+`moe_program.py`, and the cached steps `latent_moe_program.py` and
+`window_moe_program.py`): the post-2023 block's bias-free projection,
+its RMSNorm with a named scale, its attention sub-layer and the
+feed-forward half of one chip's share of an expert model, from
 `fluid.layers` alone.  Every parameter is created by the name it is
 given, so a builder decides what is shared."""
 
 from .. import fluid
 from ..fluid.param_attr import ParamAttr
 
-__all__ = ["linear", "norm", "attention", "gated_feed_forward"]
+__all__ = ["linear", "norm", "attention", "gated_feed_forward",
+           "share_feed_forward"]
 
 
 def linear(x, size, name):
@@ -70,3 +73,42 @@ def gated_feed_forward(u, width, names):
         linear(u, 2 * width, names["w_in"]), 2, dim=-1)
     return linear(fluid.layers.swish(gate) * up, u.shape[-1],
                   names["w_out"])
+
+
+def share_feed_forward(u, block, dense, d_ff, d_expert, n_experts, held,
+                       top_k, norm_topk, routed_scale, router_bias=False,
+                       n_group=0, topk_group=0):
+    """The feed-forward half of one layer of one chip's share of a
+    sigmoid-routed expert model (the DeepSeek-V3 family's, which
+    openPangu-Ultra-MoE, DeepSeek-V3.2 and K-EXAONE carry to the
+    number), for u [batch, seq, hidden], already normed; `block` names
+    the layer's parameters.  Returns (F(u), routing).
+
+    `dense`: the gated feed-forward of width `d_ff` (`ffn_in`,
+    `ffn_out`), and `routing` is None.  Otherwise a shared expert of
+    width `d_expert` (`shared_in`, `shared_out`) beside a routed layer
+    (`fluid.layers.moe`: sigmoid scores over `n_experts`, `top_k` a
+    token chosen by score plus `router_bias` inside the best
+    `topk_group` of `n_group` groups, the chosen weights normalised and
+    times `routed_scale`) that holds the experts `held` = (first,
+    count) of those its router scores; `routing` is {"top_w",
+    "top_idx", "counts": the router's Variables, "moe_in": u, "moe_out":
+    the held experts' part}."""
+    if dense:
+        return gated_feed_forward(u, d_ff, {"w_in": block["ffn_in"],
+                                            "w_out": block["ffn_out"]}), None
+    m, _, _, routing = fluid.layers.moe(
+        u, n_experts, d_expert, top_k,
+        *(ParamAttr(name=block[w])
+          for w in ("router", "w_gate", "w_up", "w_down")),
+        scoring="sigmoid", norm_topk=norm_topk, scale=routed_scale,
+        held=held,
+        bias_attr=ParamAttr(name=block["router_bias"])
+        if router_bias else None,
+        n_group=n_group, topk_group=topk_group)
+    f = gated_feed_forward(
+        u, d_expert, {"w_in": block["shared_in"],
+                      "w_out": block["shared_out"]}) + m
+    return f, dict({key: routing[key]
+                    for key in ("top_w", "top_idx", "counts")},
+                   moe_in=u, moe_out=m)

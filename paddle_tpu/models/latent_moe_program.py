@@ -45,7 +45,7 @@ The equations are in `models/reference/pangu_moe.py` and
 from .. import fluid
 from ..fluid.param_attr import ParamAttr
 from ..ops.attention import yarn_inv_freq, yarn_mscale
-from .decoder_block import gated_feed_forward, linear, norm
+from .decoder_block import linear, norm, share_feed_forward
 
 __all__ = ["build_latent_moe_cached_step_program", "latent_moe_param_names"]
 
@@ -181,27 +181,12 @@ def build_latent_moe_cached_step_program(
             a = x + (norm(o, eps, block["post_attn_norm"])
                      if sandwich_norm else o)
             u = norm(a, eps, block["pre_mlp_norm"])
-            if i < n_dense:
-                f = gated_feed_forward(u, d_ff, {"w_in": block["ffn_in"],
-                                                 "w_out": block["ffn_out"]})
-            else:
-                m, _, _, routing = fluid.layers.moe(
-                    u, n_experts, d_expert, top_k,
-                    *(ParamAttr(name=block[w])
-                      for w in ("router", "w_gate", "w_up", "w_down")),
-                    scoring="sigmoid", norm_topk=norm_topk,
-                    scale=routed_scale,
-                    held=held,
-                    bias_attr=ParamAttr(name=block["router_bias"])
-                    if router_bias else None,
-                    n_group=n_group, topk_group=topk_group)
-                f = gated_feed_forward(
-                    u, d_expert, {"w_in": block["shared_in"],
-                                  "w_out": block["shared_out"]}) + m
-                for key in ("top_w", "top_idx", "counts"):
-                    parts[key].append(routing[key])
-                parts["moe_in"].append(u)
-                parts["moe_out"].append(m)
+            f, routing = share_feed_forward(
+                u, block, i < n_dense, d_ff, d_expert, n_experts, held,
+                top_k, norm_topk, routed_scale, router_bias, n_group,
+                topk_group)
+            for key, value in (routing or {}).items():
+                parts[key].append(value)
             x = a + (norm(f, eps, block["post_mlp_norm"])
                      if sandwich_norm else f)
             parts["hidden"].append(x)

@@ -243,21 +243,42 @@ def cached_attention_op(ctx, ins, attrs):
     (O(1) work per token instead of re-attending the whole window), a
     prompt's prefill takes many positions at once.
 
-    Q/KNew/VNew: [batch, T, dim] (the block's projections);
-    KCache/VCache: [batch, heads, max_len, head_dim]; Position: int
-    [1] or [batch] (lockstep rows), the slot the block's first position
-    writes: slots Position .. Position + T - 1 are written, and query i
-    of the block attends slots 0 .. Position + i.
-    Outputs the attended context [batch, T, dim] and the updated
-    caches — wire them as ProgramDecoder state pairs.  Generation
-    never needs gradients (matching the reference's host-side
+    Q: [batch, T, heads * head_dim]; KNew/VNew: [batch, T, kv_heads *
+    head_dim] (the block's projections); KCache/VCache: [batch,
+    kv_heads, slots, head_dim]; Position: int [1] or [batch] (lockstep
+    rows), the position of the block's first entry.
+    Outputs the attended context [batch, T, heads * head_dim] and the
+    updated caches — wire them as ProgramDecoder state pairs.
+    Generation never needs gradients (matching the reference's host-side
     generation loop), so the op stops them.
 
-    No operand is narrower at T > 1 than at T = 1: both products read
-    their operands as float32 at the highest precision (on the TPU the
-    default rounds a float32 operand to bfloat16, the probabilities
-    among them; a one-row product never reaches the MXU and is exact
-    anyway), the mask and the softmax are float32.
+    `num_kv_heads` (0: as many as `num_heads`) fewer than `num_heads` is
+    grouped-query attention: query head j reads key/value head j //
+    (num_heads / num_kv_heads), by index; no key or value is repeated in
+    memory.
+
+    `window` 0: the cache holds the whole extent, slots Position ..
+    Position + T - 1 are written, and query i of the block attends
+    slots 0 .. Position + i.  `window` > 0 (T = 1 only): the cache is a
+    ring of `window` slots, the step writes slot Position mod window and
+    attends the min(Position + 1, window) slots that hold something:
+    the query sees itself and the window - 1 positions before it,
+    whatever the sequence's length (a softmax does not care in which
+    order the ring holds them).
+
+    Scopes: `kv_write` the caches' update, `attn_window` or `attn_full`
+    everything between the caches and Out.  T = 1 over 128-wide heads
+    and a multiple of 128 slots walks the live slots alone
+    (kernels/gqa_decode.py: operands in Q's type, float32 sums and
+    softmax); every other shape takes the plain path, scores over every
+    slot under a mask.
+
+    No operand is narrower at T > 1 than at T = 1 on the plain path:
+    both products read their operands as float32 at the highest
+    precision (on the TPU the default rounds a float32 operand to
+    bfloat16, the probabilities among them; a one-row product never
+    reaches the MXU and is exact anyway), the mask and the softmax are
+    float32.
     """
     q, k_new, v_new = ins["Q"][0], ins["KNew"][0], ins["VNew"][0]
     k_cache, v_cache = ins["KCache"][0], ins["VCache"][0]
@@ -265,35 +286,75 @@ def cached_attention_op(ctx, ins, attrs):
     # a per-row vector is what beam expansion produces)
     pos = jnp.reshape(ins["Position"][0], (-1,))[0].astype(jnp.int32)
     num_heads = int(attrs.get("num_heads", 1))
+    kv_heads = int(attrs.get("num_kv_heads", 0)) or num_heads
+    window = int(attrs.get("window", 0))
     sm_scale = float(attrs.get("sm_scale", 0.0)) or None
     rows, block, width = q.shape
     extent = k_cache.shape[2]
-    telemetry.on_cached_attention_lowering(block)
+    if num_heads % kv_heads or k_cache.shape[1] != kv_heads \
+            or (window and (window != extent or block != 1)):
+        raise ValueError(
+            "cached_attention: %d query heads over %d key/value heads, "
+            "caches %s, window %d, a block of %d positions: the heads do "
+            "not group, the cache is not those heads' or not the "
+            "window's ring, or a ring is given more than one position"
+            % (num_heads, kv_heads, k_cache.shape, window, block))
+    group = num_heads // kv_heads
+    kind = "window" if window else "full"
 
     # [B, T, H * Dh] -> [B, H, T, Dh]: kernels/flash_attention.py has
     # the same two lines behind an import of Pallas, which a decoder
     # would pay at its first trace for a reshape
-    qh, kh, vh = (
-        x.reshape(rows, block, num_heads, -1).transpose(0, 2, 1, 3)
-        for x in (q, k_new, v_new))
+    qh = q.reshape(rows, block, num_heads, -1).transpose(0, 2, 1, 3)
+    kh, vh = (x.reshape(rows, block, kv_heads, -1).transpose(0, 2, 1, 3)
+              for x in (k_new, v_new))
+    head_dim = qh.shape[-1]
     if sm_scale is None:
-        sm_scale = qh.shape[-1] ** -0.5
+        sm_scale = head_dim ** -0.5
+    # the walk of the live slots where what the op sees of its inputs
+    # fits it (128-wide heads: Pallas is imported for those alone)
+    block_k = 0
+    if block == 1 and head_dim == 128:
+        from ..kernels import gqa_decode
+        if gqa_decode.fits(block, extent, head_dim):
+            block_k = gqa_decode.choose_block(extent)
+    telemetry.on_cached_attention_lowering(block)
+    telemetry.on_window_attention_lowering(
+        kind, kv_heads, window, "kernel" if block_k else "plain", block_k,
+        extent)
 
-    k_cache = jax.lax.dynamic_update_slice_in_dim(
-        k_cache, kh.astype(k_cache.dtype), pos, axis=2)
-    v_cache = jax.lax.dynamic_update_slice_in_dim(
-        v_cache, vh.astype(v_cache.dtype), pos, axis=2)
+    with jax.named_scope("kv_write"):
+        at = pos % window if window else pos
+        k_cache = jax.lax.dynamic_update_slice_in_dim(
+            k_cache, kh.astype(k_cache.dtype), at, axis=2)
+        v_cache = jax.lax.dynamic_update_slice_in_dim(
+            v_cache, vh.astype(v_cache.dtype), at, axis=2)
 
-    highest = jax.lax.Precision.HIGHEST
-    s = jnp.einsum("bhqd,bhkd->bhqk", qh.astype(jnp.float32),
-                   k_cache.astype(jnp.float32),
-                   precision=highest) * sm_scale
-    valid = jnp.arange(extent)[None, :] <= pos + jnp.arange(block)[:, None]
-    s = jnp.where(valid[None, None], s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhqk,bhkd->bhqd", p, v_cache.astype(jnp.float32),
-                     precision=highest)
-    out = out.transpose(0, 2, 1, 3).reshape(rows, block, width)
+    with jax.named_scope("attn_" + kind):
+        # the last live slot: of a ring, all of it once it has wrapped
+        last = jnp.minimum(pos, window - 1) if window else pos
+        if block_k:
+            # a cache in a narrower type than the products' is read up
+            out = gqa_decode.gqa_decode(
+                qh.reshape(rows, kv_heads, group, head_dim),
+                k_cache.astype(q.dtype), v_cache.astype(q.dtype), last,
+                sm_scale, window, block_k)
+            out = out.reshape(rows, block, width)
+        else:
+            if group > 1:   # [B, KV, G, T, Dh]: a group beside its head
+                qh = qh.reshape(rows, kv_heads, group, block, head_dim)
+            highest = jax.lax.Precision.HIGHEST
+            s = jnp.einsum("bh...qd,bhkd->bh...qk", qh.astype(jnp.float32),
+                           k_cache.astype(jnp.float32),
+                           precision=highest) * sm_scale
+            valid = jnp.arange(extent)[None, :] \
+                <= last + jnp.arange(block)[:, None]
+            s = jnp.where(valid[(None,) * (s.ndim - 2)], s, -1e30)
+            p = jax.nn.softmax(s, axis=-1)
+            out = jnp.einsum("bh...qk,bhkd->bh...qd", p,
+                             v_cache.astype(jnp.float32), precision=highest)
+            out = out.reshape(rows, num_heads, block, head_dim) \
+                .transpose(0, 2, 1, 3).reshape(rows, block, width)
     return {"Out": [out.astype(q.dtype)],
             "KCacheOut": [k_cache], "VCacheOut": [v_cache]}
 
