@@ -61,7 +61,8 @@ class ProgramDecoder:
     its token feed is declared [batch, -1], it advances its state by
     the T >= 1 consecutive tokens of every row it is fed, and its
     logits are those of the last (`models/transformer_program.py
-    build_transformer_cached_step_program`).  The decoder then feeds
+    build_transformer_cached_step_program`, `models/window_moe_program.py
+    build_window_moe_cached_step_program`).  The decoder then feeds
     [rows, 1] at every decode step and beam row, and prefills a prompt
     `models.decode.PREFILL_BLOCK` positions an application instead of
     one.  Nothing but the declaration decides it.

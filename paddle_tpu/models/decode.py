@@ -23,9 +23,14 @@ NEG_INF = -1e30
 
 
 # The positions of a row that one application of a block-taking step
-# prefills.  Swept on the chip (PERF.md section 5, gpt2m-decode): the
-# float32 scores of a block, [rows, heads, block, extent], size it, not
-# the FLOPs.
+# prefills.  Swept on the chip (PERF.md section 5, gpt2m-decode): on the
+# op's plain path the float32 scores of a block, [rows, heads, block,
+# extent], size it, not the FLOPs.  Where `cached_attention` walks the
+# live slots (kernels/gqa_decode.py: a whole-extent cache of 128-wide
+# heads) no such array exists, and the same block is what keeps a
+# key/value head's group of queries, [group * block, 128], and its
+# scores over a block of slots resident in VMEM (1024 rows at a group of
+# 8: exaone-turn-32k-ep16's question is one application).
 PREFILL_BLOCK = 128
 
 

@@ -269,25 +269,27 @@ def on_cached_attention_lowering(block):
 
 
 def on_window_attention_lowering(kind, kv_heads, window, path, block_k,
-                                 slots):
+                                 slots, block):
     """A `cached_attention` op (ops/attention.py) was traced into a
     program: over which kind of cache ("window": a ring of `window`
     slots; "full": the whole extent, `window` 0), with how many
-    key/value heads, and which way it takes over the cache ("kernel":
-    the walk of the live slots in blocks of `block_k`,
+    key/value heads, over how many positions of a row an application
+    (`block`; 1: a decode step), and which way it takes over the cache
+    ("kernel": the walk of the live slots in blocks of `block_k`,
     kernels/gqa_decode.py; "plain": scores over every slot under a mask,
     `block_k` 0); and the `slots` a row of its cache holds, added up by
     kind.  One count per op instance a lowered program holds."""
     _reg().counter("window_attention_lowerings_total",
                    "key/value-cached attention ops lowered, by the kind "
                    "of cache (a window's ring or the full extent), "
-                   "key/value heads, window, path (the kernel over the "
-                   "live slots, or the plain products) and the kernel's "
-                   "block of slots",
-                   labelnames=("kind", "kv_heads", "window", "path",
-                               "block_k")) \
-          .labels(kind=kind, kv_heads=kv_heads, window=window, path=path,
-                  block_k=block_k).inc()
+                   "key/value heads, window, the positions of a row one "
+                   "application takes, path (the kernel over the live "
+                   "slots, or the plain products) and the kernel's block "
+                   "of slots",
+                   labelnames=("kind", "kv_heads", "window", "block",
+                               "path", "block_k")) \
+          .labels(kind=kind, kv_heads=kv_heads, window=window, block=block,
+                  path=path, block_k=block_k).inc()
     _reg().counter("kv_cache_slots_total",
                    "slots a row's key/value caches hold in the lowered "
                    "cached_attention ops, by the kind of cache",

@@ -259,19 +259,31 @@ def cached_attention_op(ctx, ins, attrs):
 
     `window` 0: the cache holds the whole extent, slots Position ..
     Position + T - 1 are written, and query i of the block attends
-    slots 0 .. Position + i.  `window` > 0 (T = 1 only): the cache is a
-    ring of `window` slots, the step writes slot Position mod window and
-    attends the min(Position + 1, window) slots that hold something:
-    the query sees itself and the window - 1 positions before it,
-    whatever the sequence's length (a softmax does not care in which
-    order the ring holds them).
+    slots 0 .. Position + i.  `window` > 0: the cache is a ring of
+    `window` slots, position p lives in slot p mod window, and a query
+    sees itself and the window - 1 positions before it, whatever the
+    sequence's length (a softmax does not care in which order the ring
+    holds them).  A step (T = 1) writes its slot and attends the
+    min(Position + 1, window) slots that hold something.  A block (T >
+    1, any T against any window) attends before it writes: query i
+    reads the ring as it stood before the block, the slots whose
+    position is at least Position + i - window + 1 (and that were ever
+    written), beside the block's own keys j with i - window < j <= i,
+    in the type the ring would have held them in, under one mask and one
+    softmax; then the block's last min(T, window) entries go to slots
+    (Position + j) mod window: the ring holds, slot for slot, what T
+    single steps leave there.
 
     Scopes: `kv_write` the caches' update, `attn_window` or `attn_full`
-    everything between the caches and Out.  T = 1 over 128-wide heads
-    and a multiple of 128 slots walks the live slots alone
+    everything between the caches and Out.  Over 128-wide heads and a
+    multiple of 128 slots the live slots alone are walked
     (kernels/gqa_decode.py: operands in Q's type, float32 sums and
-    softmax); every other shape takes the plain path, scores over every
-    slot under a mask.
+    softmax): a step over either kind of cache, and a block over a
+    whole extent where its group's T queries a key/value head fit the
+    kernel (its float32 scores on the plain path would be [batch, heads,
+    T, slots]); every other shape takes the plain path, scores over
+    every slot under a mask (a block through a ring: over `window` + T
+    keys).
 
     No operand is narrower at T > 1 than at T = 1 on the plain path:
     both products read their operands as float32 at the highest
@@ -292,15 +304,15 @@ def cached_attention_op(ctx, ins, attrs):
     rows, block, width = q.shape
     extent = k_cache.shape[2]
     if num_heads % kv_heads or k_cache.shape[1] != kv_heads \
-            or (window and (window != extent or block != 1)):
+            or (window and window != extent):
         raise ValueError(
             "cached_attention: %d query heads over %d key/value heads, "
-            "caches %s, window %d, a block of %d positions: the heads do "
-            "not group, the cache is not those heads' or not the "
-            "window's ring, or a ring is given more than one position"
-            % (num_heads, kv_heads, k_cache.shape, window, block))
+            "caches %s, window %d: the heads do not group, or the cache "
+            "is not those heads' or not the window's ring"
+            % (num_heads, kv_heads, k_cache.shape, window))
     group = num_heads // kv_heads
     kind = "window" if window else "full"
+    ring_block = window > 0 and block > 1
 
     # [B, T, H * Dh] -> [B, H, T, Dh]: kernels/flash_attention.py has
     # the same two lines behind an import of Pallas, which a decoder
@@ -314,21 +326,26 @@ def cached_attention_op(ctx, ins, attrs):
     # the walk of the live slots where what the op sees of its inputs
     # fits it (128-wide heads: Pallas is imported for those alone)
     block_k = 0
-    if block == 1 and head_dim == 128:
+    if head_dim == 128 and not ring_block:
         from ..kernels import gqa_decode
-        if gqa_decode.fits(block, extent, head_dim):
-            block_k = gqa_decode.choose_block(extent)
+        block_k = gqa_decode.choose_block(extent, group * block,
+                                          q.dtype.itemsize)
     telemetry.on_cached_attention_lowering(block)
     telemetry.on_window_attention_lowering(
         kind, kv_heads, window, "kernel" if block_k else "plain", block_k,
-        extent)
+        extent, block)
 
     with jax.named_scope("kv_write"):
-        at = pos % window if window else pos
-        k_cache = jax.lax.dynamic_update_slice_in_dim(
-            k_cache, kh.astype(k_cache.dtype), at, axis=2)
-        v_cache = jax.lax.dynamic_update_slice_in_dim(
-            v_cache, vh.astype(v_cache.dtype), at, axis=2)
+        before = k_cache, v_cache
+        if ring_block:
+            k_cache, v_cache = (_ring_write(cache, new, pos) for cache, new
+                                in ((k_cache, kh), (v_cache, vh)))
+        else:
+            at = pos % window if window else pos
+            k_cache = jax.lax.dynamic_update_slice_in_dim(
+                k_cache, kh.astype(k_cache.dtype), at, axis=2)
+            v_cache = jax.lax.dynamic_update_slice_in_dim(
+                v_cache, vh.astype(v_cache.dtype), at, axis=2)
 
     with jax.named_scope("attn_" + kind):
         # the last live slot: of a ring, all of it once it has wrapped
@@ -336,27 +353,62 @@ def cached_attention_op(ctx, ins, attrs):
         if block_k:
             # a cache in a narrower type than the products' is read up
             out = gqa_decode.gqa_decode(
-                qh.reshape(rows, kv_heads, group, head_dim),
+                qh.reshape(rows, kv_heads, group * block, head_dim),
                 k_cache.astype(q.dtype), v_cache.astype(q.dtype), last,
-                sm_scale, window, block_k)
-            out = out.reshape(rows, block, width)
+                sm_scale, window, block_k, block)
         else:
+            keys, values, valid = _ring_before_a_block(
+                before, (kh, vh), pos) if ring_block \
+                else (k_cache, v_cache, None)
             if group > 1:   # [B, KV, G, T, Dh]: a group beside its head
                 qh = qh.reshape(rows, kv_heads, group, block, head_dim)
             highest = jax.lax.Precision.HIGHEST
             s = jnp.einsum("bh...qd,bhkd->bh...qk", qh.astype(jnp.float32),
-                           k_cache.astype(jnp.float32),
+                           keys.astype(jnp.float32),
                            precision=highest) * sm_scale
-            valid = jnp.arange(extent)[None, :] \
-                <= last + jnp.arange(block)[:, None]
+            if valid is None:
+                valid = jnp.arange(extent)[None, :] \
+                    <= last + jnp.arange(block)[:, None]
             s = jnp.where(valid[(None,) * (s.ndim - 2)], s, -1e30)
             p = jax.nn.softmax(s, axis=-1)
             out = jnp.einsum("bh...qk,bhkd->bh...qd", p,
-                             v_cache.astype(jnp.float32), precision=highest)
-            out = out.reshape(rows, num_heads, block, head_dim) \
-                .transpose(0, 2, 1, 3).reshape(rows, block, width)
+                             values.astype(jnp.float32), precision=highest)
+        out = out.reshape(rows, num_heads, block, head_dim) \
+            .transpose(0, 2, 1, 3).reshape(rows, block, width)
     return {"Out": [out.astype(q.dtype)],
             "KCacheOut": [k_cache], "VCacheOut": [v_cache]}
+
+
+def _ring_write(cache, new, pos):
+    """The ring [B, KV, window, Dh] after the block `new` [B, KV, T, Dh]
+    from position `pos` on: slot s takes the block's last entry j with
+    (pos + j) mod window = s, in the ring's type, and keeps what it
+    holds where the block has none (T < window)."""
+    window, block = cache.shape[2], new.shape[2]
+    j = block - 1 - (pos + block - 1 - jnp.arange(window)) % window
+    taken = jnp.take(new, jnp.maximum(j, 0), axis=2).astype(cache.dtype)
+    return jnp.where((j >= 0)[:, None], taken, cache)
+
+
+def _ring_before_a_block(caches, new, pos):
+    """(keys, values [B, KV, window + T, Dh], valid [T, window + T]) of a
+    block of T positions from `pos` on over a ring as it stood before
+    the block: the ring's slots, then the block's own entries rounded to
+    the ring's type (what a step would read back from its slot).  Slot s
+    holds position pos - 1 - (pos - 1 - s) mod window, negative where
+    it was never written; query i attends the positions pos + i - window
+    + 1 .. pos + i."""
+    window, block = caches[0].shape[2], new[0].shape[2]
+    held = pos - 1 - (pos - 1 - jnp.arange(window)) % window
+    i = jnp.arange(block)[:, None]
+    own = jnp.arange(block)[None, :]
+    valid = jnp.concatenate(
+        [(held >= 0)[None, :] & (held[None, :] > pos + i - window),
+         (own <= i) & (own > i - window)], axis=1)
+    keys, values = (jnp.concatenate([cache, entry.astype(cache.dtype)],
+                                    axis=2)
+                    for cache, entry in zip(caches, new))
+    return keys, values, valid
 
 
 def _set_meta(block, name, shape, dtype):

@@ -71,7 +71,9 @@ def _set_meta(block, name, shape, dtype):
 
 
 def _tokens(shape):
-    return math.prod(shape[:-1])
+    """The rows of X [..., hidden]; -1 where the Program leaves an axis
+    open."""
+    return -1 if min(shape[:-1]) < 0 else math.prod(shape[:-1])
 
 
 def _rows_an_expert(top_idx, experts):

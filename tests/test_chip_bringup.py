@@ -237,6 +237,39 @@ def test_the_latent_decode_kernel_lowers_for_tpu():
         jax.jit(step), platforms=["tpu"])(chosen).mlir_module()
 
 
+@pytest.mark.parametrize("block,window,kernels", [
+    (1, 0, ["gqa_decode_k2048"]), (1, 128, ["gqa_decode_w128"]),
+    (128, 0, ["gqa_decode_k1024_t128"]), (128, 128, [])])
+def test_the_grouped_decode_kernel_lowers_for_tpu(block, window, kernels):
+    """`cached_attention` at exaone-turn-32k-ep16's shapes (8 rows, 64
+    query heads over 8 key/value heads of 128, a 32,768-slot cache or a
+    128-slot ring, bfloat16) lowered for the TPU from this CPU host: a
+    decode step walks the live slots of either cache, a block of 128
+    positions those of the whole extent (the kernel's name says the
+    block of slots and the positions), and a block through a ring holds
+    no kernel."""
+    from paddle_tpu.ops import registry
+
+    kernel = registry.get_op_info("cached_attention").kernel
+    b, h, kv, d, bf16 = 8, 64, 8, 128, jnp.bfloat16
+    cache = jax.ShapeDtypeStruct((b, kv, window or 32768, d), bf16)
+    ins = {"Q": [jax.ShapeDtypeStruct((b, block, h * d), bf16)],
+           "KNew": [jax.ShapeDtypeStruct((b, block, kv * d), bf16)],
+           "VNew": [jax.ShapeDtypeStruct((b, block, kv * d), bf16)],
+           "KCache": [cache], "VCache": [cache],
+           "Position": [jax.ShapeDtypeStruct((b,), jnp.int32)]}
+
+    def step(ins):
+        return kernel(None, ins, {"num_heads": h, "num_kv_heads": kv,
+                                  "window": window})
+
+    module = jax.export.export(jax.jit(step), platforms=["tpu"])(
+        ins).mlir_module()
+    assert module.count("tpu_custom_call") == len(kernels)
+    for name in kernels:
+        assert 'kernel_name = "%s"' % name in module
+
+
 def test_flash_attention_refuses_a_ragged_block():
     """A sequence its block does not divide raises with the shape in the
     message; the block no longer shrinks toward 1 without saying so."""

@@ -3,11 +3,14 @@ of two shapes (`cached_attention`'s `num_kv_heads` and `window`, the
 decode kernel kernels/gqa_decode.py), and the cached step Program built
 on them (models/window_moe_program.py) against the plain float32
 reference (models/reference/exaone_moe.py): the op against plain masked
-attention; the kernel under the interpreter against the plain path; the
-step driven from empty caches and from a session handed in against the
-reference's full forward; the shares of an expert layer adding up to the
-uncut layer; what the PR must leave as it was (the latent builder's
-Programs, GPT-2's lowering of `cached_attention`); the counters.
+attention, a block of positions through a ring against as many single
+steps; the kernel under the interpreter against the plain path, a step
+and a block of queries; the step driven from empty caches and from a
+session handed in against the reference's full forward, and a prompt
+prefilled in blocks against the same prompt a position an application;
+the shares of an expert layer adding up to the uncut layer; what must
+stay as it was (the latent builder's Programs, GPT-2's lowering of
+`cached_attention`); the counters.
 
 Tiny sizes on the CPU: 4 layers `LLGL` (the first dense), hidden 64, 4
 query heads over 2 key/value heads of 16, window 4, 8 experts scored of
@@ -130,8 +133,6 @@ def test_a_ring_holds_position_p_in_slot_p_mod_window():
     ("a cache of other heads", dict(num_heads=4, num_kv_heads=1), 8, 1),
     ("a ring of another size", dict(num_heads=4, num_kv_heads=2, window=4),
      8, 1),
-    ("a block through a ring", dict(num_heads=4, num_kv_heads=2, window=8),
-     8, 2),
 ])
 def test_what_the_op_cannot_attend_is_refused(why, attrs, slots, block):
     q, k, v = _sequence(np.random.RandomState(2), 4, 2, 16, block)
@@ -139,6 +140,42 @@ def test_what_the_op_cannot_attend_is_refused(why, attrs, slots, block):
     with pytest.raises(ValueError, match="cached_attention"):
         _attend((q,), (k, cache), (v, cache), jnp.zeros((2,), jnp.int32),
                 **attrs)
+
+
+@pytest.mark.parametrize("kv_heads", [8, 1])     # groups of 1 and of 8
+@pytest.mark.parametrize("start", [0, 2, 5, 13])  # 13: the ring has wrapped
+@pytest.mark.parametrize("block", [1, 3, 5, 10])  # 5: the window; 10: + 5
+def test_a_block_through_a_ring_is_as_many_single_steps(block, start,
+                                                        kv_heads):
+    """`start` positions a step at a time, then `block` more as one
+    application and as single steps: the same `Out`, and the rings
+    slot for slot the same."""
+    q, k, v = _sequence(np.random.RandomState(block + start), 8, kv_heads,
+                        16, start + block)
+    cuts = list(range(start)) or [0]
+    steps, step_rings = _through(q, k, v, 8, kv_heads, 5, 5,
+                                 list(range(start + block)))
+    blocks, block_rings = _through(q, k, v, 8, kv_heads, 5, 5,
+                                   sorted(set(cuts + [start])))
+    np.testing.assert_allclose(blocks, steps, atol=2e-6)
+    np.testing.assert_allclose(blocks, _masked(q, k, v, 8, kv_heads, 5),
+                               atol=2e-6)
+    for got, want in zip(block_rings, step_rings):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_a_block_through_a_bfloat16_ring_reads_its_own_keys_as_the_ring_would():
+    """The block's own keys and values reach the scores rounded to the
+    ring's type, as a step reads them back from its slot."""
+    q, k, v = _sequence(np.random.RandomState(8), 4, 2, 16, 9)
+    rings = [jnp.zeros((2, 2, 4, 16), jnp.bfloat16)] * 2
+    got, *_ = _attend((q,), (k, rings[0]), (v, rings[1]),
+                      jnp.zeros((2,), jnp.int32), num_heads=4,
+                      num_kv_heads=2, window=4)
+    rounded = [np.asarray(jnp.asarray(t, jnp.bfloat16), np.float32)
+               for t in (k, v)]
+    np.testing.assert_allclose(np.asarray(got),
+                               _masked(q, *rounded, 4, 2, 4), atol=2e-6)
 
 
 # -- (b) the kernel under the interpreter against the plain path ---------------
@@ -153,11 +190,15 @@ def _kernel_ins(rs, pos, slots, dtype=jnp.float32, past=0.0, group=4):
     return q, k, v
 
 
-def _plain(q, k, v, last):
+def _plain(q, k, v, last, positions=1):
+    """The plain masked products for queries [2, 2, group * positions,
+    128], row g * positions + t attending slots 0 .. last + t."""
+    limit = last + np.arange(q.shape[2]) % positions
     s = jnp.einsum("bhgd,bhkd->bhgk", q.astype(jnp.float32),
                    k.astype(jnp.float32),
                    precision=jax.lax.Precision.HIGHEST) * 128 ** -0.5
-    s = jnp.where(jnp.arange(k.shape[2]) <= last, s, -1e30)
+    s = jnp.where(jnp.arange(k.shape[2])[None, :] <= limit[:, None], s,
+                  -1e30)
     return jnp.einsum("bhgk,bhkd->bhgd", jax.nn.softmax(s, axis=-1),
                       v.astype(jnp.float32),
                       precision=jax.lax.Precision.HIGHEST)
@@ -188,41 +229,82 @@ def test_the_walk_reads_nothing_past_the_position(pos, block_k):
                                np.asarray(_plain(q, *clean, pos)), atol=2e-5)
 
 
+@pytest.mark.parametrize("dtype,atol", [(jnp.float32, 2e-5),
+                                        (jnp.bfloat16, 3e-2)])
+@pytest.mark.parametrize("positions,last", [
+    (2, 0), (2, 127), (2, 128),         # across an edge, and from one
+    (16, 0), (16, 120), (16, 256), (16, 368),
+    (128, 0), (128, 1), (128, 128), (128, 200), (128, 256),
+    (130, 100),                         # more positions than a block holds
+])
+def test_the_walk_over_a_block_of_queries_is_the_plain_path(positions, last,
+                                                            dtype, atol):
+    """Slots 0 .. last + positions - 1 are live and the rest hold NaN:
+    each of a head's `positions` queries attends its own extent."""
+    rs = np.random.RandomState(positions + last)
+    q, k, v = _kernel_ins(rs, last + positions - 1, 384, dtype, past=np.nan,
+                          group=4 * positions)
+    got = gqa_decode.gqa_decode(q, k, v, jnp.int32(last), 128 ** -0.5,
+                                block_k=128, positions=positions)
+    clean = [jnp.nan_to_num(t) for t in (k, v)]
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32),
+        np.asarray(_plain(q, *clean, last, positions)), atol=atol)
+
+
 def test_the_ops_kernel_path_is_its_plain_path():
-    """128-wide heads, T = 1: the op walks (ring and whole extent); the
-    same sequence at T > 1 takes the plain path."""
+    """128-wide heads: the op walks at T = 1 (ring and whole extent) and
+    over a whole extent at T > 1; a block through a ring takes the plain
+    path."""
     q, k, v = _sequence(np.random.RandomState(4), 4, 2, 128, 140, rows=1)
     before = telemetry.snapshot()
     ring, _ = _through(q, k, v, 4, 2, 128, 128, list(range(140)))
     full, _ = _through(q, k, v, 4, 2, 0, 256, list(range(140)))
-    delta = telemetry.snapshot_delta(before)
     blocks, _ = _through(q, k, v, 4, 2, 0, 256, [0, 70])
+    ring_blocks, _ = _through(q, k, v, 4, 2, 128, 128, [0, 70, 139])
+    delta = telemetry.snapshot_delta(before)
     np.testing.assert_allclose(ring, _masked(q, k, v, 4, 2, 128), atol=1e-5)
     np.testing.assert_allclose(full, _masked(q, k, v, 4, 2, 0), atol=1e-5)
     np.testing.assert_allclose(full, blocks, atol=1e-5)
-    assert delta[_lowering("window", 2, 128, "kernel", 128)] == 140
-    assert delta[_lowering("full", 2, 0, "kernel", 256)] == 140
-    assert delta["kv_cache_slots_total{kind=window}"] == 140 * 128
-    assert delta["kv_cache_slots_total{kind=full}"] == 140 * 256
+    np.testing.assert_allclose(ring, ring_blocks, atol=1e-5)
+    assert {key: n for key, n in delta.items()
+            if key.startswith("window_attention_lowerings_total")} == {
+        _lowering("window", 2, 128, "kernel", 128): 141,
+        _lowering("full", 2, 0, "kernel", 256): 140,
+        _lowering("full", 2, 0, "kernel", 256, block=70): 2,
+        _lowering("window", 2, 128, "plain", 0, block=70): 1,
+        _lowering("window", 2, 128, "plain", 0, block=69): 1}
+    assert delta["kv_cache_slots_total{kind=window}"] == 143 * 128
+    assert delta["kv_cache_slots_total{kind=full}"] == 142 * 256
 
 
-def _lowering(kind, kv_heads, window, path, block_k):
-    return "window_attention_lowerings_total{block_k=%d,kind=%s," \
-        "kv_heads=%d,path=%s,window=%d}" % (block_k, kind, kv_heads, path,
-                                            window)
+def _lowering(kind, kv_heads, window, path, block_k, block=1):
+    return "window_attention_lowerings_total{block=%d,block_k=%d,kind=%s," \
+        "kv_heads=%d,path=%s,window=%d}" % (block, block_k, kind, kv_heads,
+                                            path, window)
 
 
 def test_the_kernel_refuses_what_it_does_not_take():
     q, k, v = _kernel_ins(np.random.RandomState(5), 3, 128)
-    assert not gqa_decode.fits(2, 128, 128)
-    assert not gqa_decode.fits(1, 100, 128)
-    assert not gqa_decode.fits(1, 128, 64)
+    assert not gqa_decode.fits(8, 100, 128)
+    assert not gqa_decode.fits(8, 128, 64)
     assert gqa_decode.choose_block(32768) == 2048
     assert gqa_decode.choose_block(128) == 128
+    # the rows' float32 scores size the block of slots: a group of 8 at
+    # 128 positions takes 1024 a step, and 8192 rows fit at no block
+    assert gqa_decode.fits(8 * 128, 32768, 128)
+    assert gqa_decode.choose_block(32768, 8 * 128) == 1024
+    assert gqa_decode.choose_block(32768, 8 * 16) == 2048
+    assert not gqa_decode.fits(8 * 1024, 32768, 128)
     with pytest.raises(ValueError, match="gqa_decode"):
         gqa_decode.gqa_decode(q, k, v[:, :, :64], jnp.int32(3), 1.0)
     with pytest.raises(ValueError, match="gqa_decode"):
         gqa_decode.gqa_decode(q, k, v, jnp.int32(3), 1.0, window=64)
+    with pytest.raises(ValueError, match="gqa_decode"):   # a ring: T = 1
+        gqa_decode.gqa_decode(q, k, v, jnp.int32(3), 1.0, window=128,
+                              positions=2)
+    with pytest.raises(ValueError, match="gqa_decode"):   # 4 rows by 3
+        gqa_decode.gqa_decode(q, k, v, jnp.int32(3), 1.0, positions=3)
 
 
 # -- (c) the step Program against the reference's full forward -----------------
@@ -284,6 +366,106 @@ def built():
     return {"program": program, "scope": scope, "decoder": decoder,
             "tokens": tokens, "got": got, "state": state, "params": params,
             "want": want, "at_build": at_build, "traced": traced}
+
+
+def _probed(program, scope, max_len):
+    """(a decoder that carries every `parts` entry but "counts" out as
+    a state pair the step only writes, the state a call starts from)."""
+    parts = program[4]
+    probes = {"probe.%s_%d" % (key, i): var.name
+              for key, found in parts.items() if key != "counts"
+              for i, var in enumerate(found)}
+    decoder = fluid.ProgramDecoder(
+        program[0].clone(for_test=True), token_name="tok",
+        logits_name=program[2].name,
+        state_pairs=program[3] + list(probes.items()), scope=scope,
+        max_positions=max_len)
+    state = {f: jnp.zeros((B, KV, W if a.shape[2] == W else max_len, DH))
+             if f != "pos" else a for f, a in _empty().items()}
+    for feed in probes:
+        state[feed] = jnp.zeros((B, K), jnp.int32) if "top_idx" in feed \
+            else jnp.zeros((B, K)) if "top_w" in feed \
+            else jnp.zeros((B, 1, D))
+    return decoder, state
+
+
+def _prefilled(decoder, state, prompt, takes_block):
+    """(state, first token, the counters' rise) of `models.decode.prefill`
+    under one jit, in blocks or a position an application."""
+    from paddle_tpu.models.decode import prefill
+
+    before = telemetry.snapshot()
+    state, first = jax.jit(lambda params, s, p: prefill(
+        decoder._step_fn(params), s, p, takes_block))(
+            decoder._params, state, jnp.asarray(prompt))
+    return state, np.asarray(first), telemetry.snapshot_delta(before)
+
+
+@pytest.fixture(scope="module")
+def long_built():
+    """The same sizes at 136 positions: a prompt of more than a
+    `PREFILL_BLOCK`."""
+    program = build_window_moe_cached_step_program(B, 136, V, **SIZES)
+    return program, _start(program[1])
+
+
+@pytest.mark.parametrize("before,length", [
+    (0, 1), (0, 3), (0, W), (0, 9), (0, T), (5, 1), (5, W + 3), (2, 131)])
+def test_a_prompt_as_blocks_is_the_prompt_a_position_an_application(
+        built, long_built, before, length):
+    """From `before` positions already in the caches: the first token,
+    the caches, the position and every part (the last position's) of a
+    prompt prefilled in blocks against the same prompt a position an
+    application; the counters say which form and how many positions."""
+    from paddle_tpu.models.decode import PREFILL_BLOCK
+
+    program, scope = (built["program"], built["scope"]) \
+        if before + length <= T else long_built
+    max_len = T if before + length <= T else 136
+    decoder, state = _probed(program, scope, max_len)
+    tokens = np.random.RandomState(length).randint(
+        0, V, (B, before + length)).astype("int32")
+    if before:
+        state, _, _ = _prefilled(decoder, state, tokens[:, :before], False)
+    want, want_first, by_step = _prefilled(decoder, state, tokens[:, before:],
+                                           False)
+    got, got_first, by_block = _prefilled(decoder, state, tokens[:, before:],
+                                          True)
+    np.testing.assert_array_equal(got_first, want_first)
+    assert sorted(got) == sorted(want)
+    for feed in want:
+        if feed == "pos" or "top_idx" in feed:
+            np.testing.assert_array_equal(np.asarray(got[feed]),
+                                          np.asarray(want[feed]), feed)
+        else:
+            scale = np.abs(np.asarray(want[feed])).max()
+            np.testing.assert_allclose(
+                np.asarray(got[feed]), np.asarray(want[feed]),
+                atol=1e-5 * scale + 1e-6, err_msg=feed)
+    assert int(got["pos"][0]) == before + length
+    assert by_step["prefill_lowerings_total{block=1,form=step}"] == 1
+    assert by_block["prefill_lowerings_total{block=%d,form=block}"
+                    % PREFILL_BLOCK] == 1
+    # a remainder first, then the equal blocks' one traced body
+    blocks = {n for n in (length % PREFILL_BLOCK,
+                          PREFILL_BLOCK * (length >= PREFILL_BLOCK)) if n}
+    assert {key: n for key, n in by_block.items()
+            if key.startswith("window_attention_lowerings_total")} == dict(
+        [(_lowering("window", KV, W, "plain", 0, block=n), 3)
+         for n in blocks]
+        + [(_lowering("full", KV, 0, "plain", 0, block=n), 1)
+           for n in blocks])
+
+
+def test_the_step_says_it_takes_a_block(built):
+    block = built["program"][0].global_block()
+    assert tuple(block.var("tok").shape) == (B, -1)
+    assert built["decoder"]._takes_block
+    for key, found in built["program"][4].items():
+        for var in found:
+            assert tuple(var.shape) == {
+                "counts": (HELD[1],), "top_w": (B, K), "top_idx": (B, K),
+            }.get(key, (B, 1, D)), (key, var.shape)
 
 
 @pytest.mark.parametrize("position", range(T))
