@@ -1,6 +1,6 @@
 """One decode step of grouped-query attention over a key/value cache as
-it lies, or a block of T consecutive steps at once (a pallas TPU
-kernel).
+it lies, or a block of T consecutive steps at once (pallas TPU
+kernels).
 
     gqa_decode(q[B, KV, G * T, D], k_cache[B, KV, S, D],
                v_cache[B, KV, S, D], last, sm_scale, positions=T)
@@ -53,18 +53,51 @@ once for all of them and is bound by its products and its softmax; its
 float32 scores, [G * T, block_k], are what fills VMEM, so its block of
 slots is smaller (`choose_block`: 1024 at 1024 rows).
 
-Which shapes it takes (`fits`): heads 128 wide (the lanes), S a multiple
-of 128, and the G * T rows of a key/value head small enough that their
-scores over the smallest block of slots fit VMEM beside the operands
-(`_vmem_bytes`).  The op asks and falls back to its plain path; a cache
-in a narrower type than the query's is read up by the caller first.
+64-wide heads (GPT-2's: 16 heads, no grouping, one query a key/value
+head).  A `[B, KV, S, 64]` array with its rows in the sublanes pads
+every 64-wide row to the 128 lanes: twice the bytes, fetched whatever
+reads them.  Unasked, the TPU's compiler holds such an array the other
+way, slots-minor (the layout of `[B, KV, 64, S]`): no lane is padding.
+So at this width the kernels take the caches *with their last two axes
+swapped*, which is no copy of anything where the cache lies that way
+(the caller's `jnp.swapaxes` is a bitcast, and a decoder's scan then
+carries the caches as the call received them).  `_narrow_kernel` walks
+blocks `[rows, heads, 64, block]`: a head's 512 slots of bfloat16 are 64
+KB, no step's worth of bytes, so as many of a row's heads as divide
+them, up to 16, and then rows of the batch share a grid step, up to a
+megabyte of each cache (`choose_step`).  The scores are [1, 64] x
+[64, block] on the MXU; the values are weighted on the vector unit (one
+query a head is no product for the MXU: it would load each value tile
+as weights for one row of results) in float32, probabilities and all,
+and the weighted sum's reduction over the lanes is taken once, with the
+last block.  Slots-minor, the compiler's own `dynamic_update_slice` of
+one slot is a write of one lane of every (head, value) row, 0.17 to
+0.33 ms a pair of 100 MB caches; `write_step` is that update as a
+kernel that rewrites the 128 slots around the position in place, 0.04
+ms alone (0.08 in gpt2m-decode's step).  (It is a kernel of its own, not a part of the walk: a cache that
+is both an operand and an aliased result of the walk's call loses the
+overlap of its blocks' copies with the folds, 0.22 ms a call.)  Where
+the time goes, and the sweep these sizes came from:
+`scripts/gqa_decode_bench.py`, PERF.md section 5.
 
-Lowered for the TPU this is a Mosaic kernel named `gqa_decode_k<block_k>`
-over a whole-extent cache, `gqa_decode_k<block_k>_t<T>` where T > 1 (a
-trace tells a prefill block's calls from a decode step's) and
-`gqa_decode_w<window>` over a ring; lowered for the CPU the same kernel
-runs under the Pallas interpreter (tests), chosen by the platform of the
-lowering as the flash kernels are.
+Which shapes it takes (`fits`): S a multiple of 128; heads 128 wide (the
+lanes) with G * T rows a key/value head small enough that their scores
+over the smallest block of slots fit VMEM beside the operands
+(`_vmem_bytes`), or heads 64 wide with one row a key/value head (T = 1,
+no grouping, no ring).  The op asks and falls back to its plain path; a
+cache in a narrower type than the query's is read up by the caller
+first at 128 wide, and keeps the plain path at 64.
+
+Lowered for the TPU these are Mosaic kernels named
+`gqa_decode_k<block_k>` over a whole-extent cache,
+`gqa_decode_k<block_k>_t<T>` where T > 1 (a trace tells a prefill
+block's calls from a decode step's), `gqa_decode_w<window>` over a ring,
+and at 64 wide `gqa_decode_k<block_k>_h<heads>` (`_r<rows>` after it
+where rows share a step) and `gqa_write_r<rows>`; lowered for the CPU
+the same kernels run under the Pallas interpreter (tests), chosen by the
+platform of the lowering as the flash kernels are.  Each entry is under
+`jax.jit`: the layers of a program that hold the same instance share
+one traced body and one lowered function.
 """
 
 import functools
@@ -79,6 +112,11 @@ NEG_INF = -1e30
 _LANES = 128
 # the blocks of slots the chooser tries, largest first
 _BLOCKS = (2048, 1024, 512, 256, 128)
+# the narrower head the kernel takes, its block of slots, and the bytes
+# of each cache a grid step of it moves (section "64-wide heads")
+_NARROW = 64
+_NARROW_BLOCKS = (512, 256, 128)
+_NARROW_STEP_BYTES = 1 << 20
 
 
 # what a grid step may hold in VMEM: under the 16 MiB a kernel gets on a
@@ -99,7 +137,7 @@ def _vmem_bytes(rows, bk, itemsize):
     return resident + blocks + scores
 
 
-def choose_block(slots, rows=8, itemsize=2):
+def choose_block(slots, rows=8, itemsize=2, head_dim=_LANES):
     """The largest of the blocks that tiles `slots` and whose step fits
     VMEM with `rows` resident queries a key/value head (a group's heads
     times the block's positions), or 0 where none does: fewer grid steps
@@ -108,18 +146,50 @@ def choose_block(slots, rows=8, itemsize=2):
     A ring of 128 is one block; a group of 8 heads at 128 positions
     takes 1024 slots a step (on the chip, ms a call of 8 rows x 8 heads
     over 31,872 live slots: 2048 8.3, 1024 7.8, 512 15.2, 256 30.0: a
-    step costs 3.7 us whatever it folds up to 1024 slots)."""
+    step costs 3.7 us whatever it folds up to 1024 slots).  64-wide
+    heads, one query a head: the largest of `_NARROW_BLOCKS` that tiles
+    the extent (`choose_step` says how many heads and rows share the
+    step)."""
+    if head_dim == _NARROW:
+        return next((bk for bk in _NARROW_BLOCKS if slots % bk == 0), 0) \
+            if rows == 1 else 0
+    if head_dim != _LANES:
+        return 0
     for bk in _BLOCKS:
         if slots % bk == 0 and _vmem_bytes(rows, bk, itemsize) <= _VMEM_BYTES:
             return bk
     return 0
 
 
+def choose_step(batch, kv_heads, block_k, itemsize=2):
+    """(rows of the batch, key/value heads) a grid step of the 64-wide
+    kernel takes over blocks of `block_k` slots: as many of a row's
+    heads as divide them, up to 16, then as many rows as divide the
+    batch, while the step's block of each cache stays within
+    `_NARROW_STEP_BYTES`.  One head's 512 slots are 64 KB, no step's
+    worth of bytes (a grid step costs 0.35 us and more whatever it
+    moves), so heads, and under shorter blocks rows, share the step.  On
+    the chip (48 rows x 16 heads x 1024 slots, ms the walk alone at
+    positions 767 / 1022: scripts/gqa_decode_bench.py, PERF.md section
+    5): 16 heads x 512 slots 0.29 / 0.29, 2 rows x 16 x 256 0.31 / 0.38,
+    16 x 256 0.32 / 0.38, 16 x 128 0.38 / 0.46.  What a shorter block
+    skips does not pay for its steps, so `choose_block` takes the
+    largest that tiles the extent."""
+    room = _NARROW_STEP_BYTES // (block_k * _NARROW * itemsize)
+    heads = _divisor(kv_heads, min(16, room))
+    return _divisor(batch, room // heads), heads
+
+
+def _divisor(n, most):
+    """The largest divisor of `n` that is at most `most` (at least 1)."""
+    return max(d for d in range(1, max(min(n, most), 1) + 1) if n % d == 0)
+
+
 def fits(rows, slots, head_dim, itemsize=2):
     """Whether the kernel takes `rows` queries a key/value head (a
     group's heads times the block's positions) over these caches: see
     the module's docstring."""
-    return head_dim == _LANES and choose_block(slots, rows, itemsize) > 0
+    return choose_block(slots, rows, itemsize, head_dim) > 0
 
 
 def _top(last, positions):
@@ -228,33 +298,260 @@ def _call(q, k_cache, v_cache, last, *, sm_scale, bk, positions, name,
     )(last, q, k_cache, v_cache)
 
 
+def _narrow_kernel(last_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
+                   *, sm_scale, bk):
+    """One grid step over caches handed in with the slots in the lanes,
+    [rows, heads, D, block]: block `j - dead` of each of the step's rows
+    and heads folded into its one query's running maximum, sum [1, 1]
+    and accumulator.  The scores are a product on the MXU, [1, D] x
+    [D, block]; the values are weighted on the vector unit, [D, block]
+    times the probabilities along the lanes, and added up 128 lanes
+    wide: their sum over the lanes is taken once, with the last
+    block."""
+    j = pl.program_id(2)
+    last = last_ref[0]
+    last_block = last // bk
+    k = j - (pl.num_programs(2) - 1 - last_block)
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    def fold(at, masked):
+        s = lax.dot_general(
+            q_ref[at], k_ref[at], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32) * sm_scale
+        values = v_ref[at].astype(jnp.float32)
+        if masked:
+            live = k * bk + lax.broadcasted_iota(jnp.int32, (1, bk), 1) <= last
+            s = jnp.where(live, s, NEG_INF)
+            values = jnp.where(live, values, 0.0)
+        m_prev = m_scr[at]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_scr[at] = alpha * l_scr[at] + jnp.sum(p, axis=-1, keepdims=True)
+        weighted = values * p
+        acc_scr[at] = alpha * acc_scr[at] + sum(
+            weighted[:, lane:lane + _LANES] for lane in range(0, bk, _LANES))
+        m_scr[at] = m_new
+
+    def fold_all(masked, then=None):
+        # traced once, unrolled when lowered: the folds of a step's
+        # heads are independent, and interleave
+        rows, heads = k_ref.shape[:2]
+
+        def body(i, _):
+            at = (lax.div(i, heads), lax.rem(i, heads))
+            fold(at, masked)
+            if then is not None:
+                then(at)
+
+        lax.fori_loop(0, rows * heads, body, None, unroll=True)
+
+    @pl.when((k >= 0) & (k < last_block))
+    def _whole():
+        fold_all(masked=False)
+
+    @pl.when(k == last_block)
+    def _crossed():
+        # the accumulator's sum over the lanes is a column [D, 1]; the
+        # output wants it a row: through the diagonal of a [D, D] tile
+        diagonal = _diagonal((k_ref.shape[2],) * 2)
+
+        def write_out(at):
+            column = jnp.sum(acc_scr[at], axis=-1, keepdims=True) / l_scr[at]
+            o_ref[at] = jnp.sum(jnp.where(diagonal, column, 0.0), axis=0,
+                                keepdims=True).astype(o_ref.dtype)
+
+        fold_all(masked=True, then=write_out)
+
+
+def _diagonal(shape):
+    """Where the last two indices of `shape` are equal."""
+    return lax.broadcasted_iota(jnp.int32, shape, len(shape) - 2) \
+        == lax.broadcasted_iota(jnp.int32, shape, len(shape) - 1)
+
+
+def _narrow_call(q, k_cache, v_cache, last, *, sm_scale, bk, step, name,
+                 interpret):
+    """q [B, KV, 1, D] over caches [B, KV, D, S] -> [B, KV, 1, D]."""
+    batch, kv_heads, dim, slots = k_cache.shape
+    rows, heads = step
+    steps = slots // bk
+
+    def block(b, h, j, last):
+        # dead steps name the first block, as `_call` has them
+        return b, h, 0, jnp.maximum(j - (steps - 1 - last[0] // bk), 0)
+
+    def head(b, h, j, last):
+        return b, h, 0, 0
+
+    return pl.pallas_call(
+        functools.partial(_narrow_kernel, sm_scale=sm_scale, bk=bk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(batch // rows, kv_heads // heads, steps),
+            in_specs=[pl.BlockSpec((rows, heads, 1, dim), head),
+                      pl.BlockSpec((rows, heads, dim, bk), block),
+                      pl.BlockSpec((rows, heads, dim, bk), block)],
+            out_specs=pl.BlockSpec((rows, heads, 1, dim), head),
+            scratch_shapes=[pltpu.VMEM((rows, heads, 1, 1), jnp.float32),
+                            pltpu.VMEM((rows, heads, 1, 1), jnp.float32),
+                            pltpu.VMEM((rows, heads, dim, _LANES),
+                                       jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+        name=name,
+    )(last, q, k_cache, v_cache)
+
+
+def _write_kernel(at_ref, k_new_ref, v_new_ref, k_ref, v_ref, k_out, v_out):
+    """One grid step: the 128 slots that hold slot `at`, of every head
+    of the step's rows, [rows, KV, D, 128], with the new entries
+    [rows, KV, 1, D] a column in it (a row turned through the diagonal
+    of a [D, D] tile: one term a sum, exact)."""
+    shape = k_ref.shape
+    here = lax.broadcasted_iota(jnp.int32, shape, 3) \
+        == lax.rem(at_ref[0], _LANES)
+    diagonal = _diagonal(shape[:3] + (shape[2],))
+    for new_ref, ref, out in ((k_new_ref, k_ref, k_out),
+                              (v_new_ref, v_ref, v_out)):
+        column = jnp.sum(
+            jnp.where(diagonal, new_ref[...].astype(jnp.float32), 0.0),
+            axis=3, keepdims=True).astype(ref.dtype)
+        out[...] = jnp.where(here, column, ref[...])
+
+
+def _write_call(k_new, v_new, k_cache, v_cache, at, *, rows, interpret):
+    batch, kv_heads, dim, _ = k_cache.shape
+
+    def column(b, at):
+        return b, 0, 0, 0
+
+    def tile(b, at):
+        return b, 0, 0, at[0] // _LANES
+
+    new = pl.BlockSpec((rows, kv_heads, 1, dim), column)
+    held = pl.BlockSpec((rows, kv_heads, dim, _LANES), tile)
+    return pl.pallas_call(
+        _write_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(batch // rows,),
+            in_specs=[new, new, held, held], out_specs=[held, held]),
+        out_shape=[jax.ShapeDtypeStruct(c.shape, c.dtype)
+                   for c in (k_cache, v_cache)],
+        # in place: the prefetched scalar is operand 0
+        input_output_aliases={3: 0, 4: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="gqa_write_r%d" % rows,
+    )(at, k_new, v_new, k_cache, v_cache)
+
+
+def _by_platform(call, *operands):
+    """`call` lowered for the platform of the lowering: Mosaic on the
+    TPU, the Pallas interpreter on the CPU."""
+    return lax.platform_dependent(
+        *operands, tpu=functools.partial(call, interpret=False),
+        cpu=functools.partial(call, interpret=True))
+
+
+# Under `jax.jit`: `platform_dependent` traces the interpreter's branch
+# beside Mosaic's, and the layers of a program that hold the same
+# instance share one traced body and one lowered function.
+
+@functools.partial(jax.jit, static_argnames=("sm_scale", "bk", "positions",
+                                             "name"))
+def _wide(q, k_cache, v_cache, last, **static):
+    return _by_platform(functools.partial(_call, **static), q, k_cache,
+                        v_cache, last)
+
+
+@functools.partial(jax.jit, static_argnames=("sm_scale", "bk", "step",
+                                             "name"))
+def _narrow(q, k_cache, v_cache, last, **static):
+    # the caches with their last two axes swapped: no copy where the
+    # compiler holds them slots-minor, which is where it holds a
+    # [.., slots, 64] array unasked (no lane is padding)
+    return _by_platform(functools.partial(_narrow_call, **static), q,
+                        jnp.swapaxes(k_cache, 2, 3),
+                        jnp.swapaxes(v_cache, 2, 3), last)
+
+
+@functools.partial(jax.jit, static_argnames=("rows",))
+def _written(k_new, v_new, k_cache, v_cache, at, rows):
+    caches = _by_platform(functools.partial(_write_call, rows=rows), k_new,
+                          v_new, jnp.swapaxes(k_cache, 2, 3),
+                          jnp.swapaxes(v_cache, 2, 3), at)
+    return tuple(jnp.swapaxes(c, 2, 3) for c in caches)
+
+
+def write_step(k_cache, v_cache, k_new, v_new, at):
+    """The 64-wide caches [B, KV, S, 64] with slot `at` (an int32
+    scalar) of every row and head set to `k_new`, `v_new` [B, KV, 1,
+    64], in place where the caller gives them up: what
+    `dynamic_update_slice` does, by a kernel that rewrites the 128 slots
+    around `at` as they lie, slots-minor (there the compiler's own
+    update takes 0.09-0.16 ms a cache of 100 MB; this 0.02-0.04)."""
+    batch, kv_heads = k_cache.shape[:2]
+    if not fits(1, k_cache.shape[2], k_cache.shape[3]) \
+            or k_cache.shape[3] != _NARROW or k_cache.shape != v_cache.shape \
+            or k_new.shape != (batch, kv_heads, 1, _NARROW) \
+            or v_new.shape != k_new.shape \
+            or len({x.dtype for x in (k_cache, v_cache, k_new, v_new)}) != 1:
+        raise ValueError(
+            "gqa_decode.write_step: %s %s and %s %s into caches %s %s and "
+            "%s %s are no step the kernel writes"
+            % (k_new.shape, k_new.dtype, v_new.shape, v_new.dtype,
+               k_cache.shape, k_cache.dtype, v_cache.shape, v_cache.dtype))
+    rows = _divisor(batch, _NARROW_STEP_BYTES // (
+        kv_heads * _NARROW * _LANES * k_cache.dtype.itemsize))
+    return _written(k_new, v_new, k_cache, v_cache,
+                    jnp.reshape(at, (1,)).astype(jnp.int32), rows=rows)
+
+
 def gqa_decode(q, k_cache, v_cache, last, sm_scale, window=0, block_k=None,
-               positions=1):
+               positions=1, step=None):
     """The attended values of one decode step, or of a block of
     `positions` consecutive ones, [batch, kv_heads, group * positions,
-    128] in q's type: see the module's docstring.  `window` names the
+    D] in q's type: see the module's docstring.  `window` names the
     kernel of a ring (`gqa_decode_w<window>`, one block, one position);
-    `block_k` is chosen from the shapes unless given (tests, sweeps)."""
+    `block_k` and, over 64-wide heads, `step` (rows, heads a grid step)
+    are chosen from the shapes unless given (tests, sweeps)."""
     slots = k_cache.shape[2]
+    narrow = q.shape[-1] == _NARROW
     if q.ndim != 4 or k_cache.shape != v_cache.shape \
             or k_cache.shape[:2] != q.shape[:2] \
             or k_cache.shape[3] != q.shape[3] \
             or k_cache.dtype != q.dtype or v_cache.dtype != q.dtype \
             or positions < 1 or q.shape[2] % positions \
             or not fits(q.shape[2], slots, q.shape[3], q.dtype.itemsize) \
-            or (window and (window != slots or positions != 1)):
+            or (window and (window != slots or positions != 1 or narrow)):
         raise ValueError(
             "gqa_decode: queries %s %s at %d positions over caches %s %s "
             "and %s %s (window %d) are no step the kernel takes"
             % (q.shape, q.dtype, positions, k_cache.shape, k_cache.dtype,
                v_cache.shape, v_cache.dtype, window))
-    bk = block_k or choose_block(slots, q.shape[2], q.dtype.itemsize)
+    bk = block_k or choose_block(slots, q.shape[2], q.dtype.itemsize,
+                                 q.shape[3])
+    last = jnp.reshape(last, (1,)).astype(jnp.int32)
+    if narrow:
+        step = step or choose_step(q.shape[0], q.shape[1], bk,
+                                   q.dtype.itemsize)
+        name = "gqa_decode_k%d_h%d" % (bk, step[1])
+        if step[0] > 1:
+            name += "_r%d" % step[0]
+        return _narrow(q, k_cache, v_cache, last, sm_scale=float(sm_scale),
+                       bk=bk, step=tuple(step), name=name)
     name = "gqa_decode_w%d" % window if window else "gqa_decode_k%d" % bk
     if positions > 1:
         name += "_t%d" % positions
-    call = functools.partial(_call, sm_scale=float(sm_scale), bk=bk,
-                             positions=positions, name=name)
-    return lax.platform_dependent(
-        q, k_cache, v_cache, jnp.reshape(last, (1,)).astype(jnp.int32),
-        tpu=functools.partial(call, interpret=False),
-        cpu=functools.partial(call, interpret=True))
+    return _wide(q, k_cache, v_cache, last, sm_scale=float(sm_scale), bk=bk,
+                 positions=positions, name=name)

@@ -281,9 +281,12 @@ def cached_attention_op(ctx, ins, attrs):
     softmax): a step over either kind of cache, and a block over a
     whole extent where its group's T queries a key/value head fit the
     kernel (its float32 scores on the plain path would be [batch, heads,
-    T, slots]); every other shape takes the plain path, scores over
-    every slot under a mask (a block through a ring: over `window` + T
-    keys).
+    T, slots]).  Over 64-wide heads, one query a key/value head and
+    caches in Q's type, a step (T = 1) likewise, and the kernel's file
+    writes the slot too: it takes the caches slots-minor, where no lane
+    of a 64-wide head is padding.  Every other shape takes the plain
+    path, scores over every slot under a mask (a block through a ring:
+    over `window` + T keys).
 
     No operand is narrower at T > 1 than at T = 1 on the plain path:
     both products read their operands as float32 at the highest
@@ -324,12 +327,19 @@ def cached_attention_op(ctx, ins, attrs):
     if sm_scale is None:
         sm_scale = head_dim ** -0.5
     # the walk of the live slots where what the op sees of its inputs
-    # fits it (128-wide heads: Pallas is imported for those alone)
+    # fits it (Pallas is imported for the kernel's head widths alone).
+    # 64-wide heads: a step over whole-extent caches in Q's type, which
+    # the kernel reads as they lie; a cache in another type keeps the
+    # plain path, where reading it up would write a copy of it a layer a
+    # step
     block_k = 0
-    if head_dim == 128 and not ring_block:
+    if head_dim in (64, 128) and not ring_block and (
+            head_dim == 128 or not window
+            and k_cache.dtype == v_cache.dtype == q.dtype):
         from ..kernels import gqa_decode
         block_k = gqa_decode.choose_block(extent, group * block,
-                                          q.dtype.itemsize)
+                                          q.dtype.itemsize, head_dim)
+    writes = block_k and head_dim == 64
     telemetry.on_cached_attention_lowering(block)
     telemetry.on_window_attention_lowering(
         kind, kv_heads, window, "kernel" if block_k else "plain", block_k,
@@ -340,6 +350,10 @@ def cached_attention_op(ctx, ins, attrs):
         if ring_block:
             k_cache, v_cache = (_ring_write(cache, new, pos) for cache, new
                                 in ((k_cache, kh), (v_cache, vh)))
+        elif writes:    # slots-minor, as the 64-wide kernel reads them
+            k_cache, v_cache = gqa_decode.write_step(
+                k_cache, v_cache, kh.astype(q.dtype), vh.astype(q.dtype),
+                pos)
         else:
             at = pos % window if window else pos
             k_cache = jax.lax.dynamic_update_slice_in_dim(

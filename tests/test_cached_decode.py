@@ -479,6 +479,50 @@ def test_counters_say_block_prefill_and_its_block_lengths(decoders):
         "cached_attention_lowerings_total{block=1}": L}
 
 
+def test_counters_say_which_way_a_gpt2_step_takes_over_its_caches():
+    """The GPT-2 cached step at the decode cell's kind of shape (heads
+    of 64, a multiple of 128 slots, caches in the weights' type): a
+    decode step's op instances count the kernel, a prefill block's the
+    plain path, `cached_attention_lowerings_total{block}` as before."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.models import decode
+    from paddle_tpu.obs import telemetry
+
+    layers, heads, slots, rows = 2, 2, 256, 2
+    main, startup, logits, pairs = build_transformer_cached_step_program(
+        rows, slots, V, n_layer=layers, n_head=heads, d_model=64 * heads,
+        d_ff=64)
+    scope = fluid.Scope()
+    fluid.Executor(fluid.CPUPlace()).run(startup, scope=scope)
+    dec = fluid.ProgramDecoder(main.clone(for_test=True), token_name="tok",
+                               logits_name=logits.name, state_pairs=pairs,
+                               scope=scope, max_positions=slots)
+    state = {feed: jnp.zeros((rows, heads, slots, 64), jnp.float32)
+             for feed, _ in pairs if feed != "pos"}
+    state["pos"] = jnp.zeros((rows,), jnp.int32)
+
+    def call(params, state, prompt):
+        step = dec._step_fn(params)
+        state, first = decode.prefill(step, state, prompt, dec._takes_block)
+        return decode.greedy_decode(step, state, first, V + 1, 3, rows)
+
+    block = decode.PREFILL_BLOCK    # the module's fixture may hold it down
+    before = telemetry.snapshot()
+    jax.make_jaxpr(call)(dec._params, state,
+                         jnp.zeros((rows, block), jnp.int32))
+    delta = telemetry.snapshot_delta(before)
+    label = "window_attention_lowerings_total{block=%d,block_k=%d," \
+        "kind=full,kv_heads=%d,path=%s,window=0}"
+    assert {k: v for k, v in delta.items()
+            if k.startswith(("window_attention_lowerings_total",
+                             "cached_attention_lowerings_total"))} == {
+        label % (1, 256, heads, "kernel"): layers,
+        label % (block, 0, heads, "plain"): layers,
+        "cached_attention_lowerings_total{block=1}": layers,
+        "cached_attention_lowerings_total{block=%d}" % block: layers}
+
+
 def test_counters_say_a_one_token_step_is_scanned(decoders):
     """A `[batch]` token feed keeps the scan of single positions: the
     first outside the scan, the scan's body, the decoding scan's."""

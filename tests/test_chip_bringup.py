@@ -270,6 +270,37 @@ def test_the_grouped_decode_kernel_lowers_for_tpu(block, window, kernels):
         assert 'kernel_name = "%s"' % name in module
 
 
+@pytest.mark.parametrize("block,kernels", [
+    (1, ["gqa_write_r4", "gqa_decode_k512_h16"]), (128, [])])
+def test_the_narrow_decode_kernels_lower_for_tpu(block, kernels):
+    """`cached_attention` at gpt2m-decode's shape (48 rows, 16 heads of
+    64, 1024-slot bfloat16 caches) lowered for the TPU from this CPU
+    host: a decode step holds two Mosaic kernels, the slot's write and
+    the walk (named with its block of slots and the heads a grid step),
+    and no loop of the op's own (`decode_hbm_roofline` asks for exactly
+    two outermost `while`s a call); a prefill block of 128 positions
+    holds none."""
+    from paddle_tpu.ops import registry
+
+    kernel = registry.get_op_info("cached_attention").kernel
+    b, h, d, bf16 = 48, 16, 64, jnp.bfloat16
+    cache = jax.ShapeDtypeStruct((b, h, 1024, d), bf16)
+    new = jax.ShapeDtypeStruct((b, block, h * d), bf16)
+    ins = {"Q": [new], "KNew": [new], "VNew": [new], "KCache": [cache],
+           "VCache": [cache],
+           "Position": [jax.ShapeDtypeStruct((b,), jnp.int32)]}
+
+    def step(ins):
+        return kernel(None, ins, {"num_heads": h})
+
+    module = jax.export.export(jax.jit(step), platforms=["tpu"])(
+        ins).mlir_module()
+    assert module.count("tpu_custom_call") == len(kernels)
+    for name in kernels:
+        assert 'kernel_name = "%s"' % name in module
+    assert "stablehlo.while" not in module
+
+
 def test_flash_attention_refuses_a_ragged_block():
     """A sequence its block does not divide raises with the shape in the
     message; the block no longer shrinks toward 1 without saying so."""

@@ -287,7 +287,12 @@ def _lowering(kind, kv_heads, window, path, block_k, block=1):
 def test_the_kernel_refuses_what_it_does_not_take():
     q, k, v = _kernel_ins(np.random.RandomState(5), 3, 128)
     assert not gqa_decode.fits(8, 100, 128)
+    # 64-wide heads: one query a key/value head, 128 slots a tile; a
+    # group's rows, a block of positions and any other width are refused
+    assert gqa_decode.fits(1, 128, 64)
     assert not gqa_decode.fits(8, 128, 64)
+    assert not gqa_decode.fits(1, 100, 64)
+    assert not gqa_decode.fits(1, 1024, 32)
     assert gqa_decode.choose_block(32768) == 2048
     assert gqa_decode.choose_block(128) == 128
     # the rows' float32 scores size the block of slots: a group of 8 at
@@ -305,6 +310,110 @@ def test_the_kernel_refuses_what_it_does_not_take():
                               positions=2)
     with pytest.raises(ValueError, match="gqa_decode"):   # 4 rows by 3
         gqa_decode.gqa_decode(q, k, v, jnp.int32(3), 1.0, positions=3)
+
+
+# -- (b') 64-wide heads: the kernels over caches as they lie, slots-minor ------
+
+@pytest.mark.parametrize("batch,kv_heads,slots,block_k,step", [
+    (48, 16, 1024, 512, (1, 16)),       # gpt2m-decode's
+    (48, 16, 768, 256, (2, 16)), (4, 2, 128, 128, (4, 2)),
+    (6, 12, 1024, 512, (1, 12)), (3, 32, 640, 128, (3, 16))])
+def test_the_chooser_shares_a_step_among_narrow_heads(batch, kv_heads, slots,
+                                                      block_k, step):
+    """The block is the largest that tiles the extent; the heads and
+    the rows that share a grid step divide theirs and keep the step's
+    block of a cache within a megabyte."""
+    assert gqa_decode.choose_block(slots, 1, 2, 64) == block_k
+    assert gqa_decode.choose_step(batch, kv_heads, block_k) == step
+
+
+def _narrow_ins(rs, pos, slots, kv_heads, dtype, past=np.nan):
+    """A step's operands at 64-wide heads, 2 rows: the caches hold
+    `past` from slot `pos` on (the step writes slot `pos`)."""
+    q, k_new, v_new = (jnp.asarray(rs.randn(2, kv_heads, 1, 64), dtype)
+                       for _ in range(3))
+    live = (np.arange(slots) < pos)[None, None, :, None]
+    k, v = (jnp.asarray(np.where(live, rs.randn(2, kv_heads, slots, 64),
+                                 past), dtype) for _ in range(2))
+    return q, k_new, v_new, k, v
+
+
+@pytest.mark.parametrize("dtype,atol", [(jnp.float32, 2e-5),
+                                        (jnp.bfloat16, 3e-2)])
+@pytest.mark.parametrize("kv_heads", [2, 16])
+@pytest.mark.parametrize("pos", [0, 127, 128, 511, 512, 1023])
+def test_the_narrow_walk_is_the_plain_path(pos, kv_heads, dtype, atol):
+    """2 or 16 heads a grid step, NaN in every slot the step does not
+    write or attend: the write sets slot `pos` and no other, the walk
+    reads nothing past it, and the values are the plain path's."""
+    q, k_new, v_new, k, v = _narrow_ins(np.random.RandomState(pos), pos,
+                                        1024, kv_heads, dtype)
+    k_out, v_out = gqa_decode.write_step(k, v, k_new, v_new, jnp.int32(pos))
+    for got, cache, new in ((k_out, k, k_new), (v_out, v, v_new)):
+        np.testing.assert_array_equal(
+            np.asarray(got, np.float32),
+            np.asarray(jax.lax.dynamic_update_slice_in_dim(
+                cache, new, pos, axis=2), np.float32))
+    got = gqa_decode.gqa_decode(q, k_out, v_out, jnp.int32(pos), 64 ** -0.5,
+                                step=(1, kv_heads))
+    s = jnp.einsum("bhgd,bhkd->bhgk", q.astype(jnp.float32),
+                   jnp.nan_to_num(k_out).astype(jnp.float32),
+                   precision=jax.lax.Precision.HIGHEST) * 64 ** -0.5
+    s = jnp.where(jnp.arange(1024) <= pos, s, -1e30)
+    want = jnp.einsum("bhgk,bhkd->bhgd", jax.nn.softmax(s, axis=-1),
+                      jnp.nan_to_num(v_out).astype(jnp.float32),
+                      precision=jax.lax.Precision.HIGHEST)
+    assert np.isfinite(np.asarray(got, np.float32)).all()
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want), atol=atol)
+
+
+def test_the_narrow_kernels_refuse_what_they_do_not_take():
+    q, k_new, v_new, k, v = _narrow_ins(np.random.RandomState(6), 3, 128, 2,
+                                        jnp.float32, past=0.0)
+    with pytest.raises(ValueError, match="gqa_decode"):     # a ring
+        gqa_decode.gqa_decode(q, k, v, jnp.int32(3), 1.0, window=128)
+    with pytest.raises(ValueError, match="gqa_decode"):     # two rows a head
+        gqa_decode.gqa_decode(jnp.concatenate([q, q], axis=2), k, v,
+                              jnp.int32(3), 1.0)
+    with pytest.raises(ValueError, match="gqa_decode"):     # another type
+        gqa_decode.gqa_decode(q, k.astype(jnp.bfloat16),
+                              v.astype(jnp.bfloat16), jnp.int32(3), 1.0)
+    with pytest.raises(ValueError, match="write_step"):
+        gqa_decode.write_step(k, v, k_new.astype(jnp.bfloat16), v_new,
+                              jnp.int32(3))
+    with pytest.raises(ValueError, match="write_step"):     # 100 slots
+        gqa_decode.write_step(k[:, :, :100], v[:, :, :100], k_new, v_new,
+                              jnp.int32(3))
+
+
+def test_the_op_takes_the_narrow_kernels_for_a_step_in_qs_type():
+    """16 heads of 64 over 128 slots: a step over caches in Q's type
+    writes and walks by the kernels; a block of positions, and a step
+    over caches in another type than Q's, take the plain path; all
+    three are masked attention."""
+    q, k, v = _sequence(np.random.RandomState(9), 16, 16, 64, 12, rows=1)
+    before = telemetry.snapshot()
+    steps, _ = _through(q, k, v, 16, 16, 0, 128, list(range(12)))
+    blocks, _ = _through(q, k, v, 16, 16, 0, 128, [0, 5])
+    delta = telemetry.snapshot_delta(before)
+    want = _masked(q, k, v, 16, 16, 0)
+    np.testing.assert_allclose(steps, want, atol=1e-5)
+    np.testing.assert_allclose(blocks, want, atol=1e-5)
+    assert {key: n for key, n in delta.items()
+            if key.startswith("window_attention_lowerings_total")} == {
+        _lowering("full", 16, 0, "kernel", 128): 12,
+        _lowering("full", 16, 0, "plain", 0, block=5): 1,
+        _lowering("full", 16, 0, "plain", 0, block=7): 1}
+    caches = [jnp.zeros((1, 16, 128, 64), jnp.bfloat16)] * 2
+    before = telemetry.snapshot()
+    out, *_ = _attend((q[:, :1],), (k[:, :1], caches[0]),
+                      (v[:, :1], caches[1]), jnp.zeros((1,), jnp.int32),
+                      num_heads=16)
+    assert telemetry.snapshot_delta(before)[
+        _lowering("full", 16, 0, "plain", 0)] == 1
+    rounded = np.asarray(jnp.asarray(v[:, :1], jnp.bfloat16), np.float32)
+    np.testing.assert_allclose(np.asarray(out), rounded, atol=1e-6)
 
 
 # -- (c) the step Program against the reference's full forward -----------------
@@ -636,9 +745,12 @@ def _cached_attention_before(q, k_new, v_new, k_cache, v_cache, pos,
 
 @pytest.mark.parametrize("block", [1, 128])
 def test_gpt2s_cached_attention_lowers_as_it_did(block):
-    """16 heads of 64, no `num_kv_heads`, no `window`, bfloat16 caches:
-    the decode cell's instance of the op, a step and a prefill block."""
-    rows, heads, dim, slots = 2, 16, 64, 256
+    """16 heads of 64, no `num_kv_heads`, no `window`, bfloat16 caches,
+    a step and a prefill block over an extent the kernels do not take
+    (200 slots: no multiple of 128): the plain path is the parent's,
+    to the letter.  The decode cell's own instance takes the kernels
+    (tests/test_chip_bringup.py)."""
+    rows, heads, dim, slots = 2, 16, 64, 200
     like = jax.ShapeDtypeStruct
     args = [like((rows, block, heads * dim), jnp.bfloat16)] * 3 \
         + [like((rows, heads, slots, dim), jnp.bfloat16)] * 2 \
