@@ -153,7 +153,7 @@ def mla_index_select(q, w, k_new, cache, position, num_heads, top_k,
 def flash_attention(queries, keys, values, num_heads=1, causal=False,
                     sm_scale=None, sequence_parallel_axis="",
                     sequence_parallel_mode="ring", block_size=None,
-                    name=None):
+                    name=None, window=0):
     """Fused multi-head attention over dense [batch, seq, dim] tensors.
 
     Exceeds the reference surface (python/paddle/v2/fluid/nets.py:338
@@ -171,20 +171,27 @@ def flash_attention(queries, keys, values, num_heads=1, causal=False,
     writes `Lse`, each score row's log-sum-exp as float32 [batch,
     heads, seq] whatever the compute type: the one statistic its
     gradient reads, kept so that the backward pass does not run the
-    forward kernel again.
+    forward kernel again.  With `window` W > 0 (and `causal`) a query
+    attends its last W keys alone, its own among them; the op carries
+    the attr only then.
     """
     helper = LayerHelper("flash_attention", name=name)
     out = helper.create_tmp_variable(queries.dtype)
     lse = helper.create_tmp_variable("float32", stop_gradient=True)
+    attrs = {"num_heads": int(num_heads), "causal": bool(causal),
+             "sm_scale": float(sm_scale or 0.0),
+             "sequence_parallel_axis": sequence_parallel_axis,
+             "sequence_parallel_mode": sequence_parallel_mode,
+             "block_size": int(block_size or 0)}
+    if window:
+        if not causal:
+            raise ValueError("flash_attention: a window of %d keys bounds "
+                             "a causal query; causal is False" % window)
+        attrs["window"] = int(window)
     helper.append_op(
         type="flash_attention",
         inputs={"Q": [queries], "K": [keys], "V": [values]},
-        outputs={"Out": [out], "Lse": [lse]},
-        attrs={"num_heads": int(num_heads), "causal": bool(causal),
-               "sm_scale": float(sm_scale or 0.0),
-               "sequence_parallel_axis": sequence_parallel_axis,
-               "sequence_parallel_mode": sequence_parallel_mode,
-               "block_size": int(block_size or 0)})
+        outputs={"Out": [out], "Lse": [lse]}, attrs=attrs)
     return out
 
 
@@ -782,7 +789,8 @@ def rope(input, positions, num_heads, theta=10000.0, inv_freq=None,
 def moe(input, num_experts, expert_size, top_k, router_attr=None,
         gate_attr=None, up_attr=None, down_attr=None, name=None,
         scoring="softmax", norm_topk=False, scale=1.0, held=None,
-        bias_attr=None, n_group=0, topk_group=0):
+        bias_attr=None, n_group=0, topk_group=0, activation="silu",
+        router_input=None):
     """A routed expert layer over `input` [..., hidden] (ops/moe.py): a
     float32 router sends every token to its `top_k` of `num_experts`
     gated-SiLU experts of width `expert_size`, each computed for it (no
@@ -802,6 +810,13 @@ def moe(input, num_experts, expert_size, top_k, router_attr=None,
     the choice and not for the weights, and `n_group` > 1 limits the
     choice to the experts of a token's `topk_group` best groups of
     consecutive experts (ops/moe.py moe_router).
+
+    `held` = (first, count) makes the layer one chip's share of an
+    expert-parallel one: it holds `count` of the experts its router
+    scores, and its output and every gradient are those experts' part.
+    `activation` "relu" makes the experts ReGLU.  With `router_input`
+    [..., hidden] the router scores that tensor and the experts still
+    read `input` (a router placed before the attention sub-layer).
     """
     helper = LayerHelper("moe", name=name)
     hidden = int(input.shape[-1])
@@ -833,7 +848,10 @@ def moe(input, num_experts, expert_size, top_k, router_attr=None,
         routing = {}
     share = {} if (first, count) == (0, num_experts) else {
         "first_expert": int(first), "scored": int(num_experts)}
-    router_ins = {"X": [input], "W": [w_router]}
+    router_ins = {"X": [input if router_input is None else router_input],
+                  "W": [w_router]}
+    if activation != "silu":
+        share = dict(share, activation=str(activation))
     if bias_attr is not None:
         router_ins["Bias"] = [helper.create_parameter(
             bias_attr, shape=[num_experts], dtype="float32", is_bias=True)]

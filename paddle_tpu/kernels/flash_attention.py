@@ -42,6 +42,18 @@ kernels that walk, a crossed chunk is folded whole through the same
 fold, every score compared.  `score_pairs` counts what either way
 folds, and the counter `flash_attention_pairs_total` reports it.
 
+A `window` W > 0 under the causal mask bounds the other side: query i
+folds keys max(0, i - W + 1) .. i.  The lower edge is the diagonal moved
+W keys back, so the chunk loops get its two marks from the formulas that
+give the diagonal's: a chunk that lies wholly before a block's first
+visible key is neither fetched (the index maps re-name the first visible
+block) nor folded; one the lower edge crosses is folded whole, every
+score compared against both edges (`_see`), before the unmasked chunks
+and the diagonal's staircase; the blocks are no larger than the window
+needs (`_window_blocks`).  The kernels' names carry `_w<W>`.  With
+`window` 0, or one no query reaches past, nothing here is traced and
+the kernels are the causal ones.
+
 Two layouts, one set of kernel bodies.  `flash_attention`,
 `flash_attention_with_lse` and `_bwd` take q, k, v (and do) as
 [batch, heads, seq, dim], one head a grid step: for the callers that
@@ -121,6 +133,17 @@ def _candidates(seq):
     divide the sequence, else the whole of one that fits in a block."""
     found = [b for b in _BLOCKS if seq % b == 0]
     return found or ([seq] if seq <= _BLOCKS[0] else [])
+
+
+def _window_blocks(blocks, window):
+    """`blocks` (largest first) without those larger than the smallest
+    that holds a whole `window`: a chunk the lower edge crosses is
+    folded whole, so a block wider than the window folds mostly scores
+    nobody attends.  All of them where there is no window."""
+    if not window:
+        return blocks
+    return [b for b in blocks if b < 2 * max(window, _BLOCKS[-1])] \
+        or blocks[-1:]
 
 
 def _pad_to_lanes(n):
@@ -225,7 +248,8 @@ def _step_bytes(bq, bk, kv_rows, d, itemsize):
     return tiles + scratch + stats + chunk
 
 
-def _choose_blocks(q_shape, k_shape, itemsize, block_q=None, block_k=None):
+def _choose_blocks(q_shape, k_shape, itemsize, block_q=None, block_k=None,
+                   window=0):
     """(block_q, block_k, kv_resident) for one call, from what the
     kernel sees: the sequence lengths, the width of a grid step's heads
     and the item size (not the mask: the staircase cuts off what lies
@@ -233,12 +257,14 @@ def _choose_blocks(q_shape, k_shape, itemsize, block_q=None, block_k=None):
     names is kept as it is; what is chosen is the pair that folds most
     scores at a time under the VMEM budget, with half its block_k if
     all of one head's K and V then fit beside the fold: `kv_resident`
-    says a grid step holds them all, not one block_k chunk of them."""
+    says a grid step holds them all, not one block_k chunk of them.
+    Under a `window` no chosen block is wider than the window needs
+    (`_window_blocks`)."""
     tq, d = q_shape[2], q_shape[3]
     tk = k_shape[2]
-    qs = (_candidates(tq) if block_q is None
+    qs = (_window_blocks(_candidates(tq), window) if block_q is None
           else [_block(tq, block_q, "query", q_shape)])
-    ks = (_candidates(tk) if block_k is None
+    ks = (_window_blocks(_candidates(tk), window) if block_k is None
           else [_block(tk, block_k, "key", k_shape)])
     named = block_q is not None and block_k is not None
     fit = [(bq, bk) for bq, bk in sorted(
@@ -330,42 +356,55 @@ def _fold_crossed(fold, lead, bq, bk, q_offset, s):
         pl.when(lax.eq(lead, t))(staircase(t))
 
 
-def _chunk_pairs(lead, bq, bk, s):
+def _chunk_pairs(lead, bq, bk, s, window=0):
     """The score pairs a kernel folds of a [bk, bq] chunk whose first
-    key lies `lead` ahead of its first query."""
-    if lead >= bq:
+    key lies `lead` ahead of its first query: none of one that lies
+    wholly before the `window`, all of one its lower edge crosses."""
+    if lead >= bq or (window and lead <= 1 - bk - window):
         return 0
-    if lead <= 1 - bk or s is None:
+    if lead <= 1 - bk or s is None or (window and lead < bq - window):
         return bq * bk
     return sum(keys * (bq - query)
                for _, keys, query, _ in _stairs(lead, bq, bk, s))
 
 
 @functools.lru_cache(maxsize=None)
-def score_pairs(tq, tk, causal, q_offset, bq, bk, widest):
+def score_pairs(tq, tk, causal, q_offset, bq, bk, widest, window=0):
     """(folded, attended): the score pairs the kernels compute for one
     head at blocks (bq, bk) with pieces up to `widest` keys (None: a
     kernel that folds a crossed chunk whole whatever the shapes, as the
     two backward kernels that walk), and those among them a query
     attends.  The forward (a block of queries over chunks of keys) and
     the backward (a block of keys over chunks of queries) meet the same
-    [bk, bq] chunks."""
+    [bk, bq] chunks.  Under a `window` a query attends the last
+    `window` of the keys the causal mask leaves it."""
     if not causal:
         return tq * tk, tq * tk
     s = _stair_width(bq, bk, q_offset, widest)
-    folded = sum(_chunk_pairs(c * bk - i * bq - q_offset, bq, bk, s)
+    folded = sum(_chunk_pairs(c * bk - i * bq - q_offset, bq, bk, s, window)
                  for i in range(tq // bq) for c in range(tk // bk))
-    attended = sum(min(max(q_offset + i + 1, 0), tk) for i in range(tq))
-    return folded, attended
+    seen = [min(max(q_offset + i + 1, 0), tk) for i in range(tq)]
+    if window:
+        seen = [n - min(max(q_offset + i + 1 - window, 0), n)
+                for i, n in enumerate(seen)]
+    return folded, sum(seen)
 
 
-def _see(x, lead, fill):
+def _see(x, lead, fill, window=0):
     """`x` [keys, queries] with `fill` where the query does not see the
     key: a query sees the keys at or before its own position, and the
     first key lies `lead` ahead of the first query.  Where `lead` is
     known when the kernel is traced, only the first lead + keys queries
-    can miss a key, and only their columns are compared."""
+    can miss a key, and only their columns are compared.  Under a
+    `window` a query sees the last `window` of those keys alone, and
+    every score is compared against both edges."""
     keys, queries = x.shape
+    if window:
+        ahead = lax.sub(lax.broadcasted_iota(jnp.int32, x.shape, 1),
+                        lax.broadcasted_iota(jnp.int32, x.shape, 0))
+        seen = lax.bitwise_and(lax.ge(ahead, lead),
+                               lax.lt(ahead, lax.add(lead, window)))
+        return lax.select(seen, x, lax.full_like(x, fill))
     crossed = min(lead + keys, queries) if isinstance(lead, int) else queries
     part = x if crossed == queries else lax.slice_in_dim(x, 0, crossed, axis=1)
     ahead = lax.sub(lax.broadcasted_iota(jnp.int32, part.shape, 1),
@@ -442,7 +481,8 @@ def _on_platform(call, *args):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_scr, vt_scr,
-                *, sm_scale, causal, q_offset, bk, resident, d, stair):
+                *, sm_scale, causal, q_offset, bk, resident, d, stair,
+                window=0):
     """One (batch, heads, q_block, kv_block) grid step: the K/V block
     in VMEM (all of the keys, or one chunk of them) is folded, bk keys
     at a time and for every head of the step's, into the running max
@@ -486,10 +526,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_scr, vt_scr,
     ahead = lax.sub(lax.add(lax.mul(i, bq), q_offset),
                     lax.mul(j, kv_rows))
 
-    def _fold(c, key, keys, query, lead):
+    def _fold(c, key, keys, query, lead, edge=0):
         """`keys` keys of chunk c from its `key`-th into the block's
         queries from the `query`-th on; `lead` as `_see` takes it, None
-        where every query sees every key."""
+        where every query sees every key; `edge` the window where its
+        lower edge crosses the chunk too."""
         if keys == kv_rows:
             # one chunk, read whole: a block that is the whole of a
             # ragged sequence has no aligned slice
@@ -506,7 +547,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_scr, vt_scr,
             s = lax.mul(_matmul(k, _only_head(q, own), 1),
                         sm_scale)                         # [keys, queries]
             if lead is not None:
-                s = _see(s, lead, NEG_INF)
+                s = _see(s, lead, NEG_INF, edge)
             m_prev = m_ref[stat, cols]                       # [1, queries]
             m_new = lax.max(
                 m_prev, lax.expand_dims(lax.reduce_max(s, (0,)), (0,)))
@@ -540,7 +581,25 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_scr, vt_scr,
                         chunks)
         seen = lax.min(lax.div(lax.max(lax.add(ahead, bq + bk - 1), 0),
                                bk), chunks)
-        _fold_chunks(_fold_whole, 0, whole)
+        first = 0
+        if window:
+            # the lower edge is the diagonal `window` keys back: chunks
+            # wholly before it are never touched, those it crosses are
+            # compared against both edges.  They come first, and a
+            # query that sees none of their keys gathers exp(0) terms
+            # at a maximum of NEG_INF, which its first visible key (a
+            # later chunk's: its own position's at the latest) scales
+            # by exp(NEG_INF - m) = 0
+            back = lax.sub(ahead, window)
+            first = lax.min(lax.div(lax.max(lax.add(back, bq + bk - 1), 0),
+                                    bk), chunks)
+            _fold_chunks(
+                lambda c: _fold(c, 0, bk, 0, lax.sub(lax.mul(c, bk), ahead),
+                                window),
+                lax.min(lax.div(lax.max(lax.add(back, 1), 0), bk), chunks),
+                first)
+            whole = lax.max(whole, first)
+        _fold_chunks(_fold_whole, first, whole)
         _fold_chunks(_fold_masked, whole, seen)
     else:
         _fold_chunks(_fold_whole, 0, chunks)
@@ -556,14 +615,25 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_scr, vt_scr,
             lax.transpose(acc_scr[...], (1, 0))[:, :lanes], o_ref.dtype)
 
 
-def _stair_suffix(stair):
+def _stair_suffix(stair, window=0):
     """What a kernel's name says of its staircase: the pieces' width,
-    nothing where a crossed chunk is folded whole."""
-    return "_s%d" % stair if stair else ""
+    nothing where a crossed chunk is folded whole; and of its window."""
+    return ("_s%d" % stair if stair else "") \
+        + ("_w%d" % window if window else "")
+
+
+def _live_window(window, causal, tq, q_offset):
+    """`window` where it cuts a key off some query, else 0: the causal
+    kernels serve a window no query reaches past."""
+    if window and not causal:
+        raise ValueError("flash_attention: a window of %d keys is the "
+                         "causal mask's lower edge; causal is False"
+                         % window)
+    return window if 0 < window < q_offset + tq else 0
 
 
 def _fwd(q, k, v, sm_scale, causal, block_q, block_k, q_offset,
-         num_heads=None, split=False):
+         num_heads=None, split=False, window=0):
     """o, laid out as q, and the float32 [batch, heads, Tq] row
     statistics m and l, at blocks chosen here from what a grid step
     holds.  With `num_heads` the operands are [batch, seq, heads * d];
@@ -574,24 +644,28 @@ def _fwd(q, k, v, sm_scale, causal, block_q, block_k, q_offset,
     if call.g is None:
         o, m, l = _fwd(*(split_heads(x, num_heads) for x in (q, k, v)),
                        sm_scale, causal, block_q, block_k, q_offset,
-                       split=True)
+                       split=True, window=window)
         return merge_heads(o), m, l
+    window = _live_window(window, causal, call.tq, q_offset)
     bq, bk, resident = _choose_blocks(*call.step_shapes, q.dtype.itemsize,
-                                      block_q, block_k)
+                                      block_q, block_k, window)
     telemetry.on_flash_attention_lowering(
         bq, bk, resident, "split" if split else call.g)
     telemetry.on_flash_attention_pairs("fwd", *(
         call.batch * call.heads * n for n in score_pairs(
-            call.tq, call.tk, causal, q_offset, bq, bk, _STAIR)))
+            call.tq, call.tk, causal, q_offset, bq, bk, _STAIR, window)))
+    if window:
+        telemetry.on_flash_window_lowering("fwd", window, bq, bk)
     return _fwd_kernels(q, k, v, num_heads=num_heads, sm_scale=sm_scale,
                         causal=causal, q_offset=q_offset, bq=bq, bk=bk,
-                        resident=resident)
+                        resident=resident, window=window)
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "num_heads", "sm_scale", "causal", "q_offset", "bq", "bk", "resident"))
+    "num_heads", "sm_scale", "causal", "q_offset", "bq", "bk", "resident",
+    "window"))
 def _fwd_kernels(q, k, v, *, num_heads, sm_scale, causal, q_offset, bq, bk,
-                 resident):
+                 resident, window=0):
     """The forward kernel at blocks already chosen; under `jax.jit` for
     the reason `_bwd_kernels` gives."""
     call = _Call.of(q.shape, k.shape, num_heads)
@@ -604,6 +678,10 @@ def _fwd_kernels(q, k, v, *, num_heads, sm_scale, causal, q_offset, bq, bk,
             # pipeline does not fetch what the kernel will not read
             last = lax.add(lax.mul(i, bq), q_offset + bq - 1)
             j = lax.min(j, lax.div(lax.max(last, 0), kv_rows))
+        if window:
+            # and so does one wholly before the block's first visible key
+            first = lax.add(lax.mul(i, bq), q_offset - window + 1)
+            j = lax.max(j, lax.div(lax.max(first, 0), kv_rows))
         return j
 
     def q_index(i, j):
@@ -614,7 +692,7 @@ def _fwd_kernels(q, k, v, *, num_heads, sm_scale, causal, q_offset, bq, bk,
         pl.pallas_call,
         functools.partial(_fwd_kernel, sm_scale=sm_scale, causal=causal,
                           q_offset=q_offset, bk=bk, resident=resident,
-                          d=call.d, stair=stair),
+                          d=call.d, stair=stair, window=window),
         grid=call.steps + (call.tq // bq, call.tk // kv_rows),
         in_specs=[call.rows(bq, q_index), call.rows(kv_rows, kv_index),
                   call.rows(kv_rows, kv_index)],
@@ -633,8 +711,8 @@ def _fwd_kernels(q, k, v, *, num_heads, sm_scale, causal, q_offset, bq, bk,
                 "arbitrary" if resident else "parallel", "arbitrary")),
         # the trace shows which tiling ran; readers match the prefix
         name="flash_attention_fwd_q%d_k%d%s%s%s"
-             % (bq, bk, "_kvres" if resident else "", _stair_suffix(stair),
-                call.suffix),
+             % (bq, bk, "_kvres" if resident else "",
+                _stair_suffix(stair, window), call.suffix),
     )
     o, m, l = _on_platform(pallas_call, q, k, v)
     rows = (call.batch, call.heads, call.tq)
@@ -642,41 +720,43 @@ def _fwd_kernels(q, k, v, *, num_heads, sm_scale, causal, q_offset, bq, bk,
 
 
 def _flash_fwd_rule(q, k, v, sm_scale, causal, block_q, block_k,
-                    q_offset, num_heads):
+                    q_offset, num_heads, window=0):
     if sm_scale is None:
         sm_scale = _Call.of(q.shape, k.shape, num_heads).d ** -0.5
     o, m, l = _fwd(q, k, v, sm_scale, causal, block_q, block_k, q_offset,
-                   num_heads)
+                   num_heads, window=window)
     # a probability is exp(s - lse): of the two statistics the backward
     # reads only their log-sum-exp; a row that saw no key has l = 0
     lse = m + jnp.log(jnp.where(l > 0, l, 1.0))
     return (o, lse), (q, k, v, o, lse)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
 def flash_attention_with_lse(q, k, v, sm_scale=None, causal=False,
                              block_q=None, block_k=None, q_offset=0,
-                             num_heads=None):
+                             num_heads=None, window=0):
     """`flash_attention` and, beside its result, the float32
     [batch, heads, Tq] log-sum-exp of every row of scores: what a
     caller that writes its own gradient (`ops/attention.py`) keeps for
     `_bwd`, so that its backward pass need not run the forward kernel
     again.  q, k, v: [batch, heads, seq, dim], or with `num_heads`
     [batch, seq, num_heads * dim], and the result laid out as they
-    are."""
+    are.  With `window` W > 0 (and `causal`) query i attends keys
+    max(0, i - W + 1) .. i."""
     return _flash_fwd_rule(q, k, v, sm_scale, causal, block_q, block_k,
-                           q_offset, num_heads)[0]
+                           q_offset, num_heads, window)[0]
 
 
 def flash_attention(q, k, v, sm_scale=None, causal=False, block_q=None,
-                    block_k=None, q_offset=0):
+                    block_k=None, q_offset=0, window=0):
     """softmax(q k^T * scale [+ causal mask]) v without materializing
     the score matrix.  q,k,v: [B, H, T, D]; q_offset shifts the causal
     diagonal (used by ring attention where q is a sequence shard).
     A block left None is chosen by the kernel from the shapes; one that
-    is named must divide its sequence."""
+    is named must divide its sequence.  `window` W > 0 bounds a causal
+    query to its last W keys."""
     return flash_attention_with_lse(q, k, v, sm_scale, causal, block_q,
-                                    block_k, q_offset)[0]
+                                    block_k, q_offset, None, window)[0]
 
 
 def _bwd_step_bytes(bq, bk, d, itemsize, tq=None, heads=1):
@@ -706,7 +786,7 @@ def _bwd_step_bytes(bq, bk, d, itemsize, tq=None, heads=1):
 
 
 def _choose_bwd_blocks(q_shape, k_shape, itemsize, block_q=None,
-                       block_k=None, heads=1):
+                       block_k=None, heads=1, window=0):
     """(block_q, block_k, one_kernel) for the backward of one call,
     from what `_choose_blocks` reads and the `heads` a grid step holds:
     a named block is kept, and the chosen pair is the one that holds
@@ -714,12 +794,13 @@ def _choose_bwd_blocks(q_shape, k_shape, itemsize, block_q=None,
     that hold as many), first among the pairs that leave room for all
     of a head's queries and its dq: `one_kernel` says one kernel then
     makes dq, dk and dv together, and not one kernel dk and dv and
-    another dq, each walking the other side a block a grid step."""
+    another dq, each walking the other side a block a grid step.
+    Under a `window` no chosen block is wider than the window needs."""
     tq, d = q_shape[2], q_shape[3]
     tk = k_shape[2]
-    qs = (_candidates(tq) if block_q is None
+    qs = (_window_blocks(_candidates(tq), window) if block_q is None
           else [_block(tq, block_q, "query", q_shape)])
-    ks = (_candidates(tk) if block_k is None
+    ks = (_window_blocks(_candidates(tk), window) if block_k is None
           else [_block(tk, block_k, "key", k_shape)])
     named = block_q is not None and block_k is not None
     pairs = sorted(itertools.product(qs, ks),
@@ -752,7 +833,7 @@ def _bwd_matmul(a, b, rhs_contracts, widen):
     return _matmul(a, b, rhs_contracts)
 
 
-def _bwd_chunk(q, k, v, do, lse, delta, sm_scale, behind, widen):
+def _bwd_chunk(q, k, v, do, lse, delta, sm_scale, behind, widen, window=0):
     """Probabilities and ds of one chunk of one head, both
     [keys, queries] float32: p = exp(s - lse), ds = p * (dp - delta),
     from the scores k q^T and dp = v do^T; lse and delta are
@@ -760,11 +841,13 @@ def _bwd_chunk(q, k, v, do, lse, delta, sm_scale, behind, widen):
     come with the other heads' lanes zeroed (`_only_head`).  `behind`
     is None where every query sees every key of the chunk, else how far
     the chunk's first key lies ahead of its first query, as `_see`
-    takes it: p is 0 where the query does not see the key."""
+    takes it: p is 0 where the query does not see the key (under
+    `window`, where it lies before the query's last `window` keys
+    too)."""
     s = lax.mul(_bwd_matmul(k, q, 1, widen), sm_scale)
     p = lax.exp(lax.sub(s, lse))
     if behind is not None:
-        p = _see(p, behind, 0)
+        p = _see(p, behind, 0, window)
     ds = lax.mul(p, lax.sub(_bwd_matmul(v, do, 1, widen), delta))
     return p, ds
 
@@ -804,7 +887,7 @@ def _write_dq(dq_ref, dqt_scr, sm_scale):
 
 def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                 dk_ref, dv_ref, dqt_scr, kt_scr, dk_scr, dv_scr, *,
-                sm_scale, causal, q_offset, widen, bq, d, stair):
+                sm_scale, causal, q_offset, widen, bq, d, stair, window=0):
     """One (batch, heads, k_block) grid step of the whole backward: the
     step's bk keys meet the queries, all in VMEM, bq at a time and for
     every head of the step's, from the first chunk that sees a key of
@@ -830,10 +913,11 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     # how far this step's first key is ahead of the first query
     behind = lax.sub(lax.mul(j, bk), q_offset)
 
-    def _fold(c, key, keys, query, lead):
+    def _fold(c, key, keys, query, lead, edge=0):
         """The block's `keys` keys from its `key`-th meet the queries of
         chunk c from the chunk's `query`-th on; `lead` as `_see` takes
-        it, None where every query sees every key."""
+        it, None where every query sees every key; `edge` the window
+        where its lower edge crosses the chunk too."""
         if tq == bq:
             # one chunk, read whole: a block that is the whole of a
             # ragged sequence has no aligned slice
@@ -850,7 +934,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
             p, ds = _bwd_chunk(
                 q, _only_head(k, own), _only_head(v, own), do,
                 lse_ref[stat, rows], delta_ref[stat, rows], sm_scale, lead,
-                widen)
+                widen, edge)
             ds = lax.convert_element_type(ds, q.dtype)
             _add_dkv(dk_scr, dv_scr, h, p, ds, q, do, widen, these)
             dqt_scr[head, rows] = lax.add(
@@ -875,8 +959,22 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         seen = lax.min(lax.div(lax.max(behind, 0), bq), chunks)
         whole = lax.min(lax.div(lax.max(lax.add(behind, bk + bq - 2), 0),
                                 bq), chunks)
+        last = chunks
+        if window:
+            # the lower edge is the diagonal `window` queries on: the
+            # chunks it crosses are compared against both edges, those
+            # wholly past it never touched
+            on = lax.add(behind, window)
+            last = lax.min(lax.div(lax.max(on, 0), bq), chunks)
+            whole = lax.min(whole, last)
+            _fold_chunks(
+                lambda c: _fold(c, 0, bk, 0, lax.sub(behind, lax.mul(c, bq)),
+                                window),
+                last,
+                lax.min(lax.div(lax.max(lax.add(on, bk + bq - 2), 0), bq),
+                        chunks))
         _fold_chunks(_fold_masked, seen, whole)
-        _fold_chunks(_fold_whole, whole, chunks)
+        _fold_chunks(_fold_whole, whole, last)
     else:
         _fold_chunks(_fold_whole, 0, chunks)
     _write_dkv(dk_ref, dv_ref, dk_scr, dv_scr, sm_scale, d)
@@ -884,23 +982,30 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         lambda: _write_dq(dq_ref, dqt_scr, sm_scale))
 
 
-def _fold_where_seen(fold, causal, behind, bq, bk):
+def _fold_where_seen(fold, causal, behind, bq, bk, window=0):
     """`fold(masked)` for the one [bk, bq] chunk of a walking kernel's
     grid step, whose first key lies `behind` ahead of its first query:
     unmasked where every query sees every key, masked and whole where
-    the diagonal crosses the chunk (these kernels take no staircase),
-    not at all above it."""
+    the diagonal (or, `window` keys behind it, the window's lower edge)
+    crosses the chunk (these kernels take no staircase), not at all
+    above the one or wholly before the other."""
     if not causal:
         return fold(False)
     whole = lax.le(lax.add(behind, bk - 1), 0)
+    if window:
+        whole = lax.bitwise_and(whole, lax.ge(lax.add(behind, window), bq))
     pl.when(whole)(lambda: fold(False))
-    pl.when(lax.bitwise_and(lax.bitwise_not(whole),
-                            lax.le(behind, bq - 1)))(lambda: fold(True))
+    crossed = lax.bitwise_and(lax.bitwise_not(whole),
+                              lax.le(behind, bq - 1))
+    if window:
+        crossed = lax.bitwise_and(
+            crossed, lax.gt(lax.add(behind, window + bk - 1), 0))
+    pl.when(crossed)(lambda: fold(True))
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_scr, dv_scr, *, sm_scale, causal,
-                    q_offset, widen, d):
+                    q_offset, widen, d, window=0):
     """One (batch, heads, k_block, q_block) grid step of dK and dV
     where a head's queries do not fit VMEM: dv += p do and dk += ds q
     in float32 scratch for the step's bk keys and bq queries, a head at
@@ -924,16 +1029,17 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 q, _only_head(k_ref[...], own),
                 _only_head(v_ref[...], own), do, lse_ref[h:h + 1, :],
                 delta_ref[h:h + 1, :], sm_scale,
-                behind if masked else None, widen)
+                behind if masked else None, widen, window)
             _add_dkv(dk_scr, dv_scr, h, p, ds, q, do, widen)
 
-    _fold_where_seen(_fold, causal, behind, bq, bk)
+    _fold_where_seen(_fold, causal, behind, bq, bk, window)
     pl.when(lax.eq(i, lax.sub(pl.num_programs(3), 1)))(
         lambda: _write_dkv(dk_ref, dv_ref, dk_scr, dv_scr, sm_scale, d))
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   acc_scr, *, sm_scale, causal, q_offset, widen, d):
+                   acc_scr, *, sm_scale, causal, q_offset, widen, d,
+                   window=0):
     """One (batch, heads, q_block, k_block) grid step of dQ where a
     head's keys do not fit VMEM, shaped as `_fwd_kernel`'s walk:
     dq^T += k^T ds as [lanes, bq] float32 for the step's bq queries and
@@ -959,14 +1065,14 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                 q_ref[...], _only_head(k, own),
                 _only_head(v_ref[...], own), do_ref[...],
                 lse_ref[h:h + 1, :], delta_ref[h:h + 1, :], sm_scale,
-                behind if masked else None, widen)
+                behind if masked else None, widen, window)
             acc_scr[head] = lax.add(
                 acc_scr[head],
                 _bwd_matmul(lax.slice_in_dim(kt, h * d, (h + 1) * d),
                             lax.convert_element_type(ds, k.dtype), 0,
                             widen))
 
-    _fold_where_seen(_fold, causal, behind, bq, bk)
+    _fold_where_seen(_fold, causal, behind, bq, bk, window)
     pl.when(lax.eq(j, lax.sub(pl.num_programs(3), 1)))(
         lambda: _write_dq(dq_ref, acc_scr, sm_scale))
 
@@ -985,7 +1091,7 @@ def row_sums(do, o, num_heads=None):
 
 
 def _flash_bwd_rule(sm_scale, causal, block_q, block_k, q_offset,
-                    num_heads, res, cotangents):
+                    num_heads, window, res, cotangents):
     """The flash-attention VJP as kernels that recompute the
     probabilities chunk by chunk from the forward's statistics:
     dv = p^T do; dp = do v^T; ds = p * (dp - rowsum(do * o));
@@ -1003,11 +1109,11 @@ def _flash_bwd_rule(sm_scale, causal, block_q, block_k, q_offset,
     with jax.named_scope(BWD_SCOPE):
         return _bwd(q, k, v, do, lse, row_sums(do, o, num_heads) - dlse,
                     sm_scale, causal, block_q, block_k, q_offset,
-                    num_heads)
+                    num_heads, window=window)
 
 
 def _bwd(q, k, v, do, lse, delta, sm_scale, causal, block_q, block_k,
-         q_offset=0, num_heads=None, split=False):
+         q_offset=0, num_heads=None, split=False, window=0):
     """dq, dk, dv of one call, laid out as q, k and v are (as `_fwd`
     takes them), from the forward's row statistics `lse` and the row
     sums `delta` of do * o (`row_sums`), both float32
@@ -1018,29 +1124,33 @@ def _bwd(q, k, v, do, lse, delta, sm_scale, causal, block_q, block_k,
     if call.g is None:
         grads = _bwd(*(split_heads(x, num_heads) for x in (q, k, v, do)),
                      lse, delta, sm_scale, causal, block_q, block_k,
-                     q_offset, split=True)
+                     q_offset, split=True, window=window)
         return tuple(merge_heads(g) for g in grads)
     if sm_scale is None:
         sm_scale = call.d ** -0.5
+    window = _live_window(window, causal, call.tq, q_offset)
     bq, bk, one_kernel = _choose_bwd_blocks(
-        *call.step_shapes, q.dtype.itemsize, block_q, block_k, call.g)
+        *call.step_shapes, q.dtype.itemsize, block_q, block_k, call.g,
+        window)
     for kernel in (("dq_dkv",) if one_kernel else ("dkv", "dq")):
         telemetry.on_flash_attention_bwd_lowering(
             kernel, bq, bk, "split" if split else call.g)
         telemetry.on_flash_attention_pairs("bwd", *(
             call.batch * call.heads * n for n in score_pairs(
                 call.tq, call.tk, causal, q_offset, bq, bk,
-                _STAIR if one_kernel else None)))
+                _STAIR if one_kernel else None, window)))
+        if window:
+            telemetry.on_flash_window_lowering(kernel, window, bq, bk)
     return _bwd_kernels(q, k, v, do, lse, delta, num_heads=num_heads,
                         sm_scale=sm_scale, causal=causal, q_offset=q_offset,
-                        bq=bq, bk=bk, one_kernel=one_kernel)
+                        bq=bq, bk=bk, one_kernel=one_kernel, window=window)
 
 
 @functools.partial(jax.jit, static_argnames=(
     "num_heads", "sm_scale", "causal", "q_offset", "bq", "bk",
-    "one_kernel"))
+    "one_kernel", "window"))
 def _bwd_kernels(q, k, v, do, lse, delta, *, num_heads, sm_scale, causal,
-                 q_offset, bq, bk, one_kernel):
+                 q_offset, bq, bk, one_kernel, window=0):
     """dq, dk, dv from the row statistics and do, at blocks already
     chosen.  Under `jax.jit` so that a program holding the same
     attention many times (one a layer) traces these kernels once, and
@@ -1055,7 +1165,7 @@ def _bwd_kernels(q, k, v, do, lse, delta, *, num_heads, sm_scale, causal,
     stair = (_stair_width(bq, bk, q_offset, _STAIR)
              if causal and one_kernel else None)
     name = "flash_attention_bwd%%s_q%d_k%d%s%s" % (
-        bq, bk, _stair_suffix(stair), call.suffix)
+        bq, bk, _stair_suffix(stair, window), call.suffix)
     accumulator = pltpu.VMEM((call.g, bk, lanes), jnp.float32)
     transposed = pltpu.VMEM((_pad_to_lanes(lanes), tq if one_kernel else bq),
                             jnp.float32)
@@ -1063,7 +1173,8 @@ def _bwd_kernels(q, k, v, do, lse, delta, *, num_heads, sm_scale, causal,
     def pallas_call(kernel, interpret, **args):
         return pl.pallas_call(
             functools.partial(kernel, sm_scale=sm_scale, causal=causal,
-                              q_offset=q_offset, widen=interpret, d=call.d),
+                              q_offset=q_offset, widen=interpret, d=call.d,
+                              window=window),
             interpret=interpret, **args)
 
     def like(x):
@@ -1097,12 +1208,19 @@ def _bwd_kernels(q, k, v, do, lse, delta, *, num_heads, sm_scale, causal,
             first = lax.div(lax.max(lax.sub(lax.mul(j, bk), q_offset), 0),
                             bq)
             i = lax.max(i, lax.min(first, tq // bq - 1))
+        if window:
+            # and so does one past the last that sees it
+            last = lax.add(lax.mul(j, bk), bk + window - 2 - q_offset)
+            i = lax.min(i, lax.div(lax.max(last, 0), bq))
         return i
 
     def k_walked(i, j):
         if causal:
             last = lax.add(lax.mul(i, bq), q_offset + bq - 1)
             j = lax.min(j, lax.div(lax.max(last, 0), bk))
+        if window:
+            first = lax.add(lax.mul(i, bq), q_offset - window + 1)
+            j = lax.max(j, lax.div(lax.max(first, 0), bk))
         return j
 
     def held(a, b):

@@ -10,7 +10,7 @@ from .. import fluid
 from ..fluid.param_attr import ParamAttr
 
 __all__ = ["linear", "norm", "attention", "gated_feed_forward",
-           "share_feed_forward"]
+           "share_feed_forward", "token_feeds", "head_cross_entropy"]
 
 
 def linear(x, size, name):
@@ -21,6 +21,28 @@ def linear(x, size, name):
 def norm(x, eps, name):
     return fluid.layers.rms_norm(x, epsilon=eps,
                                  param_attr=ParamAttr(name=name))
+
+
+def token_feeds(batch, seq_len):
+    """The data layers (tokens, positions, targets) of a decoder's
+    training program: int64 [batch, seq_len] twice and [batch, seq_len,
+    1], as `transformer_program_feeds` feeds them."""
+    return tuple(
+        fluid.layers.data(name=name, shape=shape, dtype="int64",
+                          append_batch_size=False)
+        for name, shape in (("tokens", [batch, seq_len]),
+                            ("positions", [batch, seq_len]),
+                            ("targets", [batch, seq_len, 1])))
+
+
+def head_cross_entropy(x, targets, eps, norm_name, head_name, vocab_size):
+    """(logits [batch, seq, vocab], mean cross-entropy) of the final
+    RMSNorm `norm_name` and the untied head `head_name` over `x`."""
+    logits = linear(norm(x, eps, norm_name), vocab_size, head_name)
+    ce = fluid.layers.mean(x=fluid.layers.softmax_with_cross_entropy(
+        fluid.layers.reshape(x=logits, shape=[-1, vocab_size]),
+        fluid.layers.reshape(x=targets, shape=[-1, 1])))
+    return logits, ce
 
 
 def _repeat_heads(x, n_kv_head, times, d_head):
@@ -36,7 +58,7 @@ def _repeat_heads(x, n_kv_head, times, d_head):
 
 
 def attention(h, positions, names, n_head, d_head, theta, qk_norm_eps=None,
-              n_kv_head=None, sm_scale=None):
+              n_kv_head=None, sm_scale=None, window=0):
     """Causal self-attention over `h` [batch, seq, hidden], already
     normed: projections `names["wq"|"wk"|"wv"]`, rotary positions
     (rotate-half over each head, base `theta`; none where `theta` is
@@ -47,7 +69,9 @@ def attention(h, positions, names, n_head, d_head, theta, qk_norm_eps=None,
     rotated, as OLMoE does.  With `n_kv_head` fewer than `n_head`, k and
     v are projected to that many heads and each is repeated for its
     group of query heads.  `sm_scale` scales the scores (default
-    1 / sqrt(d_head))."""
+    1 / sqrt(d_head)).  With `window` W > 0 a query attends its last W
+    positions alone (the op's `window`: the flash kernels fold no chunk
+    of keys wholly before them, forward or backward)."""
     n_kv_head = n_kv_head or n_head
     q = linear(h, n_head * d_head, names["wq"])
     k, v = (linear(h, n_kv_head * d_head, names[w]) for w in ("wk", "wv"))
@@ -61,7 +85,7 @@ def attention(h, positions, names, n_head, d_head, theta, qk_norm_eps=None,
         k, v = (_repeat_heads(t, n_kv_head, n_head // n_kv_head, d_head)
                 for t in (k, v))
     o = fluid.layers.flash_attention(q, k, v, num_heads=n_head, causal=True,
-                                     sm_scale=sm_scale)
+                                     sm_scale=sm_scale, window=window)
     return linear(o, h.shape[-1], names["wo"])
 
 

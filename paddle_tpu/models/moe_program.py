@@ -14,7 +14,8 @@ to.
 
 from .. import fluid
 from ..fluid.param_attr import ParamAttr
-from .decoder_block import attention, linear, norm
+from .decoder_block import (attention, head_cross_entropy, norm,
+                            token_feeds)
 
 __all__ = ["build_olmoe_program", "olmoe_param_names"]
 
@@ -51,15 +52,7 @@ def build_olmoe_program(batch, seq_len, vocab_size, n_layer=2, n_head=4,
     main = fluid.Program()
     startup = fluid.Program()
     with fluid.program_guard(main, startup):
-        tokens = fluid.layers.data(
-            name="tokens", shape=[batch, seq_len], dtype="int64",
-            append_batch_size=False)
-        positions = fluid.layers.data(
-            name="positions", shape=[batch, seq_len], dtype="int64",
-            append_batch_size=False)
-        targets = fluid.layers.data(
-            name="targets", shape=[batch, seq_len, 1], dtype="int64",
-            append_batch_size=False)
+        tokens, positions, targets = token_feeds(batch, seq_len)
 
         x = fluid.layers.embedding(
             tokens, size=[vocab_size, d_model],
@@ -83,11 +76,8 @@ def build_olmoe_program(batch, seq_len, vocab_size, n_layer=2, n_head=4,
             for key in ("top_w", "top_idx", "counts"):
                 parts[key].append(routing[key])
 
-        logits = linear(norm(x, eps, names["norm_f"]), vocab_size,
-                        names["head"])
-        ce = fluid.layers.mean(x=fluid.layers.softmax_with_cross_entropy(
-            fluid.layers.reshape(x=logits, shape=[-1, vocab_size]),
-            fluid.layers.reshape(x=targets, shape=[-1, 1])))
+        logits, ce = head_cross_entropy(x, targets, eps, names["norm_f"],
+                                        names["head"], vocab_size)
         avg_loss = ce + fluid.layers.scale(lb, scale=aux_coef) \
             + fluid.layers.scale(z, scale=z_coef)
         parts.update(logits=logits, ce=ce, lb=lb, z=z)

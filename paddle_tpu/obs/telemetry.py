@@ -42,7 +42,8 @@ __all__ = ["on_executor_run", "on_jit_trace",
            "on_mla_cached_attention_lowering",
            "on_mla_decode_lowering",
            "on_mla_index_select_lowering", "on_cached_attention_lowering",
-           "on_window_attention_lowering",
+           "on_window_attention_lowering", "on_flash_window_lowering",
+           "on_moe_share_bwd_lowering",
            "on_prefill_lowering", "on_ssd_lowering",
            "on_causal_conv1d_lowering", "on_shared_parameter_uses",
            "on_transfer",
@@ -294,6 +295,37 @@ def on_window_attention_lowering(kind, kv_heads, window, path, block_k,
                    "slots a row's key/value caches hold in the lowered "
                    "cached_attention ops, by the kind of cache",
                    labelnames=("kind",)).labels(kind=kind).inc(slots)
+
+
+def on_flash_window_lowering(kernel, window, block_q, block_k):
+    """A flash-attention kernel of the training op ("fwd", or the
+    backward's "dq_dkv", "dkv" or "dq") was traced into a program with a
+    window: every query bounded to its last `window` keys, at the
+    blocks chosen under that bound (kernels/flash_attention.py).  What
+    the bound saves is in `flash_attention_pairs_total`, which counts a
+    window kernel's folded and attended pairs under it.  One count per
+    kernel instance a lowered program holds; none for a kernel with no
+    window."""
+    _reg().counter("flash_attention_window_lowerings_total",
+                   "flash-attention kernels lowered with a window, by "
+                   "kernel, window and tiling",
+                   labelnames=("kernel", "window", "block_q", "block_k")) \
+          .labels(kernel=kernel, window=window, block_q=block_q,
+                  block_k=block_k).inc()
+
+
+def on_moe_share_bwd_lowering(scored, held, top_k):
+    """The gradient of a routed expert layer that holds a range of the
+    experts its router scores (`moe_experts_grad`, ops/moe.py) was
+    traced into a program: its six grouped products run over the held
+    groups alone.  One count per gradient op a lowered program
+    holds."""
+    _reg().counter("moe_share_bwd_lowerings_total",
+                   "gradients lowered of expert layers that hold a range "
+                   "of the experts scored, by experts scored, held, and "
+                   "a token",
+                   labelnames=("scored", "held", "top_k")) \
+          .labels(scored=scored, held=held, top_k=top_k).inc()
 
 
 def on_prefill_lowering(form, block):

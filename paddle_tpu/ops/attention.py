@@ -24,6 +24,13 @@ That branch splits the heads into [batch, heads, seq, dim], which the
 ring's shards and ulysses' all-to-all over heads need.
 The gradient of that branch, and of a program built before the op had
 `Lse`, is the generic one.
+
+With the attr `window` W > 0 (and `causal`) a query attends its last W
+keys alone: the kernels bound their chunk loops and index maps to them
+(kernels/flash_attention.py), forward and backward, under the scope
+`attn_window` (`attn_full` with no window, the names `cached_attention`
+gives its two kinds of cache); the sequence-parallel branches take no
+window.
 """
 
 import math
@@ -42,6 +49,13 @@ def _ambient_mesh():
     the sp topology without threading a mesh argument through every
     layer."""
     return jax.sharding.get_abstract_mesh()
+
+
+def _window_scope(attrs):
+    """(window, scope): the op's window, and the name its kernels lie
+    under in a trace."""
+    window = int(attrs.get("window", 0))
+    return window, "attn_window" if window else "attn_full"
 
 
 def _sequence_parallel(attrs):
@@ -81,7 +95,11 @@ def flash_attention_op(ctx, ins, attrs):
             raise ValueError("hidden size %d must divide num_heads %d"
                              % (t.shape[-1], num_heads))
 
+    window, scope = _window_scope(attrs)
     mesh = _sequence_parallel(attrs)
+    if mesh is not None and window:
+        raise ValueError("flash_attention: the sequence-parallel paths "
+                         "take no window, got %d" % window)
     if mesh is not None:
         qh, kh, vh = (split_heads(x, num_heads) for x in (q, k, v))
         if sp_mode == "ring":
@@ -102,9 +120,10 @@ def flash_attention_op(ctx, ins, attrs):
     else:
         # 0: the kernel chooses its blocks from the shapes
         block = int(attrs.get("block_size", 0)) or None
-        out, lse = flash_attention_with_lse(
-            q, k, v, sm_scale, causal, block_q=block, block_k=block,
-            num_heads=num_heads)
+        with jax.named_scope(scope):
+            out, lse = flash_attention_with_lse(
+                q, k, v, sm_scale, causal, block_q=block, block_k=block,
+                num_heads=num_heads, window=window)
     return {"Out": [out.astype(q.dtype)], "Lse": [lse]}
 
 
@@ -131,11 +150,12 @@ def flash_attention_grad(ctx, ins, attrs):
     block = int(attrs.get("block_size", 0)) or None
     # the scope holds what it holds under the kernel's own VJP: the row
     # sums and the kernels
-    with jax.named_scope(BWD_SCOPE):
+    window, scope = _window_scope(attrs)
+    with jax.named_scope(BWD_SCOPE), jax.named_scope(scope):
         grads = _bwd(q, k, v, do, lse, row_sums(do, o, num_heads),
                      float(attrs.get("sm_scale", 0.0)) or None,
                      bool(attrs.get("causal", False)), block, block,
-                     num_heads=num_heads)
+                     num_heads=num_heads, window=window)
     return {slot + "@GRAD": [g]
             for slot, g in zip(("Q", "K", "V"), grads)}
 

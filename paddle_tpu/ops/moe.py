@@ -34,7 +34,15 @@ output is the held experts' part of the layer's, which the other chips'
 parts would be added to.  A held expert that no token chose is not
 visited either (the grouped row products walk the groups that have a
 row), so a lightly loaded share reads the weights of the experts that
-were chosen and no others.  Forward only.
+were chosen and no others.  Its gradient runs the six grouped products
+over the held groups alone: an absent assignment's row gives the input
+no gradient and its routing weight a gradient of 0 (its part of the
+layer's output, and of every gradient, is another chip's), and the
+weights' gradients are the held experts'.  The shares' outputs and
+input gradients add up to the uncut layer's.
+
+The experts' gate is SiLU, or with `activation="relu"` ReLU (ReGLU
+experts), forward and backward.
 
 The gradient of `moe_experts` is explicit, for the reason
 `flash_attention`'s is: jax.vjp of the op would run the forward's
@@ -226,8 +234,20 @@ def _by_key(keys, values):
     return jax.lax.sort((keys, values), num_keys=1)[1]
 
 
-def _silu(g):
-    return g * jax.nn.sigmoid(g)
+def _gate(g, attrs):
+    """(act(g), act'(g)) of the float32 pre-activation `g` under the
+    op's `activation`: "silu" (the default, which an op need not
+    carry) or "relu"."""
+    activation = attrs.get("activation", "silu")
+    if activation == "silu":
+        sig = jax.nn.sigmoid(g)
+        act = g * sig
+        return act, sig + act * (1.0 - sig)
+    if activation == "relu":
+        on = g > 0
+        return jnp.where(on, g, 0.0), on.astype(g.dtype)
+    raise ValueError("moe_experts: activation is silu or relu, got %r"
+                     % activation)
 
 
 @register_op("moe_experts", nondiff_inputs=("TopIdx",),
@@ -235,9 +255,9 @@ def _silu(g):
 def moe_experts(ctx, ins, attrs):
     """X [..., hidden], TopW / TopIdx [tokens, top_k], WGate and WUp
     [experts, hidden, width], WDown [experts, width, hidden] -> Out, X's
-    shape: sum_j TopW[n, j] * down_e(silu(gate_e(x_n)) * up_e(x_n)) with
-    e = TopIdx[n, j]; and what the gradient reads (the module's
-    docstring).  Where the router scores `scored` experts and the op
+    shape: sum_j TopW[n, j] * down_e(act(gate_e(x_n)) * up_e(x_n)) with
+    e = TopIdx[n, j] and act the op's `activation` (SiLU; "relu": ReLU);
+    and what the gradient reads (the module's docstring).  Where the router scores `scored` experts and the op
     holds fewer (`experts` of them from `first_expert` on), the sum is
     over the held e alone."""
     from ..kernels.grouped_matmul import gmm
@@ -272,8 +292,8 @@ def moe_experts(ctx, ins, attrs):
         wg, wu, wd = mxu_operands(w_gate, w_up, w_down)
         gate = gmm(xs, wg, counts)
         up = gmm(xs, wu, counts)
-        h = (_silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)) \
-            .astype(xs.dtype)
+        h = (_gate(gate.astype(jnp.float32), attrs)[0]
+             * up.astype(jnp.float32)).astype(xs.dtype)
         y = gmm(h, wd, counts)
     with jax.named_scope("moe_combine"):
         rows = _token_rows(y, token_row, n, k).astype(jnp.float32)
@@ -292,15 +312,17 @@ def moe_experts_grad(ctx, ins, attrs):
     """X@GRAD, TopW@GRAD and the three weights' gradients from what the
     forward op kept: six grouped products (two `gmm_dx` over the
     gate/up pair, one over down, three `gmm_dw`), the weights'
-    gradients added up and returned in float32."""
+    gradients added up and returned in float32.  Where the op holds a
+    range of the experts scored, the rows past the held groups' belong
+    to absent experts: no product wrote them, forward or here, so what
+    they hold is set to 0 wherever it would reach a sum."""
     from ..kernels.grouped_matmul import gmm_dw, gmm_dx
 
     x, top_w = ins["X"][0], ins["TopW"][0]
     w_gate, w_up, w_down = (ins[s][0] for s in ("WGate", "WUp", "WDown"))
-    if _held_range(attrs, w_gate.shape[0]) != (0, w_gate.shape[0]):
-        raise NotImplementedError(
-            "moe_experts holding a range of the experts scored has no "
-            "gradient: the rows of absent experts are never computed")
+    experts = w_gate.shape[0]
+    first, scored = _held_range(attrs, experts)
+    ranged = (first, scored) != (0, experts)
     xs, gate, up, row_slot, token_row, counts = (
         ins["O@" + s][0] for s in ("Xs", "Gate", "Up", "RowSlot",
                                    "TokenRow", "Counts"))
@@ -308,28 +330,42 @@ def moe_experts_grad(ctx, ins, attrs):
     d_out = ins["OG@Out"][0].reshape(n, x.shape[-1])
     f32 = jnp.float32
 
+    present = None
+    if ranged:
+        telemetry.on_moe_share_bwd_lowering(scored, experts, k)
+        with jax.named_scope("moe_route"), jax.named_scope("moe_hold"):
+            # the order puts the held groups' rows first
+            present = (jnp.arange(n * k, dtype=jnp.int32)
+                       < jnp.sum(counts))[:, None]
+
+    def held(rows):
+        """`rows` with 0 in those of absent experts."""
+        if present is None:
+            return rows
+        return jnp.where(present, rows, jnp.zeros((), rows.dtype))
+
     with jax.named_scope("moe_combine"):
         # every row's token's dOut, and the row's routing weight
         d_rows = d_out.astype(xs.dtype)[row_slot // k]
         w_rows = _by_key(token_row, top_w.astype(f32).reshape(-1))
     with jax.named_scope("moe_experts"):
         wg, wu, wd = mxu_operands(w_gate, w_up, w_down)
-        g, u = gate.astype(f32), up.astype(f32)
-        sig = jax.nn.sigmoid(g)
-        act = g * sig
+        g, u = held(gate).astype(f32), held(up).astype(f32)
+        act, d_act = _gate(g, attrs)
         h = act * u
         # d<y_row, dOut> / dh, before the routing weight
-        dh_raw = gmm_dx(d_rows, wd, counts).astype(f32)
+        dh_raw = held(gmm_dx(d_rows, wd, counts)).astype(f32)
         d_w_rows = jnp.sum(dh_raw * h, axis=-1)
         dh = dh_raw * w_rows[:, None]
-        d_gate = (dh * u * (sig + act * (1.0 - sig))).astype(xs.dtype)
+        d_gate = (dh * u * d_act).astype(xs.dtype)
         d_up = (dh * act).astype(xs.dtype)
         d_w_down = gmm_dw((h * w_rows[:, None]).astype(xs.dtype), d_rows,
                           counts)
         d_w_gate = gmm_dw(xs, d_gate, counts)
         d_w_up = gmm_dw(xs, d_up, counts)
-        d_xs = (gmm_dx(d_gate, wg, counts).astype(f32)
-                + gmm_dx(d_up, wu, counts).astype(f32)).astype(xs.dtype)
+        d_xs = held((gmm_dx(d_gate, wg, counts).astype(f32)
+                     + gmm_dx(d_up, wu, counts).astype(f32))
+                    .astype(xs.dtype))
     with jax.named_scope("moe_route"):
         d_x = jnp.sum(_token_rows(d_xs, token_row, n, k).astype(f32),
                       axis=1)

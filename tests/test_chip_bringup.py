@@ -89,6 +89,38 @@ def test_the_op_and_its_gradient_lower_one_forward_kernel_for_tpu(shape,
     assert explicit.count("tpu_custom_call") == 2
 
 
+@pytest.mark.parametrize("shape,heads,window,kernels", [
+    # smallthinker-train-16k-ep8's window layers: the forward walks its
+    # keys, the backward is the pair that walks
+    ((1, 16384, 3584), 28, 4096, 3),
+    # a head's queries fit: the one backward kernel, resident keys
+    ((2, 2048, 1024), 16, 512, 2),
+    # a window that is no multiple of a block, inside one block
+    ((1, 1024, 512), 4, 100, 2)])
+def test_the_window_kernels_lower_for_tpu(shape, heads, window, kernels):
+    """The op and its gradient op with a `window`, lowered for the TPU
+    as one program: Mosaic takes the lower edge's chunk loops, index
+    maps and compares, and the kernels' names carry the window."""
+    from paddle_tpu.ops import registry
+
+    info = registry.get_op_info("flash_attention")
+    attrs = {"num_heads": heads, "causal": True, "window": window}
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+
+    def step(q, k, v, dout):
+        ins = {"Q": [q], "K": [k], "V": [v]}
+        outs = info.kernel(None, ins, attrs)
+        return outs["Out"], info.grad_kernel(None, dict(
+            ins, **{"O@Out": outs["Out"], "O@Lse": outs["Lse"],
+                    "OG@Out": [dout]}), attrs)
+
+    module = jax.export.export(jax.jit(step), platforms=["tpu"])(
+        x, x, x, x).mlir_module()
+    assert module.count("tpu_custom_call") == kernels
+    assert module.count("_w%d_h" % window) >= kernels
+    assert 'kernel_name = "flash_attention_fwd' in module
+
+
 @pytest.mark.parametrize("kernel,lhs,rhs", [
     ("fwd", (32768, 2048), (64, 2048, 1024)),
     ("fwd", (32768, 1024), (64, 1024, 2048)),

@@ -482,14 +482,25 @@ def test_the_whole_range_is_the_op_as_it_was():
     assert str(plain) == str(whole)
 
 
-def test_a_share_has_no_gradient():
+def test_a_share_has_a_gradient():
+    """The range form's gradient op traces (its values:
+    tests/test_moe_share_grad.py)."""
     rs = np.random.RandomState(6)
     w = _expert_weights(rs, 4)
-    ins = {"X": [jnp.zeros((8, D))], "TopW": [jnp.zeros((8, K))],
+    x = jnp.asarray(rs.randn(8, D), jnp.float32)
+    top_idx = jnp.asarray(rs.randint(0, E, (8, K)), jnp.int32)
+    top_w = jnp.asarray(rs.uniform(0.1, 0.5, (8, K)), jnp.float32)
+    info = registry.get_op_info("moe_experts")
+    attrs = {"first_expert": 2, "scored": E}
+    ins = {"X": [x], "TopW": [top_w], "TopIdx": [top_idx],
            "WGate": [w[0]], "WUp": [w[1]], "WDown": [w[2]]}
-    with pytest.raises(NotImplementedError, match="range"):
-        registry.get_op_info("moe_experts").grad_kernel(
-            None, ins, {"first_expert": 2, "scored": E})
+    outs = info.kernel(None, ins, attrs)
+    grad_ins = dict(ins, **{"OG@Out": [jnp.ones_like(x)]})
+    grad_ins.update({"O@" + s: v for s, v in outs.items()})
+    grads = info.grad_kernel(None, grad_ins, attrs)
+    assert grads["X@GRAD"][0].shape == x.shape
+    assert grads["WGate@GRAD"][0].shape == w[0].shape
+    assert np.isfinite(np.asarray(grads["X@GRAD"][0])).all()
 
 
 def test_the_layer_refuses_a_range_outside_the_scored_experts():
