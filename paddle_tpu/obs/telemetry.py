@@ -43,7 +43,7 @@ __all__ = ["on_executor_run", "on_jit_trace",
            "on_mla_decode_lowering",
            "on_mla_index_select_lowering", "on_cached_attention_lowering",
            "on_window_attention_lowering", "on_flash_window_lowering",
-           "on_moe_share_bwd_lowering",
+           "on_moe_share_bwd_lowering", "on_moe_share_compact_lowering",
            "on_prefill_lowering", "on_ssd_lowering",
            "on_causal_conv1d_lowering", "on_shared_parameter_uses",
            "on_transfer",
@@ -326,6 +326,21 @@ def on_moe_share_bwd_lowering(scored, held, top_k):
                    "a token",
                    labelnames=("scored", "held", "top_k")) \
           .labels(scored=scored, held=held, top_k=top_k).inc()
+
+
+def on_moe_share_compact_lowering(rows, bound):
+    """An expert layer that holds a range of the experts scored was
+    traced into a program with its compact row path (ops/moe.py): where
+    the held range got at most `bound` of the `rows` assignments, the
+    work past the ordering runs on `bound` rows.  One count per forward
+    op a lowered program holds; whether a run took the path is data on
+    the device."""
+    _reg().counter("moe_share_compact_lowerings_total",
+                   "expert layers lowered with a compact row path beside "
+                   "the path over all rows, by assignments ordered and "
+                   "the compact path's rows",
+                   labelnames=("rows", "bound")) \
+          .labels(rows=rows, bound=bound).inc()
 
 
 def on_prefill_lowering(form, block):

@@ -41,6 +41,36 @@ layer's output, and of every gradient, is another chip's), and the
 weights' gradients are the held experts'.  The shares' outputs and
 input gradients add up to the uncut layer's.
 
+The stable order puts the held groups' rows first, so the first
+sum(Counts) rows of every ordered array are the held ones, and an op
+that holds an eighth of the experts scored needs an eighth of its rows.
+Where a ranged op orders 32768 rows or more it therefore has a *compact
+row path*: a bound B from the shapes alone (`_compact_rows`: twice the
+rows an even router sends the range, on the grouped kernels' row tile,
+and at most half of the rows: 24576 of 16384 x 6 for 8 of 64), and its
+row work between the grouped products is a choice, `lax.cond(sum(Counts)
+<= B, compact, all_rows)` (`_either_row_path`).  `all_rows` is the op as
+it stands for every other shape; a batch whose routers send the range
+more than B rows runs it, so nothing is ever dropped or approximated: B
+decides which of two exact bodies runs.  The compact body works on the
+first B rows: the activation and its derivative, dOut gathered for B
+rows, and a token's sum of its held rows (`_held_token_sums`: Out
+forward, X@GRAD backward) from B gathered rows in place of n * k.  Rows
+from sum(Counts) to B hold whatever was there and are masked as the rows
+past the held groups always were.  The forward is one choice (the
+activation, the down product on `[B, width]`, the sums); the ordering,
+the gather of Xs and the gate and up products stay outside it (the kept
+outputs keep their n * k rows).  The backward is three (dOut's rows;
+around the activation; the gate's and the up's input gradients and the
+sums) with the down product's `gmm_dx` and the three `gmm_dw` between
+them, once, over whole arrays: a grouped product reads and writes no row
+past the held groups' whatever its operands' length (98304 rows for
+24576 cost a row product 0.1 ms and a `gmm_dw` 0.5 on the v5e), the
+compact body pads its operands with zeros (`_whole`), and a product
+inside a choice is lowered twice, which costs set-up time (a fifth of a
+second each in a four-layer program).  The bodies open the scopes
+`moe_compact` and `moe_all_rows` inside the op's three.
+
 The experts' gate is SiLU, or with `activation="relu"` ReLU (ReGLU
 experts), forward and backward.
 
@@ -63,6 +93,7 @@ down product's result is not kept: a routing weight's gradient is
 has on its way to dh.  Six grouped products, none of them a forward one.
 """
 
+import contextlib
 import math
 
 import jax
@@ -234,6 +265,106 @@ def _by_key(keys, values):
     return jax.lax.sort((keys, values), num_keys=1)[1]
 
 
+# The compact row path of the range form (the module's docstring): only
+# where an op orders at least so many rows, and over a bound of so many
+# times the rows an even router sends the held range, on the grouped
+# kernels' row tile.
+_COMPACT_MIN_ROWS = 32768
+_COMPACT_FACTOR = 2
+_ROW_TILE = 256
+
+
+def _compact_rows(n, k, held, scored):
+    """The rows the compact path of an op works on that orders `n * k`
+    assignments and holds `held` of the `scored` experts, from the
+    shapes alone; 0 where the op has no compact path: it holds every
+    expert scored, orders too few rows for a second body to pay, or the
+    bound would pass half of them."""
+    rows = n * k
+    if held >= scored or rows < _COMPACT_MIN_ROWS:
+        return 0
+    bound = -(-_COMPACT_FACTOR * rows * held // scored)
+    bound = -(-bound // _ROW_TILE) * _ROW_TILE
+    return bound if bound <= rows // 2 else 0
+
+
+@contextlib.contextmanager
+def _scopes(phase, branch):
+    """The op's scope `phase` and, inside it, that of the row path
+    (`branch`) where the op has two."""
+    with jax.named_scope(phase):
+        if branch is None:
+            yield
+        else:
+            with jax.named_scope(branch):
+                yield
+
+
+def _first(ordered, some):
+    """The first `some` rows of an array of the order."""
+    return ordered if some == ordered.shape[0] else ordered[:some]
+
+
+def _whole(first, rows):
+    """`first` with rows of 0 behind it, `rows` in all: what a grouped
+    product takes, which reads no row past the held groups'."""
+    short = rows - first.shape[0]
+    if not short:
+        return first
+    return jnp.pad(first, ((0, short),) + ((0, 0),) * (first.ndim - 1))
+
+
+def _either_row_path(work, counts, rows, bound):
+    """`work(rows, None)` over all `rows` of the order; or, where the op
+    has a compact path (`bound` > 0), whichever the held rows' count
+    picks of `work(bound, "moe_compact")` and `work(rows,
+    "moe_all_rows")`, which return the same shapes."""
+    if not bound:
+        return work(rows, None)
+    return jax.lax.cond(jnp.sum(counts) <= bound,
+                        lambda: work(bound, "moe_compact"),
+                        lambda: work(rows, "moe_all_rows"))
+
+
+def _held_token_sums(rows, token_row, counts, n, k, weights=None):
+    """[n, width] float32: each token's sum of its held assignments'
+    rows, from the first `bound` rows of the order alone (`rows`
+    [bound, width]; sum(counts) <= bound of them are held), each times
+    its entry of `weights` [n * k] (by slot) where given.  The held
+    slots in slot order are in token order, so one sort of the n * k
+    slots (held first) puts a token's rows side by side: `bound` rows
+    are gathered, each adds the up to k - 1 after it that share its
+    token, and a token reads the sum at its first row, which lies as
+    far in as the tokens before it have held slots.  (A gather costs by
+    the rows it fetches, 42 us a thousand of 2560 on the v5e whatever
+    their order: each token fetching its k rows would fetch n * k.)"""
+    f32 = jnp.float32
+    bound = rows.shape[0]
+    slots = jnp.arange(n * k, dtype=jnp.int32)
+    held = token_row < jnp.sum(counts)
+    ordered = jax.lax.sort(
+        (jnp.where(held, slots, n * k), token_row)
+        + (() if weights is None else (weights,)), num_keys=1)
+    # k - 1 rows past the bound, the last rows' neighbours: the shifted
+    # reads are then slices of one array, in the rows' own type, which
+    # one pass reads
+    slot, row = (a[:bound + k - 1] for a in ordered[:2])
+    live = slot < n * k
+    picked = rows[jnp.where(live, row, 0)]
+    token = jnp.where(live, slot // k, -1)
+    summed = 0.0
+    for j in range(k):
+        mine = picked[j:bound + j].astype(f32)
+        if weights is not None:
+            mine = mine * ordered[2][j:bound + j, None]
+        same = live[j:bound + j] & (token[j:bound + j] == token[:bound])
+        summed = summed + jnp.where(same[:, None], mine, 0.0)
+    mine_per_token = jnp.sum(held.reshape(n, k), axis=1, dtype=jnp.int32)
+    first = jnp.cumsum(mine_per_token) - mine_per_token
+    return jnp.where((mine_per_token > 0)[:, None],
+                     summed[jnp.minimum(first, bound - 1)], 0.0)
+
+
 def _gate(g, attrs):
     """(act(g), act'(g)) of the float32 pre-activation `g` under the
     op's `activation`: "silu" (the default, which an op need not
@@ -292,15 +423,30 @@ def moe_experts(ctx, ins, attrs):
         wg, wu, wd = mxu_operands(w_gate, w_up, w_down)
         gate = gmm(xs, wg, counts)
         up = gmm(xs, wu, counts)
-        h = (_gate(gate.astype(jnp.float32), attrs)[0]
-             * up.astype(jnp.float32)).astype(xs.dtype)
-        y = gmm(h, wd, counts)
-    with jax.named_scope("moe_combine"):
-        rows = _token_rows(y, token_row, n, k).astype(jnp.float32)
-        if ranged:
-            # no product wrote an absent assignment's row
-            rows = jnp.where(held.reshape(n, k, 1), rows, 0.0)
-        out = jnp.sum(rows * top_w.astype(jnp.float32)[..., None], axis=1)
+
+    rows, bound = n * k, _compact_rows(n, k, experts, scored)
+    if bound:
+        telemetry.on_moe_share_compact_lowering(rows, bound)
+
+    def token_sums(some, branch):
+        """Out in float32 from the first `some` rows of the order."""
+        with _scopes("moe_experts", branch):
+            h = (_gate(_first(gate, some).astype(jnp.float32), attrs)[0]
+                 * _first(up, some).astype(jnp.float32)).astype(xs.dtype)
+            y = gmm(h, wd, counts)
+        with _scopes("moe_combine", branch):
+            if some < rows:
+                return _held_token_sums(
+                    y, token_row, counts, n, k,
+                    top_w.astype(jnp.float32).reshape(-1))
+            mine = _token_rows(y, token_row, n, k).astype(jnp.float32)
+            if ranged:
+                # no product wrote an absent assignment's row
+                mine = jnp.where(held.reshape(n, k, 1), mine, 0.0)
+            return jnp.sum(mine * top_w.astype(jnp.float32)[..., None],
+                           axis=1)
+
+    out = _either_row_path(token_sums, counts, rows, bound)
     out = amp_result(out, x.dtype).reshape(x.shape)
     return {"Out": [out], "Xs": [xs], "Gate": [gate], "Up": [up],
             "RowSlot": [row_slot], "TokenRow": [token_row],
@@ -330,45 +476,87 @@ def moe_experts_grad(ctx, ins, attrs):
     d_out = ins["OG@Out"][0].reshape(n, x.shape[-1])
     f32 = jnp.float32
 
+    rows, bound = n * k, _compact_rows(n, k, experts, scored)
     present = None
     if ranged:
         telemetry.on_moe_share_bwd_lowering(scored, experts, k)
         with jax.named_scope("moe_route"), jax.named_scope("moe_hold"):
             # the order puts the held groups' rows first
-            present = (jnp.arange(n * k, dtype=jnp.int32)
+            present = (jnp.arange(rows, dtype=jnp.int32)
                        < jnp.sum(counts))[:, None]
 
-    def held(rows):
-        """`rows` with 0 in those of absent experts."""
+    def held(first):
+        """The first rows of an ordered array with 0 in those of absent
+        experts."""
         if present is None:
-            return rows
-        return jnp.where(present, rows, jnp.zeros((), rows.dtype))
+            return first
+        return jnp.where(_first(present, first.shape[0]), first,
+                         jnp.zeros((), first.dtype))
 
+    def upstream(some, branch):
+        """[n * k, hidden]: dOut of the first `some` rows' tokens."""
+        with _scopes("moe_combine", branch):
+            return _whole(
+                d_out.astype(xs.dtype)[_first(row_slot, some) // k], rows)
+
+    def around_activation(some, branch):
+        """From the first `some` rows of Gate, Up and `dx_down()`: the
+        rows' part of the routing weights' gradient [n * k], and the
+        gate's, the up's and the down product's row operands [n * k,
+        width] of the five products that follow."""
+        with _scopes("moe_experts", branch):
+            g, u = (held(_first(a, some)).astype(f32) for a in (gate, up))
+            act, d_act = _gate(g, attrs)
+            h = act * u
+            # d<y_row, dOut> / dh, before the routing weight
+            dh_raw = held(_first(dx_down(), some)).astype(f32)
+            w_some = _first(w_rows, some)
+            d_w_rows = jnp.sum(dh_raw * h, axis=-1)
+            dh = dh_raw * w_some[:, None]
+            d_gate = (dh * u * d_act).astype(xs.dtype)
+            d_up = (dh * act).astype(xs.dtype)
+            hw = (h * w_some[:, None]).astype(xs.dtype)
+            return tuple(_whole(a, rows) for a in (d_w_rows, d_gate, d_up, hw))
+
+    def token_grads(some, branch):
+        """X@GRAD in float32 from the first `some` rows of the gate's
+        and the up's gradients."""
+        with _scopes("moe_experts", branch):
+            d_xs = held(
+                (gmm_dx(_first(d_gate, some), wg, counts).astype(f32)
+                 + gmm_dx(_first(d_up, some), wu, counts).astype(f32))
+                .astype(xs.dtype))
+        with _scopes("moe_route", branch):
+            if some < rows:
+                return _held_token_sums(d_xs, token_row, counts, n, k)
+            return jnp.sum(_token_rows(d_xs, token_row, n, k).astype(f32),
+                           axis=1)
+
+    d_rows = _either_row_path(upstream, counts, rows, bound)
     with jax.named_scope("moe_combine"):
-        # every row's token's dOut, and the row's routing weight
-        d_rows = d_out.astype(xs.dtype)[row_slot // k]
+        # every row's routing weight
         w_rows = _by_key(token_row, top_w.astype(f32).reshape(-1))
     with jax.named_scope("moe_experts"):
         wg, wu, wd = mxu_operands(w_gate, w_up, w_down)
-        g, u = held(gate).astype(f32), held(up).astype(f32)
-        act, d_act = _gate(g, attrs)
-        h = act * u
-        # d<y_row, dOut> / dh, before the routing weight
-        dh_raw = held(gmm_dx(d_rows, wd, counts)).astype(f32)
-        d_w_rows = jnp.sum(dh_raw * h, axis=-1)
-        dh = dh_raw * w_rows[:, None]
-        d_gate = (dh * u * d_act).astype(xs.dtype)
-        d_up = (dh * act).astype(xs.dtype)
-        d_w_down = gmm_dw((h * w_rows[:, None]).astype(xs.dtype), d_rows,
-                          counts)
+        # between two choices of row path the product stands alone
+        dh_whole = gmm_dx(d_rows, wd, counts) if bound else None
+
+    def dx_down():
+        """[n * k, width]: the down product's gradient to its rows;
+        with one row path where it always stood, behind the
+        activation."""
+        if dh_whole is None:
+            return gmm_dx(d_rows, wd, counts)
+        return dh_whole
+
+    d_w_rows, d_gate, d_up, hw = _either_row_path(
+        around_activation, counts, rows, bound)
+    with jax.named_scope("moe_experts"):
+        d_w_down = gmm_dw(hw, d_rows, counts)
         d_w_gate = gmm_dw(xs, d_gate, counts)
         d_w_up = gmm_dw(xs, d_up, counts)
-        d_xs = held((gmm_dx(d_gate, wg, counts).astype(f32)
-                     + gmm_dx(d_up, wu, counts).astype(f32))
-                    .astype(xs.dtype))
+    d_x = _either_row_path(token_grads, counts, rows, bound)
     with jax.named_scope("moe_route"):
-        d_x = jnp.sum(_token_rows(d_xs, token_row, n, k).astype(f32),
-                      axis=1)
         d_top_w = _by_key(row_slot, d_w_rows).reshape(n, k)
     return {"X@GRAD": [d_x.astype(d_out.dtype).reshape(x.shape)],
             "TopW@GRAD": [d_top_w.astype(top_w.dtype)],

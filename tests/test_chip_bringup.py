@@ -176,6 +176,48 @@ def test_the_expert_op_and_its_gradient_lower_nine_products_for_tpu():
     assert module.count("tpu_custom_call") == 9
 
 
+def test_both_row_paths_of_a_held_share_lower_for_tpu():
+    """`moe_experts` and its gradient at smallthinker-train-16k-ep8's
+    shape (16384 tokens x 6, experts 24..31 of 64, 2560 -> 768, ReGLU,
+    bfloat16 compute) as one TPU program: the compact row path over
+    24576 rows beside the path over all 98304, forward and backward.
+    The products that stand between two choices of path are lowered
+    once, over whole arrays (gate, up; the down product's dx; the three
+    dw); a pass's last choice holds its own in either body (down; the
+    gate's and the up's dx)."""
+    from paddle_tpu.obs import telemetry
+    from paddle_tpu.ops import registry
+
+    info = registry.get_op_info("moe_experts")
+    n, k, e, d, f = 16384, 6, 8, 2560, 768
+    bf16 = jnp.bfloat16
+    attrs = {"first_expert": 24, "scored": 64, "activation": "relu"}
+    ins = {"X": [jax.ShapeDtypeStruct((1, n, d), bf16)],
+           "TopW": [jax.ShapeDtypeStruct((n, k), jnp.float32)],
+           "TopIdx": [jax.ShapeDtypeStruct((n, k), jnp.int32)],
+           "WGate": [jax.ShapeDtypeStruct((e, d, f), jnp.float32)],
+           "WUp": [jax.ShapeDtypeStruct((e, d, f), jnp.float32)],
+           "WDown": [jax.ShapeDtypeStruct((e, f, d), jnp.float32)]}
+
+    def step(ins, d_out):
+        outs = info.kernel(None, ins, attrs)
+        grad_ins = dict(ins, **{"OG@Out": [d_out]})
+        grad_ins.update({"O@" + slot: v for slot, v in outs.items()})
+        return outs["Out"], info.grad_kernel(None, grad_ins, attrs)
+
+    before = telemetry.snapshot()
+    with fluid.amp.bf16_guard():
+        module = jax.export.export(jax.jit(step), platforms=["tpu"])(
+            ins, ins["X"][0]).mlir_module()
+    assert [module.count('kernel_name = "moe_gmm_%s_' % kernel)
+            for kernel in ("fwd", "dx", "dw")] == [2 + 2, 1 + 2 * 2, 3]
+    for branch in ("moe_compact", "moe_all_rows"):
+        assert "/moe_experts/%s/" % branch in module, branch
+    delta = telemetry.snapshot_delta(before)
+    assert delta[
+        "moe_share_compact_lowerings_total{bound=24576,rows=98304}"] == 1
+
+
 def test_the_scan_kernels_lower_for_tpu():
     """The chunked state-space scan's two kernels at the shapes of
     granite-train-4k (1 x 4096 positions, 64 heads of 64, state 128,

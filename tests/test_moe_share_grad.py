@@ -4,9 +4,12 @@ against dense experts; the eight shares' outputs and input gradients
 add up to the uncut layer's; garbage in the rows no product wrote
 reaches no sum; ReGLU experts (`activation="relu"`); a router that reads
 another tensor than the experts (`fluid.layers.moe(router_input=)`); the
-counters.
+counters; and the compact row path of an op that orders 32768 rows or
+more (a bound from the shapes, a check at run time, two exact bodies of
+the row work between the grouped products).
 
-Tiny sizes on the CPU: hidden 64, 8 experts of 32 scored, 2 a token.
+Tiny sizes on the CPU: hidden 64, 8 experts of 32 scored, 2 a token; the
+compact path at 8192 tokens x 4, 2 of 16 experts held, hidden 16.
 """
 
 import numpy as np
@@ -18,6 +21,7 @@ import jax.numpy as jnp
 import paddle_tpu.fluid as fluid
 from paddle_tpu.fluid.param_attr import ParamAttr
 from paddle_tpu.obs import telemetry
+from paddle_tpu.ops import moe as moe_ops
 from paddle_tpu.ops import registry
 
 D, F, E, K, N = 64, 32, 8, 2, 40
@@ -43,25 +47,27 @@ def _dense(x, top_w, top_idx, w_gate, w_up, w_down, first, activation):
     return jnp.einsum("ne,ned->nd", weight, y, precision="highest")
 
 
-def _operands(seed, experts=E):
+def _operands(seed, n=N, d=D, f=F, scored=E, k=K):
     rs = np.random.RandomState(seed)
-    x = jnp.asarray(rs.randn(N, D), jnp.float32)
+    x = jnp.asarray(rs.randn(n, d), jnp.float32)
     top_idx = jnp.asarray(
-        np.stack([rs.permutation(E)[:K] for _ in range(N)]), jnp.int32)
-    top_w = jnp.asarray(rs.uniform(0.1, 0.5, (N, K)), jnp.float32)
-    w_gate, w_up = (jnp.asarray(rs.randn(experts, D, F) * 0.2, jnp.float32)
+        np.stack([rs.permutation(scored)[:k] for _ in range(n)]), jnp.int32)
+    top_w = jnp.asarray(rs.uniform(0.1, 0.5, (n, k)), jnp.float32)
+    w_gate, w_up = (jnp.asarray(rs.randn(scored, d, f) * 0.2, jnp.float32)
                     for _ in range(2))
-    w_down = jnp.asarray(rs.randn(experts, F, D) * 0.2, jnp.float32)
-    d_out = jnp.asarray(rs.randn(N, D), jnp.float32)
+    w_down = jnp.asarray(rs.randn(scored, f, d) * 0.2, jnp.float32)
+    d_out = jnp.asarray(rs.randn(n, d), jnp.float32)
     return x, top_w, top_idx, (w_gate, w_up, w_down), d_out
 
 
 def _share(x, top_w, top_idx, weights, d_out, first, count, activation,
-           spoil=False):
+           spoil=False, counts=None):
     """The op and its gradient op holding experts first .. first +
-    count; with `spoil`, NaN where the forward wrote nothing."""
-    attrs = {} if (first, count) == (0, E) \
-        else {"first_expert": first, "scored": E}
+    count; with `spoil`, NaN where the forward wrote nothing; the
+    forward's Counts appended to `counts`."""
+    scored = weights[0].shape[0]
+    attrs = {} if (first, count) == (0, scored) \
+        else {"first_expert": first, "scored": scored}
     if activation != "silu":
         attrs["activation"] = activation
     ins = {"X": [x], "TopW": [top_w], "TopIdx": [top_idx]}
@@ -69,6 +75,8 @@ def _share(x, top_w, top_idx, weights, d_out, first, count, activation,
                 for slot, w in zip(("WGate", "WUp", "WDown"), weights)})
     outs = INFO.kernel(None, ins, attrs)
     kept = {slot: v[0] for slot, v in outs.items()}
+    if counts is not None:
+        counts.append(np.asarray(kept["Counts"]))
     if spoil:
         held = int(np.asarray(kept["Counts"]).sum())
         for slot in ("Gate", "Up"):
@@ -110,20 +118,30 @@ def test_a_share_and_every_gradient_against_dense_experts(first, count,
 @pytest.mark.parametrize("activation", ["silu", "relu"])
 @pytest.mark.parametrize("ranges", [[(i, 1) for i in range(E)],
                                     [(0, 4), (4, 4)],
-                                    [(0, 2), (2, 5), (7, 1)]])
+                                    [(0, 2), (2, 5), (7, 1)],
+                                    "compact"])
 def test_the_shares_add_up_to_the_uncut_layer_backward_too(ranges,
                                                            activation):
     """Outputs, input gradients and routing-weight gradients of the
     shares add up to the uncut layer's; each share's weight gradients
-    are the uncut layer's for its experts."""
-    x, top_w, top_idx, weights, d_out = _operands(len(ranges))
-    whole_out, whole = _share(x, top_w, top_idx, weights, d_out, 0, E,
-                              activation)
+    are the uncut layer's for its experts.  "compact": eight shares of
+    two at the shape that has a compact path, under a routing that
+    sends the first share more rows than its bound and the others
+    fewer, so both bodies are among the parts."""
+    counts = []
+    if ranges == "compact":
+        ranges = [(first, HELD) for first in range(0, SCORED, HELD)]
+        x, top_w, _, weights, d_out = _compact_operands(4)
+        top_idx = _routing(9000, 9000, first=0, others=True)
+    else:
+        x, top_w, top_idx, weights, d_out = _operands(len(ranges))
+    whole_out, whole = _share(x, top_w, top_idx, weights, d_out, 0,
+                              weights[0].shape[0], activation)
     out = 0.0
     total = {"X": 0.0, "TopW": 0.0}
     for first, count in ranges:
         part, grads = _share(x, top_w, top_idx, weights, d_out, first, count,
-                             activation)
+                             activation, counts=counts)
         out = out + part
         for slot in total:
             total[slot] = total[slot] + grads[slot]
@@ -132,6 +150,8 @@ def test_the_shares_add_up_to_the_uncut_layer_backward_too(ranges,
     _close(out, whole_out, "Out")
     for slot in total:
         _close(total[slot], whole[slot], slot)
+    if len(x) == TOKENS:
+        assert [int(c.sum()) <= BOUND for c in counts] == [False] + 7 * [True]
 
 
 def test_rows_no_product_wrote_reach_no_sum():
@@ -143,6 +163,131 @@ def test_rows_no_product_wrote_reach_no_sum():
     _, spoiled = _share(*operands, 2, 3, "silu", spoil=True)
     for slot in GRADS:
         np.testing.assert_array_equal(spoiled[slot], clean[slot])
+
+
+# the compact row path: 32768 assignments, the held two of sixteen
+# experts get an even router's 4096 of them under a bound of 8192
+TOKENS, TOP, SCORED, HELD, WIDE, NARROW, FIRST = 8192, 4, 16, 2, 16, 8, 6
+BOUND = 8192
+
+
+def _compact_operands(seed):
+    return _operands(seed, n=TOKENS, d=WIDE, f=NARROW, scored=SCORED, k=TOP)
+
+
+def _routing(in_slot_0, in_slot_1, first=FIRST, others=False):
+    """TopIdx [TOKENS, TOP] that sends expert `first` the first
+    `in_slot_0` tokens and `first + 1` the first `in_slot_1`, and the
+    held pair nothing else: every other entry is one of the 14 absent
+    experts, a token's all different (with `others` its last two)."""
+    rs = np.random.RandomState(in_slot_0 + in_slot_1)
+    absent = np.array([e for e in range(SCORED)
+                       if e not in (first, first + 1)])
+    idx = np.stack([absent[rs.permutation(len(absent))[:TOP]]
+                    for _ in range(TOKENS)])
+    if others:
+        idx = np.roll(idx, 2, axis=1)
+    idx[:in_slot_0, 0] = first
+    idx[:in_slot_1, 1] = first + 1
+    return jnp.asarray(idx, jnp.int32)
+
+
+def test_the_bound_of_the_compact_path_from_shapes():
+    """`_compact_rows` (tokens, a token, held, scored): twice an even
+    router's share on the row tile, where the op is ranged, orders 32768
+    rows or more and the bound is at most half of them; else 0."""
+    for shape, bound in (
+            ((16384, 6, 8, 64), 24576),       # smallthinker-train-16k-ep8
+            ((TOKENS, TOP, HELD, SCORED), BOUND),
+            ((8200, 4, 2, 16), 8448),         # 8200 on the tile of 256
+            ((4096, 8, 64, 64), 0),           # olmoe-train-4k: all held
+            ((256, 8, 16, 256), 0),           # pangu-decode-ep16's step
+            ((16, 8, 16, 256), 0),            # dsv32's step
+            ((16 * 128, 8, 16, 256), 0),      # and its question block
+            ((8, 8, 8, 128), 0),              # exaone's step
+            ((8 * 128, 8, 8, 128), 0),        # and its block
+            ((TOKENS, TOP, 5, SCORED), 0),    # the bound past half the rows
+            ((TOKENS - 1, TOP, HELD, SCORED), 0)):
+        assert moe_ops._compact_rows(*shape) == bound, shape
+
+
+@pytest.mark.parametrize("activation", ["silu", "relu"])
+@pytest.mark.parametrize("in_slot_0,in_slot_1,compact", [
+    (None, None, True),          # a near even router
+    (TOKENS, 4000, False),       # the range gets 12192 rows
+    (TOKENS, 0, True),           # exactly the bound
+    (TOKENS, 1, False),          # one more
+    (0, 0, True),                # none at all
+])
+def test_either_row_path_against_dense_experts(in_slot_0, in_slot_1, compact,
+                                               activation):
+    """At a shape with a compact path, Out and all five gradients
+    against dense experts whichever body the held rows' count picks:
+    nothing is dropped past the bound, the other body runs."""
+    x, top_w, top_idx, weights, d_out = _compact_operands(17)
+    if in_slot_0 is not None:
+        top_idx = _routing(in_slot_0, in_slot_1)
+    held = tuple(w[FIRST:FIRST + HELD] for w in weights)
+    counts = []
+    out, grads = _share(x, top_w, top_idx, weights, d_out, FIRST, HELD,
+                        activation, counts=counts)
+    assert moe_ops._compact_rows(TOKENS, TOP, HELD, SCORED) == BOUND
+    assert (int(counts[0].sum()) <= BOUND) == compact
+    if in_slot_0 is not None:
+        assert int(counts[0].sum()) == in_slot_0 + in_slot_1
+    _close(out, _dense(x, top_w, top_idx, *held, FIRST, activation), "Out")
+    want = jax.grad(
+        lambda x, top_w, *w: jnp.sum(
+            _dense(x, top_w, top_idx, *w, FIRST, activation) * d_out),
+        argnums=(0, 1, 2, 3, 4))(x, top_w, *held)
+    for slot, w in zip(GRADS, want):
+        _close(grads[slot], w, slot)
+
+
+@pytest.mark.parametrize("in_slot_1", [0, 1])
+def test_rows_no_product_wrote_reach_no_sum_on_either_row_path(in_slot_1):
+    """NaN in the kept rows past the held ones changes no gradient, at
+    the bound (the compact body masks its rows up to the bound and reads
+    none past it) and one past it."""
+    x, top_w, _, weights, d_out = _compact_operands(5)
+    operands = (x, top_w, _routing(TOKENS, in_slot_1), weights, d_out)
+    _, clean = _share(*operands, FIRST, HELD, "relu")
+    _, spoiled = _share(*operands, FIRST, HELD, "relu", spoil=True)
+    for slot in GRADS:
+        np.testing.assert_array_equal(spoiled[slot], clean[slot])
+
+
+def _traced(n, k, held, scored, hidden=16, width=8):
+    """The lowered text of the op and its gradient at a shape."""
+    f32 = jnp.float32
+    ins = {"X": [jax.ShapeDtypeStruct((n, hidden), f32)],
+           "TopW": [jax.ShapeDtypeStruct((n, k), f32)],
+           "TopIdx": [jax.ShapeDtypeStruct((n, k), jnp.int32)],
+           "WGate": [jax.ShapeDtypeStruct((held, hidden, width), f32)],
+           "WUp": [jax.ShapeDtypeStruct((held, hidden, width), f32)],
+           "WDown": [jax.ShapeDtypeStruct((held, width, hidden), f32)]}
+    attrs = {"first_expert": 0, "scored": scored}
+
+    def step(ins, d_out):
+        outs = INFO.kernel(None, ins, attrs)
+        grad_ins = dict(ins, **{"OG@Out": [d_out]})
+        grad_ins.update({"O@" + slot: v for slot, v in outs.items()})
+        return outs["Out"], INFO.grad_kernel(None, grad_ins, attrs)
+
+    return jax.jit(step).lower(ins, ins["X"][0]).as_text(debug_info=True)
+
+
+def test_a_share_under_32768_rows_has_one_body():
+    """pangu-decode-ep16's step (256 rows x 8, 16 of 256 held) opens
+    neither row path's scope, forward or backward: it has the one body
+    it had.  The shape with a compact path has both scopes under each of
+    the op's three."""
+    text = _traced(256, 8, 16, 256)
+    assert "moe_compact" not in text and "moe_all_rows" not in text
+    text = _traced(TOKENS, TOP, HELD, SCORED)
+    for phase in ("moe_route", "moe_experts", "moe_combine"):
+        for branch in ("moe_compact", "moe_all_rows"):
+            assert "/%s/%s/" % (phase, branch) in text, (phase, branch)
 
 
 def test_an_unknown_activation_is_refused():
@@ -169,11 +314,19 @@ def test_counters_say_a_share_was_differentiated():
     _share(*operands, 2, 3, "relu")
     _share(*operands, 0, E, "relu")
     delta = telemetry.snapshot_delta(before)
+    assert not any(key.startswith("moe_share_compact_lowerings_total")
+                   for key in delta)
     assert delta["moe_share_bwd_lowerings_total{held=3,scored=8,top_k=2}"] \
         == 1
     assert delta["moe_share_lowerings_total{held=3,scored=8,top_k=2}"] == 1
     assert sum(v for key, v in delta.items()
                if key.startswith("moe_share_bwd_lowerings_total")) == 1
+    before = telemetry.snapshot()
+    _share(*_compact_operands(3), FIRST, HELD, "relu")
+    delta = telemetry.snapshot_delta(before)
+    assert delta["moe_share_compact_lowerings_total{bound=8192,rows=32768}"] \
+        == 1
+    assert delta["moe_share_lowerings_total{held=2,scored=16,top_k=4}"] == 1
 
 
 def _layer_program(held, activation, router_elsewhere):
