@@ -24,16 +24,26 @@ Usage:
                                 init_state={"h_in": h0})
 """
 
+import contextlib
+import itertools
+import time
+
 import numpy as np
 
 import jax
 import jax.numpy as jnp
 
 from ..jit import FunctionalProgram, state_from_scope
-from ..models.decode import (greedy_decode, beam_search_decode_dense,
-                             prefill, sample_decode)
+from ..models.decode import (PREFILL_BLOCK, greedy_decode,
+                             beam_search_decode_dense, prefill,
+                             sample_decode)
+from ..obs import telemetry
+from ..obs.trace import span
 
 __all__ = ["ProgramDecoder"]
+
+# the `call` argument of the `decode/call` spans: a count per process
+_CALLS = itertools.count(1)
 
 
 class ProgramDecoder:
@@ -136,7 +146,14 @@ class ProgramDecoder:
             raise ValueError(
                 "init_state has keys %s that are not in state_pairs %s"
                 % (extra, sorted(known)))
-        state = {f: jnp.asarray(np.asarray(v)) for f, v in state.items()}
+        # what the call was handed, by where it was (a `jax.Array` goes
+        # the same way through the host as a host array)
+        handed = {"host": 0, "device": 0}
+        for f, v in state.items():
+            source = "device" if isinstance(v, jax.Array) else "host"
+            v = np.asarray(v)
+            handed[source] += v.nbytes
+            state[f] = jnp.asarray(v)
         for f, value in state.items():
             declared = self._declared.get(f, ())
             if len(declared) == value.ndim and any(
@@ -153,7 +170,7 @@ class ProgramDecoder:
                     "batch_size is required when the step program has "
                     "no state feeds")
             batch_size = next(iter(state.values())).shape[0]
-        return state, batch_size
+        return state, batch_size, handed
 
     def _jitted(self, key, builder):
         if key not in self._compiled:
@@ -227,85 +244,167 @@ class ProgramDecoder:
         step come back as a third result, {feed name: array}: a state
         pair the step only writes (its feed unread) is how a caller
         sees an intermediate of the step that chose the last token."""
-        state, batch_size = self._prep(init_state, batch_size)
-        prompt = self._norm_prompt(prompt, max_len)
         return_state = tuple(return_state)
         want = bool(return_state)
+        with _Call(self, max_len) as call:
+            state, batch_size, prompt = call.prep(init_state, batch_size,
+                                                  prompt)
 
-        def decode(step, st, bos, n):
-            # (tokens, lengths, the state after the last step where one
-            # is asked for)
-            out = greedy_decode(step, st, bos=bos, eos=eos, max_len=n,
-                                batch_size=batch_size, with_state=want)
-            return out[0], out[1], out[2] if want else {}
+            def decode(step, st, bos, n):
+                # (tokens, lengths, the state after the last step where
+                # one is asked for)
+                out = greedy_decode(step, st, bos=bos, eos=eos, max_len=n,
+                                    batch_size=batch_size, with_state=want)
+                return out[0], out[1], out[2] if want else {}
 
-        def kept(toks, lengths, last):
-            return toks, lengths, {f: last[f] for f in return_state}
+            def kept(toks, lengths, last):
+                return toks, lengths, {f: last[f] for f in return_state}
 
-        if prompt is None:
-            fn = self._jitted(
-                ("greedy", bos, eos, max_len, batch_size, return_state),
-                lambda: lambda params, s: kept(*decode(
-                    self._step_fn(params), s, bos, max_len)))
-            out = fn(self._params, state)
-        else:
-            fn = self._jitted(
-                ("greedy-prefill", eos, max_len, batch_size,
-                 prompt.shape[1], return_state),
-                lambda: lambda params, s, p: kept(*self._prefilled_run(
-                    params, s, p,
-                    lambda step, st, first: decode(step, st, first,
-                                                   max_len - 1)[::2],
-                    eos, max_len)))
-            out = fn(self._params, state, jnp.asarray(prompt))
-        toks, lengths, last = jax.tree_util.tree_map(np.asarray, out)
+            if prompt is None:
+                fn = call.program(
+                    ("greedy", bos, eos, max_len, batch_size, return_state),
+                    lambda: lambda params, s: kept(*decode(
+                        self._step_fn(params), s, bos, max_len)))
+                with call.dispatch():
+                    out = fn(self._params, state)
+            else:
+                fn = call.program(
+                    ("greedy-prefill", eos, max_len, batch_size,
+                     prompt.shape[1], return_state),
+                    lambda: lambda params, s, p: kept(*self._prefilled_run(
+                        params, s, p,
+                        lambda step, st, first: decode(step, st, first,
+                                                       max_len - 1)[::2],
+                        eos, max_len)))
+                with call.dispatch():
+                    out = fn(self._params, state, prompt)
+            toks, lengths, last = call.fetch(out)
         return (toks, lengths, last) if return_state else (toks, lengths)
 
     def sample(self, bos, eos, max_len, batch_size=None, init_state=None,
                prompt=None, seed=0, temperature=1.0, top_k=0):
         """Ancestral sampling (temperature / top-k).  With `prompt`,
         prefills first and samples the continuation."""
-        state, batch_size = self._prep(init_state, batch_size)
-        prompt = self._norm_prompt(prompt, max_len)
-        key = ("sample", eos, max_len, batch_size, temperature, top_k,
-               None if prompt is None else prompt.shape[1],
-               bos if prompt is None else None)
-        if prompt is None:
-            fn = self._jitted(key, lambda: lambda params, s, rng:
-                              sample_decode(
-                                  self._step_fn(params), s, bos=bos,
-                                  eos=eos, max_len=max_len,
-                                  batch_size=batch_size, rng=rng,
-                                  temperature=temperature, top_k=top_k))
-            toks, lengths = fn(self._params, state,
-                               jax.random.PRNGKey(seed))
-        else:
-            fn = self._jitted(
-                key,
-                lambda: lambda params, s, p, rng: self._prefilled_run(
-                    params, s, p,
-                    lambda step, st, first: (sample_decode(
-                        step, st, bos=first, eos=eos,
-                        max_len=max_len - 1, batch_size=batch_size,
-                        rng=rng, temperature=temperature,
-                        top_k=top_k)[0], None),
-                    eos, max_len)[:2])
-            toks, lengths = fn(self._params, state, jnp.asarray(prompt),
-                               jax.random.PRNGKey(seed))
-        return np.asarray(toks), np.asarray(lengths)
+        with _Call(self, max_len) as call:
+            state, batch_size, prompt = call.prep(init_state, batch_size,
+                                                  prompt)
+            key = ("sample", eos, max_len, batch_size, temperature, top_k,
+                   None if prompt is None else prompt.shape[1],
+                   bos if prompt is None else None)
+            if prompt is None:
+                fn = call.program(key, lambda: lambda params, s, rng:
+                                  sample_decode(
+                                      self._step_fn(params), s, bos=bos,
+                                      eos=eos, max_len=max_len,
+                                      batch_size=batch_size, rng=rng,
+                                      temperature=temperature, top_k=top_k))
+                with call.dispatch():
+                    out = fn(self._params, state, jax.random.PRNGKey(seed))
+            else:
+                fn = call.program(
+                    key,
+                    lambda: lambda params, s, p, rng: self._prefilled_run(
+                        params, s, p,
+                        lambda step, st, first: (sample_decode(
+                            step, st, bos=first, eos=eos,
+                            max_len=max_len - 1, batch_size=batch_size,
+                            rng=rng, temperature=temperature,
+                            top_k=top_k)[0], None),
+                        eos, max_len)[:2])
+                with call.dispatch():
+                    out = fn(self._params, state, prompt,
+                             jax.random.PRNGKey(seed))
+            return call.fetch(out)
 
     def beam(self, beam_size, bos, eos, max_len, batch_size=None,
              init_state=None, length_penalty=0.0):
         """Returns (sequences [batch, beam, max_len], scores
         [batch, beam]), best first."""
-        state, batch_size = self._prep(init_state, batch_size)
-        self._check_extent(max_len)
-        fn = self._jitted(
-            ("beam", beam_size, bos, eos, max_len, batch_size,
-             length_penalty),
-            lambda: lambda params, s: beam_search_decode_dense(
-                self._step_fn(params), s, bos=bos, eos=eos,
-                beam_size=beam_size, max_len=max_len,
-                batch_size=batch_size, length_penalty=length_penalty))
-        seqs, scores = fn(self._params, state)
-        return np.asarray(seqs), np.asarray(scores)
+        with _Call(self, max_len, beam_size) as call:
+            state, batch_size, _ = call.prep(init_state, batch_size, None)
+            fn = call.program(
+                ("beam", beam_size, bos, eos, max_len, batch_size,
+                 length_penalty),
+                lambda: lambda params, s: beam_search_decode_dense(
+                    self._step_fn(params), s, bos=bos, eos=eos,
+                    beam_size=beam_size, max_len=max_len,
+                    batch_size=batch_size, length_penalty=length_penalty))
+            with call.dispatch():
+                out = fn(self._params, state)
+            return call.fetch(out)
+
+
+class _Call:
+    """One public call of a `ProgramDecoder`, under its spans and
+    counters: `decode/call` holds `decode/prep` (`prep`: validation, the
+    state's and the prompt's way to the device), `decode/dispatch`
+    (`dispatch`, around the jitted call until it returns: trace, lower
+    and compile or cache load on a new key, the enqueue otherwise) and
+    `decode/fetch` (`fetch`: the wait for the device and the results'
+    way to the host).  Nothing here waits beyond what the call did
+    before it had spans: a transfer still in flight when `prep` returns
+    is waited for by the device, under whichever span is open then.  The
+    same three intervals feed `decoder_seconds_total` when the call ends
+    without an error, with no profiler session too.  The mode builds its
+    function and calls it in its own frame, between these."""
+
+    def __init__(self, decoder, max_len, beam_size=1):
+        self.decoder, self.max_len, self.beam_size = (decoder, max_len,
+                                                      beam_size)
+        self.seconds = {}
+
+    def __enter__(self):
+        self.span = span("decode/call", cat="decoder", call=next(_CALLS),
+                         max_len=self.max_len)
+        self.span.__enter__()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        self.span.__exit__(exc_type, exc, tb)
+        if exc_type is None:
+            telemetry.on_decoder_call(
+                self.mode, self.built, self.batch_size * self.beam_size,
+                self.prompt_len, self.max_len, self.handed["host"],
+                self.handed["device"],
+                [self.seconds[p] for p in ("prep", "dispatch", "fetch")])
+        return False
+
+    @contextlib.contextmanager
+    def _phase(self, name):
+        """`decode/<name>`, and its seconds."""
+        t0 = time.perf_counter()
+        with span("decode/" + name, cat="decoder") as phase:
+            yield phase
+        self.seconds[name] = time.perf_counter() - t0
+
+    def prep(self, init_state, batch_size, prompt):
+        """(the state on the device, the batch size, the prompt on the
+        device or None)."""
+        decoder = self.decoder
+        with self._phase("prep") as prep:
+            state, self.batch_size, self.handed = decoder._prep(
+                init_state, batch_size)
+            prompt = decoder._norm_prompt(prompt, self.max_len)
+            self.prompt_len = 0 if prompt is None else prompt.shape[1]
+            if prompt is not None:
+                prompt = jnp.asarray(prompt)
+            prep.set(host_bytes=self.handed["host"],
+                     device_bytes=self.handed["device"])
+        return state, self.batch_size, prompt
+
+    def program(self, key, builder):
+        """The jitted function of `key` (its first entry the mode)."""
+        decoder = self.decoder
+        self.mode, self.built = key[0], key not in decoder._compiled
+        self.span.set(mode=self.mode, batch=self.batch_size,
+                      prompt_len=self.prompt_len,
+                      block=PREFILL_BLOCK if decoder._takes_block else 1,
+                      built=int(self.built))
+        return decoder._jitted(key, builder)
+
+    def dispatch(self):
+        return self._phase("dispatch")
+
+    def fetch(self, out):
+        with self._phase("fetch"):
+            return jax.tree_util.tree_map(np.asarray, out)

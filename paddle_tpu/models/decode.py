@@ -33,6 +33,16 @@ NEG_INF = -1e30
 # 8: exaone-turn-32k-ep16's question is one application).
 PREFILL_BLOCK = 128
 
+# The two parts of a compiled generation call, as `op_name` scopes in
+# front of everything the part runs (`jit(<lambda>)/decode_steps/while/
+# body/closed_call/<op type>/~<instance>/...`): the whole of `prefill`,
+# and the scan of steps of each decoder below.  Names only (no kernel,
+# layout or instruction changes); no "/" in either, which would cut the
+# path.  The device trace's readers find a call's prefill and its
+# decoding by them (benchmark/reduce/decoder_trace.py).
+PREFILL_SCOPE = "decode_prefill"
+STEPS_SCOPE = "decode_steps"
+
 
 def prefill(step_fn, init_state, prompt, takes_block=False):
     """Feed a prompt through the step function, returning
@@ -51,6 +61,12 @@ def prefill(step_fn, init_state, prompt, takes_block=False):
     equal blocks inside one scan, a shorter block first for the
     remainder: every position is processed, in P / PREFILL_BLOCK
     applications instead of P."""
+    with jax.named_scope(PREFILL_SCOPE):
+        return _prefill(step_fn, init_state, prompt, takes_block)
+
+
+def _prefill(step_fn, init_state, prompt, takes_block):
+    """`prefill`, inside its scope."""
     prompt = jnp.asarray(prompt, jnp.int32)
     if not takes_block:
         telemetry.on_prefill_lowering("step", 1)
@@ -111,8 +127,9 @@ def greedy_decode(step_fn, init_state, bos, eos, max_len, batch_size,
     # GPT-2 endoftext convention) and must still generate
     done0 = (tok0 == eos) if bos.ndim else \
         jnp.zeros((batch_size,), bool)
-    (state, _, done), toks = jax.lax.scan(body, (init_state, tok0, done0),
-                                          None, length=max_len)
+    with jax.named_scope(STEPS_SCOPE):
+        (state, _, done), toks = jax.lax.scan(
+            body, (init_state, tok0, done0), None, length=max_len)
     toks = jnp.moveaxis(toks, 0, 1)               # [B, L]
     lengths = jnp.argmax(toks == eos, axis=1) + 1
     lengths = jnp.where(jnp.any(toks == eos, axis=1), lengths, max_len)
@@ -145,8 +162,9 @@ def sample_decode(step_fn, init_state, bos, eos, max_len, batch_size,
     tok0 = jnp.broadcast_to(bos, (batch_size,))
     done0 = (tok0 == eos) if bos.ndim else \
         jnp.zeros((batch_size,), bool)
-    (_, _, done, _), toks = jax.lax.scan(
-        body, (init_state, tok0, done0, rng), None, length=max_len)
+    with jax.named_scope(STEPS_SCOPE):
+        (_, _, done, _), toks = jax.lax.scan(
+            body, (init_state, tok0, done0, rng), None, length=max_len)
     toks = jnp.moveaxis(toks, 0, 1)
     lengths = jnp.argmax(toks == eos, axis=1) + 1
     lengths = jnp.where(jnp.any(toks == eos, axis=1), lengths, max_len)
@@ -197,8 +215,9 @@ def beam_search_decode_dense(step_fn, init_state, bos, eos, beam_size,
         done = done[flat_src] | (tok == eos)
         return (state, tok, scores, done), (tok_idx, beam_idx)
 
-    (state, tok, scores, done), (toks, parents) = jax.lax.scan(
-        body, (state, tok, scores, done), None, length=max_len)
+    with jax.named_scope(STEPS_SCOPE):
+        (state, tok, scores, done), (toks, parents) = jax.lax.scan(
+            body, (state, tok, scores, done), None, length=max_len)
 
     # backtrack through the per-step parent pointers (reference:
     # beam_search_decode_op PackAllSteps backtracking)

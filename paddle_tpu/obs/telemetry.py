@@ -47,7 +47,7 @@ __all__ = ["on_executor_run", "on_jit_trace",
            "on_prefill_lowering", "on_ssd_lowering",
            "on_causal_conv1d_lowering", "on_shared_parameter_uses",
            "on_transfer",
-           "on_feed_seconds", "on_program_cache_evict",
+           "on_feed_seconds", "on_decoder_call", "on_program_cache_evict",
            "jit_trace_count", "transfer_bytes", "step", "set_gauge",
            "install_step_observer", "step_observer", "snapshot",
            "snapshot_delta", "snapshot_and_delta"]
@@ -422,6 +422,48 @@ def on_transfer(direction, nbytes):
                        "host<->device bytes moved by executor "
                        "feed/fetch", labelnames=("direction",)) \
               .labels(direction=direction).inc(int(nbytes))
+
+
+def on_decoder_call(mode, built, rows, prompt_len, max_len, host_bytes,
+                    device_bytes, seconds):
+    """One public call of `fluid.ProgramDecoder` (`mode`: "greedy",
+    "greedy-prefill", "sample", "beam") has returned: whether it made a
+    new entry of the decoder's compiled programs (`built`: each a trace
+    and a compile), the tokens it was asked for (`rows` x `prompt_len`
+    of prompt, `rows` x `max_len` generated: host arithmetic, not what
+    came before an eos), the bytes of `init_state` it was handed by
+    where they were (`host_bytes` as host arrays, `device_bytes` as
+    `jax.Array`s), and `seconds`, the call's (prep, dispatch, fetch)
+    intervals, the `decode/*` spans' own, which record nothing without a
+    profiler session.  What `on_feed_seconds` and `on_transfer` are for
+    the executor."""
+    reg = _reg()
+    reg.counter("decoder_calls_total", "ProgramDecoder calls, by mode",
+                labelnames=("mode",)).labels(mode=mode).inc()
+    if built:
+        reg.counter("decoder_programs_total",
+                    "programs ProgramDecoder built (a trace and a compile "
+                    "each: one a mode, lengths and batch), by mode",
+                    labelnames=("mode",)).labels(mode=mode).inc()
+    for name, label, text, amounts in (
+            ("decoder_tokens_total", "kind",
+             "tokens ProgramDecoder calls were asked for: rows x prompt "
+             "length, rows x generated length",
+             (("prompt", rows * prompt_len), ("generated", rows * max_len))),
+            ("decoder_state_bytes_total", "source",
+             "bytes of init_state ProgramDecoder calls were handed, by "
+             "where they were (host arrays, or jax.Arrays on the device)",
+             (("host", host_bytes), ("device", device_bytes))),
+            ("decoder_seconds_total", "phase",
+             "seconds inside ProgramDecoder calls, by phase (prep: "
+             "validation and the state's and prompt's way to the device; "
+             "dispatch: the jitted call until it returns; fetch: the wait "
+             "for the device and the results' way to the host)",
+             zip(("prep", "dispatch", "fetch"), seconds))):
+        family = reg.counter(name, text, labelnames=(label,))
+        for value, amount in amounts:
+            if amount:
+                family.labels(**{label: value}).inc(amount)
 
 
 def transfer_bytes(direction):

@@ -164,3 +164,92 @@ def test_a_recurrent_state_step_prefills_a_position_at_a_time():
         lg, h = exe.run(infer, feed={"tok": prompt[:, t], "h_in": h},
                         fetch_list=[logits, h_out])
     np.testing.assert_array_equal(toks[:, 0], np.argmax(lg, axis=-1))
+
+
+def _decoder_counters(before):
+    """What the `decoder_*` counters rose by since `before`."""
+    from paddle_tpu.obs import telemetry
+
+    return {k: v for k, v in telemetry.snapshot_delta(before).items()
+            if k.startswith("decoder_")}
+
+
+def _untrained_decoder():
+    main, startup, tok, h_in, h_out, logits = _build_step_program()
+    scope = fluid.Scope()
+    fluid.Executor(fluid.CPUPlace()).run(startup, scope=scope)
+    return fluid.ProgramDecoder(main.clone(for_test=True), token_name="tok",
+                                logits_name=logits.name,
+                                state_pairs=[("h_in", h_out.name)],
+                                scope=scope)
+
+
+def test_two_calls_are_one_program_and_their_tokens_and_bytes_by_source():
+    """A state handed over as a numpy array, then as a `jax.Array`: two
+    calls of one program, the tokens asked for, the state's bytes by
+    where they were, and seconds in every phase."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.obs import telemetry
+
+    dec = _untrained_decoder()
+    batch, max_len = 3, 7
+    prompt = np.array([[3, 5, 7, 2]] * batch, np.int64)
+    state = np.zeros((batch, H), np.float32)
+    before = telemetry.snapshot()
+    for h in (state, jnp.asarray(state)):
+        dec.greedy(bos=BOS, eos=EOS, max_len=max_len, prompt=prompt,
+                   init_state={"h_in": h})
+    rose = _decoder_counters(before)
+    seconds = {k: rose.pop(k) for k in list(rose)
+               if k.startswith("decoder_seconds_total")}
+    assert rose == {
+        "decoder_calls_total{mode=greedy-prefill}": 2,
+        "decoder_programs_total{mode=greedy-prefill}": 1,
+        "decoder_tokens_total{kind=prompt}": 2 * batch * 4,
+        "decoder_tokens_total{kind=generated}": 2 * batch * max_len,
+        "decoder_state_bytes_total{source=host}": state.nbytes,
+        "decoder_state_bytes_total{source=device}": state.nbytes}
+    assert sorted(seconds) == [
+        "decoder_seconds_total{phase=%s}" % p
+        for p in ("dispatch", "fetch", "prep")]
+    assert all(v > 0 for v in seconds.values())
+    # the first call's dispatch held the trace and the compile
+    assert seconds["decoder_seconds_total{phase=dispatch}"] \
+        > seconds["decoder_seconds_total{phase=prep}"]
+
+
+def test_each_mode_counts_its_calls_and_a_beam_its_rows():
+    from paddle_tpu.obs import telemetry
+
+    dec = _untrained_decoder()
+    init = {"h_in": np.zeros((2, H), np.float32)}
+    before = telemetry.snapshot()
+    dec.greedy(bos=BOS, eos=EOS, max_len=5, init_state=init)
+    dec.sample(bos=BOS, eos=EOS, max_len=4, init_state=init)
+    dec.sample(bos=BOS, eos=EOS, max_len=4, init_state=init, seed=3)
+    dec.beam(beam_size=3, bos=BOS, eos=EOS, max_len=6, init_state=init)
+    rose = _decoder_counters(before)
+    assert {k: v for k, v in rose.items() if "seconds" not in k} == {
+        "decoder_calls_total{mode=greedy}": 1,
+        "decoder_calls_total{mode=sample}": 2,
+        "decoder_calls_total{mode=beam}": 1,
+        "decoder_programs_total{mode=greedy}": 1,
+        "decoder_programs_total{mode=sample}": 1,
+        "decoder_programs_total{mode=beam}": 1,
+        # no prompt: no prompt tokens; a beam's rows are batch x beam
+        "decoder_tokens_total{kind=generated}":
+            2 * 5 + 2 * 2 * 4 + 2 * 3 * 6,
+        "decoder_state_bytes_total{source=host}": 4 * 2 * H * 4}
+
+
+def test_a_call_that_is_refused_counts_nothing():
+    import pytest
+
+    from paddle_tpu.obs import telemetry
+
+    dec = _untrained_decoder()
+    before = telemetry.snapshot()
+    with pytest.raises(ValueError, match="init_state missing"):
+        dec.greedy(bos=BOS, eos=EOS, max_len=5, batch_size=2)
+    assert _decoder_counters(before) == {}

@@ -435,3 +435,159 @@ def test_an_op_without_outputs_still_has_an_instance():
     assert op_instance(OpDesc(
         "fused_update", {"Param": ["w0", "w1"], "Grad": ["g0", "g1"]},
         {"ParamOut": ["w0", "w1"]})) == INSTANCE_SIGIL + "w0"
+
+
+# -- the decoding layer: spans around a call, scopes inside it ------------------
+
+def _rnn_decoder(takes_block=False):
+    """A `ProgramDecoder` over a one-layer recurrent step (start-up
+    weights); with `takes_block` the token feed is declared
+    `[batch, -1]` and the step reads the block's last token."""
+    H, V = 8, 11
+    fluid.framework.reset_unique_name()
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        tok = fluid.layers.data(name="tok",
+                                shape=[-1, -1] if takes_block else [-1],
+                                dtype="int64", append_batch_size=False)
+        h_in = fluid.layers.data(name="h_in", shape=[-1, H],
+                                 dtype="float32", append_batch_size=False)
+        last = tok
+        if takes_block:
+            # the block's columns, last first: [T, batch] -> [batch]
+            last = fluid.layers.reduce_max(tok, dim=1)
+        emb = fluid.layers.embedding(last, size=[V, 6])
+        h_out = fluid.layers.fc(input=[emb, h_in], size=H, act="tanh")
+        logits = fluid.layers.fc(input=h_out, size=V)
+    scope = fluid.Scope()
+    fluid.Executor(fluid.CPUPlace()).run(startup, scope=scope)
+    return fluid.ProgramDecoder(
+        main.clone(for_test=True), token_name="tok",
+        logits_name=logits.name, state_pairs=[("h_in", h_out.name)],
+        scope=scope), H
+
+
+def _decoder_calls(decoder, hidden, batch=3):
+    """{mode: a call of it}, each with a state of `batch` rows."""
+    def init():
+        return {"h_in": np.zeros((batch, hidden), np.float32)}
+
+    prompt = np.arange(batch * 5).reshape(batch, 5) % 7
+    return {
+        "greedy": lambda: decoder.greedy(
+            bos=1, eos=0, max_len=6, init_state=init()),
+        "greedy-prefill": lambda: decoder.greedy(
+            bos=1, eos=0, max_len=6, init_state=init(), prompt=prompt),
+        "sample": lambda: decoder.sample(
+            bos=1, eos=0, max_len=4, init_state=init(), prompt=prompt),
+        "beam": lambda: decoder.beam(
+            beam_size=2, bos=1, eos=0, max_len=5, init_state=init()),
+    }
+
+
+@pytest.mark.parametrize("mode,prompt_len,max_len", [
+    ("greedy", 0, 6), ("greedy-prefill", 5, 6), ("sample", 5, 4),
+    ("beam", 0, 5)])
+def test_decoder_spans_reach_the_profiler_with_obs_trace_disabled(
+        tmp_path, mode, prompt_len, max_len):
+    decoder, hidden = _rnn_decoder()
+    call = _decoder_calls(decoder, hidden)[mode]
+    assert not obs_trace.is_enabled()
+    # the first call builds the program, the second finds it
+    lines = _profile(tmp_path, lambda: (call(), call()))
+    line, = [ln for ln in lines if any(ev[0] == "decode/call" for ev in ln)]
+    calls = [ev for ev in line if ev[0] == "decode/call"]
+    assert [c[3].pop("built") for c in calls] == [1, 0]
+    first, second = (c[3].pop("call") for c in calls)
+    assert second == first + 1
+    for whole in calls:
+        assert whole[3] == {"mode": mode, "batch": 3, "max_len": max_len,
+                            "prompt_len": prompt_len, "block": 1}
+        prep, = _inside(line, whole, "decode/prep")
+        dispatch, = _inside(line, whole, "decode/dispatch")
+        fetch, = _inside(line, whole, "decode/fetch")
+        assert prep[3] == {"host_bytes": 3 * hidden * 4, "device_bytes": 0}
+        assert dispatch[3] == {} and fetch[3] == {}
+        assert prep[2] <= dispatch[1] and dispatch[2] <= fetch[1]
+        # the jitted function stays a lambda, inside the dispatch
+        assert _inside(line, dispatch, "PjitFunction(<lambda>)")
+    assert obs_trace.events() == []
+
+
+def test_a_block_taking_decoder_says_its_block_in_the_call_span(tmp_path):
+    from paddle_tpu.models.decode import PREFILL_BLOCK
+
+    decoder, hidden = _rnn_decoder(takes_block=True)
+    assert decoder._takes_block
+    call = _decoder_calls(decoder, hidden)["greedy-prefill"]
+    lines = _profile(tmp_path, call)
+    whole, = [ev for ln in lines for ev in ln if ev[0] == "decode/call"]
+    assert whole[3]["block"] == PREFILL_BLOCK and whole[3]["built"] == 1
+
+
+def test_decoder_spans_reach_the_memory_sink_while_obs_trace_is_on():
+    decoder, hidden = _rnn_decoder()
+    call = _decoder_calls(decoder, hidden)["greedy-prefill"]
+    with obs_trace.tracing():
+        call()
+    spans = [e for e in obs_trace.events() if e["ph"] == "X"]
+    assert [e["name"] for e in spans] == [
+        "decode/prep", "decode/dispatch", "decode/fetch", "decode/call"]
+    assert {e["cat"] for e in spans} == {"decoder"}
+    assert spans[0]["args"] == {"host_bytes": 3 * hidden * 4,
+                                "device_bytes": 0}
+    assert spans[-1]["args"]["mode"] == "greedy-prefill"
+
+
+def _lowered_names(decoder, hidden, mode):
+    """The `op_name` paths of the text a decoder's call compiles to."""
+    _decoder_calls(decoder, hidden)[mode]()
+    (key, fn), = decoder._compiled.items()
+    assert key[0] == mode
+    state = {"h_in": jnp.zeros((3, hidden), jnp.float32)}
+    extra = {"greedy": (), "beam": (),
+             "greedy-prefill": (jnp.zeros((3, 5), jnp.int32),),
+             "sample": (jnp.zeros((3, 5), jnp.int32),
+                        jax.random.PRNGKey(0))}[mode]
+    text = fn.lower(decoder._params, state, *extra).compile().as_text()
+    return set(re.findall(r'op_name="([^"]*)"', text))
+
+
+@pytest.mark.parametrize("mode,prefills", [
+    ("greedy", False), ("greedy-prefill", True), ("sample", True),
+    ("beam", False)])
+def test_a_lowered_call_names_its_prefill_and_its_steps(mode, prefills):
+    decoder, hidden = _rnn_decoder()
+    names = _lowered_names(decoder, hidden, mode)
+    steps = [n for n in names if "/decode_steps/" in n]
+    assert steps
+    # the scope stands in front of the scan's own, the op's type and
+    # instance behind them
+    assert any(re.match(r"^jit\(<lambda>\)/decode_steps/while/body/.*"
+                        r"/mul/%s[^/]+/" % INSTANCE_SIGIL, n)
+               for n in steps), sorted(steps)[:5]
+    prefill = [n for n in names if "/decode_prefill/" in n]
+    assert bool(prefill) == prefills
+    if prefills:
+        # the first position runs outside the scan, the rest inside it
+        assert any(re.match(r"^jit\(<lambda>\)/decode_prefill/mul/", n)
+                   for n in prefill)
+        assert any("/decode_prefill/while/body/" in n for n in prefill)
+    assert not [n for n in names
+                if "decode_prefill" in n and "decode_steps" in n]
+
+
+def test_jit_phases_of_a_decoder_are_counted_under_its_lambda():
+    decoder, hidden = _rnn_decoder()
+    call = _decoder_calls(decoder, hidden)["greedy-prefill"]
+    before = obs_tele.snapshot()
+    call()
+    moved = obs_tele.snapshot_delta(before)
+    for phase in ("trace", "lower", "compile"):
+        assert moved.get("jit_phase_seconds_total{fun_name=<lambda>,"
+                         "phase=%s}" % phase, 0) > 0, moved
+    # the second call compiles nothing
+    before = obs_tele.snapshot()
+    call()
+    assert not [k for k in obs_tele.snapshot_delta(before)
+                if k.startswith("jit_phase_seconds_total")]
