@@ -9,11 +9,19 @@ in VMEM and folds the K/V block into the running max, sum and
 accumulator one (block_k, block_q) chunk of scores at a time, both
 products on the MXU.  The backward (custom VJP) recomputes the
 probabilities chunk by chunk from the forward's row statistics, in the
-same layout: where a head's queries and its dq fit VMEM, one kernel
-holds a block of keys and makes dq, dk and dv together; where they do
-not, one kernel holds a block of keys and gathers dk and dv over the
-queries that see it, and another holds a block of queries and gathers
-dq over the keys it sees.
+same layout, in one of three forms (`_choose_bwd_blocks`).  Where a
+head's queries and its dq fit VMEM, one kernel holds a block of keys
+and makes dq, dk and dv together: five products a chunk, the four the
+gradients are and the scores.  Where they do not but the call has a
+window, one kernel still makes all three, five products a chunk, as it
+walks the blocks of keys in order: a block of keys is seen by the
+window + block queries after it alone, so the dq that has to stay in
+VMEM is that of the few blocks of queries the keys can still reach, a
+ring of them, and a block of queries leaves the ring as dq when the
+keys on its own diagonal have been folded.  Else one kernel holds a
+block of keys and gathers dk and dv over the queries that see it, and
+another holds a block of queries and gathers dq over the keys it sees:
+seven products a chunk, the scores and dp made twice.
 
 How much one grid step does is chosen from the shapes
 (`_choose_blocks`, `_choose_bwd_blocks`): a grid step costs about
@@ -37,9 +45,9 @@ diagonal enters a chunk has to be known when the kernel is traced: the
 blocks and `q_offset` multiples of a piece (`_stair_width`; a `pl.when`
 for each place, `_leads`, where several chunks of a grid step are
 crossed).  Elsewhere (a decode step's offset, a ragged sequence that is
-one block, a sequence shard at an odd offset) and in the two backward
-kernels that walk, a crossed chunk is folded whole through the same
-fold, every score compared.  `score_pairs` counts what either way
+one block, a sequence shard at an odd offset) and in the backward's
+pair of kernels that walk, a crossed chunk is folded whole through the
+same fold, every score compared.  `score_pairs` counts what either way
 folds, and the counter `flash_attention_pairs_total` reports it.
 
 A `window` W > 0 under the causal mask bounds the other side: query i
@@ -114,6 +122,9 @@ _STAIR = 256
 # the scope the backward's operations lie under, whoever calls `_bwd`:
 # the benchmark's readers find them by it
 BWD_SCOPE = "flash_attention_bwd"
+# the kernels of each form of the backward (`_choose_bwd_blocks`), as the
+# counters name them
+_BWD_KERNELS = {"one": ("dq_dkv",), "ring": ("ring",), "pair": ("dkv", "dq")}
 
 
 def _block(seq, block, what, shape):
@@ -373,7 +384,7 @@ def score_pairs(tq, tk, causal, q_offset, bq, bk, widest, window=0):
     """(folded, attended): the score pairs the kernels compute for one
     head at blocks (bq, bk) with pieces up to `widest` keys (None: a
     kernel that folds a crossed chunk whole whatever the shapes, as the
-    two backward kernels that walk), and those among them a query
+    backward's pair of kernels that walk), and those among them a query
     attends.  The forward (a block of queries over chunks of keys) and
     the backward (a block of keys over chunks of queries) meet the same
     [bk, bq] chunks.  Under a `window` a query attends the last
@@ -759,7 +770,17 @@ def flash_attention(q, k, v, sm_scale=None, causal=False, block_q=None,
                                     block_k, q_offset, None, window)[0]
 
 
-def _bwd_step_bytes(bq, bk, d, itemsize, tq=None, heads=1):
+def _ring_slots(bq, bk, tq, window):
+    """The query blocks one block of bk keys can reach under `window`,
+    from the block its first key lies in to the one the window's lower
+    edge crosses on its last key's side (one more where a key block can
+    start inside a query block), and no more than the sequence has:
+    what the ring kernel's inner grid axis walks and its ring of dq^T
+    holds a slot for."""
+    return min((max(bq, bk) + window - 2) // bq + 1, tq // bq)
+
+
+def _bwd_step_bytes(bq, bk, d, itemsize, tq=None, heads=1, ring=0):
     """VMEM bytes one grid step of the backward holds, `d` the width of
     its `heads` heads together.  Every kernel holds four [bk, bq]
     float32 chunks (scores, probabilities, dp, ds) and the casts of two
@@ -768,17 +789,21 @@ def _bwd_step_bytes(bq, bk, d, itemsize, tq=None, heads=1):
     queries of a head: their q, do and dq [tq, d] and the K, V, dk and
     dv tiles [bk, d], double-buffered; dq^T in float32 [d, tq], its
     rows padded to 128; K transposed [d, bk]; two float32 accumulators
-    [bk, d] a head.  Without, the larger of the two kernels that walk:
-    dK/dV holds the tiles of q, do, K, V, dk and dv and two
+    [bk, d] a head.  With `ring` the one kernel that walks the keys
+    under a window: the same but for the queries' side, which is one
+    [bq, d] tile of q, do and dq and `ring` slots [d, bq] of dq^T
+    (`_ring_slots`).  With neither, the larger of the two kernels that
+    walk: dK/dV holds the tiles of q, do, K, V, dk and dv and two
     accumulators a head, dQ those of q, do, dq, K and V, K transposed
     and one accumulator."""
     lanes = _pad_to_lanes(d)
     chunk = bq * bk * (4 * 4 + 2 * itemsize)
     accumulators = heads * 2 * 4 * lanes * bk
-    if tq is not None:
-        return (chunk + 2 * itemsize * lanes * (3 * tq + 4 * bk)
-                + 4 * lanes * tq + itemsize * d * bk + accumulators
-                + 2 * 2 * 8 * tq * 4)
+    if tq is not None or ring:
+        held, slots = (tq, 1) if tq is not None else (bq, ring)
+        return (chunk + 2 * itemsize * lanes * (3 * held + 4 * bk)
+                + 4 * lanes * slots * held + itemsize * d * bk + accumulators
+                + 2 * 2 * 8 * held * 4)
     dkv = 2 * itemsize * lanes * (2 * bq + 4 * bk) + accumulators
     dq = (2 * itemsize * lanes * (3 * bq + 2 * bk) + itemsize * d * bk
           + 4 * lanes * bq)
@@ -786,16 +811,22 @@ def _bwd_step_bytes(bq, bk, d, itemsize, tq=None, heads=1):
 
 
 def _choose_bwd_blocks(q_shape, k_shape, itemsize, block_q=None,
-                       block_k=None, heads=1, window=0):
-    """(block_q, block_k, one_kernel) for the backward of one call,
-    from what `_choose_blocks` reads and the `heads` a grid step holds:
-    a named block is kept, and the chosen pair is the one that holds
-    most scores at a time under the VMEM budget (the squarer of two
-    that hold as many), first among the pairs that leave room for all
-    of a head's queries and its dq: `one_kernel` says one kernel then
-    makes dq, dk and dv together, and not one kernel dk and dv and
-    another dq, each walking the other side a block a grid step.
-    Under a `window` no chosen block is wider than the window needs."""
+                       block_k=None, heads=1, window=0, q_offset=0):
+    """(block_q, block_k, form) for the backward of one call, from what
+    `_choose_blocks` reads, the `heads` a grid step holds and, under a
+    live `window`, `q_offset`: a named block is kept, and the chosen
+    pair is the one that holds most scores at a time under the VMEM
+    budget (the squarer of two that hold as many), in the first of
+    three forms that has room for one.  "one": all of a head's queries
+    and its dq beside the chunk, so that one kernel makes dq, dk and dv
+    together.  "ring": under a window, the dq of the queries a block of
+    keys can still reach (`_ring_slots`), so that one kernel makes all
+    three as it walks the keys in order; a square call from position 0
+    at blocks of whole lane blocks, one a multiple of the other, where
+    the diagonal leaves a block of queries at the end of a block of
+    keys.  "pair": one kernel dk and dv and another dq, each walking
+    the other side a block a grid step.  Under a `window` no chosen
+    block is wider than the window needs."""
     tq, d = q_shape[2], q_shape[3]
     tk = k_shape[2]
     qs = (_window_blocks(_candidates(tq), window) if block_q is None
@@ -805,14 +836,21 @@ def _choose_bwd_blocks(q_shape, k_shape, itemsize, block_q=None,
     named = block_q is not None and block_k is not None
     pairs = sorted(itertools.product(qs, ks),
                    key=lambda qk: (-qk[0] * qk[1], -min(qk), -qk[0]))
-    for one_kernel in (True, False):
+    forms = ["one", "pair"]
+    if window and tq == tk and q_offset == 0:
+        forms.insert(1, "ring")
+    for form in forms:
         for bq, bk in pairs:
-            if _bwd_step_bytes(bq, bk, d, itemsize,
-                               tq if one_kernel else None,
-                               heads) <= _VMEM_BUDGET:
-                return bq, bk, one_kernel
+            if form == "ring" and (bq % _LANES or bk % _LANES
+                                   or max(bq, bk) % min(bq, bk)):
+                continue
+            if _bwd_step_bytes(
+                    bq, bk, d, itemsize, tq if form == "one" else None,
+                    heads, _ring_slots(bq, bk, tq, window)
+                    if form == "ring" else 0) <= _VMEM_BUDGET:
+                return bq, bk, form
     if named:
-        return pairs[0] + (False,)
+        return pairs[0] + ("pair",)
     raise ValueError(
         "flash_attention: no block among %s tiles query length %d and "
         "key length %d of shapes %s and %s within %d bytes of VMEM in "
@@ -885,6 +923,35 @@ def _write_dq(dq_ref, dqt_scr, sm_scale):
                       (1, 0))[:, :dq_ref.shape[1]], dq_ref.dtype)
 
 
+def _fold_three(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dqt_ref,
+                kt_scr, dk_scr, dv_scr, rows, these, lead, edge, *, sm_scale,
+                widen, d):
+    """The keys `these` of a grid step's block meet the queries `rows`
+    of its q tile, for every head of the step's: dv += p do and
+    dk += ds q into the heads' accumulators, dq^T += k^T ds into
+    `dqt_ref` [lanes, queries]; `lead` as `_see` takes it, None where
+    every query sees every key; `edge` the window where its lower edge
+    crosses the chunk too.  Five products: the scores and dp are made
+    once for all three gradients."""
+    lanes = k_ref.shape[1]
+    q, do = q_ref[rows, :], do_ref[rows, :]
+    k, v = k_ref[these, :], v_ref[these, :]
+
+    def one(h, head, stat):
+        own = _head_lanes(h, d, lanes, k.dtype)
+        p, ds = _bwd_chunk(
+            q, _only_head(k, own), _only_head(v, own), do,
+            lse_ref[stat, rows], delta_ref[stat, rows], sm_scale, lead,
+            widen, edge)
+        ds = lax.convert_element_type(ds, q.dtype)
+        _add_dkv(dk_scr, dv_scr, h, p, ds, q, do, widen, these)
+        dqt_ref[head, rows] = lax.add(
+            dqt_ref[head, rows],
+            _bwd_matmul(kt_scr[head, these], ds, 0, widen))
+
+    _each_head(lanes // d, d, one)
+
+
 def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                 dk_ref, dv_ref, dqt_scr, kt_scr, dk_scr, dv_scr, *,
                 sm_scale, causal, q_offset, widen, bq, d, stair, window=0):
@@ -894,13 +961,13 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     the block: dv += p do, dk += ds q, and dq^T += k^T ds into
     the float32 [lanes, Tq] scratch, which the k_block axis
     (sequential) fills and its last step scales, transposes back and
-    writes.  Five products a chunk: the scores and dp are made once for
-    all three gradients.  Chunks are [keys, queries] as in
-    `_fwd_kernel`, so the row statistics are lane-dense rows and every
-    product takes its operands as they are but dq's, which is why K is
-    transposed (once a step) and dq accumulates transposed: head h has
-    rows [h * d, (h + 1) * d) of both."""
-    (bk, lanes), tq = k_ref.shape, q_ref.shape[0]
+    writes.  Five products a chunk (`_fold_three`).  Chunks are
+    [keys, queries] as in `_fwd_kernel`, so the row statistics are
+    lane-dense rows and every product takes its operands as they are
+    but dq's, which is why K is transposed (once a step) and dq
+    accumulates transposed: head h has rows [h * d, (h + 1) * d) of
+    both."""
+    bk, tq = k_ref.shape[0], q_ref.shape[0]
     j = pl.program_id(2)
     kt_scr[...] = lax.transpose(k_ref[...], (1, 0))
     dk_scr[...] = lax.full(dk_scr.shape, 0, jnp.float32)
@@ -915,9 +982,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
     def _fold(c, key, keys, query, lead, edge=0):
         """The block's `keys` keys from its `key`-th meet the queries of
-        chunk c from the chunk's `query`-th on; `lead` as `_see` takes
-        it, None where every query sees every key; `edge` the window
-        where its lower edge crosses the chunk too."""
+        chunk c from the chunk's `query`-th on."""
         if tq == bq:
             # one chunk, read whole: a block that is the whole of a
             # ragged sequence has no aligned slice
@@ -925,23 +990,9 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         else:
             rows = pl.ds(pl.multiple_of(lax.add(lax.mul(c, bq), query),
                                         math.gcd(bq, query)), bq - query)
-        these = slice(key, key + keys)
-        q, do = q_ref[rows, :], do_ref[rows, :]
-        k, v = k_ref[these, :], v_ref[these, :]
-
-        def one(h, head, stat):
-            own = _head_lanes(h, d, lanes, k.dtype)
-            p, ds = _bwd_chunk(
-                q, _only_head(k, own), _only_head(v, own), do,
-                lse_ref[stat, rows], delta_ref[stat, rows], sm_scale, lead,
-                widen, edge)
-            ds = lax.convert_element_type(ds, q.dtype)
-            _add_dkv(dk_scr, dv_scr, h, p, ds, q, do, widen, these)
-            dqt_scr[head, rows] = lax.add(
-                dqt_scr[head, rows],
-                _bwd_matmul(kt_scr[head, these], ds, 0, widen))
-
-        _each_head(lanes // d, d, one)
+        _fold_three(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dqt_scr,
+                    kt_scr, dk_scr, dv_scr, rows, slice(key, key + keys),
+                    lead, edge, sm_scale=sm_scale, widen=widen, d=d)
 
     def _fold_whole(c):
         _fold(c, 0, bk, 0, None)
@@ -980,6 +1031,67 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     _write_dkv(dk_ref, dv_ref, dk_scr, dv_scr, sm_scale, d)
     pl.when(lax.eq(j, lax.sub(pl.num_programs(2), 1)))(
         lambda: _write_dq(dq_ref, dqt_scr, sm_scale))
+
+
+def _bwd_ring_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+                     dk_ref, dv_ref, ring_scr, kt_scr, dk_scr, dv_scr, *,
+                     sm_scale, widen, d, stair, window, chunks):
+    """One (batch, heads, k_block, reach) grid step of the whole
+    backward of a square causal call under a `window`, where a head's
+    queries do not fit VMEM: the step's bk keys meet one block of bq
+    queries, the `reach`-th from the block the first key lies in, as
+    `_bwd_kernel`'s meet a chunk (`_fold_three`: five products; the
+    diagonal's chunks as a staircase, the lower edge's whole under both
+    compares).  Both axes are sequential.  dk and dv gather over the
+    reach axis, which ends on the block the lower edge leaves the keys
+    by.  dq^T gathers over the k_block axis in a ring of float32
+    [lanes, bq] slots, block i of the queries in slot i mod their
+    number: a block's slot is zeroed at the first block of keys that
+    reaches it and written out as dq at the last, the one its own
+    diagonal ends in, by when the block whose slot it takes next has
+    not been reached.  A step past the window's reach or the sequence's
+    end re-names the last block fetched and folds nothing."""
+    bq, bk = q_ref.shape[0], k_ref.shape[0]
+    j, r = pl.program_id(2), pl.program_id(3)
+
+    @pl.when(lax.eq(r, 0))
+    def _init():
+        kt_scr[...] = lax.transpose(k_ref[...], (1, 0))
+        dk_scr[...] = lax.full(dk_scr.shape, 0, jnp.float32)
+        dv_scr[...] = lax.full(dv_scr.shape, 0, jnp.float32)
+
+    i = lax.add(lax.div(lax.mul(j, bk), bq), r)
+    dqt_ref = ring_scr.at[lax.rem(i, ring_scr.shape[0])]
+    # how far the block's first key is ahead of the chunk's first query
+    lead = lax.sub(lax.mul(j, bk), lax.mul(i, bq))
+    live = lax.bitwise_and(lax.lt(i, chunks), lax.gt(lead, 1 - bk - window))
+    # the lower edge crosses the chunk; else the diagonal does, or neither
+    edge = lax.lt(lax.add(lead, window), bq)
+    crossed = lax.bitwise_and(lax.bitwise_not(edge), lax.gt(lead, 1 - bk))
+
+    def _fold(key, keys, query, lead, edge=0):
+        _fold_three(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dqt_ref,
+                    kt_scr, dk_scr, dv_scr, slice(query, bq),
+                    slice(key, key + keys), lead, edge, sm_scale=sm_scale,
+                    widen=widen, d=d)
+
+    def _when(which):
+        return pl.when(lax.bitwise_and(live, which))
+
+    # the block of keys before this one did not reach the chunk's queries
+    @_when(lax.bitwise_or(lax.eq(j, 0), lax.le(lead, 1 - window)))
+    def _enter():
+        dqt_ref[...] = lax.full(dqt_ref.shape, 0, jnp.float32)
+
+    _when(edge)(lambda: _fold(0, bk, 0, lead, window))
+    _when(crossed)(lambda: _fold_crossed(_fold, lead, bq, bk, 0, stair))
+    _when(lax.bitwise_not(lax.bitwise_or(edge, crossed)))(
+        lambda: _fold(0, bk, 0, None))
+    # the queries' block ends where this block of keys does, or before
+    _when(lax.ge(lead, bq - bk))(
+        lambda: _write_dq(dq_ref, dqt_ref, sm_scale))
+    pl.when(lax.eq(r, lax.sub(pl.num_programs(3), 1)))(
+        lambda: _write_dkv(dk_ref, dv_ref, dk_scr, dv_scr, sm_scale, d))
 
 
 def _fold_where_seen(fold, causal, behind, bq, bk, window=0):
@@ -1097,9 +1209,12 @@ def _flash_bwd_rule(sm_scale, causal, block_q, block_k, q_offset,
     dv = p^T do; dp = do v^T; ds = p * (dp - rowsum(do * o));
     dq = ds k; dk = ds^T q.  Where a head's queries and its dq fit VMEM
     one kernel holds a block of keys and makes all three (five products
-    a chunk); where they do not, one kernel holds a block of keys and
-    gathers dk and dv over the queries that see it and another holds a
-    block of queries and gathers dq over the keys it sees (seven).
+    a chunk); where they do not and the call has a window, one kernel
+    walks the blocks of keys and makes all three, the dq of the blocks
+    of queries the keys can still reach in a ring in VMEM (five); else
+    one kernel holds a block of keys and gathers dk and dv over the
+    queries that see it and another holds a block of queries and
+    gathers dq over the keys it sees (seven).
     Products take their operands in the type they arrive in and
     accumulate in float32; nothing the size of the score square reaches
     HBM.  A cotangent of the log-sum-exp enters with the row sums:
@@ -1129,58 +1244,65 @@ def _bwd(q, k, v, do, lse, delta, sm_scale, causal, block_q, block_k,
     if sm_scale is None:
         sm_scale = call.d ** -0.5
     window = _live_window(window, causal, call.tq, q_offset)
-    bq, bk, one_kernel = _choose_bwd_blocks(
+    bq, bk, form = _choose_bwd_blocks(
         *call.step_shapes, q.dtype.itemsize, block_q, block_k, call.g,
-        window)
-    for kernel in (("dq_dkv",) if one_kernel else ("dkv", "dq")):
+        window, q_offset)
+    for kernel in _BWD_KERNELS[form]:
         telemetry.on_flash_attention_bwd_lowering(
             kernel, bq, bk, "split" if split else call.g)
         telemetry.on_flash_attention_pairs("bwd", *(
             call.batch * call.heads * n for n in score_pairs(
                 call.tq, call.tk, causal, q_offset, bq, bk,
-                _STAIR if one_kernel else None, window)))
+                None if form == "pair" else _STAIR, window)))
         if window:
             telemetry.on_flash_window_lowering(kernel, window, bq, bk)
     return _bwd_kernels(q, k, v, do, lse, delta, num_heads=num_heads,
                         sm_scale=sm_scale, causal=causal, q_offset=q_offset,
-                        bq=bq, bk=bk, one_kernel=one_kernel, window=window)
+                        bq=bq, bk=bk, form=form, window=window)
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "num_heads", "sm_scale", "causal", "q_offset", "bq", "bk",
-    "one_kernel", "window"))
+    "num_heads", "sm_scale", "causal", "q_offset", "bq", "bk", "form",
+    "window"))
 def _bwd_kernels(q, k, v, do, lse, delta, *, num_heads, sm_scale, causal,
-                 q_offset, bq, bk, one_kernel, window=0):
-    """dq, dk, dv from the row statistics and do, at blocks already
-    chosen.  Under `jax.jit` so that a program holding the same
-    attention many times (one a layer) traces these kernels once, and
-    the build's shape inference, the executor's program and the
-    functional step share that trace: a kernel body is some hundred
-    primitives and a step program would trace it twice an op (PERF.md
-    section 6, PR 26)."""
+                 q_offset, bq, bk, form, window=0):
+    """dq, dk, dv from the row statistics and do, at blocks and in the
+    form already chosen (`_choose_bwd_blocks`).  Under `jax.jit` so
+    that a program holding the same attention many times (one a layer)
+    traces these kernels once, and the build's shape inference, the
+    executor's program and the functional step share that trace: a
+    kernel body is some hundred primitives and a step program would
+    trace it twice an op (PERF.md section 6, PR 26)."""
     call = _Call.of(q.shape, k.shape, num_heads)
     tq, tk, lanes = call.tq, call.tk, call.lanes
     operands = [q, k, v, do] \
         + [x.reshape(call.stats_shape) for x in (lse, delta)]
     stair = (_stair_width(bq, bk, q_offset, _STAIR)
-             if causal and one_kernel else None)
+             if causal and form != "pair" else None)
     name = "flash_attention_bwd%%s_q%d_k%d%s%s" % (
         bq, bk, _stair_suffix(stair, window), call.suffix)
     accumulator = pltpu.VMEM((call.g, bk, lanes), jnp.float32)
-    transposed = pltpu.VMEM((_pad_to_lanes(lanes), tq if one_kernel else bq),
-                            jnp.float32)
+    masked = {"causal": causal, "q_offset": q_offset}
+
+    def transposed(queries, *slots):
+        # dq^T of `queries` queries, its rows padded to 128 so that the
+        # step that writes dq can transpose it
+        return pltpu.VMEM(slots + (_pad_to_lanes(lanes), queries),
+                          jnp.float32)
 
     def pallas_call(kernel, interpret, **args):
         return pl.pallas_call(
-            functools.partial(kernel, sm_scale=sm_scale, causal=causal,
-                              q_offset=q_offset, widen=interpret, d=call.d,
-                              window=window),
+            functools.partial(kernel, sm_scale=sm_scale, widen=interpret,
+                              d=call.d, window=window),
             interpret=interpret, **args)
 
     def like(x):
         return jax.ShapeDtypeStruct(x.shape, x.dtype)
 
-    if one_kernel:
+    def held(a, b):
+        return a
+
+    if form == "one":
         def whole(j):
             return 0
 
@@ -1189,17 +1311,53 @@ def _bwd_kernels(q, k, v, do, lse, delta, *, num_heads, sm_scale, causal,
 
         queries, keys = call.rows(tq, whole), call.rows(bk, block)
         return tuple(_on_platform(functools.partial(
-            pallas_call, functools.partial(_bwd_kernel, bq=bq, stair=stair),
+            pallas_call,
+            functools.partial(_bwd_kernel, bq=bq, stair=stair, **masked),
             grid=call.steps + (tk // bk,),
             in_specs=[queries, keys, keys, queries,
                       call.stats(tq, whole), call.stats(tq, whole)],
             out_specs=[queries, keys, keys],
             out_shape=[like(q), like(k), like(v)],
-            scratch_shapes=[transposed, pltpu.VMEM((lanes, bk), k.dtype),
+            scratch_shapes=[transposed(tq), pltpu.VMEM((lanes, bk), k.dtype),
                             accumulator, accumulator],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             name=name % ""), *operands))
+
+    if form == "ring":
+        slots = _ring_slots(bq, bk, tq, window)
+
+        def diagonal(j):
+            return lax.div(lax.mul(j, bk), bq)
+
+        def reached(j, r):
+            # a step past the last block the keys reach, or the last
+            # there is, re-names that one: it is not fetched again
+            last = lax.div(lax.add(lax.mul(j, bk), bk + window - 2), bq)
+            return lax.min(lax.add(diagonal(j), r),
+                           lax.min(last, tq // bq - 1))
+
+        def leaving(j, r):
+            # the blocks whose diagonal ends in these keys, each while
+            # it is folded, then the last of them: written once each
+            return lax.add(diagonal(j), lax.min(r, max(bk // bq, 1) - 1))
+
+        queries, stats = call.rows(bq, reached), call.stats(bq, reached)
+        keys = call.rows(bk, held)
+        return tuple(_on_platform(functools.partial(
+            pallas_call,
+            functools.partial(_bwd_ring_kernel, stair=stair, chunks=tq // bq),
+            grid=call.steps + (tk // bk, slots),
+            in_specs=[queries, keys, keys, queries, stats, stats],
+            out_specs=[call.rows(bq, leaving), keys, keys],
+            out_shape=[like(q), like(k), like(v)],
+            scratch_shapes=[transposed(bq, slots),
+                            pltpu.VMEM((lanes, bk), k.dtype),
+                            accumulator, accumulator],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary",
+                                     "arbitrary")),
+            name=name % "_ring"), *operands))
 
     def q_walked(j, i):
         if causal:
@@ -1223,16 +1381,13 @@ def _bwd_kernels(q, k, v, do, lse, delta, *, num_heads, sm_scale, causal,
             j = lax.max(j, lax.div(lax.max(first, 0), bk))
         return j
 
-    def held(a, b):
-        return a
-
     walk = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "parallel",
                              "arbitrary"))
     queries, stats = call.rows(bq, q_walked), call.stats(bq, q_walked)
     keys = call.rows(bk, held)
     dk, dv = _on_platform(functools.partial(
-        pallas_call, _bwd_dkv_kernel,
+        pallas_call, functools.partial(_bwd_dkv_kernel, **masked),
         grid=call.steps + (tk // bk, tq // bq),
         in_specs=[queries, keys, keys, queries, stats, stats],
         out_specs=[keys, keys], out_shape=[like(k), like(v)],
@@ -1241,10 +1396,11 @@ def _bwd_kernels(q, k, v, do, lse, delta, *, num_heads, sm_scale, causal,
     queries, stats = call.rows(bq, held), call.stats(bq, held)
     keys = call.rows(bk, k_walked)
     dq = _on_platform(functools.partial(
-        pallas_call, _bwd_dq_kernel,
+        pallas_call, functools.partial(_bwd_dq_kernel, **masked),
         grid=call.steps + (tq // bq, tk // bk),
         in_specs=[queries, keys, keys, queries, stats, stats],
-        out_specs=queries, out_shape=like(q), scratch_shapes=[transposed],
+        out_specs=queries, out_shape=like(q),
+        scratch_shapes=[transposed(bq)],
         compiler_params=walk, name=name % "_dq"), *operands)
     return dq, dk, dv
 

@@ -111,8 +111,10 @@ def on_flash_attention_lowering(block_q, block_k, kv_resident,
 def on_flash_attention_bwd_lowering(kernel, block_q, block_k,
                                     heads_per_step):
     """One of the flash-attention backward kernels ("dq_dkv", the one
-    that makes all three gradients, or "dkv" and "dq", the two that
-    walk) was traced into a program, with the tiling chosen for it and
+    that makes all three gradients with a head's queries resident;
+    "ring", the one that makes them walking the keys under a window, a
+    ring of dq^T resident; or "dkv" and "dq", the two that walk) was
+    traced into a program, with the tiling chosen for it and
     the heads one grid step holds (as `on_flash_attention_lowering`
     says): one count per kernel instance a lowered program holds."""
     _reg().counter("flash_attention_bwd_lowerings_total",
@@ -299,8 +301,8 @@ def on_window_attention_lowering(kind, kv_heads, window, path, block_k,
 
 def on_flash_window_lowering(kernel, window, block_q, block_k):
     """A flash-attention kernel of the training op ("fwd", or the
-    backward's "dq_dkv", "dkv" or "dq") was traced into a program with a
-    window: every query bounded to its last `window` keys, at the
+    backward's "dq_dkv", "ring", "dkv" or "dq") was traced into a
+    program with a window: every query bounded to its last `window` keys, at the
     blocks chosen under that bound (kernels/flash_attention.py).  What
     the bound saves is in `flash_attention_pairs_total`, which counts a
     window kernel's folded and attended pairs under it.  One count per

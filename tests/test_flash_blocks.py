@@ -126,7 +126,7 @@ def test_chosen_blocks_match_dense_attention(monkeypatch, kv, tq, tk,
     assert tq // bq >= 2 and tk // bk >= 2
     assert resident == (kv == "resident")
     assert fa._choose_bwd_blocks(q.shape, k.shape, 4) \
-        == (bq, bk, resident)
+        == (bq, bk, "one" if resident else "pair")
 
     def loss(attention):
         return lambda q, k, v: jnp.sum(jnp.sin(attention(q, k, v)))
@@ -196,7 +196,9 @@ def test_backward_blocks_tile_the_sequences_within_the_budget(
         tq, tk, d, dtype, causal):
     itemsize = jnp.dtype(dtype).itemsize
     q_shape, k_shape = (8, 16, tq, d), (8, 16, tk, d)
-    bq, bk, one_kernel = fa._choose_bwd_blocks(q_shape, k_shape, itemsize)
+    bq, bk, form = fa._choose_bwd_blocks(q_shape, k_shape, itemsize)
+    one_kernel = form == "one"
+    assert form in ("one", "pair")
     assert tq % bq == 0 and tk % bk == 0
     assert bq % 128 == 0 or bq == tq
     assert bk % 128 == 0 or bk == tk
@@ -234,7 +236,7 @@ def test_bf16_gradients_agree_with_dense_float32_attention(d, tolerance):
     what dense attention gives in float32 on the same (bf16) values."""
     q, k, v = (x.astype(jnp.bfloat16) for x in _qkv(256, 256, d=d, seed=3))
     do = _qkv(256, 256, d=d, seed=4)[0].astype(jnp.bfloat16)
-    assert fa._choose_bwd_blocks(q.shape, k.shape, 2) == (256, 256, True)
+    assert fa._choose_bwd_blocks(q.shape, k.shape, 2) == (256, 256, "one")
     got = _grads(lambda q, k, v: fa.flash_attention(q, k, v, None, True),
                  q, k, v, do)
     want = _grads(
@@ -276,7 +278,7 @@ def test_a_ragged_sequence_is_one_whole_block_in_the_backward(causal):
     q, k, v = _qkv(200, 200, seed=7)
     do = _qkv(200, 200, seed=8)[0]
     assert fa._choose_bwd_blocks(q.shape, k.shape, 4) \
-        == (200, 200, True)
+        == (200, 200, "one")
     got = _grads(lambda q, k, v: fa.flash_attention(q, k, v, None, causal),
                  q, k, v, do)
     want = _grads(lambda q, k, v: fa.reference_attention(
@@ -294,7 +296,7 @@ def test_named_blocks_of_16_are_kept_by_one_kernel_and_by_two(
     q, k, v = _qkv(64, 64, seed=9)
     do = _qkv(64, 64, seed=10)[0]
     assert fa._choose_bwd_blocks(q.shape, k.shape, 4, 16, 16) \
-        == (16, 16, budget is None)
+        == (16, 16, "one" if budget is None else "pair")
     got = _grads(lambda q, k, v: fa.flash_attention(
         q, k, v, None, True, 16, 16), q, k, v, do)
     want = _grads(lambda q, k, v: fa.reference_attention(
@@ -464,7 +466,7 @@ def test_heads_side_by_side_are_the_heads_held_apart(monkeypatch, d,
     for inner, label in ((call if call.g else apart, HEADS_A_STEP[d]),
                          (apart, "apart")):
         one_kernel = fa._choose_bwd_blocks(*inner.step_shapes, 4,
-                                           heads=inner.g)[2]
+                                           heads=inner.g)[2] == "one"
         if label == "apart":
             label = 1
         else:
@@ -496,11 +498,11 @@ def test_heads_side_by_side_are_the_heads_held_apart(monkeypatch, d,
 
 @pytest.mark.parametrize("shape,heads,forward,backward,apart", [
     # gpt2m-train: 16 heads of 64, two a grid step
-    ((8, 1024, 1024), 16, (1024, 1024, True), (512, 512, True),
-     (1024, 512, True)),
+    ((8, 1024, 1024), 16, (1024, 1024, True), (512, 512, "one"),
+     (1024, 512, "one")),
     # ouro-train-4k and olmoe-train-4k: 16 heads of 128
-    ((1, 4096, 2048), 16, (1024, 512, True), (512, 256, True),
-     (512, 256, True)),
+    ((1, 4096, 2048), 16, (1024, 512, True), (512, 256, "one"),
+     (512, 256, "one")),
 ])
 def test_the_cells_tilings_are_those_of_heads_held_apart(
         shape, heads, forward, backward, apart):
@@ -659,9 +661,8 @@ def _bwd_lowerings(kernel, bq, bk, heads_per_step=1):
 def test_backward_counter_rises_once_per_kernel_and_lowering(
         shape, named, blocks, kernels):
     x = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
-    bq, bk, one_kernel = fa._choose_bwd_blocks(shape, shape, 2, named,
-                                               named)
-    assert (bq, bk) == blocks and one_kernel == (len(kernels) == 1)
+    bq, bk, form = fa._choose_bwd_blocks(shape, shape, 2, named, named)
+    assert (bq, bk) == blocks and tuple(kernels) == fa._BWD_KERNELS[form]
     before = [_bwd_lowerings(kernel, bq, bk) for kernel in kernels]
 
     def loss(q, k, v):
@@ -683,3 +684,200 @@ def test_backward_counter_rises_once_per_kernel_and_lowering(
     jax.jit(jax.grad(lambda q, k, v: 2 * loss(q, k, v))).lower(x, x, x)
     assert [_bwd_lowerings(kernel, bq, bk) for kernel in kernels] \
         == [n + 2 for n in before]
+
+
+# -- the three forms of the backward -------------------------------------------
+
+def _pallas_calls(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _pallas_calls(sub)
+
+
+def _backward_calls(shape, heads, q_offset=0, **window):
+    """The backward's `pallas_call` equations of one bfloat16 call over
+    [batch, seq, heads * dim] operands of `shape`."""
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda q, k, v: fa.flash_attention_with_lse(
+            q, k, v, None, True, None, None, q_offset, heads, **window)[0]
+        .astype(jnp.float32).sum(), argnums=(0, 1, 2)))(x, x, x)
+    return _by_name(jaxpr, "flash_attention_bwd")
+
+
+def _by_name(jaxpr, part):
+    """One equation a kernel name holding `part`, in the order traced:
+    `_on_platform` traces each kernel for Mosaic and for the
+    interpreter."""
+    calls = {}
+    for eqn in _pallas_calls(jaxpr.jaxpr):
+        if part in eqn.params["name"]:
+            calls.setdefault(eqn.params["name"], eqn)
+    return list(calls.values())
+
+
+# smallthinker-train-16k-ep8's attention: 28 heads of 128 over 16,384
+# positions, three layers of four under a window of 4096
+SMALLTHINKER = ((1, 16384, 28 * 128), 28)
+
+
+@pytest.mark.parametrize("window,blocks,names", [
+    (4096, (512, 512, "ring"),
+     ["flash_attention_bwd_ring_q512_k512_s256_w4096_h1"]),
+    (0, (1024, 512, "pair"), ["flash_attention_bwd_dkv_q1024_k512_h1",
+                              "flash_attention_bwd_dq_q1024_k512_h1"]),
+    # a window no query reaches past is no window
+    (16384, (1024, 512, "pair"), ["flash_attention_bwd_dkv_q1024_k512_h1",
+                                  "flash_attention_bwd_dq_q1024_k512_h1"]),
+])
+def test_the_long_cells_backward_takes_the_ring_under_its_window(
+        window, blocks, names):
+    """A head's 16,384 queries do not fit VMEM: under the window the one
+    kernel that walks the keys with nine slots of dq^T does, at 512 x
+    512 (the largest chunk that fits beside the ring); the full layer,
+    where every later query sees a block of keys, keeps the pair that
+    walks at the blocks it had."""
+    shape, heads = SMALLTHINKER
+    call = fa._Call.of(shape, shape, heads)
+    live = fa._live_window(window, True, call.tq, 0)
+    assert fa._choose_bwd_blocks(*call.step_shapes, 2, heads=call.g,
+                                 window=live) == blocks
+    if blocks[2] == "ring":
+        slots = fa._ring_slots(512, 512, call.tq, window)
+        assert slots == 9
+        assert fa._bwd_step_bytes(512, 512, 128, 2, ring=slots) \
+            <= fa._VMEM_BUDGET < fa._bwd_step_bytes(1024, 512, 128, 2,
+                                                    ring=5)
+        assert fa._bwd_step_bytes(128, 128, 128, 2, call.tq) \
+            > fa._VMEM_BUDGET
+    calls = _backward_calls(shape, heads, window=window)
+    assert sorted(eqn.params["name"] for eqn in calls) == names
+    if blocks[2] == "ring":
+        # a grid as long as the work: 32 blocks of keys, 9 of queries
+        # each, where the pair's two grids are 16 x 32 and 32 x 16
+        assert calls[0].params["grid_mapping"].grid == (1, 28, 32, 9)
+
+
+@pytest.mark.parametrize("cell,shape,heads,blocks,name", [
+    ("gpt2m-train", (8, 1024, 1024), 16, (512, 512),
+     "flash_attention_bwd_q512_k512_s256_h2"),
+    ("ouro-train-4k", (1, 4096, 2048), 16, (512, 256),
+     "flash_attention_bwd_q512_k256_s256_h1"),
+    ("olmoe-train-4k", (1, 4096, 2048), 16, (512, 256),
+     "flash_attention_bwd_q512_k256_s256_h1"),
+    ("granite-train-4k", (1, 4096, 2048), 32, (512, 256),
+     "flash_attention_bwd_q512_k256_s256_h2"),
+])
+def test_the_other_cells_keep_the_one_kernel_at_their_blocks(
+        cell, shape, heads, blocks, name):
+    """The four other cells that train through these kernels: a head's
+    queries fit, so the one kernel, under the name the ledger's
+    `device_ops` hold (PR 50), whatever else the chooser has learned."""
+    call = fa._Call.of(shape, shape, heads)
+    assert fa._choose_bwd_blocks(*call.step_shapes, 2, heads=call.g) \
+        == blocks + ("one",)
+    calls = _backward_calls(shape, heads)
+    assert [eqn.params["name"] for eqn in calls] == [name]
+    assert calls[0].params["grid_mapping"].grid \
+        == (shape[0], heads // call.g, shape[1] // blocks[1])
+
+
+# what the ring kernel does not take, at the long cell's step shapes
+# unless a row says otherwise: (q rows, k rows, named blocks, window,
+# q_offset)
+DECLINED = {
+    "no window": (16384, 16384, None, 0, 0),
+    "a shard's offset": (16384, 16384, None, 4096, 512),
+    "more keys than queries": (8192, 16384, None, 4096, 0),
+    "a block of no whole lane blocks": (400, 400, (200, 200), 100, 0),
+    "blocks neither a multiple of the other": (1536, 1536, (256, 384),
+                                               300, 0),
+}
+
+
+@pytest.mark.parametrize("why", sorted(DECLINED))
+def test_calls_the_ring_declines_fall_back_to_the_pair(monkeypatch, why):
+    tq, tk, named, window, q_offset = DECLINED[why]
+    bq, bk = named or (None, None)
+    if named:
+        # room for the ring these blocks would ask for, none for the head
+        monkeypatch.setattr(fa, "_VMEM_BUDGET", fa._bwd_step_bytes(
+            bq, bk, 128, 2, ring=fa._ring_slots(bq, bk, tq, window)))
+    chosen = fa._choose_bwd_blocks((1, 28, tq, 128), (1, 28, tk, 128), 2,
+                                   bq, bk, 1, window, q_offset)
+    assert chosen[2] == "pair"
+    if named:
+        assert chosen[:2] == named
+
+
+def test_no_room_for_the_ring_falls_back_to_the_pair(monkeypatch):
+    """A budget that holds the pair's smallest blocks and no ring beside
+    a chunk: the pair, and its gradients are the ring's."""
+    shape = (1, 16384, 128)
+    call = fa._Call.of(shape, shape, 1)
+    monkeypatch.setattr(fa, "_VMEM_BUDGET",
+                        fa._bwd_step_bytes(128, 128, 128, 2))
+    assert fa._choose_bwd_blocks(*call.step_shapes, 2, window=4096) \
+        == (128, 128, "pair")
+    assert [eqn.params["name"] for eqn in _backward_calls(
+        shape, 1, window=4096)] == [
+            "flash_attention_bwd_dkv_q128_k128_w4096_h1",
+            "flash_attention_bwd_dq_q128_k128_w4096_h1"]
+
+
+def test_an_offset_call_under_a_window_traces_the_pair():
+    """The chooser reads `q_offset` where `_bwd` hands it over: a
+    sequence shard's backward under a window keeps the pair."""
+    shape, heads = SMALLTHINKER
+    names = [eqn.params["name"] for eqn in _backward_calls(
+        shape, heads, q_offset=1024, window=4096)]
+    assert names == ["flash_attention_bwd_dkv_q1024_k512_w4096_h1",
+                     "flash_attention_bwd_dq_q1024_k512_w4096_h1"]
+
+
+def _padded_bytes(shape, dtype):
+    """Bytes of a VMEM array: the minor dimension padded to 128 lanes,
+    a float32 statistic's rows to 8 sublanes."""
+    shape = tuple(shape[:-1]) + (-(-shape[-1] // 128) * 128,)
+    if len(shape) == 2 and shape[0] < 8:
+        shape = (8, shape[1])
+    return int(np.prod(shape)) * jnp.dtype(dtype).itemsize
+
+
+@pytest.mark.parametrize("shape,heads,dtype,bq,bk,window", [
+    (SMALLTHINKER[0], 28, jnp.bfloat16, 512, 512, 4096),
+    ((1, 2048, 4 * 64), 4, jnp.float32, 256, 128, 300),
+    ((1, 2, 2048, 64), None, jnp.float32, 128, 256, 200),
+])
+def test_ring_step_bytes_are_what_the_call_declares(shape, heads, dtype, bq,
+                                                    bk, window):
+    """`_bwd_step_bytes(ring=)` less its chunk is the tiles the
+    `pallas_call` declares, double-buffered, and its scratch: q, do and
+    dq [bq, lanes], K, V, dk and dv [bk, lanes], lse and delta rows, the
+    ring's slots, K transposed and two accumulators a head."""
+    call = fa._Call.of(shape, shape, heads)
+    x = jax.ShapeDtypeStruct(shape, dtype)
+    rows = jax.ShapeDtypeStruct((call.batch, call.heads, call.tq),
+                                jnp.float32)
+    jaxpr = jax.make_jaxpr(lambda *operands: fa._bwd_kernels(
+        *operands, num_heads=heads, sm_scale=1.0, causal=True, q_offset=0,
+        bq=bq, bk=bk, form="ring", window=window))(x, x, x, x, rows, rows)
+    eqn, = _by_name(jaxpr, "_ring_")
+    mapping = eqn.params["grid_mapping"]
+    slots = fa._ring_slots(bq, bk, call.tq, window)
+    assert mapping.grid == call.steps + (call.tk // bk, slots)
+    tiles = sum(
+        2 * _padded_bytes([n.block_size for n in block.block_shape
+                           if hasattr(n, "block_size")],
+                          block.array_aval.dtype)
+        for block in mapping.block_mappings)
+    scratch = [v.aval for v in
+               eqn.params["jaxpr"].invars[-mapping.num_scratch_operands:]]
+    assert scratch[0].shape == (slots, 128 * -(-call.lanes // 128), bq)
+    itemsize = jnp.dtype(dtype).itemsize
+    declared = tiles + sum(_padded_bytes(a.shape, a.dtype) for a in scratch)
+    assert declared == fa._bwd_step_bytes(
+        bq, bk, call.lanes, itemsize, None, call.g, slots) \
+        - bq * bk * (4 * 4 + 2 * itemsize)

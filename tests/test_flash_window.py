@@ -1,7 +1,9 @@
 """The window bound of the flash-attention kernels
 (kernels/flash_attention.py): a causal query folds its last `window`
-keys alone, in the forward kernel and in both forms of the backward,
-against plain masked attention; chunks wholly outside the window are
+keys alone, in the forward kernel and in all three forms of the
+backward (the one kernel that holds a head's queries, the one that walks
+the keys with a ring of dq^T, the pair that walks), against plain masked
+attention; chunks wholly outside the window are
 not folded, and `score_pairs`, the counter it feeds and the kernels'
 names say so; with no window nothing of it is traced.
 """
@@ -50,27 +52,44 @@ def _walking_budget(monkeypatch, lanes, heads_a_step):
         128, 128, lanes, 4, None, heads_a_step))
 
 
+def _ring_budget(monkeypatch, lanes, heads_a_step, t, window, itemsize=4,
+                 bq=128, bk=128):
+    """Room for the ring kernel's (bq, bk) blocks with the slots `window`
+    asks for, which is more than the walking pair needs and less than a
+    grid step's whole sequence does."""
+    monkeypatch.setattr(fa, "_VMEM_BUDGET", fa._bwd_step_bytes(
+        bq, bk, lanes, itemsize, None, heads_a_step,
+        fa._ring_slots(bq, bk, t, window)))
+
+
 # window < / = / > the sequence, one that is no multiple of a block, one
 # inside a single block, one key
 WINDOWS = [1, 100, 128, 200, 384, 512, 600]
 
 
-@pytest.mark.parametrize("kernels", ["one", "walking"])
+@pytest.mark.parametrize("kernels", ["one", "walking", "ring"])
 @pytest.mark.parametrize("d", [64, 128])
 @pytest.mark.parametrize("window", WINDOWS)
 def test_window_kernels_are_plain_masked_attention(monkeypatch, window, d,
                                                    kernels):
     """Values and all three gradients, 64- and 128-wide heads side by
-    side, through the backward kernel that holds a head's queries and
-    through the pair that walks."""
+    side, through the backward kernel that holds a head's queries,
+    through the pair that walks and through the kernel that walks the
+    keys with a ring of dq^T (where the budget has room for the ring and
+    not for the whole head; a window no query reaches past leaves that
+    budget the pair)."""
     heads, t = 2, 512
     q, k, v, do = _operands(t, heads, d, seed=window + d)
     call = fa._Call.of(q.shape, k.shape, heads)
     if kernels == "walking":
         _walking_budget(monkeypatch, call.lanes, call.g)
+    elif kernels == "ring":
+        _ring_budget(monkeypatch, call.lanes, call.g, t, window)
     live = window if window < t else 0
+    form = {"one": "one", "walking": "pair",
+            "ring": "ring" if live else "pair"}[kernels]
     assert fa._choose_bwd_blocks(*call.step_shapes, 4, heads=call.g,
-                                 window=live)[2] == (kernels == "one")
+                                 window=live)[2] == form
 
     def flash(q, k, v):
         return fa.flash_attention_with_lse(q, k, v, None, True,
@@ -94,9 +113,76 @@ def test_window_kernels_are_plain_masked_attention(monkeypatch, window, d,
     if not live:
         assert not rose
     else:
-        assert names == (["dq_dkv", "fwd"] if kernels == "one"
-                         else ["dkv", "dq", "fwd"])
+        assert names == sorted(fa._BWD_KERNELS[form] + ("fwd",))
         assert all("window=%d}" % window in key for key in rose)
+
+
+def _saved(q, k, v, do, heads, window):
+    """What the backward's kernels take beside q, k, v and do: the
+    forward's log-sum-exp and the row sums of do * o."""
+    o, lse = fa.flash_attention_with_lse(q, k, v, None, True,
+                                         num_heads=heads, window=window)
+    return lse, fa.row_sums(do, o, heads)
+
+
+# (t, window, bq, bk): sequences several rings long, t >= 4 * (window +
+# block), so that every slot is taken again and the last blocks of keys
+# have steps past the sequence's end; blocks of one size, queries' the
+# wider (a block of keys starts inside one of queries), keys' the wider
+# (several blocks of queries leave at one block of keys); a window inside
+# a block, of one key, and as long as the sequence less one
+RINGS = [(1024, 100, 128, 128), (1024, 128, 128, 128), (2048, 200, 256, 128),
+         (3072, 129, 128, 384), (1536, 1, 128, 128), (2048, 300, 512, 128),
+         (512, 511, 128, 256)]
+
+
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("t,window,bq,bk", RINGS)
+def test_the_ring_gives_the_walking_pairs_gradients(t, window, bq, bk, d):
+    """dq, dk and dv of the kernel that walks the keys with a ring of
+    dq^T against the walking pair's at the same blocks on the same
+    operands, within float32 rounding (the same products, summed in
+    another order), and against plain masked attention's."""
+    heads = 2
+    q, k, v, do = _operands(t, heads, d, seed=t + window)
+    lse, delta = _saved(q, k, v, do, heads, window)
+    slots = fa._ring_slots(bq, bk, t, window)
+    assert slots < t // bq or window == t - 1
+
+    def grads(form):
+        return fa._bwd_kernels(
+            q, k, v, do, lse, delta, num_heads=heads, sm_scale=d ** -0.5,
+            causal=True, q_offset=0, bq=bq, bk=bk, form=form, window=window)
+
+    ring = grads("ring")
+    for got, pair, name in zip(ring, grads("pair"), "qkv"):
+        np.testing.assert_allclose(got, pair, atol=2e-6, rtol=2e-6,
+                                   err_msg="d" + name)
+    want = jax.vjp(lambda q, k, v: _plain(q, k, v, heads, window),
+                   q, k, v)[1](do)
+    for got, ref, name in zip(ring, want, "qkv"):
+        np.testing.assert_allclose(got, ref, atol=5e-5, rtol=5e-5,
+                                   err_msg="d" + name)
+
+
+def test_the_ring_rounds_as_the_walking_pair_does():
+    """bfloat16 operands: p and ds rounded to the operands' type before
+    their second products and float32 sums, in the ring kernel as in the
+    pair, so the two agree to a rounding of the result's type."""
+    heads, t, d, window = 2, 1024, 128, 200
+    q, k, v, do = (x.astype(jnp.bfloat16)
+                   for x in _operands(t, heads, d, seed=17))
+    lse, delta = _saved(q, k, v, do, heads, window)
+    ring, pair = (fa._bwd_kernels(
+        q, k, v, do, lse, delta, num_heads=heads, sm_scale=d ** -0.5,
+        causal=True, q_offset=0, bq=128, bk=128, form=form, window=window)
+        for form in ("ring", "pair"))
+    for got, ref in zip(ring, pair):
+        assert got.dtype == jnp.bfloat16
+        scale = float(jnp.max(jnp.abs(ref.astype(jnp.float32))))
+        np.testing.assert_allclose(got.astype(jnp.float32),
+                                   ref.astype(jnp.float32),
+                                   atol=2 ** -7 * scale)
 
 
 @pytest.mark.parametrize("window,bq,bk", [(100, 128, 128), (300, 256, 128),
@@ -120,6 +206,51 @@ def test_window_with_heads_held_apart_and_named_blocks(window, bq, bk):
     np.testing.assert_allclose(out, want, atol=2e-5, rtol=2e-5)
     for got, ref in zip(vjp(do), want_vjp(do)):
         np.testing.assert_allclose(got, ref, atol=5e-5, rtol=5e-5)
+
+
+@pytest.mark.parametrize("window,bq,bk", [(100, 128, 128), (300, 256, 128),
+                                          (200, 128, 256), (384, 128, 128)])
+def test_ring_with_heads_held_apart_and_named_blocks(monkeypatch, window, bq,
+                                                     bk):
+    """[batch, heads, seq, dim] operands (64 lanes a head, the scratch
+    padded to 128) at blocks the caller names, where the budget has room
+    for the ring: the kernel's name and both window counters' label say
+    which form ran."""
+    t = 1024
+    rs = np.random.RandomState(window)
+    q, k, v, do = (jnp.asarray(0.5 * rs.randn(1, 2, t, 64), jnp.float32)
+                   for _ in range(4))
+    _ring_budget(monkeypatch, 64, 1, t, window, bq=bq, bk=bk)
+    assert fa._choose_bwd_blocks(q.shape, k.shape, 4, bq, bk,
+                                 window=window) == (bq, bk, "ring")
+
+    def flash(q, k, v):
+        return fa.flash_attention(q, k, v, None, True, bq, bk, 0, window)
+
+    def plain(q, k, v):
+        return fa.split_heads(_plain(*(fa.merge_heads(x) for x in (q, k, v)),
+                                     2, window), 2)
+
+    before = telemetry.snapshot()
+    out, vjp = jax.vjp(flash, q, k, v)
+    want, want_vjp = jax.vjp(plain, q, k, v)
+    np.testing.assert_allclose(out, want, atol=2e-5, rtol=2e-5)
+    for got, ref in zip(vjp(do), want_vjp(do)):
+        np.testing.assert_allclose(got, ref, atol=5e-5, rtol=5e-5)
+    after = telemetry.snapshot()
+    for counter, labels in (
+            ("flash_attention_window_lowerings_total",
+             "block_k=%d,block_q=%d,kernel=ring,window=%d"
+             % (bk, bq, window)),
+            ("flash_attention_bwd_lowerings_total",
+             "block_k=%d,block_q=%d,heads_per_step=1,kernel=ring"
+             % (bk, bq))):
+        key = "%s{%s}" % (counter, labels)
+        assert after[key] - before.get(key, 0) == 1, key
+    names = _kernel_names(lambda q, k, v: jax.vjp(flash, q, k, v)[1](do),
+                          q, k, v)
+    assert [n for n in names if "_bwd" in n] == [
+        "flash_attention_bwd_ring_q%d_k%d_s128_w%d" % (bq, bk, window)]
 
 
 def _kernel_names(fn, *args):
@@ -247,6 +378,32 @@ def test_pairs_counter_counts_under_the_window():
             key = "flash_attention_pairs_total{kind=%s,pass=%s}" \
                 % (kind, kernel_pass)
             assert after[key] - before.get(key, 0) == heads * n, key
+
+
+def test_pairs_counter_counts_the_rings_grid(monkeypatch):
+    """The ring kernel folds the chunks the one kernel folds, the
+    diagonal's as a staircase and the lower edge's whole, and
+    `flash_attention_pairs_total` says so: fewer than the walking pair
+    folds at the same blocks, which folds every crossed chunk whole."""
+    heads, t, window = 2, 2048, 600
+    q, k, v, do = _operands(t, heads, 128, seed=6)
+    call = fa._Call.of(q.shape, k.shape, heads)
+    _ring_budget(monkeypatch, call.lanes, call.g, t, window, bq=512, bk=512)
+    # named: smaller blocks would leave the one kernel room
+    assert fa._choose_bwd_blocks(*call.step_shapes, 4, 512, 512, call.g,
+                                 window) == (512, 512, "ring")
+    folded, attended = fa.score_pairs(t, t, True, 0, 512, 512, fa._STAIR,
+                                      window)
+    assert attended < folded \
+        < fa.score_pairs(t, t, True, 0, 512, 512, None, window)[0]
+    before = telemetry.snapshot()
+    _, vjp = jax.vjp(lambda q, k, v: fa.flash_attention_with_lse(
+        q, k, v, None, True, 512, 512, 0, heads, window)[0], q, k, v)
+    vjp(do)
+    after = telemetry.snapshot()
+    for kind, n in (("folded", folded), ("attended", attended)):
+        key = "flash_attention_pairs_total{kind=%s,pass=bwd}" % kind
+        assert after[key] - before.get(key, 0) == heads * n, key
 
 
 def test_flash_attention_op_takes_a_window():
