@@ -1,0 +1,33 @@
+"""Device milliseconds a traced generation call's prefill spends under
+the `gated_delta_rule` op, every linear layer: the block form
+(`gdn_chunks`: the chunks' products, the triangular solve and the walk
+over the chunks' states) with the l2 norms (`gdn_gates`).  First device,
+inside the `decode_prefill` scope's interval before the scan of steps, a
+call.  Prints the op's scopes apart."""
+
+from benchmark.reduce import state_ops
+
+LAYER = "ops"
+MOVES = "decode_tok_per_s"
+UNIT = "ms"
+SOURCE = "device_trace"
+OP_TYPE = "gated_delta_rule"
+SCOPES = ("gdn_gates", "gdn_chunks", "gdn_state")
+
+
+def scope(kind, instance, inner):
+    if kind != OP_TYPE:
+        return None
+    named = [p for p in inner if p in SCOPES]
+    return named[0] if named else "(no scope)"
+
+
+def read(run):
+    found = state_ops.prefill_seconds(run, scope)
+    if not found:
+        return None
+    print("%s inside the prefill, device ms a call: %s"
+          % (OP_TYPE, ", ".join("%s %.3f" % (name, s * 1e3)
+                                for name, s in sorted(found.items()))),
+          flush=True)
+    return sum(found.values()) * 1e3

@@ -30,7 +30,7 @@ __all__ = [
     "sequence_reverse", "sequence_unnest", "sequence_renest",
     "flash_attention", "cached_attention", "mla_cached_attention",
     "mla_index_select", "rms_norm", "rope", "moe",
-    "ssd_scan", "causal_conv1d", "expand", "slice", "cumsum",
+    "ssd_scan", "causal_conv1d", "gated_delta_rule", "expand", "gather", "slice", "cumsum",
 ]
 
 
@@ -917,11 +917,17 @@ def ssd_scan(x, dt, b, c, num_heads, chunk_size=256, a_log_attr=None,
 
 
 def causal_conv1d(input, filter_size=4, activation="silu", param_attr=None,
-                  bias_attr=None, name=None):
+                  bias_attr=None, name=None, tail=None):
     """Causal depthwise convolution over the sequence axis of `input`
     [batch, seq, channels] (ops/ssm.py causal_conv1d): out_t =
     act(bias + sum_j filter[:, j] x_{t-(filter_size-1)+j}), zeros before
-    position 0, each channel by itself; `activation` "silu" or None."""
+    position 0, each channel by itself; `activation` "silu" or None.
+
+    With `tail` [batch, filter_size - 1, channels], the positions before
+    the block, position 0 reads them in place of zeros and the layer
+    returns (out, tail_out): thread `tail_out` back as decode state
+    (`fluid.ProgramDecoder` state pairs).  `bias_attr=False`, beside a
+    tail alone: no bias."""
     helper = LayerHelper("causal_conv1d", name=name)
     channels = int(input.shape[-1])
     filt = helper.create_parameter(
@@ -929,14 +935,60 @@ def causal_conv1d(input, filter_size=4, activation="silu", param_attr=None,
         dtype=input.dtype,
         default_initializer=Xavier(fan_in=filter_size,
                                    fan_out=filter_size))
-    bias = helper.create_parameter(
-        bias_attr or ParamAttr(), shape=[channels], dtype=input.dtype,
-        default_initializer=Constant(0.0))
+    inputs = {"X": [input], "Filter": [filt]}
+    if bias_attr is not False:
+        inputs["Bias"] = [helper.create_parameter(
+            bias_attr or ParamAttr(), shape=[channels], dtype=input.dtype,
+            default_initializer=Constant(0.0))]
+    elif tail is None:
+        raise ValueError("causal_conv1d: bias_attr=False is a cached "
+                         "step's (beside `tail`)")
     out = helper.create_tmp_variable(input.dtype)
+    outputs = {"Out": [out]}
+    if tail is not None:
+        inputs["Tail"] = [tail]
+        outputs["TailOut"] = [helper.create_tmp_variable(tail.dtype)]
     helper.append_op(
-        type="causal_conv1d",
-        inputs={"X": [input], "Filter": [filt], "Bias": [bias]},
-        outputs={"Out": [out]}, attrs={"activation": activation or ""})
+        type="causal_conv1d", inputs=inputs, outputs=outputs,
+        attrs={"activation": activation or ""})
+    return out if tail is None else (out, outputs["TailOut"][0])
+
+
+def gated_delta_rule(q, k, v, g, beta, state, qk_l2norm=True, chunk=64,
+                     name=None):
+    """The gated delta rule over a block of T >= 1 consecutive positions
+    of every row, through a recurrent state (ops/linear_attention.py
+    gated_delta_rule; T = 1 is a decode step): `q`, `k` [batch, T, key
+    heads * key_dim], `v` [batch, T, value heads * value_dim], `g` (the
+    log of a decay, <= 0) and `beta` [batch, T, value heads] float32,
+    `state` [batch, value heads, key_dim, value_dim] float32.  Per value
+    head, position by position: S = exp(g) S; S += k (beta (v - S^T
+    k))^T; o = S^T q, with q and k l2-normed a head and q scaled by
+    key_dim ** -0.5 under `qk_l2norm`; value head j reads key head j //
+    (value heads / key heads).  T may be left open (-1) in the Program.
+    Returns (out [batch, T, value heads * value_dim], state_out): thread
+    `state_out` back as decode state (`fluid.ProgramDecoder` state
+    pairs).  Forward only."""
+    helper = LayerHelper("gated_delta_rule", name=name)
+    out = helper.create_tmp_variable(v.dtype)
+    state_out = helper.create_tmp_variable(state.dtype)
+    helper.append_op(
+        type="gated_delta_rule",
+        inputs={"Q": [q], "K": [k], "V": [v], "G": [g], "Beta": [beta],
+                "State": [state]},
+        outputs={"Out": [out], "StateOut": [state_out]},
+        attrs={"qk_l2norm": bool(qk_l2norm), "chunk": int(chunk)})
+    return out, state_out
+
+
+def gather(input, index, **kwargs):
+    """The rows of `input` [rows, ...] that `index` (int, any shape, read
+    flat) names, in its order (the `gather` op; reference:
+    gather_op.cc)."""
+    helper = LayerHelper("gather", **kwargs)
+    out = helper.create_tmp_variable(input.dtype)
+    helper.append_op(type="gather", inputs={"X": [input], "Index": [index]},
+                     outputs={"Out": [out]})
     return out
 
 

@@ -80,8 +80,8 @@ overlap of its blocks' copies with the folds, 0.22 ms a call.)  Where
 the time goes, and the sweep these sizes came from:
 `scripts/gqa_decode_bench.py`, PERF.md section 5.
 
-Which shapes it takes (`fits`): S a multiple of 128; heads 128 wide (the
-lanes) with G * T rows a key/value head small enough that their scores
+Which shapes it takes (`fits`): S a multiple of 128; heads a multiple of
+128 wide (the lanes: one lane block a head, or two at 256) with G * T rows a key/value head small enough that their scores
 over the smallest block of slots fit VMEM beside the operands
 (`_vmem_bytes`), or heads 64 wide with one row a key/value head (T = 1,
 no grouping, no ring).  The op asks and falls back to its plain path; a
@@ -91,7 +91,8 @@ first at 128 wide, and keeps the plain path at 64.
 Lowered for the TPU these are Mosaic kernels named
 `gqa_decode_k<block_k>` over a whole-extent cache,
 `gqa_decode_k<block_k>_t<T>` where T > 1 (a trace tells a prefill
-block's calls from a decode step's), `gqa_decode_w<window>` over a ring,
+block's calls from a decode step's; `_d<head_dim>` after either where a
+head is wider than the lanes), `gqa_decode_w<window>` over a ring,
 and at 64 wide `gqa_decode_k<block_k>_h<heads>` (`_r<rows>` after it
 where rows share a step) and `gqa_write_r<rows>`; lowered for the CPU
 the same kernels run under the Pallas interpreter (tests), chosen by the
@@ -124,15 +125,15 @@ _NARROW_STEP_BYTES = 1 << 20
 _VMEM_BYTES = 12 << 20
 
 
-def _vmem_bytes(rows, bk, itemsize):
-    """The bytes a grid step of `rows` resident queries over a block of
-    `bk` slots holds in VMEM: queries and output (double-buffered), the
-    running maximum and sum (a column each, padded to the lanes) and the
-    accumulator in float32; the block's keys and values
-    (double-buffered); the scores in float32, the probabilities in
-    their place, and the probabilities rounded."""
-    resident = rows * _LANES * (4 * itemsize + 3 * 4)
-    blocks = 4 * bk * _LANES * itemsize
+def _vmem_bytes(rows, bk, itemsize, dim=_LANES):
+    """The bytes a grid step of `rows` resident queries of `dim` values
+    over a block of `bk` slots holds in VMEM: queries and output
+    (double-buffered) and the accumulator in float32, the running
+    maximum and sum (a column each, padded to the lanes); the block's
+    keys and values (double-buffered); the scores in float32, the
+    probabilities in their place, and the probabilities rounded."""
+    resident = rows * (dim * (4 * itemsize + 4) + _LANES * 2 * 4)
+    blocks = 4 * bk * dim * itemsize
     scores = rows * bk * (4 + itemsize)
     return resident + blocks + scores
 
@@ -149,14 +150,18 @@ def choose_block(slots, rows=8, itemsize=2, head_dim=_LANES):
     step costs 3.7 us whatever it folds up to 1024 slots).  64-wide
     heads, one query a head: the largest of `_NARROW_BLOCKS` that tiles
     the extent (`choose_step` says how many heads and rows share the
-    step)."""
+    step).  A head of several lane blocks (Qwen3-Next's 256) is the
+    128-wide kernel with wider blocks, and its resident queries and
+    accumulator weigh twice as much: in bfloat16 a group of 8 at 128
+    positions still takes 1024 slots a step, in float32 512."""
     if head_dim == _NARROW:
         return next((bk for bk in _NARROW_BLOCKS if slots % bk == 0), 0) \
             if rows == 1 else 0
-    if head_dim != _LANES:
+    if head_dim % _LANES:
         return 0
     for bk in _BLOCKS:
-        if slots % bk == 0 and _vmem_bytes(rows, bk, itemsize) <= _VMEM_BYTES:
+        if slots % bk == 0 \
+                and _vmem_bytes(rows, bk, itemsize, head_dim) <= _VMEM_BYTES:
             return bk
     return 0
 
@@ -553,5 +558,7 @@ def gqa_decode(q, k_cache, v_cache, last, sm_scale, window=0, block_k=None,
     name = "gqa_decode_w%d" % window if window else "gqa_decode_k%d" % bk
     if positions > 1:
         name += "_t%d" % positions
+    if q.shape[3] != _LANES:
+        name += "_d%d" % q.shape[3]
     return _wide(q, k_cache, v_cache, last, sm_scale=float(sm_scale), bk=bk,
                  positions=positions, name=name)

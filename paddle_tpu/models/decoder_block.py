@@ -101,12 +101,19 @@ def gated_feed_forward(u, width, names):
 
 def share_feed_forward(u, block, dense, d_ff, d_expert, n_experts, held,
                        top_k, norm_topk, routed_scale, router_bias=False,
-                       n_group=0, topk_group=0):
-    """The feed-forward half of one layer of one chip's share of a
-    sigmoid-routed expert model (the DeepSeek-V3 family's, which
-    openPangu-Ultra-MoE, DeepSeek-V3.2 and K-EXAONE carry to the
-    number), for u [batch, seq, hidden], already normed; `block` names
-    the layer's parameters.  Returns (F(u), routing).
+                       n_group=0, topk_group=0, scoring="sigmoid",
+                       shared_gate=None):
+    """The feed-forward half of one layer of one chip's share of an
+    expert model, for u [batch, seq, hidden], already normed; `block`
+    names the layer's parameters.  Returns (F(u), routing).  The
+    defaults are the sigmoid-routed layer of the DeepSeek-V3 family
+    (which openPangu-Ultra-MoE, DeepSeek-V3.2 and K-EXAONE carry to the
+    number); `scoring="softmax"` routes by a softmax over all
+    `n_experts` scored (Qwen3-Next's: the chosen probabilities divided
+    by their sum under `norm_topk`, over the held range as over the
+    whole), and `shared_gate` names a [hidden, 1] parameter whose
+    sigmoid(u w) multiplies the shared expert's output (a gate on the
+    shared expert, one scalar a token).
 
     `dense`: the gated feed-forward of width `d_ff` (`ffn_in`,
     `ffn_out`), and `routing` is None.  Otherwise a shared expert of
@@ -125,14 +132,21 @@ def share_feed_forward(u, block, dense, d_ff, d_expert, n_experts, held,
         u, n_experts, d_expert, top_k,
         *(ParamAttr(name=block[w])
           for w in ("router", "w_gate", "w_up", "w_down")),
-        scoring="sigmoid", norm_topk=norm_topk, scale=routed_scale,
+        scoring=scoring, norm_topk=norm_topk, scale=routed_scale,
         held=held,
         bias_attr=ParamAttr(name=block["router_bias"])
         if router_bias else None,
         n_group=n_group, topk_group=topk_group)
-    f = gated_feed_forward(
+    shared = gated_feed_forward(
         u, d_expert, {"w_in": block["shared_in"],
-                      "w_out": block["shared_out"]}) + m
+                      "w_out": block["shared_out"]})
+    if shared_gate is not None:
+        # named: the ops' instances in a trace start with it
+        shared = fluid.layers.elementwise_mul(
+            shared, fluid.layers.sigmoid(linear(u, 1, shared_gate),
+                                         name="shared_gate"),
+            name="shared_gate")
+    f = shared + m
     return f, dict({key: routing[key]
                     for key in ("top_w", "top_idx", "counts")},
                    moe_in=u, moe_out=m)

@@ -45,7 +45,8 @@ __all__ = ["on_executor_run", "on_jit_trace",
            "on_window_attention_lowering", "on_flash_window_lowering",
            "on_moe_share_bwd_lowering", "on_moe_share_compact_lowering",
            "on_prefill_lowering", "on_ssd_lowering",
-           "on_causal_conv1d_lowering", "on_shared_parameter_uses",
+           "on_causal_conv1d_lowering", "on_causal_conv1d_tail_lowering",
+           "on_gated_delta_rule_lowering", "on_shared_parameter_uses",
            "on_transfer",
            "on_feed_seconds", "on_decoder_call", "on_program_cache_evict",
            "jit_trace_count", "transfer_bytes", "step", "set_gauge",
@@ -380,6 +381,46 @@ def on_causal_conv1d_lowering(width, activation):
                    "activation",
                    labelnames=("width", "activation")) \
           .labels(width=width, activation=activation).inc()
+
+
+def _recurrent_state_bytes(kind, row_bytes):
+    _reg().counter("recurrent_state_bytes_total",
+                   "bytes of recurrent state a row holds in the lowered "
+                   "ops that take a state in and hand it on, by kind (a "
+                   "delta rule's state, a convolution's tail)",
+                   labelnames=("kind",)).labels(kind=kind).inc(row_bytes)
+
+
+def on_causal_conv1d_tail_lowering(width, row_bytes):
+    """Beside `on_causal_conv1d_lowering`, for a convolution that is
+    handed the `width - 1` positions before its block and hands on its
+    own (a cached step's; `row_bytes` a row's tail): one count per op
+    instance a lowered program holds.  A convolution that starts from
+    zeros counts nothing here."""
+    _reg().counter("causal_conv1d_tail_lowerings_total",
+                   "causal depthwise convolutions lowered that carry the "
+                   "tail of the positions before their block, by width",
+                   labelnames=("width",)).labels(width=width).inc()
+    _recurrent_state_bytes("conv_tail", row_bytes)
+
+
+def on_gated_delta_rule_lowering(form, path, chunk, heads, state_dtype,
+                                 row_bytes):
+    """A `gated_delta_rule` op (ops/linear_attention.py) was traced into
+    a program: in which form ("step": one position, the state read and
+    written once; "block": chunks of `chunk` positions), which way
+    ("kernel": kernels/gdn_step.py; "plain": `jax.numpy`), over how many
+    value heads and a state of which type; `row_bytes` the state a row
+    holds.  One count per op instance a lowered program holds."""
+    _reg().counter("gated_delta_rule_lowerings_total",
+                   "gated delta rule ops lowered, by form (a step or a "
+                   "block of chunks), path (the step kernel or plain "
+                   "products), chunk, value heads and the state's type",
+                   labelnames=("form", "path", "chunk", "heads",
+                               "state_dtype")) \
+          .labels(form=form, path=path, chunk=chunk, heads=heads,
+                  state_dtype=str(state_dtype)).inc()
+    _recurrent_state_bytes("delta", row_bytes)
 
 
 def on_shared_parameter_uses(program, uses):

@@ -24,6 +24,7 @@ from . import nn            # noqa: F401
 from . import attention     # noqa: F401
 from . import moe           # noqa: F401
 from . import ssm           # noqa: F401
+from . import linear_attention  # noqa: F401
 from . import sequence      # noqa: F401
 from . import control_flow  # noqa: F401
 from . import crf           # noqa: F401

@@ -295,8 +295,9 @@ def cached_attention_op(ctx, ins, attrs):
     single steps leave there.
 
     Scopes: `kv_write` the caches' update, `attn_window` or `attn_full`
-    everything between the caches and Out.  Over 128-wide heads and a
-    multiple of 128 slots the live slots alone are walked
+    everything between the caches and Out.  Over heads a multiple of 128
+    wide (128; 256, two lane blocks a head) and a multiple of 128 slots
+    the live slots alone are walked
     (kernels/gqa_decode.py: operands in Q's type, float32 sums and
     softmax): a step over either kind of cache, and a block over a
     whole extent where its group's T queries a key/value head fit the
@@ -352,9 +353,11 @@ def cached_attention_op(ctx, ins, attrs):
     # the kernel reads as they lie; a cache in another type keeps the
     # plain path, where reading it up would write a copy of it a layer a
     # step
+    # (the kernel's widths: 64, and the multiples of the 128 lanes: 128,
+    # and 256 as two lane blocks a head)
     block_k = 0
-    if head_dim in (64, 128) and not ring_block and (
-            head_dim == 128 or not window
+    if (head_dim == 64 or head_dim % 128 == 0) and not ring_block and (
+            head_dim != 64 or not window
             and k_cache.dtype == v_cache.dtype == q.dtype):
         from ..kernels import gqa_decode
         block_k = gqa_decode.choose_block(extent, group * block,

@@ -1,0 +1,252 @@
+"""Linear attention with a recurrent state a cached step takes in and
+hands on: the gated delta rule (Gated DeltaNet, arXiv:2412.06464, as
+Qwen3-Next's `linear_attention` layers run it).
+
+One state `S` [key_dim, value_dim] float32 a row and value head, walked
+position by position:
+
+    S   = exp(g_t) S                      (the gate: a decay in (0, 1])
+    r   = S^T k_t                         (what the state holds for k_t)
+    S   = S + k_t (beta_t (v_t - r))^T    (the delta rule)
+    o_t = S^T q_t
+
+with q and k l2-normed over a head's values and q scaled by
+key_dim ** -0.5 where `qk_l2norm` is set (inside the op: the norms are
+float32 whatever type the projections come in).  Value head j reads
+key/query head j // (value heads / key heads), by index: no key is
+repeated in memory.
+
+`gated_delta_rule` is the op: Q, K [rows, T, key heads * key_dim], V
+[rows, T, value heads * value_dim], G and Beta [rows, T, value heads]
+float32 (g <= 0, beta in (0, 1)), State [rows, value heads, key_dim,
+value_dim] float32 -> Out [rows, T, value heads * value_dim] in V's
+type and StateOut, State's shape and type: a `fluid.ProgramDecoder`
+state pair that a step rewrites whole.  Two forms, chosen by T as the
+program is traced:
+
+T = 1, a decode step (`gdn_state`): every head's state is read once,
+decayed, read for `S^T k`, written with the rank-one update and read for
+`S^T q`.  On the TPU that is one Pallas kernel that stores the state in
+place (kernels/gdn_step.py); off it, and for shapes the kernel does not
+take, the same four lines in `jax.numpy`.
+
+T > 1, a block (`gdn_chunks`): the same recurrence rearranged over
+chunks of `chunk` positions (the family's `chunk_gated_delta_rule`).
+With `G_i` the running sum of g inside the chunk and `D_ij = exp(G_i -
+G_j)` for i >= j,
+
+    A     = strict_lower(diag(beta) (K K^T) * D)
+    T     = (I + A)^-1                    (a unit lower triangular solve)
+    U     = T (beta V);  W = T (beta K exp(G))
+    V_new = U - W S                       S: the state entering the chunk
+    O     = (Q exp(G)) S + lower((Q K^T) * D) V_new
+    S'    = exp(G_C) S + (K exp(G_C - G))^T V_new
+
+walked chunk by chunk; every product float32 at the highest precision
+(no exponent is ever positive: D, exp(G) and exp(G_C - G) are decays).
+A block that is no multiple of the chunk is padded with beta 0 and g 0,
+which leave the state as it is.  Plain `jax.numpy` on every platform.
+
+Forward only: generation needs no gradient, and a gradient of this op
+asked for raises (training the layer wants the block form's backward,
+which nothing here has).
+"""
+
+import jax
+import jax.numpy as jnp
+
+from ..obs import telemetry
+from .registry import (register_grad_kernel, register_op,
+                       same_meta_infer_shape)
+
+F32 = jnp.float32
+_HIGHEST = jax.lax.Precision.HIGHEST
+_EPS = 1e-6
+
+
+def _infer_shape(block, op_desc):
+    """`Out` is `V`'s and the state comes out as it went in: stated, not
+    traced, so that a block axis the Program leaves open stays open."""
+    for src, dst in (("V", "Out"), ("State", "StateOut")):
+        same_meta_infer_shape(src, dst)(block, op_desc)
+
+
+def _heads(ins):
+    """q, k [rows, T, key heads, key_dim], v [rows, T, value heads,
+    value_dim] as they come, g and beta [rows, T, value heads] float32,
+    the state; checked against one another."""
+    q, k, v = ins["Q"][0], ins["K"][0], ins["V"][0]
+    g, beta, state = ins["G"][0], ins["Beta"][0], ins["State"][0]
+    rows, heads, key_dim, value_dim = state.shape
+    if q.shape != k.shape or q.shape[-1] % key_dim \
+            or v.shape[-1] != heads * value_dim \
+            or heads % (q.shape[-1] // key_dim) \
+            or g.shape != v.shape[:2] + (heads,) or beta.shape != g.shape:
+        raise ValueError(
+            "gated_delta_rule: Q %s, K %s, V %s, G %s and Beta %s do not "
+            "fit a state of %s ([rows, value heads, key_dim, value_dim])"
+            % (q.shape, k.shape, v.shape, g.shape, beta.shape, state.shape))
+    split = lambda t, d: t.reshape(*t.shape[:2], -1, d)
+    return (split(q, key_dim), split(k, key_dim), split(v, value_dim),
+            g.astype(F32), beta.astype(F32), state)
+
+
+def l2norm(t):
+    """t / sqrt(sum(t^2) + 1e-6) over the last axis, float32."""
+    t = t.astype(F32)
+    return t * jax.lax.rsqrt(jnp.sum(t * t, axis=-1, keepdims=True) + _EPS)
+
+
+def recurrent(q, k, v, g, beta, state):
+    """The recurrence position by position (`lax.scan` over T): q, k
+    [rows, T, key heads, key_dim] (normed and scaled already), v [rows,
+    T, heads, value_dim], g, beta [rows, T, heads], state [rows, heads,
+    key_dim, value_dim], all float32 -> (out [rows, T, heads,
+    value_dim], the state after the block)."""
+    rows, _, key_heads, key_dim = q.shape
+    heads = v.shape[2]
+    group = heads // key_heads
+
+    def step(s, at):
+        q_t, k_t, v_t, g_t, b_t = at
+        # [rows, key heads, group, ...]: a value head beside its key's
+        s = s.reshape(rows, key_heads, group, key_dim, -1) \
+            * jnp.exp(g_t).reshape(rows, key_heads, group, 1, 1)
+        v_t = v_t.reshape(rows, key_heads, group, -1)
+        b_t = b_t.reshape(rows, key_heads, group, 1)
+        held = jnp.einsum("bhgkv,bhk->bhgv", s, k_t, precision=_HIGHEST)
+        s = s + k_t[:, :, None, :, None] * (b_t * (v_t - held))[..., None, :]
+        out = jnp.einsum("bhgkv,bhk->bhgv", s, q_t, precision=_HIGHEST)
+        return s.reshape(rows, heads, key_dim, -1), \
+            out.reshape(rows, heads, -1)
+
+    state, out = jax.lax.scan(
+        step, state, tuple(jnp.moveaxis(t, 1, 0)
+                           for t in (q, k, v, g, beta)))
+    return jnp.moveaxis(out, 0, 1), state
+
+
+def chunked(q, k, v, g, beta, state, chunk):
+    """The block form (the module's docstring); `recurrent`'s operands
+    and results, T any length >= 1."""
+    rows, length, key_heads, key_dim = q.shape
+    heads, value_dim = v.shape[2:]
+    group = heads // key_heads
+    pad = -length % chunk
+    if pad:
+        # beta 0 writes nothing and g 0 decays nothing: the state after
+        # the padding is the state before it
+        q, k, v, g, beta = (
+            jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+            for t in (q, k, v, g, beta))
+    count = (length + pad) // chunk
+
+    # the scan's axis first, a head's positions beside their values
+    def keyed(t):       # [rows, T, Hk, Dk] -> [N, rows, Hk, C, Dk]
+        return t.reshape(rows, count, chunk, key_heads, key_dim) \
+            .transpose(1, 0, 3, 2, 4)
+
+    def gated(t):       # [rows, T, H] -> [N, rows, Hk, R, C]
+        return t.reshape(rows, count, chunk, key_heads, group) \
+            .transpose(1, 0, 3, 4, 2)
+
+    qc, kc = keyed(q), keyed(k)
+    vc = v.reshape(rows, count, chunk, key_heads, group, value_dim) \
+        .transpose(1, 0, 3, 4, 2, 5)             # [N, rows, Hk, R, C, Dv]
+    cum = jnp.cumsum(gated(g), axis=-1)
+    bc = gated(beta)
+    lower = jnp.tril(jnp.ones((chunk, chunk), bool))
+    # D_ij = exp(G_i - G_j) for i >= j: the exponent is masked, not the
+    # exponential (a masked-out entry's would overflow)
+    decay = jnp.exp(jnp.where(lower, cum[..., :, None] - cum[..., None, :],
+                              -jnp.inf))
+    kk = jnp.einsum("nbhid,nbhjd->nbhij", kc, kc, precision=_HIGHEST)
+    qk = jnp.einsum("nbhid,nbhjd->nbhij", qc, kc, precision=_HIGHEST)
+    a = jnp.where(jnp.tril(lower, -1),
+                  bc[..., :, None] * kk[:, :, :, None] * decay, 0.0)
+    from_start = jnp.exp(cum)                    # exp(G_i)
+    rhs = jnp.concatenate(
+        [bc[..., None] * vc,
+         (bc * from_start)[..., None] * kc[:, :, :, None]], axis=-1)
+    solved = jax.scipy.linalg.solve_triangular(
+        a + jnp.eye(chunk, dtype=F32), rhs, lower=True, unit_diagonal=True)
+    u, w = solved[..., :value_dim], solved[..., value_dim:]
+    attend = qk[:, :, :, None] * decay           # lower((Q K^T) * D)
+    q_dec = from_start[..., None] * qc[:, :, :, None]
+    to_end = jnp.exp(cum[..., -1:] - cum)        # exp(G_C - G_i)
+    k_end = to_end[..., None] * kc[:, :, :, None]
+    whole = jnp.exp(cum[..., -1])                # exp(G_C)
+
+    def step(s, at):
+        u_c, w_c, attend_c, q_c, k_c, whole_c = at
+        v_new = u_c - jnp.einsum("bhrik,bhrkv->bhriv", w_c, s,
+                                 precision=_HIGHEST)
+        out = jnp.einsum("bhrik,bhrkv->bhriv", q_c, s, precision=_HIGHEST) \
+            + jnp.einsum("bhrij,bhrjv->bhriv", attend_c, v_new,
+                         precision=_HIGHEST)
+        s = whole_c[..., None, None] * s + jnp.einsum(
+            "bhrik,bhriv->bhrkv", k_c, v_new, precision=_HIGHEST)
+        return s, out
+
+    s, out = jax.lax.scan(
+        step, state.reshape(rows, key_heads, group, key_dim, value_dim),
+        (u, w, attend, q_dec, k_end, whole))
+    # [N, B, Hk, R, C, Dv] -> [B, N * C, H, Dv]
+    out = out.transpose(1, 0, 4, 2, 3, 5).reshape(
+        rows, count * chunk, heads, value_dim)
+    return out[:, :length], s.reshape(state.shape)
+
+
+@register_op("gated_delta_rule", stop_gradient_op=True,
+             infer_shape=_infer_shape)
+def gated_delta_rule(ctx, ins, attrs):
+    """The module's docstring.  attrs: `qk_l2norm` (default true: l2
+    norm q and k a head and scale q by key_dim ** -0.5), `chunk` (the
+    block form's, default 64)."""
+    q, k, v, g, beta, state = _heads(ins)
+    chunk = int(attrs.get("chunk", 64))
+    rows, length, key_heads, key_dim = q.shape
+    heads = v.shape[2]
+    step = length == 1
+    kernel = None
+    if step:
+        from ..kernels import gdn_step
+        kernel = gdn_step.choose_heads(rows, heads, key_heads, key_dim,
+                                       v.shape[-1], state.dtype)
+    telemetry.on_gated_delta_rule_lowering(
+        "step" if step else "block", "kernel" if kernel else "plain",
+        0 if step else chunk, heads, state.dtype,
+        state[0].size * state.dtype.itemsize)
+    with jax.named_scope("gdn_gates"):
+        if attrs.get("qk_l2norm", True):
+            q, k = l2norm(q) * key_dim ** -0.5, l2norm(k)
+        else:
+            q, k = q.astype(F32), k.astype(F32)
+    if step:
+        def one(q, k, v, g, beta, state):
+            """`recurrent` on the operands of one position."""
+            out, new = recurrent(q[:, None], k[:, None],
+                                 v[:, None].astype(F32), g[:, None],
+                                 beta[:, None], state.astype(F32))
+            return out[:, 0], new
+
+        with jax.named_scope("gdn_state"):
+            operands = (q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
+                        state)
+            out, new = gdn_step.step(*operands, plain=one, heads=kernel) \
+                if kernel else one(*operands)
+            out = out[:, None]
+    else:
+        with jax.named_scope("gdn_chunks"):
+            out, new = chunked(q, k, v.astype(F32), g, beta,
+                               state.astype(F32), chunk)
+    return {"Out": [out.reshape(rows, length, -1).astype(v.dtype)],
+            "StateOut": [new.astype(state.dtype)]}
+
+
+@register_grad_kernel("gated_delta_rule")
+def gated_delta_rule_grad(ctx, ins, attrs):
+    raise NotImplementedError(
+        "gated_delta_rule is forward only: generation needs no gradient, "
+        "and training the layer wants the block form's backward, which "
+        "this op does not have")

@@ -52,6 +52,16 @@ each channel by itself, zeros before position 0: one fused pass.  Its
 gradient is explicit too and reads X, Filter, Bias and dOut only: it
 computes the pre-activation again (one more fused pass over X) where
 `jax.vjp` would keep K shifted copies of X.
+
+A cached step's convolution **carries its tail**: with the optional
+input `Tail` [batch, K - 1, channels], the K - 1 positions before the
+block (zeros at a sequence's start), position 0 reads them in place of
+zeros, and the op gives `TailOut`, the last K - 1 positions of tail and
+block together, in Tail's type: a `fluid.ProgramDecoder` state pair, so
+that a sequence fed in blocks of any lengths (a prompt's, then a
+position a step) convolves as it would whole.  `Bias` is optional too
+(Qwen3-Next's convolution has none).  That form is forward only; without
+`Tail` the op lowers as it always did.
 """
 
 import jax
@@ -297,6 +307,10 @@ _ACTIVATIONS = ("", "silu")
 def _conv_infer_shape(block, op_desc):
     x = block.var_recursive(op_desc.input("X")[0]).desc
     _set_meta(block, op_desc.output("Out")[0], x.shape, x.dtype)
+    if op_desc.input("Tail"):
+        tail = block.var_recursive(op_desc.input("Tail")[0]).desc
+        _set_meta(block, op_desc.output("TailOut")[0], tail.shape,
+                  tail.dtype)
 
 
 def _shifted(x, width, j):
@@ -323,23 +337,54 @@ def _conv_attrs(attrs):
     return act
 
 
+def _pre_activation_after(x, filt, bias, tail):
+    """(bias + sum_j filter[:, j] x_{t-(K-1)+j} in float32, the tail
+    handed on) with `tail` in front of the block."""
+    width, seq = filt.shape[1], x.shape[1]
+    if tail.shape != (x.shape[0], width - 1, x.shape[2]):
+        raise ValueError(
+            "causal_conv1d: Tail %s is not the %d positions before a "
+            "block %s" % (tail.shape, width - 1, x.shape))
+    joined = jnp.concatenate([tail.astype(x.dtype), x], axis=1)
+    xf, w = joined.astype(F32), filt.astype(F32)
+    pre = sum(xf[:, j:j + seq] * w[:, j] for j in range(width))
+    if bias is not None:
+        pre = pre + bias.astype(F32)
+    return pre, joined[:, seq:].astype(tail.dtype)
+
+
 @register_op("causal_conv1d", infer_shape=_conv_infer_shape)
 def causal_conv1d(ctx, ins, attrs):
     """X [batch, seq, channels], Filter [channels, width], Bias
     [channels] -> Out, X's shape and type (the module's docstring);
-    attr `activation` "" or "silu".  Sums in float32."""
-    x, filt, bias = ins["X"][0], ins["Filter"][0], ins["Bias"][0]
+    attr `activation` "" or "silu".  Sums in float32.  With `Tail`
+    [batch, width - 1, channels] also TailOut; `Bias` may then be left
+    out."""
+    x, filt = ins["X"][0], ins["Filter"][0]
     act = _conv_attrs(attrs)
     telemetry.on_causal_conv1d_lowering(filt.shape[1], act or "none")
-    pre = _pre_activation(x, filt, bias)
+    handed = {}
+    if ins.get("Tail"):
+        tail = ins["Tail"][0]
+        telemetry.on_causal_conv1d_tail_lowering(
+            filt.shape[1], tail[0].size * tail.dtype.itemsize)
+        pre, tail = _pre_activation_after(
+            x, filt, (ins.get("Bias") or [None])[0], tail)
+        handed["TailOut"] = [tail]
+    else:
+        pre = _pre_activation(x, filt, ins["Bias"][0])
     out = pre * jax.nn.sigmoid(pre) if act == "silu" else pre
-    return {"Out": [out.astype(x.dtype)]}
+    return dict({"Out": [out.astype(x.dtype)]}, **handed)
 
 
 @register_grad_kernel("causal_conv1d")
 def causal_conv1d_grad(ctx, ins, attrs):
     """X@GRAD (dOut's type), Filter@GRAD and Bias@GRAD (float32 sums,
     the parameters' type) from X, Filter, Bias and dOut alone."""
+    if ins.get("Tail"):
+        raise NotImplementedError(
+            "causal_conv1d: the form that carries its tail is forward "
+            "only (a cached step's)")
     x, filt, bias = ins["X"][0], ins["Filter"][0], ins["Bias"][0]
     d_out = ins["OG@Out"][0]
     width = filt.shape[1]

@@ -531,3 +531,64 @@ def test_save_after_amp_startup_round_trips(tmp_path):
         assert abs(float(out.sum()) - 2.0) < 2e-2
     finally:
         fluid.amp.disable_bf16()
+
+
+@pytest.mark.parametrize("block,kernels", [
+    (1, ["gqa_decode_k1024_d256"]), (128, ["gqa_decode_k1024_t128_d256"])])
+def test_the_wide_head_decode_kernel_lowers_for_tpu(block, kernels):
+    """`cached_attention` at qwen3next-decode-ep16's shape (128 rows, 16
+    query heads over 2 key/value heads of 256, 1024-slot bfloat16
+    caches) lowered for the TPU from this CPU host: a step and a
+    prefill block of 128 positions both walk the live slots, two lane
+    blocks a head (the kernel's name says the head's width)."""
+    from paddle_tpu.ops import registry
+
+    kernel = registry.get_op_info("cached_attention").kernel
+    b, h, kv, d, bf16 = 128, 16, 2, 256, jnp.bfloat16
+    cache = jax.ShapeDtypeStruct((b, kv, 1024, d), bf16)
+    ins = {"Q": [jax.ShapeDtypeStruct((b, block, h * d), bf16)],
+           "KNew": [jax.ShapeDtypeStruct((b, block, kv * d), bf16)],
+           "VNew": [jax.ShapeDtypeStruct((b, block, kv * d), bf16)],
+           "KCache": [cache], "VCache": [cache],
+           "Position": [jax.ShapeDtypeStruct((b,), jnp.int32)]}
+
+    def step(ins):
+        return kernel(None, ins, {"num_heads": h, "num_kv_heads": kv})
+
+    module = jax.export.export(jax.jit(step), platforms=["tpu"])(
+        ins).mlir_module()
+    assert module.count("tpu_custom_call") == len(kernels)
+    for name in kernels:
+        assert 'kernel_name = "%s"' % name in module
+
+
+@pytest.mark.parametrize("length,kernels", [(1, ["gdn_step_r128_h16"]),
+                                            (128, [])])
+def test_the_delta_rule_step_kernel_lowers_for_tpu(length, kernels):
+    """`gated_delta_rule` at qwen3next-decode-ep16's shape (128 rows, 16
+    key / 32 value heads of 128, a float32 state) lowered for the TPU
+    from this CPU host: a step holds one Mosaic kernel over (rows, 16
+    value heads a grid step) whose state is its result's buffer, a block
+    of 128 positions holds none (the chunked form is plain products)."""
+    from paddle_tpu.ops import registry
+
+    kernel = registry.get_op_info("gated_delta_rule").kernel
+    b, hk, hv, d = 128, 16, 32, 128
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    ins = {"Q": [jax.ShapeDtypeStruct((b, length, hk * d), bf16)],
+           "K": [jax.ShapeDtypeStruct((b, length, hk * d), bf16)],
+           "V": [jax.ShapeDtypeStruct((b, length, hv * d), bf16)],
+           "G": [jax.ShapeDtypeStruct((b, length, hv), f32)],
+           "Beta": [jax.ShapeDtypeStruct((b, length, hv), f32)],
+           "State": [jax.ShapeDtypeStruct((b, hv, d, d), f32)]}
+
+    def step(ins):
+        return kernel(None, ins, {"chunk": 64})
+
+    module = jax.export.export(jax.jit(step), platforms=["tpu"])(
+        ins).mlir_module()
+    assert module.count("tpu_custom_call") == len(kernels)
+    for name in kernels:
+        assert 'kernel_name = "%s"' % name in module
+    if kernels:     # the state's buffer is the new state's
+        assert "output_tuple_indices = [1], operand_index = 5" in module

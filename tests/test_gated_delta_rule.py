@@ -1,0 +1,357 @@
+"""The ops a step with a recurrent state is built on, each by itself:
+the gated delta rule with a state handed in and on (`gated_delta_rule`:
+the step, the step kernel under the interpreter, the chunked block form)
+against the recurrence position by position and the forms against one
+another (a block then steps, all steps, one block); the convolution
+that carries its tail over a split sequence against the unsplit call,
+and granite's lowering of it without one; 256-wide heads with a quarter
+rotated through `rope(rotary_dim=)` and `cached_attention` (plain and
+kernel paths) against plain attention.  The cached step Program built
+on them is tests/test_linear_moe_program.py's.
+"""
+
+import functools
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from paddle_tpu.kernels import gdn_step, gqa_decode
+from paddle_tpu.models.reference import qwen3_next as reference
+from paddle_tpu.obs import telemetry
+from paddle_tpu.ops import linear_attention, registry
+from paddle_tpu.ops import ssm
+
+
+# -- (a) the op's forms -----------------------------------------------------------
+
+def _rule_ins(rs, rows, length, key_heads=2, heads=4, dim=8, state=True):
+    """(Q, K, V, G, Beta, State) as the op takes them."""
+    return (jnp.asarray(rs.randn(rows, length, key_heads * dim), jnp.float32),
+            jnp.asarray(rs.randn(rows, length, key_heads * dim), jnp.float32),
+            jnp.asarray(rs.randn(rows, length, heads * dim), jnp.float32),
+            -jnp.asarray(rs.uniform(1e-3, 0.6, (rows, length, heads)),
+                         jnp.float32),
+            jnp.asarray(rs.uniform(0.05, 0.95, (rows, length, heads)),
+                        jnp.float32),
+            jnp.asarray(0.3 * rs.randn(rows, heads, dim, dim) if state
+                        else np.zeros((rows, heads, dim, dim)), jnp.float32))
+
+
+def _rule(ins, lo=None, hi=None, state=None, **attrs):
+    """One application of the op over positions lo..hi: (out, state)."""
+    q, k, v, g, beta, s0 = ins
+    cut = lambda t: t[:, lo:hi]
+    return _applied(tuple(sorted(dict({"chunk": 64}, **attrs).items())))(
+        cut(q), cut(k), cut(v), cut(g), cut(beta),
+        s0 if state is None else state)
+
+
+@functools.lru_cache(maxsize=None)
+def _applied(attrs):
+    """The op's kernel under one jit a set of attrs (a shape each)."""
+    def apply(q, k, v, g, beta, state):
+        out = registry.get_op_info("gated_delta_rule").kernel(
+            None, {"Q": [q], "K": [k], "V": [v], "G": [g], "Beta": [beta],
+                   "State": [state]}, dict(attrs))
+        return out["Out"][0], out["StateOut"][0]
+    return jax.jit(apply)
+
+
+def _by_position(ins):
+    """The recurrence in numpy, a position and a head at a time: what
+    the reference's `delta_rule` is, with a state handed in."""
+    q, k, v, g, beta, state = (np.asarray(t, np.float64) for t in ins)
+    rows, length, heads = g.shape
+    dim = state.shape[-1]
+    group = heads // (q.shape[-1] // dim)
+    q, k = (t.reshape(rows, length, -1, dim) for t in (q, k))
+    q = q / np.sqrt((q * q).sum(-1, keepdims=True) + 1e-6) / np.sqrt(dim)
+    k = k / np.sqrt((k * k).sum(-1, keepdims=True) + 1e-6)
+    v = v.reshape(rows, length, heads, dim)
+    out = np.zeros_like(v)
+    state = state.copy()
+    for b in range(rows):
+        for j in range(heads):
+            s = state[b, j]
+            for t in range(length):
+                kt, qt = k[b, t, j // group], q[b, t, j // group]
+                s = s * np.exp(g[b, t, j])
+                s = s + np.outer(kt, beta[b, t, j] * (v[b, t, j] - s.T @ kt))
+                out[b, t, j] = s.T @ qt
+            state[b, j] = s
+    return out.reshape(rows, length, -1), state
+
+
+@pytest.mark.parametrize("length,chunk", [(1, 64), (64, 64), (128, 64),
+                                          (75, 64), (13, 4), (3, 64)])
+@pytest.mark.parametrize("state", [True, False])
+def test_the_rule_is_the_recurrence_position_by_position(length, chunk,
+                                                         state):
+    """T = 1 (the step), whole chunks, a T that is no multiple of the
+    chunk, from a state handed in and from zeros; two value heads a key
+    head."""
+    ins = _rule_ins(np.random.RandomState(length), 2, length, state=state)
+    out, new = _rule(ins, chunk=chunk)
+    want_out, want_state = _by_position(ins)
+    np.testing.assert_allclose(np.asarray(out), want_out, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(new), want_state, atol=2e-5)
+
+
+@pytest.mark.parametrize("first", [1, 5, 64, 70])
+def test_a_block_then_steps_is_all_steps_is_one_block(first):
+    ins = _rule_ins(np.random.RandomState(first), 2, 80)
+    whole, whole_state = _rule(ins, chunk=16)
+    out, state = _rule(ins, 0, first, chunk=16)
+    outs, steps, walked = [out], [], ins[5]
+    for t in range(80):
+        if t >= first:
+            out, state = _rule(ins, t, t + 1, state=state)
+            outs.append(out)
+        one, walked = _rule(ins, t, t + 1, state=walked)
+        steps.append(one)
+    for got in (jnp.concatenate(outs, axis=1),
+                jnp.concatenate(steps, axis=1)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(whole),
+                                   atol=2e-5)
+    for got in (state, walked):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(whole_state),
+                                   atol=2e-5)
+
+
+def test_the_reference_walks_the_same_recurrence():
+    rs = np.random.RandomState(4)
+    q, k, v, g, beta, _ = _rule_ins(rs, 2, 9, state=False)
+    split = lambda t, n: t.reshape(2, 9, n, 8)
+    norm = lambda t: linear_attention.l2norm(split(t, 2))
+    out, state = reference.delta_rule(
+        {}, jnp.repeat(norm(q) / np.sqrt(8), 2, axis=2),
+        jnp.repeat(norm(k), 2, axis=2), split(v, 4), g, beta)
+    want_out, want_state = _by_position((q, k, v, g, beta,
+                                         np.zeros((2, 4, 8, 8))))
+    np.testing.assert_allclose(np.asarray(out).reshape(2, 9, -1), want_out,
+                               atol=2e-5)
+    np.testing.assert_allclose(np.asarray(state), want_state, atol=2e-5)
+
+
+def _kernel_ins(rs, rows, key_heads, heads, dtype=jnp.float32):
+    q, k = (linear_attention.l2norm(jnp.asarray(
+        rs.randn(rows, key_heads, 128), jnp.float32)) for _ in range(2))
+    return (q * 128 ** -0.5, k,
+            jnp.asarray(rs.randn(rows, heads, 128), dtype),
+            -jnp.asarray(rs.uniform(1e-3, 0.6, (rows, heads)), jnp.float32),
+            jnp.asarray(rs.uniform(0.05, 0.95, (rows, heads)), jnp.float32),
+            jnp.asarray(0.3 * rs.randn(rows, heads, 128, 128), jnp.float32))
+
+
+@pytest.mark.parametrize("key_heads,heads,block", [(2, 4, None), (16, 32, 16),
+                                                   (2, 2, None)])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_the_step_kernel_is_the_plain_step(key_heads, heads, block, dtype):
+    """The kernel's body under the Pallas interpreter, at 128 x 128 a
+    head: one block of all heads, blocks of 16 of 32 value heads, and a
+    value head a key head."""
+    ins = _kernel_ins(np.random.RandomState(heads), 2, key_heads, heads,
+                      dtype)
+    assert gdn_step.choose_heads(2, heads, key_heads, 128, 128,
+                                 jnp.float32) == (block or heads)
+    got, state = gdn_step.step(*ins, plain=None, interpret=True)
+    q, k, v, g, beta, s0 = ins
+    want, want_state = linear_attention.recurrent(
+        q[:, None], k[:, None], v[:, None].astype(jnp.float32), g[:, None],
+        beta[:, None], s0)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want[:, 0]),
+                               atol=2e-5)
+    np.testing.assert_allclose(np.asarray(state), np.asarray(want_state),
+                               atol=2e-5)
+
+
+@pytest.mark.parametrize("why,shape", [
+    ("a head that is not 128 x 128", (2, 4, 2, 64, 128, jnp.float32)),
+    ("a state that is not float32", (2, 4, 2, 128, 128, jnp.bfloat16)),
+    ("value heads that do not group", (2, 5, 2, 128, 128, jnp.float32)),
+])
+def test_the_step_kernel_refuses_what_it_does_not_take(why, shape):
+    assert gdn_step.choose_heads(*shape) == 0
+
+
+def test_the_ops_kernel_path_is_counted_and_is_its_plain_path():
+    """At 128 x 128 a head the op asks for the kernel (on the CPU the
+    kernel's plain stand-in runs): the same numbers as the recurrence,
+    and the counter says which way."""
+    rs = np.random.RandomState(6)
+    ins = _rule_ins(rs, 2, 1, key_heads=2, heads=4, dim=128)
+    before = telemetry.snapshot()
+    out, state = _rule(ins)
+    traced = telemetry.snapshot_delta(before)
+    want_out, want_state = _by_position(ins)
+    np.testing.assert_allclose(np.asarray(out), want_out, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(state), want_state, atol=2e-5)
+    assert traced[_rule_lowering("step", "kernel", 0, 4)] == 1
+    assert traced["recurrent_state_bytes_total{kind=delta}"] \
+        == 4 * 128 * 128 * 4
+
+
+def test_the_rule_has_no_gradient_and_says_so():
+    assert registry.get_op_info("gated_delta_rule").stop_gradient_op
+    with pytest.raises(NotImplementedError, match="forward only"):
+        registry.get_op_info("gated_delta_rule").grad_kernel(None, {}, {})
+
+
+def test_operands_that_do_not_fit_the_state_are_refused():
+    q, k, v, g, beta, state = _rule_ins(np.random.RandomState(0), 2, 3)
+    with pytest.raises(ValueError, match="gated_delta_rule"):
+        _rule((q, k, v[..., :24], g, beta, state))
+
+
+def _rule_lowering(form, path, chunk, heads):
+    return ("gated_delta_rule_lowerings_total{chunk=%d,form=%s,heads=%d,"
+            "path=%s,state_dtype=float32}" % (chunk, form, heads, path))
+
+
+# -- (b) the convolution that carries its tail -------------------------------------
+
+def _conv(x, filt, tail=None, bias=None, activation="silu"):
+    ins = {"X": [x], "Filter": [filt]}
+    if bias is not None:
+        ins["Bias"] = [bias]
+    if tail is not None:
+        ins["Tail"] = [tail]
+    out = registry.get_op_info("causal_conv1d").kernel(
+        None, ins, {"activation": activation})
+    return out["Out"][0], out.get("TailOut", [None])[0]
+
+
+@pytest.mark.parametrize("cuts", [[0], [0, 5], [0, 1, 2, 3, 4, 5, 6],
+                                  [0, 2, 9, 10]])
+@pytest.mark.parametrize("bias", [True, False])
+def test_a_split_sequence_with_its_tail_is_the_unsplit_call(cuts, bias):
+    rs = np.random.RandomState(len(cuts))
+    x = jnp.asarray(rs.randn(2, 12, 6), jnp.float32)
+    filt = jnp.asarray(rs.randn(6, 4), jnp.float32)
+    b = jnp.asarray(rs.randn(6), jnp.float32)
+    want, _ = _conv(x, filt, bias=b if bias else jnp.zeros(6))
+    tail, outs = jnp.zeros((2, 3, 6)), []
+    for lo, hi in zip(cuts, cuts[1:] + [12]):
+        out, tail = _conv(x[:, lo:hi], filt, tail, b if bias else None)
+        outs.append(out)
+    np.testing.assert_allclose(np.asarray(jnp.concatenate(outs, axis=1)),
+                               np.asarray(want), atol=1e-6)
+    np.testing.assert_array_equal(np.asarray(tail), np.asarray(x[:, -3:]))
+
+
+def test_the_tail_keeps_its_own_type():
+    x = jnp.ones((1, 2, 4), jnp.float32)
+    _, tail = _conv(x, jnp.ones((4, 4)), jnp.zeros((1, 3, 4), jnp.bfloat16))
+    assert tail.dtype == jnp.bfloat16 and tail.shape == (1, 3, 4)
+
+
+def _conv_before(x, filt, bias, act):
+    """`causal_conv1d`'s body as it was before it took a tail (commit
+    984867f), verbatim."""
+    pre = ssm._pre_activation(x, filt, bias)
+    out = pre * jax.nn.sigmoid(pre) if act == "silu" else pre
+    return out.astype(x.dtype)
+
+
+@pytest.mark.parametrize("act", ["silu", ""])
+def test_without_a_tail_the_convolution_lowers_as_it_did(act):
+    """granite's convolution: the jaxpr of the op without `Tail` is,
+    text for text, that of its body before this PR."""
+    x = jnp.zeros((2, 16, 8), jnp.bfloat16)
+    filt, bias = jnp.zeros((8, 4), jnp.bfloat16), jnp.zeros((8,), jnp.bfloat16)
+    now = jax.make_jaxpr(lambda *a: registry.get_op_info(
+        "causal_conv1d").kernel(None, {"X": [a[0]], "Filter": [a[1]],
+                                       "Bias": [a[2]]},
+                                {"activation": act})["Out"][0])(x, filt, bias)
+    then = jax.make_jaxpr(lambda *a: _conv_before(*a, act))(x, filt, bias)
+    assert str(now) == str(then)
+
+
+def test_the_tailed_convolution_has_no_gradient_and_says_so():
+    with pytest.raises(NotImplementedError, match="forward only"):
+        registry.get_op_info("causal_conv1d").grad_kernel(
+            None, {"Tail": [jnp.zeros((1, 3, 4))]}, {})
+
+
+# -- (c) 256-wide heads, a quarter rotated, through the cache ---------------------
+
+def _attend(q, k, v, caches, pos, heads, kv_heads):
+    out = registry.get_op_info("cached_attention").kernel(
+        None, {"Q": [q], "KNew": [k], "VNew": [v], "KCache": [caches[0]],
+               "VCache": [caches[1]], "Position": [pos]},
+        {"num_heads": heads, "num_kv_heads": kv_heads})
+    return out["Out"][0], (out["KCacheOut"][0], out["VCacheOut"][0])
+
+
+def _rotated(x, heads, positions, rotary):
+    return registry.get_op_info("rope").kernel(
+        None, {"X": [x], "Positions": [positions]},
+        {"num_heads": heads, "theta": 1e7, "rotary_dim": rotary})["Out"][0]
+
+
+def _plain_attention(q, k, v, heads, kv_heads):
+    rows, seq, _ = q.shape
+    dim = q.shape[-1] // heads
+    qh = q.reshape(rows, seq, heads, dim)
+    kh, vh = (np.repeat(np.asarray(t).reshape(rows, seq, kv_heads, dim),
+                        heads // kv_heads, axis=2) for t in (k, v))
+    s = np.einsum("bqhd,bkhd->bhqk", qh, kh) / np.sqrt(dim)
+    s = np.where(np.tril(np.ones((seq, seq), bool)), s, -np.inf)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    p /= p.sum(-1, keepdims=True)
+    return np.einsum("bhqk,bkhd->bqhd", p, vh).reshape(rows, seq, -1)
+
+
+@pytest.mark.parametrize("slots,cuts,path", [
+    (128, [0, 3] + list(range(4, 12)), "kernel"),   # a block, then steps
+    (24, list(range(12)), "plain"),     # an extent the kernel does not tile
+])
+def test_wide_heads_partly_rotated_are_plain_attention(slots, cuts, path):
+    """16 / 2 heads of 256 with 64 rotated, through `rope(rotary_dim=)`
+    and `cached_attention`, against plain causal attention over the
+    reference's rotation."""
+    rs = np.random.RandomState(slots)
+    heads, kv_heads, dim, seq, rows = 16, 2, 256, 12, 2
+    q = jnp.asarray(rs.randn(rows, seq, heads * dim), jnp.float32)
+    k, v = (jnp.asarray(rs.randn(rows, seq, kv_heads * dim), jnp.float32)
+            for _ in range(2))
+    positions = jnp.broadcast_to(jnp.arange(seq), (rows, seq))
+    qr, kr = _rotated(q, heads, positions, 64), \
+        _rotated(k, kv_heads, positions, 64)
+    want_q, want_k = (np.asarray(reference.rope(
+        t.reshape(rows, seq, n, dim), jnp.arange(seq), 1e7, 64)).reshape(
+            rows, seq, -1) for t, n in ((q, heads), (k, kv_heads)))
+    np.testing.assert_allclose(np.asarray(qr), want_q, atol=1e-5)
+    np.testing.assert_array_equal(
+        np.asarray(qr).reshape(rows, seq, heads, dim)[..., 64:],
+        np.asarray(q).reshape(rows, seq, heads, dim)[..., 64:])
+    caches = [jnp.zeros((rows, kv_heads, slots, dim))] * 2
+    before = telemetry.snapshot()
+    outs = []
+    for lo, hi in zip(cuts, cuts[1:] + [seq]):
+        out, caches = _attend(qr[:, lo:hi], kr[:, lo:hi], v[:, lo:hi],
+                              caches, jnp.full((rows,), lo, jnp.int32),
+                              heads, kv_heads)
+        outs.append(np.asarray(out))
+    traced = telemetry.snapshot_delta(before)
+    assert {key.split("path=")[1].split("}")[0].split(",")[0]
+            for key in traced
+            if key.startswith("window_attention_lowerings_total")} == {path}
+    np.testing.assert_allclose(
+        np.concatenate(outs, axis=1),
+        _plain_attention(want_q, want_k, v, heads, kv_heads), atol=3e-5)
+
+
+def test_the_chooser_takes_wide_heads_with_smaller_blocks():
+    # the float32 accumulator and the operands are twice as wide at 256:
+    # a float32 prefill block of a group of 8 takes half the slots a step
+    assert gqa_decode.choose_block(1024, 1024, 2, 128) == 1024
+    assert gqa_decode.choose_block(1024, 1024, 2, 256) == 1024
+    assert gqa_decode.choose_block(1024, 1024, 4, 256) == 512
+    assert gqa_decode.choose_block(2048, 2048, 2, 128) == 512
+    assert gqa_decode.choose_block(2048, 2048, 2, 256) == 256
+    assert gqa_decode.choose_block(1024, 8, 2, 256) == 1024
+    assert gqa_decode.choose_block(1024, 8, 2, 192) == 0
