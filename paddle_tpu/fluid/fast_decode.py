@@ -75,7 +75,13 @@ class ProgramDecoder:
     build_window_moe_cached_step_program`).  The decoder then feeds
     [rows, 1] at every decode step and beam row, and prefills a prompt
     `models.decode.PREFILL_BLOCK` positions an application instead of
-    one.  Nothing but the declaration decides it.
+    one.  Nothing but the declaration decides it.  How many positions an
+    application takes is the step's to say too: an op of the Program
+    that carries a `prefill_block` attr was sized for so many
+    (`mla_cached_attention`, whose absorbed queries are a hundred times
+    a token's hidden state: `models/latent_moe_program.py`), the decoder
+    prefills by the smallest its Program states, and a step that states
+    none keeps `PREFILL_BLOCK`.
     """
 
     def __init__(self, program, token_name, logits_name, state_pairs=(),
@@ -94,6 +100,12 @@ class ProgramDecoder:
         # [batch, -1] for a step that takes a block of positions
         self._takes_block = len(
             program.global_block().var(token_name).shape) == 2
+        # the positions an application of the step prefills, where its
+        # ops say what they were sized for (else PREFILL_BLOCK)
+        self._prefill_block = min(
+            (op.attrs["prefill_block"]
+             for op in program.global_block().desc.ops
+             if "prefill_block" in op.attrs), default=PREFILL_BLOCK)
         # what the Program declares of each state feed past its rows: a
         # step's caches need not be of one extent (a window layer's ring
         # beside a full layer's whole extent), so each feed is held to
@@ -219,7 +231,8 @@ class ProgramDecoder:
         tokens (skipped when max_len == 1 — the 'predict one
         continuation token' call).  Returns (tokens, lengths, state)."""
         step = self._step_fn(params)
-        state, first = prefill(step, state, prompt, self._takes_block)
+        state, first = prefill(step, state, prompt, self._takes_block,
+                               self._prefill_block)
         if max_len == 1:
             toks = first[:, None]
         else:
@@ -398,7 +411,8 @@ class _Call:
         self.mode, self.built = key[0], key not in decoder._compiled
         self.span.set(mode=self.mode, batch=self.batch_size,
                       prompt_len=self.prompt_len,
-                      block=PREFILL_BLOCK if decoder._takes_block else 1,
+                      block=decoder._prefill_block
+                      if decoder._takes_block else 1,
                       built=int(self.built))
         return decoder._jitted(key, builder)
 

@@ -77,22 +77,28 @@ def cached_attention(query, key, value, k_cache, v_cache, position,
 def mla_cached_attention(q_nope, q_rope, c_new, r_new, cache, position,
                          num_heads, v_head_dim, uk_attr=None, uv_attr=None,
                          name=None, selected=None, live=None,
-                         sm_scale=None):
-    """One decode step of latent attention over a cache of latents
-    (ops/attention.py mla_cached_attention): `q_nope` [batch, 1,
-    num_heads * nope] and `q_rope` [batch, 1, num_heads * rope] (rotated)
-    the query, `c_new` [batch, 1, latent] (normed) and `r_new` [batch, 1,
-    rope] (rotated) the token's cache entry, `cache` [batch, positions,
-    latent + rope], `position` int [1] or [batch].  Creates the keys' and
+                         sm_scale=None, prefill_block=None):
+    """One decode step of latent attention over a cache of latents, or a
+    block of T consecutive steps at once
+    (ops/attention.py mla_cached_attention): `q_nope` [batch, T,
+    num_heads * nope] and `q_rope` [batch, T, num_heads * rope] (rotated)
+    the queries of T >= 1 consecutive tokens of every row, `c_new`
+    [batch, T, latent] (normed) and `r_new` [batch, T,
+    rope] (rotated) the tokens' cache entries, `cache` [batch, positions,
+    latent + rope], `position` int [1] or [batch], the slot the block's
+    first token writes (query t attends slots 0 .. position + t).  Creates the keys' and
     values' up-projections [latent, num_heads * nope] and [latent,
     num_heads * v_head_dim], which the op absorbs; the scores' scale is
     the op's own, (nope + rope) ** -0.5, unless `sm_scale` gives one.
     With `selected` int32 [batch, top_k] and `live` int32 [batch]
     (`mla_index_select`'s two) the step attends the slots `selected`
     names, the first `live` of each row, and not every slot up to
-    `position`.  Returns (out [batch,
-    1, num_heads * v_head_dim], cache_out): thread `cache_out` back as
-    decode state (`fluid.ProgramDecoder` state pairs)."""
+    `position` (one position a call: T = 1).  `prefill_block`: the most
+    positions a block of this op is sized for; the op carries it as an
+    attr, refuses a longer block, and `fluid.ProgramDecoder` prefills a
+    prompt through the step by the smallest its ops state.  Returns (out
+    [batch, T, num_heads * v_head_dim], cache_out): thread `cache_out`
+    back as decode state (`fluid.ProgramDecoder` state pairs)."""
     if (selected is None) != (live is None):
         raise ValueError("mla_cached_attention: `selected` and `live` "
                          "come together")
@@ -117,6 +123,8 @@ def mla_cached_attention(q_nope, q_rope, c_new, r_new, cache, position,
         inputs.update(Selected=[selected], Live=[live])
     if sm_scale:
         attrs["sm_scale"] = float(sm_scale)
+    if prefill_block:
+        attrs["prefill_block"] = int(prefill_block)
     helper.append_op(
         type="mla_cached_attention", inputs=inputs,
         outputs={"Out": [out], "CacheOut": [cache_out]}, attrs=attrs)
@@ -766,13 +774,15 @@ def rms_norm(input, epsilon=1e-6, param_attr=None, **kwargs):
 
 
 def rope(input, positions, num_heads, theta=10000.0, inv_freq=None,
-         rotary_dim=None, **kwargs):
+         rotary_dim=None, full_width=False, **kwargs):
     """Rotary position embedding on each head of `input` [batch, seq,
     num_heads * head_dim] at `positions` [batch, seq] (ops/attention.py
     rope): rotate-half form, base `theta`, or the rates `inv_freq` (a
     list, one a pair: `ops.attention.yarn_inv_freq` makes YaRN's) in
     place of theta's powers; `rotary_dim` turns the first so many values
-    of every head and hands on the rest."""
+    of every head and hands on the rest.  `full_width`: a step that takes
+    a block of positions has the op turn a block where it lies, the same
+    numbers with no view of half heads (see the op)."""
     helper = LayerHelper("rope", **kwargs)
     out = helper.create_tmp_variable(input.dtype)
     attrs = {"num_heads": int(num_heads), "theta": float(theta)}
@@ -780,6 +790,8 @@ def rope(input, positions, num_heads, theta=10000.0, inv_freq=None,
         attrs["inv_freq"] = [float(f) for f in inv_freq]
     if rotary_dim:
         attrs["rotary_dim"] = int(rotary_dim)
+    if full_width:
+        attrs["full_width"] = True
     helper.append_op(type="rope",
                      inputs={"X": [input], "Positions": [positions]},
                      outputs={"Out": [out]}, attrs=attrs)

@@ -23,7 +23,9 @@ NEG_INF = -1e30
 
 
 # The positions of a row that one application of a block-taking step
-# prefills.  Swept on the chip (PERF.md section 5, gpt2m-decode): on the
+# prefills, unless the step says otherwise (`prefill`'s `block`: the
+# latent step's absorbed queries, heads x 576 values a token, size its
+# own, models/latent_moe_program.py).  Swept on the chip (PERF.md section 5, gpt2m-decode): on the
 # op's plain path the float32 scores of a block, [rows, heads, block,
 # extent], size it, not the FLOPs.  Where `cached_attention` walks the
 # live slots (kernels/gqa_decode.py: a whole-extent cache of 128-wide
@@ -44,7 +46,7 @@ PREFILL_SCOPE = "decode_prefill"
 STEPS_SCOPE = "decode_steps"
 
 
-def prefill(step_fn, init_state, prompt, takes_block=False):
+def prefill(step_fn, init_state, prompt, takes_block=False, block=None):
     """Feed a prompt through the step function, returning
     (state, first_token) where first_token [B] is the argmax of the
     last prompt position's logits — the natural continuation to seed
@@ -57,15 +59,17 @@ def prefill(step_fn, init_state, prompt, takes_block=False):
 
     `takes_block`: step_fn(state, tokens[B, T]) takes T >= 1
     consecutive positions of every row and gives the logits of the
-    last.  The prompt goes through in blocks of PREFILL_BLOCK, the
+    last.  The prompt goes through in blocks of `block` positions
+    (PREFILL_BLOCK unless the step states its own), the
     equal blocks inside one scan, a shorter block first for the
-    remainder: every position is processed, in P / PREFILL_BLOCK
+    remainder: every position is processed, in P / block
     applications instead of P."""
     with jax.named_scope(PREFILL_SCOPE):
-        return _prefill(step_fn, init_state, prompt, takes_block)
+        return _prefill(step_fn, init_state, prompt, takes_block,
+                        block or PREFILL_BLOCK)
 
 
-def _prefill(step_fn, init_state, prompt, takes_block):
+def _prefill(step_fn, init_state, prompt, takes_block, block):
     """`prefill`, inside its scope."""
     prompt = jnp.asarray(prompt, jnp.int32)
     if not takes_block:
@@ -81,7 +85,6 @@ def _prefill(step_fn, init_state, prompt, takes_block):
         (state, logits), _ = jax.lax.scan(body, (state, logits), toks[1:])
         return state, jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
-    block = PREFILL_BLOCK
     telemetry.on_prefill_lowering("block", block)
     rows, length = prompt.shape
     state, logits = init_state, None

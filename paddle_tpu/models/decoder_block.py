@@ -1,16 +1,20 @@
 """What the decoder builders share (`looped_program.py`,
-`moe_program.py`, and the cached steps `latent_moe_program.py` and
-`window_moe_program.py`): the post-2023 block's bias-free projection,
-its RMSNorm with a named scale, its attention sub-layer and the
-feed-forward half of one chip's share of an expert model, from
-`fluid.layers` alone.  Every parameter is created by the name it is
-given, so a builder decides what is shared."""
+`moe_program.py`, and the cached steps `latent_moe_program.py`,
+`window_moe_program.py` and `linear_moe_program.py`): the post-2023
+block's bias-free projection, its RMSNorm with a named scale, its
+attention sub-layer, the feed-forward half of one chip's share of an
+expert model, and what a cached step that takes a block of positions
+reads off its token feed, from `fluid.layers` alone.  Every parameter is
+created by the name it is given, so a builder decides what is shared."""
+
+import numpy as np
 
 from .. import fluid
 from ..fluid.param_attr import ParamAttr
 
 __all__ = ["linear", "norm", "attention", "gated_feed_forward",
-           "share_feed_forward", "token_feeds", "head_cross_entropy"]
+           "share_feed_forward", "token_feeds", "head_cross_entropy",
+           "block_positions", "last", "last_token_rows"]
 
 
 def linear(x, size, name):
@@ -43,6 +47,42 @@ def head_cross_entropy(x, targets, eps, norm_name, head_name, vocab_size):
         fluid.layers.reshape(x=logits, shape=[-1, vocab_size]),
         fluid.layers.reshape(x=targets, shape=[-1, 1])))
     return logits, ce
+
+
+def block_positions(tok, pos, batch):
+    """(ones, positions) of a cached step whose token feed `tok` is
+    declared [batch, -1], T >= 1 consecutive tokens of every row from
+    position `pos` int64 [batch] on.  T is read off the feed: `ones`
+    int64 [1, T], a one a position of the block, counted before each
+    for its offset (`positions` [batch, T] = pos .. pos + T - 1) and all
+    together for the advance (`pos + reduce_sum(ones)`)."""
+    ones = fluid.layers.fill_constant_batch_size_like(
+        tok, shape=[1, 1], dtype="int64", value=1, input_dim_idx=1,
+        output_dim_idx=1)
+    return ones, fluid.layers.reshape(x=pos, shape=[batch, 1]) \
+        + fluid.layers.cumsum(ones, axis=1, exclusive=True)
+
+
+def last(t):
+    """[batch, T, ...] -> [batch, 1, ...]: the block's last position.
+    What a block-taking step hands a decoder of itself (its `parts`, the
+    head's input) is of that position, in shapes T does not change: a
+    carry keeps its shape.  At T = 1 the slice is the identity."""
+    return fluid.layers.slice(t, axes=[1], starts=[-1], ends=[2 ** 31 - 1])
+
+
+def last_token_rows(ones, batch):
+    """`last_row(t)`: [batch * T, k], a token a row as a router has
+    them, a row of the batch after a row -> [batch, k], each row's last
+    token: row b * T + T - 1, with T read off `ones`
+    (`block_positions`).  Gathered, not cut out of a reshape to [batch,
+    T, k]: the token axis is open, and shape inference stands 840 in for
+    it, which a batch of 128 does not divide."""
+    last_token = fluid.layers.assign(
+        np.arange(1, batch + 1, dtype="int64").reshape(batch, 1),
+        fluid.layers.create_tensor("int64")) \
+        * last(fluid.layers.cumsum(ones, axis=1)) - last(ones)
+    return lambda t: fluid.layers.gather(t, last_token)
 
 
 def _repeat_heads(x, n_kv_head, times, d_head):

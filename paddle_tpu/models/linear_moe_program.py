@@ -44,12 +44,11 @@ The equations are in `models/reference/qwen3_next.py`, which the tests
 hold this to.
 """
 
-import numpy as np
-
 from .. import fluid
 from ..fluid.initializer import LogScale
 from ..fluid.param_attr import ParamAttr
-from .decoder_block import linear, norm, share_feed_forward
+from .decoder_block import (block_positions, last, last_token_rows, linear,
+                            norm, share_feed_forward)
 
 __all__ = ["build_linear_moe_cached_step_program", "linear_moe_param_names",
            "LINEAR", "FULL"]
@@ -131,11 +130,7 @@ def build_linear_moe_cached_step_program(
             """RMSNorm of the float32 stream, in the weights' type."""
             return fluid.layers.cast(norm(t, eps, name), embedded)
 
-        ones = fluid.layers.fill_constant_batch_size_like(
-            tok, shape=[1, 1], dtype="int64", value=1, input_dim_idx=1,
-            output_dim_idx=1)
-        positions = fluid.layers.reshape(x=pos, shape=[batch, 1]) \
-            + fluid.layers.cumsum(ones, axis=1, exclusive=True)
+        ones, positions = block_positions(tok, pos, batch)
 
         def head_norm(t, heads, width, scale, **kwargs):
             """RMSNorm over each head's `width` values."""
@@ -144,25 +139,7 @@ def build_linear_moe_cached_step_program(
                 param_attr=ParamAttr(name=scale), **kwargs)
             return fluid.layers.reshape(t, [0, 0, heads * width])
 
-        def last(t):
-            """[batch, T, ...] -> [batch, 1, ...]: the block's last
-            position."""
-            return fluid.layers.slice(t, axes=[1], starts=[-1],
-                                      ends=[2 ** 31 - 1])
-
-        # a row's last token among the tokens as the router has them,
-        # a row after a row: b * T + T - 1, with T read off the feed.
-        # (Gathered, not cut out of a reshape to [batch, T, top_k]: the
-        # token axis is open, and shape inference stands 840 in for it,
-        # which a batch of 128 does not divide.)
-        last_token = fluid.layers.assign(
-            np.arange(1, batch + 1, dtype="int64").reshape(batch, 1),
-            fluid.layers.create_tensor("int64")) \
-            * last(fluid.layers.cumsum(ones, axis=1)) - last(ones)
-
-        def last_row(t):
-            """[batch * T, top_k], a token a row -> [batch, top_k]."""
-            return fluid.layers.gather(t, last_token)
+        last_row = last_token_rows(ones, batch)
 
         def linear_mixer(i, h, block):
             tail, state = states[i]

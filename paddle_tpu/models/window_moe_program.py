@@ -35,7 +35,8 @@ hold this to.
 
 from .. import fluid
 from ..fluid.param_attr import ParamAttr
-from .decoder_block import linear, norm, share_feed_forward
+from .decoder_block import (block_positions, last, linear, norm,
+                            share_feed_forward)
 
 __all__ = ["build_window_moe_cached_step_program", "window_moe_param_names",
            "WINDOW", "FULL"]
@@ -130,26 +131,15 @@ def build_window_moe_cached_step_program(
         def normed(t, name):
             """RMSNorm of the float32 stream, in the weights' type."""
             return fluid.layers.cast(norm(t, eps, name), embedded)
-        # T is read off the token feed: a one a position of the block,
-        # counted before each for its offset and all together for the
-        # advance; positions [batch, T] are pos .. pos + T - 1
-        ones = fluid.layers.fill_constant_batch_size_like(
-            tok, shape=[1, 1], dtype="int64", value=1, input_dim_idx=1,
-            output_dim_idx=1)
-        positions = fluid.layers.reshape(x=pos, shape=[batch, 1]) \
-            + fluid.layers.cumsum(ones, axis=1, exclusive=True)
+        # T is read off the token feed; positions [batch, T] are pos ..
+        # pos + T - 1
+        ones, positions = block_positions(tok, pos, batch)
 
         def head_norm(t, heads, name):
             """RMSNorm over each head's `d_head` values."""
             t = norm(fluid.layers.reshape(t, [0, 0, heads, d_head]), eps,
                      name)
             return fluid.layers.reshape(t, [0, 0, heads * d_head])
-
-        def last(t):
-            """[batch, T, ...] -> [batch, 1, ...]: the block's last
-            position."""
-            return fluid.layers.slice(t, axes=[1], starts=[-1],
-                                      ends=[2 ** 31 - 1])
 
         def last_row(t):
             """[batch * T, top_k], a token a row -> [batch, top_k]."""

@@ -222,18 +222,21 @@ def on_mla_cached_attention_lowering(heads, latent, rope, cache_dtype,
                   cache_dtype=str(cache_dtype), selected=selected).inc()
 
 
-def on_mla_decode_lowering(path, block_k):
+def on_mla_decode_lowering(path, block_k, positions=1):
     """Which way an `mla_cached_attention` op traced into a program
     takes over its cache: "kernel", the walk of the live slots in blocks
     of `block_k` (kernels/mla_decode.py), or "plain", the contractions
-    over the whole extent or a gathered set (`block_k` 0).  One count
-    per op instance a lowered program holds."""
+    over the whole extent or a gathered set (`block_k` 0); and the
+    `positions` of a row it was lowered for (1: a decode step; more: a
+    block of a prompt's prefill).  One count per op instance a lowered
+    program holds."""
     _reg().counter("mla_decode_lowerings_total",
                    "latent-attention decode steps lowered, by path (the "
-                   "kernel over the live slots, or the plain products) "
-                   "and the kernel's block of slots",
-                   labelnames=("path", "block_k")) \
-          .labels(path=path, block_k=block_k).inc()
+                   "kernel over the live slots, or the plain products), "
+                   "the kernel's block of slots and the positions of a "
+                   "row the op took",
+                   labelnames=("path", "block_k", "positions")) \
+          .labels(path=path, block_k=block_k, positions=positions).inc()
 
 
 def on_mla_index_select_lowering(heads, dim, top_k, cache_dtype):

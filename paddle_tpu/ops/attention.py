@@ -37,6 +37,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..obs import telemetry
 from .registry import (register_op, register_grad_kernel, run_generic_grad,
@@ -197,6 +198,40 @@ def yarn_mscale(factor, mscale=1.0):
     return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
 
 
+def _rope_full_width(x, cos, sin, num_heads, rotary):
+    """`rope` on x [batch, seq, heads * head] where it lies, with cos
+    and sin [batch, seq, 1, rotary / 2]: x * C + partner(x) * S over
+    rows of `lanes` values, whole heads of them (128 where a head
+    divides that), C and S the cosines and signed sines over a head's
+    width (1 and 0 past `rotary`), repeated a head of the row and the
+    same for every row of a token; a value's partner is the one half a
+    rotary width to its right (first half) or left (second), which a
+    product with a 0 / 1 matrix picks out exactly: the lanes are turned
+    on the MXU."""
+    b, t, d = x.shape
+    head, half = d // num_heads, rotary // 2
+    lanes = head * max(1, 128 // head)
+    if d % lanes:
+        lanes = head
+    j = np.arange(lanes)
+    turned = j % head < rotary
+    pick = np.zeros((lanes, lanes), np.float32)
+    pick[np.where(j % head < half, j + half, j - half)[turned],
+         j[turned]] = 1
+    rows = x.reshape(b * t, d // lanes, lanes)
+    partner = jnp.einsum(
+        "nrk,kl->nrl", rows, jnp.asarray(pick, x.dtype),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
+    rest = jnp.ones((b, t, 1, head - rotary), jnp.float32)
+    by_cos = jnp.tile(jnp.concatenate([cos, cos, rest], axis=-1),
+                      (1, 1, 1, lanes // head)).reshape(b * t, 1, lanes)
+    by_sin = jnp.tile(jnp.concatenate([-sin, sin, 0 * rest], axis=-1),
+                      (1, 1, 1, lanes // head)).reshape(b * t, 1, lanes)
+    return (rows.astype(jnp.float32) * by_cos + partner * by_sin) \
+        .astype(x.dtype).reshape(b, t, d)
+
+
 @register_op("rope", nondiff_inputs=("Positions",),
              infer_shape=same_meta_infer_shape("X", "Out"))
 def rope(ctx, ins, attrs):
@@ -209,7 +244,19 @@ def rope(ctx, ins, attrs):
     `inv_freq` (a list of floats, one a pair) gives the angles' rates in
     place of theta's powers (`yarn_inv_freq` makes YaRN's); `rotary_dim`
     turns only the first so many values of every head, paired (x_i,
-    x_{i + rotary_dim/2}), and hands the rest on as they are."""
+    x_{i + rotary_dim/2}), and hands the rest on as they are.
+
+    `full_width` (an attr a builder sets for a step that takes a block
+    of positions) turns a block of seq > 1 positions where it lies,
+    [batch, seq, heads * head_dim] throughout: a value's partner is the
+    one half a rotary width to its left or right, and cosines and signed
+    sines are laid out over a head's width and repeated a head.  The
+    same products and sums as below, so the same numbers; what differs
+    is that no [.., 2, head_dim / 2] view is made, whose 32 values a row
+    fill a quarter of the TPU's 128 lanes: at 256 x 16 tokens of 128
+    heads of 64 the views cost 5.9 ms an op on the v5e where the block
+    of values is 67 MB (PERF.md section 6, PR 53).  One position is
+    turned as it was."""
     x = ins["X"][0]
     pos = ins["Positions"][0]
     num_heads = int(attrs["num_heads"])
@@ -233,6 +280,8 @@ def rope(ctx, ins, attrs):
         inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
     angles = pos.reshape(b, t, 1, 1).astype(jnp.float32) * inv_freq
     cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if attrs.get("full_width") and t > 1:
+        return {"Out": [_rope_full_width(x, cos, sin, num_heads, rotary)]}
     if rotary == head:
         xs = x.astype(jnp.float32).reshape(b, t, num_heads, 2, half)
         x1, x2 = xs[..., 0, :], xs[..., 1, :]
@@ -548,18 +597,21 @@ def _mla_infer_shape(block, op_desc):
              infer_shape=_mla_infer_shape)
 def mla_cached_attention_op(ctx, ins, attrs):
     """One decode step of multi-head latent attention (DeepSeek-V2,
-    arXiv:2405.04434, section 2.1) over a cache of *latents*: a token
-    and layer keep the normed compressed key/value `c` [latent] and the
-    one rotated key `r` [rope] that all heads share, side by side, and
-    no head's key or value.
+    arXiv:2405.04434, section 2.1) over a cache of *latents*, or a block
+    of T consecutive steps at once: a token and layer keep the normed
+    compressed key/value `c` [latent] and the one rotated key `r` [rope]
+    that all heads share, side by side, and no head's key or value.
 
-    QNope [batch, 1, heads * nope] and QRope [batch, 1, heads * rope]
-    (rotated) are this token's query; CNew [batch, 1, latent] (normed)
-    and RNew [batch, 1, rope] (rotated) its cache entry; Cache [batch,
-    positions, latent + rope]; WUk [latent, heads * nope] and WUv
-    [latent, heads * value] the up-projections of keys and values;
-    Position int [1] or [batch] (lockstep rows), the slot this step
-    writes (slots 0..Position attend).
+    QNope [batch, T, heads * nope] and QRope [batch, T, heads * rope]
+    (rotated) are the queries of T >= 1 consecutive tokens of every row
+    (T = 1: a decode step; a prompt's prefill feeds many); CNew [batch,
+    T, latent] (normed) and RNew [batch, T, rope] (rotated) their cache
+    entries; Cache [batch, positions, latent + rope]; WUk [latent, heads
+    * nope] and WUv [latent, heads * value] the up-projections of keys
+    and values; Position int [1] or [batch] (lockstep rows), the slot
+    the block's first token writes: the entries go to slots Position ..
+    Position + T - 1, and query t of the block attends slots 0 ..
+    Position + t.
 
     The up-projections are absorbed, so that no key or value of a head
     is ever made: with k_h = [c W_uk,h | r] and v_h = c W_uv,h,
@@ -570,23 +622,31 @@ def mla_cached_attention_op(ctx, ins, attrs):
         o_h     = (sum_t p_h,t c_t) W_uv,h             (`mla_values`)
 
     which is attention over k_h, v_h exactly.  Both contractions over
-    the cache are one batched matrix product each ([heads, latent +
-    rope] x [positions, latent + rope]^T a row, and [heads, positions]
-    x [positions, latent + rope], whose last `rope` columns are dropped:
-    cheaper than a copy of the cache without them), in the cache's
-    type with float32 sums; scores and softmax are float32.  Out [batch,
-    1, heads * value], CacheOut the cache with the slot written: a
-    `ProgramDecoder` state pair.  No gradient, as `cached_attention`.
+    the cache are one batched matrix product each ([T * heads, latent +
+    rope] x [positions, latent + rope]^T a row, and [T * heads,
+    positions] x [positions, latent + rope], whose last `rope` columns
+    are dropped: cheaper than a copy of the cache without them), in the
+    cache's type with float32 sums; scores and softmax are float32.  The
+    absorbed queries of a block are [batch, T * heads, latent + rope], a
+    position after a position: a block reads the weights and a row's
+    latents once for all its positions, and is as many times the
+    arithmetic of a step.  Out [batch, T, heads * value], CacheOut the
+    cache with the slots written: a `ProgramDecoder` state pair.  No
+    gradient, as `cached_attention`.
 
     `sm_scale` (an attr) takes the place of 1 / sqrt(nope + rope): YaRN
-    multiplies it by its mscale squared.  With Selected int32 [batch,
+    multiplies it by its mscale squared.  `prefill_block` (an attr) is
+    the most positions a block of this op was sized for (what a step's
+    builder states for `fluid.ProgramDecoder` to prefill by): a longer
+    block is refused.  With Selected int32 [batch,
     top_k] and Live int32 [batch] (`mla_index_select`'s) the step
     attends a chosen set and not every slot: the rows Selected names are
     gathered from the cache, after this step's slot is written
     (`dsa_gather`: one [batch, top_k, latent + rope] copy), the same two
     contractions run over the gathered entries, and of a row's top_k
     entries the first Live count, the others are masked: a chosen set is
-    a set, the softmax does not care for its order."""
+    a set, the softmax does not care for its order.  A chosen set is one
+    position's: with Selected and T > 1 the op raises."""
     q_nope, q_rope = ins["QNope"][0], ins["QRope"][0]
     c_new, r_new = ins["CNew"][0], ins["RNew"][0]
     cache, w_uk, w_uv = ins["Cache"][0], ins["WUk"][0], ins["WUv"][0]
@@ -600,6 +660,18 @@ def mla_cached_attention_op(ctx, ins, attrs):
             "mla_cached_attention: the cache holds %d values a token, "
             "the latent is %d wide and the rotated key %d; W_uk is %s"
             % (width, latent, rope_dim, w_uk.shape))
+    block = q_nope.shape[1]
+    if block > 1 and selected is not None:
+        raise ValueError(
+            "mla_cached_attention: a block of %d positions with Selected: "
+            "a chosen set is one position's, and `mla_index_select` "
+            "chooses for one; feed such a step a position at a time"
+            % block)
+    if block > int(attrs.get("prefill_block", 0) or block):
+        raise ValueError(
+            "mla_cached_attention: a block of %d positions, and the op "
+            "was sized for %d (`prefill_block`)"
+            % (block, attrs["prefill_block"]))
     nope = q_nope.shape[-1] // heads
     sm_scale = float(attrs.get("sm_scale", 0.0)) \
         or (nope + rope_dim) ** -0.5
@@ -613,14 +685,17 @@ def mla_cached_attention_op(ctx, ins, attrs):
     blocks = None
     if selected is None:
         from ..kernels import mla_decode
-        if mla_decode.fits(q_nope.shape[1], positions, latent):
+        if mla_decode.fits(block, positions, latent):
+            itemsize = jnp.dtype(dtype).itemsize
             blocks = mla_decode.choose_blocks(
-                batch, heads, positions, width, latent,
-                jnp.dtype(dtype).itemsize)
+                batch, heads, positions, width, latent, itemsize) \
+                if block == 1 else mla_decode.choose_group(
+                    heads, positions, rope_dim, latent, itemsize)
     telemetry.on_mla_decode_lowering(
-        "kernel" if blocks else "plain", blocks[0] if blocks else 0)
+        "kernel" if blocks else "plain", blocks[0] if blocks else 0, block)
 
-    entry = jnp.concatenate([c_new, r_new], axis=-1).reshape(batch, 1, width)
+    entry = jnp.concatenate([c_new, r_new], axis=-1).reshape(batch, block,
+                                                             width)
     cache = jax.lax.dynamic_update_slice_in_dim(
         cache, entry.astype(cache.dtype), pos, axis=1)
     if selected is None:
@@ -632,13 +707,45 @@ def mla_cached_attention_op(ctx, ins, attrs):
                 cache, selected[:, :, None].astype(jnp.int32),
                 axis=1).astype(dtype)
 
+    if blocks and block > 1:
+        # a block through the kernel: the heads are the batch of both
+        # products with their matrices, so the queries are made, walked
+        # and handed on a head after a head, [heads, batch * T, .], and
+        # the rotated part goes beside the latent one, not joined to it
+        tokens = batch * block
+        with jax.named_scope("mla_absorb"):
+            q_lat = jnp.einsum(
+                "nhd,chd->hnc", q_nope.reshape(tokens, heads, nope),
+                w_uk.reshape(latent, heads, nope).astype(dtype),
+                preferred_element_type=f32).astype(dtype)
+            q_rot = jnp.swapaxes(
+                q_rope.reshape(tokens, heads, rope_dim), 0, 1)
+        with jax.named_scope("mla_scores"):
+            o_lat = mla_decode.mla_decode_block(q_lat, q_rot, live, pos,
+                                                sm_scale, blocks)
+        with jax.named_scope("mla_values"):
+            out = jnp.einsum(
+                "hnc,chd->nhd", o_lat,
+                w_uv.reshape(latent, heads, -1).astype(dtype),
+                preferred_element_type=f32)
+        return {"Out": [out.reshape(batch, block, -1).astype(dtype)],
+                "CacheOut": [cache]}
+
+    # a step's queries are a row's heads [batch, heads, .]; a block's
+    # keep their position in front of the head, [batch, T, heads, .],
+    # for the two products with the heads' matrices, and lie a position
+    # after a position, [batch, T * heads, .], over the cache
+    lead = (batch, heads) if block == 1 else (batch, block, heads)
+    of = "bh" if block == 1 else "bth"
     with jax.named_scope("mla_absorb"):
         q_lat = jnp.einsum(
-            "bhd,chd->bhc", q_nope.reshape(batch, heads, nope),
+            "%sd,chd->%sc" % (of, of), q_nope.reshape(lead + (nope,)),
             w_uk.reshape(latent, heads, nope).astype(dtype),
             preferred_element_type=f32).astype(dtype)
         q = jnp.concatenate(
-            [q_lat, q_rope.reshape(batch, heads, rope_dim)], axis=-1)
+            [q_lat, q_rope.reshape(lead + (rope_dim,))], axis=-1)
+        if block > 1:
+            q = q.reshape(batch, block * heads, width)
     with jax.named_scope("mla_scores"):
         if blocks:
             o_lat = mla_decode.mla_decode(q, live, pos, sm_scale, latent,
@@ -646,20 +753,25 @@ def mla_cached_attention_op(ctx, ins, attrs):
         else:
             s = jnp.einsum("bhw,btw->bht", q, live,
                            preferred_element_type=f32) * sm_scale
-            if selected is None:
-                valid = jnp.arange(positions) <= pos
-            else:
+            if selected is not None:
                 valid = jnp.arange(selected.shape[-1]) \
                     < jnp.reshape(ins["Live"][0], (-1,))[0]
-            p = jax.nn.softmax(jnp.where(valid[None, None, :], s, -1e30),
+            elif block == 1:
+                valid = jnp.arange(positions) <= pos
+            else:   # query row t * heads + h attends slots 0 .. pos + t
+                valid = jnp.arange(positions)[None, :] \
+                    <= pos + jnp.arange(block * heads)[:, None] // heads
+            p = jax.nn.softmax(jnp.where(valid[None, None, :] if block == 1
+                                         else valid[None], s, -1e30),
                                axis=-1)
     with jax.named_scope("mla_values"):
         if not blocks:
             o_lat = jnp.einsum("bht,btw->bhw", p.astype(dtype), live,
                                preferred_element_type=f32)[..., :latent]
         out = jnp.einsum(
-            "bhc,chd->bhd", o_lat.astype(dtype),
+            "%sc,chd->%sd" % (of, of),
+            o_lat.astype(dtype).reshape(lead + (latent,)),
             w_uv.reshape(latent, heads, -1).astype(dtype),
             preferred_element_type=f32)
-    return {"Out": [out.reshape(batch, 1, -1).astype(dtype)],
+    return {"Out": [out.reshape(batch, block, -1).astype(dtype)],
             "CacheOut": [cache]}

@@ -6,7 +6,13 @@ positions: what the order of `_BLOCKS` and `_ROWS` in
 PR 39).  `chiprun -- python scripts/mla_decode_bench.py`; one JSON line
 a variant, all of them in `chiprun_out/mla_decode_bench.jsonl`.  `op`
 rows time the whole `mla_cached_attention` op with the cache carried
-from call to call, as a decoder's scan carries it."""
+from call to call, as a decoder's scan carries it.  `block` rows (PR 53)
+time the kernel alone over a block of 16 positions a row, a prefill
+application of the cell, from
+an empty cache and inside a session, by block of slots and heads a
+grid step, with the FLOPs its products require (each position over its
+own live slots) and what the kernel multiplies (whole blocks of slots)
+beside them; `block_op` rows the whole op on such a block.  `--blocks` runs those two kinds alone."""
 
 import json
 import os
@@ -28,6 +34,9 @@ ROWS, HEADS, SLOTS = 256, 128, 1024
 LATENT, ROPE, NOPE, VALUE = 512, 64, 128, 128
 POSITIONS = (128, 300, 511, 512, 700, 1023)
 SCALE = (NOPE + ROPE) ** -0.5
+BLOCK = 16
+BLOCK_STARTS = (0, 112, 500, 1008)
+BLOCK_VARIANTS = ((256, 64), (128, 64), (512, 32), (256, 32), (128, 32))
 
 
 def _slope(fn, *args, repeats=3):
@@ -55,6 +64,88 @@ def plain(q, cache, pos):
                       preferred_element_type=jnp.float32)[..., :LATENT]
 
 
+def block_rows(emit, draw, cache):
+    """The kernel and the op over a block of BLOCK positions a row."""
+    tokens = ROWS * BLOCK
+    q_lat = draw(HEADS, tokens, LATENT, std=0.5)
+    q_rope = draw(HEADS, tokens, ROPE, std=0.5)
+
+    def plain_block(q_lat, q_rope, cache, pos):
+        # a position at a time: the whole block's float32 scores are 2 GB
+        q = jnp.concatenate([q_lat, q_rope], axis=-1).reshape(
+            HEADS, ROWS, BLOCK, LATENT + ROPE)
+        return jnp.stack(
+            [plain(jnp.swapaxes(q[:, :, t], 0, 1), cache, pos + t)
+             for t in range(BLOCK)], axis=2)    # [rows, heads, T, latent]
+
+    chosen = mla_decode.choose_group(HEADS, SLOTS, ROPE, LATENT, 2)
+    for blocks in BLOCK_VARIANTS:
+        bk, group = blocks
+        for pos in BLOCK_STARTS:
+            def attend(q_lat, q_rope, cache, at):
+                return mla_decode.mla_decode_block(q_lat, q_rope, cache, at,
+                                                   SCALE, blocks)
+
+            def steps(n, q_lat, q_rope, cache):
+                def body(_, c):
+                    at = pos + jnp.isnan(c).astype(jnp.int32)
+                    return attend(q_lat, q_rope, cache,
+                                  at)[0, 0, 0].astype(jnp.float32)
+                return lax.fori_loop(0, n, body, jnp.float32(0))
+
+            # multiply-adds: a query row over its own live slots, and
+            # over the whole blocks of slots its tile folds
+            tile = mla_decode._TILE
+            live = sum(pos + t + 1 for t in range(BLOCK))
+            folded = sum((-(-(pos + t0 + tile) // bk)) * bk * tile
+                         for t0 in range(0, BLOCK, tile))
+            row = {"kind": "block", "block_k": bk, "heads_a_step": group,
+                   "positions": BLOCK, "position": pos,
+                   "chosen": blocks == chosen,
+                   "gflop_required": 2e-9 * ROWS * HEADS * live
+                   * (LATENT + ROPE + LATENT),
+                   "gflop_folded": 2e-9 * ROWS * HEADS * folded
+                   * (LATENT + ROPE + LATENT)}
+            try:
+                row["ms"] = _slope(jax.jit(steps), q_lat, q_rope, cache)
+                row["tflops_required"] = row["gflop_required"] / row["ms"]
+                got = jax.jit(attend)(q_lat, q_rope, cache, pos).reshape(
+                    HEADS, ROWS, BLOCK, LATENT)
+                row["max_diff"] = float(jnp.max(jnp.abs(
+                    jnp.transpose(got, (1, 0, 2, 3)).astype(jnp.float32)
+                    - jax.jit(plain_block)(q_lat, q_rope, cache, pos))))
+            except Exception as e:  # what Mosaic refuses is a row
+                row["error"] = str(e)[-300:]
+            emit(row)
+
+    kernel = registry.get_op_info("mla_cached_attention").kernel
+    ins = {"QNope": [draw(ROWS, BLOCK, HEADS * NOPE)],
+           "QRope": [draw(ROWS, BLOCK, HEADS * ROPE)],
+           "CNew": [draw(ROWS, BLOCK, LATENT)],
+           "RNew": [draw(ROWS, BLOCK, ROPE)],
+           "WUk": [draw(LATENT, HEADS * NOPE, std=0.05)],
+           "WUv": [draw(LATENT, HEADS * VALUE, std=0.05)]}
+    for start in (0, 112):
+        def steps(n, ins, cache):
+            def body(i, carry):
+                cache, seen = carry
+                at = start + jnp.isnan(seen).astype(jnp.int32)
+                outs = kernel(None, dict(
+                    ins, Cache=[cache],
+                    Position=[jnp.full((ROWS,), at, jnp.int32)]),
+                    {"num_heads": HEADS})
+                return outs["CacheOut"][0], \
+                    seen + outs["Out"][0][0, 0, 0].astype(jnp.float32)
+            return lax.fori_loop(0, n, body, (cache, jnp.float32(0)))
+
+        # the absorb and the values' up-projection beside the kernel's
+        emit({"kind": "block_op", "positions": BLOCK,
+              "first_position": start,
+              "gflop_projections": 2e-9 * ROWS * BLOCK * HEADS * LATENT
+              * (NOPE + VALUE),
+              "ms": _slope(jax.jit(steps), ins, cache)})
+
+
 def main():
     assert jax.devices()[0].platform == "tpu", jax.devices()
     os.makedirs("chiprun_out", exist_ok=True)
@@ -73,6 +164,9 @@ def main():
 
     q = draw(ROWS, HEADS, LATENT + ROPE, std=0.5)
     cache = draw(ROWS, SLOTS, LATENT + ROPE)
+    block_rows(emit, draw, cache)
+    if "--blocks" in sys.argv[1:]:
+        return
     for blocks in [(bk, rows) for bk in mla_decode._BLOCKS
                    for rows in mla_decode._ROWS]:
         for pos in POSITIONS:

@@ -4,19 +4,30 @@ reference, on the device JAX finds, at the benchmark's configuration
 outside any timed window (PERF.md section 6, PR 36).
 
     python scripts/pangu_check.py [--config openpangu-ultra-moe-718b]
-        [--seed 7] [--rows 8] [--positions 256]
+        [--seed 7] [--rows 8] [--positions 256] [--prefill 128]
 
 The cached step Program (absorbed latent attention, a cache of latents,
 the held range of the routed experts), in the types it is served in, is
 driven over `--positions` seeded tokens of `--rows` sequences through
-its cache, one scan of step applications as `ProgramDecoder` prefills
-and decodes, and every position's logits, every layer's output and every
-expert layer's chosen experts are kept.  The program's weights are then
+its cache as `ProgramDecoder` drives it: the first `--prefill` positions
+in blocks of the positions an application that the served cell's step
+states (`latent_moe_program.prefill_block` at the workload's rows; since
+PR 53), one scan of them, then a position an
+application, one scan of steps.  A block hands out what its last
+position computed, so the positions compared are every block's last and
+every stepped one: their logits, every layer's output and every expert
+layer's chosen experts are kept.  (The stepped positions read the cache
+the blocks wrote, every prompt position of every layer.)  The chosen
+experts of *every* position are kept too, as the expert op was handed
+them (`moe_experts`' own `TopIdx`, a block's whole).  The program's
+weights are then
 let go of and the reference (paddle_tpu/models/reference/pangu_moe.py:
 the unabsorbed full-sequence forward, no cache) runs in float32 on the
 same seeded weights, a layer at a time (one expert layer is 4 GB in
 float32), twice: with its own routing, and with the program's indices
-handed to it.  Prints and holds to the options' limits:
+handed to it at every position (what a stepped position attends, the
+prompt's latents, is then of the program's routing in the reference
+too).  Prints and holds to the options' limits:
 
 (a) per expert layer, the share of tokens whose 8 experts are the
     reference's own, and for the others the reference's margin between
@@ -67,6 +78,8 @@ def main(argv=None):
     p.add_argument("--seed", type=int, default=7)
     p.add_argument("--rows", type=int, default=8)
     p.add_argument("--positions", type=int, default=256)
+    p.add_argument("--prefill", type=int, default=128,
+                   help="the leading positions that go through in blocks")
     p.add_argument("--search-path", action="append", default=[])
     # my chip runs, PR 36, 8 x 256 positions: four sound runs (seeds
     # 3000000031, 3100000019, 3100000023, 3100000029; the last three of
@@ -100,7 +113,8 @@ def main(argv=None):
     from benchmark import harness
     from paddle_tpu.jit import FunctionalProgram
     from paddle_tpu.models.latent_moe_program import (
-        build_latent_moe_cached_step_program, latent_moe_param_names)
+        build_latent_moe_cached_step_program, latent_moe_param_names,
+        prefill_block)
     from paddle_tpu.models.reference import pangu_moe as reference
 
     lookup = harness.Lookup(args.search_path)
@@ -110,24 +124,36 @@ def main(argv=None):
     model = lookup.module("models", cfg["builder"])
     device = jax.devices()[0]
     rows, positions = args.rows, args.positions
-    print("platform=%s device_kind=%s config=%s seed=%d rows=%d "
-          "positions=%d" % (device.platform, device.device_kind,
-                            cfg["name"], args.seed, rows, positions),
-          flush=True)
-
     sizes = model.sizes(cfg)
+    chunk = prefill_block(workload["batch"], sizes["n_head"],
+                          sizes["kv_rank"], sizes["d_rope"])
+    prefill = min(args.prefill, positions) // chunk * chunk
+    # the positions whose outputs are kept: each block's last, and every
+    # stepped one
+    kept = np.concatenate([np.arange(chunk - 1, prefill, chunk),
+                           np.arange(prefill, positions)])
+    print("platform=%s device_kind=%s config=%s seed=%d rows=%d "
+          "positions=%d: %d in blocks of %d, %d compared"
+          % (device.platform, device.device_kind, cfg["name"], args.seed,
+             rows, positions, prefill, chunk, kept.size), flush=True)
+
     layers, dense = sizes["n_layer"], sizes["n_dense"]
+    moe = layers - dense
     first, held = sizes["held"]
     main, _, logits, pairs, parts = build_latent_moe_cached_step_program(
         rows, positions, **sizes)
     names = latent_moe_param_names(layers, dense)
     feeds = ["tok"] + [f for f, _ in pairs]
+    # `parts` are of an application's last position; the indices of all
+    # its positions [rows * T, top_k] are the expert op's own input
+    whole_idx = [op.input("TopIdx")[0] for op in main.global_block().ops
+                 if op.type == "moe_experts"]
     fetches = [logits.name] + [o for _, o in pairs] \
         + [v.name for v in parts["hidden"]] \
         + [v.name for v in parts["top_idx"]] \
         + [v.name for v in parts["counts"]] \
         + [v.name for v in parts["moe_in"]] \
-        + [v.name for v in parts["moe_out"]]
+        + [v.name for v in parts["moe_out"]] + whole_idx
     fp = FunctionalProgram(main.clone(for_test=True), feeds, fetches)
     key = jax.random.PRNGKey(args.seed)
     served = spec if args.routed_mantissa_bits is None else dict(
@@ -146,26 +172,40 @@ def main(argv=None):
     state["pos"] = jnp.zeros((rows,), jnp.int32)
     n_state = len(pairs)
 
-    def run(params, state, toks):
+    def run(params, state, blocks, steps):
         def body(state, tok):
             out, _ = fp(params, dict(state, tok=tok))
             new = {f: v for (f, _), v in zip(pairs, out[1:1 + n_state])}
-            return new, (out[0],) + tuple(out[1 + n_state:])
-        return jax.lax.scan(body, state, toks)[1]
+            # what T does not shape, and every position's indices
+            return new, ((out[0],) + tuple(out[1 + n_state:-moe]),
+                         tuple(i.reshape(rows, tok.shape[1], -1)
+                               for i in out[-moe:]))
+        # [applications, rows, T] tokens: the blocks, then the steps
+        state, (first, first_idx) = jax.lax.scan(body, state, blocks)
+        later, later_idx = jax.lax.scan(body, state, steps)[1]
+        # [applications, rows, T, top_k] -> [rows, positions, top_k]
+        every_idx = tuple(
+            jnp.concatenate([jnp.swapaxes(i, 0, 1).reshape(
+                rows, -1, i.shape[-1]) for i in pair], axis=1)
+            for pair in zip(first_idx, later_idx))
+        return jax.tree_util.tree_map(
+            lambda a, b: jnp.concatenate([a, b]), first, later), every_idx
 
-    out = jax.device_get(jax.jit(run)(params, state,
-                                      jnp.asarray(tokens.T)))
-    # [positions, rows, ...] -> [rows, positions, ...]
+    out, every_idx = jax.device_get(jax.jit(run)(
+        params, state,
+        jnp.asarray(np.moveaxis(tokens[:, :prefill].reshape(
+            rows, -1, chunk), 1, 0)),
+        jnp.asarray(tokens[:, prefill:].T[:, :, None])))
+    # [kept, rows, ...] -> [rows, kept, ...]
     got_logits = np.swapaxes(np.asarray(out[0], np.float32), 0, 1)
     got_hidden = [np.swapaxes(np.asarray(h, np.float32)[:, :, 0], 0, 1)
                   for h in out[1:1 + layers]]
-    moe = layers - dense
     got_idx = [np.swapaxes(np.asarray(i), 0, 1).reshape(
-        rows * positions, -1) for i in out[1 + layers:1 + layers + moe]]
+        rows * kept.size, -1) for i in out[1 + layers:1 + layers + moe]]
     at = 1 + layers + moe
-    counts = [np.asarray(c).sum(axis=0) for c in out[at:at + moe]]
+    counts = [np.asarray(c) for c in out[at:at + moe]]
     moe_in, moe_out = ([np.swapaxes(np.asarray(v, np.float32)[:, :, 0], 0,
-                                    1).reshape(rows * positions, -1)
+                                    1).reshape(rows * kept.size, -1)
                         for v in out[lo:lo + moe]]
                        for lo in (at + moe, at + 2 * moe))
     del params, out
@@ -202,13 +242,27 @@ def main(argv=None):
                 idx = got_idx[i - dense]
                 margins, own_idx = (np.asarray(a) for a in
                                     margin_of(block, x_own))
+                # the reference is handed the program's indices at every
+                # position; the kept ones are `parts`' own
+                handed = np.asarray(every_idx[i - dense])
+                ok &= np.array_equal(handed[:, kept],
+                                     idx.reshape(rows, kept.size, -1))
+                margins, own_idx = (
+                    a.reshape((rows, positions) + a.shape[1:])[:, kept]
+                    .reshape((-1,) + a.shape[1:])
+                    for a in (margins, own_idx))
                 x_own, _ = one(block, x_own, None)
-                x_same, _ = one(block, x_same, jnp.asarray(idx))
+                x_same, _ = one(block, x_same, jnp.asarray(
+                    handed.reshape(rows * positions, -1)))
                 same = (np.sort(idx, 1) == np.sort(own_idx, 1)).all(1)
                 widest = float(margins[~same].max()) if (~same).any() \
                     else 0.0
                 on_held = ((idx >= first) & (idx < first + held)).mean()
-                c = counts[i - dense]
+                # an application's counts are of all its positions: the
+                # steps' are held to the indices, the blocks' are shown
+                stepped = idx.reshape(rows, kept.size, -1)[
+                    :, prefill // chunk:]
+                c = counts[i - dense].sum(axis=0)
                 print("layer %d: %.2f%% of %d tokens take the reference's "
                       "own %d experts; the others' widest margin %.3g "
                       "(limit %.3g); %.2f%% of the assignments on held "
@@ -220,8 +274,8 @@ def main(argv=None):
                          c.mean(), c.max()), flush=True)
                 ok &= same.mean() >= args.same_routing \
                     and widest <= args.tie_margin
-                ok &= int(c.sum()) == int(
-                    ((idx >= first) & (idx < first + held)).sum())
+                ok &= int(counts[i - dense][prefill // chunk:].sum()) == int(
+                    ((stepped >= first) & (stepped < first + held)).sum())
                 want = np.asarray(routed_of(
                     block, jnp.asarray(moe_in[i - dense]), jnp.asarray(idx)))
                 off = rms(moe_out[i - dense], want)
@@ -230,20 +284,22 @@ def main(argv=None):
                       "of its root mean square (limit %.3g)"
                       % (i, off, args.routed_tol), flush=True)
                 ok &= off <= args.routed_tol
-            r_same = rms(got_hidden[i], np.asarray(x_same))
+            want_same, want_own = (np.asarray(x)[:, kept]
+                                   for x in (x_same, x_own))
+            r_same = rms(got_hidden[i], want_same)
             print("layer %d output: off the reference by %.5f of its root "
                   "mean square with the program's indices (limit %.3g; "
                   "%.4f at the widest), %.5f (%.4f) with its own"
                   % (i, r_same, args.hidden_tol,
-                     rel(got_hidden[i], np.asarray(x_same)),
-                     rms(got_hidden[i], np.asarray(x_own)),
-                     rel(got_hidden[i], np.asarray(x_own))), flush=True)
+                     rel(got_hidden[i], want_same),
+                     rms(got_hidden[i], want_own),
+                     rel(got_hidden[i], want_own)), flush=True)
             ok &= r_same <= args.hidden_tol
             del block
         eps = cfg["rms_norm_eps"]
         z_same, z_own = (np.asarray(
-            reference.rms_norm(x, ends["norm_f"], eps) @ ends["head"])
-            for x in (x_same, x_own))
+            reference.rms_norm(x[:, kept], ends["norm_f"], eps)
+            @ ends["head"]) for x in (x_same, x_own))
     for name, z in (("the program's indices", z_same),
                     ("the reference's own routing", z_own)):
         first_tok = got_logits.argmax(-1)

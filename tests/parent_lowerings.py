@@ -5,16 +5,44 @@ and a recording of what the parent commit gave for them
 a checkout of commit c5d60e4: `python tests/parent_lowerings.py >
 tests/data/parent_lowerings_pr37.json`).  tests/test_dsv32_program.py
 runs the recipes on the tree as it is and holds them to the recording:
-without their new inputs the ops lower as they did, and the builder
-called with pangu's arguments builds the Program it built.
+without their new inputs the ops lower as they did.
+
+Since PR 53 the latent builder's all-slots step takes a block of
+positions, so the builder called with pangu's arguments no longer builds
+the recorded "program" op for op: the test holds the new Program to the
+recording's products (every op that reads a parameter, with the
+parameters it reads) in the recording's order.  What PR 53 promised to
+leave alone has a recording of its own (`block_lowerings`,
+tests/data/parent_lowerings_pr52.json, written by `python
+tests/parent_lowerings.py blocks > tests/data/parent_lowerings_pr52.json`
+on a checkout of commit b6c67fc, PR 52's): the latent builder with an
+`indexer` (DeepSeek-V3.2's step, one position a call), the window/full
+and the linear/full builders, op for op, and the jaxpr of a generation
+call with a prompt of the three kinds of step that prefill in blocks of
+`models.decode.PREFILL_BLOCK` (GPT-2's, the window/full one, the
+linear/full one), a remainder block and one block of 128.
 """
 
 import json
 import os
 import sys
 
-RECORDING = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data",
-                         "parent_lowerings_pr37.json")
+_DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
+RECORDING = os.path.join(_DATA, "parent_lowerings_pr37.json")
+BLOCK_RECORDING = os.path.join(_DATA, "parent_lowerings_pr52.json")
+
+
+def program_text(main):
+    """A Program's ops, a line each: type, inputs, outputs, attrs."""
+    return "\n".join(
+        "%s(%s) -> %s %s" % (
+            od.type,
+            ", ".join("%s=%s" % (s, od.input(s))
+                      for s in sorted(od.input_names())),
+            ", ".join("%s=%s" % (s, od.output(s))
+                      for s in sorted(od.output_names())),
+            sorted(od.attrs.items()))
+        for od in main.global_block().desc.ops)
 
 
 def lowerings():
@@ -62,18 +90,83 @@ def lowerings():
             "mla_cached_attention", mla_ins, {"num_heads": h})
     main = build_latent_moe_cached_step_program(
         3, 12, 97, n_layer=3, n_dense=1, held=(2, 4))[0]
-    out["program"] = "\n".join(
-        "%s(%s) -> %s %s" % (
-            od.type,
-            ", ".join("%s=%s" % (s, od.input(s))
-                      for s in sorted(od.input_names())),
-            ", ".join("%s=%s" % (s, od.output(s))
-                      for s in sorted(od.output_names())),
-            sorted(od.attrs.items()))
-        for od in main.global_block().desc.ops)
+    out["program"] = program_text(main)
+    return out
+
+
+def block_lowerings():
+    """{name: text}: the step Programs PR 53 leaves as they are, and the
+    jaxpr of a greedy call with a prompt of 131 positions (a remainder
+    of 3, then a block of 128) and two more tokens through each kind of
+    step that prefills in blocks of 128, at the builders' tiny defaults
+    on seeded weights."""
+    import numpy as np
+
+    import jax
+    import jax.numpy as jnp
+
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.models.decode import greedy_decode
+    from paddle_tpu.models.latent_moe_program import (
+        build_latent_moe_cached_step_program)
+    from paddle_tpu.models.linear_moe_program import (
+        build_linear_moe_cached_step_program)
+    from paddle_tpu.models.transformer_program import (
+        build_transformer_cached_step_program)
+    from paddle_tpu.models.window_moe_program import (
+        build_window_moe_cached_step_program)
+
+    batch, extent, vocab, prompt_len = 2, 160, 97, 131
+    out = {"program indexer": program_text(
+        build_latent_moe_cached_step_program(
+            2, 48, vocab, n_layer=2, n_dense=1, held=(2, 4), eps=1e-6,
+            sandwich_norm=False, indexer=(8, 16, 8), n_group=4,
+            topk_group=2, router_bias=True,
+            yarn={"factor": 40, "original_positions": 16, "beta_fast": 32,
+                  "beta_slow": 1, "mscale": 1})[0])}
+
+    def call_text(built):
+        """The jaxpr of prefill and two steps through a decoder over the
+        step Program `built` = (main, startup, logits, state_pairs)."""
+        main, startup, logits, pairs = built[:4]
+        scope = fluid.Scope()
+        startup.random_seed = 3
+        fluid.Executor(fluid.CPUPlace()).run(startup, scope=scope)
+        decoder = fluid.ProgramDecoder(
+            main.clone(for_test=True), token_name="tok",
+            logits_name=logits.name, state_pairs=pairs, scope=scope,
+            max_positions=extent)
+        block = main.global_block()
+        state = {feed: jnp.zeros([n if n > 0 else batch
+                                  for n in block.var(feed).shape],
+                                 block.var(feed).dtype.replace("64", "32"))
+                 for feed, _ in pairs}
+
+        def call(params, state, prompt):
+            return decoder._prefilled_run(
+                params, state, prompt,
+                lambda step, st, first: greedy_decode(
+                    step, st, bos=first, eos=vocab, max_len=2,
+                    batch_size=batch, with_state=True)[::2], vocab, 3)
+
+        prompt = np.random.RandomState(1).randint(
+            0, vocab, (batch, prompt_len)).astype("int32")
+        return str(jax.make_jaxpr(call)(decoder._params, state, prompt))
+
+    for name, built in (
+            ("gpt2", build_transformer_cached_step_program(
+                batch, extent, vocab)),
+            ("window_moe", build_window_moe_cached_step_program(
+                batch, extent, vocab, held=(2, 4))),
+            ("linear_moe", build_linear_moe_cached_step_program(
+                batch, extent, vocab, held=(2, 4)))):
+        if name != "gpt2":
+            out["program %s" % name] = program_text(built[0])
+        out["call %s" % name] = call_text(built)
     return out
 
 
 if __name__ == "__main__":
     sys.path.insert(0, os.getcwd())
-    json.dump(lowerings(), sys.stdout, indent=1, sort_keys=True)
+    json.dump(block_lowerings() if sys.argv[1:] == ["blocks"]
+              else lowerings(), sys.stdout, indent=1, sort_keys=True)

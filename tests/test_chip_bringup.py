@@ -310,6 +310,15 @@ def test_the_latent_decode_kernel_lowers_for_tpu():
                   Live=[jax.ShapeDtypeStruct((b,), jnp.int32)])
     assert "tpu_custom_call" not in jax.export.export(
         jax.jit(step), platforms=["tpu"])(chosen).mlir_module()
+    # a prefill application of the cell: 16 positions a row, one kernel
+    # named with them
+    block = {k: [jax.ShapeDtypeStruct((b, 16) + v[0].shape[2:], bf16)]
+             if k in ("QNope", "QRope", "CNew", "RNew") else v
+             for k, v in ins.items()}
+    module = jax.export.export(jax.jit(step), platforms=["tpu"])(
+        block).mlir_module()
+    assert module.count("tpu_custom_call") == 1
+    assert 'kernel_name = "mla_decode_k256_t16"' in module
 
 
 @pytest.mark.parametrize("block,window,kernels", [

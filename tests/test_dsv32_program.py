@@ -20,6 +20,7 @@ its ramp lies inside the 4 pairs), vocabulary 97, seeded weights.
 """
 
 import json
+import re
 
 import numpy as np
 import pytest
@@ -527,14 +528,67 @@ def lowerings():
 
 @pytest.mark.parametrize("what", [
     "rope", "moe_router softmax", "moe_router sigmoid",
-    "mla_cached_attention float32", "mla_cached_attention bfloat16",
-    "program"])
+    "mla_cached_attention float32", "mla_cached_attention bfloat16"])
 def test_without_the_new_inputs_everything_lowers_as_the_parent(
         lowerings, what):
-    """The jaxpr of each op without its new inputs and attrs, and the
-    Program the builder makes from pangu's arguments, op for op, against
-    the recording made on the parent commit."""
+    """The jaxpr of each op without its new inputs and attrs (one
+    position a row: T = 1), against the recording made on the parent
+    commit."""
     recorded, now = lowerings
+    assert now[what] == recorded[what]
+
+
+def _products(program_text):
+    """[(op type, the parameters it reads)] of the ops of a recorded
+    Program that read a parameter, in order."""
+    found = []
+    for line in program_text.split("\n"):
+        kind, rest = line.split("(", 1)
+        names = re.findall(
+            r"((?:block_\d+\.|embed\.|head\.)\w+|norm_f)=",
+            rest.split(") -> ")[0])
+        if names:
+            found.append((kind, names))
+    return found
+
+
+def test_pangus_program_keeps_its_products_in_their_order(lowerings):
+    """The builder called with pangu's arguments takes a block of
+    positions since PR 53 and no longer builds the recorded Program op
+    for op (slices of the block's last position, positions read off the
+    feed): it is held to the recording's products, every op that reads a
+    parameter with the parameters it reads, in the recording's order;
+    what lies between them moves no arithmetic at T = 1
+    (tests/test_latent_moe_program.py holds the logits to the
+    reference's at 1e-5)."""
+    recorded, now = lowerings
+    assert _products(now["program"]) == _products(recorded["program"])
+    assert len(_products(recorded["program"])) > 40
+    # and what changed is what was meant to: the feed, the attr
+    assert "mla_cached_attention" in now["program"] \
+        and "'prefill_block'" in now["program"] \
+        and "'prefill_block'" not in recorded["program"]
+
+
+@pytest.fixture(scope="module")
+def block_lowerings():
+    with open(parent_lowerings.BLOCK_RECORDING) as f:
+        return json.load(f), parent_lowerings.block_lowerings()
+
+
+@pytest.mark.parametrize("what", [
+    "program indexer", "program window_moe", "program linear_moe",
+    "call gpt2", "call window_moe", "call linear_moe"])
+def test_what_the_block_form_leaves_alone_is_the_parents(block_lowerings,
+                                                         what):
+    """PR 53 gave the latent step a block of positions.  With an
+    `indexer` the builder builds the parent's Program op for op; the
+    window/full and linear/full builders moved onto the shared helpers
+    of `decoder_block` and build theirs op for op; and a generation call
+    through each step that prefills in blocks of 128 (a remainder, a
+    block, two steps) traces to the parent's jaxpr: against the
+    recording made on commit b6c67fc."""
+    recorded, now = block_lowerings
     assert now[what] == recorded[what]
 
 
@@ -708,7 +762,8 @@ def test_counters_say_what_was_lowered(built):
         "heads=%d,latent=%d,rope=%d,selected=%d}"
         % (H, KVR, ROPE, TOPK)] == L
     # a chosen set keeps the plain products, whatever the shapes
-    assert lowered["mla_decode_lowerings_total{block_k=0,path=plain}"] == L
+    assert lowered["mla_decode_lowerings_total{block_k=0,path=plain,"
+                   "positions=1}"] == L
     assert not [k for k in lowered if "path=kernel" in k]
     assert lowered[
         "moe_grouped_router_lowerings_total{experts=%d,groups=%d,kept=%d,"

@@ -5,8 +5,11 @@ against the plain float32 reference (models/reference/pangu_moe.py): the
 step driven position by position through its cache against the
 reference's unabsorbed full-sequence forward; the shares of an expert
 layer adding up to the uncut layer; the router; a bfloat16 cache against
-the float32 one; the counters; and `ProgramDecoder` taking the scope's
-arrays as they are.
+the float32 one; the counters; `ProgramDecoder` taking the scope's
+arrays as they are; and a block of T > 1 positions (PR 53): the op and
+the kernel against T single steps, the step Program prefilled in blocks
+of its own `prefill_block` against the scanned single steps and the
+reference.
 
 Tiny sizes on the CPU: 3 layers (1 dense), hidden 64, 4 heads of 16 + 8
 (values 16), query rank 32, latent 16, 8 experts scored of which 4 are
@@ -22,6 +25,7 @@ import jax.numpy as jnp
 
 import paddle_tpu.fluid as fluid
 from paddle_tpu.fluid.param_attr import ParamAttr
+from paddle_tpu.models import decode, latent_moe_program
 from paddle_tpu.models.latent_moe_program import (
     build_latent_moe_cached_step_program, latent_moe_param_names)
 from paddle_tpu.models.reference import pangu_moe as reference
@@ -147,16 +151,26 @@ def test_greedy_through_the_decoder_is_the_references_greedy(built):
     assert gap.max() <= 1e-4
 
 
-def test_the_shares_one_token_step_is_prefilled_a_position_at_a_time(built):
-    """The share's step declares `tok` [batch]: its prefill stays the
-    scan of single positions."""
-    assert not built["decoder"]._takes_block
+def test_the_shares_step_is_prefilled_in_blocks_of_its_own(built):
+    """The share's step declares `tok` [batch, -1] and states the block
+    it is prefilled by: at these tiny widths `PREFILL_BLOCK`, so a prompt
+    of 3 is one application of 3."""
+    decoder = built["decoder"]
+    assert decoder._takes_block
+    assert decoder._prefill_block == decode.PREFILL_BLOCK \
+        == latent_moe_program.prefill_block(B, H, KVR, ROPE)
     before = telemetry.snapshot()
-    built["decoder"].greedy(bos=0, eos=V, max_len=2, init_state=_empty(),
-                            prompt=built["tokens"][:, :3])
-    counted = {k: v for k, v in telemetry.snapshot_delta(before).items()
-               if k.startswith("prefill_lowerings_total")}
-    assert counted == {"prefill_lowerings_total{block=1,form=step}": 1}
+    decoder.greedy(bos=0, eos=V, max_len=2, init_state=_empty(),
+                   prompt=built["tokens"][:, :3])
+    lowered = telemetry.snapshot_delta(before)
+    assert {k: v for k, v in lowered.items()
+            if k.startswith("prefill_lowerings_total")} == {
+        "prefill_lowerings_total{block=%d,form=block}"
+        % decode.PREFILL_BLOCK: 1}
+    assert {k: v for k, v in lowered.items()
+            if k.startswith("mla_decode_lowerings_total")} == {
+        "mla_decode_lowerings_total{block_k=0,path=plain,positions=3}": L,
+        "mla_decode_lowerings_total{block_k=0,path=plain,positions=1}": L}
 
 
 def test_a_position_past_the_cache_is_refused(built):
@@ -297,10 +311,10 @@ def test_the_walk_of_live_slots_is_the_plain_path(pos, dtype, atol,
 
     ins = _walk_ins(np.random.RandomState(pos), dtype, pos)
     paths, walked = _mla_paths(ins)
-    assert paths == {"{block_k=%d,path=kernel}" % WBK: 1}
+    assert paths == {"{block_k=%d,path=kernel,positions=1}" % WBK: 1}
     monkeypatch.setattr(mla_decode, "fits", lambda *shape: False)
     paths, plain = _mla_paths(ins)
-    assert paths == {"{block_k=0,path=plain}": 1}
+    assert paths == {"{block_k=0,path=plain,positions=1}": 1}
     assert walked["Out"][0].dtype == plain["Out"][0].dtype == dtype
     np.testing.assert_allclose(
         np.asarray(walked["Out"][0], np.float32),
@@ -321,7 +335,7 @@ def test_the_walk_reads_nothing_past_the_position(pos):
     dirty = dict(clean, Cache=[clean["Cache"][0].at[:, pos + 1:].set(
         jnp.nan)])
     paths, got = _mla_paths(dirty)
-    assert list(paths) == ["{block_k=%d,path=kernel}" % WBK]
+    assert list(paths) == ["{block_k=%d,path=kernel,positions=1}" % WBK]
     np.testing.assert_array_equal(got["Out"][0],
                                   _mla_paths(clean)[1]["Out"][0])
 
@@ -355,7 +369,8 @@ def test_what_the_walk_does_not_take_is_the_plain_path(why, change):
     jaxpr = jax.make_jaxpr(
         lambda i: kernel(None, i, {"num_heads": H})["Out"][0])(ins)
     lowered = telemetry.snapshot_delta(before)
-    assert lowered["mla_decode_lowerings_total{block_k=0,path=plain}"] == 1
+    assert lowered["mla_decode_lowerings_total{block_k=0,path=plain,"
+                   "positions=1}"] == 1
     assert "pallas_call" not in str(jaxpr) and "platform_index" not in \
         str(jaxpr), why
 
@@ -392,12 +407,417 @@ def test_the_walks_blocks_are_chosen_from_the_shapes():
     assert mla_decode.choose_blocks(WB, 4, WP, WL + WR, WL, 4) == (WBK, 2)
     assert mla_decode.choose_blocks(3, 4, WP, WL + WR, WL, 4) == (WBK, 1)
     assert mla_decode.fits(1, 1024, 512) and mla_decode.fits(1, 128, 128)
-    assert not mla_decode.fits(2, 1024, 512)
     assert not mla_decode.fits(1, 1000, 512)
     assert not mla_decode.fits(1, 1024, 320)
     with pytest.raises(ValueError, match="no step the kernel takes"):
         mla_decode.mla_decode(jnp.zeros((2, 4, 24)), jnp.zeros((2, 6, 24)),
                               0, 1.0, 16)
+
+
+# -- a block of T positions: the op, the kernel, the step Program (PR 53) ------
+
+def _block_ins(rs, block, pos, dtype=jnp.float32, shape=None):
+    """The op's inputs for `block` consecutive positions from `pos` on;
+    `shape` (rows, slots, latent, rope) defaults to the tiny one."""
+    rows, slots, latent, rope = shape or (B, T + 8, KVR, ROPE)
+
+    def draw(*size):
+        return jnp.asarray(rs.randn(*size), dtype)
+
+    cache = draw(rows, slots, latent + rope).at[:, pos:].set(0)
+    return {"QNope": [draw(rows, block, H * NOPE)],
+            "QRope": [draw(rows, block, H * rope)],
+            "CNew": [draw(rows, block, latent)],
+            "RNew": [draw(rows, block, rope)], "Cache": [cache],
+            "WUk": [0.3 * draw(latent, H * NOPE)],
+            "WUv": [0.3 * draw(latent, H * DV)],
+            "Position": [jnp.full((rows,), pos, jnp.int32)]}
+
+
+def _step_by_step(ins):
+    """(Out [rows, T, .], CacheOut) of the op applied a position at a
+    time over the block of `ins`."""
+    kernel = registry.get_op_info("mla_cached_attention").kernel
+    block = ins["QNope"][0].shape[1]
+    pos, cache, outs = int(ins["Position"][0][0]), ins["Cache"][0], []
+    for t in range(block):
+        one = {k: [v[0][:, t:t + 1]] if k in ("QNope", "QRope", "CNew",
+                                              "RNew") else v
+               for k, v in ins.items()}
+        got = kernel(None, dict(one, Cache=[cache], Position=[
+            jnp.full_like(ins["Position"][0], pos + t)]), {"num_heads": H})
+        cache = got["CacheOut"][0]
+        outs.append(got["Out"][0])
+    return jnp.concatenate(outs, axis=1), cache
+
+
+@pytest.mark.parametrize("block", [1, 3, 16])
+@pytest.mark.parametrize("pos", [0, 4])
+def test_a_block_of_positions_is_so_many_single_steps(block, pos):
+    """T positions at once against T applications of one: the output of
+    every position and the cache, from an empty cache and from a
+    position inside a session."""
+    ins = _block_ins(np.random.RandomState(block + pos), block, pos)
+    got = registry.get_op_info("mla_cached_attention").kernel(
+        None, ins, {"num_heads": H})
+    want, cache = _step_by_step(ins)
+    assert got["Out"][0].shape == (B, block, H * DV)
+    np.testing.assert_allclose(got["Out"][0], want, atol=2e-5)
+    np.testing.assert_array_equal(got["CacheOut"][0], cache)
+    # slots pos .. pos + T - 1 hold the block's entries, in order
+    np.testing.assert_array_equal(
+        np.asarray(got["CacheOut"][0])[:, pos:pos + block],
+        np.concatenate([np.asarray(ins["CNew"][0]),
+                        np.asarray(ins["RNew"][0])], axis=-1))
+
+
+def test_a_block_with_a_chosen_set_is_refused():
+    """A chosen set is one position's: `Selected` with T > 1 raises and
+    says so; with T = 1 it is the step it was."""
+    ins = dict(_block_ins(np.random.RandomState(3), 2, 4),
+               Selected=[jnp.zeros((B, 4), jnp.int32)],
+               Live=[jnp.full((B,), 4, jnp.int32)])
+    kernel = registry.get_op_info("mla_cached_attention").kernel
+    with pytest.raises(ValueError, match="Selected"):
+        kernel(None, ins, {"num_heads": H})
+    one = {k: [v[0][:, :1]] if k in ("QNope", "QRope", "CNew", "RNew")
+           else v for k, v in ins.items()}
+    assert kernel(None, one, {"num_heads": H})["Out"][0].shape \
+        == (B, 1, H * DV)
+
+
+def test_a_block_longer_than_the_op_was_sized_for_is_refused():
+    ins = _block_ins(np.random.RandomState(3), 4, 0)
+    kernel = registry.get_op_info("mla_cached_attention").kernel
+    with pytest.raises(ValueError, match="sized for 2"):
+        kernel(None, ins, {"num_heads": H, "prefill_block": 2})
+    assert kernel(None, ins, {"num_heads": H, "prefill_block": 4})[
+        "Out"][0].shape == (B, 4, H * DV)
+
+
+@pytest.mark.parametrize("dtype,atol", [(jnp.float32, 3e-5),
+                                        (jnp.bfloat16, 6e-2)])
+@pytest.mark.parametrize("block,pos", [(16, 0), (16, WBK - 5),
+                                       (32, 2 * WBK - 32), (48, WBK - 17)])
+def test_the_walk_takes_a_block_of_positions(block, pos, dtype, atol,
+                                             monkeypatch):
+    """The kernel at T > 1 against the op's plain path on the same
+    inputs: from an empty cache, a block that straddles two blocks of
+    slots (positions 123 .. 138 of blocks of 128), two tiles that end
+    with a block of slots, and three tiles of which the middle one
+    straddles."""
+    from paddle_tpu.kernels import mla_decode
+
+    ins = _block_ins(np.random.RandomState(pos), block, pos, dtype,
+                     (WB, WP, WL, WR))
+    paths, walked = _mla_paths(ins)
+    assert paths == {"{block_k=%d,path=kernel,positions=%d}"
+                     % (WBK, block): 1}
+    monkeypatch.setattr(mla_decode, "fits", lambda *shape: False)
+    paths, plain = _mla_paths(ins)
+    assert paths == {"{block_k=0,path=plain,positions=%d}" % block: 1}
+    # (an entry of 8 is a bfloat16 step of 0.06 from its neighbour)
+    np.testing.assert_allclose(
+        np.asarray(walked["Out"][0], np.float32),
+        np.asarray(plain["Out"][0], np.float32), atol=atol, rtol=atol / 6)
+    np.testing.assert_array_equal(
+        np.asarray(walked["CacheOut"][0], np.float32),
+        np.asarray(plain["CacheOut"][0], np.float32))
+
+
+@pytest.mark.parametrize("block", [3, 8, 24])
+def test_a_block_of_no_whole_tiles_is_the_plain_paths(block):
+    """The kernel walks tiles of 16 positions: another count of them (a
+    prompt's remainder block) takes the plain products, which take any."""
+    paths, got = _mla_paths(_block_ins(np.random.RandomState(block), block,
+                                       5, shape=(WB, WP, WL, WR)))
+    assert paths == {"{block_k=0,path=plain,positions=%d}" % block: 1}
+    assert got["Out"][0].shape == (WB, block, H * DV)
+
+
+@pytest.mark.parametrize("blocks", [(128, 4), (128, 2), (128, 1), (384, 4)])
+def test_a_block_gives_the_same_at_any_blocks(blocks):
+    """Two tiles of 16 positions from slot 120 on (the first straddles
+    two blocks of 128 slots, the second lies in one), every head a grid
+    step, two or one, over blocks of 128 slots or one of 384: the same
+    sums, each position's over its own live slots, and nothing past a
+    position's bound (NaN there) reaches them."""
+    from paddle_tpu.kernels import mla_decode
+
+    rs = np.random.RandomState(6)
+    block, pos = 32, 120
+    q_lat = jnp.asarray(rs.randn(H, WB * block, WL), jnp.float32)
+    q_rope = jnp.asarray(rs.randn(H, WB * block, WR), jnp.float32)
+    cache = jnp.asarray(rs.randn(WB, WP, WL + WR), jnp.float32)
+    got = mla_decode.mla_decode_block(
+        q_lat, q_rope, cache.at[:, pos + block:].set(jnp.nan),
+        jnp.int32(pos), 0.1, blocks).reshape(H, WB, block, WL)
+    q = jnp.concatenate([q_lat, q_rope], axis=-1).reshape(
+        H, WB, block, WL + WR)
+    for t in range(block):
+        live = cache[:, :pos + t + 1]
+        s = jnp.einsum("hbw,bsw->bhs", q[:, :, t], live) * 0.1
+        want = jnp.einsum("bhs,bsw->hbw", jax.nn.softmax(s, -1),
+                          live[..., :WL])
+        np.testing.assert_allclose(got[:, :, t], want, atol=3e-5)
+
+
+def test_a_blocks_blocks_are_chosen_from_the_shapes():
+    """pangu-decode-ep16's prefill application (256 rows x 16 positions
+    of 128 heads) walks 64 heads a grid step, 1024 query rows, over
+    blocks of 256 slots; fewer heads go whole; a block is whole tiles of
+    16 positions."""
+    from paddle_tpu.kernels import mla_decode
+
+    assert mla_decode.choose_group(128, 1024, 64, 512, 2) == (256, 64)
+    assert mla_decode.choose_group(128, 768, 64, 512, 2) == (256, 64)
+    assert mla_decode.choose_group(96, 1024, 64, 512, 2) == (512, 48)
+    assert mla_decode.choose_group(H, WP, WR, WL, 4) == (WBK, H)
+    assert mla_decode.fits(16, 1024, 512) and mla_decode.fits(128, 128, 128)
+    assert not mla_decode.fits(2, 1024, 512)
+    assert not mla_decode.fits(24, 1024, 512)
+    assert not mla_decode.fits(16, 1000, 512)
+    zeros = jnp.zeros
+    with pytest.raises(ValueError, match="do not tile"):
+        mla_decode.mla_decode_block(
+            zeros((H, 32, WL)), zeros((H, 32, WR)),
+            zeros((2, WP, WL + WR)), 0, 1.0, (128, 3))
+    with pytest.raises(ValueError, match="no block the kernel takes"):
+        mla_decode.mla_decode_block(
+            zeros((H, 16, WL)), zeros((H, 16, WR)),
+            zeros((2, WP, WL + WR)), 0, 1.0)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("heads,head,rotary", [
+    (4, 16, 0), (1, 64, 0), (3, 24, 8), (128, 64, 0), (2, 256, 64)])
+def test_rope_turns_a_block_where_it_lies(heads, head, rotary, dtype):
+    """`full_width` against the view of half heads on a block of 5
+    positions: heads that tile 128 lanes, one head, a rotary part of a
+    head that tiles nothing, pangu's 128 heads of 64, two lane tiles a
+    head.  The same products and sums: equal to a rounding of the
+    float32 sum (a compiler may fuse a product into it)."""
+    rs = np.random.RandomState(heads)
+    x = jnp.asarray(rs.randn(2, 5, heads * head), dtype)
+    pos = jnp.asarray(rs.randint(0, 1000, (2, 5)), jnp.int32)
+    attrs = {"num_heads": heads, "theta": 25600000.0}
+    if rotary:
+        attrs["rotary_dim"] = rotary
+    rope = registry.get_op_info("rope").kernel
+
+    def turned(**more):
+        return np.asarray(jax.jit(lambda x, pos: rope(
+            None, {"X": [x], "Positions": [pos]},
+            dict(attrs, **more))["Out"][0])(x, pos), np.float32)
+
+    np.testing.assert_allclose(
+        turned(full_width=True), turned(), rtol=0,
+        atol=1e-6 if dtype == jnp.float32 else 2 ** -7 * 4)
+
+
+def test_rope_turns_one_position_as_it_did():
+    """With `full_width` a single position lowers to what it lowers to
+    without: the decode step of a block-taking builder is the parent's."""
+    rope = registry.get_op_info("rope").kernel
+    x, pos = jnp.zeros((3, 1, 4 * 16)), jnp.zeros((3, 1), jnp.int32)
+
+    def jaxpr(**more):
+        return str(jax.make_jaxpr(lambda x, pos: rope(
+            None, {"X": [x], "Positions": [pos]},
+            dict({"num_heads": 4}, **more))["Out"][0])(x, pos))
+
+    assert jaxpr(full_width=True) == jaxpr()
+    assert "dot_general" in str(jax.make_jaxpr(lambda x, pos: rope(
+        None, {"X": [x], "Positions": [pos]},
+        {"num_heads": 4, "full_width": True})["Out"][0])(
+            jnp.zeros((3, 2, 64)), jnp.zeros((3, 2), jnp.int32)))
+
+
+def test_only_the_block_taking_step_asks_for_it(built):
+    """The all-slots builder sets `full_width` on its rope ops; with an
+    `indexer` (one position a call) none carries it."""
+    ropes = [od for od in built["main"].global_block().desc.ops
+             if od.type == "rope"]
+    assert len(ropes) == 2 * L and all(od.attrs.get("full_width")
+                                       for od in ropes)
+    indexed = build_latent_moe_cached_step_program(
+        B, T, V, **dict(SIZES, indexer=(2, 8, 4)))[0]
+    assert not [od for od in indexed.global_block().desc.ops
+                if od.type == "rope" and "full_width" in od.attrs]
+
+
+SMALL_BLOCK = 4
+
+
+@pytest.fixture(scope="module")
+def blocked(built):
+    """The step Program built where its byte bound allows SMALL_BLOCK
+    positions an application, over `built`'s weights."""
+    patch = pytest.MonkeyPatch()
+    # 3 rows x 4 heads x (16 + 16 + 8) values x 2 bytes a position
+    patch.setattr(latent_moe_program, "_BLOCK_BYTES",
+                  2 * SMALL_BLOCK * B * H * (2 * KVR + ROPE) * 2 - 1)
+    try:
+        main, _, logits, pairs, parts = \
+            build_latent_moe_cached_step_program(B, T, V, **SIZES)
+    finally:
+        patch.undo()
+    return {"main": main, "parts": parts, "pairs": pairs, "logits": logits,
+            "decoder": _decoder(main, logits, pairs, built["scope"])}
+
+
+def test_the_step_states_the_block_it_is_prefilled_by(blocked, built):
+    """The builder derives the block from its rows and widths under its
+    byte bound, the attention ops carry it, and the decoder reads it off
+    the Program (a clone keeps it); a step that states none (GPT-2's)
+    gets `PREFILL_BLOCK`, and one that takes one position none at all."""
+    assert blocked["decoder"]._prefill_block == SMALL_BLOCK
+    stated = [od.attrs["prefill_block"]
+              for od in blocked["main"].global_block().desc.ops
+              if od.type == "mla_cached_attention"]
+    assert stated == [SMALL_BLOCK] * L
+    assert latent_moe_program.prefill_block(256, 128, 512, 64) == 16
+    assert latent_moe_program.prefill_block(8, 128, 512, 64) == 128
+    assert latent_moe_program.prefill_block(4096, 128, 512, 64) == 1
+    main, startup, logits, pairs = build_transformer_cached_step_program(
+        B, T, 31, n_layer=1, n_head=2, d_model=32)
+    scope = fluid.Scope()
+    fluid.Executor(fluid.CPUPlace()).run(startup, scope=scope)
+    gpt2 = fluid.ProgramDecoder(main.clone(for_test=True), "tok",
+                                logits.name, pairs, scope=scope)
+    assert gpt2._takes_block \
+        and gpt2._prefill_block == decode.PREFILL_BLOCK
+    before = telemetry.snapshot()
+    jax.make_jaxpr(lambda p, s, t: decode.prefill(
+        gpt2._step_fn(p), s, t, True, gpt2._prefill_block))(
+            gpt2._params,
+            {name: jnp.zeros((B, 2, T, 16)) if name != "pos"
+             else jnp.zeros((B,), jnp.int32) for name, _ in pairs},
+            jnp.zeros((B, 5), jnp.int32))
+    assert telemetry.snapshot_delta(before)[
+        "prefill_lowerings_total{block=%d,form=block}"
+        % decode.PREFILL_BLOCK] == 1
+    indexed = build_latent_moe_cached_step_program(
+        B, T, V, **dict(SIZES, indexer=(2, 8, 4)))[0]
+    assert tuple(indexed.global_block().var("tok").shape) == (B,)
+    assert not [od for od in indexed.global_block().desc.ops
+                if "prefill_block" in od.attrs]
+
+
+def _in_blocks(decoder, tokens, sizes, state):
+    """([B, len(sizes), V] logits after each block's last position, the
+    state): the step applied to consecutive blocks of `sizes`."""
+    step = decoder._step_fn(decoder._params)
+    out, at = [], 0
+    for size in sizes:
+        logits, state = step(state, jnp.asarray(tokens[:, at:at + size]))
+        out.append(np.asarray(logits, np.float32))
+        at += size
+    return np.stack(out, axis=1), state
+
+
+@pytest.mark.parametrize("sizes", [(SMALL_BLOCK,) * 3, (3, 4, 4, 1),
+                                   (1, 2, 4, 4, 1)])
+def test_blocks_of_positions_are_the_single_steps(blocked, built, sizes):
+    """The step fed blocks of positions against the same step fed a
+    position at a time (the fixture's drive) and against the float32
+    reference: the logits after each block, and the caches."""
+    got, state = _in_blocks(blocked["decoder"], built["tokens"], sizes,
+                            _empty())
+    ends = np.cumsum(sizes) - 1
+    scale = np.abs(built["got"]).max()
+    assert np.abs(got - built["got"][:, ends]).max() <= LOGITS_RTOL * scale
+    want = np.asarray(built["want"]["logits"])[:, ends]
+    assert np.abs(got - want).max() <= LOGITS_RTOL * np.abs(want).max()
+    assert int(state["pos"][0]) == sum(sizes)
+    for i in range(L):
+        np.testing.assert_allclose(
+            state["latent_cache_%d" % i][:, :sum(sizes)],
+            built["state"]["latent_cache_%d" % i][:, :sum(sizes)],
+            atol=1e-5)
+
+
+@pytest.mark.parametrize("prompt_len", [SMALL_BLOCK, 2 * SMALL_BLOCK + 1])
+def test_prefill_in_blocks_then_greedy_steps(blocked, built, prompt_len):
+    """`ProgramDecoder.greedy` over the block-taking step (a remainder
+    block first, then a scan of blocks of SMALL_BLOCK, then the scan of
+    steps) gives the tokens of the same decoder prefilled a position at
+    a time, and every one is the reference's first (or within rounding
+    of it)."""
+    decoder, gen = blocked["decoder"], T - prompt_len
+    prompt = built["tokens"][:, :prompt_len]
+    before = telemetry.snapshot()
+    tokens, _ = decoder.greedy(bos=0, eos=V, max_len=gen,
+                               init_state=_empty(), prompt=prompt)
+    lowered = telemetry.snapshot_delta(before)
+    assert lowered["prefill_lowerings_total{block=%d,form=block}"
+                   % SMALL_BLOCK] == 1
+    assert {k.split("positions=")[1][:-1] for k in lowered
+            if k.startswith("mla_decode_lowerings_total")} == {
+        str(n) for n in (prompt_len % SMALL_BLOCK, SMALL_BLOCK, 1) if n}
+
+    def stepped(params, state, prompt):
+        step = decoder._step_fn(params)
+        state, first = decode.prefill(step, state, prompt)
+        return first, decode.greedy_decode(step, state, first, V, gen - 1,
+                                           B)[0]
+
+    first, rest = jax.jit(stepped)(decoder._params, _empty(),
+                                   jnp.asarray(prompt))
+    np.testing.assert_array_equal(
+        tokens, np.concatenate([np.asarray(first)[:, None],
+                                np.asarray(rest)], axis=1))
+    full = np.concatenate([prompt, tokens], axis=1)[:, :T]
+    z = np.asarray(reference.forward(
+        CFG, built["params"], jnp.asarray(full), held=HELD)["logits"])
+    z = z[:, prompt_len - 1:prompt_len - 1 + gen]
+    picked = np.take_along_axis(z, tokens[..., None], axis=-1)[..., 0]
+    assert (z.max(axis=-1) - picked).max() <= 1e-4
+
+
+@pytest.mark.parametrize("size", [1, 3, SMALL_BLOCK])
+def test_parts_are_of_the_blocks_last_position(blocked, built, size):
+    """`parts` keep their shapes at T = 1 and at T > 1 (a decoder carries
+    them through its scans), and hold the block's last position: what
+    the single steps gave at that position."""
+    from paddle_tpu.jit import FunctionalProgram
+
+    main, parts, pairs = blocked["main"], blocked["parts"], blocked["pairs"]
+    keys = ("hidden", "attn_in", "attn_out", "top_w", "top_idx", "moe_in",
+            "moe_out", "counts")
+    names = [v.name for key in keys for v in parts[key]]
+    feeds = ["tok"] + [f for f, _ in pairs]
+
+    def fetch(tokens, state):
+        fp = FunctionalProgram(main.clone(for_test=True), feeds,
+                               names + [o for _, o in pairs])
+        out, _ = fp(blocked["decoder"]._params,
+                    dict(state, tok=jnp.asarray(tokens)))
+        return out[:len(names)], dict(zip([f for f, _ in pairs],
+                                          out[len(names):]))
+
+    got, _ = fetch(built["tokens"][:, :size], _empty())
+    state, counts = _empty(), 0
+    for t in range(size):
+        want, state = fetch(built["tokens"][:, t:t + 1], state)
+        counts = counts + np.asarray(want[-(L - DENSE):])
+    shapes = {"hidden": (B, 1, D), "attn_in": (B, 1, D),
+              "attn_out": (B, 1, D), "top_w": (B, K), "top_idx": (B, K),
+              "moe_in": (B, 1, D), "moe_out": (B, 1, D),
+              "counts": (HELD[1],)}
+    at = 0
+    for key in keys:
+        for _ in parts[key]:
+            assert got[at].shape == shapes[key], key
+            if key == "counts":     # the whole block's
+                continue
+            if key == "top_idx":
+                np.testing.assert_array_equal(got[at], want[at])
+            else:
+                np.testing.assert_allclose(got[at], want[at], atol=2e-5,
+                                           err_msg=key)
+            at += 1
+    np.testing.assert_array_equal(np.asarray(got[-(L - DENSE):]), counts)
 
 
 # -- (b) the shares add up ------------------------------------------------------
@@ -628,7 +1048,8 @@ def test_counters_say_what_was_lowered(built):
     # one count an op instance a traced step holds
     assert lowered[mla] == L
     # a latent of 16 is nothing the walk of live slots takes
-    assert lowered["mla_decode_lowerings_total{block_k=0,path=plain}"] == L
+    assert lowered["mla_decode_lowerings_total{block_k=0,path=plain,"
+                   "positions=1}"] == L
     assert lowered[share] == L - DENSE
     assert lowered["moe_lowerings_total{experts=%d,top_k=%d}"
                    % (HELD[1], K)] == L - DENSE

@@ -308,7 +308,7 @@ def _listing(main):
 
 
 @pytest.mark.parametrize("build,options,digest", [
-    (build_latent_moe_cached_step_program, {}, "76fca9b464257f60"),
+    (build_latent_moe_cached_step_program, {}, "ad44034b7e904781"),
     (build_latent_moe_cached_step_program,
      dict(sandwich_norm=False, indexer=(2, 8, 4), n_group=4, topk_group=2,
           router_bias=True, yarn={
@@ -321,7 +321,8 @@ def test_the_other_shares_programs_are_op_for_op_what_they_were(
     """`share_feed_forward` took `scoring` and `shared_gate`: with
     neither given, the three served shares' Programs are what they were
     (the latent builder's digests are tests/test_window_moe_program.py's,
-    of commit 92c5422; the window builder's is taken from the parent's
+    of commit 92c5422 with an `indexer` and of PR 53's block-taking step
+    without; the window builder's is taken from the parent's
     `share_feed_forward`, rebuilt here)."""
     main = build(2, 16, 97, **options)[0]
     got = hashlib.sha256(_listing(main).encode()).hexdigest()[:16]
