@@ -967,7 +967,7 @@ def causal_conv1d(input, filter_size=4, activation="silu", param_attr=None,
 
 
 def gated_delta_rule(q, k, v, g, beta, state, qk_l2norm=True, chunk=64,
-                     name=None):
+                     name=None, gate_floor=None):
     """The gated delta rule over a block of T >= 1 consecutive positions
     of every row, through a recurrent state (ops/linear_attention.py
     gated_delta_rule; T = 1 is a decode step): `q`, `k` [batch, T, key
@@ -977,19 +977,30 @@ def gated_delta_rule(q, k, v, g, beta, state, qk_l2norm=True, chunk=64,
     head, position by position: S = exp(g) S; S += k (beta (v - S^T
     k))^T; o = S^T q, with q and k l2-normed a head and q scaled by
     key_dim ** -0.5 under `qk_l2norm`; value head j reads key head j //
-    (value heads / key heads).  T may be left open (-1) in the Program.
+    (value heads / key heads).  With `g` [batch, T, value heads *
+    key_dim] the gate is one a key channel (Kimi Delta Attention): S =
+    diag(exp(g)) S.  Such a caller states `gate_floor`, the least value
+    an element of `g` takes (< 0), from which the block form's
+    sub-blocks are sized so that its one growing factor stays inside
+    float32 (the op's `sub_chunk`).  T may be left open (-1) in the
+    Program.
     Returns (out [batch, T, value heads * value_dim], state_out): thread
     `state_out` back as decode state (`fluid.ProgramDecoder` state
     pairs).  Forward only."""
     helper = LayerHelper("gated_delta_rule", name=name)
     out = helper.create_tmp_variable(v.dtype)
     state_out = helper.create_tmp_variable(state.dtype)
+    attrs = {"qk_l2norm": bool(qk_l2norm), "chunk": int(chunk)}
+    # an op carries only what it was given: a gate a head's is the op it
+    # was
+    if gate_floor is not None:
+        from ...ops.linear_attention import sub_chunk
+        attrs["sub_chunk"] = sub_chunk(int(chunk), float(gate_floor))
     helper.append_op(
         type="gated_delta_rule",
         inputs={"Q": [q], "K": [k], "V": [v], "G": [g], "Beta": [beta],
                 "State": [state]},
-        outputs={"Out": [out], "StateOut": [state_out]},
-        attrs={"qk_l2norm": bool(qk_l2norm), "chunk": int(chunk)})
+        outputs={"Out": [out], "StateOut": [state_out]}, attrs=attrs)
     return out, state_out
 
 

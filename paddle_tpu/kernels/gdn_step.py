@@ -7,6 +7,10 @@ place.
 
     S  = exp(g) S;  r = S^T k;  S = S + k (beta (v - r))^T;  o = S^T q
 
+or, under a gate a key channel (g[B, H, Dk]: Kimi Delta Attention's),
+`S = diag(exp(g)) S`, row d of a head's state by its own exp(g[d]); the
+other three lines as they are.
+
 q and k come normed and scaled, float32; value head j reads key head
 j // (H / Hk).  A step of Qwen3-Next's share moves 2.1 MB of float32
 state a row and layer in and out again, and nothing else of its size:
@@ -29,7 +33,10 @@ diagonal, summed over the lanes.  One pair of columns serves the value
 heads that share a key head.  The decay, beta and beta * v arrive as
 rows [1, Dv] (the two scalars broadcast along the lanes by the caller:
 2 x 16 KB a row of the batch beside 2.1 MB of state), so no scalar is
-read out of a vector.
+read out of a vector.  A gate a key channel arrives as the row [1, Dk]
+it is (the operand's shape is the broadcast scalar's, Dk = Dv) and is
+made a column like k and q, a third a value head: the state's row d
+times element d.
 
 Which shapes it takes (`choose_heads`): a float32 state of 128 x 128 a
 head (the lanes, and a column the sublanes tile), and a block of value
@@ -37,7 +44,10 @@ heads that holds whole key heads and tiles the sublanes of the [Hk, Dk]
 and [H, Dv] operands.  The op asks, and keeps its plain path otherwise.
 
 Lowered for the TPU this is a Mosaic kernel named `gdn_step_r<rows>_h<
-heads>` (rows of the batch, value heads a grid step); lowered for any
+heads>` (rows of the batch, value heads a grid step) under a gate a
+head and `kda_step_r<rows>_h<heads>` under a gate a key channel (one
+body, the decay a row or a column; a trace's readers tell Gated
+DeltaNet's steps from KDA's by the prefix); lowered for any
 other platform the caller's plain step runs in its place (`step`'s
 `plain`, as kernels/ssd.py's entries take theirs; `interpret=True` runs
 the kernel's body under the Pallas interpreter: tests).
@@ -72,7 +82,7 @@ def choose_heads(rows, heads, key_heads, key_dim, value_dim, dtype):
 
 
 def _kernel(q_ref, k_ref, bv_ref, decay_ref, beta_ref, s_ref, o_ref, so_ref,
-            *, heads, group):
+            *, heads, group, channel):
     size = q_ref.shape[-1]
     diagonal = lax.broadcasted_iota(jnp.int32, (size, size), 0) \
         == lax.broadcasted_iota(jnp.int32, (size, size), 1)
@@ -87,7 +97,10 @@ def _kernel(q_ref, k_ref, bv_ref, decay_ref, beta_ref, s_ref, o_ref, so_ref,
         q_col = column(q_ref[0, pl.ds(key_head, 1), :])
         for j in range(key_head * group, (key_head + 1) * group):
             at = pl.ds(j, 1)
-            s = s_ref[0, j] * decay_ref[0, at, :]
+            # one decay a head along the lanes as it comes, or one a
+            # key channel down the sublanes
+            s, decay = s_ref[0, j], decay_ref[0, at, :]
+            s = s * (column(decay) if channel else decay)
             held = jnp.sum(s * k_col, axis=0, keepdims=True)
             delta = bv_ref[0, at, :] - beta_ref[0, at, :] * held
             s = s + k_col * delta
@@ -95,7 +108,7 @@ def _kernel(q_ref, k_ref, bv_ref, decay_ref, beta_ref, s_ref, o_ref, so_ref,
             so_ref[0, j] = s
 
 
-def _call(q, k, bv, decay, beta, state, *, heads, interpret):
+def _call(q, k, bv, decay, beta, state, *, heads, channel, interpret):
     rows, all_heads, key_dim, value_dim = state.shape
     group = all_heads // q.shape[1]
 
@@ -109,7 +122,8 @@ def _call(q, k, bv, decay, beta, state, *, heads, interpret):
     value_block = pl.BlockSpec((1, heads, value_dim), keyed)
     state_block = pl.BlockSpec((1, heads, key_dim, value_dim), stated)
     return pl.pallas_call(
-        functools.partial(_kernel, heads=heads, group=group),
+        functools.partial(_kernel, heads=heads, group=group,
+                          channel=channel),
         grid=(rows, all_heads // heads),
         in_specs=[key_block, key_block, value_block, value_block,
                   value_block, state_block],
@@ -122,7 +136,7 @@ def _call(q, k, bv, decay, beta, state, *, heads, interpret):
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
         # the trace's readers match the prefix
-        name="gdn_step_r%d_h%d" % (rows, heads),
+        name="%s_step_r%d_h%d" % ("kda" if channel else "gdn", rows, heads),
     )(q, k, bv, decay, beta, state)
 
 
@@ -131,16 +145,21 @@ def _call(q, k, bv, decay, beta, state, *, heads, interpret):
 @functools.partial(jax.jit, static_argnames=("heads", "interpret"))
 def _kernel_step(q, k, v, g, beta, state, heads, interpret):
     """`_call` on the kernel's operands: q, k, beta * v, the decay and
-    beta a row [1, Dv] each, the state."""
+    beta a row [1, Dv] each (a gate a key channel: the decay the row
+    [1, Dk] it is), the state."""
     wide = lambda t: jnp.broadcast_to(t.astype(jnp.float32)[..., None],
                                       v.shape)
-    return _call(q, k, wide(beta) * v.astype(jnp.float32), wide(jnp.exp(g)),
-                 wide(beta), state, heads=heads, interpret=interpret)
+    channel = g.ndim == 3
+    return _call(q, k, wide(beta) * v.astype(jnp.float32),
+                 jnp.exp(g.astype(jnp.float32)) if channel
+                 else wide(jnp.exp(g)), wide(beta), state, heads=heads,
+                 channel=channel, interpret=interpret)
 
 
 def step(q, k, v, g, beta, state, plain, heads=None, interpret=False):
     """(out [B, H, Dv] float32, the state after the step): the module's
-    docstring.  `plain(q, k, v, g, beta, state)` is what every platform
+    docstring; g [B, H] (a gate a head) or [B, H, Dk] (a gate a key
+    channel).  `plain(q, k, v, g, beta, state)` is what every platform
     but the TPU lowers in the kernel's place (the op's own step);
     `heads` (a grid step's) is chosen from the shapes unless given, and
     `interpret` runs the kernel's body under the Pallas interpreter
@@ -151,7 +170,9 @@ def step(q, k, v, g, beta, state, plain, heads=None, interpret=False):
     if not heads or q.shape != k.shape \
             or q.shape != (rows, q.shape[1], key_dim) \
             or v.shape != (rows, all_heads, value_dim) \
-            or g.shape != (rows, all_heads) or beta.shape != g.shape \
+            or g.shape not in ((rows, all_heads),
+                               (rows, all_heads, key_dim)) \
+            or beta.shape != (rows, all_heads) \
             or q.dtype != jnp.float32 or all_heads % heads:
         raise ValueError(
             "gdn_step: q %s %s, k %s, v %s, g %s, beta %s over a state of "

@@ -408,21 +408,24 @@ def on_causal_conv1d_tail_lowering(width, row_bytes):
 
 
 def on_gated_delta_rule_lowering(form, path, chunk, heads, state_dtype,
-                                 row_bytes):
+                                 row_bytes, gate="head"):
     """A `gated_delta_rule` op (ops/linear_attention.py) was traced into
     a program: in which form ("step": one position, the state read and
     written once; "block": chunks of `chunk` positions), which way
     ("kernel": kernels/gdn_step.py; "plain": `jax.numpy`), over how many
     value heads and a state of which type; `row_bytes` the state a row
-    holds.  One count per op instance a lowered program holds."""
+    holds; `gate` "head" (one decay a head: Gated DeltaNet) or "channel"
+    (one a key channel: KDA).  One count per op instance a lowered
+    program holds."""
     _reg().counter("gated_delta_rule_lowerings_total",
                    "gated delta rule ops lowered, by form (a step or a "
                    "block of chunks), path (the step kernel or plain "
-                   "products), chunk, value heads and the state's type",
+                   "products), chunk, value heads, the state's type and "
+                   "the gate (a head's or a key channel's)",
                    labelnames=("form", "path", "chunk", "heads",
-                               "state_dtype")) \
+                               "state_dtype", "gate")) \
           .labels(form=form, path=path, chunk=chunk, heads=heads,
-                  state_dtype=str(state_dtype)).inc()
+                  state_dtype=str(state_dtype), gate=gate).inc()
     _recurrent_state_bytes("delta", row_bytes)
 
 

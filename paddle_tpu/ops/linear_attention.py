@@ -21,14 +21,24 @@ repeated in memory.
 float32 (g <= 0, beta in (0, 1)), State [rows, value heads, key_dim,
 value_dim] float32 -> Out [rows, T, value heads * value_dim] in V's
 type and StateOut, State's shape and type: a `fluid.ProgramDecoder`
-state pair that a step rewrites whole.  Two forms, chosen by T as the
-program is traced:
+state pair that a step rewrites whole.
+
+**A gate a key channel** (Kimi Delta Attention, arXiv:2510.26692, as
+Ling-3.0-flash's KDA layers run it): G [rows, T, value heads * key_dim],
+and the first line of the recurrence is `S = diag(exp(g_t)) S`, row d of
+a head's state decayed by its own exp(g_t[d]).  The same op and the same
+recurrence; which gate an op instance has is read off G's last axis as
+the program is traced, and the scalar gate lowers as it always did.
+
+Two forms, chosen by T as the program is traced:
 
 T = 1, a decode step (`gdn_state`): every head's state is read once,
 decayed, read for `S^T k`, written with the rank-one update and read for
 `S^T q`.  On the TPU that is one Pallas kernel that stores the state in
-place (kernels/gdn_step.py); off it, and for shapes the kernel does not
-take, the same four lines in `jax.numpy`.
+place (kernels/gdn_step.py: `gdn_step_*` under a gate a head,
+`kda_step_*` under a gate a key channel, `kda_state` the scope); off it,
+and for shapes the kernel does not take, the same four lines in
+`jax.numpy`.
 
 T > 1, a block (`gdn_chunks`): the same recurrence rearranged over
 chunks of `chunk` positions (the family's `chunk_gated_delta_rule`).
@@ -46,6 +56,26 @@ walked chunk by chunk; every product float32 at the highest precision
 (no exponent is ever positive: D, exp(G) and exp(G_C - G) are decays).
 A block that is no multiple of the chunk is padded with beta 0 and g 0,
 which leave the state as it is.  Plain `jax.numpy` on every platform.
+
+Under a gate a key channel (`kda_chunks`) G_i is a vector over the key's
+channels and `(K K^T) * D` no longer factors through one decay a pair of
+positions: entry ij is sum_d k_i[d] k_j[d] exp(G_i[d] - G_j[d]), which
+is a matrix product only as (K exp(G - G_ref)) (K exp(G_ref - G))^T, and
+the second factor **grows**.  So a chunk is cut into sub-blocks of `sub`
+positions, and the rows of sub-block b take G_ref = G at its first
+position: the left factor exp(G_i - G_ref) is a decay; the right factor
+exp(G_ref - G_j) is a decay for every j before the sub-block (products
+between sub-blocks go through decays only), at most exp((sub - 1) |g|min)
+for a j inside it, and is left out (the exponent masked, not the
+exponential) for a j after it, which the causal mask drops anyway.  The
+caller states the least g a position can have (`gate_floor`: the
+config's `kda_lower_bound`, -5) and `sub_chunk` is chosen from it so
+that (sub - 1) * |gate_floor| <= 80 < 88.7 = log(float32's largest): 16
+positions at -5, where the largest exponent is 75 and a product of two
+l2-normed channels summed over the key's 128 stays under e^80.
+Everything after the two decayed products (the solve, V_new, O, S') is
+the form above with exp(G) a vector a position: `(Q exp(G)) S`, `(K
+exp(G_C - G))^T V_new` and `diag(exp(G_C)) S` hold decays only.
 
 Forward only: generation needs no gradient, and a gradient of this op
 asked for raises (training the layer wants the block form's backward,
@@ -73,20 +103,28 @@ def _infer_shape(block, op_desc):
 
 def _heads(ins):
     """q, k [rows, T, key heads, key_dim], v [rows, T, value heads,
-    value_dim] as they come, g and beta [rows, T, value heads] float32,
-    the state; checked against one another."""
+    value_dim] as they come, beta [rows, T, value heads] and g [rows, T,
+    value heads] (a gate a head) or [rows, T, value heads, key_dim] (a
+    gate a key channel) float32, the state; checked against one
+    another."""
     q, k, v = ins["Q"][0], ins["K"][0], ins["V"][0]
     g, beta, state = ins["G"][0], ins["Beta"][0], ins["State"][0]
     rows, heads, key_dim, value_dim = state.shape
     if q.shape != k.shape or q.shape[-1] % key_dim \
             or v.shape[-1] != heads * value_dim \
             or heads % (q.shape[-1] // key_dim) \
-            or g.shape != v.shape[:2] + (heads,) or beta.shape != g.shape:
+            or beta.shape != v.shape[:2] + (heads,) \
+            or g.shape not in (beta.shape,
+                               beta.shape[:2] + (heads * key_dim,)):
         raise ValueError(
             "gated_delta_rule: Q %s, K %s, V %s, G %s and Beta %s do not "
-            "fit a state of %s ([rows, value heads, key_dim, value_dim])"
+            "fit a state of %s ([rows, value heads, key_dim, value_dim]): "
+            "G is [rows, T, value heads] (a gate a head) or [rows, T, "
+            "value heads * key_dim] (a gate a key channel)"
             % (q.shape, k.shape, v.shape, g.shape, beta.shape, state.shape))
     split = lambda t, d: t.reshape(*t.shape[:2], -1, d)
+    if g.shape != beta.shape:
+        g = split(g, key_dim)
     return (split(q, key_dim), split(k, key_dim), split(v, value_dim),
             g.astype(F32), beta.astype(F32), state)
 
@@ -100,8 +138,9 @@ def l2norm(t):
 def recurrent(q, k, v, g, beta, state):
     """The recurrence position by position (`lax.scan` over T): q, k
     [rows, T, key heads, key_dim] (normed and scaled already), v [rows,
-    T, heads, value_dim], g, beta [rows, T, heads], state [rows, heads,
-    key_dim, value_dim], all float32 -> (out [rows, T, heads,
+    T, heads, value_dim], beta [rows, T, heads], g [rows, T, heads] or
+    [rows, T, heads, key_dim] (a gate a key channel), state [rows,
+    heads, key_dim, value_dim], all float32 -> (out [rows, T, heads,
     value_dim], the state after the block)."""
     rows, _, key_heads, key_dim = q.shape
     heads = v.shape[2]
@@ -110,8 +149,10 @@ def recurrent(q, k, v, g, beta, state):
     def step(s, at):
         q_t, k_t, v_t, g_t, b_t = at
         # [rows, key heads, group, ...]: a value head beside its key's
+        # (a gate a head is one value for the state's rows, a gate a
+        # key channel one a row)
         s = s.reshape(rows, key_heads, group, key_dim, -1) \
-            * jnp.exp(g_t).reshape(rows, key_heads, group, 1, 1)
+            * jnp.exp(g_t).reshape(rows, key_heads, group, -1, 1)
         v_t = v_t.reshape(rows, key_heads, group, -1)
         b_t = b_t.reshape(rows, key_heads, group, 1)
         held = jnp.einsum("bhgkv,bhk->bhgv", s, k_t, precision=_HIGHEST)
@@ -177,6 +218,21 @@ def chunked(q, k, v, g, beta, state, chunk):
     k_end = to_end[..., None] * kc[:, :, :, None]
     whole = jnp.exp(cum[..., -1])                # exp(G_C)
 
+    return _walk(state, u, w, attend, q_dec, k_end, whole, length)
+
+
+def _walk(state, u, w, attend, q_dec, k_end, whole, length):
+    """The chunks' states walked one after the other: `chunked`'s last
+    three lines for u, w [N, rows, Hk, R, C, .], attend [N, rows, Hk, R,
+    C, C], q_dec = Q exp(G) and k_end = K exp(G_C - G) [N, rows, Hk, R,
+    C, Dk] and whole = exp(G_C) [N, rows, Hk, R] (a gate a head) or [N,
+    rows, Hk, R, Dk] (a gate a key channel) -> (out [rows, length, H,
+    Dv], the state after the block)."""
+    count, rows, key_heads, group, chunk, value_dim = u.shape
+    key_dim = q_dec.shape[-1]
+    # one decay for a head's state, or one a row of it
+    lift = (Ellipsis, None, None) if whole.ndim == 4 else (Ellipsis, None)
+
     def step(s, at):
         u_c, w_c, attend_c, q_c, k_c, whole_c = at
         v_new = u_c - jnp.einsum("bhrik,bhrkv->bhriv", w_c, s,
@@ -184,7 +240,7 @@ def chunked(q, k, v, g, beta, state, chunk):
         out = jnp.einsum("bhrik,bhrkv->bhriv", q_c, s, precision=_HIGHEST) \
             + jnp.einsum("bhrij,bhrjv->bhriv", attend_c, v_new,
                          precision=_HIGHEST)
-        s = whole_c[..., None, None] * s + jnp.einsum(
+        s = whole_c[lift] * s + jnp.einsum(
             "bhrik,bhriv->bhrkv", k_c, v_new, precision=_HIGHEST)
         return s, out
 
@@ -193,8 +249,88 @@ def chunked(q, k, v, g, beta, state, chunk):
         (u, w, attend, q_dec, k_end, whole))
     # [N, B, Hk, R, C, Dv] -> [B, N * C, H, Dv]
     out = out.transpose(1, 0, 4, 2, 3, 5).reshape(
-        rows, count * chunk, heads, value_dim)
+        rows, count * chunk, key_heads * group, value_dim)
     return out[:, :length], s.reshape(state.shape)
+
+
+def sub_chunk(chunk, gate_floor):
+    """The positions of a sub-block of `chunked_channel` under a gate no
+    position of which lies below `gate_floor` (< 0): the largest power
+    of two, `chunk` at most, with (sub - 1) * |gate_floor| <= 80, so
+    that the one growing factor, exp(G_ref - G_j) for a j inside its own
+    sub-block, stays under e^80 where float32 ends at e^88.7 (a product
+    with an l2-normed key's channel summed over a few hundred channels
+    adds e^6 at most).  16 at Ling-3.0-flash's -5."""
+    sub = 1
+    while 2 * sub <= chunk and (2 * sub - 1) * abs(gate_floor) <= 80.0:
+        sub *= 2
+    return sub
+
+
+def chunked_channel(q, k, v, g, beta, state, chunk, sub):
+    """The block form under a gate a key channel (the module's
+    docstring): `recurrent`'s operands with g [rows, T, heads, key_dim],
+    T any length >= 1; `sub` positions a sub-block, a divisor of
+    `chunk`."""
+    rows, length, key_heads, key_dim = q.shape
+    heads, value_dim = v.shape[2:]
+    group = heads // key_heads
+    if chunk % sub:
+        raise ValueError("gated_delta_rule: a sub-block of %d positions "
+                         "does not divide a chunk of %d" % (sub, chunk))
+    pad = -length % chunk
+    if pad:
+        q, k, v, g, beta = (
+            jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+            for t in (q, k, v, g, beta))
+    count, blocks = (length + pad) // chunk, chunk // sub
+
+    def keyed(t):       # [rows, T, Hk, Dk] -> [N, rows, Hk, 1, C, Dk]
+        return t.reshape(rows, count, chunk, key_heads, 1, key_dim) \
+            .transpose(1, 0, 3, 4, 2, 5)
+
+    def headed(t, *last):   # [rows, T, H, .] -> [N, rows, Hk, R, C, .]
+        return t.reshape(rows, count, chunk, key_heads, group, *last) \
+            .transpose(1, 0, 3, 4, 2, *range(5, 5 + len(last)))
+
+    qc, kc = keyed(q), keyed(k)
+    vc, bc = headed(v, value_dim), headed(beta)
+    cum = jnp.cumsum(headed(g, key_dim), axis=-2)   # [N, B, Hk, R, C, Dk]
+    lead = cum.shape[:4]
+    # G at each sub-block's first position, beside that sub-block's rows
+    # and beside every position of the chunk
+    ref = cum.reshape(lead + (blocks, sub, key_dim))[..., :1, :]
+    left = jnp.exp(cum.reshape(lead + (blocks, sub, key_dim)) - ref)
+    # the right factor of sub-block b at position j: exp(G_ref,b - G_j),
+    # left out (0) for a j past the sub-block, which the mask drops
+    upto = (jnp.arange(chunk)[None, :]
+            < (jnp.arange(blocks)[:, None] + 1) * sub)[..., None]
+    right = jnp.exp(jnp.where(upto, ref - cum[..., None, :, :], -jnp.inf)) \
+        * kc[..., None, :, :]                       # [.., blocks, C, Dk]
+
+    def decayed(t):
+        """entry ij: sum_d t_i[d] k_j[d] exp(G_i[d] - G_j[d]), any i, j
+        in sub-blocks b >= b' (others: whatever the factors give, finite;
+        masked by the caller)."""
+        rows_of = jnp.broadcast_to(t, cum.shape).reshape(
+            lead + (blocks, sub, key_dim)) * left
+        return jnp.einsum("nbhrsid,nbhrsjd->nbhrsij", rows_of, right,
+                          precision=_HIGHEST).reshape(
+                              lead + (chunk, chunk))
+
+    lower = jnp.tril(jnp.ones((chunk, chunk), bool))
+    a = jnp.where(jnp.tril(lower, -1), bc[..., None] * decayed(kc), 0.0)
+    attend = jnp.where(lower, decayed(qc), 0.0)
+    from_start = jnp.exp(cum)                       # exp(G_i), a channel
+    rhs = jnp.concatenate(
+        [bc[..., None] * vc, bc[..., None] * from_start * kc], axis=-1)
+    solved = jax.scipy.linalg.solve_triangular(
+        a + jnp.eye(chunk, dtype=F32), rhs, lower=True, unit_diagonal=True)
+    u, w = solved[..., :value_dim], solved[..., value_dim:]
+    q_dec = from_start * qc
+    k_end = jnp.exp(cum[..., -1:, :] - cum) * kc    # K exp(G_C - G_i)
+    whole = jnp.exp(cum[..., -1, :])                # exp(G_C)
+    return _walk(state, u, w, attend, q_dec, k_end, whole, length)
 
 
 @register_op("gated_delta_rule", stop_gradient_op=True,
@@ -202,12 +338,17 @@ def chunked(q, k, v, g, beta, state, chunk):
 def gated_delta_rule(ctx, ins, attrs):
     """The module's docstring.  attrs: `qk_l2norm` (default true: l2
     norm q and k a head and scale q by key_dim ** -0.5), `chunk` (the
-    block form's, default 64)."""
+    block form's, default 64) and, for a gate a key channel,
+    `sub_chunk` (the positions of a sub-block of the block form: what
+    `sub_chunk(chunk, gate_floor)` gives for the least g a caller
+    promises, default 16, -5's)."""
     q, k, v, g, beta, state = _heads(ins)
     chunk = int(attrs.get("chunk", 64))
     rows, length, key_heads, key_dim = q.shape
     heads = v.shape[2]
-    step = length == 1
+    step, channel = length == 1, g.ndim == 4
+    # the scopes a trace's readers know the two gates by
+    scope = "kda_" if channel else "gdn_"
     kernel = None
     if step:
         from ..kernels import gdn_step
@@ -216,7 +357,8 @@ def gated_delta_rule(ctx, ins, attrs):
     telemetry.on_gated_delta_rule_lowering(
         "step" if step else "block", "kernel" if kernel else "plain",
         0 if step else chunk, heads, state.dtype,
-        state[0].size * state.dtype.itemsize)
+        state[0].size * state.dtype.itemsize,
+        "channel" if channel else "head")
     with jax.named_scope("gdn_gates"):
         if attrs.get("qk_l2norm", True):
             q, k = l2norm(q) * key_dim ** -0.5, l2norm(k)
@@ -230,16 +372,19 @@ def gated_delta_rule(ctx, ins, attrs):
                                  beta[:, None], state.astype(F32))
             return out[:, 0], new
 
-        with jax.named_scope("gdn_state"):
+        with jax.named_scope(scope + "state"):
             operands = (q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
                         state)
             out, new = gdn_step.step(*operands, plain=one, heads=kernel) \
                 if kernel else one(*operands)
             out = out[:, None]
     else:
-        with jax.named_scope("gdn_chunks"):
-            out, new = chunked(q, k, v.astype(F32), g, beta,
-                               state.astype(F32), chunk)
+        with jax.named_scope(scope + "chunks"):
+            out, new = chunked_channel(
+                q, k, v.astype(F32), g, beta, state.astype(F32), chunk,
+                min(int(attrs.get("sub_chunk", 16)), chunk)) \
+                if channel else chunked(q, k, v.astype(F32), g, beta,
+                                        state.astype(F32), chunk)
     return {"Out": [out.reshape(rows, length, -1).astype(v.dtype)],
             "StateOut": [new.astype(state.dtype)]}
 

@@ -321,6 +321,35 @@ def test_the_latent_decode_kernel_lowers_for_tpu():
     assert 'kernel_name = "mla_decode_k256_t16"' in module
 
 
+@pytest.mark.parametrize("block,name", [(1, "mla_decode_k512"),
+                                        (64, "mla_decode_k512_t64")])
+def test_the_latent_decode_kernel_takes_32_heads(block, name):
+    """`mla_cached_attention` at ling3-decode-ep16's shapes (128 rows,
+    32 heads with a full-rank query, a 1024-slot bfloat16 cache of 512 +
+    64 values) lowered for the TPU: both forms of the kernel take the
+    shape they were not sized at (a step: 4 rows of 32 heads a grid
+    step; a block of 64 positions: all 32 heads, 512 query rows, over
+    blocks of 512 slots)."""
+    from paddle_tpu.ops import registry
+
+    kernel = registry.get_op_info("mla_cached_attention").kernel
+    b, h, t, c, r, d = 128, 32, 1024, 512, 64, 128
+    bf16 = jnp.bfloat16
+    ins = {"QNope": [jax.ShapeDtypeStruct((b, block, h * d), bf16)],
+           "QRope": [jax.ShapeDtypeStruct((b, block, h * r), bf16)],
+           "CNew": [jax.ShapeDtypeStruct((b, block, c), bf16)],
+           "RNew": [jax.ShapeDtypeStruct((b, block, r), bf16)],
+           "Cache": [jax.ShapeDtypeStruct((b, t, c + r), bf16)],
+           "WUk": [jax.ShapeDtypeStruct((c, h * d), bf16)],
+           "WUv": [jax.ShapeDtypeStruct((c, h * d), bf16)],
+           "Position": [jax.ShapeDtypeStruct((b,), jnp.int32)]}
+    module = jax.export.export(
+        jax.jit(lambda ins: kernel(None, ins, {"num_heads": h})),
+        platforms=["tpu"])(ins).mlir_module()
+    assert module.count("tpu_custom_call") == 1
+    assert 'kernel_name = "%s"' % name in module
+
+
 @pytest.mark.parametrize("block,window,kernels", [
     (1, 0, ["gqa_decode_k2048"]), (1, 128, ["gqa_decode_w128"]),
     (128, 0, ["gqa_decode_k1024_t128"]), (128, 128, [])])
@@ -600,4 +629,35 @@ def test_the_delta_rule_step_kernel_lowers_for_tpu(length, kernels):
     for name in kernels:
         assert 'kernel_name = "%s"' % name in module
     if kernels:     # the state's buffer is the new state's
+        assert "output_tuple_indices = [1], operand_index = 5" in module
+
+
+@pytest.mark.parametrize("length,kernels", [(1, ["kda_step_r128_h16"]),
+                                            (64, [])])
+def test_the_channel_gated_step_kernel_lowers_for_tpu(length, kernels):
+    """`gated_delta_rule` under a gate a key channel at
+    ling3-decode-ep16's shape (128 rows, 32 heads of 128 on both sides,
+    G [rows, T, 32 * 128]) lowered for the TPU from this CPU host: a
+    step holds one Mosaic kernel named for the gate, the state its
+    result's buffer; a block of 64 positions holds none."""
+    from paddle_tpu.ops import registry
+
+    kernel = registry.get_op_info("gated_delta_rule").kernel
+    b, h, d = 128, 32, 128
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    ins = {name: [jax.ShapeDtypeStruct((b, length, h * d), bf16)]
+           for name in "QKV"}
+    ins.update(G=[jax.ShapeDtypeStruct((b, length, h * d), f32)],
+               Beta=[jax.ShapeDtypeStruct((b, length, h), f32)],
+               State=[jax.ShapeDtypeStruct((b, h, d, d), f32)])
+
+    def step(ins):
+        return kernel(None, ins, {"chunk": 64, "sub_chunk": 16})
+
+    module = jax.export.export(jax.jit(step), platforms=["tpu"])(
+        ins).mlir_module()
+    assert module.count("tpu_custom_call") == len(kernels)
+    for name in kernels:
+        assert 'kernel_name = "%s"' % name in module
+    if kernels:
         assert "output_tuple_indices = [1], operand_index = 5" in module

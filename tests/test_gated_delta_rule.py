@@ -62,10 +62,14 @@ def _applied(attrs):
 
 def _by_position(ins):
     """The recurrence in numpy, a position and a head at a time: what
-    the reference's `delta_rule` is, with a state handed in."""
+    the reference's `delta_rule` is, with a state handed in.  A gate a
+    key channel (g [rows, T, heads * dim]) decays row d of a head's
+    state by its own exp(g[d])."""
     q, k, v, g, beta, state = (np.asarray(t, np.float64) for t in ins)
-    rows, length, heads = g.shape
+    rows, length, heads = beta.shape
     dim = state.shape[-1]
+    # [rows, T, heads, dim, 1] a channel, [rows, T, heads, 1, 1] a head
+    g = g.reshape(rows, length, heads, -1, 1)
     group = heads // (q.shape[-1] // dim)
     q, k = (t.reshape(rows, length, -1, dim) for t in (q, k))
     q = q / np.sqrt((q * q).sum(-1, keepdims=True) + 1e-6) / np.sqrt(dim)
@@ -78,7 +82,7 @@ def _by_position(ins):
             s = state[b, j]
             for t in range(length):
                 kt, qt = k[b, t, j // group], q[b, t, j // group]
-                s = s * np.exp(g[b, t, j])
+                s = s * np.exp(g[b, t, j])      # [dim, 1] or [1, 1]
                 s = s + np.outer(kt, beta[b, t, j] * (v[b, t, j] - s.T @ kt))
                 out[b, t, j] = s.T @ qt
             state[b, j] = s
@@ -206,9 +210,137 @@ def test_operands_that_do_not_fit_the_state_are_refused():
         _rule((q, k, v[..., :24], g, beta, state))
 
 
-def _rule_lowering(form, path, chunk, heads):
-    return ("gated_delta_rule_lowerings_total{chunk=%d,form=%s,heads=%d,"
-            "path=%s,state_dtype=float32}" % (chunk, form, heads, path))
+# -- (a') a gate a key channel (Kimi Delta Attention) ------------------------------
+
+FLOOR = -5.0    # Ling-3.0-flash's `kda_lower_bound`
+
+
+def _channel_ins(rs, rows, length, heads=4, dim=8, held=None, **kwargs):
+    """`_rule_ins` with G [rows, T, heads * dim] in [FLOOR, 0), spread
+    over five orders; `held` = (lo, hi): the positions whose every gate
+    sits at the bound (the case the sub-blocks are for)."""
+    q, k, v, _, beta, state = _rule_ins(rs, rows, length, key_heads=heads,
+                                        heads=heads, dim=dim, **kwargs)
+    g = FLOOR * jax.nn.sigmoid(jnp.asarray(
+        rs.uniform(-10, 3, (rows, length, heads * dim)), jnp.float32))
+    if held is not None:
+        g = g.at[:, held[0]:held[1]].set(FLOOR)
+    return q, k, v, g, beta, state
+
+
+@pytest.mark.parametrize("length,chunk,sub,held", [
+    (1, 64, 16, None), (64, 64, 16, None), (128, 64, 16, (64, 128)),
+    (75, 64, 16, (0, 75)), (13, 4, 2, None), (3, 64, 16, None),
+    (40, 16, 16, (0, 40)), (96, 32, 8, (10, 50))])
+def test_the_channel_gated_rule_is_the_recurrence(length, chunk, sub, held):
+    """The op under a gate a key channel: T = 1 (the step), whole
+    chunks, a T that is no multiple of the chunk, every gate of a whole
+    chunk held at the bound -5 (where the sub-block's right factor
+    reaches e^75), against the recurrence position by position in
+    float64."""
+    ins = _channel_ins(np.random.RandomState(length), 2, length, held=held)
+    out, new = _rule(ins, chunk=chunk, sub_chunk=sub)
+    assert bool(jnp.isfinite(out).all()) and bool(jnp.isfinite(new).all())
+    want_out, want_state = _by_position(ins)
+    np.testing.assert_allclose(np.asarray(out), want_out, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(new), want_state, atol=2e-5)
+
+
+def test_the_sub_block_is_what_keeps_the_bound_held_chunk_finite():
+    """The same chunk as one sub-block of 64 positions: the right factor
+    passes e^88 and the block form is no number; `sub_chunk` picks 16
+    from the bound, and the whole chunk (one sub-block) for a gate that
+    cannot fall below -1."""
+    ins = _channel_ins(np.random.RandomState(0), 1, 64, held=(0, 64))
+    out, _ = _rule(ins, chunk=64, sub_chunk=64)
+    assert not bool(jnp.isfinite(out).all())
+    assert linear_attention.sub_chunk(64, FLOOR) == 16
+    assert linear_attention.sub_chunk(64, -1.0) == 64
+    assert linear_attention.sub_chunk(8, FLOOR) == 8
+    assert 15 * -FLOOR < 80 < 88.7      # e^75: inside float32
+
+
+@pytest.mark.parametrize("length", [1, 7, 70])
+def test_a_gate_constant_over_a_heads_channels_is_the_scalar_gate(length):
+    """One op: G [rows, T, heads * dim] with a head's channels all alike
+    gives what G [rows, T, heads] gives, step and block."""
+    ins = _rule_ins(np.random.RandomState(length), 2, length)
+    q, k, v, g, beta, state = ins
+    wide = jnp.repeat(g, 8, axis=-1)
+    want, want_state = _rule(ins, chunk=16)
+    got, got_state = _rule((q, k, v, wide, beta, state), chunk=16,
+                           sub_chunk=4)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+    np.testing.assert_allclose(np.asarray(got_state),
+                               np.asarray(want_state), atol=2e-5)
+
+
+@pytest.mark.parametrize("first", [1, 5, 64, 70])
+def test_channel_gated_block_then_steps_is_one_block(first):
+    ins = _channel_ins(np.random.RandomState(first), 2, 80, held=(20, 50))
+    whole, whole_state = _rule(ins, chunk=16, sub_chunk=8)
+    out, state = _rule(ins, 0, first, chunk=16, sub_chunk=8)
+    outs = [out]
+    for t in range(first, 80):
+        out, state = _rule(ins, t, t + 1, state=state)
+        outs.append(out)
+    np.testing.assert_allclose(np.asarray(jnp.concatenate(outs, axis=1)),
+                               np.asarray(whole), atol=2e-5)
+    np.testing.assert_allclose(np.asarray(state), np.asarray(whole_state),
+                               atol=2e-5)
+
+
+@pytest.mark.parametrize("heads,block", [(4, None), (32, 16)])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_the_channel_gated_step_kernel_is_the_plain_step(heads, block,
+                                                         dtype):
+    """The kernel's body under the Pallas interpreter with the decay a
+    column: one block of all heads, and blocks of 16 of
+    ling3-decode-ep16's 32; some gates at the bound."""
+    rs = np.random.RandomState(heads)
+    q, k, v, _, beta, s0 = _kernel_ins(rs, 2, heads, heads, dtype)
+    g = FLOOR * jax.nn.sigmoid(jnp.asarray(
+        rs.uniform(-10, 3, (2, heads, 128)), jnp.float32))
+    g = g.at[:, 0].set(FLOOR)
+    assert gdn_step.choose_heads(2, heads, heads, 128, 128,
+                                 jnp.float32) == (block or heads)
+    got, state = gdn_step.step(q, k, v, g, beta, s0, plain=None,
+                               interpret=True)
+    want, want_state = linear_attention.recurrent(
+        q[:, None], k[:, None], v[:, None].astype(jnp.float32), g[:, None],
+        beta[:, None], s0)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want[:, 0]),
+                               atol=2e-5)
+    np.testing.assert_allclose(np.asarray(state), np.asarray(want_state),
+                               atol=2e-5)
+
+
+def test_the_channel_gate_is_counted_and_scoped():
+    """The counter's `gate` label tells the two gates apart, step and
+    block; the lowered step's scopes are `kda_*`."""
+    rs = np.random.RandomState(8)
+    before = telemetry.snapshot()
+    _rule(_channel_ins(rs, 2, 1, dim=128))
+    _rule(_channel_ins(rs, 2, 9), chunk=4, sub_chunk=2)
+    traced = telemetry.snapshot_delta(before)
+    assert traced[_rule_lowering("step", "kernel", 0, 4, "channel")] == 1
+    assert traced[_rule_lowering("block", "plain", 4, 4, "channel")] == 1
+    q, k, v, g, beta, state = _channel_ins(rs, 2, 1, dim=128)
+    lowered = _applied((("chunk", 64),)).lower(
+        q, k, v, g, beta, state).as_text(debug_info=True)
+    assert "kda_state" in lowered and "gdn_state" not in lowered
+
+
+def test_a_gate_of_neither_shape_is_refused_and_both_are_named():
+    q, k, v, g, beta, state = _rule_ins(np.random.RandomState(0), 2, 3)
+    with pytest.raises(ValueError, match="a gate a key channel"):
+        _rule((q, k, v, jnp.repeat(g, 3, axis=-1), beta, state))
+
+
+def _rule_lowering(form, path, chunk, heads, gate="head"):
+    return ("gated_delta_rule_lowerings_total{chunk=%d,form=%s,gate=%s,"
+            "heads=%d,path=%s,state_dtype=float32}"
+            % (chunk, form, gate, heads, path))
 
 
 # -- (b) the convolution that carries its tail -------------------------------------

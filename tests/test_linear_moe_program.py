@@ -60,9 +60,10 @@ NAMES = linear_moe_param_names(LAYERS)
 CHANNELS = 2 * HK * DK + HV * DV
 
 
-def _rule_lowering(form, path, chunk, heads):
-    return ("gated_delta_rule_lowerings_total{chunk=%d,form=%s,heads=%d,"
-            "path=%s,state_dtype=float32}" % (chunk, form, heads, path))
+def _rule_lowering(form, path, chunk, heads, gate="head"):
+    return ("gated_delta_rule_lowerings_total{chunk=%d,form=%s,gate=%s,"
+            "heads=%d,path=%s,state_dtype=float32}"
+            % (chunk, form, gate, heads, path))
 
 
 # -- (d) the step Program against the reference's full forward --------------------
