@@ -36,6 +36,11 @@ Lowered for the TPU these are Mosaic kernels named
 `moe_gmm_{fwd,dx,dw}_m<block_m>_n<block_n>_k<block_k>`; lowered for any
 other platform the same products are `jax.lax.ragged_dot_general`, the
 plain path of the CPU tests and the chip's yardstick.
+
+    unwritten(shape, dtype, after)       -> an array no pass has filled
+
+is what a caller starts a row operand from that it writes itself, only
+as far as the products will read.
 """
 
 import functools
@@ -289,6 +294,29 @@ def _grouped(kernel, plain, a, b, counts):
     call = (functools.partial(_dw_call, blocks) if kernel == "dw"
             else functools.partial(_rows_call, kernel, blocks))
     return lax.platform_dependent(a, b, counts, tpu=call, default=plain)
+
+
+def unwritten(shape, dtype, after):
+    """An array that nothing has written, as `gmm` and `gmm_dx` leave
+    the rows of no group: for a loop that writes the rows it goes on to
+    read, and for operands of which a product reads no other row.
+    Lowered for the TPU it holds whatever was there, at the cost of no
+    pass over it (a Mosaic kernel, `moe_unwritten`, whose body is empty;
+    under the Pallas interpreter: NaN); lowered for any other platform
+    it is zeros.  `after` is an array it is made no earlier than, and
+    not read: the call's one operand, so that the array is not there
+    before its place in the program, and two calls are two arrays (the
+    compiler makes equal calls of equal operands one)."""
+    def call(after):
+        return pl.pallas_call(
+            lambda after_ref, o_ref: None,
+            out_shape=jax.ShapeDtypeStruct(shape, dtype),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            name="moe_unwritten")(after)
+
+    return lax.platform_dependent(
+        after, tpu=call, default=lambda _: jnp.zeros(shape, dtype))
 
 
 def gmm(x, w, counts):

@@ -334,19 +334,20 @@ def on_moe_share_bwd_lowering(scored, held, top_k):
           .labels(scored=scored, held=held, top_k=top_k).inc()
 
 
-def on_moe_share_compact_lowering(rows, bound):
+def on_moe_share_compact_lowering(rows, chunk):
     """An expert layer that holds a range of the experts scored was
-    traced into a program with its compact row path (ops/moe.py): where
-    the held range got at most `bound` of the `rows` assignments, the
-    work past the ordering runs on `bound` rows.  One count per forward
-    op a lowered program holds; whether a run took the path is data on
-    the device."""
+    traced into a program with its row work by chunks (ops/moe.py): the
+    passes between the grouped products are loops over `chunk` rows a
+    trip of the `rows` assignments ordered, as many trips as hold a
+    row of the held range.  One count per forward op a lowered program
+    holds.  The trips are data on the device: the op's `Counts` output
+    is what a caller fetches to see them (sum(Counts) // chunk + 1)."""
     _reg().counter("moe_share_compact_lowerings_total",
-                   "expert layers lowered with a compact row path beside "
-                   "the path over all rows, by assignments ordered and "
-                   "the compact path's rows",
-                   labelnames=("rows", "bound")) \
-          .labels(rows=rows, bound=bound).inc()
+                   "expert layers lowered with their row work as loops "
+                   "over chunks of the held rows, by assignments ordered "
+                   "and a chunk's rows",
+                   labelnames=("rows", "chunk")) \
+          .labels(rows=rows, chunk=chunk).inc()
 
 
 def on_prefill_lowering(form, block):

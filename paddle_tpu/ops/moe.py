@@ -43,33 +43,45 @@ input gradients add up to the uncut layer's.
 
 The stable order puts the held groups' rows first, so the first
 sum(Counts) rows of every ordered array are the held ones, and an op
-that holds an eighth of the experts scored needs an eighth of its rows.
-Where a ranged op orders 32768 rows or more it therefore has a *compact
-row path*: a bound B from the shapes alone (`_compact_rows`: twice the
-rows an even router sends the range, on the grouped kernels' row tile,
-and at most half of the rows: 24576 of 16384 x 6 for 8 of 64), and its
-row work between the grouped products is a choice, `lax.cond(sum(Counts)
-<= B, compact, all_rows)` (`_either_row_path`).  `all_rows` is the op as
-it stands for every other shape; a batch whose routers send the range
-more than B rows runs it, so nothing is ever dropped or approximated: B
-decides which of two exact bodies runs.  The compact body works on the
-first B rows: the activation and its derivative, dOut gathered for B
-rows, and a token's sum of its held rows (`_held_token_sums`: Out
-forward, X@GRAD backward) from B gathered rows in place of n * k.  Rows
-from sum(Counts) to B hold whatever was there and are masked as the rows
-past the held groups always were.  The forward is one choice (the
-activation, the down product on `[B, width]`, the sums); the ordering,
-the gather of Xs and the gate and up products stay outside it (the kept
-outputs keep their n * k rows).  The backward is three (dOut's rows;
-around the activation; the gate's and the up's input gradients and the
-sums) with the down product's `gmm_dx` and the three `gmm_dw` between
-them, once, over whole arrays: a grouped product reads and writes no row
-past the held groups' whatever its operands' length (98304 rows for
-24576 cost a row product 0.1 ms and a `gmm_dw` 0.5 on the v5e), the
-compact body pads its operands with zeros (`_whole`), and a product
-inside a choice is lowered twice, which costs set-up time (a fifth of a
-second each in a four-layer program).  The bodies open the scopes
-`moe_compact` and `moe_all_rows` inside the op's three.
+that holds an eighth of the experts scored needs an eighth of its rows,
+or as many more as its routers send the range: how many is data on the
+device.  Where a ranged op orders 32768 rows or more (`_chunk_rows`) the
+row work between the grouped products therefore goes *by chunks of the
+held rows*: each pass is a loop (`_over_held_chunks`) over chunks of
+8192 rows of the order, a whole number of the grouped kernels' row
+tiles, from row 0 as far as row sum(Counts), sum(Counts) // 8192 + 1
+trips, so its work follows the rows the range holds and no bound stands
+between two bodies; nothing is dropped or approximated whatever the
+routers do.  A trip slices its chunk's rows out of the whole arrays,
+works on them (the activation and its derivative; dOut gathered for the
+chunk's rows; a token's sum of its held rows, `_held_token_sums`: Out
+forward, X@GRAD backward, from a chunk's gathered rows in place of n *
+k) and writes the results to their place in a whole array that the loop
+carries in place; the chunk that holds row sum(Counts) masks the rows
+past it, as the rows past the held groups always were masked, and the
+rows past the last trip's chunk hold whatever the carried array started
+with.  A new array starts as one that nothing has written
+(`grouped_matmul.unwritten`: no pass over all rows is left, not even a
+fill; what such rows hold reaches no sum), and where the results are a
+grouped product's row operands made from arrays of the same shape that
+nothing reads again (the backward's d_gate, d_up and h * w from Gate, Up
+and the down product's gradient; the sum of the two input gradients over
+the first of them and the rows' sums of it over the second) they are
+written over those, with no array of their own.  The grouped products
+stand outside the loops, once each, over whole arrays: a grouped product
+reads and writes no row past the held groups' whatever its operands'
+length (98304 rows for 24576 cost a row product 0.1 ms and a `gmm_dw`
+0.5 on the v5e) but for the tile of row sum(Counts), where `gmm_dw`
+gives a held expert that got no row its visit, and that tile lies in the
+last trip's chunk.  The forward has two loops (the activation; after the
+down product, the token sums) with the ordering, the gather of Xs and
+the gate and up products before them (the kept outputs keep their n * k
+rows); the backward four (dOut's rows; around the activation; the sum of
+the gate's and the up's input gradients; the token sums) with the six
+products between them.  The loops open the scope `moe_compact` inside
+the op's three.  Every other op (no range, under 32768 rows, or no whole
+number of chunks) has the one plain body over every row, where a loop's
+fixed cost would not be paid back.
 
 The experts' gate is SiLU, or with `activation="relu"` ReLU (ReGLU
 experts), forward and backward.
@@ -265,104 +277,167 @@ def _by_key(keys, values):
     return jax.lax.sort((keys, values), num_keys=1)[1]
 
 
-# The compact row path of the range form (the module's docstring): only
-# where an op orders at least so many rows, and over a bound of so many
-# times the rows an even router sends the held range, on the grouped
-# kernels' row tile.
-_COMPACT_MIN_ROWS = 32768
-_COMPACT_FACTOR = 2
+# The chunked row work of the range form (the module's docstring): only
+# where an op orders at least so many rows, in chunks of so many rows of
+# the order, a whole number of the grouped kernels' row tiles.  (On the
+# v5e the op alone and the training cell read the same at 2048, 4096 and
+# 8192 rows a chunk; a served prefill's loops at the two smaller ones
+# left its decoding scan 1% slower: PERF.md section 5, PR 55.)
+_CHUNK_MIN_ROWS = 32768
+_CHUNK_ROWS = 8192
 _ROW_TILE = 256
+assert _CHUNK_ROWS % _ROW_TILE == 0
 
 
-def _compact_rows(n, k, held, scored):
-    """The rows the compact path of an op works on that orders `n * k`
-    assignments and holds `held` of the `scored` experts, from the
-    shapes alone; 0 where the op has no compact path: it holds every
-    expert scored, orders too few rows for a second body to pay, or the
-    bound would pass half of them."""
+def _chunk_rows(n, k, held, scored):
+    """The rows a trip takes of the loops that an op runs its row work
+    in which orders `n * k` assignments and holds `held` of the `scored`
+    experts, from the shapes alone; 0 where the op has the one plain
+    body over every row: it holds every expert scored (no row to leave
+    out), orders too few rows to pay a loop's fixed cost back, or no
+    whole number of chunks."""
     rows = n * k
-    if held >= scored or rows < _COMPACT_MIN_ROWS:
+    if held >= scored or rows < _CHUNK_MIN_ROWS or rows % _CHUNK_ROWS:
         return 0
-    bound = -(-_COMPACT_FACTOR * rows * held // scored)
-    bound = -(-bound // _ROW_TILE) * _ROW_TILE
-    return bound if bound <= rows // 2 else 0
+    return _CHUNK_ROWS
 
 
 @contextlib.contextmanager
-def _scopes(phase, branch):
-    """The op's scope `phase` and, inside it, that of the row path
-    (`branch`) where the op has two."""
+def _scopes(phase, chunked):
+    """The op's scope `phase` and, inside it, `moe_compact` around row
+    work that goes by chunks of the held rows."""
     with jax.named_scope(phase):
-        if branch is None:
+        if not chunked:
             yield
         else:
-            with jax.named_scope(branch):
+            with jax.named_scope("moe_compact"):
                 yield
 
 
-def _first(ordered, some):
-    """The first `some` rows of an array of the order."""
-    return ordered if some == ordered.shape[0] else ordered[:some]
+def _chunk_of(ordered, start, chunk):
+    """`chunk` rows of an array of the order from row `start` on."""
+    return jax.lax.dynamic_slice_in_dim(ordered, start, chunk)
 
 
-def _whole(first, rows):
-    """`first` with rows of 0 behind it, `rows` in all: what a grouped
-    product takes, which reads no row past the held groups'."""
-    short = rows - first.shape[0]
-    if not short:
-        return first
-    return jnp.pad(first, ((0, short),) + ((0, 0),) * (first.ndim - 1))
+def _over_held_chunks(work, counts, chunk, *whole):
+    """The arrays `whole` of the order (a whole number of chunks) with
+    their rows `start .. start + chunk` written over by `work(start,
+    present, *those rows)` (a tuple, one [chunk, ...] an array), chunk
+    by chunk from row 0 as far as row sum(counts), the first that is not
+    held: sum(counts) // chunk + 1 trips, data on the device, each chunk
+    written in place.  `present` [chunk, 1] says which of the chunk's
+    rows are held: they come first in the order, so only the last trip's
+    chunk has others (where the held rows fill their chunks it has no
+    other, and what it costs buys this: the tile of row sum(counts),
+    which `gmm_dw` visits for a held expert that got no row and
+    multiplies by 0, holds what a trip wrote).  Rows past the last
+    trip's chunk keep what `whole` had."""
+    held = jnp.sum(counts)
+    rows = whole[0].shape[0]
+
+    def trip(i, carried):
+        start = i * chunk
+        present = (start + jnp.arange(chunk, dtype=jnp.int32) < held)[:, None]
+        parts = work(start, present,
+                     *(_chunk_of(a, start, chunk) for a in carried))
+        return tuple(jax.lax.dynamic_update_slice_in_dim(a, part, start, 0)
+                     for a, part in zip(carried, parts))
+
+    return jax.lax.fori_loop(
+        0, jnp.minimum(held // chunk + 1, rows // chunk), trip, whole)
 
 
-def _either_row_path(work, counts, rows, bound):
-    """`work(rows, None)` over all `rows` of the order; or, where the op
-    has a compact path (`bound` > 0), whichever the held rows' count
-    picks of `work(bound, "moe_compact")` and `work(rows,
-    "moe_all_rows")`, which return the same shapes."""
-    if not bound:
-        return work(rows, None)
-    return jax.lax.cond(jnp.sum(counts) <= bound,
-                        lambda: work(bound, "moe_compact"),
-                        lambda: work(rows, "moe_all_rows"))
+def _row_work(phase, work, operands, counts, chunk, present=None, over=0):
+    """`work(*operands, present)` row for row, under the op's scope
+    `phase`: a tuple of arrays of the order from `operands`, arrays of
+    the order.  With `chunk` 0 over every row at once, `present`
+    [rows, 1] saying which are held (None: all).  Else over the chunks
+    that hold a held row (`_over_held_chunks`): the first `over` results
+    are written over the first `over` operands, which are given up (a
+    grouped product's row operand, whose rows past the held groups' no
+    product reads, needs no array of its own), and the others over
+    arrays nothing has written (`grouped_matmul.unwritten`): what the
+    rows past those chunks hold is nobody's to read."""
+    from ..kernels import grouped_matmul
+
+    with _scopes(phase, chunked=chunk):
+        if not chunk:
+            return work(*operands, present)
+        rows = operands[0].shape[0]
+        parts = jax.eval_shape(
+            work, *(jax.ShapeDtypeStruct((chunk,) + a.shape[1:], a.dtype)
+                    for a in operands),
+            jax.ShapeDtypeStruct((chunk, 1), jnp.bool_))
+        return _over_held_chunks(
+            lambda start, present, *mine: work(
+                *mine[:over],
+                *(_chunk_of(a, start, chunk) for a in operands[over:]),
+                present),
+            counts, chunk, *operands[:over],
+            *(grouped_matmul.unwritten((rows,) + p.shape[1:], p.dtype,
+                                       after=operands[0])
+              for p in parts[over:]))
 
 
-def _held_token_sums(rows, token_row, counts, n, k, weights=None):
-    """[n, width] float32: each token's sum of its held assignments'
-    rows, from the first `bound` rows of the order alone (`rows`
-    [bound, width]; sum(counts) <= bound of them are held), each times
-    its entry of `weights` [n * k] (by slot) where given.  The held
-    slots in slot order are in token order, so one sort of the n * k
-    slots (held first) puts a token's rows side by side: `bound` rows
+def _held_token_sums(rows, token_row, counts, n, k, chunk, dtype,
+                     weights=None, over=None):
+    """[n, width] in `dtype`: each token's float32 sum of its held
+    assignments' rows of `rows` [n * k, width], the first sum(counts)
+    of the order, each times its entry of `weights` [n * k] (by slot)
+    where given; the rows' sums are written over `over`, an array like
+    `rows` in `dtype` that is given up, where there is one (else over an
+    array nothing has written).  The held slots in slot order are in
+    token order, so one sort of the n * k slots (held first) puts a
+    token's rows side by side; then, `chunk` of them a trip, the rows
     are gathered, each adds the up to k - 1 after it that share its
-    token, and a token reads the sum at its first row, which lies as
-    far in as the tokens before it have held slots.  (A gather costs by
-    the rows it fetches, 42 us a thousand of 2560 on the v5e whatever
-    their order: each token fetching its k rows would fetch n * k.)"""
+    token, and a token reads the sum at its first row, which lies as far
+    in as the tokens before it have held slots.  (A gather costs by the
+    rows it fetches, 42 us a thousand of 2560 on the v5e whatever their
+    order: each token fetching its k rows would fetch n * k.)"""
+    from ..kernels import grouped_matmul
+
     f32 = jnp.float32
-    bound = rows.shape[0]
     slots = jnp.arange(n * k, dtype=jnp.int32)
     held = token_row < jnp.sum(counts)
     ordered = jax.lax.sort(
         (jnp.where(held, slots, n * k), token_row)
         + (() if weights is None else (weights,)), num_keys=1)
-    # k - 1 rows past the bound, the last rows' neighbours: the shifted
-    # reads are then slices of one array, in the rows' own type, which
-    # one pass reads
-    slot, row = (a[:bound + k - 1] for a in ordered[:2])
-    live = slot < n * k
-    picked = rows[jnp.where(live, row, 0)]
-    token = jnp.where(live, slot // k, -1)
-    summed = 0.0
-    for j in range(k):
-        mine = picked[j:bound + j].astype(f32)
-        if weights is not None:
-            mine = mine * ordered[2][j:bound + j, None]
-        same = live[j:bound + j] & (token[j:bound + j] == token[:bound])
-        summed = summed + jnp.where(same[:, None], mine, 0.0)
+    # k - 1 entries past the end, the last rows' neighbours: a chunk's
+    # shifted reads are then slices of one gathered array, in the rows'
+    # own type, which one pass reads
+    ordered = [jnp.pad(a, (0, k - 1), constant_values=fill)
+               for a, fill in zip(ordered, (n * k, 0, 0))]
+
+    def sums(start, *_):
+        slot, row, *weight = (_chunk_of(a, start, chunk + k - 1)
+                              for a in ordered)
+        live = slot < n * k
+        picked = rows[jnp.where(live, row, 0)]
+        token = jnp.where(live, slot // k, -1)
+        summed = 0.0
+        for j in range(k):
+            mine = picked[j:chunk + j].astype(f32)
+            if weight:
+                mine = mine * weight[0][j:chunk + j, None]
+            same = live[j:chunk + j] & (token[j:chunk + j] == token[:chunk])
+            summed = summed + jnp.where(same[:, None], mine, 0.0)
+        return (summed.astype(dtype),)
+
+    if over is None or (over.shape, over.dtype) != (rows.shape, dtype):
+        over = grouped_matmul.unwritten(rows.shape, dtype, after=rows)
+    (summed,) = _over_held_chunks(sums, counts, chunk, over)
     mine_per_token = jnp.sum(held.reshape(n, k), axis=1, dtype=jnp.int32)
     first = jnp.cumsum(mine_per_token) - mine_per_token
     return jnp.where((mine_per_token > 0)[:, None],
-                     summed[jnp.minimum(first, bound - 1)], 0.0)
+                     summed[jnp.minimum(first, n * k - 1)], 0)
+
+
+def _held(some, present):
+    """Rows of an ordered array with 0 in those of absent experts, which
+    `present` [rows, 1] says are not there (None: every row is)."""
+    if present is None:
+        return some
+    return jnp.where(present, some, jnp.zeros((), some.dtype))
 
 
 def _gate(g, attrs):
@@ -424,29 +499,29 @@ def moe_experts(ctx, ins, attrs):
         gate = gmm(xs, wg, counts)
         up = gmm(xs, wu, counts)
 
-    rows, bound = n * k, _compact_rows(n, k, experts, scored)
-    if bound:
-        telemetry.on_moe_share_compact_lowering(rows, bound)
-
-    def token_sums(some, branch):
-        """Out in float32 from the first `some` rows of the order."""
-        with _scopes("moe_experts", branch):
-            h = (_gate(_first(gate, some).astype(jnp.float32), attrs)[0]
-                 * _first(up, some).astype(jnp.float32)).astype(xs.dtype)
-            y = gmm(h, wd, counts)
-        with _scopes("moe_combine", branch):
-            if some < rows:
-                return _held_token_sums(
-                    y, token_row, counts, n, k,
-                    top_w.astype(jnp.float32).reshape(-1))
-            mine = _token_rows(y, token_row, n, k).astype(jnp.float32)
+    rows, chunk = n * k, _chunk_rows(n, k, experts, scored)
+    f32 = jnp.float32
+    if chunk:
+        telemetry.on_moe_share_compact_lowering(rows, chunk)
+    (h,) = _row_work(
+        "moe_experts",
+        lambda g, u, _: ((_gate(g.astype(f32), attrs)[0]
+                          * u.astype(f32)).astype(xs.dtype),),
+        (gate, up), counts, chunk)
+    with jax.named_scope("moe_experts"):
+        y = gmm(h, wd, counts)
+    with _scopes("moe_combine", chunked=chunk):
+        if chunk:
+            out = _held_token_sums(
+                y, token_row, counts, n, k, chunk,
+                amp_result(jnp.zeros((), f32), x.dtype).dtype,
+                top_w.astype(f32).reshape(-1))
+        else:
+            mine = _token_rows(y, token_row, n, k).astype(f32)
             if ranged:
                 # no product wrote an absent assignment's row
                 mine = jnp.where(held.reshape(n, k, 1), mine, 0.0)
-            return jnp.sum(mine * top_w.astype(jnp.float32)[..., None],
-                           axis=1)
-
-    out = _either_row_path(token_sums, counts, rows, bound)
+            out = jnp.sum(mine * top_w.astype(f32)[..., None], axis=1)
     out = amp_result(out, x.dtype).reshape(x.shape)
     return {"Out": [out], "Xs": [xs], "Gate": [gate], "Up": [up],
             "RowSlot": [row_slot], "TokenRow": [token_row],
@@ -476,87 +551,77 @@ def moe_experts_grad(ctx, ins, attrs):
     d_out = ins["OG@Out"][0].reshape(n, x.shape[-1])
     f32 = jnp.float32
 
-    rows, bound = n * k, _compact_rows(n, k, experts, scored)
+    rows, chunk = n * k, _chunk_rows(n, k, experts, scored)
     present = None
     if ranged:
         telemetry.on_moe_share_bwd_lowering(scored, experts, k)
-        with jax.named_scope("moe_route"), jax.named_scope("moe_hold"):
-            # the order puts the held groups' rows first
-            present = (jnp.arange(rows, dtype=jnp.int32)
-                       < jnp.sum(counts))[:, None]
+        if not chunk:
+            with jax.named_scope("moe_route"), jax.named_scope("moe_hold"):
+                # the order puts the held groups' rows first
+                present = (jnp.arange(rows, dtype=jnp.int32)
+                           < jnp.sum(counts))[:, None]
 
-    def held(first):
-        """The first rows of an ordered array with 0 in those of absent
-        experts."""
-        if present is None:
-            return first
-        return jnp.where(_first(present, first.shape[0]), first,
-                         jnp.zeros((), first.dtype))
+    def around_activation(g, u, dh_raw, w, present):
+        """From rows of Gate, Up, the down product's gradient to its
+        rows and the routing weights: the gate's, the up's and the down
+        product's row operands of the five products that follow (in the
+        places of Gate, Up and that gradient), and the rows' part of
+        the routing weights' gradient."""
+        g, u, dh_raw = (_held(a, present).astype(f32)
+                        for a in (g, u, dh_raw))
+        act, d_act = _gate(g, attrs)
+        h = act * u
+        # dh_raw is d<y_row, dOut> / dh, before the routing weight
+        d_w_rows = jnp.sum(dh_raw * h, axis=-1)
+        dh = dh_raw * w[:, None]
+        d_gate = (dh * u * d_act).astype(xs.dtype)
+        d_up = (dh * act).astype(xs.dtype)
+        hw = (h * w[:, None]).astype(xs.dtype)
+        return d_gate, d_up, hw, d_w_rows
 
-    def upstream(some, branch):
-        """[n * k, hidden]: dOut of the first `some` rows' tokens."""
-        with _scopes("moe_combine", branch):
-            return _whole(
-                d_out.astype(xs.dtype)[_first(row_slot, some) // k], rows)
+    def row_work(phase, work, *operands, over=0):
+        return _row_work(phase, work, operands, counts, chunk, present, over)
 
-    def around_activation(some, branch):
-        """From the first `some` rows of Gate, Up and `dx_down()`: the
-        rows' part of the routing weights' gradient [n * k], and the
-        gate's, the up's and the down product's row operands [n * k,
-        width] of the five products that follow."""
-        with _scopes("moe_experts", branch):
-            g, u = (held(_first(a, some)).astype(f32) for a in (gate, up))
-            act, d_act = _gate(g, attrs)
-            h = act * u
-            # d<y_row, dOut> / dh, before the routing weight
-            dh_raw = held(_first(dx_down(), some)).astype(f32)
-            w_some = _first(w_rows, some)
-            d_w_rows = jnp.sum(dh_raw * h, axis=-1)
-            dh = dh_raw * w_some[:, None]
-            d_gate = (dh * u * d_act).astype(xs.dtype)
-            d_up = (dh * act).astype(xs.dtype)
-            hw = (h * w_some[:, None]).astype(xs.dtype)
-            return tuple(_whole(a, rows) for a in (d_w_rows, d_gate, d_up, hw))
-
-    def token_grads(some, branch):
-        """X@GRAD in float32 from the first `some` rows of the gate's
-        and the up's gradients."""
-        with _scopes("moe_experts", branch):
-            d_xs = held(
-                (gmm_dx(_first(d_gate, some), wg, counts).astype(f32)
-                 + gmm_dx(_first(d_up, some), wu, counts).astype(f32))
-                .astype(xs.dtype))
-        with _scopes("moe_route", branch):
-            if some < rows:
-                return _held_token_sums(d_xs, token_row, counts, n, k)
-            return jnp.sum(_token_rows(d_xs, token_row, n, k).astype(f32),
-                           axis=1)
-
-    d_rows = _either_row_path(upstream, counts, rows, bound)
     with jax.named_scope("moe_combine"):
+        d_tokens = d_out.astype(xs.dtype)
         # every row's routing weight
         w_rows = _by_key(token_row, top_w.astype(f32).reshape(-1))
+    # dOut of every row's token
+    (d_rows,) = row_work(
+        "moe_combine", lambda slot, _: (d_tokens[slot // k],), row_slot)
     with jax.named_scope("moe_experts"):
         wg, wu, wd = mxu_operands(w_gate, w_up, w_down)
-        # between two choices of row path the product stands alone
-        dh_whole = gmm_dx(d_rows, wd, counts) if bound else None
-
-    def dx_down():
-        """[n * k, width]: the down product's gradient to its rows;
-        with one row path where it always stood, behind the
-        activation."""
-        if dh_whole is None:
-            return gmm_dx(d_rows, wd, counts)
-        return dh_whole
-
-    d_w_rows, d_gate, d_up, hw = _either_row_path(
-        around_activation, counts, rows, bound)
+        dh_raw = gmm_dx(d_rows, wd, counts)
+    d_gate, d_up, hw, d_w_rows = row_work(
+        "moe_experts", around_activation, gate, up, dh_raw, w_rows, over=3)
     with jax.named_scope("moe_experts"):
         d_w_down = gmm_dw(hw, d_rows, counts)
         d_w_gate = gmm_dw(xs, d_gate, counts)
         d_w_up = gmm_dw(xs, d_up, counts)
-    d_x = _either_row_path(token_grads, counts, rows, bound)
+        by_gate = gmm_dx(d_gate, wg, counts)
+        by_up = gmm_dx(d_up, wu, counts)
+    (d_xs,) = row_work(
+        "moe_experts",
+        lambda a, b, present: (
+            _held((a.astype(f32) + b.astype(f32)).astype(xs.dtype),
+                  present),),
+        by_gate, by_up, over=1)
+    with _scopes("moe_route", chunked=chunk):
+        if chunk:
+            # over the up product's input gradient, which nothing but
+            # the sum above read
+            d_x = _held_token_sums(d_xs, token_row, counts, n, k, chunk,
+                                   d_out.dtype, over=by_up)
+        else:
+            d_x = jnp.sum(_token_rows(d_xs, token_row, n, k).astype(f32),
+                          axis=1)
     with jax.named_scope("moe_route"):
+        if chunk:
+            # no trip wrote the rows past the last one's chunk: an absent
+            # assignment's routing weight has a gradient of 0
+            d_w_rows = jnp.where(
+                jnp.arange(rows, dtype=jnp.int32) < jnp.sum(counts),
+                d_w_rows, 0.0)
         d_top_w = _by_key(row_slot, d_w_rows).reshape(n, k)
     return {"X@GRAD": [d_x.astype(d_out.dtype).reshape(x.shape)],
             "TopW@GRAD": [d_top_w.astype(top_w.dtype)],

@@ -177,15 +177,16 @@ def test_the_expert_op_and_its_gradient_lower_nine_products_for_tpu():
     assert module.count("tpu_custom_call") == 9
 
 
-def test_both_row_paths_of_a_held_share_lower_for_tpu():
+def test_the_chunked_row_work_of_a_held_share_lowers_for_tpu():
     """`moe_experts` and its gradient at smallthinker-train-16k-ep8's
     shape (16384 tokens x 6, experts 24..31 of 64, 2560 -> 768, ReGLU,
-    bfloat16 compute) as one TPU program: the compact row path over
-    24576 rows beside the path over all 98304, forward and backward.
-    The products that stand between two choices of path are lowered
-    once, over whole arrays (gate, up; the down product's dx; the three
-    dw); a pass's last choice holds its own in either body (down; the
-    gate's and the up's dx)."""
+    bfloat16 compute) as one TPU program: the row work between the
+    grouped products as loops over chunks of 8192 of the 98304 rows,
+    under `moe_compact` in each of the op's three scopes, forward and
+    backward.  Every grouped product stands outside the loops, over
+    whole arrays, and is lowered once: three forward, three dx, three
+    dw.  No scope `moe_all_rows` is left (that the lowering holds no
+    choice of a body on data: tests/test_moe_share_grad.py)."""
     from paddle_tpu.obs import telemetry
     from paddle_tpu.ops import registry
 
@@ -210,13 +211,19 @@ def test_both_row_paths_of_a_held_share_lower_for_tpu():
     with fluid.amp.bf16_guard():
         module = jax.export.export(jax.jit(step), platforms=["tpu"])(
             ins, ins["X"][0]).mlir_module()
-    assert [module.count('kernel_name = "moe_gmm_%s_' % kernel)
-            for kernel in ("fwd", "dx", "dw")] == [2 + 2, 1 + 2 * 2, 3]
-    for branch in ("moe_compact", "moe_all_rows"):
-        assert "/moe_experts/%s/" % branch in module, branch
     delta = telemetry.snapshot_delta(before)
+    assert [module.count('kernel_name = "moe_gmm_%s_' % kernel)
+            for kernel in ("fwd", "dx", "dw")] == [3, 3, 3]
+    # and the arrays nothing has written that the loops start from: the
+    # forward's h and rows' sums, the backward's rows of dOut and the
+    # rows' parts of the routing weights' gradient
+    assert module.count('kernel_name = "moe_unwritten"') == 4
+    assert module.count("tpu_custom_call") == 9 + 4
+    for phase in ("moe_route", "moe_experts", "moe_combine"):
+        assert "/%s/moe_compact/while/" % phase in module, phase
+    assert "moe_all_rows" not in module and "stablehlo.if" not in module
     assert delta[
-        "moe_share_compact_lowerings_total{bound=24576,rows=98304}"] == 1
+        "moe_share_compact_lowerings_total{chunk=8192,rows=98304}"] == 1
 
 
 def test_the_scan_kernels_lower_for_tpu():

@@ -4,13 +4,16 @@ against dense experts; the eight shares' outputs and input gradients
 add up to the uncut layer's; garbage in the rows no product wrote
 reaches no sum; ReGLU experts (`activation="relu"`); a router that reads
 another tensor than the experts (`fluid.layers.moe(router_input=)`); the
-counters; and the compact row path of an op that orders 32768 rows or
-more (a bound from the shapes, a check at run time, two exact bodies of
-the row work between the grouped products).
+counters; and the chunked row work of an op that orders 32768 rows or
+more (the passes between the grouped products as loops over chunks of
+the order, as many trips as hold a held row: one body, no choice).
 
 Tiny sizes on the CPU: hidden 64, 8 experts of 32 scored, 2 a token; the
-compact path at 8192 tokens x 4, 2 of 16 experts held, hidden 16.
+chunked row work at 8192 tokens x 4, 2 of 16 experts held, hidden 16.
 """
+
+import functools
+import re
 
 import numpy as np
 import pytest
@@ -120,14 +123,14 @@ def test_a_share_and_every_gradient_against_dense_experts(first, count,
                                     [(0, 4), (4, 4)],
                                     [(0, 2), (2, 5), (7, 1)],
                                     "compact"])
-def test_the_shares_add_up_to_the_uncut_layer_backward_too(ranges,
-                                                           activation):
+def test_the_shares_add_up_to_the_uncut_layer_backward_too(
+        ranges, activation, nan_where_nothing_wrote):
     """Outputs, input gradients and routing-weight gradients of the
     shares add up to the uncut layer's; each share's weight gradients
     are the uncut layer's for its experts.  "compact": eight shares of
-    two at the shape that has a compact path, under a routing that
-    sends the first share more rows than its bound and the others
-    fewer, so both bodies are among the parts."""
+    two at the shape whose row work goes by chunks, under a routing
+    that sends the first share several chunks' rows and the others
+    less than one, while the uncut layer has the plain body."""
     counts = []
     if ranges == "compact":
         ranges = [(first, HELD) for first in range(0, SCORED, HELD)]
@@ -151,7 +154,7 @@ def test_the_shares_add_up_to_the_uncut_layer_backward_too(ranges,
     for slot in total:
         _close(total[slot], whole[slot], slot)
     if len(x) == TOKENS:
-        assert [int(c.sum()) <= BOUND for c in counts] == [False] + 7 * [True]
+        assert [int(c.sum()) > CHUNK for c in counts] == [True] + 7 * [False]
 
 
 def test_rows_no_product_wrote_reach_no_sum():
@@ -165,14 +168,26 @@ def test_rows_no_product_wrote_reach_no_sum():
         np.testing.assert_array_equal(spoiled[slot], clean[slot])
 
 
-# the compact row path: 32768 assignments, the held two of sixteen
-# experts get an even router's 4096 of them under a bound of 8192
+# the chunked row work: 32768 assignments in chunks of 8192, the held two
+# of sixteen experts get an even router's 4096 of them
 TOKENS, TOP, SCORED, HELD, WIDE, NARROW, FIRST = 8192, 4, 16, 2, 16, 8, 6
-BOUND = 8192
+ROWS, CHUNK = TOKENS * TOP, 8192
 
 
 def _compact_operands(seed):
     return _operands(seed, n=TOKENS, d=WIDE, f=NARROW, scored=SCORED, k=TOP)
+
+
+@pytest.fixture
+def nan_where_nothing_wrote(monkeypatch):
+    """Off the TPU an array nothing has written is zeros
+    (`grouped_matmul.unwritten`); on it, whatever the memory held.  NaN
+    in its place shows a read of a row that no trip wrote."""
+    from paddle_tpu.kernels import grouped_matmul
+
+    monkeypatch.setattr(grouped_matmul, "unwritten",
+                        lambda shape, dtype, after: jnp.full(shape, jnp.nan,
+                                                             dtype))
 
 
 def _routing(in_slot_0, in_slot_1, first=FIRST, others=False):
@@ -192,49 +207,94 @@ def _routing(in_slot_0, in_slot_1, first=FIRST, others=False):
     return jnp.asarray(idx, jnp.int32)
 
 
-def test_the_bound_of_the_compact_path_from_shapes():
-    """`_compact_rows` (tokens, a token, held, scored): twice an even
-    router's share on the row tile, where the op is ranged, orders 32768
-    rows or more and the bound is at most half of them; else 0."""
-    for shape, bound in (
-            ((16384, 6, 8, 64), 24576),       # smallthinker-train-16k-ep8
-            ((TOKENS, TOP, HELD, SCORED), BOUND),
-            ((8200, 4, 2, 16), 8448),         # 8200 on the tile of 256
+def _held_routing(held_rows):
+    """TopIdx [TOKENS, TOP] with `held_rows` of its entries, anywhere,
+    on the held pair and the others on the 14 absent experts (a token
+    may name an expert twice: each is an assignment of its own); with
+    all ROWS of them a router that sends the range everything."""
+    rs = np.random.RandomState(held_rows)
+    absent = np.array([e for e in range(SCORED)
+                       if e not in (FIRST, FIRST + 1)])
+    flat = absent[rs.randint(0, len(absent), ROWS)]
+    flat[rs.permutation(ROWS)[:held_rows]] = \
+        FIRST + rs.randint(0, HELD, held_rows)
+    return jnp.asarray(flat.reshape(TOKENS, TOP), jnp.int32)
+
+
+def test_which_shapes_take_the_loop_and_with_what_chunk():
+    """`_chunk_rows` (tokens, a token, held, scored): a ranged op that
+    orders 32768 rows or more, a whole number of chunks, runs its row
+    work in chunks of 8192, a whole number of the grouped kernels' row
+    tiles, however few or many of the experts scored it holds; every
+    other op has the plain body (0)."""
+    assert moe_ops._CHUNK_ROWS == CHUNK and CHUNK % moe_ops._ROW_TILE == 0
+    for shape, chunk in (
+            ((16384, 6, 8, 64), CHUNK),       # smallthinker-train-16k-ep8
+            ((TOKENS, TOP, HELD, SCORED), CHUNK),
+            ((4096, 8, 16, 256), CHUNK),      # pangu-decode-ep16's prefill
+            ((8192, 8, 8, 128), CHUNK),       # exaone-turn-32k-ep16's
+            ((16384, 10, 32, 512), CHUNK),    # qwen3next-decode-ep16's
+            ((TOKENS, TOP, 15, SCORED), CHUNK),  # all but one held
             ((4096, 8, 64, 64), 0),           # olmoe-train-4k: all held
             ((256, 8, 16, 256), 0),           # pangu-decode-ep16's step
             ((16, 8, 16, 256), 0),            # dsv32's step
             ((16 * 128, 8, 16, 256), 0),      # and its question block
             ((8, 8, 8, 128), 0),              # exaone's step
             ((8 * 128, 8, 8, 128), 0),        # and its block
-            ((TOKENS, TOP, 5, SCORED), 0),    # the bound past half the rows
+            ((8200, 4, 2, 16), 0),            # no whole number of chunks
             ((TOKENS - 1, TOP, HELD, SCORED), 0)):
-        assert moe_ops._compact_rows(*shape) == bound, shape
+        assert moe_ops._chunk_rows(*shape) == chunk, shape
+
+
+@functools.lru_cache(maxsize=None)
+def _jitted_share(activation, chunked):
+    """`_share` at the chunked shape's range as one jitted program; with
+    `chunked` False the plain body at the same shape (the threshold is
+    out of reach while it is traced)."""
+    def step(*operands):
+        saved = moe_ops._CHUNK_MIN_ROWS
+        moe_ops._CHUNK_MIN_ROWS = saved if chunked else 2 * ROWS
+        try:
+            return _share(*operands, FIRST, HELD, activation)
+        finally:
+            moe_ops._CHUNK_MIN_ROWS = saved
+
+    return jax.jit(step)
 
 
 @pytest.mark.parametrize("activation", ["silu", "relu"])
-@pytest.mark.parametrize("in_slot_0,in_slot_1,compact", [
-    (None, None, True),          # a near even router
-    (TOKENS, 4000, False),       # the range gets 12192 rows
-    (TOKENS, 0, True),           # exactly the bound
-    (TOKENS, 1, False),          # one more
-    (0, 0, True),                # none at all
+@pytest.mark.parametrize("held_rows,trips", [
+    (None, 1),                   # a near even router
+    (0, 1),                      # none at all
+    (1, 1),
+    (CHUNK - 1, 1),
+    (CHUNK, 2),                  # exactly a chunk: the trip of row 8192
+    (CHUNK + 1, 2),              # one more
+    (3 * CHUNK + 700, 4),        # several chunks, the last in part
+    (ROWS, 4),                   # the range gets everything
 ])
-def test_either_row_path_against_dense_experts(in_slot_0, in_slot_1, compact,
-                                               activation):
-    """At a shape with a compact path, Out and all five gradients
-    against dense experts whichever body the held rows' count picks:
-    nothing is dropped past the bound, the other body runs."""
+def test_the_chunked_row_work_against_dense_experts(
+        held_rows, trips, activation, monkeypatch, nan_where_nothing_wrote):
+    """At a shape whose row work goes by chunks, Out and all five
+    gradients against dense experts however many rows the range is
+    sent, and bit for bit the plain body's on the same inputs where the
+    order of the sums is the same: every sum adds the same terms in the
+    same order (the plain body adds an absent assignment's 0 between
+    them), so on the CPU the gradients agree to the bit as two jitted
+    programs, and Out op by op (jitted, the plain body's sum over a
+    token's rows is the compiler's to order, and a chunk's
+    multiply-adds are its to contract)."""
     x, top_w, top_idx, weights, d_out = _compact_operands(17)
-    if in_slot_0 is not None:
-        top_idx = _routing(in_slot_0, in_slot_1)
+    if held_rows is not None:
+        top_idx = _held_routing(held_rows)
+    operands = (x, top_w, top_idx, weights, d_out)
     held = tuple(w[FIRST:FIRST + HELD] for w in weights)
     counts = []
-    out, grads = _share(x, top_w, top_idx, weights, d_out, FIRST, HELD,
-                        activation, counts=counts)
-    assert moe_ops._compact_rows(TOKENS, TOP, HELD, SCORED) == BOUND
-    assert (int(counts[0].sum()) <= BOUND) == compact
-    if in_slot_0 is not None:
-        assert int(counts[0].sum()) == in_slot_0 + in_slot_1
+    out, grads = _share(*operands, FIRST, HELD, activation, counts=counts)
+    assert moe_ops._chunk_rows(TOKENS, TOP, HELD, SCORED) == CHUNK
+    assert min(int(counts[0].sum()) // CHUNK + 1, ROWS // CHUNK) == trips
+    if held_rows is not None:
+        assert int(counts[0].sum()) == held_rows
     _close(out, _dense(x, top_w, top_idx, *held, FIRST, activation), "Out")
     want = jax.grad(
         lambda x, top_w, *w: jnp.sum(
@@ -242,19 +302,68 @@ def test_either_row_path_against_dense_experts(in_slot_0, in_slot_1, compact,
         argnums=(0, 1, 2, 3, 4))(x, top_w, *held)
     for slot, w in zip(GRADS, want):
         _close(grads[slot], w, slot)
+    _, jitted = _jitted_share(activation, True)(*operands)
+    _, plain_jitted = _jitted_share(activation, False)(*operands)
+    for slot in GRADS:
+        np.testing.assert_array_equal(jitted[slot], plain_jitted[slot], slot)
+    monkeypatch.setattr(moe_ops, "_CHUNK_MIN_ROWS", 2 * ROWS)
+    plain_out, _ = _share(*operands, FIRST, HELD, activation)
+    np.testing.assert_array_equal(out, plain_out)
 
 
-@pytest.mark.parametrize("in_slot_1", [0, 1])
-def test_rows_no_product_wrote_reach_no_sum_on_either_row_path(in_slot_1):
-    """NaN in the kept rows past the held ones changes no gradient, at
-    the bound (the compact body masks its rows up to the bound and reads
-    none past it) and one past it."""
+@pytest.mark.parametrize("held_rows", [CHUNK, CHUNK + 1])
+def test_rows_no_product_wrote_reach_no_sum_in_the_chunked_row_work(
+        held_rows, nan_where_nothing_wrote):
+    """NaN in the kept rows past the held ones changes no gradient:
+    where the held rows fill their last chunk no trip reads one, and
+    one row on the chunk that holds row sum(Counts) masks its others."""
     x, top_w, _, weights, d_out = _compact_operands(5)
-    operands = (x, top_w, _routing(TOKENS, in_slot_1), weights, d_out)
+    operands = (x, top_w, _held_routing(held_rows), weights, d_out)
     _, clean = _share(*operands, FIRST, HELD, "relu")
     _, spoiled = _share(*operands, FIRST, HELD, "relu", spoil=True)
     for slot in GRADS:
         np.testing.assert_array_equal(spoiled[slot], clean[slot])
+
+
+@pytest.mark.parametrize("in_slot_0", [0, CHUNK])
+def test_the_tile_of_an_empty_held_expert_holds_what_a_trip_wrote(
+        in_slot_0, monkeypatch, nan_where_nothing_wrote):
+    """The grouped kernels' own bodies under the Pallas interpreter,
+    which fills what nothing wrote with NaN: the range's second expert
+    gets no row and the first none or exactly a chunk's, so `gmm_dw`
+    gives the empty one its visit of the tile at row 0 or 8192, whose
+    rows it multiplies by 0.  The loops run as far as that row, so the
+    rows there are a trip's zeros and not what Gate and Up held, and
+    every gradient is the plain path's."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from paddle_tpu.kernels import grouped_matmul
+
+    x, top_w, _, weights, d_out = _compact_operands(23)
+    operands = (x, top_w, _routing(in_slot_0, 0), weights, d_out)
+    out, plain = _share(*operands, FIRST, HELD, "relu")
+
+    def through(kernel):
+        def call(a, b, counts):
+            k, n = (a.shape[1], b.shape[1]) if kernel == "dw" else b.shape[1:]
+            blocks = grouped_matmul.choose_blocks(a.shape[0], k, n, 4, kernel)
+            if kernel == "dw":
+                return grouped_matmul._dw_call(blocks, a, b, counts)
+            return grouped_matmul._rows_call(kernel, blocks, a, b, counts)
+        return call
+
+    # off the TPU the products take their plain path: hand it the kernels
+    for name, kernel in (("ragged_gmm", "fwd"), ("ragged_gmm_dx", "dx"),
+                         ("ragged_gmm_dw", "dw")):
+        monkeypatch.setattr(grouped_matmul, name, through(kernel))
+    counts = []
+    with pltpu.force_tpu_interpret_mode():
+        got_out, got = _share(*operands, FIRST, HELD, "relu", counts=counts)
+    assert list(counts[0]) == [in_slot_0, 0]
+    _close(got_out, out, "Out")
+    for slot in GRADS:
+        assert np.isfinite(np.asarray(got[slot])).all(), slot
+        _close(got[slot], plain[slot], slot)
 
 
 def _traced(n, k, held, scored, hidden=16, width=8):
@@ -277,17 +386,40 @@ def _traced(n, k, held, scored, hidden=16, width=8):
     return jax.jit(step).lower(ins, ins["X"][0]).as_text(debug_info=True)
 
 
-def test_a_share_under_32768_rows_has_one_body():
+def _choices_on_data(text):
+    """The `stablehlo.case` operations of a lowering whose index is
+    neither a constant nor another such operation's result: a choice
+    between the platforms a computation may be lowered for
+    (`lax.platform_dependent`: the grouped products, the arrays nothing
+    has written) is on a constant; `lax.cond` on data is not."""
+    cases = re.findall(r'(%\w+) = "stablehlo\.case"\((%\w+)\)', text)
+    of_a_case = {result for result, _ in cases}
+    return [index for _, index in cases
+            if not re.fullmatch(r"%c(_\d+)?", index)
+            and index not in of_a_case]
+
+
+@pytest.mark.parametrize("shape", [(TOKENS, TOP, HELD, SCORED),
+                                   (16384, 6, 8, 64)])
+def test_a_share_under_32768_rows_has_one_body(shape):
     """pangu-decode-ep16's step (256 rows x 8, 16 of 256 held) opens
-    neither row path's scope, forward or backward: it has the one body
-    it had.  The shape with a compact path has both scopes under each of
-    the op's three."""
-    text = _traced(256, 8, 16, 256)
-    assert "moe_compact" not in text and "moe_all_rows" not in text
-    text = _traced(TOKENS, TOP, HELD, SCORED)
+    no `moe_compact`, forward or backward: it has the one body it had.
+    A shape whose row work goes by chunks (the test's; the rows of
+    smallthinker-train-16k-ep8's) opens it under each of the op's three
+    scopes around loops (`stablehlo.while`), and holds no scope
+    `moe_all_rows` and no choice of a body on data (`stablehlo.case`,
+    `.if`)."""
+    plain = _traced(256, 8, 16, 256)
+    assert "moe_compact" not in plain and "moe_all_rows" not in plain
+    text = _traced(*shape)
     for phase in ("moe_route", "moe_experts", "moe_combine"):
-        for branch in ("moe_compact", "moe_all_rows"):
-            assert "/%s/%s/" % (phase, branch) in text, (phase, branch)
+        assert "/%s/moe_compact/while/" % phase in text, phase
+    assert "moe_all_rows" not in text
+    assert "stablehlo.case" in text and not _choices_on_data(text)
+    assert "stablehlo.if" not in text and "stablehlo.if" not in plain
+    chosen = jax.jit(lambda p, x: jax.lax.cond(p, jnp.sin, jnp.cos, x)) \
+        .lower(True, 1.0).as_text()
+    assert _choices_on_data(chosen)
 
 
 def test_an_unknown_activation_is_refused():
@@ -324,7 +456,7 @@ def test_counters_say_a_share_was_differentiated():
     before = telemetry.snapshot()
     _share(*_compact_operands(3), FIRST, HELD, "relu")
     delta = telemetry.snapshot_delta(before)
-    assert delta["moe_share_compact_lowerings_total{bound=8192,rows=32768}"] \
+    assert delta["moe_share_compact_lowerings_total{chunk=8192,rows=32768}"] \
         == 1
     assert delta["moe_share_lowerings_total{held=2,scored=16,top_k=4}"] == 1
 
