@@ -105,7 +105,7 @@ def build_image_model(model, batch, image_size, class_dim):
     its loss and a momentum optimizer, as `__graft_entry__` builds it."""
     from __graft_entry__ import _build_model
     from paddle_tpu import models
-    from paddle_tpu.tune.models import MODELS
+    from paddle_tpu.models.image_train import MODELS
 
     return _build_model(getattr(models, model), batch, image_size,
                         class_dim, with_loss=True,

@@ -16,11 +16,10 @@ __version__ = "0.1.0"
 
 __all__ = ["reader", "dataset", "batch", "fluid", "v2", "infer",
            "layer", "image", "obs", "resilience", "analysis",
-           "compile", "tune"]
+           "compile"]
 
 from . import analysis  # noqa: E402
 from . import compile  # noqa: E402,A004 — paddle_tpu.compile subsystem
-from . import tune  # noqa: E402
 from . import obs  # noqa: E402
 from . import resilience  # noqa: E402
 from . import fluid  # noqa: E402

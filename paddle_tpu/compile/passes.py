@@ -153,7 +153,7 @@ class RewritePass:
                     # an explicitly-spelled default ("fuse:cap=0") is
                     # the SAME pipeline as the bare pass: it must not
                     # mint a distinct spec_token/pipeline_id (one
-                    # semantics -> one cache key, one ptune point)
+                    # semantics -> one cache key)
                     self._explicit[key] = value
             else:
                 value = default
@@ -486,8 +486,7 @@ class PassManager:
     @property
     def spec(self):
         """The canonical comma-joined spec these passes resolve to
-        (knobs included) — what tune/space.py normalizes pipelines
-        through."""
+        (knobs included)."""
         return ",".join(p.spec_token for p in self.passes)
 
     @property

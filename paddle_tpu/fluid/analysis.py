@@ -6,10 +6,10 @@ floor on a machine with a given MXU peak and HBM bandwidth:
 
     t_op >= max(flops / peak_flops, bytes / bandwidth)
 
-This is the tool behind the "profile-backed ceiling analysis" in
-docs/PERF.md: the device profile (benchmark/run.py --trace 1) says
-where the time WENT; this says where it HAS to go, so the gap between
-the two is the actionable headroom.  The reference has no counterpart
+This is the tool behind the ceiling analysis in PERF.md: the device
+profile (benchmark/run.py --trace 1) says where the time WENT; this
+says where it HAS to go, so the gap between the two is the actionable
+headroom.  The reference has no counterpart
 (its benchmark suite only reports throughput); on TPU the
 compute/bandwidth split is the whole performance story, so the
 analyzer is a first-class framework facility.
@@ -32,7 +32,7 @@ Model caveats (documented, deliberate):
     sublane dim — the honest basis for the `layout` rewrite pass's
     accept/decline decision (compile/opt_passes.py).  Off by default:
     XLA re-layouts MXU operands itself, so logical-shape bytes remain
-    the fairer fleet-wide default for perf blobs and ptune ranking.
+    the fairer fleet-wide default.
   * with ``bf16_act`` (the FLAGS_amp_bf16_act policy), non-persistable
     float tensors count 2 bytes/element; persistable (master weights,
     running stats) stay 4.

@@ -20,9 +20,9 @@ OIHW filters in both layouts (ops/conv.py _layout4d), so parameters
 and checkpoints are layout-portable.
 
 On TPU this is an experimentation surface, not a default: XLA already
-assigns C-minor physical layouts to NCHW convolutions (docs/PERF.md
-round-3 profile), so the pass exists for capability parity with the
-reference and for measuring that claim.
+assigns C-minor physical layouts to NCHW convolutions, so the pass
+exists for capability parity with the reference and for measuring that
+claim.
 """
 
 from ..core.desc import OpDesc, VarDesc

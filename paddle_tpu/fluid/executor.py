@@ -846,9 +846,8 @@ class Executor:
                                                             val)
                     # input time as a counter of seconds:
                     # snapshot_delta turns it into the per-step/per-leg
-                    # h2d-INPUT share the obs.perf classifier reads
-                    # (bytes alone can't say whether the feed path is
-                    # the bottleneck)
+                    # h2d-INPUT share (bytes alone can't say whether
+                    # the feed path is the bottleneck)
                     obs_tele.on_feed_seconds(time.perf_counter() - t_feed)
 
             with obs_trace.span("executor/plan",

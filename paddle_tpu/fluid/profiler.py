@@ -38,17 +38,6 @@ def is_enabled():
     return _enabled[0]
 
 
-def set_enabled(on):
-    """Switch the timing table on or off without the reset and the
-    printed table of `profiler()`; returns what it was.  While it is on
-    the executor blocks on each compiled segment's outputs, so a row,
-    and the `executor/segment` span around it, covers the segment's
-    device time (obs.perf's sampled steps want that)."""
-    prev = _enabled[0]
-    _enabled[0] = bool(on)
-    return prev
-
-
 # cached (registry, seconds_family, calls_family): record() runs on
 # the serving request path, so resolve the families once per registry
 # instead of two locked get-or-creates per observation

@@ -1,21 +1,17 @@
-"""Model-name -> program-builder mapping for the autotuner.
+"""Model-name -> training-program builder for the image models.
 
-`tune/rank.py` scores Programs, not model names; this module turns
-the image-model names into `builder(batch)` callables that construct
-the training topology of `__graft_entry__._build_model`
-(concrete-shape feeds, softmax-with-cross-entropy loss, Momentum
-update), so a ranked prediction and a measured record describe the
-same program.
-
-Kept inside the package because ranking must work wheel-installed
-with zero devices."""
+Turns the image-model names into `builder(model)(batch)` callables
+that construct the training topology of
+`__graft_entry__._build_model` (concrete-shape feeds,
+softmax-with-cross-entropy loss, Momentum update), so the tools that
+take a `--model` name (`pmem`, `pshard`) and `chip_smoke.py` describe
+the same program."""
 
 __all__ = ["MODELS", "builder", "model_names"]
 
 # channels / default image size / default class count per model —
-# lenet5 is the canonical 1x28x28 MNIST topology (the proglint and
-# ptune selftest flagship); the rest are the reference benchmark
-# set's shapes
+# lenet5 is the canonical 1x28x28 MNIST topology (the selftests'
+# flagship); the rest are the reference benchmark set's shapes
 MODELS = {
     "lenet5": dict(channels=1, image_size=28, class_dim=10),
     "smallnet": dict(channels=3, image_size=32, class_dim=10),
@@ -32,7 +28,7 @@ def model_names():
 
 
 def _model_fn(name):
-    from .. import models as model_zoo
+    from . import image as model_zoo
 
     return {"lenet5": model_zoo.lenet5,
             "smallnet": model_zoo.smallnet_mnist_cifar,
@@ -43,14 +39,8 @@ def _model_fn(name):
             "resnet50": model_zoo.resnet50}[name]
 
 
-def builder(model, image_size=None, class_dim=None,
-            with_startup=False):
-    """batch -> (main_program, loss_name) for `model`.
-
-    with_startup=True returns (main, startup, loss_name) instead —
-    callers that actually RUN the program (the pshard selftest)
-    need the startup program to materialize parameters;
-    ranking-only callers keep the two-tuple contract.
+def builder(model, image_size=None, class_dim=None):
+    """batch -> (main_program, startup_program, loss_name) for `model`.
 
     The training program: concrete feed shapes
     (append_batch_size=False, so the sharding analyzer sees the real
@@ -58,7 +48,7 @@ def builder(model, image_size=None, class_dim=None,
     0.9).  Raises KeyError-style ValueError for unknown names so the
     CLI can list what exists."""
     if model not in MODELS:
-        raise ValueError("unknown model %r; ptune knows %s"
+        raise ValueError("unknown model %r; known: %s"
                          % (model, ", ".join(model_names())))
     spec = MODELS[model]
     channels = spec["channels"]
@@ -83,8 +73,6 @@ def builder(model, image_size=None, class_dim=None,
             avg_loss = fluid.layers.mean(loss)
             fluid.optimizer.MomentumOptimizer(
                 learning_rate=0.01, momentum=0.9).minimize(avg_loss)
-        if with_startup:
-            return main, startup, avg_loss.name
-        return main, avg_loss.name
+        return main, startup, avg_loss.name
 
     return build

@@ -1,6 +1,6 @@
 """paddle_tpu.obs — the unified observability layer.
 
-One place for the three signals every perf/serving PR reads
+One place for the three signals every performance and serving PR reads
 (reference: paddle/platform/profiler.h:27-146 wraps every op in a
 RecordEvent and parses one global event table — here the same idea is
 split into composable pieces instead of one table):
@@ -37,12 +37,6 @@ split into composable pieces instead of one table):
                  pushed through the coordinator's TTL-lease store,
                  merged with `host=` labels, with step-time skew and
                  `fleet_straggler{host=}` detection.
-  * `perf`     — continuous step profiler (per-step time-split records
-                 in a bounded ring, Chrome-trace/JSONL export), the
-                 bottleneck classifier (compute/hbm/input/host verdicts
-                 over the fluid/analysis roofline + XLA attribution),
-                 and the perf-history regression gate behind `pperf`
-                 (tools/perf_cli.py).
   * `mem`      — HBM memory observability: the static liveness
                  timeline (per-op live bytes, top buffers blamed to
                  defining ops) vs XLA's measured `memory_analysis()`
@@ -64,11 +58,10 @@ from . import registry
 from . import telemetry
 from . import health
 from . import flight
-from . import perf
 from . import mem
 from . import context
 from . import tail
 from . import fleet
 
 __all__ = ["trace", "registry", "telemetry", "health", "flight",
-           "perf", "mem", "context", "tail", "fleet"]
+           "mem", "context", "tail", "fleet"]

@@ -1,6 +1,6 @@
 """Collective/communication observability: per-bucket comm spans, the
-overlap-efficiency truth loop, analytic-floor drift calibration, and
-cross-host trace merge.
+overlap-efficiency truth loop, analytic-floor drift, and cross-host
+trace merge.
 
 The SPMD mainline predicts communication cost (the PartitionPlan's
 ring floor) and schedules it (the bucketed ring-allreduce in
@@ -27,9 +27,8 @@ This module closes the loop from three sides:
     (`make_overlapped_dp_step(skip_reduce=True)`); the difference is
     the EXPOSED comm time the schedule failed to hide behind backward
     compute.  `comm_exposed_seconds` and `overlap_efficiency` gauges
-    publish the split; `calibration_blob` distills the per-bucket
-    measured/predicted drift into the blob `ptune fit` consumes
-    (`tune.fit.load_comm_calibration`), exactly like PR 15's HBM blob.
+    publish the split; `drift_report` gives the per-bucket
+    measured/predicted drift off the ring-cost floor.
   * **Cross-host trace merge** — workers push bounded span windows
     into the master's TTL-lease store (`FleetReporter(span_window=N)`
     -> `/obsspan/<host>`); `merge_windows` re-bases every host's
@@ -41,9 +40,7 @@ This module closes the loop from three sides:
     which host's backward ran long vs whose allreduce stalled, at
     phase granularity.
 
-`tools/comm_cli.py` ("pcomm") is the operator surface; `pperf gate
---comm-tolerance` regresses on the exposed-comm history the same way
-`--mem-tolerance` regresses on HBM peaks.
+`tools/comm_cli.py` ("pcomm") is the operator surface.
 """
 
 import json
@@ -58,13 +55,10 @@ from . import trace as trace_mod
 __all__ = ["record_schedule", "bucket_span", "schedule_span",
            "last_schedule", "reset", "measure_bucket_times",
            "measure_trainer_comm", "overlap_report", "drift_report",
-           "calibration_blob", "save_calibration",
            "span_window_payload", "push_span_window",
            "collect_span_windows", "merge_windows", "ClockResponder",
-           "estimate_clock_offsets", "COMM_CALIBRATION_KIND",
-           "SPAN_PREFIX", "CLOCK_PING_PREFIX", "CLOCK_PONG_PREFIX"]
-
-COMM_CALIBRATION_KIND = "paddle_tpu.comm_calibration"
+           "estimate_clock_offsets", "SPAN_PREFIX",
+           "CLOCK_PING_PREFIX", "CLOCK_PONG_PREFIX"]
 
 # lease-store key prefixes: span windows ride beside the /obs/
 # snapshot pushes; the clock ping/pong exchange gets its own namespace
@@ -446,7 +440,7 @@ def overlap_report(trainer, feeds, reps=3, bucket_report=None):
 
 
 # ---------------------------------------------------------------------------
-# analytic-floor drift -> ptune calibration blob
+# analytic-floor drift
 # ---------------------------------------------------------------------------
 
 def drift_report(bucket_report):
@@ -471,52 +465,6 @@ def drift_report(bucket_report):
     return {"kind": "paddle_tpu.comm_drift", "version": 1,
             "rows": rows, "n": len(rows),
             "median_ratio": _median(ratios)}
-
-
-def _platform_class():
-    import jax
-
-    from . import perf as obs_perf
-
-    devs = jax.devices()
-    return obs_perf.platform_class({
-        "platform": devs[0].platform, "n_devices": len(devs)})
-
-
-def calibration_blob(bucket_report, platform_class=None, model=None,
-                     leg="pcomm"):
-    """The per-bucket drift distilled into the blob `ptune fit`
-    consumes (`tune.fit.load_comm_calibration` ->
-    `fit_calibration(comm_pairs=...)`): one measured/predicted pair
-    per bucket, each stamped with its platform class so the fit's
-    same-class filter keeps cpu-simulated rings out of a TPU
-    calibration.  None when nothing was measured."""
-    buckets = (bucket_report or {}).get("buckets") or []
-    pairs = []
-    cls = platform_class or _platform_class()
-    for r in buckets:
-        if not r.get("measured_s") or not r.get("pred_s") \
-                or r["pred_s"] <= 0:
-            continue
-        pairs.append({"leg": "%s:bucket%d" % (leg, r["bucket"]),
-                      "measured_s": float(r["measured_s"]),
-                      "pred_s": float(r["pred_s"]),
-                      "wire_bytes": int(r["wire_bytes"]),
-                      "platform_class": cls})
-    if not pairs:
-        return None
-    ratios = [p["measured_s"] / p["pred_s"] for p in pairs]
-    return {"kind": COMM_CALIBRATION_KIND, "version": 1,
-            "comm_ratio": _median(ratios), "n": len(pairs),
-            "platform_class": cls, "model": model, "pairs": pairs}
-
-
-def save_calibration(blob, path):
-    tmp = str(path) + ".tmp"
-    with open(tmp, "w") as f:
-        json.dump(blob, f, indent=1, sort_keys=True)
-    os.replace(tmp, str(path))
-    return str(path)
 
 
 # ---------------------------------------------------------------------------

@@ -33,12 +33,6 @@ server's `/debug/tail` span trees and `/metrics` exemplars by
 request_id / trace_id — one command from "p99 is bad" to the span
 tree that explains it.
 
-`latency_blob(report)` distills a run into the `latency` blob
-`perf.normalize_record` passes into perf_history.jsonl, where
-`gate_history(latency_tolerance=)` / `pperf gate --latency-tolerance`
-turns tail-latency regressions into CI failures (same-key discipline
-as the mem/comm gates).
-
 `python -m paddle_tpu.tools.load_cli --selftest` ("pload") certifies
 the whole loop, including the omission-safety claim itself: an
 injected engine stall must inflate the open-loop p99 while the
@@ -59,7 +53,7 @@ __all__ = [
     "TrafficMix", "parse_phases", "rate_at", "build_schedule",
     "load_access_log", "replay_schedule", "HttpTarget",
     "LoopbackTarget", "vector_payload", "run_open_loop",
-    "run_closed_loop", "build_report", "percentile", "latency_blob",
+    "run_closed_loop", "build_report", "percentile",
     "join_tail", "parse_exemplars", "join_exemplars", "format_report",
 ]
 
@@ -600,25 +594,6 @@ def build_report(samples, mode, wall_s, slo_ms=None, offered_rps=None,
             "violations": len(lats) - good,
         }
     return report
-
-
-def latency_blob(report):
-    """The `latency` blob a bench record carries into
-    perf_history.jsonl (perf.normalize_record passes these keys
-    through; `gate_history(latency_tolerance=)` regresses on the
-    percentile keys with the same-key discipline of the mem/comm
-    gates)."""
-    blob = {"mode": report["mode"], "n": report["n"]}
-    blob.update(report["percentiles_ms"])
-    if report.get("offered_rps") is not None:
-        blob["offered_rps"] = report["offered_rps"]
-    if report.get("achieved_rps") is not None:
-        blob["achieved_rps"] = report["achieved_rps"]
-    slo = report.get("slo")
-    if slo:
-        blob["slo_ms"] = slo["slo_ms"]
-        blob["slo_attainment"] = slo["attainment"]
-    return blob
 
 
 # ---------------------------------------------------------------------------

@@ -1,11 +1,10 @@
 """HBM memory observability: static liveness timeline vs XLA actuals,
-buffer-donation audit, OOM pre-flight/post-mortems, and the drift
-calibration feed for `paddle_tpu.tune`.
+buffer-donation audit, OOM pre-flight/post-mortems.
 
-The repo *estimates* HBM in three places — the shard analyzer's S005
-per-device peaks, `ptune`'s budget rejections, `auto_remat`'s accept
-gate — but until this module nothing ever checked those predictions
-against what XLA actually allocates.  Five layers close the loop:
+The repo *estimates* HBM in two places — the shard analyzer's S005
+per-device peaks and `auto_remat`'s accept gate — but until this
+module nothing ever checked those predictions against what XLA
+actually allocates.  Five layers close the loop:
 
   * **static timeline** — `program_timeline(program, fetches)` runs
     the ONE shared liveness walk (`analysis.dataflow
@@ -14,8 +13,8 @@ against what XLA actually allocates.  Five layers close the loop:
     top-N buffers resident at the peak, each blamed to its defining
     op.  `render_timeline` draws it, `timeline_chrome_trace` exports
     a Chrome-trace counter track ("ph": "C") co-loadable with the
-    obs.trace / obs.perf exports (its timebase is synthetic — one µs
-    per op index — so it loads as a profile shape, not wall time).
+    obs.trace exports (its timebase is synthetic — one µs per op
+    index — so it loads as a profile shape, not wall time).
   * **actuals capture** — the executor registers each jit segment's
     static peak at first build (`register_segment_static`) and
     `obs.health.publish_compile_stats` forwards the segment's
@@ -26,12 +25,8 @@ against what XLA actually allocates.  Five layers close the loop:
     watermarks (`mem_device_*{device=}`; CPU backends report none —
     graceful).
   * **drift report** — `drift_report()` joins static peak vs XLA
-    temp+output bytes per segment, publishes
-    `mem_estimate_ratio{segment=}`, and `calibration_blob()` distills
-    the median actual/static ratio into a JSON blob
-    `tune.fit.load_hbm_calibration` feeds back into `ptune plan`
-    (`rank(..., hbm_ratio=)`) — the HBM term stops being purely
-    analytic.
+    temp+output bytes per segment and publishes
+    `mem_estimate_ratio{segment=}`.
   * **donation audit** — `audit_donation(program)` walks the
     registry's `in_place_outputs` declarations against the signature
     the executor will actually donate (`mutated = outputs ∩ reads`
@@ -39,7 +34,7 @@ against what XLA actually allocates.  Five layers close the loop:
     are dead-after-use but NOT donated (forked slots, dropped
     aliases, updates stranded in non-jittable segments), with the
     bytes reclaimable — the measurement half of the buffer-donation
-    work (docs/PERF.md).
+    work.
   * **OOM pre-flight + post-mortem** — `FLAGS_mem_budget_gb` makes
     the executor refuse to compile a program whose static peak busts
     the budget (`preflight` raises `MemoryBudgetError`, an honest
@@ -69,13 +64,10 @@ __all__ = ["program_timeline", "segment_static_peak",
            "retire_segments", "segments", "xla_program_bytes_total",
            "device_watermarks", "publish_device_watermarks",
            "record_bucket_bytes", "health_memory_section",
-           "drift_report", "render_drift", "calibration_blob",
-           "save_calibration", "dump_store", "load_store",
+           "drift_report", "render_drift", "dump_store", "load_store",
            "audit_donation", "render_audit",
-           "MemoryBudgetError", "preflight", "is_oom", "oom_context",
-           "bench_memory_blob", "MEM_CALIBRATION_KIND"]
+           "MemoryBudgetError", "preflight", "is_oom", "oom_context"]
 
-MEM_CALIBRATION_KIND = "paddle_tpu.mem_calibration"
 GiB = float(1 << 30)
 MiB = float(1 << 20)
 
@@ -216,8 +208,8 @@ def render_timeline(tl, width=48, max_rows=64):
 
 def timeline_chrome_trace(tl, path=None, name="mem_live_bytes"):
     """The timeline as a Chrome trace-event counter track ("ph": "C")
-    plus one span per op, co-loadable with the obs.trace / obs.perf
-    exports in Perfetto.  The timebase is SYNTHETIC — one µs per op
+    plus one span per op, co-loadable with the obs.trace exports in
+    Perfetto.  The timebase is SYNTHETIC — one µs per op
     index (a static walk has no wall clock) — so it reads as a
     profile shape next to the real tracks, not as wall time."""
     evs = [{"name": "process_name", "ph": "M", "pid": 3, "tid": 0,
@@ -507,29 +499,6 @@ def render_drift(report):
     return "\n".join(lines)
 
 
-def calibration_blob(report, model=None):
-    """The drift report distilled into the blob `ptune` consumes
-    (`tune.fit.load_hbm_calibration` -> `rank(..., hbm_ratio=)`):
-    the median measured actual/static ratio scales the static HBM
-    peak before the S005 budget check, so the tuner's HBM term stops
-    being purely analytic.  None when nothing joined."""
-    if not report.get("n"):
-        return None
-    return {"kind": MEM_CALIBRATION_KIND, "version": 1,
-            "hbm_ratio": report["median_ratio"], "n": report["n"],
-            "model": model,
-            "segments": {r["segment"]: r["ratio"]
-                         for r in report["segments"] if r["ratio"]}}
-
-
-def save_calibration(blob, path):
-    tmp = str(path) + ".tmp"
-    with open(tmp, "w") as f:
-        json.dump(blob, f, indent=1, sort_keys=True)
-    os.replace(tmp, str(path))
-    return str(path)
-
-
 def dump_store(path):
     """Persist this process's capture store for an offline
     `pmem drift --store` join (atomic write)."""
@@ -734,42 +703,3 @@ def oom_context(exc, program=None, fetches=None):
     if marks:
         oom["device"] = marks
     return {"oom": oom}
-
-
-# ---------------------------------------------------------------------------
-# bench blob
-# ---------------------------------------------------------------------------
-
-def bench_memory_blob(program, fetches=(), xla_stats=None):
-    """The BENCH-record "memory" blob for one leg: static peak, the
-    AOT artifact's XLA temp/arg/output bytes (what
-    `obs.health.publish_compile_stats` returns), the device watermark, and the
-    estimate ratio — XLA total footprint / static total, the SAME
-    actual/static direction as `mem_estimate_ratio` and the
-    calibration blob (1.0 = the static model is exact)."""
-    tl = program_timeline(program, fetches=fetches, top_n=3)
-    xla = xla_stats or {}
-    blob = {
-        "static_peak_bytes": tl["total_peak_bytes"],
-        "activation_peak_bytes": tl["peak_bytes"],
-        "params_bytes": tl["params_bytes"],
-        "top_buffers": tl["top_buffers"],
-    }
-    for key in ("xla_temp_bytes", "xla_argument_bytes",
-                "xla_output_bytes"):
-        if xla.get(key) is not None:
-            blob[key] = int(xla[key])
-    xla_total = sum(blob.get(k, 0) for k in
-                    ("xla_temp_bytes", "xla_argument_bytes",
-                     "xla_output_bytes"))
-    if xla_total and blob["static_peak_bytes"]:
-        blob["xla_total_bytes"] = xla_total
-        blob["estimate_ratio"] = round(
-            xla_total / blob["static_peak_bytes"], 4)
-    elif xla_total:
-        blob["xla_total_bytes"] = xla_total
-    marks = device_watermarks()
-    if marks:
-        blob["device_peak_bytes"] = max(
-            s.get("peak_bytes_in_use", 0) for s in marks.values())
-    return blob

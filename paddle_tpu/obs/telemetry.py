@@ -50,8 +50,7 @@ __all__ = ["on_executor_run", "on_jit_trace",
            "on_transfer",
            "on_feed_seconds", "on_decoder_call", "on_program_cache_evict",
            "jit_trace_count", "transfer_bytes", "step", "set_gauge",
-           "install_step_observer", "step_observer", "snapshot",
-           "snapshot_delta", "snapshot_and_delta"]
+           "snapshot", "snapshot_delta", "snapshot_and_delta"]
 
 # histogram bounds for step wall time: sub-ms tiny CPU steps up to
 # multi-second compile-included first steps
@@ -584,34 +583,13 @@ jax.monitoring.register_event_listener(_on_compile_cache_event)
 # trainer-side hooks
 # ---------------------------------------------------------------------------
 
-# single step observer slot (obs.perf.StepProfiler): begin_step() at
-# step entry, end_step() at exit.  One None check per step when empty.
-_step_observer = None
-
-
-def install_step_observer(observer):
-    """Register `observer` (needs begin_step(trainer) /
-    end_step(trainer, dt, examples, failed=...)) on every
-    `telemetry.step(...)` boundary; pass None to remove.  Returns the
-    previous observer so callers can restore it."""
-    global _step_observer
-    prev = _step_observer
-    _step_observer = observer
-    return prev
-
-
-def step_observer():
-    return _step_observer
-
-
 class _StepTimer:
     """Times one training step inside a `<trainer>/step` span, in
     which whatever the step runs nests, and feeds the trainer metric
     family.  `examples` may be set after entry, by a step that learns
     its batch size from its feeds."""
 
-    __slots__ = ("trainer", "examples", "args", "_t0", "_dt", "_obs",
-                 "_span")
+    __slots__ = ("trainer", "examples", "args", "_t0", "_dt", "_span")
 
     def __init__(self, trainer, examples, args):
         self.trainer = trainer
@@ -620,11 +598,6 @@ class _StepTimer:
         self._dt = None
 
     def __enter__(self):
-        # pin the observer for the step: an install/uninstall mid-step
-        # must not end a step that was never begun (or vice versa)
-        self._obs = _step_observer
-        if self._obs is not None:
-            self._obs.begin_step(self.trainer)
         self._span = trace_mod.span(self.trainer + "/step",
                                     cat="trainer", **self.args)
         self._span.__enter__()
@@ -634,13 +607,7 @@ class _StepTimer:
     def __exit__(self, exc_type, exc, tb):
         if exc_type is None and self._dt is None:
             self.record()
-        dt = self._dt
-        if dt is None:
-            dt = time.perf_counter() - self._t0
         self._span.__exit__(exc_type, exc, tb)
-        if self._obs is not None:
-            self._obs.end_step(self.trainer, dt, self.examples,
-                               failed=exc_type is not None)
         return False
 
     def record(self):

@@ -10,8 +10,8 @@ profiler's own check decides whether it records; with no session it is
 one object made and dropped.  While `obs.trace` is enabled
 (`enable()`/`tracing()`) the same span is also kept in memory and is
 exportable as Chrome trace-event JSON (the `{"traceEvents": [...]}`
-format Perfetto and chrome://tracing load directly); `obs.tail`,
-`obs.perf` and `obs_dump` read that sink.
+format Perfetto and chrome://tracing load directly); `obs.tail`
+and `obs_dump` read that sink.
 
 Spans are recorded as complete ("X") events — begin timestamp plus
 duration — which Perfetto nests by containment per thread track, so
@@ -41,7 +41,7 @@ from jax.profiler import TraceAnnotation
 
 __all__ = ["enable", "disable", "is_enabled", "reset", "tracing",
            "span", "instant", "emit_span", "events", "event_count",
-           "events_since", "truncate_to", "epoch", "dropped_events",
+           "events_since", "epoch", "dropped_events",
            "export_chrome_trace", "to_chrome_trace"]
 
 _lock = threading.Lock()
@@ -161,14 +161,14 @@ def events():
 
 def epoch():
     """The perf_counter() origin of event timestamps (re-based by
-    enable(clear=True)/reset) — lets sibling exporters (obs.perf) put
+    enable(clear=True)/reset) — lets sibling exporters (obs.comm) put
     their tracks on the same timeline."""
     return _epoch
 
 
 def event_count():
     """Current buffer length — a cheap bookmark for `events_since`
-    (the step profiler takes one per step instead of copying the whole
+    (obs.comm's overlap report takes one instead of copying the whole
     buffer)."""
     with _lock:
         return len(_events)
@@ -178,20 +178,9 @@ def events_since(index):
     """Copy of the events appended after bookmark `index` (an earlier
     `event_count()` result).  A reset/clear since the bookmark leaves
     the buffer shorter than the bookmark, so the slice is empty — the
-    window's events are gone and the caller's sample is lost (the
-    step profiler records such a step without a time split)."""
+    window's events are gone and the caller's sample is lost."""
     with _lock:
         return list(_events[index:])
-
-
-def truncate_to(index):
-    """Drop events at positions >= bookmark `index` — how obs.perf
-    removes its owned sampling windows after copying them out, WITHOUT
-    touching events buffered before the window or re-basing the epoch
-    (a full reset() would destroy spans a user recorded earlier and
-    kept for a later export)."""
-    with _lock:
-        del _events[index:]
 
 
 def dropped_events():

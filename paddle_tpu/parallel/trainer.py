@@ -272,8 +272,7 @@ class ParallelTrainer:
             # whoever needs this step's values on the host inside this
             # step makes it wait for its own fetches; otherwise one
             # step stays in flight and the wait is for the one before
-            own = (monitor is not None or recording
-                   or obs_tele.step_observer() is not None)
+            own = monitor is not None or recording
             blamed = step_id, described
             try:
                 # trace under the mesh context so mesh-aware op kernels

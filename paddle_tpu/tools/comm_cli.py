@@ -5,8 +5,7 @@
     # simulated dp=8 mesh (JAX_PLATFORMS=cpu; virtual devices are
     # provisioned automatically)
     pcomm report [--dp 8] [--bucket-kb 24] [--reps 3] \\
-                 [--trace-out comm_trace.json] \\
-                 [--calibration-out comm_cal.json] [--json]
+                 [--trace-out comm_trace.json] [--json]
 
     # cross-host merge: pull every live /obsspan/* window from the
     # master's lease store (workers push them via
@@ -24,13 +23,9 @@ traced bucket schedule nests one `comm/bucket` span per bucket in
 last-produced-first order with byte labels; `overlap_report` splits
 step wall into exposed-vs-hidden comm against the reduction-elided
 twin (and a gspmd-fallback trainer is refused WITHOUT an exposed_s);
-a real master lease store carries span windows + the NTP-style clock
-exchange (a ClockResponder with 0.5s injected skew is recovered and
-the merged trace re-bases by it, validating as a Chrome trace); the
-drift calibration blob round-trips through
-`tune.fit.load_comm_calibration` into a fitted comm coefficient
-(same-platform-class only); and `pperf gate --comm-tolerance` passes
-±2% exposed-comm noise while failing an injected 20% regression.
+and a real master lease store carries span windows + the NTP-style
+clock exchange (a ClockResponder with 0.5s injected skew is recovered
+and the merged trace re-bases by it, validating as a Chrome trace).
 """
 
 import argparse
@@ -45,8 +40,7 @@ def parse_args(argv=None):
     p.add_argument("cmd", nargs="?", choices=["report", "merge"],
                    help="operator command (or use --selftest)")
     p.add_argument("--selftest", action="store_true",
-                   help="spans + overlap split + cross-host merge + "
-                        "calibration round-trip + comm gate "
+                   help="spans + overlap split + cross-host merge "
                         "certification (CPU, 8 virtual devices)")
     # report
     p.add_argument("--dp", type=int, default=8,
@@ -60,9 +54,6 @@ def parse_args(argv=None):
     p.add_argument("--trace-out", default=None,
                    help="report: write this process's span trace "
                         "here (Chrome trace JSON)")
-    p.add_argument("--calibration-out", default=None,
-                   help="report: write the measured/predicted ring "
-                        "blob `ptune fit --comm-calibration` eats")
     # merge
     p.add_argument("--master", default=None,
                    help="merge: master host:port whose /obsspan/* "
@@ -208,20 +199,6 @@ def cmd_report(args):
         print("[pcomm] mlp probe, dp=%d, bucket %d KiB:"
               % (args.dp, args.bucket_kb))
         print(_render_report(rep, bucket_report, drift))
-    if args.calibration_out:
-        blob = obs_comm.calibration_blob(bucket_report,
-                                         model="pcomm-mlp")
-        if blob is None:
-            print("[pcomm] nothing measured — no calibration "
-                  "written", file=sys.stderr)
-            return 2
-        obs_comm.save_calibration(blob, args.calibration_out)
-        if not args.json:
-            print("[pcomm] calibration written: %s (comm_ratio %.3f "
-                  "over %d bucket(s)) — feed it to `ptune fit "
-                  "--comm-calibration`"
-                  % (args.calibration_out, blob["comm_ratio"],
-                     blob["n"]))
     if args.trace_out:
         obs_trace.export_chrome_trace(args.trace_out)
         if not args.json:
@@ -372,45 +349,8 @@ def _selftest_spans_and_overlap(workdir):
     return rep, bucket_report
 
 
-def _selftest_calibration(workdir, bucket_report):
-    """Leg 3: drift blob -> tune.fit comm coefficient, same-class
-    only."""
-    from paddle_tpu.obs import comm as obs_comm
-    from paddle_tpu.tune import fit as tune_fit
-
-    blob = obs_comm.calibration_blob(bucket_report, model="pcomm-mlp")
-    assert blob and blob["n"] >= 2 and blob["comm_ratio"] > 0, blob
-    cal_path = os.path.join(workdir, "comm_cal.json")
-    obs_comm.save_calibration(blob, cal_path)
-    pairs = tune_fit.load_comm_calibration(cal_path)
-    assert len(pairs) == blob["n"] \
-        and pairs[0]["platform_class"] == blob["platform_class"]
-    cal = tune_fit.fit_calibration([], comm_pairs=pairs)
-    assert abs(cal.coef["comm"] - blob["comm_ratio"]) < 1e-9, \
-        (cal.coef, blob["comm_ratio"])
-    # same-platform-class discipline: training on a DIFFERENT class
-    # keeps the analytic prior instead of ingesting these pairs
-    foreign = [{"leg": "ptune:x", "measured_s": 0.1,
-                "meas_compute_s": 0.08, "overhead_s": 0.01,
-                "platform_class": "tpu:d8:dp=8"}]
-    cal2 = tune_fit.fit_calibration(foreign, comm_pairs=pairs)
-    assert cal2.coef["comm"] == 1.0, cal2.coef
-    assert "kept analytic" in cal2.note, cal2.note
-    # a wrong-kind blob must be refused, not silently skipped
-    bad_path = os.path.join(workdir, "not_comm.json")
-    with open(bad_path, "w") as f:
-        json.dump({"kind": "paddle_tpu.mem_calibration",
-                   "pairs": []}, f)
-    try:
-        tune_fit.load_comm_calibration(bad_path)
-        raise AssertionError("wrong-kind blob loaded")
-    except ValueError:
-        pass
-    return blob, cal
-
-
 def _selftest_merge(workdir):
-    """Leg 4: span windows + clock exchange + merged trace over a
+    """Leg 3: span windows + clock exchange + merged trace over a
     REAL master lease store."""
     from paddle_tpu import native
     from paddle_tpu.obs import comm as obs_comm
@@ -486,73 +426,6 @@ def _selftest_merge(workdir):
         master.stop()
 
 
-def _comm_history(path, regress=False):
-    """Six rounds of multichip records with ±2% exposed-comm noise
-    (and one gspmd-fallback record that carries no exposed_s — it
-    must not drag the overlap baseline); optionally a 20% exposed
-    regression as the candidate."""
-    from paddle_tpu.obs import perf as obs_perf
-
-    noise = [1.0, 0.99, 1.012, 0.994, 1.009, 0.98]
-    base_v, base_e = 512.0, 0.004
-    if os.path.exists(path):
-        os.remove(path)
-    ts = 1_700_000_000.0
-    for i, n in enumerate(noise):
-        e = base_e * (1.2 if (regress and i == len(noise) - 1) else n)
-        obs_perf.append_history(
-            {"metric": "mlp_multichip_imgs_per_sec",
-             "value": round(base_v * n, 2), "unit": "img/s",
-             "step_ms": 31.0, "platform": "cpu",
-             "comm": {"measured_s": 0.005,
-                      "exposed_s": round(e, 6),
-                      "overlap_efficiency": 0.8,
-                      "step_mode": "overlap-dp",
-                      "plan_fingerprint": "fp0"}},
-            path, leg="dp=8", ts=ts + i)
-        if i == 2:
-            # the fallback run: huge standalone ring, NO exposed_s
-            obs_perf.append_history(
-                {"metric": "mlp_multichip_imgs_per_sec",
-                 "value": round(base_v, 2), "unit": "img/s",
-                 "step_ms": 31.0, "platform": "cpu",
-                 "comm": {"measured_s": 10.0, "step_mode": "gspmd",
-                          "overlap_fallback_reason": "mesh is not "
-                          "pure data-parallel"}},
-                path, leg="dp=8", ts=ts + i + 0.5)
-    return path
-
-
-def _selftest_gate(workdir):
-    """Leg 5: the comm gate discriminates — noise passes, an injected
-    exposed-comm regression fails, fallback records don't pollute."""
-    from paddle_tpu.obs import perf as obs_perf
-    from paddle_tpu.tools import perf_cli
-
-    path = _comm_history(os.path.join(workdir, "comm_hist.jsonl"))
-    res = obs_perf.gate_history(obs_perf.load_history(path),
-                                comm_tolerance=0.1)
-    assert res.ok, obs_perf.format_gate(res)
-    rc = perf_cli.main(["gate", "--history", path,
-                        "--comm-tolerance", "0.1"])
-    assert rc == 0, rc
-
-    bad = _comm_history(os.path.join(workdir, "comm_bad.jsonl"),
-                        regress=True)
-    res = obs_perf.gate_history(obs_perf.load_history(bad),
-                                comm_tolerance=0.1)
-    assert not res.ok and res.failures[0]["kind"] == "comm", \
-        res.to_dict()
-    assert "exposed_s" in res.failures[0]["why"], res.failures
-    # without the opt-in flag the same history passes (throughput
-    # noise hides the regression — exactly why the gate exists)
-    assert obs_perf.gate_history(obs_perf.load_history(bad)).ok
-    rc = perf_cli.main(["gate", "--history", bad,
-                        "--comm-tolerance", "0.1"])
-    assert rc == 1, rc
-    return res.failures[0]["why"]
-
-
 def selftest(args):
     import shutil
 
@@ -561,22 +434,19 @@ def selftest(args):
     workdir = tempfile.mkdtemp(prefix="paddle_pcomm_")
     try:
         rep, bucket_report = _selftest_spans_and_overlap(workdir)
-        blob, cal = _selftest_calibration(workdir, bucket_report)
         n_hosts, off, n_events = _selftest_merge(workdir)
-        gate_why = _selftest_gate(workdir)
     finally:
         shutil.rmtree(workdir, ignore_errors=True)
 
     print("[pcomm] selftest green: %d bucket(s) traced in reduce "
           "order, overlap split step %.2fms = compute %.2fms + "
-          "exposed %.2fms (efficiency %.0f%%); calibration %d "
-          "pair(s) -> comm coef %.2f (foreign class kept analytic); "
-          "%d host window(s) merged on a common timebase (%d events, "
-          "recovered skew %.3fs); comm gate discriminates: %s"
+          "exposed %.2fms (efficiency %.0f%%); %d host window(s) "
+          "merged on a common timebase (%d events, recovered skew "
+          "%.3fs)"
           % (len(bucket_report["buckets"]), rep["step_s"] * 1e3,
              rep["compute_s"] * 1e3, rep["exposed_s"] * 1e3,
-             rep["overlap_efficiency"] * 100, blob["n"],
-             cal.coef["comm"], n_hosts, n_events, off, gate_why),
+             rep["overlap_efficiency"] * 100, n_hosts, n_events,
+             off),
           flush=True)
     return 0
 

@@ -1,6 +1,5 @@
 """Load CLI ("pload"): load generation + traffic replay over
-`paddle_tpu.obs.load`, with coordinated-omission-safe latency truth
-and the serving tail-latency gate hookup.
+`paddle_tpu.obs.load`, with coordinated-omission-safe latency truth.
 
     # the CI entry point (scripts/ci.sh, scripts/smoke.sh):
     python -m paddle_tpu.tools.load_cli --selftest
@@ -15,10 +14,6 @@ and the serving tail-latency gate hookup.
     # replay a recorded access log at 4x speed, original gaps:
     pload replay --url ... --log access.jsonl --speed 4
 
-    # land the run in perf history for `pperf gate --latency-tolerance`:
-    pload run --url ... --rate 100 --n 1000 --slo-ms 50 \
-        --history perf_history.jsonl
-
 `--selftest` certifies the harness end to end on a loopback server
 (docs/SERVING.md has the runbook):
 
@@ -32,11 +27,7 @@ and the serving tail-latency gate hookup.
      resolve to a span tree in the server's /debug/tail ring, and the
      /metrics exemplars must parse (the "p99 is bad -> why" loop);
   4. **replay fidelity** — replaying the run's own access-log JSONL
-     must reproduce its request count and bucket mix exactly;
-  5. **gate round-trip** — a `latency` blob must flow through
-     perf_history.jsonl into `pperf gate --latency-tolerance`: an
-     injected p99 regression fails the gate naming the percentile,
-     and the same history passes with the flag omitted (opt-in).
+     must reproduce its request count and bucket mix exactly.
 """
 
 import argparse
@@ -54,7 +45,7 @@ def parse_args(argv=None):
                    help="operator command (or use --selftest)")
     p.add_argument("--selftest", action="store_true",
                    help="loopback open-vs-closed omission proof, tail "
-                        "join, replay fidelity, latency gate")
+                        "join, replay fidelity")
     p.add_argument("--url", default="http://127.0.0.1:8500",
                    help="server base URL (POST <url>/v1/infer)")
     p.add_argument("--mode", choices=["open", "closed"], default="open",
@@ -110,9 +101,6 @@ def parse_args(argv=None):
                    help="skip the /debug/tail + /metrics joins")
     p.add_argument("--report", default=None,
                    help="write the full JSON report here")
-    p.add_argument("--history", default=None,
-                   help="append a latency-blob record to this perf "
-                        "history (pperf gate --latency-tolerance)")
     p.add_argument("--json", action="store_true",
                    help="print the report as JSON instead of text")
     return p.parse_args(argv)
@@ -144,21 +132,10 @@ def _run_report(args, target, schedule):
 
 def _emit(args, report):
     from paddle_tpu.obs import load as obs_load
-    from paddle_tpu.obs import perf as obs_perf
 
     if args.report:
         with open(args.report, "w") as f:
             json.dump(report, f, sort_keys=True, indent=1)
-    if args.history:
-        blob = obs_load.latency_blob(report)
-        record = {
-            "metric": "pload_%s_rps" % report["mode"],
-            "value": report["achieved_rps"],
-            "unit": "req/s",
-            "platform": "cpu",
-            "latency": blob,
-        }
-        obs_perf.append_history(record, args.history, leg="pload")
     if args.json:
         print(json.dumps(report, sort_keys=True))
     else:
@@ -381,50 +358,6 @@ def _selftest_replay(workdir, access_log):
     return report
 
 
-def _selftest_gate(workdir, open_report):
-    """Leg 5: the latency blob's CI story — baseline history + a
-    doubled-p99 candidate must FAIL `pperf gate --latency-tolerance`
-    naming the percentile, and PASS with the flag omitted."""
-    from paddle_tpu.obs import load as obs_load
-    from paddle_tpu.obs import perf as obs_perf
-    from paddle_tpu.tools import perf_cli
-
-    path = os.path.join(workdir, "perf_history.jsonl")
-    blob = obs_load.latency_blob(open_report)
-
-    def record(latency):
-        return {"metric": "serving_slo_openloop_rps",
-                "value": open_report["achieved_rps"],
-                "unit": "req/s", "platform": "cpu",
-                "latency": latency}
-
-    ts = 1_700_000_000.0
-    for i in range(5):
-        norm = obs_perf.append_history(record(dict(blob)), path,
-                                       leg="serving-slo", ts=ts + i)
-        assert norm and norm["latency"].get("p99_ms") == \
-            blob["p99_ms"], "latency blob did not survive " \
-            "normalize_record: %r" % (norm,)
-    regressed = dict(blob)
-    for key in ("p50_ms", "p90_ms", "p99_ms", "p99_9_ms"):
-        regressed[key] = round(blob[key] * 3.0, 3)
-    obs_perf.append_history(record(regressed), path, leg="serving-slo",
-                            ts=ts + 5)
-
-    res = obs_perf.gate_history(obs_perf.load_history(path),
-                                latency_tolerance=0.25)
-    assert not res.ok and res.failures[0]["kind"] == "latency", \
-        res.to_dict()
-    assert "p99" in res.failures[0]["why"], res.to_dict()
-    rc = perf_cli.main(["gate", "--history", path,
-                        "--latency-tolerance", "0.25"])
-    assert rc == 1, "pperf gate exit %r for a 3x tail regression" % rc
-    # opt-in: the same history passes when latency is not gated
-    rc = perf_cli.main(["gate", "--history", path])
-    assert rc == 0, "latency gate fired without --latency-tolerance"
-    return res.failures[0]["why"]
-
-
 def selftest(args):
     import shutil
 
@@ -435,7 +368,6 @@ def selftest(args):
         (open_report, closed_report, open_p99, closed_p99,
          access_log) = _selftest_omission(workdir)
         replay_report = _selftest_replay(workdir, access_log)
-        gate_why = _selftest_gate(workdir, open_report)
     finally:
         # ci.sh/smoke.sh run this every time: don't stack /tmp dirs
         shutil.rmtree(workdir, ignore_errors=True)
@@ -443,8 +375,8 @@ def selftest(args):
     print("[pload] selftest green: injected stall -> open-loop p99 "
           "%.1fms vs closed-loop p99 %.1fms (the coordinated-omission "
           "gap), worst request joined to its /debug/tail span tree, "
-          "replay reproduced %d requests + bucket mix, latency gate: "
-          "%s" % (open_p99, closed_p99, replay_report["n"], gate_why),
+          "replay reproduced %d requests + bucket mix"
+          % (open_p99, closed_p99, replay_report["n"]),
           flush=True)
     return 0
 

@@ -6,9 +6,8 @@
     pmem timeline --model lenet5 --batch 128 [--trace-out mem.json]
 
     # static-vs-XLA drift: run one step under attribution (or join a
-    # saved --store dump), report actual/static per segment, and
-    # emit the calibration blob `ptune plan --hbm-calibration` eats
-    pmem drift --model lenet5 [--calibration-out mem_cal.json]
+    # saved --store dump) and report actual/static per segment
+    pmem drift --model lenet5
     pmem drift --store mem_store.json
 
     # buffer-donation audit: param/optimizer-state buffers that are
@@ -21,9 +20,9 @@
 `--selftest` proves the whole loop on CPU: timeline render + counter
 track (validated as Chrome trace JSON), a REAL lenet5 step whose
 static peak joins XLA's `memory_analysis()` actuals into a drift
-report with a usable calibration blob, a donation audit that finds a
-deliberately-forked Adam moment slot (and nothing on the clean
-program), and a forced-tiny-budget OOM whose flight bundle carries
+report, a donation audit that finds a deliberately-forked Adam moment
+slot (and nothing on the clean program), and a forced-tiny-budget OOM
+whose flight bundle carries
 the same top blamed buffer the static timeline names.
 """
 
@@ -43,7 +42,7 @@ def parse_args(argv=None):
                    help="timeline + drift join + donation audit + "
                         "OOM flight-bundle certification (CPU)")
     p.add_argument("--model", default="lenet5",
-                   help="model name (paddle_tpu.tune.models)")
+                   help="model name (paddle_tpu.models.image_train)")
     p.add_argument("--batch", type=int, default=8)
     p.add_argument("--image-size", type=int, default=None)
     p.add_argument("--class-dim", type=int, default=None)
@@ -60,48 +59,27 @@ def parse_args(argv=None):
     p.add_argument("--store-out", default=None,
                    help="drift: also dump this process's capture "
                         "store for later offline joins")
-    p.add_argument("--calibration-out", default=None,
-                   help="drift: write the hbm_ratio calibration blob "
-                        "`ptune plan --hbm-calibration` consumes")
     p.add_argument("--json", action="store_true",
                    help="machine-readable output")
     return p.parse_args(argv)
 
 
 def _build_train(model, batch, image_size=None, class_dim=None):
-    """(main, startup, loss_var): the tune.models training recipe,
-    with the startup program the drift run needs (tune's builder
-    discards it — ranking never executes)."""
-    import paddle_tpu.fluid as fluid
-    from paddle_tpu.tune.models import MODELS, _model_fn
+    """(main, startup, loss_var): the models.image_train recipe."""
+    from paddle_tpu.models import image_train
 
-    if model not in MODELS:
-        raise SystemExit("unknown model %r; pmem knows %s"
-                         % (model, ", ".join(sorted(MODELS))))
-    spec = MODELS[model]
-    size = int(image_size or spec["image_size"])
-    classes = int(class_dim or spec["class_dim"])
-    fn = _model_fn(model)
-    main, startup = fluid.Program(), fluid.Program()
-    with fluid.program_guard(main, startup):
-        image = fluid.layers.data(
-            name="image", shape=[batch, spec["channels"], size, size],
-            dtype="float32", append_batch_size=False)
-        logits = fn(image, class_dim=classes)
-        label = fluid.layers.data(
-            name="label", shape=[batch, 1], dtype="int64",
-            append_batch_size=False)
-        loss = fluid.layers.mean(
-            fluid.layers.softmax_with_cross_entropy(logits, label))
-        fluid.optimizer.MomentumOptimizer(
-            learning_rate=0.01, momentum=0.9).minimize(loss)
-    return main, startup, loss
+    try:
+        build = image_train.builder(model, image_size, class_dim)
+    except ValueError as e:
+        raise SystemExit("pmem: %s" % e)
+    main, startup, loss_name = build(batch)
+    return main, startup, main.global_block().var(loss_name)
 
 
 def _feeds(model, batch, image_size=None, class_dim=None):
     import numpy as np
 
-    from paddle_tpu.tune.models import MODELS
+    from paddle_tpu.models.image_train import MODELS
 
     spec = MODELS[model]
     size = int(image_size or spec["image_size"])
@@ -208,20 +186,6 @@ def cmd_drift(args):
                            else "%s batch %d, one captured step"
                            % (args.model, args.batch)))
         print(obs_mem.render_drift(rep))
-    if args.calibration_out:
-        blob = obs_mem.calibration_blob(rep, model=None if args.store
-                                        else args.model)
-        if blob is None:
-            print("[pmem] no joined segments — no calibration "
-                  "written", file=sys.stderr)
-            return 2
-        obs_mem.save_calibration(blob, args.calibration_out)
-        if not args.json:
-            print("[pmem] calibration written: %s (hbm_ratio %.3f "
-                  "over %d segment(s)) — feed it to `ptune plan "
-                  "--hbm-calibration`"
-                  % (args.calibration_out, blob["hbm_ratio"],
-                     blob["n"]))
     return 0 if rep["n"] else 2
 
 
@@ -270,7 +234,6 @@ def selftest(args):
     from paddle_tpu.obs import flight as obs_flight
     from paddle_tpu.obs import mem as obs_mem
     from paddle_tpu.tools import obs_dump
-    from paddle_tpu.tune.fit import load_hbm_calibration
     from paddle_tpu.utils import flags as pt_flags
 
     workdir = tempfile.mkdtemp(prefix="paddle_pmem_")
@@ -290,7 +253,7 @@ def selftest(args):
     assert any(ev["ph"] == "C" for ev in events), \
         "no counter events in the mem trace"
 
-    # --- leg 2: drift join on a real captured step + calibration -------
+    # --- leg 2: drift join on a real captured step ---------------------
     scope = fluid.Scope()
     exe = fluid.Executor(fluid.CPUPlace())
     from paddle_tpu.obs import health as obs_health
@@ -305,11 +268,6 @@ def selftest(args):
     assert joined, "no static-vs-XLA joined segments:\n%s" \
         % obs_mem.render_drift(rep)
     assert rep["median_ratio"] and rep["median_ratio"] > 0
-    cal_path = os.path.join(workdir, "mem_cal.json")
-    obs_mem.save_calibration(
-        obs_mem.calibration_blob(rep, model="lenet5"), cal_path)
-    ratio = load_hbm_calibration(cal_path)
-    assert ratio == rep["median_ratio"], (ratio, rep["median_ratio"])
     store_path = os.path.join(workdir, "mem_store.json")
     obs_mem.dump_store(store_path)
     offline = obs_mem.drift_report(obs_mem.load_store(store_path))
@@ -378,14 +336,14 @@ def selftest(args):
 
     print("[pmem] selftest green: timeline %d op(s) peak %.2f MiB at "
           "op %s (%s), counter track %d event(s); drift joined %d "
-          "segment(s) median ratio %.3f -> calibration %s; donation "
+          "segment(s) median ratio %.3f; donation "
           "audit: clean program donates %d buffer(s), forked Adam "
           "slot %r flagged A001 with %.1f KiB reclaimable and "
           "FLAGS_donation=off surrenders the full delta; OOM bundle "
           "%s blames %r"
           % (tl["ops"], tl["peak_bytes"] / 2**20, tl["peak_op"],
              tl["peak_op_type"], len(events), rep["n"],
-             rep["median_ratio"], cal_path, len(clean["donated"]),
+             rep["median_ratio"], len(clean["donated"]),
              forked_name, hits[0]["bytes"] / 1024.0, bundle,
              top[0]["name"]),
           flush=True)

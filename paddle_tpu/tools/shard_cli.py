@@ -36,7 +36,7 @@ def parse_args(argv=None):
                    help="plan: build + save the partition plan; "
                         "show: render a saved plan")
     p.add_argument("--model", default="lenet5",
-                   help="tune/models name (default lenet5)")
+                   help="models/image_train name (default lenet5)")
     p.add_argument("--mesh", default="dp=8",
                    help="mesh spec, e.g. dp=4,mp=2 (default dp=8)")
     p.add_argument("--batch", type=int, default=64,
@@ -57,9 +57,9 @@ def parse_args(argv=None):
 
 
 def _build_program(model, batch):
-    from ..tune import models as tune_models
+    from ..models import image_train
 
-    return tune_models.builder(model, with_startup=True)(batch)
+    return image_train.builder(model)(batch)
 
 
 def cmd_plan(args):

@@ -31,20 +31,14 @@ timeout 300 python -m paddle_tpu.tools.chaos_cli --selftest
 echo "[ci] pelastic selftest (two-phase view-change protocol over a real master with lease expiry, simulated-fleet dp 8->4->8 with densify restore, 2 real workers with one SIGTERM'd mid-step: shrink commit + shard-exact continue + rejoin grow) ..."
 timeout 600 python -m paddle_tpu.tools.elastic_cli --selftest
 
-echo "[ci] pperf selftest (gate discriminates 20% regression + tpu-stale, step profiler ring/exports, loopback SLO burn) ..."
-timeout 300 python -m paddle_tpu.tools.perf_cli --selftest
-
-echo "[ci] pload selftest (open-loop p99 surfaces an injected stall closed-loop hides, worst request joins its /debug/tail span tree, access-log replay reproduces count + bucket mix, latency blob -> pperf gate --latency-tolerance verdict) ..."
+echo "[ci] pload selftest (open-loop p99 surfaces an injected stall closed-loop hides, worst request joins its /debug/tail span tree, access-log replay reproduces count + bucket mix) ..."
 timeout 300 python -m paddle_tpu.tools.load_cli --selftest
 
-echo "[ci] pmem selftest (static timeline + counter track, static-vs-XLA drift join on lenet5 with calibration blob, donation audit finds a forked Adam slot, forced-tiny-budget OOM flight bundle blames the peak buffer) ..."
+echo "[ci] pmem selftest (static timeline + counter track, static-vs-XLA drift join on lenet5, donation audit finds a forked Adam slot, forced-tiny-budget OOM flight bundle blames the peak buffer) ..."
 timeout 300 python -m paddle_tpu.tools.mem_cli --selftest
 
-echo "[ci] pcomm selftest (per-bucket comm spans in reduce order, overlap exposed-vs-hidden split, cross-host span merge with recovered clock skew, drift blob -> ptune comm coef, comm gate discriminates) ..."
+echo "[ci] pcomm selftest (per-bucket comm spans in reduce order, overlap exposed-vs-hidden split, cross-host span merge with recovered clock skew) ..."
 timeout 300 python -m paddle_tpu.tools.comm_cli --selftest
-
-echo "[ci] ptune selftest (deterministic plan, S002/S005 rejected at rank time, top-K joined from history by config blobs, calibration error shrinks) ..."
-timeout 600 python -m paddle_tpu.tools.tune_cli --selftest
 
 echo "[ci] pshard selftest (rule precedence, rules reshape the layout, plan save/load fingerprint-stable, plan-driven SPMD step on 8 devices, sharded checkpoint round-trip with zero densified vars) ..."
 timeout 300 python -m paddle_tpu.tools.shard_cli --selftest

@@ -43,9 +43,7 @@ setup(
         "console_scripts": [
             "paddle_trainer=paddle_tpu.tools.trainer_cli:main",
             "paddle_serve=paddle_tpu.tools.serve_cli:main",
-            "pperf=paddle_tpu.tools.perf_cli:main",
             "pmem=paddle_tpu.tools.mem_cli:main",
-            "ptune=paddle_tpu.tools.tune_cli:main",
             "pshard=paddle_tpu.tools.shard_cli:main",
             "pcomm=paddle_tpu.tools.comm_cli:main",
             "pload=paddle_tpu.tools.load_cli:main",

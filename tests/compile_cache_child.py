@@ -162,12 +162,10 @@ def supervisor(args):
     """Cold: a fault-free supervised run (the reference losses) and
     one killed by a real SIGTERM mid-epoch under `--workdir`.  Warm:
     the rescheduled process — it resumes the killed run's checkpoint
-    and finishes, with a StepProfiler watching.  The cold process never
-    resumes: what the resumed one runs is what an uninterrupted run
+    and finishes.  The cold process never resumes: what the resumed one runs is what an uninterrupted run
     compiled."""
     import paddle_tpu.fluid as fluid
     from paddle_tpu.core.scope import Scope
-    from paddle_tpu.obs import perf as obs_perf
     from paddle_tpu.obs import telemetry as obs_tele
     from paddle_tpu.reader import host_prefetch
     from paddle_tpu.resilience import faults
@@ -215,14 +213,8 @@ def supervisor(args):
         finally:
             faults.disable()
         return {"fetches": losses}
-    profiler = obs_perf.install(capacity=64, sample_every=1 << 30)
-    try:
-        summary, losses = supervised("killed")
-    finally:
-        obs_perf.uninstall()
-    return {"fetches": losses, "steps": summary["steps"],
-            "profiled_pcache_hits": sum(
-                r["pcache_hits"] for r in profiler.records())}
+    summary, losses = supervised("killed")
+    return {"fetches": losses, "steps": summary["steps"]}
 
 
 # ---------------------------------------------------------------------------

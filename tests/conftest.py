@@ -33,10 +33,8 @@ def fresh_obs():
     from paddle_tpu.obs import flight as obs_flight
     from paddle_tpu.obs import health as obs_health
     from paddle_tpu.obs import mem as obs_mem
-    from paddle_tpu.obs import perf as obs_perf
     from paddle_tpu.obs import registry as obs_registry
     from paddle_tpu.obs import tail as obs_tail
-    from paddle_tpu.obs import telemetry as obs_tele
     from paddle_tpu.obs import trace as obs_trace
     from paddle_tpu.resilience import faults as r_faults
 
@@ -52,9 +50,7 @@ def fresh_obs():
     obs_health.disable()
     obs_flight.uninstall()
     obs_flight.clear_host_context()
-    obs_perf.uninstall()
     obs_tail.uninstall()
-    obs_tele.install_step_observer(None)
     obs_trace.disable()
     obs_trace.reset()
     r_faults.disable()

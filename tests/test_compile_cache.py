@@ -113,10 +113,13 @@ def test_restart_compiles_nothing(restart, entry):
             cold["warmup"]["jit_compiles"]
 
 
-def test_step_profiler_records_cache_hits(restart):
-    """A StepProfiler watching the resumed run sees the hits in its
-    step records (`pcache_hits`, a key others read)."""
-    assert restart[1]["supervisor"]["profiled_pcache_hits"] > 0
+def test_resumed_run_loads_what_the_cold_process_left(restart):
+    """The registry's counters around the resumed run
+    (`compile_cache_hits_total`, what the engine's warm-up stats read
+    too): it loads executables, and no more of them than the cold
+    process compiled or loaded itself."""
+    cold, warm = restart[0]["supervisor"], restart[1]["supervisor"]
+    assert 0 < warm["hits"] <= cold["misses"] + cold["hits"]
 
 
 # ---------------------------------------------------------------------------
@@ -308,8 +311,7 @@ class TestProgramCacheEvictionMetric:
 @pytest.mark.parametrize("gone", [
     # the home-made executable cache, gone with its flag, its option and
     # the mode it degraded donation to.  `pcache_hits` / `pcache_misses`
-    # stay: they are keys of records others read (`obs/perf.py`, the
-    # engine's warm-up stats)
+    # stay: they are keys of the engine's warm-up stats
     r"compile_cache_dir|use_pcache|effective_mode"
     r"|pcache(?!_hits|_misses)",
     # the measuring programs from before `benchmark/run.py`, gone with

@@ -1,7 +1,6 @@
 """paddle_tpu.obs.comm: per-bucket comm spans, overlap-efficiency
-truth, drift calibration, cross-host merge, and the comm regression
-gate (tools/comm_cli.py `pcomm` is the operator surface; scripts/ci.sh
-runs its --selftest).
+truth, analytic-floor drift and cross-host merge (tools/comm_cli.py
+`pcomm` is the operator surface; scripts/ci.sh runs its --selftest).
 """
 
 import json
@@ -16,14 +15,12 @@ from paddle_tpu import native
 from paddle_tpu.obs import comm as obs_comm
 from paddle_tpu.obs import fleet as obs_fleet
 from paddle_tpu.obs import flight as obs_flight
-from paddle_tpu.obs import perf as obs_perf
 from paddle_tpu.obs import registry as obs_registry
 from paddle_tpu.obs import trace as obs_trace
 from paddle_tpu.parallel import make_mesh
 from paddle_tpu.spmd import SpmdTrainer
 from paddle_tpu.spmd import overlap as spmd_overlap
 from paddle_tpu.tools.obs_dump import validate_chrome_trace
-from paddle_tpu.tune import fit as tune_fit
 
 BATCH, DIM, HIDDEN, CLASSES = 16, 8, 1024, 4
 
@@ -234,7 +231,7 @@ def test_trainer_stamps_flight_host_context(overlap_setup):
     assert ctx.get("host")
 
 
-# -- drift -> calibration blob -> ptune fit --------------------------------
+# -- drift off the ring-cost floor -----------------------------------------
 
 _BUCKET_REPORT = {
     "collective": "allreduce", "axis": "dp", "n": 8,
@@ -260,143 +257,6 @@ def test_drift_report_rows_and_gauge():
             for s in gauge.samples()}
     assert vals == {"0": 2.0, "1": 3.0}
     assert obs_comm.drift_report(None)["n"] == 0
-
-
-def test_calibration_blob_roundtrip_and_class_discipline(tmp_path):
-    blob = obs_comm.calibration_blob(
-        _BUCKET_REPORT, platform_class="cpu:d8:dp=8", model="mlp")
-    assert blob["kind"] == obs_comm.COMM_CALIBRATION_KIND
-    assert blob["n"] == 2 and blob["comm_ratio"] == 2.5
-    assert all(p["platform_class"] == "cpu:d8:dp=8"
-               for p in blob["pairs"])
-    path = str(tmp_path / "comm_cal.json")
-    obs_comm.save_calibration(blob, path)
-    pairs = tune_fit.load_comm_calibration(path)
-    assert len(pairs) == 2 and pairs[0]["leg"].endswith("bucket0")
-    cal = tune_fit.fit_calibration([], comm_pairs=pairs)
-    assert cal.coef["comm"] == pytest.approx(2.5)
-    # same-platform-class discipline: training legs from a DIFFERENT
-    # class keep the analytic prior instead of ingesting these pairs
-    foreign = [{"leg": "ptune:x", "measured_s": 0.1,
-                "meas_compute_s": 0.08, "overhead_s": 0.01,
-                "platform_class": "tpu:d8:dp=8"}]
-    cal2 = tune_fit.fit_calibration(foreign, comm_pairs=pairs)
-    assert cal2.coef["comm"] == 1.0
-    assert "kept analytic" in cal2.note
-    # nothing measured -> no blob (the CLI turns this into rc 2)
-    assert obs_comm.calibration_blob({"buckets": []}) is None
-    assert obs_comm.calibration_blob(None,
-                                     platform_class="x") is None
-
-
-def test_load_comm_calibration_refuses_bad_blobs(tmp_path):
-    wrong = tmp_path / "mem.json"
-    wrong.write_text(json.dumps(
-        {"kind": "paddle_tpu.mem_calibration", "pairs": []}))
-    with pytest.raises(ValueError, match="not a pcomm"):
-        tune_fit.load_comm_calibration(str(wrong))
-    # right kind, nothing usable: must raise, never silently keep the
-    # analytic prior while claiming to have fitted
-    empty = tmp_path / "empty.json"
-    empty.write_text(json.dumps(
-        {"kind": obs_comm.COMM_CALIBRATION_KIND,
-         "pairs": [{"leg": "x", "measured_s": 0.0, "pred_s": 0.001},
-                   {"leg": "y", "measured_s": 0.01, "pred_s": -1}]}))
-    with pytest.raises(ValueError, match="no usable"):
-        tune_fit.load_comm_calibration(str(empty))
-
-
-# -- history schema + the comm gate ----------------------------------------
-
-def test_normalize_record_forwards_comm_blob():
-    norm = obs_perf.normalize_record(
-        {"metric": "m", "value": 1.0,
-         "comm": {"measured_s": 0.005, "pred_s": 0.002,
-                  "exposed_s": 0.001, "hidden_s": 0.004,
-                  "overlap_efficiency": 0.8,
-                  "step_mode": "overlap-dp", "plan_fingerprint": "fp",
-                  "buckets": [{"bucket": 0}]}})
-    comm = norm["comm"]
-    assert comm["exposed_s"] == 0.001
-    assert comm["step_mode"] == "overlap-dp"
-    assert comm["plan_fingerprint"] == "fp"
-    # per-bucket detail stays OUT of history lines
-    assert "buckets" not in comm
-    # fallback stamp rides along; absent comm -> absent key
-    norm2 = obs_perf.normalize_record(
-        {"metric": "m", "value": 1.0,
-         "comm": {"measured_s": 10.0, "step_mode": "gspmd",
-                  "overlap_fallback_reason": "mesh is not pure dp"}})
-    assert norm2["comm"]["overlap_fallback_reason"]
-    assert "exposed_s" not in norm2["comm"]
-    assert "comm" not in obs_perf.normalize_record(
-        {"metric": "m", "value": 1.0})
-
-
-def _comm_history(path, regress=False, candidate_fallback=False):
-    """±2% exposed-comm noise plus one mid-history gspmd fallback
-    record (no exposed_s, huge measured_s) that must not drag the
-    overlap baseline."""
-    noise = [1.0, 0.99, 1.012, 0.994, 1.009, 0.98]
-    ts = 1_700_000_000.0
-    for i, n in enumerate(noise):
-        e = 0.004 * (1.2 if (regress and i == len(noise) - 1) else n)
-        obs_perf.append_history(
-            {"metric": "mlp_multichip_imgs_per_sec",
-             "value": round(512.0 * n, 2), "unit": "img/s",
-             "step_ms": 31.0, "platform": "cpu",
-             "comm": {"measured_s": 0.005, "exposed_s": round(e, 6),
-                      "overlap_efficiency": 0.8,
-                      "step_mode": "overlap-dp",
-                      "plan_fingerprint": "fp0"}},
-            path, leg="dp=8", ts=ts + i)
-        if i == 2:
-            obs_perf.append_history(
-                {"metric": "mlp_multichip_imgs_per_sec",
-                 "value": 512.0, "unit": "img/s", "step_ms": 31.0,
-                 "platform": "cpu",
-                 "comm": {"measured_s": 10.0, "step_mode": "gspmd",
-                          "overlap_fallback_reason": "not pure dp"}},
-                path, leg="dp=8", ts=ts + i + 0.5)
-    if candidate_fallback:
-        obs_perf.append_history(
-            {"metric": "mlp_multichip_imgs_per_sec", "value": 512.0,
-             "unit": "img/s", "step_ms": 31.0, "platform": "cpu",
-             "comm": {"measured_s": 0.02, "step_mode": "gspmd",
-                      "overlap_fallback_reason": "not pure dp"}},
-            path, leg="dp=8", ts=ts + 10)
-    return path
-
-
-def test_comm_gate_passes_noise_fails_regression(tmp_path):
-    ok = _comm_history(str(tmp_path / "ok.jsonl"))
-    res = obs_perf.gate_history(obs_perf.load_history(ok),
-                                comm_tolerance=0.1)
-    assert res.ok, obs_perf.format_gate(res)
-
-    bad = _comm_history(str(tmp_path / "bad.jsonl"), regress=True)
-    res = obs_perf.gate_history(obs_perf.load_history(bad),
-                                comm_tolerance=0.1)
-    assert not res.ok and res.failures[0]["kind"] == "comm"
-    assert "exposed_s" in res.failures[0]["why"]
-    # the gate is OPT-IN: without the flag, throughput noise hides
-    # the regression — exactly why the flag exists
-    assert obs_perf.gate_history(obs_perf.load_history(bad)).ok
-
-
-def test_comm_gate_same_key_discipline(tmp_path):
-    # a fallback CANDIDATE carries no exposed_s, so it gates on
-    # measured_s — against the overlapped baseline's standalone ring
-    # (0.005s), the 0.02s ring fails on THAT key, and the mid-history
-    # fallback record (measured_s=10) never polluted the exposed_s
-    # baseline of the overlapped runs before it
-    path = _comm_history(str(tmp_path / "fb.jsonl"),
-                         candidate_fallback=True)
-    res = obs_perf.gate_history(obs_perf.load_history(path),
-                                comm_tolerance=0.1)
-    assert not res.ok and res.failures[0]["kind"] == "comm"
-    assert "measured_s" in res.failures[0]["why"]
-    assert "exposed_s" not in res.failures[0]["why"]
 
 
 # -- span windows, clock exchange, cross-host merge ------------------------

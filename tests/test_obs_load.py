@@ -1,14 +1,13 @@
 """paddle_tpu.obs.load: traffic mix, arrival schedules, replay,
 open-vs-closed-loop latency accounting (the coordinated-omission
-asymmetry, demonstrated on a fake stalling target), report math, the
-tail/exemplar joins, and the latency blob -> gate round trip.
+asymmetry, demonstrated on a fake stalling target), report math and
+the tail/exemplar joins.
 
 Tier-1 (CPU, no real server — the loopback/HTTP integration is
 `pload --selftest`'s job): schedules must be deterministic under
 seed, replay must preserve gaps and batches, open-loop latency must
 be measured from the SCHEDULE while closed-loop latency is measured
-from the send, and `gate_history(latency_tolerance=)` must regress
-same-key/same-mode only."""
+from the send."""
 
 import json
 import random
@@ -18,7 +17,6 @@ import time
 import pytest
 
 from paddle_tpu.obs import load as obs_load
-from paddle_tpu.obs import perf as obs_perf
 from paddle_tpu.obs.registry import MetricsRegistry
 
 
@@ -318,83 +316,3 @@ def test_parse_and_join_exemplars():
         mode="open", wall_s=1.0)
     assert obs_load.join_exemplars(report, text) == 1
     assert report["worst"][0]["exemplars"][0]["le"] == "0.05"
-
-
-# ---------------------------------------------------------------------------
-# latency blob -> history -> gate
-# ---------------------------------------------------------------------------
-
-def _lat_record(blob, value=100.0):
-    return {"metric": "serving_slo_openloop_rps", "value": value,
-            "unit": "req/s", "platform": "cpu", "latency": blob}
-
-
-def _blob(scale=1.0, mode="open", **extra):
-    blob = {"mode": mode, "n": 200, "p50_ms": 5.0 * scale,
-            "p90_ms": 8.0 * scale, "p99_ms": 20.0 * scale,
-            "p99_9_ms": 45.0 * scale, "slo_ms": 50.0,
-            "slo_attainment": 0.99, "offered_rps": 100.0,
-            "achieved_rps": 99.0}
-    blob.update(extra)
-    return blob
-
-
-def test_latency_blob_survives_normalize_record():
-    report = obs_load.build_report(_samples([1.0, 2.0, 3.0]),
-                                   mode="open", wall_s=1.0, slo_ms=2.5,
-                                   offered_rps=3.0)
-    blob = obs_load.latency_blob(report)
-    assert blob["mode"] == "open" and blob["n"] == 3
-    assert blob["slo_attainment"] == pytest.approx(2 / 3, abs=1e-4)
-    norm = obs_perf.normalize_record(_lat_record(blob), leg="pload",
-                                     ts=1.0)
-    assert norm["latency"]["p99_ms"] == blob["p99_ms"]
-    assert norm["latency"]["mode"] == "open"
-    # records without the blob stay blob-free
-    assert "latency" not in obs_perf.normalize_record(
-        {"metric": "m", "value": 1.0}, ts=1.0)
-
-
-def _gate(records, **kw):
-    return obs_perf.gate_history(
-        [obs_perf.normalize_record(r, leg="pload", ts=1000.0 + i)
-         for i, r in enumerate(records)], **kw)
-
-
-def test_latency_gate_is_opt_in_and_names_the_percentile():
-    records = [_lat_record(_blob()) for _ in range(5)]
-    records.append(_lat_record(_blob(scale=3.0)))
-    # opt-in: without the tolerance the regression passes
-    assert _gate(records).ok
-    res = _gate(records, latency_tolerance=0.25)
-    assert not res.ok
-    f = res.failures[0]
-    assert f["kind"] == "latency"
-    assert "p99_9_ms" in f["why"] and "open loop" in f["why"]
-    # within tolerance passes
-    ok = [_lat_record(_blob()) for _ in range(5)]
-    ok.append(_lat_record(_blob(scale=1.1)))
-    assert _gate(ok, latency_tolerance=0.25).ok
-
-
-def test_latency_gate_same_key_fallback():
-    """A candidate that only carries p50 gates on p50 against the
-    baselines' p50 — never a cross-percentile comparison."""
-    records = [_lat_record(_blob()) for _ in range(5)]
-    records.append(_lat_record(
-        {"mode": "open", "n": 10, "p50_ms": 50.0}))
-    res = _gate(records, latency_tolerance=0.25)
-    assert not res.ok and "p50_ms" in res.failures[0]["why"]
-
-
-def test_latency_gate_mode_separation():
-    """Closed-loop percentiles are omission-blind: an open-loop
-    candidate must never gate against a closed-loop baseline even
-    when its numbers are higher."""
-    records = [_lat_record(_blob(mode="closed")) for _ in range(5)]
-    records.append(_lat_record(_blob(scale=3.0, mode="open")))
-    assert _gate(records, latency_tolerance=0.25).ok
-    # and records with no latency blob are never failed on latency
-    bare = [{"metric": "m", "value": 100.0, "platform": "cpu"}
-            for _ in range(6)]
-    assert _gate(bare, latency_tolerance=0.25).ok

@@ -1,6 +1,5 @@
 """paddle_tpu.obs.mem: static memory timeline vs XLA actuals, the
-donation audit, OOM pre-flight/post-mortems, gauge retirement, and
-the memory regression gate (PR 15)."""
+donation audit, OOM pre-flight/post-mortems and gauge retirement."""
 
 import json
 import os
@@ -13,7 +12,6 @@ from paddle_tpu.core.scope import Scope
 from paddle_tpu.obs import flight as obs_flight
 from paddle_tpu.obs import health as obs_health
 from paddle_tpu.obs import mem as obs_mem
-from paddle_tpu.obs import perf as obs_perf
 from paddle_tpu.obs import registry as obs_registry
 from paddle_tpu.utils import flags as pt_flags
 
@@ -194,48 +192,6 @@ def test_store_dump_load_roundtrip(tmp_path):
         obs_mem.load_store(bad)
 
 
-def test_calibration_blob_feeds_ptune(tmp_path):
-    from paddle_tpu.tune.fit import load_hbm_calibration
-
-    main, startup, loss, feeds = _build_lenet5()
-    _run_captured(main, startup, loss, feeds)
-    rep = obs_mem.drift_report()
-    blob = obs_mem.calibration_blob(rep, model="lenet5")
-    assert blob["kind"] == obs_mem.MEM_CALIBRATION_KIND
-    path = str(tmp_path / "cal.json")
-    obs_mem.save_calibration(blob, path)
-    ratio = load_hbm_calibration(path)
-    assert ratio == rep["median_ratio"] > 0
-    # wrong kind / unusable ratio must raise, never silently widen
-    with pytest.raises(ValueError):
-        bad = str(tmp_path / "notcal.json")
-        with open(bad, "w") as f:
-            json.dump({"kind": "something"}, f)
-        load_hbm_calibration(bad)
-
-
-def test_rank_applies_hbm_ratio():
-    """A measured ratio scales the static peak before the S005 budget
-    check: a budget the analytic peak fits busts under ratio 10."""
-    from paddle_tpu.tune import models as tune_models
-    from paddle_tpu.tune import rank as tune_rank
-    from paddle_tpu.tune.space import SearchSpace
-
-    builder = tune_models.builder("lenet5")
-    cands = SearchSpace(1, meshes=["dp=1"], pipelines=["none"],
-                        batches=[8], micro_batches=[1]).points()
-    analytic = tune_rank.rank(builder, cands, 1, model="lenet5",
-                              hbm_gb=1.0, bf16_act=False)
-    assert analytic.ranked and not analytic.rejected
-    budget_gb = (analytic.ranked[0].peak_hbm_bytes * 3) / 2 ** 30
-    calibrated = tune_rank.rank(builder, cands, 1, model="lenet5",
-                                hbm_gb=budget_gb, bf16_act=False,
-                                hbm_ratio=10.0)
-    assert not calibrated.ranked and calibrated.rejected
-    rej = calibrated.rejected[0]
-    assert rej.code == "S005" and "calibration" in rej.message
-
-
 # ---------------------------------------------------------------------------
 # donation audit
 # ---------------------------------------------------------------------------
@@ -411,95 +367,6 @@ def test_eviction_keeps_labels_shared_with_live_program():
                 scope=scope_b)
     assert _segment_gauge_labels("mem_static_peak_bytes") == labels
     assert _segment_gauge_labels("xla_temp_bytes") >= labels
-
-
-# ---------------------------------------------------------------------------
-# history + regression gate (satellite: bench memory blob)
-# ---------------------------------------------------------------------------
-
-def _mem_record(value, peak_bytes, platform="tpu"):
-    return {"metric": "resnet50_train_imgs_per_sec_batch128",
-            "value": value, "unit": "img/s", "step_ms": 50.0,
-            "amp_bf16": True, "platform": platform,
-            "memory": {"static_peak_bytes": peak_bytes,
-                       "xla_total_bytes": peak_bytes,
-                       "estimate_ratio": 1.0}}
-
-
-def test_normalize_record_forwards_memory():
-    norm = obs_perf.normalize_record(_mem_record(2400.0, 1 << 30),
-                                     leg="default-b128")
-    assert norm["memory"]["xla_total_bytes"] == 1 << 30
-    assert norm["memory"]["estimate_ratio"] == 1.0
-    # records without the blob normalize without the key
-    rec = _mem_record(2400.0, 1 << 30)
-    del rec["memory"]
-    assert "memory" not in obs_perf.normalize_record(rec)
-
-
-def test_gate_memory_regression_opt_in():
-    base = 1 << 30
-    records = [obs_perf.normalize_record(_mem_record(2400.0, base),
-                                         ts=i) for i in range(4)]
-    # newest run: same throughput, 40% more HBM
-    records.append(obs_perf.normalize_record(
-        _mem_record(2400.0, int(base * 1.4)), ts=9))
-    # memory is OPT-IN: the default gate passes
-    assert obs_perf.gate_history(records).ok
-    result = obs_perf.gate_history(records, mem_tolerance=0.10)
-    assert not result.ok
-    assert result.failures[0]["kind"] == "memory"
-    assert "peak memory" in result.failures[0]["why"]
-    # within tolerance passes
-    ok = obs_perf.gate_history(records[:-1], mem_tolerance=0.10)
-    assert ok.ok
-
-
-def test_gate_memory_never_mixes_keys():
-    """A candidate that lost its AOT capture (static bytes only) must
-    not gate its static peak against an XLA-bytes baseline — the two
-    quantities legitimately differ by the pinned factor.  With no
-    shared key the memory check is a no-op, not a false verdict."""
-    base = 1 << 30
-    records = []
-    for i in range(4):
-        r = obs_perf.normalize_record(_mem_record(2400.0, base), ts=i)
-        del r["memory"]["static_peak_bytes"]  # baseline: xla only
-        records.append(r)
-    cand = obs_perf.normalize_record(
-        _mem_record(2400.0, int(base * 0.5)), ts=9)
-    del cand["memory"]["xla_total_bytes"]     # candidate: static only
-    records.append(cand)
-    # static 0.5 GiB vs xla 1.0 GiB would "pass" a real regression if
-    # mixed — and a static candidate ABOVE an xla baseline would
-    # false-fail; either way the keys must not join
-    assert obs_perf.gate_history(records, mem_tolerance=0.10).ok
-    cand["memory"]["static_peak_bytes"] = int(base * 2)
-    assert obs_perf.gate_history(records, mem_tolerance=0.10).ok
-    # once the baseline shares the static key, the same candidate
-    # fails on it
-    for r in records[:-1]:
-        r["memory"]["static_peak_bytes"] = base
-    result = obs_perf.gate_history(records, mem_tolerance=0.10)
-    assert not result.ok
-    assert "static_peak_bytes" in result.failures[0]["why"]
-
-
-def test_bench_memory_blob_shapes():
-    main, _startup, loss, _feeds = _build_lenet5()
-    blob = obs_mem.bench_memory_blob(main, fetches=[loss.name])
-    assert blob["static_peak_bytes"] == \
-        blob["params_bytes"] + blob["activation_peak_bytes"]
-    assert "estimate_ratio" not in blob  # no xla capture given
-    blob2 = obs_mem.bench_memory_blob(
-        main, fetches=[loss.name],
-        xla_stats={"xla_temp_bytes": 1000, "xla_argument_bytes": 500,
-                   "xla_output_bytes": 100})
-    assert blob2["xla_total_bytes"] == 1600
-    # actual/static — the SAME direction as mem_estimate_ratio and
-    # the calibration blob (1.0 = static model exact)
-    assert blob2["estimate_ratio"] == round(
-        1600 / blob2["static_peak_bytes"], 4)
 
 
 # ---------------------------------------------------------------------------

@@ -216,7 +216,7 @@ class TestSpecGrammar:
 
     def test_explicit_default_knob_is_same_pipeline(self):
         # "fuse:cap=0" IS the bare fuse pass: one semantics -> one
-        # pipeline id (no duplicate ptune points)
+        # pipeline id
         assert passes.pipeline_id("fuse:cap=0") == \
             passes.pipeline_id("fuse")
         assert passes.pipeline_id("layout:force=0") == \
@@ -227,7 +227,7 @@ class TestSpecGrammar:
     def test_float_knob_token_reparses(self):
         # '%g' rendered 2e6 as '2e+06', whose '+' is a token
         # separator — the canonical spec must round-trip through the
-        # parser (tune/space normalizes specs exactly this way)
+        # parser
         pid = passes.pipeline_id("auto_remat:budget_gb=2000000")
         spec = passes.PassManager(
             "auto_remat:budget_gb=2000000", verify=False).spec

@@ -224,15 +224,7 @@ def test_step_waits_for_the_step_before_and_never_for_its_own(
                             for i in range(4)]
 
 
-class _Observer:
-    def begin_step(self, trainer):
-        pass
-
-    def end_step(self, trainer, dt, examples, failed=False):
-        pass
-
-
-@pytest.mark.parametrize("who", ["monitor", "flight", "observer"])
+@pytest.mark.parametrize("who", ["monitor", "flight"])
 def test_step_waits_for_its_own_fetches_when_someone_reads_them(
         monkeypatch, tmp_path, who):
     if who == "monitor":
@@ -246,8 +238,6 @@ def test_step_waits_for_its_own_fetches_when_someone_reads_them(
             recorder, "record_step",
             lambda trainer, step, **kw: recorded.append((step, kw))
             or real(trainer, step, **kw))
-    if who == "observer":
-        obs_tele.install_step_observer(_Observer())
     waited = _watch_waits(monkeypatch)
     with obs_trace.tracing():
         for i in range(3):

@@ -105,8 +105,7 @@ def test_rewrite_reaches_xla():
     optimization_barriers.  (Whether the backend *honors* them is
     platform policy: XLA:CPU strips the barrier and CSEs the clones
     away — verified jax.checkpoint itself gets undone there too — while
-    XLA:TPU schedules them late, which is where the HBM win lands:
-    docs/PERF.md, ResNet-50 at batch 256 with stride 8, 2026-07-31.)"""
+    XLA:TPU schedules them late, which is where the HBM win lands.)"""
     import jax
 
     stats = {}

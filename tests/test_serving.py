@@ -655,3 +655,68 @@ def test_queue_depth_peak_high_watermark():
             fut.result(timeout=30)
     finally:
         batcher.close()
+
+
+# ---------------------------------------------------------------------------
+# SLO burn
+# ---------------------------------------------------------------------------
+
+def test_slo_tracker_burn_windows():
+    from paddle_tpu.serving.metrics import SLOTracker
+
+    m = ServingMetrics()
+    slo = SLOTracker(m, objective_ms=100.0, target=0.9, model="mdl")
+    assert slo.update() == 0.0                   # no traffic yet
+    for _ in range(8):
+        m.total_seconds.observe(0.01)            # within objective
+    for _ in range(2):
+        m.total_seconds.observe(5.0)             # violations
+    # 20% violating / 10% budget = burn 2x
+    assert slo.update() == pytest.approx(2.0, rel=0.05)
+    # next window: all good -> burn back to 0
+    for _ in range(5):
+        m.total_seconds.observe(0.01)
+    assert slo.update() == pytest.approx(0.0, abs=1e-9)
+    # gauge surfaced in the default registry, labeled by model
+    from paddle_tpu.obs import registry as obs_registry
+
+    fam = obs_registry.get_registry().gauge(
+        "slo_burn_rate", labelnames=("model",))
+    assert fam.labels(model="mdl").value == 0.0
+    with pytest.raises(ValueError):
+        SLOTracker(m, objective_ms=50, target=1.0)
+    # objectives beyond the histogram's largest finite bucket are
+    # unmeasurable (violations would land in +Inf and read as good)
+    with pytest.raises(ValueError):
+        SLOTracker(m, objective_ms=60_000)
+
+
+def test_server_healthz_carries_slo_burn():
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        img = fluid.layers.data(name="img", shape=[4], dtype="float32")
+        out = fluid.layers.fc(input=img, size=2)
+    scope = Scope()
+    exe = fluid.Executor(fluid.CPUPlace())
+    with fluid.scope_guard(scope):
+        exe.run(startup)
+    program = fluid_io.prune_program(main, [out])
+    engine = InferenceEngine(program, ["img"], [out], scope=scope,
+                             config=EngineConfig(batch_buckets=[2]))
+    server = InferenceServer(
+        engine, ServerConfig(warmup=False, slo_ms=30_000,
+                             slo_target=0.99, model_name="m0"))
+    server.batcher.start()
+    try:
+        status, _ = server.handle_infer(
+            {"inputs": {"img": np.zeros((1, 4)).tolist()}})
+        assert status == 200
+        health = server.health_signals()
+    finally:
+        server.batcher.close()
+    assert health["slo"]["objective_ms"] == 30_000
+    # generous objective: nothing burned
+    assert health["slo_burn_rate"] == 0.0
+    # without an SLO config the key stays absent (contract: opt-in)
+    server2 = InferenceServer(engine, ServerConfig(warmup=False))
+    assert "slo_burn_rate" not in server2.health_signals()

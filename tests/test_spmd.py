@@ -14,10 +14,7 @@ Four contracts pinned here:
   * resilience: sharded checkpoint save -> restore reassembles the
     exact state with NOTHING densified, and a supervisor attached via
     `attach_supervisor` auto-resumes a fresh trainer from the sharded
-    snapshots;
-  * measurement: MULTICHIP records carry platform_class / comm blobs,
-    the perf gate refuses cross-class baselines, and `ptune fit`
-    prices the comm coefficient only from same-class multichip pairs.
+    snapshots.
 """
 
 import json
@@ -293,59 +290,3 @@ def test_supervisor_auto_resume_sharded(tmp_path):
         np.testing.assert_array_equal(np.asarray(tr2.state[n]),
                                       np.asarray(tr.state[n]),
                                       err_msg=n)
-
-
-# -- platform_class gating + comm calibration ------------------------------
-
-def _record(step_ms, platform="cpu", n_devices=None, mesh=None,
-            comm=None, ts=0):
-    rec = {"ts": ts, "metric": "multichip_mlp", "leg": "L",
-           "value": 1000.0 / step_ms, "unit": "img/s",
-           "step_ms": step_ms, "mfu": None, "amp_bf16": False,
-           "platform": platform}
-    if n_devices:
-        rec["n_devices"] = n_devices
-        rec["platform_class"] = "%s:d%d" % (platform, n_devices)
-    if mesh:
-        rec["mesh"] = mesh
-        rec["platform_class"] += ":" + ",".join(
-            "%s=%d" % kv for kv in sorted(mesh.items()))
-    if comm:
-        rec["comm"] = comm
-    return rec
-
-
-def test_gate_refuses_cross_class_baseline():
-    from paddle_tpu.obs import perf as obs_perf
-
-    history = [_record(10.0, ts=i) for i in range(3)]
-    cand = _record(10.0, n_devices=8, mesh={"dp": 8}, ts=9)
-    res = obs_perf.gate_history(history + [cand])
-    assert not res.ok
-    assert any("platform class mismatch" in f["why"]
-               for f in res.failures)
-    # same class present: the 8-device baseline gates the 8-device run
-    history8 = [_record(10.0, n_devices=8, mesh={"dp": 8}, ts=i)
-                for i in range(3)]
-    res = obs_perf.gate_history(history8 + [cand])
-    assert res.ok
-    assert any(c.get("platform_class") == "cpu:d8:dp=8"
-               for c in res.checked)
-
-
-def test_fit_prices_comm_from_multichip_pairs():
-    from paddle_tpu.obs import perf as obs_perf
-    from paddle_tpu.tune import fit as tune_fit
-
-    comm = {"wire_bytes": 1 << 20, "pred_s": 1e-3, "measured_s": 3e-3}
-    recs = [_record(10.0, n_devices=8, mesh={"dp": 8}, comm=comm,
-                    ts=i) for i in range(3)]
-    pairs = tune_fit.join_comm_history(recs)
-    assert len(pairs) == 3
-    assert pairs[0]["platform_class"] == "cpu:d8:dp=8"
-    cal = tune_fit.fit_calibration([], comm_pairs=pairs)
-    assert cal.coef["comm"] == pytest.approx(3.0)
-    assert "multichip measurement" in cal.note
-    # no multichip pairs: the comm term stays analytic, and says so
-    cal = tune_fit.fit_calibration([], comm_pairs=[])
-    assert cal.coef.get("comm", 1.0) == pytest.approx(1.0)
