@@ -159,7 +159,15 @@ def _chosen(top_w, attrs):
 def _kept_groups(choice, groups, kept, top_k):
     """`choice` [tokens, experts] with -inf on every expert outside the
     token's `kept` best of `groups` groups of consecutive experts; a
-    group scores the sum of its two largest entries."""
+    group scores the sum of its two largest entries (a largest that
+    occurs twice counts twice), and of groups that score the same the
+    lower index is the better: what a stable sort gives, without one.
+    A group's largest entry, the largest of the others, and a group's
+    rank counted over all pairs of groups.  `lax.top_k` is a stable sort
+    of its whole last axis on the TPU, and [tokens, groups, width] is
+    sorted with the 8 groups in a tile's 128 lanes: the two sorts were
+    94 of the router's 132 us at 128 tokens x 512 experts (PERF.md
+    section 6, PR 57)."""
     n, experts = choice.shape
     if experts % groups or not 0 < kept <= groups \
             or experts // groups < 2 or kept * (experts // groups) < top_k:
@@ -169,10 +177,17 @@ def _kept_groups(choice, groups, kept, top_k):
                                                 top_k))
     telemetry.on_moe_grouped_router_lowering(experts, groups, kept, top_k)
     with jax.named_scope("moe_groups"):
-        grouped = choice.reshape(n, groups, experts // groups)
-        group_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
-        _, best = jax.lax.top_k(group_score, kept)
-        keep = jnp.any(best[:, :, None] == jnp.arange(groups), axis=1)
+        width = experts // groups
+        grouped = choice.reshape(n, groups, width)
+        at = jnp.argmax(grouped, axis=-1)
+        others = jnp.where(jnp.arange(width) == at[:, :, None], -jnp.inf,
+                           grouped)
+        score = jnp.max(grouped, axis=-1) + jnp.max(others, axis=-1)
+        # ahead[t, g, h]: group h is chosen before group g
+        mine, theirs = score[:, :, None], score[:, None, :]
+        lower = jnp.arange(groups) < jnp.arange(groups)[:, None]
+        ahead = (theirs > mine) | ((theirs == mine) & lower)
+        keep = jnp.sum(ahead, axis=-1, dtype=jnp.int32) < kept
         return jnp.where(keep[:, :, None], grouped,
                          -jnp.inf).reshape(n, experts)
 
@@ -199,7 +214,8 @@ def moe_router(ctx, ins, attrs):
     and with `n_group` > 1 the experts are `n_group` groups of
     consecutive ones, a group scores the sum of its two largest s', and
     an expert outside the `topk_group` best groups cannot be chosen
-    whatever its score (`moe_groups`)."""
+    whatever its score (`moe_groups`; of groups that score the same the
+    lower index is the better, as of experts in the choice itself)."""
     x, w = ins["X"][0], ins["W"][0]
     k = int(attrs["top_k"])
     experts = w.shape[1]

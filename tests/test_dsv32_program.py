@@ -35,6 +35,7 @@ from paddle_tpu.models.reference import deepseek_v32 as reference
 from paddle_tpu.obs import telemetry
 from paddle_tpu.ops import registry
 from paddle_tpu.ops.attention import yarn_inv_freq, yarn_mscale
+from paddle_tpu.ops.moe import _kept_groups
 
 import parent_lowerings
 
@@ -506,6 +507,100 @@ def test_an_expert_outside_the_kept_groups_is_never_chosen():
     assert (np.asarray(free["TopIdx"][0])[:, 0] == 0).all()
     # every chosen pair lies inside two groups at most, by construction
     assert got["TopIdx"][0].shape == (n, K)
+
+
+def _kept_groups_by_sorting(choice, groups, kept):
+    """What `ops.moe._kept_groups` returned while it sorted (until PR
+    57), kept here as the reference: `lax.top_k` is a stable sort, so of
+    equal scores the lower index comes first."""
+    n, experts = choice.shape
+    grouped = choice.reshape(n, groups, experts // groups)
+    group_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
+    _, best = jax.lax.top_k(group_score, kept)
+    keep = jnp.any(best[:, :, None] == jnp.arange(groups), axis=1)
+    return jnp.where(keep[:, :, None], grouped,
+                     -jnp.inf).reshape(n, experts)
+
+
+def _scores_with_ties(rs, n, experts, groups, kept):
+    """Scores in steps of 2**-(1 + row % 6), so that maxima occur twice
+    and groups score the same all over, and rows planted by hand (row i
+    is i % n): an entry doubled inside a group, two groups that score
+    the same across the `kept` boundary (twice, the other way round in
+    index), a constant row, groups holding -inf."""
+    width = experts // groups
+    step = (2.0 ** -(1 + np.arange(n) % 6))[:, None]
+    c = (np.floor(rs.uniform(0.0, 1.0, (n, experts)) / step)
+         * step).astype("float32")
+    grouped = c.reshape(n, groups, width)       # a view: writes reach c
+
+    def row(i):
+        grouped[i % n] = np.floor(
+            rs.uniform(0.0, 0.5, (groups, width)) * 64) / 64
+        return grouped[i % n]
+
+    r = row(0)                                  # a doubled maximum
+    r[1, 3] = r[1, width - 1] = 0.75
+    for i, order in ((1, rs.permutation(groups)),
+                     (2, rs.permutation(groups)[::-1])):
+        # the groups score 2 + their place / 16, but the last kept and
+        # the first left out: the same sum from different entries
+        r = row(i)
+        for place, g in enumerate(order):
+            r[g, 0], r[g, 1] = 1.0 + place / 16.0, 1.0
+        last, first = order[groups - kept - 1], order[groups - kept]
+        r[last, 0], r[last, 1] = r[first, 0] - 1 / 16.0, 1.0 + 1 / 16.0
+    row(3)[:] = 0.25                            # a constant row
+    r = row(4)                                  # groups holding -inf
+    r[0, 1:] = -np.inf
+    r[groups - 1, :] = -np.inf
+    r[1, ::2] = -np.inf
+    return c
+
+
+@pytest.mark.parametrize("scores", ["random", "ties"])
+@pytest.mark.parametrize("n,experts,groups,kept", [
+    (128, 512, 8, 4), (16, 256, 8, 4), (8192, 512, 8, 4), (1, 64, 4, 2),
+    (7, 96, 8, 8)])
+def test_the_groups_are_kept_as_a_stable_sort_keeps_them(
+        n, experts, groups, kept, scores):
+    """Bit for bit the array the sorting form returns: a maximum that
+    occurs twice counts twice, and of groups that score the same the
+    lower index is kept."""
+    rs = np.random.RandomState(57)
+    c = rs.uniform(0.0, 1.0, (n, experts)).astype("float32") \
+        if scores == "random" \
+        else _scores_with_ties(rs, n, experts, groups, kept)
+    want = np.asarray(_kept_groups_by_sorting(jnp.asarray(c), groups, kept))
+    np.testing.assert_array_equal(
+        np.asarray(_kept_groups(jnp.asarray(c), groups, kept, 2)), want)
+    # `kept` groups whole and the others out, in every row
+    left = np.isfinite(want.reshape(n, groups, -1)).any(-1).sum(-1)
+    assert (left <= kept).all() and (scores == "ties" or (left == kept).all())
+
+
+def test_no_sort_is_traced_where_the_groups_are_chosen():
+    """Under `moe_groups` no `sort` and no `top_k`; the router's one
+    `top_k` is the choice of the experts itself."""
+    u, w, bias = _routing_inputs(8)
+    closed = jax.make_jaxpr(lambda *a: _router(
+        {"n_group": GROUPS, "topk_group": KEPT}, *a)["TopIdx"][0])(
+            u, w, bias)
+    found = []
+
+    def walk(jaxpr, inside):
+        for eqn in jaxpr.eqns:
+            here = inside or "moe_groups" in str(eqn.source_info.name_stack)
+            found.append((eqn.primitive.name, here))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub, here)
+
+    walk(closed.jaxpr, False)
+    under = {name for name, here in found if here}
+    assert {"argmax", "reduce_max", "reduce_sum"} <= under
+    assert not under & {"sort", "top_k"}
+    assert [name for name, _ in found
+            if name in ("sort", "top_k")] == ["top_k"]
 
 
 def test_the_grouped_router_refuses_what_it_cannot_do():
