@@ -59,7 +59,10 @@ class ProgramDecoder:
     A call need not start at position 0: `init_state` may hand in
     caches that already hold a session and the position it ends at (a
     decode-pool chip is handed its caches by a prefill pool), and the
-    prompt and the generated tokens continue from there.  `max_positions`
+    prompt and the generated tokens continue from there.  A state handed
+    in as a `jax.Array` is used where it lies and is not written to (no
+    call donates its state), so one session on the device serves call
+    after call; a host array goes to the device anew at every call.  `max_positions`
     is checked against the prompt and `max_len` alone (`_check_extent`):
     the caller of such a call answers for pos + prompt + max_len - 1 <=
     max_positions.  It is the extent of the *positions*; what a state
@@ -158,13 +161,18 @@ class ProgramDecoder:
             raise ValueError(
                 "init_state has keys %s that are not in state_pairs %s"
                 % (extra, sorted(known)))
-        # what the call was handed, by where it was (a `jax.Array` goes
-        # the same way through the host as a host array)
+        # what the call was handed, by where it was: a `jax.Array` is
+        # taken where it lies (a session a caller put on the device once
+        # costs its later calls nothing: 5.7 GB of caches through the
+        # host were 4.2-5.9 s of an 11 s call, PERF.md section 6, PR 58),
+        # anything else goes to the device from the host
         handed = {"host": 0, "device": 0}
         for f, v in state.items():
-            source = "device" if isinstance(v, jax.Array) else "host"
+            if isinstance(v, jax.Array):
+                handed["device"] += v.nbytes
+                continue
             v = np.asarray(v)
-            handed[source] += v.nbytes
+            handed["host"] += v.nbytes
             state[f] = jnp.asarray(v)
         for f, value in state.items():
             declared = self._declared.get(f, ())

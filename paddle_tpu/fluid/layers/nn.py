@@ -36,7 +36,7 @@ __all__ = [
 
 def cached_attention(query, key, value, k_cache, v_cache, position,
                      num_heads=1, sm_scale=None, num_kv_heads=None,
-                     window=0, name=None):
+                     window=0, name=None, selected=None, live=None):
     """Attention through a KV cache over a block of T >= 1 consecutive
     positions of every row (ops/attention.py cached_attention; T = 1 is
     a decode step): query [batch, T, num_heads * head_dim], key/value
@@ -48,6 +48,10 @@ def cached_attention(query, key, value, k_cache, v_cache, position,
     head j // (num_heads / num_kv_heads).  `window` > 0 (T = 1): the
     caches are rings of `window` slots written at position mod window,
     and a query sees itself and the window - 1 positions before it.
+    With `selected` int32 [batch, top_k] and `live` int32 [batch]
+    (`mla_index_select`'s two; whole-extent caches, T = 1) the step
+    attends the slots `selected` names, the first `live` of each row,
+    one set for every key/value head.
     Returns (out [batch, T, num_heads * head_dim], k_cache_out,
     v_cache_out) — thread the cache outputs back as decode state
     (`fluid.ProgramDecoder` state pairs)."""
@@ -63,11 +67,13 @@ def cached_attention(query, key, value, k_cache, v_cache, position,
         attrs["num_kv_heads"] = int(num_kv_heads)
     if window:
         attrs["window"] = int(window)
+    inputs = {"Q": [query], "KNew": [key], "VNew": [value],
+              "KCache": [k_cache], "VCache": [v_cache],
+              "Position": [position]}
+    if selected is not None:
+        inputs.update(Selected=[selected], Live=[live])
     helper.append_op(
-        type="cached_attention",
-        inputs={"Q": [query], "KNew": [key], "VNew": [value],
-                "KCache": [k_cache], "VCache": [v_cache],
-                "Position": [position]},
+        type="cached_attention", inputs=inputs,
         outputs={"Out": [out], "KCacheOut": [kc_out],
                  "VCacheOut": [vc_out]},
         attrs=attrs)
@@ -774,7 +780,7 @@ def rms_norm(input, epsilon=1e-6, param_attr=None, **kwargs):
 
 
 def rope(input, positions, num_heads, theta=10000.0, inv_freq=None,
-         rotary_dim=None, full_width=False, **kwargs):
+         rotary_dim=None, full_width=False, sections=None, **kwargs):
     """Rotary position embedding on each head of `input` [batch, seq,
     num_heads * head_dim] at `positions` [batch, seq] (ops/attention.py
     rope): rotate-half form, base `theta`, or the rates `inv_freq` (a
@@ -782,7 +788,10 @@ def rope(input, positions, num_heads, theta=10000.0, inv_freq=None,
     place of theta's powers; `rotary_dim` turns the first so many values
     of every head and hands on the rest.  `full_width`: a step that takes
     a block of positions has the op turn a block where it lies, the same
-    numbers with no view of half heads (see the op)."""
+    numbers with no view of half heads (see the op).  `sections` (three
+    pair counts that add up to the rotated pairs) with `positions` [3,
+    batch, seq]: a token's temporal, height and width positions, pair i
+    turned by the component its section names."""
     helper = LayerHelper("rope", **kwargs)
     out = helper.create_tmp_variable(input.dtype)
     attrs = {"num_heads": int(num_heads), "theta": float(theta)}
@@ -792,6 +801,8 @@ def rope(input, positions, num_heads, theta=10000.0, inv_freq=None,
         attrs["rotary_dim"] = int(rotary_dim)
     if full_width:
         attrs["full_width"] = True
+    if sections:
+        attrs["sections"] = [int(n) for n in sections]
     helper.append_op(type="rope",
                      inputs={"X": [input], "Positions": [positions]},
                      outputs={"Out": [out]}, attrs=attrs)

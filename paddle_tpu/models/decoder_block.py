@@ -164,7 +164,9 @@ def share_feed_forward(u, block, dense, d_ff, d_expert, n_experts, held,
     times `routed_scale`) that holds the experts `held` = (first,
     count) of those its router scores; `routing` is {"top_w",
     "top_idx", "counts": the router's Variables, "moe_in": u, "moe_out":
-    the held experts' part}."""
+    the held experts' part}.  A `block` that names no `shared_in` has no
+    shared expert (Keye-VL-2.0's: softmax experts alone), and F(u) is
+    the held experts' part."""
     if dense:
         return gated_feed_forward(u, d_ff, {"w_in": block["ffn_in"],
                                             "w_out": block["ffn_out"]}), None
@@ -177,16 +179,18 @@ def share_feed_forward(u, block, dense, d_ff, d_expert, n_experts, held,
         bias_attr=ParamAttr(name=block["router_bias"])
         if router_bias else None,
         n_group=n_group, topk_group=topk_group)
-    shared = gated_feed_forward(
-        u, d_expert, {"w_in": block["shared_in"],
-                      "w_out": block["shared_out"]})
-    if shared_gate is not None:
-        # named: the ops' instances in a trace start with it
-        shared = fluid.layers.elementwise_mul(
-            shared, fluid.layers.sigmoid(linear(u, 1, shared_gate),
-                                         name="shared_gate"),
-            name="shared_gate")
-    f = shared + m
+    f = m
+    if "shared_in" in block:
+        shared = gated_feed_forward(
+            u, d_expert, {"w_in": block["shared_in"],
+                          "w_out": block["shared_out"]})
+        if shared_gate is not None:
+            # named: the ops' instances in a trace start with it
+            shared = fluid.layers.elementwise_mul(
+                shared, fluid.layers.sigmoid(linear(u, 1, shared_gate),
+                                             name="shared_gate"),
+                name="shared_gate")
+        f = shared + m
     return f, dict({key: routing[key]
                     for key in ("top_w", "top_idx", "counts")},
                    moe_in=u, moe_out=m)

@@ -43,6 +43,7 @@ __all__ = ["on_executor_run", "on_jit_trace",
            "on_mla_decode_lowering",
            "on_mla_index_select_lowering", "on_cached_attention_lowering",
            "on_window_attention_lowering", "on_flash_window_lowering",
+           "on_sparse_attention_lowering", "on_sectioned_rope_lowering",
            "on_moe_share_bwd_lowering", "on_moe_share_compact_lowering",
            "on_prefill_lowering", "on_ssd_lowering",
            "on_causal_conv1d_lowering", "on_causal_conv1d_tail_lowering",
@@ -274,6 +275,15 @@ def on_cached_attention_lowering(block):
                    labelnames=("block",)).labels(block=block).inc()
 
 
+def _kv_cache_slots(kind, slots):
+    _reg().counter("kv_cache_slots_total",
+                   "slots a row's key/value caches hold in the lowered "
+                   "cached_attention ops, by the kind of cache (a "
+                   "window's ring, the full extent, or the full extent "
+                   "under a chosen set)",
+                   labelnames=("kind",)).labels(kind=kind).inc(slots)
+
+
 def on_window_attention_lowering(kind, kv_heads, window, path, block_k,
                                  slots, block):
     """A `cached_attention` op (ops/attention.py) was traced into a
@@ -296,10 +306,43 @@ def on_window_attention_lowering(kind, kv_heads, window, path, block_k,
                                "path", "block_k")) \
           .labels(kind=kind, kv_heads=kv_heads, window=window, block=block,
                   path=path, block_k=block_k).inc()
-    _reg().counter("kv_cache_slots_total",
-                   "slots a row's key/value caches hold in the lowered "
-                   "cached_attention ops, by the kind of cache",
-                   labelnames=("kind",)).labels(kind=kind).inc(slots)
+    _kv_cache_slots(kind, slots)
+
+
+def on_sparse_attention_lowering(kv_heads, top_k, slots, path, block_k):
+    """A `cached_attention` op (ops/attention.py) was traced into a
+    program over a chosen set (Selected and Live: a step
+    over whole-extent caches of `slots` slots a row that gathers `top_k`
+    of them for all `kv_heads` key/value heads): which way it takes over
+    the gathered slots ("kernel": kernels/gqa_decode.py in blocks of
+    `block_k`; "plain": scores under a mask, `block_k` 0).  One count
+    per op instance a lowered program holds; the caches' slots go to
+    `kv_cache_slots_total` under the kind "sparse"."""
+    _reg().counter("sparse_attention_lowerings_total",
+                   "key/value-cached attention ops over a chosen set "
+                   "lowered, by key/value heads, slots chosen, the cache's "
+                   "extent, path (the kernel over the gathered slots, or "
+                   "the plain products) and the kernel's block of slots",
+                   labelnames=("kv_heads", "top_k", "slots", "path",
+                               "block_k")) \
+          .labels(kv_heads=kv_heads, top_k=top_k, slots=slots, path=path,
+                  block_k=block_k).inc()
+    _kv_cache_slots("sparse", slots)
+
+
+def on_sectioned_rope_lowering(heads, sections, block):
+    """A `rope` op (ops/attention.py) with `sections` was traced into a
+    program: three positions a token, the pairs of a head split between
+    them `sections[0]` : `sections[1]` : `sections[2]`, over `block`
+    positions of a row.  One count per op instance a lowered program
+    holds."""
+    _reg().counter("sectioned_rope_lowerings_total",
+                   "rotary ops with three-part positions lowered, by "
+                   "heads, the pairs a component turns and the positions "
+                   "of a row one application takes",
+                   labelnames=("heads", "sections", "block")) \
+          .labels(heads=heads, sections="-".join(map(str, sections)),
+                  block=block).inc()
 
 
 def on_flash_window_lowering(kernel, window, block_q, block_k):

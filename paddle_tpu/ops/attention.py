@@ -256,7 +256,17 @@ def rope(ctx, ins, attrs):
     fill a quarter of the TPU's 128 lanes: at 256 x 16 tokens of 128
     heads of 64 the views cost 5.9 ms an op on the v5e where the block
     of values is 67 MB (PERF.md section 6, PR 53).  One position is
-    turned as it was."""
+    turned as it was.
+
+    `sections` (a list of three pair counts that add up to the rotated
+    pairs: Qwen2-VL's multimodal rotary positions, arXiv:2409.12191,
+    `mrope_section`) gives a token three positions, Positions [3, batch,
+    seq] (temporal, height, width): pair i is turned by the component
+    its section names, the first `sections[0]` pairs by the temporal
+    one, the next `sections[1]` by the height, the rest by the width, at
+    the rate theta^(-2i / head_dim) of its own index i.  A text token
+    carries one position three times and is turned as without
+    `sections`."""
     x = ins["X"][0]
     pos = ins["Positions"][0]
     num_heads = int(attrs["num_heads"])
@@ -278,7 +288,21 @@ def rope(ctx, ins, attrs):
         inv_freq = jnp.asarray(attrs["inv_freq"], jnp.float32)
     else:
         inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    angles = pos.reshape(b, t, 1, 1).astype(jnp.float32) * inv_freq
+    sections = [int(n) for n in attrs.get("sections") or ()]
+    if sections:
+        if len(sections) != 3 or sum(sections) != half \
+                or pos.shape != (3, b, t):
+            raise ValueError(
+                "rope: sections %s over %d pairs with Positions %s: three "
+                "counts that add up to the pairs, and Positions [3, %d, %d]"
+                % (sections, half, pos.shape, b, t))
+        telemetry.on_sectioned_rope_lowering(num_heads, sections, t)
+        # [b, t, pairs]: pair i reads the component of its section
+        pos = jnp.moveaxis(pos, 0, -1).astype(jnp.float32)[
+            ..., np.repeat(np.arange(3), sections)]
+        angles = pos.reshape(b, t, 1, half) * inv_freq
+    else:
+        angles = pos.reshape(b, t, 1, 1).astype(jnp.float32) * inv_freq
     cos, sin = jnp.cos(angles), jnp.sin(angles)
     if attrs.get("full_width") and t > 1:
         return {"Out": [_rope_full_width(x, cos, sin, num_heads, rotary)]}
@@ -344,7 +368,8 @@ def cached_attention_op(ctx, ins, attrs):
     single steps leave there.
 
     Scopes: `kv_write` the caches' update, `attn_window` or `attn_full`
-    everything between the caches and Out.  Over heads a multiple of 128
+    (`attn_sparse` over a chosen set, below) everything between the
+    caches and Out.  Over heads a multiple of 128
     wide (128; 256, two lane blocks a head) and a multiple of 128 slots
     the live slots alone are walked
     (kernels/gqa_decode.py: operands in Q's type, float32 sums and
@@ -364,9 +389,22 @@ def cached_attention_op(ctx, ins, attrs):
     bfloat16, the probabilities among them; a one-row product never
     reaches the MXU and is exact anyway), the mask and the softmax are
     float32.
+
+    With Selected int32 [batch, top_k] and Live int32 [batch]
+    (`mla_index_select`'s two) a step attends a chosen set and not every
+    live slot (whole-extent caches, `window` 0, T = 1; anything else
+    raises): the step's slot is written, the slots Selected names are
+    gathered from both caches, one set for every key/value head
+    (`kv_gather`: two [batch, kv_heads, top_k, head_dim] copies), and
+    the group's queries attend the first Live of a row's top_k entries
+    (`attn_sparse`): through the same kernel over the gathered slots,
+    `Live - 1` its last live slot, or the plain path under the mask
+    entry < Live.  A chosen set is a set: the softmax does not care for
+    its order.
     """
     q, k_new, v_new = ins["Q"][0], ins["KNew"][0], ins["VNew"][0]
     k_cache, v_cache = ins["KCache"][0], ins["VCache"][0]
+    selected = (ins.get("Selected") or [None])[0]
     # Position may be [1] or per-row [batch] (rows advance in lockstep;
     # a per-row vector is what beam expansion produces)
     pos = jnp.reshape(ins["Position"][0], (-1,))[0].astype(jnp.int32)
@@ -383,9 +421,19 @@ def cached_attention_op(ctx, ins, attrs):
             "caches %s, window %d: the heads do not group, or the cache "
             "is not those heads' or not the window's ring"
             % (num_heads, kv_heads, k_cache.shape, window))
+    if selected is not None and (window or block != 1
+                                 or not ins.get("Live")):
+        raise ValueError(
+            "cached_attention: Selected with window %d over a block of %d "
+            "positions%s: a chosen set is one position's over whole-extent "
+            "caches, and comes with Live"
+            % (window, block, "" if ins.get("Live") else ", without Live"))
     group = num_heads // kv_heads
-    kind = "window" if window else "full"
+    kind = "window" if window else "full" if selected is None else "sparse"
     ring_block = window > 0 and block > 1
+    # the slots a query's products run over: a chosen set's, else the
+    # cache's own
+    attended = extent if selected is None else selected.shape[-1]
 
     # [B, T, H * Dh] -> [B, H, T, Dh]: kernels/flash_attention.py has
     # the same two lines behind an import of Pallas, which a decoder
@@ -409,13 +457,22 @@ def cached_attention_op(ctx, ins, attrs):
             head_dim != 64 or not window
             and k_cache.dtype == v_cache.dtype == q.dtype):
         from ..kernels import gqa_decode
-        block_k = gqa_decode.choose_block(extent, group * block,
+        block_k = gqa_decode.choose_block(attended, group * block,
                                           q.dtype.itemsize, head_dim)
+    # a chosen set's gathered slots lie slots-major: the 64-wide kernel
+    # reads slots-minor caches, which a gather would have to turn
+    if selected is not None and head_dim == 64:
+        block_k = 0
     writes = block_k and head_dim == 64
     telemetry.on_cached_attention_lowering(block)
-    telemetry.on_window_attention_lowering(
-        kind, kv_heads, window, "kernel" if block_k else "plain", block_k,
-        extent, block)
+    if selected is None:
+        telemetry.on_window_attention_lowering(
+            kind, kv_heads, window, "kernel" if block_k else "plain",
+            block_k, extent, block)
+    else:
+        telemetry.on_sparse_attention_lowering(
+            kv_heads, attended, extent, "kernel" if block_k else "plain",
+            block_k)
 
     with jax.named_scope("kv_write"):
         before = k_cache, v_cache
@@ -433,19 +490,30 @@ def cached_attention_op(ctx, ins, attrs):
             v_cache = jax.lax.dynamic_update_slice_in_dim(
                 v_cache, vh.astype(v_cache.dtype), at, axis=2)
 
+    if selected is not None:
+        with jax.named_scope("kv_gather"):
+            at = selected[:, None, :, None].astype(jnp.int32)
+            k_live, v_live = (jnp.take_along_axis(cache, at, axis=2)
+                              for cache in (k_cache, v_cache))
+        live = jnp.reshape(ins["Live"][0], (-1,))[0].astype(jnp.int32)
+    else:
+        k_live, v_live = k_cache, v_cache
+
     with jax.named_scope("attn_" + kind):
-        # the last live slot: of a ring, all of it once it has wrapped
-        last = jnp.minimum(pos, window - 1) if window else pos
+        # the last live slot: of a ring, all of it once it has wrapped;
+        # of a chosen set, the last of its live entries
+        last = live - 1 if selected is not None \
+            else jnp.minimum(pos, window - 1) if window else pos
         if block_k:
             # a cache in a narrower type than the products' is read up
             out = gqa_decode.gqa_decode(
                 qh.reshape(rows, kv_heads, group * block, head_dim),
-                k_cache.astype(q.dtype), v_cache.astype(q.dtype), last,
+                k_live.astype(q.dtype), v_live.astype(q.dtype), last,
                 sm_scale, window, block_k, block)
         else:
             keys, values, valid = _ring_before_a_block(
                 before, (kh, vh), pos) if ring_block \
-                else (k_cache, v_cache, None)
+                else (k_live, v_live, None)
             if group > 1:   # [B, KV, G, T, Dh]: a group beside its head
                 qh = qh.reshape(rows, kv_heads, group, block, head_dim)
             highest = jax.lax.Precision.HIGHEST
@@ -453,7 +521,7 @@ def cached_attention_op(ctx, ins, attrs):
                            keys.astype(jnp.float32),
                            precision=highest) * sm_scale
             if valid is None:
-                valid = jnp.arange(extent)[None, :] \
+                valid = jnp.arange(attended)[None, :] \
                     <= last + jnp.arange(block)[:, None]
             s = jnp.where(valid[(None,) * (s.ndim - 2)], s, -1e30)
             p = jax.nn.softmax(s, axis=-1)
