@@ -146,8 +146,10 @@ def mla_index_select(q, w, k_new, cache, position, num_heads, top_k,
     [batch, positions, dim] the chooser's own cache, `position` int [1]
     or [batch].  A slot s <= position scores scale * sum_j w_j relu(q_j
     . k_s).  Returns (selected int32 [batch, top_k], live int32 [batch],
-    cache_out): hand the first two to `mla_cached_attention`, thread
-    `cache_out` back as decode state."""
+    cache_out): `selected` is the `top_k` best-scoring slots as a set,
+    in ascending slot order, of which the first `live` are slots to
+    attend; hand the two to `mla_cached_attention` or
+    `cached_attention`, thread `cache_out` back as decode state."""
     helper = LayerHelper("mla_index_select", name=name)
     selected = helper.create_tmp_variable("int32", stop_gradient=True)
     live = helper.create_tmp_variable("int32", stop_gradient=True)

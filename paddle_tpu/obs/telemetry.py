@@ -239,16 +239,21 @@ def on_mla_decode_lowering(path, block_k, positions=1):
           .labels(path=path, block_k=block_k, positions=positions).inc()
 
 
-def on_mla_index_select_lowering(heads, dim, top_k, cache_dtype):
+def on_mla_index_select_lowering(heads, dim, top_k, cache_dtype, select):
     """A decode step of a chooser of cache slots (`mla_index_select`,
     ops/attention.py) was traced into a program: one count per op
-    instance a lowered program holds."""
+    instance a lowered program holds.  `select` is how the op picks its
+    slots from the scores: "count" (kernels/topk_select.py: the
+    threshold by counting, the slots in slot order) or "sort"
+    (`lax.top_k`, which the op no longer takes for any shape)."""
     _reg().counter("mla_index_select_lowerings_total",
                    "index-select decode steps lowered, by index heads, "
-                   "their width, the slots chosen and the key cache's type",
-                   labelnames=("heads", "dim", "top_k", "cache_dtype")) \
+                   "their width, the slots chosen, the key cache's type "
+                   "and the selection's form",
+                   labelnames=("heads", "dim", "top_k", "cache_dtype",
+                               "select")) \
           .labels(heads=heads, dim=dim, top_k=top_k,
-                  cache_dtype=str(cache_dtype)).inc()
+                  cache_dtype=str(cache_dtype), select=select).inc()
 
 
 def on_moe_grouped_router_lowering(experts, groups, kept, top_k):

@@ -599,16 +599,21 @@ def mla_index_select_op(ctx, ins, attrs):
 
     The products take their operands in Q's type (a cache in a narrower
     type is read up to it) and add up in float32; relu, the weighted sum
-    over the heads and the top-k are float32.  A slot past Position
-    scores -inf and is never among the live ones.  Scopes: `dsa_index`
-    holds the key's write and everything that makes the scores,
-    `dsa_select` the top-k.
+    over the heads and the selection are float32.  A slot past Position
+    scores -inf and is never among the live ones.  The selection is
+    `lax.top_k`'s set (of equal scores the lower slots) and orders
+    nothing: kernels/topk_select.py finds the top_k-th largest score by
+    counting and writes the chosen slots as they lie in the cache.
+    Scopes: `dsa_index` holds the key's write and everything that makes
+    the scores, `dsa_select` the selection.
     CacheOut is the cache with the slot written (a `ProgramDecoder`
-    state pair); Selected int32 [batch, top_k], best first; Live int32
-    [batch] = min(top_k, Position + 1): only the first Live entries of a
-    row are slots to attend, what follows them (where fewer slots are
-    live than were asked for) names slots that hold nothing and must be
-    masked.  No gradient, as `mla_cached_attention`."""
+    state pair); Selected int32 [batch, top_k], the chosen set in
+    ascending slot order; Live int32 [batch] = min(top_k, Position + 1):
+    only the first Live entries of a row are slots to attend (the live
+    slots have the lowest numbers, so they come first), what follows
+    them (where fewer slots are live than were asked for) names slots
+    that hold nothing and must be masked.  No gradient, as
+    `mla_cached_attention`."""
     q, w, k_new = ins["Q"][0], ins["W"][0], ins["KNew"][0]
     cache = ins["Cache"][0]
     pos = jnp.reshape(ins["Position"][0], (-1,))[0].astype(jnp.int32)
@@ -625,7 +630,8 @@ def mla_index_select_op(ctx, ins, attrs):
     if top_k > positions:
         raise ValueError("mla_index_select: top_k %d of a cache of %d "
                          "positions" % (top_k, positions))
-    telemetry.on_mla_index_select_lowering(heads, dim, top_k, cache.dtype)
+    telemetry.on_mla_index_select_lowering(heads, dim, top_k, cache.dtype,
+                                           "count")
     f32 = jnp.float32
 
     # everything that makes the [batch, positions] scores is one scope:
@@ -643,11 +649,14 @@ def mla_index_select_op(ctx, ins, attrs):
         if scale != 1.0:
             score = score * scale
         score = jnp.where(jnp.arange(positions) <= pos, score, -jnp.inf)
+    # the kernel takes the scores as `dsa_index` leaves them: a custom
+    # call is a fusion's boundary, and nothing written here can draw the
+    # scores' fusion under this scope
     with jax.named_scope("dsa_select"):
-        _, selected = jax.lax.top_k(score, top_k)
+        from ..kernels import topk_select
+        selected = topk_select.select_slots(score, top_k)
     live = jnp.full((batch,), jnp.minimum(top_k, pos + 1), jnp.int32)
-    return {"CacheOut": [cache], "Selected": [selected.astype(jnp.int32)],
-            "Live": [live]}
+    return {"CacheOut": [cache], "Selected": [selected], "Live": [live]}
 
 
 def _mla_infer_shape(block, op_desc):

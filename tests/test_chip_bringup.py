@@ -390,6 +390,36 @@ def test_the_grouped_decode_kernel_lowers_for_tpu(block, window, kernels):
         assert 'kernel_name = "%s"' % name in module
 
 
+@pytest.mark.parametrize("rows,slots,heads,dim,name", [
+    (8, 65536, 16, 64, "topk_select_s65536_k2048"),
+    (16, 16384, 64, 128, "topk_select_s16384_k2048")])
+def test_the_choosers_selection_lowers_one_kernel_for_tpu(rows, slots, heads,
+                                                          dim, name):
+    """`mla_index_select` at keye-turn-64k-ep8's and dsv32-turn-16k-ep16's
+    shapes (2048 of 65,536 or 16,384 bfloat16 index keys a row) lowered
+    for the TPU from this CPU host: the selection is one Mosaic kernel
+    named with the extent and the slots chosen, and the module holds no
+    sort and no top-k."""
+    from paddle_tpu.ops import registry
+
+    kernel = registry.get_op_info("mla_index_select").kernel
+    bf16 = jnp.bfloat16
+    ins = {"Q": [jax.ShapeDtypeStruct((rows, 1, heads * dim), bf16)],
+           "W": [jax.ShapeDtypeStruct((rows, 1, heads), bf16)],
+           "KNew": [jax.ShapeDtypeStruct((rows, 1, dim), bf16)],
+           "Cache": [jax.ShapeDtypeStruct((rows, slots, dim), bf16)],
+           "Position": [jax.ShapeDtypeStruct((rows,), jnp.int32)]}
+
+    def step(ins):
+        return kernel(None, ins, {"num_heads": heads, "top_k": 2048})
+
+    module = jax.export.export(jax.jit(step), platforms=["tpu"])(
+        ins).mlir_module()
+    assert module.count("tpu_custom_call") == 1
+    assert 'kernel_name = "%s"' % name in module
+    assert "stablehlo.sort" not in module and "top_k" not in module
+
+
 @pytest.mark.parametrize("block,kernels", [
     (1, ["gqa_write_r4", "gqa_decode_k512_h16"]), (128, [])])
 def test_the_narrow_decode_kernels_lower_for_tpu(block, kernels):

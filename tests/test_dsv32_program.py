@@ -312,9 +312,8 @@ def test_index_select_is_the_references_scores_and_set(pos):
         assert sorted(selected[row, :live]) == \
             np.flatnonzero(mask[row, 0]).tolist()
         assert selected[row, :live].max() <= pos
-        # best first
-        taken = np.asarray(scores)[row, 0, selected[row, :live]]
-        assert (np.diff(taken) <= 0).all()
+        # slot order: the live entries first, every entry once
+        assert (np.diff(selected[row]) > 0).all()
 
 
 def test_index_scores_add_up_in_float32():
@@ -340,8 +339,15 @@ def test_index_scores_add_up_in_float32():
                   if (B, T, ID) in [v.aval.shape for v in e.invars]]
     assert len(over_cache) == 1 and all(
         v.aval.dtype == jnp.bfloat16 for v in over_cache[0].invars)
-    top = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "top_k"]
-    assert [e.invars[0].aval.dtype for e in top] == [jnp.float32]
+    # the selection reads the float32 scores as they are: no `top_k`,
+    # the kernel's one operand
+    names = [e.primitive.name for e in jaxpr.jaxpr.eqns]
+    assert "top_k" not in names and "sort" not in names
+    picks = [e for e in jaxpr.jaxpr.eqns
+             if e.primitive.name in ("pjit", "jit")
+             and e.params["name"] == "_select"]
+    assert [[(v.aval.shape, v.aval.dtype) for v in e.invars]
+            for e in picks] == [[((8, 128), jnp.float32)]]
 
 
 def test_index_select_refuses_what_it_cannot_do():
@@ -851,7 +857,7 @@ def test_counters_say_what_was_lowered(built):
     # one count an op instance a traced step holds
     assert lowered[
         "mla_index_select_lowerings_total{cache_dtype=float32,dim=%d,"
-        "heads=%d,top_k=%d}" % (ID, IH, TOPK)] == L
+        "heads=%d,select=count,top_k=%d}" % (ID, IH, TOPK)] == L
     assert lowered[
         "mla_cached_attention_lowerings_total{cache_dtype=float32,"
         "heads=%d,latent=%d,rope=%d,selected=%d}"

@@ -446,7 +446,10 @@ def test_counters_say_what_was_lowered():
         return sum(v for k, v in counted.items() if k.startswith(prefix))
 
     assert total("sparse_attention_lowerings_total") == L
-    assert total("mla_index_select_lowerings_total") == L
+    assert total("mla_index_select_lowerings_total") == L == sum(
+        v for k, v in counted.items()
+        if k.startswith("mla_index_select_lowerings_total")
+        and "select=count" in k)
     # q and k a layer; the chooser's two rotations have one position
     assert total("sectioned_rope_lowerings_total") == 2 * L
     assert total("window_attention_lowerings_total") == 0
