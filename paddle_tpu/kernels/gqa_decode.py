@@ -80,6 +80,42 @@ overlap of its blocks' copies with the folds, 0.22 ms a call.)  Where
 the time goes, and the sweep these sizes came from:
 `scripts/gqa_decode_bench.py`, PERF.md section 5.
 
+A chosen set (`cached_attention` with `Selected`: 2048 of 65,536 slots
+a query, one set for every key/value head).
+
+    gqa_decode_chosen(q[B, KV, G, D], k_chosen[B, top_k, KV, D],
+                      v_chosen[B, top_k, KV, D], live, sm_scale)
+        -> [B, KV, G, D]
+
+The chosen slots are fetched by the op's gather, not by this file: a
+fetch of scattered slots is bound by its copies' count, about 12 ns a
+copy descriptor on a v5e whether the compiler's gather starts them
+(10-13 ns a row of 1 KB) or a Mosaic kernel (`pltpu.make_async_copy`:
+12.2 ns a copy of one to four 512-byte rows, no queue beside it, 5.6 ns
+a loop step more; `scripts/gqa_decode_bench.py chosen`, PERF.md section
+6, PR 60), not by their bytes, so a kernel that fetched the slots where
+they lie would start as many copies as the gather and run no faster (a
+prototype read 0.85-0.89 ms a layer at keye-turn-64k-ep8's shape beside
+the 0.46 of gather and kernel).  What this entry takes away is the
+turn between the two: the compiler carries such caches heads-minor
+through a decoder's scan (a slot's KV x D values together, 1 KB: one
+copy a slot), so a gather of whole slots, `[B, top_k, KV, D]`, is what
+it writes anyway, and a kernel that wants the heads apart, `[B, KV,
+top_k, D]`, costs a transposing pass over both copies a layer.  Here
+the copies are read as they lie: a grid step is `chunk` entries of one
+row of the batch, every head of them, `[chunk * KV, D]` with row e * KV
++ h head h of entry e.  In bfloat16 a 32-bit word of a sublane row holds
+two heads of one entry, so the block is folded two heads at a time:
+every (KV / 2)-th word row (a strided load) is `[chunk * 2, D]`, column
+2 e + i head i of the pair, under the pair's 2 G queries, each attending
+its own head's columns; the fold is the one above (`_fold`).  In float32
+a word is a head and a fold takes one.  Entry i >= `live` is masked and
+its values zeroed; a chunk past the last live entry is neither fetched
+nor folded.  It takes (`choose_chunk`) 128-wide heads, operands of 16 or
+32 bits in q's type, a count of key/value heads that fills the words
+(even in bfloat16, or one), and a `top_k` that a chunk of 128 to 2048
+entries tiles.
+
 Which shapes it takes (`fits`): S a multiple of 128; heads a multiple of
 128 wide (the lanes: one lane block a head, or two at 256) with G * T rows a key/value head small enough that their scores
 over the smallest block of slots fit VMEM beside the operands
@@ -94,7 +130,8 @@ Lowered for the TPU these are Mosaic kernels named
 block's calls from a decode step's; `_d<head_dim>` after either where a
 head is wider than the lanes), `gqa_decode_w<window>` over a ring,
 and at 64 wide `gqa_decode_k<block_k>_h<heads>` (`_r<rows>` after it
-where rows share a step) and `gqa_write_r<rows>`; lowered for the CPU
+where rows share a step) and `gqa_write_r<rows>`, over a chosen set
+`gqa_decode_sel<top_k>_c<chunk>`; lowered for the CPU
 the same kernels run under the Pallas interpreter (tests), chosen by the
 platform of the lowering as the flash kernels are.  Each entry is under
 `jax.jit`: the layers of a program that hold the same instance share
@@ -113,6 +150,8 @@ NEG_INF = -1e30
 _LANES = 128
 # the blocks of slots the chooser tries, largest first
 _BLOCKS = (2048, 1024, 512, 256, 128)
+# the entries of a chosen set a grid step folds, largest first
+_CHUNKS = (2048, 1024, 512, 256, 128)
 # the narrower head the kernel takes, its block of slots, and the bytes
 # of each cache a grid step of it moves (section "64-wide heads")
 _NARROW = 64
@@ -166,6 +205,32 @@ def choose_block(slots, rows=8, itemsize=2, head_dim=_LANES):
     return 0
 
 
+def _heads_a_word(kv_heads, itemsize):
+    """The heads of one chosen slot that a 32-bit word of a sublane row
+    holds where the slot's heads lie side by side: two of bfloat16, one
+    of float32; one head lies as a cache does."""
+    return 1 if kv_heads == 1 else 4 // itemsize
+
+
+def choose_chunk(top_k, kv_heads, group, itemsize=2, head_dim=_LANES):
+    """The entries of a chosen set a grid step of `gqa_decode_chosen`
+    folds: the largest of `_CHUNKS` that tiles `top_k` and whose block of
+    every head's keys and values fits VMEM beside the `group` queries a
+    head, or 0 where the kernel takes no such set: heads of another
+    width than the lanes, operands that are no 16- or 32-bit floats, or
+    key/value heads that do not fill the 32-bit words of a sublane row
+    (an odd count of bfloat16 heads; one head is a set that lies as a
+    cache does)."""
+    if head_dim != _LANES or itemsize not in (2, 4) \
+            or kv_heads % _heads_a_word(kv_heads, itemsize):
+        return 0
+    for chunk in _CHUNKS:
+        if top_k % chunk == 0 and _vmem_bytes(
+                kv_heads * group, chunk * kv_heads, itemsize) <= _VMEM_BYTES:
+            return chunk
+    return 0
+
+
 def choose_step(batch, kv_heads, block_k, itemsize=2):
     """(rows of the batch, key/value heads) a grid step of the 64-wide
     kernel takes over blocks of `block_k` slots: as many of a row's
@@ -202,6 +267,33 @@ def _top(last, positions):
     return last if positions == 1 else last + (positions - 1)
 
 
+def _fold(q, keys, values, m_ref, l_ref, acc_ref, sm_scale, attended=None,
+          held=None):
+    """`keys` and `values` [entries, D] folded into the running maximum
+    `m`, sum `l` [rows, 1] and accumulator [rows, D] of the queries `q`
+    [rows, D]: the flash kernels' online softmax, scores in the
+    operands' type with float32 sums, the probabilities rounded to the
+    operands' type.  `attended` (bool, over [rows, entries]) says which
+    scores count, `held` (bool, over [entries, D]) which values are not
+    zeroed, so that nothing a dead entry holds reaches a sum; None:
+    all."""
+    s = lax.dot_general(q, keys, (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32) * sm_scale
+    if attended is not None:
+        s = jnp.where(attended, s, NEG_INF)
+    if held is not None:
+        values = jnp.where(held, values, jnp.zeros_like(values))
+    m_prev = m_ref[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new)
+    l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=-1, keepdims=True)
+    acc_ref[...] = alpha * acc_ref[...] + lax.dot_general(
+        p.astype(values.dtype), values, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    m_ref[...] = m_new
+
+
 def _kernel(last_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
             sm_scale, bk, positions):
     """One grid step: block `j - dead` of one key/value head folded into
@@ -220,29 +312,19 @@ def _kernel(last_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     def fold(masked):
-        keys, values = k_ref[0, 0], v_ref[0, 0]
-        s = lax.dot_general(
-            q_ref[0, 0], keys, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
+        attended = held = None
         if masked:
             first = k * bk
             limit = last
             if positions > 1:   # row g * T + t is position t
                 limit = last + lax.rem(lax.broadcasted_iota(
-                    jnp.int32, (s.shape[0], 1), 0), positions)
-            live = first + lax.broadcasted_iota(jnp.int32, (1, bk), 1) <= limit
-            s = jnp.where(live, s, NEG_INF)
-            live = first + lax.broadcasted_iota(jnp.int32, (bk, 1), 0) <= top
-            values = jnp.where(live, values, jnp.zeros_like(values))
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[...] = alpha * acc_scr[...] + lax.dot_general(
-            p.astype(values.dtype), values, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[...] = m_new
+                    jnp.int32, (q_ref.shape[2], 1), 0), positions)
+            attended = first + lax.broadcasted_iota(
+                jnp.int32, (1, bk), 1) <= limit
+            held = first + lax.broadcasted_iota(
+                jnp.int32, (bk, 1), 0) <= top
+        _fold(q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], m_scr, l_scr, acc_scr,
+              sm_scale, attended, held)
 
     # the first block some position of the block does not attend whole
     # (block 0 holds slot 0, which every position attends: no row's first
@@ -460,6 +542,99 @@ def _write_call(k_new, v_new, k_cache, v_cache, at, *, rows, interpret):
     )(at, k_new, v_new, k_cache, v_cache)
 
 
+def _chosen_kernel(live_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
+                   *, sm_scale, chunk, kv_heads, pack):
+    """One grid step: `chunk` entries of a row's chosen set, every
+    key/value head of them, [chunk * KV, D] as the gather left them (row
+    e * KV + h is head h of entry e), folded into the running maximum,
+    sum [KV * G, 1] and accumulator [KV * G, D] of all the row's
+    queries.  A 32-bit word of the block's sublanes holds `pack` heads of
+    one entry (two bfloat16 heads, one float32 head), so the block is
+    read `pack` heads at a time: every (KV / pack)-th word row, which is
+    [chunk * pack, D] in the operands' type, column e * pack + i head i
+    of those of entry e, under the `pack * G` queries that read one of
+    them, each attending its own head's columns."""
+    j = pl.program_id(1)
+    live = live_ref[0]
+    last_chunk = (live - 1) // chunk
+    group = q_ref.shape[1] // kv_heads
+    rows, sets = pack * group, kv_heads // pack
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    def fold(masked):
+        width = chunk * pack
+        attended = held = None
+        col = lax.broadcasted_iota(jnp.int32, (1, width), 1)
+        if pack > 1:    # query row r reads head r // G of the word's
+            attended = lax.rem(col, pack) == lax.div(
+                lax.broadcasted_iota(jnp.int32, (rows, 1), 0), group)
+        if masked:      # entry >= Live is dead, whatever it holds
+            alive = j * chunk + lax.div(col, pack) < live
+            attended = alive if attended is None else attended & alive
+            held = j * chunk + lax.div(lax.broadcasted_iota(
+                jnp.int32, (width, 1), 0), pack) < live
+        for g in range(sets):
+            if sets == 1 and pack == 1:
+                keys, values = k_ref[0], v_ref[0]
+            else:
+                keys, values = (
+                    pltpu.bitcast(ref.bitcast(jnp.uint32)[
+                        0, pl.ds(g, chunk, stride=sets), :], ref.dtype)
+                    for ref in (k_ref, v_ref))
+            at = pl.ds(g * rows, rows)
+            _fold(q_ref[0, at], keys, values, m_scr.at[at], l_scr.at[at],
+                  acc_scr.at[at], sm_scale, attended, held)
+
+    @pl.when(j < last_chunk)
+    def _whole():
+        fold(masked=False)
+
+    @pl.when(j == last_chunk)
+    def _crossed():
+        fold(masked=True)
+        o_ref[0] = (acc_scr[...] / l_scr[...]).astype(o_ref.dtype)
+
+
+def _chosen_call(q, k_chosen, v_chosen, live, *, sm_scale, chunk, kv_heads,
+                 name, interpret):
+    """q [B, KV * G, D] over chosen slots [B, top_k * KV, D]."""
+    batch, rows, dim = q.shape
+
+    def entries(b, j, live):
+        # a chunk past the last live entry names that one: fetched once
+        return b, jnp.minimum(j, (live[0] - 1) // chunk), 0
+
+    def row(b, j, live):
+        return b, 0, 0
+
+    block = pl.BlockSpec((1, chunk * kv_heads, dim), entries)
+    return pl.pallas_call(
+        functools.partial(
+            _chosen_kernel, sm_scale=sm_scale, chunk=chunk,
+            kv_heads=kv_heads,
+            pack=_heads_a_word(kv_heads, q.dtype.itemsize)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(batch, k_chosen.shape[1] // (chunk * kv_heads)),
+            in_specs=[pl.BlockSpec((1, rows, dim), row), block, block],
+            out_specs=pl.BlockSpec((1, rows, dim), row),
+            scratch_shapes=[pltpu.VMEM((rows, 1), jnp.float32),
+                            pltpu.VMEM((rows, 1), jnp.float32),
+                            pltpu.VMEM((rows, dim), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name=name,
+    )(live, q, k_chosen, v_chosen)
+
+
 def _by_platform(call, *operands):
     """`call` lowered for the platform of the lowering: Mosaic on the
     TPU, the Pallas interpreter on the CPU."""
@@ -488,6 +663,13 @@ def _narrow(q, k_cache, v_cache, last, **static):
     return _by_platform(functools.partial(_narrow_call, **static), q,
                         jnp.swapaxes(k_cache, 2, 3),
                         jnp.swapaxes(v_cache, 2, 3), last)
+
+
+@functools.partial(jax.jit, static_argnames=("sm_scale", "chunk", "kv_heads",
+                                             "name"))
+def _chosen(q, k_chosen, v_chosen, live, **static):
+    return _by_platform(functools.partial(_chosen_call, **static), q,
+                        k_chosen, v_chosen, live)
 
 
 @functools.partial(jax.jit, static_argnames=("rows",))
@@ -562,3 +744,37 @@ def gqa_decode(q, k_cache, v_cache, last, sm_scale, window=0, block_k=None,
         name += "_d%d" % q.shape[3]
     return _wide(q, k_cache, v_cache, last, sm_scale=float(sm_scale), bk=bk,
                  positions=positions, name=name)
+
+
+def gqa_decode_chosen(q, k_chosen, v_chosen, live, sm_scale, chunk=None):
+    """The attended values of one decode step over a chosen set,
+    [batch, kv_heads, group, D] in q's type: `k_chosen`, `v_chosen`
+    [batch, top_k, kv_heads, D] hold the chosen slots as a gather of
+    whole slots leaves them, a slot's heads side by side, and the
+    queries attend the first `live` (an int32 scalar, at least 1) of a
+    row's entries: see the module's docstring.  `chunk`, the entries a
+    grid step folds, is chosen from the shapes unless given (tests,
+    sweeps)."""
+    batch, kv_heads, group, dim = q.shape
+    top_k = k_chosen.shape[1]
+    chunk = chunk or choose_chunk(top_k, kv_heads, group, q.dtype.itemsize,
+                                  dim)
+    if k_chosen.shape != (batch, top_k, kv_heads, dim) \
+            or v_chosen.shape != k_chosen.shape \
+            or k_chosen.dtype != q.dtype or v_chosen.dtype != q.dtype \
+            or not chunk or top_k % chunk \
+            or not choose_chunk(chunk, kv_heads, group, q.dtype.itemsize,
+                                dim):     # a given chunk: tiled and held too
+        raise ValueError(
+            "gqa_decode_chosen: queries %s %s over chosen slots %s %s and "
+            "%s %s (chunk %s) are no step the kernel takes"
+            % (q.shape, q.dtype, k_chosen.shape, k_chosen.dtype,
+               v_chosen.shape, v_chosen.dtype, chunk))
+    out = _chosen(
+        q.reshape(batch, kv_heads * group, dim),
+        k_chosen.reshape(batch, top_k * kv_heads, dim),
+        v_chosen.reshape(batch, top_k * kv_heads, dim),
+        jnp.reshape(live, (1,)).astype(jnp.int32), sm_scale=float(sm_scale),
+        chunk=chunk, kv_heads=kv_heads,
+        name="gqa_decode_sel%d_c%d" % (top_k, chunk))
+    return out.reshape(q.shape)

@@ -319,15 +319,21 @@ def on_sparse_attention_lowering(kv_heads, top_k, slots, path, block_k):
     program over a chosen set (Selected and Live: a step
     over whole-extent caches of `slots` slots a row that gathers `top_k`
     of them for all `kv_heads` key/value heads): which way it takes over
-    the gathered slots ("kernel": kernels/gqa_decode.py in blocks of
-    `block_k`; "plain": scores under a mask, `block_k` 0).  One count
+    the gathered slots.  `path` has two values: "kernel", whole slots
+    gathered with their heads side by side and
+    kernels/gqa_decode.py `gqa_decode_chosen` over the copies as they
+    lie, `block_k` the entries a grid step folds; "plain", a copy a
+    cache with the heads apart and scores under a mask, `block_k` 0.
+    (No third: a kernel that fetched the chosen slots itself was
+    measured and is not built, PERF.md section 6, PR 60.)  One count
     per op instance a lowered program holds; the caches' slots go to
     `kv_cache_slots_total` under the kind "sparse"."""
     _reg().counter("sparse_attention_lowerings_total",
                    "key/value-cached attention ops over a chosen set "
                    "lowered, by key/value heads, slots chosen, the cache's "
                    "extent, path (the kernel over the gathered slots, or "
-                   "the plain products) and the kernel's block of slots",
+                   "the plain products) and the entries a grid step of "
+                   "the kernel folds",
                    labelnames=("kv_heads", "top_k", "slots", "path",
                                "block_k")) \
           .labels(kv_heads=kv_heads, top_k=top_k, slots=slots, path=path,

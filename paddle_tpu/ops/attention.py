@@ -395,12 +395,19 @@ def cached_attention_op(ctx, ins, attrs):
     live slot (whole-extent caches, `window` 0, T = 1; anything else
     raises): the step's slot is written, the slots Selected names are
     gathered from both caches, one set for every key/value head
-    (`kv_gather`: two [batch, kv_heads, top_k, head_dim] copies), and
-    the group's queries attend the first Live of a row's top_k entries
-    (`attn_sparse`): through the same kernel over the gathered slots,
-    `Live - 1` its last live slot, or the plain path under the mask
-    entry < Live.  A chosen set is a set: the softmax does not care for
-    its order.
+    (`kv_gather`; an entry is clipped into the extent), and the group's
+    queries attend the first Live of a row's top_k entries
+    (`attn_sparse`).  Over 128-wide heads whose count fills the 32-bit
+    words of a sublane row (even, or one) and a top_k that a chunk of
+    128 to 2048 entries tiles, whole slots are gathered, a slot's heads
+    side by side ([batch, top_k, kv_heads, head_dim]: where the caches
+    lie heads-minor, as a decoder's scan carries them, that is one fetch
+    a slot and what the gather writes anyway), and
+    `kernels/gqa_decode.py gqa_decode_chosen` reads the copies as they
+    lie: no pass turns or fills them in between.  Every other set takes
+    two [batch, kv_heads, top_k, head_dim] copies and the plain path
+    under the mask entry < Live.  A chosen set is a set: the softmax
+    does not care for its order.
     """
     q, k_new, v_new = ins["Q"][0], ins["KNew"][0], ins["VNew"][0]
     k_cache, v_cache = ins["KCache"][0], ins["VCache"][0]
@@ -453,16 +460,18 @@ def cached_attention_op(ctx, ins, attrs):
     # (the kernel's widths: 64, and the multiples of the 128 lanes: 128,
     # and 256 as two lane blocks a head)
     block_k = 0
-    if (head_dim == 64 or head_dim % 128 == 0) and not ring_block and (
+    if selected is not None:
+        # a chosen set: the kernel over the slots as their gather leaves
+        # them, where it takes the heads' width and count
+        from ..kernels import gqa_decode
+        block_k = gqa_decode.choose_chunk(
+            attended, kv_heads, group, q.dtype.itemsize, head_dim)
+    elif (head_dim == 64 or head_dim % 128 == 0) and not ring_block and (
             head_dim != 64 or not window
             and k_cache.dtype == v_cache.dtype == q.dtype):
         from ..kernels import gqa_decode
         block_k = gqa_decode.choose_block(attended, group * block,
                                           q.dtype.itemsize, head_dim)
-    # a chosen set's gathered slots lie slots-major: the 64-wide kernel
-    # reads slots-minor caches, which a gather would have to turn
-    if selected is not None and head_dim == 64:
-        block_k = 0
     writes = block_k and head_dim == 64
     telemetry.on_cached_attention_lowering(block)
     if selected is None:
@@ -491,11 +500,26 @@ def cached_attention_op(ctx, ins, attrs):
                 v_cache, vh.astype(v_cache.dtype), at, axis=2)
 
     if selected is not None:
-        with jax.named_scope("kv_gather"):
-            at = selected[:, None, :, None].astype(jnp.int32)
-            k_live, v_live = (jnp.take_along_axis(cache, at, axis=2)
-                              for cache in (k_cache, v_cache))
         live = jnp.reshape(ins["Live"][0], (-1,))[0].astype(jnp.int32)
+        with jax.named_scope("kv_gather"):
+            # an entry is clipped into the extent, not filled in
+            # afterwards (a pass over both copies): a dead one may name
+            # anything, and what it fetches is masked
+            if block_k:
+                # whole slots, a slot's heads side by side: where the
+                # caches lie heads-minor (a decoder's scan carries them
+                # so) the turn is no copy, a slot is one fetch, and the
+                # kernel reads the copies as they are written
+                at = selected[:, :, None, None].astype(jnp.int32)
+                k_live, v_live = (
+                    jnp.take_along_axis(jnp.swapaxes(cache, 1, 2), at,
+                                        axis=1, mode="clip")
+                    for cache in (k_cache, v_cache))
+            else:
+                at = selected[:, None, :, None].astype(jnp.int32)
+                k_live, v_live = (
+                    jnp.take_along_axis(cache, at, axis=2, mode="clip")
+                    for cache in (k_cache, v_cache))
     else:
         k_live, v_live = k_cache, v_cache
 
@@ -504,7 +528,13 @@ def cached_attention_op(ctx, ins, attrs):
         # of a chosen set, the last of its live entries
         last = live - 1 if selected is not None \
             else jnp.minimum(pos, window - 1) if window else pos
-        if block_k:
+        if block_k and selected is not None:
+            # copies of a cache in a narrower type are read up
+            out = gqa_decode.gqa_decode_chosen(
+                qh.reshape(rows, kv_heads, group, head_dim),
+                k_live.astype(q.dtype), v_live.astype(q.dtype), live,
+                sm_scale, block_k)
+        elif block_k:
             # a cache in a narrower type than the products' is read up
             out = gqa_decode.gqa_decode(
                 qh.reshape(rows, kv_heads, group * block, head_dim),

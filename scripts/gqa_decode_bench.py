@@ -9,7 +9,23 @@ a variant, all of them in `chiprun_out/gqa_decode_bench.jsonl`.  A
 `step` runs as a decoder's scan runs it: the caches carried from
 application to application, the step's slot written, then attended;
 `attend` is the attention kernel alone over caches that stay, `write`
-the slot's write alone."""
+the slot's write alone.
+
+`chosen` (PR 60; `python scripts/gqa_decode_bench.py chosen`) is the
+attention over a chosen set at keye-turn-64k-ep8's shape (8 rows, 4
+key/value heads of 128 under 32 query heads, 65,536-slot bfloat16
+caches, 2048 chosen in ascending order, all live), an application as a
+decoder's scan runs it: the caches carried, the step's slot written,
+the set gathered and attended.  `apart` is what the op did before PR 60
+(a gather a cache that leaves the heads apart, `[8, 4, 2048, 128]`, and
+`gqa_decode_k2048` over it, an entry out of the extent filled in by a
+pass over the copies), `whole` what it does now (whole slots, a slot's
+heads side by side, an entry clipped, and `gqa_decode_sel2048_c<chunk>`
+over the copies as they lie), by chunk; `copies` is what a kernel pays to fetch
+chosen slots itself, ns a copy by what one copy covers (one, two or all
+four heads of a slot's 32-bit word row; one cache or both; both of the
+queue's priorities): a Mosaic kernel that only starts the copies of 512
+entries a row and waits for them."""
 
 import json
 import os
@@ -18,9 +34,13 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 import numpy as np
 
 from paddle_tpu.kernels import gqa_decode
@@ -93,6 +113,143 @@ def slope(fn, *args, repeats=3):
     return (best[LONG] - best[SHORT]) / (LONG - SHORT) * 1e3
 
 
+# -- a chosen set at keye-turn-64k-ep8's shape --------------------------------
+
+C_ROWS, C_KV, C_GROUP, C_SLOTS, C_DIM, C_TOP_K = 8, 4, 8, 65536, 128, 2048
+C_SCALE = C_DIM ** -0.5
+C_ENTRIES = 512     # the entries a row of the `copies` kernel fetches
+
+
+def _moved(q):
+    """0, which the compiler cannot know: added to a loop's indices it
+    ties them to the carry, so that no fetch leaves the loop."""
+    return (jnp.sum(q.astype(jnp.float32)) > 1e30).astype(jnp.int32)
+
+
+def chosen_applications(form, chunk=None):
+    """fn(n, q, k, v, new, selected): n steps of the op's chosen-set path
+    as a scan carries it."""
+    def attend(q, k, v, selected):
+        live = jnp.int32(C_TOP_K)
+        if form == "apart":
+            at = selected[:, None, :, None]
+            k_live, v_live = (jnp.take_along_axis(c, at, axis=2)
+                              for c in (k, v))
+            return gqa_decode.gqa_decode(q, k_live, v_live, live - 1,
+                                         C_SCALE)
+        at = selected[:, :, None, None]
+        k_live, v_live = (jnp.take_along_axis(jnp.swapaxes(c, 1, 2), at,
+                                              axis=1, mode="clip")
+                          for c in (k, v))
+        return gqa_decode.gqa_decode_chosen(q, k_live, v_live, live, C_SCALE,
+                                            chunk)
+
+    def fn(n, q, k, v, new, selected):
+        def body(i, carry):
+            q, k, v = carry
+            k, v = (lax.dynamic_update_slice_in_dim(c, new, C_SLOTS - 1024 + i,
+                                                    axis=2) for c in (k, v))
+            return attend(q, k, v, selected + _moved(q)), k, v
+        return lax.fori_loop(0, n, body, (q, k, v))[0]
+    return jax.jit(fn)
+
+
+def _copies_kernel(pair_ref, at_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems,
+                   *, heads, covered, caches, priority):
+    """Starts the copies of `C_ENTRIES` chosen word rows of one row of
+    the batch, `heads` of the `covered` key/value heads a copy, and
+    waits for all."""
+    b = pl.program_id(0)
+    words = [c.bitcast(jnp.uint32) for c in (k_hbm, v_hbm)][:caches]
+    bufs = (k_buf, v_buf)[:caches]
+
+    def start(i):
+        pair, at = pair_ref[b, i], at_ref[b, i]
+        for j, (src, dst) in enumerate(zip(words, bufs)):
+            for h in range(0, covered, heads):
+                pltpu.make_async_copy(
+                    src.at[b, pl.ds(h, heads), pair],
+                    dst.at[pl.ds(h, heads), pl.ds(at, 1), :],
+                    sems.at[j]).start(priority=priority if j else 0)
+
+    def eight(u, _):
+        for j in range(8):
+            start(u * 8 + j)
+
+    lax.fori_loop(0, C_ENTRIES // 8, eight, None)
+    for j, dst in enumerate(bufs):
+        # one wait a cache: the semaphore counts the bytes of all copies
+        held = dst.at[pl.ds(0, covered)]
+        pltpu.make_async_copy(held, held, sems.at[j]).wait()
+    o_ref[0] = pltpu.bitcast(k_buf[0, :8] ^ v_buf[0, :8], jnp.float32)
+
+
+@functools.partial(jax.jit, static_argnames=("heads", "covered", "caches",
+                                             "priority"))
+def copies(q, k, v, pair, at, heads, covered, caches, priority):
+    """The caches come as they lie, a slot pair the two bfloat16 rows of
+    a 32-bit word row: `[.., S / 2, 2, D]` is no copy, its sublane tile
+    is one word row, and a word row is what one copy can name."""
+    view = (C_ROWS, C_KV, C_SLOTS // 2, 2, C_DIM)
+    any_space = pl.BlockSpec(memory_space=pl.ANY)
+    buf = pltpu.VMEM((C_KV, C_ENTRIES, C_DIM), jnp.uint32)
+    out = pl.pallas_call(
+        functools.partial(_copies_kernel, heads=heads, covered=covered,
+                          caches=caches, priority=priority),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(C_ROWS,),
+            in_specs=[any_space, any_space],
+            out_specs=pl.BlockSpec((1, 8, C_DIM), lambda b, *_: (b, 0, 0)),
+            scratch_shapes=[buf, buf, pltpu.SemaphoreType.DMA((2,))]),
+        out_shape=jax.ShapeDtypeStruct((C_ROWS, 8, C_DIM), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        name="gqa_chosen_copies_h%d_c%d" % (heads, caches),
+    )(pair, at, k.reshape(view), v.reshape(view))
+    return q + (out[:, None] * 1e-30).astype(q.dtype)
+
+
+def chosen(emit):
+    rs = np.random.RandomState(0)
+    key = jax.random.PRNGKey(0)
+    k, v = (jax.random.normal(jax.random.fold_in(key, i),
+                              (C_ROWS, C_KV, C_SLOTS, C_DIM), jnp.bfloat16)
+            for i in range(2))
+    q = jax.random.normal(jax.random.fold_in(key, 2),
+                          (C_ROWS, C_KV, C_GROUP, C_DIM), jnp.bfloat16)
+    new = jax.random.normal(jax.random.fold_in(key, 3),
+                            (C_ROWS, C_KV, 1, C_DIM), jnp.bfloat16)
+    selected = jnp.asarray(np.stack(
+        [np.sort(rs.choice(C_SLOTS - 1024, C_TOP_K, replace=False))
+         for _ in range(C_ROWS)]), jnp.int32)
+    want = None
+    for form, chunk in [("apart", None)] + [("whole", c)
+                                            for c in (2048, 1024, 512, 256)]:
+        fn = chosen_applications(form, chunk)
+        got = fn(1, q, k, v, new, selected).astype(jnp.float32)
+        want = got if want is None else want
+        emit({"kind": "chosen", "form": form, "chunk": chunk,
+              "ms": slope(fn, q, k, v, new, selected),
+              "max_abs_off_apart": float(jnp.max(jnp.abs(got - want)))})
+    pair = selected[:, :C_ENTRIES] >> 1
+    at = jnp.asarray(np.stack([rs.permutation(C_ENTRIES)
+                               for _ in range(C_ROWS)]), jnp.int32)
+    for heads, covered, caches, priority in (
+            (1, 1, 1, 0), (2, 2, 1, 0), (4, 4, 1, 0), (4, 4, 2, 0),
+            (4, 4, 2, 1), (1, 4, 2, 0)):
+        def fn(n, q, k, v, pair, at):
+            return lax.fori_loop(
+                0, n, lambda _, q: copies(q, k, v, pair + _moved(q), at,
+                                          heads, covered, caches, priority),
+                q)
+        ms = slope(jax.jit(fn), q, k, v, pair, at)
+        started = C_ROWS * C_ENTRIES * caches * (covered // heads)
+        emit({"kind": "copies", "heads_a_copy": heads,
+              "heads_covered": covered, "caches": caches,
+              "priority": priority, "copies": started, "ms": ms,
+              "ns_a_copy": ms * 1e6 / started})
+
+
 def main():
     assert jax.devices()[0].platform == "tpu", jax.devices()
     os.makedirs("chiprun_out", exist_ok=True)
@@ -104,6 +261,14 @@ def main():
         out.write(line + "\n")
         out.flush()
 
+    cases = sys.argv[1:] or ["narrow", "chosen"]
+    if "chosen" in cases:
+        chosen(emit)
+    if "narrow" in cases:
+        narrow(emit)
+
+
+def narrow(emit):
     rs = np.random.RandomState(0)
 
     def draw(*shape):

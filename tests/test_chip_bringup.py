@@ -390,6 +390,37 @@ def test_the_grouped_decode_kernel_lowers_for_tpu(block, window, kernels):
         assert 'kernel_name = "%s"' % name in module
 
 
+def test_the_chosen_sets_decode_kernel_lowers_for_tpu():
+    """`cached_attention` with `Selected` at keye-turn-64k-ep8's shape (8
+    rows, 32 query heads over 4 key/value heads of 128, 65,536-slot
+    bfloat16 caches, 2048 chosen) lowered for the TPU from this CPU
+    host: one Mosaic kernel, named with the set and the entries a grid
+    step folds, over the two gathers of whole slots."""
+    from paddle_tpu.ops import registry
+
+    kernel = registry.get_op_info("cached_attention").kernel
+    b, h, kv, d, bf16 = 8, 32, 4, 128, jnp.bfloat16
+    cache = jax.ShapeDtypeStruct((b, kv, 65536, d), bf16)
+    ins = {"Q": [jax.ShapeDtypeStruct((b, 1, h * d), bf16)],
+           "KNew": [jax.ShapeDtypeStruct((b, 1, kv * d), bf16)],
+           "VNew": [jax.ShapeDtypeStruct((b, 1, kv * d), bf16)],
+           "KCache": [cache], "VCache": [cache],
+           "Position": [jax.ShapeDtypeStruct((b,), jnp.int32)],
+           "Selected": [jax.ShapeDtypeStruct((b, 2048), jnp.int32)],
+           "Live": [jax.ShapeDtypeStruct((b,), jnp.int32)]}
+
+    def step(ins):
+        return kernel(None, ins, {"num_heads": h, "num_kv_heads": kv})
+
+    module = jax.export.export(jax.jit(step), platforms=["tpu"])(
+        ins).mlir_module()
+    assert module.count("tpu_custom_call") == 1
+    assert 'kernel_name = "gqa_decode_sel2048_c2048"' in module
+    assert module.count("stablehlo.gather") == 2
+    # whole slots, their heads side by side
+    assert "tensor<8x2048x4x128xbf16>" in module
+
+
 @pytest.mark.parametrize("rows,slots,heads,dim,name", [
     (8, 65536, 16, 64, "topk_select_s65536_k2048"),
     (16, 16384, 64, 128, "topk_select_s16384_k2048")])
