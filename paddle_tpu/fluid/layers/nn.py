@@ -29,7 +29,8 @@ __all__ = [
     "sequence_concat", "beam_search", "beam_search_decode",
     "sequence_reverse", "sequence_unnest", "sequence_renest",
     "flash_attention", "cached_attention", "mla_cached_attention",
-    "mla_index_select", "rms_norm", "rope", "moe",
+    "mla_index_select", "rms_norm", "rope", "moe", "hc_maps", "hc_pre",
+    "hc_post",
     "ssd_scan", "causal_conv1d", "gated_delta_rule", "expand", "gather", "slice", "cumsum",
 ]
 
@@ -83,7 +84,7 @@ def cached_attention(query, key, value, k_cache, v_cache, position,
 def mla_cached_attention(q_nope, q_rope, c_new, r_new, cache, position,
                          num_heads, v_head_dim, uk_attr=None, uv_attr=None,
                          name=None, selected=None, live=None,
-                         sm_scale=None, prefill_block=None):
+                         sm_scale=None, prefill_block=None, sink_attr=None):
     """One decode step of latent attention over a cache of latents, or a
     block of T consecutive steps at once
     (ops/attention.py mla_cached_attention): `q_nope` [batch, T,
@@ -102,7 +103,10 @@ def mla_cached_attention(q_nope, q_rope, c_new, r_new, cache, position,
     `position` (one position a call: T = 1).  `prefill_block`: the most
     positions a block of this op is sized for; the op carries it as an
     attr, refuses a longer block, and `fluid.ProgramDecoder` prefills a
-    prompt through the step by the smallest its ops state.  Returns (out
+    prompt through the step by the smallest its ops state.  `sink_attr`
+    creates a float32 parameter [num_heads] (zeros at the start), a
+    learned sink: a logit a head that joins the softmax's denominator and
+    has no value.  Returns (out
     [batch, T, num_heads * v_head_dim], cache_out): thread `cache_out`
     back as decode state (`fluid.ProgramDecoder` state pairs)."""
     if (selected is None) != (live is None):
@@ -127,6 +131,10 @@ def mla_cached_attention(q_nope, q_rope, c_new, r_new, cache, position,
     # attends every slot at the op's own scale stays what it was
     if selected is not None:
         inputs.update(Selected=[selected], Live=[live])
+    if sink_attr is not None:
+        inputs["Sink"] = [helper.create_parameter(
+            sink_attr, shape=[num_heads], dtype="float32",
+            default_initializer=Constant(0.0))]
     if sm_scale:
         attrs["sm_scale"] = float(sm_scale)
     if prefill_block:
@@ -164,6 +172,66 @@ def mla_index_select(q, w, k_new, cache, position, num_heads, top_k,
         outputs={"CacheOut": [cache_out], "Selected": [selected],
                  "Live": [live]}, attrs=attrs)
     return selected, live, cache_out
+
+
+def hc_maps(x, p_attr=None, alpha_attr=None, bias_attr=None, epsilon=1e-6,
+            magnitude=2.0, iterations=20, name=None):
+    """The three mappings of one sub-layer's hyper-connection
+    (ops/hyper_connection.py hc_maps) from the residual's streams `x`
+    [batch, seq, n, hidden]: creates the float32 projections [n *
+    hidden, n * n + 2 * n] (`pre | post | res`; N(0, 1 / (n hidden)), so
+    that a normed token's products are of size 1), their three scalars
+    [3] (ones) and biases [n * n + 2 * n] (zeros).  Returns (pre [batch,
+    seq, n], post [batch, seq, n], res [batch, seq, n, n]) in float32:
+    sigmoid, `magnitude` x sigmoid, and exp brought to a doubly
+    stochastic matrix by `iterations` Sinkhorn steps."""
+    helper = LayerHelper("hc_maps", name=name)
+    n, hidden = int(x.shape[-2]), int(x.shape[-1])
+    maps = n * n + 2 * n
+    p = helper.create_parameter(
+        p_attr or ParamAttr(), shape=[n * hidden, maps], dtype="float32",
+        default_initializer=Normal(0.0, (n * hidden) ** -0.5))
+    alpha = helper.create_parameter(
+        alpha_attr or ParamAttr(), shape=[3], dtype="float32",
+        default_initializer=Constant(1.0))
+    bias = helper.create_parameter(
+        bias_attr or ParamAttr(), shape=[maps], dtype="float32",
+        default_initializer=Constant(0.0))
+    pre, post, res = (helper.create_tmp_variable("float32",
+                                                 stop_gradient=True)
+                      for _ in range(3))
+    helper.append_op(
+        type="hc_maps",
+        inputs={"X": [x], "P": [p], "Alpha": [alpha], "Bias": [bias]},
+        outputs={"Pre": [pre], "Post": [post], "Res": [res]},
+        attrs={"epsilon": float(epsilon), "magnitude": float(magnitude),
+               "iterations": int(iterations)})
+    return pre, post, res
+
+
+def hc_pre(x, pre, name=None):
+    """The sub-layer's input read off the streams `x` [batch, seq, n,
+    hidden] (ops/hyper_connection.py hc_pre): sum_j pre[j] x_j, [batch,
+    seq, hidden] in x's type."""
+    helper = LayerHelper("hc_pre", name=name)
+    out = helper.create_tmp_variable(x.dtype)
+    helper.append_op(type="hc_pre", inputs={"X": [x], "Pre": [pre]},
+                     outputs={"U": [out]})
+    return out
+
+
+def hc_post(x, res, post, y, name=None):
+    """The streams after a sub-layer (ops/hyper_connection.py hc_post):
+    x'_i = sum_j res[i, j] x_j + post[i] y for the streams `x` [batch,
+    seq, n, hidden] and the sub-layer's output `y` [batch, seq,
+    hidden]."""
+    helper = LayerHelper("hc_post", name=name)
+    out = helper.create_tmp_variable(x.dtype)
+    helper.append_op(
+        type="hc_post",
+        inputs={"X": [x], "Res": [res], "Post": [post], "Y": [y]},
+        outputs={"XOut": [out]})
+    return out
 
 
 def flash_attention(queries, keys, values, num_heads=1, causal=False,
@@ -815,7 +883,7 @@ def moe(input, num_experts, expert_size, top_k, router_attr=None,
         gate_attr=None, up_attr=None, down_attr=None, name=None,
         scoring="softmax", norm_topk=False, scale=1.0, held=None,
         bias_attr=None, n_group=0, topk_group=0, activation="silu",
-        router_input=None):
+        router_input=None, swiglu_limit=None):
     """A routed expert layer over `input` [..., hidden] (ops/moe.py): a
     float32 router sends every token to its `top_k` of `num_experts`
     gated-SiLU experts of width `expert_size`, each computed for it (no
@@ -839,7 +907,8 @@ def moe(input, num_experts, expert_size, top_k, router_attr=None,
     `held` = (first, count) makes the layer one chip's share of an
     expert-parallel one: it holds `count` of the experts its router
     scores, and its output and every gradient are those experts' part.
-    `activation` "relu" makes the experts ReGLU.  With `router_input`
+    `activation` "relu" makes the experts ReGLU; `swiglu_limit` L clamps
+    them, act(min(gate, L)) * clip(up, -L, L).  With `router_input`
     [..., hidden] the router scores that tensor and the experts still
     read `input` (a router placed before the attention sub-layer).
     """
@@ -877,6 +946,8 @@ def moe(input, num_experts, expert_size, top_k, router_attr=None,
                   "W": [w_router]}
     if activation != "silu":
         share = dict(share, activation=str(activation))
+    if swiglu_limit:
+        share = dict(share, swiglu_limit=float(swiglu_limit))
     if bias_attr is not None:
         router_ins["Bias"] = [helper.create_parameter(
             bias_attr, shape=[num_experts], dtype="float32", is_bias=True)]

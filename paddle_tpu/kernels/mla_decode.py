@@ -82,8 +82,12 @@ cache in a narrower type than the query's is read up by the caller
 first.  The chosen-set path of the op (`Selected`, DeepSeek-V3.2's
 sparse attention) does not come here: its two contractions run over
 2048 *gathered* entries, all live, at 63% of their roofline, and what
-costs there is the gather, which a kernel that reads the chosen slots
-where they lie would take away (ROADMAP Reach A8), not this one.
+costs there is the gather.  A kernel that reads the chosen slots where
+they lie does not take that away: PR 60 closed the question by
+measurement (ROADMAP Reach A8(a), Speed 3): the fetch is bound by the
+count of its copy descriptors, 10-13 ns each whoever starts them, and a
+kernel starts as many as the gather does.  A learned sink (`Sink`) keeps
+the op on its plain path too: the walk's softmax has no such term.
 
 Lowered for the TPU these are Mosaic kernels named
 `mla_decode_k<block_k>` and `mla_decode_k<block_k>_t<T>` (a trace tells

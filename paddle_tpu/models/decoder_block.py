@@ -10,16 +10,41 @@ created by the name it is given, so a builder decides what is shared."""
 import numpy as np
 
 from .. import fluid
+from ..fluid.layer_helper import LayerHelper
 from ..fluid.param_attr import ParamAttr
 
-__all__ = ["linear", "norm", "attention", "gated_feed_forward",
+__all__ = ["linear", "linear_float32", "norm", "attention", "gated_feed_forward",
            "share_feed_forward", "token_feeds", "head_cross_entropy",
            "block_positions", "last", "last_token_rows"]
+
+
+# below every value a 16-bit float holds: a clip with no floor
+_NO_FLOOR = -3e38
 
 
 def linear(x, size, name):
     return fluid.layers.fc(input=x, size=size, num_flatten_dims=2,
                            param_attr=ParamAttr(name=name), bias_attr=False)
+
+
+def linear_float32(x, size, name):
+    """`linear` with the product in float32 whatever the weight's type
+    (the `mul` op's `float32`: neither operand is rounded on its way to
+    the matrix unit), the result float32: a head whose logits are asked
+    for in float32 (`enable_lm_head_fp32`).  `x` is cast up first; the
+    parameter is declared as `linear` declares it and may be served in a
+    narrower type, which is read as it lies."""
+    helper = LayerHelper("fc", param_attr=ParamAttr(name=name))
+    w = helper.create_parameter(helper.param_attr,
+                                shape=[int(x.shape[-1]), size],
+                                dtype=x.dtype)
+    out = helper.create_tmp_variable("float32")
+    helper.append_op(
+        type="mul", inputs={"X": [fluid.layers.cast(x, "float32")],
+                            "Y": [w]},
+        outputs={"Out": [out]},
+        attrs={"x_num_col_dims": 2, "y_num_col_dims": 1, "float32": True})
+    return out
 
 
 def norm(x, eps, name):
@@ -129,12 +154,16 @@ def attention(h, positions, names, n_head, d_head, theta, qk_norm_eps=None,
     return linear(o, h.shape[-1], names["wo"])
 
 
-def gated_feed_forward(u, width, names):
+def gated_feed_forward(u, width, names, limit=None):
     """(silu(g) * v) W_out with [g | v] = u W_in, gate and up in one
     [hidden, 2 * width] matrix `names["w_in"]`, the first `width`
-    columns the gate; `names["w_out"]` back to hidden."""
+    columns the gate; `names["w_out"]` back to hidden.  With `limit` L
+    the clamped form, silu(min(g, L)) * clip(v, -L, L)."""
     gate, up = fluid.layers.split(
         linear(u, 2 * width, names["w_in"]), 2, dim=-1)
+    if limit:
+        gate = fluid.layers.clip(gate, min=_NO_FLOOR, max=float(limit))
+        up = fluid.layers.clip(up, min=-float(limit), max=float(limit))
     return linear(fluid.layers.swish(gate) * up, u.shape[-1],
                   names["w_out"])
 
@@ -142,7 +171,7 @@ def gated_feed_forward(u, width, names):
 def share_feed_forward(u, block, dense, d_ff, d_expert, n_experts, held,
                        top_k, norm_topk, routed_scale, router_bias=False,
                        n_group=0, topk_group=0, scoring="sigmoid",
-                       shared_gate=None):
+                       shared_gate=None, swiglu_limit=None):
     """The feed-forward half of one layer of one chip's share of an
     expert model, for u [batch, seq, hidden], already normed; `block`
     names the layer's parameters.  Returns (F(u), routing).  The
@@ -164,12 +193,15 @@ def share_feed_forward(u, block, dense, d_ff, d_expert, n_experts, held,
     times `routed_scale`) that holds the experts `held` = (first,
     count) of those its router scores; `routing` is {"top_w",
     "top_idx", "counts": the router's Variables, "moe_in": u, "moe_out":
-    the held experts' part}.  A `block` that names no `shared_in` has no
+    the held experts' part}.  `swiglu_limit` clamps every gated
+    feed-forward here, dense, shared and routed (`gated_feed_forward`).
+    A `block` that names no `shared_in` has no
     shared expert (Keye-VL-2.0's: softmax experts alone), and F(u) is
     the held experts' part."""
     if dense:
-        return gated_feed_forward(u, d_ff, {"w_in": block["ffn_in"],
-                                            "w_out": block["ffn_out"]}), None
+        return gated_feed_forward(
+            u, d_ff, {"w_in": block["ffn_in"], "w_out": block["ffn_out"]},
+            swiglu_limit), None
     m, _, _, routing = fluid.layers.moe(
         u, n_experts, d_expert, top_k,
         *(ParamAttr(name=block[w])
@@ -178,12 +210,12 @@ def share_feed_forward(u, block, dense, d_ff, d_expert, n_experts, held,
         held=held,
         bias_attr=ParamAttr(name=block["router_bias"])
         if router_bias else None,
-        n_group=n_group, topk_group=topk_group)
+        n_group=n_group, topk_group=topk_group, swiglu_limit=swiglu_limit)
     f = m
     if "shared_in" in block:
         shared = gated_feed_forward(
             u, d_expert, {"w_in": block["shared_in"],
-                          "w_out": block["shared_out"]})
+                          "w_out": block["shared_out"]}, swiglu_limit)
         if shared_gate is not None:
             # named: the ops' instances in a trace start with it
             shared = fluid.layers.elementwise_mul(

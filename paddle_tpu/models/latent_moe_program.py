@@ -4,7 +4,10 @@ cached decode step Program: the DeepSeek-V3 family's block
 (huggingface.co/FreedomIntelligence/openPangu-Ultra-MoE-718B) has it
 with sandwich norms, and as DeepSeek-V3.2
 (huggingface.co/deepseek-ai/DeepSeek-V3.2) has it pre-norm with a learned
-chooser of the cache slots its attention reads.
+chooser of the cache slots its attention reads, and as Hy4-preview
+(huggingface.co/tencent/Hy4-preview) has it with a chooser on some
+layers only, a residual of several streams, a gate on the attention's
+output, a sink in its softmax and a clamp in its feed-forwards.
 
 A block of T >= 1 consecutive tokens of every row in (T = 1: a decode
 step; a prompt's prefill feeds many), the logits after the block's last
@@ -53,16 +56,38 @@ What a model's options change:
   "mscale"}: YaRN's blended rotary frequencies for every rotation, and
   the attention's scale times (0.1 mscale ln factor + 1)^2.
 
-The equations are in `models/reference/pangu_moe.py` and
-`models/reference/deepseek_v32.py`, which the tests hold this to.
+- `indexer_types`, a "full" or a "shared" a layer (Hy4-preview's): a
+  "shared" layer holds no index weights and **no `index_cache_<i>`**,
+  and its attention reads the `Selected` / `Live` of the nearest "full"
+  layer below it: a chosen set made in one layer is read by every layer
+  up to the next that chooses.  The step's state is then one cache a
+  layer and a second on the layers that choose.
+- `hc` = {"streams", "eps", "magnitude", "iterations"}: the residual is
+  `streams` streams [batch, T, streams, d_model], the embedding repeated,
+  and every sub-layer reads its input off them and writes its output to
+  them through mappings of its own (`ops/hyper_connection.py`: `hc_maps`,
+  `hc_pre`, `hc_post`, float32 inside; two applications a layer); the
+  streams are summed before the final norm.
+- `gated`: the heads' outputs times sigmoid(h `w_g`), elementwise,
+  before `wo` (ops named `mla_gate`).  `sink`: a learned logit a head in
+  the softmax's denominator (`mla_cached_attention`'s `Sink`).
+- `swiglu_limit` L: every gated feed-forward, dense, shared and routed,
+  is silu(min(gate, L)) * clip(up, -L, L).
+- `head_float32`: the final norm and the head in float32, the logits
+  float32 (`decoder_block.linear_float32`).
+
+The equations are in `models/reference/pangu_moe.py`,
+`models/reference/deepseek_v32.py` and `models/reference/hy4_preview.py`,
+which the tests hold this to.
 """
 
 from .. import fluid
 from ..fluid.param_attr import ParamAttr
 from ..ops.attention import yarn_inv_freq, yarn_mscale
 from .decode import PREFILL_BLOCK
+from ..obs import telemetry
 from .decoder_block import (block_positions, last, last_token_rows, linear,
-                            norm, share_feed_forward)
+                            linear_float32, norm, share_feed_forward)
 
 __all__ = ["build_latent_moe_cached_step_program", "latent_moe_param_names",
            "prefill_block"]
@@ -72,17 +97,29 @@ _ATTENTION = ("input_norm", "w_dq", "q_norm", "w_uq_nope", "w_uq_rope",
 _INDEXER = ("w_iq", "w_ik", "ik_norm", "ik_norm_b", "w_iw")
 _DENSE = ("ffn_in", "ffn_out")
 _EXPERTS = ("shared_in", "shared_out", "router", "w_gate", "w_up", "w_down")
+# a hyper-connection's projections, scalars and biases, for the attention
+# sub-layer and for the feed-forward
+_STREAMS = tuple("hc_%s_%s" % (sub, what) for sub in ("attn", "mlp")
+                 for what in ("p", "a", "b"))
 
 
 def latent_moe_param_names(n_layer, n_dense, sandwich_norm=True,
-                           indexer=False, router_bias=False):
+                           indexer=False, router_bias=False,
+                           indexer_types=None, hc=False, gated=False,
+                           sink=False):
     """The parameters' names, laid out as the reference's `params`; the
-    indexer's, the router's bias and the sandwich's two further norms
-    only where the options ask for them."""
+    indexer's (on the layers `indexer_types` calls "full", every layer
+    where it is None), the router's bias, the sandwich's two further
+    norms, the hyper-connections', the gate's and the sink's only where
+    the options ask for them."""
     def block(i):
+        chooses = indexer and (indexer_types is None
+                               or indexer_types[i] == "full")
         kinds = _ATTENTION \
             + (("post_attn_norm",) if sandwich_norm else ()) \
-            + ("pre_mlp_norm",) + (_INDEXER if indexer else ()) \
+            + ("pre_mlp_norm",) + (_INDEXER if chooses else ()) \
+            + (("w_g",) if gated else ()) + (("sink",) if sink else ()) \
+            + (_STREAMS if hc else ()) \
             + (_DENSE if i < n_dense else _EXPERTS
                + (("router_bias",) if router_bias else ())) \
             + (("post_mlp_norm",) if sandwich_norm else ())
@@ -124,7 +161,8 @@ def build_latent_moe_cached_step_program(
         d_ff=128, d_expert=32, n_experts=8, held=None, top_k=2,
         norm_topk=True, routed_scale=2.5, eps=1e-5, rope_theta=1e4,
         sandwich_norm=True, indexer=None, n_group=0, topk_group=0,
-        router_bias=False, yarn=None):
+        router_bias=False, yarn=None, indexer_types=None, hc=None,
+        gated=False, sink=False, swiglu_limit=None, head_float32=False):
     """Returns (main, startup, logits, state_pairs, parts): feeds "tok"
     int32 [batch, T] (declared [batch, -1]: T >= 1 consecutive tokens of
     every row, read off the feed), "pos" int64 [batch], the position of
@@ -139,7 +177,8 @@ def build_latent_moe_cached_step_program(
     a prompt `prefill_block(batch, n_head, kv_rank, d_rope)` positions an
     application (the attention op carries the number as an attr).  With
     an `indexer` the step takes one position: "tok" is int32 [batch],
-    there is also "index_cache_<i>" [batch, max_len, its width] a layer,
+    there is also "index_cache_<i>" [batch, max_len, its width] a layer
+    that chooses (every layer, or those `indexer_types` calls "full"),
     and T below is 1.
 
     `parts` are **of the block's last position**, in shapes that T does
@@ -153,9 +192,23 @@ def build_latent_moe_cached_step_program(
     attention sub-layer's normed input, and "attn_out", that sub-layer's
     output (after `wo`, before any norm); and with an
     `indexer`, per layer, "selected" [batch, top_k] and "live"
-    [batch]."""
-    names = latent_moe_param_names(n_layer, n_dense, sandwich_norm,
-                                   indexer is not None, router_bias)
+    [batch], the set the layer attends (a "shared" layer's are the
+    Variables of the layer it inherits from).  With `hc`, "hidden" is the
+    streams after the layer [batch, 1, streams, d_model], and per layer
+    "streams_in" and "streams_out" are the streams before the attention
+    sub-layer and after it."""
+    if indexer_types is not None:
+        indexer_types = list(indexer_types)
+        if indexer is None or len(indexer_types) != n_layer \
+                or indexer_types[0] != "full" \
+                or set(indexer_types) - {"full", "shared"}:
+            raise ValueError(
+                "indexer_types %r: a \"full\" or a \"shared\" for each of "
+                "the %d layers of a step with an indexer, the first of "
+                "them \"full\"" % (indexer_types, n_layer))
+    names = latent_moe_param_names(
+        n_layer, n_dense, sandwich_norm, indexer is not None, router_bias,
+        indexer_types, hc is not None, gated, sink)
     width = kv_rank + d_rope
     inv_freq = sm_scale = None
     if yarn is not None:
@@ -186,12 +239,14 @@ def build_latent_moe_cached_step_program(
             name="latent_cache_%d" % i, shape=[batch, max_len, width],
             dtype="float32", append_batch_size=False)
             for i in range(n_layer)]
+        index_caches = {}
         if indexer is not None:
             i_heads, i_dim, i_top_k = indexer
-            index_caches = [fluid.layers.data(
+            index_caches = {i: fluid.layers.data(
                 name="index_cache_%d" % i, shape=[batch, max_len, i_dim],
                 dtype="float32", append_batch_size=False)
-                for i in range(n_layer)]
+                for i in range(n_layer)
+                if indexer_types is None or indexer_types[i] == "full"}
         # lookup_table squeezes a trailing size-1 ids dim: [batch, T, 1]
         # ids give [batch, T, d_model]; 0 keeps an axis as it comes
         x = fluid.layers.embedding(
@@ -218,7 +273,31 @@ def build_latent_moe_cached_step_program(
                  "top_idx": [],
                  "counts": [], "moe_in": [], "moe_out": [], "selected": [],
                  "live": []}
+        streams = None
+        if hc is not None:
+            # the embedding repeated: [batch, T, d] -> [batch, T, n, d]
+            streams = fluid.layers.expand(
+                fluid.layers.reshape(
+                    x, [0, 0, 1, d_model] if takes_block
+                    else [batch, 1, 1, d_model]),
+                [1, 1, hc["streams"], 1])
+            parts.update(streams_in=[], streams_out=[])
+
+        def read_streams(block, sub):
+            """(a sub-layer's input read off the streams, the two
+            mappings that write its output back)."""
+            pre, post, res = fluid.layers.hc_maps(
+                streams, *(ParamAttr(name=block["hc_%s_%s" % (sub, w)])
+                           for w in ("p", "a", "b")),
+                epsilon=hc["eps"], magnitude=hc["magnitude"],
+                iterations=hc["iterations"])
+            return fluid.layers.hc_pre(streams, pre), (res, post)
+
+        chosen = {}
         for i, block in enumerate(names["blocks"]):
+            if streams is not None:
+                parts["streams_in"].append(final(streams))
+                x, back = read_streams(block, "attn")
             h = norm(x, eps, block["input_norm"])
             parts["attn_in"].append(final(h))
             c_q = norm(linear(h, q_rank, block["w_dq"]), eps,
@@ -228,8 +307,8 @@ def build_latent_moe_cached_step_program(
                             n_head)
             c, r = fluid.layers.split(
                 linear(h, width, block["w_dkv"]), [kv_rank, d_rope], dim=-1)
-            chosen = {}
-            if indexer is not None:
+            index_out = None
+            if i in index_caches:
                 k_index = fluid.layers.layer_norm(
                     linear(h, i_dim, block["w_ik"]), begin_norm_axis=2,
                     epsilon=eps, param_attr=ParamAttr(name=block["ik_norm"]),
@@ -241,41 +320,71 @@ def build_latent_moe_cached_step_program(
                     rotate(k_index, 1, d_rope), index_caches[i], pos,
                     i_heads, i_top_k, scale=(i_heads * i_dim) ** -0.5)
                 chosen = {"selected": selected, "live": live}
-                parts["selected"].append(selected)
-                parts["live"].append(live)
+            # a layer that does not choose attends the set of the nearest
+            # layer below it that did (none: every slot)
+            if chosen:
+                parts["selected"].append(chosen["selected"])
+                parts["live"].append(chosen["live"])
+            a_sink = {"sink_attr": ParamAttr(name=block["sink"])} \
+                if sink else {}
             o, cache_out = fluid.layers.mla_cached_attention(
                 q_nope, q_rope, norm(c, eps, block["kv_norm"]),
                 rotate(r, 1), caches[i],
                 pos, n_head, d_v, uk_attr=ParamAttr(name=block["w_uk"]),
                 uv_attr=ParamAttr(name=block["w_uv"]), sm_scale=sm_scale,
-                **chosen, **sized)
+                **chosen, **sized, **a_sink)
             state_pairs.append(("latent_cache_%d" % i, cache_out.name))
-            if indexer is not None:
+            if index_out is not None:
                 state_pairs.append(("index_cache_%d" % i, index_out.name))
+            if gated:
+                # named: the ops' instances in a trace start with it
+                o = fluid.layers.elementwise_mul(
+                    o, fluid.layers.sigmoid(
+                        fluid.layers.fc(
+                            input=h, size=n_head * d_v, num_flatten_dims=2,
+                            param_attr=ParamAttr(name=block["w_g"]),
+                            bias_attr=False, name="mla_gate"),
+                        name="mla_gate"), name="mla_gate")
             o = linear(o, d_model, block["wo"])
             parts["attn_out"].append(final(o))
-            a = x + (norm(o, eps, block["post_attn_norm"])
-                     if sandwich_norm else o)
+            o = norm(o, eps, block["post_attn_norm"]) if sandwich_norm else o
+            if streams is not None:
+                streams = fluid.layers.hc_post(streams, *back, o)
+                parts["streams_out"].append(final(streams))
+                a, back = read_streams(block, "mlp")
+            else:
+                a = x + o
             u = norm(a, eps, block["pre_mlp_norm"])
             f, routing = share_feed_forward(
                 u, block, i < n_dense, d_ff, d_expert, n_experts, held,
                 top_k, norm_topk, routed_scale, router_bias, n_group,
-                topk_group)
+                topk_group, swiglu_limit=swiglu_limit)
             for key, value in (routing or {}).items():
                 if key != "counts":     # the whole block's, as it comes
                     value = (final_row if key in ("top_w", "top_idx")
                              else final)(value)
                 parts[key].append(value)
-            x = a + (norm(f, eps, block["post_mlp_norm"])
-                     if sandwich_norm else f)
-            parts["hidden"].append(final(x))
+            f = norm(f, eps, block["post_mlp_norm"]) if sandwich_norm else f
+            if streams is not None:
+                streams = fluid.layers.hc_post(streams, *back, f)
+                parts["hidden"].append(final(streams))
+            else:
+                x = a + f
+                parts["hidden"].append(final(x))
 
         # the head reads the block's last position alone
+        x = final(x if streams is None else streams)
+        if head_float32:
+            x = fluid.layers.cast(x, "float32")
+        if streams is not None:
+            x = fluid.layers.reduce_sum(x, dim=2)
         logits = fluid.layers.reshape(
-            x=linear(norm(final(x), eps, names["norm_f"]), vocab_size,
-                     names["head"]),
+            x=(linear_float32 if head_float32 else linear)(
+                norm(x, eps, names["norm_f"]), vocab_size, names["head"]),
             shape=[batch, vocab_size])
         pos_out = pos + fluid.layers.reduce_sum(ones) if takes_block \
             else fluid.layers.increment(pos, value=1, in_place=False)
         state_pairs.append(("pos", pos_out.name))
+    if indexer_types is not None and "shared" in indexer_types:
+        telemetry.on_index_sets_reused(main, indexer_types.count("shared"))
     return main, startup, logits, state_pairs, parts

@@ -48,6 +48,7 @@ __all__ = ["on_executor_run", "on_jit_trace",
            "on_prefill_lowering", "on_ssd_lowering",
            "on_causal_conv1d_lowering", "on_causal_conv1d_tail_lowering",
            "on_gated_delta_rule_lowering", "on_shared_parameter_uses",
+           "on_index_sets_reused",
            "on_transfer",
            "on_feed_seconds", "on_decoder_call", "on_program_cache_evict",
            "jit_trace_count", "transfer_bytes", "step", "set_gauge",
@@ -492,6 +493,18 @@ def on_shared_parameter_uses(program, uses):
                    "parameters that several ops read",
                    labelnames=("program",)) \
           .labels(program=str(program._cache_token)).inc(uses)
+
+
+def on_index_sets_reused(program, reused):
+    """A cached step was built whose `reused` attention layers hold no
+    chooser and attend the set a layer below them chose
+    (`models/latent_moe_program.py`, `indexer_types`): counted at build,
+    once a layer that inherits its set."""
+    _reg().counter("program_index_sets_reused",
+                   "attention layers of a built step that attend the "
+                   "chosen set of a layer below them",
+                   labelnames=("program",)) \
+          .labels(program=str(program._cache_token)).inc(reused)
 
 
 def on_program_cache_evict():

@@ -25,6 +25,7 @@ from . import attention     # noqa: F401
 from . import moe           # noqa: F401
 from . import ssm           # noqa: F401
 from . import linear_attention  # noqa: F401
+from . import hyper_connection  # noqa: F401
 from . import sequence      # noqa: F401
 from . import control_flow  # noqa: F401
 from . import crf           # noqa: F401

@@ -753,12 +753,19 @@ def mla_cached_attention_op(ctx, ins, attrs):
     contractions run over the gathered entries, and of a row's top_k
     entries the first Live count, the others are masked: a chosen set is
     a set, the softmax does not care for its order.  A chosen set is one
-    position's: with Selected and T > 1 the op raises."""
+    position's: with Selected and T > 1 the op raises.
+
+    With Sink float32 [heads] (a learned sink: one logit a head) the
+    softmax's denominator holds exp(Sink_h) beside the attended slots'
+    terms, and the sink has no value: p_h,t = exp(s_h,t) / (exp(Sink_h)
+    + sum_t' exp(s_h,t')), so a head may attend nothing much.  The sink
+    is the plain path's: the walk of the live slots does not take it."""
     q_nope, q_rope = ins["QNope"][0], ins["QRope"][0]
     c_new, r_new = ins["CNew"][0], ins["RNew"][0]
     cache, w_uk, w_uv = ins["Cache"][0], ins["WUk"][0], ins["WUv"][0]
     pos = jnp.reshape(ins["Position"][0], (-1,))[0].astype(jnp.int32)
     selected = (ins.get("Selected") or [None])[0]
+    sink = (ins.get("Sink") or [None])[0]
     heads = int(attrs["num_heads"])
     batch, positions, width = cache.shape
     latent, rope_dim = c_new.shape[-1], r_new.shape[-1]
@@ -790,7 +797,7 @@ def mla_cached_attention_op(ctx, ins, attrs):
     # the walk of the live slots (kernels/mla_decode.py) where what the
     # op sees of its inputs fits it, the plain path otherwise
     blocks = None
-    if selected is None:
+    if selected is None and sink is None:
         from ..kernels import mla_decode
         if mla_decode.fits(block, positions, latent):
             itemsize = jnp.dtype(dtype).itemsize
@@ -868,9 +875,19 @@ def mla_cached_attention_op(ctx, ins, attrs):
             else:   # query row t * heads + h attends slots 0 .. pos + t
                 valid = jnp.arange(positions)[None, :] \
                     <= pos + jnp.arange(block * heads)[:, None] // heads
-            p = jax.nn.softmax(jnp.where(valid[None, None, :] if block == 1
-                                         else valid[None], s, -1e30),
-                               axis=-1)
+            s = jnp.where(valid[None, None, :] if block == 1
+                          else valid[None], s, -1e30)
+            if sink is None:
+                p = jax.nn.softmax(s, axis=-1)
+            else:
+                # a query row is a head's (a step) or position t's head
+                # h at row t * heads + h (a block)
+                z = jnp.tile(sink.astype(f32).reshape(heads), block)[
+                    None, :, None]
+                top = jnp.maximum(jnp.max(s, axis=-1, keepdims=True), z)
+                e = jnp.exp(s - top)
+                p = e / (jnp.sum(e, axis=-1, keepdims=True)
+                         + jnp.exp(z - top))
     with jax.named_scope("mla_values"):
         if not blocks:
             o_lat = jnp.einsum("bht,btw->bhw", p.astype(dtype), live,

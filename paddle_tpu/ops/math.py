@@ -38,6 +38,29 @@ def _flatten2d(x, num_col_dims):
     return jnp.reshape(x, (lead, -1))
 
 
+def _float32_dot(x, w):
+    """x w in float32 with neither operand rounded on the way, whatever
+    the matrix unit's default for float32 operands is (on a TPU one
+    bfloat16 pass, which rounds x to 8 bits of mantissa).  A bfloat16
+    `w` is read as it lies and once: a float32 x is exactly the sum of
+    three bfloat16 terms, which go through one product as three times
+    the rows, every term's products exact and summed in float32, the
+    smallest term's rows added first.  Any other `w`: the highest
+    precision."""
+    f32 = jnp.float32
+    x = x.astype(f32)
+    if w.dtype != jnp.bfloat16:
+        return jnp.dot(x, w.astype(f32), precision=jax.lax.Precision.HIGHEST)
+    terms, rest = [], x
+    for _ in range(3):
+        terms.append(rest.astype(jnp.bfloat16))
+        rest = rest - terms[-1].astype(f32)
+    rows = x.shape[0]
+    out = jnp.dot(jnp.concatenate(terms[::-1]), w,
+                  preferred_element_type=f32)
+    return out[:rows] + out[rows:2 * rows] + out[2 * rows:]
+
+
 @register_op("mul")
 def mul(ctx, ins, attrs):
     x, y = _vals(_x(ins)), _vals(_x(ins, "Y"))
@@ -46,8 +69,12 @@ def mul(ctx, ins, attrs):
     x2 = _flatten2d(x, xn)
     y2 = _flatten2d(y, yn)
     dtype = jnp.result_type(x.dtype, y.dtype)
-    x2, y2 = mxu_operands(x2, y2)
-    out = amp_result(jnp.dot(x2, y2, **acc_kwargs(x2, y2)), dtype)
+    if attrs.get("float32"):
+        # a head asked for in float32 (`decoder_block.linear_float32`)
+        out = _float32_dot(x2, y2)
+    else:
+        x2, y2 = mxu_operands(x2, y2)
+        out = amp_result(jnp.dot(x2, y2, **acc_kwargs(x2, y2)), dtype)
     out_shape = x.shape[:xn] + y.shape[yn:]
     out = jnp.reshape(out, out_shape)
     xin = ins["X"][0]

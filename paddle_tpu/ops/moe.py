@@ -461,6 +461,12 @@ def _gate(g, attrs):
     op's `activation`: "silu" (the default, which an op need not
     carry) or "relu"."""
     activation = attrs.get("activation", "silu")
+    limit = attrs.get("swiglu_limit")
+    if limit:
+        # the clamped form: silu(min(g, limit)), flat past the limit
+        act, slope = _gate(jnp.minimum(g, limit),
+                           dict(attrs, swiglu_limit=None))
+        return act, jnp.where(g < limit, slope, 0.0)
     if activation == "silu":
         sig = jax.nn.sigmoid(g)
         act = g * sig
@@ -472,13 +478,24 @@ def _gate(g, attrs):
                      % activation)
 
 
+def _up(u, attrs):
+    """(the up projection as the product reads it, its slope): `u`
+    itself, or under `swiglu_limit` clip(u, -limit, limit)."""
+    limit = attrs.get("swiglu_limit")
+    if not limit:
+        return u, 1.0
+    return jnp.clip(u, -limit, limit), (jnp.abs(u) < limit).astype(u.dtype)
+
+
 @register_op("moe_experts", nondiff_inputs=("TopIdx",),
              infer_shape=_experts_infer_shape)
 def moe_experts(ctx, ins, attrs):
     """X [..., hidden], TopW / TopIdx [tokens, top_k], WGate and WUp
     [experts, hidden, width], WDown [experts, width, hidden] -> Out, X's
     shape: sum_j TopW[n, j] * down_e(act(gate_e(x_n)) * up_e(x_n)) with
-    e = TopIdx[n, j] and act the op's `activation` (SiLU; "relu": ReLU);
+    e = TopIdx[n, j] and act the op's `activation` (SiLU; "relu": ReLU;
+    under `swiglu_limit` L the clamped form act(min(gate, L)) * clip(up,
+    -L, L));
     and what the gradient reads (the module's docstring).  Where the router scores `scored` experts and the op
     holds fewer (`experts` of them from `first_expert` on), the sum is
     over the held e alone."""
@@ -522,7 +539,7 @@ def moe_experts(ctx, ins, attrs):
     (h,) = _row_work(
         "moe_experts",
         lambda g, u, _: ((_gate(g.astype(f32), attrs)[0]
-                          * u.astype(f32)).astype(xs.dtype),),
+                          * _up(u.astype(f32), attrs)[0]).astype(xs.dtype),),
         (gate, up), counts, chunk)
     with jax.named_scope("moe_experts"):
         y = gmm(h, wd, counts)
@@ -586,12 +603,13 @@ def moe_experts_grad(ctx, ins, attrs):
         g, u, dh_raw = (_held(a, present).astype(f32)
                         for a in (g, u, dh_raw))
         act, d_act = _gate(g, attrs)
+        u, d_u = _up(u, attrs)
         h = act * u
         # dh_raw is d<y_row, dOut> / dh, before the routing weight
         d_w_rows = jnp.sum(dh_raw * h, axis=-1)
         dh = dh_raw * w[:, None]
         d_gate = (dh * u * d_act).astype(xs.dtype)
-        d_up = (dh * act).astype(xs.dtype)
+        d_up = (dh * act * d_u).astype(xs.dtype)
         hw = (h * w[:, None]).astype(xs.dtype)
         return d_gate, d_up, hw, d_w_rows
 

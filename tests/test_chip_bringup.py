@@ -423,11 +423,13 @@ def test_the_chosen_sets_decode_kernel_lowers_for_tpu():
 
 @pytest.mark.parametrize("rows,slots,heads,dim,name", [
     (8, 65536, 16, 64, "topk_select_s65536_k2048"),
-    (16, 16384, 64, 128, "topk_select_s16384_k2048")])
+    (16, 16384, 64, 128, "topk_select_s16384_k2048"),
+    (8, 32768, 32, 128, "topk_select_s32768_k2048")])
 def test_the_choosers_selection_lowers_one_kernel_for_tpu(rows, slots, heads,
                                                           dim, name):
-    """`mla_index_select` at keye-turn-64k-ep8's and dsv32-turn-16k-ep16's
-    shapes (2048 of 65,536 or 16,384 bfloat16 index keys a row) lowered
+    """`mla_index_select` at keye-turn-64k-ep8's, dsv32-turn-16k-ep16's
+    and hy4-turn-32k-ep16's
+    shapes (2048 of 65,536, 16,384 or 32,768 bfloat16 index keys a row) lowered
     for the TPU from this CPU host: the selection is one Mosaic kernel
     named with the extent and the slots chosen, and the module holds no
     sort and no top-k."""
@@ -449,6 +451,62 @@ def test_the_choosers_selection_lowers_one_kernel_for_tpu(rows, slots, heads,
     assert module.count("tpu_custom_call") == 1
     assert 'kernel_name = "%s"' % name in module
     assert "stablehlo.sort" not in module and "top_k" not in module
+
+
+def test_the_sink_and_the_streams_lower_for_tpu_without_a_kernel():
+    """hy4-turn-32k-ep16's step at its shapes (8 rows, 64 heads of 192 +
+    64 over 512 latents, 2048 chosen of 32,768 bfloat16 slots, a sink a
+    head; four streams of 6144) lowered for the TPU from this CPU host:
+    the chosen-set path with `Sink` is plain products around one gather,
+    and the hyper-connection's three ops are plain float32 arithmetic
+    under the scope `hyper_connection`, twenty Sinkhorn iterations
+    unrolled: no Mosaic kernel in either."""
+    from paddle_tpu.ops import registry
+
+    b, h, bf16, f32 = 8, 64, jnp.bfloat16, jnp.float32
+    attend = registry.get_op_info("mla_cached_attention").kernel
+    ins = {"QNope": [jax.ShapeDtypeStruct((b, 1, h * 192), bf16)],
+           "QRope": [jax.ShapeDtypeStruct((b, 1, h * 64), bf16)],
+           "CNew": [jax.ShapeDtypeStruct((b, 1, 512), bf16)],
+           "RNew": [jax.ShapeDtypeStruct((b, 1, 64), bf16)],
+           "Cache": [jax.ShapeDtypeStruct((b, 32768, 576), bf16)],
+           "WUk": [jax.ShapeDtypeStruct((512, h * 192), bf16)],
+           "WUv": [jax.ShapeDtypeStruct((512, h * 256), bf16)],
+           "Position": [jax.ShapeDtypeStruct((b,), jnp.int32)],
+           "Selected": [jax.ShapeDtypeStruct((b, 2048), jnp.int32)],
+           "Live": [jax.ShapeDtypeStruct((b,), jnp.int32)],
+           "Sink": [jax.ShapeDtypeStruct((h,), f32)]}
+    module = jax.export.export(
+        jax.jit(lambda ins: attend(None, ins, {"num_heads": h})),
+        platforms=["tpu"])(ins).mlir_module()
+    assert "tpu_custom_call" not in module
+    assert module.count('"stablehlo.gather"') == 1
+    assert "tensor<8x2048x576xbf16>" in module
+
+    maps = registry.get_op_info("hc_maps").kernel
+    pre = registry.get_op_info("hc_pre").kernel
+    post = registry.get_op_info("hc_post").kernel
+    streams = jax.ShapeDtypeStruct((b, 1, 4, 6144), bf16)
+
+    def mixed(x, p, a, bias, y):
+        m = maps(None, {"X": [x], "P": [p], "Alpha": [a], "Bias": [bias]},
+                 {"epsilon": 1e-6, "magnitude": 2.0, "iterations": 20})
+        u = pre(None, {"X": [x], "Pre": m["Pre"]}, {})["U"][0]
+        out = post(None, {"X": [x], "Res": m["Res"], "Post": m["Post"],
+                          "Y": [y]}, {})["XOut"][0]
+        return u, out
+
+    exported = jax.export.export(jax.jit(mixed), platforms=["tpu"])(
+        streams, jax.ShapeDtypeStruct((4 * 6144, 24), f32),
+        jax.ShapeDtypeStruct((3,), f32), jax.ShapeDtypeStruct((24,), f32),
+        jax.ShapeDtypeStruct((b, 1, 6144), bf16))
+    module = exported.mlir_module()
+    assert "tpu_custom_call" not in module
+    assert [str(a.dtype) for a in exported.out_avals] == ["bfloat16"] * 2
+    assert exported.out_avals[1].shape == (b, 1, 4, 6144)
+    # rows over their sums, columns over theirs, twenty times
+    assert module.count("stablehlo.divide") >= 40
+    assert "tensor<8x1x24576xf32>" in module
 
 
 @pytest.mark.parametrize("block,kernels", [
