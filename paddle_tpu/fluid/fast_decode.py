@@ -84,7 +84,13 @@ class ProgramDecoder:
     (`mla_cached_attention`, whose absorbed queries are a hundred times
     a token's hidden state: `models/latent_moe_program.py`), the decoder
     prefills by the smallest its Program states, and a step that states
-    none keeps `PREFILL_BLOCK`.
+    none keeps `PREFILL_BLOCK`.  A step whose attention reads a chosen
+    set of the slots is such a step too where its ops choose and attend
+    a set a position of the block (`mla_index_select`,
+    `mla_cached_attention` with `Selected`: the latent builder with an
+    `indexer`); one whose ops take a single query's set declares
+    [batch] and is prefilled a position an application
+    (`models/sparse_kv_moe_program.py`).
     """
 
     def __init__(self, program, token_name, logits_name, state_pairs=(),

@@ -98,9 +98,10 @@ def mla_cached_attention(q_nope, q_rope, c_new, r_new, cache, position,
     num_heads * v_head_dim], which the op absorbs; the scores' scale is
     the op's own, (nope + rope) ** -0.5, unless `sm_scale` gives one.
     With `selected` int32 [batch, top_k] and `live` int32 [batch]
-    (`mla_index_select`'s two) the step attends the slots `selected`
-    names, the first `live` of each row, and not every slot up to
-    `position` (one position a call: T = 1).  `prefill_block`: the most
+    (`mla_index_select`'s two; [batch, T, top_k] and [batch, T] of a
+    block of T > 1 positions, a set a position) each position attends
+    the slots its set names, the first `live` of them, and not every
+    slot up to its own.  `prefill_block`: the most
     positions a block of this op is sized for; the op carries it as an
     attr, refuses a longer block, and `fluid.ProgramDecoder` prefills a
     prompt through the step by the smallest its ops state.  `sink_attr`
@@ -147,17 +148,23 @@ def mla_cached_attention(q_nope, q_rope, c_new, r_new, cache, position,
 
 def mla_index_select(q, w, k_new, cache, position, num_heads, top_k,
                      scale=1.0, name=None):
-    """One decode step of a learned chooser of cache slots
-    (ops/attention.py mla_index_select): `q` [batch, 1, num_heads * dim]
-    (rotated) and `w` [batch, 1, num_heads] the token's index queries
-    and their weights, `k_new` [batch, 1, dim] its index key, `cache`
-    [batch, positions, dim] the chooser's own cache, `position` int [1]
-    or [batch].  A slot s <= position scores scale * sum_j w_j relu(q_j
-    . k_s).  Returns (selected int32 [batch, top_k], live int32 [batch],
-    cache_out): `selected` is the `top_k` best-scoring slots as a set,
-    in ascending slot order, of which the first `live` are slots to
-    attend; hand the two to `mla_cached_attention` or
-    `cached_attention`, thread `cache_out` back as decode state."""
+    """One decode step of a learned chooser of cache slots, or a block
+    of T consecutive steps at once (ops/attention.py mla_index_select):
+    `q` [batch, T, num_heads * dim] (rotated) and `w` [batch, T,
+    num_heads] the index queries and their weights of T >= 1
+    consecutive tokens of every row, `k_new` [batch, T, dim] their index
+    keys, `cache` [batch, positions, dim] the chooser's own cache,
+    `position` int [1] or [batch], the slot the block's first token
+    writes.  For query t a slot s <= position + t scores scale * sum_j
+    w_t,j relu(q_t,j . k_s).  Returns (selected, live, cache_out):
+    `selected` is each query's `top_k` best-scoring slots as a set, in
+    ascending slot order, of which the first `live` are slots to attend:
+    int32 [batch, top_k] and [batch] where `q` is declared one position
+    a call, [batch, T, top_k] and [batch, T] where it declares a block
+    axis (T may be left open, -1; such a step fed T = 1 gives a step's
+    [batch, top_k] and [batch]).  Hand the two to `mla_cached_attention`
+    (a step's or a block's) or `cached_attention` (a step's), thread
+    `cache_out` back as decode state."""
     helper = LayerHelper("mla_index_select", name=name)
     selected = helper.create_tmp_variable("int32", stop_gradient=True)
     live = helper.create_tmp_variable("int32", stop_gradient=True)

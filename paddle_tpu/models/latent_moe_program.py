@@ -32,9 +32,12 @@ weights once for all its positions: a prompt's prefill is a few
 compute-bound applications, not a weight-bound one a position (its
 `rope` ops carry `full_width`, so a block's rotary heads of 64 are
 turned where they lie and not through a view of half heads).  With an
-`indexer` the step takes one position a call, [batch], and prefill is
-its scan over the prompt: a chosen set is one position's
-(`mla_index_select` chooses for one query).
+`indexer` the step takes a block as well: `mla_index_select` chooses a
+set for each of the block's positions and `mla_cached_attention` attends
+each position's own, a tile of positions at a time, so what a block
+shares is the weights and what it does not is the gathers, a position's
+`top_k` rows each.  Such a block is sized by the rows at which the dense
+products stop being bound by the weights' read (`prefill_block`).
 
 What a model's options change:
 
@@ -47,8 +50,9 @@ What a model's options change:
   consumers, the heads' up-projections and the index queries `w_iq`;
   the index key is LayerNorm(h `w_ik`), the first `d_rope` values of it
   and of every index query rotated; the index heads' weights are h
-  `w_iw`; `mla_index_select` writes the key, scores the live slots and
-  picks `top_k`, and `mla_cached_attention` attends those.
+  `w_iw`; `mla_index_select` writes the block's keys, scores each
+  position's live slots and picks its `top_k`, and
+  `mla_cached_attention` attends those.
 - `n_group`, `topk_group`, `router_bias`: the router's choice limited to
   the best groups of experts and steered by a selection bias
   (`fluid.layers.moe`).
@@ -59,8 +63,9 @@ What a model's options change:
 - `indexer_types`, a "full" or a "shared" a layer (Hy4-preview's): a
   "shared" layer holds no index weights and **no `index_cache_<i>`**,
   and its attention reads the `Selected` / `Live` of the nearest "full"
-  layer below it: a chosen set made in one layer is read by every layer
-  up to the next that chooses.  The step's state is then one cache a
+  layer below it ([batch, T, top_k] and [batch, T] of a block): a chosen
+  set made in one layer is read by every layer up to the next that
+  chooses.  The step's state is then one cache a
   layer and a second on the layers that choose.
 - `hc` = {"streams", "eps", "magnitude", "iterations"}: the residual is
   `streams` streams [batch, T, streams, d_model], the embedding repeated,
@@ -83,7 +88,7 @@ which the tests hold this to.
 
 from .. import fluid
 from ..fluid.param_attr import ParamAttr
-from ..ops.attention import yarn_inv_freq, yarn_mscale
+from ..ops.attention import TILE_BYTES, yarn_inv_freq, yarn_mscale
 from .decode import PREFILL_BLOCK
 from ..obs import telemetry
 from .decoder_block import (block_positions, last, last_token_rows, linear,
@@ -139,7 +144,17 @@ def latent_moe_param_names(n_layer, n_dense, sandwich_norm=True,
 _BLOCK_BYTES = 5 << 28
 
 
-def prefill_block(batch, n_head, kv_rank, d_rope):
+# The token rows of an application past which a chooser's block gains
+# nothing: twice the 240 multiply-adds a byte of weights at which the
+# v5e's dense products turn from bound by the weights' read to bound by
+# arithmetic.  What such a block does not share, the gathers of its
+# positions' chosen sets, costs the same a position however many ride
+# together, and the step serves beside sessions that fill the chip
+# (dsv32-turn-16k-ep16: 88% of it), so a longer block buys memory alone
+_CHOOSER_ROWS = 512
+
+
+def prefill_block(batch, n_head, kv_rank, d_rope, indexer=None, max_len=0):
     """The positions of a row one application of the step prefills: the
     largest power of two, `models.decode.PREFILL_BLOCK` at most, whose
     absorbed queries and latent sums over `batch` rows stay within
@@ -147,10 +162,23 @@ def prefill_block(batch, n_head, kv_rank, d_rope):
     likely to see, and no remainder block is a second program.  256 rows
     of 128 heads over 512 + 64: 16 positions, 4096 tokens an
     application, which is compute-bound already (13.9 TFLOP, 71 ms at
-    the v5e's peak, against 12 ms to read the weights)."""
+    the v5e's peak, against 12 ms to read the weights).
+
+    With an `indexer` (heads, width, top_k) over `max_len` slots a
+    position also holds its index scores [max_len] float32 and its set
+    [top_k] int32, the budget is less the two tiles a chooser's block
+    works through (`ops.attention.TILE_BYTES`: the index scores before
+    the heads are summed, the gathered rows beside their attention's
+    scores), and the block stops at `_CHOOSER_ROWS` token rows: 16 rows
+    take 32 positions, 8 rows 64."""
     a_position = batch * n_head * (2 * kv_rank + d_rope) * 2
+    most, room = PREFILL_BLOCK, _BLOCK_BYTES
+    if indexer is not None:
+        a_position += batch * (max_len + indexer[2]) * 4
+        most = min(most, max(1, _CHOOSER_ROWS // batch))
+        room -= 2 * TILE_BYTES
     block = 1
-    while block < PREFILL_BLOCK and 2 * block * a_position <= _BLOCK_BYTES:
+    while block < most and 2 * block * a_position <= room:
         block *= 2
     return block
 
@@ -174,12 +202,11 @@ def build_latent_moe_cached_step_program(
     alone; `state_pairs` wires the caches and the position, advanced by
     T, into
     `fluid.ProgramDecoder` (pass max_positions=max_len), which prefills
-    a prompt `prefill_block(batch, n_head, kv_rank, d_rope)` positions an
-    application (the attention op carries the number as an attr).  With
-    an `indexer` the step takes one position: "tok" is int32 [batch],
-    there is also "index_cache_<i>" [batch, max_len, its width] a layer
-    that chooses (every layer, or those `indexer_types` calls "full"),
-    and T below is 1.
+    a prompt `prefill_block(batch, n_head, kv_rank, d_rope, indexer,
+    max_len)` positions an application (the attention op carries the
+    number as an attr).  With an `indexer` there is also
+    "index_cache_<i>" [batch, max_len, its width] a layer that chooses
+    (every layer, or those `indexer_types` calls "full").
 
     `parts` are **of the block's last position**, in shapes that T does
     not change (a decoder carries them through its scans as state pairs,
@@ -192,8 +219,9 @@ def build_latent_moe_cached_step_program(
     attention sub-layer's normed input, and "attn_out", that sub-layer's
     output (after `wo`, before any norm); and with an
     `indexer`, per layer, "selected" [batch, top_k] and "live"
-    [batch], the set the layer attends (a "shared" layer's are the
-    Variables of the layer it inherits from).  With `hc`, "hidden" is the
+    [batch], the set the block's last position attends in the layer (a
+    "shared" layer's are the Variables of the layer it inherits from).
+    With `hc`, "hidden" is the
     streams after the layer [batch, 1, streams, d_model], and per layer
     "streams_in" and "streams_out" are the streams before the attention
     sub-layer and after it."""
@@ -218,21 +246,16 @@ def build_latent_moe_cached_step_program(
         sm_scale = (d_nope + d_rope) ** -0.5 \
             * yarn_mscale(yarn["factor"], yarn.get("mscale", 1.0)) ** 2
 
-    # the all-slots step takes a block of positions; a chooser's step one
-    # position, and is built as it was
-    takes_block = indexer is None
-
     def rotate(x, heads, rotary_dim=None):
         return fluid.layers.rope(x, positions, heads, rope_theta,
                                  inv_freq=inv_freq, rotary_dim=rotary_dim,
-                                 full_width=takes_block)
+                                 full_width=True)
 
     main = fluid.Program()
     startup = fluid.Program()
     with fluid.program_guard(main, startup):
-        tok = fluid.layers.data(
-            name="tok", shape=[batch, -1] if takes_block else [batch],
-            dtype="int32", append_batch_size=False)
+        tok = fluid.layers.data(name="tok", shape=[batch, -1],
+                                dtype="int32", append_batch_size=False)
         pos = fluid.layers.data(name="pos", shape=[batch], dtype="int64",
                                 append_batch_size=False)
         caches = [fluid.layers.data(
@@ -251,22 +274,16 @@ def build_latent_moe_cached_step_program(
         # ids give [batch, T, d_model]; 0 keeps an axis as it comes
         x = fluid.layers.embedding(
             fluid.layers.reshape(x=fluid.layers.cast(tok, "int64"),
-                                 shape=[0, 0, 1] if takes_block
-                                 else [batch, 1, 1]),
+                                 shape=[0, 0, 1]),
             size=[vocab_size, d_model],
             param_attr=ParamAttr(name=names["embed"]))
-        if takes_block:
-            # T is read off the token feed; positions [batch, T] are
-            # pos .. pos + T - 1.  What a decoder is handed of the step
-            # is of the block's last position
-            ones, positions = block_positions(tok, pos, batch)
-            final, final_row = last, last_token_rows(ones, batch)
-            sized = {"prefill_block": prefill_block(batch, n_head, kv_rank,
-                                                    d_rope)}
-        else:
-            positions = fluid.layers.reshape(x=pos, shape=[batch, 1])
-            final = final_row = lambda t: t
-            sized = {}
+        # T is read off the token feed; positions [batch, T] are
+        # pos .. pos + T - 1.  What a decoder is handed of the step
+        # is of the block's last position
+        ones, positions = block_positions(tok, pos, batch)
+        final, final_row = last, last_token_rows(ones, batch)
+        sized = {"prefill_block": prefill_block(
+            batch, n_head, kv_rank, d_rope, indexer, max_len)}
 
         state_pairs = []
         parts = {"hidden": [], "attn_in": [], "attn_out": [], "top_w": [],
@@ -277,9 +294,7 @@ def build_latent_moe_cached_step_program(
         if hc is not None:
             # the embedding repeated: [batch, T, d] -> [batch, T, n, d]
             streams = fluid.layers.expand(
-                fluid.layers.reshape(
-                    x, [0, 0, 1, d_model] if takes_block
-                    else [batch, 1, 1, d_model]),
+                fluid.layers.reshape(x, [0, 0, 1, d_model]),
                 [1, 1, hc["streams"], 1])
             parts.update(streams_in=[], streams_out=[])
 
@@ -320,11 +335,18 @@ def build_latent_moe_cached_step_program(
                     rotate(k_index, 1, d_rope), index_caches[i], pos,
                     i_heads, i_top_k, scale=(i_heads * i_dim) ** -0.5)
                 chosen = {"selected": selected, "live": live}
+                # a set a position of the block, a position after a
+                # position a row: the last is a token row's
+                of_last = {
+                    "selected": final_row(
+                        fluid.layers.reshape(selected, [-1, i_top_k])),
+                    "live": fluid.layers.reshape(
+                        final_row(fluid.layers.reshape(live, [-1, 1])),
+                        [batch])}
             # a layer that does not choose attends the set of the nearest
             # layer below it that did (none: every slot)
-            if chosen:
-                parts["selected"].append(chosen["selected"])
-                parts["live"].append(chosen["live"])
+            for what in chosen:
+                parts[what].append(of_last[what])
             a_sink = {"sink_attr": ParamAttr(name=block["sink"])} \
                 if sink else {}
             o, cache_out = fluid.layers.mla_cached_attention(
@@ -382,8 +404,7 @@ def build_latent_moe_cached_step_program(
             x=(linear_float32 if head_float32 else linear)(
                 norm(x, eps, names["norm_f"]), vocab_size, names["head"]),
             shape=[batch, vocab_size])
-        pos_out = pos + fluid.layers.reduce_sum(ones) if takes_block \
-            else fluid.layers.increment(pos, value=1, in_place=False)
+        pos_out = pos + fluid.layers.reduce_sum(ones)
         state_pairs.append(("pos", pos_out.name))
     if indexer_types is not None and "shared" in indexer_types:
         telemetry.on_index_sets_reused(main, indexer_types.count("shared"))

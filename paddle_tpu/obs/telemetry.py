@@ -208,19 +208,24 @@ def on_moe_share_lowering(scored, held, top_k):
 
 
 def on_mla_cached_attention_lowering(heads, latent, rope, cache_dtype,
-                                     selected="all"):
+                                     selected="all", positions=1, tile=1):
     """A decode step of latent attention (`mla_cached_attention`,
-    ops/attention.py) was traced into a program, over every slot of its
-    cache (`selected` "all") or over so many chosen ones: one count per
-    op instance a lowered program holds."""
+    ops/attention.py), or a block of `positions` of them, was traced
+    into a program, over every slot of its cache (`selected` "all") or
+    over so many chosen ones a position, `tile` positions' sets gathered
+    and attended at once: one count per op instance a lowered program
+    holds."""
     _reg().counter("mla_cached_attention_lowerings_total",
                    "latent-attention decode steps lowered, by heads, "
-                   "latent and rotated-key widths, the cache's type and "
-                   "the slots attended (all, or so many chosen)",
+                   "latent and rotated-key widths, the cache's type, "
+                   "the slots attended (all, or so many chosen), the "
+                   "positions of a row the op took and how many of them "
+                   "it attends at once",
                    labelnames=("heads", "latent", "rope", "cache_dtype",
-                               "selected")) \
+                               "selected", "positions", "tile")) \
           .labels(heads=heads, latent=latent, rope=rope,
-                  cache_dtype=str(cache_dtype), selected=selected).inc()
+                  cache_dtype=str(cache_dtype), selected=selected,
+                  positions=positions, tile=tile).inc()
 
 
 def on_mla_decode_lowering(path, block_k, positions=1):
@@ -240,21 +245,25 @@ def on_mla_decode_lowering(path, block_k, positions=1):
           .labels(path=path, block_k=block_k, positions=positions).inc()
 
 
-def on_mla_index_select_lowering(heads, dim, top_k, cache_dtype, select):
+def on_mla_index_select_lowering(heads, dim, top_k, cache_dtype, select,
+                                 positions=1, tile=1):
     """A decode step of a chooser of cache slots (`mla_index_select`,
-    ops/attention.py) was traced into a program: one count per op
-    instance a lowered program holds.  `select` is how the op picks its
-    slots from the scores: "count" (kernels/topk_select.py: the
-    threshold by counting, the slots in slot order) or "sort"
+    ops/attention.py), or a block of `positions` of them whose scores
+    are made `tile` positions at a time, was traced into a program: one
+    count per op instance a lowered program holds.  `select` is how the
+    op picks its slots from the scores: "count" (kernels/topk_select.py:
+    the threshold by counting, the slots in slot order) or "sort"
     (`lax.top_k`, which the op no longer takes for any shape)."""
     _reg().counter("mla_index_select_lowerings_total",
                    "index-select decode steps lowered, by index heads, "
-                   "their width, the slots chosen, the key cache's type "
-                   "and the selection's form",
+                   "their width, the slots chosen, the key cache's type, "
+                   "the selection's form, the positions of a row the op "
+                   "took and how many of them it scores at once",
                    labelnames=("heads", "dim", "top_k", "cache_dtype",
-                               "select")) \
+                               "select", "positions", "tile")) \
           .labels(heads=heads, dim=dim, top_k=top_k,
-                  cache_dtype=str(cache_dtype), select=select).inc()
+                  cache_dtype=str(cache_dtype), select=select,
+                  positions=positions, tile=tile).inc()
 
 
 def on_moe_grouped_router_lowering(experts, groups, kept, top_k):

@@ -601,12 +601,62 @@ def _set_meta(block, name, shape, dtype):
 
 
 def _index_select_infer_shape(block, op_desc):
+    """`Selected` and `Live` follow Q's positions as the Program declares
+    them: [batch, top_k] and [batch] for a step declared one position a
+    call, [batch, T, top_k] and [batch, T] for a block axis, which stays
+    open (-1) where the Program leaves it open."""
     cache = block.var_recursive(op_desc.input("Cache")[0]).desc
+    steps = tuple(block.var_recursive(op_desc.input("Q")[0]).desc.shape)[1:2]
+    steps = () if steps in ((), (1,)) else steps
     top_k = int(op_desc.attrs["top_k"])
     _set_meta(block, op_desc.output("CacheOut")[0], cache.shape, cache.dtype)
     _set_meta(block, op_desc.output("Selected")[0],
-              (cache.shape[0], top_k), "int32")
-    _set_meta(block, op_desc.output("Live")[0], (cache.shape[0],), "int32")
+              (cache.shape[0],) + steps + (top_k,), "int32")
+    _set_meta(block, op_desc.output("Live")[0], (cache.shape[0],) + steps,
+              "int32")
+
+
+# What one tile of a chooser's block may hold: the index scores of its
+# positions before the heads are summed, [batch, P, heads, positions]
+# float32 (`mla_index_select`), or the rows its positions' chosen sets
+# name, gathered, beside their attention's scores (`mla_cached_attention`
+# with `Selected`).  DeepSeek-V3.2's share at 16 rows x 16,384 slots: 67
+# MB of index scores a position, four positions a tile; 37.7 MB of
+# gathered rows and 16.8 of scores a position, four again
+TILE_BYTES = 1 << 28
+
+
+def _tile_positions(block, a_position):
+    """The positions a tile of a block takes: the largest power of two
+    whose tile of `a_position` bytes a position stays within
+    `TILE_BYTES`, one at least and the block at most."""
+    tile = 1
+    while 2 * tile <= block and 2 * tile * a_position <= TILE_BYTES:
+        tile *= 2
+    return tile
+
+
+def _by_tiles(fn, block, tile, *xs):
+    """`fn(first, *tiles)` over the block's positions a tile at a time,
+    its results [batch, P, ...] side by side along the positions: `xs`
+    are [batch, block, ...], a tile is their positions first .. first +
+    P - 1.  The whole tiles are one loop (one traced body), what remains
+    of a block the tile does not divide is a shorter tile after it."""
+    whole, out = block // tile, []
+    if whole == 1:
+        out.append(fn(0, *(x[:, :tile] for x in xs)))
+    elif whole:
+        def body(_, first):
+            return None, fn(first, *(
+                jax.lax.dynamic_slice_in_dim(x, first, tile, axis=1)
+                for x in xs))
+
+        tiles = jax.lax.scan(body, None, jnp.arange(whole) * tile)[1]
+        out.append(jnp.moveaxis(tiles, 0, 1).reshape(
+            (tiles.shape[1], whole * tile) + tiles.shape[3:]))
+    if block % tile:
+        out.append(fn(whole * tile, *(x[:, whole * tile:] for x in xs)))
+    return out[0] if len(out) == 1 else jnp.concatenate(out, axis=1)
 
 
 @register_op("mla_index_select", stop_gradient_op=True,
@@ -614,35 +664,46 @@ def _index_select_infer_shape(block, op_desc):
 def mla_index_select_op(ctx, ins, attrs):
     """One decode step of a learned chooser of cache slots (the
     "lightning indexer" of DeepSeek-V3.2's sparse attention, as the
-    family's released inference code has it): a token keeps one small key a layer
-    in a cache of the chooser's own, and a query's few heads score every
-    live slot to pick the `top_k` the attention is to read.
+    family's released inference code has it), or a block of T
+    consecutive steps at once: a token keeps one small key a layer in a
+    cache of the chooser's own, and a query's few heads score every live
+    slot to pick the `top_k` the attention is to read.
 
-    Q [batch, 1, heads * dim] (rotated) and W [batch, 1, heads] are this
-    token's index queries and their weights, KNew [batch, 1, dim]
-    (normed, rotated) its index key; Cache [batch, positions, dim];
-    Position int [1] or [batch] (lockstep rows), the slot this step
-    writes.
+    Q [batch, T, heads * dim] (rotated) and W [batch, T, heads] are the
+    index queries and their weights of T >= 1 consecutive tokens of
+    every row (T = 1: a decode step; a prompt's prefill feeds many),
+    KNew [batch, T, dim] (normed, rotated) their index keys; Cache
+    [batch, positions, dim]; Position int [1] or [batch] (lockstep
+    rows), the slot the block's first token writes.  The T keys go to
+    slots Position .. Position + T - 1 first, then query t scores the
+    slots up to its own:
 
-        I_s = scale * sum_j W_j relu(q_j . k_s)      s <= Position
-        Selected = the top_k slots with the largest I_s
+        I_t,s = scale * sum_j W_t,j relu(q_t,j . k_s)    s <= Position + t
+        Selected_t = the top_k slots with the largest I_t,s
 
     The products take their operands in Q's type (a cache in a narrower
     type is read up to it) and add up in float32; relu, the weighted sum
     over the heads and the selection are float32.  A slot past Position
-    scores -inf and is never among the live ones.  The selection is
+    + t scores -inf and is never among the live ones.  The selection is
     `lax.top_k`'s set (of equal scores the lower slots) and orders
     nothing: kernels/topk_select.py finds the top_k-th largest score by
-    counting and writes the chosen slots as they lie in the cache.
-    Scopes: `dsa_index` holds the key's write and everything that makes
+    counting and writes the chosen slots as they lie in the cache, a row
+    of its input a query: batch * T rows of a block, eight a grid step.
+    A block's scores before the heads are summed, [batch, T, heads,
+    positions] float32, are never whole: they are made a tile of
+    positions at a time (`TILE_BYTES`), and what is kept of a tile is
+    its [batch, P, positions] sums.
+    Scopes: `dsa_index` holds the keys' write and everything that makes
     the scores, `dsa_select` the selection.
-    CacheOut is the cache with the slot written (a `ProgramDecoder`
-    state pair); Selected int32 [batch, top_k], the chosen set in
-    ascending slot order; Live int32 [batch] = min(top_k, Position + 1):
-    only the first Live entries of a row are slots to attend (the live
-    slots have the lowest numbers, so they come first), what follows
-    them (where fewer slots are live than were asked for) names slots
-    that hold nothing and must be masked.  No gradient, as
+    CacheOut is the cache with the slots written (a `ProgramDecoder`
+    state pair).  For T = 1: Selected int32 [batch, top_k], the chosen
+    set in ascending slot order; Live int32 [batch] = min(top_k,
+    Position + 1).  For T > 1 a set a position: Selected [batch, T,
+    top_k], Live [batch, T] = min(top_k, Position + t + 1).  Only the
+    first Live entries of a set are slots to attend (the live slots have
+    the lowest numbers, so they come first), what follows them (where
+    fewer slots are live than were asked for) names slots that hold
+    nothing and must be masked.  No gradient, as
     `mla_cached_attention`."""
     q, w, k_new = ins["Q"][0], ins["W"][0], ins["KNew"][0]
     cache = ins["Cache"][0]
@@ -660,9 +721,14 @@ def mla_index_select_op(ctx, ins, attrs):
     if top_k > positions:
         raise ValueError("mla_index_select: top_k %d of a cache of %d "
                          "positions" % (top_k, positions))
+    block = q.shape[1]
+    tile = _tile_positions(block, batch * heads * positions * 4)
     telemetry.on_mla_index_select_lowering(heads, dim, top_k, cache.dtype,
-                                           "count")
+                                           "count", block, tile)
     f32 = jnp.float32
+    if block > 1:
+        return _index_select_block(q, w, k_new, cache, pos, heads, top_k,
+                                   scale, tile)
 
     # everything that makes the [batch, positions] scores is one scope:
     # XLA fuses the heads' products into the sum that reads them, and a
@@ -687,6 +753,45 @@ def mla_index_select_op(ctx, ins, attrs):
         selected = topk_select.select_slots(score, top_k)
     live = jnp.full((batch,), jnp.minimum(top_k, pos + 1), jnp.int32)
     return {"CacheOut": [cache], "Selected": [selected], "Live": [live]}
+
+
+def _index_select_block(q, w, k_new, cache, pos, heads, top_k, scale, tile):
+    """`mla_index_select` for a block of T > 1 positions: the step's
+    arithmetic a query, the keys written once and read once a tile of
+    `tile` positions."""
+    batch, positions, dim = cache.shape
+    block = q.shape[1]
+    f32 = jnp.float32
+    with jax.named_scope("dsa_index"):
+        cache = jax.lax.dynamic_update_slice_in_dim(
+            cache, k_new.astype(cache.dtype), pos, axis=1)
+        keys = cache.astype(q.dtype)
+        slots = jnp.arange(positions)
+
+        def scores(first, q, w):
+            s = jnp.einsum("bphd,bsd->bphs", q, keys,
+                           preferred_element_type=f32)
+            score = jnp.einsum("bph,bphs->bps", w.astype(f32),
+                               jax.nn.relu(s),
+                               precision=jax.lax.Precision.HIGHEST)
+            if scale != 1.0:
+                score = score * scale
+            # query t of the block sees slots 0 .. pos + t
+            last = pos + first + jnp.arange(q.shape[1])
+            return jnp.where(slots <= last[:, None], score, -jnp.inf)
+
+        score = _by_tiles(scores, block, tile,
+                          q.reshape(batch, block, heads, dim), w)
+    with jax.named_scope("dsa_select"):
+        from ..kernels import topk_select
+        selected = topk_select.select_slots(
+            score.reshape(batch * block, positions), top_k)
+    live = jnp.broadcast_to(
+        jnp.minimum(top_k, pos + 1 + jnp.arange(block, dtype=jnp.int32)),
+        (batch, block))
+    return {"CacheOut": [cache],
+            "Selected": [selected.reshape(batch, block, top_k)],
+            "Live": [live]}
 
 
 def _mla_infer_shape(block, op_desc):
@@ -753,13 +858,24 @@ def mla_cached_attention_op(ctx, ins, attrs):
     contractions run over the gathered entries, and of a row's top_k
     entries the first Live count, the others are masked: a chosen set is
     a set, the softmax does not care for its order.  A chosen set is one
-    position's: with Selected and T > 1 the op raises.
+    position's: a block of T > 1 positions comes with Selected [batch,
+    T, top_k] and Live [batch, T], a set a position.  The block's T
+    entries are written first, then a tile of P positions at a time
+    (`TILE_BYTES`) the rows of the tile's sets are gathered (one gather
+    of [batch, P * top_k] rows, of the kind a step makes) and the two
+    contractions run over [batch, P, heads, top_k], position t masked by
+    Live[b, t]; the queries are absorbed and the values projected up
+    once for the whole block.  Nothing is shared between the sets: the
+    gathers of a block are those of its T steps, everything else is read
+    once.
 
     With Sink float32 [heads] (a learned sink: one logit a head) the
     softmax's denominator holds exp(Sink_h) beside the attended slots'
     terms, and the sink has no value: p_h,t = exp(s_h,t) / (exp(Sink_h)
     + sum_t' exp(s_h,t')), so a head may attend nothing much.  The sink
-    is the plain path's: the walk of the live slots does not take it."""
+    is the plain path's: the walk of the live slots does not take it.
+    A block over chosen sets takes it and a gate downstream as a step
+    does."""
     q_nope, q_rope = ins["QNope"][0], ins["QRope"][0]
     c_new, r_new = ins["CNew"][0], ins["RNew"][0]
     cache, w_uk, w_uv = ins["Cache"][0], ins["WUk"][0], ins["WUv"][0]
@@ -775,12 +891,13 @@ def mla_cached_attention_op(ctx, ins, attrs):
             "the latent is %d wide and the rotated key %d; W_uk is %s"
             % (width, latent, rope_dim, w_uk.shape))
     block = q_nope.shape[1]
-    if block > 1 and selected is not None:
+    if selected is not None and tuple(selected.shape[:-1]) \
+            != ((batch,) if block == 1 else (batch, block)):
         raise ValueError(
-            "mla_cached_attention: a block of %d positions with Selected: "
-            "a chosen set is one position's, and `mla_index_select` "
-            "chooses for one; feed such a step a position at a time"
-            % block)
+            "mla_cached_attention: Selected %s with a block of %d "
+            "positions of %d rows: a chosen set is one position's, "
+            "[batch, top_k] for a step and [batch, T, top_k] for a block "
+            "(`mla_index_select`'s)" % (selected.shape, block, batch))
     if block > int(attrs.get("prefill_block", 0) or block):
         raise ValueError(
             "mla_cached_attention: a block of %d positions, and the op "
@@ -789,10 +906,15 @@ def mla_cached_attention_op(ctx, ins, attrs):
     nope = q_nope.shape[-1] // heads
     sm_scale = float(attrs.get("sm_scale", 0.0)) \
         or (nope + rope_dim) ** -0.5
+    dtype = q_nope.dtype
+    # the positions of a block over chosen sets that are gathered and
+    # attended at once: their gathered rows beside their scores
+    tile = block if selected is None else _tile_positions(
+        block, batch * selected.shape[-1] * (
+            width * jnp.dtype(dtype).itemsize + heads * 4))
     telemetry.on_mla_cached_attention_lowering(
         heads, latent, rope_dim, cache.dtype,
-        "all" if selected is None else selected.shape[-1])
-    dtype = q_nope.dtype
+        "all" if selected is None else selected.shape[-1], block, tile)
     f32 = jnp.float32
     # the walk of the live slots (kernels/mla_decode.py) where what the
     # op sees of its inputs fits it, the plain path otherwise
@@ -812,6 +934,11 @@ def mla_cached_attention_op(ctx, ins, attrs):
                                                              width)
     cache = jax.lax.dynamic_update_slice_in_dim(
         cache, entry.astype(cache.dtype), pos, axis=1)
+    if selected is not None and block > 1:
+        out = _attend_chosen_block(
+            q_nope, q_rope, cache, w_uk, w_uv, selected, ins["Live"][0],
+            sink, heads, sm_scale, tile)
+        return {"Out": [out], "CacheOut": [cache]}
     if selected is None:
         # a cache in a narrower type than the products' is read up to it
         live = cache.astype(dtype)
@@ -882,12 +1009,9 @@ def mla_cached_attention_op(ctx, ins, attrs):
             else:
                 # a query row is a head's (a step) or position t's head
                 # h at row t * heads + h (a block)
-                z = jnp.tile(sink.astype(f32).reshape(heads), block)[
-                    None, :, None]
-                top = jnp.maximum(jnp.max(s, axis=-1, keepdims=True), z)
-                e = jnp.exp(s - top)
-                p = e / (jnp.sum(e, axis=-1, keepdims=True)
-                         + jnp.exp(z - top))
+                p = _softmax_beside_a_sink(
+                    s, jnp.tile(sink.astype(f32).reshape(heads), block)[
+                        None, :, None])
     with jax.named_scope("mla_values"):
         if not blocks:
             o_lat = jnp.einsum("bht,btw->bhw", p.astype(dtype), live,
@@ -899,3 +1023,62 @@ def mla_cached_attention_op(ctx, ins, attrs):
             preferred_element_type=f32)
     return {"Out": [out.reshape(batch, block, -1).astype(dtype)],
             "CacheOut": [cache]}
+
+
+def _softmax_beside_a_sink(s, z):
+    """softmax of the scores `s` over their last axis with one more term
+    in the denominator, exp(`z`) (a sink's logit, shaped to broadcast
+    against `s` with a last axis of 1), and none in the result."""
+    top = jnp.maximum(jnp.max(s, axis=-1, keepdims=True), z)
+    e = jnp.exp(s - top)
+    return e / (jnp.sum(e, axis=-1, keepdims=True) + jnp.exp(z - top))
+
+
+def _attend_chosen_block(q_nope, q_rope, cache, w_uk, w_uv, selected, live,
+                         sink, heads, sm_scale, tile):
+    """`mla_cached_attention` for a block of T > 1 positions that each
+    attend a chosen set: Out [batch, T, heads * value] over `cache` with
+    the block's entries written, `selected` [batch, T, top_k] and `live`
+    [batch, T].  A step's arithmetic a position; the heads' matrices are
+    read once for the block, the sets' rows gathered and attended a tile
+    of `tile` positions at a time."""
+    batch, block, top_k = selected.shape
+    latent, width = w_uk.shape[0], cache.shape[-1]
+    dtype, f32 = q_nope.dtype, jnp.float32
+    with jax.named_scope("mla_absorb"):
+        q_lat = jnp.einsum(
+            "bthd,chd->bthc", q_nope.reshape(batch, block, heads, -1),
+            w_uk.reshape(latent, heads, -1).astype(dtype),
+            preferred_element_type=f32).astype(dtype)
+        q = jnp.concatenate(
+            [q_lat, q_rope.reshape(batch, block, heads, -1)], axis=-1)
+    z = None if sink is None else sink.astype(f32).reshape(heads, 1)
+
+    def attend(first, q, selected, live):
+        held = selected.shape[1]
+        with jax.named_scope("dsa_gather"):
+            # an entry is clipped into the extent, not filled in
+            # afterwards (a pass over the copy): a dead one may name
+            # anything, and what it fetches is masked
+            rows = jnp.take_along_axis(
+                cache, selected.reshape(batch, held * top_k, 1)
+                .astype(jnp.int32), axis=1, mode="clip").astype(dtype) \
+                .reshape(batch, held, top_k, width)
+        with jax.named_scope("mla_scores"):
+            s = jnp.einsum("bphw,bpkw->bphk", q, rows,
+                           preferred_element_type=f32) * sm_scale
+            valid = jnp.arange(top_k) < live.reshape(batch, held, 1, 1)
+            s = jnp.where(valid, s, -1e30)
+            p = jax.nn.softmax(s, axis=-1) if z is None \
+                else _softmax_beside_a_sink(s, z)
+        with jax.named_scope("mla_values"):
+            return jnp.einsum("bphk,bpkw->bphw", p.astype(dtype), rows,
+                              preferred_element_type=f32)[
+                                  ..., :latent].astype(dtype)
+
+    o_lat = _by_tiles(attend, block, tile, q, selected, live)
+    with jax.named_scope("mla_values"):
+        out = jnp.einsum("bthc,chd->bthd", o_lat,
+                         w_uv.reshape(latent, heads, -1).astype(dtype),
+                         preferred_element_type=f32)
+    return out.reshape(batch, block, -1).astype(dtype)

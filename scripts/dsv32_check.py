@@ -5,18 +5,24 @@ timed window and FROM POSITION 0, through the step's own caches (PERF.md
 section 6, PR 38).
 
     python scripts/dsv32_check.py [--config deepseek-v3.2]
-        [--seeds 7,8,9] [--rows 8] [--positions 2304]
+        [--seeds 7,8,9] [--rows 8] [--positions 2304] [--block 64]
         [--index-dtype float8_e4m3fn | --top-k 1024]     (the controls)
 
 The cached step Program (the lightning indexer and its key cache,
 absorbed latent attention over the chosen slots, the group-limited
 router, the held range of the routed experts), in the types it is served
 in, is driven over `--positions` seeded tokens of `--rows` sequences, one
-scan of step applications as `ProgramDecoder` prefills and decodes: 256
-positions past `index_topk`, so the last 256 steps choose 2048 of up to
+scan of step applications as `ProgramDecoder` prefills: `--block`
+positions an application (default: what the step's own `prefill_block`
+says for these rows, 64 for 8; 1 is a position a call, as the step
+decodes), 256
+positions past `index_topk`, so the last 256 queries choose 2048 of up to
 2304 slots and every earlier one attends all it has.  Every position's
-logits, every layer's output, chosen slots and chosen experts, and the
-caches as the last step left them are kept.  The program's weights are
+layer outputs, chosen slots and chosen experts (read where the block
+still holds all its positions, before the step cuts out its last), the
+logits after every application (the step's head reads a block's last
+position alone) and the caches as the last application left them are
+kept.  The program's weights are
 then let go of and the reference
 (paddle_tpu/models/reference/deepseek_v32.py: the unabsorbed
 full-sequence forward, dense [T, T] index scores, a boolean mask, no
@@ -65,6 +71,9 @@ def main(argv=None):
     p.add_argument("--seeds", default="7")
     p.add_argument("--rows", type=int, default=8)
     p.add_argument("--positions", type=int, default=2304)
+    p.add_argument("--block", type=int, default=0,
+                   help="positions an application (default: the step's "
+                        "own prefill_block)")
     p.add_argument("--search-path", action="append", default=[])
     p.add_argument("--index-dtype", default=None,
                    help="a control: the type the index keys are cached in")
@@ -129,9 +138,28 @@ def check(args, cfg, workload, model, seed):
     names = latent_moe_param_names(layers, dense, sandwich_norm=False,
                                    indexer=True, router_bias=True)
     feeds = ["tok"] + [f for f, _ in pairs]
+    ops = main.global_block().desc.ops
+    made_by = {name: od for od in ops for name in od.output_names()}
+
+    def whole(name):
+        """What a part of the block's last position was cut from: the
+        Variable of all the block's positions."""
+        while made_by[name].type in ("slice", "gather", "reshape"):
+            od = made_by[name]
+            name = od.input("Input" if od.type == "slice" else "X")[0]
+        return name
+
     kept = ("hidden", "selected", "top_idx")
     fetches = [logits.name] + [o for _, o in pairs] \
-        + [v.name for key in kept for v in parts[key]]
+        + [whole(v.name) for key in kept for v in parts[key]]
+    span = args.block or min(
+        od.attrs["prefill_block"] for od in ops if "prefill_block" in od.attrs)
+    if positions % span:
+        raise SystemExit("dsv32_check: %d positions are not whole blocks "
+                         "of %d" % (positions, span))
+    print("blocks of %d positions, %d applications" % (span,
+                                                       positions // span),
+          flush=True)
     fp = FunctionalProgram(main.clone(for_test=True), feeds, fetches)
     key = jax.random.PRNGKey(seed)
     made = jax.block_until_ready(
@@ -159,18 +187,23 @@ def check(args, cfg, workload, model, seed):
             return new, (out[0],) + tuple(out[1 + n_state:])
         return jax.lax.scan(body, state, toks)
 
-    last, out = jax.device_get(jax.jit(run)(params, state,
-                                            jnp.asarray(tokens.T)))
+    # [applications, rows, block]
+    last, out = jax.device_get(jax.jit(run)(params, state, jnp.asarray(
+        tokens.reshape(rows, -1, span).swapaxes(0, 1))))
     del params
     gc.collect()
-    # [positions, rows, ...] -> [rows, positions, ...]
+
+    def by_row(a):
+        """[applications, rows (x) block, n] -> [rows, positions, n]"""
+        a = np.asarray(a)
+        a = a.reshape(a.shape[0], rows, span, a.shape[-1])
+        return np.swapaxes(a, 0, 1).reshape(rows, positions, a.shape[-1])
+
+    # the logits of each application's last position
     got_logits = np.swapaxes(np.asarray(out[0], np.float32), 0, 1)
-    got_hidden = [np.swapaxes(np.asarray(h, np.float32)[:, :, 0], 0, 1)
-                  for h in out[1:1 + layers]]
-    got_selected = [np.swapaxes(np.asarray(s), 0, 1)
-                    for s in out[1 + layers:1 + 2 * layers]]
-    got_idx = [np.swapaxes(np.asarray(i), 0, 1)
-               for i in out[1 + 2 * layers:]]
+    got_hidden = [by_row(h).astype(np.float32) for h in out[1:1 + layers]]
+    got_selected = [by_row(s) for s in out[1 + layers:1 + 2 * layers]]
+    got_idx = [by_row(i) for i in out[1 + 2 * layers:]]
     del out
     top_k = got_selected[0].shape[-1]
     # the program's selection as the reference's boolean mask
@@ -242,9 +275,10 @@ def check(args, cfg, workload, model, seed):
                 and cache_off <= args.cache_tol
             del block
         eps = cfg["rms_norm_eps"]
+        # at the positions the step's head read
         z = np.concatenate([np.asarray(
-            reference.rms_norm(x, ends["norm_f"], eps) @ ends["head"])
-            for x in xs])
+            reference.rms_norm(x[:, span - 1::span], ends["norm_f"], eps)
+            @ ends["head"]) for x in xs])
     first_tok = got_logits.argmax(-1)
     picked = np.take_along_axis(z, first_tok[..., None], -1)[..., 0]
     gaps = z.max(-1) - picked

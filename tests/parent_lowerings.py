@@ -21,6 +21,15 @@ and the linear/full builders, op for op, and the jaxpr of a generation
 call with a prompt of the three kinds of step that prefill in blocks of
 `models.decode.PREFILL_BLOCK` (GPT-2's, the window/full one, the
 linear/full one), a remainder block and one block of 128.
+
+Since PR 62 a chooser's step takes a block too, so "program indexer" of
+that recording is held to its products as pangu's is, and what PR 62
+promised to leave alone has a recording of its own (`step_lowerings`,
+tests/data/parent_lowerings_pr61.json, written by `python
+tests/parent_lowerings.py steps > tests/data/parent_lowerings_pr61.json`
+on a checkout of commit cc05484, PR 61's): the jaxprs of
+`mla_index_select` and of `mla_cached_attention` over a chosen set, with
+a sink and without, at one position a row.
 """
 
 import json
@@ -30,6 +39,7 @@ import sys
 _DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
 RECORDING = os.path.join(_DATA, "parent_lowerings_pr37.json")
 BLOCK_RECORDING = os.path.join(_DATA, "parent_lowerings_pr52.json")
+STEP_RECORDING = os.path.join(_DATA, "parent_lowerings_pr61.json")
 
 
 def program_text(main):
@@ -166,7 +176,59 @@ def block_lowerings():
     return out
 
 
+def step_lowerings():
+    """{name: text}: the jaxprs of a chooser's two ops at one position a
+    row (T = 1), on small seeded inputs in float32 and bfloat16: the
+    chooser, the attention over its set, and that with a sink."""
+    import numpy as np
+
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import registry
+
+    rs = np.random.RandomState(0)
+    b, t, h, nope, rope, latent, dv, ih, idim, top_k = \
+        2, 24, 4, 8, 4, 8, 8, 4, 8, 6
+
+    def jaxpr(op, ins, attrs):
+        kernel = registry.get_op_info(op).kernel
+        return str(jax.make_jaxpr(lambda i: kernel(None, i, attrs))(ins))
+
+    out = {}
+    for dtype in (jnp.float32, jnp.bfloat16):
+        def draw(*shape):
+            return jnp.asarray(rs.randn(*shape), dtype)
+
+        name = jnp.dtype(dtype).name
+        position = [jnp.full((b,), 9, jnp.int32)]
+        out["mla_index_select %s" % name] = jaxpr(
+            "mla_index_select",
+            {"Q": [draw(b, 1, ih * idim)], "W": [draw(b, 1, ih)],
+             "KNew": [draw(b, 1, idim)], "Cache": [draw(b, t, idim)],
+             "Position": position},
+            {"num_heads": ih, "top_k": top_k, "scale": 0.25})
+        chosen = {
+            "QNope": [draw(b, 1, h * nope)], "QRope": [draw(b, 1, h * rope)],
+            "CNew": [draw(b, 1, latent)], "RNew": [draw(b, 1, rope)],
+            "Cache": [draw(b, t, latent + rope)],
+            "WUk": [draw(latent, h * nope)], "WUv": [draw(latent, h * dv)],
+            "Position": position,
+            "Selected": [jnp.asarray(
+                np.sort(rs.rand(b, t).argsort(-1)[:, :top_k], -1),
+                jnp.int32)],
+            "Live": [jnp.full((b,), top_k, jnp.int32)]}
+        out["mla_cached_attention chosen %s" % name] = jaxpr(
+            "mla_cached_attention", chosen, {"num_heads": h})
+        out["mla_cached_attention chosen sink %s" % name] = jaxpr(
+            "mla_cached_attention",
+            dict(chosen, Sink=[jnp.asarray(rs.randn(h), jnp.float32)]),
+            {"num_heads": h, "sm_scale": 0.3})
+    return out
+
+
 if __name__ == "__main__":
     sys.path.insert(0, os.getcwd())
-    json.dump(block_lowerings() if sys.argv[1:] == ["blocks"]
-              else lowerings(), sys.stdout, indent=1, sort_keys=True)
+    json.dump({"blocks": block_lowerings, "steps": step_lowerings}.get(
+        "".join(sys.argv[1:]), lowerings)(), sys.stdout, indent=1,
+        sort_keys=True)

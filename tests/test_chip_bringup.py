@@ -453,6 +453,48 @@ def test_the_choosers_selection_lowers_one_kernel_for_tpu(rows, slots, heads,
     assert "stablehlo.sort" not in module and "top_k" not in module
 
 
+@pytest.mark.parametrize("rows,block,slots,heads,dim,tile,name", [
+    (16, 32, 16384, 64, 128, 4, "topk_select_s16384_k2048"),
+    (8, 64, 32768, 32, 128, 8, "topk_select_s32768_k2048")])
+def test_a_block_of_the_choosers_positions_lowers_for_tpu(rows, block, slots,
+                                                          heads, dim, tile,
+                                                          name):
+    """`mla_index_select` over a block of a question's positions at
+    dsv32-turn-16k-ep16's and hy4-turn-32k-ep16's shapes (the blocks
+    their steps state: 32 positions of 16 rows, 64 of 8) lowered for the
+    TPU from this CPU host: still the one selection kernel, over rows x
+    block rows of scores; the heads' scores are a tile of positions
+    inside a loop and never the whole block's; no sort."""
+    from paddle_tpu.models.latent_moe_program import prefill_block
+    from paddle_tpu.ops import registry
+
+    assert prefill_block(rows, 128 if rows == 16 else 64, 512, 64,
+                         (heads, dim, 2048), slots) == block
+    kernel = registry.get_op_info("mla_index_select").kernel
+    bf16 = jnp.bfloat16
+    ins = {"Q": [jax.ShapeDtypeStruct((rows, block, heads * dim), bf16)],
+           "W": [jax.ShapeDtypeStruct((rows, block, heads), bf16)],
+           "KNew": [jax.ShapeDtypeStruct((rows, block, dim), bf16)],
+           "Cache": [jax.ShapeDtypeStruct((rows, slots, dim), bf16)],
+           "Position": [jax.ShapeDtypeStruct((rows,), jnp.int32)]}
+
+    def step(ins):
+        return kernel(None, ins, {"num_heads": heads, "top_k": 2048})
+
+    exported = jax.export.export(jax.jit(step), platforms=["tpu"])(ins)
+    assert [tuple(a.shape) for a in exported.out_avals] == [
+        (rows, slots, dim), (rows, block), (rows, block, 2048)]
+    module = exported.mlir_module()
+    assert module.count("tpu_custom_call") == 1
+    assert 'kernel_name = "%s"' % name in module
+    assert "stablehlo.sort" not in module and "top_k" not in module
+    assert "stablehlo.while" in module
+    assert "tensor<%dx%dx%dx%dxf32>" % (rows, tile, heads, slots) in module
+    assert "tensor<%dx%dx%dx%dxf32>" % (rows, block, heads, slots) \
+        not in module
+    assert "tensor<%dx%dxf32>" % (rows * block, slots) in module
+
+
 def test_the_sink_and_the_streams_lower_for_tpu_without_a_kernel():
     """hy4-turn-32k-ep16's step at its shapes (8 rows, 64 heads of 192 +
     64 over 512 latents, 2048 chosen of 32,768 bfloat16 slots, a sink a

@@ -198,6 +198,101 @@ def test_greedy_through_the_decoder_is_the_references_greedy(built):
     assert gap.max() <= 1e-4
 
 
+# -- (a') a block of positions through the step --------------------------------
+
+@pytest.fixture(scope="module")
+def probed(built):
+    """A decoder over the same step and weights that also carries each
+    layer's `selected` and `live` out as state the step only writes."""
+    probes = [("probe_%d.%s" % (i, what), built["parts"][what][i].name)
+              for i in range(L) for what in ("selected", "live")]
+    decoder = _decoder(built["main"], built["logits"],
+                       built["pairs"] + probes, built["scope"])
+
+    def state(held):
+        held = dict(held)
+        for i in range(L):
+            held["probe_%d.selected" % i] = jnp.zeros((B, TOPK), jnp.int32)
+            held["probe_%d.live" % i] = jnp.zeros((B,), jnp.int32)
+        return held
+
+    return decoder, state
+
+
+@pytest.mark.parametrize("start,block,tile", [
+    (0, 5, 0), (3, 9, 2), (TOPK - 2, 7, 4), (12, 16, 4)],
+    ids=["under top_k", "tiles of 2 and one over", "across top_k",
+         "past top_k"])
+def test_a_block_through_the_step_is_so_many_single_steps(
+        built, probed, start, block, tile, tile_bytes):
+    """T tokens of every row in one application against T applications
+    of one: the last position's logits, both caches a layer, the
+    position, and each layer's set and `Live` of the block's last
+    position, in the shapes a step gives them."""
+    if tile:
+        # a position's index scores are B * IH * T * 4 bytes, its
+        # gathered rows and their scores B * TOPK * (24 + H) * 4: 3072
+        # and 1792, so both ops work through tiles of `tile` positions
+        tile_bytes(tile * B * IH * T * 4)
+    decoder, with_probes = probed
+    tokens = built["tokens"]
+    step = decoder._step_fn(decoder._params)
+    state = with_probes(_empty())
+    if start:
+        _, state = _drive(decoder, tokens[:, :start], state)
+    want, after = _drive(decoder, tokens[:, start:start + block], state)
+    logits, got = step(state, jnp.asarray(tokens[:, start:start + block]))
+    assert logits.shape == (B, V)
+    assert np.abs(np.asarray(logits) - want[:, -1]).max() \
+        <= LOGITS_RTOL * np.abs(want[:, -1]).max()
+    assert int(got["pos"][0]) == start + block == int(after["pos"][0])
+    for i in range(L):
+        for cache in ("latent_cache_%d" % i, "index_cache_%d" % i):
+            np.testing.assert_allclose(
+                got[cache], after[cache],
+                atol=LOGITS_RTOL * np.abs(np.asarray(after[cache])).max())
+            assert not np.asarray(got[cache])[:, start + block:].any()
+        np.testing.assert_array_equal(got["probe_%d.selected" % i],
+                                      after["probe_%d.selected" % i])
+        assert got["probe_%d.selected" % i].shape == (B, TOPK)
+        assert np.asarray(got["probe_%d.live" % i]).tolist() \
+            == [min(TOPK, start + block)] * B
+
+
+@pytest.mark.parametrize("prompt_len,block", [(11, 4), (20, 8), (7, 128)],
+                         ids=["3 + 2 x 4", "4 + 2 x 8", "one short block"])
+def test_a_prompt_is_prefilled_in_blocks(built, prompt_len, block):
+    """`ProgramDecoder` reads the declaration: the prompt goes through
+    the chooser's step `block` positions an application (the remainder
+    first), and every served token is the reference's first given the
+    tokens before it."""
+    assert built["decoder"]._takes_block
+    assert built["decoder"]._prefill_block == 128    # 2 rows: the most
+    decoder = _decoder(built["main"], built["logits"], built["pairs"],
+                       built["scope"])
+    decoder._prefill_block = block
+    prompt = built["tokens"][:, :prompt_len]
+    gen = T - prompt_len + 1
+    before = telemetry.snapshot()
+    tokens, lengths = decoder.greedy(bos=0, eos=V, max_len=gen,
+                                     init_state=_empty(), prompt=prompt)
+    traced = telemetry.snapshot_delta(before)
+    assert traced["prefill_lowerings_total{block=%d,form=block}"
+                  % block] == 1
+    # the chooser was traced for the remainder, a block and a step
+    sizes = {prompt_len % block, min(block, prompt_len), 1} - {0}
+    assert {int(k.split("positions=")[1].split(",")[0]) for k in traced
+            if k.startswith("mla_index_select_lowerings_total")} == sizes
+    assert tokens.shape == (B, gen) and (lengths == gen).all()
+    full = np.concatenate([prompt, tokens], axis=1)[:, :T]
+    z = np.asarray(reference.forward(
+        CFG, built["params"], jnp.asarray(full), held=HELD)["logits"])
+    at = prompt_len - 1
+    served = tokens[:, :T - at]
+    picked = np.take_along_axis(z[:, at:], served[..., None], axis=-1)[..., 0]
+    assert (z[:, at:].max(axis=-1) - picked).max() <= 1e-4
+
+
 # -- (b) continued from a handed-in session ------------------------------------
 
 @pytest.fixture(scope="module")
@@ -361,6 +456,96 @@ def test_index_select_refuses_what_it_cannot_do():
     assert registry.get_op_info("mla_index_select").stop_gradient_op
 
 
+def _index_block_ins(rs, block, pos, dtype=jnp.float32):
+    q = jnp.asarray(rs.randn(B, block, IH * ID), dtype)
+    w = jnp.asarray(rs.uniform(-0.5, 1.0, (B, block, IH)), dtype)
+    k_new = jnp.asarray(rs.randn(B, block, ID), dtype)
+    cache = jnp.asarray(rs.randn(B, T, ID), dtype).at[:, pos:].set(0)
+    return {"Q": [q], "W": [w], "KNew": [k_new], "Cache": [cache],
+            "Position": [jnp.full((B,), pos, jnp.int32)]}
+
+
+def _one_position(ins, t, at, **state):
+    """Position t of a block's inputs as a step's, at slot `at`."""
+    return dict(
+        {k: [v[0][:, t:t + 1]] if v[0].ndim == 3 and k != "Cache"
+         and not k.startswith("W") or k == "W" else v
+         for k, v in ins.items()},
+        Position=[jnp.full((B,), at, jnp.int32)],
+        **{k: [v] for k, v in state.items()})
+
+
+@pytest.fixture
+def tile_bytes(monkeypatch):
+    """`set(n)`: a tile of a chooser's block may hold n bytes (the tiny
+    shapes here are one tile at the op's own 256 MB)."""
+    from paddle_tpu.ops import attention
+    return lambda n: monkeypatch.setattr(attention, "TILE_BYTES", n)
+
+
+# a tile of index scores is B * IH * T * 4 = 3072 bytes a position here
+@pytest.mark.parametrize("block,pos,tile", [
+    (2, 0, 1), (5, 0, 8), (7, 3, 2), (16, TOPK - 3, 4), (13, 20, 4),
+    (T - 1, 1, 16)],
+    ids=["two from empty", "one tile", "tiles of 2 and one over",
+         "across top_k", "tiles of 4 and one over", "to the extent's end"])
+def test_a_block_through_the_chooser_is_so_many_single_steps(
+        block, pos, tile, tile_bytes):
+    """T positions at once against T applications of one: the same set
+    a position bit for bit, `Live` a position (fewer live than `top_k`
+    where `pos + t + 1 < top_k`), the same cache; with the block one
+    tile, several tiles, and tiles that do not divide it."""
+    tile_bytes(tile * B * IH * T * 4)
+    ins = _index_block_ins(np.random.RandomState(block + pos), block, pos)
+    kernel = registry.get_op_info("mla_index_select").kernel
+    attrs = {"num_heads": IH, "top_k": TOPK, "scale": 0.5}
+    before = telemetry.snapshot()
+    got = kernel(None, ins, attrs)
+    assert telemetry.snapshot_delta(before)[
+        "mla_index_select_lowerings_total{cache_dtype=float32,dim=%d,"
+        "heads=%d,positions=%d,select=count,tile=%d,top_k=%d}"
+        % (ID, IH, block, min(tile, 1 << (block.bit_length() - 1)),
+           TOPK)] == 1
+    assert got["Selected"][0].shape == (B, block, TOPK)
+    assert got["Selected"][0].dtype == jnp.int32
+    assert got["Live"][0].shape == (B, block)
+    cache = ins["Cache"][0]
+    for t in range(block):
+        one = kernel(None, _one_position(ins, t, pos + t, Cache=cache),
+                     attrs)
+        cache = one["CacheOut"][0]
+        np.testing.assert_array_equal(got["Selected"][0][:, t],
+                                      one["Selected"][0])
+        np.testing.assert_array_equal(got["Live"][0][:, t], one["Live"][0])
+        assert int(one["Live"][0][0]) == min(TOPK, pos + t + 1)
+    np.testing.assert_array_equal(got["CacheOut"][0], cache)
+
+
+def test_a_block_of_scores_is_never_whole(tile_bytes):
+    """The [batch, T, heads, positions] scores are made a tile at a
+    time: the largest float32 array a traced block holds is a tile's,
+    inside one loop over the whole tiles."""
+    tile_bytes(4 * B * IH * T * 4)
+    ins = _index_block_ins(np.random.RandomState(0), 16, 4)
+    kernel = registry.get_op_info("mla_index_select").kernel
+    jaxpr = jax.make_jaxpr(lambda i: kernel(
+        None, i, {"num_heads": IH, "top_k": TOPK})["Selected"][0])(ins)
+    loops = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "scan"]
+    assert len(loops) == 1 and loops[0].params["length"] == 4
+
+    def shapes(eqns):
+        for e in eqns:
+            for v in e.outvars:
+                yield tuple(v.aval.shape)
+            for sub in jax.core.jaxprs_in_params(e.params):
+                if e.primitive.name != "pjit":
+                    yield from shapes(sub.eqns)
+
+    found = set(shapes(jaxpr.jaxpr.eqns))
+    assert (B, 4, IH, T) in found and (B, 16, IH, T) not in found
+    assert (B, 16, T) in found
+
+
 # -- attention over a chosen set -------------------------------------------------
 
 def _mla_ins(rs, pos):
@@ -408,6 +593,82 @@ def test_a_dead_entry_of_the_selection_is_not_attended():
         kernel(None, ins, {"num_heads": H})["Out"][0])).max() > 1e-3
 
 
+def _chosen_block_ins(rs, block, pos):
+    """A block's inputs with a set a position: `TOPK` slots of the live
+    ones, or all of those and dead ones after them where fewer are
+    live."""
+    def draw(*shape):
+        return jnp.asarray(rs.randn(*shape), jnp.float32)
+
+    selected = np.zeros((B, block, TOPK), np.int32)
+    live = np.zeros((B, block), np.int32)
+    for row in range(B):
+        for t in range(block):
+            n = min(TOPK, pos + t + 1)
+            selected[row, t] = np.concatenate([
+                np.sort(rs.permutation(pos + t + 1)[:n]),
+                np.arange(pos + t + 1, pos + t + 1 + TOPK - n)])
+            live[row, t] = n
+    cache = draw(B, T, KVR + ROPE).at[:, pos:].set(0)
+    return {"QNope": [draw(B, block, H * NOPE)],
+            "QRope": [draw(B, block, H * ROPE)],
+            "CNew": [draw(B, block, KVR)], "RNew": [draw(B, block, ROPE)],
+            "Cache": [cache], "WUk": [0.3 * draw(KVR, H * NOPE)],
+            "WUv": [0.3 * draw(KVR, H * DV)],
+            "Position": [jnp.full((B,), pos, jnp.int32)],
+            "Selected": [jnp.asarray(selected)], "Live": [jnp.asarray(live)]}
+
+
+# a tile of gathered rows and scores is B * TOPK * (24 * 4 + H * 4) bytes
+# a position here
+@pytest.mark.parametrize("sink", [False, True], ids=["", "sink"])
+@pytest.mark.parametrize("block,pos,tile", [
+    (2, 0, 1), (6, 2, 8), (7, 5, 2), (13, 20, 4)],
+    ids=["two from empty", "one tile", "tiles of 2 and one over",
+         "tiles of 4 and one over"])
+def test_a_block_over_chosen_sets_is_so_many_single_steps(
+        block, pos, tile, sink, tile_bytes):
+    """T positions that each attend a set of their own against T steps:
+    every position's output, the cache; a set may name the block's own
+    slots (they are written first); with a sink and without."""
+    tile_bytes(tile * B * TOPK * ((KVR + ROPE) * 4 + H * 4))
+    rs = np.random.RandomState(block + pos)
+    ins = _chosen_block_ins(rs, block, pos)
+    if sink:
+        ins["Sink"] = [jnp.asarray(rs.randn(H), jnp.float32)]
+    kernel = registry.get_op_info("mla_cached_attention").kernel
+    before = telemetry.snapshot()
+    got = kernel(None, ins, {"num_heads": H})
+    assert telemetry.snapshot_delta(before)[
+        "mla_cached_attention_lowerings_total{cache_dtype=float32,"
+        "heads=%d,latent=%d,positions=%d,rope=%d,selected=%d,tile=%d}"
+        % (H, KVR, block, ROPE, TOPK,
+           min(tile, 1 << (block.bit_length() - 1)))] == 1
+    assert got["Out"][0].shape == (B, block, H * DV)
+    cache = ins["Cache"][0]
+    for t in range(block):
+        one = kernel(None, _one_position(
+            ins, t, pos + t, Cache=cache, Selected=ins["Selected"][0][:, t],
+            Live=ins["Live"][0][:, t]), {"num_heads": H})
+        cache = one["CacheOut"][0]
+        np.testing.assert_allclose(got["Out"][0][:, t], one["Out"][0][:, 0],
+                                   atol=2e-5)
+    np.testing.assert_array_equal(got["CacheOut"][0], cache)
+    # the last position's set holds a slot the block itself wrote
+    assert int(ins["Selected"][0][0, -1, :int(ins["Live"][0][0, -1])]
+               .max()) >= pos or block == 1
+
+
+def test_a_blocks_sets_come_a_position_each():
+    """`Selected` of a block is [batch, T, top_k]: one set for the whole
+    block, or a step's set with a block, is refused."""
+    ins = _chosen_block_ins(np.random.RandomState(3), 4, 6)
+    kernel = registry.get_op_info("mla_cached_attention").kernel
+    for wrong in (ins["Selected"][0][:, 0], ins["Selected"][0][:, :2]):
+        with pytest.raises(ValueError, match="one position's"):
+            kernel(None, dict(ins, Selected=[wrong]), {"num_heads": H})
+
+
 def test_the_scale_is_the_attrs_where_given():
     ins = _mla_ins(np.random.RandomState(8), 6)
     kernel = registry.get_op_info("mla_cached_attention").kernel
@@ -438,6 +699,16 @@ def test_the_layer_wants_selected_and_live_together():
         assert tuple(selected.shape) == (B, TOPK)
         assert tuple(live.shape) == (B,)
         assert tuple(kept.shape) == (B, T, ID)
+        # a block axis, open or not, is the sets' too
+        for n, steps in enumerate((-1, 5)):
+            selected, live, _ = fluid.layers.mla_index_select(
+                data("qi%d" % n, [B, steps, IH * ID]),
+                data("wi%d" % n, [B, steps, IH]),
+                data("ki%d" % n, [B, steps, ID]),
+                data("icache%d" % n, [B, T, ID]),
+                data("pos%d" % (3 + n), [B], "int64"), IH, TOPK)
+            assert tuple(selected.shape) == (B, steps, TOPK)
+            assert tuple(live.shape) == (B, steps)
 
 
 # -- the router ------------------------------------------------------------------
@@ -682,14 +953,45 @@ def block_lowerings():
     "call gpt2", "call window_moe", "call linear_moe"])
 def test_what_the_block_form_leaves_alone_is_the_parents(block_lowerings,
                                                          what):
-    """PR 53 gave the latent step a block of positions.  With an
-    `indexer` the builder builds the parent's Program op for op; the
+    """PR 53 gave the latent step a block of positions.  The
     window/full and linear/full builders moved onto the shared helpers
-    of `decoder_block` and build theirs op for op; and a generation call
-    through each step that prefills in blocks of 128 (a remainder, a
-    block, two steps) traces to the parent's jaxpr: against the
-    recording made on commit b6c67fc."""
+    of `decoder_block` and build their Programs op for op; and a
+    generation call through each step that prefills in blocks of 128 (a
+    remainder, a block, two steps) traces to the parent's jaxpr: against
+    the recording made on commit b6c67fc.  With an `indexer` the builder
+    built that commit's Program op for op until PR 62 gave a chooser's
+    step the block form too: it is held to the recording's products in
+    their order, as pangu's is."""
     recorded, now = block_lowerings
+    if what == "program indexer":
+        assert _products(now[what]) == _products(recorded[what])
+        assert len(_products(recorded[what])) > 30
+        assert "'prefill_block'" in now[what] \
+            and "'prefill_block'" not in recorded[what]
+    else:
+        assert now[what] == recorded[what]
+
+
+@pytest.fixture(scope="module")
+def step_lowerings():
+    with open(parent_lowerings.STEP_RECORDING) as f:
+        return json.load(f), parent_lowerings.step_lowerings()
+
+
+@pytest.mark.parametrize("what", [
+    "mla_index_select float32", "mla_index_select bfloat16",
+    "mla_cached_attention chosen float32",
+    "mla_cached_attention chosen bfloat16",
+    "mla_cached_attention chosen sink float32",
+    "mla_cached_attention chosen sink bfloat16"])
+def test_one_position_of_a_chooser_lowers_as_the_parent(step_lowerings,
+                                                        what):
+    """PR 62 gave the chooser and the attention over its set a block of
+    positions.  At T = 1 both trace to the jaxpr they traced to before,
+    equation for equation (the selection kernel's body included): the
+    decoding step of a chooser's cell is the step it was.  Against the
+    recording made on commit cc05484."""
+    recorded, now = step_lowerings
     assert now[what] == recorded[what]
 
 
@@ -857,10 +1159,11 @@ def test_counters_say_what_was_lowered(built):
     # one count an op instance a traced step holds
     assert lowered[
         "mla_index_select_lowerings_total{cache_dtype=float32,dim=%d,"
-        "heads=%d,select=count,top_k=%d}" % (ID, IH, TOPK)] == L
+        "heads=%d,positions=1,select=count,tile=1,top_k=%d}"
+        % (ID, IH, TOPK)] == L
     assert lowered[
         "mla_cached_attention_lowerings_total{cache_dtype=float32,"
-        "heads=%d,latent=%d,rope=%d,selected=%d}"
+        "heads=%d,latent=%d,positions=1,rope=%d,selected=%d,tile=1}"
         % (H, KVR, ROPE, TOPK)] == L
     # a chosen set keeps the plain products, whatever the shapes
     assert lowered["mla_decode_lowerings_total{block_k=0,path=plain,"

@@ -171,6 +171,73 @@ def test_prefill_then_greedy_is_the_references_greedy(built, prompt_len):
     assert (z[:, at:].max(axis=-1) - picked).max() <= 1e-4
 
 
+@pytest.fixture(scope="module")
+def probed(built):
+    """A decoder over the same step and weights that also carries each
+    layer's `selected` and `live` out as state the step only writes."""
+    probes = [("probe_%d.%s" % (i, what), built["parts"][what][i].name)
+              for i in range(L) for what in ("selected", "live")]
+    decoder = _decoder(built["main"], built["logits"],
+                       built["pairs"] + probes, built["scope"])
+
+    def state(held):
+        held = dict(held)
+        for i in range(L):
+            held["probe_%d.selected" % i] = jnp.zeros((B, TOPK), jnp.int32)
+            held["probe_%d.live" % i] = jnp.zeros((B,), jnp.int32)
+        return held
+
+    return decoder, state
+
+
+@pytest.mark.parametrize("start,block,tile", [
+    (0, 6, 0), (TOPK - 3, 7, 2), (10, 13, 4)],
+    ids=["under top_k", "across top_k in tiles of 2 and one over",
+         "past top_k in tiles of 4 and one over"])
+def test_a_block_through_the_step_is_so_many_single_steps(
+        built, probed, start, block, tile, monkeypatch):
+    """T tokens of every row in one application, through the inherited
+    sets, the sink, the gate and the four streams, against T
+    applications of one: the last position's logits, every cache, and
+    each layer's set of the block's last position, a shared layer's the
+    one it inherits."""
+    if tile:
+        # a position's index scores are B * IH * T * 4 bytes, its
+        # gathered rows and scores B * TOPK * (24 + H) * 4: both ops
+        # work through tiles of `tile` positions
+        from paddle_tpu.ops import attention
+        monkeypatch.setattr(attention, "TILE_BYTES",
+                            tile * B * TOPK * (KVR + ROPE + H) * 4)
+        assert B * IH * T * 4 <= B * TOPK * (KVR + ROPE + H) * 4
+    decoder, with_probes = probed
+    tokens = built["tokens"]
+    step = decoder._step_fn(decoder._params)
+    state = with_probes(_empty())
+    if start:
+        _, state = _drive(decoder, tokens[:, :start], state)
+    want, after = _drive(decoder, tokens[:, start:start + block], state)
+    logits, got = step(state, jnp.asarray(tokens[:, start:start + block]))
+    assert logits.shape == (B, V) and logits.dtype == jnp.float32
+    assert np.abs(np.asarray(logits) - want[:, -1]).max() \
+        <= LOGITS_RTOL * np.abs(want[:, -1]).max()
+    assert int(got["pos"][0]) == start + block
+    for feed, _ in built["pairs"]:
+        if feed != "pos":
+            np.testing.assert_allclose(
+                got[feed], after[feed],
+                atol=LOGITS_RTOL * np.abs(np.asarray(after[feed])).max())
+    for i in range(L):
+        np.testing.assert_array_equal(got["probe_%d.selected" % i],
+                                      after["probe_%d.selected" % i])
+        assert np.asarray(got["probe_%d.live" % i]).tolist() \
+            == [min(TOPK, start + block)] * B
+    np.testing.assert_array_equal(got["probe_2.selected"],
+                                  got["probe_1.selected"])
+    if start + block > TOPK + 1:
+        assert (np.asarray(got["probe_0.selected"])
+                != np.asarray(got["probe_1.selected"])).any()
+
+
 def test_a_shared_layer_holds_no_index_cache_and_no_index_weights(built):
     feeds = {name for name, _ in built["pairs"]}
     assert feeds == {"latent_cache_%d" % i for i in range(L)} \
@@ -194,8 +261,15 @@ def test_a_shared_layer_attends_the_set_of_the_full_layer_below(built):
            if od.type == "mla_cached_attention"]
     sets = [(od.input("Selected")[0], od.input("Live")[0]) for od in ops]
     assert sets[0] != sets[1] and sets[1] == sets[2] == sets[3]
-    assert [v.name for v in built["parts"]["selected"]] \
-        == [s for s, _ in sets]
+    # and what a decoder is handed of a layer's set, the block's last
+    # position's, is of those Variables: a shared layer's is the very
+    # Variable of the layer it inherits from
+    handed = [v.name for v in built["parts"]["selected"]]
+    assert handed[0] != handed[1] and handed[1] == handed[2] == handed[3]
+    made = {od.output("Out")[0]: od.input("X")[0]
+            for od in built["main"].global_block().desc.ops
+            if od.type in ("gather", "reshape")}
+    assert [made[made[name]] for name in handed] == [s for s, _ in sets]
 
 
 def test_the_sets_reused_are_counted_at_build(built):
@@ -577,14 +651,15 @@ def _listing(main):
     (dict(sandwich_norm=False, indexer=(2, 8, 4), n_group=4, topk_group=2,
           router_bias=True, yarn={
               "factor": 40, "original_positions": 4096, "beta_fast": 32,
-              "beta_slow": 1, "mscale": 1}), "924fd268474f266f"),
+              "beta_slow": 1, "mscale": 1}), "820727a57c275686"),
 ], ids=["openpangu-ultra-moe-718b", "deepseek-v3.2"])
 def test_the_builders_other_programs_are_op_for_op_the_parents(options,
                                                                digest):
-    """The options this PR adds default to what the builder did: the
+    """The options PR 61 added default to what the builder did: the
     step Programs of the two configurations that share it are, op for op
-    and attr for attr, the parent commit's (64b2a1a; the digests are
-    tests/test_window_moe_program.py's, which PR 53 last set)."""
+    and attr for attr, what they are without them (the digests are
+    tests/test_window_moe_program.py's: pangu's as PR 53 set it,
+    DeepSeek-V3.2's as PR 62 did, when a chooser's step took a block)."""
     main = build_latent_moe_cached_step_program(2, 16, 97, **options)[0]
     assert hashlib.sha256(_listing(main).encode()).hexdigest()[:16] == digest
 
@@ -596,4 +671,4 @@ def test_this_steps_program_digest(built):
         == DIGEST
 
 
-DIGEST = "b38b6cfbdf17c92e"
+DIGEST = "913f924a169bce6d"

@@ -472,21 +472,23 @@ def _digest(program):
     return hashlib.sha256(text.encode()).hexdigest()
 
 
-# the digests of the parent commit's step Programs (PR 57, 0e84e89): a
-# Program's names are its own counters', so a build is deterministic
+# the digests of the step Programs PR 58's parent commit built (PR 57,
+# 0e84e89; the latent step's with an `indexer` as PR 62 built it, when a
+# chooser's step took a block of positions): a Program's names are its
+# own counters', so a build is deterministic
 DIGESTS = {
     "gpt2":
         "caaac60e420d0c495b0bb9d3bd421230d8887991792e9c7de610c7293ba46833",
     "window_moe":
         "c2faad61b6caf27eaa16f96dae4884f76c69fbe7ffa2c826284ce52af94f7f3e",
     "latent_moe":
-        "8c66a30301c57d0b352b4801129e082b1b5460805cb4864124853844909c7f1a"}
+        "8d2246a6ac1fb9e63617f92272f82752e12b69b03da05b4b87d63c1f1ff747aa"}
 
 
 @pytest.mark.parametrize("which", sorted(DIGESTS))
 def test_the_step_programs_the_repo_had_are_op_for_op_what_they_were(which):
     """GPT-2's, K-EXAONE's and DeepSeek-V3.2's steps at toy sizes, op
-    for op, input for input and attr for attr what the parent commit
+    for op, input for input and attr for attr what the recorded commits
     built: no cached_attention op has a `Selected`, no rope a
     `sections`, and an expert layer that names a shared expert still
     adds it.  The new inputs and attrs are said only where asked for."""

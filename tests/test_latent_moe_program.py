@@ -471,9 +471,12 @@ def test_a_block_of_positions_is_so_many_single_steps(block, pos):
                         np.asarray(ins["RNew"][0])], axis=-1))
 
 
-def test_a_block_with_a_chosen_set_is_refused():
-    """A chosen set is one position's: `Selected` with T > 1 raises and
-    says so; with T = 1 it is the step it was."""
+def test_a_block_with_a_chosen_set_wants_a_set_a_position():
+    """A chosen set is one position's: a block of T > 1 positions takes
+    `Selected` [batch, T, top_k] and `Live` [batch, T] (here every
+    position the same four slots, so each is attention over those), and
+    a step's [batch, top_k] with a block is refused and says so; with T
+    = 1 it is the step it was."""
     ins = dict(_block_ins(np.random.RandomState(3), 2, 4),
                Selected=[jnp.zeros((B, 4), jnp.int32)],
                Live=[jnp.full((B,), 4, jnp.int32)])
@@ -484,6 +487,19 @@ def test_a_block_with_a_chosen_set_is_refused():
            else v for k, v in ins.items()}
     assert kernel(None, one, {"num_heads": H})["Out"][0].shape \
         == (B, 1, H * DV)
+    slots = jnp.asarray([0, 2, 4, 5], jnp.int32)
+    a_position = dict(ins, Selected=[jnp.tile(slots, (B, 2, 1))],
+                      Live=[jnp.full((B, 2), 4, jnp.int32)])
+    got = kernel(None, a_position, {"num_heads": H})
+    assert got["Out"][0].shape == (B, 2, H * DV)
+    # position 1 of the block alone, over the cache the block leaves
+    last = {k: [v[0][:, 1:]] if k in ("QNope", "QRope", "CNew", "RNew")
+            else v for k, v in ins.items()}
+    want = kernel(None, dict(
+        last, Cache=got["CacheOut"], Selected=[jnp.tile(slots, (B, 1))],
+        Position=[jnp.full_like(ins["Position"][0], 5)]), {"num_heads": H})
+    np.testing.assert_allclose(got["Out"][0][:, 1:], want["Out"][0],
+                               atol=2e-5)
 
 
 def test_a_block_longer_than_the_op_was_sized_for_is_refused():
@@ -634,15 +650,25 @@ def test_rope_turns_one_position_as_it_did():
 
 
 def test_only_the_block_taking_step_asks_for_it(built):
-    """The all-slots builder sets `full_width` on its rope ops; with an
-    `indexer` (one position a call) none carries it."""
+    """The builder sets `full_width` on its rope ops, the index queries'
+    and keys' too where there is an `indexer` (a chooser's step takes a
+    block since PR 62); a step that takes one position a call sets none
+    (the grouped-cache chooser's, `models/sparse_kv_moe_program.py`)."""
+    from paddle_tpu.models.sparse_kv_moe_program import \
+        build_sparse_kv_moe_cached_step_program
     ropes = [od for od in built["main"].global_block().desc.ops
              if od.type == "rope"]
     assert len(ropes) == 2 * L and all(od.attrs.get("full_width")
                                        for od in ropes)
     indexed = build_latent_moe_cached_step_program(
         B, T, V, **dict(SIZES, indexer=(2, 8, 4)))[0]
-    assert not [od for od in indexed.global_block().desc.ops
+    ropes = [od for od in indexed.global_block().desc.ops
+             if od.type == "rope"]
+    assert len(ropes) == 4 * L and all(od.attrs.get("full_width")
+                                       for od in ropes)
+    one = build_sparse_kv_moe_cached_step_program(B, T, V)[0]
+    assert [od for od in one.global_block().desc.ops if od.type == "rope"]
+    assert not [od for od in one.global_block().desc.ops
                 if od.type == "rope" and "full_width" in od.attrs]
 
 
@@ -670,7 +696,10 @@ def test_the_step_states_the_block_it_is_prefilled_by(blocked, built):
     """The builder derives the block from its rows and widths under its
     byte bound, the attention ops carry it, and the decoder reads it off
     the Program (a clone keeps it); a step that states none (GPT-2's)
-    gets `PREFILL_BLOCK`, and one that takes one position none at all."""
+    gets `PREFILL_BLOCK`.  With an `indexer` the block also counts a
+    position's index scores and the two tiles, and stops at 512 token
+    rows an application: 32 positions at dsv32-turn-16k-ep16's 16 rows,
+    64 at hy4-turn-32k-ep16's 8."""
     assert blocked["decoder"]._prefill_block == SMALL_BLOCK
     stated = [od.attrs["prefill_block"]
               for od in blocked["main"].global_block().desc.ops
@@ -699,9 +728,19 @@ def test_the_step_states_the_block_it_is_prefilled_by(blocked, built):
         % decode.PREFILL_BLOCK] == 1
     indexed = build_latent_moe_cached_step_program(
         B, T, V, **dict(SIZES, indexer=(2, 8, 4)))[0]
-    assert tuple(indexed.global_block().var("tok").shape) == (B,)
-    assert not [od for od in indexed.global_block().desc.ops
-                if "prefill_block" in od.attrs]
+    assert tuple(indexed.global_block().var("tok").shape) == (B, -1)
+    assert [od.attrs["prefill_block"]
+            for od in indexed.global_block().desc.ops
+            if "prefill_block" in od.attrs] == [decode.PREFILL_BLOCK] * L
+    chooser = (64, 128, 2048)
+    assert latent_moe_program.prefill_block(
+        16, 128, 512, 64, chooser, 16384) == 32
+    assert latent_moe_program.prefill_block(
+        8, 64, 512, 64, (32, 128, 2048), 32768) == 64
+    assert latent_moe_program.prefill_block(
+        8, 128, 512, 64, chooser, 2304) == 64
+    assert latent_moe_program.prefill_block(
+        2048, 128, 512, 64, chooser, 16384) == 1
 
 
 def _in_blocks(decoder, tokens, sizes, state):
@@ -1042,7 +1081,8 @@ def test_counters_say_what_was_lowered(built):
         _empty(), jnp.asarray(built["tokens"][:, 0]))
     lowered = telemetry.snapshot_delta(before)
     mla = "mla_cached_attention_lowerings_total{cache_dtype=float32," \
-        "heads=%d,latent=%d,rope=%d,selected=all}" % (H, KVR, ROPE)
+        "heads=%d,latent=%d,positions=1,rope=%d,selected=all,tile=1}" \
+        % (H, KVR, ROPE)
     share = "moe_share_lowerings_total{held=%d,scored=%d,top_k=%d}" \
         % (HELD[1], E, K)
     # one count an op instance a traced step holds

@@ -314,7 +314,7 @@ def _listing(main):
      dict(sandwich_norm=False, indexer=(2, 8, 4), n_group=4, topk_group=2,
           router_bias=True, yarn={
               "factor": 40, "original_positions": 4096, "beta_fast": 32,
-              "beta_slow": 1, "mscale": 1}), "924fd268474f266f"),
+              "beta_slow": 1, "mscale": 1}), "820727a57c275686"),
     (build_window_moe_cached_step_program, {}, None),
 ])
 def test_the_other_shares_programs_are_op_for_op_what_they_were(
@@ -322,7 +322,7 @@ def test_the_other_shares_programs_are_op_for_op_what_they_were(
     """`share_feed_forward` took `scoring` and `shared_gate`: with
     neither given, the three served shares' Programs are what they were
     (the latent builder's digests are tests/test_window_moe_program.py's,
-    of commit 92c5422 with an `indexer` and of PR 53's block-taking step
+    of PR 62's block-taking step with an `indexer` and of PR 53's
     without; the window builder's is taken from the parent's
     `share_feed_forward`, rebuilt here)."""
     main = build(2, 16, 97, **options)[0]

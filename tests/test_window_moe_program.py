@@ -701,17 +701,17 @@ def _listing(main):
     (dict(sandwich_norm=False, indexer=(2, 8, 4), n_group=4, topk_group=2,
           router_bias=True, yarn={
               "factor": 40, "original_positions": 4096, "beta_fast": 32,
-              "beta_slow": 1, "mscale": 1}), "924fd268474f266f"),
+              "beta_slow": 1, "mscale": 1}), "820727a57c275686"),
 ])
 def test_the_latent_builders_programs_are_op_for_op_what_they_were(options,
                                                                    digest):
-    """The feed-forward half is `decoder_block.share_feed_forward` now;
-    DeepSeek-V3.2's digest is of the Program the builder gave before it
-    was lifted (commit 92c5422): every op's type, inputs, outputs and
-    attrs, in order.  pangu's options build a step that takes a block of
-    positions since PR 53: its digest is of the Program that PR built
-    (it was 76fca9b464257f60 until then; tests/test_dsv32_program.py
-    holds the new Program to the old one's products in their order)."""
+    """The feed-forward half is `decoder_block.share_feed_forward` now:
+    every op's type, inputs, outputs and attrs, in order.  pangu's
+    options build a step that takes a block of positions since PR 53,
+    DeepSeek-V3.2's since PR 62: each digest is of the Program that PR
+    built (they were 76fca9b464257f60 and 924fd268474f266f until then;
+    tests/test_dsv32_program.py holds the new Programs to the old ones'
+    products in their order)."""
     main = build_latent_moe_cached_step_program(2, 16, 97, **options)[0]
     assert hashlib.sha256(_listing(main).encode()).hexdigest()[:16] == digest
 
