@@ -10,7 +10,9 @@ trained through the flash-attention kernel (and the kernel checked
 against dense attention), the routed expert op forward and backward at
 OLMoE's widths against the dense reference, the state-space scan op
 forward and backward at granite-4.0-h-micro's widths against the
-sequential recurrence, ResNet-50 served over HTTP
+sequential recurrence, the Mamba-1 scan op with its carried state at
+Phi-4-mini-flash-reasoning's widths (a block against its steps and
+against the recurrence), ResNet-50 served over HTTP
 as serve_cli
 serves it, and — on a host with four chips — ResNet-50 under
 SpmdTrainer.  Weights are random, from a seed; no phase is cut down.
@@ -425,6 +427,74 @@ def ssd_scan_check(seq=4096, heads=64, dim=64, state=128, chunk=256):
                                       chunk, worst), flush=True)
 
 
+def selective_scan_check(rows=16, block=128, channels=5120, state=16):
+    """The Mamba-1 scan op with its carried state (`selective_scan`) at
+    Phi-4-mini-flash-reasoning's widths: a block of 128 positions from an
+    entering state against 128 single steps (the output and the state
+    handed on), and the first row against the recurrence walked in
+    float64 on the host."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import registry
+
+    keys = jax.random.split(jax.random.PRNGKey(0), 7)
+    f32 = jnp.float32
+    wide = (rows, block, channels)
+    ins = {
+        "X": [jax.random.normal(keys[0], wide, f32).astype(jnp.bfloat16)],
+        "Dt": [0.3 * jax.random.normal(keys[1], wide, f32)],
+        # steps log-uniform on [1e-3, 1e-1], rates 1 .. state
+        "DtBias": [jnp.log(jnp.expm1(jnp.exp(jax.random.uniform(
+            keys[2], (channels,), f32, np.log(1e-3), np.log(1e-1)))))],
+        "ALog": [jnp.broadcast_to(jnp.log(jnp.arange(1, state + 1,
+                                                     dtype=f32)),
+                                  (channels, state))],
+        "B": [jax.random.normal(keys[3], (rows, block, state), f32)],
+        "C": [jax.random.normal(keys[4], (rows, block, state), f32)],
+        "D": [jnp.ones((channels,), f32)],
+        "State": [jax.random.normal(keys[5], (rows, state, channels), f32)]}
+    kernel = registry.get_op_info("selective_scan").kernel
+    moving = ("X", "Dt", "B", "C")
+
+    def whole(ins):
+        out = kernel(None, ins, {})
+        return out["Out"][0], out["StateOut"][0]
+
+    def stepped(ins):
+        def one(state, at):
+            out = kernel(None, dict(
+                ins, State=[state],
+                **{k: [v[:, None]] for k, v in zip(moving, at)}), {})
+            return out["StateOut"][0], out["Out"][0][:, 0]
+
+        state, ys = jax.lax.scan(one, ins["State"][0], tuple(
+            jnp.moveaxis(ins[k][0], 1, 0) for k in moving))
+        return jnp.moveaxis(ys, 0, 1), state
+
+    got_y, got_s = jax.jit(whole)(ins)
+    want_y, want_s = jax.jit(stepped)(ins)
+    for name, g, w in (("Out", got_y, want_y), ("StateOut", got_s, want_s)):
+        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+        check(np.isfinite(g).all() and np.abs(g - w).max()
+              <= 1e-5 * np.abs(w).max(),
+              "selective_scan %s: a block is not its steps" % name)
+    x, dt, b, c = (np.asarray(ins[k][0][0], np.float64) for k in moving)
+    dt = np.log1p(np.exp(dt + np.asarray(ins["DtBias"][0], np.float64)))
+    a = -np.exp(np.asarray(ins["ALog"][0], np.float64)).T
+    s = np.asarray(ins["State"][0][0], np.float64)
+    ys = []
+    for t in range(block):
+        s = np.exp(dt[t] * a) * s + (dt[t] * x[t]) * b[t][:, None]
+        ys.append((s * c[t][:, None]).sum(0) + x[t])
+    err = float(np.abs(np.asarray(got_y[0], np.float64) - np.stack(ys)).max()
+                / np.abs(np.stack(ys)).max())
+    check(err < BF16_TOL, "selective_scan: off the recurrence by %.4f of "
+          "its largest value" % err)
+    print("  selective_scan [%d, %d, %d], state %d: a block is its steps, "
+          "within %.4f of the recurrence" % (rows, block, channels, state,
+                                             err), flush=True)
+
+
 def resnet50_serve(image_size=224, class_dim=1000, buckets=(1, 4, 16),
                    sizes=(1, 2, 4, 3, 8, 16, 5, 1)):
     import jax
@@ -563,7 +633,7 @@ def main():
     print("compile cache: %s" % enable_compile_cache(), flush=True)
     clock = CompileClock()
     phases = [resnet50_train, transformer_train, moe_experts_check,
-              ssd_scan_check, resnet50_serve]
+              ssd_scan_check, selective_scan_check, resnet50_serve]
     if len(devices) >= 4:
         phases.append(multichip)
     for phase in phases:

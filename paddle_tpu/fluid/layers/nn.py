@@ -32,12 +32,14 @@ __all__ = [
     "mla_index_select", "rms_norm", "rope", "moe", "hc_maps", "hc_pre",
     "hc_post",
     "ssd_scan", "causal_conv1d", "gated_delta_rule", "expand", "gather", "slice", "cumsum",
+    "selective_scan", "diff_combine",
 ]
 
 
 def cached_attention(query, key, value, k_cache, v_cache, position,
                      num_heads=1, sm_scale=None, num_kv_heads=None,
-                     window=0, name=None, selected=None, live=None):
+                     window=0, name=None, selected=None, live=None,
+                     reader=0, shared_readers=0):
     """Attention through a KV cache over a block of T >= 1 consecutive
     positions of every row (ops/attention.py cached_attention; T = 1 is
     a decode step): query [batch, T, num_heads * head_dim], key/value
@@ -55,19 +57,45 @@ def cached_attention(query, key, value, k_cache, v_cache, position,
     one set for every key/value head.
     Returns (out [batch, T, num_heads * head_dim], k_cache_out,
     v_cache_out) — thread the cache outputs back as decode state
-    (`fluid.ProgramDecoder` state pairs)."""
+    (`fluid.ProgramDecoder` state pairs).
+
+    With `key` and `value` None the layer **reads caches it does not
+    write** (whole-extent, no chosen set) and returns `out` alone:
+    `k_cache` and `v_cache` are then another layer's `k_cache_out` and
+    `v_cache_out` of the same step (query i attends slots 0 .. position
+    + i of them, the step's own among them), `reader` which of that
+    cache's readers this one is, numbered from 1; the layer that writes
+    such a cache states `shared_readers`, how many attend it, itself
+    among them.  Both are counters' labels and change no arithmetic."""
     helper = LayerHelper("cached_attention", name=name)
     out = helper.create_tmp_variable(query.dtype)
-    kc_out = helper.create_tmp_variable(k_cache.dtype)
-    vc_out = helper.create_tmp_variable(v_cache.dtype)
     attrs = {"num_heads": int(num_heads),
              "sm_scale": float(sm_scale or 0.0)}
+    if key is None:
+        if value is not None or window or selected is not None:
+            raise ValueError(
+                "cached_attention: a layer that reads caches it does not "
+                "write has neither key nor value, window or chosen set")
+        if num_kv_heads and int(num_kv_heads) != int(num_heads):
+            attrs["num_kv_heads"] = int(num_kv_heads)
+        if reader:
+            attrs["reader"] = int(reader)
+        helper.append_op(
+            type="cached_attention",
+            inputs={"Q": [query], "KCache": [k_cache], "VCache": [v_cache],
+                    "Position": [position]},
+            outputs={"Out": [out]}, attrs=attrs)
+        return out
+    kc_out = helper.create_tmp_variable(k_cache.dtype)
+    vc_out = helper.create_tmp_variable(v_cache.dtype)
     # said only where asked for: a Program without them is, attr for
     # attr, the Program it was
     if num_kv_heads and int(num_kv_heads) != int(num_heads):
         attrs["num_kv_heads"] = int(num_kv_heads)
     if window:
         attrs["window"] = int(window)
+    if shared_readers:
+        attrs["shared_readers"] = int(shared_readers)
     inputs = {"Q": [query], "KNew": [key], "VNew": [value],
               "KCache": [k_cache], "VCache": [v_cache],
               "Position": [position]}
@@ -1093,6 +1121,76 @@ def gated_delta_rule(q, k, v, g, beta, state, qk_l2norm=True, chunk=64,
                 "State": [state]},
         outputs={"Out": [out], "StateOut": [state_out]}, attrs=attrs)
     return out, state_out
+
+
+def selective_scan(x, dt, b, c, state, d_state, a_log_attr=None,
+                   d_attr=None, dt_bias_attr=None, name=None):
+    """Mamba-1's selective scan over a block of T >= 1 consecutive
+    positions of every row, through a carried state (ops/ssm.py
+    selective_scan; T = 1 is a decode step): `x` and `dt` (before the
+    softplus) [batch, T, channels], `b` and `c` [batch, T, d_state],
+    `state` [batch, d_state, channels] float32.  A channel and state
+    entry at a time: S = exp(dt A) S + dt x B; y = S . C + D x, with dt =
+    softplus(dt + dt_bias) and A = -exp(a_log).  Creates `a_log`
+    [channels, d_state] (decay rates log-uniform on [1, d_state]), `d`
+    (ones) and `dt_bias` [channels] (steps log-uniform on [0.001, 0.1],
+    as `ssd_scan`'s), float32.  T may
+    be left open (-1) in the Program.  Returns (out [batch, T, channels],
+    state_out): thread `state_out` back as decode state
+    (`fluid.ProgramDecoder` state pairs).  Forward only."""
+    helper = LayerHelper("selective_scan", name=name)
+    channels = int(x.shape[-1])
+    a_log = helper.create_parameter(
+        a_log_attr or ParamAttr(), shape=[channels, d_state],
+        dtype="float32",
+        default_initializer=LogScale(1.0, float(d_state), "log_uniform"))
+    d_skip = helper.create_parameter(
+        d_attr or ParamAttr(), shape=[channels], dtype="float32",
+        default_initializer=Constant(1.0))
+    dt_bias = helper.create_parameter(
+        dt_bias_attr or ParamAttr(), shape=[channels], dtype="float32",
+        default_initializer=LogScale(1e-3, 1e-1,
+                                     "inverse_softplus_log_uniform"))
+    out = helper.create_tmp_variable(x.dtype)
+    state_out = helper.create_tmp_variable(state.dtype)
+    helper.append_op(
+        type="selective_scan",
+        inputs={"X": [x], "Dt": [dt], "DtBias": [dt_bias],
+                "ALog": [a_log], "B": [b], "C": [c], "D": [d_skip],
+                "State": [state]},
+        outputs={"Out": [out], "StateOut": [state_out]})
+    return out, state_out
+
+
+def diff_combine(x, width, lambda_init, epsilon=1e-5, lambda_attrs=None,
+                 scale_attr=None, subtract=True, name=None):
+    """The second half of differential attention (ops/attention.py
+    diff_combine): `x` [batch, T, 2 * pairs * width] holds, a pair of
+    heads, the pair's two attention maps applied to its `width` values,
+    side by side; the layer gives (1 - lambda_init) * RMSNorm(first -
+    lambda * second) [batch, T, pairs * width] with lambda = exp(lq1 .
+    lk1) - exp(lq2 . lk2) + lambda_init.  Creates the four [width // 2]
+    vectors (`lambda_attrs`: four ParamAttr in the order lq1, lk1, lq2,
+    lk2; N(0, 0.1) at the start) and the norm's scale [width] (ones),
+    float32.  `subtract=False` drops the second map (a control)."""
+    helper = LayerHelper("diff_combine", name=name)
+    inputs = {"X": [x]}
+    for slot, attr in zip(("LambdaQ1", "LambdaK1", "LambdaQ2", "LambdaK2"),
+                          lambda_attrs or (None,) * 4):
+        inputs[slot] = [helper.create_parameter(
+            attr or ParamAttr(), shape=[width // 2], dtype="float32",
+            default_initializer=Normal(0.0, 0.1))]
+    inputs["Scale"] = [helper.create_parameter(
+        scale_attr or ParamAttr(), shape=[width], dtype="float32",
+        default_initializer=Constant(1.0))]
+    attrs = {"width": int(width), "lambda_init": float(lambda_init),
+             "epsilon": float(epsilon)}
+    if not subtract:
+        attrs["subtract"] = False
+    out = helper.create_tmp_variable(x.dtype)
+    helper.append_op(type="diff_combine", inputs=inputs,
+                     outputs={"Out": [out]}, attrs=attrs)
+    return out
 
 
 def gather(input, index, **kwargs):

@@ -48,7 +48,9 @@ __all__ = ["on_executor_run", "on_jit_trace",
            "on_prefill_lowering", "on_ssd_lowering",
            "on_causal_conv1d_lowering", "on_causal_conv1d_tail_lowering",
            "on_gated_delta_rule_lowering", "on_shared_parameter_uses",
-           "on_index_sets_reused",
+           "on_index_sets_reused", "on_selective_scan_lowering",
+           "on_cached_attention_readonly_lowering",
+           "on_decoder_positions", "on_shared_cache_readers",
            "on_transfer",
            "on_feed_seconds", "on_decoder_call", "on_program_cache_evict",
            "jit_trace_count", "transfer_bytes", "step", "set_gauge",
@@ -454,7 +456,8 @@ def _recurrent_state_bytes(kind, row_bytes):
     _reg().counter("recurrent_state_bytes_total",
                    "bytes of recurrent state a row holds in the lowered "
                    "ops that take a state in and hand it on, by kind (a "
-                   "delta rule's state, a convolution's tail)",
+                   "delta rule's state, a convolution's tail, a "
+                   "selective scan's state)",
                    labelnames=("kind",)).labels(kind=kind).inc(row_bytes)
 
 
@@ -491,6 +494,70 @@ def on_gated_delta_rule_lowering(form, path, chunk, heads, state_dtype,
           .labels(form=form, path=path, chunk=chunk, heads=heads,
                   state_dtype=str(state_dtype), gate=gate).inc()
     _recurrent_state_bytes("delta", row_bytes)
+
+
+def on_selective_scan_lowering(form, state_dtype, row_bytes):
+    """A `selective_scan` op (ops/ssm.py: Mamba-1's recurrence with its
+    state handed in and on) was traced into a program: in which form
+    ("step": one position, the state read and written once; "block":
+    the positions of a block walked through that same update) and with
+    a state of which type; `row_bytes` the state a row holds.  One count
+    per op instance a lowered program holds."""
+    _reg().counter("selective_scan_lowerings_total",
+                   "selective state-space scans lowered that carry "
+                   "their state, by form (a step or a block of "
+                   "positions) and the state's type",
+                   labelnames=("form", "state_dtype")) \
+          .labels(form=form, state_dtype=str(state_dtype)).inc()
+    _recurrent_state_bytes("ssm", row_bytes)
+
+
+def on_decoder_positions(part, positions):
+    """A step Program with a self-decoder and a cross-decoder was
+    lowered: the positions of a row an application runs through `part`
+    ("self": the layers that hold state, counted by the one op that
+    writes the cache the cross-decoder reads; "cross": the layers that
+    read it and hold none, counted by the first of its readers).  A
+    block of 128 positions whose cross-decoder runs at its last alone
+    counts 128 and 1, a decode step 1 and 1."""
+    _reg().counter("decoder_positions_total",
+                   "positions of a row that the lowered step programs "
+                   "run through their self-decoder and through their "
+                   "cross-decoder, an application",
+                   labelnames=("part",)).labels(part=part).inc(positions)
+
+
+def on_cached_attention_readonly_lowering(reader, block, path, block_k):
+    """A `cached_attention` op without KNew / VNew was traced into a
+    program: it reads a cache another op of the step wrote and writes
+    none, over `block` positions of every row, by `path` ("kernel":
+    kernels/gqa_decode.py over blocks of `block_k` slots; "plain");
+    `reader` is which of the cache's readers it is, as its builder
+    numbered them from 1 (0: not said).  One count per op instance a
+    lowered program holds; the first reader also counts the
+    cross-decoder's positions (`on_decoder_positions`).  It holds no
+    cache, so `kv_cache_slots_total` counts nothing for it."""
+    _reg().counter("cached_attention_readonly_lowerings_total",
+                   "key/value-cached attention ops lowered that read a "
+                   "cache another op wrote and write none, by the "
+                   "positions of a row one application takes, path and "
+                   "the kernel's block of slots",
+                   labelnames=("block", "path", "block_k")) \
+          .labels(block=block, path=path, block_k=block_k).inc()
+    if reader == 1:
+        on_decoder_positions("cross", block)
+
+
+def on_shared_cache_readers(program, readers):
+    """A cached step was built in which `readers` attention layers
+    attend one layer's key/value cache (the layer that writes it among
+    them; `models/sambay_program.py`): counted at build, as
+    `on_shared_parameter_uses` is."""
+    _reg().counter("program_shared_cache_readers",
+                   "attention layers of a built step that attend one "
+                   "layer's key/value cache, its writer among them",
+                   labelnames=("program",)) \
+          .labels(program=str(program._cache_token)).inc(readers)
 
 
 def on_shared_parameter_uses(program, uses):

@@ -325,7 +325,8 @@ def _cached_attention_infer_shape(block, op_desc):
     open and is never written into the cache's extent."""
     for src, dst in (("Q", "Out"), ("KCache", "KCacheOut"),
                      ("VCache", "VCacheOut")):
-        same_meta_infer_shape(src, dst)(block, op_desc)
+        if dst in op_desc.outputs:  # the read-only form gives no cache
+            same_meta_infer_shape(src, dst)(block, op_desc)
 
 
 @register_op("cached_attention", stop_gradient_op=True,
@@ -408,8 +409,24 @@ def cached_attention_op(ctx, ins, attrs):
     two [batch, kv_heads, top_k, head_dim] copies and the plain path
     under the mask entry < Live.  A chosen set is a set: the softmax
     does not care for its order.
+
+    Without KNew and VNew the op **reads a cache it does not write**
+    (whole-extent caches, no chosen set; anything else raises): query i
+    attends slots 0 .. Position + i of KCache / VCache as they are
+    handed in, nothing is written and no cache comes out.  A layer that
+    attends another layer's keys and values is wired to that layer's
+    KCacheOut / VCacheOut, so that write-then-read is the Program's data
+    flow and the slot of the step's own position is among those read.
+    Its scope is `attn_cross`, and it has no `kv_write`.  The attr
+    `reader` says which of the cache's readers the op is (its builder
+    numbers them from 1) and `shared_readers`, on the op that writes
+    such a cache, how many they are: both are for the counters alone
+    (obs/telemetry.py `on_decoder_positions`).
     """
-    q, k_new, v_new = ins["Q"][0], ins["KNew"][0], ins["VNew"][0]
+    q = ins["Q"][0]
+    readonly = not ins.get("KNew")
+    k_new, v_new = (None, None) if readonly \
+        else (ins["KNew"][0], ins["VNew"][0])
     k_cache, v_cache = ins["KCache"][0], ins["VCache"][0]
     selected = (ins.get("Selected") or [None])[0]
     # Position may be [1] or per-row [batch] (rows advance in lockstep;
@@ -435,8 +452,14 @@ def cached_attention_op(ctx, ins, attrs):
             "positions%s: a chosen set is one position's over whole-extent "
             "caches, and comes with Live"
             % (window, block, "" if ins.get("Live") else ", without Live"))
+    if readonly and (window or selected is not None or ins.get("VNew")):
+        raise ValueError(
+            "cached_attention: without KNew and VNew the op reads "
+            "whole-extent caches another op wrote (window %d%s)"
+            % (window, ", Selected" if selected is not None else ""))
     group = num_heads // kv_heads
-    kind = "window" if window else "full" if selected is None else "sparse"
+    kind = "cross" if readonly else "window" if window \
+        else "full" if selected is None else "sparse"
     ring_block = window > 0 and block > 1
     # the slots a query's products run over: a chosen set's, else the
     # cache's own
@@ -446,8 +469,9 @@ def cached_attention_op(ctx, ins, attrs):
     # the same two lines behind an import of Pallas, which a decoder
     # would pay at its first trace for a reshape
     qh = q.reshape(rows, block, num_heads, -1).transpose(0, 2, 1, 3)
-    kh, vh = (x.reshape(rows, block, kv_heads, -1).transpose(0, 2, 1, 3)
-              for x in (k_new, v_new))
+    kh, vh = (None, None) if readonly else (
+        x.reshape(rows, block, kv_heads, -1).transpose(0, 2, 1, 3)
+        for x in (k_new, v_new))
     head_dim = qh.shape[-1]
     if sm_scale is None:
         sm_scale = head_dim ** -0.5
@@ -467,14 +491,20 @@ def cached_attention_op(ctx, ins, attrs):
         block_k = gqa_decode.choose_chunk(
             attended, kv_heads, group, q.dtype.itemsize, head_dim)
     elif (head_dim == 64 or head_dim % 128 == 0) and not ring_block and (
-            head_dim != 64 or not window
+            head_dim != 64 or not (window or readonly)
             and k_cache.dtype == v_cache.dtype == q.dtype):
         from ..kernels import gqa_decode
         block_k = gqa_decode.choose_block(attended, group * block,
                                           q.dtype.itemsize, head_dim)
     writes = block_k and head_dim == 64
     telemetry.on_cached_attention_lowering(block)
-    if selected is None:
+    if attrs.get("shared_readers"):
+        telemetry.on_decoder_positions("self", block)
+    if readonly:    # it holds no cache: no slots are counted for it
+        telemetry.on_cached_attention_readonly_lowering(
+            int(attrs.get("reader", 0)), block,
+            "kernel" if block_k else "plain", block_k)
+    elif selected is None:
         telemetry.on_window_attention_lowering(
             kind, kv_heads, window, "kernel" if block_k else "plain",
             block_k, extent, block)
@@ -485,7 +515,9 @@ def cached_attention_op(ctx, ins, attrs):
 
     with jax.named_scope("kv_write"):
         before = k_cache, v_cache
-        if ring_block:
+        if readonly:
+            pass    # the caches are another op's: nothing is written
+        elif ring_block:
             k_cache, v_cache = (_ring_write(cache, new, pos) for cache, new
                                 in ((k_cache, kh), (v_cache, vh)))
         elif writes:    # slots-minor, as the 64-wide kernel reads them
@@ -559,6 +591,8 @@ def cached_attention_op(ctx, ins, attrs):
                              values.astype(jnp.float32), precision=highest)
         out = out.reshape(rows, num_heads, block, head_dim) \
             .transpose(0, 2, 1, 3).reshape(rows, block, width)
+    if readonly:
+        return {"Out": [out.astype(q.dtype)]}
     return {"Out": [out.astype(q.dtype)],
             "KCacheOut": [k_cache], "VCacheOut": [v_cache]}
 
@@ -598,6 +632,59 @@ def _ring_before_a_block(caches, new, pos):
 def _set_meta(block, name, shape, dtype):
     desc = block.var_recursive(name).desc
     desc.shape, desc.dtype, desc.lod_level = tuple(shape), dtype, 0
+
+
+def _diff_combine_infer_shape(block, op_desc):
+    """`Out` is half as wide as `X`, a pair's two maps made one; a block
+    axis the Program leaves open (-1) stays open."""
+    x = block.var_recursive(op_desc.input("X")[0]).desc
+    shape = tuple(x.shape)
+    _set_meta(block, op_desc.output("Out")[0],
+              shape[:-1] + (int(shape[-1]) // 2,), x.dtype)
+
+
+@register_op("diff_combine", stop_gradient_op=True,
+             infer_shape=_diff_combine_infer_shape)
+def diff_combine_op(ctx, ins, attrs):
+    """What differential attention (arXiv:2410.05258) does with a pair
+    of heads' two attention maps once each has been applied to the
+    pair's values: X [batch, T, 2 * pairs * width] holds, a pair, `P1 V`
+    and `P2 V` side by side, `width` values each (the two softmaxes are
+    ordinary attention, `cached_attention`'s: this op follows it), and
+
+        lambda = exp(LambdaQ1 . LambdaK1) - exp(LambdaQ2 . LambdaK2)
+                 + lambda_init
+        Out_p = (1 - lambda_init) * RMSNorm_width(P1 V - lambda * P2 V)
+
+    with the RMSNorm's learned `Scale` [width] and `epsilon`, in float32
+    inside: Out [batch, T, pairs * width] in X's type.  The four vectors
+    are a layer's (one scalar lambda for all its pairs).  The attr
+    `subtract` false leaves the second map out (lambda 0 in the
+    difference: a control of a cell's `correct`).  Forward only, as
+    `cached_attention`; its scope in a trace is the op's own type."""
+    x = ins["X"][0]
+    width = int(attrs["width"])
+    lambda_init = float(attrs["lambda_init"])
+    eps = float(attrs.get("epsilon", 1e-5))
+    rows, block, total = x.shape
+    if total % (2 * width) or ins["Scale"][0].shape != (width,):
+        raise ValueError(
+            "diff_combine: X %s is not pairs of two maps of %d values, or "
+            "Scale %s is not a pair's norm"
+            % (x.shape, width, ins["Scale"][0].shape))
+    q1, k1, q2, k2 = (ins[slot][0].astype(jnp.float32) for slot in (
+        "LambdaQ1", "LambdaK1", "LambdaQ2", "LambdaK2"))
+    lam = jnp.exp(jnp.sum(q1 * k1)) - jnp.exp(jnp.sum(q2 * k2)) \
+        + lambda_init
+    if not attrs.get("subtract", True):
+        lam = 0.0
+    maps = x.astype(jnp.float32).reshape(rows, block, -1, 2, width)
+    diff = maps[..., 0, :] - lam * maps[..., 1, :]
+    normed = diff * jax.lax.rsqrt(
+        jnp.mean(jnp.square(diff), axis=-1, keepdims=True) + eps) \
+        * ins["Scale"][0].astype(jnp.float32)
+    out = (1.0 - lambda_init) * normed
+    return {"Out": [out.reshape(rows, block, total // 2).astype(x.dtype)]}
 
 
 def _index_select_infer_shape(block, op_desc):

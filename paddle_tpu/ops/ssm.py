@@ -1,5 +1,6 @@
-"""A state-space layer's two ops: Mamba-2's selective scan and the
-causal depthwise convolution in front of it.
+"""A state-space layer's ops: Mamba-2's selective scan (`ssd_scan`),
+Mamba-1's (`selective_scan`, which a decoder carries a state through)
+and the causal depthwise convolution in front of either.
 
 `ssd_scan` is the recurrence of "Transformers are SSMs"
 (arXiv:2405.21060), one state `S` [head_dim, d_state] a head:
@@ -62,6 +63,28 @@ that a sequence fed in blocks of any lengths (a prompt's, then a
 position a step) convolves as it would whole.  `Bias` is optional too
 (Qwen3-Next's convolution has none).  That form is forward only; without
 `Tail` the op lowers as it always did.
+
+`selective_scan` is Mamba-1's recurrence (arXiv:2312.00752), a decay a
+channel *and* a state entry and one step size a channel, B and C shared
+by all channels, with the state handed in and handed on:
+
+    dt_t = softplus(Dt_t + DtBias)          A = -exp(ALog)   [D, N]
+    S_t  = exp(dt_t (x) A) * S_{t-1} + (dt_t * x_t) (x) B_t
+    y_t  = S_t C_t + D * x_t
+
+for x_t, dt_t [D] channels and B_t, C_t [N] state entries.  `State`
+[batch, N, D] float32 is what the positions before the block left (zeros
+at a sequence's start: the state's entries are the sublanes and the
+channels the lanes, so that 16 entries pad nothing) and `StateOut` what
+the block leaves, a `fluid.ProgramDecoder` state pair; Dt comes before
+the softplus as `ssd_scan` takes it.  Everything inside is float32
+whatever the operands' type.  T = 1 is one fused update of the state;
+T > 1 walks the block's positions one by one through that same update
+(`lax.scan`), so a block leaves, rounding for rounding, what T steps
+leave: a decay a channel and entry has no chunked form with one
+`[chunk, chunk]` mask a head, which is what `ssd_scan` rests on.  No
+gradient: generation needs none, and training the layer wants a
+parallel scan this op does not have.
 """
 
 import jax
@@ -69,7 +92,8 @@ import jax.numpy as jnp
 
 from ..obs import telemetry
 from .amp_util import amp_result, mxu_operands
-from .registry import register_grad_kernel, register_op
+from .registry import (register_grad_kernel, register_op,
+                       same_meta_infer_shape)
 
 F32 = jnp.float32
 _ACC = {"preferred_element_type": F32}
@@ -405,3 +429,71 @@ def causal_conv1d_grad(ctx, ins, attrs):
     return {"X@GRAD": [dx.astype(d_out.dtype)],
             "Filter@GRAD": [d_filter.astype(filt.dtype)],
             "Bias@GRAD": [jnp.sum(d_pre, axis=(0, 1)).astype(bias.dtype)]}
+
+
+# -- selective_scan --------------------------------------------------------------
+
+def _selective_infer_shape(block, op_desc):
+    """`Out` is `X`'s and `StateOut` is `State`'s: stated, so that a
+    block axis the Program leaves open (-1) stays open."""
+    for src, dst in (("X", "Out"), ("State", "StateOut")):
+        same_meta_infer_shape(src, dst)(block, op_desc)
+
+
+def selective_update(state, x, dt, b, c, neg_a, d_skip):
+    """One position of Mamba-1's recurrence, all float32: `state`
+    [batch, N, D], `x` and `dt` (after the softplus) [batch, D], `b`
+    and `c` [batch, N], `neg_a` = -exp(ALog) transposed [N, D], `d_skip`
+    [D] -> (the state after the position, y [batch, D])."""
+    state = jnp.exp(dt[:, None, :] * neg_a) * state \
+        + (dt * x)[:, None, :] * b[:, :, None]
+    return state, jnp.sum(state * c[:, :, None], axis=1) + d_skip * x
+
+
+@register_op("selective_scan", stop_gradient_op=True,
+             infer_shape=_selective_infer_shape)
+def selective_scan(ctx, ins, attrs):
+    """X, Dt (before the softplus) [batch, T, D], DtBias [D], ALog
+    [D, N], B and C [batch, T, N], D [D], State [batch, N, D] -> Out, X's
+    shape and type, and StateOut, State's (the module's docstring).
+    Under the op's own scope, `selective_scan`, whatever implements it:
+    plain `jax.numpy`, a step one fusion over the state."""
+    x, dt_raw, state = ins["X"][0], ins["Dt"][0], ins["State"][0]
+    b, c = ins["B"][0].astype(F32), ins["C"][0].astype(F32)
+    a_log = ins["ALog"][0].astype(F32)
+    rows, length, width = x.shape
+    entries = a_log.shape[1]
+    if state.shape != (rows, entries, width) \
+            or a_log.shape[0] != width or b.shape != (rows, length, entries):
+        raise ValueError(
+            "selective_scan: X %s, ALog %s, B %s and State %s are not "
+            "[batch, T, D], [D, N], [batch, T, N] and [batch, N, D]"
+            % (x.shape, a_log.shape, b.shape, state.shape))
+    telemetry.on_selective_scan_lowering(
+        "step" if length == 1 else "block", state.dtype,
+        state[0].size * state.dtype.itemsize)
+    neg_a = -jnp.exp(a_log).T
+    d_skip = ins["D"][0].astype(F32)
+    dt = jax.nn.softplus(dt_raw.astype(F32) + ins["DtBias"][0].astype(F32))
+    xf = x.astype(F32)
+    if length == 1:
+        new, y = selective_update(state.astype(F32), xf[:, 0], dt[:, 0],
+                                  b[:, 0], c[:, 0], neg_a, d_skip)
+        y = y[:, None]
+    else:
+        def one(s, at):
+            return selective_update(s, *at, neg_a, d_skip)
+
+        new, y = jax.lax.scan(one, state.astype(F32), tuple(
+            jnp.moveaxis(t, 1, 0) for t in (xf, dt, b, c)))
+        y = jnp.moveaxis(y, 0, 1)
+    return {"Out": [y.astype(x.dtype)],
+            "StateOut": [new.astype(state.dtype)]}
+
+
+@register_grad_kernel("selective_scan")
+def selective_scan_grad(ctx, ins, attrs):
+    raise NotImplementedError(
+        "selective_scan is forward only: generation needs no gradient, "
+        "and training the layer wants a parallel scan over the "
+        "positions, which this op does not have")

@@ -390,6 +390,74 @@ def test_the_grouped_decode_kernel_lowers_for_tpu(block, window, kernels):
         assert 'kernel_name = "%s"' % name in module
 
 
+@pytest.mark.parametrize("block,window,readonly,kernels", [
+    (1, 0, False, ["gqa_decode_k2048"]), (1, 512, False, ["gqa_decode_w512"]),
+    (128, 0, False, ["gqa_decode_k2048_t128"]), (128, 512, False, []),
+    (1, 0, True, ["gqa_decode_k2048"])])
+def test_pairs_of_64_wide_heads_lower_the_grouped_kernel_for_tpu(
+        block, window, readonly, kernels):
+    """`cached_attention` at phi4flash-turn-16k's shapes (16 rows, 40
+    query heads over 10 pairs of key/value heads kept side by side as
+    128-wide heads, a 16,384-slot cache or a 512-slot ring, bfloat16)
+    lowered for the TPU from this CPU host: a step walks the live slots
+    of either cache, a block of 128 positions those of the whole extent,
+    a block through a ring holds no kernel; and the form without KNew /
+    VNew walks the same kernel and writes no slot."""
+    from paddle_tpu.ops import registry
+
+    kernel = registry.get_op_info("cached_attention").kernel
+    b, h, kv, d, bf16 = 16, 40, 10, 128, jnp.bfloat16
+    cache = jax.ShapeDtypeStruct((b, kv, window or 16384, d), bf16)
+    ins = {"Q": [jax.ShapeDtypeStruct((b, block, h * d), bf16)],
+           "KCache": [cache], "VCache": [cache],
+           "Position": [jax.ShapeDtypeStruct((b,), jnp.int32)]}
+    if not readonly:
+        new = jax.ShapeDtypeStruct((b, block, kv * d), bf16)
+        ins.update(KNew=[new], VNew=[new])
+
+    def step(ins):
+        return kernel(None, ins, {"num_heads": h, "num_kv_heads": kv,
+                                  "window": window, "sm_scale": 0.125})
+
+    exported = jax.export.export(jax.jit(step), platforms=["tpu"])(ins)
+    module = exported.mlir_module()
+    assert module.count("tpu_custom_call") == len(kernels)
+    for name in kernels:
+        assert 'kernel_name = "%s"' % name in module
+    if readonly:
+        assert len(exported.out_avals) == 1
+        assert "dynamic_update_slice" not in module
+
+
+def test_the_selective_scan_lowers_for_tpu_without_a_kernel():
+    """`selective_scan` at phi4flash-turn-16k's shapes (16 rows, 5120
+    channels, 16 state entries, a float32 state [16, 16, 5120]) lowered
+    for the TPU from this CPU host: a step is plain float32 arithmetic
+    over the state, a block of 128 positions one `while` over that same
+    update; no Mosaic kernel in either, and the state keeps its type."""
+    from paddle_tpu.ops import registry
+
+    kernel = registry.get_op_info("selective_scan").kernel
+    b, d, n, bf16, f32 = 16, 5120, 16, jnp.bfloat16, jnp.float32
+    for block in (1, 128):
+        ins = {"X": [jax.ShapeDtypeStruct((b, block, d), bf16)],
+               "Dt": [jax.ShapeDtypeStruct((b, block, d), f32)],
+               "DtBias": [jax.ShapeDtypeStruct((d,), f32)],
+               "ALog": [jax.ShapeDtypeStruct((d, n), f32)],
+               "B": [jax.ShapeDtypeStruct((b, block, n), f32)],
+               "C": [jax.ShapeDtypeStruct((b, block, n), f32)],
+               "D": [jax.ShapeDtypeStruct((d,), f32)],
+               "State": [jax.ShapeDtypeStruct((b, n, d), f32)]}
+        exported = jax.export.export(
+            jax.jit(lambda ins: kernel(None, ins, {})), platforms=["tpu"])(ins)
+        module = exported.mlir_module()
+        assert "tpu_custom_call" not in module
+        assert ("stablehlo.while" in module) == (block > 1)
+        assert [str(a.dtype) for a in exported.out_avals] \
+            == ["bfloat16", "float32"]
+        assert exported.out_avals[1].shape == (b, n, d)
+
+
 def test_the_chosen_sets_decode_kernel_lowers_for_tpu():
     """`cached_attention` with `Selected` at keye-turn-64k-ep8's shape (8
     rows, 32 query heads over 4 key/value heads of 128, 65,536-slot
