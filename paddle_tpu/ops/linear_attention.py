@@ -352,7 +352,7 @@ def gated_delta_rule(ctx, ins, attrs):
     kernel = None
     if step:
         from ..kernels import gdn_step
-        kernel = gdn_step.choose_heads(rows, heads, key_heads, key_dim,
+        kernel = gdn_step.choose_block(rows, heads, key_heads, key_dim,
                                        v.shape[-1], state.dtype)
     telemetry.on_gated_delta_rule_lowering(
         "step" if step else "block", "kernel" if kernel else "plain",
@@ -375,7 +375,7 @@ def gated_delta_rule(ctx, ins, attrs):
         with jax.named_scope(scope + "state"):
             operands = (q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
                         state)
-            out, new = gdn_step.step(*operands, plain=one, heads=kernel) \
+            out, new = gdn_step.step(*operands, plain=one, block=kernel) \
                 if kernel else one(*operands)
             out = out[:, None]
     else:

@@ -3,6 +3,7 @@ kernel lowers for the TPU through Mosaic, no entry point hides the
 device it ran on, and the compile cache has one fixed place."""
 
 import os
+import re
 import subprocess
 import sys
 import time
@@ -836,14 +837,26 @@ def test_the_wide_head_decode_kernel_lowers_for_tpu(block, kernels):
         assert 'kernel_name = "%s"' % name in module
 
 
-@pytest.mark.parametrize("length,kernels", [(1, ["gdn_step_r128_h16"]),
+def _vmem_limit_stated(module):
+    """The scoped VMEM a module's one Mosaic call asks for, bytes."""
+    # the backend's configuration is a quoted string: \22 is its quote
+    sizes = re.findall(r'scoped_memory_configs.{0,80}?size\\22: (\d+)',
+                       module)
+    assert len(sizes) == 1, sizes
+    return int(sizes[0])
+
+
+@pytest.mark.parametrize("length,kernels", [(1, ["gdn_step_r128_h32_b4"]),
                                             (128, [])])
 def test_the_delta_rule_step_kernel_lowers_for_tpu(length, kernels):
     """`gated_delta_rule` at qwen3next-decode-ep16's shape (128 rows, 16
     key / 32 value heads of 128, a float32 state) lowered for the TPU
-    from this CPU host: a step holds one Mosaic kernel over (rows, 16
-    value heads a grid step) whose state is its result's buffer, a block
-    of 128 positions holds none (the chunked form is plain products)."""
+    from this CPU host: a step holds one Mosaic kernel over blocks of 4
+    rows' 32 value heads (8 MiB of state a grid step, and the VMEM limit
+    that follows from the block) whose state is its result's buffer, a
+    block of 128 positions holds none (the chunked form is plain
+    products)."""
+    from paddle_tpu.kernels import gdn_step
     from paddle_tpu.ops import registry
 
     kernel = registry.get_op_info("gated_delta_rule").kernel
@@ -866,16 +879,18 @@ def test_the_delta_rule_step_kernel_lowers_for_tpu(length, kernels):
         assert 'kernel_name = "%s"' % name in module
     if kernels:     # the state's buffer is the new state's
         assert "output_tuple_indices = [1], operand_index = 5" in module
+        assert _vmem_limit_stated(module) == gdn_step.vmem_limit((4, 32))
 
 
-@pytest.mark.parametrize("length,kernels", [(1, ["kda_step_r128_h16"]),
+@pytest.mark.parametrize("length,kernels", [(1, ["kda_step_r128_h32_b4"]),
                                             (64, [])])
 def test_the_channel_gated_step_kernel_lowers_for_tpu(length, kernels):
     """`gated_delta_rule` under a gate a key channel at
     ling3-decode-ep16's shape (128 rows, 32 heads of 128 on both sides,
     G [rows, T, 32 * 128]) lowered for the TPU from this CPU host: a
-    step holds one Mosaic kernel named for the gate, the state its
-    result's buffer; a block of 64 positions holds none."""
+    step holds one Mosaic kernel named for the gate and the block, the
+    state its result's buffer; a block of 64 positions holds none."""
+    from paddle_tpu.kernels import gdn_step
     from paddle_tpu.ops import registry
 
     kernel = registry.get_op_info("gated_delta_rule").kernel
@@ -897,3 +912,4 @@ def test_the_channel_gated_step_kernel_lowers_for_tpu(length, kernels):
         assert 'kernel_name = "%s"' % name in module
     if kernels:
         assert "output_tuple_indices = [1], operand_index = 5" in module
+        assert _vmem_limit_stated(module) == gdn_step.vmem_limit((4, 32))

@@ -150,18 +150,26 @@ def _kernel_ins(rs, rows, key_heads, heads, dtype=jnp.float32):
             jnp.asarray(0.3 * rs.randn(rows, heads, 128, 128), jnp.float32))
 
 
-@pytest.mark.parametrize("key_heads,heads,block", [(2, 4, None), (16, 32, 16),
-                                                   (2, 2, None)])
+# (rows, key heads, value heads, (rows, value heads) a grid step; None:
+# the chooser's): one block of everything, blocks of 16 of 32 value
+# heads (four grid steps: a block comes in, one is worked, one goes
+# out), a value head a key head; several rows a grid step, a loop over
+# them inside; all 32 heads a step; an odd number of rows; a batch of 1
+_BLOCKS = [(2, 2, 4, None), (2, 16, 32, (1, 16)), (2, 2, 2, None),
+           (4, 2, 4, (1, 4)), (4, 2, 4, (2, 4)), (4, 2, 4, (4, 4)),
+           (2, 16, 32, (1, 32)), (3, 2, 4, None), (1, 2, 4, None)]
+
+
+@pytest.mark.parametrize("rows,key_heads,heads,block", _BLOCKS)
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_the_step_kernel_is_the_plain_step(key_heads, heads, block, dtype):
+def test_the_step_kernel_is_the_plain_step(rows, key_heads, heads, block,
+                                           dtype):
     """The kernel's body under the Pallas interpreter, at 128 x 128 a
-    head: one block of all heads, blocks of 16 of 32 value heads, and a
-    value head a key head."""
-    ins = _kernel_ins(np.random.RandomState(heads), 2, key_heads, heads,
+    head, by the block of state a grid step takes."""
+    ins = _kernel_ins(np.random.RandomState(heads), rows, key_heads, heads,
                       dtype)
-    assert gdn_step.choose_heads(2, heads, key_heads, 128, 128,
-                                 jnp.float32) == (block or heads)
-    got, state = gdn_step.step(*ins, plain=None, interpret=True)
+    got, state = gdn_step.step(*ins, plain=None, block=block,
+                               interpret=True)
     q, k, v, g, beta, s0 = ins
     want, want_state = linear_attention.recurrent(
         q[:, None], k[:, None], v[:, None].astype(jnp.float32), g[:, None],
@@ -172,13 +180,38 @@ def test_the_step_kernel_is_the_plain_step(key_heads, heads, block, dtype):
                                atol=2e-5)
 
 
+@pytest.mark.parametrize("shape,block", [
+    # qwen3next-decode-ep16's and ling3-decode-ep16's: all heads, and
+    # the rows that fill the budget
+    ((128, 32, 16), (4, 32)), ((128, 32, 32), (4, 32)),
+    # rows the budget's four do not divide, and a batch of 1
+    ((6, 32, 16), (3, 32)), ((3, 32, 16), (3, 32)), ((1, 32, 16), (1, 32)),
+    # few heads: the rows make the block up
+    ((4, 4, 2), (4, 4)), ((2, 2, 2), (2, 2)), ((128, 4, 2), (32, 4)),
+    # more heads than the budget holds: whole key heads that tile the
+    # sublanes, a row a step
+    ((8, 256, 128), (1, 128)), ((8, 256, 256), (1, 128)),
+])
+def test_the_block_is_the_most_state_that_fits(shape, block):
+    """`choose_block` from the shapes alone: (rows, value heads, key
+    heads) -> (rows, value heads) a grid step, and the VMEM the call may
+    take follows from it."""
+    rows, heads, key_heads = shape
+    assert gdn_step.choose_block(rows, heads, key_heads, 128, 128,
+                                 jnp.float32) == block
+    state = block[0] * block[1] * 128 * 128 * 4
+    assert state <= gdn_step._STEP_BYTES
+    # a block comes in, one is worked where it lies, one goes out
+    assert 3 * state < gdn_step.vmem_limit(block) <= 3 * state + (8 << 20)
+
+
 @pytest.mark.parametrize("why,shape", [
     ("a head that is not 128 x 128", (2, 4, 2, 64, 128, jnp.float32)),
     ("a state that is not float32", (2, 4, 2, 128, 128, jnp.bfloat16)),
     ("value heads that do not group", (2, 5, 2, 128, 128, jnp.float32)),
 ])
 def test_the_step_kernel_refuses_what_it_does_not_take(why, shape):
-    assert gdn_step.choose_heads(*shape) == 0
+    assert gdn_step.choose_block(*shape) is None
 
 
 def test_the_ops_kernel_path_is_counted_and_is_its_plain_path():
@@ -290,22 +323,22 @@ def test_channel_gated_block_then_steps_is_one_block(first):
                                atol=2e-5)
 
 
-@pytest.mark.parametrize("heads,block", [(4, None), (32, 16)])
+@pytest.mark.parametrize("rows,heads,block", [
+    (2, 4, None), (2, 32, (1, 16)), (4, 4, (1, 4)), (4, 4, (2, 4)),
+    (4, 4, (4, 4)), (2, 32, (1, 32)), (3, 4, None), (1, 4, None)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_the_channel_gated_step_kernel_is_the_plain_step(heads, block,
+def test_the_channel_gated_step_kernel_is_the_plain_step(rows, heads, block,
                                                          dtype):
     """The kernel's body under the Pallas interpreter with the decay a
-    column: one block of all heads, and blocks of 16 of
-    ling3-decode-ep16's 32; some gates at the bound."""
+    column, over `_BLOCKS`' kinds of block (None: the chooser's), 32
+    heads ling3-decode-ep16's; some gates at the bound."""
     rs = np.random.RandomState(heads)
-    q, k, v, _, beta, s0 = _kernel_ins(rs, 2, heads, heads, dtype)
+    q, k, v, _, beta, s0 = _kernel_ins(rs, rows, heads, heads, dtype)
     g = FLOOR * jax.nn.sigmoid(jnp.asarray(
-        rs.uniform(-10, 3, (2, heads, 128)), jnp.float32))
+        rs.uniform(-10, 3, (rows, heads, 128)), jnp.float32))
     g = g.at[:, 0].set(FLOOR)
-    assert gdn_step.choose_heads(2, heads, heads, 128, 128,
-                                 jnp.float32) == (block or heads)
     got, state = gdn_step.step(q, k, v, g, beta, s0, plain=None,
-                               interpret=True)
+                               block=block, interpret=True)
     want, want_state = linear_attention.recurrent(
         q[:, None], k[:, None], v[:, None].astype(jnp.float32), g[:, None],
         beta[:, None], s0)
