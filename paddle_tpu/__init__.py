@@ -8,9 +8,14 @@ executables; parallelism is expressed as jax.sharding meshes (see
 paddle_tpu.parallel).
 """
 
-from . import reader
-from . import dataset
-from .reader.decorator import batch
+import time as _time
+
+_IMPORT_BEGAN = _time.perf_counter()
+
+# the tracer first: the package's import is the first event of its
+# start-up timeline (JAX's own import is inside it where the caller has
+# not imported JAX before)
+from .obs.trace import STARTUP as _STARTUP, span as _span  # noqa: E402
 
 __version__ = "0.1.0"
 
@@ -18,12 +23,18 @@ __all__ = ["reader", "dataset", "batch", "fluid", "v2", "infer",
            "layer", "image", "obs", "resilience", "analysis",
            "compile"]
 
-from . import analysis  # noqa: E402
-from . import compile  # noqa: E402,A004 — paddle_tpu.compile subsystem
-from . import obs  # noqa: E402
-from . import resilience  # noqa: E402
-from . import fluid  # noqa: E402
-from . import v2  # noqa: E402
-from .v2 import layer  # noqa: E402
-from .v2 import image  # noqa: E402
-from .v2.inference import infer  # noqa: E402
+with _span("startup/import", cat=_STARTUP).began(_IMPORT_BEGAN):
+    from . import reader
+    from . import dataset
+    from .reader.decorator import batch
+    from . import analysis
+    from . import compile  # noqa: A004 — paddle_tpu.compile subsystem
+    from . import obs
+    from . import resilience
+    with _span("startup/import_fluid", cat=_STARTUP):
+        from . import fluid
+    with _span("startup/import_v2", cat=_STARTUP):
+        from . import v2
+        from .v2 import layer
+        from .v2 import image
+        from .v2.inference import infer

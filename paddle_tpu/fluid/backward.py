@@ -18,6 +18,7 @@ from collections import defaultdict
 
 from ..core.desc import OpDesc
 from ..core.types import grad_var_name, GRAD_SUFFIX
+from ..obs import trace as obs_trace
 from ..ops import registry as op_registry
 from . import framework
 
@@ -168,6 +169,17 @@ def append_backward(loss, parameter_list=None, no_grad_set=None,
     assert isinstance(loss, framework.Variable)
     program = loss.block.program
     block = program.global_block()
+    with obs_trace.span("startup/program_backward", cat=obs_trace.STARTUP,
+                        ops_before=len(block.desc.ops)) as appended:
+        params_grads = _append_backward(loss, program, block,
+                                        parameter_list, no_grad_set,
+                                        callbacks)
+        appended.set(ops_after=len(block.desc.ops))
+    return params_grads
+
+
+def _append_backward(loss, program, block, parameter_list, no_grad_set,
+                     callbacks):
     no_grad_names = _collect_no_grad(block, no_grad_set)
 
     # seed: d loss / d loss = 1 (reference fills with fill_constant)

@@ -394,6 +394,8 @@ class _CompiledProgram:
         block_desc = program.desc.block(block_idx)
         self.segments = _segment_block(block_desc.ops)
         self._jit_cache = {}
+        # segments traced so far: a run that raised it was a first run
+        self.traces = 0
         self._plan = self._analyze()
         self._donation = self._donation_setup()
 
@@ -681,6 +683,7 @@ class _CompiledProgram:
                                 and post_traces is not None
                                 and post_traces > pre_traces)
         if traced:
+            self.traces += 1
             obs_tele.on_jit_trace(label)
         if profiled:
             jax.block_until_ready((outs, rng))
@@ -727,6 +730,7 @@ class _CompiledProgram:
                 return None  # jit path reports its own trace error
             # a real XLA compile: telemetry must see it, exactly like
             # a jit-call-path trace would have been counted
+            self.traces += 1
             obs_tele.on_jit_trace(label)
             obs_health.publish_compile_stats(label, compiled)
             attr[sig] = aot = compiled
@@ -836,25 +840,21 @@ class Executor:
     def _run_traced(self, run_span, program, feed, fetch_names, scope,
                     return_numpy, use_program_cache, eager):
         with run_span:
+            t_run = time.perf_counter()
             feed_env = {}
             block0 = program.desc.block(0)
             if feed:
                 with obs_trace.span("executor/feed", cat="executor"):
-                    t_feed = time.perf_counter()
                     for name, val in feed.items():
                         feed_env[name] = self._prepare_feed(block0, name,
                                                             val)
-                    # input time as a counter of seconds:
-                    # snapshot_delta turns it into the per-step/per-leg
-                    # h2d-INPUT share (bytes alone can't say whether
-                    # the feed path is the bottleneck)
-                    obs_tele.on_feed_seconds(time.perf_counter() - t_feed)
 
             with obs_trace.span("executor/plan",
                                 cat="executor") as plan_span:
                 compiled, miss = self._compiled_for(
                     program, feed_env, fetch_names, use_program_cache)
                 plan_span.set(miss=miss)
+            traces = compiled.traces
 
             try:
                 results = compiled.run(scope, feed_env, eager=eager)
@@ -875,6 +875,15 @@ class Executor:
             if return_numpy:
                 with obs_trace.span("executor/fetch", cat="executor"):
                     results = [self._to_numpy(r) for r in results]
+            if miss or compiled.traces != traces:
+                # a run that built its plan or traced a segment is a
+                # part of start-up; one that did neither leaves nothing
+                obs_trace.emit_span(
+                    "startup/executor_first_run", t_run,
+                    time.perf_counter() - t_run, cat=obs_trace.STARTUP,
+                    args={"place": type(self.place).__name__,
+                          "ops": len(block0.ops), "plan_miss": int(miss),
+                          "traces": compiled.traces - traces})
             return results
 
     def _compiled_for(self, program, feed_env, fetch_names,
@@ -893,8 +902,11 @@ class Executor:
                flags.get_flag("compile_passes"),
                flags.get_flag("donation"))
         compiled = self._cache.get(key) if use_program_cache else None
-        miss = compiled is None
-        if miss:
+        if compiled is not None:
+            self._cache.move_to_end(key)
+            return compiled, False
+        with obs_trace.span("startup/executor_plan", cat=obs_trace.STARTUP,
+                            ops=len(program.desc.block(0).ops)) as planned:
             # verify-before-first-compile (FLAGS_verify_program):
             # a malformed program fails HERE with a Diagnostic-
             # derived error naming op index + var, not three
@@ -929,19 +941,15 @@ class Executor:
                 self._cache[key] = compiled
                 while len(self._cache) > self._CACHE_MAX:
                     ekey, evicted = self._cache.popitem(last=False)
-                    # LRU eviction was silent: a hot serving mix
-                    # thrashing the program cache looked like
-                    # random recompiles.  Count it and name the
-                    # victim.
-                    obs_tele.on_program_cache_evict()
+                    # name the victim: a hot serving mix thrashing
+                    # the program cache looks like random recompiles
                     self._retire_segment_gauges(evicted)
                     _log.debug(
                         "evicted program cache entry: token=%s "
                         "version=%s feeds=%s fetches=%s",
                         ekey[0], ekey[1], ekey[3], ekey[4])
-        elif use_program_cache:
-            self._cache.move_to_end(key)
-        return compiled, miss
+            planned.set(segments=len(compiled._plan))
+        return compiled, True
 
     def _retire_segment_gauges(self, evicted):
         """Per-segment gauges (`xla_*`/`mem_*{segment=}`) are

@@ -38,7 +38,7 @@ from ..models.decode import (PREFILL_BLOCK, greedy_decode,
                              beam_search_decode_dense, prefill,
                              sample_decode)
 from ..obs import telemetry
-from ..obs.trace import span
+from ..obs.trace import STARTUP, emit_span, span
 
 __all__ = ["ProgramDecoder"]
 
@@ -95,6 +95,12 @@ class ProgramDecoder:
 
     def __init__(self, program, token_name, logits_name, state_pairs=(),
                  scope=None, max_positions=None):
+        with span("startup/decoder_init", cat=STARTUP):
+            self._init(program, token_name, logits_name, state_pairs,
+                       scope, max_positions)
+
+    def _init(self, program, token_name, logits_name, state_pairs, scope,
+              max_positions):
         self.token_name = token_name
         self.state_pairs = list(state_pairs)
         # the step program's position extent (KV-cache length /
@@ -125,9 +131,18 @@ class ProgramDecoder:
         # the scope's device arrays as they are: a round trip through
         # the host would hold every weight twice on the device until
         # the scope lets go of its own
-        self._params = {n: v if isinstance(v, jax.Array)
-                        else jnp.asarray(np.asarray(v)) for n, v in
-                        state_from_scope(self._fp, scope).items()}
+        params = state_from_scope(self._fp, scope)
+        from_host = [n for n, v in params.items()
+                     if not isinstance(v, jax.Array)]
+        with span("startup/state_place", cat=STARTUP,
+                  arrays=len(from_host)) as placed:
+            nbytes = 0
+            for n in from_host:
+                host = np.asarray(params[n])
+                nbytes += host.nbytes
+                params[n] = jnp.asarray(host)
+            placed.set(bytes=nbytes)
+        self._params = params
         missing = sorted(set(self._fp.state_in_names) - set(self._params))
         if missing:
             raise ValueError(
@@ -384,11 +399,21 @@ class _Call:
         self.span = span("decode/call", cat="decoder", call=next(_CALLS),
                          max_len=self.max_len)
         self.span.__enter__()
+        self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         self.span.__exit__(exc_type, exc, tb)
         if exc_type is None:
+            if self.built:
+                # a call that built its program is a part of start-up
+                # (the jit phases are inside it); one on a key the
+                # decoder has leaves nothing
+                emit_span("startup/decoder_build", self.t0,
+                          time.perf_counter() - self.t0, cat=STARTUP,
+                          args={"mode": self.mode, "batch": self.batch_size,
+                                "prompt_len": self.prompt_len,
+                                "max_len": self.max_len})
             telemetry.on_decoder_call(
                 self.mode, self.built, self.batch_size * self.beam_size,
                 self.prompt_len, self.max_len, self.handed["host"],

@@ -16,6 +16,7 @@ from . import framework
 from .framework import Program, Parameter, Variable, default_main_program
 from ..core.scope import global_scope
 from ..core.ragged import RaggedTensor
+from ..obs import trace as obs_trace
 
 __all__ = [
     "save_vars", "save_params", "save_persistables", "load_vars",
@@ -118,16 +119,24 @@ def load_vars(executor, dirname, main_program=None, vars=None,
     import jax
 
     device = executor.place.device() if executor is not None else None
-    for var in vars:
-        name = var.name if isinstance(var, Variable) else str(var)
-        # vars that had no value at save time were skipped there; mirror
-        # that instead of failing the round-trip
-        val = _load_one(dirname, name, missing_ok=True)
-        if val is None:
-            continue
-        if isinstance(val, np.ndarray) and device is not None:
-            val = jax.device_put(val, device)
-        scope.set_local(name, val)
+    # the restart path of a server or a resumed job: the files' way from
+    # the disk to the device is a part of start-up
+    with obs_trace.span("startup/load", cat=obs_trace.STARTUP) as loaded:
+        files = nbytes = 0
+        for var in vars:
+            name = var.name if isinstance(var, Variable) else str(var)
+            # vars that had no value at save time were skipped there;
+            # mirror that instead of failing the round-trip
+            val = _load_one(dirname, name, missing_ok=True)
+            if val is None:
+                continue
+            files += 1
+            if isinstance(val, np.ndarray):
+                nbytes += val.nbytes
+                if device is not None:
+                    val = jax.device_put(val, device)
+            scope.set_local(name, val)
+        loaded.set(files=files, bytes=nbytes)
 
 
 def load_params(executor, dirname, main_program=None):
@@ -298,6 +307,13 @@ def load_inference_model(dirname, executor, model_filename="__model__",
     """reference: io.py:325 — returns (program, feed_names, fetch_vars);
     with `return_meta`, appends the raw export metadata dict
     (feed_meta/bucket_hints) as a fourth element."""
+    with obs_trace.span("startup/load", cat=obs_trace.STARTUP,
+                        program=model_filename):
+        return _load_inference_model(dirname, executor, model_filename,
+                                     return_meta)
+
+
+def _load_inference_model(dirname, executor, model_filename, return_meta):
     with open(os.path.join(dirname, model_filename)) as f:
         meta = json.load(f)
     from ..core.desc import ProgramDesc

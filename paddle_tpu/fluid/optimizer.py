@@ -32,6 +32,7 @@ from .initializer import Constant
 from .layer_helper import LayerHelper
 from .regularizer import append_regularization_ops
 from . import clip as clip_mod
+from ..obs import trace as obs_trace
 from ..utils import flags
 
 __all__ = ["SGD", "Momentum", "Adagrad", "Adam", "Adamax", "DecayedAdagrad",
@@ -200,14 +201,20 @@ class Optimizer:
     def minimize(self, loss, startup_program=None, parameter_list=None,
                  no_grad_set=None, fuse_updates=None):
         """reference: optimizer.py:204."""
-        params_grads = append_backward(loss, parameter_list, no_grad_set)
-        params_grads = sorted(params_grads, key=lambda x: x[0].name)
-        params_grads, clip_ops = clip_mod.append_gradient_clip_ops(
-            params_grads)
-        params_grads = append_regularization_ops(params_grads,
-                                                 self.regularization)
-        optimize_ops = self.create_optimization_pass(
-            params_grads, loss, startup_program, fuse_updates=fuse_updates)
+        with obs_trace.span("startup/program_optimize",
+                            cat=obs_trace.STARTUP,
+                            op_type=self.op_type) as minimized:
+            params_grads = append_backward(loss, parameter_list,
+                                           no_grad_set)
+            params_grads = sorted(params_grads, key=lambda x: x[0].name)
+            params_grads, clip_ops = clip_mod.append_gradient_clip_ops(
+                params_grads)
+            params_grads = append_regularization_ops(params_grads,
+                                                     self.regularization)
+            optimize_ops = self.create_optimization_pass(
+                params_grads, loss, startup_program,
+                fuse_updates=fuse_updates)
+            minimized.set(parameters=len(params_grads))
         return optimize_ops, params_grads
 
 

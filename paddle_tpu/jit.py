@@ -14,6 +14,7 @@ caller owns placement/sharding of every buffer.
 import jax
 
 from .fluid.executor import ExecContext, apply_op, RNG_STATE_NAME
+from .obs import trace as obs_trace
 
 __all__ = ["FunctionalProgram", "functionalize", "state_from_scope",
            "state_to_scope"]
@@ -31,6 +32,12 @@ class FunctionalProgram:
     """
 
     def __init__(self, program, feed_names, fetch_names, block_idx=0):
+        with obs_trace.span("startup/functional_program",
+                            cat=obs_trace.STARTUP) as made:
+            self._analyze(program, feed_names, fetch_names, block_idx)
+            made.set(ops=len(self.ops), state=len(self.state_in_names))
+
+    def _analyze(self, program, feed_names, fetch_names, block_idx):
         self.program = program
         self.feed_names = list(feed_names)
         self.fetch_names = list(fetch_names)

@@ -52,7 +52,7 @@ __all__ = ["on_executor_run", "on_jit_trace",
            "on_cached_attention_readonly_lowering",
            "on_decoder_positions", "on_shared_cache_readers",
            "on_transfer",
-           "on_feed_seconds", "on_decoder_call", "on_program_cache_evict",
+           "on_decoder_call",
            "jit_trace_count", "transfer_bytes", "step", "set_gauge",
            "snapshot", "snapshot_delta", "snapshot_and_delta"]
 
@@ -583,28 +583,6 @@ def on_index_sets_reused(program, reused):
           .labels(program=str(program._cache_token)).inc(reused)
 
 
-def on_program_cache_evict():
-    """The executor's program-level LRU cache dropped an entry — the
-    next run of that program pays a full replan (and, unbucketed, a
-    retrace).  Silent before; a thrashing serving mix looked like
-    random recompiles."""
-    _reg().counter("executor_program_cache_evictions_total",
-                   "compiled-program entries evicted from the "
-                   "executor's LRU cache").inc()
-
-
-def on_feed_seconds(seconds):
-    """Wall time the executor spent preparing feeds (dtype casts, the
-    int64 guard, host->device placement) for one run.  A counter of
-    seconds, so `snapshot_delta` attributes input time per step/leg —
-    the h2d-INPUT half of the time split that `on_transfer` only
-    reports in bytes."""
-    if seconds > 0:
-        _reg().counter("executor_feed_seconds_total",
-                       "seconds spent preparing/placing executor "
-                       "feeds (host->device input time)").inc(seconds)
-
-
 def on_transfer(direction, nbytes):
     """Host<->device bytes moved by the executor feed/fetch paths.
     direction: "h2d" (feeds placed on device) or "d2h" (fetches pulled
@@ -627,8 +605,7 @@ def on_decoder_call(mode, built, rows, prompt_len, max_len, host_bytes,
     where they were (`host_bytes` as host arrays, `device_bytes` as
     `jax.Array`s), and `seconds`, the call's (prep, dispatch, fetch)
     intervals, the `decode/*` spans' own, which record nothing without a
-    profiler session.  What `on_feed_seconds` and `on_transfer` are for
-    the executor."""
+    profiler session.  What `on_transfer` is for the executor."""
     reg = _reg()
     reg.counter("decoder_calls_total", "ProgramDecoder calls, by mode",
                 labelnames=("mode",)).labels(mode=mode).inc()
@@ -682,12 +659,19 @@ def _on_jit_phase(event, duration, fun_name="", **_):
     other two; one label value serves all three (the executor's
     function is `segment_fn`, the trainers' is `step`).  A persistent
     cache hit is a `compile` of its load time.  Fires only when
-    something compiles."""
+    something compiles, and is told a phase when it has ended: the
+    start-up timeline gets it as `startup/jit_<phase>` from `duration`
+    ago, under the start-up event open on this thread (the first run
+    that made JAX compile; none for a jit of the caller's own)."""
     phase = _JIT_PHASES.get(event)
     if phase is None:
         return
     if fun_name.startswith("jit(") and fun_name.endswith(")"):
         fun_name = fun_name[len("jit("):-1]
+    trace_mod.emit_span("startup/jit_" + phase,
+                        time.perf_counter() - duration, duration,
+                        cat=trace_mod.STARTUP,
+                        args={"fun_name": fun_name})
     _reg().counter("jit_phase_seconds_total",
                    "seconds JAX spent tracing, lowering and compiling "
                    "(or loading from the persistent cache), per jitted "
@@ -732,7 +716,7 @@ class _StepTimer:
     family.  `examples` may be set after entry, by a step that learns
     its batch size from its feeds."""
 
-    __slots__ = ("trainer", "examples", "args", "_t0", "_dt", "_span")
+    __slots__ = ("trainer", "examples", "args", "t0", "_dt", "_span")
 
     def __init__(self, trainer, examples, args):
         self.trainer = trainer
@@ -744,7 +728,7 @@ class _StepTimer:
         self._span = trace_mod.span(self.trainer + "/step",
                                     cat="trainer", **self.args)
         self._span.__enter__()
-        self._t0 = time.perf_counter()
+        self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
@@ -758,7 +742,7 @@ class _StepTimer:
         does it; a step with work of its own after the timed part (its
         monitor, its flight record, which reads these counters) calls
         it there, inside its span, and exit does not count it twice."""
-        self._dt = dt = time.perf_counter() - self._t0
+        self._dt = dt = time.perf_counter() - self.t0
         reg = _reg()
         reg.counter("trainer_steps_total", "completed train steps",
                     labelnames=("trainer",)) \

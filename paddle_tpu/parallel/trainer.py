@@ -8,6 +8,8 @@ is ONE jitted function laid out over the mesh: batch sharded on dp,
 weights sharded on mp, gradients all-reduced by XLA over ICI.
 """
 
+import time
+
 import numpy as np
 
 import jax
@@ -175,6 +177,11 @@ class ParallelTrainer:
         self._base_rng = jax.random.PRNGKey(seed)
         self._step_count = 0
         self._step_fn = None
+        # the step function's own count of the signatures it has traced
+        # (`init` finds it; `int` where a step function has none: always
+        # 0) and what it read last: a step that raised the count was a
+        # first step
+        self._traced, self._traces = int, 0
         self._monitor = None
         self.state = None
 
@@ -198,6 +205,12 @@ class ParallelTrainer:
         FIRST — before the startup program executes, before any jit
         trace — so a non-divisible shard or schedule hazard rejects
         with op/var/spec identity instead of burning an XLA compile."""
+        with obs_trace.span("startup/trainer_init", cat=obs_trace.STARTUP,
+                            trainer=type(self).__name__):
+            self._init(scope, executor)
+        return self
+
+    def _init(self, scope, executor):
         from ..fluid.executor import Executor, CPUPlace
         from ..core.scope import Scope
 
@@ -224,12 +237,16 @@ class ParallelTrainer:
         state = state_from_scope(fp, scope)
         self._step_fn, self._shardings = self._make_step(fp, state,
                                                          fetch_all)
+        self._traced = getattr(self._step_fn, "_cache_size", int)
+        self._traces = self._traced()
         # place state on the mesh
-        self.state = {
-            n: jax.device_put(np.asarray(v), self._shardings[n])
-            for n, v in state.items()
-        }
-        return self
+        with obs_trace.span("startup/state_place", cat=obs_trace.STARTUP,
+                            arrays=len(state),
+                            bytes=sum(v.nbytes for v in state.values())):
+            self.state = {
+                n: jax.device_put(np.asarray(v), self._shardings[n])
+                for n, v in state.items()
+            }
 
     def _verify(self):
         """The pre-startup trust-boundary gate; `SpmdTrainer` replaces
@@ -303,6 +320,14 @@ class ParallelTrainer:
                 obs_flight.on_crash(exc, origin="parallel/step",
                                     step=blamed[0], feeds=blamed[1])
                 raise
+            if self._traced() != self._traces:
+                # a step that traced is a part of start-up; one that did
+                # not leaves nothing
+                self._traces = self._traced()
+                obs_trace.emit_span(
+                    "startup/trainer_first_step", timer.t0,
+                    time.perf_counter() - timer.t0, cat=obs_trace.STARTUP,
+                    args={"step": step_id})
             with obs_trace.span("parallel/record", cat="trainer"):
                 timer.record()
                 if monitor is not None:

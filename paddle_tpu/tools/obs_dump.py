@@ -10,6 +10,11 @@
     # pretty-print a tail-capture dump (obs.tail / GET /debug/tail):
     python -m paddle_tpu.tools.obs_dump --tail tail.json
 
+    # where the time to the first answer went: the start-up timeline
+    # of a trace file a process wrote (`--trace-out`), or, in-process
+    # with no file, this process's own
+    python -m paddle_tpu.tools.obs_dump --startup trace.json
+
     # the CI entry point (scripts/ci.sh, scripts/smoke.sh):
     python -m paddle_tpu.tools.obs_dump --selftest
 
@@ -63,6 +68,11 @@ def parse_args(argv=None):
                    help="validate and pretty-print a tail-capture "
                         "dump (obs.tail / the server's /debug/tail "
                         "body) and exit")
+    p.add_argument("--startup", nargs="?", const="", default=None,
+                   metavar="TRACE_JSON",
+                   help="print the start-up timeline's summary as a "
+                        "table: of a trace file, or of this process "
+                        "when no file is given")
     p.add_argument("--selftest", action="store_true",
                    help="run a tiny traced workload and assert the "
                         "whole obs pipeline works end to end")
@@ -264,6 +274,59 @@ def render_tail(doc, max_requests=8):
         lines.append(head)
         for root in rec["spans"]:
             _render_span_node(root, 0, lines)
+    return "\n".join(lines)
+
+
+def startup_events_of(doc):
+    """The start-up timeline a Chrome trace document (dict or path)
+    carries, as `obs.trace.startup_events()` gives it (seconds, on the
+    file's own clock)."""
+    if not isinstance(doc, dict):
+        with open(doc) as f:
+            doc = json.load(f)
+    carried = sorted((ev for ev in doc["traceEvents"]
+                      if ev.get("cat") == "startup"),
+                     key=lambda ev: ev["args"]["startup_index"])
+    events = []
+    for ev in carried:
+        args = dict(ev["args"])
+        del args["startup_index"]
+        events.append({"name": ev["name"], "t0": ev["ts"] / 1e6,
+                       "dur": ev["dur"] / 1e6, "tid": ev["tid"],
+                       "parent": args.pop("startup_parent"),
+                       "args": args})
+    return events
+
+
+def render_startup(doc=None):
+    """`obs.trace.startup_summary` as a table, the largest self time
+    first: of the timeline a Chrome trace document (dict or path)
+    carries, else of this process's."""
+    from paddle_tpu.obs import trace as obs_trace
+
+    if doc is None:
+        events = obs_trace.startup_events()
+        summary = obs_trace.startup_summary(events=events)
+    else:
+        if not isinstance(doc, dict):
+            with open(doc) as f:
+                doc = json.load(f)
+        events = startup_events_of(doc)
+        summary = obs_trace.startup_summary(events=events)
+        summary["dropped"] = doc.get("otherData", {}).get(
+            "startup_dropped_events", 0)
+    rows = sorted(summary["events"].items(),
+                  key=lambda item: -item[1]["self_s"])
+    began = min((ev["t0"] for ev in events), default=0.0)
+    ended = max((ev["t0"] + (ev["dur"] or 0.0) for ev in events),
+                default=0.0)
+    lines = ["start-up timeline: %d events over %.3f s, %.3f s under a "
+             "program event, %d dropped"
+             % (len(events), ended - began, summary["covered"],
+                summary["dropped"]),
+             "%-40s %6s %10s" % ("event", "calls", "self s")]
+    lines += ["%-40s %6d %10.3f" % (name, row["calls"], row["self_s"])
+              for name, row in rows]
     return "\n".join(lines)
 
 
@@ -549,6 +612,15 @@ def selftest(args):
     trace_path = args.trace_out or os.path.join(workdir, "trace.json")
     obs_trace.export_chrome_trace(trace_path)
     events = validate_chrome_trace(trace_path)
+    # --- the start-up timeline: always on, carried by the same file,
+    # and `--startup` makes one table of the file's and the process's
+    carried = startup_events_of(trace_path)
+    assert [ev["name"] for ev in carried] \
+        == [ev["name"] for ev in obs_trace.startup_events()]
+    table = render_startup(trace_path)
+    for needed in ("startup/import", "startup/executor_first_run",
+                   "startup/jit_compile"):
+        assert needed in table, table
     steps = _find_span(events, "v2/step")
     runs = _find_span(events, "executor/run")
     segs = _find_span(events, "executor/segment")
@@ -639,10 +711,13 @@ def main(argv=None):
     if args.tail:
         print(render_tail(args.tail), flush=True)
         return 0
+    if args.startup is not None:
+        print(render_startup(args.startup or None), flush=True)
+        return 0
     if not args.trace_out and not args.metrics_out:
         raise SystemExit("nothing to do: pass --selftest, --check, "
-                         "--flight, --tail, --trace-out and/or "
-                         "--metrics-out")
+                         "--flight, --tail, --startup, --trace-out "
+                         "and/or --metrics-out")
     from paddle_tpu.obs import registry as obs_registry
     from paddle_tpu.obs import trace as obs_trace
 
