@@ -85,12 +85,12 @@ class ProgramDecoder:
     a token's hidden state: `models/latent_moe_program.py`), the decoder
     prefills by the smallest its Program states, and a step that states
     none keeps `PREFILL_BLOCK`.  A step whose attention reads a chosen
-    set of the slots is such a step too where its ops choose and attend
-    a set a position of the block (`mla_index_select`,
-    `mla_cached_attention` with `Selected`: the latent builder with an
-    `indexer`); one whose ops take a single query's set declares
-    [batch] and is prefilled a position an application
-    (`models/sparse_kv_moe_program.py`).
+    set of the slots is such a step too, since its ops choose and attend
+    a set a position of the block (`mla_index_select`, and
+    `mla_cached_attention` or `cached_attention` with `Selected`: the
+    latent builder with an `indexer`, `models/sparse_kv_moe_program.py`);
+    a step whose ops took a single query's set would declare [batch]
+    and be prefilled a position an application.
     """
 
     def __init__(self, program, token_name, logits_name, state_pairs=(),

@@ -39,7 +39,7 @@ __all__ = [
 def cached_attention(query, key, value, k_cache, v_cache, position,
                      num_heads=1, sm_scale=None, num_kv_heads=None,
                      window=0, name=None, selected=None, live=None,
-                     reader=0, shared_readers=0):
+                     reader=0, shared_readers=0, prefill_block=None):
     """Attention through a KV cache over a block of T >= 1 consecutive
     positions of every row (ops/attention.py cached_attention; T = 1 is
     a decode step): query [batch, T, num_heads * head_dim], key/value
@@ -52,9 +52,13 @@ def cached_attention(query, key, value, k_cache, v_cache, position,
     caches are rings of `window` slots written at position mod window,
     and a query sees itself and the window - 1 positions before it.
     With `selected` int32 [batch, top_k] and `live` int32 [batch]
-    (`mla_index_select`'s two; whole-extent caches, T = 1) the step
-    attends the slots `selected` names, the first `live` of each row,
-    one set for every key/value head.
+    (`mla_index_select`'s two; whole-extent caches) a step attends the
+    slots `selected` names, the first `live` of each row, one set for
+    every key/value head; a block of T > 1 positions takes a set a
+    position, `selected` [batch, T, top_k] and `live` [batch, T].
+    `prefill_block`: the most positions a block of this op was sized
+    for, which `fluid.ProgramDecoder` reads off the Program to prefill
+    by (a longer block is refused).
     Returns (out [batch, T, num_heads * head_dim], k_cache_out,
     v_cache_out) — thread the cache outputs back as decode state
     (`fluid.ProgramDecoder` state pairs).
@@ -96,6 +100,8 @@ def cached_attention(query, key, value, k_cache, v_cache, position,
         attrs["window"] = int(window)
     if shared_readers:
         attrs["shared_readers"] = int(shared_readers)
+    if prefill_block:
+        attrs["prefill_block"] = int(prefill_block)
     inputs = {"Q": [query], "KNew": [key], "VNew": [value],
               "KCache": [k_cache], "VCache": [v_cache],
               "Position": [position]}

@@ -85,7 +85,7 @@ a query, one set for every key/value head).
 
     gqa_decode_chosen(q[B, KV, G, D], k_chosen[B, top_k, KV, D],
                       v_chosen[B, top_k, KV, D], live, sm_scale)
-        -> [B, KV, G, D]
+        -> [B, KV, G, D]        (live: an int32 scalar, or [B] a count a row)
 
 The chosen slots are fetched by the op's gather, not by this file: a
 fetch of scattered slots is bound by its copies' count, about 12 ns a
@@ -111,10 +111,18 @@ every (KV / 2)-th word row (a strided load) is `[chunk * 2, D]`, column
 its own head's columns; the fold is the one above (`_fold`).  In float32
 a word is a head and a fold takes one.  Entry i >= `live` is masked and
 its values zeroed; a chunk past the last live entry is neither fetched
-nor folded.  It takes (`choose_chunk`) 128-wide heads, operands of 16 or
+nor folded.  `live` is one count for every row (the rows of a decode
+step move in lockstep) or a count a row, `[B]`: a block of positions
+comes here a position a row, B = batch * P, and position t of a session
+that has not filled its `top_k` yet has one live entry more than
+position t - 1.  It takes (`choose_chunk`) 128-wide heads, operands of 16 or
 32 bits in q's type, a count of key/value heads that fills the words
 (even in bfloat16, or one), and a `top_k` that a chunk of 128 to 2048
-entries tiles.
+entries tiles.  The call states its cost (`pl.CostEstimate`: the copies'
+bytes, the two products' operations): what a custom call costs is
+opaque to the compiler otherwise, and its memory-space assignment then
+places the gathered copies in fast memory by chance (PERF.md section 6,
+PR 66).
 
 Which shapes it takes (`fits`): S a multiple of 128; heads a multiple of
 128 wide (the lanes: one lane block a head, or two at 256) with G * T rows a key/value head small enough that their scores
@@ -555,7 +563,8 @@ def _chosen_kernel(live_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
     of those of entry e, under the `pack * G` queries that read one of
     them, each attending its own head's columns."""
     j = pl.program_id(1)
-    live = live_ref[0]
+    # one count for every row, or a row's own of the prefetched vector
+    live = live_ref[pl.program_id(0) if live_ref.shape[0] > 1 else 0]
     last_chunk = (live - 1) // chunk
     group = q_ref.shape[1] // kv_heads
     rows, sets = pack * group, kv_heads // pack
@@ -604,20 +613,33 @@ def _chosen_call(q, k_chosen, v_chosen, live, *, sm_scale, chunk, kv_heads,
                  name, interpret):
     """q [B, KV * G, D] over chosen slots [B, top_k * KV, D]."""
     batch, rows, dim = q.shape
+    a_row = live.shape[0] > 1   # a count a row, else one for every row
 
     def entries(b, j, live):
         # a chunk past the last live entry names that one: fetched once
-        return b, jnp.minimum(j, (live[0] - 1) // chunk), 0
+        return b, jnp.minimum(j, (live[b if a_row else 0] - 1) // chunk), 0
 
     def row(b, j, live):
         return b, 0, 0
 
     block = pl.BlockSpec((1, chunk * kv_heads, dim), entries)
+    entries_held = k_chosen.shape[1] // kv_heads
     return pl.pallas_call(
         functools.partial(
             _chosen_kernel, sm_scale=sm_scale, chunk=chunk,
             kv_heads=kv_heads,
             pack=_heads_a_word(kv_heads, q.dtype.itemsize)),
+        # what the call reads and computes, for the compiler: a custom
+        # call's cost is opaque to it, and the memory-space assignment
+        # places the gathered copies in fast memory only where it knows
+        # that their one reader is bound by their bytes (with no
+        # estimate it placed 6 of a step's 10 copies there in one
+        # program and none in the next: PERF.md section 6, PR 66)
+        cost_estimate=pl.CostEstimate(
+            flops=4 * batch * rows * entries_held * dim,
+            transcendentals=batch * rows * entries_held,
+            bytes_accessed=(k_chosen.size + v_chosen.size + 2 * q.size)
+            * q.dtype.itemsize),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(batch, k_chosen.shape[1] // (chunk * kv_heads)),
@@ -751,10 +773,12 @@ def gqa_decode_chosen(q, k_chosen, v_chosen, live, sm_scale, chunk=None):
     [batch, kv_heads, group, D] in q's type: `k_chosen`, `v_chosen`
     [batch, top_k, kv_heads, D] hold the chosen slots as a gather of
     whole slots leaves them, a slot's heads side by side, and the
-    queries attend the first `live` (an int32 scalar, at least 1) of a
-    row's entries: see the module's docstring.  `chunk`, the entries a
-    grid step folds, is chosen from the shapes unless given (tests,
-    sweeps)."""
+    queries attend the first `live` of a row's entries (int32, at least
+    1: a scalar, one count for every row, as the rows of a decode step
+    have; or [batch], a count a row, as the positions of a block have
+    that lie a row each): see the module's docstring.  `chunk`, the
+    entries a grid step folds, is chosen from the shapes unless given
+    (tests, sweeps)."""
     batch, kv_heads, group, dim = q.shape
     top_k = k_chosen.shape[1]
     chunk = chunk or choose_chunk(top_k, kv_heads, group, q.dtype.itemsize,
@@ -762,6 +786,7 @@ def gqa_decode_chosen(q, k_chosen, v_chosen, live, sm_scale, chunk=None):
     if k_chosen.shape != (batch, top_k, kv_heads, dim) \
             or v_chosen.shape != k_chosen.shape \
             or k_chosen.dtype != q.dtype or v_chosen.dtype != q.dtype \
+            or jnp.size(live) not in (1, batch) \
             or not chunk or top_k % chunk \
             or not choose_chunk(chunk, kv_heads, group, q.dtype.itemsize,
                                 dim):     # a given chunk: tiled and held too
@@ -774,7 +799,7 @@ def gqa_decode_chosen(q, k_chosen, v_chosen, live, sm_scale, chunk=None):
         q.reshape(batch, kv_heads * group, dim),
         k_chosen.reshape(batch, top_k * kv_heads, dim),
         v_chosen.reshape(batch, top_k * kv_heads, dim),
-        jnp.reshape(live, (1,)).astype(jnp.int32), sm_scale=float(sm_scale),
+        jnp.reshape(live, (-1,)).astype(jnp.int32), sm_scale=float(sm_scale),
         chunk=chunk, kv_heads=kv_heads,
         name="gqa_decode_sel%d_c%d" % (top_k, chunk))
     return out.reshape(q.shape)

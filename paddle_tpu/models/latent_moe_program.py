@@ -95,7 +95,7 @@ from .decoder_block import (block_positions, last, last_token_rows, linear,
                             linear_float32, norm, share_feed_forward)
 
 __all__ = ["build_latent_moe_cached_step_program", "latent_moe_param_names",
-           "prefill_block"]
+           "prefill_block", "sized_block"]
 
 _ATTENTION = ("input_norm", "w_dq", "q_norm", "w_uq_nope", "w_uq_rope",
               "w_dkv", "kv_norm", "w_uk", "w_uv", "wo")
@@ -162,16 +162,23 @@ def prefill_block(batch, n_head, kv_rank, d_rope, indexer=None, max_len=0):
     likely to see, and no remainder block is a second program.  256 rows
     of 128 heads over 512 + 64: 16 positions, 4096 tokens an
     application, which is compute-bound already (13.9 TFLOP, 71 ms at
-    the v5e's peak, against 12 ms to read the weights).
+    the v5e's peak, against 12 ms to read the weights).  With an
+    `indexer`, what `sized_block` says of a chooser: 16 rows take 32
+    positions, 8 rows 64."""
+    return sized_block(batch, batch * n_head * (2 * kv_rank + d_rope) * 2,
+                       indexer, max_len)
 
-    With an `indexer` (heads, width, top_k) over `max_len` slots a
+
+def sized_block(batch, a_position, indexer=None, max_len=0):
+    """`prefill_block` for a step whose largest arrays hold `a_position`
+    bytes a position of the block over its `batch` rows.  With an
+    `indexer` (heads, width, top_k) over `max_len` slots (a chooser's
+    step, this builder's or `models/sparse_kv_moe_program.py`'s) a
     position also holds its index scores [max_len] float32 and its set
     [top_k] int32, the budget is less the two tiles a chooser's block
     works through (`ops.attention.TILE_BYTES`: the index scores before
     the heads are summed, the gathered rows beside their attention's
-    scores), and the block stops at `_CHOOSER_ROWS` token rows: 16 rows
-    take 32 positions, 8 rows 64."""
-    a_position = batch * n_head * (2 * kv_rank + d_rope) * 2
+    scores), and the block stops at `_CHOOSER_ROWS` token rows."""
     most, room = PREFILL_BLOCK, _BLOCK_BYTES
     if indexer is not None:
         a_position += batch * (max_len + indexer[2]) * 4

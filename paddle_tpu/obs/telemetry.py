@@ -326,12 +326,15 @@ def on_window_attention_lowering(kind, kv_heads, window, path, block_k,
     _kv_cache_slots(kind, slots)
 
 
-def on_sparse_attention_lowering(kv_heads, top_k, slots, path, block_k):
+def on_sparse_attention_lowering(kv_heads, top_k, slots, path, block_k,
+                                 positions=1, tile=1):
     """A `cached_attention` op (ops/attention.py) was traced into a
-    program over a chosen set (Selected and Live: a step
+    program over a chosen set (Selected and Live: a step, or a block of
+    `positions` of them with a set each, `tile` positions' sets gathered
+    and attended at once,
     over whole-extent caches of `slots` slots a row that gathers `top_k`
-    of them for all `kv_heads` key/value heads): which way it takes over
-    the gathered slots.  `path` has two values: "kernel", whole slots
+    of them a position for all `kv_heads` key/value heads): which way it
+    takes over the gathered slots.  `path` has two values: "kernel", whole slots
     gathered with their heads side by side and
     kernels/gqa_decode.py `gqa_decode_chosen` over the copies as they
     lie, `block_k` the entries a grid step folds; "plain", a copy a
@@ -344,12 +347,13 @@ def on_sparse_attention_lowering(kv_heads, top_k, slots, path, block_k):
                    "key/value-cached attention ops over a chosen set "
                    "lowered, by key/value heads, slots chosen, the cache's "
                    "extent, path (the kernel over the gathered slots, or "
-                   "the plain products) and the entries a grid step of "
-                   "the kernel folds",
+                   "the plain products), the entries a grid step of "
+                   "the kernel folds, the positions of a row the op took "
+                   "and how many of them it attends at once",
                    labelnames=("kv_heads", "top_k", "slots", "path",
-                               "block_k")) \
+                               "block_k", "positions", "tile")) \
           .labels(kv_heads=kv_heads, top_k=top_k, slots=slots, path=path,
-                  block_k=block_k).inc()
+                  block_k=block_k, positions=positions, tile=tile).inc()
     _kv_cache_slots("sparse", slots)
 
 

@@ -393,8 +393,8 @@ def cached_attention_op(ctx, ins, attrs):
 
     With Selected int32 [batch, top_k] and Live int32 [batch]
     (`mla_index_select`'s two) a step attends a chosen set and not every
-    live slot (whole-extent caches, `window` 0, T = 1; anything else
-    raises): the step's slot is written, the slots Selected names are
+    live slot (whole-extent caches, `window` 0; a ring or a Selected
+    without Live raises): the step's slot is written, the slots Selected names are
     gathered from both caches, one set for every key/value head
     (`kv_gather`; an entry is clipped into the extent), and the group's
     queries attend the first Live of a row's top_k entries
@@ -408,7 +408,25 @@ def cached_attention_op(ctx, ins, attrs):
     lie: no pass turns or fills them in between.  Every other set takes
     two [batch, kv_heads, top_k, head_dim] copies and the plain path
     under the mask entry < Live.  A chosen set is a set: the softmax
-    does not care for its order.
+    does not care for its order.  A chosen set is one position's: a
+    block of T > 1 positions comes with Selected [batch, T, top_k] and
+    Live [batch, T], a set a position (`mla_index_select`'s for T
+    queries).  The block's T entries are written first, slots Position
+    .. Position + T - 1 of both caches, so that query t may choose and
+    read the slots of the block's positions up to its own, as T single
+    steps would; then a tile of P positions at a time
+    (`_CHOSEN_TILE_BYTES`: a position's two copies, beside its scores on
+    the plain path, within half the chip's fast memory) the
+    tile's [batch, P * top_k] slots are gathered from each cache in one
+    gather of the kind a step makes, and attended a position a row:
+    `gqa_decode_chosen` over batch * P rows, each under its own Live[b,
+    t] (a session that has not filled its top_k yet has one live entry
+    more a position), or the plain products under entry < Live[b, t].
+    Nothing is shared between the sets: the gathers of a block are those
+    of its T steps, and the caches come out bit for bit as T steps leave
+    them.  `prefill_block` (an attr) is the most positions a block of
+    this op was sized for (what a step's builder states for
+    `fluid.ProgramDecoder` to prefill by): a longer block is refused.
 
     Without KNew and VNew the op **reads a cache it does not write**
     (whole-extent caches, no chosen set; anything else raises): query i
@@ -445,13 +463,21 @@ def cached_attention_op(ctx, ins, attrs):
             "caches %s, window %d: the heads do not group, or the cache "
             "is not those heads' or not the window's ring"
             % (num_heads, kv_heads, k_cache.shape, window))
-    if selected is not None and (window or block != 1
-                                 or not ins.get("Live")):
+    if selected is not None and (
+            window or not ins.get("Live") or tuple(selected.shape[:-1])
+            != ((rows,) if block == 1 else (rows, block))):
         raise ValueError(
-            "cached_attention: Selected with window %d over a block of %d "
-            "positions%s: a chosen set is one position's over whole-extent "
-            "caches, and comes with Live"
-            % (window, block, "" if ins.get("Live") else ", without Live"))
+            "cached_attention: Selected %s with window %d over a block of "
+            "%d positions of %d rows%s: a chosen set is one position's over "
+            "whole-extent caches, [batch, top_k] for a step and [batch, T, "
+            "top_k] for a block, and comes with Live"
+            % (selected.shape, window, block, rows,
+               "" if ins.get("Live") else ", without Live"))
+    if block > int(attrs.get("prefill_block", 0) or block):
+        raise ValueError(
+            "cached_attention: a block of %d positions, and the op was "
+            "sized for %d (`prefill_block`)"
+            % (block, attrs["prefill_block"]))
     if readonly and (window or selected is not None or ins.get("VNew")):
         raise ValueError(
             "cached_attention: without KNew and VNew the op reads "
@@ -497,6 +523,13 @@ def cached_attention_op(ctx, ins, attrs):
         block_k = gqa_decode.choose_block(attended, group * block,
                                           q.dtype.itemsize, head_dim)
     writes = block_k and head_dim == 64
+    # the positions of a block over chosen sets that are gathered and
+    # attended at once: their two copies, beside their scores where
+    # these are made whole (the plain path)
+    tile = 1 if selected is None or block == 1 else _tile_positions(
+        block, rows * attended * (
+            2 * kv_heads * head_dim * q.dtype.itemsize
+            + (0 if block_k else num_heads * 4)), _CHOSEN_TILE_BYTES)
     telemetry.on_cached_attention_lowering(block)
     if attrs.get("shared_readers"):
         telemetry.on_decoder_positions("self", block)
@@ -511,7 +544,7 @@ def cached_attention_op(ctx, ins, attrs):
     else:
         telemetry.on_sparse_attention_lowering(
             kv_heads, attended, extent, "kernel" if block_k else "plain",
-            block_k)
+            block_k, block, tile)
 
     with jax.named_scope("kv_write"):
         before = k_cache, v_cache
@@ -531,6 +564,11 @@ def cached_attention_op(ctx, ins, attrs):
             v_cache = jax.lax.dynamic_update_slice_in_dim(
                 v_cache, vh.astype(v_cache.dtype), at, axis=2)
 
+    if selected is not None and block > 1:
+        out = _attend_chosen_sets(q, k_cache, v_cache, selected,
+                                  ins["Live"][0], kv_heads, sm_scale,
+                                  block_k, tile)
+        return {"Out": [out], "KCacheOut": [k_cache], "VCacheOut": [v_cache]}
     if selected is not None:
         live = jnp.reshape(ins["Live"][0], (-1,))[0].astype(jnp.int32)
         with jax.named_scope("kv_gather"):
@@ -595,6 +633,62 @@ def cached_attention_op(ctx, ins, attrs):
         return {"Out": [out.astype(q.dtype)]}
     return {"Out": [out.astype(q.dtype)],
             "KCacheOut": [k_cache], "VCacheOut": [v_cache]}
+
+
+def _attend_chosen_sets(q, k_cache, v_cache, selected, live, kv_heads,
+                        sm_scale, chunk, tile):
+    """`cached_attention` for a block of T > 1 positions that each attend
+    a chosen set: Out [batch, T, heads * head_dim] over the caches with
+    the block's entries written, `selected` [batch, T, top_k] and `live`
+    [batch, T].  A step's gathers and a step's arithmetic a position, a
+    tile of `tile` positions at a time: one gather a cache of the tile's
+    [batch, P * top_k] slots, then `gqa_decode_chosen` over batch * P
+    rows, a position a row with its own `live` (`chunk` entries a grid
+    step), or, with `chunk` 0, the plain products under entry <
+    live[b, t]."""
+    rows, block, width = q.shape
+    top_k, head_dim = selected.shape[-1], k_cache.shape[-1]
+    group = width // (kv_heads * head_dim)
+
+    def attend(first, q, selected, live):
+        held = selected.shape[1]
+        at = selected.reshape(rows, held * top_k).astype(jnp.int32)
+        with jax.named_scope("kv_gather"):
+            # clipped, and whole slots for the kernel, as a step's
+            if chunk:
+                k_live, v_live = (
+                    jnp.take_along_axis(
+                        jnp.swapaxes(cache, 1, 2), at[:, :, None, None],
+                        axis=1, mode="clip")
+                    .reshape(rows * held, top_k, kv_heads, head_dim)
+                    for cache in (k_cache, v_cache))
+            else:
+                k_live, v_live = (
+                    jnp.take_along_axis(cache, at[:, None, :, None], axis=2,
+                                        mode="clip")
+                    .reshape(rows, kv_heads, held, top_k, head_dim)
+                    for cache in (k_cache, v_cache))
+        with jax.named_scope("attn_sparse"):
+            if chunk:
+                from ..kernels import gqa_decode
+                out = gqa_decode.gqa_decode_chosen(
+                    q.reshape(rows * held, kv_heads, group, head_dim),
+                    k_live.astype(q.dtype), v_live.astype(q.dtype),
+                    live.reshape(rows * held), sm_scale, chunk)
+            else:
+                highest = jax.lax.Precision.HIGHEST
+                s = jnp.einsum(
+                    "bphgd,bhpkd->bphgk", q.astype(jnp.float32).reshape(
+                        rows, held, kv_heads, group, head_dim),
+                    k_live.astype(jnp.float32), precision=highest) * sm_scale
+                valid = jnp.arange(top_k) < live.reshape(rows, held, 1, 1, 1)
+                p = jax.nn.softmax(jnp.where(valid, s, -1e30), axis=-1)
+                out = jnp.einsum("bphgk,bhpkd->bphgd", p,
+                                 v_live.astype(jnp.float32),
+                                 precision=highest)
+            return out.reshape(rows, held, width).astype(q.dtype)
+
+    return _by_tiles(attend, block, tile, q, selected, live)
 
 
 def _ring_write(cache, new, pos):
@@ -713,12 +807,24 @@ def _index_select_infer_shape(block, op_desc):
 TILE_BYTES = 1 << 28
 
 
-def _tile_positions(block, a_position):
+# What a tile of `cached_attention`'s block over chosen sets may hold: the
+# two copies of its positions' sets, which are written by a gather and
+# read once by the kernel next to it.  A gather of scattered slots runs at
+# the rate of its copy descriptors only while what it writes lies in fast
+# memory: at keye-turn-64k-ep8's shape (33.5 MB a position) tiles of 1 and
+# 2 positions read 10.8 ns a slot, a step's own rate, 4 read 12.4 and 8
+# and 16 read 14.4 (`scripts/gqa_decode_bench.py block`, PERF.md section
+# 6, PR 66): half the v5e's 128 MiB of fast memory is what a tile gets
+_CHOSEN_TILE_BYTES = 1 << 26
+
+
+def _tile_positions(block, a_position, within=None):
     """The positions a tile of a block takes: the largest power of two
-    whose tile of `a_position` bytes a position stays within
-    `TILE_BYTES`, one at least and the block at most."""
+    whose tile of `a_position` bytes a position stays within `within`
+    (`TILE_BYTES` unless given), one at least and the block at most."""
+    within = within or TILE_BYTES
     tile = 1
-    while 2 * tile <= block and 2 * tile * a_position <= TILE_BYTES:
+    while 2 * tile <= block and 2 * tile * a_position <= within:
         tile *= 2
     return tile
 

@@ -25,7 +25,17 @@ over the copies as they lie), by chunk; `copies` is what a kernel pays to fetch
 chosen slots itself, ns a copy by what one copy covers (one, two or all
 four heads of a slot's 32-bit word row; one cache or both; both of the
 queue's priorities): a Mosaic kernel that only starts the copies of 512
-entries a row and waits for them."""
+entries a row and waits for them.
+
+`block` (PR 66; `python scripts/gqa_decode_bench.py block`) is the op
+itself, `cached_attention` with `Selected` [8, 64, 2048] and `Live` [8,
+64] at the same shape: an application of 64 positions as a decoder's
+prefill scan runs it (the caches carried, the block's 64 slots written,
+then a tile of positions at a time the sets gathered and attended), by
+the positions a tile takes (`ops.attention._CHOSEN_TILE_BYTES` set for 1
+to 16),
+beside 64 single steps of the same op: ms an application and ns a
+gathered slot, what the tile was decided from."""
 
 import json
 import os
@@ -250,6 +260,72 @@ def chosen(emit):
               "ns_a_copy": ms * 1e6 / started})
 
 
+B_POSITIONS = 64
+
+
+def block_applications(positions):
+    """fn(n, q, k, v, k_new, v_new, selected, live): n applications of
+    `cached_attention` over `positions` positions with a chosen set each
+    as a scan carries them."""
+    import paddle_tpu.fluid  # noqa: F401  (registers the ops)
+    from paddle_tpu.ops import registry
+
+    op = registry.get_op_info("cached_attention").kernel
+    attrs = {"num_heads": C_KV * C_GROUP, "num_kv_heads": C_KV}
+    first = C_SLOTS - (LONG + 1) * B_POSITIONS
+
+    def fn(n, q, k, v, k_new, v_new, selected, live):
+        def body(i, carry):
+            q, k, v = carry
+            out = op(None, {
+                "Q": [q], "KNew": [k_new], "VNew": [v_new], "KCache": [k],
+                "VCache": [v],
+                "Position": [jnp.full((C_ROWS,), first + i * positions)],
+                "Selected": [selected + _moved(q)], "Live": [live]}, attrs)
+            return out["Out"][0], out["KCacheOut"][0], out["VCacheOut"][0]
+        return lax.fori_loop(0, n, body, (q, k, v))[0]
+    return jax.jit(fn)
+
+
+def block(emit):
+    from paddle_tpu.ops import attention
+
+    rs = np.random.RandomState(0)
+    key = jax.random.PRNGKey(0)
+    heads = C_KV * C_GROUP
+    k, v = (jax.random.normal(jax.random.fold_in(key, i),
+                              (C_ROWS, C_KV, C_SLOTS, C_DIM), jnp.bfloat16)
+            for i in range(2))
+    q, k_new, v_new = (
+        jax.random.normal(jax.random.fold_in(key, 2 + i),
+                          (C_ROWS, B_POSITIONS, n * C_DIM), jnp.bfloat16)
+        for i, n in enumerate((heads, C_KV, C_KV)))
+    selected = jnp.asarray(np.sort(rs.randint(
+        0, C_SLOTS - (LONG + 1) * B_POSITIONS,
+        (C_ROWS, B_POSITIONS, C_TOP_K)), -1), jnp.int32)
+    live = jnp.full((C_ROWS, B_POSITIONS), C_TOP_K, jnp.int32)
+    slots = C_ROWS * B_POSITIONS * C_TOP_K * 2
+    a_position = C_ROWS * C_TOP_K * 2 * C_KV * C_DIM * 2
+    own = attention._tile_positions(B_POSITIONS, a_position,
+                                    attention._CHOSEN_TILE_BYTES)
+    step = block_applications(1)
+    ms = slope(step, q[:, :1], k, v, k_new[:, :1], v_new[:, :1],
+               selected[:, 0], live[:, 0]) * B_POSITIONS
+    emit({"kind": "block", "tile": 0, "form": "64 single steps", "ms": ms,
+          "ns_a_slot": ms * 1e6 / slots})
+    want = None
+    for tile in (1, 2, 4, 8, 16):
+        attention._CHOSEN_TILE_BYTES = tile * a_position
+        fn = block_applications(B_POSITIONS)
+        got = fn(1, q, k, v, k_new, v_new, selected, live) \
+            .astype(jnp.float32)
+        want = got if want is None else want
+        ms = slope(fn, q, k, v, k_new, v_new, selected, live)
+        emit({"kind": "block", "tile": tile, "the_ops_own": tile == own,
+              "ms": ms, "ns_a_slot": ms * 1e6 / slots,
+              "max_abs_off_tile_1": float(jnp.max(jnp.abs(got - want)))})
+
+
 def main():
     assert jax.devices()[0].platform == "tpu", jax.devices()
     os.makedirs("chiprun_out", exist_ok=True)
@@ -264,6 +340,8 @@ def main():
     cases = sys.argv[1:] or ["narrow", "chosen"]
     if "chosen" in cases:
         chosen(emit)
+    if "block" in cases:
+        block(emit)
     if "narrow" in cases:
         narrow(emit)
 

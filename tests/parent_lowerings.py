@@ -30,6 +30,17 @@ tests/parent_lowerings.py steps > tests/data/parent_lowerings_pr61.json`
 on a checkout of commit cc05484, PR 61's): the jaxprs of
 `mla_index_select` and of `mla_cached_attention` over a chosen set, with
 a sink and without, at one position a row.
+
+Since PR 66 `cached_attention` takes a chosen set a position of a block,
+and what PR 66 promised to leave alone has a recording of its own
+(`attend_lowerings`, tests/data/parent_lowerings_pr65.json, written by
+`python tests/parent_lowerings.py attends >
+tests/data/parent_lowerings_pr65.json` on a checkout of commit 56cbe1e,
+PR 65's): the jaxprs of `cached_attention` at one position a row over a
+chosen set (128-wide heads: `gqa_decode_chosen`'s body included; 64-wide:
+the plain path) and without one (the walk of the live slots over either
+width, a ring, a cache the op only reads), and of a block of positions
+without a chosen set.
 """
 
 import json
@@ -40,6 +51,7 @@ _DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
 RECORDING = os.path.join(_DATA, "parent_lowerings_pr37.json")
 BLOCK_RECORDING = os.path.join(_DATA, "parent_lowerings_pr52.json")
 STEP_RECORDING = os.path.join(_DATA, "parent_lowerings_pr61.json")
+ATTEND_RECORDING = os.path.join(_DATA, "parent_lowerings_pr65.json")
 
 
 def program_text(main):
@@ -227,8 +239,67 @@ def step_lowerings():
     return out
 
 
+def attend_lowerings():
+    """{name: text}: the jaxprs of `cached_attention` on small seeded
+    inputs in float32 and bfloat16, 2 rows of 4 query heads over 2
+    key/value heads, and over 4 at 64 wide ("ungrouped": GPT-2's, whose
+    step's kernel writes the slot too): a step (T = 1) over a chosen set
+    and over every live slot, 128-wide heads (the kernels, their bodies
+    in the text) and 64-wide (a chosen set: the plain path); a step
+    through a ring and one that reads a cache it does not write; a block
+    of 8 positions over every live slot."""
+    import numpy as np
+
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import registry
+
+    rs = np.random.RandomState(0)
+    rows, heads, slots, top_k = 2, 4, 256, 128
+    kernel = registry.get_op_info("cached_attention").kernel
+
+    out = {}
+    for dtype in (jnp.float32, jnp.bfloat16):
+        for dim, kv_heads in ((64, 2), (64, 4), (128, 2)):
+            def jaxpr(ins, **attrs):
+                attrs = dict({"num_heads": heads, "num_kv_heads": kv_heads},
+                             **attrs)
+                return str(jax.make_jaxpr(
+                    lambda i: kernel(None, i, attrs))(ins))
+
+            def draw(*shape):
+                return jnp.asarray(rs.randn(*shape), dtype)
+
+            def ins(block, **more):
+                return dict({
+                    "Q": [draw(rows, block, heads * dim)],
+                    "KNew": [draw(rows, block, kv_heads * dim)],
+                    "VNew": [draw(rows, block, kv_heads * dim)],
+                    "KCache": [draw(rows, kv_heads, slots, dim)],
+                    "VCache": [draw(rows, kv_heads, slots, dim)],
+                    "Position": [jnp.full((rows,), 200, jnp.int32)]}, **more)
+
+            name = "%d-wide %s%s" % (
+                dim, "ungrouped " if kv_heads == heads else "",
+                jnp.dtype(dtype).name)
+            out["step chosen " + name] = jaxpr(ins(
+                1, Selected=[jnp.asarray(
+                    np.sort(rs.rand(rows, 201).argsort(-1)[:, :top_k], -1),
+                    jnp.int32)],
+                Live=[jnp.full((rows,), top_k, jnp.int32)]))
+            out["step " + name] = jaxpr(ins(1))
+            out["step ring " + name] = jaxpr(ins(1), window=slots)
+            reads = ins(1)
+            del reads["KNew"], reads["VNew"]
+            out["step read-only " + name] = jaxpr(reads)
+            out["block " + name] = jaxpr(ins(8))
+    return out
+
+
 if __name__ == "__main__":
     sys.path.insert(0, os.getcwd())
-    json.dump({"blocks": block_lowerings, "steps": step_lowerings}.get(
+    json.dump({"blocks": block_lowerings, "steps": step_lowerings,
+               "attends": attend_lowerings}.get(
         "".join(sys.argv[1:]), lowerings)(), sys.stdout, indent=1,
         sort_keys=True)

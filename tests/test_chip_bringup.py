@@ -490,6 +490,44 @@ def test_the_chosen_sets_decode_kernel_lowers_for_tpu():
     assert "tensor<8x2048x4x128xbf16>" in module
 
 
+def test_a_block_of_chosen_sets_lowers_for_tpu():
+    """`cached_attention` with `Selected` [batch, T, top_k] at
+    keye-turn-64k-ep8's shape, the 64 positions an application of its
+    prefill takes: the block is written, then a loop over tiles of
+    positions holds one gather of whole slots a cache and the step's own
+    Mosaic kernel over batch x tile rows, a `Live` a row."""
+    from paddle_tpu.ops import attention, registry
+
+    kernel = registry.get_op_info("cached_attention").kernel
+    b, t, h, kv, d, bf16 = 8, 64, 32, 4, 128, jnp.bfloat16
+    tile = attention._tile_positions(t, b * 2048 * 2 * kv * d * 2,
+                                     attention._CHOSEN_TILE_BYTES)
+    assert tile == 2
+    cache = jax.ShapeDtypeStruct((b, kv, 65536, d), bf16)
+    ins = {"Q": [jax.ShapeDtypeStruct((b, t, h * d), bf16)],
+           "KNew": [jax.ShapeDtypeStruct((b, t, kv * d), bf16)],
+           "VNew": [jax.ShapeDtypeStruct((b, t, kv * d), bf16)],
+           "KCache": [cache], "VCache": [cache],
+           "Position": [jax.ShapeDtypeStruct((b,), jnp.int32)],
+           "Selected": [jax.ShapeDtypeStruct((b, t, 2048), jnp.int32)],
+           "Live": [jax.ShapeDtypeStruct((b, t), jnp.int32)]}
+
+    def block(ins):
+        return kernel(None, ins, {"num_heads": h, "num_kv_heads": kv,
+                                  "prefill_block": t})
+
+    exported = jax.export.export(jax.jit(block), platforms=["tpu"])(ins)
+    module = exported.mlir_module()
+    assert module.count("tpu_custom_call") == 1
+    assert 'kernel_name = "gqa_decode_sel2048_c2048"' in module
+    assert "stablehlo.while" in module
+    assert module.count("stablehlo.gather") == 2
+    # a tile's whole slots, a position a row of the kernel
+    assert "tensor<%dx2048x4x128xbf16>" % (b * tile) in module
+    assert "tensor<%dx%dx4x128xbf16>" % (b, t * 2048) not in module
+    assert exported.out_avals[1].shape == (b, t, h * d)
+
+
 @pytest.mark.parametrize("rows,slots,heads,dim,name", [
     (8, 65536, 16, 64, "topk_select_s65536_k2048"),
     (16, 16384, 64, 128, "topk_select_s16384_k2048"),

@@ -5,8 +5,9 @@ the gather leaves them.  Held to masked attention over the whole caches
 in float64: even and odd slots (a 32-bit word of a bfloat16 cache holds
 two), both slots of such a pair, the first and the last slot, dead
 entries that hold anything, one live entry, one query a head and eight,
-one key/value head and four, both operand types, several chunks a row;
-and the sets the kernel does not take keep the plain path."""
+one key/value head and four, both operand types, several chunks a row,
+a live count a row; and the sets the kernel does not take keep the plain
+path."""
 
 import numpy as np
 import pytest
@@ -173,6 +174,36 @@ def test_the_live_entries_of_several_chunks(live, dtype):
                                _attended(q, k, v, live), atol=atol)
 
 
+@pytest.mark.parametrize("dtype", sorted(TYPES))
+@pytest.mark.parametrize("kv_heads,group", [(4, 8), (1, 8), (2, 1)])
+def test_a_live_count_a_row(kv_heads, group, dtype):
+    """`live` [batch]: every row attends its own count of entries, as
+    the positions of a block do that lie a row each: one, a count inside
+    the first chunk, a chunk's edge, one past it, all."""
+    rs = np.random.RandomState(kv_heads + group)
+    dtype, atol = TYPES[dtype]
+    live = np.array([1, 77, 128, 129, 384, 512], np.int32)
+    draw = lambda *s: jnp.asarray(rs.randn(*s), dtype)  # noqa: E731
+    q = draw(live.size, kv_heads, group, DIM)
+    k, v = (draw(live.size, 512, kv_heads, DIM) for _ in range(2))
+    dead = np.arange(512)[None, :, None, None] >= live[:, None, None, None]
+    # what a dead entry holds reaches no sum
+    k, v = jnp.where(dead, jnp.nan, k), jnp.where(dead, jnp.inf, v)
+    got = gqa_decode.gqa_decode_chosen(q, k, v, jnp.asarray(live),
+                                       DIM ** -0.5, chunk=128)
+    for b, n in enumerate(live):
+        at = slice(b, b + 1)
+        np.testing.assert_allclose(
+            np.asarray(got[at].astype(jnp.float32)),
+            _attended(q[at], k[at], v[at], int(n)), atol=atol)
+        # and a row alone under one count gives the row bit for bit
+        np.testing.assert_array_equal(
+            np.asarray(got[at].astype(jnp.float32)),
+            np.asarray(gqa_decode.gqa_decode_chosen(
+                q[at], k[at], v[at], jnp.int32(n), DIM ** -0.5,
+                chunk=128).astype(jnp.float32)))
+
+
 def test_the_chunk_is_chosen_from_the_shapes():
     assert gqa_decode.choose_chunk(2048, 4, 8) == 2048
     assert gqa_decode.choose_chunk(2048, 4, 8, itemsize=4) == 1024
@@ -192,5 +223,8 @@ def test_what_is_no_chosen_set_is_refused():
                 (q[:, :3], k, v)):
         with pytest.raises(ValueError, match="no step the kernel takes"):
             gqa_decode.gqa_decode_chosen(*bad, jnp.int32(128), 1.0)
+    with pytest.raises(ValueError, match="no step the kernel takes"):
+        # three counts for two rows
+        gqa_decode.gqa_decode_chosen(q, k, v, jnp.full((3,), 128), 1.0)
     with pytest.raises(ValueError, match="no step the kernel takes"):
         gqa_decode.gqa_decode_chosen(q, k, v, jnp.int32(128), 1.0, chunk=96)

@@ -8,8 +8,10 @@ position through its three caches a layer against the reference's full
 forward, with `top_k` smaller than the context so that slots are left
 out and an image span inside the prefill; the shares of an expert layer
 adding up to the uncut layer; the op against a masked dense computation,
-kernel (interpreter) and plain path; the counters; and the step Programs
-the repo had, unchanged.
+kernel (interpreter) and plain path; a block of positions with a set each
+through the op, through the Program and through `ProgramDecoder` against
+its single steps; the counters; and the step Programs the repo had,
+unchanged.
 
 Tiny sizes on the CPU: 3 layers, hidden 64, 4 query / 2 key/value heads
 of 16 (sections 2 : 3 : 3), a chooser of 4 heads of 8 that picks 5 slots,
@@ -18,6 +20,7 @@ random weights (norm scales moved off their initial 1).
 """
 
 import hashlib
+import re
 
 import numpy as np
 import pytest
@@ -27,12 +30,13 @@ import jax.numpy as jnp
 
 import paddle_tpu.fluid as fluid
 from paddle_tpu.jit import FunctionalProgram
+from paddle_tpu.models import latent_moe_program
 from paddle_tpu.models.decoder_block import share_feed_forward
 from paddle_tpu.models.reference import keye_vl2 as reference
 from paddle_tpu.models.sparse_kv_moe_program import (
     build_sparse_kv_moe_cached_step_program, sparse_kv_moe_param_names)
 from paddle_tpu.obs import telemetry
-from paddle_tpu.ops import registry
+from paddle_tpu.ops import attention, registry
 
 B, T, V, L = 2, 24, 97, 3
 H, KV, DH, D, FE, E, K, HELD = 4, 2, 16, 64, 32, 8, 2, (2, 4)
@@ -112,7 +116,7 @@ def built():
         held = np.zeros((B, 1, D), "float32")
         if t in slots:
             held[:, 0] = vectors[:, t - SPAN[0]]
-        fed = dict(state, tok=jnp.asarray(tokens[:, t]),
+        fed = dict(state, tok=jnp.asarray(tokens[:, t:t + 1]),
                    mrope_pos=jnp.asarray(np.broadcast_to(
                        positions[:, None, t, None], (3, B, 1)), jnp.int32),
                    image_embeds=jnp.asarray(held),
@@ -287,7 +291,8 @@ def test_a_chosen_set_is_masked_attention_over_the_caches(dim, slots, top_k,
 
 
 @pytest.mark.parametrize("why,change", [
-    ("a ring", {"window": 40}), ("a block of positions", {"block": 2}),
+    ("a ring", {"window": 40}), ("one set for a block of positions",
+                                 {"block": 2}),
     ("no Live", {"live": None})])
 def test_what_a_chosen_set_cannot_be_is_refused(why, change):
     rs = np.random.RandomState(0)
@@ -306,6 +311,248 @@ def test_what_a_chosen_set_cannot_be_is_refused(why, change):
              "window": change.get("window", 0)}
     with pytest.raises(ValueError, match="chosen set"):
         registry.get_op_info("cached_attention").kernel(None, ins, attrs)
+
+
+def test_a_block_longer_than_the_op_was_sized_for_is_refused():
+    rs = np.random.RandomState(0)
+    ins = {"Q": [jnp.asarray(rs.randn(2, 3, 64), jnp.float32)],
+           "KNew": [jnp.asarray(rs.randn(2, 3, 32), jnp.float32)],
+           "VNew": [jnp.asarray(rs.randn(2, 3, 32), jnp.float32)],
+           "KCache": [jnp.zeros((2, 2, 40, 16))],
+           "VCache": [jnp.zeros((2, 2, 40, 16))],
+           "Position": [jnp.full((2,), 7)],
+           "Selected": [jnp.zeros((2, 3, 4), jnp.int32)],
+           "Live": [jnp.full((2, 3), 4, jnp.int32)]}
+    with pytest.raises(ValueError, match="prefill_block"):
+        registry.get_op_info("cached_attention").kernel(
+            None, ins, {"num_heads": 4, "num_kv_heads": 2,
+                        "prefill_block": 2})
+
+
+# -- (b') a block of positions, a set each, against its single steps ------------
+
+def _sets_of_a_block(rs, rows, slots, top_k, pos, block):
+    """(Selected [rows, block, top_k], Live [rows, block]) as a chooser
+    gives them: position t's set holds min(top_k, pos + t + 1) of the
+    slots up to its own, ascending, then entries that name anything."""
+    selected = rs.choice([-1, 0, slots, 10 ** 6], (rows, block, top_k))
+    live = np.minimum(top_k, pos + 1 + np.arange(block))
+    for b in range(rows):
+        for t in range(block):
+            selected[b, t, :live[t]] = np.sort(
+                rs.permutation(pos + t + 1)[:live[t]])
+    return selected.astype("int32"), \
+        np.broadcast_to(live, (rows, block)).astype("int32")
+
+
+BLOCKS = {
+    # path, head width, slots, top_k, first position, q's and the caches'
+    # types, tolerance
+    "kernel from an empty cache": (
+        "kernel", 128, 256, 128, 0, "float32", "float32", 2e-6),
+    "kernel across the set's filling": (
+        "kernel", 128, 256, 128, 124, "bfloat16", "bfloat16", 1e-2),
+    "kernel over a narrower cache": (
+        "kernel", 128, 256, 128, 124, "float32", "bfloat16", 2e-6),
+    "plain from an empty cache": (
+        "plain", 16, 40, 8, 0, "float32", "float32", 2e-6),
+    "plain across the set's filling": (
+        "plain", 16, 40, 8, 5, "float32", "float32", 2e-6),
+    "plain over a narrower cache": (
+        "plain", 16, 40, 8, 5, "float32", "bfloat16", 2e-6)}
+
+
+@pytest.mark.parametrize("tile", [1, 2, 4], ids=lambda n: "tile %d" % n)
+@pytest.mark.parametrize("case", sorted(BLOCKS))
+def test_a_block_over_chosen_sets_is_its_single_steps(case, tile,
+                                                      monkeypatch):
+    """Seven positions through the op at once, a set and a `Live` each,
+    against seven steps: the caches bit for bit, the outputs to rounding,
+    with tiles of 1 (seven in one loop), of 2 (three in one loop and a
+    remainder of one) and of 4 (one and a remainder of three)."""
+    path, dim, slots, top_k, pos, q_type, cache_type, atol = BLOCKS[case]
+    rs = np.random.RandomState(len(case) + tile)
+    rows, heads, kv_heads, block = 2, 8, 2, 7
+    draw = lambda dtype, *s: jnp.asarray(rs.randn(*s), dtype)  # noqa: E731
+    q, k_new, v_new = (draw(q_type, rows, block, n * dim)
+                       for n in (heads, kv_heads, kv_heads))
+    caches = [draw(cache_type, rows, kv_heads, slots, dim) for _ in "kv"]
+    selected, live = _sets_of_a_block(rs, rows, slots, top_k, pos, block)
+    op = registry.get_op_info("cached_attention").kernel
+    attrs = {"num_heads": heads, "num_kv_heads": kv_heads}
+
+    def ins(at, caches, positions):
+        cut = (slice(None), positions)
+        chosen = selected[cut], live[cut]
+        if chosen[0].shape[1] == 1:     # a step's: [rows, top_k], [rows]
+            chosen = [x[:, 0] for x in chosen]
+        return {"Q": [q[cut]], "KNew": [k_new[cut]], "VNew": [v_new[cut]],
+                "KCache": [caches[0]], "VCache": [caches[1]],
+                "Position": [jnp.full((rows,), at)],
+                "Selected": [jnp.asarray(chosen[0])],
+                "Live": [jnp.asarray(chosen[1])]}
+
+    stepped, outs = caches, []
+    for t in range(block):
+        out = op(None, ins(pos + t, stepped, slice(t, t + 1)), attrs)
+        stepped = [out["KCacheOut"][0], out["VCacheOut"][0]]
+        outs.append(out["Out"][0])
+    # a position's two copies, beside its scores on the plain path
+    a_position = rows * top_k * (2 * kv_heads * dim * q.dtype.itemsize
+                                 + (0 if path == "kernel" else heads * 4))
+    monkeypatch.setattr(attention, "_CHOSEN_TILE_BYTES", tile * a_position)
+    before = telemetry.snapshot()
+    got = op(None, ins(pos, caches, slice(None)), attrs)
+    lowered = [k for k in telemetry.snapshot_delta(before)
+               if k.startswith("sparse_attention_lowerings_total")]
+    assert len(lowered) == 1 and "path=%s" % path in lowered[0] \
+        and "positions=7," in lowered[0] \
+        and "tile=%d," % tile in lowered[0]
+    assert got["Out"][0].shape == (rows, block, heads * dim) \
+        and got["Out"][0].dtype == q.dtype
+    for name, want in zip(("KCacheOut", "VCacheOut"), stepped):
+        assert got[name][0].dtype == want.dtype
+        np.testing.assert_array_equal(
+            np.asarray(got[name][0].astype(jnp.float32)),
+            np.asarray(want.astype(jnp.float32)))
+    np.testing.assert_allclose(
+        np.asarray(got["Out"][0].astype(jnp.float32)),
+        np.asarray(jnp.concatenate(outs, axis=1).astype(jnp.float32)),
+        atol=atol)
+
+
+def _run(main, scope, feeds, fetches):
+    fp = FunctionalProgram(main.clone(for_test=True), sorted(feeds),
+                           fetches)
+    return fp({n: scope.get(n) for n in fp.state_in_names},
+              {k: jnp.asarray(v) for k, v in feeds.items()})[0]
+
+
+@pytest.mark.parametrize("start,block", [(0, 7), (3, 8), (9, 1)])
+def test_a_block_through_the_program_is_its_single_steps(built, start,
+                                                         block):
+    """T positions through the built Program at once against T
+    applications of one: the logits, every state pair, and the `parts`
+    (the last position's, in the shapes a step's have); the chosen sets
+    are identical."""
+    main, pairs, parts = built["main"], built["pairs"], built["parts"]
+    probes = [(key, i, v.name) for key, made in sorted(parts.items())
+              for i, v in enumerate(made)]
+    fetches = [built["decoder"]._fp.fetch_names[0]] \
+        + [o for _, o in pairs] + [name for _, _, name in probes]
+    rs = np.random.RandomState(start + block)
+    state = {f: jnp.asarray(rs.randn(*v.shape) * 0.3, v.dtype)
+             for f, v in _empty().items()}
+    state["pos"] = jnp.full((B,), start, jnp.int32)
+    state["rope_delta"] = jnp.full((B,), -2, jnp.int32)
+    tokens = built["tokens"][:, start:start + block]
+    stepped = dict(state)
+    for t in range(block):
+        one = _run(main, built["scope"],
+                   dict(stepped, tok=tokens[:, t:t + 1]), fetches)
+        stepped = {f: v for (f, _), v in zip(pairs, one[1:])}
+    got = _run(main, built["scope"], dict(state, tok=tokens), fetches)
+    assert len(got) == len(one) == 1 + len(pairs) + len(probes)
+    for (key, i, _), a, b in zip(probes, got[1 + len(pairs):],
+                                 one[1 + len(pairs):]):
+        assert a.shape == b.shape, key
+        if key in ("selected", "live", "top_idx"):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        elif key != "counts":   # the experts' rows over the whole block
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=2e-5, err_msg=key)
+    assert got[1 + len(pairs) + [k for k, _, _ in probes].index("live")] \
+        .tolist() == [min(INDEXER[2], start + block)] * B
+    for (feed, _), a, b in zip(pairs, got[1:], one[1:]):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5,
+                                   err_msg=feed)
+    assert np.asarray(got[len(pairs) - 1]).tolist() == [start + block] * B
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(one[0]),
+                               atol=LOGITS_RTOL * np.abs(one[0]).max())
+
+
+def test_a_block_with_an_image_span_is_the_references(built):
+    """The prefill's 14 positions, the image span among them, through
+    the step that takes a tower's vectors as one block: the logits after
+    it are the reference's at its last position and the caches hold what
+    the reference would."""
+    seeing, _, logits, pairs, _ = build_sparse_kv_moe_cached_step_program(
+        B, T, V, images=True, **SIZES)
+    slots, positions = built["slots"], built["positions"]
+    held = np.zeros((B, PREFILL, D), "float32")
+    held[:, slots] = built["vectors"]
+    mask = np.zeros((B, PREFILL, 1), "float32")
+    mask[:, slots] = 1
+    got = _run(seeing, built["scope"], dict(
+        _empty(), tok=built["tokens"][:, :PREFILL],
+        mrope_pos=np.broadcast_to(positions[:, None, :PREFILL],
+                                  (3, B, PREFILL)).astype("int32"),
+        image_embeds=held, image_mask=mask),
+        [logits.name] + [o for _, o in pairs])
+    want = np.asarray(built["want"]["logits"])[:, PREFILL - 1]
+    assert np.abs(np.asarray(got[0]) - want).max() \
+        <= LOGITS_RTOL * np.abs(want).max()
+    state = {f: np.asarray(v) for (f, _), v in zip(pairs, got[1:])}
+    assert state["pos"].tolist() == [PREFILL] * B
+    for i in range(L):
+        for feed, made in (("k_cache_%d" % i, built["want"]["keys"][i]),
+                           ("v_cache_%d" % i, built["want"]["values"][i])):
+            np.testing.assert_allclose(
+                state[feed][:, :, :PREFILL],
+                np.asarray(made).transpose(0, 2, 1, 3)[:, :, :PREFILL],
+                atol=3e-5)
+            assert not state[feed][:, :, PREFILL:].any()
+        np.testing.assert_allclose(
+            state["index_cache_%d" % i][:, :PREFILL],
+            np.asarray(built["want"]["index_keys"][i])[:, :PREFILL],
+            atol=3e-5)
+
+
+@pytest.mark.parametrize("prompt_len,block", [(11, 4), (20, 8), (7, 64)],
+                         ids=["3 + 2 x 4", "4 + 2 x 8", "one short block"])
+def test_a_prompt_is_prefilled_in_blocks(built, prompt_len, block,
+                                         monkeypatch):
+    """`ProgramDecoder` reads the declaration and the attention op's
+    `prefill_block`: the prompt goes through the step `block` positions
+    an application (the remainder first), and serves the tokens the
+    position-by-position prefill serves."""
+    # so many token rows an application: B rows take `block` positions
+    monkeypatch.setattr(latent_moe_program, "_CHOOSER_ROWS", B * block)
+    main, _, logits, pairs, _ = build_sparse_kv_moe_cached_step_program(
+        B, T, V, **SIZES)
+    assert {od.attrs["prefill_block"] for od in main.global_block().desc.ops
+            if od.type == "cached_attention"} == {block}
+    prompt = built["tokens"][:, :prompt_len]
+    gen = T - prompt_len + 1
+    served = {}
+    for how in ("blocks", "positions"):
+        decoder = fluid.ProgramDecoder(
+            main.clone(for_test=True), token_name="tok",
+            logits_name=logits.name, state_pairs=pairs,
+            scope=built["scope"], max_positions=T)
+        assert decoder._takes_block and decoder._prefill_block == block
+        if how == "positions":
+            decoder._prefill_block = 1
+        before = telemetry.snapshot()
+        served[how], lengths, state = decoder.greedy(
+            bos=0, eos=V, max_len=gen, init_state=_empty(), prompt=prompt,
+            return_state=[f for f, _ in pairs])
+        traced = telemetry.snapshot_delta(before)
+        assert (lengths == gen).all()
+        served[how + " state"] = state
+        if how == "blocks":
+            assert traced["prefill_lowerings_total{block=%d,form=block}"
+                          % block] == 1
+            # the attention was traced for the remainder, a block, a step
+            sizes = {prompt_len % block, min(block, prompt_len), 1} - {0}
+            assert {int(k.split("positions=")[1].split(",")[0])
+                    for k in traced if k.startswith(
+                        "sparse_attention_lowerings_total")} == sizes
+    np.testing.assert_array_equal(served["blocks"], served["positions"])
+    for feed, want in served["positions state"].items():
+        np.testing.assert_allclose(
+            np.asarray(served["blocks state"][feed]), np.asarray(want),
+            atol=3e-5, err_msg=feed)
 
 
 # -- (c) three-part positions ---------------------------------------------------
@@ -461,6 +708,38 @@ def test_parameter_names_are_the_references_tree(built):
     assert set(jax.tree_util.tree_leaves(NAMES)) == made
     assert not any("shared" in name for name in made)
     assert [f for f, _ in built["pairs"]][-2:] == ["pos", "rope_delta"]
+
+
+@pytest.fixture(scope="module")
+def attend_lowerings():
+    import json
+
+    import parent_lowerings
+    with open(parent_lowerings.ATTEND_RECORDING) as f:
+        return json.load(f), parent_lowerings.attend_lowerings()
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("width", ["64-wide", "64-wide ungrouped",
+                                   "128-wide"])
+@pytest.mark.parametrize("what", ["step chosen", "step", "step ring",
+                                  "step read-only", "block"])
+def test_what_the_op_lowered_to_it_lowers_to(attend_lowerings, what, width,
+                                             dtype):
+    """PR 66 gave `cached_attention` a chosen set a position of a block.
+    A step over a chosen set (the kernel's body included) and every form
+    without one trace to the jaxpr they traced to before, equation for
+    equation: the decoding step of keye's cell and the three cells that
+    run the op without `Selected` are what they were.  Against the
+    recording made on commit 56cbe1e."""
+    recorded, now = attend_lowerings
+    name = "%s %s %s" % (what, width, dtype)
+    # `gqa_decode_chosen`'s call tells the compiler what it costs since
+    # PR 66 (one param of one equation, and no arithmetic)
+    told = re.sub(r"cost_estimate=CostEstimate\([^)]*\)",
+                  "cost_estimate=None", now[name])
+    assert (told != now[name]) == (name.startswith("step chosen 128-wide"))
+    assert told == recorded[name]
 
 
 def _digest(program):

@@ -652,8 +652,9 @@ def test_rope_turns_one_position_as_it_did():
 def test_only_the_block_taking_step_asks_for_it(built):
     """The builder sets `full_width` on its rope ops, the index queries'
     and keys' too where there is an `indexer` (a chooser's step takes a
-    block since PR 62); a step that takes one position a call sets none
-    (the grouped-cache chooser's, `models/sparse_kv_moe_program.py`)."""
+    block since PR 62); the grouped-cache chooser's step
+    (`models/sparse_kv_moe_program.py`), which takes a block since PR 66,
+    sets none: an application's rotated values are a few megabytes."""
     from paddle_tpu.models.sparse_kv_moe_program import \
         build_sparse_kv_moe_cached_step_program
     ropes = [od for od in built["main"].global_block().desc.ops

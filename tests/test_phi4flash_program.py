@@ -525,15 +525,17 @@ def _listing(main):
               "beta_slow": 1, "mscale": 1}), "820727a57c275686"),
     (build_window_moe_cached_step_program, {}, "28a50da12a521ca8"),
     (build_linear_moe_cached_step_program, {}, "f284162f98cd8cb3"),
-    (build_sparse_kv_moe_cached_step_program, {}, "4d8d8d0cb0e051d6"),
+    (build_sparse_kv_moe_cached_step_program, {}, "e865ced12acdb4b2"),
 ], ids=["pangu", "dsv32", "exaone", "qwen3next", "keye"])
 def test_the_other_steps_programs_are_op_for_op_what_they_were(
         build, options, digest):
     """`cached_attention` took a read-only form and two counters' attrs:
     a Program that asks for neither is, op for op and attr for attr,
     the Program it was (the first three digests are
-    tests/test_linear_moe_program.py's; the last two were taken on the
-    parent commit)."""
+    tests/test_linear_moe_program.py's; qwen3next's was taken on the
+    parent commit; keye's is PR 66's, whose step takes a block of
+    positions: its attention op carries `prefill_block` and no other
+    new attr)."""
     main = build(2, 16, 97, **options)[0]
     assert hashlib.sha256(_listing(main).encode()).hexdigest()[:16] == digest
 
