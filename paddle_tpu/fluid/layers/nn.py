@@ -1092,7 +1092,7 @@ def causal_conv1d(input, filter_size=4, activation="silu", param_attr=None,
 
 
 def gated_delta_rule(q, k, v, g, beta, state, qk_l2norm=True, chunk=64,
-                     name=None, gate_floor=None):
+                     name=None, gate_floor=None, state_pack=1):
     """The gated delta rule over a block of T >= 1 consecutive positions
     of every row, through a recurrent state (ops/linear_attention.py
     gated_delta_rule; T = 1 is a decode step): `q`, `k` [batch, T, key
@@ -1107,8 +1107,12 @@ def gated_delta_rule(q, k, v, g, beta, state, qk_l2norm=True, chunk=64,
     diag(exp(g)) S.  Such a caller states `gate_floor`, the least value
     an element of `g` takes (< 0), from which the block form's
     sub-blocks are sized so that its one growing factor stays inside
-    float32 (the op's `sub_chunk`).  T may be left open (-1) in the
-    Program.
+    float32 (the op's `sub_chunk`).  `beta`'s range is the caller's: (0,
+    1), or (0, 2) where negative eigenvalues are allowed.  With
+    `state_pack` p > 1, `state` is [batch, value heads / p, key_dim, p *
+    value_dim], p heads side by side (kernels/gdn_step.py `pack_state`:
+    a state of 192 values a head is not padded to 256 in HBM).  T may be
+    left open (-1) in the Program.
     Returns (out [batch, T, value heads * value_dim], state_out): thread
     `state_out` back as decode state (`fluid.ProgramDecoder` state
     pairs).  Forward only."""
@@ -1121,6 +1125,8 @@ def gated_delta_rule(q, k, v, g, beta, state, qk_l2norm=True, chunk=64,
     if gate_floor is not None:
         from ...ops.linear_attention import sub_chunk
         attrs["sub_chunk"] = sub_chunk(int(chunk), float(gate_floor))
+    if state_pack != 1:
+        attrs["state_pack"] = int(state_pack)
     helper.append_op(
         type="gated_delta_rule",
         inputs={"Q": [q], "K": [k], "V": [v], "G": [g], "Beta": [beta],

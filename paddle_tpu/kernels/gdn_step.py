@@ -4,6 +4,7 @@ place.
 
     step(q[B, Hk, Dk], k[B, Hk, Dk], v[B, H, Dv], g[B, H], beta[B, H],
          state[B, H, Dk, Dv]) -> (out [B, H, Dv], state')
+    (state[B, H / p, Dk, p Dv] where p heads lie side by side)
 
     S  = exp(g) S;  r = S^T k;  S = S + k (beta (v - r))^T;  o = S^T q
 
@@ -64,10 +65,29 @@ made a column like k and q, a third a value head: the state's row d
 times element d.
 
 Which shapes it takes (`choose_block`): a float32 state of 128 x 128 a
-head (the lanes, and a column the sublanes tile); a block's value heads
-hold whole key heads and tile the sublanes of the [Hk, Dk] and [H, Dv]
-operands (all heads where a row's state fits `_STEP_BYTES`), and its
-rows divide the batch's.  The op asks, and keeps its plain path
+head (the lanes, and a column the sublanes tile) under either gate; a
+block's value heads hold whole key heads and tile the sublanes of the
+[Hk, Dk] and [H, Dv] operands (all heads where a row's state fits
+`_STEP_BYTES`), and its rows divide the batch's.  And, under a gate a
+head with a key head a value head, **a state that is not square**: a
+key_dim of whole sublane tiles (96: twelve) and, along the lanes, whole
+lane blocks.  192 values a head are a lane block and a half, and an
+array of 192 lanes lies in HBM as 256 (a step would move 2 x 2.95 MB a
+row and layer of Olmo-Hybrid's 30 heads, not 2 x 2.21), so the program
+lays **two heads side by side** (`state_pack`, `pack_state`: [rows, 15,
+96, 384], three whole lane blocks a unit, no lane of it padding) and the
+kernel works a unit as it lies: the pair's v, decay, beta and output are
+one row [1, 384] each (the operands [B, H, Dv] read as [B, H / 2, 2
+Dv]), and the key column beside each half of the lanes is chosen by a
+lane select between the pair's two columns, so `S * k`, the update and
+`S * q` stay one pass over the unit on the vector unit.  q and k arrive
+zero-padded to a whole lane block (96 -> 128: 15 KB a row) and a column
+is made on a [96, 128] diagonal.  At [128, 30, 96, 192] a call takes
+0.790 ms in blocks of 4 rows (8.4 MiB, `_WIDE_STEP_BYTES`; 2 rows 0.815,
+8 rows 0.792), 89.8% of the HBM peak on the state's own bytes; the same
+state zero-padded to 256 values a head takes 1.057 ms, 67% (PERF.md
+section 6, PR 67; `scripts/gdn_step_bench.py --key-dim 96 --value-dim
+192 --pad-to 0 --pad-to 256`).  The op asks, and keeps its plain path
 otherwise.
 
 Lowered for the TPU this is a Mosaic kernel named `gdn_step_r<rows>_h<
@@ -75,7 +95,9 @@ heads>_b<rows a step>` (rows of the batch, value heads and rows a grid
 step: a trace's `device_ops` row says which block ran) under a gate a
 head and `kda_step_r<rows>_h<heads>_b<rows a step>` under a gate a key
 channel (one body, the decay a row or a column; a trace's readers tell
-Gated DeltaNet's steps from KDA's by the prefix); lowered for any
+Gated DeltaNet's steps from KDA's by the prefix); a state that is not
+128 x 128 a head says its shape, `gdn_step_r<rows>_h<heads>_k<key_dim>_
+v<value_dim>_b<rows a step>` (the prefix stays).  Lowered for any
 other platform the caller's plain step runs in its place (`step`'s
 `plain`, as kernels/ssd.py's entries take theirs; `interpret=True` runs
 the kernel's body, copies and all, under the Pallas interpreter: tests).
@@ -90,9 +112,15 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _LANES = 128
+_SUBLANES = 8
 _HEAD_BYTES = _LANES * _LANES * 4
 # the bytes of state a grid step takes in (and a step later gives out)
 _STEP_BYTES = 8 << 20
+# the same for a state that is not 128 x 128 a head, whose rows are no
+# power of two of bytes (Olmo-Hybrid's 30 heads of 96 x 192: 2.11 MiB a
+# row, so 4 rows are 8.44 MiB; scripts/gdn_step_bench.py --key-dim 96
+# --value-dim 192, PERF.md section 6, PR 67)
+_WIDE_STEP_BYTES = 9 << 20
 # a block comes in, one is worked where it lies, one goes out
 _BUFFERS = 3
 # what a call may hold in VMEM beside its blocks of state: the operands'
@@ -107,16 +135,70 @@ _READ_CUTS = 8
 _WRITE_CUTS = 4
 
 
-def choose_block(rows, heads, key_heads, key_dim, value_dim, dtype):
+def state_pack(heads, value_dim):
+    """How many value heads a program lays side by side along the lanes
+    of one [key_dim, pack * value_dim] tile of state, so that the state
+    is not padded in HBM (an array's last axis is stored in whole blocks
+    of 128 lanes: 192 values a head would be stored as 256): 1 where
+    `value_dim` fills its lane blocks, else the fewest heads (2 or 4)
+    that together do and divide `heads`; 1 where none does."""
+    if value_dim % _LANES:
+        for pack in (2, 4):
+            if (pack * value_dim) % _LANES == 0 and heads % pack == 0:
+                return pack
+    return 1
+
+
+def pack_state(state, pack):
+    """[rows, heads, key_dim, value_dim] -> [rows, heads / pack, key_dim,
+    pack * value_dim]: heads pack * u .. pack * u + pack - 1 side by
+    side in unit u, head after head along the lanes."""
+    if pack == 1:
+        return state
+    rows, heads, key_dim, value_dim = state.shape
+    return state.reshape(rows, heads // pack, pack, key_dim, value_dim) \
+        .transpose(0, 1, 3, 2, 4) \
+        .reshape(rows, heads // pack, key_dim, pack * value_dim)
+
+
+def unpack_state(state, pack):
+    """`pack_state`'s inverse: the state as the recurrence has it."""
+    if pack == 1:
+        return state
+    rows, units, key_dim, wide = state.shape
+    return state.reshape(rows, units, key_dim, pack, wide // pack) \
+        .transpose(0, 1, 3, 2, 4) \
+        .reshape(rows, units * pack, key_dim, wide // pack)
+
+
+def choose_block(rows, heads, key_heads, key_dim, value_dim, dtype, pack=1,
+                 channel=False):
     """(rows, value heads) a grid step holds, or None where the kernel
-    does not take the shape.  The heads first: the most that divide
-    `heads`, hold whole key heads, tile the operands' sublanes (a
-    multiple of 8 key heads, or all of them) and keep one row's state
-    within `_STEP_BYTES`; then the most rows that divide `rows` and keep
-    the block's within it."""
-    if key_dim != _LANES or value_dim != _LANES \
-            or jnp.dtype(dtype) != jnp.float32 or heads % key_heads:
+    does not take the shape.
+
+    128 x 128 a head: the heads first, the most that divide `heads`,
+    hold whole key heads, tile the operands' sublanes (a multiple of 8
+    key heads, or all of them) and keep one row's state within
+    `_STEP_BYTES`; then the most rows that divide `rows` and keep the
+    block's within it.
+
+    Any other head, with `pack` heads side by side in the state
+    (`state_pack`), under a gate a head alone (not `channel`): a key
+    head a value head, `key_dim` whole sublane tiles and `pack *
+    value_dim` whole lane blocks; all the heads of the most rows that
+    divide `rows` and keep the block within `_WIDE_STEP_BYTES`."""
+    if jnp.dtype(dtype) != jnp.float32 or heads % key_heads:
         return None
+    if (key_dim, value_dim, pack) != (_LANES, _LANES, 1):
+        row_bytes = heads * key_dim * value_dim * 4
+        if channel or heads != key_heads or heads % pack \
+                or key_dim % _SUBLANES \
+                or (pack * value_dim) % _LANES \
+                or row_bytes > _WIDE_STEP_BYTES:
+            return None
+        return max(n for n in range(1, rows + 1)
+                   if rows % n == 0
+                   and n * row_bytes <= _WIDE_STEP_BYTES), heads
     group = heads // key_heads
     room = max(_STEP_BYTES // _HEAD_BYTES, group)
     held = max((n for n in range(group, min(heads, room) + 1, group)
@@ -128,34 +210,42 @@ def choose_block(rows, heads, key_heads, key_dim, value_dim, dtype):
                if rows % n == 0 and n * held <= max(room, held)), held
 
 
-def vmem_limit(block):
+def vmem_limit(block, head_bytes=_HEAD_BYTES):
     """The VMEM a call over `block` may take: `_BUFFERS` blocks of state
-    and `_VMEM_BESIDE`."""
-    return _BUFFERS * block[0] * block[1] * _HEAD_BYTES + _VMEM_BESIDE
+    (`head_bytes` a value head) and `_VMEM_BESIDE`."""
+    return _BUFFERS * block[0] * block[1] * head_bytes + _VMEM_BESIDE
 
 
-def _cuts(n):
-    """A head's sublanes in n slices."""
-    return [pl.ds(i * (_LANES // n), _LANES // n) for i in range(n)]
+def _cuts(size, most):
+    """`size` sublanes in the most slices, `most` at most, that are
+    whole sublane tiles each."""
+    n = max(n for n in range(1, most + 1) if size % (_SUBLANES * n) == 0)
+    return [pl.ds(i * (size // n), size // n) for i in range(n)]
 
 
 def _kernel(q_ref, k_ref, bv_ref, decay_ref, beta_ref, s_hbm, o_ref, so_hbm,
-            buf, came, went, *, block, group, channel):
+            buf, came, went, *, block, group, channel, pack):
     held, heads = block
+    # what the state's second axis counts: a head, or `pack` of them
+    # side by side
+    units = heads // pack
+    key_dim, wide = buf.shape[-2:]
+    value_dim = wide // pack
     across = pl.num_programs(1)
     at = pl.program_id(0) * across + pl.program_id(1)
     last = pl.num_programs(0) * across - 1
+    reads, writes = _cuts(key_dim, _READ_CUTS), _cuts(key_dim, _WRITE_CUTS)
 
     def placed(t):
         """Block t where it lies in HBM, and its buffer."""
         slot = t % _BUFFERS
         return (pl.ds(t // across * held, held),
-                pl.ds(t % across * heads, heads)), buf.at[slot], slot
+                pl.ds(t % across * units, units)), buf.at[slot], slot
 
     def fetch(t):
         """Starts block t's copies in."""
         where, to, slot = placed(t)
-        for cut in _cuts(_READ_CUTS):
+        for cut in reads:
             pltpu.make_async_copy(s_hbm.at[where + (cut,)],
                                   to.at[:, :, cut], came.at[slot]).start()
 
@@ -168,16 +258,16 @@ def _kernel(q_ref, k_ref, bv_ref, decay_ref, beta_ref, s_hbm, o_ref, so_hbm,
 
     def give(t):
         """Starts block t's copies out."""
-        (rows_at, heads_at), out, slot = placed(t)
+        (rows_at, units_at), out, slot = placed(t)
 
         def head(h, _):
-            for cut in _cuts(_WRITE_CUTS):
+            for cut in writes:
                 pltpu.make_async_copy(
                     out.at[:, pl.ds(h, 1), cut],
-                    so_hbm.at[rows_at, pl.ds(heads_at.start + h, 1), cut],
+                    so_hbm.at[rows_at, pl.ds(units_at.start + h, 1), cut],
                     went.at[slot]).start()
 
-        lax.fori_loop(0, heads, head, None)
+        lax.fori_loop(0, units, head, None)
 
     def given(t):
         """Waits for all of block t's way out."""
@@ -185,31 +275,46 @@ def _kernel(q_ref, k_ref, bv_ref, decay_ref, beta_ref, s_hbm, o_ref, so_hbm,
         pltpu.make_async_copy(out, out, went.at[slot]).wait()
 
     s_ref = placed(at)[1]
-    diagonal = lax.broadcasted_iota(jnp.int32, (_LANES, _LANES), 0) \
-        == lax.broadcasted_iota(jnp.int32, (_LANES, _LANES), 1)
+    # q and k arrive in whole lane blocks (`_operands`)
+    key_lanes = k_ref.shape[-1]
+    diagonal = lax.broadcasted_iota(jnp.int32, (key_dim, key_lanes), 0) \
+        == lax.broadcasted_iota(jnp.int32, (key_dim, key_lanes), 1)
 
     def column(row):
         """[1, size] -> [size, 1]: the row down the sublanes, the
         diagonal kept, summed over the lanes."""
         return jnp.sum(jnp.where(diagonal, row, 0.0), axis=1, keepdims=True)
 
+    lane = lax.broadcasted_iota(jnp.int32, (1, wide), 1)
+
+    def columns(ref, b, first):
+        """The columns of heads first .. first + pack - 1, each beside
+        its own head's lanes of a unit: [key_dim, 1] for one head,
+        [key_dim, pack * value_dim] by a lane select for several."""
+        out = column(ref[b, pl.ds(first + pack - 1, 1), :])
+        for i in reversed(range(pack - 1)):
+            out = jnp.where(lane < (i + 1) * value_dim,
+                            column(ref[b, pl.ds(first + i, 1), :]), out)
+        return out
+
     # Reads and writes take turns at the HBM: block at + 1 comes in
     # beside the first half of this block's work, block at - 1 goes out
     # beside the second.  The work is one body over a quarter of the
-    # block's key heads (a half, or all, where they do not divide), which
+    # block's key heads (a half, or all, where they do not divide; a
+    # fifth or a third of the units of heads side by side), which
     # quarter a loop's index: the kernel's text is a quarter's heads.
-    key_heads = heads // group
-    parts = max(n for n in (4, 2, 1) if key_heads % n == 0)
-    tail = _cuts(_READ_CUTS)[-1]
+    keyed = units if pack > 1 else heads // group
+    parts = next(n for n in (4, 2, 5, 3, 1) if keyed % n == 0)
+    tail = reads[-1]
 
     def work(part):
         """The step of this part's key heads' value heads in every row
         of the block, where the state lies."""
         def row(b, _):
-            for key_head in range(key_heads // parts):
-                key_head = part * (key_heads // parts) + key_head
-                k_col = column(k_ref[b, pl.ds(key_head, 1), :])
-                q_col = column(q_ref[b, pl.ds(key_head, 1), :])
+            for key_head in range(keyed // parts):
+                key_head = part * (keyed // parts) + key_head
+                k_col = columns(k_ref, b, key_head * pack)
+                q_col = columns(q_ref, b, key_head * pack)
                 for j in range(group):
                     j = key_head * group + j
                     one = pl.ds(j, 1)
@@ -231,7 +336,7 @@ def _kernel(q_ref, k_ref, bv_ref, decay_ref, beta_ref, s_hbm, o_ref, so_hbm,
     @pl.when(at == 0)
     def _():
         fetch(0)
-        fetched(0, pl.ds(0, _LANES))
+        fetched(0, pl.ds(0, key_dim))
 
     @pl.when(at < last)
     def _():
@@ -265,21 +370,27 @@ def _kernel(q_ref, k_ref, bv_ref, decay_ref, beta_ref, s_hbm, o_ref, so_hbm,
         given(at)
 
 
-def _call(q, k, bv, decay, beta, state, *, block, channel, interpret):
-    rows, all_heads, key_dim, value_dim = state.shape
+def _call(q, k, bv, decay, beta, state, *, block, channel, interpret,
+          pack=1):
+    """q, k [B, Hk, key lanes]; bv, decay, beta [B, H / pack, pack * Dv]
+    (a gate a key channel: decay [B, H, Dk]); state [B, H / pack, Dk,
+    pack * Dv]."""
+    rows, units, key_dim, wide = state.shape
+    all_heads = units * pack
     group = all_heads // q.shape[1]
     held, heads = block
 
     def keyed(b, h):
         return b, h, 0
 
-    key_block = pl.BlockSpec((held, heads // group, key_dim), keyed)
-    value_block = pl.BlockSpec((held, heads, value_dim), keyed)
+    key_block = pl.BlockSpec((held, heads // group, q.shape[-1]), keyed)
+    value_block = pl.BlockSpec((held, heads // pack, wide), keyed)
     # the state stays in HBM: the kernel's own copies move it
     where_it_lies = pl.BlockSpec(memory_space=pl.ANY)
+    square = (key_dim, wide, pack) == (_LANES, _LANES, 1)
     return pl.pallas_call(
         functools.partial(_kernel, block=block, group=group,
-                          channel=channel),
+                          channel=channel, pack=pack),
         grid=(rows // held, all_heads // heads),
         in_specs=[key_block, key_block, value_block, value_block,
                   value_block, where_it_lies],
@@ -287,7 +398,7 @@ def _call(q, k, bv, decay, beta, state, *, block, channel, interpret):
         out_shape=[jax.ShapeDtypeStruct(bv.shape, jnp.float32),
                    jax.ShapeDtypeStruct(state.shape, state.dtype)],
         scratch_shapes=[
-            pltpu.VMEM((_BUFFERS, held, heads, key_dim, value_dim),
+            pltpu.VMEM((_BUFFERS, held, heads // pack, key_dim, wide),
                        jnp.float32),
             pltpu.SemaphoreType.DMA((_BUFFERS,)),
             pltpu.SemaphoreType.DMA((_BUFFERS,))],
@@ -296,61 +407,78 @@ def _call(q, k, bv, decay, beta, state, *, block, channel, interpret):
         compiler_params=pltpu.CompilerParams(
             # a step's copies are the steps' before and after it
             dimension_semantics=("arbitrary", "arbitrary"),
-            vmem_limit_bytes=vmem_limit(block)),
+            vmem_limit_bytes=vmem_limit(
+                block, key_dim * (wide // pack) * 4)),
         interpret=interpret,
-        # the trace's readers match the prefix
-        name="%s_step_r%d_h%d_b%d" % ("kda" if channel else "gdn", rows,
-                                      heads, held),
+        # the trace's readers match the prefix; a state that is not
+        # 128 x 128 a head says its shape
+        name="%s_step_r%d_h%d_%sb%d" % (
+            "kda" if channel else "gdn", rows, heads,
+            "" if square else "k%d_v%d_" % (key_dim, wide // pack), held),
     )(q, k, bv, decay, beta, state)
 
 
-def _operands(q, k, v, g, beta):
-    """The kernel's operands beside the state: q, k, beta * v, the decay
-    and beta a row [1, Dv] each (a gate a key channel: the decay the row
-    [1, Dk] it is)."""
+def _operands(q, k, v, g, beta, pack=1):
+    """The kernel's operands beside the state: q and k in whole lane
+    blocks (a key of 96 values zero-padded to 128: 15 KB a row beside
+    2.2 MB of state), beta * v, the decay and beta a row [1, Dv] each (a
+    gate a key channel: the decay the row [1, Dk] it is), `pack` heads'
+    rows side by side as their states lie."""
     wide = lambda t: jnp.broadcast_to(t.astype(jnp.float32)[..., None],
                                       v.shape)
-    return (q, k, wide(beta) * v.astype(jnp.float32),
+    beside = lambda t: t.reshape(t.shape[0], -1, pack * t.shape[-1])
+    short = -q.shape[-1] % _LANES
+    if short:
+        q, k = (jnp.pad(t, ((0, 0), (0, 0), (0, short))) for t in (q, k))
+    return (q, k, beside(wide(beta) * v.astype(jnp.float32)),
             jnp.exp(g.astype(jnp.float32)) if g.ndim == 3
-            else wide(jnp.exp(g)), wide(beta))
+            else beside(wide(jnp.exp(g))), beside(wide(beta)))
 
 
 # Under `jax.jit`: the layers of a program that hold the same instance
 # share one traced body and one lowered function.
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def _kernel_step(q, k, v, g, beta, state, block, interpret):
-    """`_call` on `_operands` and the state."""
-    return _call(*_operands(q, k, v, g, beta), state, block=block,
-                 channel=g.ndim == 3, interpret=interpret)
+@functools.partial(jax.jit, static_argnames=("block", "interpret", "pack"))
+def _kernel_step(q, k, v, g, beta, state, block, interpret, pack):
+    """`_call` on `_operands` and the state; the output a head a row
+    again."""
+    out, state = _call(*_operands(q, k, v, g, beta, pack), state,
+                       block=block, channel=g.ndim == 3,
+                       interpret=interpret, pack=pack)
+    return out.reshape(v.shape), state
 
 
-def step(q, k, v, g, beta, state, plain, block=None, interpret=False):
+def step(q, k, v, g, beta, state, plain, block=None, interpret=False,
+         pack=1):
     """(out [B, H, Dv] float32, the state after the step): the module's
     docstring; g [B, H] (a gate a head) or [B, H, Dk] (a gate a key
-    channel).  `plain(q, k, v, g, beta, state)` is what every platform
-    but the TPU lowers in the kernel's place (the op's own step);
-    `block` ((rows, value heads) a grid step) is chosen from the shapes
-    unless given, and `interpret` runs the kernel's body under the
-    Pallas interpreter whatever the platform (tests, sweeps)."""
-    rows, all_heads, key_dim, value_dim = state.shape
+    channel); `state` [B, H / pack, Dk, pack * Dv], `pack` heads side by
+    side (`pack_state`).  `plain(q, k, v, g, beta, state)` is what every
+    platform but the TPU lowers in the kernel's place (the op's own
+    step, over the state as it is handed in); `block` ((rows, value
+    heads) a grid step) is chosen from the shapes unless given, and
+    `interpret` runs the kernel's body under the Pallas interpreter
+    whatever the platform (tests, sweeps)."""
+    rows, units, key_dim, wide = state.shape
+    all_heads, value_dim = units * pack, wide // pack
     block = block or choose_block(rows, all_heads, q.shape[1], key_dim,
-                                  value_dim, state.dtype)
+                                  value_dim, state.dtype, pack, g.ndim == 3)
     if not block or q.shape != k.shape \
             or q.shape != (rows, q.shape[1], key_dim) \
             or v.shape != (rows, all_heads, value_dim) \
             or g.shape not in ((rows, all_heads),
                                (rows, all_heads, key_dim)) \
+            or (g.ndim == 3 and (pack > 1 or key_dim != value_dim)) \
             or beta.shape != (rows, all_heads) \
             or q.dtype != jnp.float32 \
-            or rows % block[0] or all_heads % block[1]:
+            or rows % block[0] or all_heads % block[1] or block[1] % pack:
         raise ValueError(
             "gdn_step: q %s %s, k %s, v %s, g %s, beta %s over a state of "
-            "%s %s are no step the kernel takes"
+            "%s %s (%d heads side by side) are no step the kernel takes"
             % (q.shape, q.dtype, k.shape, v.shape, g.shape, beta.shape,
-               state.shape, state.dtype))
+               state.shape, state.dtype, pack))
 
     kernel = functools.partial(_kernel_step, block=tuple(block),
-                               interpret=bool(interpret))
+                               interpret=bool(interpret), pack=int(pack))
     operands = (q, k, v, g, beta, state)
     if interpret:
         return kernel(*operands)

@@ -154,24 +154,29 @@ def attention(h, positions, names, n_head, d_head, theta, qk_norm_eps=None,
     return linear(o, h.shape[-1], names["wo"])
 
 
-def gated_feed_forward(u, width, names, limit=None):
+def gated_feed_forward(u, width, names, limit=None, name=None):
     """(silu(g) * v) W_out with [g | v] = u W_in, gate and up in one
     [hidden, 2 * width] matrix `names["w_in"]`, the first `width`
     columns the gate; `names["w_out"]` back to hidden.  With `limit` L
-    the clamped form, silu(min(g, L)) * clip(v, -L, L)."""
+    the clamped form, silu(min(g, L)) * clip(v, -L, L).  `name` names
+    the ops between the two products (the instances in a trace start
+    with it)."""
+    named = {"name": name} if name else {}
     gate, up = fluid.layers.split(
-        linear(u, 2 * width, names["w_in"]), 2, dim=-1)
+        linear(u, 2 * width, names["w_in"]), 2, dim=-1, **named)
     if limit:
         gate = fluid.layers.clip(gate, min=_NO_FLOOR, max=float(limit))
         up = fluid.layers.clip(up, min=-float(limit), max=float(limit))
-    return linear(fluid.layers.swish(gate) * up, u.shape[-1],
-                  names["w_out"])
+    act = fluid.layers.elementwise_mul(
+        fluid.layers.swish(gate, **named), up, **named) if name \
+        else fluid.layers.swish(gate) * up
+    return linear(act, u.shape[-1], names["w_out"])
 
 
 def share_feed_forward(u, block, dense, d_ff, d_expert, n_experts, held,
                        top_k, norm_topk, routed_scale, router_bias=False,
                        n_group=0, topk_group=0, scoring="sigmoid",
-                       shared_gate=None, swiglu_limit=None):
+                       shared_gate=None, swiglu_limit=None, dense_name=None):
     """The feed-forward half of one layer of one chip's share of an
     expert model, for u [batch, seq, hidden], already normed; `block`
     names the layer's parameters.  Returns (F(u), routing).  The
@@ -185,7 +190,8 @@ def share_feed_forward(u, block, dense, d_ff, d_expert, n_experts, held,
     shared expert, one scalar a token).
 
     `dense`: the gated feed-forward of width `d_ff` (`ffn_in`,
-    `ffn_out`), and `routing` is None.  Otherwise a shared expert of
+    `ffn_out`; `dense_name` names its ops between the two products), and
+    `routing` is None.  Otherwise a shared expert of
     width `d_expert` (`shared_in`, `shared_out`) beside a routed layer
     (`fluid.layers.moe`: sigmoid scores over `n_experts`, `top_k` a
     token chosen by score plus `router_bias` inside the best
@@ -201,7 +207,7 @@ def share_feed_forward(u, block, dense, d_ff, d_expert, n_experts, held,
     if dense:
         return gated_feed_forward(
             u, d_ff, {"w_in": block["ffn_in"], "w_out": block["ffn_out"]},
-            swiglu_limit), None
+            swiglu_limit, dense_name), None
     m, _, _, routing = fluid.layers.moe(
         u, n_experts, d_expert, top_k,
         *(ParamAttr(name=block[w])

@@ -1,13 +1,19 @@
 """One chip's share of a decoder that mixes linear-attention layers
-(the gated delta rule) and attention layers over a cache, every layer
-with routed experts beside a shared expert, as a cached decode step
-Program: Qwen3-Next-80B-A3B's block
+(the gated delta rule) and attention layers over a cache, as a cached
+decode step Program, three families' blocks from one builder:
+Qwen3-Next-80B-A3B's
 (huggingface.co/Qwen/Qwen3-Next-80B-A3B-Instruct, `model_type`
-`qwen3_next`: Gated DeltaNet beside gated full attention, softmax
-routing), and Ling-3.0-flash's (huggingface.co/inclusionAI/Ling-3.0-flash,
+`qwen3_next`: Gated DeltaNet beside gated full attention, every layer
+with routed experts beside a shared expert, softmax routing),
+Ling-3.0-flash's (huggingface.co/inclusionAI/Ling-3.0-flash,
 `model_type` `bailing_hybrid`: Kimi Delta Attention, the rule under a
 gate a key channel, beside latent attention, sigmoid routing inside the
-best groups, leading dense layers; "Ling-3.0-flash's options" below).
+best groups, leading dense layers; "Ling-3.0-flash's options" below) and
+Olmo-Hybrid-7B's (huggingface.co/allenai/Olmo-Hybrid-7B, `model_type`
+`olmo_hybrid`: Gated DeltaNet over a 96 x 192 state with beta in (0,
+2), beside ungated full attention without positions, a dense
+feed-forward on every layer and no router, each sub-layer's output
+normed; "Olmo-Hybrid's options" below).
 
 A block of T >= 1 consecutive tokens of every row in (T = 1: a decode
 step; a prompt's prefill feeds `models.decode.PREFILL_BLOCK` an
@@ -73,13 +79,42 @@ sigmoid scoring, the choice by score plus `router_bias` inside the best
 `topk_group` of `n_group` groups, the chosen scores divided by their sum
 and scaled by `routed_scale`, and a shared expert without a gate.
 
-The equations are in `models/reference/qwen3_next.py` and
-`models/reference/ling3_flash.py`, which the tests hold this to.
+Olmo-Hybrid's options (`norm_order="post"`, `qk_norm="whole"`,
+`attn_gate=False`, `rope_theta=None`, `beta_scale=2`, `n_dense` = every
+layer): the block norms a sub-layer's **output** and not its input, a
+= x + N(mixer(x)), y = a + N(ffn(a)) (`post_attn_norm`,
+`post_ffn_norm`; the mixer reads the float32 stream cast to the weights'
+type); every layer's feed-forward is the dense gated one of width
+`d_ff`, so the Program holds no `moe_*` op and a call carries no expert
+state.
+
+a `linear_attention` layer is the one above with beta = `beta_scale` *
+sigmoid(b) (the family's `allow_neg_eigval`: beta in (0, 2), a step's
+transition `I - beta k k^T` with an eigenvalue in (-1, 1)), a key head a
+value head, and a state whose value_dim need not be its key_dim.  Where
+`value_dim` fills no whole lane blocks (192), "delta_state_<i>" holds
+`state_pack` heads side by side, [batch, value heads / 2, key_dim, 2 *
+value_dim] (kernels/gdn_step.py `state_pack`, `pack_state`: the state
+is not padded in HBM, and the step kernel works it as it lies);
+`parts["delta_state"]` is taken apart again, the state as the
+recurrence has it.
+
+a `full_attention` layer under these options has no gate ([q] = h W_q),
+norms q and k over their **whole projection** (`q_norm` [n_head *
+d_head], `k_norm` [n_kv_head * d_head], named `mha_attn`), and rotates
+nothing (`rope_theta=None`: the convolution and the recurrence of the
+linear layers carry order).  Where every layer is dense the ops between
+a feed-forward's two products are named `dense_ffn`.
+
+The equations are in `models/reference/qwen3_next.py`,
+`models/reference/ling3_flash.py` and `models/reference/olmo_hybrid.py`,
+which the tests hold this to.
 """
 
 from .. import fluid
 from ..fluid.initializer import LogScale
 from ..fluid.param_attr import ParamAttr
+from ..kernels import gdn_step
 from .decoder_block import (block_positions, last, last_token_rows, linear,
                             norm, share_feed_forward)
 from .latent_moe_program import prefill_block
@@ -89,7 +124,8 @@ __all__ = ["build_linear_moe_cached_step_program", "linear_moe_param_names",
 
 LINEAR, FULL, LATENT = ("linear_attention", "full_attention",
                         "latent_attention")
-_NORMS = ("input_norm", "pre_mlp_norm")
+_NORMS = {"pre": ("input_norm", "pre_mlp_norm"),
+          "post": ("post_attn_norm", "post_ffn_norm")}
 _DENSE = ("ffn_in", "ffn_out")
 _EXPERTS = ("shared_in", "shared_out", "router", "w_gate", "w_up", "w_down")
 _MIXER = {LINEAR: ("w_qkvz", "w_ba", "conv", "a_log", "dt_bias", "out_norm",
@@ -102,16 +138,18 @@ _MIXER = {LINEAR: ("w_qkvz", "w_ba", "conv", "a_log", "dt_bias", "out_norm",
 
 
 def linear_moe_param_names(layer_types, n_dense=0, gate="head",
-                           shared_gate=True, router_bias=False):
+                           shared_gate=True, router_bias=False,
+                           norm_order="pre"):
     """The parameters' names, laid out as the reference's `params`: a
     linear layer's under the gate it has, the first `n_dense` layers'
     dense feed-forward, the shared expert's gate and the router's bias
-    where the options ask for them."""
+    where the options ask for them, a layer's two norms by where they
+    stand (`norm_order`)."""
     experts = _EXPERTS + (("shared_gate",) if shared_gate else ()) \
         + (("router_bias",) if router_bias else ())
     return {"embed": "embed.w",
             "blocks": [{w: "block_%d.%s" % (i, w)
-                        for w in _NORMS
+                        for w in _NORMS[norm_order]
                         + (_DENSE if i < n_dense else experts)
                         + _MIXER["channel" if kind == LINEAR
                                  and gate == "channel" else kind]}
@@ -127,7 +165,9 @@ def build_linear_moe_cached_step_program(
         rope_theta=1e7, chunk=64, state_rows=0, gate="head",
         gate_floor=-5.0, n_dense=0, d_ff=0, scoring="softmax",
         shared_gate=True, routed_scale=1.0, router_bias=False, n_group=0,
-        topk_group=0, kv_rank=16, d_nope=16, d_rope=8, d_v=16):
+        topk_group=0, kv_rank=16, d_nope=16, d_rope=8, d_v=16,
+        norm_order="pre", qk_norm="head", attn_gate=True, beta_scale=1.0,
+        state_pack=None):
     """Returns (main, startup, logits, state_pairs, parts): feeds "tok"
     int32 [batch, T] (declared [batch, -1]: T >= 1 consecutive tokens of
     every row, read off the feed), "pos" int64 [batch], the position of
@@ -139,6 +179,16 @@ def build_linear_moe_cached_step_program(
     `state_pairs` wires every state and the position, advanced by T,
     into `fluid.ProgramDecoder` (pass max_positions=max_len).
 
+    `norm_order` ("pre": a sub-layer's input is normed; "post": its
+    output is, Olmo's), `qk_norm` ("head", or "whole": over the whole
+    projection), `attn_gate`, `rope_theta` (None: no rotation),
+    `beta_scale` (2: beta in (0, 2)) and `state_pack` (the value heads
+    side by side in a unit of "delta_state_<i>", [batch, value_heads /
+    p, key_dim, p * value_dim]; None: `gdn_step.state_pack`'s choice,
+    1 under a gate a key channel) are the module docstring's
+    "Olmo-Hybrid's options"; with `n_dense` = every layer there is no
+    router.
+
     `parts` are **of the block's last position**, in shapes that T does
     not change, as the window builder's: per layer "hidden", "attn_in"
     and "attn_out" (the mixer's normed input and its output after
@@ -147,14 +197,25 @@ def build_linear_moe_cached_step_program(
     and "counts"; and with `state_rows` > 0, per linear layer
     "delta_state", the first `state_rows` rows of the state the step
     hands on (what a caller can afford to read back of 2 MB a row and
-    layer)."""
+    layer), the heads apart whatever `state_pack`: [state_rows, value
+    heads, key_dim, value_dim]."""
     if set(layer_types) - {LINEAR, FULL, LATENT} \
-            or gate not in ("head", "channel"):
+            or gate not in ("head", "channel") \
+            or norm_order not in _NORMS or qk_norm not in ("head", "whole"):
         raise ValueError("linear_moe: layer_types %s are not %s / %s / %s, "
-                         "or the gate %r is not a head's or a channel's"
-                         % (layer_types, LINEAR, FULL, LATENT, gate))
+                         "the gate %r is not a head's or a channel's, the "
+                         "norms stand neither %r a sub-layer nor %r it, or "
+                         "q and k are normed neither a %r nor %r"
+                         % (layer_types, LINEAR, FULL, LATENT, gate, "pre",
+                            "post", "head", "whole"))
     names = linear_moe_param_names(layer_types, n_dense, gate, shared_gate,
-                                   router_bias)
+                                   router_bias, norm_order)
+    if state_pack is None:
+        state_pack = 1 if gate == "channel" \
+            else gdn_step.state_pack(value_heads, value_dim)
+    # a model whose every layer is dense has no router: its
+    # feed-forward's ops are named, for a trace's readers
+    dense_name = "dense_ffn" if n_dense >= len(layer_types) else None
     key_width, value_width = key_heads * key_dim, value_heads * value_dim
     conv_channels = 2 * key_width + value_width
     main = fluid.Program()
@@ -169,8 +230,9 @@ def build_linear_moe_cached_step_program(
         states = [
             [feed("conv_tail_%d" % i, [batch, conv_width - 1,
                                        conv_channels]),
-             feed("delta_state_%d" % i, [batch, value_heads, key_dim,
-                                         value_dim])]
+             feed("delta_state_%d" % i,
+                  [batch, value_heads // state_pack, key_dim,
+                   state_pack * value_dim])]
             if kind == LINEAR else
             [feed("latent_cache_%d" % i, [batch, max_len, kv_rank + d_rope])]
             if kind == LATENT else
@@ -213,12 +275,24 @@ def build_linear_moe_cached_step_program(
             """The rule's output for layer i, its two states wired into
             the decoder and the carried rows of the new state kept."""
             o, state_out = fluid.layers.gated_delta_rule(
-                *qkv, g, beta, states[i][1], chunk=chunk, **gate)
+                *qkv, g, beta, states[i][1], chunk=chunk,
+                state_pack=state_pack, **gate)
             state_pairs.append(("conv_tail_%d" % i, tail_out.name))
             state_pairs.append(("delta_state_%d" % i, state_out.name))
             if state_rows:
-                parts["delta_state"].append(fluid.layers.slice(
-                    state_out, axes=[0], starts=[0], ends=[state_rows]))
+                kept = fluid.layers.slice(
+                    state_out, axes=[0], starts=[0], ends=[state_rows])
+                if state_pack > 1:
+                    # the heads apart again, as the recurrence has them
+                    kept = fluid.layers.reshape(
+                        fluid.layers.transpose(
+                            fluid.layers.reshape(
+                                kept, [state_rows,
+                                       value_heads // state_pack, key_dim,
+                                       state_pack, value_dim]),
+                            [0, 1, 3, 2, 4]),
+                        [state_rows, value_heads, key_dim, value_dim])
+                parts["delta_state"].append(kept)
             return o
 
         def head_gated(y, z, heads, width, name):
@@ -249,6 +323,9 @@ def build_linear_moe_cached_step_program(
             gates = {"name": "gdn_gates"}
             beta = fluid.layers.sigmoid(
                 fluid.layers.cast(b, "float32", **gates), **gates)
+            if beta_scale != 1.0:
+                beta = fluid.layers.scale(beta, scale=float(beta_scale),
+                                          **gates)
             g = fluid.layers.elementwise_mul(
                 fluid.layers.softplus(fluid.layers.elementwise_add(
                     fluid.layers.cast(a, "float32", **gates), dt_bias,
@@ -329,26 +406,42 @@ def build_linear_moe_cached_step_program(
                 fluid.layers.reshape(o, [0, 0, n_head, d_v]),
                 linear(h, n_head, block["w_z"]), n_head, d_v, "latent_gate")
 
+        def qk_normed(t, heads, scale):
+            """q or k RMS-normed a head, or over the whole projection
+            (one scale an output column; named for a trace's readers)."""
+            if qk_norm == "head":
+                return head_norm(t, heads, d_head, scale)
+            return fluid.layers.rms_norm(
+                t, epsilon=eps, param_attr=ParamAttr(name=scale),
+                name="mha_attn")
+
         def full_mixer(i, h, block):
-            # a head's query values, then its gate values
-            q, gate = (fluid.layers.reshape(t, [0, 0, n_head * d_head])
-                       for t in fluid.layers.split(
-                           fluid.layers.reshape(
-                               linear(h, 2 * n_head * d_head, block["wq"]),
-                               [0, 0, n_head, 2 * d_head]), 2, dim=-1))
-            q = head_norm(q, n_head, d_head, block["q_norm"])
-            k = head_norm(linear(h, n_kv_head * d_head, block["wk"]),
-                          n_kv_head, d_head, block["k_norm"])
+            if attn_gate:
+                # a head's query values, then its gate values
+                q, gate = (fluid.layers.reshape(t, [0, 0, n_head * d_head])
+                           for t in fluid.layers.split(
+                               fluid.layers.reshape(
+                                   linear(h, 2 * n_head * d_head,
+                                          block["wq"]),
+                                   [0, 0, n_head, 2 * d_head]), 2, dim=-1))
+            else:
+                q = linear(h, n_head * d_head, block["wq"])
+            q = qk_normed(q, n_head, block["q_norm"])
+            k = qk_normed(linear(h, n_kv_head * d_head, block["wk"]),
+                          n_kv_head, block["k_norm"])
             v = linear(h, n_kv_head * d_head, block["wv"])
-            q = fluid.layers.rope(q, positions, n_head, rope_theta,
-                                  rotary_dim=rotary_dim)
-            k = fluid.layers.rope(k, positions, n_kv_head, rope_theta,
-                                  rotary_dim=rotary_dim)
+            if rope_theta is not None:
+                q = fluid.layers.rope(q, positions, n_head, rope_theta,
+                                      rotary_dim=rotary_dim)
+                k = fluid.layers.rope(k, positions, n_kv_head, rope_theta,
+                                      rotary_dim=rotary_dim)
             o, k_out, v_out = fluid.layers.cached_attention(
                 q, k, v, states[i][0], states[i][1], pos, num_heads=n_head,
                 num_kv_heads=n_kv_head)
             state_pairs.append(("k_cache_%d" % i, k_out.name))
             state_pairs.append(("v_cache_%d" % i, v_out.name))
+            if not attn_gate:
+                return o
             return fluid.layers.elementwise_mul(
                 o, fluid.layers.sigmoid(gate, name="attn_gate"),
                 name="attn_gate")
@@ -357,27 +450,44 @@ def build_linear_moe_cached_step_program(
         parts = {"hidden": [], "attn_in": [], "attn_out": [], "top_w": [],
                  "top_idx": [], "counts": [], "moe_in": [], "moe_out": [],
                  "delta_state": []}
+        pre = norm_order == "pre"
+
+        def entering(t, name):
+            """What a sub-layer reads of the float32 stream: its norm,
+            or under `norm_order="post"` the stream itself, in the
+            weights' type."""
+            return normed(t, name) if pre \
+                else fluid.layers.cast(t, embedded)
+
+        def leaving(t, name):
+            """What a sub-layer adds to the stream, float32: its output,
+            or under `norm_order="post"` the output's norm."""
+            t = fluid.layers.cast(t, "float32")
+            return t if pre else norm(t, eps, name)
+
         for i, block in enumerate(names["blocks"]):
-            h = normed(x, block["input_norm"])
+            attn_norm, ffn_norm = (block[w] for w in _NORMS[norm_order])
+            h = entering(x, attn_norm)
             parts["attn_in"].append(last(h))
             mixer = {LINEAR: channel_mixer if gate == "channel"
                      else linear_mixer, FULL: full_mixer,
                      LATENT: latent_mixer}[layer_types[i]]
             o = linear(mixer(i, h, block), d_model, block["wo"])
             parts["attn_out"].append(last(o))
-            a = x + fluid.layers.cast(o, "float32")
-            u = normed(a, block["pre_mlp_norm"])
+            a = x + leaving(o, attn_norm)
+            u = entering(a, ffn_norm)
             f, routing = share_feed_forward(
                 u, block, i < n_dense, d_ff, d_expert, n_experts, held,
                 top_k, norm_topk, routed_scale, router_bias, n_group,
                 topk_group, scoring=scoring,
-                shared_gate=block.get("shared_gate"))
+                shared_gate=block.get("shared_gate"),
+                dense_name=dense_name)
             for key, value in (routing or {}).items():
                 if key != "counts":     # the whole block's, as it comes
                     value = (last_row if key in ("top_w", "top_idx")
                              else last)(value)
                 parts[key].append(value)
-            x = a + fluid.layers.cast(f, "float32")
+            x = a + leaving(f, ffn_norm)
             parts["hidden"].append(last(x))
 
         # the head reads the block's last position alone

@@ -479,24 +479,30 @@ def on_causal_conv1d_tail_lowering(width, row_bytes):
 
 
 def on_gated_delta_rule_lowering(form, path, chunk, heads, state_dtype,
-                                 row_bytes, gate="head"):
+                                 row_bytes, gate="head", key_dim=0,
+                                 value_dim=0):
     """A `gated_delta_rule` op (ops/linear_attention.py) was traced into
     a program: in which form ("step": one position, the state read and
     written once; "block": chunks of `chunk` positions), which way
     ("kernel": kernels/gdn_step.py; "plain": `jax.numpy`), over how many
-    value heads and a state of which type; `row_bytes` the state a row
-    holds; `gate` "head" (one decay a head: Gated DeltaNet) or "channel"
-    (one a key channel: KDA).  One count per op instance a lowered
-    program holds."""
+    value heads and a state of which type and shape a head (`key_dim` x
+    `value_dim`: a reader tells a 96 x 192 instance from a 128 x 128
+    one, and `path="plain"` at a shape the kernel is to take is a
+    fault it can see); `row_bytes` the state a row holds; `gate` "head"
+    (one decay a head: Gated DeltaNet) or "channel" (one a key channel:
+    KDA).  One count per op instance a lowered program holds."""
     _reg().counter("gated_delta_rule_lowerings_total",
                    "gated delta rule ops lowered, by form (a step or a "
                    "block of chunks), path (the step kernel or plain "
-                   "products), chunk, value heads, the state's type and "
-                   "the gate (a head's or a key channel's)",
+                   "products), chunk, value heads, the state's type, "
+                   "the gate (a head's or a key channel's) and a head's "
+                   "state (key_dim x value_dim)",
                    labelnames=("form", "path", "chunk", "heads",
-                               "state_dtype", "gate")) \
+                               "state_dtype", "gate", "key_dim",
+                               "value_dim")) \
           .labels(form=form, path=path, chunk=chunk, heads=heads,
-                  state_dtype=str(state_dtype), gate=gate).inc()
+                  state_dtype=str(state_dtype), gate=gate, key_dim=key_dim,
+                  value_dim=value_dim).inc()
     _recurrent_state_bytes("delta", row_bytes)
 
 

@@ -18,10 +18,23 @@ repeated in memory.
 
 `gated_delta_rule` is the op: Q, K [rows, T, key heads * key_dim], V
 [rows, T, value heads * value_dim], G and Beta [rows, T, value heads]
-float32 (g <= 0, beta in (0, 1)), State [rows, value heads, key_dim,
-value_dim] float32 -> Out [rows, T, value heads * value_dim] in V's
-type and StateOut, State's shape and type: a `fluid.ProgramDecoder`
-state pair that a step rewrites whole.
+float32 (g <= 0; beta's range is the caller's: sigmoid(b) in (0, 1) as
+Qwen3-Next has it, or 2 sigmoid(b) in (0, 2) under the family's
+`allow_neg_eigval`, Olmo-Hybrid's, where a step's transition `I - beta
+k k^T` has an eigenvalue in (-1, 1); the op clamps nothing), State
+[rows, value heads, key_dim, value_dim] float32 -> Out [rows, T, value
+heads * value_dim] in V's type and StateOut, State's shape and type: a
+`fluid.ProgramDecoder` state pair that a step rewrites whole.
+
+**Heads side by side** (`state_pack` = p > 1): State is [rows, value
+heads / p, key_dim, p * value_dim], heads p u .. p u + p - 1 beside one
+another along the last axis of unit u (kernels/gdn_step.py
+`pack_state`).  A device stores an array's last axis in whole blocks of
+128 lanes, so a state of 192 values a head would lie in HBM as 256; two
+such heads side by side are three whole blocks, and a step moves the
+state's own bytes.  The recurrence is the same; the step kernel works
+the state as it lies, and the plain step and the block form take it
+apart and put it together again around the same lines.
 
 **A gate a key channel** (Kimi Delta Attention, arXiv:2510.26692, as
 Ling-3.0-flash's KDA layers run it): G [rows, T, value heads * key_dim],
@@ -38,7 +51,11 @@ decayed, read for `S^T k`, written with the rank-one update and read for
 place (kernels/gdn_step.py: `gdn_step_*` under a gate a head,
 `kda_step_*` under a gate a key channel, `kda_state` the scope); off it,
 and for shapes the kernel does not take, the same four lines in
-`jax.numpy`.
+`jax.numpy`.  Which shapes it takes is `gdn_step.choose_block`'s to
+say: a float32 state of 128 x 128 a head under either gate, and under a
+gate a head any state of a key head a value head whose key_dim is whole
+sublane tiles and whose (packed) last axis is whole lane blocks
+(Olmo-Hybrid's 96 x 192, two heads side by side).
 
 T > 1, a block (`gdn_chunks`): the same recurrence rearranged over
 chunks of `chunk` positions (the family's `chunk_gated_delta_rule`).
@@ -54,6 +71,10 @@ G_j)` for i >= j,
 
 walked chunk by chunk; every product float32 at the highest precision
 (no exponent is ever positive: D, exp(G) and exp(G_C - G) are decays).
+With beta up to 2 the entries of A are up to twice as large and those of
+(I + A)^-1 grow with them (l2-normed keys keep |k_i . k_j| <= 1, so |A|
+<= 2 an entry): the solve is exact in exact arithmetic whatever beta,
+and the tests hold it to the recurrence at beta drawn over (0, 2).
 A block that is no multiple of the chunk is padded with beta 0 and g 0,
 which leave the state as it is.  Plain `jax.numpy` on every platform.
 
@@ -101,16 +122,17 @@ def _infer_shape(block, op_desc):
         same_meta_infer_shape(src, dst)(block, op_desc)
 
 
-def _heads(ins):
+def _heads(ins, pack=1):
     """q, k [rows, T, key heads, key_dim], v [rows, T, value heads,
     value_dim] as they come, beta [rows, T, value heads] and g [rows, T,
     value heads] (a gate a head) or [rows, T, value heads, key_dim] (a
-    gate a key channel) float32, the state; checked against one
-    another."""
+    gate a key channel) float32, the state as it is handed in (`pack`
+    heads side by side); checked against one another."""
     q, k, v = ins["Q"][0], ins["K"][0], ins["V"][0]
     g, beta, state = ins["G"][0], ins["Beta"][0], ins["State"][0]
-    rows, heads, key_dim, value_dim = state.shape
-    if q.shape != k.shape or q.shape[-1] % key_dim \
+    rows, units, key_dim, wide = state.shape
+    heads, value_dim = units * pack, wide // pack
+    if q.shape != k.shape or q.shape[-1] % key_dim or wide % pack \
             or v.shape[-1] != heads * value_dim \
             or heads % (q.shape[-1] // key_dim) \
             or beta.shape != v.shape[:2] + (heads,) \
@@ -118,10 +140,14 @@ def _heads(ins):
                                beta.shape[:2] + (heads * key_dim,)):
         raise ValueError(
             "gated_delta_rule: Q %s, K %s, V %s, G %s and Beta %s do not "
-            "fit a state of %s ([rows, value heads, key_dim, value_dim]): "
-            "G is [rows, T, value heads] (a gate a head) or [rows, T, "
-            "value heads * key_dim] (a gate a key channel)"
-            % (q.shape, k.shape, v.shape, g.shape, beta.shape, state.shape))
+            "fit a state of %s ([rows, value heads / %d, key_dim, %d * "
+            "value_dim]: state_pack %d): G is [rows, T, value heads] (a "
+            "gate a head) or [rows, T, value heads * key_dim] (a gate a "
+            "key channel), Beta [rows, T, value heads], in (0, 1) or, "
+            "with negative eigenvalues allowed, (0, 2) as the caller "
+            "made it"
+            % (q.shape, k.shape, v.shape, g.shape, beta.shape, state.shape,
+               pack, pack, pack))
     split = lambda t, d: t.reshape(*t.shape[:2], -1, d)
     if g.shape != beta.shape:
         g = split(g, key_dim)
@@ -338,53 +364,68 @@ def chunked_channel(q, k, v, g, beta, state, chunk, sub):
 def gated_delta_rule(ctx, ins, attrs):
     """The module's docstring.  attrs: `qk_l2norm` (default true: l2
     norm q and k a head and scale q by key_dim ** -0.5), `chunk` (the
-    block form's, default 64) and, for a gate a key channel,
+    block form's, default 64), `state_pack` (the value heads side by
+    side in a unit of State, default 1) and, for a gate a key channel,
     `sub_chunk` (the positions of a sub-block of the block form: what
     `sub_chunk(chunk, gate_floor)` gives for the least g a caller
     promises, default 16, -5's)."""
-    q, k, v, g, beta, state = _heads(ins)
+    from ..kernels import gdn_step
+
+    pack = int(attrs.get("state_pack", 1))
+    q, k, v, g, beta, state = _heads(ins, pack)
     chunk = int(attrs.get("chunk", 64))
     rows, length, key_heads, key_dim = q.shape
-    heads = v.shape[2]
+    heads, value_dim = v.shape[2:]
     step, channel = length == 1, g.ndim == 4
     # the scopes a trace's readers know the two gates by
     scope = "kda_" if channel else "gdn_"
-    kernel = None
-    if step:
-        from ..kernels import gdn_step
-        kernel = gdn_step.choose_block(rows, heads, key_heads, key_dim,
-                                       v.shape[-1], state.dtype)
+    kernel = step and gdn_step.choose_block(
+        rows, heads, key_heads, key_dim, value_dim, state.dtype, pack,
+        channel)
     telemetry.on_gated_delta_rule_lowering(
         "step" if step else "block", "kernel" if kernel else "plain",
         0 if step else chunk, heads, state.dtype,
         state[0].size * state.dtype.itemsize,
-        "channel" if channel else "head")
+        "channel" if channel else "head", key_dim, value_dim)
     with jax.named_scope("gdn_gates"):
         if attrs.get("qk_l2norm", True):
             q, k = l2norm(q) * key_dim ** -0.5, l2norm(k)
         else:
             q, k = q.astype(F32), k.astype(F32)
+
+    def apart(form):
+        """`form` over the state as the recurrence has it, the result's
+        heads side by side again as the state came."""
+        def run(*operands):
+            out, new = form(
+                *operands[:-1],
+                gdn_step.unpack_state(operands[-1], pack).astype(F32))
+            return out, gdn_step.pack_state(new, pack)
+        return run
+
     if step:
+        @apart
         def one(q, k, v, g, beta, state):
             """`recurrent` on the operands of one position."""
             out, new = recurrent(q[:, None], k[:, None],
                                  v[:, None].astype(F32), g[:, None],
-                                 beta[:, None], state.astype(F32))
+                                 beta[:, None], state)
             return out[:, 0], new
 
         with jax.named_scope(scope + "state"):
             operands = (q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
                         state)
-            out, new = gdn_step.step(*operands, plain=one, block=kernel) \
+            out, new = gdn_step.step(*operands, plain=one, block=kernel,
+                                     pack=pack) \
                 if kernel else one(*operands)
             out = out[:, None]
     else:
         with jax.named_scope(scope + "chunks"):
-            out, new = chunked_channel(
-                q, k, v.astype(F32), g, beta, state.astype(F32), chunk,
-                min(int(attrs.get("sub_chunk", 16)), chunk)) \
-                if channel else chunked(q, k, v.astype(F32), g, beta,
-                                        state.astype(F32), chunk)
+            out, new = apart(
+                lambda *a: chunked_channel(
+                    *a, chunk, min(int(attrs.get("sub_chunk", 16)), chunk))
+                if channel else chunked(*a, chunk))(
+                    q, k, v.astype(F32), g, beta, state)
     return {"Out": [out.reshape(rows, length, -1).astype(v.dtype)],
             "StateOut": [new.astype(state.dtype)]}
 

@@ -9,6 +9,9 @@ decided from (PERF.md section 6, PR 64).
     chiprun -- python scripts/gdn_step_bench.py --gate channel \
         --rows-step 2 --heads 32                           # one block
     chiprun -- python scripts/gdn_step_bench.py --streams  # the yardsticks
+    chiprun -- python scripts/gdn_step_bench.py --gate head --all-heads 30 \
+        --key-heads 30 --key-dim 96 --value-dim 192 --heads 30 --check \
+        --pad-to 0 --pad-to 256     # Olmo-Hybrid's state, both layouts
 
 A call runs as a decoder's scan runs it: the state carried from call to
 call through the kernel's alias, the operands the same every call.  ms a
@@ -21,6 +24,14 @@ alone over operands that are there; `step` is the op's whole step, the
 operands made from v, g and beta beside it.  `--check` holds every
 block's output and state to the plain recurrence on the chip (one call
 from the same state; exits 1 past 2e-5).
+
+A state that is not 128 x 128 a head (`--key-dim`, `--value-dim`) lies
+in HBM one of two ways, and `--pad-to` says which: 0, `state_pack` heads
+side by side ([rows, heads / 2, 96, 384] for 192 values a head: the
+state's own bytes), or the values a head zero-padded to that many
+(256: what an array of 192 lanes costs in HBM anyway).  The share of the
+HBM peak counts the state's own bytes in both, so the larger share is
+the faster call.
 
 `--streams` are the yardsticks the kernel's form was chosen by: the same
 268 MB of state moved with no arithmetic by a Pallas kernel whose blocks
@@ -57,41 +68,53 @@ HBM = 819e9
 OUT = "chiprun_out/gdn_step_bench.jsonl"
 
 
-def operands(rows, key_heads, heads, channel, seed=0):
+def operands(rows, key_heads, heads, channel, seed=0, key_dim=DIM,
+             value_dim=DIM, pad_to=0, beta_most=0.95):
+    """q, k, v, g, beta and the state as the recurrence has it, the
+    values a head zero-padded to `pad_to` where given."""
     rs = np.random.RandomState(seed)
     q, k = (linear_attention.l2norm(jnp.asarray(
-        rs.randn(rows, key_heads, DIM), jnp.float32)) for _ in range(2))
-    gate = (rows, heads, DIM) if channel else (rows, heads)
-    return (q * DIM ** -0.5, k,
-            jnp.asarray(rs.randn(rows, heads, DIM), jnp.bfloat16),
+        rs.randn(rows, key_heads, key_dim), jnp.float32)) for _ in range(2))
+    gate = (rows, heads, key_dim) if channel else (rows, heads)
+    pad = ((0, 0),) * 2 + ((0, max(pad_to - value_dim, 0)),)
+    return (q * key_dim ** -0.5, k,
+            jnp.pad(jnp.asarray(rs.randn(rows, heads, value_dim),
+                                jnp.bfloat16), pad),
             -jnp.asarray(rs.uniform(1e-3, 0.6, gate), jnp.float32),
-            jnp.asarray(rs.uniform(0.05, 0.95, (rows, heads)), jnp.float32),
-            jnp.asarray(0.3 * rs.randn(rows, heads, DIM, DIM), jnp.float32))
+            jnp.asarray(rs.uniform(0.05, beta_most, (rows, heads)),
+                        jnp.float32),
+            jnp.pad(jnp.asarray(
+                0.3 * rs.randn(rows, heads, key_dim, value_dim),
+                jnp.float32), ((0, 0),) + pad))
 
 
-def rule_bytes(rows, key_heads, heads):
+def rule_bytes(rows, key_heads, heads, key_dim=DIM, value_dim=DIM):
     """The state in and out, q and k, beta * v, the decay and beta a row
     each, the output."""
-    return rows * (2 * heads * DIM * DIM + 2 * key_heads * DIM
-                   + 4 * heads * DIM) * 4
+    return rows * (2 * heads * key_dim * value_dim + 2 * key_heads * key_dim
+                   + 4 * heads * value_dim) * 4
 
 
-def calls(kind, block):
-    """fn(n, q, k, v, g, beta, state): n calls, the state carried."""
+def calls(kind, block, pack=1):
+    """fn(n, q, k, v, g, beta, state): n calls, the state carried (as
+    it lies: `pack` heads side by side)."""
     def fn(n, q, k, v, g, beta, state):
+        made = gdn_step._operands(q, k, v, g, beta, pack)
         if kind == "call":
-            made = gdn_step._operands(q, k, v, g, beta)
-
             def body(_, carry):
                 return gdn_step._call(*made, carry[1], block=block,
-                                      channel=g.ndim == 3, interpret=False)
+                                      channel=g.ndim == 3, interpret=False,
+                                      pack=pack)
         else:
             def body(_, carry):
                 # the next call's values are this one's output: nothing
                 # of a step leaves the loop
-                return gdn_step.step(q, k, carry[0].astype(v.dtype), g, beta,
-                                     carry[1], plain=None, block=block)
-        return lax.fori_loop(0, n, body, (v.astype(jnp.float32), state))
+                return gdn_step.step(
+                    q, k, carry[0].reshape(v.shape).astype(v.dtype), g,
+                    beta, carry[1], plain=None, block=block, pack=pack)
+        return lax.fori_loop(
+            0, n, body,
+            (made[2] if kind == "call" else v.astype(jnp.float32), state))
     return jax.jit(fn, donate_argnums=(6,), static_argnums=(0,))
 
 
@@ -109,19 +132,19 @@ def slope(fn, ins, repeats=3):
     return (best[LONG] - best[SHORT]) / (LONG - SHORT) * 1e3
 
 
-def off_plain(block, ins):
+def off_plain(block, ins, pack=1):
     """The largest difference of one call's output and state from the
-    plain recurrence's."""
+    plain recurrence's; `ins`' state as the recurrence has it."""
     q, k, v, g, beta, state = ins
     want, want_state = jax.jit(linear_attention.recurrent)(
         q[:, None], k[:, None], v[:, None].astype(jnp.float32), g[:, None],
         beta[:, None], state)
     got, got_state = jax.jit(
-        lambda *a: gdn_step._call(*gdn_step._operands(*a[:-1]), a[-1],
-                                  block=block, channel=g.ndim == 3,
-                                  interpret=False))(*ins)
+        lambda *a: gdn_step.step(*a[:-1], gdn_step.pack_state(a[-1], pack),
+                                 plain=None, block=block, pack=pack))(*ins)
     return max(float(jnp.max(jnp.abs(got - want[:, 0]))),
-               float(jnp.max(jnp.abs(got_state - want_state))))
+               float(jnp.max(jnp.abs(gdn_step.unpack_state(got_state, pack)
+                                     - want_state))))
 
 
 def stream(way, held):
@@ -183,6 +206,15 @@ def main():
     ap.add_argument("--kind", choices=("call", "step"), action="append",
                     help="the kernel alone, or the op's step; the kernel "
                          "when not given")
+    ap.add_argument("--key-dim", type=int, default=DIM)
+    ap.add_argument("--value-dim", type=int, default=DIM)
+    ap.add_argument("--pad-to", type=int, action="append",
+                    help="the values a head the state is zero-padded to "
+                         "in HBM; 0 (the default): `state_pack` heads "
+                         "side by side, no padding")
+    ap.add_argument("--beta-most", type=float, default=0.95,
+                    help="beta is drawn in (0.05, this): 1.95 with "
+                         "negative eigenvalues allowed")
     ap.add_argument("--streams", action="store_true",
                     help="the yardsticks in the kernel's place")
     ap.add_argument("--check", action="store_true")
@@ -217,25 +249,35 @@ def main():
                 state.nbytes * (2 if way in ("both", "xla") else 1))
         return
     worst = 0.0
-    for gate in args.gate or ("head", "channel"):
+    for gate, pad_to in itertools.product(args.gate or ("head", "channel"),
+                                          args.pad_to or (0,)):
         channel = gate == "channel"
         key_heads = args.key_heads or (32 if channel else 16)
-        ins = operands(args.rows, key_heads, args.all_heads, channel)
+        ins = operands(args.rows, key_heads, args.all_heads, channel,
+                       key_dim=args.key_dim, value_dim=args.value_dim,
+                       pad_to=pad_to, beta_most=args.beta_most)
+        lies = max(pad_to, args.value_dim)
+        pack = gdn_step.state_pack(args.all_heads, lies)
         chosen = gdn_step.choose_block(
-            args.rows, args.all_heads, key_heads, DIM, DIM, jnp.float32)
+            args.rows, args.all_heads, key_heads, args.key_dim, lies,
+            jnp.float32, pack)
         for kind, held, heads in itertools.product(
                 args.kind or ("call",), args.rows_step or (1, 2, 4, 8),
                 args.heads or (16, 32)):
             block = (held, heads)
             line = {"gate": gate, "kind": kind, "rows": args.rows,
                     "key_heads": key_heads, "heads": args.all_heads,
-                    "block": block, "chosen": block == chosen,
-                    "step_mib": held * heads * DIM * DIM * 4 / 2 ** 20}
+                    "head": [args.key_dim, args.value_dim], "lies": lies,
+                    "pack": pack, "block": block, "chosen": block == chosen,
+                    "step_mib": held * heads * args.key_dim * lies * 4
+                    / 2 ** 20}
             if args.check:
-                line["off_plain"] = off_plain(block, ins)
+                line["off_plain"] = off_plain(block, ins, pack)
                 worst = max(worst, line["off_plain"])
-            say(line, calls(kind, block), ins,
-                rule_bytes(args.rows, key_heads, args.all_heads))
+            say(line, calls(kind, block, pack),
+                ins[:-1] + (gdn_step.pack_state(ins[-1], pack),),
+                rule_bytes(args.rows, key_heads, args.all_heads,
+                           args.key_dim, args.value_dim))
     if worst > 2e-5:
         sys.exit("gdn_step_bench: a block is %.3g off the plain recurrence"
                  % worst)

@@ -920,6 +920,43 @@ def test_the_delta_rule_step_kernel_lowers_for_tpu(length, kernels):
         assert _vmem_limit_stated(module) == gdn_step.vmem_limit((4, 32))
 
 
+@pytest.mark.parametrize("length,kernels", [
+    (1, ["gdn_step_r128_h30_k96_v192_b4"]), (128, [])])
+def test_the_wide_delta_rule_step_kernel_lowers_for_tpu(length, kernels):
+    """`gated_delta_rule` at olmohybrid-decode-pp4's shape (128 rows, 30
+    heads of 96 x 192 on both sides, a float32 state with two heads side
+    by side: [128, 15, 96, 384], no lane of it padding) lowered for the
+    TPU from this CPU host: a step holds one Mosaic kernel whose name
+    says the head's shape, over blocks of 4 rows' 30 heads (8.4 MiB a
+    grid step, the VMEM limit that follows), the state its result's
+    buffer; a block of 128 positions holds none."""
+    from paddle_tpu.kernels import gdn_step
+    from paddle_tpu.ops import registry
+
+    kernel = registry.get_op_info("gated_delta_rule").kernel
+    b, h, dk, dv = 128, 30, 96, 192
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    ins = {"Q": [jax.ShapeDtypeStruct((b, length, h * dk), bf16)],
+           "K": [jax.ShapeDtypeStruct((b, length, h * dk), bf16)],
+           "V": [jax.ShapeDtypeStruct((b, length, h * dv), bf16)],
+           "G": [jax.ShapeDtypeStruct((b, length, h), f32)],
+           "Beta": [jax.ShapeDtypeStruct((b, length, h), f32)],
+           "State": [jax.ShapeDtypeStruct((b, h // 2, dk, 2 * dv), f32)]}
+
+    def step(ins):
+        return kernel(None, ins, {"chunk": 64, "state_pack": 2})
+
+    module = jax.export.export(jax.jit(step), platforms=["tpu"])(
+        ins).mlir_module()
+    assert module.count("tpu_custom_call") == len(kernels)
+    for name in kernels:
+        assert 'kernel_name = "%s"' % name in module
+    if kernels:
+        assert "output_tuple_indices = [1], operand_index = 5" in module
+        assert _vmem_limit_stated(module) \
+            == gdn_step.vmem_limit((4, 30), dk * dv * 4)
+
+
 @pytest.mark.parametrize("length,kernels", [(1, ["kda_step_r128_h32_b4"]),
                                             (64, [])])
 def test_the_channel_gated_step_kernel_lowers_for_tpu(length, kernels):

@@ -206,7 +206,14 @@ def test_the_block_is_the_most_state_that_fits(shape, block):
 
 
 @pytest.mark.parametrize("why,shape", [
-    ("a head that is not 128 x 128", (2, 4, 2, 64, 128, jnp.float32)),
+    ("grouped heads that are not 128 x 128",
+     (2, 4, 2, 64, 128, jnp.float32)),
+    ("a key that is no whole sublane tiles", (2, 4, 4, 12, 128, jnp.float32)),
+    ("values that fill no lane blocks", (2, 4, 4, 96, 192, jnp.float32)),
+    ("heads the pack does not divide", (2, 5, 5, 96, 192, jnp.float32, 2)),
+    ("a row past a grid step's bytes", (2, 64, 64, 128, 384, jnp.float32)),
+    ("a gate a key channel over a state that is not square",
+     (2, 4, 4, 96, 192, jnp.float32, 2, True)),
     ("a state that is not float32", (2, 4, 2, 128, 128, jnp.bfloat16)),
     ("value heads that do not group", (2, 5, 2, 128, 128, jnp.float32)),
 ])
@@ -357,7 +364,8 @@ def test_the_channel_gate_is_counted_and_scoped():
     _rule(_channel_ins(rs, 2, 9), chunk=4, sub_chunk=2)
     traced = telemetry.snapshot_delta(before)
     assert traced[_rule_lowering("step", "kernel", 0, 4, "channel")] == 1
-    assert traced[_rule_lowering("block", "plain", 4, 4, "channel")] == 1
+    assert traced[_rule_lowering("block", "plain", 4, 4, "channel",
+                                 (8, 8))] == 1
     q, k, v, g, beta, state = _channel_ins(rs, 2, 1, dim=128)
     lowered = _applied((("chunk", 64),)).lower(
         q, k, v, g, beta, state).as_text(debug_info=True)
@@ -370,10 +378,191 @@ def test_a_gate_of_neither_shape_is_refused_and_both_are_named():
         _rule((q, k, v, jnp.repeat(g, 3, axis=-1), beta, state))
 
 
-def _rule_lowering(form, path, chunk, heads, gate="head"):
+def _rule_lowering(form, path, chunk, heads, gate="head", dims=(128, 128)):
     return ("gated_delta_rule_lowerings_total{chunk=%d,form=%s,gate=%s,"
-            "heads=%d,path=%s,state_dtype=float32}"
-            % (chunk, form, gate, heads, path))
+            "heads=%d,key_dim=%d,path=%s,state_dtype=float32,"
+            "value_dim=%d}"
+            % (chunk, form, gate, heads, dims[0], path, dims[1]))
+
+
+# -- (a') beta in (0, 2), a state that is not square, heads side by side ----------
+
+def _wide_ins(rs, rows, length, heads, key_dim, value_dim, state=True):
+    """(Q, K, V, G, Beta, State) with beta drawn over (0, 2) (negative
+    eigenvalues allowed: Olmo-Hybrid's) and a state of key_dim x
+    value_dim a head, a key head a value head."""
+    return (jnp.asarray(rs.randn(rows, length, heads * key_dim), jnp.float32),
+            jnp.asarray(rs.randn(rows, length, heads * key_dim), jnp.float32),
+            jnp.asarray(rs.randn(rows, length, heads * value_dim),
+                        jnp.float32),
+            -jnp.asarray(rs.uniform(1e-3, 0.6, (rows, length, heads)),
+                         jnp.float32),
+            jnp.asarray(rs.uniform(0.02, 1.98, (rows, length, heads)),
+                        jnp.float32),
+            jnp.asarray(0.3 * rs.randn(rows, heads, key_dim, value_dim)
+                        if state else
+                        np.zeros((rows, heads, key_dim, value_dim)),
+                        jnp.float32))
+
+
+def _normed(ins):
+    """`recurrent`'s operands of the op's: q and k split, normed and
+    scaled, v split."""
+    q, k, v, g, beta, state = ins
+    heads, key_dim = state.shape[1:3]
+    split = lambda t: t.reshape(*t.shape[:2], heads, -1)
+    return (linear_attention.l2norm(split(q)) * key_dim ** -0.5,
+            linear_attention.l2norm(split(k)), split(v), g, beta, state)
+
+
+# (heads, key_dim, value_dim, T, chunk): Olmo-Hybrid's head at the cell's
+# chunk, whole chunks and a block that is no multiple of one; a small
+# head, a short chunk
+_WIDE = [(3, 96, 192, 128, 64), (3, 96, 192, 75, 64), (4, 8, 24, 13, 4),
+         (4, 8, 24, 64, 16)]
+
+
+@pytest.mark.parametrize("heads,key_dim,value_dim,length,chunk", _WIDE)
+@pytest.mark.parametrize("state", [True, False])
+def test_the_block_form_is_the_recurrence_at_beta_up_to_2(
+        heads, key_dim, value_dim, length, chunk, state):
+    """`chunked` against `recurrent` with beta over (0, 2): the entries
+    of A in (I + A)^-1 are up to twice those at beta < 1 and nothing is
+    clamped.  The tolerance: a position's output is a sum of up to
+    `length` updates of O(1) values in float32, both forms at the
+    highest precision, in different orders; 1e-4 of the largest entry
+    (5e-5 was read at T = 128; at beta < 1 the same shapes read 2e-5)."""
+    ins = _normed(_wide_ins(np.random.RandomState(length), 2, length, heads,
+                            key_dim, value_dim, state))
+    want, want_state = jax.jit(linear_attention.recurrent)(*ins)
+    got, got_state = jax.jit(functools.partial(
+        linear_attention.chunked, chunk=chunk))(*ins)
+    for g, w in ((got, want), (got_state, want_state)):
+        w = np.asarray(w)
+        np.testing.assert_allclose(np.asarray(g), w,
+                                   atol=1e-4 * np.abs(w).max())
+
+
+@pytest.mark.parametrize("heads,key_dim,value_dim,pack", [
+    (4, 8, 24, 2), (4, 8, 24, 4), (2, 96, 192, 2), (4, 8, 24, 1)])
+@pytest.mark.parametrize("length", [1, 9])
+def test_heads_side_by_side_are_the_heads_apart(heads, key_dim, value_dim,
+                                                pack, length):
+    """`state_pack`: the op over a state whose heads lie side by side
+    (a step and a block, the plain path here) gives the op's output over
+    the heads apart, and the state it hands on is that state side by
+    side; the layout's round trip is the identity."""
+    ins = _wide_ins(np.random.RandomState(pack), 2, length, heads, key_dim,
+                    value_dim)
+    want, want_state = _rule(ins, chunk=4)
+    beside = gdn_step.pack_state(ins[5], pack)
+    assert beside.shape == (2, heads // pack, key_dim, pack * value_dim)
+    np.testing.assert_array_equal(
+        np.asarray(gdn_step.unpack_state(beside, pack)), np.asarray(ins[5]))
+    # unit u holds heads pack * u .. pack * u + pack - 1, a head after a
+    # head along the lanes
+    np.testing.assert_array_equal(
+        np.asarray(beside[:, 0, :, value_dim * (pack - 1):]),
+        np.asarray(ins[5][:, pack - 1]))
+    got, got_state = _rule(ins, state=beside, chunk=4, state_pack=pack)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-6)
+    np.testing.assert_allclose(
+        np.asarray(gdn_step.unpack_state(got_state, pack)),
+        np.asarray(want_state), atol=1e-6)
+
+
+def _wide_kernel_ins(rs, rows, heads, key_dim, value_dim, dtype):
+    q, k = (linear_attention.l2norm(jnp.asarray(
+        rs.randn(rows, heads, key_dim), jnp.float32)) for _ in range(2))
+    return (q * key_dim ** -0.5, k,
+            jnp.asarray(rs.randn(rows, heads, value_dim), dtype),
+            -jnp.asarray(rs.uniform(1e-3, 0.6, (rows, heads)), jnp.float32),
+            jnp.asarray(rs.uniform(0.02, 1.98, (rows, heads)), jnp.float32),
+            jnp.asarray(0.3 * rs.randn(rows, heads, key_dim, value_dim),
+                        jnp.float32))
+
+
+# (rows, heads, key_dim, value_dim, pack, (rows, heads) a grid step):
+# Olmo-Hybrid's 30 heads of 96 x 192 in pairs, the chooser's block and a
+# row a grid step (four grid steps: a block comes in, one is worked, one
+# goes out); a small key; values padded to whole lane blocks, a head a
+# unit; four heads side by side
+_WIDE_BLOCKS = [(4, 30, 96, 192, 2, None), (4, 30, 96, 192, 2, (1, 30)),
+                (2, 6, 8, 192, 2, None), (2, 6, 8, 192, 2, (1, 6)),
+                (2, 4, 8, 256, 1, None), (3, 4, 16, 96, 4, None)]
+
+
+@pytest.mark.parametrize("rows,heads,key_dim,value_dim,pack,block",
+                         _WIDE_BLOCKS)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_the_step_kernel_takes_a_state_that_is_not_square(
+        rows, heads, key_dim, value_dim, pack, block, dtype):
+    """The kernel's body under the Pallas interpreter over a state of
+    key_dim x value_dim a head, `pack` heads side by side as it lies in
+    HBM, beta over (0, 2): the plain step's output and state (2e-5, the
+    square kernel's tolerance: the same four lines on the same values,
+    the sums over the sublanes in another order), the state's layout
+    round trip included."""
+    ins = _wide_kernel_ins(np.random.RandomState(heads), rows, heads,
+                           key_dim, value_dim, dtype)
+    q, k, v, g, beta, s0 = ins
+    got, state = gdn_step.step(q, k, v, g, beta,
+                               gdn_step.pack_state(s0, pack), plain=None,
+                               block=block, interpret=True, pack=pack)
+    assert state.shape == (rows, heads // pack, key_dim, pack * value_dim)
+    want, want_state = linear_attention.recurrent(
+        q[:, None], k[:, None], v[:, None].astype(jnp.float32), g[:, None],
+        beta[:, None], s0)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want[:, 0]),
+                               atol=2e-5)
+    np.testing.assert_allclose(
+        np.asarray(gdn_step.unpack_state(state, pack)),
+        np.asarray(want_state), atol=2e-5)
+
+
+@pytest.mark.parametrize("shape,block", [
+    # olmohybrid-decode-pp4's: 4 rows of all 30 heads, 8.4 MiB
+    ((128, 30, 30, 96, 192, jnp.float32, 2), (4, 30)),
+    # rows the four do not divide; a batch of 1; the padded layout
+    ((6, 30, 30, 96, 192, jnp.float32, 2), (3, 30)),
+    ((1, 30, 30, 96, 192, jnp.float32, 2), (1, 30)),
+    ((128, 30, 30, 96, 256, jnp.float32, 1), (2, 30)),
+    # qwen3next-decode-ep16's and ling3-decode-ep16's, as they were
+    ((128, 32, 16, 128, 128, jnp.float32), (4, 32)),
+    ((128, 32, 32, 128, 128, jnp.float32, 1), (4, 32)),
+])
+def test_the_block_of_a_state_that_is_not_square(shape, block):
+    """`choose_block` at a head that is not 128 x 128: all the heads of
+    the most rows within `_WIDE_STEP_BYTES`; the square shapes' blocks
+    are PR 64's."""
+    assert gdn_step.choose_block(*shape) == block
+    rows, heads = block
+    state = rows * heads * shape[3] * shape[4] * 4
+    assert state <= max(gdn_step._WIDE_STEP_BYTES, gdn_step._STEP_BYTES)
+    assert 3 * state < gdn_step.vmem_limit(
+        block, shape[3] * shape[4] * 4) <= 3 * state + (8 << 20)
+
+
+def test_the_ops_wide_kernel_path_is_counted_by_its_shape():
+    """At 96 x 192 a head with two heads side by side the op asks for
+    the kernel (on the CPU its plain stand-in runs) and the counter says
+    the head's shape; the heads apart (a state of 192 lanes) stay
+    plain."""
+    rs = np.random.RandomState(5)
+    ins = _wide_ins(rs, 2, 1, 2, 96, 192)
+    before = telemetry.snapshot()
+    want, want_state = _rule(ins)
+    got, state = _rule(ins, state=gdn_step.pack_state(ins[5], 2),
+                       state_pack=2)
+    traced = telemetry.snapshot_delta(before)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+    np.testing.assert_allclose(
+        np.asarray(gdn_step.unpack_state(state, 2)), np.asarray(want_state),
+        atol=2e-5)
+    assert traced[_rule_lowering("step", "kernel", 0, 2,
+                                 dims=(96, 192))] == 1
+    assert traced[_rule_lowering("step", "plain", 0, 2,
+                                 dims=(96, 192))] == 1
 
 
 # -- (b) the convolution that carries its tail -------------------------------------

@@ -60,10 +60,11 @@ NAMES = linear_moe_param_names(LAYERS)
 CHANNELS = 2 * HK * DK + HV * DV
 
 
-def _rule_lowering(form, path, chunk, heads, gate="head"):
+def _rule_lowering(form, path, chunk, heads, gate="head", dims=(DK, DV)):
     return ("gated_delta_rule_lowerings_total{chunk=%d,form=%s,gate=%s,"
-            "heads=%d,path=%s,state_dtype=float32}"
-            % (chunk, form, gate, heads, path))
+            "heads=%d,key_dim=%d,path=%s,state_dtype=float32,"
+            "value_dim=%d}"
+            % (chunk, form, gate, heads, dims[0], path, dims[1]))
 
 
 # -- (d) the step Program against the reference's full forward --------------------

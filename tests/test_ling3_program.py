@@ -62,10 +62,11 @@ NAMES = linear_moe_param_names(LAYERS, DENSE, "channel", shared_gate=False,
 CHANNELS = 3 * H * DH
 
 
-def _rule_lowering(form, path, chunk, heads, gate="channel"):
+def _rule_lowering(form, path, chunk, heads, gate="channel", dims=(DH, DH)):
     return ("gated_delta_rule_lowerings_total{chunk=%d,form=%s,gate=%s,"
-            "heads=%d,path=%s,state_dtype=float32}"
-            % (chunk, form, gate, heads, path))
+            "heads=%d,key_dim=%d,path=%s,state_dtype=float32,"
+            "value_dim=%d}"
+            % (chunk, form, gate, heads, dims[0], path, dims[1]))
 
 
 def _start(startup, seed=3):
