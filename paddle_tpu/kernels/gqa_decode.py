@@ -53,6 +53,43 @@ once for all of them and is bound by its products and its softmax; its
 float32 scores, [G * T, block_k], are what fills VMEM, so its block of
 slots is smaller (`choose_block`: 1024 at 1024 rows).
 
+128-wide heads, one block no step's worth (Olmo-Hybrid's full layers:
+30 key/value heads with one query each over an extent of 512 slots, 128
+rows).  The grid above, a head a step, is right where a head's block is
+a step's worth of bytes: every grouped caller walks blocks of 2048 slots,
+0.5 MB of each cache.  One head's 512 slots are 128 KB, 0.32 us of the
+HBM for both caches, and a grid step costs 0.3 us on top of what it
+moves (on the chip, [128, 30, 512, 128] bfloat16, us a grid step by the
+heads it takes: 1 0.63, 2 0.98, 3 1.25, 5 1.95, 6 2.20, 10 3.49; the
+bytes alone 0.32 a head): 3,840 steps a layer took 2.41 ms a call, 1.96
+times the whole extents' bytes' time.  So there a grid step takes
+several key/value heads of a row, and then several rows of the batch
+(`choose_step`): blocks `[rows, heads, block_k, D]` of each cache,
+`[rows, heads, G * T, D]` of the queries and the output, scratch with
+the same two leading axes, and the body folds the step's (row, head)
+pairs one after another with the same `_fold` (traced once, unrolled
+when lowered).  Ten heads a step, 1.25 MB of each cache, is 384 steps a
+layer and 1.34 ms a call whatever the position, 92% of the HBM's peak on
+the bytes fetched; more heads or rows a step move nothing (15 heads
+1.34, 2 rows x 10 1.34).  The block stays the whole extent: blocks of
+256 or 128 slots skip the dead ones but reach 61% of the peak on what
+they fetch (ms a call at positions 128 / 320 / 510: 512 slots 1.34 /
+1.34 / 1.34; 256 slots, 8 rows x 5 heads, 1.06 / 2.05 / 2.05; 128 slots,
+2 rows x 30 heads, 1.06 / 1.57 / 2.07), so `choose_block` is as it was.
+The one-row products stay on the MXU: at 1.09 times its bytes' time the
+call has nothing left for the vector unit to win.  `choose_step` answers
+(1, 1), the grid, blocks and name every caller had before, where a
+head's block is over `_WIDE_HEAD_BYTES` of each cache or its queries are
+more than a sublane tile (a block of positions is bound by its products
+and its softmax), and never shares so far that a call has fewer than
+`_WIDE_MIN_STEPS` grid steps.  The rings are small blocks of the same
+walk and share a step by the same rule (ms a call, a head a step / as
+chosen: exaone's [8, 8, 128, 128] under groups of 8, 32 KB a head,
+0.035 / 0.013-0.014 at a row's 8 heads; phi4flash's [16, 10, 512, 128]
+under groups of 4, 0.101-0.103 / 0.060-0.062 at a row's 10, wrapped or
+not).  The sweep: `scripts/gqa_decode_bench.py wide`, PERF.md section 5
+(PR 68).
+
 64-wide heads (GPT-2's: 16 heads, no grouping, one query a key/value
 head).  A `[B, KV, S, 64]` array with its rows in the sublanes pads
 every 64-wide row to the 128 lanes: twice the bytes, fetched whatever
@@ -137,8 +174,11 @@ Lowered for the TPU these are Mosaic kernels named
 `gqa_decode_k<block_k>_t<T>` where T > 1 (a trace tells a prefill
 block's calls from a decode step's; `_d<head_dim>` after either where a
 head is wider than the lanes), `gqa_decode_w<window>` over a ring,
-and at 64 wide `gqa_decode_k<block_k>_h<heads>` (`_r<rows>` after it
-where rows share a step) and `gqa_write_r<rows>`, over a chosen set
+each with `_h<heads>` after it where a grid step takes several
+key/value heads (`_r<rows>` after that where rows share it too:
+`gqa_decode_k512_h10`), at 64 wide always
+`gqa_decode_k<block_k>_h<heads>` (`_r<rows>`) and `gqa_write_r<rows>`,
+over a chosen set
 `gqa_decode_sel<top_k>_c<chunk>`; lowered for the CPU
 the same kernels run under the Pallas interpreter (tests), chosen by the
 platform of the lowering as the flash kernels are.  Each entry is under
@@ -165,6 +205,13 @@ _CHUNKS = (2048, 1024, 512, 256, 128)
 _NARROW = 64
 _NARROW_BLOCKS = (512, 256, 128)
 _NARROW_STEP_BYTES = 1 << 20
+# the 128-wide walk: the bytes of a head's block of each cache over which
+# a head is a grid step by itself, what a step that heads and rows share
+# moves of each cache, and the grid steps a call keeps
+_WIDE_HEAD_BYTES = 1 << 18
+_WIDE_STEP_BYTES = 3 << 19
+_WIDE_MIN_STEPS = 8
+_SUBLANES = 8
 
 
 # what a grid step may hold in VMEM: under the 16 MiB a kernel gets on a
@@ -239,22 +286,45 @@ def choose_chunk(top_k, kv_heads, group, itemsize=2, head_dim=_LANES):
     return 0
 
 
-def choose_step(batch, kv_heads, block_k, itemsize=2):
-    """(rows of the batch, key/value heads) a grid step of the 64-wide
-    kernel takes over blocks of `block_k` slots: as many of a row's
-    heads as divide them, up to 16, then as many rows as divide the
-    batch, while the step's block of each cache stays within
-    `_NARROW_STEP_BYTES`.  One head's 512 slots are 64 KB, no step's
-    worth of bytes (a grid step costs 0.35 us and more whatever it
-    moves), so heads, and under shorter blocks rows, share the step.  On
+def choose_step(batch, kv_heads, block_k, itemsize=2, rows=1,
+                head_dim=_NARROW):
+    """(rows of the batch, key/value heads) a grid step takes over blocks
+    of `block_k` slots: a grid step costs 0.3 us and more on top of what
+    it moves, so where one head's block is no step's worth of bytes,
+    heads of a row, and then rows of the batch, share the step.
+
+    The 64-wide kernel: as many of a row's heads as divide them, up to
+    16, then as many rows as divide the batch, while the step's block of
+    each cache stays within `_NARROW_STEP_BYTES`.  One head's 512 slots
+    are 64 KB.  On
     the chip (48 rows x 16 heads x 1024 slots, ms the walk alone at
     positions 767 / 1022: scripts/gqa_decode_bench.py, PERF.md section
     5): 16 heads x 512 slots 0.29 / 0.29, 2 rows x 16 x 256 0.31 / 0.38,
     16 x 256 0.32 / 0.38, 16 x 128 0.38 / 0.46.  What a shorter block
     skips does not pay for its steps, so `choose_block` takes the
-    largest that tiles the extent."""
-    room = _NARROW_STEP_BYTES // (block_k * _NARROW * itemsize)
-    heads = _divisor(kv_heads, min(16, room))
+    largest that tiles the extent.
+
+    The 128-wide walk under `rows` queries a key/value head (the module
+    docstring's section "128-wide heads, one block no step's worth"):
+    (1, 1), the grid every caller had before PR 68, where a head's block
+    is over `_WIDE_HEAD_BYTES` of each cache (a step's worth: 2048 slots
+    are 0.5 MB) or its queries are more than a sublane tile (a block of
+    positions is products and a softmax, not bytes); else heads, then
+    rows, while the step's block of each cache stays within
+    `_WIDE_STEP_BYTES`, the step fits VMEM, and the call keeps
+    `_WIDE_MIN_STEPS` grid steps (nothing hides the first step's
+    fetch)."""
+    head = block_k * head_dim * itemsize
+    if head_dim == _NARROW:
+        room = _NARROW_STEP_BYTES // head
+        heads = _divisor(kv_heads, min(16, room))
+        return _divisor(batch, room // heads), heads
+    if rows > _SUBLANES or head > _WIDE_HEAD_BYTES:
+        return 1, 1
+    room = min(_WIDE_STEP_BYTES // head,
+               _VMEM_BYTES // _vmem_bytes(rows, block_k, itemsize, head_dim),
+               batch * kv_heads // _WIDE_MIN_STEPS)
+    heads = _divisor(kv_heads, room)
     return _divisor(batch, room // heads), heads
 
 
@@ -304,14 +374,19 @@ def _fold(q, keys, values, m_ref, l_ref, acc_ref, sm_scale, attended=None,
 
 def _kernel(last_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
             sm_scale, bk, positions):
-    """One grid step: block `j - dead` of one key/value head folded into
-    its queries' running maximum `m`, sum `l` [G * T, 1] and accumulator
-    [G * T, D]; nothing in the head's first `dead` steps."""
+    """One grid step: block `j - dead` of each of the step's rows and
+    key/value heads (blocks [rows, heads, ..]; one of each but where a
+    head's block is no step's worth, `choose_step`) folded into its
+    queries' running maximum `m`, sum `l` [G * T, 1] and accumulator
+    [G * T, D], one (row, head) pair after another; nothing in the first
+    `dead` steps."""
     j = pl.program_id(2)
     last = last_ref[0]
     top = _top(last, positions)
     last_block = top // bk
     k = j - (pl.num_programs(2) - 1 - last_block)
+    heads = k_ref.shape[1]
+    pairs = k_ref.shape[0] * heads
 
     @pl.when(j == 0)
     def _init():
@@ -319,7 +394,7 @@ def _kernel(last_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    def fold(masked):
+    def fold(masked, write=False):
         attended = held = None
         if masked:
             first = k * bk
@@ -331,8 +406,23 @@ def _kernel(last_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
                 jnp.int32, (1, bk), 1) <= limit
             held = first + lax.broadcasted_iota(
                 jnp.int32, (bk, 1), 0) <= top
-        _fold(q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], m_scr, l_scr, acc_scr,
-              sm_scale, attended, held)
+
+        def pair(at, m_ref, l_ref, acc_ref):
+            _fold(q_ref[at], k_ref[at], v_ref[at], m_ref, l_ref, acc_ref,
+                  sm_scale, attended, held)
+            if write:
+                o_ref[at] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+        if pairs == 1:      # the scratch is the pair's own
+            return pair((0, 0), m_scr, l_scr, acc_scr)
+
+        def body(i, _):
+            # traced once, unrolled when lowered: the folds of a step's
+            # pairs are independent, and interleave
+            at = (lax.div(i, heads), lax.rem(i, heads))
+            pair(at, m_scr.at[at], l_scr.at[at], acc_scr.at[at])
+
+        lax.fori_loop(0, pairs, body, None, unroll=True)
 
     # the first block some position of the block does not attend whole
     # (block 0 holds slot 0, which every position attends: no row's first
@@ -350,14 +440,14 @@ def _kernel(last_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
     @pl.when(k == last_block)
     def _crossed():
-        fold(masked=True)
-        o_ref[0, 0] = (acc_scr[...] / l_scr[...]).astype(o_ref.dtype)
+        fold(masked=True, write=True)
 
 
-def _call(q, k_cache, v_cache, last, *, sm_scale, bk, positions, name,
+def _call(q, k_cache, v_cache, last, *, sm_scale, bk, positions, step, name,
           interpret):
     batch, kv_heads, rows, dim = q.shape
     steps = k_cache.shape[2] // bk
+    shared = step if step != (1, 1) else ()     # the scratch's leading axes
 
     def slots(b, h, j, last):
         # a head's dead steps name its first block, which the step
@@ -374,14 +464,14 @@ def _call(q, k_cache, v_cache, last, *, sm_scale, bk, positions, name,
                           positions=positions),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(batch, kv_heads, steps),
-            in_specs=[pl.BlockSpec((1, 1, rows, dim), head),
-                      pl.BlockSpec((1, 1, bk, dim), slots),
-                      pl.BlockSpec((1, 1, bk, dim), slots)],
-            out_specs=pl.BlockSpec((1, 1, rows, dim), head),
-            scratch_shapes=[pltpu.VMEM((rows, 1), jnp.float32),
-                            pltpu.VMEM((rows, 1), jnp.float32),
-                            pltpu.VMEM((rows, dim), jnp.float32)],
+            grid=(batch // step[0], kv_heads // step[1], steps),
+            in_specs=[pl.BlockSpec(step + (rows, dim), head),
+                      pl.BlockSpec(step + (bk, dim), slots),
+                      pl.BlockSpec(step + (bk, dim), slots)],
+            out_specs=pl.BlockSpec(step + (rows, dim), head),
+            scratch_shapes=[pltpu.VMEM(shared + (rows, 1), jnp.float32),
+                            pltpu.VMEM(shared + (rows, 1), jnp.float32),
+                            pltpu.VMEM(shared + (rows, dim), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -670,7 +760,7 @@ def _by_platform(call, *operands):
 # instance share one traced body and one lowered function.
 
 @functools.partial(jax.jit, static_argnames=("sm_scale", "bk", "positions",
-                                             "name"))
+                                             "step", "name"))
 def _wide(q, k_cache, v_cache, last, **static):
     return _by_platform(functools.partial(_call, **static), q, k_cache,
                         v_cache, last)
@@ -732,8 +822,9 @@ def gqa_decode(q, k_cache, v_cache, last, sm_scale, window=0, block_k=None,
     `positions` consecutive ones, [batch, kv_heads, group * positions,
     D] in q's type: see the module's docstring.  `window` names the
     kernel of a ring (`gqa_decode_w<window>`, one block, one position);
-    `block_k` and, over 64-wide heads, `step` (rows, heads a grid step)
-    are chosen from the shapes unless given (tests, sweeps)."""
+    `block_k` and `step` (the rows of the batch and the key/value heads
+    a grid step takes, which have to divide them) are chosen from the
+    shapes unless given (tests, sweeps)."""
     slots = k_cache.shape[2]
     narrow = q.shape[-1] == _NARROW
     if q.ndim != 4 or k_cache.shape != v_cache.shape \
@@ -751,21 +842,29 @@ def gqa_decode(q, k_cache, v_cache, last, sm_scale, window=0, block_k=None,
     bk = block_k or choose_block(slots, q.shape[2], q.dtype.itemsize,
                                  q.shape[3])
     last = jnp.reshape(last, (1,)).astype(jnp.int32)
+    step = tuple(step or choose_step(q.shape[0], q.shape[1], bk,
+                                     q.dtype.itemsize, q.shape[2],
+                                     q.shape[3]))
+    if len(step) != 2 or min(step) < 1 or q.shape[0] % step[0] \
+            or q.shape[1] % step[1]:
+        raise ValueError(
+            "gqa_decode: a grid step of %s (rows, key/value heads) does not "
+            "divide the %d rows and %d key/value heads of %s"
+            % (step, q.shape[0], q.shape[1], q.shape))
+    # what a grid step takes, in the kernel's name
+    shared = "_h%d" % step[1] + ("_r%d" % step[0] if step[0] > 1 else "")
     if narrow:
-        step = step or choose_step(q.shape[0], q.shape[1], bk,
-                                   q.dtype.itemsize)
-        name = "gqa_decode_k%d_h%d" % (bk, step[1])
-        if step[0] > 1:
-            name += "_r%d" % step[0]
         return _narrow(q, k_cache, v_cache, last, sm_scale=float(sm_scale),
-                       bk=bk, step=tuple(step), name=name)
+                       bk=bk, step=step, name="gqa_decode_k%d" % bk + shared)
     name = "gqa_decode_w%d" % window if window else "gqa_decode_k%d" % bk
     if positions > 1:
         name += "_t%d" % positions
     if q.shape[3] != _LANES:
         name += "_d%d" % q.shape[3]
+    if step != (1, 1):
+        name += shared
     return _wide(q, k_cache, v_cache, last, sm_scale=float(sm_scale), bk=bk,
-                 positions=positions, name=name)
+                 positions=positions, step=step, name=name)
 
 
 def gqa_decode_chosen(q, k_chosen, v_chosen, live, sm_scale, chunk=None):

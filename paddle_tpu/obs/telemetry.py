@@ -302,27 +302,33 @@ def _kv_cache_slots(kind, slots):
 
 
 def on_window_attention_lowering(kind, kv_heads, window, path, block_k,
-                                 slots, block):
+                                 slots, block, step):
     """A `cached_attention` op (ops/attention.py) was traced into a
     program: over which kind of cache ("window": a ring of `window`
     slots; "full": the whole extent, `window` 0), with how many
     key/value heads, over how many positions of a row an application
     (`block`; 1: a decode step), and which way it takes over the cache
     ("kernel": the walk of the live slots in blocks of `block_k`,
-    kernels/gqa_decode.py; "plain": scores over every slot under a mask,
-    `block_k` 0); and the `slots` a row of its cache holds, added up by
-    kind.  One count per op instance a lowered program holds."""
+    kernels/gqa_decode.py, `step` the (rows of the batch, key/value
+    heads) a grid step of it takes: more than (1, 1) where one head's
+    block is no step's worth of bytes; "plain": scores over every slot
+    under a mask, `block_k` 0, `step` (1, 1)); and the `slots` a row of
+    its cache holds, added up by kind.  One count per op instance a
+    lowered program holds."""
     _reg().counter("window_attention_lowerings_total",
                    "key/value-cached attention ops lowered, by the kind "
                    "of cache (a window's ring or the full extent), "
                    "key/value heads, window, the positions of a row one "
                    "application takes, path (the kernel over the live "
-                   "slots, or the plain products) and the kernel's block "
-                   "of slots",
+                   "slots, or the plain products), the kernel's block "
+                   "of slots, and the rows and key/value heads a grid "
+                   "step of it takes",
                    labelnames=("kind", "kv_heads", "window", "block",
-                               "path", "block_k")) \
+                               "path", "block_k", "step_rows",
+                               "step_heads")) \
           .labels(kind=kind, kv_heads=kv_heads, window=window, block=block,
-                  path=path, block_k=block_k).inc()
+                  path=path, block_k=block_k, step_rows=step[0],
+                  step_heads=step[1]).inc()
     _kv_cache_slots(kind, slots)
 
 

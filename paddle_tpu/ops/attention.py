@@ -523,6 +523,11 @@ def cached_attention_op(ctx, ins, attrs):
         block_k = gqa_decode.choose_block(attended, group * block,
                                           q.dtype.itemsize, head_dim)
     writes = block_k and head_dim == 64
+    # the rows and key/value heads a grid step of the walk takes: more
+    # than one where a head's block is no step's worth of bytes
+    step = gqa_decode.choose_step(
+        rows, kv_heads, block_k, q.dtype.itemsize, group * block,
+        head_dim) if block_k and selected is None else (1, 1)
     # the positions of a block over chosen sets that are gathered and
     # attended at once: their two copies, beside their scores where
     # these are made whole (the plain path)
@@ -540,7 +545,7 @@ def cached_attention_op(ctx, ins, attrs):
     elif selected is None:
         telemetry.on_window_attention_lowering(
             kind, kv_heads, window, "kernel" if block_k else "plain",
-            block_k, extent, block)
+            block_k, extent, block, step)
     else:
         telemetry.on_sparse_attention_lowering(
             kv_heads, attended, extent, "kernel" if block_k else "plain",
@@ -609,7 +614,7 @@ def cached_attention_op(ctx, ins, attrs):
             out = gqa_decode.gqa_decode(
                 qh.reshape(rows, kv_heads, group * block, head_dim),
                 k_live.astype(q.dtype), v_live.astype(q.dtype), last,
-                sm_scale, window, block_k, block)
+                sm_scale, window, block_k, block, step)
         else:
             keys, values, valid = _ring_before_a_block(
                 before, (kh, vh), pos) if ring_block \

@@ -1,4 +1,4 @@
-"""Times the 64-wide grouped-query decode kernels on the chip at
+"""Times the grouped-query decode kernels on the chip: the 64-wide ones at
 gpt2m-decode's shape (48 rows, 16 heads of 64, 1024-slot bfloat16
 caches) over the rows and key/value heads a grid step takes and its
 block of slots, beside the `cached_attention` op's plain path: what
@@ -35,7 +35,17 @@ then a tile of positions at a time the sets gathered and attended), by
 the positions a tile takes (`ops.attention._CHOSEN_TILE_BYTES` set for 1
 to 16),
 beside 64 single steps of the same op: ms an application and ns a
-gathered slot, what the tile was decided from."""
+gathered slot, what the tile was decided from.
+
+`wide` (PR 68; `python scripts/gqa_decode_bench.py wide [batch kv_heads
+slots group window]`) is the 128-wide walk over caches that stay, at
+olmohybrid-decode-pp4's full layers' shape unless given one (128 rows,
+30 ungrouped heads, 512 slots; `wide 8 8 128 8 128` and `wide 16 10 512 4
+512` are exaone-turn-32k-ep16's and phi4flash-turn-16k's rings), by the
+(rows, key/value heads) a grid step takes and its block of slots: ms a
+call, us a grid step, and the share of the HBM's peak that the live and
+the fetched bytes are of it, what `_WIDE_STEP_BYTES`, `_WIDE_HEAD_BYTES`
+and the whole-extent block were decided from (PERF.md section 5)."""
 
 import json
 import os
@@ -65,13 +75,13 @@ SHAPES = (((2, 16), 256), ((1, 16), 512), ((1, 16), 256), ((4, 16), 128),
 POSITIONS = (512, 767, 1022)
 
 
-def plain(q, k, v, last):
+def plain(q, k, v, last, scale=SCALE):
     """The op's plain path: float32 products at the highest precision
     over every slot under a mask."""
     s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
                    k.astype(jnp.float32),
-                   precision=lax.Precision.HIGHEST) * SCALE
-    p = jax.nn.softmax(jnp.where(jnp.arange(SLOTS) <= last, s, -1e30),
+                   precision=lax.Precision.HIGHEST) * scale
+    p = jax.nn.softmax(jnp.where(jnp.arange(k.shape[2]) <= last, s, -1e30),
                        axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32),
                       precision=lax.Precision.HIGHEST).astype(q.dtype)
@@ -326,6 +336,67 @@ def block(emit):
               "max_abs_off_tile_1": float(jnp.max(jnp.abs(got - want)))})
 
 
+# -- 128-wide heads, one block of a head no step's worth ---------------------
+
+W_DIM = 128
+W_SCALE = W_DIM ** -0.5
+
+
+def wide(emit, batch=128, kv_heads=30, slots=512, group=1, window=0):
+    """The 128-wide walk at [batch, kv_heads, slots, 128] bfloat16 under
+    `group` queries a key/value head (`window`: a ring of that many
+    slots, one block; the defaults are olmohybrid-decode-pp4's full
+    layers), by the (rows, heads) a grid step takes and its
+    block of slots: ms a call over caches that stay, us a grid step, and
+    the share of the HBM's peak that the live bytes and the fetched
+    bytes (whole blocks) are of it."""
+    rs = np.random.RandomState(0)
+
+    def draw(*shape):
+        return jnp.asarray(rs.randn(*shape), jnp.bfloat16)
+
+    k, v = (draw(batch, kv_heads, slots, W_DIM) for _ in range(2))
+    q = draw(batch, kv_heads, group, W_DIM)
+    own_block = gqa_decode.choose_block(slots, group)
+    own = (gqa_decode.choose_step(batch, kv_heads, own_block, 2, group,
+                                  W_DIM), own_block)
+    divisors = [d for d in range(1, kv_heads + 1) if kv_heads % d == 0]
+    blocks = (slots,) if window else [
+        b for b in (512, 256, 128) if slots % b == 0]
+    variants = [((r, h), bk) for bk in blocks for r in (1, 2, 4, 8)
+                if batch % r == 0 for h in divisors
+                if (r == 1 or h >= 5)
+                and r * h * gqa_decode._vmem_bytes(group, bk, 2)
+                <= gqa_decode._VMEM_BYTES]
+    # a ring: not wrapped yet, and wrapped (every slot live)
+    positions = (slots // 4, slots - 1) if window \
+        else (slots // 4, slots * 5 // 8, slots - 2)
+
+    for step, bk in variants:
+        def fn(n, q, k, v, last):
+            return lax.fori_loop(0, n, lambda _, q: gqa_decode.gqa_decode(
+                q, k, v, last, W_SCALE, window, bk, step=step), q)
+        fn = jax.jit(fn)
+        for last in positions:
+            at = jnp.int32(last)
+            err = float(jnp.max(jnp.abs(
+                fn(1, q, k, v, at)[:2].astype(jnp.float32)
+                - plain(q[:2], k[:2], v[:2], at, W_SCALE)
+                .astype(jnp.float32))))
+            ms = slope(fn, q, k, v, at)
+            a_slot = 2 * batch * kv_heads * W_DIM * 2
+            fetched = -(-(last + 1) // bk) * bk
+            grid = batch // step[0] * (kv_heads // step[1]) * (slots // bk)
+            emit({"kind": "wide",
+                  "shape": [batch, kv_heads, slots, group, window],
+                  "step": list(step), "block_k": bk, "last": last,
+                  "the_kernels_own": (step, bk) == own, "ms": ms,
+                  "us_a_grid_step": ms * 1e3 / grid,
+                  "live_share": a_slot * (last + 1) / HBM / (ms / 1e3),
+                  "fetched_share": a_slot * fetched / HBM / (ms / 1e3),
+                  "max_abs_err": err})
+
+
 def main():
     assert jax.devices()[0].platform == "tpu", jax.devices()
     os.makedirs("chiprun_out", exist_ok=True)
@@ -338,6 +409,9 @@ def main():
         out.flush()
 
     cases = sys.argv[1:] or ["narrow", "chosen"]
+    if "wide" in cases:
+        shape = [int(n) for n in cases[cases.index("wide") + 1:]]
+        wide(emit, *shape)
     if "chosen" in cases:
         chosen(emit)
     if "block" in cases:

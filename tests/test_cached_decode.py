@@ -513,12 +513,13 @@ def test_counters_say_which_way_a_gpt2_step_takes_over_its_caches():
                          jnp.zeros((rows, block), jnp.int32))
     delta = telemetry.snapshot_delta(before)
     label = "window_attention_lowerings_total{block=%d,block_k=%d," \
-        "kind=full,kv_heads=%d,path=%s,window=0}"
+        "kind=full,kv_heads=%d,path=%s,step_heads=%d,step_rows=%d,window=0}"
     assert {k: v for k, v in delta.items()
             if k.startswith(("window_attention_lowerings_total",
                              "cached_attention_lowerings_total"))} == {
-        label % (1, 256, heads, "kernel"): layers,
-        label % (block, 0, heads, "plain"): layers,
+        # a grid step of the 64-wide kernel takes both rows' two heads
+        label % (1, 256, heads, "kernel", heads, rows): layers,
+        label % (block, 0, heads, "plain", 1, 1): layers,
         "cached_attention_lowerings_total{block=1}": layers,
         "cached_attention_lowerings_total{block=%d}" % block: layers}
 

@@ -359,7 +359,7 @@ def test_the_latent_decode_kernel_takes_32_heads(block, name):
 
 
 @pytest.mark.parametrize("block,window,kernels", [
-    (1, 0, ["gqa_decode_k2048"]), (1, 128, ["gqa_decode_w128"]),
+    (1, 0, ["gqa_decode_k2048"]), (1, 128, ["gqa_decode_w128_h8"]),
     (128, 0, ["gqa_decode_k1024_t128"]), (128, 128, [])])
 def test_the_grouped_decode_kernel_lowers_for_tpu(block, window, kernels):
     """`cached_attention` at exaone-turn-32k-ep16's shapes (8 rows, 64
@@ -367,8 +367,9 @@ def test_the_grouped_decode_kernel_lowers_for_tpu(block, window, kernels):
     128-slot ring, bfloat16) lowered for the TPU from this CPU host: a
     decode step walks the live slots of either cache, a block of 128
     positions those of the whole extent (the kernel's name says the
-    block of slots and the positions), and a block through a ring holds
-    no kernel."""
+    block of slots and the positions; a ring's, whose 128 slots of a
+    head are 32 KB, that a grid step takes a row's eight heads), and a
+    block through a ring holds no kernel."""
     from paddle_tpu.ops import registry
 
     kernel = registry.get_op_info("cached_attention").kernel
@@ -392,7 +393,8 @@ def test_the_grouped_decode_kernel_lowers_for_tpu(block, window, kernels):
 
 
 @pytest.mark.parametrize("block,window,readonly,kernels", [
-    (1, 0, False, ["gqa_decode_k2048"]), (1, 512, False, ["gqa_decode_w512"]),
+    (1, 0, False, ["gqa_decode_k2048"]),
+    (1, 512, False, ["gqa_decode_w512_h10"]),
     (128, 0, False, ["gqa_decode_k2048_t128"]), (128, 512, False, []),
     (1, 0, True, ["gqa_decode_k2048"])])
 def test_pairs_of_64_wide_heads_lower_the_grouped_kernel_for_tpu(
@@ -401,7 +403,8 @@ def test_pairs_of_64_wide_heads_lower_the_grouped_kernel_for_tpu(
     query heads over 10 pairs of key/value heads kept side by side as
     128-wide heads, a 16,384-slot cache or a 512-slot ring, bfloat16)
     lowered for the TPU from this CPU host: a step walks the live slots
-    of either cache, a block of 128 positions those of the whole extent,
+    of either cache (a grid step of a ring's walk takes a row's ten
+    heads, 128 KB each), a block of 128 positions those of the whole extent,
     a block through a ring holds no kernel; and the form without KNew /
     VNew walks the same kernel and writes no slot."""
     from paddle_tpu.ops import registry
@@ -873,6 +876,31 @@ def test_the_wide_head_decode_kernel_lowers_for_tpu(block, kernels):
     assert module.count("tpu_custom_call") == len(kernels)
     for name in kernels:
         assert 'kernel_name = "%s"' % name in module
+
+
+@pytest.mark.parametrize("block,kernel_name", [
+    (1, "gqa_decode_k512_h10"), (128, "gqa_decode_k512_t128")])
+def test_ungrouped_wide_heads_share_a_grid_step_for_tpu(block, kernel_name):
+    """`cached_attention` at olmohybrid-decode-pp4's shape (128 rows, 30
+    ungrouped heads of 128, 512-slot bfloat16 caches) lowered for the TPU
+    from this CPU host: one head's 512 slots are 128 KB of each cache, no
+    grid step's worth, so a decode step's walk takes ten heads a grid
+    step (384 steps a layer, not 3,840) and its name says so; a prefill
+    block of 128 positions is products, a head a step as before."""
+    from paddle_tpu.ops import registry
+
+    kernel = registry.get_op_info("cached_attention").kernel
+    b, h, d, bf16 = 128, 30, 128, jnp.bfloat16
+    cache = jax.ShapeDtypeStruct((b, h, 512, d), bf16)
+    new = jax.ShapeDtypeStruct((b, block, h * d), bf16)
+    ins = {"Q": [new], "KNew": [new], "VNew": [new],
+           "KCache": [cache], "VCache": [cache],
+           "Position": [jax.ShapeDtypeStruct((b,), jnp.int32)]}
+    module = jax.export.export(jax.jit(
+        lambda ins: kernel(None, ins, {"num_heads": h})),
+        platforms=["tpu"])(ins).mlir_module()
+    assert module.count("tpu_custom_call") == 1
+    assert 'kernel_name = "%s"' % kernel_name in module
 
 
 def _vmem_limit_stated(module):

@@ -369,6 +369,37 @@ def test_at_the_published_head_every_linear_layer_asks_for_the_kernel():
     assert wide % "plain" not in traced
 
 
+def test_at_the_published_head_the_full_layers_share_a_grid_step():
+    """Olmo-Hybrid's full layers at their own head shape (30 ungrouped
+    heads of 128 over a 512-slot extent; the other widths small): one
+    head's block of slots is no grid step's worth of bytes, so a traced
+    step's `cached_attention` asks the walk of the live slots for a grid
+    step that several key/value heads share (kernels/gqa_decode.py
+    `choose_step`), and the counter says so."""
+    program = build_linear_moe_cached_step_program(
+        4, 512, V, **dict(SIZES, n_head=30, n_kv_head=30, d_head=128))
+    scope = fluid.Scope()
+    fluid.Executor(fluid.CPUPlace()).run(program[1], scope=scope)
+    decoder = fluid.ProgramDecoder(
+        program[0].clone(for_test=True), token_name="tok",
+        logits_name=program[2].name, state_pairs=program[3], scope=scope,
+        max_positions=512)
+    block = program[0].global_block()
+    state = {feed: jax.ShapeDtypeStruct(
+        tuple(4 if n == -1 else n for n in block.var(feed).shape),
+        jnp.int32 if feed == "pos" else jnp.float32)
+        for feed, _ in program[3]}
+    assert state["k_cache_2"].shape == (4, 30, 512, 128)
+    before = telemetry.snapshot()
+    jax.eval_shape(decoder._step_fn(decoder._params), state,
+                   jax.ShapeDtypeStruct((4,), jnp.int32))
+    traced = {key: n for key, n in telemetry.snapshot_delta(before).items()
+              if key.startswith("window_attention_lowerings_total")}
+    assert traced == {
+        "window_attention_lowerings_total{block=1,block_k=512,kind=full,"
+        "kv_heads=30,path=kernel,step_heads=6,step_rows=1,window=0}": 1}
+
+
 def test_the_build_lowers_nothing(built):
     assert not [k for k in built["at_build"] if "_lowerings_total" in k
                 or k.startswith("recurrent_state_bytes_total")]

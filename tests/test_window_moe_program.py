@@ -278,10 +278,10 @@ def test_the_ops_kernel_path_is_its_plain_path():
     assert delta["kv_cache_slots_total{kind=full}"] == 142 * 256
 
 
-def _lowering(kind, kv_heads, window, path, block_k, block=1):
+def _lowering(kind, kv_heads, window, path, block_k, block=1, step=(1, 1)):
     return "window_attention_lowerings_total{block=%d,block_k=%d,kind=%s," \
-        "kv_heads=%d,path=%s,window=%d}" % (block, block_k, kind, kv_heads,
-                                            path, window)
+        "kv_heads=%d,path=%s,step_heads=%d,step_rows=%d,window=%d}" % (
+            block, block_k, kind, kv_heads, path, step[1], step[0], window)
 
 
 def test_the_kernel_refuses_what_it_does_not_take():
@@ -402,7 +402,8 @@ def test_the_op_takes_the_narrow_kernels_for_a_step_in_qs_type():
     np.testing.assert_allclose(blocks, want, atol=1e-5)
     assert {key: n for key, n in delta.items()
             if key.startswith("window_attention_lowerings_total")} == {
-        _lowering("full", 16, 0, "kernel", 128): 12,
+        # the 64-wide kernel's grid step takes a row's 16 heads
+        _lowering("full", 16, 0, "kernel", 128, step=(1, 16)): 12,
         _lowering("full", 16, 0, "plain", 0, block=5): 1,
         _lowering("full", 16, 0, "plain", 0, block=7): 1}
     caches = [jnp.zeros((1, 16, 128, 64), jnp.bfloat16)] * 2
