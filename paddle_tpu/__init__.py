@@ -20,15 +20,13 @@ from .obs.trace import STARTUP as _STARTUP, span as _span  # noqa: E402
 __version__ = "0.1.0"
 
 __all__ = ["reader", "dataset", "batch", "fluid", "v2", "infer",
-           "layer", "image", "obs", "resilience", "analysis",
-           "compile"]
+           "layer", "image", "obs", "resilience", "analysis"]
 
 with _span("startup/import", cat=_STARTUP).began(_IMPORT_BEGAN):
     from . import reader
     from . import dataset
     from .reader.decorator import batch
     from . import analysis
-    from . import compile  # noqa: A004 — paddle_tpu.compile subsystem
     from . import obs
     from . import resilience
     with _span("startup/import_fluid", cat=_STARTUP):

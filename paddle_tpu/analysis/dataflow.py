@@ -49,8 +49,8 @@ __all__ = ["Liveness", "analyze_block", "analyze_dataflow",
 def liveness_timeline(op_descs, var_bytes, final_live=(), top_n=0):
     """Per-op live-bytes series of `sum(var_bytes(name))` over each
     op's live set (live-in plus own defs).  THE activation-peak walk:
-    the shard analyzer's S005 estimate, the auto_remat pass's accept
-    gate, and the obs.mem memory timeline all run it, parameterized
+    the shard analyzer's S005 estimate and the obs.mem memory
+    timeline both run it, parameterized
     only by the byte policy (`var_bytes`: name -> bytes, returning 0
     for names that don't count), so the accountings cannot drift
     apart structurally.
@@ -281,8 +281,8 @@ def _referenced_names(desc):
     """Every name any op in any block reads or writes — the D002
     universe, computed ONCE per program (analyze_dataflow passes it
     down).  String attr refs count: the recurrent op names its
-    carries through attrs, and sweeping those VarDescs would break
-    the scan lowering."""
+    carries through attrs, and those VarDescs are what the scan
+    lowering reads."""
     referenced = set()
     for b in desc.blocks:
         for od in b.ops:
@@ -299,10 +299,7 @@ def dead_op_indices(desc, block_idx, fetches, name_sets=None):
     op may kill its producers); effectful ops (host side effects,
     sub-block holders, unregistered types) are never dead.
 
-    Shared by the D001 diagnostic below and the dead-op-elimination
-    rewrite pass (`paddle_tpu.compile.passes`), so the lint and the
-    transform can never disagree about what is removable.  Returns
-    (dead_index_set, Liveness).
+    Returns (dead_index_set, Liveness).
 
     The live seed takes the WHOLE cross-block read set, not just the
     names this block declares: control-flow carry variables (a while

@@ -56,6 +56,7 @@ import re
 from collections import OrderedDict
 
 from ..core.types import GRAD_SUFFIX
+from ..ops.optimizer_ops import UPDATE_OPS
 from .common import EMPTY, find_var_desc
 from .costmodel import CommCostReport
 from .dataflow import liveness_timeline
@@ -63,13 +64,6 @@ from .diagnostics import Diagnostic, Report, Severity
 
 __all__ = ["analyze_sharding", "ShardingPlan", "mesh_axis_sizes",
            "check_pipeline", "check_moe", "check_ring"]
-
-# ops whose outputs alias their inputs (state advance): specs are
-# preserved by construction, nothing to propagate
-_UPDATE_OPS = frozenset([
-    "sgd", "momentum", "adam", "adamax", "adagrad", "decayed_adagrad",
-    "adadelta", "rmsprop", "ftrl", "proximal_gd", "proximal_adagrad",
-    "fused_update"])
 
 _NON_STATE_SLOTS = frozenset(["Param", "Grad", "LearningRate"])
 
@@ -390,7 +384,7 @@ def analyze_sharding(program, mesh, feed_names=None, feed_specs=None,
     for i, od in enumerate(bd.ops):
         if od.type in ("flash_attention", "flash_attention_grad"):
             _check_flash_attention(desc, bd, i, od, axes, comm, report)
-        if od.type in _UPDATE_OPS:
+        if od.type in UPDATE_OPS:
             continue  # outputs alias inputs; specs preserved
         _propagate_op(desc, bd, i, od, axes, plan, comm, report)
 
@@ -433,7 +427,7 @@ def _optimizer_state_params(bd):
     desc-level sibling of parallel.sharding.optimizer_state_names)."""
     out = {}
     for od in bd.ops:
-        if od.type not in _UPDATE_OPS:
+        if od.type not in UPDATE_OPS:
             continue
         pnames = od.input("Param")
         pname = pnames[0] if pnames else None

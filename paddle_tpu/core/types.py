@@ -86,12 +86,6 @@ def is_float_dtype(dtype) -> bool:
 
 GRAD_SUFFIX = "@GRAD"
 
-# the fused elementwise-chain op type: the kernel registration
-# (ops/math.py) and the program rewrite that emits it
-# (fluid/fusion.py) must agree on the name, and neither package may
-# import the other — this leaf module is the one source
-FUSED_ELEMWISE_OP = "fused_elemwise_chain"
-
 
 def grad_var_name(name: str) -> str:
     """reference: paddle/framework/grad_op_desc_maker.h GradVarName."""

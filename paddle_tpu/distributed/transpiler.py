@@ -24,7 +24,7 @@ import numpy as np
 
 from .. import native
 from ..core.types import VarType
-from ..fluid import framework, fusion
+from ..fluid import framework
 from ..ops.dist import ClientPool as _ClientPool, _bname
 
 __all__ = ["DistributeTranspiler", "split_dense_variable", "run_pserver"]
@@ -142,9 +142,6 @@ class DistributeTranspiler:
         self.endpoints = endpoints
 
         block = program.global_block()
-        # pserver placement scatters per-parameter update ops across
-        # endpoints, so any stacked fused_update ops must come apart first
-        fusion.unfuse_update_ops(block)
         params = [p for p, g in params_grads]
         grads = {p.name: g for p, g in params_grads}
 

@@ -23,16 +23,6 @@ Model caveats (documented, deliberate):
     fuses elementwise chains, so the true traffic sits between the
     per-op sum and the unique-bytes bound where each distinct tensor
     moves through HBM exactly once; both are reported.
-  * ``tpu_tiling=True`` counts PHYSICAL bytes under the TPU's memory
-    tiling — the minor dim pads to 128 lanes and the second-minor to
-    8/16/32 sublanes (4/2/1-byte elements).  This is what makes the
-    cost model layout-aware: a late-ResNet NCHW activation
-    [N, 2048, 7, 7] pads its W=7 minor dim to 128 (an 18x physical
-    blowup) where the NHWC form [N, 7, 7, 2048] pads only 7->8 on the
-    sublane dim — the honest basis for the `layout` rewrite pass's
-    accept/decline decision (compile/opt_passes.py).  Off by default:
-    XLA re-layouts MXU operands itself, so logical-shape bytes remain
-    the fairer fleet-wide default.
   * with ``bf16_act`` (the FLAGS_amp_bf16_act policy), non-persistable
     float tensors count 2 bytes/element; persistable (master weights,
     running stats) stay 4.
@@ -94,29 +84,6 @@ def _elem_bytes(dtype, persistable, bf16_act):
     return size
 
 
-def _ceil_to(n, mult):
-    return (n + mult - 1) // mult * mult
-
-
-def _numel_tiled(shape, esize):
-    """Physical element count under TPU memory tiling: the minor dim
-    pads to 128 lanes, the second-minor to the dtype's sublane count
-    (f32 8, bf16 16, int8 32 — (sublane x 128) is the minimum tile).
-    Rank-0/1 tensors occupy whole tiles of the minor dim."""
-    sublane = {4: 8, 2: 16, 1: 32}.get(esize, 8)
-    if shape is None:
-        return 0
-    dims = [max(int(s), 1) for s in shape]  # -1 (dynamic) counted as 1
-    if not dims:
-        return sublane * 128
-    if len(dims) == 1:
-        return _ceil_to(dims[0], 128) * sublane
-    n = 1
-    for s in dims[:-2]:
-        n *= s
-    return n * _ceil_to(dims[-2], sublane) * _ceil_to(dims[-1], 128)
-
-
 def _conv_flops(block, od, fwd_type):
     """2 * out_spatial * N * K * C/g * prod(kernel). Output shape is
     the forward Output's; for grad ops it's the O@Output operand."""
@@ -150,7 +117,7 @@ def _mul_flops(block, od, fwd_type):
     return flops * (1 if od.type == fwd_type else 2)
 
 
-def op_cost(block, od, bf16_act=False, tiled=False):
+def op_cost(block, od, bf16_act=False):
     """(flops, bytes, klass) for one OpDesc."""
     fwd = od.type
     if op_registry.is_grad_op_type(od.type):
@@ -167,31 +134,29 @@ def op_cost(block, od, bf16_act=False, tiled=False):
     total_bytes = 0
     for names in list(od.inputs.values()) + list(od.outputs.values()):
         for n in names:
-            total_bytes += _tensor_bytes(block, n, bf16_act,
-                                         tiled=tiled)
+            total_bytes += _tensor_bytes(block, n, bf16_act)
     return flops, total_bytes, klass
 
 
-def _tensor_bytes(block, name, bf16_act, tiled=False):
+def _tensor_bytes(block, name, bf16_act):
     meta = _var_meta(block, name)
     if not meta or meta[0] is None:
         return 0
     v = block.var_recursive(name)
     esize = _elem_bytes(meta[1], bool(getattr(v, "persistable", False)),
                         bf16_act)
-    numel = _numel_tiled(meta[0], esize) if tiled else _numel(meta[0])
-    return numel * esize
+    return _numel(meta[0]) * esize
 
 
-def program_costs(program, bf16_act=False, block=None, tiled=False):
+def program_costs(program, bf16_act=False, block=None):
     """Per-op cost rows for the global block (or ``block``):
     [(op_type, flops, bytes, klass), ...] in op order."""
     block = block if block is not None else program.global_block()
-    return [(od.type,) + op_cost(block, od, bf16_act, tiled=tiled)
+    return [(od.type,) + op_cost(block, od, bf16_act)
             for od in block.desc.ops]
 
 
-def _unique_bytes(block, bf16_act, tiled=False):
+def _unique_bytes(block, bf16_act):
     """Bytes if every referenced tensor moved exactly once — the
     perfect-fusion traffic floor (intermediates inside a fusion are
     free, but each distinct value is produced/consumed through HBM at
@@ -203,14 +168,13 @@ def _unique_bytes(block, bf16_act, tiled=False):
             for n in names:
                 if n not in seen:
                     seen.add(n)
-                    total += _tensor_bytes(block, n, bf16_act,
-                                           tiled=tiled)
+                    total += _tensor_bytes(block, n, bf16_act)
     return total
 
 
 def roofline_report(program, peak_tflops=DEFAULT_PEAK_TFLOPS,
                     hbm_gbps=DEFAULT_HBM_GBPS, bf16_act=False,
-                    block=None, tpu_tiling=False):
+                    block=None):
     """Aggregate time floors.  Returns a dict with per-op-type rows and
     two step floors:
       * ``floor_ms_serial`` — sum over ops of max(t_mxu, t_hbm): every
@@ -224,8 +188,7 @@ def roofline_report(program, peak_tflops=DEFAULT_PEAK_TFLOPS,
     ``floor_ms_serial`` from ``floor_ms_ideal`` is the remaining
     fusion headroom."""
     block_ = block if block is not None else program.global_block()
-    rows = program_costs(program, bf16_act=bf16_act, block=block_,
-                         tiled=tpu_tiling)
+    rows = program_costs(program, bf16_act=bf16_act, block=block_)
     peak = peak_tflops * 1e12
     bw = hbm_gbps * 1e9
     agg = defaultdict(lambda: [0, 0, 0, 0.0])  # count, flops, bytes, t
@@ -242,7 +205,7 @@ def roofline_report(program, peak_tflops=DEFAULT_PEAK_TFLOPS,
         t_serial += t
         tot_flops += flops
         tot_bytes += nbytes
-    uniq = _unique_bytes(block_, bf16_act, tiled=tpu_tiling)
+    uniq = _unique_bytes(block_, bf16_act)
     return {
         "per_type": {k: {"count": v[0], "gflops": v[1] / 1e9,
                          "mbytes": v[2] / 1e6, "t_ms": v[3] * 1e3}
@@ -255,7 +218,6 @@ def roofline_report(program, peak_tflops=DEFAULT_PEAK_TFLOPS,
         "peak_tflops": peak_tflops,
         "hbm_gbps": hbm_gbps,
         "bf16_act": bf16_act,
-        "tpu_tiling": bool(tpu_tiling),
     }
 
 

@@ -66,20 +66,16 @@ def _permute_shape(shape, perm):
     return tuple(shape[p] for p in perm)
 
 
-def convert_layout(program, to="NHWC", block=None, layout_out=None):
+def convert_layout(program, to="NHWC", block=None):
     """Rewrite a forward program's conv stack to run in ``to`` layout.
 
     Feeds and parameters keep their declared layouts; consumers that
     are neither layout-capable nor layout-agnostic see NCHW restored at
     their inputs, so the program's observable contract (feeds, fetches
     of boundary values, parameter shapes) is unchanged.  Returns the
-    number of inserted transpose ops.  ``layout_out`` (a dict, when
-    given) is filled with the final var -> "NHWC" map so callers (the
-    `layout` rewrite pass) can tell which vars now live in the new
-    layout — shape comparison cannot: a C==H==W tensor permutes to an
-    identical shape.  Must run before the backward is appended —
-    rewriting grad ops would require transforming grad chains too,
-    which append_backward does for free afterwards.
+    number of inserted transpose ops.  Must run before the backward
+    is appended — rewriting grad ops would require transforming grad
+    chains too, which append_backward does for free afterwards.
     """
     if to != "NHWC":
         raise ValueError("convert_layout targets NHWC (programs are "
@@ -94,7 +90,7 @@ def convert_layout(program, to="NHWC", block=None, layout_out=None):
     new_ops = []
     inserted = [0]
     # var name -> "NHWC" for vars currently in NHWC
-    layout = layout_out if layout_out is not None else {}
+    layout = {}
     alias = {}       # (var name, target layout) -> transposed alias name
 
     def transposed(name, to_layout):

@@ -260,9 +260,9 @@ def op_instance(op_desc):
     forward op has: `append_backward` hands it the forward's outputs as
     the `O@<slot>` inputs, in the forward's order, so an op and its
     gradient share an instance and join without a table.  An op that
-    updates a parameter (the optimizers' ops, a fused update) has its
-    first `Param`.  A weight applied four times gives four instances;
-    two ops of one type that write one variable in place share one."""
+    updates a parameter (the optimizers' ops) has its `Param`.  A weight
+    applied four times gives four instances; two ops of one type that
+    write one variable in place share one."""
     names = op_desc.input("Param")
     if not names and op_registry.is_grad_op_type(op_desc.type):
         names = [n for slot, vs in op_desc.inputs.items()
@@ -702,7 +702,7 @@ class _CompiledProgram:
         when a jit call after `lower().compile()` compiled again; at
         jax 0.9.0 it does not — the two share the executable, checked
         on the CPU backend — so executing the artifact is a habit now,
-        not a saving: ROADMAP Design 2.)  Returns (outs, rng), or None
+        not a saving: ROADMAP Design 4.)  Returns (outs, rng), or None
         to fall back to the jit call path: an unknown signature with
         `allow_compile` off (post-warmup retraces, and signatures
         already warm in the jit cache, compile through the normal jit
@@ -856,21 +856,7 @@ class Executor:
                 plan_span.set(miss=miss)
             traces = compiled.traces
 
-            try:
-                results = compiled.run(scope, feed_env, eager=eager)
-            except Exception as exc:
-                # a device OOM must be blamed on the program that
-                # ACTUALLY ran — under FLAGS_compile_passes that is
-                # the rewritten clone (auto_remat already dropped the
-                # buffers the original would name); run()'s flight
-                # hook reads this through oom_context
-                if obs_mem.is_oom(exc) \
-                        and not hasattr(exc, "_mem_program"):
-                    try:
-                        exc._mem_program = compiled.program
-                    except Exception:
-                        pass  # __slots__ exception: original blamed
-                raise
+            results = compiled.run(scope, feed_env, eager=eager)
 
             if return_numpy:
                 with obs_trace.span("executor/fetch", cat="executor"):
@@ -890,16 +876,14 @@ class Executor:
                       use_program_cache):
         """(the `_CompiledProgram` for this call, whether it had to be
         built): the cache key, the lookup and, on a miss, verification,
-        the rewrite passes and the plan itself."""
-        # dtype policy and the rewrite pipeline are trace-time
-        # state: a flipped amp flag (or pass config) must not
-        # reuse executables built under the old policy
+        the memory pre-flight and the plan itself."""
+        # dtype policy is trace-time state: a flipped amp flag must
+        # not reuse executables built under the old policy
         key = (program._cache_token, program.version, 0,
                tuple(sorted(feed_env.keys())), tuple(fetch_names),
                flags.get_flag("amp_bf16"),
                flags.get_flag("amp_bf16_act"),
                flags.get_flag("bn_shifted_stats"),
-               flags.get_flag("compile_passes"),
                flags.get_flag("donation"))
         compiled = self._cache.get(key) if use_program_cache else None
         if compiled is not None:
@@ -913,28 +897,15 @@ class Executor:
             # layers down as an XLA trace error
             if flags.get_flag("verify_program"):
                 self._verify_program(program, fetch_names)
-            # FLAGS_compile_passes: rewrite a CLONE through the
-            # verified pass pipeline (dce/fold/cse/dve) before
-            # segmentation; the original program (and the cache
-            # key above) are untouched
-            program_to_compile = program
-            spec = flags.get_flag("compile_passes")
-            if spec:
-                from ..compile import passes as passes_mod
-
-                program_to_compile, _ = passes_mod.optimize_program(
-                    program, spec, fetches=list(fetch_names))
             # OOM pre-flight (FLAGS_mem_budget_gb): refuse a
             # program whose static peak busts the budget BEFORE
-            # any compile, on the program that will actually run
-            # (post-pass: auto_remat may have bought headroom).
-            # The MemoryBudgetError routes through the same OOM
-            # flight-bundle path a device RESOURCE_EXHAUSTED does.
+            # any compile.  The MemoryBudgetError routes through the
+            # same OOM flight-bundle path a device
+            # RESOURCE_EXHAUSTED does.
             budget = flags.get_flag("mem_budget_gb")
             if budget:
-                obs_mem.preflight(program_to_compile, fetch_names,
-                                  budget)
-            compiled = _CompiledProgram(self, program_to_compile, 0,
+                obs_mem.preflight(program, fetch_names, budget)
+            compiled = _CompiledProgram(self, program, 0,
                                         sorted(feed_env.keys()),
                                         fetch_names)
             if use_program_cache:

@@ -14,10 +14,7 @@ update); here an optimizer *declares* its update rule as data —
   * ``_hyper_attrs()`` — the op's hyperparameter attrs,
 
 and a single engine materialises the state variables and emits the ops.
-Declaring the rule (rather than open-coding op emission per class) is
-what lets ``fluid.fusion`` re-group the emitted ops into a few stacked
-``fused_update`` kernels: every op of one optimizer provably shares a
-recipe.  `minimize` = append_backward + clipping + regularization +
+`minimize` = append_backward + clipping + regularization +
 this pass; the whole train step then compiles into one XLA executable
 with parameter buffers donated for in-place update.
 """
@@ -25,7 +22,6 @@ with parameter buffers donated for in-place update.
 from collections import namedtuple
 
 from . import framework
-from . import fusion
 from .framework import unique_name, Variable
 from .backward import append_backward
 from .initializer import Constant
@@ -33,7 +29,6 @@ from .layer_helper import LayerHelper
 from .regularizer import append_regularization_ops
 from . import clip as clip_mod
 from ..obs import trace as obs_trace
-from ..utils import flags
 
 __all__ = ["SGD", "Momentum", "Adagrad", "Adam", "Adamax", "DecayedAdagrad",
            "Adadelta", "RMSProp", "Ftrl",
@@ -161,10 +156,9 @@ class Optimizer:
                                attrs=self._hyper_attrs())
 
     def create_optimization_pass(self, parameters_and_grads, loss,
-                                 startup_program=None, fuse_updates=None):
+                                 startup_program=None):
         """Materialise state and emit one update op per parameter
-        (reference entry point: optimizer.py:151), then optionally stack
-        same-recipe ops into fused_update ops."""
+        (reference entry point: optimizer.py:151)."""
         program = loss.block.program
         block = program.global_block()
         self._target_program = program
@@ -192,14 +186,10 @@ class Optimizer:
             tensor_layers.increment(self._global_step, value=1.0,
                                     in_place=True)
 
-        if fuse_updates is None:
-            fuse_updates = flags.get_flag("fuse_optimizer")
-        if fuse_updates:
-            update_ops = fusion.fuse_update_ops(block, update_ops)
         return update_ops
 
     def minimize(self, loss, startup_program=None, parameter_list=None,
-                 no_grad_set=None, fuse_updates=None):
+                 no_grad_set=None):
         """reference: optimizer.py:204."""
         with obs_trace.span("startup/program_optimize",
                             cat=obs_trace.STARTUP,
@@ -212,8 +202,7 @@ class Optimizer:
             params_grads = append_regularization_ops(params_grads,
                                                      self.regularization)
             optimize_ops = self.create_optimization_pass(
-                params_grads, loss, startup_program,
-                fuse_updates=fuse_updates)
+                params_grads, loss, startup_program)
             minimized.set(parameters=len(params_grads))
         return optimize_ops, params_grads
 

@@ -1,14 +1,13 @@
 """HBM memory observability: static liveness timeline vs XLA actuals,
 buffer-donation audit, OOM pre-flight/post-mortems.
 
-The repo *estimates* HBM in two places — the shard analyzer's S005
-per-device peaks and `auto_remat`'s accept gate — but until this
-module nothing ever checked those predictions against what XLA
-actually allocates.  Five layers close the loop:
+The repo *estimates* HBM in the shard analyzer's S005 per-device
+peaks, but until this module nothing ever checked those predictions
+against what XLA actually allocates.  Five layers close the loop:
 
   * **static timeline** — `program_timeline(program, fetches)` runs
     the ONE shared liveness walk (`analysis.dataflow
-    .liveness_timeline`, the same accounting S005 and auto_remat use)
+    .liveness_timeline`, the same accounting S005 uses)
     and returns the per-op live-activation-bytes series with the
     top-N buffers resident at the peak, each blamed to its defining
     op.  `render_timeline` draws it, `timeline_chrome_trace` exports
@@ -676,10 +675,6 @@ def oom_context(exc, program=None, fetches=None):
     if not is_oom(exc):
         return {}
     tl = getattr(exc, "timeline", None)
-    # the executor annotates a device OOM with the program that
-    # ACTUALLY ran (the post-pass rewrite) — prefer it over the
-    # caller's original so the blame table matches reality
-    program = getattr(exc, "_mem_program", None) or program
     if tl is None and program is not None:
         try:
             tl = program_timeline(program, fetches=fetches, top_n=8)

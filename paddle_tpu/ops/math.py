@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from .registry import register_op
 from .amp_util import mxu_operands, acc_kwargs, amp_result, amp_harmonize
 from ..core.ragged import RaggedTensor
-from ..core.types import FUSED_ELEMWISE_OP
 
 
 def _x(ins, slot="X"):
@@ -132,39 +131,6 @@ _ew("elementwise_div", lambda x, y: x / y)
 _ew("elementwise_max", jnp.maximum)
 _ew("elementwise_min", jnp.minimum)
 _ew("elementwise_pow", jnp.power)
-
-
-@register_op(FUSED_ELEMWISE_OP)
-def fused_elemwise_chain(ctx, ins, attrs):
-    """One op standing for a fused chain of elementwise/activation/
-    bias stages (built by fluid/fusion.py `fuse_elemwise_chains`, run
-    from the `fuse` rewrite pass).
-
-    The ``stages`` attr is a JSON list, in chain order:
-      {"op": <registered type>, "attrs": {...},
-       "in": "X"|"Y"            — the slot the chain value feeds,
-       "side": <SideIns index>} — the other operand of a binary stage.
-    Each stage applies the ORIGINAL registered kernel with the
-    original attrs, so per-lane numerics are identical to the unfused
-    op sequence by construction (same primitives, same order — the
-    bit-identity tests/test_opt_passes.py asserts)."""
-    import json as _json
-
-    from .registry import get_op_info
-
-    stages = _json.loads(attrs["stages"])
-    val = ins["X"][0]
-    side_vals = ins.get("SideIns", [])
-    for st in stages:
-        kernel = get_op_info(st["op"]).kernel
-        main_slot = st.get("in", "X")
-        sins = {main_slot: [val]}
-        side = st.get("side")
-        if side is not None:
-            other = "Y" if main_slot == "X" else "X"
-            sins[other] = [side_vals[side]]
-        val = kernel(ctx, sins, st.get("attrs") or {})["Out"][0]
-    return {"Out": [val]}
 
 
 @register_op("minus")

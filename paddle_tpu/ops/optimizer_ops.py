@@ -15,12 +15,16 @@ the aliased slots really name the same variable and that no concurrent
 reader races the in-place write.
 """
 
-import numpy as np
-
 import jax.numpy as jnp
 
-from .registry import register_op, get_op_info
+from .registry import register_op
 from ..core.ragged import SelectedRows
+
+# every op type that advances a parameter in place: the one list the
+# sharding rules, the sharding analyzer and the diagram read
+UPDATE_OPS = frozenset([
+    "sgd", "momentum", "adam", "adamax", "adagrad", "decayed_adagrad",
+    "adadelta", "rmsprop", "ftrl", "proximal_gd", "proximal_adagrad"])
 
 
 def _p(ins, slot):
@@ -204,42 +208,6 @@ def ftrl(ctx, ins, attrs):
                       jnp.zeros_like(p))
     return {"ParamOut": [p_out], "SquaredAccumOut": [new_accum],
             "LinearAccumOut": [lin_out]}
-
-
-@register_op("fused_update", stop_gradient_op=True,
-             in_place_outputs=("ParamOut",))
-def fused_update(ctx, ins, attrs):
-    """Stacked same-recipe update (fluid/fusion.py): concatenate the
-    flattened per-parameter tensors of each stacked slot, run the inner
-    recipe once, split back.  All recipes are elementwise per parameter,
-    so results are bit-identical to the unfused ops."""
-    inner = get_op_info(attrs["inner_type"]).kernel
-    stacked = set(attrs["stacked_slots"])
-    inner_attrs = {k: v for k, v in attrs.items()
-                   if k not in ("inner_type", "stacked_slots")}
-    n = len(ins["Param"])
-
-    if any(isinstance(g, SelectedRows) for g in ins["Grad"]):
-        # row-sparse grads index into their own parameter; apply the
-        # recipe per parameter (correct, just unstacked)
-        outs = {}
-        for i in range(n):
-            one = {k: ([v[i]] if k in stacked else v) for k, v in ins.items()}
-            for k, v in inner(ctx, one, inner_attrs).items():
-                outs.setdefault(k, []).append(v[0])
-        return outs
-
-    shapes = [p.shape for p in ins["Param"]]
-    split_at = np.cumsum([int(np.prod(s)) for s in shapes])[:-1]
-
-    def cat(vals):
-        return jnp.concatenate([jnp.ravel(v) for v in vals])
-
-    res = inner(ctx, {k: ([cat(v)] if k in stacked else v)
-                      for k, v in ins.items()}, inner_attrs)
-    return {k: [piece.reshape(s) for piece, s
-                in zip(jnp.split(v[0], split_at), shapes)]
-            for k, v in res.items()}
 
 
 @register_op("proximal_gd", stop_gradient_op=True,

@@ -12,6 +12,8 @@ import jax
 from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from ..ops.optimizer_ops import UPDATE_OPS
+
 __all__ = ["param_spec", "param_spec_reason", "batch_spec", "replicated",
            "shard_state", "shard_feeds", "zero1_spec",
            "zero1_spec_reason"]
@@ -108,13 +110,6 @@ _ACC_NAME = _re.compile(
     r"_(velocity|moment[12]?|inf_norm|avg_squared_grad|"
     r"avg_squared_update|mean_square|squared|linear)_\d+$")
 
-_OPTIMIZER_OPS = frozenset([
-    "sgd", "momentum", "adam", "adamax", "adagrad", "decayed_adagrad",
-    "adadelta", "rmsprop", "ftrl", "proximal_gd", "proximal_adagrad",
-    # stacked same-recipe updates (fluid/fusion.py) — same slot layout,
-    # so the Param/Grad/LearningRate exclusion below applies unchanged
-    "fused_update"])
-
 # optimizer-op input slots that are NOT accumulator state
 _NON_STATE_SLOTS = frozenset(["Param", "Grad", "LearningRate"])
 
@@ -127,7 +122,7 @@ def optimizer_state_names(program):
     names = set()
     for block in program.blocks:
         for op in block.ops:
-            if op.type not in _OPTIMIZER_OPS:
+            if op.type not in UPDATE_OPS:
                 continue
             for slot, vars_ in op.desc.inputs.items():
                 if slot not in _NON_STATE_SLOTS:

@@ -30,6 +30,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ..fluid.executor import ExecContext, apply_op, RNG_STATE_NAME
 from ..jit import FunctionalProgram
 from ..obs import trace as obs_trace
+from ..ops.optimizer_ops import UPDATE_OPS
 from ..parallel import sharding as psharding
 from ..parallel.ring import bucketed_allreduce
 
@@ -49,7 +50,7 @@ def _split_point(ops):
     split = None
     grads = set()
     for i, od in enumerate(ops):
-        if od.type in psharding._OPTIMIZER_OPS:
+        if od.type in UPDATE_OPS:
             if split is None:
                 split = i
             grads.update(n for n in od.input("Grad") if n)

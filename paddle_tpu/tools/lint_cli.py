@@ -78,12 +78,6 @@ def parse_args(argv=None):
     p.add_argument("--zero", type=int, default=0, metavar="STAGE",
                    help="ZeRO stage for the sharding analysis "
                         "(1 = dp-shard optimizer state)")
-    p.add_argument("--passes", default=None, metavar="SPEC",
-                   help="optimize each target through this rewrite "
-                        "pipeline (compile/passes.py spec, e.g. "
-                        "default+layout+fuse+auto_remat) BEFORE "
-                        "linting — proves a pass can never emit a "
-                        "program the linter would reject")
     p.add_argument("--donation", action="store_true",
                    help="also run the A0xx donation-safety analysis "
                         "(analysis/alias.py): per jit segment, which "
@@ -217,16 +211,6 @@ def lint_golden(args):
 
     results = []  # (name, report, sharding plan, donation plan)
     for name, desc in _golden_descs(args.golden):
-        if args.passes:
-            # lint the POST-PASS program: the optimized IR is what
-            # compiles, so it must satisfy the same linter contract as
-            # the pinned fixture (no fetch set here — passes needing
-            # one, dce/fuse, decline by contract)
-            from paddle_tpu.compile.passes import optimize_program
-
-            desc, _pm = optimize_program(desc, args.passes,
-                                         fetches=_split(args.fetch))
-            name = "%s [%s]" % (name, _pm.pipeline_id)
         report = analysis.check_program(
             desc, level=args.level, suppress=_split(args.suppress),
             origin="lint_golden")

@@ -4,15 +4,19 @@ TPU-native equivalent of the reference's gflags tiers (reference:
 paddle/utils/Flags.cpp:18-100 flag registry; python/paddle/v2/fluid/
 __init__.py:89-96 `init_gflags(--tryfromenv=...)` pulling FLAGS_* from
 the environment).  Flags registered here are read at runtime by the
-executor (check_nan_inf, memory benchmarking) and trainers.
+executor (check_nan_inf, the dtype and donation policies) and trainers.
 """
 
+import logging
 import os
 
 __all__ = ["DEFINE_flag", "get_flag", "set_flag", "parse_flags_from_env",
            "all_flags"]
 
 _FLAGS = {}
+_log = logging.getLogger("paddle_tpu")
+# FLAGS_* variables no flag answers to, each named once a process
+_unknown_told = set()
 
 
 def _coerce(value, default):
@@ -48,47 +52,40 @@ def all_flags():
 
 def parse_flags_from_env(names=None):
     """Read FLAGS_<name> env vars (reference: the __init__.py:89-96
-    `tryfromenv` bootstrap)."""
+    `tryfromenv` bootstrap).  A FLAGS_<x> variable with no flag <x>
+    (a typo, or a flag of another checkout) sets nothing and is named
+    in a warning, so that it does not pass for one that took effect."""
     for name in (names or list(_FLAGS)):
         env = os.environ.get("FLAGS_" + name)
         if env is not None:
             set_flag(name, env)
+    for var in sorted(os.environ):
+        name = var.removeprefix("FLAGS_")
+        if name != var and name not in _FLAGS \
+                and var not in _unknown_told:
+            _unknown_told.add(var)
+            _log.warning("%s is set in the environment and paddle_tpu "
+                         "defines no flag %r: it has no effect",
+                         var, name)
 
 
 # core flags (reference: executor.cc:28-31, Flags.cpp)
 DEFINE_flag("check_nan_inf", False,
             "scan every op output for NaN/Inf in eager mode "
             "(reference: executor.cc:29)")
-DEFINE_flag("do_memory_benchmark", False,
-            "log per-segment buffer sizes (reference: executor.cc:130)")
-DEFINE_flag("use_debug_nans", False,
-            "enable jax debug_nans for compiled segments")
 DEFINE_flag("amp_bf16", False,
             "cast MXU op operands (mul/matmul/conv) to bfloat16 with "
             "f32 accumulation (see fluid.amp)")
-DEFINE_flag("fuse_optimizer", False,
-            "stack same-recipe per-parameter update ops into fused_update "
-            "ops (fluid/fusion.py).  Default off: under XLA the whole "
-            "step is one executable with no per-op launch overhead, so "
-            "the CUDA-style motivation does not apply and the measured "
-            "TPU A/B (ResNet-50 b128: unfused 2171.9 vs size-capped "
-            "fused 2129.5 img/s) shows the stack's concat/split traffic "
-            "is a small net loss; the pass remains for pserver-sharding "
-            "experiments")
-DEFINE_flag("fuse_optimizer_max_numel", 1 << 18,
-            "only parameters this small (elements) join a fused_update "
-            "stack; launch overhead is dominated by the many tiny "
-            "tensors while concat/split HBM traffic is dominated by the "
-            "few big ones.  0 = stack everything")
 DEFINE_flag("bn_shifted_stats", False,
             "compute batch-norm statistics in the shifted one-pass form "
             "(cancellation-safe for pathological input scales, e.g. raw "
             "0-255 pixels into the first BN).  Default off: the "
             "per-channel shift subtract defeats XLA's multi-output "
-            "reduce fusion, costing a full-size pass per BN (measured "
-            "TPU A/B, ResNet-50 b128: plain 2471.1 vs shifted 2129.5 "
-            "img/s); the plain E[x^2]-E[x]^2 form accumulates in f32 "
-            "with a >=0 clamp, fine for normalized inputs")
+            "reduce fusion, costing a full-size pass per BN (a TPU A/B "
+            "of 2026-07-31, before the ledger, ResNet-50 b128: plain "
+            "2471.1 vs shifted 2129.5 img/s); the plain E[x^2]-E[x]^2 "
+            "form accumulates in f32 with a >=0 clamp, fine for "
+            "normalized inputs")
 DEFINE_flag("xla_cost_attribution", False,
             "capture per-segment XLA memory/cost analyses at jit-build "
             "time into xla_* registry gauges (obs/health.py).  Each "
@@ -130,20 +127,6 @@ DEFINE_flag("verify_sharding", False,
             "instead of surfacing minutes later as an XLA GSPMD "
             "error.  Default off: the multichip dryrun, tests, and "
             "proglint --mesh opt in explicitly")
-DEFINE_flag("compile_passes", "",
-            "Program-level IR rewrite pipeline applied by the "
-            "executor before compiling a program "
-            "(paddle_tpu.compile.passes): pass names joined by ',' "
-            "or '+' — the cleanup set (dce,fold,cse,dve; 'default') "
-            "plus the cost-model-guided opt passes "
-            "(layout/fuse/auto_remat, compile/opt_passes.py), with "
-            "knobs attached via ':' as in "
-            "'default+fuse:cap=8+auto_remat:stride=4'.  Every pass "
-            "is re-verified with the analysis verifier before and "
-            "after it runs, and the spec is part of the executor's "
-            "program-cache key, so compiled programs never alias "
-            "across pass configs.  Empty (the default) compiles "
-            "programs exactly as built")
 DEFINE_flag("donation", "auto",
             "jit-segment buffer donation policy (analysis/alias.py). "
             "'conservative' donates the executor's classic "

@@ -36,6 +36,7 @@ def program_to_dot(program, max_label=40):
     ops doubled), parameters as gray ellipses, data edges labeled by
     dtype/shape.  Accepts a fluid Program or a bare ProgramDesc."""
     from ..ops import registry as op_registry
+    from ..ops.optimizer_ops import UPDATE_OPS
 
     desc = getattr(program, "desc", program)
     out = ["digraph program {", "  rankdir=TB;",
@@ -58,8 +59,7 @@ def program_to_dot(program, max_label=40):
             style = ""
             if op_registry.is_grad_op_type(op.type):
                 style = ", style=dashed"
-            elif op.type in ("sgd", "momentum", "adam", "adagrad",
-                             "rmsprop", "fused_update"):
+            elif op.type in UPDATE_OPS:
                 style = ", peripheries=2"
             node = "b%d_op%d" % (block.idx, i)
             out.append('%s"%s" [label="%s"%s];'

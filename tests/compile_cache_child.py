@@ -36,9 +36,8 @@ def _hex(values):
 # programs
 # ---------------------------------------------------------------------------
 
-def _fit_a_line(batch=8, scale=1.0, dead_op=False):
-    """Linear regression with Adam; `scale` is one op attribute,
-    `dead_op` adds a branch no fetch reads."""
+def _fit_a_line(batch=8, scale=1.0):
+    """Linear regression with Adam; `scale` is one op attribute."""
     import paddle_tpu.fluid as fluid
 
     main, startup = fluid.Program(), fluid.Program()
@@ -47,8 +46,6 @@ def _fit_a_line(batch=8, scale=1.0, dead_op=False):
         y = fluid.layers.data(name="y", shape=[1], dtype="float32")
         pred = fluid.layers.fc(input=fluid.layers.scale(x=x, scale=scale),
                                size=1)
-        if dead_op:
-            fluid.layers.scale(x=pred, scale=7.0)
         loss = fluid.layers.mean(
             x=fluid.layers.square_error_cost(input=pred, label=y))
         fluid.optimizer.Adam(learning_rate=0.01).minimize(loss)
@@ -218,16 +215,16 @@ def supervisor(args):
 
 
 # ---------------------------------------------------------------------------
-# group "programs": five settings, each with a changed twin
+# group "programs": four settings, each with a changed twin
 # ---------------------------------------------------------------------------
 
-def _with_flag(name, value, **kw):
+def _with_flag(name, value):
     from paddle_tpu.utils import flags
 
     prev = flags.get_flag(name)
     flags.set_flag(name, value)
     try:
-        return {"fetches": _hex(_train(**kw))}
+        return {"fetches": _hex(_train())}
     finally:
         flags.set_flag(name, prev)
 
@@ -248,16 +245,10 @@ def donation_flag(args):
     return _with_flag("donation", "off" if args.changed else "auto")
 
 
-def passes_flag(args):
-    return _with_flag("compile_passes",
-                      "default" if args.changed else "", dead_op=True)
-
-
 GROUPS = {
     "restart": [executor_f32, executor_bf16, functional, spmd, engine,
                 supervisor],
-    "programs": [feed_shape, op_attribute, amp_flag, donation_flag,
-                 passes_flag],
+    "programs": [feed_shape, op_attribute, amp_flag, donation_flag],
     "tiny": [executor_f32],
 }
 

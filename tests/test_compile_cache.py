@@ -127,12 +127,12 @@ def test_resumed_run_loads_what_the_cold_process_left(restart):
 # ---------------------------------------------------------------------------
 
 PROGRAM_ENTRIES = ["feed_shape", "op_attribute", "amp_flag",
-                   "donation_flag", "passes_flag"]
+                   "donation_flag"]
 
 
 @pytest.fixture(scope="module")
 def programs(tmp_path_factory):
-    """Five settings of one training program fill a cache; a second
+    """Four settings of one training program fill a cache; a second
     process changes each setting against that cache; a third runs the
     changed settings with no cache at all, from a checkout of its own."""
     cache = tmp_path_factory.mktemp("programs_cache")
@@ -151,12 +151,9 @@ def test_warm_cache_never_serves_another_program(programs, entry):
     if entry in ("feed_shape", "op_attribute", "amp_flag"):
         assert changed[entry]["fetches"] != base[entry]["fetches"]
     else:
-        # donation is aliasing only, and the dead op feeds no fetch
+        # donation is aliasing only
         assert changed[entry]["fetches"] == base[entry]["fetches"]
-    if entry != "passes_flag":
-        # XLA drops the dead op itself: the lowered program may be
-        # the one the cache holds, and then a hit is right
-        assert changed[entry]["misses"] > 0, changed[entry]
+    assert changed[entry]["misses"] > 0, changed[entry]
 
 
 def test_off_means_no_disk(programs):

@@ -3,8 +3,11 @@ program lifetimes (id() can be reused after GC; reference executors
 key on the C++ ProgramDesc identity which has the same hazard)."""
 
 import gc
+import re
 
+import jax
 import numpy as np
+import pytest
 
 import paddle_tpu.fluid as fluid
 from paddle_tpu.fluid import framework
@@ -48,8 +51,6 @@ def test_no_stale_cache_hit_after_program_rebuild():
 def test_int64_feed_overflow_is_loud():
     """int64 feeds narrow to int32 (x64 off); out-of-range ids must
     raise instead of silently wrapping (embedding/beam id corruption)."""
-    import pytest
-
     x = fluid.layers.data(name="ids", shape=[1], dtype="int64")
     y = fluid.layers.cast(x=x, dtype="float32")
     exe = fluid.Executor(fluid.CPUPlace())
@@ -204,3 +205,163 @@ def test_attribution_numerics_match_plain_path():
         return np.concatenate(outs)
 
     np.testing.assert_array_equal(run(False), run(True))
+
+
+# ---------------------------------------------------------------------------
+# a Program is compiled as it was built: the plan's key, the ops a
+# segment applies, and what is left to XLA
+# ---------------------------------------------------------------------------
+
+# flags read while a segment is traced, each with a value that is not
+# its default: the plan of one setting must never serve another
+TRACE_TIME_FLAGS = {"amp_bf16": True, "amp_bf16_act": False,
+                    "bn_shifted_stats": True, "donation": "off"}
+
+
+@pytest.mark.parametrize("flag", sorted(TRACE_TIME_FLAGS))
+def test_trace_time_flag_keys_the_plan(flag):
+    """A flip builds a new plan; the flip back hits the old one."""
+    main, startup, cost = _tiny_train_program()
+    scope = fluid.Scope()
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(startup, scope=scope)
+    feed = {"x": np.ones((2, 4), np.float32)}
+
+    def step():
+        exe.run(main, feed=feed, fetch_list=[cost], scope=scope)
+        return next(reversed(exe._cache.values()))
+
+    before = flags.get_flag(flag)
+    try:
+        first = step()
+        plans = len(exe._cache)
+        assert step() is first and len(exe._cache) == plans
+        flags.set_flag(flag, TRACE_TIME_FLAGS[flag])
+        assert flags.get_flag(flag) != before
+        flipped = step()
+        assert flipped is not first and len(exe._cache) == plans + 1
+        flags.set_flag(flag, before)
+        assert step() is first and len(exe._cache) == plans + 1
+    finally:
+        flags.set_flag(flag, before)
+
+
+def test_executor_compiles_the_block_as_built(monkeypatch):
+    """The ops the jitted segment applies are block 0's own, in order,
+    and the Program the caller handed over is the one the plan holds,
+    as it was."""
+    from paddle_tpu.fluid import executor as executor_mod
+
+    main, startup, cost = _tiny_train_program()
+    scope = fluid.Scope()
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(startup, scope=scope)
+    built = [od.to_dict() for od in main.desc.block(0).ops]
+    version = main.version
+
+    applied = []
+    real_apply = executor_mod.apply_op
+    monkeypatch.setattr(executor_mod, "apply_op",
+                        lambda ctx, od: (applied.append(od),
+                                         real_apply(ctx, od))[1])
+    exe.run(main, feed={"x": np.ones((2, 4), np.float32)},
+            fetch_list=[cost], scope=scope)
+    ops = main.desc.block(0).ops
+    assert len(applied) == len(ops)
+    assert all(a is b for a, b in zip(applied, ops))
+    compiled = next(reversed(exe._cache.values()))
+    assert compiled.program is main
+    assert main.version == version
+    assert [od.to_dict() for od in ops] == built
+
+
+def _mul(x, w, name):
+    """`mul` as a bare op: X [batch, 8] by W [8, 8] into `name`."""
+    block = x.block
+    out = block.create_var(name=name, dtype="float32", shape=[-1, 8])
+    block.append_op(type="mul", inputs={"X": [x.name], "Y": [w.name]},
+                    outputs={"Out": [out.name]},
+                    attrs={"x_num_col_dims": 1, "y_num_col_dims": 1})
+    return out
+
+
+def _compiled_segment_text(build):
+    """The optimized HLO of the one jitted segment of the forward
+    program `build(x, w)` makes over `x` [4, 8] and `w` [8, 8], as the
+    CPU backend compiles it: the executor's own jitted function,
+    lowered for the arguments the executor called it with."""
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        x = fluid.layers.data(name="x", shape=[4, 8], dtype="float32",
+                              append_batch_size=False)
+        w = fluid.layers.create_parameter(shape=[8, 8], dtype="float32",
+                                          name="w")
+        fetch = build(x, w)
+    scope = fluid.Scope()
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(startup, scope=scope)
+    feed = {"x": np.ones((4, 8), np.float32)}
+    exe.run(main, feed=feed, fetch_list=[fetch], scope=scope)
+    compiled = next(reversed(exe._cache.values()))
+    assert list(compiled._jit_cache) == [0]
+    jitted = compiled._jit_cache[0]
+    fn, called_with = jitted["fn"], []
+
+    def spy(*args):
+        called_with.append(jax.tree_util.tree_map(
+            lambda v: jax.ShapeDtypeStruct(v.shape, v.dtype), args))
+        return fn(*args)
+
+    spy._cache_size = fn._cache_size
+    jitted["fn"] = spy
+    exe.run(main, feed=feed, fetch_list=[fetch], scope=scope)
+    text = fn.lower(*called_with[0]).compile().as_text()
+    # products the CPU backend hands to a library would hide from the
+    # count below
+    assert "custom-call" not in text, text
+    return text
+
+
+def _dots(text):
+    return len(re.findall(r"\bdot\(", text))
+
+
+def test_a_dead_product_is_not_compiled():
+    """What a dead-op pass would drop from the Program, XLA drops from
+    the executable: a `mul` no fetch reads leaves no `dot`."""
+    def build(x, w):
+        _mul(x, w, "dead")
+        return fluid.layers.scale(x=x, scale=2.0)
+
+    text = _compiled_segment_text(build)
+    assert _dots(text) == 0, text
+    assert "multiply(" in text
+
+
+def test_a_product_written_twice_is_compiled_once():
+    """Two `mul` ops over the same operands are one `dot`."""
+    def build(x, w):
+        return fluid.layers.elementwise_add(x=_mul(x, w, "a"),
+                                            y=_mul(x, w, "b"))
+
+    text = _compiled_segment_text(build)
+    assert _dots(text) == 1, text
+
+
+def test_a_static_shape_is_a_constant():
+    """`shape` of a variable whose dims are static is a constant of the
+    executable: nothing is computed at run time, the scaled input it
+    was taken from not even read."""
+    def build(x, w):
+        y = fluid.layers.scale(x=x, scale=2.0)
+        block = x.block
+        dims = block.create_var(name="dims", dtype="int32", shape=[2])
+        block.append_op(type="shape", inputs={"Input": [y.name]},
+                        outputs={"Out": [dims.name]}, infer_shape=False)
+        return dims
+
+    text = _compiled_segment_text(build)
+    entry = text[text.index("ENTRY"):]
+    assert "constant({4, 8})" in entry, text
+    assert "multiply(" not in text and "fusion(" not in entry, text
+    assert re.findall(r"parameter\((\d+)\)", entry) == ["0"], text

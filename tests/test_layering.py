@@ -26,7 +26,7 @@ ORDER = [
     "kernels", "ops",
     "resilience", "reader", "dataset",  # faults and retries; input
     "fluid", "jit",                     # Programs and how they run
-    "analysis", "compile",              # passes over a Program
+    "analysis",                         # checks over a Program
     "parallel", "models", "spmd", "distributed", "serving",
     "v2", "trainer_config_helpers",     # the source paper's API
     "capi_impl",
@@ -60,10 +60,9 @@ UP_EDGES = {
     ("ops/control_flow.py", "fluid"): "Design 16(d)",
     ("ops/sequence.py", "fluid"): "Design 16(d)",
     ("ops/attention.py", "parallel"): "Design 16(d)",
-    # fluid/: the executor's verify gate, donation plan and pass
-    # pipeline; the decoder that builds a model
+    # fluid/: the executor's verify gate and donation plan; the
+    # decoder that builds a model
     ("fluid/executor.py", "analysis"): "Design 16(e), with Design 4",
-    ("fluid/executor.py", "compile"): "Design 16(e), with Design 4",
     ("fluid/io.py", "analysis"): "Design 16(e)",
     ("fluid/memory_optimization_transpiler.py", "analysis"):
         "Design 16(e)",

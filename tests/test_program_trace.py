@@ -431,10 +431,6 @@ def test_an_op_without_outputs_still_has_an_instance():
         INSTANCE_SIGIL + "x"
     assert op_instance(OpDesc("barrier", {}, {})) == \
         INSTANCE_SIGIL + "barrier"
-    # a fused update names its first parameter
-    assert op_instance(OpDesc(
-        "fused_update", {"Param": ["w0", "w1"], "Grad": ["g0", "g1"]},
-        {"ParamOut": ["w0", "w1"]})) == INSTANCE_SIGIL + "w0"
 
 
 # -- the decoding layer: spans around a call, scopes inside it ------------------
