@@ -2,7 +2,7 @@
 block of T consecutive steps at once (pallas TPU kernels).
 
     mla_decode(q[B, H, latent + rope], cache[B, P, latent + rope],
-               position, sm_scale, latent) -> [B, H, latent]
+               position, sm_scale, latent[, sink=[H]]) -> [B, H, latent]
     mla_decode_block(q_lat[H, B * T, latent], q_rope[H, B * T, rope],
                      cache[B, P, latent + rope], position, sm_scale)
         -> [H, B * T, latent]
@@ -79,15 +79,42 @@ multiple of 128 (the slice of the values and the output's lanes), and
 either one query position a row or a multiple of 16.  The op asks and
 falls back to its plain path; a
 cache in a narrower type than the query's is read up by the caller
-first.  The chosen-set path of the op (`Selected`, DeepSeek-V3.2's
-sparse attention) does not come here: its two contractions run over
-2048 *gathered* entries, all live, at 63% of their roofline, and what
-costs there is the gather.  A kernel that reads the chosen slots where
-they lie does not take that away: PR 60 closed the question by
+first.
+
+A step over a chosen set (`Selected`, DeepSeek-V3.2's sparse attention;
+since PR 70).  The op gathers the 2048 chosen rows of a row's cache into
+[batch, top_k, latent + rope], the first `Live` of them live: that is a
+cache whose last live slot is `Live - 1`, and the step form reads it as
+it reads any other, `mla_decode(q, gathered, Live - 1, ...)`, the whole
+set one block a row where that fits (`choose_blocks(whole=True)`): no
+block of it is dead, and a fold is dearer than a grid step.  Before, two
+plain products read the gathered set behind a transposing copy of all of
+it (37.7 MB a layer at 16 rows, a second copy in the fast memory the
+gather had written: 0.29 ms a step of `dsv32-turn-16k-ep16`), which was
+what the compiler made of the default fill of the step's
+`take_along_axis`; the op's gather clips now, and with the clip alone
+the plain products read the gather's result as it lies too.  Measured
+on that cell the two readers are on a par (10.27 ms a step through this
+kernel, 10.25 through the plain products, 10.52 before: PERF.md section
+6, PR 70): the kernel's own part is that no float32 score array is made
+(`mla_scores` + `mla_values` 0.342 ms a step against 0.360).  What the
+kernel does not do is fetch the chosen rows itself: PR 60 closed that by
 measurement (ROADMAP Reach A8(a), Speed 3): the fetch is bound by the
 count of its copy descriptors, 10-13 ns each whoever starts them, and a
-kernel starts as many as the gather does.  A learned sink (`Sink`) keeps
-the op on its plain path too: the walk's softmax has no such term.
+kernel starts as many as the gather does.  The call states its cost
+(`pl.CostEstimate`: both products over the slots it is given, an
+exponential a score, the cache operand, the queries and the output
+once): a custom call is opaque to the compiler, whose memory-space
+assignment keeps every gathered copy in fast memory only where it knows
+what its reader costs (without the estimate one of that cell's five
+gathered copies a step lay in HBM in the compiled text).  A block of
+T > 1 positions with a set each stays with the op's plain products.
+
+A learned sink (`sink` float32 [heads]: one logit a head in the
+softmax's denominator and none in the sum) is one more term of the
+step's last fold, `l += exp(sink - m)` before `acc / l`; without one
+the kernel traces as it did.  The block form takes none: a block of
+positions with a sink keeps the op's plain path.
 
 Lowered for the TPU these are Mosaic kernels named
 `mla_decode_k<block_k>` and `mla_decode_k<block_k>_t<T>` (a trace tells
@@ -148,7 +175,8 @@ def _step_bytes(rows, heads, bk, width, latent, itemsize):
     return rows * (tiles + scratch) + fold
 
 
-def choose_blocks(batch, heads, positions, width, latent, itemsize):
+def choose_blocks(batch, heads, positions, width, latent, itemsize,
+                  whole=False):
     """(block_k, rows) from the shapes: the largest of 512, 256 and 128
     slots that tiles the cache, and of 4, 2 and 1 rows a step that tile
     the batch, that fit the VMEM budget together (slots before rows).
@@ -158,8 +186,17 @@ def choose_blocks(batch, heads, positions, width, latent, itemsize):
     over positions 128..1023 (scripts/mla_decode_bench.py; PERF.md
     section 6, PR 39): (512, 4) 0.48, (512, 2) 0.50, (512, 1) 0.57,
     (256, 4) 0.56, (256, 1) 0.69, (128, 4) 0.77, (128, 1) 1.12; eight
-    rows a step were 2-3% under four, not worth twice the VMEM."""
-    for bk in _BLOCKS:
+    rows a step were 2-3% under four, not worth twice the VMEM.
+
+    `whole`: the cache is a step's gathered set, live from end to end
+    but in a session's first `positions` steps, so no block is there to
+    be skipped and the whole set is tried first as one block a row:
+    every further fold of a row rescales its [heads, latent] float32
+    accumulator and reduces its scores' maximum and sum again.  ms a
+    call on the v5e over 2048 gathered slots, all live (my chip runs,
+    PR 70): 16 rows x 128 heads (2048, 1) 0.062, (1024, 2) 0.071,
+    (512, 4) 0.082; 8 rows x 64 heads 0.019, 0.023, 0.029."""
+    for bk in ((positions,) if whole else ()) + _BLOCKS:
         for rows in _ROWS:
             if positions % bk == 0 and batch % rows == 0 and _step_bytes(
                     rows, heads, bk, width, latent,
@@ -186,11 +223,14 @@ def _fold_in(s, values, m_scr, l_scr, acc_scr, at):
     m_scr[at] = m_new
 
 
-def _kernel(pos_ref, q_ref, c_ref, o_ref, m_scr, l_scr, acc_scr, *,
-            sm_scale, bk, rows, latent):
+def _kernel(pos_ref, q_ref, c_ref, *refs, sm_scale, bk, rows, latent):
     """One grid step: block `j - dead` of each of the step's rows folded
     into the row's running maximum `m`, sum `l` [heads, 1] and
-    accumulator [heads, latent]; nothing in a row's first `dead` steps."""
+    accumulator [heads, latent]; nothing in a row's first `dead` steps.
+    `refs`: a sink's logits [heads, 1] where the call has one, then the
+    output and the three scratch arrays."""
+    sink_ref = refs[0] if len(refs) == 5 else None
+    o_ref, m_scr, l_scr, acc_scr = refs[-4:]
     j = pl.program_id(1)
     pos = pos_ref[0]
     last = pos // bk
@@ -225,12 +265,17 @@ def _kernel(pos_ref, q_ref, c_ref, o_ref, m_scr, l_scr, acc_scr, *,
     def _crossed():
         for r in range(rows):
             fold(r, masked=True)
-        o_ref[...] = (acc_scr[...] / l_scr[...]).astype(o_ref.dtype)
+        total = l_scr[...]
+        if sink_ref is not None:
+            # one more term in the denominator and none in the sum
+            total = total + jnp.exp(sink_ref[...][None] - m_scr[...])
+        o_ref[...] = (acc_scr[...] / total).astype(o_ref.dtype)
 
 
-def _call(q, cache, position, *, sm_scale, latent, bk, rows, interpret):
+def _call(q, cache, position, *sink, sm_scale, latent, bk, rows, interpret):
     batch, heads, width = q.shape
-    steps = cache.shape[1] // bk
+    slots_held = cache.shape[1]
+    steps = slots_held // bk
 
     def slots(b, j, pos):
         # a row's dead steps name its first block, which the step before
@@ -243,11 +288,25 @@ def _call(q, cache, position, *, sm_scale, latent, bk, rows, interpret):
     return pl.pallas_call(
         functools.partial(_kernel, sm_scale=sm_scale, bk=bk, rows=rows,
                           latent=latent),
+        # what the call reads and computes over the slots it is given,
+        # for the compiler: a custom call's cost is opaque to it, and
+        # where the cache operand is a step's gathered set its
+        # memory-space assignment keeps every such copy in fast memory
+        # only where it knows what the one reader costs
+        # (kernels/gqa_decode.py `_chosen_call`; PERF.md section 6, PRs
+        # 66 and 70)
+        cost_estimate=pl.CostEstimate(
+            flops=2 * batch * heads * slots_held * (width + latent),
+            transcendentals=batch * heads * slots_held,
+            bytes_accessed=(cache.size + q.size + batch * heads * latent)
+            * q.dtype.itemsize),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(batch // rows, steps),
             in_specs=[pl.BlockSpec((rows, heads, width), row),
-                      pl.BlockSpec((rows, bk, width), slots)],
+                      pl.BlockSpec((rows, bk, width), slots)]
+            + [pl.BlockSpec((heads, 1), lambda b, j, pos: (0, 0))
+               for _ in sink],
             out_specs=pl.BlockSpec((rows, heads, latent), row),
             scratch_shapes=[pltpu.VMEM((rows, heads, 1), jnp.float32),
                             pltpu.VMEM((rows, heads, 1), jnp.float32),
@@ -259,14 +318,14 @@ def _call(q, cache, position, *, sm_scale, latent, bk, rows, interpret):
         interpret=interpret,
         # the trace shows which block ran; readers match the prefix
         name="mla_decode_k%d" % bk,
-    )(position, q, cache)
+    )(position, q, cache, *sink)
 
 
-def mla_decode(q, cache, position, sm_scale, latent, blocks=None):
+def mla_decode(q, cache, position, sm_scale, latent, blocks=None, sink=None):
     """The weighted sum of latents of one decode step, [batch, heads,
     latent] in q's type: see the module's docstring.  `blocks`
     (block_k, rows) are chosen from the shapes unless given (tests,
-    sweeps)."""
+    sweeps); `sink` float32 [heads] is a learned sink's logits."""
     batch, heads, width = q.shape
     if cache.shape[0] != batch or cache.shape[2] != width \
             or cache.dtype != q.dtype or not fits(1, cache.shape[1], latent):
@@ -278,8 +337,10 @@ def mla_decode(q, cache, position, sm_scale, latent, blocks=None):
                                        latent, q.dtype.itemsize)
     call = functools.partial(_call, sm_scale=float(sm_scale), latent=latent,
                              bk=bk, rows=rows)
+    sink = () if sink is None else (
+        jnp.reshape(sink, (heads, 1)).astype(jnp.float32),)
     return lax.platform_dependent(
-        q, cache, jnp.reshape(position, (1,)).astype(jnp.int32),
+        q, cache, jnp.reshape(position, (1,)).astype(jnp.int32), *sink,
         tpu=functools.partial(call, interpret=False),
         cpu=functools.partial(call, interpret=True))
 

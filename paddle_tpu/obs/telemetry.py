@@ -232,15 +232,17 @@ def on_mla_cached_attention_lowering(heads, latent, rope, cache_dtype,
 
 def on_mla_decode_lowering(path, block_k, positions=1):
     """Which way an `mla_cached_attention` op traced into a program
-    takes over its cache: "kernel", the walk of the live slots in blocks
-    of `block_k` (kernels/mla_decode.py), or "plain", the contractions
-    over the whole extent or a gathered set (`block_k` 0); and the
-    `positions` of a row it was lowered for (1: a decode step; more: a
-    block of a prompt's prefill).  One count per op instance a lowered
-    program holds."""
+    takes over its cache: "kernel", the walk of the live slots of the
+    whole extent in blocks of `block_k` (kernels/mla_decode.py);
+    "kernel_chosen", the same kernel over a step's gathered set;
+    or "plain", the contractions over the whole extent or a gathered
+    set (`block_k` 0); and the `positions` of a row it was lowered for
+    (1: a decode step; more: a block of a prompt's prefill).  One count
+    per op instance a lowered program holds."""
     _reg().counter("mla_decode_lowerings_total",
                    "latent-attention decode steps lowered, by path (the "
-                   "kernel over the live slots, or the plain products), "
+                   "kernel over the live slots of the whole extent or "
+                   "of a step's gathered set, or the plain products), "
                    "the kernel's block of slots and the positions of a "
                    "row the op took",
                    labelnames=("path", "block_k", "positions")) \

@@ -1070,10 +1070,19 @@ def mla_cached_attention_op(ctx, ins, attrs):
     With Sink float32 [heads] (a learned sink: one logit a head) the
     softmax's denominator holds exp(Sink_h) beside the attended slots'
     terms, and the sink has no value: p_h,t = exp(s_h,t) / (exp(Sink_h)
-    + sum_t' exp(s_h,t')), so a head may attend nothing much.  The sink
-    is the plain path's: the walk of the live slots does not take it.
-    A block over chosen sets takes it and a gate downstream as a step
-    does."""
+    + sum_t' exp(s_h,t')), so a head may attend nothing much.  A step's
+    walk takes it as one more term of its last fold; a block of
+    positions with a sink keeps the plain products.  A block over
+    chosen sets takes it and a gate downstream as a step does.
+
+    Which way the two contractions go (`mla_decode_lowerings_total`):
+    kernels/mla_decode.py where it takes the shape, a step over the
+    whole extent ("kernel"), a step over its gathered set, which is a
+    [batch, top_k, latent + rope] cache whose first Live entries are
+    live ("kernel_chosen": the gather's one reader, and no float32
+    score array is made), or a block of
+    positions over the whole extent without a sink ("kernel"); the
+    plain products otherwise ("plain")."""
     q_nope, q_rope = ins["QNope"][0], ins["QRope"][0]
     c_new, r_new = ins["CNew"][0], ins["RNew"][0]
     cache, w_uk, w_uv = ins["Cache"][0], ins["WUk"][0], ins["WUv"][0]
@@ -1115,18 +1124,27 @@ def mla_cached_attention_op(ctx, ins, attrs):
         "all" if selected is None else selected.shape[-1], block, tile)
     f32 = jnp.float32
     # the walk of the live slots (kernels/mla_decode.py) where what the
-    # op sees of its inputs fits it, the plain path otherwise
-    blocks = None
-    if selected is None and sink is None:
-        from ..kernels import mla_decode
-        if mla_decode.fits(block, positions, latent):
-            itemsize = jnp.dtype(dtype).itemsize
+    # op sees of its inputs fits it, the plain path otherwise: a step
+    # over the whole extent or over its gathered set (a [batch, top_k,
+    # width] cache whose first Live entries are live), with a sink or
+    # without; a block of positions over the whole extent without one
+    from ..kernels import mla_decode
+    blocks, path = None, "plain"
+    itemsize = jnp.dtype(dtype).itemsize
+    if block == 1:
+        slots = positions if selected is None else selected.shape[-1]
+        if mla_decode.fits(1, slots, latent):
             blocks = mla_decode.choose_blocks(
-                batch, heads, positions, width, latent, itemsize) \
-                if block == 1 else mla_decode.choose_group(
-                    heads, positions, rope_dim, latent, itemsize)
-    telemetry.on_mla_decode_lowering(
-        "kernel" if blocks else "plain", blocks[0] if blocks else 0, block)
+                batch, heads, slots, width, latent, itemsize,
+                whole=selected is not None)
+            path = "kernel" if selected is None else "kernel_chosen"
+    elif selected is None and sink is None \
+            and mla_decode.fits(block, positions, latent):
+        blocks = mla_decode.choose_group(
+            heads, positions, rope_dim, latent, itemsize)
+        path = "kernel"
+    telemetry.on_mla_decode_lowering(path, blocks[0] if blocks else 0,
+                                     block)
 
     entry = jnp.concatenate([c_new, r_new], axis=-1).reshape(batch, block,
                                                              width)
@@ -1142,9 +1160,15 @@ def mla_cached_attention_op(ctx, ins, attrs):
         live = cache.astype(dtype)
     else:
         with jax.named_scope("dsa_gather"):
+            # an entry is clipped into the extent, not filled in
+            # afterwards: the first Live name live slots, and what a
+            # dead one fetches is masked.  (The fill is no small thing:
+            # the compiler made of it a transposing copy of the whole
+            # gathered set in front of the reader, 0.29 ms a step of
+            # dsv32-turn-16k-ep16: PERF.md section 6, PR 70.)
             live = jnp.take_along_axis(
                 cache, selected[:, :, None].astype(jnp.int32),
-                axis=1).astype(dtype)
+                axis=1, mode="clip").astype(dtype)
 
     if blocks and block > 1:
         # a block through the kernel: the heads are the batch of both
@@ -1187,8 +1211,12 @@ def mla_cached_attention_op(ctx, ins, attrs):
             q = q.reshape(batch, block * heads, width)
     with jax.named_scope("mla_scores"):
         if blocks:
-            o_lat = mla_decode.mla_decode(q, live, pos, sm_scale, latent,
-                                          blocks)
+            # a gathered set's last live entry: a step's rows move in
+            # lockstep, Live is one count as Position is one slot
+            o_lat = mla_decode.mla_decode(
+                q, live, pos if selected is None
+                else jnp.reshape(ins["Live"][0], (-1,))[0] - 1, sm_scale,
+                latent, blocks, sink)
         else:
             s = jnp.einsum("bhw,btw->bht", q, live,
                            preferred_element_type=f32) * sm_scale

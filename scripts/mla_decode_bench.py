@@ -12,7 +12,16 @@ application of the cell, from
 an empty cache and inside a session, by block of slots and heads a
 grid step, with the FLOPs its products require (each position over its
 own live slots) and what the kernel multiplies (whole blocks of slots)
-beside them; `block_op` rows the whole op on such a block.  `--blocks` runs those two kinds alone."""
+beside them; `block_op` rows the whole op on such a block.  `--blocks` runs those two kinds alone.
+`chosen` (PR 70; `python scripts/mla_decode_bench.py chosen`, half a
+minute) runs `chosen` rows alone: the op over a chosen set of 2048 slots,
+all live, at `dsv32-turn-16k-ep16`'s shape (16 rows x 128 heads over a
+16,384-slot cache) and at `hy4-turn-32k-ep16`'s (8 rows x 64 heads over
+32,768 slots, a sink a head), the cache carried from call to call and a
+slot written a call as a decoder's scan carries it: the gather and the
+kernel that reads its rows (`kernel`: the op as it is) beside the gather
+and the plain products (`plain`: `mla_decode.fits` made to refuse, the
+op has no switch), ms a layer and ns a gathered row."""
 
 import json
 import os
@@ -146,10 +155,72 @@ def block_rows(emit, draw, cache):
               "ms": _slope(jax.jit(steps), ins, cache)})
 
 
+# (cell, rows, heads, slots, nope, value, a sink): the two chooser cells
+# whose steps attend 2048 chosen latents
+CHOSEN = (("dsv32-turn-16k-ep16", 16, 128, 16384, 128, 128, False),
+          ("hy4-turn-32k-ep16", 8, 64, 32768, 192, 256, True))
+TOP_K, CHOSEN_START = 2048, 15488
+
+
+def chosen_rows(emit, draw, rs):
+    """The op's chosen-set step, the gather and what reads it, as a
+    decoder's scan carries them."""
+    kernel = registry.get_op_info("mla_cached_attention").kernel
+    takes = mla_decode.fits
+    for cell, rows, heads, slots, nope, value, sink in CHOSEN:
+        cache = draw(rows, slots, LATENT + ROPE)
+        ins = {"QNope": [draw(rows, 1, heads * nope, std=0.5)],
+               "QRope": [draw(rows, 1, heads * ROPE, std=0.5)],
+               "CNew": [draw(rows, 1, LATENT)], "RNew": [draw(rows, 1, ROPE)],
+               "WUk": [draw(LATENT, heads * nope, std=0.05)],
+               "WUv": [draw(LATENT, heads * value, std=0.05)],
+               "Selected": [jnp.asarray(np.stack(
+                   [np.sort(rs.choice(CHOSEN_START, TOP_K, replace=False))
+                    for _ in range(rows)]), jnp.int32)],
+               "Live": [jnp.full((rows,), TOP_K, jnp.int32)]}
+        if sink:
+            ins["Sink"] = [jnp.asarray(rs.randn(heads), jnp.float32)]
+
+        got = {}
+        for variant in ("kernel", "plain"):
+            # traced anew a variant: jit's cache is keyed by the function
+            def attend(ins, cache, at):
+                return kernel(None, dict(
+                    ins, Cache=[cache],
+                    Position=[jnp.full((rows,), at, jnp.int32)]),
+                    {"num_heads": heads})
+
+            def steps(n, ins, cache):
+                def body(i, carry):
+                    cache, seen = carry
+                    outs = attend(ins, cache, CHOSEN_START + i)
+                    return outs["CacheOut"][0], \
+                        seen + outs["Out"][0][0, 0, 0].astype(jnp.float32)
+                return lax.fori_loop(0, n, body, (cache, jnp.float32(0)))
+
+            mla_decode.fits = takes if variant == "kernel" \
+                else lambda *shape: False
+            try:
+                ms = _slope(jax.jit(steps), ins, cache)
+                got[variant] = jax.jit(attend)(ins, cache, CHOSEN_START)[
+                    "Out"][0].astype(jnp.float32)
+            finally:
+                mla_decode.fits = takes
+            emit({"kind": "chosen", "cell": cell, "variant": variant,
+                  "rows": rows, "heads": heads, "slots": slots,
+                  "top_k": TOP_K, "sink": sink, "ms_a_layer": ms,
+                  "ns_a_gathered_row": ms * 1e6 / (rows * TOP_K)})
+        emit({"kind": "chosen", "cell": cell, "max_diff": float(jnp.max(
+            jnp.abs(got["kernel"] - got["plain"]))),
+            "max_abs": float(jnp.max(jnp.abs(got["plain"])))})
+
+
 def main():
     assert jax.devices()[0].platform == "tpu", jax.devices()
     os.makedirs("chiprun_out", exist_ok=True)
-    out = open("chiprun_out/mla_decode_bench.jsonl", "w")
+    only_chosen = "chosen" in sys.argv[1:]
+    out = open("chiprun_out/mla_decode_bench%s.jsonl"
+               % ("_chosen" if only_chosen else ""), "w")
 
     def emit(row):
         line = json.dumps(row)
@@ -162,6 +233,8 @@ def main():
     def draw(*shape, std=1.0):
         return jnp.asarray(rs.randn(*shape) * std, jnp.bfloat16)
 
+    if only_chosen:
+        return chosen_rows(emit, draw, rs)
     q = draw(ROWS, HEADS, LATENT + ROPE, std=0.5)
     cache = draw(ROWS, SLOTS, LATENT + ROPE)
     block_rows(emit, draw, cache)

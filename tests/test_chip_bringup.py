@@ -291,8 +291,9 @@ def test_the_latent_decode_kernel_lowers_for_tpu():
     """`mla_cached_attention` at pangu-decode-ep16's shapes (256 rows,
     128 heads, a 1024-slot bfloat16 cache of 512 + 64 values) lowered
     for the TPU from this CPU host: one Mosaic kernel, named with the
-    block of slots chosen from the shapes; with a chosen set the same
-    op holds none."""
+    block of slots chosen from the shapes; with a chosen set of 256 the
+    same kernel over the gathered rows, in one block of 256 (PR 70),
+    and the plain products where the set is no multiple of 128."""
     from paddle_tpu.ops import registry
 
     kernel = registry.get_op_info("mla_cached_attention").kernel
@@ -314,10 +315,14 @@ def test_the_latent_decode_kernel_lowers_for_tpu():
         ins).mlir_module()
     assert module.count("tpu_custom_call") == 1
     assert 'kernel_name = "mla_decode_k512"' in module
-    chosen = dict(ins, Selected=[jax.ShapeDtypeStruct((b, 256), jnp.int32)],
-                  Live=[jax.ShapeDtypeStruct((b,), jnp.int32)])
-    assert "tpu_custom_call" not in jax.export.export(
-        jax.jit(step), platforms=["tpu"])(chosen).mlir_module()
+    for top_k, name in ((256, "mla_decode_k256"), (200, None)):
+        chosen = dict(
+            ins, Selected=[jax.ShapeDtypeStruct((b, top_k), jnp.int32)],
+            Live=[jax.ShapeDtypeStruct((b,), jnp.int32)])
+        module = jax.export.export(
+            jax.jit(step), platforms=["tpu"])(chosen).mlir_module()
+        assert module.count("tpu_custom_call") == (name is not None)
+        assert name is None or 'kernel_name = "%s"' % name in module
     # a prefill application of the cell: 16 positions a row, one kernel
     # named with them
     block = {k: [jax.ShapeDtypeStruct((b, 16) + v[0].shape[2:], bf16)]
@@ -605,14 +610,16 @@ def test_a_block_of_the_choosers_positions_lowers_for_tpu(rows, block, slots,
     assert "tensor<%dx%dxf32>" % (rows * block, slots) in module
 
 
-def test_the_sink_and_the_streams_lower_for_tpu_without_a_kernel():
+def test_the_sink_and_the_streams_lower_for_tpu():
     """hy4-turn-32k-ep16's step at its shapes (8 rows, 64 heads of 192 +
     64 over 512 latents, 2048 chosen of 32,768 bfloat16 slots, a sink a
     head; four streams of 6144) lowered for the TPU from this CPU host:
-    the chosen-set path with `Sink` is plain products around one gather,
-    and the hyper-connection's three ops are plain float32 arithmetic
-    under the scope `hyper_connection`, twenty Sinkhorn iterations
-    unrolled: no Mosaic kernel in either."""
+    the chosen-set step with `Sink` is one gather read by one Mosaic
+    kernel, the walk that takes the sink (PR 70; no float32 score of
+    the 2048 entries is in the module), and the hyper-connection's
+    three ops are plain float32 arithmetic under the scope
+    `hyper_connection`, twenty Sinkhorn iterations unrolled, without a
+    kernel."""
     from paddle_tpu.ops import registry
 
     b, h, bf16, f32 = 8, 64, jnp.bfloat16, jnp.float32
@@ -631,9 +638,11 @@ def test_the_sink_and_the_streams_lower_for_tpu_without_a_kernel():
     module = jax.export.export(
         jax.jit(lambda ins: attend(None, ins, {"num_heads": h})),
         platforms=["tpu"])(ins).mlir_module()
-    assert "tpu_custom_call" not in module
+    assert module.count("tpu_custom_call") == 1
+    assert 'kernel_name = "mla_decode_k2048"' in module
     assert module.count('"stablehlo.gather"') == 1
     assert "tensor<8x2048x576xbf16>" in module
+    assert "tensor<8x64x2048xf32>" not in module
 
     maps = registry.get_op_info("hc_maps").kernel
     pre = registry.get_op_info("hc_pre").kernel

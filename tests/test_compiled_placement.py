@@ -1,0 +1,144 @@
+"""What the TPU's compiler makes of a decode step's chosen-set attention,
+read from the text it compiles for a described v5e (no chip: the
+`on-chip-measurement` guide, section 2; the topology is described inside
+a fixture, and this is the one file that does so): `mla_cached_attention`
+with `Selected` at one position a row, one layer in a scan that carries
+the cache as a decoder's does, at the two chooser cells' shapes.
+
+What PR 70 bought and this guards at no chip time: the gather of the
+chosen latents (`dsa_gather`, a custom fusion) writes fast memory
+(`S(1)`), and its one reader is the Pallas call `mla_decode_k2048`, which
+takes the rows as the gather leaves them: no transposing `copy` of the
+[batch, 2048, 576] set, no fill pass behind the gather and no float32
+score array stand between (before, the step's gather filled in behind
+itself, `take_along_axis`'s default, and the compiler made of that a
+turn of the 37.7 MB set, `{2,1,0}` -> `{1,2,0}`, in front of two plain
+products: a second copy in fast memory, 0.29 ms a step of
+`dsv32-turn-16k-ep16`; the op's gather clips now).  A one-layer scan
+places as `dsv32-turn-16k-ep16`'s and `hy4-turn-32k-ep16`'s whole
+generation calls do (PERF.md section 6, PR 70, step (a))."""
+
+import os
+import re
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+TOP_K, LATENT, ROPE = 2048, 512, 64
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache
+    and cannot be read back without one: off around it."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compiled_scan(chip, rows, heads, slots, nope, value, sink):
+    """The text of 64 chosen-set steps of one layer, the cache carried."""
+    from paddle_tpu.ops import registry
+
+    def of(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    ins = {"QNope": [of((rows, 1, heads * nope))],
+           "QRope": [of((rows, 1, heads * ROPE))],
+           "CNew": [of((rows, 1, LATENT))], "RNew": [of((rows, 1, ROPE))],
+           "WUk": [of((LATENT, heads * nope))],
+           "WUv": [of((LATENT, heads * value))],
+           "Selected": [of((rows, TOP_K), jnp.int32)],
+           "Live": [of((rows,), jnp.int32)]}
+    if sink:
+        ins["Sink"] = [of((heads,), jnp.float32)]
+    kernel = registry.get_op_info("mla_cached_attention").kernel
+
+    def steps(ins, cache):
+        def body(carry, i):
+            cache, seen = carry
+            outs = kernel(None, dict(
+                ins, Cache=[cache],
+                Position=[jnp.full((rows,), slots - 1024 + i, jnp.int32)]),
+                {"num_heads": heads})
+            return (outs["CacheOut"][0],
+                    seen + outs["Out"][0].astype(jnp.float32)), None
+        return lax.scan(
+            body, (cache, jnp.zeros((rows, 1, heads * value), jnp.float32)),
+            jnp.arange(64))[0]
+
+    return jax.jit(steps).lower(
+        ins, of((rows, slots, LATENT + ROPE))).compile().as_text()
+
+
+_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?%([\w.\-]+) = (\S+) ([\w\-]+)\((.*?)\)(?:, (.*))?$")
+
+
+def _instructions(text):
+    """{name: (type, opcode, operand names, the rest of the line)}."""
+    found = {}
+    for line in text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if m:
+            name, kind, op, operands, rest = m.groups()
+            found[name] = (kind, op, re.findall(r"%([\w.\-]+)", operands),
+                           rest or "")
+    return found
+
+
+@pytest.mark.parametrize("cell,rows,heads,slots,nope,value,sink", [
+    ("dsv32-turn-16k-ep16", 16, 128, 16384, 128, 128, False),
+    ("hy4-turn-32k-ep16", 8, 64, 32768, 192, 256, True)])
+def test_nothing_stands_between_a_steps_gather_and_its_reader(
+        one_chip, no_compile_cache, cell, rows, heads, slots, nope, value,
+        sink):
+    text = _compiled_scan(one_chip, rows, heads, slots, nope, value, sink)
+    found = _instructions(text)
+    readers = [n for n, (_, op, _, rest) in found.items()
+               if op == "custom-call" and "mla_decode_k2048" in rest]
+    assert len(readers) == 1, cell
+    # the kernel's operands: the position, the queries, the cache, a sink
+    operands = found[readers[0]][2]
+    assert len(operands) == 3 + sink
+    at, through = operands[2], []
+    while found[at][1] == "bitcast":
+        through.append(at)
+        at = found[at][2][0]
+    kind, op, _, rest = found[at]
+    gathered = "bf16[%d,%d]" % (rows * TOP_K, LATENT + ROPE)
+    assert op == "fusion" and "kind=kCustom" in rest \
+        and "dsa_gather" in rest and kind.startswith(gathered), (cell, at)
+    # fast memory, the gather's result and the kernel's own
+    assert "S(1)" in kind and "S(1)" in found[through[0]][0], cell
+    assert "S(1)" in found[readers[0]][0], cell
+    # and no other instruction makes an array of the gathered set's
+    # size: no turn, no fill pass, no second copy (inside the gather's
+    # own fusion its steps carry no memory space of their own)
+    whole = "bf16[%d,%d,%d]" % (rows, TOP_K, LATENT + ROPE)
+    others = [n for n, (kind, op, _, _) in found.items()
+              if kind.startswith((whole, gathered)) and n != at
+              and op not in ("bitcast", "parameter", "gather", "transpose",
+                             "reshape")]
+    assert not others, (cell, others)
+    assert "f32[%d,%d,%d]" % (rows, heads, TOP_K) not in text, cell

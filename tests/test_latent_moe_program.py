@@ -287,15 +287,21 @@ def _walk_ins(rs, dtype, pos, past=0.0):
             "Position": [jnp.full((WB,), pos, jnp.int32)]}
 
 
-def _mla_paths(ins):
+def _decode_paths(delta):
+    """{path: count} of `mla_decode_lowerings_total` in a snapshot's
+    delta."""
+    return {k[len("mla_decode_lowerings_total"):]: v
+            for k, v in delta.items()
+            if k.startswith("mla_decode_lowerings_total")}
+
+
+def _mla_paths(ins, heads=H):
     """{path: count} of `mla_decode_lowerings_total` and the op's outputs
     for one trace of the op over `ins`."""
     before = telemetry.snapshot()
     outs = registry.get_op_info("mla_cached_attention").kernel(
-        None, ins, {"num_heads": H})
-    return {k[len("mla_decode_lowerings_total"):]: v
-            for k, v in telemetry.snapshot_delta(before).items()
-            if k.startswith("mla_decode_lowerings_total")}, outs
+        None, ins, {"num_heads": heads})
+    return _decode_paths(telemetry.snapshot_delta(before)), outs
 
 
 @pytest.mark.parametrize("dtype,atol", [(jnp.float32, 2e-5),
@@ -350,7 +356,7 @@ def test_the_walk_is_attention_over_the_heads_keys():
 
 
 @pytest.mark.parametrize("why,change", [
-    ("a chosen set", {"Selected": [jnp.zeros((WB, 4), jnp.int32)],
+    ("a chosen set of 4", {"Selected": [jnp.zeros((WB, 4), jnp.int32)],
                       "Live": [jnp.full((WB,), 4, jnp.int32)]}),
     ("6 positions", {"Cache": [jnp.zeros((WB, 6, WL + WR))],
                      "Position": [jnp.full((WB,), 3, jnp.int32)]}),
@@ -406,12 +412,210 @@ def test_the_walks_blocks_are_chosen_from_the_shapes():
     assert mla_decode.choose_blocks(6, 128, 1024, 576, 512, 2) == (512, 2)
     assert mla_decode.choose_blocks(WB, 4, WP, WL + WR, WL, 4) == (WBK, 2)
     assert mla_decode.choose_blocks(3, 4, WP, WL + WR, WL, 4) == (WBK, 1)
+    # a gathered set is live from end to end: one block a row where it
+    # fits (the two chooser cells' 2048 of 576 values, 128 and 64 heads)
+    for rows, heads in ((16, 128), (8, 64)):
+        assert mla_decode.choose_blocks(
+            rows, heads, 2048, 576, 512, 2, whole=True) == (2048, 1)
+        assert mla_decode.choose_blocks(
+            rows, heads, 2048, 576, 512, 2) == (512, 4)
+    assert mla_decode.choose_blocks(
+        16, 128, 8192, 576, 512, 2, whole=True) == (512, 4)
     assert mla_decode.fits(1, 1024, 512) and mla_decode.fits(1, 128, 128)
     assert not mla_decode.fits(1, 1000, 512)
     assert not mla_decode.fits(1, 1024, 320)
     with pytest.raises(ValueError, match="no step the kernel takes"):
         mla_decode.mla_decode(jnp.zeros((2, 4, 24)), jnp.zeros((2, 6, 24)),
                               0, 1.0, 16)
+
+
+# -- a step over its gathered set through the same kernel (PR 70) --------------
+# 2 rows choose 384 of 512 slots: the gathered rows are a cache whose first
+# Live entries are live, walked as one block a row where that fits the
+# kernel's VMEM budget and in three blocks of 128 where it does not
+
+CP, CK = 512, 384
+
+
+def _chosen_ins(rs, heads, live, sink, dtype=jnp.float32, top_k=CK):
+    """The op's inputs for a step over a chosen set of `top_k` of CP
+    slots a row, `live` of them live; slot CP - 1 is this step's."""
+    def draw(*shape):
+        return jnp.asarray(rs.randn(*shape), dtype)
+
+    ins = {"QNope": [draw(WB, 1, heads * NOPE)],
+           "QRope": [draw(WB, 1, heads * WR)],
+           "CNew": [draw(WB, 1, WL)], "RNew": [draw(WB, 1, WR)],
+           "Cache": [draw(WB, CP, WL + WR)],
+           "WUk": [0.1 * draw(WL, heads * NOPE)],
+           "WUv": [0.1 * draw(WL, heads * DV)],
+           "Position": [jnp.full((WB,), CP - 1, jnp.int32)],
+           "Selected": [jnp.asarray(np.stack(
+               [np.sort(rs.choice(CP, top_k, replace=False))
+                for _ in range(WB)]), jnp.int32)],
+           "Live": [jnp.full((WB,), live, jnp.int32)]}
+    if sink:
+        ins["Sink"] = [jnp.asarray(rs.randn(heads) + 2.0, jnp.float32)]
+    return ins
+
+
+def _in_blocks_of_128(monkeypatch, heads):
+    """The kernel's VMEM budget held to what a block of 128 slots takes:
+    the set then fits as no one block (the op has no switch)."""
+    from paddle_tpu.kernels import mla_decode
+
+    monkeypatch.setattr(mla_decode, "_VMEM_BUDGET", mla_decode._step_bytes(
+        1, heads, WBK, WL + WR, WL, 4))
+
+
+def _attention_over_the_chosen(ins, heads):
+    """Attention over the first Live of the chosen slots' keys [c W_uk |
+    r] and values c W_uv made whole, a sink's term in the denominator,
+    in float64."""
+    f = lambda name: np.asarray(ins[name][0], np.float64)
+    live = int(ins["Live"][0][0])
+    cache = f("Cache")
+    cache[:, CP - 1] = np.concatenate([f("CNew"), f("RNew")], -1)[:, 0]
+    rows = np.take_along_axis(
+        cache, np.asarray(ins["Selected"][0])[:, :live, None], axis=1)
+    c, r = rows[..., :WL], rows[..., WL:]
+    k = np.concatenate(
+        [(c @ f("WUk")).reshape(WB, live, heads, NOPE),
+         np.broadcast_to(r[:, :, None], (WB, live, heads, WR))], -1)
+    v = (c @ f("WUv")).reshape(WB, live, heads, DV)
+    q = np.concatenate([f("QNope").reshape(WB, heads, NOPE),
+                        f("QRope").reshape(WB, heads, WR)], -1)
+    s = np.einsum("bhd,bthd->bht", q, k) / np.sqrt(NOPE + WR)
+    top = s.max(-1, keepdims=True)
+    e = np.exp(s - top)
+    total = e.sum(-1, keepdims=True)
+    if "Sink" in ins:
+        total = total + np.exp(f("Sink")[None, :, None] - top)
+    return np.einsum("bht,bthd->bhd", e / total, v).reshape(WB, 1, -1)
+
+
+@pytest.mark.parametrize("heads", [128, 64])
+@pytest.mark.parametrize("sink", [False, True], ids=["no_sink", "sink"])
+@pytest.mark.parametrize("live", [CK, 5, CK - 7],
+                         ids=["all", "first_block", "last_block"])
+@pytest.mark.parametrize("block_k", [CK, WBK], ids=["whole", "blocks"])
+def test_a_steps_gathered_set_goes_through_the_kernel(block_k, live, sink,
+                                                      heads, monkeypatch):
+    """The kernel over the gathered rows against the op's plain products
+    on the same inputs and against attention made whole, in float32:
+    every entry live, the count inside the first 128 entries and inside
+    the last, with a learned sink and without, at dsv32's 128 heads and
+    hy4's 64; the set as one block a row, as the op chooses where it
+    fits the kernel's VMEM budget, and in three blocks of 128 where the
+    test says it does not."""
+    from paddle_tpu.kernels import mla_decode
+
+    if block_k != CK:
+        _in_blocks_of_128(monkeypatch, heads)
+    ins = _chosen_ins(np.random.RandomState(live + heads), heads, live,
+                      sink)
+    paths, walked = _mla_paths(ins, heads)
+    assert paths == {"{block_k=%d,path=kernel_chosen,positions=1}"
+                     % block_k: 1}
+    monkeypatch.setattr(mla_decode, "fits", lambda *shape: False)
+    paths, plain = _mla_paths(ins, heads)
+    assert paths == {"{block_k=0,path=plain,positions=1}": 1}
+    np.testing.assert_allclose(walked["Out"][0], plain["Out"][0], atol=2e-5)
+    np.testing.assert_allclose(
+        walked["Out"][0], _attention_over_the_chosen(ins, heads), atol=5e-5)
+    np.testing.assert_array_equal(walked["CacheOut"][0],
+                                  plain["CacheOut"][0])
+
+
+@pytest.mark.parametrize("sink", [False, True], ids=["no_sink", "sink"])
+def test_a_gathered_set_in_bfloat16_rounds_as_the_plain_products(
+        sink, monkeypatch):
+    from paddle_tpu.kernels import mla_decode
+
+    ins = _chosen_ins(np.random.RandomState(3), H, CK - 40, sink,
+                      jnp.bfloat16)
+    paths, walked = _mla_paths(ins, H)
+    assert list(paths) == ["{block_k=%d,path=kernel_chosen,positions=1}"
+                           % CK]
+    monkeypatch.setattr(mla_decode, "fits", lambda *shape: False)
+    plain = _mla_paths(ins, H)[1]
+    assert walked["Out"][0].dtype == plain["Out"][0].dtype == jnp.bfloat16
+    np.testing.assert_allclose(
+        np.asarray(walked["Out"][0], np.float32),
+        np.asarray(plain["Out"][0], np.float32), atol=6e-2)
+
+
+@pytest.mark.parametrize("live", [1, WBK, WBK + 9])
+@pytest.mark.parametrize("block_k", [CK, WBK], ids=["whole", "blocks"])
+def test_a_dead_entry_of_a_gathered_set_reaches_no_sum(block_k, live,
+                                                       monkeypatch):
+    """Past the first Live entries a set names slots that hold nothing:
+    NaN there, in the block the count falls in and in a whole dead block
+    alike, reaches no sum, and an entry past the extent is clipped into
+    it, not filled in."""
+    if block_k != CK:
+        _in_blocks_of_128(monkeypatch, H)
+    ins = _chosen_ins(np.random.RandomState(live), H, live, sink=True)
+    chosen = np.asarray(ins["Selected"][0])
+    dead = np.zeros((WB, CP), bool)
+    np.put_along_axis(dead, chosen[:, live:], True, axis=1)
+    np.put_along_axis(dead, chosen[:, :live], False, axis=1)
+    dead[:, CP - 1] = False     # this step's slot is written, whatever
+    dirty = dict(
+        ins, Cache=[jnp.where(dead[:, :, None], jnp.nan, ins["Cache"][0])],
+        Selected=[ins["Selected"][0].at[:, live:].add(
+            jnp.where(jnp.arange(CK - live) % 2 == 0, 4 * CP, 0))])
+    paths, got = _mla_paths(dirty, H)
+    assert list(paths) == ["{block_k=%d,path=kernel_chosen,positions=1}"
+                           % block_k]
+    np.testing.assert_array_equal(got["Out"][0],
+                                  _mla_paths(ins, H)[1]["Out"][0])
+
+
+@pytest.mark.parametrize("why,top_k,path", [
+    ("a set of 384", CK, "{block_k=%d,path=kernel_chosen,positions=1}"
+     % CK),
+    ("a set of 256", 256, "{block_k=256,path=kernel_chosen,positions=1}"),
+    ("no multiple of 128", 200, "{block_k=0,path=plain,positions=1}"),
+])
+def test_the_counter_tells_a_chosen_step_through_the_kernel(why, top_k,
+                                                            path):
+    """`mla_decode_lowerings_total` by `path`: a chosen-set step through
+    the kernel is "kernel_chosen" (its block the whole set, where that
+    fits), one the kernel's `fits` refuses "plain", a whole-extent walk
+    "kernel" (in blocks of 512 at most: a dead block is skipped); the op
+    asks the shapes and has no switch."""
+    ins = _chosen_ins(np.random.RandomState(8), H, top_k, False,
+                      top_k=top_k)
+    kernel = registry.get_op_info("mla_cached_attention").kernel
+    before = telemetry.snapshot()
+    jaxpr = jax.make_jaxpr(
+        lambda i: kernel(None, i, {"num_heads": H})["Out"][0])(ins)
+    assert _decode_paths(telemetry.snapshot_delta(before)) == {path: 1}, why
+    assert ("pallas_call" in str(jaxpr)) == ("kernel" in path)
+    whole = {k: v for k, v in ins.items() if k not in ("Selected", "Live")}
+    assert list(_mla_paths(whole, H)[0]) == [
+        "{block_k=%d,path=kernel,positions=1}" % CP]
+
+
+@pytest.mark.parametrize("pos", [3, WBK, WP - 1])
+def test_a_whole_extent_step_with_a_sink_walks_too(pos, monkeypatch):
+    """The sink is one more term of the walk's last fold, whatever the
+    cache the step walks: the whole extent through the kernel against
+    the plain products."""
+    from paddle_tpu.kernels import mla_decode
+
+    rs = np.random.RandomState(pos)
+    ins = dict(_walk_ins(rs, jnp.float32, pos),
+               Sink=[jnp.asarray(rs.randn(H) + 2.0, jnp.float32)])
+    paths, walked = _mla_paths(ins)
+    assert paths == {"{block_k=%d,path=kernel,positions=1}" % WBK: 1}
+    without = _mla_paths({k: v for k, v in ins.items() if k != "Sink"})[1]
+    assert np.abs(np.asarray(walked["Out"][0])
+                  - np.asarray(without["Out"][0])).max() > 1e-3
+    monkeypatch.setattr(mla_decode, "fits", lambda *shape: False)
+    np.testing.assert_allclose(walked["Out"][0],
+                               _mla_paths(ins)[1]["Out"][0], atol=2e-5)
 
 
 # -- a block of T positions: the op, the kernel, the step Program (PR 53) ------
