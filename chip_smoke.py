@@ -425,6 +425,82 @@ def ssd_scan_check(seq=4096, heads=64, dim=64, state=128, chunk=256):
     print("  ssd_scan [1, %d, %d x %d], state %d, %d chunks of %d: within "
           "%.4f of the recurrence" % (seq, heads, dim, state, seq // chunk,
                                       chunk, worst), flush=True)
+    ssd_scan_carried_check()
+
+
+def ssd_scan_carried_check(rows=4, heads=128, dim=64, state=128, chunk=256,
+                           steps=8):
+    """The same op with its state handed in and on (`State` /
+    `StateOut`: kernels/ssd.py's `ssd_block_*`, the plain `ssd_update`) at
+    granite-4.0-h-small's widths: a prompt of two chunks as one block
+    from zeros, then `steps` positions a step at a time, the state
+    through both borders, against the recurrence walked one position
+    after another in float32 over the whole sequence
+    (models/reference/granite_moe_hybrid.py): the output at every
+    position and the state after the last."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.models.reference import granite_moe_hybrid as reference
+    from paddle_tpu.ops import registry, ssm
+
+    seq = 2 * chunk + steps
+    keys = jax.random.split(jax.random.PRNGKey(1), 7)
+    wide, f32 = (rows, seq, heads * dim), jnp.float32
+    slots = ("X", "Dt", "DtBias", "ALog", "B", "C", "D")
+    values = (
+        jax.random.normal(keys[0], wide, f32),
+        jax.random.normal(keys[1], (rows, seq, heads), f32),
+        jnp.log(jnp.expm1(jnp.exp(jax.random.uniform(
+            keys[2], (heads,), f32, np.log(1e-3), np.log(1e-1))))),
+        jnp.log(jax.random.uniform(keys[3], (heads,), f32, 1.0, 16.0)),
+        0.5 * jax.random.normal(keys[4], (rows, seq, state), f32),
+        0.5 * jax.random.normal(keys[5], (rows, seq, state), f32),
+        1.0 + 0.1 * jax.random.normal(keys[6], (heads,), f32))
+    info = registry.get_op_info("ssd_scan")
+    attrs = {"num_heads": heads, "chunk_size": chunk}
+
+    def part(values, start, stop, carried):
+        ins = {s: [v[:, start:stop] if v.ndim == 3 else v]
+               for s, v in zip(slots, values)}
+        outs = info.kernel(None, dict(ins, State=[carried]), attrs)
+        return outs["Y"][0], outs["StateOut"][0]
+
+    def program(*values):
+        carried = jnp.zeros((rows, state, heads * dim), f32)
+        ys = []
+        for start, stop in [(0, 2 * chunk)] + [
+                (at, at + 1) for at in range(2 * chunk, seq)]:
+            y, carried = part(values, start, stop, carried)
+            ys.append(y)
+        return jnp.concatenate(ys, axis=1), carried
+
+    def plain(x, dt, dt_bias, a_log, b, c, d_skip):
+        with jax.default_matmul_precision("highest"):
+            y, last = reference.recurrence(
+                {}, x.reshape(rows, seq, heads, dim),
+                jax.nn.softplus(dt + dt_bias), -jnp.exp(a_log), b, c,
+                d_skip)
+        return y.reshape(wide), last
+
+    text = jax.jit(program).lower(*values).as_text()
+    check(text.count("ssd_block_c%d" % chunk) >= 1,
+          "ssd_scan with a state lowered without its block kernel")
+    y, last = jax.jit(program)(*values)
+    want_y, want_last = jax.jit(plain)(*values)
+    worst = 0.0
+    for name, g, w in (("y", y, want_y),
+                       ("state", ssm.heads_apart(last, heads), want_last)):
+        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+        check(g.shape == w.shape and np.isfinite(g).all(),
+              "ssd_scan with a state, %s: bad shape or non-finite" % name)
+        err = float(np.abs(g - w).max() / np.abs(w).max())
+        check(err < BF16_TOL, "ssd_scan with a state, %s: off the "
+              "recurrence by %.4f of its largest value" % (name, err))
+        worst = max(worst, err)
+    print("  ssd_scan with its state [%d, %d + %d x 1, %d x %d], state %d: "
+          "a block of two chunks, then %d steps, within %.4f of the "
+          "recurrence" % (rows, 2 * chunk, steps, heads, dim, state, steps,
+                          worst), flush=True)
 
 
 def selective_scan_check(rows=16, block=128, channels=5120, state=16):

@@ -1019,7 +1019,7 @@ def moe(input, num_experts, expert_size, top_k, router_attr=None,
 
 
 def ssd_scan(x, dt, b, c, num_heads, chunk_size=256, a_log_attr=None,
-             d_attr=None, dt_bias_attr=None, name=None):
+             d_attr=None, dt_bias_attr=None, name=None, state=None):
     """Mamba-2's selective state-space scan (ops/ssm.py ssd_scan) over
     `x` [batch, seq, num_heads * head_dim] with the steps `dt` [batch,
     seq, num_heads] as the projection gives them (the op adds its bias
@@ -1030,7 +1030,17 @@ def ssd_scan(x, dt, b, c, num_heads, chunk_size=256, a_log_attr=None,
     log of a uniform [1, 16]), `D` (ones) and `DtBias` (the inverse
     softplus of a step drawn log-uniformly from [1e-3, 1e-1]), as
     arXiv:2405.21060 initialises them, so that decays lie between 0.2
-    and 0.999 a step.  `seq` must be a multiple of `chunk_size`."""
+    and 0.999 a step.  `seq` must be a multiple of `chunk_size`.
+
+    With `state` [batch, d_state, num_heads * head_dim] float32, what
+    the positions before the block left (zeros at a sequence's start;
+    `ops.ssm.heads_apart` gives it a head at a time), the scan starts
+    from it and the layer returns (out, state_out): thread `state_out`
+    back as decode state (`fluid.ProgramDecoder` state pairs).  `seq`
+    is then one position (a decode step: one update of the state) or a
+    multiple of `chunk_size`, and may be left open (-1); the op says
+    `prefill_block` = `chunk_size`, the positions `fluid.ProgramDecoder`
+    prefills a prompt by.  Forward only."""
     helper = LayerHelper("ssd_scan", name=name)
 
     def param(attr, init):
@@ -1043,13 +1053,21 @@ def ssd_scan(x, dt, b, c, num_heads, chunk_size=256, a_log_attr=None,
     dt_bias = param(dt_bias_attr,
                     LogScale(1e-3, 1e-1, "inverse_softplus_log_uniform"))
     out = helper.create_tmp_variable(x.dtype)
+    inputs = {"X": [x], "Dt": [dt], "DtBias": [dt_bias],
+              "ALog": [a_log], "B": [b], "C": [c], "D": [d_skip]}
+    attrs = {"num_heads": int(num_heads), "chunk_size": int(chunk_size)}
+    if state is not None:
+        state_out = helper.create_tmp_variable("float32",
+                                               stop_gradient=True)
+        helper.append_op(
+            type="ssd_scan", inputs=dict(inputs, State=[state]),
+            outputs={"Y": [out], "StateOut": [state_out]},
+            attrs=dict(attrs, prefill_block=int(chunk_size)))
+        return out, state_out
     states = helper.create_tmp_variable("float32", stop_gradient=True)
     helper.append_op(
-        type="ssd_scan",
-        inputs={"X": [x], "Dt": [dt], "DtBias": [dt_bias],
-                "ALog": [a_log], "B": [b], "C": [c], "D": [d_skip]},
-        outputs={"Y": [out], "States": [states]},
-        attrs={"num_heads": int(num_heads), "chunk_size": int(chunk_size)})
+        type="ssd_scan", inputs=inputs,
+        outputs={"Y": [out], "States": [states]}, attrs=attrs)
     return out
 
 

@@ -24,6 +24,14 @@ into rows: a product with a matrix of ones would round one side of sums
 that cancel (measured on the chip: the gradients of ALog and DtBias off
 by a fifth).
 
+A cached step's block of positions (`scan_from`, the op with `State`)
+runs the forward kernel's body from a state handed in, [batch, d_state,
+heads * head_dim] float32 (the scratch's own layout a head group: a
+group's [d_state, 128] block is fetched at the first chunk), and writes
+the state it carries out again after every chunk, over the one before
+(the last chunk's stays); it keeps no state a chunk, which nothing
+reads.  That kernel is named `ssd_block_c<chunk>_h<g>`.
+
 The kernels are named `ssd_fwd_c<chunk>_h<g>` and `ssd_bwd_c<chunk>_h<g>`.
 Around them, in XLA: the sums of `dt A` inside each chunk, B and C
 transposed ([batch, d_state, seq], 1 MB each), the per-head rows the
@@ -134,18 +142,43 @@ def _step_decays(cum_cols, lane_head):
 def _fwd_kernel(x_ref, dt_ref, cumc_ref, cumr_ref, bt_ref, c_ref, d_ref,
                 y_ref, states_ref, state_scr, g_scr, *, g):
     chunk, group = pl.program_id(1), pl.program_id(2)
-    kind = x_ref.dtype
 
     @pl.when(chunk == 0)
     def _():
         state_scr[group] = jnp.zeros(state_scr.shape[1:], F32)
+
+    states_ref[...] = state_scr[group]
+    _fwd_chunk(x_ref, dt_ref, cumc_ref, cumr_ref, bt_ref, c_ref, d_ref,
+               y_ref, state_scr, g_scr, g)
+
+
+def _block_kernel(x_ref, dt_ref, cumc_ref, cumr_ref, bt_ref, c_ref, d_ref,
+                  s_ref, y_ref, so_ref, state_scr, g_scr, *, g):
+    """The forward kernel from a state handed in: the carried state is
+    written out after every chunk, the last one's stays."""
+    chunk, group = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(chunk == 0)
+    def _():
+        state_scr[group] = s_ref[...]
+
+    _fwd_chunk(x_ref, dt_ref, cumc_ref, cumr_ref, bt_ref, c_ref, d_ref,
+               y_ref, state_scr, g_scr, g)
+    so_ref[...] = state_scr[group]
+
+
+def _fwd_chunk(x_ref, dt_ref, cumc_ref, cumr_ref, bt_ref, c_ref, d_ref,
+               y_ref, state_scr, g_scr, g):
+    """A chunk of one head group: y, and the group's carried state moved
+    on."""
+    group = pl.program_id(2)
+    kind = x_ref.dtype
 
     @pl.when(group == 0)
     def _():
         g_scr[...] = _nn(c_ref[...], bt_ref[...])
 
     entering = state_scr[group]
-    states_ref[...] = entering
     lane_head = lax.broadcasted_iota(jnp.int32, (1, LANES), 1) \
         // (LANES // g)
     cum_cols = _columns(cumc_ref, group * g, g)
@@ -280,9 +313,11 @@ def _by_lane_row(per_head, dim):
     return jnp.repeat(per_head.astype(F32), dim)[None, :]
 
 
-def fwd_kernels(x, dt, a, b, c, d_skip, chunk, interpret=False):
+def fwd_kernels(x, dt, a, b, c, d_skip, chunk, interpret=False,
+                entering=None):
     """`ops.ssm.chunked_scan` as the forward kernel: y in x's type and
-    the entering states, float32."""
+    the entering states, float32; with `entering` [batch, d_state, heads
+    * head_dim] float32, from it, and the state after the last chunk."""
     batch, seq, width = x.shape
     heads, state = dt.shape[-1], b.shape[-1]
     g = heads_a_step(width, heads)
@@ -292,15 +327,33 @@ def fwd_kernels(x, dt, a, b, c, d_skip, chunk, interpret=False):
     per_head = pl.BlockSpec((None, chunk, heads), lambda i, j, h: (i, j, 0))
     rows = pl.BlockSpec((None, None, g, chunk),
                         lambda i, j, h: (i, h, 0, j))
+    in_specs = [
+        wide, per_head, per_head, rows,
+        pl.BlockSpec((None, state, chunk), lambda i, j, h: (i, 0, j)),
+        pl.BlockSpec((None, chunk, state), lambda i, j, h: (i, j, 0)),
+        pl.BlockSpec((1, LANES), lambda i, j, h: (0, h)),
+    ]
+    operands = (x, dt, cum, _rows(cum, g), jnp.swapaxes(b, 1, 2), c,
+                _by_lane_row(d_skip, width // heads))
+    scratch = [pltpu.VMEM((groups, state, LANES), F32),
+               pltpu.VMEM((chunk, chunk), F32)]
+    if entering is not None:
+        a_group = pl.BlockSpec((None, state, LANES),
+                               lambda i, j, h: (i, 0, h))
+        return tuple(pl.pallas_call(
+            functools.partial(_block_kernel, g=g),
+            grid=(batch, chunks, groups),
+            in_specs=in_specs + [a_group], out_specs=[wide, a_group],
+            out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
+                       jax.ShapeDtypeStruct(entering.shape, F32)],
+            scratch_shapes=scratch,
+            name="ssd_block_c%d_h%d" % (chunk, g),
+            **_params(interpret),
+        )(*operands, entering))
     return tuple(pl.pallas_call(
         functools.partial(_fwd_kernel, g=g),
         grid=(batch, chunks, groups),
-        in_specs=[
-            wide, per_head, per_head, rows,
-            pl.BlockSpec((None, state, chunk), lambda i, j, h: (i, 0, j)),
-            pl.BlockSpec((None, chunk, state), lambda i, j, h: (i, j, 0)),
-            pl.BlockSpec((1, LANES), lambda i, j, h: (0, h)),
-        ],
+        in_specs=in_specs,
         out_specs=[
             wide,
             pl.BlockSpec((None, None, state, LANES),
@@ -310,12 +363,10 @@ def fwd_kernels(x, dt, a, b, c, d_skip, chunk, interpret=False):
             jax.ShapeDtypeStruct(x.shape, x.dtype),
             jax.ShapeDtypeStruct((batch, chunks, state, width), F32),
         ],
-        scratch_shapes=[pltpu.VMEM((groups, state, LANES), F32),
-                        pltpu.VMEM((chunk, chunk), F32)],
+        scratch_shapes=scratch,
         name="ssd_fwd_c%d_h%d" % (chunk, g),
         **_params(interpret),
-    )(x, dt, cum, _rows(cum, g), jnp.swapaxes(b, 1, 2), c,
-      _by_lane_row(d_skip, width // heads)))
+    )(*operands))
 
 
 def bwd_kernels(x, dt, a, b, c, d_skip, states, dy, chunk,
@@ -388,6 +439,21 @@ def scan(x, dt, a, b, c, d_skip, chunk, plain):
             x, dt, a, b, c, d_skip,
             tpu=functools.partial(fwd_kernels, chunk=chunk),
             default=lambda *args: _typed(plain(*args, chunk), x.dtype))
+
+
+def scan_from(x, dt, a, b, c, d_skip, state, chunk, plain):
+    """y and the state after the block, from `state` [batch, d_state,
+    heads * head_dim] float32: `plain(..., chunk, state=state)`
+    anywhere but on the TPU."""
+    if not heads_a_step(x.shape[-1], dt.shape[-1]):
+        return plain(x, dt, a, b, c, d_skip, chunk, state=state)
+    with jax.named_scope("ssd_chunks"):
+        return lax.platform_dependent(
+            x, dt, a, b, c, d_skip, state,
+            tpu=lambda *args: fwd_kernels(*args[:-1], chunk=chunk,
+                                          entering=args[-1]),
+            default=lambda *args: _typed(
+                plain(*args[:-1], chunk, state=args[-1]), x.dtype))
 
 
 def _typed(outs, dtype):

@@ -176,7 +176,8 @@ def gated_feed_forward(u, width, names, limit=None, name=None):
 def share_feed_forward(u, block, dense, d_ff, d_expert, n_experts, held,
                        top_k, norm_topk, routed_scale, router_bias=False,
                        n_group=0, topk_group=0, scoring="sigmoid",
-                       shared_gate=None, swiglu_limit=None, dense_name=None):
+                       shared_gate=None, swiglu_limit=None, dense_name=None,
+                       d_shared=None):
     """The feed-forward half of one layer of one chip's share of an
     expert model, for u [batch, seq, hidden], already normed; `block`
     names the layer's parameters.  Returns (F(u), routing).  The
@@ -192,7 +193,9 @@ def share_feed_forward(u, block, dense, d_ff, d_expert, n_experts, held,
     `dense`: the gated feed-forward of width `d_ff` (`ffn_in`,
     `ffn_out`; `dense_name` names its ops between the two products), and
     `routing` is None.  Otherwise a shared expert of
-    width `d_expert` (`shared_in`, `shared_out`) beside a routed layer
+    width `d_shared` (`shared_in`, `shared_out`; default `d_expert`, the
+    routed experts': granite-4.0-h-small's is twice theirs) beside a
+    routed layer
     (`fluid.layers.moe`: sigmoid scores over `n_experts`, `top_k` a
     token chosen by score plus `router_bias` inside the best
     `topk_group` of `n_group` groups, the chosen weights normalised and
@@ -220,7 +223,7 @@ def share_feed_forward(u, block, dense, d_ff, d_expert, n_experts, held,
     f = m
     if "shared_in" in block:
         shared = gated_feed_forward(
-            u, d_expert, {"w_in": block["shared_in"],
+            u, d_shared or d_expert, {"w_in": block["shared_in"],
                           "w_out": block["shared_out"]}, swiglu_limit)
         if shared_gate is not None:
             # named: the ops' instances in a trace start with it

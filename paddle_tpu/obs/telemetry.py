@@ -469,7 +469,7 @@ def _recurrent_state_bytes(kind, row_bytes):
                    "bytes of recurrent state a row holds in the lowered "
                    "ops that take a state in and hand it on, by kind (a "
                    "delta rule's state, a convolution's tail, a "
-                   "selective scan's state)",
+                   "selective scan's state, a Mamba-2 scan's)",
                    labelnames=("kind",)).labels(kind=kind).inc(row_bytes)
 
 
@@ -528,6 +528,27 @@ def on_selective_scan_lowering(form, state_dtype, row_bytes):
                    labelnames=("form", "state_dtype")) \
           .labels(form=form, state_dtype=str(state_dtype)).inc()
     _recurrent_state_bytes("ssm", row_bytes)
+
+
+def on_ssd_scan_lowering(form, path, chunk, heads, state_dtype, row_bytes):
+    """An `ssd_scan` op that carries its state (ops/ssm.py: Mamba-2's
+    recurrence with `State`) was traced into a program: in which form
+    ("step": one position, the state read and written once; "block":
+    whole chunks of `chunk` positions), which way ("kernel":
+    kernels/ssd.py's `ssd_block_*`; "plain": `jax.numpy`), over how many
+    heads and carried states of which type; `row_bytes` the state a row
+    hands on.  One count per op instance a lowered program holds.
+    Training's form, which carries none, is counted by its kernels
+    (`on_ssd_lowering`)."""
+    _reg().counter("ssd_scan_lowerings_total",
+                   "Mamba-2 scan ops lowered that carry their state, by "
+                   "form (a step or a block of chunks), path (a kernel "
+                   "or plain products), chunk, heads and the state's type",
+                   labelnames=("form", "path", "chunk", "heads",
+                               "state_dtype")) \
+          .labels(form=form, path=path, chunk=chunk, heads=heads,
+                  state_dtype=str(state_dtype)).inc()
+    _recurrent_state_bytes("ssd", row_bytes)
 
 
 def on_decoder_positions(part, positions):

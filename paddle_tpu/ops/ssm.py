@@ -48,6 +48,25 @@ type, and on the diagonal, where L is 1 whatever the decay, gains and
 losses cancel to nothing but their rounding.  It runs none of the
 forward's other products (`M (dt X)`, the chunk states).
 
+A cached step's scan **carries its state**: with the optional input
+`State` [batch, d_state, heads * head_dim] float32, what the positions
+before the block left (zeros at a sequence's start; the kernels' own
+layout, state rows by head lanes, which pads nothing: 128 sublanes by
+8192 lanes at granite-4.0-h-small's 128 heads of 64), `S_{-1}` is that
+state and the op gives `StateOut`, the state after the block's last
+position: a `fluid.ProgramDecoder` state pair.  T = 1 is one update of
+the state (`ssd_update`, plain `jax.numpy` on every platform: the
+compiler fuses the decay, the update and the read into one pass over the
+state, 81% of a v5e's HBM peak at 128 heads of 64 over 128, which a
+Pallas kernel through the compiler's own pipeline did not beat: PERF.md
+section 6, PR 71); T a multiple of the chunk is the
+chunked scan started from `State` (kernels/ssd.py's `ssd_block_*`, which
+keeps no `States` a chunk: nothing reads them), leaving what T steps
+leave to rounding; any other T is the error it always was.  `heads_apart`
+gives a state as the recurrence has it, [batch, heads, head_dim,
+d_state].  That form is forward only; without `State` the op lowers as
+it always did.
+
 `causal_conv1d` is out_t = act(bias + sum_j filter[:, j] x_{t-(K-1)+j}),
 each channel by itself, zeros before position 0: one fused pass.  Its
 gradient is explicit too and reads X, Filter, Bias and dOut only: it
@@ -121,19 +140,21 @@ def _decay_mask(cum):
                              -jnp.inf))
 
 
-def _carried(decay, local, reverse=False):
+def _carried(decay, local, reverse=False, start=None, handed=False):
     """The state entering each chunk (leaving it, walked in `reverse`):
-    s_0 = 0, s_{c+1} = decay_c s_c + local_c.  decay [batch, chunks,
-    heads], local [batch, chunks, d_state, heads, head_dim]."""
+    s_0 = `start` (zeros), s_{c+1} = decay_c s_c + local_c.  decay
+    [batch, chunks, heads], local [batch, chunks, d_state, heads,
+    head_dim].  With `handed`, also the state after the last chunk."""
     def step(s, inputs):
         d, loc = inputs
         return d[:, None, :, None] * s + loc, s
 
-    _, entering = jax.lax.scan(
-        step, jnp.zeros_like(local[:, 0]),
+    last, entering = jax.lax.scan(
+        step, jnp.zeros_like(local[:, 0]) if start is None else start,
         (jnp.moveaxis(decay, 1, 0), jnp.moveaxis(local, 1, 0)),
         reverse=reverse)
-    return jnp.moveaxis(entering, 0, 1)
+    entering = jnp.moveaxis(entering, 0, 1)
+    return (entering, last) if handed else entering
 
 
 def _state_product(rows, cols):
@@ -145,12 +166,14 @@ def _state_product(rows, cols):
         *cols.shape[:2], rows.shape[-1], *cols.shape[3:])
 
 
-def chunked_scan(x, dt, a, b, c, d_skip, chunk):
+def chunked_scan(x, dt, a, b, c, d_skip, chunk, state=None):
     """x [batch, seq, heads * head_dim], b and c [batch, seq, d_state] in
     the compute type; dt (after the softplus), a = dt * A [batch, seq,
     heads] and d_skip [heads] float32 -> y float32 [batch, seq, heads *
     head_dim] and the states entering the chunks, float32 [batch,
-    chunks, d_state, heads * head_dim]."""
+    chunks, d_state, heads * head_dim].  With `state` [batch, d_state,
+    heads * head_dim] float32 the first chunk enters from it, and the
+    second result is the state after the last chunk, in its shape."""
     heads = dt.shape[-1]
     xc = _chunks(x.reshape(*x.shape[:2], heads, -1), chunk)
     bc, cc, dtc = _chunks(b, chunk), _chunks(c, chunk), _chunks(dt, chunk)
@@ -163,11 +186,18 @@ def chunked_scan(x, dt, a, b, c, d_skip, chunk):
     with jax.named_scope("ssd_state"):
         to_end = jnp.exp(cum[:, :, -1:] - cum)
         local = _state_product(bc, (xd * to_end[..., None]).astype(x.dtype))
-        entering = _carried(jnp.exp(cum[:, :, -1]), local)
+        if state is None:
+            entering = _carried(jnp.exp(cum[:, :, -1]), local)
+        else:
+            entering, handed = _carried(
+                jnp.exp(cum[:, :, -1]), local, handed=True,
+                start=state.reshape(*state.shape[:2], heads, -1))
     with jax.named_scope("ssd_inter"):
         y = y + jnp.exp(cum)[..., None] * jnp.einsum(
             "bcin,bcnhp->bcihp", cc, entering.astype(x.dtype), **_ACC)
     y = y + d_skip[:, None] * xc.astype(F32)
+    if state is not None:
+        return y.reshape(x.shape), handed.reshape(state.shape)
     return y.reshape(x.shape), entering.reshape(*entering.shape[:3], -1)
 
 
@@ -238,16 +268,20 @@ def chunked_scan_grad(x, dt, a, b, c, d_skip, states, dy, chunk):
 
 # -- ssd_scan --------------------------------------------------------------------
 
-def _scan_sizes(x_shape, dt_shape, chunk):
+def _scan_sizes(x_shape, dt_shape, chunk, carried=False):
+    """`carried`: the form with `State`, whose block may also be one
+    position, or left open when the program is built (-1: the lowering
+    sees the length and checks it)."""
     batch, seq, width = (int(s) for s in x_shape)
     heads = int(dt_shape[-1])
     if width % heads:
         raise ValueError("ssd_scan: X's width %d is no multiple of %d heads"
                          % (width, heads))
-    if seq % chunk:
+    if seq % chunk and not (carried and seq in (1, -1)):
         raise ValueError(
             "ssd_scan: a sequence of %d positions is no multiple of the "
-            "chunk (%d); pad the data, the op pads nothing" % (seq, chunk))
+            "chunk (%d)%s; pad the data, the op pads nothing"
+            % (seq, chunk, " nor one position" if carried else ""))
     return batch, seq, width, heads
 
 
@@ -256,6 +290,13 @@ def _scan_infer_shape(block, op_desc):
     dt = block.var_recursive(op_desc.input("Dt")[0]).desc
     b = block.var_recursive(op_desc.input("B")[0]).desc
     chunk = int(op_desc.attrs["chunk_size"])
+    if op_desc.input("State"):
+        _scan_sizes(x.shape, dt.shape, chunk, carried=True)
+        state = block.var_recursive(op_desc.input("State")[0]).desc
+        _set_meta(block, op_desc.output("Y")[0], x.shape, x.dtype)
+        _set_meta(block, op_desc.output("StateOut")[0], state.shape,
+                  state.dtype)
+        return
     batch, seq, width, _ = _scan_sizes(x.shape, dt.shape, chunk)
     _set_meta(block, op_desc.output("Y")[0], x.shape, x.dtype)
     _set_meta(block, op_desc.output("States")[0],
@@ -284,8 +325,10 @@ def ssd_scan(ctx, ins, attrs):
     docstring)."""
     from ..kernels import ssd
 
-    x, b, c, dt_raw, dt_bias, a_log, d_skip = _scan_inputs(ins)
     chunk = int(attrs["chunk_size"])
+    if ins.get("State"):
+        return _scan_carried(ins, chunk)
+    x, b, c, dt_raw, dt_bias, a_log, d_skip = _scan_inputs(ins)
     _scan_sizes(x.shape, dt_raw.shape, chunk)
     with jax.named_scope("ssd_decay"):
         dt, a, _ = _steps(dt_raw, dt_bias, a_log)
@@ -294,12 +337,73 @@ def ssd_scan(ctx, ins, attrs):
     return {"Y": [amp_result(y, ins["X"][0].dtype)], "States": [states]}
 
 
+def heads_apart(state, heads):
+    """A carried state [batch, d_state, heads * head_dim] as the
+    recurrence has it, [batch, heads, head_dim, d_state]."""
+    batch, entries, width = state.shape
+    return jnp.transpose(state.reshape(batch, entries, heads, width // heads),
+                         (0, 2, 3, 1))
+
+
+def ssd_update(state, x, dt, a, b, c, d_skip):
+    """One position of Mamba-2's recurrence, all float32, over the state
+    as it is carried: `state` [batch, d_state, heads * head_dim], `x`
+    [batch, heads * head_dim], `dt` (after the softplus) and `a` = dt * A
+    [batch, heads], `b` and `c` [batch, d_state], `d_skip` [heads] ->
+    (y [batch, heads * head_dim], the state after the position)."""
+    dim = x.shape[-1] // dt.shape[-1]
+    by_lane = lambda t: jnp.repeat(t, dim, axis=-1)
+    state = by_lane(jnp.exp(a))[:, None, :] * state \
+        + b[:, :, None] * (by_lane(dt) * x)[:, None, :]
+    return jnp.sum(state * c[:, :, None], axis=1) + by_lane(d_skip) * x, \
+        state
+
+
+def _scan_carried(ins, chunk):
+    """The op with `State`: a step (T = 1) or a block of whole chunks
+    from the state handed in; Y and StateOut."""
+    from ..kernels import ssd
+
+    x, b, c, dt_raw, dt_bias, a_log, d_skip = _scan_inputs(ins)
+    state = ins["State"][0]
+    rows, length, width = x.shape
+    heads, entries = dt_raw.shape[-1], b.shape[-1]
+    _scan_sizes(x.shape, dt_raw.shape, chunk, carried=True)
+    if state.shape != (rows, entries, width) or state.dtype != F32:
+        raise ValueError(
+            "ssd_scan: State %s %s is not float32 [batch, d_state, heads * "
+            "head_dim] = %s" % (state.shape, state.dtype,
+                                (rows, entries, width)))
+    step = length == 1
+    kernel = not step and ssd.heads_a_step(width, heads)
+    telemetry.on_ssd_scan_lowering(
+        "step" if step else "block", "kernel" if kernel else "plain",
+        0 if step else chunk, heads, state.dtype,
+        state[0].size * state.dtype.itemsize)
+    with jax.named_scope("ssd_decay"):
+        dt, a, _ = _steps(dt_raw, dt_bias, a_log)
+    if step:
+        with jax.named_scope("ssd_step"):
+            y, new = ssd_update(
+                state, x[:, 0].astype(F32), dt[:, 0], a[:, 0],
+                b[:, 0].astype(F32), c[:, 0].astype(F32), d_skip)
+        y = y[:, None]
+    else:
+        y, new = ssd.scan_from(x, dt, a, b, c, d_skip, state, chunk,
+                               plain=chunked_scan)
+    return {"Y": [amp_result(y, ins["X"][0].dtype)], "StateOut": [new]}
+
+
 @register_grad_kernel("ssd_scan")
 def ssd_scan_grad(ctx, ins, attrs):
     """The seven gradients from O@States and OG@Y; X's in dY's type,
     B's and C's in theirs, the parameters' float32."""
     from ..kernels import ssd
 
+    if ins.get("State"):
+        raise NotImplementedError(
+            "ssd_scan: the form that carries its state is forward only "
+            "(a cached step's)")
     x, b, c, dt_raw, dt_bias, a_log, d_skip = _scan_inputs(ins)
     chunk = int(attrs["chunk_size"])
     states, dy = ins["O@States"][0], ins["OG@Y"][0]

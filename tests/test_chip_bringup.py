@@ -287,6 +287,41 @@ def test_the_scan_op_and_its_gradient_lower_one_kernel_each_for_tpu():
     assert module.count("tpu_custom_call") == 2
 
 
+@pytest.mark.parametrize("length,kernels", [
+    (1, []), (256, ["ssd_block_c256_h2"])])
+def test_the_carried_scan_lowers_for_tpu(length, kernels):
+    """`ssd_scan` with `State` at granite-decode-ep4's shapes (64 rows,
+    128 heads of 64 over a state of 128, bfloat16 operands, a float32
+    state of [rows, 128, 8192]) lowered for the TPU from this CPU host:
+    a step is the plain update, no Mosaic kernel; a prompt's block of
+    one chunk holds the block kernel, which keeps no state a chunk."""
+    from paddle_tpu.ops import registry
+
+    info = registry.get_op_info("ssd_scan")
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    rows, heads, dim, entries = 64, 128, 64, 128
+    ins = {"X": [jax.ShapeDtypeStruct((rows, length, heads * dim), bf16)],
+           "Dt": [jax.ShapeDtypeStruct((rows, length, heads), bf16)],
+           "B": [jax.ShapeDtypeStruct((rows, length, entries), bf16)],
+           "C": [jax.ShapeDtypeStruct((rows, length, entries), bf16)],
+           "State": [jax.ShapeDtypeStruct((rows, entries, heads * dim),
+                                          f32)]}
+    ins.update({slot: [jax.ShapeDtypeStruct((heads,), f32)]
+                for slot in ("DtBias", "ALog", "D")})
+
+    def step(ins):
+        return info.kernel(None, ins, {"num_heads": heads,
+                                       "chunk_size": 256})
+
+    exported = jax.export.export(jax.jit(step), platforms=["tpu"])(ins)
+    module = exported.mlir_module()
+    assert module.count("tpu_custom_call") == len(kernels)
+    for kernel in kernels:
+        assert 'kernel_name = "%s"' % kernel in module
+    assert sorted(tuple(a.shape) for a in exported.out_avals) == sorted([
+        (rows, length, heads * dim), (rows, entries, heads * dim)])
+
+
 def test_the_latent_decode_kernel_lowers_for_tpu():
     """`mla_cached_attention` at pangu-decode-ep16's shapes (256 rows,
     128 heads, a 1024-slot bfloat16 cache of 512 + 64 values) lowered
