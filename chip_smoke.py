@@ -431,7 +431,8 @@ def ssd_scan_check(seq=4096, heads=64, dim=64, state=128, chunk=256):
 def ssd_scan_carried_check(rows=4, heads=128, dim=64, state=128, chunk=256,
                            steps=8):
     """The same op with its state handed in and on (`State` /
-    `StateOut`: kernels/ssd.py's `ssd_block_*`, the plain `ssd_update`) at
+    `StateOut`: kernels/ssd.py's `ssd_block_*`, kernels/ssd_step.py's
+    `ssd_step_*`) at
     granite-4.0-h-small's widths: a prompt of two chunks as one block
     from zeros, then `steps` positions a step at a time, the state
     through both borders, against the recurrence walked one position
@@ -485,6 +486,8 @@ def ssd_scan_carried_check(rows=4, heads=128, dim=64, state=128, chunk=256,
     text = jax.jit(program).lower(*values).as_text()
     check(text.count("ssd_block_c%d" % chunk) >= 1,
           "ssd_scan with a state lowered without its block kernel")
+    check(text.count("ssd_step_r%d_b" % rows) >= 1,
+          "ssd_scan with a state lowered a step without its step kernel")
     y, last = jax.jit(program)(*values)
     want_y, want_last = jax.jit(plain)(*values)
     worst = 0.0

@@ -1355,17 +1355,22 @@ def split(input, num_or_sections, dim=-1, **kwargs):
     return outs
 
 
-def slice(input, axes, starts, ends, **kwargs):
+def slice(input, axes, starts, ends, own_layout=False, **kwargs):
     """`input[starts[i]:ends[i]]` along each of `axes` (reference:
     slice_op.cc): a negative index counts from the end, so `starts=[-1],
     ends=[2 ** 31 - 1]` is the last element of an axis whose extent the
-    Program leaves open."""
+    Program leaves open.  `own_layout`: the result is an array of its
+    own, laid out as it is declared, whatever reads it (a few rows cut
+    from a large array and read transposed: without it the compiler may
+    lay the whole array out anew for the transpose's sake, a copy of all
+    of it where one of the rows would do)."""
     helper = LayerHelper("slice", **kwargs)
     out = helper.create_tmp_variable(input.dtype)
+    attrs = {"axes": list(axes), "starts": list(starts), "ends": list(ends)}
+    if own_layout:
+        attrs["own_layout"] = True
     helper.append_op(type="slice", inputs={"Input": [input]},
-                     outputs={"Out": [out]},
-                     attrs={"axes": list(axes), "starts": list(starts),
-                            "ends": list(ends)})
+                     outputs={"Out": [out]}, attrs=attrs)
     return out
 
 

@@ -31,8 +31,9 @@ same `_mamba_mixer`, **carrying two states a layer**: the last `d_conv -
 - 1, heads * d_head + 2 * d_state], in the weights' type) and the
 scan's state ("ssd_state_<i>" [batch, d_state, heads * d_head] float32,
 the kernels' own layout: ops/ssm.py), which a step rewrites whole
-through `ssd_scan`'s `State` (one position through the plain
-`ssd_update`, a block through kernels/ssd.py's `ssd_block_*`).  An
+through `ssd_scan`'s `State` (one position through
+kernels/ssd_step.py's `ssd_step_*` where its shape allows, else the
+plain `ssd_update`; a block through kernels/ssd.py's `ssd_block_*`).  An
 "attention" layer keeps keys and values over the whole extent
 ("k_cache_<i>", "v_cache_<i>"
 [batch, n_kv_head, max_len, d_head]) through `cached_attention`, grouped
@@ -290,12 +291,17 @@ def build_granite_hybrid_cached_step_program(
 
         def heads_apart(state, heads=mamba_heads):
             """The first rows' state a head at a time, as the recurrence
-            has it: the first `heads` heads."""
+            has it: the first `heads` heads.  The rows are an array of
+            their own (`own_layout`): turned as they are cut, where the
+            compiler would else turn the whole carried state every step
+            for their sake, 268 MB a layer beside a step kernel that
+            takes the state as it lies (PERF.md section 6, PR 72)."""
             return fluid.layers.transpose(
                 fluid.layers.reshape(
                     fluid.layers.slice(
                         state, axes=[0, 2], starts=[0, 0],
-                        ends=[state_rows, heads * mamba_d_head]),
+                        ends=[state_rows, heads * mamba_d_head],
+                        own_layout=True),
                     [state_rows, d_state, heads, mamba_d_head]),
                 [0, 2, 3, 1])
 

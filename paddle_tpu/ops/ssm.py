@@ -55,11 +55,16 @@ layout, state rows by head lanes, which pads nothing: 128 sublanes by
 8192 lanes at granite-4.0-h-small's 128 heads of 64), `S_{-1}` is that
 state and the op gives `StateOut`, the state after the block's last
 position: a `fluid.ProgramDecoder` state pair.  T = 1 is one update of
-the state (`ssd_update`, plain `jax.numpy` on every platform: the
-compiler fuses the decay, the update and the read into one pass over the
-state, 81% of a v5e's HBM peak at 128 heads of 64 over 128, which a
-Pallas kernel through the compiler's own pipeline did not beat: PERF.md
-section 6, PR 71); T a multiple of the chunk is the
+the state: `ssd_update`, plain `jax.numpy`, which the compiler fuses
+into one pass over the state at 81% of a v5e's HBM peak at 128 heads of
+64 over 128, its own pipeline's ceiling (a Pallas kernel through that
+pipeline did not beat it: PERF.md section 6, PR 71); lowered for the
+TPU at a shape kernels/ssd_step.py takes (`choose_block`: a float32
+state, `d_state` whole sublane tiles, `heads * head_dim` whole lane
+blocks) the same arithmetic as `ssd_step_r<rows>_b<rows a grid step>`,
+which moves the state with copies of its own, reads and writes taking
+turns, and hands the state's buffer back as `StateOut` (PERF.md section
+6, PR 72).  T a multiple of the chunk is the
 chunked scan started from `State` (kernels/ssd.py's `ssd_block_*`, which
 keeps no `States` a chunk: nothing reads them), leaving what T steps
 leave to rounding; any other T is the error it always was.  `heads_apart`
@@ -362,7 +367,7 @@ def ssd_update(state, x, dt, a, b, c, d_skip):
 def _scan_carried(ins, chunk):
     """The op with `State`: a step (T = 1) or a block of whole chunks
     from the state handed in; Y and StateOut."""
-    from ..kernels import ssd
+    from ..kernels import ssd, ssd_step
 
     x, b, c, dt_raw, dt_bias, a_log, d_skip = _scan_inputs(ins)
     state = ins["State"][0]
@@ -375,7 +380,8 @@ def _scan_carried(ins, chunk):
             "head_dim] = %s" % (state.shape, state.dtype,
                                 (rows, entries, width)))
     step = length == 1
-    kernel = not step and ssd.heads_a_step(width, heads)
+    kernel = ssd_step.choose_block(rows, entries, width, state.dtype) \
+        if step else ssd.heads_a_step(width, heads)
     telemetry.on_ssd_scan_lowering(
         "step" if step else "block", "kernel" if kernel else "plain",
         0 if step else chunk, heads, state.dtype,
@@ -383,10 +389,11 @@ def _scan_carried(ins, chunk):
     with jax.named_scope("ssd_decay"):
         dt, a, _ = _steps(dt_raw, dt_bias, a_log)
     if step:
+        at = (state, x[:, 0].astype(F32), dt[:, 0], a[:, 0],
+              b[:, 0].astype(F32), c[:, 0].astype(F32), d_skip)
         with jax.named_scope("ssd_step"):
-            y, new = ssd_update(
-                state, x[:, 0].astype(F32), dt[:, 0], a[:, 0],
-                b[:, 0].astype(F32), c[:, 0].astype(F32), d_skip)
+            y, new = ssd_step.step(*at, plain=ssd_update) if kernel \
+                else ssd_update(*at)
         y = y[:, None]
     else:
         y, new = ssd.scan_from(x, dt, a, b, c, d_skip, state, chunk,

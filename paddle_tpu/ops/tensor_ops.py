@@ -263,13 +263,21 @@ def crop(ctx, ins, attrs):
 def slice_op(ctx, ins, attrs):
     """reference: slice_op.cc — Input[starts[i]:ends[i]] along axes[i];
     a negative index counts from the end, an end past the extent is the
-    extent."""
+    extent; attr `own_layout`: the result in its declared layout,
+    whatever reads it (fluid.layers.slice)."""
     x = _x(ins, "Input")
     index = [slice(None)] * x.ndim
     for axis, start, end in zip(attrs["axes"], attrs["starts"],
                                 attrs["ends"]):
         index[int(axis)] = slice(int(start), int(end))
-    return {"Out": [x[tuple(index)]]}
+    out = x[tuple(index)]
+    if attrs.get("own_layout"):
+        # an array of its own, rows-major as declared: a transpose that
+        # reads it turns these elements, not the array they were cut from
+        from jax.experimental.layout import Layout, with_layout_constraint
+        out = with_layout_constraint(
+            out, Layout(major_to_minor=tuple(range(out.ndim))))
+    return {"Out": [out]}
 
 
 @register_op("cumsum")

@@ -119,10 +119,11 @@ def calls(kind, block, pack=1):
 
 
 def slope(fn, ins, repeats=3):
-    """ms a call: the slope between SHORT and LONG."""
+    """ms a call: the slope between SHORT and LONG.  `ins`' last is the
+    state the loop carries (an array, or several), fn's last result."""
     best = {}
     for n in (SHORT, LONG):
-        state = jnp.copy(ins[-1])
+        state = jax.tree.map(jnp.copy, ins[-1])
         state = jax.block_until_ready(fn(n, *ins[:-1], state))[-1]
         best[n] = float("inf")
         for _ in range(repeats):

@@ -288,13 +288,16 @@ def test_the_scan_op_and_its_gradient_lower_one_kernel_each_for_tpu():
 
 
 @pytest.mark.parametrize("length,kernels", [
-    (1, []), (256, ["ssd_block_c256_h2"])])
+    (1, ["ssd_step_r64_b4"]), (256, ["ssd_block_c256_h2"])])
 def test_the_carried_scan_lowers_for_tpu(length, kernels):
     """`ssd_scan` with `State` at granite-decode-ep4's shapes (64 rows,
     128 heads of 64 over a state of 128, bfloat16 operands, a float32
     state of [rows, 128, 8192]) lowered for the TPU from this CPU host:
-    a step is the plain update, no Mosaic kernel; a prompt's block of
-    one chunk holds the block kernel, which keeps no state a chunk."""
+    a step is the step kernel over blocks of 4 rows (16 MiB of state a
+    grid step, and the VMEM limit that follows from the block) whose
+    state is its result's buffer; a prompt's block of one chunk holds
+    the block kernel, which keeps no state a chunk."""
+    from paddle_tpu.kernels import ssd_step
     from paddle_tpu.ops import registry
 
     info = registry.get_op_info("ssd_scan")
@@ -320,6 +323,10 @@ def test_the_carried_scan_lowers_for_tpu(length, kernels):
         assert 'kernel_name = "%s"' % kernel in module
     assert sorted(tuple(a.shape) for a in exported.out_avals) == sorted([
         (rows, length, heads * dim), (rows, entries, heads * dim)])
+    if length == 1:     # the state's buffer is the new state's
+        assert "output_tuple_indices = [1], operand_index = 4" in module
+        assert _vmem_limit_stated(module) \
+            == ssd_step.vmem_limit(4, entries, heads * dim)
 
 
 def test_the_latent_decode_kernel_lowers_for_tpu():

@@ -16,7 +16,19 @@ turn of the 37.7 MB set, `{2,1,0}` -> `{1,2,0}`, in front of two plain
 products: a second copy in fast memory, 0.29 ms a step of
 `dsv32-turn-16k-ep16`; the op's gather clips now).  A one-layer scan
 places as `dsv32-turn-16k-ep16`'s and `hy4-turn-32k-ep16`'s whole
-generation calls do (PERF.md section 6, PR 70, step (a))."""
+generation calls do (PERF.md section 6, PR 70, step (a)).
+
+And of a Mamba-2 step: `ssd_scan` with `State` at one position a row at
+granite-decode-ep4's shape, one layer in a scan that carries the state.
+What PR 72 bought and this guards: the step is the Pallas call
+`ssd_step_r64_b4`, whose state operand is the scan's carry itself and
+whose state result is the next carry, one buffer (`kernels/ssd_step.py`'s
+`input_output_aliases`): no `copy` of the 268 MB state stands before or
+after it, with the rows a served step carries out of the state it was
+handed read beside it (two rows' first heads, a head at a time: the
+slice says `own_layout`, without which the compiler lays the whole
+state out anew every step for that transpose's sake, `{1,2,0}`, 0.83 ms
+a layer of `granite-decode-ep4`: PERF.md section 6, PR 72)."""
 
 import os
 import re
@@ -142,3 +154,69 @@ def test_nothing_stands_between_a_steps_gather_and_its_reader(
                              "reshape")]
     assert not others, (cell, others)
     assert "f32[%d,%d,%d]" % (rows, heads, TOP_K) not in text, cell
+
+
+def _compiled_state_scan(chip, rows, heads, dim, entries):
+    """The text of 8 steps of one Mamba-2 layer, the state carried."""
+    from paddle_tpu.ops import registry
+
+    def of(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    ins = {"X": [of((rows, 1, heads * dim))],
+           "Dt": [of((rows, 1, heads))],
+           "B": [of((rows, 1, entries))], "C": [of((rows, 1, entries))],
+           "DtBias": [of((heads,), jnp.float32)],
+           "ALog": [of((heads,), jnp.float32)],
+           "D": [of((heads,), jnp.float32)]}
+    kernel = registry.get_op_info("ssd_scan").kernel
+
+    cut = registry.get_op_info("slice").kernel
+
+    def steps(ins, state):
+        def body(carry, _):
+            state, seen, _ = carry
+            # the next step's x is this one's y, as a layer's stream is
+            outs = kernel(None, dict(ins, X=[seen], State=[state]),
+                          {"num_heads": heads, "chunk_size": 256})
+            # what models/hybrid_program.py carries out of the state a
+            # step was handed: two rows' first 16 heads, a head at a time
+            handed = cut(None, {"Input": [state]}, {
+                "axes": [0, 2], "starts": [0, 0], "ends": [2, 16 * dim],
+                "own_layout": True})["Out"][0]
+            handed = jnp.transpose(handed.reshape(2, entries, 16, dim),
+                                   (0, 2, 3, 1))
+            return (outs["StateOut"][0], outs["Y"][0], handed), None
+        return lax.scan(
+            body, (state, ins["X"][0],
+                   jnp.zeros((2, 16, dim, entries), jnp.float32)),
+            jnp.arange(8))[0]
+
+    return jax.jit(steps, donate_argnums=(1,)).lower(
+        ins, of((rows, entries, heads * dim), jnp.float32)) \
+        .compile().as_text()
+
+
+def test_a_mamba_steps_state_is_the_scans_carry(one_chip, no_compile_cache):
+    rows, heads, dim, entries = 64, 128, 64, 128
+    lines = _compiled_state_scan(one_chip, rows, heads, dim,
+                                 entries).splitlines()
+    state = "f32[%d,%d,%d]" % (rows, entries, heads * dim)
+    calls = [line for line in lines
+             if " custom-call(" in line and "ssd_step_r64_b4" in line]
+    assert len(calls) == 1
+    results, rest = calls[0].split(" custom-call(", 1)
+    # the state is the last operand and the second result, in HBM (no
+    # memory space said), and the result is the operand's buffer
+    assert results.count(state) == 1 \
+        and state + "{2,1,0:T(8,128)})" in results
+    assert "output_to_operand_aliasing={{1}: (4, {})}" in rest
+    carried = re.findall(r"%([\w.\-]+)", rest.split(")", 1)[0])[-1]
+    # the operand is the loop's carry as it arrives, the result leaves
+    # as it is: nothing makes a second array of the state's size
+    made = {m.group(1): m.group(2) for m in (
+        re.match(r"\s*(?:ROOT )?%%([\w.\-]+) = %s\S* ([\w\-]+)\("
+                 % re.escape(state), line) for line in lines) if m}
+    assert made[carried] == "get-tuple-element"
+    assert set(made.values()) <= {"parameter", "get-tuple-element",
+                                  "bitcast"}, made

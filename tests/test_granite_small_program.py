@@ -439,7 +439,7 @@ def test_the_builders_program_digest():
         == DIGEST
 
 
-DIGEST = "f837cb14a0e35977"
+DIGEST = "abe2978260fb3df0"
 
 
 def test_the_benchmarks_copy_of_the_reference_is_this_one():

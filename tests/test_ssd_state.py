@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 
 import paddle_tpu.fluid as fluid
-from paddle_tpu.kernels import ssd
+from paddle_tpu.kernels import ssd, ssd_step
 from paddle_tpu.models.hybrid_program import build_granite_hybrid_program
 from paddle_tpu.models.reference import granite_moe_hybrid as reference
 from paddle_tpu.obs import telemetry
@@ -233,23 +233,137 @@ def test_the_plain_step_is_one_position_of_the_recurrence():
     assert _rel(ssm.heads_apart(new, s["heads"]), want_state) < RTOL
 
 
-def test_a_step_lowers_the_plain_update_whatever_its_shape():
-    """No kernel stands beside `ssd_update`: the counter says "plain"
-    for a step at the shapes a lane block fits as at those it does
-    not."""
-    for k in (STEP_CASES["16x64"], STEP_CASES["4x8"]):
-        (x, dt, a, b, c, d_skip), state = _kernel_operands(dict(k, seq=1))
-        ins = {"X": x, "Dt": dt, "B": b, "C": c,
-               "DtBias": jnp.zeros(k["heads"]), "ALog": jnp.zeros(k["heads"]),
-               "D": d_skip, "State": state}
-        before = telemetry.snapshot()
-        out = registry.get_op_info("ssd_scan").kernel(
-            None, {name: [value] for name, value in ins.items()},
-            {"num_heads": k["heads"], "chunk_size": 8})
-        delta = telemetry.snapshot_delta(before)
-        assert out["StateOut"][0].shape == state.shape
+# the step kernel's body, copies and all (kernels/ssd_step.py): STEP_CASES
+# at the block the shapes choose, the last block the only block; several
+# grid steps, so that a block's way in, work and way out lie beside
+# their neighbours'; 16 state entries, B and C padded to a lane block;
+# 8, a block that comes in as one slice; the rows beside the state
+KERNEL_CASES = {
+    "16x64": (STEP_CASES["16x64"], None),
+    "32x64": (STEP_CASES["32x64"], None),
+    "8x128": (STEP_CASES["8x128"], None),
+    "32x64-a-row-a-step": (STEP_CASES["32x64"], 1),
+    "32x64-two-rows-a-step": (STEP_CASES["32x64"], 2),
+    "6-rows-of-16-entries": (dict(batch=6, heads=4, dim=32, state=16), 2),
+    "8-entries": (dict(batch=2, heads=2, dim=64, state=8), 1),
+    # the rows beside the state a tile of 8 that four grid steps share,
+    # a block's own 8, and all 12 where neither divides
+    "16-rows-two-a-step": (dict(batch=16, heads=2, dim=64, state=8), 2),
+    "16-rows-eight-a-step": (dict(batch=16, heads=2, dim=64, state=8), 8),
+    "12-rows-four-a-step": (dict(batch=12, heads=2, dim=64, state=8), 4),
+}
+
+
+def _step_operands(k, seed=3):
+    (x, dt, a, b, c, d_skip), state = _kernel_operands(dict(k, seq=1), seed)
+    return state, x[:, 0], dt[:, 0], a[:, 0], b[:, 0], c[:, 0], d_skip
+
+
+@pytest.mark.parametrize("case", list(KERNEL_CASES))
+@pytest.mark.parametrize("against", ["ssd_update", "recurrence"])
+def test_the_step_kernel_is_the_plain_step(case, against):
+    k, block = KERNEL_CASES[case]
+    at = _step_operands(k)
+    got_y, got_state = ssd_step.step(*at, plain=None, block=block,
+                                     interpret=True)
+    if against == "ssd_update":
+        want_y, want_state = ssm.ssd_update(*at)
+    else:
+        state, x, dt, a, b, c, d_skip = at
+        want_y, apart = reference.recurrence(
+            {}, x.reshape(k["batch"], 1, k["heads"], k["dim"]), dt[:, None],
+            (a / dt)[0], b[:, None], c[:, None], d_skip,
+            start=ssm.heads_apart(state, k["heads"]))
+        want_y = want_y.reshape(got_y.shape)
+        got_state = ssm.heads_apart(got_state, k["heads"])
+        want_state = apart
+    assert got_y.dtype == jnp.float32 and got_state.dtype == jnp.float32
+    assert got_state.shape == want_state.shape
+    assert _rel(got_y, want_y) < RTOL
+    assert _rel(got_state, want_state) < RTOL
+
+
+def test_the_step_kernel_hands_back_the_buffer_it_was_handed():
+    """The state is the kernel's fifth operand and its second result,
+    one buffer (`input_output_aliases`), and stays in HBM: no block of
+    it is the pipeline's to move."""
+    at = _step_operands(STEP_CASES["32x64"])
+    text = str(jax.make_jaxpr(lambda *v: ssd_step.step(
+        *v, plain=None, block=2, interpret=True))(*at))
+    assert "name=ssd_step_r4_b2" in text
+    assert "input_output_aliases=((4, 1),)" in text
+    # the kernel's own arguments: the state in and the state out
+    assert text.count("Ref<any>{f32[4,128,2048]}") >= 2
+    assert "Ref<vmem>{f32[4,128,2048]}" not in text
+
+
+@pytest.mark.parametrize("what,shape,dtype", [
+    ("a-bfloat16-state", (2, 128, 1024), jnp.bfloat16),
+    ("entries-off-the-sublane-tile", (2, 12, 1024), jnp.float32),
+    ("a-width-off-the-lane-block", (3, 16, 32), jnp.float32),
+    ("a-row-over-a-block", (2, 1024, 8192), jnp.float32)])
+def test_the_step_kernel_refuses_what_it_cannot_tile(what, shape, dtype):
+    assert ssd_step.choose_block(*shape, dtype) is None
+
+
+@pytest.mark.parametrize("rows,held", [(64, 4), (6, 3), (7, 1), (1, 1)])
+def test_a_block_of_the_step_kernel_divides_the_rows(rows, held):
+    """Granite's row of 128 x 8192 float32 is a quarter of
+    `_STEP_BYTES`."""
+    assert ssd_step.choose_block(rows, 128, 8192, jnp.float32) == held
+
+
+def test_the_step_kernel_refuses_rows_its_block_does_not_divide():
+    at = _step_operands(STEP_CASES["32x64"])
+    with pytest.raises(ValueError, match="no step the kernel takes"):
+        ssd_step.step(*at, plain=None, block=3, interpret=True)
+    with pytest.raises(ValueError, match="no step the kernel takes"):
+        ssd_step.step(at[0].astype(jnp.bfloat16), *at[1:], plain=None,
+                      interpret=True)
+
+
+def _lowered_step(k):
+    """(the op's outputs, what the counters say) of one step at k."""
+    state, x, dt, a, b, c, d_skip = _step_operands(k)
+    ins = {"X": x[:, None], "Dt": dt[:, None], "B": b[:, None],
+           "C": c[:, None], "DtBias": jnp.zeros(k["heads"]),
+           "ALog": jnp.zeros(k["heads"]), "D": d_skip, "State": state}
+    before = telemetry.snapshot()
+    out = registry.get_op_info("ssd_scan").kernel(
+        None, {name: [value] for name, value in ins.items()},
+        {"num_heads": k["heads"], "chunk_size": 8})
+    return out, telemetry.snapshot_delta(before)
+
+
+def test_a_step_lowers_the_kernel_where_its_shape_allows():
+    """`path=kernel` at the shapes `choose_block` takes (on any platform
+    but the TPU what is lowered in its place is `ssd_update`, and the
+    numbers are `ssd_update`'s), `path=plain` at a width that is no whole
+    lane block."""
+    for case, path in (("16x64", "kernel"), ("4x8", "plain")):
+        k = STEP_CASES[case]
+        out, delta = _lowered_step(k)
         assert delta["ssd_scan_lowerings_total{chunk=0,form=step,heads=%d,"
-                     "path=plain,state_dtype=float32}" % k["heads"]] == 1
+                     "path=%s,state_dtype=float32}" % (k["heads"], path)] == 1
+        width = k["heads"] * k["dim"]
+        assert out["StateOut"][0].shape == (k["batch"], k["state"], width)
+        assert out["Y"][0].shape == (k["batch"], 1, width)
+
+
+@pytest.mark.parametrize("case", ["16x64", "4x8"])
+def test_the_op_s_step_is_ssd_update_on_this_platform(case):
+    """Through the op, kernel path or plain: one float32 update of the
+    state handed in, bit for bit what `ssd_update` gives from the op's
+    own dt and a."""
+    k = STEP_CASES[case]
+    out, _ = _lowered_step(k)
+    state, x, dt_raw, _, b, c, d_skip = _step_operands(k)
+    dt, a, _ = ssm._steps(dt_raw, jnp.zeros(k["heads"]),
+                          jnp.zeros(k["heads"]))
+    want_y, want_state = ssm.ssd_update(state, x, dt, a, b, c, d_skip)
+    np.testing.assert_array_equal(np.asarray(out["StateOut"][0]),
+                                  np.asarray(want_state))
+    assert _rel(out["Y"][0][:, 0], want_y) < RTOL
 
 
 # -- what is refused, shapes, counters -----------------------------------------

@@ -144,10 +144,14 @@ class TestSlice(OpTest):
         ([1], [-1], [2 ** 31 - 1], (slice(None), slice(-1, None))),
         ([0, 1], [1, -4], [3, -1], (slice(1, 3), slice(-4, -1))),
     ])
-    def test(self, axes, starts, ends, want):
+    @pytest.mark.parametrize("own_layout", [False, True])
+    def test(self, axes, starts, ends, want, own_layout):
+        """`own_layout` says where the result lies, not what it is."""
         x = RS.rand(4, 5).astype("float32")
         self.inputs = {"Input": x}
         self.attrs = {"axes": axes, "starts": starts, "ends": ends}
+        if own_layout:
+            self.attrs["own_layout"] = True
         self.outputs = {"Out": x[want]}
         self.check_output()
         self.check_grad(["Input"], "Out")
