@@ -574,6 +574,61 @@ def selective_scan_check(rows=16, block=128, channels=5120, state=16):
                                              err), flush=True)
 
 
+def block_causal_walk_check(rows=8, heads=32, kv_heads=4, dim=128, slots=1024,
+                            diffusion_block=4):
+    """`cached_attention` under the block-causal mask of generation by
+    diffusion over blocks (`diffusion_block`: the walk of the live slots,
+    `gqa_decode_k<slots>_t<T>_b<B>`) at SDAR-30B-A3B-Chat's widths, a
+    pass over one block (T = 4) behind 640 stored slots and a prefill
+    block (T = 128) behind 128, against a float32 softmax over the slots
+    to the end of each query's block, on the device."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import registry
+
+    kernel = registry.get_op_info("cached_attention").kernel
+    bf16, group = jnp.bfloat16, heads // kv_heads
+    for positions, pos in ((diffusion_block, 640), (128, 128)):
+        keys = jax.random.split(jax.random.PRNGKey(positions), 5)
+        q = jax.random.normal(keys[0], (rows, positions, heads * dim), bf16)
+        new = [jax.random.normal(k, (rows, positions, kv_heads * dim), bf16)
+               for k in keys[1:3]]
+        caches = [jax.random.normal(k, (rows, kv_heads, slots, dim), bf16)
+                  for k in keys[3:]]
+        ins = {"Q": [q], "KNew": [new[0]], "VNew": [new[1]],
+               "KCache": [caches[0]], "VCache": [caches[1]],
+               "Position": [jnp.full((rows,), pos, jnp.int32)]}
+        out = jax.jit(lambda ins: kernel(None, ins, {
+            "num_heads": heads, "num_kv_heads": kv_heads,
+            "diffusion_block": diffusion_block}))(ins)
+
+        def dense(q, k_cache, v_cache):
+            with jax.default_matmul_precision("highest"):
+                qh = q.astype(jnp.float32).reshape(
+                    rows, positions, kv_heads, group, dim)
+                s = jnp.einsum("btkgd,bksd->bkgts", qh,
+                               k_cache.astype(jnp.float32)) * dim ** -0.5
+                reach = pos + (jnp.arange(positions) // diffusion_block
+                               + 1) * diffusion_block
+                s = jnp.where(jnp.arange(slots)[None, :] < reach[:, None],
+                              s, -jnp.inf)
+                o = jnp.einsum("bkgts,bksd->btkgd", jax.nn.softmax(s, -1),
+                               v_cache.astype(jnp.float32))
+            return o.reshape(rows, positions, heads * dim)
+
+        want = np.asarray(jax.jit(dense)(q, out["KCacheOut"][0],
+                                         out["VCacheOut"][0]))
+        got = np.asarray(out["Out"][0], np.float32)
+        err = float(np.abs(got - want).max() / np.abs(want).max())
+        check(np.isfinite(got).all() and err < BF16_TOL,
+              "cached_attention under diffusion_block %d, %d positions at "
+              "%d: off the dense softmax by %.4f of its largest value"
+              % (diffusion_block, positions, pos, err))
+        print("  block-causal walk [%d, %d, %d x %d] over %d slots from "
+              "%d: within %.4f of the dense softmax"
+              % (rows, positions, heads, dim, slots, pos, err), flush=True)
+
+
 def resnet50_serve(image_size=224, class_dim=1000, buckets=(1, 4, 16),
                    sizes=(1, 2, 4, 3, 8, 16, 5, 1)):
     import jax
@@ -712,7 +767,8 @@ def main():
     print("compile cache: %s" % enable_compile_cache(), flush=True)
     clock = CompileClock()
     phases = [resnet50_train, transformer_train, moe_experts_check,
-              ssd_scan_check, selective_scan_check, resnet50_serve]
+              ssd_scan_check, selective_scan_check, block_causal_walk_check,
+              resnet50_serve]
     if len(devices) >= 4:
         phases.append(multichip)
     for phase in phases:

@@ -34,9 +34,9 @@ import jax
 import jax.numpy as jnp
 
 from ..jit import FunctionalProgram, state_from_scope
-from ..models.decode import (PREFILL_BLOCK, greedy_decode,
-                             beam_search_decode_dense, prefill,
-                             sample_decode)
+from ..models.decode import (PREFILL_BLOCK, block_diffusion_decode,
+                             greedy_decode, beam_search_decode_dense,
+                             prefill, sample_decode)
 from ..obs import telemetry
 from ..obs.trace import STARTUP, emit_span, span
 
@@ -47,7 +47,9 @@ _CALLS = itertools.count(1)
 
 
 class ProgramDecoder:
-    """Compiled greedy/beam generation from a single-step Program.
+    """Compiled greedy/sample/beam generation from a single-step Program,
+    and generation by diffusion over blocks (`diffuse`) from a step that
+    takes a block.
 
     The step program's contract: it reads a token feed (int tensor
     [batch]), any number of state feeds ([batch, ...]), and fetches
@@ -357,6 +359,79 @@ class ProgramDecoder:
                     out = fn(self._params, state, prompt,
                              jax.random.PRNGKey(seed))
             return call.fetch(out)
+
+    def diffuse(self, prompt, max_len, block_length, denoising_steps,
+                remasking, confidence_threshold, mask_id, temperature=0.0,
+                top_k=0, init_state=None, return_state=(), eos=None,
+                seed=0, hold=("pos",)):
+        """Generation by diffusion over blocks
+        (`models.decode.block_diffusion_decode`, read there for the
+        loop): `max_len` tokens after `prompt` [batch, P], in blocks of
+        `block_length` positions that each take up to `denoising_steps`
+        denoising passes and a commit pass, `remasking` one of
+        `models.decode.REMASKING`.  Greedy at `temperature` 0, else
+        sampled as `sample` samples (temperature, `top_k`, `seed`).
+
+        The step Program takes a block (token feed [batch, -1]) under a
+        block-causal mask of `block_length` (`cached_attention`'s
+        `diffusion_block`) and fetches the logits of every position it
+        is fed, [batch, T, vocab]
+        (`models/diffusion_moe_program.py`); `hold` names the state
+        feeds a pass that stores nothing hands on unchanged (the
+        position).  The extent has to hold the prompt's whole blocks and
+        every generated block whole.
+
+        Returns (tokens [batch, max_len], lengths [batch], info): info
+        holds "denoise_passes" and "commit_passes" (ints), "fixed_pass"
+        [batch, max_len] int32 and "fixed_conf" [batch, max_len]
+        float32 (the pass of its block that fixed a position, and the
+        confidence it was fixed at), and "state", {feed: array} of the
+        `return_state` feeds after the last commit."""
+        return_state = tuple(return_state)
+        if not self._takes_block:
+            raise ValueError(
+                "diffuse: the step program's token feed %r is declared "
+                "[batch]: a pass feeds a block of positions, so the step "
+                "declares [batch, -1]" % self.token_name)
+        with _Call(self, max_len) as call:
+            state, batch_size, prompt = call.prep(init_state, None, prompt)
+            if prompt is None:
+                raise ValueError("diffuse: a prompt [batch, P >= 1]")
+
+            def kept(*out):     # of the last state, what was asked for
+                return out[:-1] + ({f: out[-1][f] for f in return_state},)
+
+            blocks = -(-(prompt.shape[1] + max_len) // block_length)
+            if self.max_positions is not None \
+                    and blocks * block_length > self.max_positions:
+                raise ValueError(
+                    "diffuse: %d blocks of %d positions (prompt %d + %d "
+                    "generated) exceed the step program's extent %d"
+                    % (blocks, block_length, prompt.shape[1], max_len,
+                       self.max_positions))
+            fn = call.program(
+                ("diffuse", max_len, batch_size, prompt.shape[1],
+                 block_length, denoising_steps, remasking,
+                 confidence_threshold, mask_id, temperature, top_k, eos,
+                 return_state, tuple(hold)),
+                lambda: lambda params, s, p, rng: kept(*block_diffusion_decode(
+                    self._step_fn(params), s, p, max_len, block_length,
+                    denoising_steps, mask_id, remasking,
+                    confidence_threshold, temperature, top_k, rng, eos,
+                    tuple(hold), self._prefill_block)))
+            call.span.set(block_length=block_length,
+                          denoising_steps=denoising_steps)
+            with call.dispatch():
+                out = fn(self._params, state, prompt,
+                         jax.random.PRNGKey(seed))
+            toks, lengths, passes, at, conf, last = call.fetch(out)
+            denoised, committed = (int(passes[k])
+                                   for k in ("denoise", "commit"))
+            call.span.set(denoise_passes=denoised, commit_passes=committed)
+            telemetry.on_diffusion_call(denoised, committed, toks.size)
+        return toks, lengths, {
+            "denoise_passes": denoised, "commit_passes": committed,
+            "fixed_pass": at, "fixed_conf": conf, "state": last}
 
     def beam(self, beam_size, bos, eos, max_len, batch_size=None,
              init_state=None, length_penalty=0.0):

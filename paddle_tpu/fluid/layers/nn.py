@@ -39,7 +39,8 @@ __all__ = [
 def cached_attention(query, key, value, k_cache, v_cache, position,
                      num_heads=1, sm_scale=None, num_kv_heads=None,
                      window=0, name=None, selected=None, live=None,
-                     reader=0, shared_readers=0, prefill_block=None):
+                     reader=0, shared_readers=0, prefill_block=None,
+                     diffusion_block=0):
     """Attention through a KV cache over a block of T >= 1 consecutive
     positions of every row (ops/attention.py cached_attention; T = 1 is
     a decode step): query [batch, T, num_heads * head_dim], key/value
@@ -58,7 +59,10 @@ def cached_attention(query, key, value, k_cache, v_cache, position,
     position, `selected` [batch, T, top_k] and `live` [batch, T].
     `prefill_block`: the most positions a block of this op was sized
     for, which `fluid.ProgramDecoder` reads off the Program to prefill
-    by (a longer block is refused).
+    by (a longer block is refused).  `diffusion_block` B > 0: the
+    block-causal mask of generation by diffusion over blocks, T a
+    multiple of B and query i attends slots 0 .. position + B (i // B +
+    1) - 1, to the end of its own block of B.
     Returns (out [batch, T, num_heads * head_dim], k_cache_out,
     v_cache_out) — thread the cache outputs back as decode state
     (`fluid.ProgramDecoder` state pairs).
@@ -102,6 +106,8 @@ def cached_attention(query, key, value, k_cache, v_cache, position,
         attrs["shared_readers"] = int(shared_readers)
     if prefill_block:
         attrs["prefill_block"] = int(prefill_block)
+    if diffusion_block:
+        attrs["diffusion_block"] = int(diffusion_block)
     inputs = {"Q": [query], "KNew": [key], "VNew": [value],
               "KCache": [k_cache], "VCache": [v_cache],
               "Position": [position]}

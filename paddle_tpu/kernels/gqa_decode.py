@@ -161,6 +161,19 @@ opaque to the compiler otherwise, and its memory-space assignment then
 places the gathered copies in fast memory by chance (PERF.md section 6,
 PR 66).
 
+The block-causal mask (`gqa_decode`'s `diffusion` B > 0; generation by
+diffusion over blocks, `cached_attention`'s `diffusion_block`).  The T
+positions are T / B whole blocks of B counted from the block's first,
+and position t attends slots 0 .. last + B (t // B + 1) - 1: to the end
+of its own block, the later positions of it among them.  One line of the
+fold differs, the limit a row's scores are masked by; the last live slot,
+the blocks fetched and the blocks folded whole are what they were (a
+pass, T = B, masks nothing but the slots past the block; SDAR's pass of
+4 positions under groups of 8 is 32 rows a key/value head over one block
+of 1024 slots, the whole extent of the cell's cache, whatever the
+position).  The kernel's name says it, `_b<B>` after `_t<T>`, and with
+`diffusion` 0 every call is, argument for argument, what it was.
+
 Which shapes it takes (`fits`): S a multiple of 128; heads a multiple of
 128 wide (the lanes: one lane block a head, or two at 256) with G * T rows a key/value head small enough that their scores
 over the smallest block of slots fit VMEM beside the operands
@@ -172,7 +185,8 @@ first at 128 wide, and keeps the plain path at 64.
 Lowered for the TPU these are Mosaic kernels named
 `gqa_decode_k<block_k>` over a whole-extent cache,
 `gqa_decode_k<block_k>_t<T>` where T > 1 (a trace tells a prefill
-block's calls from a decode step's; `_d<head_dim>` after either where a
+block's calls from a decode step's; `_b<B>` after it under the
+block-causal mask; `_d<head_dim>` after either where a
 head is wider than the lanes), `gqa_decode_w<window>` over a ring,
 each with `_h<heads>` after it where a grid step takes several
 key/value heads (`_r<rows>` after that where rows share it too:
@@ -373,7 +387,7 @@ def _fold(q, keys, values, m_ref, l_ref, acc_ref, sm_scale, attended=None,
 
 
 def _kernel(last_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
-            sm_scale, bk, positions):
+            sm_scale, bk, positions, diffusion=0):
     """One grid step: block `j - dead` of each of the step's rows and
     key/value heads (blocks [rows, heads, ..]; one of each but where a
     head's block is no step's worth, `choose_step`) folded into its
@@ -400,8 +414,12 @@ def _kernel(last_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
             first = k * bk
             limit = last
             if positions > 1:   # row g * T + t is position t
-                limit = last + lax.rem(lax.broadcasted_iota(
+                at = lax.rem(lax.broadcasted_iota(
                     jnp.int32, (q_ref.shape[2], 1), 0), positions)
+                if diffusion:   # block-causal: to the end of t's block
+                    at = lax.div(at, diffusion) * diffusion \
+                        + (diffusion - 1)
+                limit = last + at
             attended = first + lax.broadcasted_iota(
                 jnp.int32, (1, bk), 1) <= limit
             held = first + lax.broadcasted_iota(
@@ -444,7 +462,7 @@ def _kernel(last_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 
 def _call(q, k_cache, v_cache, last, *, sm_scale, bk, positions, step, name,
-          interpret):
+          interpret, **block_causal):
     batch, kv_heads, rows, dim = q.shape
     steps = k_cache.shape[2] // bk
     shared = step if step != (1, 1) else ()     # the scratch's leading axes
@@ -461,7 +479,7 @@ def _call(q, k_cache, v_cache, last, *, sm_scale, bk, positions, step, name,
 
     return pl.pallas_call(
         functools.partial(_kernel, sm_scale=sm_scale, bk=bk,
-                          positions=positions),
+                          positions=positions, **block_causal),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(batch // step[0], kv_heads // step[1], steps),
@@ -760,7 +778,7 @@ def _by_platform(call, *operands):
 # instance share one traced body and one lowered function.
 
 @functools.partial(jax.jit, static_argnames=("sm_scale", "bk", "positions",
-                                             "step", "name"))
+                                             "step", "name", "diffusion"))
 def _wide(q, k_cache, v_cache, last, **static):
     return _by_platform(functools.partial(_call, **static), q, k_cache,
                         v_cache, last)
@@ -817,14 +835,16 @@ def write_step(k_cache, v_cache, k_new, v_new, at):
 
 
 def gqa_decode(q, k_cache, v_cache, last, sm_scale, window=0, block_k=None,
-               positions=1, step=None):
+               positions=1, step=None, diffusion=0):
     """The attended values of one decode step, or of a block of
     `positions` consecutive ones, [batch, kv_heads, group * positions,
     D] in q's type: see the module's docstring.  `window` names the
     kernel of a ring (`gqa_decode_w<window>`, one block, one position);
     `block_k` and `step` (the rows of the batch and the key/value heads
     a grid step takes, which have to divide them) are chosen from the
-    shapes unless given (tests, sweeps)."""
+    shapes unless given (tests, sweeps).  `diffusion` B > 0: the
+    block-causal mask over whole blocks of B positions (the module's
+    docstring), `_b<B>` in the kernel's name."""
     slots = k_cache.shape[2]
     narrow = q.shape[-1] == _NARROW
     if q.ndim != 4 or k_cache.shape != v_cache.shape \
@@ -833,12 +853,14 @@ def gqa_decode(q, k_cache, v_cache, last, sm_scale, window=0, block_k=None,
             or k_cache.dtype != q.dtype or v_cache.dtype != q.dtype \
             or positions < 1 or q.shape[2] % positions \
             or not fits(q.shape[2], slots, q.shape[3], q.dtype.itemsize) \
-            or (window and (window != slots or positions != 1 or narrow)):
+            or (window and (window != slots or positions != 1 or narrow)) \
+            or (diffusion and (window or narrow or positions % diffusion)):
         raise ValueError(
             "gqa_decode: queries %s %s at %d positions over caches %s %s "
-            "and %s %s (window %d) are no step the kernel takes"
+            "and %s %s (window %d, diffusion block %d) are no step the "
+            "kernel takes"
             % (q.shape, q.dtype, positions, k_cache.shape, k_cache.dtype,
-               v_cache.shape, v_cache.dtype, window))
+               v_cache.shape, v_cache.dtype, window, diffusion))
     bk = block_k or choose_block(slots, q.shape[2], q.dtype.itemsize,
                                  q.shape[3])
     last = jnp.reshape(last, (1,)).astype(jnp.int32)
@@ -859,12 +881,15 @@ def gqa_decode(q, k_cache, v_cache, last, sm_scale, window=0, block_k=None,
     name = "gqa_decode_w%d" % window if window else "gqa_decode_k%d" % bk
     if positions > 1:
         name += "_t%d" % positions
+    if diffusion:   # said only where asked: every other call is as it was
+        name += "_b%d" % diffusion
     if q.shape[3] != _LANES:
         name += "_d%d" % q.shape[3]
     if step != (1, 1):
         name += shared
     return _wide(q, k_cache, v_cache, last, sm_scale=float(sm_scale), bk=bk,
-                 positions=positions, step=step, name=name)
+                 positions=positions, step=step, name=name,
+                 **({"diffusion": diffusion} if diffusion else {}))
 
 
 def gqa_decode_chosen(q, k_chosen, v_chosen, live, sm_scale, chunk=None):

@@ -17,7 +17,7 @@ import jax.numpy as jnp
 from ..obs import telemetry
 
 __all__ = ["greedy_decode", "beam_search_decode_dense", "prefill",
-           "sample_decode"]
+           "sample_decode", "block_diffusion_decode", "REMASKING"]
 
 NEG_INF = -1e30
 
@@ -44,6 +44,16 @@ PREFILL_BLOCK = 128
 # decoding by them (benchmark/reduce/decoder_trace.py).
 PREFILL_SCOPE = "decode_prefill"
 STEPS_SCOPE = "decode_steps"
+
+# Generation by diffusion over blocks (`block_diffusion_decode`): what a
+# pass is under, inside `decode_steps`.  A pass that fixes positions and
+# stores nothing, the rule that fixes them, and a block's last pass,
+# whose keys and values the cache keeps.
+DENOISE_SCOPE = "diffusion_denoise"
+UNMASK_SCOPE = "diffusion_unmask"
+COMMIT_SCOPE = "diffusion_commit"
+REMASKING = ("low_confidence_static", "low_confidence_dynamic",
+             "sequential")
 
 
 def prefill(step_fn, init_state, prompt, takes_block=False, block=None):
@@ -245,3 +255,198 @@ def beam_search_decode_dense(step_fn, init_state, bos, eos, beam_size,
     sequences = jnp.take_along_axis(sequences, order[:, :, None], axis=1)
     final_scores = jnp.take_along_axis(final_scores, order, axis=1)
     return sequences, final_scores
+
+
+def _prefill_blocks(step_fn, state, tokens, block):
+    """The state after `tokens` [rows, n] went through a step that takes
+    a block, `block` positions an application (a shorter one first for
+    the remainder, the equal ones in one scan).  No logits are asked
+    for: whatever the step computes for them alone is dead code."""
+    rows, length = tokens.shape
+    if length % block:
+        state = step_fn(state, tokens[:, :length % block])[1]
+    if length >= block:
+        blocks = jnp.moveaxis(
+            tokens[:, length % block:].reshape(rows, -1, block), 1, 0)
+        state, _ = jax.lax.scan(
+            lambda state, toks: (step_fn(state, toks)[1], None), state,
+            blocks)
+    return state
+
+
+def _transfers(block_length, denoising_steps):
+    """k_s [denoising_steps]: the positions a block's s-th denoising
+    pass fixes at least, B // T and one more in the first B mod T."""
+    base, more = divmod(block_length, denoising_steps)
+    return jnp.asarray([base + (s < more) for s in range(denoising_steps)],
+                       jnp.int32)
+
+
+def _unmask(logits, masked, k, remasking, threshold, temperature, top_k,
+            key, mask_id):
+    """(x0, conf, fix) [rows, B] of one denoising pass: each position's
+    own prediction from its own row of `logits` [rows, B, V] (no shift),
+    the probability it was predicted at, and which of the `masked`
+    positions the pass fixes; `k` is the pass's k_s.  The mask token is
+    no prediction: its logit counts for nothing, in the choice and in
+    the probabilities (the published loop leaves it in, which a trained
+    model never picks and seeded weights do once in a vocabulary's worth
+    of positions: a position fixed to it would be masked again)."""
+    logits = jnp.where(jnp.arange(logits.shape[-1]) == mask_id, NEG_INF,
+                       logits.astype(jnp.float32))
+    if temperature > 0:
+        logits = logits / temperature
+        if top_k:
+            kth = jax.lax.top_k(logits, top_k)[0][..., -1:]
+            logits = jnp.where(logits < kth, NEG_INF, logits)
+        x0 = jax.random.categorical(key, logits, axis=-1)
+    else:
+        x0 = jnp.argmax(logits, axis=-1)
+    # softmax(l)[x0] from two reductions: no [rows, B, V] of
+    # probabilities is made
+    conf = jnp.exp(
+        jnp.take_along_axis(logits, x0[..., None], axis=-1)[..., 0]
+        - jax.nn.logsumexp(logits, axis=-1))
+    x0 = x0.astype(jnp.int32)
+    conf = jnp.where(masked, conf, -jnp.inf)
+    width = masked.shape[-1]
+    if remasking == "sequential":
+        fix = masked & (jnp.cumsum(masked, axis=-1) <= k)
+        return x0, conf, fix
+    # the k masked positions of largest confidence (of equals the first)
+    order = jax.lax.top_k(conf, width)[1]
+    rank = jnp.argsort(order, axis=-1)
+    fix = masked & (rank < k)
+    if remasking == "low_confidence_dynamic":
+        high = conf > threshold
+        enough = jnp.sum(high, axis=-1, keepdims=True) >= k
+        fix = jnp.where(enough, high, fix)
+    return x0, conf, fix
+
+
+def block_diffusion_decode(step_fn, init_state, prompt, gen_len,
+                           block_length, denoising_steps, mask_id,
+                           remasking="low_confidence_dynamic",
+                           confidence_threshold=0.9, temperature=0.0,
+                           top_k=0, rng=None, eos=None, hold=("pos",),
+                           prefill_block=PREFILL_BLOCK):
+    """Generation by diffusion over blocks (`block_diffusion_generate`
+    of github.com/JetLM/SDAR's generate.py), lockstep rows, under jit.
+
+    step_fn(state, tokens[rows, T]) -> (logits [rows, T, V], new_state)
+    takes T consecutive positions of every row from the position `state`
+    holds, T a multiple of `block_length` B, under a block-causal mask
+    (position i sees every position up to the end of its own block of
+    B), stores their keys and values and advances the position by T;
+    row i of the logits predicts position i's own token.  `state` is a
+    dict, and `hold` names the entries (the position) that a pass which
+    stores nothing hands on as it got them.
+
+    The prompt's first B floor(P / B) positions are prefilled,
+    `prefill_block` positions an application (cut to a multiple of B),
+    and no logits are made for them.  Then block after block: the P mod
+    B prompt tokens left over stand first in the first block and are
+    never rewritten, every other entry starts as `mask_id`.  A
+    *denoising pass* (`diffusion_denoise`) is the step over the block's
+    tokens c; its state is handed on but for `hold`, so the slots it
+    wrote are overwritten by the next pass and nothing ever reads them
+    (no position advanced, and no later block exists yet).  The rule
+    (`diffusion_unmask`) takes x0 = argmax (`temperature` 0) or a sample
+    (temperature, `top_k`), conf = softmax(l)[x0] where c is masked, and
+    with k_s = B // T + (s < B mod T) fixes, of the masked positions:
+    "low_confidence_static" the k_s of largest conf;
+    "low_confidence_dynamic" every one with conf > `confidence_threshold`
+    if those are at least k_s, else the k_s largest; "sequential" the
+    first k_s.  The *commit pass* (`diffusion_commit`) is the step once
+    more over the block's final tokens, kept whole: the cache holds what
+    the final tokens give and the position advances by B.
+
+    "low_confidence_static" and "sequential" are scans of `denoising_steps`
+    passes and a commit a block (a pass over a block with nothing masked
+    fixes nothing); "low_confidence_dynamic" is a loop of at most that
+    many that ends when no row has a masked position left (the published
+    loop's test, over the batch).
+
+    Returns (tokens [rows, gen_len], lengths [rows], passes {"denoise",
+    "commit"} int32 scalars, fixed_pass [rows, gen_len] int32, the pass
+    of its block (0 ..) that fixed a position, fixed_conf [rows,
+    gen_len] float32, the confidence it was fixed at, state): pass s of
+    a block was fed the final tokens where fixed_pass < s and `mask_id`
+    elsewhere, so a call's whole trajectory can be replayed from these.
+    `eos` only shapes `lengths` (the first eos and everything before it):
+    nothing stops early."""
+    if remasking not in REMASKING:
+        raise ValueError("block_diffusion_decode: remasking %r is none of %s"
+                         % (remasking, list(REMASKING)))
+    if not 1 <= denoising_steps <= block_length:
+        raise ValueError(
+            "block_diffusion_decode: %d denoising steps for a block of %d "
+            "(every pass fixes a position at least)"
+            % (denoising_steps, block_length))
+    prompt = jnp.asarray(prompt, jnp.int32)
+    rows, length = prompt.shape
+    whole = length // block_length * block_length
+    left = length - whole
+    blocks = -(-(left + gen_len) // block_length)
+    transfers = _transfers(block_length, denoising_steps)
+    dynamic = remasking == "low_confidence_dynamic"
+    rng = jax.random.PRNGKey(0) if rng is None else rng
+
+    def handed_on(state, new):
+        return dict(new, **{name: state[name] for name in hold})
+
+    def denoise(carry, s):
+        state, c, at, conf_at, key = carry
+        key, sub = jax.random.split(key)
+        with jax.named_scope(DENOISE_SCOPE):
+            logits, new = step_fn(state, c)
+        with jax.named_scope(UNMASK_SCOPE):
+            x0, conf, fix = _unmask(
+                logits, c == mask_id, transfers[s], remasking,
+                confidence_threshold, temperature, top_k, sub, mask_id)
+            c = jnp.where(fix, x0, c)
+            at = jnp.where(fix, s, at)
+            conf_at = jnp.where(fix, conf, conf_at)
+        return handed_on(state, new), c, at, conf_at, key
+
+    def one_block(carry, c):
+        state, key, taken = carry
+        start = (state, c, jnp.full(c.shape, -1, jnp.int32),
+                 jnp.zeros(c.shape, jnp.float32), key)
+        if dynamic:
+            s, (state, c, at, conf_at, key) = jax.lax.while_loop(
+                lambda loop: (loop[0] < denoising_steps)
+                & jnp.any(loop[1][1] == mask_id),
+                lambda loop: (loop[0] + 1, denoise(loop[1], loop[0])),
+                (jnp.int32(0), start))
+        else:
+            (state, c, at, conf_at, key), _ = jax.lax.scan(
+                lambda carry, s: (denoise(carry, s), None), start,
+                jnp.arange(denoising_steps, dtype=jnp.int32))
+            s = jnp.int32(denoising_steps)
+        with jax.named_scope(COMMIT_SCOPE):
+            state = step_fn(state, c)[1]
+        return (state, key, taken + s), (c, at, conf_at)
+
+    with jax.named_scope(PREFILL_SCOPE):
+        telemetry.on_prefill_lowering("block", prefill_block)
+        state = init_state if not whole else _prefill_blocks(
+            step_fn, init_state, prompt[:, :whole],
+            max(prefill_block // block_length, 1) * block_length)
+    first = jnp.full((blocks, rows, block_length), mask_id, jnp.int32)
+    first = first.at[0, :, :left].set(prompt[:, whole:])
+    with jax.named_scope(STEPS_SCOPE):
+        (state, _, denoised), (toks, at, conf_at) = jax.lax.scan(
+            one_block, (state, rng, jnp.int32(0)), first)
+
+    def generated(x):   # [blocks, rows, B] -> [rows, gen_len]
+        return jnp.moveaxis(x, 0, 1).reshape(rows, -1)[
+            :, left:left + gen_len]
+
+    toks = generated(toks)
+    lengths = jnp.full((rows,), gen_len, jnp.int32)
+    if eos is not None:
+        lengths = jnp.where(jnp.any(toks == eos, axis=1),
+                            jnp.argmax(toks == eos, axis=1) + 1, lengths)
+    passes = {"denoise": denoised, "commit": jnp.int32(blocks)}
+    return toks, lengths, passes, generated(at), generated(conf_at), state

@@ -13,7 +13,8 @@ from .. import fluid
 from ..fluid.layer_helper import LayerHelper
 from ..fluid.param_attr import ParamAttr
 
-__all__ = ["linear", "linear_float32", "norm", "attention", "gated_feed_forward",
+__all__ = ["linear", "linear_float32", "norm", "head_norm", "attention",
+           "gated_feed_forward",
            "share_feed_forward", "token_feeds", "head_cross_entropy",
            "block_positions", "last", "last_token_rows"]
 
@@ -50,6 +51,14 @@ def linear_float32(x, size, name):
 def norm(x, eps, name):
     return fluid.layers.rms_norm(x, epsilon=eps,
                                  param_attr=ParamAttr(name=name))
+
+
+def head_norm(t, heads, d_head, eps, name):
+    """RMSNorm over each head's `d_head` values of t [batch, T, heads *
+    d_head], one learned [d_head] scale `name` for every head (the
+    Qwen3 block's q and k norms)."""
+    t = norm(fluid.layers.reshape(t, [0, 0, heads, d_head]), eps, name)
+    return fluid.layers.reshape(t, [0, 0, heads * d_head])
 
 
 def token_feeds(batch, seq_len):

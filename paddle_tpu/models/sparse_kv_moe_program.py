@@ -54,8 +54,9 @@ hold this to.
 
 from .. import fluid
 from ..fluid.param_attr import ParamAttr
-from .decoder_block import (block_positions, last, last_token_rows, linear,
-                            norm, share_feed_forward)
+from .decoder_block import (block_positions, head_norm, last,
+                            last_token_rows, linear, norm,
+                            share_feed_forward)
 from .latent_moe_program import sized_block
 
 __all__ = ["build_sparse_kv_moe_cached_step_program",
@@ -174,12 +175,6 @@ def build_sparse_kv_moe_cached_step_program(
             """RMSNorm of the float32 stream, in the weights' type."""
             return fluid.layers.cast(norm(t, eps, name), embedded)
 
-        def head_norm(t, heads, name):
-            """RMSNorm over each head's `d_head` values."""
-            t = norm(fluid.layers.reshape(t, [0, 0, heads, d_head]), eps,
-                     name)
-            return fluid.layers.reshape(t, [0, 0, heads * d_head])
-
         def turn(t, heads):
             return fluid.layers.rope(t, three, heads, rope_theta,
                                      sections=sections)
@@ -192,9 +187,11 @@ def build_sparse_kv_moe_cached_step_program(
             h = normed(x, block["input_norm"])
             parts["attn_in"].append(final(h))
             q = turn(head_norm(linear(h, n_head * d_head, block["wq"]),
-                               n_head, block["q_norm"]), n_head)
+                               n_head, d_head, eps, block["q_norm"]),
+                     n_head)
             k = turn(head_norm(linear(h, n_kv_head * d_head, block["wk"]),
-                               n_kv_head, block["k_norm"]), n_kv_head)
+                               n_kv_head, d_head, eps, block["k_norm"]),
+                     n_kv_head)
             v = linear(h, n_kv_head * d_head, block["wv"])
             k_index = fluid.layers.layer_norm(
                 linear(h, i_dim, block["w_ik"]), begin_norm_axis=2,

@@ -334,6 +334,24 @@ def on_window_attention_lowering(kind, kv_heads, window, path, block_k,
     _kv_cache_slots(kind, slots)
 
 
+def on_block_causal_attention_lowering(diffusion_block, block, path):
+    """A `cached_attention` op under the block-causal mask of generation
+    by diffusion over blocks (its `diffusion_block` attr, B) was traced
+    into a program: over `block` positions of a row an application (B: a
+    pass over one block; more: a prompt's prefill, block / B whole
+    blocks), by the way it takes over the cache.  The same op counts in
+    `window_attention_lowerings_total` under kind "block_causal".  One
+    count per op instance a lowered program holds."""
+    _reg().counter("block_causal_attention_lowerings_total",
+                   "key/value-cached attention ops lowered under the "
+                   "block-causal mask, by the diffusion block's length, "
+                   "the positions of a row one application takes, and "
+                   "path",
+                   labelnames=("diffusion_block", "block", "path")) \
+          .labels(diffusion_block=diffusion_block, block=block,
+                  path=path).inc()
+
+
 def on_sparse_attention_lowering(kv_heads, top_k, slots, path, block_k,
                                  positions=1, tile=1):
     """A `cached_attention` op (ops/attention.py) was traced into a
@@ -425,6 +443,31 @@ def on_moe_share_compact_lowering(rows, chunk):
                    "and a chunk's rows",
                    labelnames=("rows", "chunk")) \
           .labels(rows=rows, chunk=chunk).inc()
+
+
+def on_diffusion_call(denoise_passes, commit_passes, tokens):
+    """One `ProgramDecoder.diffuse` call has returned (generation by
+    diffusion over blocks, models/decode.py `block_diffusion_decode`):
+    the passes its blocks took by kind, read off the call's own results
+    ("denoise": a pass that fixed positions of a block by confidence and
+    stored nothing; "commit": a block's last pass, over its final
+    tokens, whose keys and values the cache keeps: one a block, so they
+    count the blocks committed too) and the tokens it generated (rows x
+    the generated length).  Tokens over passes is what a pass yields: none, one or
+    several a row."""
+    reg = _reg()
+    family = reg.counter("decoder_diffusion_passes_total",
+                         "passes of block-diffusion generation calls, by "
+                         "kind (denoise: fixes positions, stores nothing; "
+                         "commit: writes the cache)", labelnames=("kind",))
+    family.labels(kind="denoise").inc(denoise_passes)
+    family.labels(kind="commit").inc(commit_passes)
+    reg.counter("decoder_diffusion_blocks_total",
+                "blocks block-diffusion generation calls committed") \
+       .inc(commit_passes)
+    reg.counter("decoder_diffusion_tokens_total",
+                "tokens block-diffusion generation calls generated (rows "
+                "x generated length)").inc(tokens)
 
 
 def on_prefill_lowering(form, block):

@@ -440,6 +440,19 @@ def cached_attention_op(ctx, ins, attrs):
     numbers them from 1) and `shared_readers`, on the op that writes
     such a cache, how many they are: both are for the counters alone
     (obs/telemetry.py `on_decoder_positions`).
+
+    `diffusion_block` B > 0 (whole-extent caches it writes, no chosen
+    set; T a multiple of B) is the **block-causal** mask of generation
+    by diffusion over blocks: the T positions are T / B blocks of B
+    counted from Position (which such a decoder keeps a multiple of B),
+    and query i attends slots 0 .. Position + B (i // B + 1) - 1, every
+    slot up to the end of its own block, the later positions of its
+    block among them.  A denoising pass is T = B (all of the block, both
+    directions), a prompt's prefill many whole blocks.  The slots are
+    written as ever, before they are read: a pass that is not to be kept
+    is overwritten by the next, and nothing reads past the block.  Its
+    scope is `attn_block_causal`, the walk's kernel says `_b<B>`, and
+    with the attr 0 the op is, instruction for instruction, what it was.
     """
     q = ins["Q"][0]
     readonly = not ins.get("KNew")
@@ -454,8 +467,17 @@ def cached_attention_op(ctx, ins, attrs):
     kv_heads = int(attrs.get("num_kv_heads", 0)) or num_heads
     window = int(attrs.get("window", 0))
     sm_scale = float(attrs.get("sm_scale", 0.0)) or None
+    diffusion = int(attrs.get("diffusion_block", 0))
     rows, block, width = q.shape
     extent = k_cache.shape[2]
+    if diffusion and (window or readonly or selected is not None
+                      or block % diffusion):
+        raise ValueError(
+            "cached_attention: diffusion_block %d over a block of %d "
+            "positions (window %d%s%s): the block-causal mask is whole "
+            "blocks' over whole-extent caches the op writes"
+            % (diffusion, block, window, ", read-only" if readonly else "",
+               ", Selected" if selected is not None else ""))
     if num_heads % kv_heads or k_cache.shape[1] != kv_heads \
             or (window and window != extent):
         raise ValueError(
@@ -485,7 +507,8 @@ def cached_attention_op(ctx, ins, attrs):
             % (window, ", Selected" if selected is not None else ""))
     group = num_heads // kv_heads
     kind = "cross" if readonly else "window" if window \
-        else "full" if selected is None else "sparse"
+        else "sparse" if selected is not None \
+        else "block_causal" if diffusion else "full"
     ring_block = window > 0 and block > 1
     # the slots a query's products run over: a chosen set's, else the
     # cache's own
@@ -546,6 +569,9 @@ def cached_attention_op(ctx, ins, attrs):
         telemetry.on_window_attention_lowering(
             kind, kv_heads, window, "kernel" if block_k else "plain",
             block_k, extent, block, step)
+        if diffusion:
+            telemetry.on_block_causal_attention_lowering(
+                diffusion, block, "kernel" if block_k else "plain")
     else:
         telemetry.on_sparse_attention_lowering(
             kv_heads, attended, extent, "kernel" if block_k else "plain",
@@ -614,7 +640,8 @@ def cached_attention_op(ctx, ins, attrs):
             out = gqa_decode.gqa_decode(
                 qh.reshape(rows, kv_heads, group * block, head_dim),
                 k_live.astype(q.dtype), v_live.astype(q.dtype), last,
-                sm_scale, window, block_k, block, step)
+                sm_scale, window, block_k, block, step,
+                **({"diffusion": diffusion} if diffusion else {}))
         else:
             keys, values, valid = _ring_before_a_block(
                 before, (kh, vh), pos) if ring_block \
@@ -625,7 +652,12 @@ def cached_attention_op(ctx, ins, attrs):
             s = jnp.einsum("bh...qd,bhkd->bh...qk", qh.astype(jnp.float32),
                            keys.astype(jnp.float32),
                            precision=highest) * sm_scale
-            if valid is None:
+            if diffusion:
+                # query i attends to the end of its block of B
+                valid = jnp.arange(attended)[None, :] <= last + (
+                    jnp.arange(block)[:, None] // diffusion * diffusion
+                    + (diffusion - 1))
+            elif valid is None:
                 valid = jnp.arange(attended)[None, :] \
                     <= last + jnp.arange(block)[:, None]
             s = jnp.where(valid[(None,) * (s.ndim - 2)], s, -1e30)

@@ -405,6 +405,35 @@ def test_the_latent_decode_kernel_takes_32_heads(block, name):
     assert 'kernel_name = "%s"' % name in module
 
 
+@pytest.mark.parametrize("block,kernel_name", [
+    (4, "gqa_decode_k1024_t4_b4"), (128, "gqa_decode_k1024_t128_b4")])
+def test_the_block_causal_walk_lowers_for_tpu(block, kernel_name):
+    """`cached_attention` under `diffusion_block` 4 at sdar-diffuse-pp8's
+    shapes (128 rows, 32 query heads over 4 key/value heads of 128, a
+    1024-slot bfloat16 cache) lowered for the TPU from this CPU host: a
+    pass over one block of 4 and a prefill block of 128 positions both
+    walk the live slots, and the kernel's name says the mask's block."""
+    from paddle_tpu.ops import registry
+
+    kernel = registry.get_op_info("cached_attention").kernel
+    b, h, kv, d, bf16 = 128, 32, 4, 128, jnp.bfloat16
+    cache = jax.ShapeDtypeStruct((b, kv, 1024, d), bf16)
+    ins = {"Q": [jax.ShapeDtypeStruct((b, block, h * d), bf16)],
+           "KNew": [jax.ShapeDtypeStruct((b, block, kv * d), bf16)],
+           "VNew": [jax.ShapeDtypeStruct((b, block, kv * d), bf16)],
+           "KCache": [cache], "VCache": [cache],
+           "Position": [jax.ShapeDtypeStruct((b,), jnp.int32)]}
+
+    def step(ins):
+        return kernel(None, ins, {"num_heads": h, "num_kv_heads": kv,
+                                  "diffusion_block": 4})
+
+    module = jax.export.export(jax.jit(step), platforms=["tpu"])(
+        ins).mlir_module()
+    assert module.count("tpu_custom_call") == 1
+    assert 'kernel_name = "%s"' % kernel_name in module
+
+
 @pytest.mark.parametrize("block,window,kernels", [
     (1, 0, ["gqa_decode_k2048"]), (1, 128, ["gqa_decode_w128_h8"]),
     (128, 0, ["gqa_decode_k1024_t128"]), (128, 128, [])])
