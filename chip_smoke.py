@@ -579,16 +579,19 @@ def block_causal_walk_check(rows=8, heads=32, kv_heads=4, dim=128, slots=1024,
     """`cached_attention` under the block-causal mask of generation by
     diffusion over blocks (`diffusion_block`: the walk of the live slots,
     `gqa_decode_k<slots>_t<T>_b<B>`) at SDAR-30B-A3B-Chat's widths, a
-    pass over one block (T = 4) behind 640 stored slots and a prefill
-    block (T = 128) behind 128, against a float32 softmax over the slots
-    to the end of each query's block, on the device."""
+    pass over one block (T = 4) behind 640 stored slots, a commit and
+    the next block's first pass in one application (T = 8) from 644, a
+    multiple of 4 and not of 8, and a prefill block (T = 128) behind
+    128, against a float32 softmax over the slots to the end of each
+    query's block, on the device."""
     import jax
     import jax.numpy as jnp
     from paddle_tpu.ops import registry
 
     kernel = registry.get_op_info("cached_attention").kernel
     bf16, group = jnp.bfloat16, heads // kv_heads
-    for positions, pos in ((diffusion_block, 640), (128, 128)):
+    for positions, pos in ((diffusion_block, 640),
+                           (2 * diffusion_block, 644), (128, 128)):
         keys = jax.random.split(jax.random.PRNGKey(positions), 5)
         q = jax.random.normal(keys[0], (rows, positions, heads * dim), bf16)
         new = [jax.random.normal(k, (rows, positions, kv_heads * dim), bf16)

@@ -368,25 +368,34 @@ class ProgramDecoder:
         (`models.decode.block_diffusion_decode`, read there for the
         loop): `max_len` tokens after `prompt` [batch, P], in blocks of
         `block_length` positions that each take up to `denoising_steps`
-        denoising passes and a commit pass, `remasking` one of
-        `models.decode.REMASKING`.  Greedy at `temperature` 0, else
-        sampled as `sample` samples (temperature, `top_k`, `seed`).
+        denoising passes and a commit, `remasking` one of
+        `models.decode.REMASKING`.  A block's commit rides on the next
+        block's first denoising pass, one application of the step over
+        both blocks; the last block's is an application of its own.
+        Greedy at `temperature` 0, else sampled as `sample` samples
+        (temperature, `top_k`, `seed`).
 
         The step Program takes a block (token feed [batch, -1]) under a
         block-causal mask of `block_length` (`cached_attention`'s
         `diffusion_block`) and fetches the logits of every position it
         is fed, [batch, T, vocab]
         (`models/diffusion_moe_program.py`); `hold` names the state
-        feeds a pass that stores nothing hands on unchanged (the
-        position).  The extent has to hold the prompt's whole blocks and
-        every generated block whole.
+        feeds that count positions (the position: the step adds the
+        positions it was fed), which the loop sets itself.  The extent
+        has to hold the prompt's whole blocks and every generated block
+        whole.
 
         Returns (tokens [batch, max_len], lengths [batch], info): info
-        holds "denoise_passes" and "commit_passes" (ints), "fixed_pass"
-        [batch, max_len] int32 and "fixed_conf" [batch, max_len]
-        float32 (the pass of its block that fixed a position, and the
-        confidence it was fixed at), and "state", {feed: array} of the
-        `return_state` feeds after the last commit."""
+        holds "denoise_passes" and "commit_passes" (ints: the denoising
+        passes and the blocks committed, one a block wherever its commit
+        ran, not the step's applications), "folded_commits" (of the
+        commits, those that rode on a denoising pass: every block's but
+        the last) and "step_applications" (the step's applications
+        after the prefill: every denoising pass and the last commit),
+        "fixed_pass" [batch, max_len] int32 and "fixed_conf" [batch,
+        max_len] float32 (the pass of its block that fixed a position,
+        and the confidence it was fixed at), and "state", {feed: array}
+        of the `return_state` feeds after the last commit."""
         return_state = tuple(return_state)
         if not self._takes_block:
             raise ValueError(
@@ -425,13 +434,14 @@ class ProgramDecoder:
                 out = fn(self._params, state, prompt,
                          jax.random.PRNGKey(seed))
             toks, lengths, passes, at, conf, last = call.fetch(out)
-            denoised, committed = (int(passes[k])
-                                   for k in ("denoise", "commit"))
-            call.span.set(denoise_passes=denoised, commit_passes=committed)
-            telemetry.on_diffusion_call(denoised, committed, toks.size)
-        return toks, lengths, {
-            "denoise_passes": denoised, "commit_passes": committed,
-            "fixed_pass": at, "fixed_conf": conf, "state": last}
+            counted = {"denoise_passes": int(passes["denoise"]),
+                       "commit_passes": int(passes["commit"]),
+                       "folded_commits": int(passes["folded"]),
+                       "step_applications": int(passes["applications"])}
+            call.span.set(**counted)
+            telemetry.on_diffusion_call(tokens=toks.size, **counted)
+        return toks, lengths, dict(counted, fixed_pass=at, fixed_conf=conf,
+                                   state=last)
 
     def beam(self, beam_size, bos, eos, max_len, batch_size=None,
              init_state=None, length_penalty=0.0):

@@ -46,10 +46,13 @@ PREFILL_SCOPE = "decode_prefill"
 STEPS_SCOPE = "decode_steps"
 
 # Generation by diffusion over blocks (`block_diffusion_decode`): what a
-# pass is under, inside `decode_steps`.  A pass that fixes positions and
-# stores nothing, the rule that fixes them, and a block's last pass,
-# whose keys and values the cache keeps.
+# pass is under, inside `decode_steps`.  A pass that fixes positions of
+# a block (inside it, the application that also stores the block before
+# for good: a block's first pass), the rule that fixes them, and the
+# last block's commit, an application of its own after the scan of
+# blocks.
 DENOISE_SCOPE = "diffusion_denoise"
+FOLD_SCOPE = "diffusion_fold"
 UNMASK_SCOPE = "diffusion_unmask"
 COMMIT_SCOPE = "diffusion_commit"
 REMASKING = ("low_confidence_static", "low_confidence_dynamic",
@@ -339,8 +342,9 @@ def block_diffusion_decode(step_fn, init_state, prompt, gen_len,
     (position i sees every position up to the end of its own block of
     B), stores their keys and values and advances the position by T;
     row i of the logits predicts position i's own token.  `state` is a
-    dict, and `hold` names the entries (the position) that a pass which
-    stores nothing hands on as it got them.
+    dict, and `hold` names its entries that count positions (the
+    position: the step adds T to each), which the loop sets itself: a
+    pass that stores nothing for good leaves them where they stood.
 
     The prompt's first B floor(P / B) positions are prefilled,
     `prefill_block` positions an application (cut to a multiple of B),
@@ -357,24 +361,48 @@ def block_diffusion_decode(step_fn, init_state, prompt, gen_len,
     "low_confidence_static" the k_s of largest conf;
     "low_confidence_dynamic" every one with conf > `confidence_threshold`
     if those are at least k_s, else the k_s largest; "sequential" the
-    first k_s.  The *commit pass* (`diffusion_commit`) is the step once
-    more over the block's final tokens, kept whole: the cache holds what
-    the final tokens give and the position advances by B.
+    first k_s.  A block's *commit* is the step over its final tokens,
+    kept: the cache holds what the final tokens give and the position
+    stands B further.
 
-    "low_confidence_static" and "sequential" are scans of `denoising_steps`
-    passes and a commit a block (a pass over a block with nothing masked
-    fixes nothing); "low_confidence_dynamic" is a loop of at most that
-    many that ends when no row has a masked position left (the published
-    loop's test, over the batch).
+    A commit rides on the next block's first denoising pass
+    (`diffusion_fold`, inside `diffusion_denoise`): one application over
+    the 2B positions [the block before's final tokens | c] from the
+    block before's first position, of whose logits the last B rows are
+    read and whose state is kept with the position B further.  Under the
+    mask that is the commit and the pass as two applications would give
+    them (the block before sees the cache and itself; c sees the cache,
+    the block before as just stored, and itself) and every weight is
+    read once where twice.  The first generated block's "block before"
+    is the prompt's last whole block, fed again at its own positions, so
+    that every block starts on the same application; a prompt shorter
+    than a block has none, and its first block starts on a plain pass.
+    Only the last block's commit is an application of its own
+    (`diffusion_commit`, after the scan of blocks; no logits read).  A
+    block's first pass always runs (a fresh block holds a mask), so it
+    stands before the loop of the others: "low_confidence_static" and
+    "sequential" scan the `denoising_steps` - 1 others (a pass over a
+    block with nothing masked fixes nothing), "low_confidence_dynamic"
+    loops over at most that many and ends when no row has a masked
+    position left (the published loop's test, over the batch), which
+    only brings the next block's first pass, and the commit on it,
+    sooner.
 
-    Returns (tokens [rows, gen_len], lengths [rows], passes {"denoise",
-    "commit"} int32 scalars, fixed_pass [rows, gen_len] int32, the pass
-    of its block (0 ..) that fixed a position, fixed_conf [rows,
-    gen_len] float32, the confidence it was fixed at, state): pass s of
-    a block was fed the final tokens where fixed_pass < s and `mask_id`
-    elsewhere, so a call's whole trajectory can be replayed from these.
-    `eos` only shapes `lengths` (the first eos and everything before it):
-    nothing stops early."""
+    Returns (tokens [rows, gen_len], lengths [rows], passes, fixed_pass
+    [rows, gen_len] int32, the pass of its block (0 ..) that fixed a
+    position, fixed_conf [rows, gen_len] float32, the confidence it was
+    fixed at, state): pass s of a block was fed the final tokens where
+    fixed_pass < s and `mask_id` elsewhere, so a call's whole trajectory
+    can be replayed from these.  `passes` holds int32 scalars that count
+    what was done, not how many applications it took: "denoise" the
+    denoising passes and "commit" the blocks committed (one a block,
+    wherever the commit ran); beside them "folded", the commits of those
+    that rode on a denoising pass (every block's but the last), and
+    "applications", the step's applications after the prefill (every
+    denoising pass and the last commit: `blocks * denoising_steps + 1`
+    where no block ends early, `blocks * (denoising_steps + 1)` with a
+    commit pass a block).  `eos` only shapes `lengths` (the first eos
+    and everything before it): nothing stops early."""
     if remasking not in REMASKING:
         raise ValueError("block_diffusion_decode: remasking %r is none of %s"
                          % (remasking, list(REMASKING)))
@@ -392,14 +420,27 @@ def block_diffusion_decode(step_fn, init_state, prompt, gen_len,
     dynamic = remasking == "low_confidence_dynamic"
     rng = jax.random.PRNGKey(0) if rng is None else rng
 
-    def handed_on(state, new):
-        return dict(new, **{name: state[name] for name in hold})
+    def standing(new, state, further):
+        """`new` with the position `further` positions past `state`'s."""
+        return dict(new, **{name: state[name] + further for name in hold})
 
-    def denoise(carry, s):
+    def denoise(carry, s, before=None):
+        """Pass s of a block; given `before`, the final tokens of the
+        block before, the same application commits them."""
         state, c, at, conf_at, key = carry
         key, sub = jax.random.split(key)
         with jax.named_scope(DENOISE_SCOPE):
-            logits, new = step_fn(state, c)
+            if before is None:
+                logits, new = step_fn(state, c)
+            else:
+                with jax.named_scope(FOLD_SCOPE):
+                    logits, new = step_fn(
+                        state, jnp.concatenate([before, c], axis=1))
+                    # before anything casts or reduces them: the rule
+                    # never sees the block before's rows
+                    logits = logits[:, block_length:]
+            state = standing(new, state,
+                             0 if before is None else block_length)
         with jax.named_scope(UNMASK_SCOPE):
             x0, conf, fix = _unmask(
                 logits, c == mask_id, transfers[s], remasking,
@@ -407,26 +448,30 @@ def block_diffusion_decode(step_fn, init_state, prompt, gen_len,
             c = jnp.where(fix, x0, c)
             at = jnp.where(fix, s, at)
             conf_at = jnp.where(fix, conf, conf_at)
-        return handed_on(state, new), c, at, conf_at, key
+        return state, c, at, conf_at, key
 
     def one_block(carry, c):
-        state, key, taken = carry
-        start = (state, c, jnp.full(c.shape, -1, jnp.int32),
-                 jnp.zeros(c.shape, jnp.float32), key)
+        """A block's denoising passes: `carry` holds the state at the
+        block before's first position and its final tokens (at the
+        block's own and None where none is before), and leaves this
+        block's so."""
+        state, before, key, taken = carry
+        loop = denoise((state, c, jnp.full(c.shape, -1, jnp.int32),
+                        jnp.zeros(c.shape, jnp.float32), key), jnp.int32(0),
+                       before)
         if dynamic:
-            s, (state, c, at, conf_at, key) = jax.lax.while_loop(
-                lambda loop: (loop[0] < denoising_steps)
-                & jnp.any(loop[1][1] == mask_id),
-                lambda loop: (loop[0] + 1, denoise(loop[1], loop[0])),
-                (jnp.int32(0), start))
+            s, loop = jax.lax.while_loop(
+                lambda it: (it[0] < denoising_steps)
+                & jnp.any(it[1][1] == mask_id),
+                lambda it: (it[0] + 1, denoise(it[1], it[0])),
+                (jnp.int32(1), loop))
         else:
-            (state, c, at, conf_at, key), _ = jax.lax.scan(
-                lambda carry, s: (denoise(carry, s), None), start,
-                jnp.arange(denoising_steps, dtype=jnp.int32))
+            loop, _ = jax.lax.scan(
+                lambda loop, s: (denoise(loop, s), None), loop,
+                jnp.arange(1, denoising_steps, dtype=jnp.int32))
             s = jnp.int32(denoising_steps)
-        with jax.named_scope(COMMIT_SCOPE):
-            state = step_fn(state, c)[1]
-        return (state, key, taken + s), (c, at, conf_at)
+        state, c, at, conf_at, key = loop
+        return (state, c, key, taken + s), (c, at, conf_at)
 
     with jax.named_scope(PREFILL_SCOPE):
         telemetry.on_prefill_lowering("block", prefill_block)
@@ -436,8 +481,22 @@ def block_diffusion_decode(step_fn, init_state, prompt, gen_len,
     first = jnp.full((blocks, rows, block_length), mask_id, jnp.int32)
     first = first.at[0, :, :left].set(prompt[:, whole:])
     with jax.named_scope(STEPS_SCOPE):
-        (state, _, denoised), (toks, at, conf_at) = jax.lax.scan(
-            one_block, (state, rng, jnp.int32(0)), first)
+        if whole:
+            carry = (standing(state, state, -block_length),
+                     prompt[:, whole - block_length:whole], rng,
+                     jnp.int32(0))
+            carry, fixed = jax.lax.scan(one_block, carry, first)
+        else:
+            carry, head = one_block((state, None, rng, jnp.int32(0)),
+                                    first[0])
+            carry, fixed = jax.lax.scan(one_block, carry, first[1:])
+            fixed = jax.tree_util.tree_map(
+                lambda one, others: jnp.concatenate([one[None], others]),
+                head, fixed)
+        state, last, _, denoised = carry
+        with jax.named_scope(COMMIT_SCOPE):
+            state = step_fn(state, last)[1]
+    toks, at, conf_at = fixed
 
     def generated(x):   # [blocks, rows, B] -> [rows, gen_len]
         return jnp.moveaxis(x, 0, 1).reshape(rows, -1)[
@@ -448,5 +507,6 @@ def block_diffusion_decode(step_fn, init_state, prompt, gen_len,
     if eos is not None:
         lengths = jnp.where(jnp.any(toks == eos, axis=1),
                             jnp.argmax(toks == eos, axis=1) + 1, lengths)
-    passes = {"denoise": denoised, "commit": jnp.int32(blocks)}
+    passes = {"denoise": denoised, "commit": jnp.int32(blocks),
+              "folded": jnp.int32(blocks - 1), "applications": denoised + 1}
     return toks, lengths, passes, generated(at), generated(conf_at), state

@@ -445,16 +445,20 @@ def on_moe_share_compact_lowering(rows, chunk):
           .labels(rows=rows, chunk=chunk).inc()
 
 
-def on_diffusion_call(denoise_passes, commit_passes, tokens):
+def on_diffusion_call(denoise_passes, commit_passes, folded_commits,
+                      step_applications, tokens):
     """One `ProgramDecoder.diffuse` call has returned (generation by
     diffusion over blocks, models/decode.py `block_diffusion_decode`):
     the passes its blocks took by kind, read off the call's own results
     ("denoise": a pass that fixed positions of a block by confidence and
-    stored nothing; "commit": a block's last pass, over its final
-    tokens, whose keys and values the cache keeps: one a block, so they
-    count the blocks committed too) and the tokens it generated (rows x
-    the generated length).  Tokens over passes is what a pass yields: none, one or
-    several a row."""
+    stored nothing for good; "commit": the step over a block's final
+    tokens, whose keys and values the cache keeps: one a block wherever
+    it ran, so they count the blocks committed too) and the tokens it
+    generated (rows x the generated length).  Tokens over passes is what
+    a pass yields: none, one or several a row.  What the passes cost is
+    counted apart: `folded_commits` of the commits rode on the next
+    block's first denoising pass, and the step was applied
+    `step_applications` times after the prefill."""
     reg = _reg()
     family = reg.counter("decoder_diffusion_passes_total",
                          "passes of block-diffusion generation calls, by "
@@ -465,6 +469,13 @@ def on_diffusion_call(denoise_passes, commit_passes, tokens):
     reg.counter("decoder_diffusion_blocks_total",
                 "blocks block-diffusion generation calls committed") \
        .inc(commit_passes)
+    reg.counter("decoder_diffusion_folded_commits_total",
+                "commits of block-diffusion generation calls that rode on "
+                "the next block's first denoising pass").inc(folded_commits)
+    reg.counter("decoder_diffusion_applications_total",
+                "applications of the step block-diffusion generation "
+                "calls made after their prefills (a denoising pass each, "
+                "and a commit that rode on none)").inc(step_applications)
     reg.counter("decoder_diffusion_tokens_total",
                 "tokens block-diffusion generation calls generated (rows "
                 "x generated length)").inc(tokens)

@@ -4,7 +4,8 @@ benchmark/models/sdar_decode.py (the Qwen3-MoE block under a
 block-causal mask of 4, all 128 experts eight a token, the logits of
 every position fed) driven through `fluid.ProgramDecoder.diffuse` from
 empty caches at the cell's own size (`--rows` rows x (256 prompt + 768
-generated), blocks of 4, 4 denoising passes and a commit each), and the
+generated), blocks of 4, 4 denoising passes each and a commit that
+rides on the next block's first), and the
 reference's replay of that call's own trajectory
 (benchmark/reference/sdar_moe.py, a layer at a time; what `correct`
 compares in the cell: benchmark/drivers/decode_diffusion.py `compare`).

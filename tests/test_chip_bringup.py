@@ -406,13 +406,16 @@ def test_the_latent_decode_kernel_takes_32_heads(block, name):
 
 
 @pytest.mark.parametrize("block,kernel_name", [
-    (4, "gqa_decode_k1024_t4_b4"), (128, "gqa_decode_k1024_t128_b4")])
+    (4, "gqa_decode_k1024_t4_b4"), (128, "gqa_decode_k1024_t128_b4"),
+    (8, "gqa_decode_k1024_t8_b4")])
 def test_the_block_causal_walk_lowers_for_tpu(block, kernel_name):
     """`cached_attention` under `diffusion_block` 4 at sdar-diffuse-pp8's
     shapes (128 rows, 32 query heads over 4 key/value heads of 128, a
     1024-slot bfloat16 cache) lowered for the TPU from this CPU host: a
-    pass over one block of 4 and a prefill block of 128 positions both
-    walk the live slots, and the kernel's name says the mask's block."""
+    pass over one block of 4, a prefill block of 128 positions and a
+    block's first pass over 8 (the block before's commit and its own)
+    all walk the live slots, and the kernel's name says the mask's
+    block."""
     from paddle_tpu.ops import registry
 
     kernel = registry.get_op_info("cached_attention").kernel

@@ -28,7 +28,14 @@ after it, with the rows a served step carries out of the state it was
 handed read beside it (two rows' first heads, a head at a time: the
 slice says `own_layout`, without which the compiler lays the whole
 state out anew every step for that transpose's sake, `{1,2,0}`, 0.83 ms
-a layer of `granite-decode-ep4`: PERF.md section 6, PR 72)."""
+a layer of `granite-decode-ep4`: PERF.md section 6, PR 72).
+
+And of generation by diffusion over blocks (`models/decode.py
+block_diffusion_decode`), whose blocks start on one application of the
+step over 2B positions, the block before's commit and their own first
+denoising pass: of that application's logits the rule reads its own
+block's rows where they lie, in the step's type; no float32 array of the
+2B positions' logits is made (PERF.md section 6, PR 74)."""
 
 import os
 import re
@@ -220,3 +227,37 @@ def test_a_mamba_steps_state_is_the_scans_carry(one_chip, no_compile_cache):
     assert made[carried] == "get-tuple-element"
     assert set(made.values()) <= {"parameter", "get-tuple-element",
                                   "bitcast"}, made
+
+
+def test_the_rule_reads_its_own_blocks_rows_of_a_folded_pass(
+        one_chip, no_compile_cache):
+    from paddle_tpu.models import decode
+
+    rows, block, vocab, hidden, extent = 16, 4, 2048, 256, 64
+
+    def of(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def call(embed, head, state, prompt):
+        def step(state, tokens):
+            width = tokens.shape[1]
+            slots = state["pos"][:, None] + jnp.arange(width)
+            seen = state["seen"].at[jnp.arange(rows)[:, None], slots].set(
+                embed[tokens])
+            # a position's logits from every position stored so far
+            stream = embed[tokens] + jnp.mean(seen, axis=1, keepdims=True)
+            # the `mul` op's product: the positions of every row flat
+            logits = jnp.dot(stream.reshape(rows * width, hidden), head)
+            return logits.reshape(rows, width, vocab), {
+                "pos": state["pos"] + width, "seen": seen}
+        return decode.block_diffusion_decode(
+            step, state, prompt, 16, block, 4, vocab - 1)
+
+    text = jax.jit(call).lower(
+        of((vocab, hidden)), of((hidden, vocab)),
+        {"pos": of((rows,), jnp.int32), "seen": of((rows, extent, hidden))},
+        of((rows, 8), jnp.int32)).compile().as_text()
+    kinds = set(re.findall(r"(?:bf16|f32)\[%d,\d+,%d\]" % (rows, vocab),
+                           text))
+    assert "f32[%d,%d,%d]" % (rows, block, vocab) in kinds
+    assert "f32[%d,%d,%d]" % (rows, 2 * block, vocab) not in kinds, kinds

@@ -6,10 +6,12 @@ of every position it is fed (models/diffusion_moe_program.py) and
 `block_diffusion_decode`) against the plain float32 reference
 (models/reference/sdar_moe.py): logits and trajectories for the three
 strategies, T = B and T < B, a threshold some positions clear, a prompt
-with P mod B != 0; the reference's replay of a trajectory against its
-own whole forwards; the head dead in the prefill; the counters and the
-spans' arguments; and two step Programs the repo had, their jaxprs
-unchanged.
+with P mod B != 0, one shorter than a block, one block only; the step's
+applications of a call, a commit riding on the next block's first pass,
+from a step that logs them; the reference's replay of a trajectory
+against its own whole forwards; the head dead in the prefill; the
+counters and the spans' arguments; and two step Programs the repo had,
+their jaxprs unchanged.
 
 Tiny sizes on the CPU: 2 layers, hidden 64, 4 query / 2 key/value heads
 of 16, 8 experts, 2 a token, vocabulary 97 (the mask id its last),
@@ -166,6 +168,10 @@ CASES = {
     "static_leftover": (10, 9, 4, "low_confidence_static", 0.9, 1.0),
     "dynamic_leftover": (11, 8, 4, "low_confidence_dynamic", 0.9, 1.0),
     "sequential_leftover": (9, 7, 2, "sequential", 0.9, 1.0),
+    "static_one_block": (8, 4, 4, "low_confidence_static", 0.9, 1.0),
+    # no whole block in the prompt: the first block starts on a plain pass
+    "dynamic_short_prompt": (3, 9, 4, "low_confidence_dynamic", 0.9, 1.0),
+    "sequential_short_one_block": (2, 2, 2, "sequential", 0.9, 1.0),
 }
 
 
@@ -217,22 +223,34 @@ def test_diffuse_counts_its_passes(diffused, case):
         assert info["denoise_passes"] == blocks * steps
     else:
         assert blocks <= info["denoise_passes"] < blocks * steps
+    # what the passes cost: every commit but the last rode on a
+    # denoising pass, so the step was applied once a denoising pass and
+    # once more
+    assert info["folded_commits"] == blocks - 1
+    assert info["step_applications"] == info["denoise_passes"] + 1
     assert counted["decoder_diffusion_passes_total{kind=denoise}"] \
         == info["denoise_passes"]
     assert counted["decoder_diffusion_passes_total{kind=commit}"] == blocks
     assert counted["decoder_diffusion_blocks_total"] == blocks
+    assert counted.get("decoder_diffusion_folded_commits_total", 0) \
+        == blocks - 1
+    assert counted["decoder_diffusion_applications_total"] \
+        == info["step_applications"]
     assert counted["decoder_diffusion_tokens_total"] == ROWS * gen
     assert counted["decoder_calls_total{mode=diffuse}"] == 1
     assert counted["decoder_tokens_total{kind=generated}"] == ROWS * gen
 
 
 @pytest.mark.parametrize("case", ["dynamic_floor", "static_leftover",
-                                  "dynamic_cleared"])
+                                  "dynamic_cleared", "sequential_T<B",
+                                  "static_one_block",
+                                  "dynamic_short_prompt"])
 def test_the_cache_keeps_the_commit_pass(diffused, case):
     """The first layer's keys and values after the call are the whole
     forward's of the final sequence: no pass whose input held a mask
-    left anything (rule 4), and the position ends at the last block's
-    end."""
+    left anything (rule 4), wherever a block's commit ran (on the next
+    block's first pass, or alone after the last), and the position ends
+    at the last block's end, B a block further and not 2B."""
     (tokens, _, info), _, _, (prompt, params) = diffused[case]
     final = np.concatenate([prompt, tokens], axis=1)
     whole = final.shape[1] // BLOCK * BLOCK
@@ -373,6 +391,7 @@ def test_the_spans_say_what_the_call_was(built):
     assert call["mode"] == "diffuse" and call["max_len"] == 8
     assert call["block_length"] == BLOCK and call["denoising_steps"] == 4
     assert call["denoise_passes"] == 8 and call["commit_passes"] == 2
+    assert call["folded_commits"] == 1 and call["step_applications"] == 9
     assert call["prompt_len"] == 8
 
 
@@ -383,12 +402,15 @@ def test_the_lowering_says_block_causal(built):
         np.zeros((ROWS, 16), "int32"), 4, BLOCK, 4, "sequential", 0.9,
         MASK, init_state=_init())
     counted = telemetry.snapshot_delta(before)
-    # the prefill's op (two applications of 8: PREFILL_BLOCK cut to the
-    # extent by the test's decoder), a denoising pass's and a commit's
+    # the prefill's op (one application of 16), a block's first pass's
+    # (the block before and its own), a later pass's and the last
+    # commit's
     by_block = {key: value for key, value in counted.items()
                 if key.startswith("block_causal_attention_lowerings_total")}
     assert by_block == {
         "block_causal_attention_lowerings_total{block=16,"
+        "diffusion_block=4,path=plain}": L,
+        "block_causal_attention_lowerings_total{block=8,"
         "diffusion_block=4,path=plain}": L,
         "block_causal_attention_lowerings_total{block=4,"
         "diffusion_block=4,path=plain}": 2 * L}
@@ -398,8 +420,11 @@ def test_the_lowering_says_block_causal(built):
 
 
 def test_the_scopes_of_a_call(built):
-    """`diffusion_denoise`, `diffusion_unmask` and `diffusion_commit`
-    under `decode_steps`, the prefill under `decode_prefill`."""
+    """`diffusion_denoise` and `diffusion_unmask` inside the scan of
+    blocks under `decode_steps`, a block's first pass under
+    `diffusion_fold` inside `diffusion_denoise` and before the loop of
+    the others, the last commit under `diffusion_commit` after the scan;
+    the prefill under `decode_prefill`."""
     scope = _start(built["startup"])
     decoder = _decoder(built, scope)
     step = decoder._step_fn(decoder._params)
@@ -407,17 +432,136 @@ def test_the_scopes_of_a_call(built):
         step, s, p, 8, BLOCK, 4, MASK)).lower(
             _init(), jnp.zeros((ROWS, 16), jnp.int32)).compile().as_text()
     names = set(re.findall(r'op_name="([^"]*)"', text))
-    for inner in (decode.DENOISE_SCOPE, decode.UNMASK_SCOPE,
-                  decode.COMMIT_SCOPE):
-        assert any(re.search(r"/%s/while/body/(.*/)?%s/"
-                             % (decode.STEPS_SCOPE, inner), n)
-                   for n in names), inner
+    steps = [n.split("/%s/" % decode.STEPS_SCOPE, 1)[1] for n in names
+             if "/%s/" % decode.STEPS_SCOPE in n]
+    for inner in (decode.DENOISE_SCOPE, decode.UNMASK_SCOPE):
+        assert any(re.match(r"while/body/(.*/)?%s/" % inner, n)
+                   for n in steps), inner
+    fold = "%s/%s/" % (decode.DENOISE_SCOPE, decode.FOLD_SCOPE)
+    folded = [n for n in steps if fold in n]
+    # in the scan of blocks' body, not in the loop of the later passes
+    assert folded and all(re.match(r"while/body/(closed_call/)?%s" % fold, n)
+                          for n in folded)
+    assert any(re.match(r"while/body/(.*/)?while/body/(.*/)?%s/(?!%s)"
+                        % (decode.DENOISE_SCOPE, decode.FOLD_SCOPE), n)
+               for n in steps)
+    assert not [n for n in steps if decode.FOLD_SCOPE in n
+                and decode.UNMASK_SCOPE in n]
+    committed = [n for n in steps if decode.COMMIT_SCOPE in n]
+    assert committed and all(n.startswith(decode.COMMIT_SCOPE + "/")
+                             for n in committed)
     # the block-causal attention under a pass's scope, by its own name
     assert any(re.search(r"%s/.*cached_attention/.*attn_block_causal"
                          % decode.DENOISE_SCOPE, n) for n in names)
     prefill = [n for n in names if "/%s/" % decode.PREFILL_SCOPE in n]
     assert prefill      # one application: 16 positions are no scan
     assert not [n for n in prefill if decode.STEPS_SCOPE in n]
+
+
+# -- the step's applications of a call ---------------------------------------------------
+
+LOGGED_V, LOGGED_EXTENT, LOGGED_MOST = 13, 32, 32
+
+
+def _logged_step(state, tokens):
+    """A step of no model that logs its applications: (the first row's
+    position, the positions fed) at the call's running count, the tokens
+    fed stored at their positions; flat bfloat16 logits of a position
+    and its token (rows of a table: nothing wider is made in another
+    type), which clear no threshold."""
+    rows, width = tokens.shape
+    slots = state["pos"][:, None] + jnp.arange(width)
+    table = jnp.sin(1.3 * jnp.arange(LOGGED_V)[:, None]
+                    + 0.37 * jnp.arange(LOGGED_V)).astype(jnp.bfloat16)
+    return table[(tokens + slots) % LOGGED_V], {
+        "pos": state["pos"] + width,
+        "stored": state["stored"].at[jnp.arange(rows)[:, None],
+                                     slots].set(tokens),
+        "log": state["log"].at[state["applied"]].set(
+            jnp.stack([state["pos"][0], jnp.int32(width)])),
+        "applied": state["applied"] + 1}
+
+
+def _logged_state():
+    return {"pos": jnp.zeros((ROWS,), jnp.int32),
+            "stored": jnp.full((ROWS, LOGGED_EXTENT), -1, jnp.int32),
+            "log": jnp.full((LOGGED_MOST, 2), -1, jnp.int32),
+            "applied": jnp.int32(0)}
+
+
+def _floor_passes(masked, steps, dynamic):
+    """The denoising passes of a block of `masked` masks where no
+    confidence clears the threshold."""
+    if not dynamic:
+        return steps
+    fixed = np.cumsum(decode._transfers(BLOCK, steps))
+    return int(np.searchsorted(fixed, masked)) + 1
+
+
+@pytest.mark.parametrize("how", decode.REMASKING)
+@pytest.mark.parametrize("length, gen, steps", [
+    (8, 12, 4), (8, 12, 2), (10, 9, 4), (8, 4, 4), (3, 9, 4), (2, 2, 2)])
+def test_a_commit_rides_on_the_next_blocks_first_pass(how, length, gen,
+                                                      steps):
+    """Every application of the step a call makes, in order, by where it
+    stood and how many positions it took: the prefill, then a block's
+    first pass over 2B positions from the block before's first position
+    (the prompt's last whole block before the first generated one; a
+    plain pass where the prompt has none), its later passes over B from
+    its own, B further and not 2B, and one commit of B after the last
+    block: `blocks * T + 1` applications at the floor where a commit
+    pass a block took `blocks * (T + 1)`."""
+    prompt = np.random.RandomState(length).randint(
+        0, LOGGED_V - 1, (ROWS, length)).astype("int32")
+    toks, _, passes, at, _, state = jax.jit(
+        lambda state, prompt: decode.block_diffusion_decode(
+            _logged_step, state, prompt, gen, BLOCK, steps, LOGGED_V - 1,
+            how, 0.9))(_logged_state(), prompt)
+    whole, left = length // BLOCK * BLOCK, length % BLOCK
+    blocks = -(-(left + gen) // BLOCK)
+    want = [(0, whole)] if whole else []
+    for n in range(blocks):
+        start = whole + n * BLOCK
+        took = _floor_passes(BLOCK - left if n == 0 else BLOCK, steps,
+                             how == "low_confidence_dynamic")
+        first = (start - BLOCK, 2 * BLOCK) if start else (0, BLOCK)
+        want += [first] + [(start, BLOCK)] * (took - 1)
+    want.append((whole + (blocks - 1) * BLOCK, BLOCK))
+    applied = int(state["applied"])
+    assert [tuple(entry) for entry in np.asarray(state["log"])[:applied]] \
+        == want
+    prefills = 1 if whole else 0
+    assert int(passes["applications"]) == applied - prefills \
+        == int(passes["denoise"]) + 1
+    if how != "low_confidence_dynamic" or not left:
+        assert int(passes["applications"]) == blocks * steps + 1
+    assert int(passes["commit"]) == blocks
+    assert int(passes["folded"]) == blocks - 1
+    wide = sum(1 for _, width in want[prefills:] if width == 2 * BLOCK)
+    assert wide == (blocks if whole else blocks - 1)
+    # what the applications left: every position's final token, the
+    # position at the last block's end
+    assert (np.asarray(state["pos"]) == whole + blocks * BLOCK).all()
+    final = np.concatenate([prompt, np.asarray(toks)], axis=1)
+    np.testing.assert_array_equal(
+        np.asarray(state["stored"])[:, :length + gen], final)
+    assert (np.asarray(at) >= 0).all()
+
+
+def test_the_rule_sees_its_own_blocks_logits_alone():
+    """Of the 2B positions' logits a block's first pass makes, the rule
+    is handed the last B before anything casts or reduces them: the
+    traced call holds the step's bfloat16 [rows, 2B, vocab] and no
+    float32 array of that shape, where the rule's own float32 [rows, B,
+    vocab] is (the CPU's compiler widens bfloat16 by itself; what the
+    TPU's makes of it: tests/test_compiled_placement.py)."""
+    traced = str(jax.make_jaxpr(lambda state, prompt: (
+        decode.block_diffusion_decode(
+            _logged_step, state, prompt, 8, BLOCK, 4, LOGGED_V - 1)))(
+                _logged_state(), jnp.zeros((ROWS, 8), jnp.int32)))
+    wide = "[%d,%d,%d]" % (ROWS, 2 * BLOCK, LOGGED_V)
+    assert "bf16" + wide in traced and "f32" + wide not in traced
+    assert "f32[%d,%d,%d]" % (ROWS, BLOCK, LOGGED_V) in traced
 
 
 # -- the op under the block-causal mask ------------------------------------------------
@@ -445,13 +589,15 @@ def _dense(q, k_cache, v_cache, pos, heads, kv_heads, block):
 
 @pytest.mark.parametrize("path, dim", [("plain", 16), ("kernel", 128)])
 @pytest.mark.parametrize("positions, pos", [(4, 0), (4, 124), (128, 0),
-                                            (128, 128), (8, 248)])
+                                            (128, 128), (8, 248), (8, 244)])
 def test_the_op_under_the_block_causal_mask(path, dim, positions, pos):
     """`cached_attention` with `diffusion_block` 4 against a dense masked
-    softmax: a pass (T = B), a prefill block (T = 128) and two blocks,
-    from an empty cache and behind stored slots, the plain path (16-wide
-    heads) and the walk of the live slots (128-wide heads, the kernel
-    under the interpreter)."""
+    softmax: a pass (T = B), a prefill block (T = 128) and two blocks
+    (a commit and the next block's first pass in one application, from a
+    position that is a multiple of 2B and from one of B alone), from an
+    empty cache and behind stored slots, the plain path (16-wide heads)
+    and the walk of the live slots (128-wide heads, the kernel under the
+    interpreter)."""
     heads, kv_heads, rows, extent = 4, 2, 2, 256
     rs = np.random.RandomState(positions + pos)
     q = rs.randn(rows, positions, heads * dim).astype("float32")
