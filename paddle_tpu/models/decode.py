@@ -285,34 +285,68 @@ def _transfers(block_length, denoising_steps):
                        jnp.int32)
 
 
+def _max_and_first(values):
+    """(the largest, the first index that holds it) along the last axis
+    of float32 `values`, in one variadic reduce: one read for the two."""
+    axis = values.ndim - 1
+    index = jax.lax.broadcasted_iota(jnp.int32, values.shape, axis)
+
+    def larger(a, b):
+        take = (a[0] > b[0]) | ((a[0] == b[0]) & (a[1] < b[1]))
+        return jnp.where(take, a[0], b[0]), jnp.where(take, a[1], b[1])
+
+    return jax.lax.reduce(
+        (values, index), (jnp.float32(-jnp.inf), jnp.int32(0)), larger,
+        (axis,))
+
+
 def _unmask(logits, masked, k, remasking, threshold, temperature, top_k,
             key, mask_id):
     """(x0, conf, fix) [rows, B] of one denoising pass: each position's
-    own prediction from its own row of `logits` [rows, B, V] (no shift),
-    the probability it was predicted at, and which of the `masked`
-    positions the pass fixes; `k` is the pass's k_s.  The mask token is
-    no prediction: its logit counts for nothing, in the choice and in
-    the probabilities (the published loop leaves it in, which a trained
-    model never picks and seeded weights do once in a vocabulary's worth
-    of positions: a position fixed to it would be masked again)."""
-    logits = jnp.where(jnp.arange(logits.shape[-1]) == mask_id, NEG_INF,
-                       logits.astype(jnp.float32))
+    own prediction from its own row of `logits` (no shift), the
+    probability it was predicted at, and which of the `masked` [rows, B]
+    positions the pass fixes; `k` is the pass's k_s.  `logits` is the
+    step's [rows, T, V], T = B or, from a block's first pass that
+    carries a commit, 2B of which the last B are the block's own.  The
+    mask token is no prediction: its logit counts for nothing, in the
+    choice and in the probabilities (the published loop leaves it in,
+    which a trained model never picks and seeded weights do once in a
+    vocabulary's worth of positions: a position fixed to it would be
+    masked again).
+
+    The reductions over the vocabulary run on the logits as the head's
+    product left them, [rows x T, V] in the step's own type, and widen
+    to float32 inside themselves; their [rows x T] results are shaped
+    afterwards.  ([rows, B, V] puts B positions in a tile of 8 or 16
+    sublanes: a cast or a mask of that array is a relayout of the whole
+    of it.)  Greedy, a 2B pass's every row is reduced and the last B
+    results of a row are read: a slice of the logits in front would be
+    an array of its own.  x0 is the first largest logit and conf =
+    exp(max - logsumexp) = 1 / sum(exp(l - max)), so nothing is
+    gathered; a sample's logit is, from its own B positions' rows."""
+    rows, width = masked.shape
+    vocab = logits.shape[-1]
+
+    def struck(flat):
+        return jnp.where(jnp.arange(vocab) == mask_id, NEG_INF,
+                         flat.astype(jnp.float32))
+
     if temperature > 0:
-        logits = logits / temperature
+        flat = struck(logits[:, -width:].reshape(-1, vocab)) / temperature
         if top_k:
-            kth = jax.lax.top_k(logits, top_k)[0][..., -1:]
-            logits = jnp.where(logits < kth, NEG_INF, logits)
-        x0 = jax.random.categorical(key, logits, axis=-1)
+            kth = jax.lax.top_k(flat, top_k)[0][..., -1:]
+            flat = jnp.where(flat < kth, NEG_INF, flat)
+        x0 = jax.random.categorical(key, flat, axis=-1)
+        conf = jnp.exp(
+            jnp.take_along_axis(flat, x0[..., None], axis=-1)[..., 0]
+            - jax.nn.logsumexp(flat, axis=-1))
     else:
-        x0 = jnp.argmax(logits, axis=-1)
-    # softmax(l)[x0] from two reductions: no [rows, B, V] of
-    # probabilities is made
-    conf = jnp.exp(
-        jnp.take_along_axis(logits, x0[..., None], axis=-1)[..., 0]
-        - jax.nn.logsumexp(logits, axis=-1))
+        flat = struck(logits.reshape(-1, vocab))
+        most, x0 = _max_and_first(flat)
+        conf = 1 / jnp.sum(jnp.exp(flat - most[:, None]), axis=-1)
+    x0, conf = (x.reshape(rows, -1)[:, -width:] for x in (x0, conf))
     x0 = x0.astype(jnp.int32)
     conf = jnp.where(masked, conf, -jnp.inf)
-    width = masked.shape[-1]
     if remasking == "sequential":
         fix = masked & (jnp.cumsum(masked, axis=-1) <= k)
         return x0, conf, fix
@@ -355,8 +389,10 @@ def block_diffusion_decode(step_fn, init_state, prompt, gen_len,
     tokens c; its state is handed on but for `hold`, so the slots it
     wrote are overwritten by the next pass and nothing ever reads them
     (no position advanced, and no later block exists yet).  The rule
-    (`diffusion_unmask`) takes x0 = argmax (`temperature` 0) or a sample
-    (temperature, `top_k`), conf = softmax(l)[x0] where c is masked, and
+    (`diffusion_unmask`, `_unmask`: its reductions over the vocabulary
+    run on the logits flat, [rows x T, V] in the step's type) takes x0 =
+    argmax (`temperature` 0) or a sample (temperature, `top_k`), conf =
+    softmax(l)[x0] where c is masked, and
     with k_s = B // T + (s < B mod T) fixes, of the masked positions:
     "low_confidence_static" the k_s of largest conf;
     "low_confidence_dynamic" every one with conf > `confidence_threshold`
@@ -368,8 +404,11 @@ def block_diffusion_decode(step_fn, init_state, prompt, gen_len,
     A commit rides on the next block's first denoising pass
     (`diffusion_fold`, inside `diffusion_denoise`): one application over
     the 2B positions [the block before's final tokens | c] from the
-    block before's first position, of whose logits the last B rows are
-    read and whose state is kept with the position B further.  Under the
+    block before's first position, whose state is kept with the position
+    B further; the rule reduces its logits whole, [rows x 2B, V] as the
+    head left them, and reads the last B of a row's 2B results (the
+    first B's are computed and dropped: a slice of the logits in front
+    of the rule would be an array of its own).  Under the
     mask that is the commit and the pass as two applications would give
     them (the block before sees the cache and itself; c sees the cache,
     the block before as just stored, and itself) and every weight is
@@ -436,9 +475,6 @@ def block_diffusion_decode(step_fn, init_state, prompt, gen_len,
                 with jax.named_scope(FOLD_SCOPE):
                     logits, new = step_fn(
                         state, jnp.concatenate([before, c], axis=1))
-                    # before anything casts or reduces them: the rule
-                    # never sees the block before's rows
-                    logits = logits[:, block_length:]
             state = standing(new, state,
                              0 if before is None else block_length)
         with jax.named_scope(UNMASK_SCOPE):

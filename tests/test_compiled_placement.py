@@ -31,12 +31,18 @@ state out anew every step for that transpose's sake, `{1,2,0}`, 0.83 ms
 a layer of `granite-decode-ep4`: PERF.md section 6, PR 72).
 
 And of generation by diffusion over blocks (`models/decode.py
-block_diffusion_decode`), whose blocks start on one application of the
-step over 2B positions, the block before's commit and their own first
-denoising pass: of that application's logits the rule reads its own
-block's rows where they lie, in the step's type; no float32 array of the
-2B positions' logits is made (PERF.md section 6, PR 74)."""
+block_diffusion_decode`), whose rule (`_unmask`) reduces a pass's logits
+where the head's product left them: the compiled call holds no float32
+array with the vocabulary's extent, of a later pass's [rows, B] positions
+or of a block's first pass's [rows, 2B]; the rule's reducing fusions (max
+with argmax, the sum of exponentials) take the product's bfloat16
+[rows x T, vocab] result itself; and no `reshape` or `copy` makes another
+array of that size (before, the rule cast `[rows, B, vocab]`, whose tiles
+hold B positions in 8 or 16 sublanes, so the cast was a relayout: two
+float32 copies and three relayouts a pass, 2.35 s of a 19.45 s call of
+`sdar-diffuse-pp8`: PERF.md section 6, PR 75)."""
 
+import math
 import os
 import re
 
@@ -111,7 +117,7 @@ def _compiled_scan(chip, rows, heads, slots, nope, value, sink):
 
 
 _INSTRUCTION = re.compile(
-    r"^\s*(?:ROOT )?%([\w.\-]+) = (\S+) ([\w\-]+)\((.*?)\)(?:, (.*))?$")
+    r"^\s*(?:ROOT )?%([\w.\-]+) = (\(.*?\)|\S+) ([\w\-]+)\((.*?)\)(?:, (.*))?$")
 
 
 def _instructions(text):
@@ -229,11 +235,28 @@ def test_a_mamba_steps_state_is_the_scans_carry(one_chip, no_compile_cache):
                                   "bitcast"}, made
 
 
-def test_the_rule_reads_its_own_blocks_rows_of_a_folded_pass(
+def _arrays(text):
+    """`_instructions` of a compiled module outside its fusions' own
+    computations: what writes an array of its own (inside a fusion
+    nothing does)."""
+    fused = set(re.findall(r"calls=%([\w.\-]+)", text))
+    kept, keep = [], True
+    for line in text.splitlines():
+        m = re.match(r"^(?:ENTRY )?%([\w.\-]+) \(.*\{$", line)
+        if m:
+            keep = m.group(1) not in fused
+        elif keep:
+            kept.append(line)
+    return _instructions("\n".join(kept))
+
+
+def test_the_rule_reduces_the_logits_where_the_head_left_them(
         one_chip, no_compile_cache):
     from paddle_tpu.models import decode
 
-    rows, block, vocab, hidden, extent = 16, 4, 2048, 256, 64
+    # a vocabulary that no other axis shares, rows x T that no slice of
+    # the head has
+    rows, block, vocab, hidden, extent = 24, 4, 2176, 256, 64
 
     def of(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -246,9 +269,11 @@ def test_the_rule_reads_its_own_blocks_rows_of_a_folded_pass(
                 embed[tokens])
             # a position's logits from every position stored so far
             stream = embed[tokens] + jnp.mean(seen, axis=1, keepdims=True)
-            # the `mul` op's product: the positions of every row flat
-            logits = jnp.dot(stream.reshape(rows * width, hidden), head)
-            return logits.reshape(rows, width, vocab), {
+            # the `mul` op's product: the positions of every row flat,
+            # accumulated in float32 and handed on in the step's type
+            logits = jnp.dot(stream.reshape(rows * width, hidden), head,
+                             preferred_element_type=jnp.float32)
+            return logits.astype(jnp.bfloat16).reshape(rows, width, vocab), {
                 "pos": state["pos"] + width, "seen": seen}
         return decode.block_diffusion_decode(
             step, state, prompt, 16, block, 4, vocab - 1)
@@ -257,7 +282,33 @@ def test_the_rule_reads_its_own_blocks_rows_of_a_folded_pass(
         of((vocab, hidden)), of((hidden, vocab)),
         {"pos": of((rows,), jnp.int32), "seen": of((rows, extent, hidden))},
         of((rows, 8), jnp.int32)).compile().as_text()
-    kinds = set(re.findall(r"(?:bf16|f32)\[%d,\d+,%d\]" % (rows, vocab),
-                           text))
-    assert "f32[%d,%d,%d]" % (rows, block, vocab) in kinds
-    assert "f32[%d,%d,%d]" % (rows, 2 * block, vocab) not in kinds, kinds
+    made = _arrays(text)
+    wide = {n: kind for n, (kind, *_) in made.items()
+            if re.match(r"\w+\[(\d+,)+%d\]" % vocab, kind)}
+    # no float32 array of the vocabulary's extent, in any shape
+    assert not [n for n, kind in wide.items() if kind.startswith("f32")], wide
+    # of a pass's size ([rows x T, vocab], [rows, T, vocab]) the call
+    # holds the head's product in the step's type, a later pass's and a
+    # block's first pass's, and nothing else: no `reshape`, no `copy`, no
+    # second form of the logits
+    sized = {}
+    for n, kind in wide.items():
+        *lead, _ = (int(d) for d in kind[kind.index("[") + 1:
+                                         kind.index("]")].split(","))
+        width, rest = divmod(math.prod(lead), rows)
+        if not rest and width in (block, 2 * block) \
+                and made[n][1] not in ("parameter", "get-tuple-element",
+                                       "bitcast"):
+            sized[n] = width
+    assert sorted(sized.values()) == [block, 2 * block], \
+        {n: made[n][:2] for n in sized}
+    for n, width in sized.items():
+        kind, op, _, rest = made[n]
+        assert kind.startswith("bf16[%d,%d]{" % (rows * width, vocab)) \
+            and op == "fusion" and "kind=kOutput" in rest, made[n]
+        # read by the rule's two reducing fusions and by nothing else
+        readers = {m: made[m] for m in made if n in made[m][2]}
+        assert len(readers) == 2, (n, readers)
+        for _, op, _, rest in readers.values():
+            assert op == "fusion" and decode.UNMASK_SCOPE in rest \
+                and "reduce" in rest, (n, readers)

@@ -8,7 +8,8 @@ of every position it is fed (models/diffusion_moe_program.py) and
 strategies, T = B and T < B, a threshold some positions clear, a prompt
 with P mod B != 0, one shorter than a block, one block only; the step's
 applications of a call, a commit riding on the next block's first pass,
-from a step that logs them; the reference's replay of a trajectory
+from a step that logs them; the rule alone (`_unmask`) against a plain
+float64 one; the reference's replay of a trajectory
 against its own whole forwards; the head dead in the prefill; the
 counters and the spans' arguments; and two step Programs the repo had,
 their jaxprs unchanged.
@@ -548,20 +549,136 @@ def test_a_commit_rides_on_the_next_blocks_first_pass(how, length, gen,
     assert (np.asarray(at) >= 0).all()
 
 
+def _equations(jaxpr, under=""):
+    """(scopes, primitive, the shapes it reads, the shapes it writes) of
+    every equation of a jaxpr and of the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        scopes = "%s/%s" % (under, eqn.source_info.name_stack)
+        yield (scopes, eqn.primitive.name,
+               [v.aval for v in eqn.invars if hasattr(v.aval, "shape")],
+               [v.aval for v in eqn.outvars])
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(inner, scopes)
+
+
 def test_the_rule_sees_its_own_blocks_logits_alone():
-    """Of the 2B positions' logits a block's first pass makes, the rule
-    is handed the last B before anything casts or reduces them: the
-    traced call holds the step's bfloat16 [rows, 2B, vocab] and no
-    float32 array of that shape, where the rule's own float32 [rows, B,
-    vocab] is (the CPU's compiler widens bfloat16 by itself; what the
-    TPU's makes of it: tests/test_compiled_placement.py)."""
-    traced = str(jax.make_jaxpr(lambda state, prompt: (
+    """The rule reduces the logits flat, in the shape the head's product
+    gave them: the traced call holds the step's bfloat16 [rows, T,
+    vocab], T = B and 2B, and no float32 array of either shape; under
+    `diffusion_unmask` every array with the vocabulary's extent but the
+    rule's own input is [rows x T, vocab], a block's first pass's all 2B
+    positions a row (no slice of the logits stands in front of the
+    rule), and the greedy rule gathers nothing (the CPU's half; what the
+    TPU's compiler makes of it, no float32 array at all:
+    tests/test_compiled_placement.py)."""
+    traced = jax.make_jaxpr(lambda state, prompt: (
         decode.block_diffusion_decode(
             _logged_step, state, prompt, 8, BLOCK, 4, LOGGED_V - 1)))(
-                _logged_state(), jnp.zeros((ROWS, 8), jnp.int32)))
-    wide = "[%d,%d,%d]" % (ROWS, 2 * BLOCK, LOGGED_V)
-    assert "bf16" + wide in traced and "f32" + wide not in traced
-    assert "f32[%d,%d,%d]" % (ROWS, BLOCK, LOGGED_V) in traced
+                _logged_state(), jnp.zeros((ROWS, 8), jnp.int32))
+    text = str(traced)
+    for width in (BLOCK, 2 * BLOCK):
+        wide = "[%d,%d,%d]" % (ROWS, width, LOGGED_V)
+        assert "bf16" + wide in text and "f32" + wide not in text
+    rule = [eqn for eqn in _equations(traced.jaxpr)
+            if decode.UNMASK_SCOPE in eqn[0]]
+    assert "gather" not in {name for _, name, _, _ in rule}
+    shapes = {(name, aval.shape, str(aval.dtype))
+              for _, name, read, written in rule
+              for aval in read + written
+              if aval.shape[-1:] == (LOGGED_V,) and len(aval.shape) > 1}
+    flat = {(ROWS * BLOCK, LOGGED_V), (ROWS * 2 * BLOCK, LOGGED_V)}
+    assert {shape for _, shape, _ in shapes if len(shape) == 2} == flat
+    assert {entry for entry in shapes if len(entry[1]) != 2} == {
+        ("reshape", (ROWS, width, LOGGED_V), "bfloat16")
+        for width in (BLOCK, 2 * BLOCK)}
+    reduced = {read[0].shape for _, name, read, _ in rule
+               if name.startswith(("reduce", "argm")) and read
+               and read[0].shape[-1] == LOGGED_V}
+    assert reduced == flat
+
+
+# -- the rule against a plain one -----------------------------------------------------
+
+RULE_V, RULE_MASK, RULE_ROWS, RULE_K = 203, 131, 6, 2
+
+
+def _plain_rule(logits, masked, k, remasking, threshold, mask_id):
+    """The rule in float64 numpy over the last B positions of a row:
+    softmax over the vocabulary without the mask token, the first
+    largest, the three strategies."""
+    width = masked.shape[1]
+    scores = np.asarray(logits, np.float64)[:, -width:].copy()
+    scores[..., mask_id] = -np.inf
+    x0 = scores.argmax(-1)                      # the first on a tie
+    probs = np.exp(scores - scores.max(-1, keepdims=True))
+    probs /= probs.sum(-1, keepdims=True)
+    conf = np.where(masked, np.take_along_axis(
+        probs, x0[..., None], -1)[..., 0], -np.inf)
+    if remasking == "sequential":
+        return x0, conf, masked & (np.cumsum(masked, -1) <= k)
+    rank = np.argsort(np.argsort(-conf, axis=-1, kind="stable"), axis=-1,
+                      kind="stable")
+    fix = masked & (rank < k)
+    if remasking == "low_confidence_dynamic":
+        high = conf > threshold
+        fix = np.where(high.sum(-1, keepdims=True) >= k, high, fix)
+    return x0, conf, fix
+
+
+def _rule_logits(dtype, positions, cleared):
+    """[rows, positions, V] in `dtype` and masked [rows, B]: the last B
+    positions of a row are its own (a 2B pass's first B hold larger
+    logits that count for nothing); at `cleared` the rows' first 0, 1, 2
+    ... own positions carry a peak that clears 0.9, so that some rows
+    have fewer than k over the threshold and some k or more; a planted
+    tie of two largest logits, and a position whose largest is the mask
+    token's."""
+    rs = np.random.RandomState(positions + cleared)
+    logits = rs.randn(RULE_ROWS, positions, RULE_V) * 2.0
+    logits[:, :positions - BLOCK] += 30.0 * rs.rand(
+        RULE_ROWS, positions - BLOCK, RULE_V)
+    own = logits[:, positions - BLOCK:]
+    if cleared:
+        for row in range(RULE_ROWS):
+            for at in range(min(row, BLOCK)):
+                own[row, at, rs.randint(RULE_V - 1)] += 16.0
+    own[0, 3, [57, 150]] = 11.0                 # a tie: 57 is taken
+    own[1, 2, RULE_MASK] = 40.0                 # the mask token's: not taken
+    masked = np.ones((RULE_ROWS, BLOCK), bool)
+    masked[2] = [True, False, True, True]
+    masked[-1] = [True, False, False, True]
+    return jnp.asarray(logits, dtype), masked
+
+
+@pytest.mark.parametrize("cleared", [False, True])
+@pytest.mark.parametrize("positions", [BLOCK, 2 * BLOCK])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("remasking", decode.REMASKING)
+def test_the_rule_against_a_plain_one(remasking, dtype, positions, cleared):
+    """`_unmask` on [rows, T, V], T = B and 2B read at the last B, in the
+    step's type and in float32, against the float64 rule on the same
+    values: the same tokens (every position's), the same positions
+    fixed, the confidences to float32's rounding."""
+    logits, masked = _rule_logits(dtype, positions, cleared)
+    x0, conf, fix = decode._unmask(
+        logits, jnp.asarray(masked), jnp.int32(RULE_K), remasking, 0.9, 0.0,
+        0, None, RULE_MASK)
+    want_x0, want_conf, want_fix = _plain_rule(
+        np.asarray(logits.astype(jnp.float32)), masked, RULE_K, remasking,
+        0.9, RULE_MASK)
+    assert x0.dtype == jnp.int32 and conf.dtype == jnp.float32
+    np.testing.assert_array_equal(x0, want_x0)
+    assert x0[0, 3] == 57 and x0[1, 2] != RULE_MASK
+    np.testing.assert_array_equal(fix, want_fix)
+    np.testing.assert_allclose(conf, want_conf, rtol=1e-5)
+    assert not (np.asarray(fix) & ~masked).any()
+    over = (want_conf > 0.9).sum(-1)
+    if remasking == "low_confidence_dynamic" and cleared:
+        # rows under k over the threshold and rows at k or over it
+        assert (over < RULE_K).any() and (over > RULE_K).any()
+        assert (np.asarray(fix).sum(-1) > RULE_K).any()
+    if not cleared:
+        assert not over.any()
 
 
 # -- the op under the block-causal mask ------------------------------------------------
